@@ -120,12 +120,13 @@ class HardwareSpec:
                 f"{self.hbm_bytes / 1e9:.1f} GB)")
 
 
-# The bench chip, calibrated against the recorded rounds: peak is the
-# BENCH_r04 measured 191.5 TFLOP/s bf16; ResNet-50 sustains ~1 TB/s HBM
-# at its ~27% roofline (docs/PERF.md); 15.75 GB HBM per chip; matmul_eff
-# + hbm_traffic_fraction are fit so the full-size transformer's
-# predicted MFU lands on the recorded 0.46-0.51 band and ResNet stays
-# bandwidth-bound (tests/test_planner.py pins the band).
+# The v5e chip as fit to records of 2026-07-31 from an installation that
+# no longer exists (BENCH_r04, in git history only): peak 191.5 TFLOP/s
+# bf16 as measured then; hbm_bw from the ResNet-50 trace of that round;
+# 15.75 GB HBM per chip; matmul_eff + hbm_traffic_fraction fit so the
+# full-size transformer's predicted MFU lands on that round's 0.46-0.51
+# band (tests/test_planner.py pins the band). None of it has been
+# re-measured on the current installation (ROADMAP D7).
 TPU_CHIP = HardwareSpec(
     name="tpu-dev-chip", peak_flops=191.5e12, hbm_bw=1.23e12,
     hbm_bytes=15.75e9, ici_bw=9.0e10, launch_us=2.0, dispatch_us=30.0,
@@ -144,14 +145,26 @@ CPU_REHEARSAL = HardwareSpec(
     min_tile=32, parallel_scaling=0.0)
 
 
+# jax `device_kind` -> the spec fitted for it. A chip that is not listed
+# has no fitted constants, and borrowing another chip's would rank meshes
+# against the wrong machine.
+_SPEC_BY_DEVICE_KIND = {"TPU v5 lite": TPU_CHIP}
+
+
 def detect_hardware() -> HardwareSpec:
-    """CPU backends get the rehearsal profile, anything else the chip."""
-    try:
-        import jax
-        platform = jax.devices()[0].platform
-    except Exception:
-        platform = "cpu"
-    return CPU_REHEARSAL if platform == "cpu" else TPU_CHIP
+    """CPU backends get the rehearsal profile; an accelerator gets the
+    spec fitted for its `device_kind`, and an unknown one raises."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return CPU_REHEARSAL
+    spec = _SPEC_BY_DEVICE_KIND.get(dev.device_kind)
+    if spec is None:
+        raise RuntimeError(
+            f"no HardwareSpec for device kind {dev.device_kind!r} "
+            f"(platform {dev.platform!r}); known kinds: "
+            f"{sorted(_SPEC_BY_DEVICE_KIND)} — pass hw= explicitly")
+    return spec
 
 
 # ---------------------------------------------------------------------------

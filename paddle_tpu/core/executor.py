@@ -55,13 +55,18 @@ class CPUPlace(Place):
         # local_devices: under multi-controller jax, jax.devices()[0] can
         # belong to ANOTHER process — computing there would leave this
         # process holding arrays with no addressable shards
-        for d in jax.local_devices():
-            if d.platform == "cpu":
-                return d
-        return jax.local_devices()[0]
+        return jax.local_devices(backend="cpu")[0]
 
     def __repr__(self):
         return "CPUPlace()"
+
+
+def _cpu_requested() -> bool:
+    """Whether this process asked jax for the CPU backend outright
+    (JAX_PLATFORMS=cpu or the equivalent config update — the test rig
+    and the rehearsal tools). Only then may a TPUPlace resolve to a CPU
+    device."""
+    return (jax.config.jax_platforms or "").split(",")[0].strip() == "cpu"
 
 
 class TPUPlace(Place):
@@ -70,7 +75,16 @@ class TPUPlace(Place):
 
     def jax_device(self):
         devs = jax.local_devices()
-        return devs[self.device_id % len(devs)]
+        if devs[0].platform != "tpu" and not _cpu_requested():
+            raise RuntimeError(
+                f"{self!r}: jax found no TPU (local devices: {devs}) and "
+                f"this process did not ask for the CPU backend — set "
+                f"JAX_PLATFORMS=cpu to rehearse on CPU")
+        if not 0 <= self.device_id < len(devs):
+            raise ValueError(
+                f"{self!r}: device id out of range, jax sees "
+                f"{len(devs)} local device(s)")
+        return devs[self.device_id]
 
     def __repr__(self):
         return f"TPUPlace({self.device_id})"
@@ -289,14 +303,12 @@ def donation_safe() -> bool:
     the same cache is bit-deterministic). Donated mutable state is a
     core perf design (in-place HBM updates), so instead of banning the
     cache, the executor drops donation whenever a compilation cache dir
-    is configured on a CPU backend — the cache is a test/dev iteration
-    lever (tests/conftest.py), never configured on the TPU
-    serving/training path, which keeps full donation."""
-    try:
-        cache_dir = jax.config.jax_compilation_cache_dir
-    except AttributeError:
-        return True
-    return not cache_dir or jax.default_backend() != "cpu"
+    is configured on a CPU backend. The package configures one for every
+    process (paddle_tpu/__init__.py), so CPU runs never donate; on the
+    TPU a warm-cache run is checked bit-identical to the cold one by
+    chip_smoke.py, and donation stays on."""
+    return (not jax.config.jax_compilation_cache_dir
+            or jax.default_backend() != "cpu")
 
 
 class _CompiledProgram:

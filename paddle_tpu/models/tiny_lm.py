@@ -238,7 +238,7 @@ def save_tiny_lm(dirname, sig=None, seed=11, scale=1.0, **sig_kwargs):
 
     prefill, decode_prog, startup, (p_logits, d_logits), sig = \
         build_tiny_lm(sig=sig, seed=seed, **sig_kwargs)
-    exe = fluid.Executor(fluid.CPUPlace())
+    exe = fluid.Executor()
     scope = fluid.Scope()
     exe.run(startup, scope=scope)
     if scale != 1.0:

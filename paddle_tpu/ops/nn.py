@@ -221,9 +221,14 @@ def _dropout(ctx, X):
     # the surrounding chain at ~zero HBM cost, so "auto" prefers it; the
     # kernel stays selectable for A/B via FLAGS dropout_impl=pallas.
     impl_flag = _flags.get_flag("dropout_impl")
-    if (impl_flag == "pallas"
-            and impl == "upscale_in_train" and jax.default_backend() != "cpu"
-            and pallas_dropout.supports(X, p)):
+    if impl_flag == "pallas" and jax.default_backend() != "cpu":
+        # asked for by name: what the kernel cannot take raises rather
+        # than quietly running the XLA path under the kernel's label
+        if impl != "upscale_in_train" or not pallas_dropout.supports(X, p):
+            raise ValueError(
+                f"dropout_impl=pallas supports upscale_in_train dropout "
+                f"with 0 < p < 1 on a lane-aligned minor dim; got "
+                f"implementation {impl!r}, p={p}, shape {X.shape}")
         seed = (jax.random.key_data(ctx.key).reshape(-1)[0]
                 .astype(jnp.int32).reshape(1, 1))
         out = pallas_dropout.dropout_tpu(X, seed, float(p))

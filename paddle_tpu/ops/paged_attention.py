@@ -28,35 +28,49 @@ Two phases share the cache:
   at position ``seq_len - 1``, attend over ``[0, seq_len)`` through the
   block table.
 
-On TPU (or under PADDLE_TPU_PALLAS_INTERPRET=1) the decode read side
-runs as a Pallas kernel streaming cache blocks through the grid's
+On TPU (or, on CPU, under PADDLE_TPU_PALLAS_INTERPRET=1) the decode read
+side runs as a Pallas kernel streaming cache blocks through the grid's
 innermost dimension with the block-table indirection in the index map
-(scalar prefetch); everywhere else a masked-lane jnp reference computes
-the same math — tests pin the reference path bit-identical to dense
-attention on the valid region, and the kernel against the reference
-under the interpreter.
+(scalar prefetch); on a CPU backend without the switch a masked-lane jnp
+reference computes the same math — tests pin the reference path
+bit-identical to dense attention on the valid region, and the kernel
+against the reference under the interpreter. On the TPU there is no
+second path: a cache geometry outside the kernel's envelope raises.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..core.registry import register_op
-from .pallas_attention import NEG_INF, flash_attention
+from .pallas_attention import NEG_INF, _interpret, flash_attention
+
+_LANES = 128
 
 
-def _interpret():
-    return os.environ.get("PADDLE_TPU_PALLAS_INTERPRET", "0") == "1"
-
-
-def _pallas_ok():
-    return jax.default_backend() != "cpu" or _interpret()
+def _pallas_ok(q, k_cache):
+    """Kernel or reference? The reference is a CPU-only path. On the TPU
+    the kernel's blocks must be whole tiles — head_dim a multiple of the
+    128 lanes, block_size * heads a multiple of the cache dtype's sublane
+    tile (8 for float32, 32 for int8) — and anything else raises."""
+    if jax.default_backend() == "cpu":
+        return _interpret()
+    _, H, Dh = q.shape
+    rows = k_cache.shape[1] * H
+    sublanes = 32 // k_cache.dtype.itemsize
+    if Dh % _LANES or rows % sublanes:
+        raise ValueError(
+            f"paged attention on the {jax.default_backend()!r} backend "
+            f"needs head_dim % {_LANES} == 0 and block_size * heads % "
+            f"{sublanes} == 0 for a {k_cache.dtype} cache; got head_dim "
+            f"{Dh}, block_size {k_cache.shape[1]}, heads {H}")
+    return True
 
 
 # ---------------------------------------------------------------------------
@@ -135,15 +149,33 @@ def paged_attention_reference(q, k_cache, v_cache, block_tables, seq_lens,
 # pallas kernel: stream cache blocks via block-table indirection
 # ---------------------------------------------------------------------------
 
-def _paged_decode_kernel(seq_lens_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_sc, l_sc, acc_sc, *, sm_scale, block_size):
+def _paged_decode_kernel(*refs, sm_scale, block_size, quantized):
     """Grid (slot, block-ordinal). The k/v BlockSpec index maps read the
     prefetched block table, so program (s, j) sees the j-th cache block
     of slot s — the paged gather costs a scalar lookup, not a host-side
     reorder. Online-softmax state is carried in VMEM scratch across the
     innermost (sequential) dimension, exactly the flash-attention idiom
-    of ops/pallas_attention.py."""
+    of ops/pallas_attention.py.
+
+    The cache block arrives as a 2-D [block_size * H, Dh] tile (row
+    b * H + h), so both contractions are plain 2-D MXU matmuls: q @ K^T
+    gives [H, block_size * H] scores of every query head against every
+    (position, head) row, and the mask keeps only each head's own rows
+    (and positions inside the sequence). The H-fold surplus of FLOPs is
+    free — a decode step is bound by reading K/V, not by the MXU — and
+    nothing needs a batched dot or an in-kernel transpose.
+
+    `quantized`: the blocks are int8 and two more scalar-prefetch
+    operands carry the per-block K/V scales, applied right after the
+    load."""
     from jax.experimental import pallas as pl
+
+    if quantized:
+        (seq_lens_ref, bt_ref, ks_ref, vs_ref, head_ref, pos_ref, q_ref,
+         k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc) = refs
+    else:
+        (seq_lens_ref, bt_ref, head_ref, pos_ref, q_ref,
+         k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc) = refs
 
     s = pl.program_id(0)
     j = pl.program_id(1)
@@ -162,56 +194,75 @@ def _paged_decode_kernel(seq_lens_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _update():
-        q = q_ref[0]                                    # [H, Dh]
-        k = k_ref[0]                                    # [BS, H, Dh]
-        v = v_ref[0]
-        scores = jnp.einsum(
-            "hd,bhd->hb", q.astype(jnp.float32),
-            k.astype(jnp.float32),
-            preferred_element_type=jnp.float32) * sm_scale  # [H, BS]
-        pos = j * block_size + lax.broadcasted_iota(
-            jnp.int32, scores.shape, 1)
-        scores = jnp.where(pos < seq_len, scores, NEG_INF)
+        q = q_ref[0].astype(jnp.float32)                # [H, Dh]
+        k = k_ref[0].astype(jnp.float32)                # [BS * H, Dh]
+        v = v_ref[0].astype(jnp.float32)
+        if quantized:
+            blk = bt_ref[s, j]
+            k = k * ks_ref[blk]
+            v = v * vs_ref[blk]
+        scores = lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale  # [H, BS * H]
+        row = lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+        keep = (head_ref[...] == row) \
+            & (j * block_size + pos_ref[...] < seq_len)
+        scores = jnp.where(keep, scores, NEG_INF)
+        # m/l live lane-broadcast in [H, 128] scratch; [:, :1] reads the
+        # per-head column back
         m = m_sc[...]
-        m_new = jnp.maximum(m, jnp.max(scores, axis=1))
-        p = jnp.exp(scores - m_new[:, None])
+        m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
+        # an unkept entry must weigh exactly 0 (not exp(NEG_INF - m),
+        # which is 1 while a head's running max is still NEG_INF)
+        p = jnp.where(keep, jnp.exp(scores - m_new[:, :1]), 0.0)
         alpha = jnp.exp(m - m_new)
-        l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=1)
-        acc_sc[...] = acc_sc[...] * alpha[:, None] + jnp.einsum(
-            "hb,bhd->hd", p, v.astype(jnp.float32),
+        l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_sc[...] = acc_sc[...] * alpha[:, :1] + lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_sc[...] = m_new
 
     @pl.when(j == nb - 1)
     def _finalize():
         l = jnp.maximum(l_sc[...], 1e-20)
-        o_ref[0] = (acc_sc[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_sc[...] / l[:, :1]).astype(o_ref.dtype)
 
 
-def _paged_attention_pallas(q, k_cache, v_cache, block_tables, seq_lens,
-                            sm_scale):
+def _paged_call(q, k_cache, v_cache, block_tables, seq_lens, sm_scale,
+                scales=()):
+    """Shared pallas_call of the float and int8 decode reads. `scales`:
+    () or (k_scale, v_scale), each [num_blocks] float32 riding SMEM next
+    to the block table."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     S, H, Dh = q.shape
-    bs = k_cache.shape[1]
+    nblk, bs = k_cache.shape[0], k_cache.shape[1]
+    rows = bs * H
     max_b = block_tables.shape[1]
+    n_pre = 2 + len(scales)
     kernel = functools.partial(_paged_decode_kernel, sm_scale=sm_scale,
-                               block_size=bs)
+                               block_size=bs, quantized=bool(scales))
+    # which head / which in-block position each row of the 2-D cache
+    # tile belongs to (constants; fetched once, their block never moves)
+    col = np.arange(rows, dtype=np.int32)[None, :]
+    head_of_row, pos_of_row = col % H, col // H
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=n_pre,
         grid=(S, max_b),
         in_specs=[
-            pl.BlockSpec((1, H, Dh), lambda s, j, sl, bt: (s, 0, 0)),
-            pl.BlockSpec((1, bs, H, Dh),
-                         lambda s, j, sl, bt: (bt[s, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, H, Dh),
-                         lambda s, j, sl, bt: (bt[s, j], 0, 0, 0)),
+            pl.BlockSpec((1, rows), lambda s, j, *pre: (0, 0)),
+            pl.BlockSpec((1, rows), lambda s, j, *pre: (0, 0)),
+            pl.BlockSpec((1, H, Dh), lambda s, j, *pre: (s, 0, 0)),
+            pl.BlockSpec((1, rows, Dh),
+                         lambda s, j, sl, bt, *pre: (bt[s, j], 0, 0)),
+            pl.BlockSpec((1, rows, Dh),
+                         lambda s, j, sl, bt, *pre: (bt[s, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, H, Dh), lambda s, j, sl, bt: (s, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, Dh), lambda s, j, *pre: (s, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
+            pltpu.VMEM((H, _LANES), jnp.float32),
+            pltpu.VMEM((H, _LANES), jnp.float32),
             pltpu.VMEM((H, Dh), jnp.float32),
         ],
     )
@@ -219,19 +270,29 @@ def _paged_attention_pallas(q, k_cache, v_cache, block_tables, seq_lens,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, Dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
     )(seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32),
-      q, k_cache, v_cache)
+      *(sc.astype(jnp.float32) for sc in scales),
+      head_of_row, pos_of_row, q,
+      k_cache.reshape(nblk, rows, Dh), v_cache.reshape(nblk, rows, Dh))
+
+
+def _paged_attention_pallas(q, k_cache, v_cache, block_tables, seq_lens,
+                            sm_scale):
+    return _paged_call(q, k_cache, v_cache, block_tables, seq_lens,
+                       sm_scale)
 
 
 def paged_attention(q, k_cache, v_cache, block_tables, seq_lens,
                     sm_scale=None):
     """Public entry: kernel on TPU / under the interpreter, masked-lane
-    reference math elsewhere (the CPU test suite pins the reference
-    bit-identical to dense attention on the valid region)."""
+    reference math on a plain CPU backend (the CPU test suite pins the
+    reference bit-identical to dense attention on the valid region)."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    if _pallas_ok():
+    if _pallas_ok(q, k_cache):
         return _paged_attention_pallas(q, k_cache, v_cache, block_tables,
                                        seq_lens, sm_scale)
     return paged_attention_reference(q, k_cache, v_cache, block_tables,
@@ -418,100 +479,19 @@ def paged_attention_q8_reference(q, k_cache, v_cache, k_scale, v_scale,
     return o.astype(q.dtype)
 
 
-def _paged_decode_kernel_q8(seq_lens_ref, bt_ref, ks_ref, vs_ref, q_ref,
-                            k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
-                            sm_scale, block_size):
-    """The _paged_decode_kernel with two more scalar-prefetch operands:
-    the per-block K/V scales ride SMEM next to the block table, and the
-    streamed int8 tile dequantizes in VMEM right after the load — the
-    grid, index maps and online-softmax carry are unchanged."""
-    from jax.experimental import pallas as pl
-
-    s = pl.program_id(0)
-    j = pl.program_id(1)
-    nb = pl.num_programs(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
-        acc_sc[...] = jnp.zeros_like(acc_sc)
-
-    seq_len = seq_lens_ref[s]
-    live = j * block_size < seq_len
-
-    @pl.when(live)
-    def _update():
-        blk = bt_ref[s, j]
-        q = q_ref[0]                                    # [H, Dh]
-        k = k_ref[0].astype(jnp.float32) * ks_ref[blk]  # [BS, H, Dh]
-        v = v_ref[0].astype(jnp.float32) * vs_ref[blk]
-        scores = jnp.einsum(
-            "hd,bhd->hb", q.astype(jnp.float32), k,
-            preferred_element_type=jnp.float32) * sm_scale
-        pos = j * block_size + lax.broadcasted_iota(
-            jnp.int32, scores.shape, 1)
-        scores = jnp.where(pos < seq_len, scores, NEG_INF)
-        m = m_sc[...]
-        m_new = jnp.maximum(m, jnp.max(scores, axis=1))
-        p = jnp.exp(scores - m_new[:, None])
-        alpha = jnp.exp(m - m_new)
-        l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=1)
-        acc_sc[...] = acc_sc[...] * alpha[:, None] + jnp.einsum(
-            "hb,bhd->hd", p, v, preferred_element_type=jnp.float32)
-        m_sc[...] = m_new
-
-    @pl.when(j == nb - 1)
-    def _finalize():
-        l = jnp.maximum(l_sc[...], 1e-20)
-        o_ref[0] = (acc_sc[...] / l[:, None]).astype(o_ref.dtype)
-
-
 def _paged_attention_q8_pallas(q, k_cache, v_cache, k_scale, v_scale,
                                block_tables, seq_lens, sm_scale):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    S, H, Dh = q.shape
-    bs = k_cache.shape[1]
-    max_b = block_tables.shape[1]
-    kernel = functools.partial(_paged_decode_kernel_q8, sm_scale=sm_scale,
-                               block_size=bs)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(S, max_b),
-        in_specs=[
-            pl.BlockSpec((1, H, Dh), lambda s, j, sl, bt, ks, vs: (s, 0, 0)),
-            pl.BlockSpec((1, bs, H, Dh),
-                         lambda s, j, sl, bt, ks, vs: (bt[s, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, H, Dh),
-                         lambda s, j, sl, bt, ks, vs: (bt[s, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, H, Dh),
-                               lambda s, j, sl, bt, ks, vs: (s, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H, Dh), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, Dh), q.dtype),
-        interpret=_interpret(),
-    )(seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32),
-      k_scale.astype(jnp.float32), v_scale.astype(jnp.float32),
-      q, k_cache, v_cache)
+    return _paged_call(q, k_cache, v_cache, block_tables, seq_lens,
+                       sm_scale, scales=(k_scale, v_scale))
 
 
 def paged_attention_q8(q, k_cache, v_cache, k_scale, v_scale, block_tables,
                        seq_lens, sm_scale=None):
     """Quantized-residency decode read: kernel on TPU / under the
-    interpreter, dequantizing reference math elsewhere."""
+    interpreter, dequantizing reference math on a plain CPU backend."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    if _pallas_ok():
+    if _pallas_ok(q, k_cache):
         return _paged_attention_q8_pallas(q, k_cache, v_cache, k_scale,
                                           v_scale, block_tables, seq_lens,
                                           sm_scale)

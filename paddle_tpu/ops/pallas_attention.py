@@ -14,9 +14,11 @@ Attention-weight dropout runs inside the kernel using the TPU PRNG
 k-block) so forward and both backward kernels regenerate identical masks in
 any iteration order.
 
-Off-TPU the same kernels run under the Pallas interpreter when
+On a CPU backend the same kernels run under the Pallas interpreter when
 PADDLE_TPU_PALLAS_INTERPRET=1 (used by the CPU test suite); otherwise a
-pure-jnp reference path takes over.
+pure-jnp reference path takes over there. On the TPU there is no second
+path: a shape the kernels do not support raises, and so does the
+interpreter switch.
 """
 
 from __future__ import annotations
@@ -85,11 +87,19 @@ def _blk(T, causal=False):
 
 
 def _interpret():
-    return os.environ.get("PADDLE_TPU_PALLAS_INTERPRET", "0") == "1"
+    """The CPU rehearsal switch. Refused on any other backend: a kernel
+    quietly interpreted on the chip would pass every check and prove
+    nothing about Mosaic."""
+    on = os.environ.get("PADDLE_TPU_PALLAS_INTERPRET", "0") == "1"
+    if on and jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"PADDLE_TPU_PALLAS_INTERPRET=1 is a CPU rehearsal switch; "
+            f"refused on the {jax.default_backend()!r} backend — unset it")
+    return on
 
 
 # ---------------------------------------------------------------------------
-# reference jnp implementation (off-TPU fallback)
+# reference jnp implementation (CPU path; the numerical contract)
 # ---------------------------------------------------------------------------
 
 def _attention_reference(q, k, v, causal, sm_scale, dropout_rate=0.0,
@@ -329,12 +339,8 @@ def _compiler_params():
     """Innermost grid dim iterates sequentially (it carries the scratch
     accumulators); the outer two are parallel."""
     from jax.experimental.pallas import tpu as pltpu
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except AttributeError:  # older jax naming
-        return pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0):
@@ -444,12 +450,22 @@ def _flash_backward(q, k, v, o, lse, g, causal, sm_scale, dropout_rate, seed):
 
 
 def _pallas_ok(q, dropout_rate=0.0):
-    if jax.default_backend() == "cpu" and not _interpret():
-        return False
+    """Kernel or reference? The reference is a CPU-only path; on the TPU
+    a shape outside the kernels' envelope raises instead of quietly
+    materializing the [T, T] scores."""
     B, H, T, D = q.shape
-    if _interpret() and dropout_rate:
+    supported = T % 128 == 0 and D <= 256
+    if jax.default_backend() != "cpu":
+        if not supported:
+            raise ValueError(
+                f"flash attention on the {jax.default_backend()!r} backend "
+                f"needs T % 128 == 0 and D <= 256, got q shape {q.shape}")
+        return True
+    if not _interpret():
+        return False
+    if dropout_rate:
         return False  # pltpu.prng_* has no interpreter implementation
-    return T % 128 == 0 and D <= 256
+    return supported
 
 
 # ---------------------------------------------------------------------------
@@ -545,13 +561,8 @@ def ring_attention(q, k, v, mesh, axis="sp", causal=False, sm_scale=None):
     Exceeds reference capability: the reference has no sequence parallelism
     (SURVEY.md §5.7).
     """
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map          # jax >= 0.8 home
-        _replication_kw = {"check_vma": False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        _replication_kw = {"check_rep": False}
 
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
@@ -600,4 +611,4 @@ def ring_attention(q, k, v, mesh, axis="sp", causal=False, sm_scale=None):
         else None
     spec = P(b_ax, h_ax, axis, None)
     return shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, **_replication_kw)(q, k, v)
+                     out_specs=spec, check_vma=False)(q, k, v)

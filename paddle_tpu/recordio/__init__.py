@@ -20,6 +20,7 @@ toolchain."""
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import struct
@@ -48,15 +49,20 @@ def _load_native():
     if _native is not None:
         return _native
     here = os.path.dirname(os.path.abspath(__file__))
-    cache = os.path.join(os.path.expanduser("~/.cache/paddle_tpu"),
-                         "librecordio.so")
     src = os.path.join(here, "native.cc")
     try:
-        if not os.path.exists(cache) or (os.path.getmtime(cache)
-                                         < os.path.getmtime(src)):
+        # built inside the checkout (git-ignored) and named by the
+        # source's content: a library from another checkout or an older
+        # native.cc can never be picked up in its place
+        with open(src, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+        cache = os.path.join(here, "_build", f"librecordio-{digest}.so")
+        if not os.path.exists(cache):
             os.makedirs(os.path.dirname(cache), exist_ok=True)
-            subprocess.run(["g++", "-O2", "-fPIC", "-shared", "-o", cache,
+            tmp = f"{cache}.{os.getpid()}.tmp"
+            subprocess.run(["g++", "-O2", "-fPIC", "-shared", "-o", tmp,
                             src], check=True, capture_output=True)
+            os.replace(tmp, cache)
         lib = ctypes.CDLL(cache)
         lib.rio_crc32.restype = ctypes.c_uint32
         lib.rio_crc32.argtypes = [ctypes.c_char_p, ctypes.c_size_t]

@@ -38,7 +38,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from .. import io as _io
-from ..core.executor import CPUPlace, Executor, Place, Scope
+from ..core.executor import Executor, Place, Scope
 from ..observe import metrics as _metrics
 from ..observe import steplog as _steplog
 from .bucketing import BucketLadder, feed_spec, warm_feed_shapes
@@ -178,7 +178,7 @@ class _Slot:
 class ModelRegistry:
     def __init__(self, place: Optional[Place] = None,
                  executor: Optional[Executor] = None):
-        self._exe = executor or Executor(place or CPUPlace())
+        self._exe = executor or Executor(place)
         self._lock = threading.Lock()
         self._slots: Dict[str, _Slot] = {}
         self._watcher: Optional[threading.Thread] = None

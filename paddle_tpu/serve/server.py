@@ -3,7 +3,7 @@
 Ties the registry (hot-swappable warmed models) to one MicroBatcher per
 model and exposes the two request APIs:
 
-    srv = serve.InferenceServer(fluid.CPUPlace())
+    srv = serve.InferenceServer(fluid.TPUPlace(0))
     srv.add_model("ranker", "/models/ranker",
                   ladder=serve.BucketLadder(rows=(1, 2, 4, 8)))
     out, = srv.infer("ranker", {"x": batch})          # blocking

@@ -176,6 +176,24 @@ class TestPagedAttentionMath:
         # same math, different (online-softmax) accumulation order
         np.testing.assert_allclose(ker, ref, atol=1e-5, rtol=1e-5)
 
+    def test_q8_kernel_matches_reference_under_interpreter(self,
+                                                           monkeypatch):
+        import jax.numpy as jnp
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+        rng = np.random.RandomState(4)
+        q, kc, vc, btj, seqj, _ = _random_cache(rng, H=3)
+        sm = 1.0 / np.sqrt(q.shape[-1])
+        ks = jnp.max(jnp.abs(kc), axis=(1, 2, 3)) / 127.0
+        vs = jnp.max(jnp.abs(vc), axis=(1, 2, 3)) / 127.0
+        kq = jnp.rint(kc / ks[:, None, None, None]).astype(jnp.int8)
+        vq = jnp.rint(vc / vs[:, None, None, None]).astype(jnp.int8)
+        ref = np.asarray(pa.paged_attention_q8_reference(
+            q, kq, vq, ks, vs, btj, seqj, sm))
+        ker = np.asarray(pa._paged_attention_q8_pallas(
+            q, kq, vq, ks, vs, btj, seqj, sm))
+        np.testing.assert_allclose(ker, ref, atol=1e-5, rtol=1e-5)
+        assert np.array_equal(ker[1], np.zeros_like(ker[1]))  # inactive
+
     def test_append_places_kv_and_trash_isolates_inactive(self):
         import jax.numpy as jnp
         rng = np.random.RandomState(3)

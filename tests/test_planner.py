@@ -4,7 +4,8 @@ and ranked flag search (ROADMAP item 4).
 Planner-vs-reality is the acceptance gate here: mesh ranking is pinned
 against the recorded MULTICHIP dryrun configs and the measured 4-mesh
 step-time table (docs/PLANNER.md §validation), predicted MFU against
-the recorded BENCH_r04 bench round, and the ranked flag sweep against
+the BENCH_r04 bench round (2026-07-31, a removed installation; its two
+figures are inlined below), and the ranked flag sweep against
 the recorded phase-1 sweep ratios. The slow drill re-measures the mesh
 table live on the 8-device virtual mesh."""
 
@@ -254,15 +255,14 @@ def test_plan_collective_kinds_match_recorded_dryrun_inventory():
 
 def test_predicted_mfu_within_band_of_recorded_bench():
     """Roofline honesty: predicted MFU of the bench transformer (full
-    base config, batch 64 x seq 256) against the MFU the recorded
-    BENCH_r04 round measured, using that round's measured peak. The
-    documented band is 0.6-1.6 (docs/PLANNER.md §calibration); bench.py
-    re-records the live ratio as plan_agreement every round."""
-    with open(os.path.join(REPO, "BENCH_r04.json")) as f:
-        rec = json.load(f)["parsed"]["extra"]
-    measured_mfu = rec["transformer_mfu"]
-    peak = rec["measured_peak_tflops_bf16"] * 1e12
-    assert measured_mfu > 0.3
+    base config, batch 64 x seq 256) against the MFU the BENCH_r04
+    round measured, using that round's measured peak. The documented
+    band is 0.6-1.6 (docs/PLANNER.md §calibration); bench.py re-records
+    the live ratio as plan_agreement every round."""
+    # recorded 2026-07-31 on a removed installation (BENCH_r04.json, in
+    # git history only); nothing on the current one has replaced them
+    measured_mfu = 0.464
+    peak = 191.5e12
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup), fluid.unique_name.guard():
         feeds, fetches = models.transformer.build(

@@ -221,14 +221,13 @@ def test_donation_dropped_while_compile_cache_configured_on_cpu():
     state corruption under identical seeds). The runtime makes the
     unsound combination unrepresentable: donation_safe() must be False
     exactly when a compilation-cache dir is configured on a CPU
-    backend, and True the moment the cache is off (the TPU
-    training/serving posture, which never configures one)."""
+    backend, and True the moment the cache is off. The dir itself is the
+    one the package's cache rule placed (paddle_tpu/__init__.py)."""
     from paddle_tpu.core.executor import donation_safe
 
     prev = jax.config.jax_compilation_cache_dir
     try:
-        # the tier-1 suite posture (conftest configures the cache):
-        jax.config.update("jax_compilation_cache_dir", "/tmp/_pin_cache")
+        assert prev, "the package rule configures a cache for every process"
         assert jax.default_backend() == "cpu"
         assert donation_safe() is False
         # no cache dir -> full donation is sound again

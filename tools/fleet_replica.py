@@ -14,6 +14,11 @@ parent process to read:
 Runs until SIGTERM (clean close: leaves the fleet, drains) or SIGKILL
 (the chaos drill's case: the router finds out the hard way).
 
+The device is jax's default for the process, as for any jax program: on
+a TPU host the replica holds the chip (one replica process per chip);
+rehearsal fleets are started with JAX_PLATFORMS=cpu in the child's
+environment (tools/fleet_router.py::spawn_replicas does).
+
     python tools/fleet_replica.py --model-dir /models/m --router HOST:PORT
     python tools/fleet_replica.py --model-dir /models/dfm \
         --sparse-endpoints host:4471,host:4472 --sparse-quant int8
@@ -34,8 +39,6 @@ import threading
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def main(argv=None):
@@ -83,9 +86,6 @@ def main(argv=None):
                     "(fluid-horizon stitches one per fleet process)")
     args = ap.parse_args(argv)
 
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
     import paddle_tpu as fluid
     from paddle_tpu import fleet, serve
     from paddle_tpu.observe import xray
@@ -95,15 +95,13 @@ def main(argv=None):
     if args.pulse_port is not None or args.trace_out:
         fluid.set_flag("observe", True)
 
-    srv = serve.InferenceServer(
-        fluid.CPUPlace(),
-        serve.ServeConfig(batch_timeout_ms=args.batch_timeout_ms,
-                          max_queue=args.max_queue,
-                          watch_interval_s=args.watch_interval_s or 2.0,
-                          pulse_port=args.pulse_port,
-                          simulate_prefill_us_per_token=(
-                              args.sim_prefill_us_per_token),
-                          simulate_decode_step_us=args.sim_decode_step_us))
+    srv = serve.InferenceServer(config=serve.ServeConfig(
+        batch_timeout_ms=args.batch_timeout_ms,
+        max_queue=args.max_queue,
+        watch_interval_s=args.watch_interval_s or 2.0,
+        pulse_port=args.pulse_port,
+        simulate_prefill_us_per_token=args.sim_prefill_us_per_token,
+        simulate_decode_step_us=args.sim_decode_step_us))
     sparse = None
     if args.sparse_endpoints:
         sparse = fleet.SparseServeConfig(

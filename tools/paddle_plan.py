@@ -94,8 +94,9 @@ def main(argv=None):
         }[args.model](fluid, layers, batch)
     feed_shapes = {k: tuple(v.shape) for k, v in feed.items()}
 
-    hw = {"tpu": planner.TPU_CHIP, "cpu": planner.CPU_REHEARSAL,
-          "auto": planner.detect_hardware()}[args.hw]
+    hw = {"tpu": lambda: planner.TPU_CHIP,
+          "cpu": lambda: planner.CPU_REHEARSAL,
+          "auto": planner.detect_hardware}[args.hw]()
     if args.peak_tflops is not None:
         hw = hw.replace(peak_flops=args.peak_tflops * 1e12)
     if args.hbm_gb is not None:

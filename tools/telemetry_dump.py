@@ -189,7 +189,7 @@ def main(argv=None):
         return 0
 
     import jax
-    jax.config.update("jax_platforms", "cpu")  # env var alone is overridden
+    jax.config.update("jax_platforms", "cpu")
 
     import numpy as np
 

@@ -138,7 +138,7 @@ def run_sparse(fluid, np):
 
 def main():
     import jax
-    jax.config.update("jax_platforms", "cpu")  # env var alone is overridden
+    jax.config.update("jax_platforms", "cpu")
 
     import numpy as np
 
