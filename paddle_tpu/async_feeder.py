@@ -23,6 +23,7 @@ import jax
 
 from . import flags as _flags
 from .observe import metrics as _metrics
+from .observe import steplog as _steplog
 
 
 class AsyncFeeder:
@@ -61,15 +62,16 @@ class AsyncFeeder:
         previous step's compute, and every transfer is issued from the
         thread that dispatches the steps."""
         target = self._sharding or self._device
-        if target is not None:
-            out = {}
+        if target is None:
+            return feed
+        out = {}
+        with _steplog.span(_steplog.FEEDER_PUT):
             for k, v in feed.items():
                 if isinstance(v, tuple):
                     out[k] = tuple(jax.device_put(x, target) for x in v)
                 else:
                     out[k] = jax.device_put(v, target)
-            return out
-        return feed
+        return out
 
     def __iter__(self):
         q: queue.Queue = queue.Queue(maxsize=self._capacity)

@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import itertools
 import logging
-import time
 from typing import Any, Dict, List, Optional, Sequence, Union
 
 import jax
@@ -416,12 +415,16 @@ class _CompiledProgram:
         mut, const = self.gather_state(scope)
         return self.run_with_state(scope, feeds, mut, const, counter)[0]
 
-    def run_with_state(self, scope: Scope, feeds, mut, const, counter):
+    def run_with_state(self, scope: Scope, feeds, mut, const, counter,
+                       spans=None):
         """One step against pre-gathered state dicts; returns (fetches,
         new_state) so callers holding a state cache can refresh their mut
         entries (the mut arrays were donated to XLA and are dead after the
-        call)."""
+        call). `spans` (a `steplog.RunSpans` in its jit_call phase) moves
+        to write_back once the jitted step has returned."""
         fetches, new_state, flags = self._step(feeds, mut, const, counter)
+        if spans is not None:
+            spans.phase(_steplog.WRITE_BACK)
         # bulk write-back: one dict update + one version bump (set_var per
         # name costs ~10µs/step on wide optimizers; equality-based cache
         # invalidation only needs the version to CHANGE, not count)
@@ -599,92 +602,72 @@ class PreparedProgram:
             raise RuntimeError(
                 "program was mutated after prepare(); prepare() it again "
                 "(Executor.run() re-prepares automatically)")
-        # telemetry gate: ONE flag read + branch when off — the prepared
-        # fast path performs zero registry writes unless observing
-        obs_on = _flags.get_flag("observe")
-        t0 = time.perf_counter() if obs_on else 0.0
-        feed = feed or {}
-        # py_reader-fed program: no feed -> pop the next queued batch
-        # (raises EOFException at end of pass, reference read-op contract)
-        if not feed and getattr(program, "_py_reader", None) is not None:
-            feed = program._py_reader.next_feed()
-        # steady state: the feed-conversion PLAN (per-name target dtype,
-        # no LoD) was resolved at bind time, so conversion is one tight
-        # loop without block-var lookups or dtype re-resolution
-        plan = self._feed_plan
-        if plan is not None and feed.keys() == self._plan_keys:
-            feed_arrays = {}
-            for name, val in feed.items():
-                if type(val) is np.ndarray:
+        # host spans on the profiler's clock at default flags; StepStats on
+        # the same boundaries only when observing, so the prepared fast
+        # path still performs zero registry writes (observe/steplog.py)
+        with _steplog.RunSpans(
+                program._uid, self.telemetry_source,
+                self._exe._run_counts.get(program._uid, 0)) as spans:
+            feed = feed or {}
+            # py_reader-fed program: no feed -> pop the next queued batch
+            # (raises EOFException at end of pass, reference read-op
+            # contract); the wait is the reader's own span
+            if not feed and getattr(program, "_py_reader", None) is not None:
+                feed = program._py_reader.next_feed()
+            spans.phase(_steplog.FEED_CONVERT)
+            # steady state: the feed-conversion PLAN (per-name target
+            # dtype, no LoD) was resolved at bind time, so conversion is
+            # one tight loop without block-var lookups or dtype
+            # re-resolution
+            plan = self._feed_plan
+            if plan is not None and feed.keys() == self._plan_keys:
+                feed_arrays = {}
+                for name, val in feed.items():
+                    if type(val) is not np.ndarray:
+                        if isinstance(val, jax.Array):
+                            feed_arrays[name] = val   # pre-placed: never
+                            continue                  # round-trip
+                        val = np.asarray(val)
                     dt = plan[name]
                     if dt is not None and val.dtype != dt \
                             and val.dtype.kind in "fiub":
                         val = val.astype(dt)
                     feed_arrays[name] = val
-                elif isinstance(val, jax.Array):
-                    feed_arrays[name] = val   # pre-placed: never round-trip
-                else:
-                    arr = np.asarray(val)
-                    dt = plan[name]
-                    if dt is not None and arr.dtype != dt \
-                            and arr.dtype.kind in "fiub":
-                        arr = arr.astype(dt)
-                    feed_arrays[name] = arr
-        else:
-            feed_arrays = _convert_feed_dict(self._block, feed)
-        if obs_on:
-            t_fc = time.perf_counter()  # end of feed conversion proper
-        entry = self._entry
-        bound = False
-        if entry is None or feed_arrays.keys() != self._entry_keys:
-            entry = self._bind(feed, feed_arrays)
-            bound = True
-        if obs_on:
-            # feed_shape observatory: a new shape/dtype signature on a
-            # bound entry means jax.jit retraces + XLA recompiles
-            _steplog.track_shapes(entry, program._uid, feed_arrays,
-                                  source=self.telemetry_source)
-            t1 = time.perf_counter()
-        counter = self._exe._count_run(program._uid)
-        mut, const = self._state.get(entry, self.scope)
-        if obs_on:
-            t2 = time.perf_counter()
-        if self._use_device_ctx:
-            with jax.default_device(self._device):
+            else:
+                feed_arrays = _convert_feed_dict(self._block, feed)
+            entry = self._entry
+            if entry is None or feed_arrays.keys() != self._entry_keys:
+                # binding (validation, feed plan, cache lookup) is its own
+                # one-shot phase: it never pollutes steady-state
+                # feed_convert numbers
+                spans.phase(_steplog.BIND)
+                entry = self._bind(feed, feed_arrays)
+            if spans.observing:
+                # feed_shape observatory: a new shape/dtype signature on a
+                # bound entry means jax.jit retraces + XLA recompiles
+                _steplog.track_shapes(entry, program._uid, feed_arrays,
+                                      source=self.telemetry_source)
+            spans.phase(_steplog.STATE_GATHER)
+            counter = self._exe._count_run(program._uid)
+            mut, const = self._state.get(entry, self.scope)
+            # jit_call ends when the jitted step returns (under async
+            # dispatch the device runs on); run_with_state opens
+            # write_back before its scope update
+            spans.phase(_steplog.JIT_CALL)
+            if self._use_device_ctx:
+                with jax.default_device(self._device):
+                    fetches, new_state = entry.run_with_state(
+                        self.scope, feed_arrays, mut, const, counter, spans)
+            else:
                 fetches, new_state = entry.run_with_state(
-                    self.scope, feed_arrays, mut, const, counter)
-        else:
-            fetches, new_state = entry.run_with_state(
-                self.scope, feed_arrays, mut, const, counter)
-        if obs_on:
-            t3 = time.perf_counter()
-        self._state.commit(entry, self.scope, new_state)
-        if obs_on:
-            t4 = time.perf_counter()
-        if return_numpy:
-            fetches = [np.asarray(f) for f in fetches]
-        if obs_on:
-            t5 = time.perf_counter()
-            # device_compute is the run_with_state wall: jitted dispatch +
-            # (under sync dispatch) device time + the in-call scope update
-            # (first call also traces + XLA-compiles inside it);
-            # write_back is the state-cache commit; fetch is the host
-            # transfer np.asarray forces (zero when return_numpy=False —
-            # the async-dispatch overlap the fast path is built on).
-            # Binding (validation, feed plan, cache lookup) is recorded as
-            # its own one-shot `bind` phase so it never pollutes the
-            # steady-state feed_convert numbers.
-            phases = {
-                "feed_convert": t_fc - t0,
-                "state_gather": t2 - t1,
-                "device_compute": t3 - t2,
-                "write_back": t4 - t3,
-                "fetch": t5 - t4,
-            }
-            if bound:
-                phases["bind"] = t1 - t_fc
-            _steplog.get_steplog().record(_steplog.StepStats(
-                program._uid, self.telemetry_source, time.time(), phases))
+                    self.scope, feed_arrays, mut, const, counter, spans)
+            self._state.commit(entry, self.scope, new_state)
+            if return_numpy:
+                # the host transfer np.asarray forces; no span with
+                # return_numpy=False — the async-dispatch overlap the fast
+                # path is built on
+                spans.phase(_steplog.FETCH)
+                fetches = [np.asarray(f) for f in fetches]
         return fetches
 
     def _build_feed_plan(self, feed):

@@ -67,7 +67,11 @@ class BlockLowerer:
     def _run_op(self, block: ir.Block, op: ir.Operator, op_idx: int,
                 env: Dict[str, Any], key):
         if op.type.endswith(GRAD_OP_SUFFIX) and FWD_OP_ATTR in op.attrs:
-            self._run_grad_op(block, op, env, key)
+            # the grad op's own type: whatever the forward rule lowers in
+            # here (the generic vjp path re-traces it) is told apart, in the
+            # compiled module and a device trace, from the forward op's
+            with jax.named_scope(op.type):
+                self._run_grad_op(block, op, env, key)
             if self.check_nan_inf and self._block_depth == 1:
                 self._record_nan_flags_env(op, env)
             return
@@ -75,7 +79,8 @@ class BlockLowerer:
         op_key = jax.random.fold_in(key, _op_seed(op, op_idx)) if opdef.needs_rng else None
         ins = _gather_inputs(op.inputs, env, op.type)
         ctx = LoweringContext(op.attrs, key=op_key, lowerer=self, op=op, env=env)
-        outs = registry.call_rule(opdef, ctx, ins)
+        with jax.named_scope(op.type):
+            outs = registry.call_rule(opdef, ctx, ins)
         _scatter_outputs(op, outs, env)
         if opdef.propagate_seqlen:
             _propagate_seqlen(op, env)

@@ -6,8 +6,9 @@ Three cooperating pieces (see docs/OBSERVABILITY.md):
   histograms (thread-safe, labeled, snapshot/JSON/Prometheus export)
 - `observe.tracer`   — structured spans in a bounded ring buffer with
   chrome://tracing export; absorbs the profiler's host-event table
-- `observe.steplog`  — per-run() StepStats phase timings + the
-  recompilation observatory (every jit cache miss, with attributed cause)
+- `observe.steplog`  — the spans inside run() (`RunSpans`), per-run()
+  StepStats phase timings + the recompilation observatory (every jit
+  cache miss, with attributed cause and the seconds each stage cost)
 - `observe.xray`     — W3C trace contexts across processes (round 11)
 - `observe.flight`   — the crash flight recorder (round 11)
 - `observe.pulse`    — per-process HTTP health endpoint: /metrics,
@@ -30,6 +31,18 @@ With the flag off, the prepared-program fast path performs ZERO registry
 writes per step (one flag read + branch only). Compile-time recompile
 events are recorded regardless — they are never hot and they are what
 `tools/telemetry_dump.py --assert-no-recompiles` audits in CI.
+
+One thing is on at DEFAULT flags: the host spans of a run. Every
+`PreparedProgram.run` / `ParallelExecutor.run` opens `paddle_tpu:run` and,
+inside it, `paddle_tpu:feed_convert`, `:bind` (a step that binds),
+`:state_gather`, `:jit_call`, `:write_back`, `:fetch` (with
+`return_numpy=True`) as `jax.profiler.TraceAnnotation`s; `py_reader` and
+`AsyncFeeder` add `paddle_tpu:reader_pop` and `paddle_tpu:feeder_put`.
+They cost one atomic check each while no profile is taken, and in any
+`profiler.profiler(...)` / TensorBoard capture they sit next to the
+device track, on its clock. The `observe` flag adds the `StepStats` ring
+on the same boundaries; its `device_compute` key is the host wall of the
+jitted call — dispatch, not device time, under async dispatch.
 """
 
 from __future__ import annotations

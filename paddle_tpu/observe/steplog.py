@@ -2,11 +2,14 @@
 
 Two runtime questions dominate TPU cost and were previously invisible:
 
-1. *Where does a step's host time go?* `StepStats` records the wall time
-   of each host phase around the jitted call — feed conversion, state
-   gather, device dispatch+compute, state write-back, fetch transfer —
-   for every `Executor`/`ParallelExecutor` run when the `observe` flag is
-   on. bench.py records the aggregate next to each headline number.
+1. *Where does a step's host time go?* `RunSpans` opens one
+   `paddle_tpu:run` span per `PreparedProgram.run` / `ParallelExecutor.run`
+   and one child span per host phase around the jitted call — feed
+   conversion, bind, state gather, the jitted call, state write-back,
+   fetch transfer — as `jax.profiler.TraceAnnotation`s: on at default
+   flags, on the profiler's clock, beside the device track of any
+   capture, and free when no capture runs. With the `observe` flag on the
+   same boundaries also fill a `StepStats` in the bounded `StepLog`.
 
 2. *Why did XLA recompile?* The static lint (analysis/, PR 2) can only
    WARN about feed-shape recompile hazards; the observatory closes the
@@ -42,6 +45,19 @@ Two runtime questions dominate TPU cost and were previously invisible:
    observatory is the whole point of `tools/telemetry_dump.py
    --assert-no-recompiles`. Only the per-step shape *tracking* that
    detects `feed_shape` misses is flag-gated (it is on the hot path).
+
+   Each event also carries what the compile cost, `stages_s`: the seconds
+   jax itself reports (`jax.monitoring`) for tracing the Program through
+   `BlockLowerer` (`trace`), lowering to MLIR (`lower`), the XLA compile
+   or the load from the persistent cache (`backend`) and, of that, the
+   cache read (`cache_retrieval`), from the event's creation until the
+   run() that caused it returns. When jax compiles again inside the
+   jitted call of a later run() and the executor recorded no cause for
+   it (what its keys cannot see: with `observe` off a new feed shape; on
+   the TPU the SECOND call of every step, whose state the startup program
+   left uncommitted and the first call committed — chip run, PR 25), the
+   durations go to the program's latest event, whose `backend_compiles`
+   then reads 2. No cause is invented for it.
 """
 
 from __future__ import annotations
@@ -51,12 +67,32 @@ import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
+import jax.monitoring
+import jax.profiler
+
+from .. import flags as _flags
 from . import flight as _flight
 from . import metrics as _metrics
 from . import tracer as _tracer
 
-PHASES = ("feed_convert", "state_gather", "device_compute", "write_back",
-          "fetch", "bind")
+# (span in the profiler's trace, StepStats key) of each host phase of a
+# run(); constants, so a step builds no string. `device_compute` is the
+# host wall of the jitted call: dispatch only under async dispatch (the
+# device runs on after it returns), trace + lower + compile on a first call.
+FEED_CONVERT = ("paddle_tpu:feed_convert", "feed_convert")
+BIND = ("paddle_tpu:bind", "bind")
+STATE_GATHER = ("paddle_tpu:state_gather", "state_gather")
+JIT_CALL = ("paddle_tpu:jit_call", "device_compute")
+WRITE_BACK = ("paddle_tpu:write_back", "write_back")
+FETCH = ("paddle_tpu:fetch", "fetch")
+# the two places a host-fed loop waits outside the phases above
+READER_POP = "paddle_tpu:reader_pop"    # py_reader.next_feed()
+FEEDER_PUT = "paddle_tpu:feeder_put"    # AsyncFeeder's device transfer
+
+PHASES = tuple(key for _, key in (FEED_CONVERT, STATE_GATHER, JIT_CALL,
+                                  WRITE_BACK, FETCH, BIND))
+
+span = jax.profiler.TraceAnnotation
 
 
 class StepStats:
@@ -154,8 +190,90 @@ class StepLog:
             self._count = 0
 
 
+class RunSpans:
+    """The host spans of one `PreparedProgram.run` / `ParallelExecutor.run`.
+
+        with RunSpans(program_uid, source, step) as spans:
+            spans.phase(FEED_CONVERT); ...
+            spans.phase(STATE_GATHER); ...
+
+    `paddle_tpu:run` covers the `with` block and carries `step` (the
+    per-program run counter: what all spans of one step share, and by order
+    the k-th module execution on the device's track), `program` and
+    `source`. `phase()` ends the open child span and starts the next, so
+    the children are leaves that tile the run. At default flags that is
+    all it does, but for noting the run as this thread's current one (for
+    a compile that jax reports inside it, `_on_duration`): a
+    `TraceAnnotation` costs one atomic check while no profile is taken. With
+    the `observe` flag on, the same boundaries fill a `StepStats`,
+    recorded after the run span has closed unless the body raised."""
+
+    __slots__ = ("observing", "program_uid", "source", "which", "_run",
+                 "_child", "_phases", "_key", "_t")
+
+    def __init__(self, program_uid: int, source: str, step: int):
+        self.observing = _flags.get_flag("observe")
+        self.program_uid, self.source = program_uid, source
+        self.which = self._child = None
+        self._run = span("paddle_tpu:run", step=step, program=program_uid,
+                         source=source)
+        _building.run = self
+        if self.observing:
+            self._phases: Dict[str, float] = {}
+            self._key = None
+
+    def __enter__(self):
+        return self
+
+    def phase(self, which):
+        if self._child is not None:
+            self._child.__exit__(None, None, None)
+        self.which = which
+        self._child = span(which[0])
+        if self.observing:
+            self._tick(which[1])
+
+    def _tick(self, key):
+        now = time.perf_counter()
+        if self._key is not None:
+            self._phases[self._key] = (self._phases.get(self._key, 0.0)
+                                       + now - self._t)
+        self._key, self._t = key, now
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._child is not None:
+            self._child.__exit__(None, None, None)
+        self._run.__exit__(None, None, None)
+        # the compile event this run built stops taking stage durations
+        _building.run = _building.event = None
+        if self.observing:
+            self._tick(None)
+            if exc_type is None:
+                _steplog.record(StepStats(self.program_uid, self.source,
+                                          time.time(), self._phases))
+        return False
+
+
+# jax.monitoring duration events -> the keys of RecompileEvent.stages_s
+_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
+                 "/jax/compilation_cache/cache_misses": "cache_misses"}
+
+# `event`: the compile event last recorded on this thread; it takes the
+# stage durations jax reports until the run() that built it returns
+# (RunSpans) or the thread records another. `run`: the RunSpans in progress
+# on this thread, if any.
+_building = threading.local()
+
+
 class RecompileEvent:
-    __slots__ = ("ts", "program_uid", "cause", "source", "detail")
+    __slots__ = ("ts", "program_uid", "cause", "source", "detail",
+                 "cache_hits", "cache_misses", "_stage_spans")
 
     def __init__(self, ts, program_uid, cause, source, detail):
         self.ts = ts
@@ -163,11 +281,41 @@ class RecompileEvent:
         self.cause = cause
         self.source = source
         self.detail = detail
+        self.cache_hits = self.cache_misses = 0   # persistent compile cache
+        self._stage_spans: Dict[str, list] = {}
+        _building.event = self
+
+    def add_stage(self, stage: str, seconds: float):
+        """jax reports a duration when it ends, and a traced function that
+        calls jitted ones reports theirs inside its own: keep the union
+        (durations arrive in order of their ends)."""
+        end = time.perf_counter()
+        start = end - seconds
+        merged = self._stage_spans.setdefault(stage, [])
+        while merged and merged[-1][0] >= start:
+            merged.pop()
+        if merged and merged[-1][1] > start:
+            merged[-1][1] = end
+        else:
+            merged.append([start, end])
+
+    @property
+    def stages_s(self) -> Dict[str, float]:
+        """Seconds by compile stage (`_STAGES`); empty if nothing compiled
+        while the event was being built."""
+        return {stage: sum(e - s for s, e in merged)
+                for stage, merged in self._stage_spans.items()}
 
     def as_dict(self) -> dict:
         return {"ts": self.ts, "program_uid": self.program_uid,
                 "cause": self.cause, "source": self.source,
-                "detail": self.detail}
+                "detail": self.detail,
+                "stages_s": {k: round(v, 6)
+                             for k, v in self.stages_s.items()},
+                # disjoint backend intervals: 2 = the step was built twice
+                "backend_compiles": len(self._stage_spans.get("backend", ())),
+                "cache_hits": self.cache_hits,
+                "cache_misses": self.cache_misses}
 
     def __repr__(self):
         return (f"RecompileEvent(uid={self.program_uid}, "
@@ -268,6 +416,14 @@ class RecompilationObservatory:
                 time.time(), program_uid, cause, source, detail))
         self._emit_metric(cause, source)
 
+    def latest(self, program_uid: int) -> Optional[RecompileEvent]:
+        """The program's most recent compile event, if the ring holds one."""
+        with self._lock:
+            for event in reversed(self._events):
+                if event.program_uid == program_uid:
+                    return event
+        return None
+
     @staticmethod
     def _emit_metric(cause: str, source: str):
         _metrics.counter(
@@ -305,6 +461,39 @@ class RecompilationObservatory:
 
 _steplog = StepLog()
 _observatory = RecompilationObservatory()
+
+
+def _on_duration(event, seconds, **_):
+    stage = _STAGES.get(event)
+    if stage is None:
+        return
+    building = getattr(_building, "event", None)
+    if building is None:
+        # jax compiles and the executor recorded no cause. Inside the
+        # jitted call of a run() it is that program's step being built
+        # again: its latest event takes the cost for the rest of this
+        # run(). Anywhere else (a user's own jnp code) it is not the
+        # executor's to record.
+        run = getattr(_building, "run", None)
+        if run is None or run.which is not JIT_CALL:
+            return
+        building = _observatory.latest(run.program_uid)
+        if building is None:
+            return
+        _building.event = building
+    building.add_stage(stage, seconds)
+
+
+def _on_event(event, **_):
+    attr = _CACHE_EVENTS.get(event)
+    building = getattr(_building, "event", None)
+    if attr is not None and building is not None:
+        setattr(building, attr, getattr(building, attr) + 1)
+
+
+# they fire only when something compiles, never in a steady step
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+jax.monitoring.register_event_listener(_on_event)
 
 
 def get_steplog() -> StepLog:

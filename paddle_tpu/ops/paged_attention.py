@@ -273,6 +273,7 @@ def _paged_call(q, k_cache, v_cache, block_tables, seq_lens, sm_scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
+        name="paged_attention",
     )(seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32),
       *(sc.astype(jnp.float32) for sc in scales),
       head_of_row, pos_of_row, q,

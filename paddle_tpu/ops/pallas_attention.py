@@ -379,6 +379,7 @@ def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0):
         ],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name="flash_fwd",
     )(_seed_arr(seed), q3, k3, v3)
     return out.reshape(B, H, T, D), lse
 
@@ -415,6 +416,7 @@ def _flash_backward(q, k, v, o, lse, g, causal, sm_scale, dropout_rate, seed):
         scratch_shapes=[pltpu.VMEM((BQ, D), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name="flash_dq",
     )(_seed_arr(seed), q3, k3, v3, g3, lse, delta)
 
     dkv_kernel = functools.partial(_flash_dkv_kernel, sm_scale=sm_scale,
@@ -443,6 +445,7 @@ def _flash_backward(q, k, v, o, lse, g, causal, sm_scale, dropout_rate, seed):
                         pltpu.VMEM((BK, D), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name="flash_dkv",
     )(_seed_arr(seed), q3, k3, v3, g3, lse, delta)
 
     return (dq.reshape(B, H, T, D), dk.reshape(B, H, T, D),

@@ -69,6 +69,7 @@ def _run(x2d, seed, rate, interpret):
         out_specs=pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
         interpret=interpret,
+        name="pallas_dropout",
     )(seed, x2d)
 
 

@@ -18,7 +18,6 @@ reference details/reduce_op_handle.cc) maps to sharding optimizer state over
 from __future__ import annotations
 
 import enum
-import time
 from typing import Dict, Optional, Sequence
 
 import jax
@@ -178,96 +177,86 @@ class ParallelExecutor:
         return self._mesh.devices.size
 
     def run(self, fetch_list, feed=None, feed_dict=None, return_numpy=True):
-        feed = feed if feed is not None else feed_dict or {}
-        if isinstance(feed, (list, tuple)):
-            merged: Dict[str, np.ndarray] = {}
-            for d in feed:
-                for k, v in d.items():
-                    merged.setdefault(k, []).append(np.asarray(v))
-            feed = {k: np.concatenate(v, axis=0) for k, v in merged.items()}
-
         from .. import flags as _flags
-        obs_on = _flags.get_flag("observe")
-        t0 = time.perf_counter() if obs_on else 0.0
-        fetch_names = [f.name if isinstance(f, ir.Variable) else str(f)
-                       for f in fetch_list]
-        feed_arrays = self._convert_feeds(feed)
-        if obs_on:
-            t_fc = time.perf_counter()  # end of feed conversion proper
+        # host spans at default flags, StepStats when observing: the same
+        # helper and boundaries as PreparedProgram.run (observe/steplog.py)
+        with _steplog.RunSpans(self._program._uid, "parallel",
+                               self._run_counter) as spans:
+            spans.phase(_steplog.FEED_CONVERT)
+            feed = feed if feed is not None else feed_dict or {}
+            if isinstance(feed, (list, tuple)):
+                merged: Dict[str, np.ndarray] = {}
+                for d in feed:
+                    for k, v in d.items():
+                        merged.setdefault(k, []).append(np.asarray(v))
+                feed = {k: np.concatenate(v, axis=0)
+                        for k, v in merged.items()}
+            fetch_names = [f.name if isinstance(f, ir.Variable) else str(f)
+                           for f in fetch_list]
+            feed_arrays = self._convert_feeds(feed)
 
-        fast_key = (self._program._uid, self._program._version,
-                    frozenset(feed_arrays), tuple(fetch_names),
-                    _flags.version())
-        hit = self._fast.get(fast_key)
-        bound = hit is None
-        if hit is None:
-            from ..core.executor import resolve_compiler_options
-            copts = resolve_compiler_options(
-                self._mesh.devices.flat[0].platform, self._program)
-            key = (self._program._uid, self._program._version,
-                   tuple(sorted(feed_arrays)), tuple(fetch_names),
-                   _flags.get_flag("dropout_impl"),
-                   tuple(sorted(copts.items())) if copts else None)
-            compiled = self._cache.get(key)
-            if compiled is None:
-                _steplog.observatory().note_entry_build(
-                    self._program._uid, self._program._version,
-                    tuple(sorted(feed_arrays)), tuple(fetch_names),
-                    tuple(sorted(copts.items())) if copts else None,
-                    source="parallel", scope_uid=self._scope._uid)
-                compiled = _CompiledProgram(self._program, sorted(feed_arrays),
-                                            fetch_names, self._scope,
-                                            donate=True,
-                                            amp=self._build_strategy.amp,
-                                            mesh=self._mesh,
-                                            compiler_options=copts)
-                _evict_stale_versions(self._cache, self._program._uid,
-                                      self._program._version)
-                self._cache[key] = compiled
-            _evict_stale_versions(self._fast, self._program._uid,
-                                  self._program._version)
-            # a flag flip re-keys the memo for the same (program, feed
-            # signature, fetch set) — drop the superseded entry
-            _evict_superseded(self._fast, fast_key)
-            hit = self._fast[fast_key] = (compiled, key)
-        compiled, self._last_key = hit
-
-        if obs_on:
-            _steplog.track_shapes(compiled, self._program._uid, feed_arrays,
-                                  source="parallel")
-            t1 = time.perf_counter()
-        # per-program run counter (see Executor.run): deterministic
-        # trajectories from seeded init, per-step mask variation
-        counter = np.uint32(self._run_counter)
-        self._run_counter += 1
-        mut, const = self._state_cache.get(compiled, self._scope)
-        if obs_on:
-            t2 = time.perf_counter()
-        fetches, new_state = compiled.run_with_state(
-            self._scope, feed_arrays, mut, const, counter)
-        if obs_on:
-            t3 = time.perf_counter()
-        self._state_cache.commit(compiled, self._scope, new_state)
-        if obs_on:
-            t4 = time.perf_counter()
-        if return_numpy:
-            fetches = [self._fetch_numpy(f) for f in fetches]
-        if obs_on:
-            t5 = time.perf_counter()
-            phases = {
-                "feed_convert": t_fc - t0,
-                "state_gather": t2 - t1,
-                "device_compute": t3 - t2,
-                "write_back": t4 - t3,
-                "fetch": t5 - t4,
-            }
-            if bound:
-                # one-shot memo-resolution/build cost, kept out of the
+            fast_key = (self._program._uid, self._program._version,
+                        frozenset(feed_arrays), tuple(fetch_names),
+                        _flags.version())
+            hit = self._fast.get(fast_key)
+            if hit is None:
+                # one-shot memo resolution / build, kept out of the
                 # steady-state feed_convert numbers
-                phases["bind"] = t1 - t_fc
-            _steplog.get_steplog().record(_steplog.StepStats(
-                self._program._uid, "parallel", time.time(), phases))
+                spans.phase(_steplog.BIND)
+                hit = self._fast[fast_key] = self._bind(
+                    fast_key, feed_arrays, fetch_names)
+            compiled, self._last_key = hit
+
+            if spans.observing:
+                _steplog.track_shapes(compiled, self._program._uid,
+                                      feed_arrays, source="parallel")
+            spans.phase(_steplog.STATE_GATHER)
+            # per-program run counter (see Executor.run): deterministic
+            # trajectories from seeded init, per-step mask variation
+            counter = np.uint32(self._run_counter)
+            self._run_counter += 1
+            mut, const = self._state_cache.get(compiled, self._scope)
+            spans.phase(_steplog.JIT_CALL)  # run_with_state: -> write_back
+            fetches, new_state = compiled.run_with_state(
+                self._scope, feed_arrays, mut, const, counter, spans)
+            self._state_cache.commit(compiled, self._scope, new_state)
+            if return_numpy:
+                spans.phase(_steplog.FETCH)
+                fetches = [self._fetch_numpy(f) for f in fetches]
         return fetches
+
+    def _bind(self, fast_key, feed_arrays, fetch_names):
+        """(compiled step, its cache key) for a feed signature and fetch
+        set the fast memo has not seen: from the compile cache, or built."""
+        from .. import flags as _flags
+        from ..core.executor import resolve_compiler_options
+        copts = resolve_compiler_options(
+            self._mesh.devices.flat[0].platform, self._program)
+        copts_sig = tuple(sorted(copts.items())) if copts else None
+        feed_sig = tuple(sorted(feed_arrays))
+        key = (self._program._uid, self._program._version, feed_sig,
+               tuple(fetch_names), _flags.get_flag("dropout_impl"), copts_sig)
+        compiled = self._cache.get(key)
+        if compiled is None:
+            _steplog.observatory().note_entry_build(
+                self._program._uid, self._program._version, feed_sig,
+                tuple(fetch_names), copts_sig, source="parallel",
+                scope_uid=self._scope._uid)
+            compiled = _CompiledProgram(self._program, sorted(feed_arrays),
+                                        fetch_names, self._scope,
+                                        donate=True,
+                                        amp=self._build_strategy.amp,
+                                        mesh=self._mesh,
+                                        compiler_options=copts)
+            _evict_stale_versions(self._cache, self._program._uid,
+                                  self._program._version)
+            self._cache[key] = compiled
+        _evict_stale_versions(self._fast, self._program._uid,
+                              self._program._version)
+        # a flag flip re-keys the memo for the same (program, feed
+        # signature, fetch set) — drop the superseded entry
+        _evict_superseded(self._fast, fast_key)
+        return compiled, key
 
     @staticmethod
     def _fetch_numpy(f):

@@ -27,6 +27,7 @@ from ..core import ir
 from ..core.executor import EOFException
 from ..data_feeder import DataFeeder
 from ..layer_helper import LayerHelper
+from ..observe import steplog as _steplog
 
 _EOF = object()
 
@@ -142,7 +143,8 @@ class PyReader:
     def next_feed(self):
         if self._queue is None:
             raise RuntimeError("py_reader not started — call reader.start()")
-        item = self._queue.get()
+        with _steplog.span(_steplog.READER_POP):   # the wait for data
+            item = self._queue.get()
         if item is _EOF:
             if self._producer_error is not None:
                 err = self._producer_error

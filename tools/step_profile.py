@@ -1,11 +1,12 @@
 """Capture a profiler trace of the framework transformer step and print
 the top device ops by total self time (round-4 MFU hunt).
 
-Round 8: host-side timing rides the unified fluid-scope tracer
-(paddle_tpu.profiler.record_event -> observe.tracer) instead of private
-jax.profiler calls — the run leaves a host timeline
-(`host_timeline.json`, chrome://tracing) and an aggregated host-event
-table next to the device-op summary parsed from the perfetto trace.
+The framework's steps need no wrapper: every `exe.run` opens its own
+`paddle_tpu:run` span and phase spans in the captured trace
+(observe/steplog.py::RunSpans), and their table is printed next to the
+device-op summary parsed from the same perfetto trace. The yardstick (plain
+jax, no Program) is wrapped in `record_event`; either run leaves a host
+timeline (`host_timeline.json`, chrome://tracing).
 
 Usage: python tools/step_profile.py [--yardstick]
 """
@@ -35,6 +36,15 @@ def summarize(trace_dir, top=30):
     with gzip.open(sorted(paths)[-1], "rt") as f:
         data = json.load(f)
     events = data.get("traceEvents", [])
+    # the program's own host spans: one paddle_tpu:run per exe.run, its
+    # phases inside it
+    spans = {}
+    for e in events:
+        if e.get("ph") == "X" and e.get("name", "").startswith("paddle_tpu:"):
+            spans.setdefault(e["name"], []).append(e.get("dur", 0))
+    for name, durs in sorted(spans.items(), key=lambda kv: -sum(kv[1])):
+        print(f"{name:60} {sum(durs) / 1e3:9.2f} {len(durs):5d} "
+              f"(mean {sum(durs) / len(durs):.0f} us)")
     # the per-op device timeline is the thread named "XLA Ops" on the
     # /device:TPU process
     op_tracks = set()
@@ -90,8 +100,7 @@ def main():
         np.asarray(out[0])
         prof.start_profiler(profile_path=trace_dir)
         for _ in range(3):
-            with prof.record_event("train_step"):
-                out = run()
+            out = run()     # its paddle_tpu:run span is in the trace
         with prof.record_event("fetch_sync"):
             np.asarray(out[0])
         prof.stop_profiler()

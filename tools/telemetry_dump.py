@@ -86,6 +86,16 @@ def print_status_table(doc):
                             key=lambda kv: -kv[1]):
         print(f"  {phase:<16} {us:>12.1f} us total")
     print("recompiles:", doc["recompiles"]["counts"] or "none")
+    for e in doc["recompiles"].get("events", []):
+        # which program compiled, why, and what each stage cost
+        stages = ", ".join(f"{k} {v:.3f} s"
+                           for k, v in e.get("stages_s", {}).items())
+        print(f"  program {e['program_uid']} {e['cause']} ({e['source']}): "
+              f"{stages or 'no stage recorded'} in "
+              f"{e.get('backend_compiles', 0)} backend compile(s); "
+              f"persistent cache "
+              f"{e.get('cache_hits', 0)} hit(s), "
+              f"{e.get('cache_misses', 0)} miss(es)")
     mem = doc.get("memory") or {}
     if mem.get("programs"):
         print(f"memory: peak est {mem['estimate_peak_bytes'] / 1e6:.2f} MB "
