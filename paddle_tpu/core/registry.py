@@ -151,7 +151,7 @@ class LoweringContext:
 # f32 grads to the optimizer automatically.
 AMP_BF16_OPS = frozenset({"conv2d", "depthwise_conv2d", "conv2d_transpose",
                           "mul", "matmul", "lstm", "gru", "fc",
-                          "fused_attention"})
+                          "fused_attention", "grouped_matmul"})
 # NOTE: plain `softmax` deliberately NOT f32-listed: jax.nn.softmax is
 # max-subtracted so bf16 is safe, and an f32 round trip on [B,H,T,T]
 # attention weights doubles the dominant HBM traffic of unfused attention.
@@ -161,7 +161,12 @@ AMP_F32_OPS = frozenset({"log_softmax", "cross_entropy",
                          "sigmoid_cross_entropy_with_logits",
                          "square_error_cost", "smooth_l1", "huber_loss",
                          "mean", "reduce_mean", "nce", "hierarchical_sigmoid",
-                         "linear_chain_crf", "warpctc", "cos_sim"})
+                         "linear_chain_crf", "warpctc", "cos_sim",
+                         # router logits, the softmax over all experts and
+                         # what both router losses are built from; rms_norm
+                         # keeps its statistics in f32 inside its rule and
+                         # needs no entry (its output stays in bf16)
+                         "moe_router"})
 # Mixed-dtype elementwise ops downcast the f32 side to bf16 instead of
 # letting numpy promotion upcast the bf16 side: one f32 mask/bias/table
 # leaking into the residual or attention-score stream would otherwise

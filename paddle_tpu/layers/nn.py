@@ -1325,3 +1325,144 @@ def sequence_erase(input, tokens, name=None):
             helper.append_op("assign", inputs={"X": [src.name]},
                              outputs={"Out": [dst.name]})
     return out
+
+
+# ---------------------------------------------------------------------------
+# decoder-LM blocks (no reference analog): RMSNorm, rotary embedding, the
+# silu-gated product, flash attention as a layer, and sparse experts
+# ---------------------------------------------------------------------------
+
+def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+    """`x * rsqrt(mean(x^2) + epsilon) * w` over the last axis, with a
+    learned weight of that width (initialised to 1); statistics in float32."""
+    helper = LayerHelper("rms_norm", **locals())
+    scale = helper.create_parameter(
+        param_attr, [input.shape[-1]], "float32",
+        default_initializer=init.ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op("rms_norm",
+                     inputs={"X": [input.name], "Scale": [scale.name]},
+                     outputs={"Y": [out.name]}, attrs={"epsilon": epsilon})
+    return out
+
+
+def rotary_embedding(input, theta=10000.0, name=None):
+    """Rotary position embedding (rotate-half convention) on
+    `[batch, heads, seq, head_dim]`, positions 0..seq-1."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op("rotary_embedding", inputs={"X": [input.name]},
+                     outputs={"Out": [out.name]},
+                     attrs={"theta": float(theta)})
+    return out
+
+
+def swiglu(gate, up, name=None):
+    """`silu(gate) * up`."""
+    helper = LayerHelper("swiglu", name=name)
+    out = helper.create_variable_for_type_inference(gate.dtype)
+    helper.append_op("swiglu", inputs={"Gate": [gate.name], "Up": [up.name]},
+                     outputs={"Out": [out.name]})
+    return out
+
+
+def fused_attention(q, k, v, causal=False, sm_scale=None, dropout_rate=0.0,
+                    is_test=False, name=None):
+    """Softmax attention on `[batch, heads, seq, head_dim]` through the
+    flash kernels (`ops/pallas_attention.py`): O(seq) memory, dropout on the
+    attention weights inside the kernel."""
+    helper = LayerHelper("fused_attention", name=name)
+    out = helper.create_variable_for_type_inference(q.dtype)
+    if sm_scale is None:
+        sm_scale = q.shape[-1] ** -0.5
+    helper.append_op("fused_attention",
+                     inputs={"Q": [q.name], "K": [k.name], "V": [v.name]},
+                     outputs={"Out": [out.name]},
+                     attrs={"causal": causal, "sm_scale": sm_scale,
+                            "dropout_rate": dropout_rate, "is_test": is_test})
+    return out
+
+
+def moe_router(input, num_experts, k, param_attr=None, name=None):
+    """Softmax top-k router over `input` [tokens, width]: float32 logits and
+    softmax over all `num_experts`, the k largest probabilities used as they
+    are (not renormalised). Returns a dict: `weight` and `index`
+    [tokens, k], `tokens_per_expert` [num_experts] (int32 counts of the
+    assignments), `probs` [tokens, num_experts] and `logsumexp` [tokens]
+    (what the load-balancing loss and the z-loss are built from)."""
+    helper = LayerHelper("moe_router", **locals())
+    w = helper.create_parameter(param_attr, [input.shape[-1], num_experts],
+                                "float32")
+    new = helper.create_variable_for_type_inference
+    outs = {"TopKWeight": new("float32"),
+            "TopKIndex": new("int32", stop_gradient=True),
+            "TokensPerExpert": new("int32", stop_gradient=True),
+            "Probs": new("float32"), "LogSumExp": new("float32")}
+    helper.append_op("moe_router", inputs={"X": [input.name], "W": [w.name]},
+                     outputs={s: [v.name] for s, v in outs.items()},
+                     attrs={"k": int(k)})
+    return {"weight": outs["TopKWeight"], "index": outs["TopKIndex"],
+            "tokens_per_expert": outs["TokensPerExpert"],
+            "probs": outs["Probs"], "logsumexp": outs["LogSumExp"]}
+
+
+def moe_experts(input, routing, num_experts, expert_size, param_attr=None,
+                name=None):
+    """Dropless gated-silu experts on `input` [tokens, width] under
+    `routing` (what `moe_router` returned): every assignment is computed,
+    `down_e(silu(gate_e(x)) * up_e(x))` summed over a token's experts with
+    its router weights; the rows are laid out by expert in groups of whole
+    row tiles (`ops/moe.py`), so the step's time does not follow the
+    routing. The weights are stacked over experts,
+    `<name>.gate.w` / `<name>.up.w` [experts, width, expert_size] and
+    `<name>.down.w` [experts, expert_size, width]; `param_attr` gives their
+    initializer."""
+    from ..ops.moe import ROW_TILE
+    from ..param_attr import ParamAttr
+    helper = LayerHelper("moe_experts", **locals())
+    prefix = name or helper.name
+    base = ParamAttr._to_attr(param_attr)
+    width = input.shape[-1]
+    dtype = input.dtype
+    new = helper.create_variable_for_type_inference
+
+    def weight(which, shape):
+        return helper.create_parameter(
+            ParamAttr(name=f"{prefix}.{which}.w",
+                      initializer=base.initializer), shape, "float32")
+
+    w_gate = weight("gate", [num_experts, width, expert_size])
+    w_up = weight("up", [num_experts, width, expert_size])
+    w_down = weight("down", [num_experts, expert_size, width])
+    x_sorted = new(dtype)
+    slot = new("int32", stop_gradient=True)
+    source = new("int32", stop_gradient=True)
+    sizes = new("int32", stop_gradient=True)
+    helper.append_op("moe_dispatch",
+                     inputs={"X": [input.name],
+                             "TopKIndex": [routing["index"].name],
+                             "TokensPerExpert":
+                                 [routing["tokens_per_expert"].name]},
+                     outputs={"XSorted": [x_sorted.name],
+                              "Slot": [slot.name], "Source": [source.name],
+                              "GroupSizes": [sizes.name]},
+                     attrs={"row_tile": ROW_TILE})
+
+    def grouped(x, w):
+        out = new(dtype)
+        helper.append_op(
+            "grouped_matmul",
+            inputs={"X": [x.name], "W": [w.name],
+                    "GroupSizes": [sizes.name]},
+            outputs={"Out": [out.name]})
+        return out
+
+    hidden = swiglu(grouped(x_sorted, w_gate), grouped(x_sorted, w_up))
+    y_sorted = grouped(hidden, w_down)
+    out = new(dtype)
+    helper.append_op("moe_combine",
+                     inputs={"Y": [y_sorted.name],
+                             "TopKWeight": [routing["weight"].name],
+                             "Slot": [slot.name], "Source": [source.name]},
+                     outputs={"Out": [out.name]})
+    return out

@@ -1,7 +1,8 @@
 """Model zoo mirroring the reference benchmark configs
 (reference: benchmark/fluid/models/{mnist,resnet,vgg,stacked_dynamic_lstm,
 machine_translation}.py) plus Transformer-base and DeepFM (the BASELINE.json
-target workloads)."""
+target workloads), and OLMoE: a sparse-expert decoder LM at a published width
+(the first model whose loss is a cross-entropy plus two router losses)."""
 
 from . import mnist  # noqa: F401
 from . import resnet  # noqa: F401
@@ -12,3 +13,4 @@ from . import deepfm  # noqa: F401
 from . import machine_translation  # noqa: F401
 from . import se_resnext  # noqa: F401
 from . import tiny_lm  # noqa: F401
+from . import olmoe  # noqa: F401

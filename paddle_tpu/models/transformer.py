@@ -41,19 +41,6 @@ def _pos_table(size, d_model):
     return out
 
 
-def _fused_attention(qh, kh, vh, d_head, causal, dropout_rate, is_test):
-    """Flash-attention op: one O(T)-memory Pallas kernel instead of the
-    matmul/softmax/dropout/matmul chain (in-kernel weight dropout)."""
-    helper = LayerHelper("fused_attention")
-    out = helper.create_variable_for_type_inference(dtype=qh.dtype)
-    helper.append_op("fused_attention",
-                     inputs={"Q": [qh.name], "K": [kh.name], "V": [vh.name]},
-                     outputs={"Out": [out.name]},
-                     attrs={"causal": causal, "sm_scale": d_head ** -0.5,
-                            "dropout_rate": dropout_rate, "is_test": is_test})
-    return out
-
-
 def multi_head_attention(q_in, kv_in, d_model, num_heads, dropout_rate=0.0,
                          causal=False, is_test=False, name="", fused=True):
     d_head = d_model // num_heads
@@ -70,8 +57,10 @@ def multi_head_attention(q_in, kv_in, d_model, num_heads, dropout_rate=0.0,
 
     qh, kh, vh = split_heads(q), split_heads(k), split_heads(v)
     if fused:
-        ctx = _fused_attention(qh, kh, vh, d_head, causal, dropout_rate,
-                               is_test)
+        ctx = layers.fused_attention(qh, kh, vh, causal=causal,
+                                     sm_scale=d_head ** -0.5,
+                                     dropout_rate=dropout_rate,
+                                     is_test=is_test)
     else:
         scores = layers.matmul(qh, kh, transpose_y=True, alpha=d_head ** -0.5)
         if causal:

@@ -16,3 +16,5 @@ from . import loss_extra  # noqa: F401
 from . import pallas_attention  # noqa: F401
 from . import paged_attention  # noqa: F401
 from . import extra_nn  # noqa: F401
+from . import decoder_block  # noqa: F401
+from . import moe  # noqa: F401

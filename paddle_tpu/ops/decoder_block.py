@@ -1,0 +1,59 @@
+"""The pieces of a 2024 decoder block that are not attention or a plain
+matmul: RMSNorm, rotary position embedding, the silu-gated product.
+
+No reference analog (the reference predates all three); the equations are
+those of the public `olmoe` / `llama`-style model code. Each is plain jnp,
+so XLA fuses it into its neighbours; statistics and trigonometry run in
+float32 whatever dtype flows through (under AMP the residual stream is
+bf16), and the result returns in the input's dtype.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..core.registry import register_op
+
+
+@register_op("rms_norm")
+def _rms_norm(ctx, X, Scale):
+    """`x * rsqrt(mean(x^2) + eps) * w` over the last axis."""
+    eps = ctx.attr("epsilon", 1e-5)
+    x32 = X.astype(jnp.float32)
+    ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    y = x32 * lax.rsqrt(ms + eps) * Scale.astype(jnp.float32)
+    return {"Y": y.astype(X.dtype)}
+
+
+def rotary_tables(seq_len, dim, theta):
+    """cos and sin `[seq_len, dim]` of the rotate-half convention: the
+    frequencies `theta^(-2i/dim)`, i < dim/2, repeated over both halves."""
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32)
+                                / dim))
+    angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * inv_freq
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+@register_op("rotary_embedding", propagate_seqlen=False)
+def _rotary_embedding(ctx, X):
+    """X `[..., T, D]` (heads already split): position t rotates the pair
+    `(x[i], x[i + D/2])` by `t * theta^(-2i/D)`; positions are 0..T-1."""
+    T, D = X.shape[-2], X.shape[-1]
+    if D % 2:
+        raise ValueError(f"rotary_embedding needs an even head size, got {D}")
+    cos, sin = rotary_tables(T, D, float(ctx.attr("theta", 10000.0)))
+    x32 = X.astype(jnp.float32)
+    x1, x2 = x32[..., : D // 2], x32[..., D // 2:]
+    rotated = jnp.concatenate([-x2, x1], axis=-1)
+    return {"Out": (x32 * cos + rotated * sin).astype(X.dtype)}
+
+
+@register_op("swiglu")
+def _swiglu(ctx, Gate, Up):
+    """`silu(gate) * up`, the gated feed-forward's elementwise middle."""
+    g32 = Gate.astype(jnp.float32)
+    return {"Out": (jax.nn.silu(g32) * Up.astype(jnp.float32))
+            .astype(Gate.dtype)}

@@ -33,6 +33,9 @@ BUILDS = {
                                           embedding_size=8),
     "machine_translation": lambda: models.machine_translation.build(
         dict_size=200, emb_dim=16, hidden_dim=16),
+    "olmoe": lambda: models.olmoe.build(
+        vocab_size=128, seq_len=128, n_layer=2, d_model=64, n_head=2,
+        n_expert=8, top_k=2, d_expert=32),
 }
 
 

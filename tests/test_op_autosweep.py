@@ -550,6 +550,37 @@ SPECS.update({
                   lod={"Input": np.array([4, 2], np.int32)},
                   outs=("Projection",), grad=["Weight", "ProjWeight"],
                   rtol=5e-2, atol=5e-3),
+
+    # ---- decoder-LM blocks and sparse experts ------------------------------
+    "rms_norm": Spec(inputs={"X": T(4, 6), "Scale": POS(6)}, outs=("Y",)),
+    "rotary_embedding": Spec(inputs={"X": T(1, 2, 4, 6)},
+                             attrs={"theta": 100.0}),
+    "swiglu": Spec(inputs={"Gate": T(3, 4), "Up": T(3, 4)}),
+    "moe_router": Spec(inputs={"X": T(6, 5), "W": T(5, 4) * 2},
+                       attrs={"k": 2},
+                       outs=("TopKWeight", "TopKIndex", "TokensPerExpert",
+                             "Probs", "LogSumExp")),
+    # expert 1 of 4 gets no row; groups of whole 8-row tiles, 8 + 4 * 8 rows
+    "moe_dispatch": Spec(inputs={"X": T(4, 3),
+                                 "TopKIndex": np.array(
+                                     [[2, 0], [3, 2], [0, 3], [2, 3]],
+                                     "int32"),
+                                 "TokensPerExpert": np.array(
+                                     [2, 0, 3, 3], "int32")},
+                         attrs={"row_tile": 8},
+                         outs=("XSorted", "Slot", "Source", "GroupSizes")),
+    # the middle expert gets no row: a group of size zero
+    "grouped_matmul": Spec(inputs={"X": T(6, 3), "W": T(3, 3, 4),
+                                   "GroupSizes": np.array([2, 0, 4],
+                                                          "int32")},
+                           amp=True),
+    # 4 tokens x 2 slots in 3 groups of 4 rows: rows 3, 7, 10, 11 are padding
+    "moe_combine": Spec(inputs={"Y": T(12, 3), "TopKWeight": POS(4, 2),
+                                "Slot": np.array(
+                                    [4, 0, 8, 5, 1, 9, 6, 2], "int32"),
+                                "Source": np.array(
+                                    [1, 4, 7, -1, 0, 3, 6, -1, 2, 5, -1, -1],
+                                    "int32")}),
 })
 
 # Waivers: ops whose correct behavior needs surrounding machinery that a

@@ -12,7 +12,6 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import layers, observe
-from paddle_tpu.models.transformer import _fused_attention
 
 PHASES = {"feed_convert", "state_gather", "jit_call", "write_back"}
 
@@ -130,7 +129,7 @@ def _attention_program():
     with fluid.program_guard(main, startup), fluid.unique_name.guard():
         x = layers.data(name="x", shape=[2, 8, 4], dtype="float32")
         q = layers.fc(input=x, size=4, num_flatten_dims=3, bias_attr=False)
-        out = _fused_attention(q, x, x, 4, True, 0.0, False)
+        out = layers.fused_attention(q, x, x, causal=True, sm_scale=4 ** -0.5)
         loss = layers.mean(out)
         fluid.optimizer.Adam(learning_rate=0.1).minimize(loss)
     return main, startup, loss
