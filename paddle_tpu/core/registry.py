@@ -186,17 +186,36 @@ def _cast_to(v, dt_from, dt_to):
     return v
 
 
-def call_rule(opdef: OpDef, ctx: LoweringContext, ins_by_slot: Dict[str, List[Any]]):
-    """Dispatch arrays to the rule per its signature; normalize outputs."""
-    amp_on = ctx.lowerer is not None and getattr(ctx.lowerer, "amp", False)
-    to_bf16 = amp_on and opdef.type in AMP_BF16_OPS
-    to_f32 = amp_on and opdef.type in AMP_F32_OPS
-    if amp_on and not to_bf16 and not to_f32 and opdef.type in AMP_DOWNCAST_OPS:
+def amp_cast(opdef: OpDef, ctx: LoweringContext,
+             ins_by_slot: Dict[str, List[Any]]) -> Dict[str, List[Any]]:
+    """`ins_by_slot` as AMP hands it to the op's rule: float32 -> bf16 for
+    AMP_BF16_OPS (and for a mixed-dtype AMP_DOWNCAST_OPS op), bf16 -> float32
+    for AMP_F32_OPS, untouched otherwise. `call_rule` applies it; a grad rule
+    that works on saved outputs instead of re-tracing the forward rule calls
+    it itself, so both see the same dtypes."""
+    if ctx.lowerer is None or not getattr(ctx.lowerer, "amp", False):
+        return ins_by_slot
+    to_bf16 = opdef.type in AMP_BF16_OPS
+    to_f32 = opdef.type in AMP_F32_OPS
+    if not to_bf16 and not to_f32 and opdef.type in AMP_DOWNCAST_OPS:
         dtypes = {jnp.dtype(v.dtype)
                   for vals in ins_by_slot.values() for v in vals
                   if hasattr(v, "dtype")}
         to_bf16 = (jnp.dtype(jnp.bfloat16) in dtypes
                    and jnp.dtype(jnp.float32) in dtypes)
+    if to_bf16:
+        pair = (jnp.float32, jnp.bfloat16)
+    elif to_f32:
+        pair = (jnp.bfloat16, jnp.float32)
+    else:
+        return ins_by_slot
+    return {slot: [_cast_to(v, *pair) for v in vals]
+            for slot, vals in ins_by_slot.items() if vals}
+
+
+def call_rule(opdef: OpDef, ctx: LoweringContext, ins_by_slot: Dict[str, List[Any]]):
+    """Dispatch arrays to the rule per its signature; normalize outputs."""
+    ins_by_slot = amp_cast(opdef, ctx, ins_by_slot)
     kwargs = {}
     for slot in opdef.input_slots:
         vals = ins_by_slot.get(slot)
@@ -204,10 +223,6 @@ def call_rule(opdef: OpDef, ctx: LoweringContext, ins_by_slot: Dict[str, List[An
             if slot not in opdef.optional_slots:
                 raise ValueError(f"op {opdef.type}: required input slot {slot!r} missing")
             continue
-        if to_bf16:
-            vals = [_cast_to(v, jnp.float32, jnp.bfloat16) for v in vals]
-        elif to_f32:
-            vals = [_cast_to(v, jnp.bfloat16, jnp.float32) for v in vals]
         kwargs[slot] = vals[0] if len(vals) == 1 else list(vals)
     out = opdef.lower(ctx, **kwargs)
     if out is None:

@@ -1370,14 +1370,23 @@ def fused_attention(q, k, v, causal=False, sm_scale=None, dropout_rate=0.0,
                     is_test=False, name=None):
     """Softmax attention on `[batch, heads, seq, head_dim]` through the
     flash kernels (`ops/pallas_attention.py`): O(seq) memory, dropout on the
-    attention weights inside the kernel."""
+    attention weights inside the kernel.
+
+    The op has a second output, `Lse`: the forward kernel's log-sum-exp of
+    every score row, float32 `[batch * heads, 1, seq]`, the kernels' own
+    layout. `fused_attention_grad` reads `Out` and `Lse` back and runs the
+    two backward kernels alone. Where the forward op wrote no `Lse` (under
+    sequence parallelism, on the CPU reference path, in a program built
+    without the slot) the grad op traces the forward again under `jax.vjp`."""
     helper = LayerHelper("fused_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
+    lse = helper.create_variable_for_type_inference("float32",
+                                                    stop_gradient=True)
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     helper.append_op("fused_attention",
                      inputs={"Q": [q.name], "K": [k.name], "V": [v.name]},
-                     outputs={"Out": [out.name]},
+                     outputs={"Out": [out.name], "Lse": [lse.name]},
                      attrs={"causal": causal, "sm_scale": sm_scale,
                             "dropout_rate": dropout_rate, "is_test": is_test})
     return out

@@ -14,6 +14,20 @@ Attention-weight dropout runs inside the kernel using the TPU PRNG
 k-block) so forward and both backward kernels regenerate identical masks in
 any iteration order.
 
+The `fused_attention` op writes two outputs on the kernel path: `Out` and
+`Lse`, the forward kernel's log-sum-exp of every score row (float32
+[B*H, 1, T], the layout the backward kernels read; never reshaped, never
+cast, in no AMP list). Its grad op reads both back and calls the dQ and
+dK/dV kernels alone, so the forward kernel runs once a step. Where the
+forward op left no `Lse` in the environment (a program built without the
+slot, ring attention under an 'sp' mesh axis, the CPU reference path) the
+grad op traces the forward rule again under `jax.vjp`, which is what the
+generic grad lowering (core/lowering.py) does for every op without a grad
+rule. That generic path is cheap where XLA merges the duplicated forward,
+and a debt wherever the rule holds a custom call, which XLA does not merge:
+such an op pays a second call a step (`pallas_dropout`, where
+FLAGS dropout_impl=pallas chooses it, is the other one on a training path).
+
 On a CPU backend the same kernels run under the Pallas interpreter when
 PADDLE_TPU_PALLAS_INTERPRET=1 (used by the CPU test suite); otherwise a
 pure-jnp reference path takes over there. On the TPU there is no second
@@ -32,7 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..core.registry import register_op
+from ..core.registry import (amp_cast, call_rule, get_op_def, register_grad,
+                             register_op)
 
 NEG_INF = -1e30
 
@@ -476,53 +491,78 @@ def _pallas_ok(q, dropout_rate=0.0):
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash_out_lse(q, k, v, seed, causal, sm_scale, dropout_rate):
+    """The forward kernel's two results: `out` [B, H, T, D] and the rows'
+    log-sum-exp, float32 [B*H, 1, T] (the layout the backward kernels read;
+    no gradient flows through it)."""
+    return _flash_forward(q, k, v, causal, sm_scale, dropout_rate, seed)
+
+
+def _fol_fwd(q, k, v, seed, causal, sm_scale, dropout_rate):
+    out, lse = _flash_forward(q, k, v, causal, sm_scale, dropout_rate, seed)
+    return (out, lse), (q, k, v, out, lse, seed)
+
+
+def _fol_bwd(causal, sm_scale, dropout_rate, res, g):
+    q, k, v, o, lse, seed = res
+    dq, dk, dv = _flash_backward(q, k, v, o, lse, g[0], causal, sm_scale,
+                                 dropout_rate, seed)
+    return dq, dk, dv, np.zeros(jnp.shape(seed), jax.dtypes.float0)
+
+
+_flash_out_lse.defvjp(_fol_fwd, _fol_bwd)
+
+
 def flash_attention(q, k, v, seed, causal=False, sm_scale=1.0,
                     dropout_rate=0.0):
-    """seed: int32 scalar (traced) driving attention-weight dropout."""
+    """seed: int32 scalar (traced) driving attention-weight dropout. For
+    direct callers (tools, tests): under `jax.grad` the forward kernel is
+    the residual pass and the backward kernels follow."""
     if _pallas_ok(q, dropout_rate):
-        out, _ = _flash_forward(q, k, v, causal, sm_scale, dropout_rate, seed)
-        return out
+        return _flash_out_lse(q, k, v, seed, causal, sm_scale,
+                              dropout_rate)[0]
     return _attention_reference(q, k, v, causal, sm_scale, dropout_rate, seed)
 
 
-def _fa_fwd(q, k, v, seed, causal, sm_scale, dropout_rate):
-    if _pallas_ok(q, dropout_rate):
-        out, lse = _flash_forward(q, k, v, causal, sm_scale, dropout_rate,
-                                  seed)
-        return out, (q, k, v, out, lse, seed)
-    out = _attention_reference(q, k, v, causal, sm_scale, dropout_rate, seed)
-    return out, (q, k, v, None, None, seed)
+def _attrs(ctx, Q):
+    """(sm_scale, causal, dropout rate) of a fused_attention op."""
+    rate = 0.0 if ctx.attr("is_test", False) else ctx.attr("dropout_rate", 0.0)
+    return (ctx.attr("sm_scale", 1.0 / math.sqrt(Q.shape[-1])),
+            ctx.attr("causal", False), float(rate))
 
 
-def _fa_bwd(causal, sm_scale, dropout_rate, res, g):
-    q, k, v, o, lse, seed = res
-    if o is not None:
-        dq, dk, dv = _flash_backward(q, k, v, o, lse, g, causal, sm_scale,
-                                     dropout_rate, seed)
-    else:
-        _, vjp = jax.vjp(
-            lambda a, b, c: _attention_reference(a, b, c, causal, sm_scale,
-                                                 dropout_rate, seed),
-            q, k, v)
-        dq, dk, dv = vjp(g)
-    dseed = np.zeros(jnp.shape(seed), jax.dtypes.float0)
-    return dq, dk, dv, dseed
+def _dropout_seed(ctx, rate):
+    """The int32 the kernels seed their masks from. The forward rule and
+    the grad op both take it from here, from the same per-op key
+    (core/lowering.py folds the forward op's index into the step's key for
+    both), so the backward kernels regenerate the mask the loss saw."""
+    if rate and ctx.key is not None:
+        return jax.random.key_data(ctx.key).reshape(-1)[0].astype(jnp.int32)
+    return jnp.int32(0)
 
 
-flash_attention.defvjp(_fa_fwd, _fa_bwd)
+def _fused_attention_infer(ctx, structs):
+    """Build-time shapes without a trace of the rule: a machine with no TPU
+    takes the reference path, which has no `Lse`, and the program it builds
+    may run on one that has."""
+    Q = structs["Q"][0]
+    B, H, T, _ = Q.shape
+    return {"Out": jax.ShapeDtypeStruct(Q.shape, Q.dtype),
+            "Lse": jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32)}
 
 
-@register_op("fused_attention", propagate_seqlen=False, needs_rng=True)
+@register_op("fused_attention", infer=_fused_attention_infer,
+             propagate_seqlen=False, needs_rng=True)
 def _fused_attention(ctx, Q, K, V):
     """Q/K/V: [B, H, T, Dh]. attrs: causal, sm_scale, dropout_rate, is_test.
 
     Replaces the reference's matmul+softmax+dropout+matmul composition
     (nets.py:329) with one O(T)-memory kernel. Dropout is applied to the
     attention weights inside the kernel, keyed from the executor's
-    functional PRNG."""
-    sm_scale = ctx.attr("sm_scale", 1.0 / math.sqrt(Q.shape[-1]))
-    causal = ctx.attr("causal", False)
-    rate = 0.0 if ctx.attr("is_test", False) else ctx.attr("dropout_rate", 0.0)
+    functional PRNG. On the kernel path the rule also returns `Lse`, the
+    forward kernel's log-sum-exp (float32 [B*H, 1, T]), which the grad op
+    reads back instead of running the forward kernel again."""
+    sm_scale, causal, rate = _attrs(ctx, Q)
     mesh = getattr(ctx.lowerer, "mesh", None) if ctx.lowerer else None
     if (mesh is not None and "sp" in mesh.axis_names
             and mesh.shape["sp"] > 1):
@@ -541,11 +581,44 @@ def _fused_attention(ctx, Q, K, V):
                 f"or choose an sp that divides it")
         return {"Out": ring_attention(Q, K, V, mesh, axis="sp",
                                       causal=causal, sm_scale=sm_scale)}
-    seed = jnp.uint32(0)
-    if rate and ctx.key is not None:
-        seed = jax.random.key_data(ctx.key).reshape(-1)[0]
-    return {"Out": flash_attention(Q, K, V, seed.astype(jnp.int32), causal,
-                                   sm_scale, float(rate))}
+    seed = _dropout_seed(ctx, rate)
+    if _pallas_ok(Q, rate):
+        out, lse = _flash_out_lse(Q, K, V, seed, causal, sm_scale, rate)
+        return {"Out": out, "Lse": lse}
+    return {"Out": _attention_reference(Q, K, V, causal, sm_scale, rate,
+                                        seed)}
+
+
+@register_grad("fused_attention")
+def _fused_attention_grad(ctx, ins, out_grads):
+    """dQ/dK/dV from the backward kernels alone, on the forward op's saved
+    `Out` and `Lse`. Which path runs is read off the environment: where the
+    forward op left no `Lse` (a program built without the slot, the ring
+    path, the CPU reference path) the forward rule is traced again under
+    `jax.vjp`, as the generic grad lowering does for every op without a
+    grad rule. A grad rule sees the scope's values, so AMP's casts
+    (registry.amp_cast: float32 -> bf16 for this op) and the cast of
+    `Out@GRAD` to the primal's dtype happen here."""
+    g = out_grads["Out"][0]
+    if g is None:
+        return {}
+    opdef = get_op_def("fused_attention")
+    slots = ("Q", "K", "V")
+    raw = [ins[s][0] for s in slots]
+    out, lse = ctx.fwd_outs["Out"][0], ctx.fwd_outs.get("Lse", [None])[0]
+    if lse is None:
+        out, vjp = jax.vjp(
+            lambda q, k, v: call_rule(
+                opdef, ctx, {"Q": [q], "K": [k], "V": [v]})["Out"][0], *raw)
+        grads = vjp(g.astype(out.dtype))
+    else:
+        cast = amp_cast(opdef, ctx, {s: [x] for s, x in zip(slots, raw)})
+        q, k, v = (cast[s][0] for s in slots)
+        sm_scale, causal, rate = _attrs(ctx, q)
+        grads = _flash_backward(q, k, v, out, lse, g.astype(out.dtype),
+                                causal, sm_scale, rate,
+                                _dropout_seed(ctx, rate))
+    return {s: d.astype(x.dtype) for s, d, x in zip(slots, grads, raw)}
 
 
 # ---------------------------------------------------------------------------

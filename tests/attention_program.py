@@ -1,0 +1,88 @@
+"""One `fused_attention` op in a program of its own, for the tests that hold
+its grad op (tests/test_flash_attention.py on the CPU under the Pallas
+interpreter, tests/test_flash_grad_tpu.py on the chip)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+import paddle_tpu as fluid
+from paddle_tpu import layers
+from paddle_tpu.core import registry
+from paddle_tpu.layer_helper import LayerHelper
+
+
+def float32_grad_layer(monkeypatch):
+    """Registers, for one test, an identity op whose grad rule hands a
+    float32 gradient to a bf16 input, as a hand-written grad rule may: what
+    makes `Out@GRAD` float32 beside a bf16 `Out` under AMP. Returns the
+    layer function."""
+    opdef = registry.OpDef(
+        "float32_grad_identity", lambda ctx, X: {"Out": X}, None, False,
+        False, grad_lower=lambda ctx, ins, out_grads: {
+            "X": out_grads["Out"][0].astype(jnp.float32)})
+    monkeypatch.setitem(registry._REGISTRY, opdef.type, opdef)
+
+    def layer(x):
+        helper = LayerHelper(opdef.type)
+        out = helper.create_variable_for_type_inference(x.dtype)
+        helper.append_op(opdef.type, inputs={"X": [x.name]},
+                         outputs={"Out": [out.name]})
+        return out
+    return layer
+
+
+def qkv_feed(names, shape=(2, 2, 256, 64), seed=5, dtype=np.float32):
+    rng = np.random.RandomState(seed)
+    feed = {n: rng.randn(*shape).astype(np.float32).astype(dtype)
+            for n in names}
+    feed["probe"] = rng.randn(*shape).astype(np.float32)
+    return feed
+
+
+def attention_grads(feed, causal, amp, rate=0.0, after=None, strip_lse=False,
+                    place=None):
+    """A program of one `fused_attention` over data vars (one var in all
+    three slots if the feed has only `q`), loss = sum(after(out) * probe):
+    returns Out, the fetched input gradients by name, and the traced step's
+    text."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        data = {n: layers.data(name=n, shape=list(x.shape),
+                               dtype=str(x.dtype), append_batch_size=False,
+                               stop_gradient=False)
+                for n, x in feed.items() if n != "probe"}
+        # an op in front, so that the attention op is not the block's op 0
+        q = layers.scale(data["q"], scale=1.0)
+        out = layers.fused_attention(q, data.get("k", q), data.get("v", q),
+                                     causal=causal, dropout_rate=rate)
+        if strip_lse:
+            del main.global_block().ops[-1].outputs["Lse"]
+        probe = layers.data(name="probe", shape=list(feed["probe"].shape),
+                            dtype="float32", append_batch_size=False)
+        loss = layers.reduce_sum(layers.elementwise_mul(
+            after(out) if after else out, probe))
+        fluid.append_backward(loss)
+    main.random_seed = 7
+    scope = fluid.Scope()
+    exe = fluid.Executor(place or fluid.CPUPlace(), amp=amp)
+    exe.run(startup, scope=scope)
+    wrt = sorted(data)
+    fetched = exe.run(main, feed=feed, scope=scope,
+                      fetch_list=[out] + [n + "@GRAD" for n in wrt])
+    return fetched[0], dict(zip(wrt, fetched[1:])), step_text(exe, main,
+                                                              scope, feed)
+
+
+def step_text(exe, main, scope, feed):
+    """The jaxpr of the executor's jitted step for `main`, as text: every
+    `pallas_call` appears in it with its `name=`."""
+    compiled, = [c for c in exe._cache.values() if c.program is main]
+    return str(compiled._step.trace(
+        feed, {n: scope.find_var(n) for n in compiled.mut_names},
+        {n: scope.find_var(n) for n in compiled.const_names},
+        np.uint32(0)).jaxpr)
+
+
+def kernel_calls(text, kernel):
+    return text.count(f"name={kernel}\n") + text.count(f"name={kernel} ")

@@ -14,10 +14,15 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu as fluid
-from paddle_tpu import models
+from paddle_tpu import layers, models
+from paddle_tpu.core import registry
+from paddle_tpu.ops import pallas_attention
 from paddle_tpu.ops.pallas_attention import (flash_attention,
                                              _attention_reference,
                                              ring_attention)
+
+from attention_program import (attention_grads, float32_grad_layer,
+                               kernel_calls, qkv_feed, step_text)
 
 
 @pytest.fixture
@@ -165,3 +170,136 @@ def test_flash_kernel_multiblock_streaming(interpret_kernels, causal):
     for a, b in zip(g, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-4, rtol=5e-4)
+
+
+# -- fused_attention saves its log-sum-exp; its grad op runs the backward
+#    kernels alone (ops/pallas_attention.py::_fused_attention_grad) ----------
+
+@pytest.mark.parametrize("names", [("q", "k", "v"), ("q",)],
+                         ids=["distinct_qkv", "one_var_in_three_slots"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_grad_op_on_saved_lse_is_bitwise_the_generic_path(
+        interpret_kernels, monkeypatch, causal, names):
+    """The same program lowered twice, with the op's grad rule and with it
+    taken off the op's definition (the generic vjp path, which runs the
+    forward kernel again): dQ/dK/dV bitwise equal over 2 x 2 blocks a row,
+    under AMP (float32 inputs the forward saw as bf16) with a float32
+    `Out@GRAD`; one forward kernel against two."""
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", (128, 128))
+    feed, float32_grad_op = qkv_feed(names), float32_grad_layer(monkeypatch)
+    out, grads, text = attention_grads(feed, causal, amp=True,
+                                        after=float32_grad_op)
+    monkeypatch.setattr(registry.get_op_def("fused_attention"), "grad_lower",
+                        None)
+    out_g, grads_g, text_g = attention_grads(feed, causal, amp=True,
+                                              after=float32_grad_op)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(out_g, np.float32))
+    for n in grads:
+        assert grads[n].dtype == np.float32 and np.abs(grads[n]).max() > 0
+        np.testing.assert_array_equal(grads[n], grads_g[n], err_msg=n)
+    assert [kernel_calls(text, k) for k in
+            ("flash_fwd", "flash_dq", "flash_dkv")] == [1, 1, 1]
+    assert [kernel_calls(text_g, k) for k in
+            ("flash_fwd", "flash_dq", "flash_dkv")] == [2, 1, 1]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_grad_op_on_saved_lse_matches_reference(interpret_kernels,
+                                                monkeypatch, causal):
+    """Float32, no AMP: the grad op's dQ/dK/dV against `jax.grad` of the
+    jnp reference, over 2 x 2 blocks a row."""
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", (128, 128))
+    feed = qkv_feed(("q", "k", "v"), shape=(1, 2, 256, 64))
+    out, grads, _ = attention_grads(feed, causal, amp=False)
+    want = jax.grad(lambda q, k, v: (_attention_reference(
+        q, k, v, causal, 64 ** -0.5) * feed["probe"]).sum(), (0, 1, 2))(
+            *(jnp.asarray(feed[n]) for n in "qkv"))
+    for n, w in zip("qkv", want):
+        np.testing.assert_allclose(grads[n], np.asarray(w), atol=1e-4,
+                                   rtol=1e-4, err_msg=n)
+
+
+def test_lse_has_a_shape_at_build_time_without_a_tpu():
+    """Shape inference does not trace the rule: on this machine the rule
+    takes the reference path and returns no `Lse`."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        q = layers.data(name="q", shape=[8, 256, 64], dtype="float32")
+        out = layers.fused_attention(q, q, q, causal=True)
+    block = main.global_block()
+    lse = block.var(block.ops[-1].outputs["Lse"][0])
+    assert tuple(out.shape) == (-1, 8, 256, 64)
+    assert tuple(lse.shape) == (-1, 1, 256) and str(lse.dtype) == "float32"
+    assert lse.stop_gradient
+
+
+def _tiny_transformer(strip_lse):
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        feeds, fetches = models.transformer.build(
+            src_vocab_size=64, trg_vocab_size=64, seq_len=128, n_layer=1,
+            n_head=2, d_model=32, d_inner=64, dropout_rate=0.0)
+        if strip_lse:
+            for op in main.global_block().ops:
+                if op.type == "fused_attention":
+                    del op.outputs["Lse"]
+        fluid.optimizer.Adam(learning_rate=1e-2).minimize(fetches["loss"])
+    return main, startup, fetches["loss"]
+
+
+@pytest.mark.parametrize("strip_lse", [False, True],
+                         ids=["with_lse", "program_without_lse"])
+def test_tiny_transformer_step_runs_flash_fwd_once_a_block(interpret_kernels,
+                                                           strip_lse):
+    """The lowered training step of the one-layer model (encoder self,
+    decoder self, cross attention) holds one `flash_fwd` `pallas_call` for
+    each attention block. A program whose ops have no `Lse` output (built
+    before the slot existed) falls back to the forward under `jax.vjp`, two
+    a block, and trains all the same."""
+    main, startup, loss = _tiny_transformer(strip_lse)
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace(), amp=True)
+    exe.run(startup, scope=scope)
+    rng = np.random.RandomState(0)
+    feed = {k: rng.randint(1, 64, (2, 128)).astype(np.int64)
+            for k in ("src_word", "trg_word", "lbl_word")}
+    losses = [float(np.asarray(exe.run(main, feed=feed, fetch_list=[loss],
+                                       scope=scope)[0]).reshape(-1)[0])
+              for _ in range(6)]
+    assert all(np.isfinite(l) for l in losses) and losses[-1] < losses[0]
+    text = step_text(exe, main, scope, feed)
+    assert kernel_calls(text, "flash_fwd") == (6 if strip_lse else 3)
+    assert kernel_calls(text, "flash_dq") == 3
+    assert kernel_calls(text, "flash_dkv") == 3
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_forward_op_and_grad_op_draw_one_dropout_mask(causal):
+    """Dropout on (CPU reference path; the grad op falls back to `jax.vjp`
+    over the rule): Out and the gradients through the program equal the
+    reference and its `jax.grad` at the key the forward op was given —
+    fold_in(step key, the op's index in the block) — so the grad op was
+    given the same one."""
+    rate, shape = 0.3, (2, 2, 128, 32)
+    feed = qkv_feed(("q", "k", "v"), shape=shape)
+    out, grads, _ = attention_grads(feed, causal, amp=False, rate=rate)
+    step_key = jax.random.fold_in(jax.random.key(7), np.uint32(0))
+    op_key = jax.random.fold_in(step_key, 1)      # scale is op 0
+    seed = jax.random.key_data(op_key).reshape(-1)[0].astype(jnp.int32)
+
+    def f(q, k, v):
+        return _attention_reference(q, k, v, causal, shape[-1] ** -0.5, rate,
+                                    seed)
+
+    q, k, v = (jnp.asarray(feed[n]) for n in "qkv")
+    np.testing.assert_allclose(out, np.asarray(f(q, k, v)), atol=1e-5,
+                               rtol=1e-5)
+    assert not np.allclose(out, np.asarray(_attention_reference(
+        q, k, v, causal, shape[-1] ** -0.5)), atol=1e-2)
+    want = jax.grad(lambda *a: (f(*a) * feed["probe"]).sum(), (0, 1, 2))(
+        q, k, v)
+    for n, w in zip("qkv", want):
+        np.testing.assert_allclose(grads[n], np.asarray(w), atol=1e-4,
+                                   rtol=1e-4, err_msg=n)

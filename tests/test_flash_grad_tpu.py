@@ -1,0 +1,71 @@
+"""TPU-only: `fused_attention_grad` on the forward op's saved `Out` and
+`Lse` against the generic vjp path (the forward kernel run again inside the
+grad op), at the benchmark cells' own shapes and tiles, with the cells'
+dropout rate. The CPU suite holds the same equality under the Pallas
+interpreter (tests/test_flash_attention.py), where dropout is off and a
+row has the blocks a test gives it; what only the chip can say is that the
+Mosaic kernels, the hardware PRNG's masks re-seeded per tile, and `Lse`
+written and read through 4, 8 or 16 q-blocks give the same bits both ways."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as fluid
+from paddle_tpu.core import registry
+from paddle_tpu.ops import pallas_attention
+
+from attention_program import (attention_grads, float32_grad_layer,
+                               kernel_calls, qkv_feed)
+
+pytestmark = pytest.mark.skipif(
+    jax.default_backend() != "tpu",
+    reason="Mosaic kernels and in-kernel dropout need real TPU hardware")
+
+# (q shape, causal, the tiles `_blk` gives it): transformer_base.seq256,
+# .seq2048 (encoder self and cross, decoder self), olmoe_1b_7b.bs1
+CELLS = [((96, 8, 256, 64), False, (256, 256)),
+         ((96, 8, 256, 64), True, (256, 256)),
+         ((12, 8, 2048, 64), False, (512, 2048)),
+         ((12, 8, 2048, 64), True, (256, 2048)),
+         ((1, 16, 4096, 128), True, (1024, 1024))]
+
+
+@pytest.mark.parametrize("inputs", ["bf16_inputs", "float32_out_grad"])
+@pytest.mark.parametrize("shape,causal,tiles", CELLS,
+                         ids=[f"{s[2]}x{s[3]}_{'causal' if c else 'full'}"
+                              for s, c, _ in CELLS])
+def test_saved_lse_grad_is_bitwise_the_generic_path(monkeypatch, shape,
+                                                    causal, tiles, inputs):
+    """`bf16_inputs`: bf16 Q/K/V in the scope, a bf16 `Out@GRAD`.
+    `float32_out_grad`: float32 Q/K/V that AMP casts for the op, and a
+    float32 `Out@GRAD` beside the bf16 `Out`."""
+    assert pallas_attention._blk(shape[2], causal) == tiles
+    if inputs == "bf16_inputs":
+        feed, after = qkv_feed(("q", "k", "v"), shape, dtype=jnp.bfloat16), None
+    else:
+        feed, after = qkv_feed(("q", "k", "v"), shape), \
+            float32_grad_layer(monkeypatch)
+    place = fluid.TPUPlace(0)
+    out, grads, text = attention_grads(feed, causal, amp=True, rate=0.1,
+                                       after=after, place=place)
+    monkeypatch.setattr(registry.get_op_def("fused_attention"), "grad_lower",
+                        None)
+    out_g, grads_g, text_g = attention_grads(feed, causal, amp=True, rate=0.1,
+                                             after=after, place=place)
+    assert kernel_calls(text, "flash_fwd") == 1
+    assert kernel_calls(text_g, "flash_fwd") == 2
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(out_g, np.float32))
+    for n in "qkv":
+        got, want = (np.asarray(g[n], np.float32) for g in (grads, grads_g))
+        assert grads[n].dtype == feed[n].dtype
+        assert np.isfinite(got).all() and np.abs(got).max() > 0
+        np.testing.assert_array_equal(got, want, err_msg=f"d{n}")
+    # dropout is on: another step (another key) gives another mask
+    assert not np.array_equal(np.asarray(out, np.float32), np.asarray(
+        attention_grads(feed, causal, amp=True, rate=0.0, after=after,
+                        place=place)[0], np.float32))
