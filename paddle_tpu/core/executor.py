@@ -555,7 +555,6 @@ class PreparedProgram:
         # fresh handle on the next run() (direct handle holders keep the
         # settings they prepared with — re-prepare to pick up flag flips)
         self._check_nan_inf = executor.check_nan_inf
-        self._dropout_impl = _flags.get_flag("dropout_impl")
         self._copts = resolve_compiler_options(self._device.platform, program)
         ls = [op for op in self._block.ops if op.type == "listen_and_serv"]
         self._serve_attrs = ls[0].attrs if ls else None
@@ -697,7 +696,7 @@ class PreparedProgram:
             copts = self._copts
             cache_key = (program._uid, program._version, sig,
                          tuple(self.fetch_names), self.scope._uid, exe.amp,
-                         self._check_nan_inf, self._dropout_impl,
+                         self._check_nan_inf,
                          tuple(sorted(copts.items())) if copts else None,
                          program.random_seed)  # seed is baked into the trace
             entry = exe._cache.get(cache_key)

@@ -1,6 +1,6 @@
 """Runtime flag registry (reference: gflags end-to-end — FLAGS_check_nan_inf
-/ FLAGS_benchmark etc. in C++, forwarded from `FLAGS_*` environment
-variables at import by python/paddle/fluid/__init__.py; SURVEY.md §5.6).
+etc. in C++, forwarded from `FLAGS_*` environment variables at import by
+python/paddle/fluid/__init__.py; SURVEY.md §5.6).
 
 Flags initialize from `PADDLE_TPU_<NAME>` (or legacy `FLAGS_<name>`)
 environment variables and can be flipped at runtime with `set_flag`:
@@ -15,12 +15,6 @@ from typing import Any, Dict
 _DEFS: Dict[str, tuple] = {
     # name: (default, type)
     "check_nan_inf": (False, bool),   # reference FLAGS_check_nan_inf
-    "benchmark": (False, bool),       # reference FLAGS_benchmark
-    "profile": (False, bool),
-    # dropout lowering: "auto"/"xla" = the fused counter-hash XLA path
-    # (measured default, docs/PERF.md); "pallas" forces the in-kernel-PRNG
-    # Pallas kernel on eligible tensors for A/B measurement
-    "dropout_impl": ("auto", str),
     # XLA compile options for the jitted step (round-5 flag sweep,
     # docs/PERF.md): "auto" = the measured-good TPU set (scoped VMEM
     # 32 MiB — bigger fusion budget, worth ~9% on transformer-base);
@@ -40,8 +34,7 @@ _DEFS: Dict[str, tuple] = {
     # span ids, span recording, and the traceparent element on outbound
     # RPC frames. Only consulted while "observe" is on; turning it off
     # leaves metrics/pulse armed but makes every wire frame legacy-shaped
-    # and every span a no-op — bench.py's horizon segment A/Bs exactly
-    # this bit to price trace context on the serve path
+    # and every span a no-op
     "trace": (True, bool),
 }
 
@@ -84,9 +77,8 @@ def get_flag(name: str):
 
 
 # enumerated string flags: value must be one of the choices (a typo like
-# dropout_impl=palas would otherwise silently select the default path)
+# validate=eror would otherwise silently select another behaviour)
 _CHOICES: Dict[str, tuple] = {
-    "dropout_impl": ("auto", "pallas", "xla"),
     "validate": ("error", "warn", "off"),
 }
 

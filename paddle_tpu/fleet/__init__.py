@@ -20,8 +20,7 @@ subsystem the repo already trusts:
 
 Drills: `tools/serve_loadgen.py --replicas N` (QPS scaling + skew-free
 swap under load), `tools/chaos_drill.py --scenario replica_kill` (a
-SIGKILLed replica degrades p99, not availability); bench.py's `fleet`
-segment records qps_scaling and p99_under_kill.
+SIGKILLed replica degrades p99, not availability).
 """
 
 from __future__ import annotations

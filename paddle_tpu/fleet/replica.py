@@ -270,7 +270,7 @@ class ReplicaServer(_wire.HardCutServer):
                        "version_key": ver_key,
                        "ttft_us": res.ttft_us,
                        # engine-observed TTFT rides FleetResult.outs so
-                       # fleet callers (torrent_bench's co-located arm)
+                       # fleet callers (a co-located prefill+decode arm)
                        # can compare first-token latency across modes
                        "outs": {"ttft_us": res.ttft_us,
                                 "finish_reason": res.finish_reason},

@@ -45,6 +45,14 @@ class UpdateLog:
         self._degraded = False  # guarded_by: self._cond
         # a fresh pair always starts with a sync
         self._needs_resync = True  # guarded_by: self._cond
+        # Until the first snapshot cut (`resume`/`rebase`) updates are
+        # numbered but not retained: the cut drops every record at or
+        # below it and the backup refuses records before its snapshot,
+        # so the window has nothing to protect yet. Retained, they can
+        # fill it and block an appender inside the mutator gate while
+        # the first sync's quiesce waits for that very mutator, until
+        # the stall timeout degrades the log.
+        self._cut_taken = False  # guarded_by: self._cond
 
     # -- primary write path ----------------------------------------------
     def append(self, cmd: str, payload: dict,
@@ -59,6 +67,10 @@ class UpdateLog:
         with self._cond:
             if self._degraded:
                 return None
+            if not self._cut_taken:
+                self._head += 1
+                self._acked = self._head
+                return self._head
             while self._head - self._acked >= self.window:
                 remaining = deadline - time.monotonic()
                 if remaining <= 0:
@@ -176,6 +188,7 @@ class UpdateLog:
         while self._records and self._records[0][0] <= self._acked:
             self._records.pop(0)
         self._degraded = False
+        self._cut_taken = True
         self._cond.notify_all()
 
     def resume(self, seq: int):

@@ -358,10 +358,11 @@ class HavenState:
 
     def _ensure_renewer(self) -> None:
         if self._renewer is None or not self._renewer.is_alive():
-            self._renewer = threading.Thread(
+            t = threading.Thread(
                 target=self._renew_loop, daemon=True,
                 name=f"quorum-renew@{self.server.endpoint}")
-            self._renewer.start()
+            t.start()   # before it is published: close() joins what it finds
+            self._renewer = t
 
     def _renew_loop(self) -> None:
         """Lease renewal at lease/3. The loop follows the LEASE, not
@@ -512,6 +513,11 @@ class HavenState:
                        self.server.endpoint, primary, epoch, self.epoch)
         _flight.note("haven_demotion", endpoint=self.server.endpoint,
                      new_primary=primary, epoch=epoch)
+        # epoch and primary before the role: whoever sees "backup" (a
+        # redirect verdict, a status read) must see whose backup, and
+        # stopping the replicator below can take seconds
+        self.epoch = max(self.epoch, int(epoch))
+        self.primary_ep = primary
         self.role = "backup"
         self._qlease = None   # the rival's higher epoch fenced our lease
         self._stop_replicator()
@@ -698,10 +704,11 @@ class HavenState:
         promotion, so a node demoted back to standby needs a fresh
         thread or it could never self-elect again."""
         if self._monitor is None or not self._monitor.is_alive():
-            self._monitor = threading.Thread(
+            t = threading.Thread(
                 target=self._monitor_loop, daemon=True,
                 name=f"haven-monitor@{self.server.endpoint}")
-            self._monitor.start()
+            t.start()   # before it is published: close() joins what it finds
+            self._monitor = t
 
     # -- wiring ------------------------------------------------------------
     def start_standby(self, auto_promote: bool = True) -> "HavenState":

@@ -80,7 +80,7 @@ def disable():
 
 def summary() -> dict:
     """One dict with everything a run left behind — what
-    tools/telemetry_dump.py prints and bench.py records. Derived from
+    tools/telemetry_dump.py prints. Derived from
     pulse.status_document() (the live `/status` body) minus process
     identity, so the dead- and live-process shapes CANNOT diverge —
     one source of truth for the one-tool-reads-both contract."""
@@ -91,7 +91,7 @@ def summary() -> dict:
 
 
 def reset():
-    """Clear every telemetry store (tests / between bench segments)."""
+    """Clear every telemetry store (tests)."""
     default_registry().reset()
     get_tracer().clear()
     get_steplog().clear()

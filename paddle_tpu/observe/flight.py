@@ -7,7 +7,7 @@ most recent *operationally interesting* records — step summaries, RPC
 outcomes, compile events, lease transitions, chaos injections — plus a
 named "stage", and dumps the whole thing as JSON when the process dies
 abnormally (SIGTERM, unhandled exception, or an explicit `dump()` from
-a crash path such as bench.py's wakeup-fd watcher).
+a crash path of the caller's own).
 
 Recording is an O(1) deque append under a lock; emitters gate on the
 `observe` flag exactly like the metrics registry where the path is hot
@@ -102,7 +102,7 @@ class FlightRecorder:
             self._events.append(ev)
 
     def set_stage(self, stage: Optional[str]):
-        """Name the phase the process is in (bench segment, drill
+        """Name the phase the process is in (drill
         scenario, epoch...) — dumped as `failure_stage`."""
         self._stage = stage
 
@@ -177,8 +177,8 @@ class FlightRecorder:
         after dumping — the process was being killed anyway, and a
         half-torn-down runtime should not keep running.
 
-        Only usable from the main thread (CPython signal rule); bench.py
-        keeps its own wakeup-fd watcher and just calls `dump()`."""
+        Only usable from the main thread (CPython signal rule); a
+        caller with its own signal watcher just calls `dump()`."""
         self._dump_path = path
         self._extra_dump = extra
         if not self._installed and excepthook:

@@ -15,9 +15,8 @@ error. The observatory must be safe to consult from a signal handler
 public entry point swallows backend exceptions.
 
 Estimates are recorded at executor compile time (never hot, and only
-while the `observe` flag is on); bench.py reads `segment_peak()` per
-segment and `tools/telemetry_dump.py` / the pulse `/status` endpoint
-render `report()`.
+while the `observe` flag is on); `tools/telemetry_dump.py` / the pulse
+`/status` endpoint render `report()`.
 """
 
 from __future__ import annotations
@@ -92,8 +91,7 @@ class MemoryObservatory:
                        default=0.0)
 
     def segment_peak(self, reset: bool = False) -> float:
-        """Max peak estimate recorded since the last reset (bench.py
-        reads this per segment)."""
+        """Max peak estimate recorded since the last reset."""
         with self._lock:
             v = self._segment_peak
             if reset:

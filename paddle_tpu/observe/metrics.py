@@ -5,8 +5,8 @@ Fluid aggregate counts; TensorFlow's whitepaper credits built-in metrics
 plumbing for making large-scale training debuggable. Here the registry is
 a plain thread-safe in-process store — no exporter daemon, no deps — with
 `snapshot()` (dict), `to_json()` and `to_prometheus()` (text exposition
-format) so a training loop, bench.py, or tools/telemetry_dump.py can dump
-it at any point.
+format) so a training loop or tools/telemetry_dump.py can dump it at any
+point.
 
 All three metric kinds support labels passed as keyword arguments:
 

@@ -26,8 +26,7 @@ Two runtime questions dominate TPU cost and were previously invisible:
    - ``new_scope``        the same program bound against a different
                           Scope (train/test scopes, per-request scopes)
    - ``options_change``   an executor-setting flip re-keyed the compile
-                          cache (amp / check_nan_inf / dropout_impl /
-                          random_seed)
+                          cache (amp / check_nan_inf / random_seed)
    - ``uncached``         use_program_cache=False (tests probing
                           recompilation; never attributed further)
    - ``warmup``           an ahead-of-time compile the serving layer
@@ -338,10 +337,10 @@ class RecompilationObservatory:
     ``program_version``; new compiler options → ``copts_change``; new
     feed-name set → ``feed_names``; new fetch list → ``fetch_set``; new
     scope → ``new_scope``; anything else that re-keyed the compile cache
-    (amp / check_nan_inf / dropout_impl / random_seed flips) →
-    ``options_change``. Run-time shape tracking (flag-gated, see note in
-    the module docstring) reports jax-level retraces of an already-bound
-    entry as ``feed_shape``."""
+    (amp / check_nan_inf / random_seed flips) → ``options_change``.
+    Run-time shape tracking (flag-gated, see note in the module
+    docstring) reports jax-level retraces of an already-bound entry as
+    ``feed_shape``."""
 
     def __init__(self, capacity: int = 256):
         self._events: deque = deque(maxlen=capacity)
@@ -377,7 +376,7 @@ class RecompilationObservatory:
             else:
                 # every observed key dimension matched, so the re-key came
                 # from an executor-setting flip (amp / check_nan_inf /
-                # dropout_impl / random_seed)
+                # random_seed)
                 cause = "options_change"
             s["versions"].add(version)
             s["copts"].add(copts_sig)

@@ -207,43 +207,13 @@ def _dropout(ctx, X):
     if p >= 1.0:
         # degenerate: drop everything (upscale would divide by zero)
         return {"Out": jnp.zeros_like(X), "Mask": jnp.zeros_like(X)}
-    # Hot path: Pallas kernel with in-kernel TPU PRNG — XLA's counter-based
-    # RNG is a long VPU integer chain that dominated transformer step time
-    # (reference dropout_op.cu pays the same via cuRAND but on idle SMs).
-    # The kernel's custom_vjp regenerates the mask from the seed, so no
-    # mask tensor ever hits HBM.
-    from . import pallas_dropout
-    from .. import flags as _flags
-    # Path choice (measured, docs/PERF.md): the Pallas kernel's in-kernel
-    # PRNG made it the winner over threefry-fed XLA dropout, but it is a
-    # fusion barrier — one extra read+write of the tensor fwd AND bwd.
-    # With the counter-hash bits path (below) the XLA version fuses into
-    # the surrounding chain at ~zero HBM cost, so "auto" prefers it; the
-    # kernel stays selectable for A/B via FLAGS dropout_impl=pallas.
-    impl_flag = _flags.get_flag("dropout_impl")
-    if impl_flag == "pallas" and jax.default_backend() != "cpu":
-        # asked for by name: what the kernel cannot take raises rather
-        # than quietly running the XLA path under the kernel's label
-        if impl != "upscale_in_train" or not pallas_dropout.supports(X, p):
-            raise ValueError(
-                f"dropout_impl=pallas supports upscale_in_train dropout "
-                f"with 0 < p < 1 on a lane-aligned minor dim; got "
-                f"implementation {impl!r}, p={p}, shape {X.shape}")
-        seed = (jax.random.key_data(ctx.key).reshape(-1)[0]
-                .astype(jnp.int32).reshape(1, 1))
-        out = pallas_dropout.dropout_tpu(X, seed, float(p))
-        # The true keep mask, regenerated from the same seed over a
-        # never-zero input. It's an independent expression, so XLA DCEs
-        # it when nothing consumes the Mask output (the backward doesn't:
-        # the vjp re-derives the mask in-kernel).
-        mask = (pallas_dropout.dropout_tpu(
-            jnp.ones(X.shape, jnp.float32), seed, float(p)) != 0)
-        return {"Out": out, "Mask": mask.astype(X.dtype)}
-    # XLA fallback: uint8 bit-compare instead of bernoulli (bernoulli
-    # materializes a full f32 uniform tensor; one random byte per element
-    # decides keep at 1/256 resolution and fuses into the chain at a
-    # quarter of the RNG traffic). custom_vjp regenerates the bits in the
-    # backward so the mask is never stored as a residual.
+    # One random byte per element from a counter hash decides keep at
+    # 1/256 resolution (a bernoulli draw would materialize a full f32
+    # uniform tensor): ~8 integer ops that XLA fuses into the surrounding
+    # elementwise chain, so the op adds no pass over HBM. A custom kernel
+    # here would be a fusion barrier, one extra read and write of the
+    # tensor forward and backward. The custom_vjp regenerates the bits in
+    # the backward, so the mask is never stored as a residual.
     scale = 1.0 if impl != "upscale_in_train" else 1.0 / (1.0 - p)
     out = _bits_dropout(X, ctx.key, float(p), float(scale))
     # true keep mask from the same key; DCE'd when the Mask var is unused

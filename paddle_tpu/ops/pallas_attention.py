@@ -25,8 +25,8 @@ grad op traces the forward rule again under `jax.vjp`, which is what the
 generic grad lowering (core/lowering.py) does for every op without a grad
 rule. That generic path is cheap where XLA merges the duplicated forward,
 and a debt wherever the rule holds a custom call, which XLA does not merge:
-such an op pays a second call a step (`pallas_dropout`, where
-FLAGS dropout_impl=pallas chooses it, is the other one on a training path).
+such an op pays a second call a step. `fused_attention` is the only op on a
+training path whose rule holds one, and it has its `grad_lower`.
 
 On a CPU backend the same kernels run under the Pallas interpreter when
 PADDLE_TPU_PALLAS_INTERPRET=1 (used by the CPU test suite); otherwise a

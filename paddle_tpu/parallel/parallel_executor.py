@@ -228,14 +228,13 @@ class ParallelExecutor:
     def _bind(self, fast_key, feed_arrays, fetch_names):
         """(compiled step, its cache key) for a feed signature and fetch
         set the fast memo has not seen: from the compile cache, or built."""
-        from .. import flags as _flags
         from ..core.executor import resolve_compiler_options
         copts = resolve_compiler_options(
             self._mesh.devices.flat[0].platform, self._program)
         copts_sig = tuple(sorted(copts.items())) if copts else None
         feed_sig = tuple(sorted(feed_arrays))
         key = (self._program._uid, self._program._version, feed_sig,
-               tuple(fetch_names), _flags.get_flag("dropout_impl"), copts_sig)
+               tuple(fetch_names), copts_sig)
         compiled = self._cache.get(key)
         if compiled is None:
             _steplog.observatory().note_entry_build(

@@ -18,8 +18,8 @@ each independently testable:
   admission (QueueFullError fast-reject) and per-request deadlines.
 
 `serve.InferenceServer` fronts all three. Load-test with
-`tools/serve_loadgen.py`; bench.py records `serve_p50_us`/`serve_p99_us`
-/`serve_qps`/`serve_recompiles`.
+`tools/serve_loadgen.py` (`serve_p50_us`/`serve_p99_us`/`serve_qps`/
+`serve_recompiles`).
 """
 
 from __future__ import annotations

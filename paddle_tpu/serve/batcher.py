@@ -73,23 +73,15 @@ class SlotScheduler:
     tracks a fixed array of SLOTS (the decode step's batch rows): a
     finished sequence vacates its slot mid-batch and the next queued
     request is admitted into the hole without stopping the slots still
-    running — CONTINUOUS batching. `admission="drain"` is the deliberate
-    strawman (refill only when every slot is empty — the classic
-    drain-and-refill baseline the bench A/Bs against).
+    running — CONTINUOUS batching.
 
     Admission control mirrors MicroBatcher: a bounded pending queue with
     fast-reject (QueueFullError) and queued-deadline expiry. The decode
     engine owns WHAT runs in a slot; the scheduler owns which slots run.
     """
 
-    def __init__(self, n_slots: int, max_queue: int = 256,
-                 admission: str = "continuous"):
-        if admission not in ("continuous", "drain"):
-            raise ValueError(
-                f"admission must be 'continuous' or 'drain', "
-                f"got {admission!r}")
+    def __init__(self, n_slots: int, max_queue: int = 256):
         self.n_slots = int(n_slots)
-        self.admission = admission
         self.max_queue = int(max_queue)
         self.cond = threading.Condition()
         self.slots: List[Optional[object]] = [None] * self.n_slots
@@ -132,10 +124,8 @@ class SlotScheduler:
         free = [i for i, s in enumerate(self.slots) if s is None]
         if not self.pending or not free:
             return []
-        if self.admission == "drain" and self.active_count():
-            return []     # the strawman: wait for the whole batch
         want = min(self.ADMIT_BATCH, len(self.pending), self.n_slots)
-        if self.admission == "continuous" and len(free) < want:
+        if len(free) < want:
             return []     # let a small admission batch accumulate
         return free
 

@@ -19,8 +19,7 @@ useless for decode. The engine splits the phases:
 - **Continuous batching** (serve/batcher.py SlotScheduler): a finished
   sequence vacates its slot between steps and a queued request is
   prefilled into the hole while the other slots keep decoding — the
-  batch never drains. `admission="drain"` keeps the classic
-  drain-and-refill behavior for the bench A/B.
+  batch never drains.
 
 Sampling is greedy argmax on the host — generations are deterministic,
 so continuous-vs-solo token parity is testable (and the loadgen's
@@ -149,22 +148,19 @@ class DecodeEngine:
     """One generative model's slots + decode thread."""
 
     def __init__(self, registry, name: str, max_queue: int = 256,
-                 admission: str = "continuous",
                  simulate_prefill_us_per_token: float = 0.0,
                  simulate_decode_step_us: float = 0.0):
         self._registry = registry
         self._name = name
-        # rehearsal-rig knobs (tools/bench honesty posture): model the
-        # compute-bound prefill (us per PADDED token of the chunk) and
-        # memory-bound decode (us per fixed-slot STEP — the whole-cache
+        # rehearsal-rig knobs: model the compute-bound prefill (us per
+        # PADDED token of the chunk) and memory-bound decode (us per fixed-slot STEP — the whole-cache
         # read every step pays regardless of live lanes) so topology
         # effects show on the CPU test backend
         self._sim_prefill_us = float(simulate_prefill_us_per_token)
         self._sim_decode_us = float(simulate_decode_step_us)
         self._requant_seen = 0            # engine thread only
         sig = registry.get(name).decode.signature
-        self._sched = SlotScheduler(sig["max_slots"], max_queue=max_queue,
-                                    admission=admission)
+        self._sched = SlotScheduler(sig["max_slots"], max_queue=max_queue)
         self._cond = self._sched.cond
         self._ver = None                  # acquired while slots are live
         self._closed = False
@@ -362,7 +358,6 @@ class DecodeEngine:
         return {
             "active_slots": active,
             "queued": pending,
-            "admission": self._sched.admission,
             "tokens": self._m_tokens.value(model=self._name),
             "steps": self._m_steps.value(model=self._name),
             "avg_ttft_us": round(ttft["mean"], 1) if ttft else 0.0,

@@ -45,10 +45,6 @@ class ServeConfig:
     max_queue: int = 256              # admission-control bound, requests
     default_deadline_ms: Optional[float] = None
     watch_interval_s: float = 2.0
-    # fluid-decode: slot-admission policy for generative models —
-    # "continuous" (finished sequences vacate mid-batch, default) or
-    # "drain" (classic drain-and-refill; the bench A/B baseline)
-    decode_admission: str = "continuous"
     # fluid-torrent rehearsal knobs (tools/ fleet processes): model the
     # compute-bound prefill / memory-bound decode cost split on the CPU
     # test backend — 0.0 disables (see DecodeEngine)
@@ -165,7 +161,6 @@ class InferenceServer:
                     self.registry, name,
                     max_queue=(max_queue if max_queue is not None
                                else self.config.max_queue),
-                    admission=self.config.decode_admission,
                     simulate_prefill_us_per_token=(
                         self.config.simulate_prefill_us_per_token),
                     simulate_decode_step_us=(

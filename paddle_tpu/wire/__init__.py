@@ -23,7 +23,7 @@ compressed parameter-server traffic. Two prongs, one numerical contract
 
 Compression is a first-class metric: `pserver_wire_bytes_raw` /
 `pserver_wire_bytes_encoded` counters per command (surfaced by
-`tools/telemetry_dump.py --format table` and bench.py's `wire` segment).
+`tools/telemetry_dump.py --format table`).
 """
 
 from __future__ import annotations

@@ -123,15 +123,6 @@ def test_unseeded_programs_draw_decorrelated_masks():
         "unseeded programs drew identical dropout masks"
 
 
-def test_pallas_dropout_supports_gate():
-    from paddle_tpu.ops import pallas_dropout as pd
-    import jax.numpy as jnp
-    assert pd.supports(jnp.zeros((4, 8, 256)), 0.1)
-    assert not pd.supports(jnp.zeros((4, 100)), 0.1)   # minor dim not 128-al
-    assert not pd.supports(jnp.zeros((4, 256)), 0.0)   # no-op rate
-    assert not pd.supports(jnp.zeros((4, 256)), 1.0)
-
-
 def test_batch_norm_amp_dtype():
     """BN keeps X's dtype on Y while computing f32 stats (conv models)."""
     x = layers.data(name="x", shape=[-1, 8, 4, 4], dtype="float32",

@@ -359,8 +359,7 @@ def test_async_feeder_overlap_speedup():
 
     # producer sleeps 4x the calibrated step: under xdist contention the
     # step can only get SLOWER than calibrated, which RAISES the
-    # overlap ratio's floor of 1.25 — robust to parallel workers
-    # (bench.py runs the sleep_factor=1 variant solo and records ~2x).
+    # overlap ratio's floor of 1.25 — robust to parallel workers.
     # One retry: on this 1-core box a worst-case scheduling burst can
     # still starve the producer thread mid-window (observed ~1/run-of-
     # suite); a genuine overlap regression fails both attempts.

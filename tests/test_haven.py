@@ -125,6 +125,28 @@ def test_update_log_lag_is_nonzero_while_resync_pending():
     assert log.lag() == 0
 
 
+def test_update_log_before_the_first_cut_counts_but_never_blocks():
+    """Regression pin for the tier-1 flake of
+    test_failover_loss_bounded_by_inflight_window: with window=8 and a
+    forwarder slow to reach its first sync, the 9th pre-cut append
+    blocked on the full window INSIDE the mutator gate while the sync's
+    quiesce waited for that mutator; both sat out stall_timeout_s, the
+    log degraded, and seqs stopped counting updates. Before the first
+    cut nothing is retained (the cut would drop it), so nothing blocks."""
+    log = UpdateLog(window=2, stall_timeout_s=30.0)
+    t0 = time.monotonic()
+    assert [log.append("push_grad", {"i": i}) for i in range(5)] \
+        == [1, 2, 3, 4, 5]
+    assert time.monotonic() - t0 < 5.0
+    assert not log.degraded and log.batch() == []
+    assert log.lag() == 1             # still "not caught up": no sync yet
+    log.resume(log.head_seq)          # the first cut, at seq 5
+    assert log.append("push_grad", {}) == 6
+    assert [s for s, _c, _p, _tr in log.batch()] == [6]
+    log.rebase(5)
+    assert log.lag() == 1 and not log.needs_resync
+
+
 # -- replication ----------------------------------------------------------
 
 def test_replicated_pair_is_bit_identical_to_unreplicated_baseline():
@@ -207,9 +229,16 @@ def test_failover_loss_bounded_by_inflight_window():
         for g in grads[:12]:
             c.push_grad(ep, "w", g)
         _wait(lambda: primary._haven.log.lag() == 0, what="ack drain")
+        # seq == updates applied only while the log never degraded
+        assert not primary._haven.log.degraded
+        assert primary._haven.log.head_seq == 13
         # freeze the forwarder (a backup that stopped acking): the next
         # pushes are applied on the primary but stay in-flight
-        primary._haven._replicator.stop()
+        rep = primary._haven._replicator
+        fwd = rep._thread
+        rep.stop()
+        fwd.join(timeout=10.0)
+        assert not fwd.is_alive(), "a live forwarder would still ack"
         for g in grads[12:12 + WINDOW - 1]:
             c.push_grad(ep, "w", g)
         inflight = primary._haven.log.lag()

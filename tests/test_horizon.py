@@ -80,7 +80,7 @@ def test_baggage_is_bounded_and_stringified():
 
 
 def test_trace_flag_disarms_spans_and_wire_meta(observe_on):
-    """The `trace` kill switch (bench.py's horizon A/B baseline):
+    """The `trace` kill switch:
     observe stays on, but span creation no-ops and outbound frames go
     legacy-shaped — no ids allocated, nothing recorded."""
     fluid.set_flag("trace", False)
@@ -666,6 +666,7 @@ def test_update_log_batch_carries_trace_and_tolerates_legacy():
     log = fluid.haven.UpdateLog(window=8) if hasattr(fluid, "haven") \
         else __import__("paddle_tpu.haven",
                         fromlist=["UpdateLog"]).UpdateLog(window=8)
+    log.rebase()   # fresh pair synced at seq 0: records are retained
     log.append("push_grad", {"name": "w"}, trace="00-" + "a" * 32 +
                "-" + "b" * 16 + "-01")
     log.append("push_grad", {"name": "v"})          # untraced

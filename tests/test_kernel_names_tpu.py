@@ -54,8 +54,7 @@ def test_flash_kernels_keep_their_names_in_the_compiled_step():
     # and Lse, so no forward kernel is lowered again inside it
     for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
         assert sum(kernel in n for n in names) == 3, (kernel, names)
-    assert all(any(k in n for k in ("flash_fwd", "flash_dq", "flash_dkv",
-                                    "pallas_dropout"))
+    assert all(any(k in n for k in ("flash_fwd", "flash_dq", "flash_dkv"))
                for n in names), names
 
 

@@ -257,8 +257,7 @@ def test_predicted_mfu_within_band_of_recorded_bench():
     """Roofline honesty: predicted MFU of the bench transformer (full
     base config, batch 64 x seq 256) against the MFU the BENCH_r04
     round measured, using that round's measured peak. The documented
-    band is 0.6-1.6 (docs/PLANNER.md §calibration); bench.py re-records
-    the live ratio as plan_agreement every round."""
+    band is 0.6-1.6 (docs/PLANNER.md §calibration)."""
     # recorded 2026-07-31 on a removed installation (BENCH_r04.json, in
     # git history only); nothing on the current one has replaced them
     measured_mfu = 0.464

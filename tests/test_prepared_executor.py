@@ -204,11 +204,11 @@ def test_run_still_fast_pathed_after_flag_flip():
     f = _batches(1)[0]
     out0, = exe.run(main, feed=f, fetch_list=[loss], scope=scope)
     n_cache = len(exe._cache)
-    fluid.flags.set_flag("benchmark", True)  # unrelated flag: new memo key
+    fluid.flags.set_flag("trace", False)  # unrelated flag: new memo key
     try:
         out1, = exe.run(main, feed=f, fetch_list=[loss], scope=scope)
     finally:
-        fluid.flags.set_flag("benchmark", False)
+        fluid.flags.set_flag("trace", True)
     assert len(exe._cache) == n_cache  # no recompile
 
 

@@ -381,7 +381,7 @@ def test_executor_compile_path_feeds_memory_observatory():
     assert all(r["peak_bytes"] > 0 for r in progs.values())
     assert obs.segment_peak() >= max(r["peak_bytes"]
                                      for r in progs.values())
-    # bench.py's per-segment read: drain and start fresh
+    # a per-segment read: drain and start fresh
     peak = obs.segment_peak(reset=True)
     assert peak > 0 and obs.segment_peak() == 0.0
     # re-running the same shapes compiles nothing and adds nothing

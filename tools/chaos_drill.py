@@ -611,7 +611,7 @@ def drill_replica_kill(seed, workdir, trace_out=None):
     the dead replica's membership lease expires (it stops renewing),
     and the survivors keep serving with zero steady-state recompiles.
     Emits a JSON line (fleet_p99_pre_kill_us / fleet_p99_post_kill_us /
-    fleet_kill_failed) that bench.py's `fleet` segment records."""
+    fleet_kill_failed)."""
     import json
     import random
     import signal

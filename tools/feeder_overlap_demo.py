@@ -9,8 +9,7 @@ CPU backend:
   async loop : AsyncFeeder produces on its thread while the consumer steps
 
 With production cost ~= step cost, perfect overlap halves the loop time;
-the demo asserts >= 1.3x. Run standalone or via bench.py (subprocess,
-because the bench process has already initialized the TPU backend).
+the demo asserts >= 1.3x (tests/test_data_plane.py runs it).
 """
 
 from __future__ import annotations

@@ -2,7 +2,7 @@
 """fluid-serve load generator: closed+open-loop, with a hot-swap drill.
 
 Drives an in-process InferenceServer with mixed-shape traffic and
-reports the serving numbers bench.py records:
+reports the serving numbers:
 
     python tools/serve_loadgen.py --duration 10
         phase 1 (closed loop): N threads issue back-to-back requests —
@@ -20,8 +20,7 @@ reports the serving numbers bench.py records:
         probe set is decoded SOLO first; probe prompts re-issued under
         load must produce token-identical generations (greedy decode is
         deterministic — any divergence is a KV-cache aliasing or
-        batching bug). `--admission drain` runs the drain-and-refill
-        baseline the bench A/Bs against.
+        batching bug).
 
 Exit status is the CI gate: nonzero if ANY steady-state recompile was
 recorded by the observatory after warmup (cause `padding_bucket` means
@@ -99,8 +98,7 @@ def run_generate(args):
         prefill_rows=(1, 2, 4), prefill_seq_rungs=(8, 16))
     srv = serve.InferenceServer(
         fluid.CPUPlace(),
-        serve.ServeConfig(max_queue=args.max_queue, watch_interval_s=0.2,
-                          decode_admission=args.admission))
+        serve.ServeConfig(max_queue=args.max_queue, watch_interval_s=0.2))
     srv.add_model("g", mdir)
     v0 = srv.registry.get("g").version_id
 
@@ -234,7 +232,6 @@ def run_generate(args):
         "decode_rejected": rejected[0],
         "decode_mismatches": len(mismatches),
         "decode_hot_swap_ok": bool(swapped["ok"]),
-        "decode_admission": args.admission,
         "decode_steps": stats["steps"],
         "decode_avg_occupancy": round(
             tokens / max(stats["steps"], 1), 2),
@@ -263,7 +260,7 @@ def run_generate(args):
         print("FAIL: hot swap never landed", file=sys.stderr)
         rc = 1
     if rc == 0:
-        print(f"decode loadgen OK ({args.admission}): "
+        print(f"decode loadgen OK: "
               f"{out['decode_tokens_per_s']} tok/s, ttft p50 "
               f"{out['ttft_p50_us']:.0f} us / p99 "
               f"{out['ttft_p99_us']:.0f} us, {len(results)} generations, "
@@ -287,7 +284,7 @@ def run_fleet(args):
     precedes every new-version one; (3) zero steady-state recompiles on
     EVERY replica process (each replica's own observatory, summed over
     the fleet via the fleet_stats RPC). JSON carries fleet_qps /
-    fleet_p50_us / fleet_p99_us for bench.py's qps-scaling segment.
+    fleet_p50_us / fleet_p99_us.
 
     `--fleet-model deepfm-sparse` swaps the tiny MLP for a DeepFM whose
     embedding tables live ONLY in pserver shards started by this
@@ -366,7 +363,7 @@ def run_fleet(args):
     finally:
         # EVERY exit path (including early failures) reaps the replica
         # subprocesses — an orphaned replica would sit in done.wait()
-        # forever, eating the single core under later bench segments
+        # forever, eating a core under whatever runs next
         for w in workers:
             if w.poll() is None:
                 w.terminate()
@@ -560,10 +557,6 @@ def main(argv=None):
                     default="oneshot",
                     help="oneshot = padded single-step inference drill; "
                     "generate = fluid-decode continuous-batching drill")
-    ap.add_argument("--admission", choices=("continuous", "drain"),
-                    default="continuous",
-                    help="generate workload: slot-admission policy "
-                    "(drain = the drain-and-refill A/B baseline)")
     ap.add_argument("--model-dir", help="existing save_inference_model dir "
                     "with a single feed named 'x' (default: build a tiny "
                     "MLP in a tempdir)")
@@ -599,9 +592,8 @@ def main(argv=None):
                     help="oneshot workload: observe stays ON (metrics, "
                     "pulse) but the `trace` flag goes off — no span ids, "
                     "no recording, legacy wire frames. The baseline half "
-                    "of bench.py's fluid-horizon trace-overhead A/B: "
-                    "both halves pay for metrics, the delta prices trace "
-                    "context alone")
+                    "of a trace-overhead A/B: both halves pay for "
+                    "metrics, the delta prices trace context alone")
     ap.add_argument("--trace-ab", type=int, default=0, metavar="ROUNDS",
                     help="oneshot workload: PAIRED in-process trace A/B "
                     "— after warmup, alternate the `trace` flag off/on "
@@ -610,8 +602,7 @@ def main(argv=None):
                     "one process controls the between-process variance "
                     "(allocator layout, CPU frequency) that dwarfs a "
                     "tens-of-microseconds effect when separate "
-                    "subprocess runs are compared; bench.py's horizon "
-                    "gate reads this")
+                    "subprocess runs are compared")
     ap.add_argument("--replicas", type=int, default=0, metavar="N",
                     help="fluid-fleet mode: spawn N replica SUBPROCESSES "
                     "behind a FleetRouter and drive the open loop "

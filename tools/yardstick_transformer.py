@@ -5,13 +5,12 @@ Same architecture, precision policy, and step semantics as
 + Adam(1e-3): embeddings*sqrt(d)+sinusoid, 6 enc / 6 dec post-LN blocks,
 unfused attention (bf16 matmuls, bf16 max-subtracted softmax), dropout 0.1
 via uint8 bit-compare (threshold on 8 random bits — the same trick
-`ops/pallas_dropout.py` uses on the XLA path), f32 master params, f32
+`ops/nn.py::_dropout` uses), f32 master params, f32
 softmax-cross-entropy loss.
 
 Purpose (docs/PERF.md): this is what an expert would write *without* the
 Program/IR parity layer; the delta between its step time and the
-framework's step time is the true cost of the layer. `tools/hlo_diff.py`
-compares the two compiled programs structurally and by wall clock.
+framework's step time is the true cost of the layer.
 """
 
 from __future__ import annotations
