@@ -209,7 +209,7 @@ def main():
     with phase("transformer-base seq256 unfused"):
         train_transformer("transformer256_unfused", sz["seq"], sz["batch"],
                           False, sz["steps"])
-    with phase("transformer-base seq256 fused (flash fwd/dQ/dKV)"):
+    with phase("transformer-base seq256 fused (flash fwd + fused bwd)"):
         train_transformer("transformer256_fused", sz["seq"], sz["batch"],
                           True, sz["steps"])
     with phase("transformer-base long-context fused (tuned tiles)"):

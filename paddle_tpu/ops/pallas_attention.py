@@ -4,21 +4,26 @@ The reference's only attention is an unfused softmax(QK^T)V composition
 (reference: python/paddle/fluid/nets.py:329 scaled_dot_product_attention).
 TPU-native redesign: Pallas kernels stream K/V blocks through VMEM with an
 online-softmax accumulator, so the [T, T] score matrix never materializes in
-HBM — O(T) memory instead of O(T^2) in both forward AND backward (the
-backward kernels recompute attention weights from the saved logsumexp, the
-FlashAttention-2 scheme: one kernel for dQ gridded over query blocks, one for
-dK/dV gridded over key blocks).
+HBM — O(T) memory instead of O(T^2) in both forward AND backward. The
+backward recomputes the attention weights from the saved logsumexp, once a
+(q-block, k-block) tile: one kernel gridded over key blocks, queries
+innermost, gives dK and dV from its scratch accumulators and dQ from the
+same `ds` tile (five products a tile). dQ is written straight out where a
+row is one K block, and accumulates in a VMEM-resident float32 row where it
+has several. Only a row too long to stay resident (`_bwd_plan`) takes the
+FlashAttention-2 pair instead, one kernel for dQ gridded over query blocks
+and one for dK/dV over key blocks, each recomputing the tile (seven).
 
 Attention-weight dropout runs inside the kernel using the TPU PRNG
 (pltpu.prng_seed / prng_random_bits), re-seeded per (batch·head, q-block,
-k-block) so forward and both backward kernels regenerate identical masks in
-any iteration order.
+k-block) so the forward and the backward kernels regenerate identical masks
+in any iteration order.
 
 The `fused_attention` op writes two outputs on the kernel path: `Out` and
 `Lse`, the forward kernel's log-sum-exp of every score row (float32
 [B*H, 1, T], the layout the backward kernels read; never reshaped, never
-cast, in no AMP list). Its grad op reads both back and calls the dQ and
-dK/dV kernels alone, so the forward kernel runs once a step. Where the
+cast, in no AMP list). Its grad op reads both back and calls the backward
+kernel alone, so the forward kernel runs once a step. Where the
 forward op left no `Lse` in the environment (a program built without the
 slot, ring attention under an 'sp' mesh axis, the CPU reference path) the
 grad op traces the forward rule again under `jax.vjp`, which is what the
@@ -101,6 +106,25 @@ def _blk(T, causal=False):
     raise ValueError(f"flash attention needs T % 128 == 0, got {T}")
 
 
+# What the fused backward kernel may keep resident for its dQ accumulator:
+# one (batch, head) row of float32 [T, D] where a row has several K blocks
+# (2 MiB = OLMoE's 4096 x 128; its output block of the same rows rides
+# beside it). Above it the row does not stay in VMEM and dQ takes its own
+# kernel again (32768 x 128 x 4 B = 16 MiB).
+_DQ_ROW_VMEM_BYTES = 2 * 1024 * 1024
+
+
+def _bwd_plan(T, D, BK):
+    """"fused": one kernel gives dQ, dK and dV from one pass over the score
+    tiles. "split": dQ and dK/dV each recompute them, where a row's dQ
+    accumulator is more than the budget above. With one K block a row
+    (every attention block of both transformer cells) a q-block's dQ is
+    complete in its one grid step and nothing is kept."""
+    if T == BK or T * D * 4 <= _DQ_ROW_VMEM_BYTES:
+        return "fused"
+    return "split"
+
+
 def _interpret():
     """The CPU rehearsal switch. Refused on any other backend: a kernel
     quietly interpreted on the chip would pass every check and prove
@@ -146,7 +170,7 @@ _HASH_B = 40503
 
 def _causal_live(qi, kj, blk_q, blk_k):
     """Whether the (qi, kj) block intersects the causal lower triangle.
-    Shared by all three kernels — block coverage and dropout-mask seeding
+    Shared by all the kernels — block coverage and dropout-mask seeding
     are keyed to the same (qi, kj) indices, so the fwd/dQ/dKV predicates
     must be structurally identical."""
     return kj * blk_k <= qi * blk_q + blk_q - 1
@@ -162,7 +186,7 @@ def _apply_causal_mask(s, qi, kj, blk_q, blk_k):
 def _dropout_mask(seed_ref, bh, qi, kj, shape, rate):
     """Deterministic keep-mask for one (bh, q-block, k-block) tile. Re-seeding
     per tile makes the mask independent of kernel iteration order, so the
-    forward, dQ and dK/dV kernels all regenerate the same mask."""
+    forward and the backward kernels all regenerate the same mask."""
     from jax.experimental.pallas import tpu as pltpu
 
     s = seed_ref[0, 0] * _HASH_A + bh * _HASH_B + qi
@@ -346,14 +370,105 @@ def _flash_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
 
+def _flash_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                      delta_ref, dq_ref, dk_ref, dv_ref, dk_sc, dv_sc,
+                      *dq_sc, sm_scale, causal, dropout_rate):
+    """dQ, dK and dV from one pass: `_flash_dkv_kernel` (grid (BH, kj, qi),
+    q innermost) with one product more, this tile's share of dQ from the
+    `ds` it has already formed. Without `dq_sc` a row is one K block and
+    the share is the q-block's whole dQ, written straight to its output
+    block. With it a row has several: the row's dQ accumulates over kj in
+    float32 `[T, D]` scratch (in ascending kj for every q-block, as
+    `_flash_dq_kernel` adds them) and is cast and written after the row's
+    last tile."""
+    from jax.experimental import pallas as pl
+
+    dq_sc = dq_sc[0] if dq_sc else None
+    bh = pl.program_id(0)
+    kj = pl.program_id(1)
+    qi = pl.program_id(2)
+    nk = pl.num_programs(1)
+    nq = pl.num_programs(2)
+    blk_q = q_ref.shape[1]
+    blk_k = k_ref.shape[1]
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_sc[...] = jnp.zeros_like(dk_sc)
+        dv_sc[...] = jnp.zeros_like(dv_sc)
+
+    if dq_sc is not None:
+        @pl.when((kj == 0) & (qi == 0))
+        def _init_row():
+            dq_sc[...] = jnp.zeros_like(dq_sc)
+
+    # with one K block a row (kj == 0) every tile is live
+    live = _causal_live(qi, kj, blk_q, blk_k) if causal else True
+
+    @pl.when(live)
+    def _update():
+        k = k_ref[0]                                   # [blk_k, D]
+        v = v_ref[0]
+        q = q_ref[0]
+        do = do_ref[0]
+        lse = lse_ref[0, 0]
+        delta = delta_ref[0, 0]
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * sm_scale
+        if causal:
+            s = _apply_causal_mask(s, qi, kj, blk_q, blk_k)
+        w = jnp.exp(s - lse[:, None])                  # [blk_q, blk_k]
+        dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+        if dropout_rate:
+            keep = _dropout_mask(seed_ref, bh, qi, kj, (blk_q, blk_k),
+                                 dropout_rate)
+            w_drop = jnp.where(keep, w / (1.0 - dropout_rate), 0.0)
+            dw = jnp.where(keep, dpv / (1.0 - dropout_rate), 0.0)
+        else:
+            w_drop, dw = w, dpv
+        dv_sc[...] = dv_sc[...] + lax.dot_general(
+            w_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = (w * (dw - delta[:, None]) * sm_scale).astype(q.dtype)
+        dk_sc[...] = dk_sc[...] + lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dq = lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        if dq_sc is None:
+            dq_ref[0] = dq.astype(dq_ref.dtype)
+        else:
+            rows = pl.ds(pl.multiple_of(qi * blk_q, blk_q), blk_q)
+            dq_sc[rows, :] = dq_sc[rows, :] + dq
+
+    @pl.when(qi == nq - 1)
+    def _finalize():
+        dk_ref[0] = dk_sc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
+
+    if dq_sc is not None:
+        @pl.when((kj == nk - 1) & (qi == nq - 1))
+        def _finalize_row():
+            dq_ref[0] = dq_sc[...].astype(dq_ref.dtype)
+
+
 def _seed_arr(seed):
     return jnp.asarray(seed, jnp.int32).reshape(1, 1)
 
 
-def _compiler_params():
+def _compiler_params(resident_row=False):
     """Innermost grid dim iterates sequentially (it carries the scratch
-    accumulators); the outer two are parallel."""
+    accumulators); the outer two are parallel. With `resident_row` the
+    middle one carries an accumulator too (the fused backward's dQ row),
+    and the kernel asks for the scoped VMEM that row needs itself (the
+    executor's 32 MiB, core/executor.py::resolve_compiler_options, which
+    a caller under plain `jax.jit` does not have)."""
     from jax.experimental.pallas import tpu as pltpu
+    if resident_row:
+        return pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=32 * 1024 * 1024)
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
@@ -400,71 +515,116 @@ def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0):
 
 
 def _flash_backward(q, k, v, o, lse, g, causal, sm_scale, dropout_rate, seed):
-    from jax.experimental import pallas as pl
-
     B, H, T, D = q.shape
     q3, k3, v3 = (x.reshape(B * H, T, D) for x in (q, k, v))
     o3 = o.reshape(B * H, T, D)
     g3 = g.reshape(B * H, T, D)
     delta = jnp.sum(g3.astype(jnp.float32) * o3.astype(jnp.float32),
                     axis=-1)[:, None, :]
+    BQ, BK = _blk(T, causal)
+    run = (_flash_bwd_fused if _bwd_plan(T, D, BK) == "fused"
+           else _flash_bwd_split)
+    grads = run((_seed_arr(seed), q3, k3, v3, g3, lse, delta), BQ, BK,
+                dict(sm_scale=sm_scale, causal=causal,
+                     dropout_rate=dropout_rate))
+    return tuple(d.reshape(B, H, T, D) for d in grads)
 
+
+def _bwd_specs(BQ, BK, D, q_axis):
+    """Block specs of (seed, q, k, v, dO, lse, delta) for a backward grid
+    (bh, ., .) whose q-block index is grid axis `q_axis` (1 or 2) and whose
+    k-block index is the other; and the index maps of a q and a k block."""
+    from jax.experimental import pallas as pl
+
+    def at_q(*g):
+        return (g[0], g[q_axis], 0)
+
+    def at_k(*g):
+        return (g[0], g[3 - q_axis], 0)
+
+    def row_q(*g):
+        return (g[0], 0, g[q_axis])
+
+    return [
+        pl.BlockSpec((1, 1), lambda *g: (0, 0)),
+        pl.BlockSpec((1, BQ, D), at_q),
+        pl.BlockSpec((1, BK, D), at_k),
+        pl.BlockSpec((1, BK, D), at_k),
+        pl.BlockSpec((1, BQ, D), at_q),
+        pl.BlockSpec((1, 1, BQ), row_q),
+        pl.BlockSpec((1, 1, BQ), row_q),
+    ], at_q, at_k
+
+
+def _flash_bwd_fused(args, BQ, BK, attrs):
+    """One call for dQ, dK and dV. Its name holds both `flash_dq` and
+    `flash_dkv`: the benchmark's metrics of those names each read it."""
+    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    BQ, BK = _blk(T, causal)
-    dq_kernel = functools.partial(_flash_dq_kernel, sm_scale=sm_scale,
-                                  causal=causal, dropout_rate=dropout_rate)
+    q3, k3, v3 = args[1:4]
+    BH, T, D = q3.shape
+    in_specs, at_q, at_k = _bwd_specs(BQ, BK, D, q_axis=2)
+    scratch = [pltpu.VMEM((BK, D), jnp.float32),
+               pltpu.VMEM((BK, D), jnp.float32)]
+    if T == BK:
+        dq_spec = pl.BlockSpec((1, BQ, D), at_q)
+    else:
+        # the row's dQ stays in VMEM over both inner grid axes
+        dq_spec = pl.BlockSpec((1, T, D), lambda bh, kj, qi: (bh, 0, 0))
+        scratch.append(pltpu.VMEM((T, D), jnp.float32))
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, **attrs),
+        grid=(BH, T // BK, T // BQ),
+        in_specs=in_specs,
+        out_specs=[dq_spec, pl.BlockSpec((1, BK, D), at_k),
+                   pl.BlockSpec((1, BK, D), at_k)],
+        out_shape=[jax.ShapeDtypeStruct((BH, T, D), x.dtype)
+                   for x in (q3, k3, v3)],
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(resident_row=T != BK),
+        interpret=_interpret(),
+        name="flash_dq_flash_dkv",
+    )(*args)
+
+
+def _flash_bwd_split(args, BQ, BK, attrs):
+    """dQ gridded over query blocks, then dK/dV over key blocks: each
+    recomputes the score tiles. For rows whose dQ the fused kernel cannot
+    keep resident, and the tests' oracle for the fused kernel."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    q3, k3, v3 = args[1:4]
+    BH, T, D = q3.shape
+    in_specs, at_q, _ = _bwd_specs(BQ, BK, D, q_axis=1)
     dq = pl.pallas_call(
-        dq_kernel,
-        grid=(B * H, T // BQ, T // BK),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bh, qi, kj: (0, 0)),
-            pl.BlockSpec((1, BQ, D), lambda bh, qi, kj: (bh, qi, 0)),
-            pl.BlockSpec((1, BK, D), lambda bh, qi, kj: (bh, kj, 0)),
-            pl.BlockSpec((1, BK, D), lambda bh, qi, kj: (bh, kj, 0)),
-            pl.BlockSpec((1, BQ, D), lambda bh, qi, kj: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, BQ), lambda bh, qi, kj: (bh, 0, qi)),
-            pl.BlockSpec((1, 1, BQ), lambda bh, qi, kj: (bh, 0, qi)),
-        ],
-        out_specs=pl.BlockSpec((1, BQ, D), lambda bh, qi, kj: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
+        functools.partial(_flash_dq_kernel, **attrs),
+        grid=(BH, T // BQ, T // BK),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, BQ, D), at_q),
+        out_shape=jax.ShapeDtypeStruct((BH, T, D), q3.dtype),
         scratch_shapes=[pltpu.VMEM((BQ, D), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
         name="flash_dq",
-    )(_seed_arr(seed), q3, k3, v3, g3, lse, delta)
-
-    dkv_kernel = functools.partial(_flash_dkv_kernel, sm_scale=sm_scale,
-                                   causal=causal, dropout_rate=dropout_rate)
+    )(*args)
+    in_specs, _, at_k = _bwd_specs(BQ, BK, D, q_axis=2)
     dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(B * H, T // BK, T // BQ),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bh, kj, qi: (0, 0)),
-            pl.BlockSpec((1, BQ, D), lambda bh, kj, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, BK, D), lambda bh, kj, qi: (bh, kj, 0)),
-            pl.BlockSpec((1, BK, D), lambda bh, kj, qi: (bh, kj, 0)),
-            pl.BlockSpec((1, BQ, D), lambda bh, kj, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, BQ), lambda bh, kj, qi: (bh, 0, qi)),
-            pl.BlockSpec((1, 1, BQ), lambda bh, kj, qi: (bh, 0, qi)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, BK, D), lambda bh, kj, qi: (bh, kj, 0)),
-            pl.BlockSpec((1, BK, D), lambda bh, kj, qi: (bh, kj, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, T, D), v.dtype),
-        ],
+        functools.partial(_flash_dkv_kernel, **attrs),
+        grid=(BH, T // BK, T // BQ),
+        in_specs=in_specs,
+        out_specs=[pl.BlockSpec((1, BK, D), at_k),
+                   pl.BlockSpec((1, BK, D), at_k)],
+        out_shape=[jax.ShapeDtypeStruct((BH, T, D), k3.dtype),
+                   jax.ShapeDtypeStruct((BH, T, D), v3.dtype)],
         scratch_shapes=[pltpu.VMEM((BK, D), jnp.float32),
                         pltpu.VMEM((BK, D), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
         name="flash_dkv",
-    )(_seed_arr(seed), q3, k3, v3, g3, lse, delta)
-
-    return (dq.reshape(B, H, T, D), dk.reshape(B, H, T, D),
-            dv.reshape(B, H, T, D))
+    )(*args)
+    return dq, dk, dv
 
 
 def _pallas_ok(q, dropout_rate=0.0):
@@ -591,7 +751,7 @@ def _fused_attention(ctx, Q, K, V):
 
 @register_grad("fused_attention")
 def _fused_attention_grad(ctx, ins, out_grads):
-    """dQ/dK/dV from the backward kernels alone, on the forward op's saved
+    """dQ/dK/dV from the backward kernel alone, on the forward op's saved
     `Out` and `Lse`. Which path runs is read off the environment: where the
     forward op left no `Lse` (a program built without the slot, the ring
     path, the CPU reference path) the forward rule is traced again under

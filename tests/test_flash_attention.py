@@ -25,6 +25,11 @@ from attention_program import (attention_grads, float32_grad_layer,
                                kernel_calls, qkv_feed, step_text)
 
 
+# the forward kernel, the fused backward kernel, and the split pair it
+# replaces wherever a row's dQ accumulator fits (`_bwd_plan`)
+BWD_KERNELS = ("flash_fwd", "flash_dq_flash_dkv", "flash_dq", "flash_dkv")
+
+
 @pytest.fixture
 def interpret_kernels(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
@@ -199,10 +204,8 @@ def test_grad_op_on_saved_lse_is_bitwise_the_generic_path(
     for n in grads:
         assert grads[n].dtype == np.float32 and np.abs(grads[n]).max() > 0
         np.testing.assert_array_equal(grads[n], grads_g[n], err_msg=n)
-    assert [kernel_calls(text, k) for k in
-            ("flash_fwd", "flash_dq", "flash_dkv")] == [1, 1, 1]
-    assert [kernel_calls(text_g, k) for k in
-            ("flash_fwd", "flash_dq", "flash_dkv")] == [2, 1, 1]
+    assert [kernel_calls(text, k) for k in BWD_KERNELS] == [1, 1, 0, 0]
+    assert [kernel_calls(text_g, k) for k in BWD_KERNELS] == [2, 1, 0, 0]
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -219,6 +222,74 @@ def test_grad_op_on_saved_lse_matches_reference(interpret_kernels,
     for n, w in zip("qkv", want):
         np.testing.assert_allclose(grads[n], np.asarray(w), atol=1e-4,
                                    rtol=1e-4, err_msg=n)
+
+
+# -- one backward kernel: dQ, dK and dV from one pass over the score tiles
+#    (ops/pallas_attention.py::_flash_bwd_kernel); the split pair is its
+#    oracle and what rows above `_DQ_ROW_VMEM_BYTES` still take ------------
+
+@pytest.mark.parametrize("inputs", ["float32", "amp_float32_out_grad"])
+@pytest.mark.parametrize("tiles", [None, (128, 128)],
+                         ids=["one_block_a_row", "2x2_blocks"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_fused_backward_is_bitwise_the_split_kernels(
+        interpret_kernels, monkeypatch, causal, tiles, inputs):
+    """The same program lowered with the fused backward kernel and with the
+    plan forced to the split pair: dQ/dK/dV bitwise equal, and within
+    tolerance of `jax.grad` of the jnp reference. `one_block_a_row`: T 256
+    at `_blk`'s (256, 256), dQ written straight from its one grid step;
+    `2x2_blocks`: the row's dQ accumulates in scratch over kj. Float32
+    without AMP, and float32 inputs the op sees as bf16 with a float32
+    `Out@GRAD`."""
+    if tiles:
+        monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
+    assert pallas_attention._blk(256, causal) == (tiles or (256, 256))
+    amp = inputs != "float32"
+    after = float32_grad_layer(monkeypatch) if amp else None
+    feed = qkv_feed(("q", "k", "v"))
+    out, grads, text = attention_grads(feed, causal, amp=amp, after=after)
+    monkeypatch.setattr(pallas_attention, "_bwd_plan", lambda *a: "split")
+    out_s, grads_s, text_s = attention_grads(feed, causal, amp=amp,
+                                              after=after)
+    assert [kernel_calls(text, k) for k in BWD_KERNELS] == [1, 1, 0, 0]
+    assert [kernel_calls(text_s, k) for k in BWD_KERNELS] == [1, 0, 1, 1]
+    assert out.dtype == (jnp.bfloat16 if amp else jnp.float32)
+    for n in "qkv":
+        assert grads[n].dtype == np.float32 and np.abs(grads[n]).max() > 0
+        np.testing.assert_array_equal(grads[n], grads_s[n], err_msg=n)
+    q, k, v = (jnp.asarray(feed[n]) for n in "qkv")
+    if amp:  # what the op saw
+        q, k, v = (x.astype(jnp.bfloat16).astype(jnp.float32)
+                   for x in (q, k, v))
+    want = jax.grad(lambda q, k, v: (_attention_reference(
+        q, k, v, causal, 64 ** -0.5) * feed["probe"]).sum(), (0, 1, 2))(
+            q, k, v)
+    tol = 6e-2 if amp else 1e-4
+    for n, w in zip("qkv", want):
+        np.testing.assert_allclose(grads[n], np.asarray(w), atol=tol,
+                                   rtol=tol, err_msg=n)
+
+
+@pytest.mark.parametrize("shape,causal,plan", [
+    ((96, 8, 256, 64), False, "fused"),       # transformer_base.seq256
+    ((96, 8, 256, 64), True, "fused"),
+    ((12, 8, 2048, 64), False, "fused"),      # transformer_base.seq2048
+    ((12, 8, 2048, 64), True, "fused"),
+    ((1, 16, 4096, 128), True, "fused"),      # olmoe_1b_7b.bs1: 2 MiB row
+    ((1, 4, 8192, 128), True, "split"),       # 4 MiB
+    ((1, 1, 32768, 64), True, "split"),       # tests/test_long_context_tpu
+    ((1, 1, 32768, 128), False, "split"),     # 16 MiB cannot be resident
+], ids=lambda x: "x".join(map(str, x)) if isinstance(x, tuple) else str(x))
+def test_backward_plan_follows_the_resident_dq_row(shape, causal, plan):
+    """Fused wherever a row is one K block or its float32 `[T, D]` dQ
+    accumulator is within the budget; the choice reads T, D and the tile
+    alone."""
+    _, _, T, D = shape
+    _, BK = pallas_attention._blk(T, causal)
+    assert pallas_attention._bwd_plan(T, D, BK) == plan
+    if T != BK:
+        assert (T * D * 4 <= pallas_attention._DQ_ROW_VMEM_BYTES) == (
+            plan == "fused")
 
 
 def test_lse_has_a_shape_at_build_time_without_a_tpu():
@@ -270,9 +341,8 @@ def test_tiny_transformer_step_runs_flash_fwd_once_a_block(interpret_kernels,
               for _ in range(6)]
     assert all(np.isfinite(l) for l in losses) and losses[-1] < losses[0]
     text = step_text(exe, main, scope, feed)
-    assert kernel_calls(text, "flash_fwd") == (6 if strip_lse else 3)
-    assert kernel_calls(text, "flash_dq") == 3
-    assert kernel_calls(text, "flash_dkv") == 3
+    assert [kernel_calls(text, k) for k in BWD_KERNELS] == [
+        6 if strip_lse else 3, 3, 0, 0]
 
 
 @pytest.mark.parametrize("causal", [False, True])

@@ -32,11 +32,11 @@ pytestmark = pytest.mark.skipif(
     reason="in-kernel PRNG dropout only runs on real TPU hardware")
 
 
-def _setup(rate, seed=7):
+def _setup(rate, seed=7, T=128):
     rng = np.random.RandomState(0)
-    q = jnp.asarray(rng.randn(1, 2, 128, 64).astype(np.float32))
-    k = jnp.asarray(rng.randn(1, 2, 128, 64).astype(np.float32))
-    v = jnp.asarray(rng.randn(1, 2, 128, 64).astype(np.float32))
+    q = jnp.asarray(rng.randn(1, 2, T, 64).astype(np.float32))
+    k = jnp.asarray(rng.randn(1, 2, T, 64).astype(np.float32))
+    v = jnp.asarray(rng.randn(1, 2, T, 64).astype(np.float32))
     s = jnp.int32(seed)
 
     def loss(q_, k_, v_):
@@ -69,12 +69,17 @@ def test_dropout_keep_rate():
     assert err < 0.25, err
 
 
+@pytest.mark.parametrize("T,tiles", [(128, None), (512, (128, 128))],
+                         ids=["one_block_a_row", "4x4_blocks"])
 @pytest.mark.parametrize("rate", [0.0, 0.3])
-def test_fwd_bwd_masks_agree_via_directional_fd(rate):
+def test_fwd_bwd_masks_agree_via_directional_fd(monkeypatch, rate, T, tiles):
     """grad . v == (loss(x+eps v) - loss(x-eps v)) / 2eps for random
-    directions v — only true if dQ and dK/dV regenerate the forward's
-    dropout mask exactly."""
-    q, k, v, loss = _setup(rate)
+    directions v — only true if the backward kernel regenerates the
+    forward's dropout mask exactly, tile by tile. `4x4_blocks`: the fused
+    kernel visits the tiles k-block first, the forward q-block first."""
+    from paddle_tpu.ops import pallas_attention
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
+    q, k, v, loss = _setup(rate, T=T)
     g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
     rng = np.random.RandomState(3)
     eps = 1e-2

@@ -5,7 +5,9 @@ dropout rate. The CPU suite holds the same equality under the Pallas
 interpreter (tests/test_flash_attention.py), where dropout is off and a
 row has the blocks a test gives it; what only the chip can say is that the
 Mosaic kernels, the hardware PRNG's masks re-seeded per tile, and `Lse`
-written and read through 4, 8 or 16 q-blocks give the same bits both ways."""
+written and read through 4, 8 or 16 q-blocks give the same bits both ways.
+And that the fused backward kernel (dQ, dK and dV from one pass over the
+score tiles) gives the bits of the split pair it replaced, masks and all."""
 
 import numpy as np
 import pytest
@@ -31,6 +33,27 @@ CELLS = [((96, 8, 256, 64), False, (256, 256)),
          ((12, 8, 2048, 64), False, (512, 2048)),
          ((12, 8, 2048, 64), True, (256, 2048)),
          ((1, 16, 4096, 128), True, (1024, 1024))]
+KERNELS = ("flash_fwd", "flash_dq_flash_dkv", "flash_dq", "flash_dkv")
+
+
+def _feed_for(inputs, shape, monkeypatch):
+    """`bf16_inputs`: bf16 Q/K/V in the scope, a bf16 `Out@GRAD`.
+    `float32_out_grad`: float32 Q/K/V that AMP casts for the op, and a
+    float32 `Out@GRAD` beside the bf16 `Out`."""
+    if inputs == "bf16_inputs":
+        return qkv_feed(("q", "k", "v"), shape, dtype=jnp.bfloat16), None
+    return qkv_feed(("q", "k", "v"), shape), float32_grad_layer(monkeypatch)
+
+
+def _assert_same_bits(feed, out, grads, out_w, grads_w):
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(out_w, np.float32))
+    for n in "qkv":
+        got, want = (np.asarray(g[n], np.float32) for g in (grads, grads_w))
+        assert grads[n].dtype == feed[n].dtype
+        assert np.isfinite(got).all() and np.abs(got).max() > 0
+        np.testing.assert_array_equal(got, want, err_msg=f"d{n}")
 
 
 @pytest.mark.parametrize("inputs", ["bf16_inputs", "float32_out_grad"])
@@ -39,15 +62,8 @@ CELLS = [((96, 8, 256, 64), False, (256, 256)),
                               for s, c, _ in CELLS])
 def test_saved_lse_grad_is_bitwise_the_generic_path(monkeypatch, shape,
                                                     causal, tiles, inputs):
-    """`bf16_inputs`: bf16 Q/K/V in the scope, a bf16 `Out@GRAD`.
-    `float32_out_grad`: float32 Q/K/V that AMP casts for the op, and a
-    float32 `Out@GRAD` beside the bf16 `Out`."""
     assert pallas_attention._blk(shape[2], causal) == tiles
-    if inputs == "bf16_inputs":
-        feed, after = qkv_feed(("q", "k", "v"), shape, dtype=jnp.bfloat16), None
-    else:
-        feed, after = qkv_feed(("q", "k", "v"), shape), \
-            float32_grad_layer(monkeypatch)
+    feed, after = _feed_for(inputs, shape, monkeypatch)
     place = fluid.TPUPlace(0)
     out, grads, text = attention_grads(feed, causal, amp=True, rate=0.1,
                                        after=after, place=place)
@@ -55,17 +71,34 @@ def test_saved_lse_grad_is_bitwise_the_generic_path(monkeypatch, shape,
                         None)
     out_g, grads_g, text_g = attention_grads(feed, causal, amp=True, rate=0.1,
                                              after=after, place=place)
-    assert kernel_calls(text, "flash_fwd") == 1
-    assert kernel_calls(text_g, "flash_fwd") == 2
-    assert out.dtype == jnp.bfloat16
-    np.testing.assert_array_equal(np.asarray(out, np.float32),
-                                  np.asarray(out_g, np.float32))
-    for n in "qkv":
-        got, want = (np.asarray(g[n], np.float32) for g in (grads, grads_g))
-        assert grads[n].dtype == feed[n].dtype
-        assert np.isfinite(got).all() and np.abs(got).max() > 0
-        np.testing.assert_array_equal(got, want, err_msg=f"d{n}")
+    assert [kernel_calls(text, k) for k in KERNELS] == [1, 1, 0, 0]
+    assert [kernel_calls(text_g, k) for k in KERNELS] == [2, 1, 0, 0]
+    _assert_same_bits(feed, out, grads, out_g, grads_g)
     # dropout is on: another step (another key) gives another mask
     assert not np.array_equal(np.asarray(out, np.float32), np.asarray(
         attention_grads(feed, causal, amp=True, rate=0.0, after=after,
                         place=place)[0], np.float32))
+
+
+@pytest.mark.parametrize("inputs", ["bf16_inputs", "float32_out_grad"])
+@pytest.mark.parametrize("shape,causal,tiles", CELLS,
+                         ids=[f"{s[2]}x{s[3]}_{'causal' if c else 'full'}"
+                              for s, c, _ in CELLS])
+def test_fused_backward_is_bitwise_the_split_kernels(monkeypatch, shape,
+                                                     causal, tiles, inputs):
+    """Every cell's shape takes the fused kernel (one K block a row in the
+    transformer cells, a resident `[4096, 128]` float32 dQ row in OLMoE's);
+    with the plan forced to the split pair the same program gives the same
+    dQ/dK/dV, dropout 0.1."""
+    _, _, T, D = shape
+    assert pallas_attention._bwd_plan(T, D, tiles[1]) == "fused"
+    feed, after = _feed_for(inputs, shape, monkeypatch)
+    place = fluid.TPUPlace(0)
+    out, grads, text = attention_grads(feed, causal, amp=True, rate=0.1,
+                                       after=after, place=place)
+    monkeypatch.setattr(pallas_attention, "_bwd_plan", lambda *a: "split")
+    out_s, grads_s, text_s = attention_grads(feed, causal, amp=True, rate=0.1,
+                                             after=after, place=place)
+    assert [kernel_calls(text, k) for k in KERNELS] == [1, 1, 0, 0]
+    assert [kernel_calls(text_s, k) for k in KERNELS] == [1, 0, 1, 1]
+    _assert_same_bits(feed, out, grads, out_s, grads_s)

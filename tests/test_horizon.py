@@ -404,8 +404,11 @@ def test_live_scrape_matches_workload_accounting(observe_on):
             c.inc(model="m", outcome="ok")
             h.observe(1500.0, model="m")
         time.sleep(0.25)
-        sc.poll_once()
+        # a round is stamped when it starts: the store's span runs from
+        # the first poll's start to the second's, not to its end (a fetch
+        # takes tens of ms beside five busy workers)
         elapsed = time.time() - t0
+        sc.poll_once()
 
         inc = sc.store.increase("serve_requests_total", window_s=60)
         assert inc == n_total - n_first

@@ -69,7 +69,7 @@ def test_grouped_matmul_kernels_keep_their_names_each_forward_once():
     assert sum(n.startswith("gmm") for n in names) == 6, names
     assert sum(n.startswith("tgmm") for n in names) == 3, names
     assert any(n.startswith("flash_fwd") for n in names), names
-    assert any("flash_dq" in n for n in names), names
+    assert any(n.startswith("flash_dq_flash_dkv") for n in names), names
 
 
 def test_small_model_under_amp_is_within_bf16_of_the_reference():
