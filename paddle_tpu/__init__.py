@@ -25,7 +25,8 @@ if not _jax.config.jax_compilation_cache_dir:
 
 from .core import ir as _ir
 from .core.ir import (Program, program_guard, default_main_program,  # noqa: F401
-                      default_startup_program, Variable, Parameter, Operator)
+                      default_startup_program, Variable, Parameter, Operator,
+                      name_scope)
 from .core.executor import (Executor, PreparedProgram, Scope,  # noqa: F401
                             global_scope, CPUPlace, TPUPlace, CUDAPlace,
                             EOFException, scope_guard, _switch_scope,

@@ -200,6 +200,33 @@ def _insert_sum_ops(block: ir.Block, contribs, loss_name: str,
                         attrs={"__role__": "backward"})
 
 
+def parameter_sharing(program: ir.Program) -> Dict[str, int]:
+    """How far the program shares its weights, read back from the global
+    block: `parameters`; `parameter_uses`, the reads of a parameter by a
+    forward op (a parameter counts once an op); `grad_fanin_max`, the most
+    gradient contributions summed into one parameter: the longest `X` of the
+    `sum` ops `_insert_sum_ops` wrote for a parameter's gradient, 1 where
+    every parameter has one contribution, 0 in a program without a backward
+    pass. An unrolled loop over shared layers reads `uses = loops x
+    parameters` and a fan-in of `loops`; a copy of the weights per pass
+    would read a fan-in of 1. Goes on the program's compile events
+    (`observe.observatory()`, `detail`)."""
+    block = program.global_block()
+    params = {p.name for p in block.all_parameters()}
+    grads = {grad_var_name(n) for n in params}
+    uses = fanin = 0
+    for op in block.ops:
+        if op.attrs.get("__role__") is None:
+            uses += len(params.intersection(op.input_arg_names))
+        elif op.attrs["__role__"] == "backward":
+            if op.type == "sum" and op.output("Out")[0] in grads:
+                fanin = max(fanin, len(op.input("X")))
+            elif not fanin and grads.intersection(op.output_arg_names):
+                fanin = 1
+    return {"parameters": len(params), "parameter_uses": uses,
+            "grad_fanin_max": fanin}
+
+
 def _grad_needing_inputs(block, op, no_grad, parameter_list) -> List[str]:
     """Inputs of `op` that should receive gradients (dedup, order-stable)."""
     seen, out = set(), []

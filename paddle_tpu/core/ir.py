@@ -14,6 +14,7 @@ to describe dataflow, not execution.
 
 from __future__ import annotations
 
+import contextlib
 import copy
 import itertools
 import json
@@ -258,6 +259,8 @@ class Block:
 
     # -- op management --------------------------------------------------
     def append_op(self, type: str, inputs=None, outputs=None, attrs=None) -> Operator:
+        if _name_scopes:
+            attrs = {NAME_SCOPE_ATTR: "/".join(_name_scopes), **(attrs or {})}
         op = Operator(self, type, inputs, outputs, attrs)
         self.ops.append(op)
         self.program._bump()
@@ -539,3 +542,25 @@ class program_guard:
         if self._startup is not None:
             switch_startup_program(self._prev_startup)
         return False
+
+
+# ---------------------------------------------------------------------------
+# name_scope (reference framework.py `name_scope`: ops carry `op_namescope`).
+# ---------------------------------------------------------------------------
+
+NAME_SCOPE_ATTR = "op_namescope"
+_name_scopes: List[str] = []
+
+
+@contextlib.contextmanager
+def name_scope(prefix: str):
+    """`with name_scope("ut_step2"):` every op appended inside carries the
+    open prefixes, joined by `/`, in its `op_namescope` attribute; the
+    lowering puts them before the op's type in its `jax.named_scope`, for the
+    compiled text's metadata. Its grad op finds them on the forward op it
+    keeps. Nothing else reads the attribute."""
+    _name_scopes.append(prefix)
+    try:
+        yield
+    finally:
+        _name_scopes.pop()

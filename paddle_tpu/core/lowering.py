@@ -70,7 +70,7 @@ class BlockLowerer:
             # the grad op's own type: whatever the forward rule lowers in
             # here (the generic vjp path re-traces it) is told apart, in the
             # compiled module and a device trace, from the forward op's
-            with jax.named_scope(op.type):
+            with _named_scope(op.type, op.attrs[FWD_OP_ATTR]["attrs"]):
                 self._run_grad_op(block, op, env, key)
             if self.check_nan_inf and self._block_depth == 1:
                 self._record_nan_flags_env(op, env)
@@ -79,7 +79,7 @@ class BlockLowerer:
         op_key = jax.random.fold_in(key, _op_seed(op, op_idx)) if opdef.needs_rng else None
         ins = _gather_inputs(op.inputs, env, op.type)
         ctx = LoweringContext(op.attrs, key=op_key, lowerer=self, op=op, env=env)
-        with jax.named_scope(op.type):
+        with _named_scope(op.type, op.attrs):
             outs = registry.call_rule(opdef, ctx, ins)
         _scatter_outputs(op, outs, env)
         if opdef.propagate_seqlen:
@@ -193,6 +193,13 @@ class BlockLowerer:
         for name, g in acc.items():
             if name in declared_by_base:
                 env[declared_by_base[name]] = g
+
+
+def _named_scope(op_type: str, attrs: Dict[str, Any]):
+    """The op's type, behind the prefixes of the `name_scope` it was
+    appended in (a grad op passes its forward op's attributes)."""
+    prefix = attrs.get(ir.NAME_SCOPE_ATTR)
+    return jax.named_scope(f"{prefix}/{op_type}" if prefix else op_type)
 
 
 def _op_seed(op: ir.Operator, op_idx: int) -> int:

@@ -166,7 +166,12 @@ AMP_F32_OPS = frozenset({"log_softmax", "cross_entropy",
                          # what both router losses are built from; rms_norm
                          # keeps its statistics in f32 inside its rule and
                          # needs no entry (its output stays in bf16)
-                         "moe_router"})
+                         "moe_router",
+                         # a looped LM's gate logit; what is built from it
+                         # (log-sigmoids, their running sums, the exit
+                         # distribution and its entropy) then stays float32:
+                         # elementwise ops keep the dtype that reaches them
+                         "exit_gate"})
 # Mixed-dtype elementwise ops downcast the f32 side to bf16 instead of
 # letting numpy promotion upcast the bf16 side: one f32 mask/bias/table
 # leaking into the residual or attention-score stream would otherwise

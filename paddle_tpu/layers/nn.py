@@ -1366,6 +1366,21 @@ def swiglu(gate, up, name=None):
     return out
 
 
+def exit_gate(input, param_attr=None, bias_attr=None, name=None):
+    """A looped LM's exit gate on `[..., width]`: the logit of
+    `Linear(width, 1)` with bias, `[..., 1]`, float32 whatever dtype flows in
+    (its sigmoid is the probability of leaving after this pass)."""
+    helper = LayerHelper("exit_gate", **locals())
+    w = helper.create_parameter(param_attr, [input.shape[-1], 1], "float32")
+    b = helper.create_parameter(bias_attr, [1], "float32", is_bias=True)
+    out = helper.create_variable_for_type_inference("float32")
+    helper.append_op("exit_gate",
+                     inputs={"X": [input.name], "W": [w.name],
+                             "Bias": [b.name]},
+                     outputs={"Out": [out.name]})
+    return out
+
+
 def fused_attention(q, k, v, causal=False, sm_scale=None, dropout_rate=0.0,
                     is_test=False, name=None):
     """Softmax attention on `[batch, heads, seq, head_dim]` through the

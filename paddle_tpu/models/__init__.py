@@ -1,8 +1,10 @@
 """Model zoo mirroring the reference benchmark configs
 (reference: benchmark/fluid/models/{mnist,resnet,vgg,stacked_dynamic_lstm,
 machine_translation}.py) plus Transformer-base and DeepFM (the BASELINE.json
-target workloads), and OLMoE: a sparse-expert decoder LM at a published width
-(the first model whose loss is a cross-entropy plus two router losses)."""
+target workloads), OLMoE: a sparse-expert decoder LM at a published width
+(the first model whose loss is a cross-entropy plus two router losses), and
+Ouro: a looped decoder LM (the first model that uses a weight more than once
+a step: one stack of layers applied four times, four heads, an exit gate)."""
 
 from . import mnist  # noqa: F401
 from . import resnet  # noqa: F401
@@ -14,3 +16,4 @@ from . import machine_translation  # noqa: F401
 from . import se_resnext  # noqa: F401
 from . import tiny_lm  # noqa: F401
 from . import olmoe  # noqa: F401
+from . import ouro  # noqa: F401

@@ -352,9 +352,11 @@ class RecompilationObservatory:
     def note_entry_build(self, program_uid: int, version: int,
                          feed_sig: Tuple, fetch_sig: Tuple, copts_sig,
                          source: str = "executor",
-                         scope_uid=None) -> str:
+                         scope_uid=None, detail=None) -> str:
         """Called on every executor compile-cache miss (a new
-        _CompiledProgram is about to be built). Returns the cause."""
+        _CompiledProgram is about to be built). Returns the cause.
+        `detail`: what else the caller knows of the program (the executors
+        pass `backward.parameter_sharing`), kept on the event."""
         with self._lock:
             s = self._seen.get(program_uid)
             if s is None:
@@ -387,7 +389,7 @@ class RecompilationObservatory:
             self._events.append(RecompileEvent(
                 time.time(), program_uid, cause, source,
                 {"version": version, "feeds": list(feed_sig),
-                 "fetches": list(fetch_sig)}))
+                 "fetches": list(fetch_sig), **(detail or {})}))
         self._emit_metric(cause, source)
         return cause
 

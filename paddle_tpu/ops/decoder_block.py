@@ -1,5 +1,6 @@
 """The pieces of a 2024 decoder block that are not attention or a plain
-matmul: RMSNorm, rotary position embedding, the silu-gated product.
+matmul: RMSNorm, rotary position embedding, the silu-gated product, and a
+looped LM's exit gate.
 
 No reference analog (the reference predates all three); the equations are
 those of the public `olmoe` / `llama`-style model code. Each is plain jnp,
@@ -57,3 +58,15 @@ def _swiglu(ctx, Gate, Up):
     g32 = Gate.astype(jnp.float32)
     return {"Out": (jax.nn.silu(g32) * Up.astype(jnp.float32))
             .astype(Gate.dtype)}
+
+
+@register_op("exit_gate")
+def _exit_gate(ctx, X, W, Bias):
+    """The gate logit of a looped LM (Ouro, arXiv:2510.25741): `X W + b`,
+    X `[..., D]`, W `[D, 1]`, Bias `[1]` -> `[..., 1]`, in float32 with the
+    product at HIGHEST (AMP_F32_OPS): the exit distribution is a running
+    product of its sigmoids over the passes, and its entropy is part of the
+    loss."""
+    logit = jnp.dot(X.astype(jnp.float32), W.astype(jnp.float32),
+                    precision=lax.Precision.HIGHEST)
+    return {"Out": logit + Bias.astype(jnp.float32)}

@@ -54,6 +54,7 @@ def pytest_collection_modifyitems(config, items):
                                            "test_paged_attention_tpu",
                                            "test_kernel_names_tpu",
                                            "test_olmoe_tpu",
+                                           "test_ouro_tpu",
                                            "test_flash_grad_tpu")):
                 item.add_marker(skip)
     # under pytest-xdist, serialize each subprocess-spawning file into one

@@ -556,6 +556,7 @@ SPECS.update({
     "rotary_embedding": Spec(inputs={"X": T(1, 2, 4, 6)},
                              attrs={"theta": 100.0}),
     "swiglu": Spec(inputs={"Gate": T(3, 4), "Up": T(3, 4)}),
+    "exit_gate": Spec(inputs={"X": T(2, 3, 5), "W": T(5, 1), "Bias": T(1)}),
     "moe_router": Spec(inputs={"X": T(6, 5), "W": T(5, 4) * 2},
                        attrs={"k": 2},
                        outs=("TopKWeight", "TopKIndex", "TokensPerExpert",
