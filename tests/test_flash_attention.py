@@ -25,9 +25,11 @@ from attention_program import (attention_grads, float32_grad_layer,
                                kernel_calls, qkv_feed, step_text)
 
 
-# the forward kernel, the fused backward kernel, and the split pair it
-# replaces wherever a row's dQ accumulator fits (`_bwd_plan`)
-BWD_KERNELS = ("flash_fwd", "flash_dq_flash_dkv", "flash_dq", "flash_dkv")
+# the one-pass forward kernel (a row is one K block, `_fwd_plan`) and the
+# streaming one, the fused backward kernel, and the split pair it replaces
+# wherever a row's dQ accumulator fits (`_bwd_plan`)
+KERNELS = ("flash_fwd_onepass", "flash_fwd", "flash_dq_flash_dkv",
+           "flash_dq", "flash_dkv")
 
 
 @pytest.fixture
@@ -152,13 +154,19 @@ def test_transformer_fused_attention_trains():
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_kernel_multiblock_streaming(interpret_kernels, causal):
+def test_flash_kernel_multiblock_streaming(interpret_kernels, monkeypatch,
+                                           causal):
     """T=1024 at block 512 = multiple innermost-grid steps: exercises the
-    scratch-carried online softmax across kj iterations, the kj==0 init /
-    kj==nk-1 finalize split, and the causal live-block skip — all of
-    which degenerate to a single no-op step at T=256."""
+    scratch-carried online softmax across kj iterations (its statistics
+    lane-replicated `[blk_q, 128]` arrays), the kj==0 init / kj==nk-1
+    finalize split, and the causal live-block skip — none of which the
+    one-pass kernel of a one-block row has. (`_blk` alone gives 1024 one
+    1024-tile, so the tiles are set here.)"""
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", (512, 512))
     rng = np.random.RandomState(1)
     B, H, T, D = 1, 2, 1024, 64
+    assert pallas_attention._fwd_plan(
+        T, pallas_attention._blk(T, causal)[1]) == "stream"
     q, k, v = (jnp.asarray(rng.randn(B, H, T, D) * 0.2, jnp.float32)
                for _ in range(3))
     seed = jnp.int32(0)
@@ -204,8 +212,8 @@ def test_grad_op_on_saved_lse_is_bitwise_the_generic_path(
     for n in grads:
         assert grads[n].dtype == np.float32 and np.abs(grads[n]).max() > 0
         np.testing.assert_array_equal(grads[n], grads_g[n], err_msg=n)
-    assert [kernel_calls(text, k) for k in BWD_KERNELS] == [1, 1, 0, 0]
-    assert [kernel_calls(text_g, k) for k in BWD_KERNELS] == [2, 1, 0, 0]
+    assert [kernel_calls(text, k) for k in KERNELS] == [0, 1, 1, 0, 0]
+    assert [kernel_calls(text_g, k) for k in KERNELS] == [0, 2, 1, 0, 0]
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -251,8 +259,9 @@ def test_fused_backward_is_bitwise_the_split_kernels(
     monkeypatch.setattr(pallas_attention, "_bwd_plan", lambda *a: "split")
     out_s, grads_s, text_s = attention_grads(feed, causal, amp=amp,
                                               after=after)
-    assert [kernel_calls(text, k) for k in BWD_KERNELS] == [1, 1, 0, 0]
-    assert [kernel_calls(text_s, k) for k in BWD_KERNELS] == [1, 0, 1, 1]
+    fwd = [0, 1] if tiles else [1, 0]   # one block a row: no state kept
+    assert [kernel_calls(text, k) for k in KERNELS] == fwd + [1, 0, 0]
+    assert [kernel_calls(text_s, k) for k in KERNELS] == fwd + [0, 1, 1]
     assert out.dtype == (jnp.bfloat16 if amp else jnp.float32)
     for n in "qkv":
         assert grads[n].dtype == np.float32 and np.abs(grads[n]).max() > 0
@@ -325,10 +334,11 @@ def _tiny_transformer(strip_lse):
 def test_tiny_transformer_step_runs_flash_fwd_once_a_block(interpret_kernels,
                                                            strip_lse):
     """The lowered training step of the one-layer model (encoder self,
-    decoder self, cross attention) holds one `flash_fwd` `pallas_call` for
-    each attention block. A program whose ops have no `Lse` output (built
-    before the slot existed) falls back to the forward under `jax.vjp`, two
-    a block, and trains all the same."""
+    decoder self, cross attention) holds one forward `pallas_call` for
+    each attention block: `flash_fwd_onepass`, a row of 128 being one K
+    block, and no `flash_fwd` besides. A program whose ops have no `Lse`
+    output (built before the slot existed) falls back to the forward under
+    `jax.vjp`, two a block, and trains all the same."""
     main, startup, loss = _tiny_transformer(strip_lse)
     scope = fluid.Scope()
     exe = fluid.Executor(fluid.CPUPlace(), amp=True)
@@ -341,8 +351,8 @@ def test_tiny_transformer_step_runs_flash_fwd_once_a_block(interpret_kernels,
               for _ in range(6)]
     assert all(np.isfinite(l) for l in losses) and losses[-1] < losses[0]
     text = step_text(exe, main, scope, feed)
-    assert [kernel_calls(text, k) for k in BWD_KERNELS] == [
-        6 if strip_lse else 3, 3, 0, 0]
+    assert [kernel_calls(text, k) for k in KERNELS] == [
+        6 if strip_lse else 3, 0, 3, 0, 0]
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -373,3 +383,115 @@ def test_forward_op_and_grad_op_draw_one_dropout_mask(causal):
     for n, w in zip("qkv", want):
         np.testing.assert_allclose(grads[n], np.asarray(w), atol=1e-4,
                                    rtol=1e-4, err_msg=n)
+
+
+# -- two forward kernels, one algorithm: no softmax state where a row is one
+#    K block (`_flash_fwd_onepass_kernel`), the online-softmax state carried
+#    over the blocks where it has several (`_flash_fwd_kernel`) -------------
+
+FWD_SHAPES = [(128, 64), (256, 64), (256, 128)]
+
+
+def _forward_both_ways(monkeypatch, T, D, BQ, causal, seed=3):
+    """`_flash_forward` at the tiles (BQ, T) with the plan the shape gives
+    (one pass) and with it forced to the streaming kernel: (out, lse) of
+    each."""
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", (BQ, T))
+    assert pallas_attention._blk(T, causal) == (BQ, T)
+    rng = np.random.RandomState(seed)
+    q, k, v = (jnp.asarray(rng.randn(2, 2, T, D), jnp.float32)
+               for _ in range(3))
+    assert pallas_attention._fwd_plan(T, T) == "onepass"
+    one = pallas_attention._flash_forward(q, k, v, causal, D ** -0.5)
+    monkeypatch.setattr(pallas_attention, "_fwd_plan", lambda *a: "stream")
+    stream = pallas_attention._flash_forward(q, k, v, causal, D ** -0.5)
+    return one, stream
+
+
+@pytest.mark.parametrize("whole_row", [True, False],
+                         ids=["BQ=T", "BQ=T/2"])
+@pytest.mark.parametrize("T,D", FWD_SHAPES,
+                         ids=[f"{t}x{d}" for t, d in FWD_SHAPES])
+@pytest.mark.parametrize("causal", [False, True])
+def test_onepass_forward_is_bitwise_the_streaming_kernel(
+        interpret_kernels, monkeypatch, causal, T, D, whole_row):
+    """Same tiles, same products, same float32 `exp`, a true division: the
+    streaming kernel's first step scales a zero state by exp(NEG_INF - m)
+    = 0, so dropping the state changes no bit of `Out` or `Lse`."""
+    one, stream = _forward_both_ways(monkeypatch, T, D,
+                                     T if whole_row else T // 2, causal)
+    for a, b, name in zip(one, stream, ("Out", "Lse")):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+    assert one[1].shape == (4, 1, T) and one[1].dtype == jnp.float32
+
+
+@pytest.mark.parametrize("plan", ["onepass", "stream"])
+@pytest.mark.parametrize("T,D", FWD_SHAPES,
+                         ids=[f"{t}x{d}" for t, d in FWD_SHAPES])
+@pytest.mark.parametrize("causal", [False, True])
+def test_forward_lse_is_the_logsumexp_of_the_masked_scores(
+        interpret_kernels, monkeypatch, causal, T, D, plan):
+    """`Lse` of both forward kernels against `jax.nn.logsumexp` of the
+    scores the reference masks, in the layout the backward reads; the
+    streaming kernel over 2 x 2 blocks a row."""
+    if plan == "stream":
+        monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE",
+                            (T // 2, T // 2))
+    BQ, BK = pallas_attention._blk(T, causal)
+    assert pallas_attention._fwd_plan(T, BK) == plan
+    rng = np.random.RandomState(11)
+    q, k, v = (jnp.asarray(rng.randn(1, 2, T, D), jnp.float32)
+               for _ in range(3))
+    out, lse = pallas_attention._flash_forward(q, k, v, causal, D ** -0.5)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * D ** -0.5
+    if causal:
+        s = jnp.where(jnp.arange(T)[None, :] > jnp.arange(T)[:, None],
+                      pallas_attention.NEG_INF, s)
+    want = jax.nn.logsumexp(s, axis=-1).reshape(2, 1, T)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want), atol=2e-5,
+                               rtol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_attention_reference(q, k, v, causal,
+                                                         D ** -0.5)),
+        atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("shape,causal,plan", [
+    ((96, 8, 256, 64), False, "onepass"),     # transformer_base.seq256
+    ((96, 8, 256, 64), True, "onepass"),
+    ((12, 8, 2048, 64), False, "onepass"),    # transformer_base.seq2048
+    ((12, 8, 2048, 64), True, "onepass"),
+    ((1, 16, 4096, 128), True, "stream"),     # olmoe_1b_7b.bs1, ouro_2_6b.bs1
+    ((1, 16, 4096, 128), False, "stream"),    # (512, 2048): two K blocks
+    ((2, 2, 128, 64), False, "onepass"),      # the tiny transformer step
+    ((2, 2, 384, 64), False, "stream"),       # 128-tiles, three a row
+    ((1, 2, 1024, 64), True, "onepass"),      # one 1024-tile
+    ((1, 1, 32768, 64), True, "stream"),      # tests/test_long_context_tpu
+], ids=lambda x: "x".join(map(str, x)) if isinstance(x, tuple) else str(x))
+def test_forward_plan_follows_the_k_blocks_of_a_row(shape, causal, plan):
+    """One pass wherever a row is one K block; the choice reads T and the
+    tile alone."""
+    _, _, T, _ = shape
+    _, BK = pallas_attention._blk(T, causal)
+    assert pallas_attention._fwd_plan(T, BK) == plan
+    assert (T // BK == 1) == (plan == "onepass")
+
+
+@pytest.mark.parametrize("D", [32, 64, 128, 192, 256])
+def test_streaming_statistics_reach_every_head_width(interpret_kernels,
+                                                     monkeypatch, D):
+    """The lane-replicated statistics are sliced or tiled to the head
+    width (`_lanes`): under, at and over one vreg of lanes, and a width
+    that is no multiple of it."""
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", (128, 128))
+    rng = np.random.RandomState(D)
+    q, k, v = (jnp.asarray(rng.randn(1, 1, 256, D), jnp.float32)
+               for _ in range(3))
+    assert pallas_attention._fwd_plan(256, 128) == "stream"
+    out, _ = pallas_attention._flash_forward(q, k, v, True, D ** -0.5)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_attention_reference(q, k, v, True,
+                                                         D ** -0.5)),
+        atol=3e-5, rtol=3e-5)

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 import paddle_tpu as fluid
 from paddle_tpu.core import registry
+from paddle_tpu.core.executor import resolve_compiler_options
 from paddle_tpu.ops import pallas_attention
 
 from attention_program import (attention_grads, float32_grad_layer,
@@ -33,7 +34,14 @@ CELLS = [((96, 8, 256, 64), False, (256, 256)),
          ((12, 8, 2048, 64), False, (512, 2048)),
          ((12, 8, 2048, 64), True, (256, 2048)),
          ((1, 16, 4096, 128), True, (1024, 1024))]
-KERNELS = ("flash_fwd", "flash_dq_flash_dkv", "flash_dq", "flash_dkv")
+KERNELS = ("flash_fwd_onepass", "flash_fwd", "flash_dq_flash_dkv",
+           "flash_dq", "flash_dkv")
+
+
+def _fwd(tiles, shape, calls=1):
+    """Call counts of (one-pass, streaming) forward kernels: a row that is
+    one K block keeps no softmax state (`_fwd_plan`)."""
+    return [calls, 0] if tiles[1] == shape[2] else [0, calls]
 
 
 def _feed_for(inputs, shape, monkeypatch):
@@ -71,8 +79,10 @@ def test_saved_lse_grad_is_bitwise_the_generic_path(monkeypatch, shape,
                         None)
     out_g, grads_g, text_g = attention_grads(feed, causal, amp=True, rate=0.1,
                                              after=after, place=place)
-    assert [kernel_calls(text, k) for k in KERNELS] == [1, 1, 0, 0]
-    assert [kernel_calls(text_g, k) for k in KERNELS] == [2, 1, 0, 0]
+    assert [kernel_calls(text, k) for k in KERNELS] == (
+        _fwd(tiles, shape) + [1, 0, 0])
+    assert [kernel_calls(text_g, k) for k in KERNELS] == (
+        _fwd(tiles, shape, 2) + [1, 0, 0])
     _assert_same_bits(feed, out, grads, out_g, grads_g)
     # dropout is on: another step (another key) gives another mask
     assert not np.array_equal(np.asarray(out, np.float32), np.asarray(
@@ -99,6 +109,58 @@ def test_fused_backward_is_bitwise_the_split_kernels(monkeypatch, shape,
     monkeypatch.setattr(pallas_attention, "_bwd_plan", lambda *a: "split")
     out_s, grads_s, text_s = attention_grads(feed, causal, amp=True, rate=0.1,
                                              after=after, place=place)
-    assert [kernel_calls(text, k) for k in KERNELS] == [1, 1, 0, 0]
-    assert [kernel_calls(text_s, k) for k in KERNELS] == [1, 0, 1, 1]
+    assert [kernel_calls(text, k) for k in KERNELS] == (
+        _fwd(tiles, shape) + [1, 0, 0])
+    assert [kernel_calls(text_s, k) for k in KERNELS] == (
+        _fwd(tiles, shape) + [0, 1, 1])
     _assert_same_bits(feed, out, grads, out_s, grads_s)
+
+
+ONEPASS = [((1, 8, 256, 64), False), ((1, 8, 256, 64), True),
+           ((1, 2, 2048, 64), False), ((1, 2, 2048, 64), True)]
+
+
+@pytest.mark.parametrize("shape,causal", ONEPASS,
+                         ids=[f"{s[1]}x{s[2]}x{s[3]}_"
+                              f"{'causal' if c else 'full'}"
+                              for s, c in ONEPASS])
+def test_onepass_forward_is_bitwise_the_streaming_kernel(monkeypatch, shape,
+                                                         causal):
+    """Dropout 0.1, the cells' tiles: the one-pass forward kernel against
+    the streaming kernel the plan is forced to, bitwise in `Out`, in `Lse`
+    and, through the fused backward kernel on each one's saved pair, in
+    dQ, dK and dV: both draw the mask of tile (bh, qi, 0)."""
+    _, _, T, D = shape
+    BQ, BK = pallas_attention._blk(T, causal)
+    assert pallas_attention._fwd_plan(T, BK) == "onepass"
+    rng = np.random.RandomState(T + causal)
+    q, k, v, g = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+                  for _ in range(4))
+
+    def run(q, k, v, g):
+        out, lse = pallas_attention._flash_forward(q, k, v, causal,
+                                                   D ** -0.5, 0.1, 77)
+        return (out, lse) + pallas_attention._flash_backward(
+            q, k, v, out, lse, g, causal, D ** -0.5, 0.1, 77)
+
+    def compiled_run(kernel):
+        """`run` traced afresh (a new function object, so the plan in
+        force is read) and compiled with the executor's options: the
+        backward's (512, 2048) tile needs its scoped-VMEM budget."""
+        fn = jax.jit(lambda *a: run(*a))
+        text = str(jax.make_jaxpr(fn)(q, k, v, g))
+        assert [kernel_calls(text, n) for n in KERNELS[:2]] == [
+            int(n == kernel) for n in KERNELS[:2]]
+        return fn.lower(q, k, v, g).compile(
+            compiler_options=resolve_compiler_options("tpu"))(q, k, v, g)
+
+    one = compiled_run("flash_fwd_onepass")
+    monkeypatch.setattr(pallas_attention, "_fwd_plan", lambda *a: "stream")
+    stream = compiled_run("flash_fwd")
+    no_drop = pallas_attention._flash_forward(q, k, v, causal, D ** -0.5)
+    assert not np.array_equal(np.asarray(one[0], np.float32),
+                              np.asarray(no_drop[0], np.float32))
+    for a, b, name in zip(one, stream, ("Out", "Lse", "dQ", "dK", "dV")):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a).all() and np.abs(a).max() > 0, name
+        np.testing.assert_array_equal(a, b, err_msg=name)

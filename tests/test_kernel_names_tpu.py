@@ -53,12 +53,25 @@ def test_flash_kernels_keep_their_names_in_the_compiled_step():
     # each kernel once a block: the grad op reads the forward op's saved Out
     # and Lse, so no forward kernel is lowered again inside it, and one
     # backward kernel gives dQ, dK and dV. Its name holds both `flash_dq`
-    # and `flash_dkv`: the benchmark's metrics of those names each find it
-    assert all(n.split(".")[0] in ("flash_fwd", "flash_dq_flash_dkv")
+    # and `flash_dkv`: the benchmark's metrics of those names each find it.
+    # A row of 128 is one K block, so the forward is the one-pass kernel,
+    # whose name holds `flash_fwd` for the same reason
+    assert all(n.split(".")[0] in ("flash_fwd_onepass", "flash_dq_flash_dkv")
                for n in names), names
-    for kernel in ("flash_fwd", "flash_dq_flash_dkv", "flash_dq",
-                   "flash_dkv"):
+    for kernel in ("flash_fwd_onepass", "flash_fwd", "flash_dq_flash_dkv",
+                   "flash_dq", "flash_dkv"):
         assert sum(kernel in n for n in names) == 3, (kernel, names)
+
+
+def test_streaming_forward_keeps_its_name():
+    """A row of several K blocks (384 = three 128-tiles) takes the
+    streaming kernel, `%flash_fwd.N` as before."""
+    from paddle_tpu.ops import pallas_attention
+    q = jnp.ones((1, 2, 384, 64), jnp.bfloat16)
+    text = jax.jit(lambda q: pallas_attention._flash_forward(
+        q, q, q, True, 0.125)).lower(q).compile().as_text()
+    names = _custom_calls(text)
+    assert [n.split(".")[0] for n in names] == ["flash_fwd"], names
 
 
 def test_paged_kernel_keeps_its_name():
