@@ -227,6 +227,41 @@ def parameter_sharing(program: ir.Program) -> Dict[str, int]:
             "grad_fanin_max": fanin}
 
 
+def layer_census(program: ir.Program) -> Dict[str, object]:
+    """What kinds of mixer and expert layer the program holds, read back
+    from the global block's forward ops: `layer_kinds`, the layers by their
+    mixer (`linear_attention`: a `gated_delta_rule` op, `full_attention`: a
+    `fused_attention` op); and where it has expert layers,
+    `moe_experts_routed` (the router's width) and `moe_experts_held` (the
+    experts whose weights live here: fewer under a share). Empty for a
+    program with neither. (`moe_row_buffer_rows`, the rows of the expert
+    layer's layout, follows the batch: `moe_dispatch`'s rule notes it on
+    the same event under the trace, `LoweringContext.note`.)"""
+    block = program.global_block()
+    kinds = {"linear_attention": 0, "full_attention": 0}
+    out: Dict[str, object] = {}
+    for op in block.ops:
+        if op.attrs.get("__role__") is not None:
+            continue
+        if op.type == "gated_delta_rule":
+            kinds["linear_attention"] += 1
+        elif op.type == "fused_attention":
+            kinds["full_attention"] += 1
+        elif op.type == "moe_router":
+            out["moe_experts_routed"] = block.var(op.input("W")[0]).shape[-1]
+        elif op.type == "moe_dispatch":
+            out["moe_experts_held"] = op.attrs.get(
+                "experts_held", out.get("moe_experts_routed"))
+    if any(kinds.values()):
+        out["layer_kinds"] = {k: n for k, n in kinds.items() if n}
+    return out
+
+
+def program_detail(program: ir.Program) -> Dict[str, object]:
+    """What the executors write on a program's compile events."""
+    return {**parameter_sharing(program), **layer_census(program)}
+
+
 def _grad_needing_inputs(block, op, no_grad, parameter_list) -> List[str]:
     """Inputs of `op` that should receive gradients (dedup, order-stable)."""
     seen, out = set(), []

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import ir, registry
-from .backward import parameter_sharing
+from .backward import program_detail
 from .. import flags as _flags
 from ..observe import steplog as _steplog
 from .lowering import BlockLowerer
@@ -710,7 +710,7 @@ class PreparedProgram:
                     tuple(self.fetch_names),
                     tuple(sorted(copts.items())) if copts else None,
                     source=self.telemetry_source, scope_uid=self.scope._uid,
-                    detail=parameter_sharing(program))
+                    detail=program_detail(program))
                 if _flags.get_flag("observe"):
                     # fluid-pulse memory observatory: a compile costs
                     # seconds, the concrete-shape walk costs milliseconds

@@ -139,6 +139,17 @@ class LoweringContext:
     def attr(self, name: str, default=None):
         return self.attrs.get(name, default)
 
+    def note(self, **facts):
+        """What a rule knows only under the trace (a static row count that
+        follows the batch), onto the detail of the program's newest compile
+        event. Nothing where a rule is called outside a program's lowering."""
+        if self.lowerer is None:
+            return
+        from ..observe import steplog
+        event = steplog.observatory().latest(self.lowerer.program._uid)
+        if event is not None and isinstance(event.detail, dict):
+            event.detail.update(facts)
+
 
 # AMP policy (torch-autocast style; reference analog:
 # paddle/contrib/float16/float16_transpiler.py rewrote programs to fp16).
@@ -171,7 +182,13 @@ AMP_F32_OPS = frozenset({"log_softmax", "cross_entropy",
                          # (log-sigmoids, their running sums, the exit
                          # distribution and its entropy) then stays float32:
                          # elementwise ops keep the dtype that reaches them
-                         "exit_gate"})
+                         "exit_gate",
+                         # a delta-rule layer's log-decay and write strength
+                         # from two bf16 projections: softplus, exp and
+                         # sigmoid in float32; `gated_delta_rule` then keeps
+                         # its sums, norms and state in float32 inside its
+                         # rule, like rms_norm, and needs no entry
+                         "delta_rule_gates"})
 # Mixed-dtype elementwise ops downcast the f32 side to bf16 instead of
 # letting numpy promotion upcast the bf16 side: one f32 mask/bias/table
 # leaking into the residual or attention-score stream would otherwise

@@ -1332,28 +1332,98 @@ def sequence_erase(input, tokens, name=None):
 # silu-gated product, flash attention as a layer, and sparse experts
 # ---------------------------------------------------------------------------
 
-def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+def rms_norm(input, epsilon=1e-5, param_attr=None, zero_centered=False,
+             name=None):
     """`x * rsqrt(mean(x^2) + epsilon) * w` over the last axis, with a
-    learned weight of that width (initialised to 1); statistics in float32."""
+    learned weight of that width (initialised to 1); statistics in float32.
+    `zero_centered`: `* (1 + w)` with w initialised to 0."""
     helper = LayerHelper("rms_norm", **locals())
+    scale = helper.create_parameter(
+        param_attr, [input.shape[-1]], "float32",
+        default_initializer=init.ConstantInitializer(
+            0.0 if zero_centered else 1.0))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    attrs = {"epsilon": epsilon}
+    if zero_centered:
+        attrs["zero_centered"] = True
+    helper.append_op("rms_norm",
+                     inputs={"X": [input.name], "Scale": [scale.name]},
+                     outputs={"Y": [out.name]}, attrs=attrs)
+    return out
+
+
+def gated_rms_norm(input, gate, epsilon=1e-6, param_attr=None, name=None):
+    """`x * rsqrt(mean(x^2) + epsilon) * w * silu(gate)` over the last axis,
+    `gate` of `input`'s shape, a learned weight of that width (initialised
+    to 1): the output norm of a gated-delta-rule layer, over a head."""
+    helper = LayerHelper("gated_rms_norm", **locals())
     scale = helper.create_parameter(
         param_attr, [input.shape[-1]], "float32",
         default_initializer=init.ConstantInitializer(1.0))
     out = helper.create_variable_for_type_inference(input.dtype)
-    helper.append_op("rms_norm",
-                     inputs={"X": [input.name], "Scale": [scale.name]},
+    helper.append_op("gated_rms_norm",
+                     inputs={"X": [input.name], "Gate": [gate.name],
+                             "Scale": [scale.name]},
                      outputs={"Y": [out.name]}, attrs={"epsilon": epsilon})
     return out
 
 
-def rotary_embedding(input, theta=10000.0, name=None):
+def rotary_embedding(input, theta=10000.0, rotary_dim=None, name=None):
     """Rotary position embedding (rotate-half convention) on
-    `[batch, heads, seq, head_dim]`, positions 0..seq-1."""
+    `[batch, heads, seq, head_dim]`, positions 0..seq-1; with `rotary_dim`
+    on the first `rotary_dim` dims of a head only, the others pass through."""
     helper = LayerHelper("rotary_embedding", name=name)
     out = helper.create_variable_for_type_inference(input.dtype)
+    attrs = {"theta": float(theta)}
+    if rotary_dim is not None:
+        attrs["rotary_dim"] = int(rotary_dim)
     helper.append_op("rotary_embedding", inputs={"X": [input.name]},
+                     outputs={"Out": [out.name]}, attrs=attrs)
+    return out
+
+
+def causal_conv1d(input, kernel_size, param_attr=None, name=None):
+    """Depthwise causal convolution over time on `[batch, seq, channels]`,
+    no bias, then silu: output t reads inputs t - kernel_size + 1 .. t of
+    its own channel. The weight is `[channels, kernel_size]`."""
+    helper = LayerHelper("causal_conv1d", **locals())
+    w = helper.create_parameter(param_attr, [input.shape[-1], kernel_size],
+                                "float32")
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op("causal_conv1d",
+                     inputs={"X": [input.name], "W": [w.name]},
                      outputs={"Out": [out.name]},
-                     attrs={"theta": float(theta)})
+                     attrs={"activation": "silu"})
+    return out
+
+
+def gated_delta_rule(q, k, v, a, b, a_log_attr=None, dt_bias_attr=None,
+                     chunk=64, name=None):
+    """Linear attention by the gated delta rule (`ops/linear_attention.py`)
+    on q, k `[batch, seq, key_heads, key_dim]` and v `[batch, seq,
+    value_heads, value_dim]`; `a`, `b` `[batch, seq, value_heads]` make a
+    head's log-decay `g = -exp(A_log) * softplus(a + dt_bias)` and write
+    strength `sigmoid(b)` in float32, with the learned `A_log` and `dt_bias`
+    `[value_heads]` (`a_log_attr`, `dt_bias_attr`). q and k are l2-normalised
+    over a head inside the op; seq must be a multiple of `chunk`. Returns
+    `[batch, seq, value_heads, value_dim]`."""
+    helper = LayerHelper("gated_delta_rule", name=name)
+    heads = v.shape[2]
+    a_log = helper.create_parameter(a_log_attr, [heads], "float32")
+    dt_bias = helper.create_parameter(
+        dt_bias_attr, [heads], "float32",
+        default_initializer=init.ConstantInitializer(1.0))
+    new = helper.create_variable_for_type_inference
+    g, beta = new("float32"), new("float32")
+    helper.append_op("delta_rule_gates",
+                     inputs={"A": [a.name], "B": [b.name],
+                             "ALog": [a_log.name], "DtBias": [dt_bias.name]},
+                     outputs={"G": [g.name], "Beta": [beta.name]})
+    out = new(v.dtype)
+    helper.append_op("gated_delta_rule",
+                     inputs={"Q": [q.name], "K": [k.name], "V": [v.name],
+                             "G": [g.name], "Beta": [beta.name]},
+                     outputs={"Out": [out.name]}, attrs={"chunk": int(chunk)})
     return out
 
 
@@ -1407,10 +1477,12 @@ def fused_attention(q, k, v, causal=False, sm_scale=None, dropout_rate=0.0,
     return out
 
 
-def moe_router(input, num_experts, k, param_attr=None, name=None):
+def moe_router(input, num_experts, k, param_attr=None, norm_topk_prob=False,
+               name=None):
     """Softmax top-k router over `input` [tokens, width]: float32 logits and
     softmax over all `num_experts`, the k largest probabilities used as they
-    are (not renormalised). Returns a dict: `weight` and `index`
+    are, or with `norm_topk_prob` divided by their sum (over all k, whichever
+    chip holds the chosen experts). Returns a dict: `weight` and `index`
     [tokens, k], `tokens_per_expert` [num_experts] (int32 counts of the
     assignments), `probs` [tokens, num_experts] and `logsumexp` [tokens]
     (what the load-balancing loss and the z-loss are built from)."""
@@ -1422,16 +1494,19 @@ def moe_router(input, num_experts, k, param_attr=None, name=None):
             "TopKIndex": new("int32", stop_gradient=True),
             "TokensPerExpert": new("int32", stop_gradient=True),
             "Probs": new("float32"), "LogSumExp": new("float32")}
+    attrs = {"k": int(k)}
+    if norm_topk_prob:
+        attrs["norm_topk_prob"] = True
     helper.append_op("moe_router", inputs={"X": [input.name], "W": [w.name]},
                      outputs={s: [v.name] for s, v in outs.items()},
-                     attrs={"k": int(k)})
+                     attrs=attrs)
     return {"weight": outs["TopKWeight"], "index": outs["TopKIndex"],
             "tokens_per_expert": outs["TokensPerExpert"],
             "probs": outs["Probs"], "logsumexp": outs["LogSumExp"]}
 
 
 def moe_experts(input, routing, num_experts, expert_size, param_attr=None,
-                name=None):
+                name=None, first_expert=None, experts_held=None):
     """Dropless gated-silu experts on `input` [tokens, width] under
     `routing` (what `moe_router` returned): every assignment is computed,
     `down_e(silu(gate_e(x)) * up_e(x))` summed over a token's experts with
@@ -1440,7 +1515,16 @@ def moe_experts(input, routing, num_experts, expert_size, param_attr=None,
     routing. The weights are stacked over experts,
     `<name>.gate.w` / `<name>.up.w` [experts, width, expert_size] and
     `<name>.down.w` [experts, expert_size, width]; `param_attr` gives their
-    initializer."""
+    initializer.
+
+    All `num_experts` experts are held unless `experts_held` is given: then
+    this is one chip's share of an expert-parallel layer. The router chose
+    among `num_experts`; the weights are `[experts_held, ...]`, those of
+    experts `first_expert .. first_expert + experts_held - 1`; the result is
+    the part those experts give, and assignments to the others add nothing
+    here, forward or backward. Still dropless for the held experts: the rows
+    of the layout are the worst case `tokens x k + experts_held x 128`,
+    which every routing fits (`ops/moe.py::_dispatch_share`)."""
     from ..ops.moe import ROW_TILE
     from ..param_attr import ParamAttr
     helper = LayerHelper("moe_experts", **locals())
@@ -1455,13 +1539,21 @@ def moe_experts(input, routing, num_experts, expert_size, param_attr=None,
             ParamAttr(name=f"{prefix}.{which}.w",
                       initializer=base.initializer), shape, "float32")
 
-    w_gate = weight("gate", [num_experts, width, expert_size])
-    w_up = weight("up", [num_experts, width, expert_size])
-    w_down = weight("down", [num_experts, expert_size, width])
+    stacked = num_experts if experts_held is None else experts_held
+    w_gate = weight("gate", [stacked, width, expert_size])
+    w_up = weight("up", [stacked, width, expert_size])
+    w_down = weight("down", [stacked, expert_size, width])
     x_sorted = new(dtype)
     slot = new("int32", stop_gradient=True)
     source = new("int32", stop_gradient=True)
     sizes = new("int32", stop_gradient=True)
+    share = {}
+    if experts_held is not None:
+        first = int(first_expert or 0)
+        if not 0 <= first <= num_experts - experts_held:
+            raise ValueError(f"experts {first} .. + {experts_held} are not "
+                             f"among {num_experts}")
+        share = {"first_expert": first, "experts_held": int(experts_held)}
     helper.append_op("moe_dispatch",
                      inputs={"X": [input.name],
                              "TopKIndex": [routing["index"].name],
@@ -1470,7 +1562,7 @@ def moe_experts(input, routing, num_experts, expert_size, param_attr=None,
                      outputs={"XSorted": [x_sorted.name],
                               "Slot": [slot.name], "Source": [source.name],
                               "GroupSizes": [sizes.name]},
-                     attrs={"row_tile": ROW_TILE})
+                     attrs={"row_tile": ROW_TILE, **share})
 
     def grouped(x, w):
         out = new(dtype)
@@ -1488,5 +1580,5 @@ def moe_experts(input, routing, num_experts, expert_size, param_attr=None,
                      inputs={"Y": [y_sorted.name],
                              "TopKWeight": [routing["weight"].name],
                              "Slot": [slot.name], "Source": [source.name]},
-                     outputs={"Out": [out.name]})
+                     outputs={"Out": [out.name]}, attrs=dict(share))
     return out

@@ -4,7 +4,10 @@ machine_translation}.py) plus Transformer-base and DeepFM (the BASELINE.json
 target workloads), OLMoE: a sparse-expert decoder LM at a published width
 (the first model whose loss is a cross-entropy plus two router losses), and
 Ouro: a looped decoder LM (the first model that uses a weight more than once
-a step: one stack of layers applied four times, four heads, an exit gate)."""
+a step: one stack of layers applied four times, four heads, an exit gate),
+and Qwen3-Next: gated-delta-rule linear-attention layers beside gated softmax
+attention, over one chip's share of a renormalised top-k expert layer with a
+shared expert (the first model built for a share of a stated deployment)."""
 
 from . import mnist  # noqa: F401
 from . import resnet  # noqa: F401
@@ -17,3 +20,4 @@ from . import se_resnext  # noqa: F401
 from . import tiny_lm  # noqa: F401
 from . import olmoe  # noqa: F401
 from . import ouro  # noqa: F401
+from . import qwen3_next  # noqa: F401

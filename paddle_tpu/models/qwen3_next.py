@@ -1,0 +1,267 @@
+"""Qwen3-Next: a decoder-only LM whose layers alternate a linear-attention
+mixer (the gated delta rule, three layers in four) with gated softmax
+attention (the fourth), each over a sparse-expert feed-forward with a shared
+expert (Qwen3-Next-80B-A3B; layer equations as in the public `qwen3_next`
+model code). Built for ONE CHIP'S SHARE of an expert-parallel deployment:
+the router chooses among all `n_expert` experts, this chip holds
+`experts_held` of them from `first_expert` on and computes their part.
+
+    N(x) = x * rsqrt(mean(x^2) + eps) * (1 + w)      every RMSNorm over the
+           hidden size and over a softmax head: zero-centred, w starts at 0
+    layer i:  h = x + Mixer_i(N(x));  y = h + MoE(N(h));  after the last
+              layer N, then the untied head
+    Mixer_i = GDN where (i + 1) % full_attention_interval != 0, else Attn
+
+    Attn: [q | gate] = x W_q (per head: its query and its output gate side
+          by side), k = x W_k, v = x W_v (`n_kv_head` heads), no bias;
+          q = N(q), k = N(k) over a head; rotary (rotate-half) on the first
+          `rotary_dim` dims of q and k; causal softmax attention at
+          head_dim^-0.5, each key-value head serving n_head / n_kv_head
+          query heads (the key and value heads are repeated in the Program);
+          out = (ctx * sigmoid(gate)) W_o
+    GDN:  [q, k, v, z] = x W_qkvz (per key head: q, k, then its value heads'
+          v, then their z), [b, a] = x W_ba (per key head: b's, then a's);
+          [q | k | v] <- silu(causal depthwise conv, no bias);
+          beta = sigmoid(b);  g = -exp(A_log) * softplus(a + dt_bias) <= 0;
+          q = q / sqrt(sum q^2 + 1e-6) * key_dim^-0.5, k likewise unscaled;
+          per value head a state S [key_dim, value_dim], S_0 = 0, every token
+              S <- exp(g_t) S;  d = beta_t (v_t - S^T k_t);  S <- S + k_t d^T;
+              o_t = S^T q_t
+          out = (o * rsqrt(mean(o^2) + eps) * w * silu(z)) W_out   (over a
+          value head; a plain weight that starts at 1)
+    MoE:  p = softmax(x W_r) in float32 over all experts; the top-k of p
+          divided by their sum (`norm_topk_prob`); routed = sum over the
+          chosen experts held here of p_k * down_e(silu(gate_e x) * up_e x),
+          dropless; shared = sigmoid(x w_s) * down_s(silu(gate_s x) * up_s x);
+          MoE(x) = routed + shared
+    loss = mean cross-entropy + aux_coef * n_expert * sum_e f_e P_e over all
+           layers' router rows (the form `models/olmoe.py` has; no z-loss)
+
+Left out: the multi-token-prediction module (the public config has no key
+for it). Float32 under AMP: the router (`moe_router`, AMP_F32_OPS), g and
+beta (`delta_rule_gates`, AMP_F32_OPS), and inside their rules the decay's
+running sums, the l2-norms, the solve and the state of `gated_delta_rule`,
+the convolution's sums and every norm's statistics. Built from
+`fluid.layers` only; parameter names are fixed (`l0.gdn.qkvz.w`,
+`l3.attn.q.w`, `l0.experts.gate.w`, `l0.shared.gate.w`, ...) so that a
+reference can be handed the same weights by name. Each layer's ops carry
+`fluid.name_scope("l<i>.gdn" | "l<i>.attn" | "l<i>.moe")`.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+from .. import initializer as init
+from .. import layers
+from ..core.ir import name_scope
+from ..param_attr import ParamAttr
+
+INIT_STD = 0.02
+
+
+def _normal():
+    return init.NormalInitializer(0.0, INIT_STD)
+
+
+def _w(name):
+    return ParamAttr(name=name, initializer=_normal())
+
+
+def _linear(x, size, name):
+    return layers.fc(input=x, size=size, num_flatten_dims=2, bias_attr=False,
+                     param_attr=_w(name + ".w"))
+
+
+def _norm(x, rms_eps, name):
+    return layers.rms_norm(x, epsilon=rms_eps, zero_centered=True,
+                           param_attr=ParamAttr(name=name + ".w"))
+
+
+def _last(x, first, end):
+    """x[..., first:end]."""
+    axis = len(x.shape) - 1
+    return layers.slice(x, axes=[axis], starts=[first], ends=[end])
+
+
+def layer_kind(i, full_attention_interval):
+    return "full_attention" if (i + 1) % full_attention_interval == 0 \
+        else "linear_attention"
+
+
+def _attention(x, n_head, n_kv_head, head_dim, rotary_dim, rope_theta,
+               rms_eps, name):
+    qg = layers.reshape(_linear(x, n_head * 2 * head_dim, name + ".q"),
+                        shape=[0, 0, n_head, 2 * head_dim])
+    q, gate = _last(qg, 0, head_dim), _last(qg, head_dim, 2 * head_dim)
+
+    def kv_heads(t):
+        return layers.reshape(t, shape=[0, 0, n_kv_head, head_dim])
+
+    k = kv_heads(_linear(x, n_kv_head * head_dim, name + ".k"))
+    v = kv_heads(_linear(x, n_kv_head * head_dim, name + ".v"))
+
+    def heads_first(t):
+        return layers.transpose(t, perm=[0, 2, 1, 3])
+
+    q = layers.rotary_embedding(
+        heads_first(_norm(q, rms_eps, name + ".q_norm")), theta=rope_theta,
+        rotary_dim=rotary_dim)
+    k = layers.rotary_embedding(
+        heads_first(_norm(k, rms_eps, name + ".k_norm")), theta=rope_theta,
+        rotary_dim=rotary_dim)
+
+    def serve_group(t):     # [B, kv, T, Dh] -> [B, heads, T, Dh], h // group
+        group = n_head // n_kv_head
+        t = layers.expand(layers.unsqueeze(t, axes=[2]),
+                          expand_times=[1, 1, group, 1, 1])
+        return layers.reshape(t, shape=[0, n_head, -1, head_dim])
+
+    ctx = layers.fused_attention(q, serve_group(k),
+                                 serve_group(heads_first(v)), causal=True,
+                                 sm_scale=head_dim ** -0.5)
+    ctx = layers.elementwise_mul(layers.transpose(ctx, perm=[0, 2, 1, 3]),
+                                 layers.sigmoid(gate))
+    ctx = layers.reshape(ctx, shape=[0, 0, n_head * head_dim])
+    return _linear(ctx, x.shape[-1], name + ".o")
+
+
+def _a_log(heads, seed):
+    """log of uniform(0, 16), as the public code initialises `A_log`; drawn
+    here so that the startup program holds the values."""
+    draws = np.random.RandomState(seed).uniform(0.0, 16.0, size=heads)
+    return init.NumpyArrayInitializer(
+        np.log(np.maximum(draws, 1e-3)).astype("float32"))
+
+
+def _gated_delta_net(x, n_key_head, n_value_head, key_dim, value_dim,
+                     conv_kernel, rms_eps, name, seed):
+    r = n_value_head // n_key_head
+    wide_k, wide_v = n_key_head * key_dim, n_value_head * value_dim
+    per_head = 2 * key_dim + 2 * r * value_dim
+    mixed = layers.reshape(_linear(x, n_key_head * per_head, name + ".qkvz"),
+                           shape=[0, 0, n_key_head, per_head])
+    ba = layers.reshape(_linear(x, n_key_head * 2 * r, name + ".ba"),
+                        shape=[0, 0, n_key_head, 2 * r])
+
+    def flat(t, width):
+        return layers.reshape(t, shape=[0, 0, width])
+
+    qkv = layers.concat(
+        [flat(_last(mixed, 0, key_dim), wide_k),
+         flat(_last(mixed, key_dim, 2 * key_dim), wide_k),
+         flat(_last(mixed, 2 * key_dim, 2 * key_dim + r * value_dim), wide_v)],
+        axis=2)
+    z = layers.reshape(_last(mixed, 2 * key_dim + r * value_dim, per_head),
+                       shape=[0, 0, n_value_head, value_dim])
+    qkv = layers.causal_conv1d(
+        qkv, conv_kernel, param_attr=ParamAttr(
+            name=name + ".conv.w",
+            initializer=init.UniformInitializer(-conv_kernel ** -0.5,
+                                                conv_kernel ** -0.5)))
+    q = layers.reshape(_last(qkv, 0, wide_k),
+                       shape=[0, 0, n_key_head, key_dim])
+    k = layers.reshape(_last(qkv, wide_k, 2 * wide_k),
+                       shape=[0, 0, n_key_head, key_dim])
+    v = layers.reshape(_last(qkv, 2 * wide_k, 2 * wide_k + wide_v),
+                       shape=[0, 0, n_value_head, value_dim])
+    o = layers.gated_delta_rule(
+        q, k, v, a=flat(_last(ba, r, 2 * r), n_value_head),
+        b=flat(_last(ba, 0, r), n_value_head),
+        a_log_attr=ParamAttr(name=name + ".A_log",
+                             initializer=_a_log(n_value_head, seed)),
+        dt_bias_attr=ParamAttr(name=name + ".dt_bias"))
+    o = layers.gated_rms_norm(o, z, epsilon=rms_eps,
+                              param_attr=ParamAttr(name=name + ".norm.w"))
+    return _linear(flat(o, wide_v), x.shape[-1], name + ".out")
+
+
+def _sparse_experts(x, seq_len, n_expert, top_k, d_expert, d_shared,
+                    first_expert, experts_held, norm_topk_prob, name):
+    d_model = x.shape[-1]
+    tokens = layers.reshape(x, shape=[-1, d_model])
+    routing = layers.moe_router(tokens, n_expert, top_k,
+                                param_attr=_w(name + ".router.w"),
+                                norm_topk_prob=norm_topk_prob)
+    routed = layers.moe_experts(
+        tokens, routing, n_expert, d_expert, param_attr=_normal(),
+        name=name + ".experts", first_expert=first_expert,
+        experts_held=experts_held)
+    hidden = layers.swiglu(_linear(x, d_shared, name + ".shared.gate"),
+                           _linear(x, d_shared, name + ".shared.up"))
+    shared = layers.elementwise_mul(
+        _linear(hidden, d_model, name + ".shared.down"),
+        layers.sigmoid(_linear(x, 1, name + ".shared_gate")))
+    out = layers.elementwise_add(
+        layers.reshape(routed, shape=[-1, seq_len, d_model]), shared)
+    return out, routing
+
+
+def qwen3_next(vocab_size=151936, seq_len=4096, n_layer=48, d_model=2048,
+               full_attention_interval=4, n_head=16, n_kv_head=2,
+               head_dim=256, rotary_dim=64, rope_theta=1e7, n_key_head=16,
+               n_value_head=32, key_dim=128, value_dim=128, conv_kernel=4,
+               n_expert=512, top_k=10, d_expert=512, d_shared=512,
+               norm_topk_prob=True, first_expert=0, experts_held=None,
+               rms_eps=1e-6, aux_coef=0.001):
+    """Returns (feeds, fetches) of one training step on `[batch, seq_len]`
+    token ids and next-token labels. `experts_held` None holds all
+    `n_expert` experts."""
+    tokens = layers.data(name="tokens", shape=[-1, seq_len], dtype="int64",
+                         append_batch_size=False)
+    labels = layers.data(name="labels", shape=[-1, seq_len], dtype="int64",
+                         append_batch_size=False)
+
+    x = layers.embedding(tokens, size=[vocab_size, d_model],
+                         param_attr=_w("embed.w"))
+    routings = []
+    for i in range(n_layer):
+        name = f"l{i}"
+        normed = _norm(x, rms_eps, name + ".in_norm")
+        if layer_kind(i, full_attention_interval) == "full_attention":
+            with name_scope(name + ".attn"):
+                mixed = _attention(normed, n_head, n_kv_head, head_dim,
+                                   rotary_dim, rope_theta, rms_eps,
+                                   name + ".attn")
+        else:
+            with name_scope(name + ".gdn"):
+                mixed = _gated_delta_net(normed, n_key_head, n_value_head,
+                                         key_dim, value_dim, conv_kernel,
+                                         rms_eps, name + ".gdn", seed=i)
+        x = layers.elementwise_add(x, mixed)
+        with name_scope(name + ".moe"):
+            moe, routing = _sparse_experts(
+                _norm(x, rms_eps, name + ".post_norm"), seq_len, n_expert,
+                top_k, d_expert, d_shared, first_expert, experts_held,
+                norm_topk_prob, name)
+        x = layers.elementwise_add(x, moe)
+        routings.append(routing)
+    x = _norm(x, rms_eps, "final_norm")
+    logits = _linear(x, vocab_size, "head")
+
+    ce = layers.mean(layers.softmax_with_cross_entropy(logits=logits,
+                                                       label=labels))
+    # all layers' router rows taken together, as `models/olmoe.py` does:
+    # f_e = assignments to e / rows, P_e = mean probability of e, over all
+    # `n_expert` experts wherever they live
+    counts = layers.sums([layers.cast(r["tokens_per_expert"], "float32")
+                          for r in routings])
+    rows = layers.scale(layers.reduce_sum(counts), scale=1.0 / top_k)
+    share = layers.elementwise_div(counts, rows)
+    share.stop_gradient = True      # counts: nothing to differentiate
+    mean_prob = layers.scale(
+        layers.sums([layers.reduce_mean(r["probs"], dim=0)
+                     for r in routings]), scale=1.0 / n_layer)
+    load_balance = layers.scale(
+        layers.reduce_sum(layers.elementwise_mul(share, mean_prob)),
+        scale=float(n_expert))
+    loss = layers.sums([ce, layers.scale(load_balance, scale=aux_coef)])
+    tokens_per_expert = layers.stack(
+        [r["tokens_per_expert"] for r in routings], axis=0)
+    return ({"tokens": tokens, "labels": labels},
+            {"loss": loss, "ce": ce, "load_balance": load_balance,
+             "logits": logits, "tokens_per_expert": tokens_per_expert})
+
+
+def build(**kw):
+    return qwen3_next(**kw)

@@ -356,7 +356,8 @@ class RecompilationObservatory:
         """Called on every executor compile-cache miss (a new
         _CompiledProgram is about to be built). Returns the cause.
         `detail`: what else the caller knows of the program (the executors
-        pass `backward.parameter_sharing`), kept on the event."""
+        pass `backward.program_detail`: the sharing counters and the census
+        of mixer and expert layers), kept on the event."""
         with self._lock:
             s = self._seen.get(program_uid)
             if s is None:

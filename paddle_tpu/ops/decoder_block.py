@@ -1,5 +1,6 @@
 """The pieces of a 2024 decoder block that are not attention or a plain
-matmul: RMSNorm, rotary position embedding, the silu-gated product, and a
+matmul: RMSNorm (plain, zero-centred, and gated over a head), rotary position
+embedding (on a whole head or its first dims), the silu-gated product, and a
 looped LM's exit gate.
 
 No reference analog (the reference predates all three); the equations are
@@ -20,12 +21,31 @@ from ..core.registry import register_op
 
 @register_op("rms_norm")
 def _rms_norm(ctx, X, Scale):
-    """`x * rsqrt(mean(x^2) + eps) * w` over the last axis."""
+    """`x * rsqrt(mean(x^2) + eps) * w` over the last axis; with
+    `zero_centered`, `* (1 + w)`: the weight is stored around 0 (the
+    `qwen3_next` norms)."""
     eps = ctx.attr("epsilon", 1e-5)
     x32 = X.astype(jnp.float32)
     ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    y = x32 * lax.rsqrt(ms + eps) * Scale.astype(jnp.float32)
+    w = Scale.astype(jnp.float32)
+    if ctx.attr("zero_centered", False):
+        w = 1.0 + w
+    y = x32 * lax.rsqrt(ms + eps) * w
     return {"Y": y.astype(X.dtype)}
+
+
+@register_op("gated_rms_norm")
+def _gated_rms_norm(ctx, X, Gate, Scale):
+    """`x * rsqrt(mean(x^2) + eps) * w * silu(gate)` over the last axis (a
+    head): the output norm of a gated-delta-rule layer. The normed value is
+    rounded to the input's dtype before the gate multiplies it in float32,
+    as the public `qwen3_next` code does."""
+    eps = ctx.attr("epsilon", 1e-6)
+    x32 = X.astype(jnp.float32)
+    ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    normed = (x32 * lax.rsqrt(ms + eps)).astype(X.dtype)
+    y = Scale.astype(jnp.float32) * normed.astype(jnp.float32)
+    return {"Y": (y * jax.nn.silu(Gate.astype(jnp.float32))).astype(X.dtype)}
 
 
 def rotary_tables(seq_len, dim, theta):
@@ -41,15 +61,22 @@ def rotary_tables(seq_len, dim, theta):
 @register_op("rotary_embedding", propagate_seqlen=False)
 def _rotary_embedding(ctx, X):
     """X `[..., T, D]` (heads already split): position t rotates the pair
-    `(x[i], x[i + D/2])` by `t * theta^(-2i/D)`; positions are 0..T-1."""
+    `(x[i], x[i + R/2])` by `t * theta^(-2i/R)` over the first R =
+    `rotary_dim` dims of a head (all D where the attribute is absent); the
+    other D - R pass through. Positions are 0..T-1."""
     T, D = X.shape[-2], X.shape[-1]
-    if D % 2:
-        raise ValueError(f"rotary_embedding needs an even head size, got {D}")
-    cos, sin = rotary_tables(T, D, float(ctx.attr("theta", 10000.0)))
+    R = int(ctx.attr("rotary_dim") or D)
+    if R % 2 or R > D:
+        raise ValueError(f"rotary_embedding needs an even rotary size within "
+                         f"the head, got {R} of {D}")
+    cos, sin = rotary_tables(T, R, float(ctx.attr("theta", 10000.0)))
     x32 = X.astype(jnp.float32)
-    x1, x2 = x32[..., : D // 2], x32[..., D // 2:]
-    rotated = jnp.concatenate([-x2, x1], axis=-1)
-    return {"Out": (x32 * cos + rotated * sin).astype(X.dtype)}
+    head = x32 if R == D else x32[..., :R]
+    x1, x2 = head[..., : R // 2], head[..., R // 2:]
+    out = head * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+    if R < D:
+        out = jnp.concatenate([out, x32[..., R:]], axis=-1)
+    return {"Out": out.astype(X.dtype)}
 
 
 @register_op("swiglu")

@@ -31,6 +31,27 @@ backend `ragged_dot` is the path (the kernel only under the Pallas
 interpreter, PADDLE_TPU_PALLAS_INTERPRET=1). Dispatch and combine are
 permutations: their hand-written grads gather through the inverse
 permutation instead of scatter-adding.
+
+Under a share (`moe_dispatch` with `experts_held`: one chip of an
+expert-parallel layer; the router still chooses among all E experts and the
+weights are `[experts_held, K, F]`) the layout holds the assignments to the
+held experts only, each group padded to whole tiles (none for an expert
+nobody chose), from row 0 on. The rows are static and the worst case, N*k +
+held*ROW_TILE: every routing fits, so nothing is ever dropped and nothing
+has to be checked; what the held groups do not use lies behind them (the
+rule notes the count on the program's compile event, `moe_row_buffer_rows`).
+`GroupSizes` sums to what is used, and the kernels' grid is as long as the
+tiles they visit (megablox counts them from the group sizes), so the work
+follows the held assignments and the unused rows are never written: they
+hold whatever was in memory. Nothing does arithmetic on them, not even times
+a zero weight (0 x NaN is NaN): a row's `Source` and an assignment's `Slot`
+are -1 where there is nothing, and both are applied with a select.
+
+Two ways to read a token's k rows back (`_rows_of_slots`): token-major
+`[N, k, D]` where every expert is held, slot-major `[k, N, D]` with the
+select under a share. Each is the faster one on its side (measured both
+ways on the chip, PERF.md section 6, PR 34), so both stay, chosen by the
+attribute.
 """
 
 from __future__ import annotations
@@ -61,13 +82,16 @@ def _moe_router(ctx, X, W):
     router losses' inputs in float32 (AMP_F32_OPS; the product at HIGHEST,
     since a TPU's default float32 product rounds its inputs to bf16 and a
     near-tie between experts flips on that). The k weights are the
-    probabilities as they are: not renormalised over the chosen k."""
+    probabilities as they are, or, with the attribute `norm_topk_prob`,
+    divided by their sum over all k chosen experts (wherever those live)."""
     k = int(ctx.attr("k"))
     logits = jnp.dot(X.astype(jnp.float32), W.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
     probs = jnp.exp(logits - lse[:, None])
     weight, index = lax.top_k(probs, k)
+    if ctx.attr("norm_topk_prob", False):
+        weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
     experts = jnp.arange(W.shape[1], dtype=index.dtype)
     counts = jnp.sum(index[:, :, None] == experts, axis=(0, 1),
                      dtype=jnp.int32)
@@ -89,9 +113,15 @@ def _moe_dispatch(ctx, X, TopKIndex, TokensPerExpert):
     grouped by expert, each group padded to whole row tiles (module
     docstring). `Slot` [N*k]: the row that holds assignment a (token a // k).
     `Source` [rows]: the assignment a row holds, -1 for padding.
-    `GroupSizes` [E]: the padded groups, which fill the rows."""
+    `GroupSizes` [E]: the padded groups, which fill the rows. With the
+    attribute `experts_held` it lays out a share instead (`_dispatch_share`)."""
     tile = int(ctx.attr("row_tile"))
     n, k = TopKIndex.shape
+    if ctx.attr("experts_held") is not None:
+        held = int(ctx.attr("experts_held"))
+        ctx.note(moe_row_buffer_rows=n * k + held * tile)
+        return _dispatch_share(X, TopKIndex, TokensPerExpert, tile,
+                               int(ctx.attr("first_expert")), held)
     counts = TokensPerExpert.astype(jnp.int32)
     rows = n * k + counts.shape[0] * tile
     sizes = _padded_groups(counts, rows, tile)
@@ -113,15 +143,72 @@ def _moe_dispatch(ctx, X, TopKIndex, TokensPerExpert):
             "Slot": slot, "Source": source, "GroupSizes": sizes}
 
 
+def _dispatch_share(X, TopKIndex, TokensPerExpert, tile, first, held):
+    """The layout of one chip's share: the router chose among all E experts,
+    this chip holds experts `first .. first + held - 1`. Only assignments to
+    those are placed, grouped by expert, each group padded to whole row
+    tiles (none for an expert nobody chose), from row 0 on, in N*k +
+    held*tile rows: the worst case (every assignment on a held expert, a
+    partly filled tile a group), so every routing fits. `GroupSizes` [held]
+    sums to what is used, and the grouped kernels visit those tiles only.
+    The rows after them are never written by anybody, so nothing may do
+    arithmetic on them: `Source` is -1 there and in padding, `Slot` is -1
+    for an assignment to an expert that lives elsewhere, and both are
+    applied with a select."""
+    n, k = TopKIndex.shape
+    counts = TokensPerExpert.astype(jnp.int32)[first:first + held]
+    sizes = -(-counts // tile) * tile
+    ends, packed_ends = jnp.cumsum(sizes), jnp.cumsum(counts)
+    local = TopKIndex.reshape(-1) - first
+    inside = (local >= 0) & (local < held)
+    # sorted position p holds assignment order[p] of held expert expert[p];
+    # assignments to experts that live elsewhere sort behind all of those
+    iota = lax.iota(jnp.int32, n * k)
+    expert, order = lax.sort_key_val(jnp.where(inside, local, held), iota,
+                                     is_stable=True)
+    shift = (ends - sizes) - (packed_ends - counts)
+    _, slot = lax.sort_key_val(
+        order, jnp.where(expert < held, iota + jnp.take(
+            shift, jnp.minimum(expert, held - 1)), -1))
+    row = lax.iota(jnp.int32, n * k + held * tile)
+    group = jnp.sum(row[:, None] >= ends[None, :-1], axis=1, dtype=jnp.int32)
+    rank = row - jnp.take(ends - sizes, group)
+    filled = rank < jnp.take(counts, group)
+    packed = jnp.take(packed_ends - counts, group) + rank
+    source = jnp.where(filled,
+                       jnp.take(order, jnp.where(filled, packed, 0)), -1)
+    x_rows = jnp.take(X, jnp.maximum(source, 0) // k, axis=0)
+    return {"XSorted": jnp.where(filled[:, None], x_rows, 0),
+            "Slot": slot, "Source": source, "GroupSizes": sizes}
+
+
+def _rows_of_slots(ctx, rows, slot, n, k):
+    """rows [M, D] at the k slots of n tokens, and the axis the k slots lie
+    on. Every expert held: `[n, k, D]`, 1. Under a share (the attribute
+    `experts_held`): `[k, n, D]`, 0, and an assignment to an expert that
+    lives elsewhere (slot -1) reads zeros, by a select. Slot-major there
+    because a `[n, k, D]` array whose k is no multiple of 8 (ten experts a
+    token) is laid out in padded tiles on a TPU, and the reshape into it is a
+    copy of every gathered row; `[k, n, D]` is free (-18.4 ms a step at k =
+    10). Token-major where every expert is held because it is the faster
+    one there: at k = 8 slot-major takes 1.9 ms more of a 66.9 ms step
+    (chip runs, PERF.md section 6, PR 34)."""
+    if ctx.attr("experts_held") is None:
+        return jnp.take(rows, slot, axis=0).reshape(n, k, -1), 1
+    slot = slot.reshape(n, k).T.reshape(-1)
+    per_slot = jnp.take(rows, jnp.maximum(slot, 0), axis=0)
+    return jnp.where((slot >= 0)[:, None], per_slot, 0).reshape(k, n, -1), 0
+
+
 @register_grad("moe_dispatch")
 def _moe_dispatch_grad(ctx, ins, out_grads):
     X, index = ins["X"][0], ins["TopKIndex"][0]
     g = out_grads["XSorted"][0]
     if g is None:
         return {}
-    n, k = index.shape
-    per_slot = jnp.take(g, ctx.fwd_outs["Slot"][0], axis=0).reshape(n, k, -1)
-    return {"X": jnp.sum(per_slot.astype(jnp.float32), axis=1)
+    per_slot, axis = _rows_of_slots(ctx, g, ctx.fwd_outs["Slot"][0],
+                                    *index.shape)
+    return {"X": jnp.sum(per_slot.astype(jnp.float32), axis=axis)
             .astype(X.dtype)}
 
 
@@ -200,11 +287,13 @@ def _grouped_matmul_grad(ctx, ins, out_grads):
 @register_op("moe_combine", propagate_seqlen=False)
 def _moe_combine(ctx, Y, TopKWeight, Slot, Source):
     """Y [rows, D] in `moe_dispatch`'s layout -> Out [N, D]: each token's k
-    expert results times its k router weights, summed in float32."""
-    n, k = TopKWeight.shape
-    per_slot = jnp.take(Y, Slot, axis=0).reshape(n, k, -1)
-    out = jnp.sum(per_slot.astype(jnp.float32)
-                  * TopKWeight.astype(jnp.float32)[:, :, None], axis=1)
+    expert results times its k router weights, summed in float32. Under a
+    share (the attribute `experts_held`) an assignment to an expert that
+    lives elsewhere adds nothing."""
+    per_slot, axis = _rows_of_slots(ctx, Y, Slot, *TopKWeight.shape)
+    weight = jnp.moveaxis(TopKWeight.astype(jnp.float32), 1, axis)
+    out = jnp.sum(per_slot.astype(jnp.float32) * weight[:, :, None],
+                  axis=axis)
     return {"Out": out.astype(Y.dtype)}
 
 
@@ -216,9 +305,11 @@ def _moe_combine_grad(ctx, ins, out_grads):
     if g is None:
         return {}
     n, k = weight.shape
-    per_slot = jnp.take(Y, slot, axis=0).reshape(n, k, -1)
-    d_weight = jnp.sum(per_slot.astype(jnp.float32)
-                       * g.astype(jnp.float32)[:, None, :], axis=-1)
+    per_slot, axis = _rows_of_slots(ctx, Y, slot, n, k)
+    d_weight = jnp.moveaxis(
+        jnp.sum(per_slot.astype(jnp.float32)
+                * jnp.expand_dims(g.astype(jnp.float32), axis), axis=-1),
+        axis, 1)
     held = jnp.maximum(source, 0)
     # a padding row's weight is 0: no slot read its result
     w_row = jnp.where(source >= 0, jnp.take(

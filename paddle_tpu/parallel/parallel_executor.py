@@ -25,7 +25,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..core import ir
-from ..core.backward import parameter_sharing
+from ..core.backward import program_detail
 from ..core.executor import (Scope, _CompiledProgram, _StateCache,
                              _evict_stale_versions, _evict_superseded,
                              global_scope)
@@ -242,7 +242,7 @@ class ParallelExecutor:
                 self._program._uid, self._program._version, feed_sig,
                 tuple(fetch_names), copts_sig, source="parallel",
                 scope_uid=self._scope._uid,
-                detail=parameter_sharing(self._program))
+                detail=program_detail(self._program))
             compiled = _CompiledProgram(self._program, sorted(feed_arrays),
                                         fetch_names, self._scope,
                                         donate=True,

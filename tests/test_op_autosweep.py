@@ -557,6 +557,19 @@ SPECS.update({
                              attrs={"theta": 100.0}),
     "swiglu": Spec(inputs={"Gate": T(3, 4), "Up": T(3, 4)}),
     "exit_gate": Spec(inputs={"X": T(2, 3, 5), "W": T(5, 1), "Bias": T(1)}),
+    "gated_rms_norm": Spec(inputs={"X": T(3, 2, 6), "Gate": T(3, 2, 6),
+                                   "Scale": POS(6)}, outs=("Y",)),
+    "causal_conv1d": Spec(inputs={"X": T(2, 6, 3), "W": T(3, 4)},
+                          attrs={"activation": "silu"}),
+    "delta_rule_gates": Spec(inputs={"A": T(2, 4, 3), "B": T(2, 4, 3),
+                                     "ALog": T(3), "DtBias": T(3)},
+                             outs=("G", "Beta")),
+    # two chunks of four tokens, a key head serving two value heads
+    "gated_delta_rule": Spec(inputs={"Q": T(1, 8, 1, 4), "K": T(1, 8, 1, 4),
+                                     "V": T(1, 8, 2, 3),
+                                     "G": T(1, 8, 2, lo=-1.0, hi=-0.05),
+                                     "Beta": T(1, 8, 2, lo=0.1, hi=0.9)},
+                             attrs={"chunk": 4}),
     "moe_router": Spec(inputs={"X": T(6, 5), "W": T(5, 4) * 2},
                        attrs={"k": 2},
                        outs=("TopKWeight", "TopKIndex", "TokensPerExpert",
