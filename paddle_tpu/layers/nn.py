@@ -1406,7 +1406,14 @@ def gated_delta_rule(q, k, v, a, b, a_log_attr=None, dt_bias_attr=None,
     strength `sigmoid(b)` in float32, with the learned `A_log` and `dt_bias`
     `[value_heads]` (`a_log_attr`, `dt_bias_attr`). q and k are l2-normalised
     over a head inside the op; seq must be a multiple of `chunk`. Returns
-    `[batch, seq, value_heads, value_dim]`."""
+    `[batch, seq, value_heads, value_dim]`.
+
+    The op has a second output, `States`: float32 `[seq / chunk, batch,
+    value_heads, key_dim, value_dim]`, the state each chunk started from, as
+    the forward kernel saves it. `gated_delta_rule_grad` reads it back and
+    runs the backward kernel alone. Where the forward op wrote none (head
+    dims that do not fill a vreg, a CPU backend: the XLA form) the grad op
+    traces the rule again under `jax.vjp`."""
     helper = LayerHelper("gated_delta_rule", name=name)
     heads = v.shape[2]
     a_log = helper.create_parameter(a_log_attr, [heads], "float32")
@@ -1420,10 +1427,12 @@ def gated_delta_rule(q, k, v, a, b, a_log_attr=None, dt_bias_attr=None,
                              "ALog": [a_log.name], "DtBias": [dt_bias.name]},
                      outputs={"G": [g.name], "Beta": [beta.name]})
     out = new(v.dtype)
+    states = new("float32", stop_gradient=True)
     helper.append_op("gated_delta_rule",
                      inputs={"Q": [q.name], "K": [k.name], "V": [v.name],
                              "G": [g.name], "Beta": [beta.name]},
-                     outputs={"Out": [out.name]}, attrs={"chunk": int(chunk)})
+                     outputs={"Out": [out.name], "States": [states.name]},
+                     attrs={"chunk": int(chunk)})
     return out
 
 

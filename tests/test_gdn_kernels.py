@@ -1,0 +1,298 @@
+"""The gated delta rule's two Pallas kernels (`ops/linear_attention.py`:
+`gdn_fwd`, `gdn_bwd`) under the Pallas interpreter on the CPU, at head dims
+that fill a vreg: against `jax.vjp` of `chunked_gated_delta_rule` (the XLA
+form the op keeps outside the kernels' envelope) and against the
+token-by-token recurrence of `tests/qwen3_next_reference.py`; the saved
+states; the op through a Program with and without the kernels; the plan's
+table; and the names and shapes the benchmark's patterns find the kernels
+by (`benchmark/metrics/gdn_*.json`)."""
+
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu import layers, observe
+from paddle_tpu.ops import linear_attention as la
+
+import qwen3_next_reference as ref
+from test_olmoe import run_piece
+from test_qwen3_next import REGIMES, RTOL, frob
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+B, HK, HV, D, CHUNK = 1, 2, 4, 128, 64
+SLOTS = "q k v g beta".split()
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+
+
+def _inputs(t, regime, seed=0, dk=D, dv=D):
+    """Raw q and k (the kernels normalise them) at 2 key / 4 value heads."""
+    rng = np.random.RandomState(seed)
+    (gs, go), (bs, bo) = REGIMES[regime]
+    q = rng.randn(B, t, HK, dk).astype(np.float32)
+    k = rng.randn(B, t, HK, dk).astype(np.float32)
+    v = rng.randn(B, t, HV, dv).astype(np.float32)
+    a = rng.randn(B, t, HV).astype(np.float32) * gs + go
+    g = -np.exp(rng.uniform(-1, 2.5, HV)).astype(np.float32) \
+        * np.log1p(np.exp(a))
+    beta = 1 / (1 + np.exp(-(rng.randn(B, t, HV) * bs + bo)))
+    return q, k, v, g.astype(np.float32), beta.astype(np.float32)
+
+
+def _prepared(q, k):
+    """What the op does before either oracle: l2-norm, scale, a key head
+    repeated over its value heads."""
+    q = la.l2_normalize(jnp.asarray(q)) * q.shape[-1] ** -0.5
+    k = la.l2_normalize(jnp.asarray(k))
+    return jnp.repeat(q, HV // HK, 2), jnp.repeat(k, HV // HK, 2)
+
+
+def _chunked(q, k, v, g, beta):
+    return la.chunked_gated_delta_rule(*_prepared(q, k), v, g, beta, CHUNK)
+
+
+def _recurrence(q, k, v, g, beta):
+    return ref.delta_rule(*_prepared(q, k), v, g, beta, token_block=64)
+
+
+@pytest.mark.parametrize("chunks", [2, 3])
+@pytest.mark.parametrize("regime", sorted(REGIMES))
+def test_kernels_match_both_oracles(regime, chunks, interpreted):
+    """Forward and all five input gradients: strong, weak and mixed decay,
+    write strengths near 0 and near 1."""
+    args = _inputs(chunks * CHUNK, regime)
+    probe = np.random.RandomState(9).randn(
+        B, chunks * CHUNK, HV, D).astype(np.float32)
+    out, states = la._gdn_forward(*args, CHUNK)
+    grads = la._gdn_backward(*args, states, probe, CHUNK)
+    assert np.all(np.isfinite(out))
+    with jax.default_matmul_precision("highest"):
+        for oracle in (_chunked, _recurrence):
+            want, vjp = jax.vjp(oracle, *args)
+            assert frob(out, want) < RTOL, oracle.__name__
+            for name, got, w in zip(SLOTS, grads, vjp(jnp.asarray(probe))):
+                assert np.all(np.isfinite(got)), name
+                assert got.shape == w.shape
+                assert frob(got, w) < 2e-4, (oracle.__name__, name,
+                                             frob(got, w))
+
+
+def test_kernels_at_unequal_head_dims(interpreted):
+    """Key heads of 256 over value heads of 128: the state is [256, 128]."""
+    args = _inputs(2 * CHUNK, "mixed", dk=256)
+    probe = np.random.RandomState(9).randn(B, 2 * CHUNK, HV, D).astype(
+        np.float32)
+    out, states = la._gdn_forward(*args, CHUNK)
+    assert states.shape == (2, B, HV, 256, D)
+    grads = la._gdn_backward(*args, states, probe, CHUNK)
+    with jax.default_matmul_precision("highest"):
+        want, vjp = jax.vjp(_chunked, *args)
+        assert frob(out, want) < RTOL
+        for name, got, w in zip(SLOTS, grads, vjp(jnp.asarray(probe))):
+            assert frob(got, w) < 2e-4, (name, frob(got, w))
+
+
+@pytest.mark.parametrize("regime", ["g_near_0", "g_strongly_negative",
+                                    "mixed"])
+def test_saved_states_are_the_recurrence_states(regime, interpreted):
+    """`States[c]` is the recurrence's state after the tokens before chunk
+    c. The recurrence gives no state away, so it is read through it: after
+    a prefix, Dk more tokens that neither decay nor write (g = 0,
+    beta = 0) and ask with the unit vectors: `o_t = S^T e_t` is row t."""
+    q, k, v, g, beta = _inputs(3 * CHUNK, regime)
+    _, states = la._gdn_forward(q, k, v, g, beta, CHUNK)
+    assert states.shape == (3, B, HV, D, D) and states.dtype == jnp.float32
+    np.testing.assert_array_equal(states[0], 0.0)
+    q_n, k_n = _prepared(q, k)
+    ask = jnp.broadcast_to(jnp.eye(D)[None, :, None, :], (B, D, HV, D))
+    for c in (1, 2):
+        cut = c * CHUNK
+
+        def grown(x, tail):
+            return jnp.concatenate([jnp.asarray(x)[:, :cut], tail], axis=1)
+
+        nothing = jnp.zeros((B, D, HV), jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            o = ref.delta_rule(grown(q_n, ask), grown(k_n, ask),
+                               grown(v, jnp.zeros((B, D, HV, D))),
+                               grown(g, nothing), grown(beta, nothing))
+        want = jnp.moveaxis(o[:, cut:], 1, 2)       # [B, Hv, Dk, Dv]
+        assert frob(states[c], want) < RTOL, c
+
+
+def _layer(feed, params, chunk=CHUNK):
+    return run_piece(
+        lambda d: [layers.gated_delta_rule(
+            d["q"], d["k"], d["v"], d["a"], d["b"],
+            a_log_attr=fluid.ParamAttr(name="A_log"),
+            dt_bias_attr=fluid.ParamAttr(name="dt_bias"), chunk=chunk)],
+        feed, params)
+
+
+def _layer_feed(t=2 * CHUNK, d=D):
+    rng = np.random.RandomState(2)
+    feed = {"q": rng.randn(B, t, HK, d), "k": rng.randn(B, t, HK, d),
+            "v": rng.randn(B, t, HV, d), "a": rng.randn(B, t, HV),
+            "b": rng.randn(B, t, HV)}
+    params = {"A_log": np.log(rng.uniform(0.1, 4, HV)).astype(np.float32),
+              "dt_bias": rng.uniform(0.5, 1.5, HV).astype(np.float32)}
+    return {n: x.astype(np.float32) for n, x in feed.items()}, params
+
+
+def _plan_noted():
+    plans = [e.detail.get("gdn_plan") for e in observe.observatory().events()
+             if isinstance(e.detail, dict)]
+    return [p for p in plans if p][-1]
+
+
+def test_the_op_gives_the_same_numbers_with_and_without_the_kernels(
+        monkeypatch):
+    """One op, one grad op (`gated_delta_rule_grad`): the kernels and their
+    saved `States` where the backend takes them, the XLA form traced again
+    under `jax.vjp` where it does not, and a chunk outside the envelope
+    (the rule does not depend on how it is cut)."""
+    feed, params = _layer_feed()
+    (xla,), xla_grads, _ = _layer(feed, params)
+    assert _plan_noted() == "xla"
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    (kernel,), kernel_grads, _ = _layer(feed, params)
+    assert _plan_noted() == "kernel"
+    (cut32,), cut32_grads, _ = _layer(feed, params, chunk=32)
+    assert _plan_noted() == "xla"
+    for out, grads in ((kernel, kernel_grads), (cut32, cut32_grads)):
+        assert frob(out, xla) < RTOL
+        assert sorted(grads) == sorted(xla_grads)
+        for name, w in xla_grads.items():
+            assert frob(grads[name], w) < 2e-4, name
+
+
+def test_head_dims_that_fill_no_vreg_keep_the_xla_form(interpreted):
+    feed, params = _layer_feed(d=8)
+    (out,), grads, _ = _layer(feed, params)
+    assert _plan_noted() == "xla"
+    assert np.all(np.isfinite(out)) and sorted(grads) == sorted(
+        ["q", "k", "v", "a", "b", "A_log", "dt_bias"])
+
+
+@pytest.mark.parametrize("dk,dv,chunk,plan", [
+    (128, 128, 64, "kernel"), (256, 128, 64, "kernel"),
+    (128, 256, 64, "kernel"), (8, 8, 64, "xla"), (32, 16, 64, "xla"),
+    (64, 128, 64, "xla"), (128, 64, 64, "xla"), (192, 128, 64, "xla"),
+    (128, 128, 32, "xla"), (128, 128, 128, "xla")])
+def test_plan_reads_the_shape_alone(dk, dv, chunk, plan):
+    assert la._plan(dk, dv, chunk) == plan
+
+
+def test_a_cpu_backend_takes_the_kernels_only_when_interpreted(monkeypatch):
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    assert not la._kernels_run(128, 128, 64)
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    assert la._kernels_run(128, 128, 64)
+    assert not la._kernels_run(8, 8, 64)
+
+
+def test_the_program_declares_the_saved_states():
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        q = layers.data(name="q", shape=[1, 256, 2, 128], dtype="float32",
+                        append_batch_size=False)
+        v = layers.data(name="v", shape=[1, 256, 4, 128], dtype="float32",
+                        append_batch_size=False)
+        a = layers.data(name="a", shape=[1, 256, 4], dtype="float32",
+                        append_batch_size=False)
+        out = layers.gated_delta_rule(q, q, v, a, a)
+    (op,) = [o for o in main.global_block().ops
+             if o.type == "gated_delta_rule"]
+    states = main.global_block().var(op.output("States")[0])
+    assert tuple(states.shape) == (4, 1, 4, 128, 128)
+    assert states.dtype == "float32" and states.stop_gradient
+    assert tuple(out.shape) == (1, 256, 4, 128)
+
+
+# -- what the benchmark finds the kernels by ----------------------------------
+
+def _metric(name):
+    with open(os.path.join(ROOT, "benchmark", "metrics", name + ".json")) as f:
+        return json.load(f)
+
+
+def _instruction(eqn):
+    """The line a TPU trace names a `pallas_call` by: its name, then the
+    tuple of its results in row-major layouts."""
+    def result(aval):
+        dtype = {"float32": "f32", "bfloat16": "bf16"}[str(aval.dtype)]
+        dims = ",".join(str(d) for d in aval.shape)
+        order = ",".join(str(i) for i in reversed(range(len(aval.shape))))
+        return f"{dtype}[{dims}]{{{order}}}"
+
+    name = eqn.params["name"]
+    results = ", ".join(result(v.aval) for v in eqn.outvars)
+    return f"%{name}.1 = ({results}) custom-call(%reshape.8, %reshape.9)"
+
+
+def _cell_instructions():
+    """`gdn_fwd` and `gdn_bwd` as `qwen3_next_80b_a3b.bs1` calls them."""
+    bf16 = jnp.bfloat16
+    q = jax.ShapeDtypeStruct((1, 4096, 16, 128), bf16)
+    v = jax.ShapeDtypeStruct((1, 4096, 32, 128), bf16)
+    g = jax.ShapeDtypeStruct((1, 4096, 32), jnp.float32)
+    states = jax.ShapeDtypeStruct((64, 1, 32, 128, 128), jnp.float32)
+    lines = {}
+    for fn, args in ((la._gdn_forward, (q, q, v, g, g)),
+                     (la._gdn_backward, (q, q, v, g, g, states, v))):
+        jaxpr = jax.make_jaxpr(lambda *a: fn(*a, 64))(*args)
+        (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        lines[call.params["name"]] = _instruction(call)
+    return lines
+
+
+def test_the_kernels_stay_inside_the_benchmarks_pattern():
+    """`gdn_scan_ms.train` and its roofline share find the rule's
+    instructions by the shape of their first result; the kernels are
+    counted there only while their first outputs keep such a shape, and
+    must not be taken for flash or grouped-matmul kernels."""
+    lines = _cell_instructions()
+    assert sorted(lines) == ["gdn_bwd", "gdn_fwd"]
+    assert lines["gdn_fwd"].startswith("%gdn_fwd.1 = (f32[64,1,32,128,128]{")
+    assert lines["gdn_bwd"].startswith("%gdn_bwd.1 = (f32[1,32,64,1,64]{")
+    for metric in ("gdn_scan_ms.train", "gdn_scan_roofline_pct.train",
+                   "gdn_kernel_ms.train", "gdn_kernel_calls.train"):
+        pattern = _metric(metric)["args"]["pattern"]
+        for line in lines.values():
+            assert re.search(pattern, line), (metric, line)
+    for metric in ("hybrid_attention_kernels_ms.train",
+                   "share_expert_matmul_ms.train"):
+        pattern = _metric(metric)["args"]["pattern"]
+        for line in lines.values():
+            assert not re.search(pattern, line), (metric, line)
+
+
+@pytest.mark.parametrize("metric,reader", [
+    ("gdn_kernel_calls.train", "trace_calls"),
+    ("gdn_kernel_ms.train", "trace_ops")])
+def test_the_kernel_metrics_load_and_find_the_kernels_alone(metric, reader):
+    spec = _metric(metric)
+    assert spec["reader"] == reader
+    assert os.path.isfile(os.path.join(ROOT, "benchmark", "readers",
+                                       reader + ".py"))
+    pattern = spec["args"]["pattern"]
+    tail = "(f32[8,128]{1,0}, bf16[8,128]{1,0}) custom-call(%p.1)"
+    for name in ("%gdn_fwd.1", "%gdn_bwd.3", "gdn_bwd"):
+        assert re.search(pattern, f"{name} = {tail}")
+    for name in ("%flash_fwd.1", "%gmm.9", "%tgmm.2", "%fusion.7"):
+        assert not re.search(pattern, f"{name} = {tail}")
+    assert not re.search(pattern, "%gdn_fwd.1 = f32[8]{0} fusion(%p.1)")
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        (entry,) = [m for m in json.load(f)["per_layer"]
+                    if m["name"] == metric]
+    assert entry["layer"] == "linear attention"
+    assert entry["workloads"] == ["qwen3_next_80b_a3b.bs1"]

@@ -1,0 +1,67 @@
+"""Kernels of the main paths compiled by the TPU's own compiler for a
+described v5e chip, at the cells' widths, with no chip attached: what the
+Pallas interpreter cannot refuse (a slice off the tiling, too much VMEM, a
+product Mosaic has no lowering for). Nothing runs, so nothing here is a
+result or a time. Every such compile lives in this one file: the worker
+that is given it loads the TPU's library, and keeps it."""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.ops import linear_attention as la
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # and cannot be read back without one: keep these out of it
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cached)
+    compilation_cache.reset_cache()
+
+
+def _custom_calls(compiled, name):
+    return [line for line in compiled.as_text().splitlines()
+            if f"%{name}" in line and " custom-call(" in line
+            and "= " in line]
+
+
+def test_gdn_kernels_compile_at_the_cells_shapes(one_chip, monkeypatch):
+    """`gdn_fwd` and `gdn_bwd` as `qwen3_next_80b_a3b.bs1` calls them: bf16
+    q, k `[1, 4096, 16, 128]`, v `[1, 4096, 32, 128]`, the chip's one-pass
+    products; each is one Mosaic custom call whose first result is the one
+    the benchmark's pattern knows."""
+    monkeypatch.setattr(la, "_on_chip", lambda: True)
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q = arg((1, 4096, 16, 128), jnp.bfloat16)
+    v = arg((1, 4096, 32, 128), jnp.bfloat16)
+    g = arg((1, 4096, 32), jnp.float32)
+    states = arg((64, 1, 32, 128, 128), jnp.float32)
+    fwd = jax.jit(lambda *a: la._gdn_forward(*a, 64)).lower(
+        q, q, v, g, g).compile()
+    (call,) = _custom_calls(fwd, "gdn_fwd")
+    assert "(f32[64,1,32,128,128]{" in call and "tpu_custom_call" in call
+    bwd = jax.jit(lambda *a: la._gdn_backward(*a, 64)).lower(
+        q, q, v, g, g, states, v).compile()
+    (call,) = _custom_calls(bwd, "gdn_bwd")
+    assert "(f32[1,32,64,1,64]{" in call and "tpu_custom_call" in call
