@@ -196,7 +196,7 @@ def main():
         if fused and not rehearsal:
             # a quiet reference path cannot pass: the kernels must be IN
             # the compiled step
-            text = compile_main_step(exe, scope, feed).as_text()
+            text = compile_main_step(exe, scope, main_p).as_text()
             n = text.count("tpu_custom_call")
             assert n, "no Mosaic custom call in the compiled fused step"
             print(f"  {n} tpu_custom_call mentions in the compiled step",

@@ -17,8 +17,10 @@ dispatch.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import logging
+import weakref
 from typing import Any, Dict, List, Optional, Sequence, Union
 
 import jax
@@ -311,6 +313,85 @@ def donation_safe() -> bool:
             or jax.default_backend() != "cpu")
 
 
+def _abstract(x):
+    """What the jitted step's cache key sees of an argument: shape, dtype
+    and, for a committed array, its sharding."""
+    if isinstance(x, jax.ShapeDtypeStruct):
+        return x
+    sharding = x.sharding if isinstance(x, jax.Array) and x.committed \
+        else None
+    return jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=sharding)
+
+
+def _needs_device_ctx(device, entry: "_CompiledProgram") -> bool:
+    """Whether `entry`'s step has to be called (and lowered) under
+    `jax.default_device(device)`. Entering that context per step costs
+    ~hundreds of µs (it defeats pjit's C++ fast path) and it is part of
+    jax's trace-cache key, so it is entered only where it decides
+    something: the ctx can be skipped when the device IS the process
+    default and the step reads scope state. jit outputs are UNCOMMITTED, so
+    a stateful step with numpy feeds would otherwise migrate to jax's
+    global default backend (e.g. CPUPlace selected in a TPU-default
+    process): place selection must hold even without the per-step ctx."""
+    try:
+        default_dev = (jax.config.jax_default_device
+                       or jax.local_devices()[0])
+    except Exception:
+        default_dev = None
+    return device != default_dev or not (entry.mut_names or entry.const_names)
+
+
+def _step_device_ctx(device, entry: "_CompiledProgram"):
+    """The context a run() calls `entry`'s step under: a lowering made
+    under the same one finds what jax cached for the running step (its
+    trace, its lowering and, through them, its executable)."""
+    if device is not None and _needs_device_ctx(device, entry):
+        return jax.default_device(device)
+    return contextlib.nullcontext()
+
+
+def lower_step(entry: "_CompiledProgram", feeds: Dict[str, Any], scope: Scope):
+    """`entry`'s step lowered (a `jax.stages.Lowered`) from abstract
+    arguments: the signature of `feeds` (arrays, or what `_abstract` made of
+    them) and of the state `scope` holds now. No array is read, and the
+    entry's own compiler options apply, so `.compile()` of a step that has
+    run gives the executable that runs: lowered under the context a run
+    calls the step in (`_step_device_ctx`), jax finds the running step's
+    own trace, lowering and executable in its in-process caches, and
+    nothing is compiled again. The one place the executors and the tools
+    get a step's text."""
+    mut, const = entry.gather_state(scope)
+    return entry._step.lower(
+        {n: _abstract(feeds[n]) for n in sorted(feeds)},
+        {n: _abstract(v) for n, v in mut.items()},
+        {n: _abstract(v) for n, v in const.items()},
+        jax.ShapeDtypeStruct((), np.uint32))
+
+
+def offer_step_text(program_uid: int, entry: "_CompiledProgram",
+                    feed_arrays: Dict[str, Any], scope: Scope, device=None):
+    """Put a lazy `compiled_text()` on the compile event just recorded for
+    `entry`: the feeds' abstract signature is noted here, once, the state's
+    is read from the scope at the ask (by then the committed outputs of a
+    step), and nothing is lowered until someone asks. The closure holds the
+    entry, the avals and a weak reference to the scope; no arrays."""
+    entry.feed_avals = {n: _abstract(v) for n, v in feed_arrays.items()}
+    event = _steplog.observatory().latest(program_uid)
+    if event is None:
+        return
+    scope_ref = weakref.ref(scope)
+
+    def text():
+        scope = scope_ref()
+        if scope is None:
+            return None
+        with _step_device_ctx(device, entry):
+            return lower_step(entry, entry.feed_avals,
+                              scope).compile().as_text()
+
+    event.offer_text(text)
+
+
 class _CompiledProgram:
     """One lowered+jitted step for a (program version, feed/fetch set)."""
 
@@ -322,6 +403,9 @@ class _CompiledProgram:
         self.fetch_names = list(fetch_names)
         self.check_nan_inf = check_nan_inf
         self._nan_meta = []
+        # abstract signature of the feeds the entry was bound with
+        # (`offer_step_text`): what `lower_step` needs instead of a feed
+        self.feed_avals: Optional[Dict[str, Any]] = None
         block = program.global_block()
         lowerer = BlockLowerer(program, amp=amp, check_nan_inf=check_nan_inf,
                                mesh=mesh)
@@ -726,24 +810,15 @@ class PreparedProgram:
                         donate=True, amp=exe.amp,
                         check_nan_inf=self._check_nan_inf,
                         compiler_options=copts, rng_stream=stream)
+                offer_step_text(program._uid, entry, feed_arrays, self.scope,
+                                self._device)
                 _evict_stale_versions(exe._cache, program._uid,
                                       program._version)
                 exe._cache[cache_key] = entry
             self._entries[sig] = entry
         self._entry = entry
         self._entry_keys = frozenset(sig)
-        # the ctx can be skipped only when this handle's device IS the
-        # process default: jit outputs are UNCOMMITTED, so a stateful step
-        # with numpy feeds would otherwise migrate to jax's global default
-        # backend (e.g. CPUPlace selected in a TPU-default process) —
-        # place selection must hold even without the per-step ctx
-        try:
-            default_dev = (jax.config.jax_default_device
-                           or jax.local_devices()[0])
-        except Exception:
-            default_dev = None
-        self._use_device_ctx = (self._device != default_dev
-                                or not (entry.mut_names or entry.const_names))
+        self._use_device_ctx = _needs_device_ctx(self._device, entry)
         self._feed_plan = self._build_feed_plan(feed)
         self._plan_keys = frozenset(feed)
         return entry
@@ -913,6 +988,34 @@ class Executor:
         if return_numpy:
             fetches = [np.asarray(f) for f in fetches]
         return fetches
+
+    def compiled_step(self, program: Optional[ir.Program] = None,
+                      scope: Optional[Scope] = None):
+        """The `jax.stages.Compiled` of a step this executor has run
+        against `scope`: `.as_text()` is its optimized HLO,
+        `.cost_analysis()` XLA's own count. Built from the abstract
+        signature noted when the step was bound and the state the scope
+        holds now (`lower_step`), so no feed is needed, and answered from
+        jax's in-process caches once the step has run with that
+        signature."""
+        program = program or ir.default_main_program()
+        scope = scope or global_scope()
+        # cache keys lead with (program uid, version, feed names, fetch
+        # names, scope uid): PreparedProgram._bind
+        entries = [e for k, e in self._cache.items()
+                   if (k[0], k[1], k[4]) == (program._uid, program._version,
+                                             scope._uid)]
+        if not entries:
+            raise RuntimeError("compiled_step requires a prior run() of the "
+                               "program against this scope")
+        entry = entries[-1]     # the newest feed signature and fetch set
+        with _step_device_ctx(self.place.jax_device(), entry):
+            return lower_step(entry, entry.feed_avals, scope).compile()
+
+    def compiled_text(self, program: Optional[ir.Program] = None,
+                      scope: Optional[Scope] = None) -> str:
+        """Optimized HLO of `compiled_step(program, scope)`."""
+        return self.compiled_step(program, scope).as_text()
 
     def close(self):
         self._cache.clear()

@@ -272,7 +272,8 @@ _building = threading.local()
 
 class RecompileEvent:
     __slots__ = ("ts", "program_uid", "cause", "source", "detail",
-                 "cache_hits", "cache_misses", "_stage_spans")
+                 "cache_hits", "cache_misses", "_stage_spans", "_text_fn",
+                 "_op_map")
 
     def __init__(self, ts, program_uid, cause, source, detail):
         self.ts = ts
@@ -282,7 +283,33 @@ class RecompileEvent:
         self.detail = detail
         self.cache_hits = self.cache_misses = 0   # persistent compile cache
         self._stage_spans: Dict[str, list] = {}
+        self._text_fn = self._op_map = None
         _building.event = self
+
+    def offer_text(self, fn):
+        """The executor that built this event's step says how to get its
+        compiled text: `fn()` lowers and compiles when asked, not before."""
+        self._text_fn = fn
+
+    def compiled_text(self) -> Optional[str]:
+        """Optimized HLO of the step this event built, as it runs in steady
+        state; None where no executor offered one (a shape miss, an uncached
+        run) or its scope is gone. Made at every ask and not kept (a step's
+        text runs to tens of MB; `op_map` keeps what is read from it): once
+        the step has run with the signature asked for, jax answers the
+        trace, the lowering and the executable from what it cached for the
+        running step, and the ask costs the printing of the text; before
+        that it is a lowering and a compile."""
+        return self._text_fn() if self._text_fn is not None else None
+
+    def op_map(self, build):
+        """`build(compiled_text())`, made once (`profiler.op_map` passes its
+        builder); None where there is no text."""
+        if self._op_map is None:
+            text = self.compiled_text()
+            if text is not None:
+                self._op_map = build(text)
+        return self._op_map
 
     def add_stage(self, stage: str, seconds: float):
         """jax reports a duration when it ends, and a traced function that

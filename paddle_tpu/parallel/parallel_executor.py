@@ -28,7 +28,7 @@ from ..core import ir
 from ..core.backward import program_detail
 from ..core.executor import (Scope, _CompiledProgram, _StateCache,
                              _evict_stale_versions, _evict_superseded,
-                             global_scope)
+                             global_scope, lower_step, offer_step_text)
 from ..observe import steplog as _steplog
 from . import mesh as mesh_lib
 
@@ -249,6 +249,8 @@ class ParallelExecutor:
                                         amp=self._build_strategy.amp,
                                         mesh=self._mesh,
                                         compiler_options=copts)
+            offer_step_text(self._program._uid, compiled, feed_arrays,
+                            self._scope)
             _evict_stale_versions(self._cache, self._program._uid,
                                   self._program._version)
             self._cache[key] = compiled
@@ -296,13 +298,11 @@ class ParallelExecutor:
                 feed_arrays[name] = self._shard_feed(val, var)
         return feed_arrays
 
-    def lowered_text(self, feed) -> str:
-        """StableHLO text of the step this feed shape ran through — the
-        supported way to inspect what GSPMD emitted (tests/dryrun assert
-        on collective ops here instead of poking privates). Requires a
-        prior run() with the same feed names (and fetch list)."""
+    def _entry_for(self, feed, what):
+        """(cache entry, converted feeds) of the step a run() with this
+        feed's names went through."""
         if not self._cache:
-            raise RuntimeError("lowered_text requires a prior run()")
+            raise RuntimeError(f"{what} requires a prior run()")
         feeds = self._convert_feeds(feed)
         names = tuple(sorted(feeds))
         cands = [k for k in self._cache
@@ -313,11 +313,15 @@ class ParallelExecutor:
                 f"run() with this feed first")
         # prefer the step the LAST run used (disambiguates fetch lists)
         key = self._last_key if self._last_key in cands else cands[-1]
-        compiled = self._cache[key]
-        mut = {n: self._scope.find_var(n) for n in compiled.mut_names}
-        const = {n: self._scope.find_var(n) for n in compiled.const_names}
-        return compiled._step.lower({k: feeds[k] for k in sorted(feeds)},
-                                    mut, const, np.uint32(0)).as_text()
+        return self._cache[key], feeds
+
+    def lowered_text(self, feed) -> str:
+        """StableHLO text of the step this feed shape ran through — the
+        supported way to inspect what GSPMD emitted (tests/dryrun assert
+        on collective ops here instead of poking privates). Requires a
+        prior run() with the same feed names (and fetch list)."""
+        compiled, feeds = self._entry_for(feed, "lowered_text")
+        return lower_step(compiled, feeds, self._scope).as_text()
 
     def compiled_text(self, feed) -> str:
         """Optimized-HLO text of the compiled step — AFTER GSPMD
@@ -325,30 +329,15 @@ class ParallelExecutor:
         (all-reduce / all-gather / collective-permute / reduce-scatter)
         are visible and countable. Same contract as lowered_text: run()
         with this feed first."""
-        if not self._cache:
-            raise RuntimeError("compiled_text requires a prior run()")
-        feeds = self._convert_feeds(feed)
-        names = tuple(sorted(feeds))
-        cands = [k for k in self._cache
-                 if k[2] == names and k[1] == self._program._version]
-        if not cands:
-            raise RuntimeError(
-                f"no compiled step matches feed names {sorted(feeds)}; "
-                f"run() with this feed first")
-        key = self._last_key if self._last_key in cands else cands[-1]
-        compiled = self._cache[key]
+        compiled, feeds = self._entry_for(feed, "compiled_text")
         # memoize: the AOT compile below is a second full GSPMD+XLA
-        # compile of a step run() already compiled (the jit-internal
-        # executable is not publicly reachable); callers probing the
-        # inventory repeatedly must not pay it repeatedly
-        if getattr(compiled, "_hlo_text", None) is not None:
-            return compiled._hlo_text
-        mut = {n: self._scope.find_var(n) for n in compiled.mut_names}
-        const = {n: self._scope.find_var(n) for n in compiled.const_names}
-        compiled._hlo_text = (
-            compiled._step.lower({k: feeds[k] for k in sorted(feeds)},
-                                 mut, const, np.uint32(0))
-            .compile().as_text())
+        # compile of a step run() already compiled where the compile cache
+        # is cold (the jit-internal executable is not publicly reachable);
+        # callers probing the inventory repeatedly must not pay it
+        # repeatedly
+        if getattr(compiled, "_hlo_text", None) is None:
+            compiled._hlo_text = lower_step(
+                compiled, feeds, self._scope).compile().as_text()
         return compiled._hlo_text
 
     def _shard_feed(self, arr, var=None):

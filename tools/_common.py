@@ -1,24 +1,17 @@
 """Shared helper for the perf tools: compile a framework program's main
 training step and return a jax `Compiled` for cost analysis / HLO dumps.
 
-Centralizes the private-API dance (pick the largest cached step, collect
-mut/const state, lower+compile) so a change to Executor internals breaks
-one place, not three."""
+`Executor.compiled_step` is the public accessor; this keeps the tools'
+call shape."""
 
 from __future__ import annotations
 
 
-def compile_main_step(exe, scope, feed):
-    """exe must have run the program at least once with `feed`."""
-    import numpy as np
-
-    compiled = max(exe._cache.values(),
-                   key=lambda c: len(c.program.global_block().ops))
-    mut = {n: scope.find_var(n) for n in compiled.mut_names}
-    const = {n: scope.find_var(n) for n in compiled.const_names}
-    feeds = {k: feed[k] for k in sorted(feed)}
-    return (compiled._step.lower(feeds, mut, const, np.uint32(0))
-            .compile())
+def compile_main_step(exe, scope, program=None):
+    """exe must have run `program` (the default main program if None) at
+    least once against `scope`. No feed is needed: the executor noted the
+    feeds' signature when it bound the step."""
+    return exe.compiled_step(program, scope=scope)
 
 
 def parse_flag(argv, name, default):
