@@ -150,6 +150,17 @@ class LoweringContext:
         if event is not None and isinstance(event.detail, dict):
             event.detail.update(facts)
 
+    def tally(self, key: str):
+        """Count this op under `key` on the compile event: how many of the
+        program's ops took a path that only their rule knows of. `note`
+        overwrites, so the lowerer keeps which ops have counted: an op counts
+        once however often the step is traced."""
+        if self.lowerer is None:
+            return
+        counted = self.lowerer.tallies.setdefault(key, set())
+        counted.add(id(self.op))
+        self.note(**{key: len(counted)})
+
 
 # AMP policy (torch-autocast style; reference analog:
 # paddle/contrib/float16/float16_transpiler.py rewrote programs to fp16).

@@ -1521,7 +1521,7 @@ def moe_experts(input, routing, num_experts, expert_size, param_attr=None,
     `down_e(silu(gate_e(x)) * up_e(x))` summed over a token's experts with
     its router weights; the rows are laid out by expert in groups of whole
     row tiles (`ops/moe.py`), so the step's time does not follow the
-    routing. The weights are stacked over experts,
+    routing where every expert is held. The weights are stacked over experts,
     `<name>.gate.w` / `<name>.up.w` [experts, width, expert_size] and
     `<name>.down.w` [experts, expert_size, width]; `param_attr` gives their
     initializer.
@@ -1533,7 +1533,13 @@ def moe_experts(input, routing, num_experts, expert_size, param_attr=None,
     the part those experts give, and assignments to the others add nothing
     here, forward or backward. Still dropless for the held experts: the rows
     of the layout are the worst case `tokens x k + experts_held x 128`,
-    which every routing fits (`ops/moe.py::_dispatch_share`)."""
+    which every routing fits (`ops/moe.py::_dispatch_share`). What is done
+    with them follows the routing: the grouped kernels visit the tiles the
+    held groups use, and dispatch, combine and their grads move those rows
+    only, a chunk at a time, so a share's time grows with the assignments
+    that fall on it (at one chip's even share ~6% of the rows are used; with
+    every assignment on a held expert the movements cost 1.3 times what
+    static gathers over all the rows would)."""
     from ..ops.moe import ROW_TILE
     from ..param_attr import ParamAttr
     helper = LayerHelper("moe_experts", **locals())
@@ -1585,9 +1591,12 @@ def moe_experts(input, routing, num_experts, expert_size, param_attr=None,
     hidden = swiglu(grouped(x_sorted, w_gate), grouped(x_sorted, w_up))
     y_sorted = grouped(hidden, w_down)
     out = new(dtype)
+    # under a share the movements follow the held groups, as the kernels do
+    used = {"GroupSizes": [sizes.name]} if share else {}
     helper.append_op("moe_combine",
                      inputs={"Y": [y_sorted.name],
                              "TopKWeight": [routing["weight"].name],
-                             "Slot": [slot.name], "Source": [source.name]},
+                             "Slot": [slot.name], "Source": [source.name],
+                             **used},
                      outputs={"Out": [out.name]}, attrs=dict(share))
     return out

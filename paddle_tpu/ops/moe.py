@@ -29,8 +29,8 @@ would run again inside the generic vjp grad op, so `grouped_matmul`
 registers its own grad: the forward kernels run once a step. On a CPU
 backend `ragged_dot` is the path (the kernel only under the Pallas
 interpreter, PADDLE_TPU_PALLAS_INTERPRET=1). Dispatch and combine are
-permutations: their hand-written grads gather through the inverse
-permutation instead of scatter-adding.
+permutations where every expert is held: their hand-written grads gather
+through the inverse permutation instead of scatter-adding.
 
 Under a share (`moe_dispatch` with `experts_held`: one chip of an
 expert-parallel layer; the router still chooses among all E experts and the
@@ -40,21 +40,38 @@ nobody chose), from row 0 on. The rows are static and the worst case, N*k +
 held*ROW_TILE: every routing fits, so nothing is ever dropped and nothing
 has to be checked; what the held groups do not use lies behind them (the
 rule notes the count on the program's compile event, `moe_row_buffer_rows`).
-`GroupSizes` sums to what is used, and the kernels' grid is as long as the
-tiles they visit (megablox counts them from the group sizes), so the work
-follows the held assignments and the unused rows are never written: they
-hold whatever was in memory. Nothing does arithmetic on them, not even times
-a zero weight (0 x NaN is NaN): a row's `Source` and an assignment's `Slot`
-are -1 where there is nothing, and both are applied with a select.
+`GroupSizes` sums to what is used, and everything that touches a row follows
+it. The kernels' grid is as long as the tiles they visit (megablox counts
+them from the group sizes). The four row movements (dispatch, combine and
+their grads) are `lax.while_loop`s over the used rows, `_MOVE_ROWS` a step,
+`sum(GroupSizes) / _MOVE_ROWS` steps (`_over_used_rows`; each op counts
+itself on the compile event, `moe_share_bounded_moves`): the two that write
+the layout gather a chunk's rows by token and write them in place
+(`_dispatch_share`, which finds each chunk's `Source` in the same step, and
+`_combine_share_grad`), the two that read it add a
+chunk's rows to their tokens in a float32 accumulator (`_tokens_from_rows`:
+a token's experts are summed in expert order, the same order every run).
+So the step's time follows the held assignments: at the even load of one
+chip in sixteen (4096 tokens, top 10 of 512, 32 held: ~2560 assignments in
+4096-4224 used rows of 45056) the four movements of a layer take 1.7 ms
+alone on a v5e where gathers over the static rows took 9.1; with every
+assignment on a held expert (40960, the worst case) they take 12.3 ms
+against 9.1 (PERF.md section 6, PR 37). The rows behind the used ones are
+never written and never read: their buffers are allocated, not filled, and
+hold whatever was in memory. Nothing does arithmetic on them or on a padding
+row that reaches a result, not even times a zero weight (0 x NaN is NaN): a
+row's `Source` and an assignment's `Slot` are -1 where there is nothing, and
+a movement applies `Source` with a select or drops the row by an index out
+of range.
 
-Two ways to read a token's k rows back (`_rows_of_slots`): token-major
-`[N, k, D]` where every expert is held, slot-major `[k, N, D]` with the
-select under a share. Each is the faster one on its side (measured both
-ways on the chip, PERF.md section 6, PR 34), so both stay, chosen by the
-attribute.
+Where every expert is held all N*k assignments have a row and the movements
+are static gathers, token-major `[N, k, D]` (`_rows_of_slots`): the faster
+layout there (PERF.md section 6, PR 34). The attribute chooses.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +91,8 @@ ROW_TILE = 128
 # float32 over a group's row tiles, _TGMM_BLOCK of it a step.
 _GMM_BLOCK = 2048 * 1024
 _TGMM_BLOCK = (1024, 1024)
+# Rows a step of a share's row movement carries (`_over_used_rows`).
+_MOVE_ROWS = 512
 
 
 @register_op("moe_router", propagate_seqlen=False)
@@ -120,6 +139,7 @@ def _moe_dispatch(ctx, X, TopKIndex, TokensPerExpert):
     if ctx.attr("experts_held") is not None:
         held = int(ctx.attr("experts_held"))
         ctx.note(moe_row_buffer_rows=n * k + held * tile)
+        ctx.tally("moe_share_bounded_moves")
         return _dispatch_share(X, TopKIndex, TokensPerExpert, tile,
                                int(ctx.attr("first_expert")), held)
     counts = TokensPerExpert.astype(jnp.int32)
@@ -143,6 +163,19 @@ def _moe_dispatch(ctx, X, TopKIndex, TokensPerExpert):
             "Slot": slot, "Source": source, "GroupSizes": sizes}
 
 
+def _over_used_rows(sizes, rows, step, init):
+    """`step(start, chunk, carry)` for every chunk of the rows that the held
+    groups of a share use, in ascending order: `chunk` rows from `start` on,
+    as many chunks as `sizes` (whole tiles, from row 0 on) reach into. A
+    `lax.while_loop`: its trip count is the routing's, as the grouped
+    kernels' grid is. `chunk` divides the buffer's `rows`, so the last chunk
+    never leaves it; what it holds behind the used rows has `Source` -1."""
+    chunk = math.gcd(rows, _MOVE_ROWS)
+    steps = -(-jnp.sum(sizes.astype(jnp.int32)) // chunk)
+    return lax.fori_loop(
+        0, steps, lambda i, carry: step(i * chunk, chunk, carry), init)
+
+
 def _dispatch_share(X, TopKIndex, TokensPerExpert, tile, first, held):
     """The layout of one chip's share: the router chose among all E experts,
     this chip holds experts `first .. first + held - 1`. Only assignments to
@@ -150,11 +183,11 @@ def _dispatch_share(X, TopKIndex, TokensPerExpert, tile, first, held):
     tiles (none for an expert nobody chose), from row 0 on, in N*k +
     held*tile rows: the worst case (every assignment on a held expert, a
     partly filled tile a group), so every routing fits. `GroupSizes` [held]
-    sums to what is used, and the grouped kernels visit those tiles only.
-    The rows after them are never written by anybody, so nothing may do
-    arithmetic on them: `Source` is -1 there and in padding, `Slot` is -1
-    for an assignment to an expert that lives elsewhere, and both are
-    applied with a select."""
+    sums to what is used, and the grouped kernels and the row movements
+    visit those rows only. The rows after them are never written by
+    anybody, so nothing may do arithmetic on them: `Source` is -1 there and
+    in padding (the movements go by it), `Slot` is -1 for an assignment to
+    an expert that lives elsewhere."""
     n, k = TopKIndex.shape
     counts = TokensPerExpert.astype(jnp.int32)[first:first + held]
     sizes = -(-counts // tile) * tile
@@ -166,38 +199,69 @@ def _dispatch_share(X, TopKIndex, TokensPerExpert, tile, first, held):
     iota = lax.iota(jnp.int32, n * k)
     expert, order = lax.sort_key_val(jnp.where(inside, local, held), iota,
                                      is_stable=True)
-    shift = (ends - sizes) - (packed_ends - counts)
+    starts, packed_starts = ends - sizes, packed_ends - counts
     _, slot = lax.sort_key_val(
         order, jnp.where(expert < held, iota + jnp.take(
-            shift, jnp.minimum(expert, held - 1)), -1))
-    row = lax.iota(jnp.int32, n * k + held * tile)
-    group = jnp.sum(row[:, None] >= ends[None, :-1], axis=1, dtype=jnp.int32)
-    rank = row - jnp.take(ends - sizes, group)
-    filled = rank < jnp.take(counts, group)
-    packed = jnp.take(packed_ends - counts, group) + rank
-    source = jnp.where(filled,
-                       jnp.take(order, jnp.where(filled, packed, 0)), -1)
-    x_rows = jnp.take(X, jnp.maximum(source, 0) // k, axis=0)
-    return {"XSorted": jnp.where(filled[:, None], x_rows, 0),
-            "Slot": slot, "Source": source, "GroupSizes": sizes}
+            starts - packed_starts, jnp.minimum(expert, held - 1)), -1))
+    rows = n * k + held * tile
+
+    def step(start, chunk, carry):
+        """What rows start .. start + chunk hold, and their rows of X."""
+        x_sorted, source = carry
+        row = (start + lax.iota(jnp.int32, chunk))[:, None]
+        # a row's group picked out of the `held` by a masked sum (1-D
+        # gathers from their tables cost more than moving the rows); a row
+        # behind the used ones lies in no group and reads 0: not filled
+        in_group = (row >= starts[None, :]) & (row < ends[None, :])
+
+        def of_group(table):
+            return jnp.sum(jnp.where(in_group, table[None, :], 0), axis=1)
+
+        rank = row[:, 0] - of_group(starts)
+        filled = rank < of_group(counts)
+        packed = of_group(packed_starts) + rank
+        src = jnp.where(filled,
+                        jnp.take(order, jnp.where(filled, packed, 0)), -1)
+        x_rows = jnp.take(X, jnp.maximum(src, 0) // k, axis=0)
+        return (lax.dynamic_update_slice(
+            x_sorted, jnp.where(filled[:, None], x_rows, 0), (start, 0)),
+                lax.dynamic_update_slice(source, src, (start,)))
+
+    # the rows behind the used ones are not visited: `lax.empty` is an
+    # allocation on a TPU (zeros on the CPU backend) and they keep what it
+    # held; their `Source` is the fill's -1
+    x_sorted, source = _over_used_rows(
+        sizes, rows, step, (lax.empty((rows, X.shape[1]), X.dtype),
+                            jnp.full((rows,), -1, jnp.int32)))
+    return {"XSorted": x_sorted, "Slot": slot, "Source": source,
+            "GroupSizes": sizes}
 
 
-def _rows_of_slots(ctx, rows, slot, n, k):
-    """rows [M, D] at the k slots of n tokens, and the axis the k slots lie
-    on. Every expert held: `[n, k, D]`, 1. Under a share (the attribute
-    `experts_held`): `[k, n, D]`, 0, and an assignment to an expert that
-    lives elsewhere (slot -1) reads zeros, by a select. Slot-major there
-    because a `[n, k, D]` array whose k is no multiple of 8 (ten experts a
-    token) is laid out in padded tiles on a TPU, and the reshape into it is a
-    copy of every gathered row; `[k, n, D]` is free (-18.4 ms a step at k =
-    10). Token-major where every expert is held because it is the faster
-    one there: at k = 8 slot-major takes 1.9 ms more of a 66.9 ms step
-    (chip runs, PERF.md section 6, PR 34)."""
-    if ctx.attr("experts_held") is None:
-        return jnp.take(rows, slot, axis=0).reshape(n, k, -1), 1
-    slot = slot.reshape(n, k).T.reshape(-1)
-    per_slot = jnp.take(rows, jnp.maximum(slot, 0), axis=0)
-    return jnp.where((slot >= 0)[:, None], per_slot, 0).reshape(k, n, -1), 0
+def _tokens_from_rows(moved, source, k, n, sizes, scale=None):
+    """A share's token-side movement, [n, width] in float32: every used row
+    r of `moved` (times `scale` [N*k] at r's assignment) added to its
+    token's row, in row order: by expert, then by token, the same in every
+    run. A padding row's token is `n`, out of range, and is dropped by its
+    index; it is not multiplied by a zero."""
+    rows, width = moved.shape
+
+    def step(start, chunk, acc):
+        src = lax.dynamic_slice(source, (start,), (chunk,))
+        value = lax.dynamic_slice(moved, (start, 0), (chunk, width)) \
+            .astype(jnp.float32)
+        if scale is not None:
+            value = value * jnp.take(scale, jnp.maximum(src, 0))[:, None]
+        return acc.at[jnp.where(src >= 0, src // k, n)].add(value,
+                                                            mode="drop")
+
+    return _over_used_rows(sizes, rows, step,
+                           jnp.zeros((n, width), jnp.float32))
+
+
+def _rows_of_slots(rows, slot, n, k):
+    """rows [M, D] at the k slots of n tokens, `[n, k, D]`: where every
+    expert is held, every slot has a row and all N*k of them are read."""
+    return jnp.take(rows, slot, axis=0).reshape(n, k, -1)
 
 
 @register_grad("moe_dispatch")
@@ -206,9 +270,14 @@ def _moe_dispatch_grad(ctx, ins, out_grads):
     g = out_grads["XSorted"][0]
     if g is None:
         return {}
-    per_slot, axis = _rows_of_slots(ctx, g, ctx.fwd_outs["Slot"][0],
-                                    *index.shape)
-    return {"X": jnp.sum(per_slot.astype(jnp.float32), axis=axis)
+    n, k = index.shape
+    if ctx.attr("experts_held") is not None:
+        ctx.tally("moe_share_bounded_moves")
+        d_x = _tokens_from_rows(g, ctx.fwd_outs["Source"][0], k, n,
+                                ctx.fwd_outs["GroupSizes"][0])
+        return {"X": d_x.astype(X.dtype)}
+    per_slot = _rows_of_slots(g, ctx.fwd_outs["Slot"][0], n, k)
+    return {"X": jnp.sum(per_slot.astype(jnp.float32), axis=1)
             .astype(X.dtype)}
 
 
@@ -285,16 +354,54 @@ def _grouped_matmul_grad(ctx, ins, out_grads):
 
 
 @register_op("moe_combine", propagate_seqlen=False)
-def _moe_combine(ctx, Y, TopKWeight, Slot, Source):
+def _moe_combine(ctx, Y, TopKWeight, Slot, Source, GroupSizes=None):
     """Y [rows, D] in `moe_dispatch`'s layout -> Out [N, D]: each token's k
     expert results times its k router weights, summed in float32. Under a
-    share (the attribute `experts_held`) an assignment to an expert that
-    lives elsewhere adds nothing."""
-    per_slot, axis = _rows_of_slots(ctx, Y, Slot, *TopKWeight.shape)
-    weight = jnp.moveaxis(TopKWeight.astype(jnp.float32), 1, axis)
-    out = jnp.sum(per_slot.astype(jnp.float32) * weight[:, :, None],
-                  axis=axis)
+    share (the attribute `experts_held`; `GroupSizes` is given then) the
+    used rows are added to their tokens (`_tokens_from_rows`): an assignment
+    to an expert that lives elsewhere has no row and adds nothing."""
+    n, k = TopKWeight.shape
+    weight = TopKWeight.astype(jnp.float32)
+    if ctx.attr("experts_held") is not None:
+        ctx.tally("moe_share_bounded_moves")
+        out = _tokens_from_rows(Y, Source, k, n, GroupSizes,
+                                scale=weight.reshape(-1))
+        return {"Out": out.astype(Y.dtype)}
+    per_slot = _rows_of_slots(Y, Slot, n, k)
+    out = jnp.sum(per_slot.astype(jnp.float32) * weight[:, :, None], axis=1)
     return {"Out": out.astype(Y.dtype)}
+
+
+def _combine_share_grad(Y, weight, source, sizes, g):
+    """`moe_combine`'s gradients under a share, over the used rows: dY[r] =
+    w(r) g[token of r], and `dot(Y[r], g[token of r])` is the weight
+    gradient of r's assignment (an assignment without a row keeps 0). A
+    padding row writes zeros and its assignment is N*k, dropped by index."""
+    n, k = weight.shape
+    rows, width = Y.shape
+    w_flat = weight.reshape(-1).astype(jnp.float32)
+
+    def step(start, chunk, carry):
+        d_y, d_w = carry
+        src = lax.dynamic_slice(source, (start,), (chunk,))
+        held = jnp.maximum(src, 0)
+        # gather in the incoming dtype (bf16 under AMP), widen afterwards
+        g_rows = jnp.take(g, held // k, axis=0).astype(jnp.float32)
+        y_rows = lax.dynamic_slice(Y, (start, 0), (chunk, width))
+        # a padding row's assignment: out of range, and no two alike
+        absent = n * k + lax.iota(jnp.int32, chunk)
+        d_w = d_w.at[jnp.where(src >= 0, src, absent)].set(
+            jnp.sum(y_rows.astype(jnp.float32) * g_rows, axis=-1),
+            mode="drop", unique_indices=True)
+        d_rows = jnp.where((src >= 0)[:, None],
+                           g_rows * jnp.take(w_flat, held)[:, None], 0)
+        return lax.dynamic_update_slice(d_y, d_rows.astype(Y.dtype),
+                                        (start, 0)), d_w
+
+    d_y, d_w = _over_used_rows(
+        sizes, rows, step, (lax.empty((rows, width), Y.dtype),
+                            jnp.zeros((n * k,), jnp.float32)))
+    return d_y, d_w.reshape(n, k)
 
 
 @register_grad("moe_combine")
@@ -305,11 +412,14 @@ def _moe_combine_grad(ctx, ins, out_grads):
     if g is None:
         return {}
     n, k = weight.shape
-    per_slot, axis = _rows_of_slots(ctx, Y, slot, n, k)
-    d_weight = jnp.moveaxis(
-        jnp.sum(per_slot.astype(jnp.float32)
-                * jnp.expand_dims(g.astype(jnp.float32), axis), axis=-1),
-        axis, 1)
+    if ctx.attr("experts_held") is not None:
+        ctx.tally("moe_share_bounded_moves")
+        d_y, d_weight = _combine_share_grad(Y, weight, source,
+                                            ins["GroupSizes"][0], g)
+        return {"Y": d_y, "TopKWeight": d_weight.astype(weight.dtype)}
+    per_slot = _rows_of_slots(Y, slot, n, k)
+    d_weight = jnp.sum(per_slot.astype(jnp.float32)
+                       * jnp.expand_dims(g.astype(jnp.float32), 1), axis=-1)
     held = jnp.maximum(source, 0)
     # a padding row's weight is 0: no slot read its result
     w_row = jnp.where(source >= 0, jnp.take(

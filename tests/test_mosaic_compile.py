@@ -65,3 +65,32 @@ def test_gdn_kernels_compile_at_the_cells_shapes(one_chip, monkeypatch):
         q, q, v, g, g, states, v).compile()
     (call,) = _custom_calls(bwd, "gdn_bwd")
     assert "(f32[1,32,64,1,64]{" in call and "tpu_custom_call" in call
+
+
+def test_share_movements_compile_at_the_cells_shapes(one_chip):
+    """A share's layout and a token-side movement as `qwen3_next_80b_a3b.bs1`
+    runs them (4096 tokens, top 10 of 512, experts 64..95 held, 2048 wide):
+    `lax.while_loop`s over the used rows, the 45056-row buffer an
+    `AllocateBuffer` that the loop takes as it is (no fill, no copy: the
+    program needs no temporary of the buffer's size beside its result)."""
+    from paddle_tpu.ops import moe
+    n, k, width, held = 4096, 10, 2048, 32
+    rows = n * k + held * moe.ROW_TILE
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    layout = jax.jit(lambda x, index, counts: moe._dispatch_share(
+        x, index, counts, moe.ROW_TILE, 64, held)).lower(
+            arg((n, width), jnp.bfloat16), arg((n, k), jnp.int32),
+            arg((512,), jnp.int32)).compile()
+    text = layout.as_text()
+    assert 'custom_call_target="AllocateBuffer"' in text and " while(" in text
+    assert layout.memory_analysis().temp_size_in_bytes < rows * width * 2
+    combine = jax.jit(lambda y, source, sizes, weight: moe._tokens_from_rows(
+        y, source, k, n, sizes, scale=weight)).lower(
+            arg((rows, width), jnp.bfloat16), arg((rows,), jnp.int32),
+            arg((held,), jnp.int32), arg((n * k,), jnp.float32)).compile()
+    assert " while(" in combine.as_text()
+    # no 40960-row gather of the layout is left in either
+    assert f"[{n * k},{width}]" not in text + combine.as_text()

@@ -7,6 +7,7 @@ float32, AMP off unless a test says otherwise."""
 
 import filecmp
 import hashlib
+import math
 import os
 
 import jax
@@ -18,6 +19,7 @@ import paddle_tpu as fluid
 from paddle_tpu import layers, models, observe
 from paddle_tpu.core import ir
 from paddle_tpu.ops import linear_attention as la
+from paddle_tpu.ops import moe
 
 import qwen3_next_reference as ref
 from test_olmoe import rel_err, run_piece
@@ -475,6 +477,164 @@ def test_share_layout_follows_the_held_assignments():
     assert np.all(source[sizes.sum():] == -1)
 
 
+def _held_weights(rng):
+    return {"e.gate.w": rng.randn(HELD, D, F).astype(np.float32) * .3,
+            "e.up.w": rng.randn(HELD, D, F).astype(np.float32) * .3,
+            "e.down.w": rng.randn(HELD, F, D).astype(np.float32) * .3}
+
+
+def _on_a_chunks_edge(rng, n, groups):
+    """n tokens, each on the four held experts 8..11 once, then one choice
+    of token 0 bent away from every group that is to be one short: to
+    expert 11 if that is to be one over, else to an expert held elsewhere."""
+    index = np.stack([rng.permutation(4) + 8 for _ in range(n)])
+    for expert, count in zip(range(8, 12), groups):
+        if count < n:
+            index[0, list(index[0]).index(expert)] = 11 if n + 1 in groups \
+                else 0
+    return index.astype(np.int32)
+
+
+@pytest.mark.parametrize("groups,used", [
+    ([128, 128, 128, 127], 512),    # one row short of the first chunk's end
+    ([128, 128, 128, 128], 512),    # exactly on it
+    ([127, 128, 128, 129], 640),    # one row into the next chunk
+], ids=["one_short", "on_the_edge", "one_over"])
+def test_held_rows_that_end_at_a_chunks_edge_are_all_moved(groups, used):
+    """The movements go over the used rows a chunk at a time
+    (`ops/moe.py::_over_used_rows`): 512 rows a chunk here, and the held
+    rows end at row 510, at row 511 and at row 512 of the layout. Result
+    and gradients are the loop's over the held experts."""
+    n = 128
+    assert math.gcd(n * K + HELD * moe.ROW_TILE, moe._MOVE_ROWS) == 512
+    rng = np.random.RandomState(11)
+    x = rng.randn(n, D).astype(np.float32)
+    index = _on_a_chunks_edge(rng, n, groups)
+    assert [int(np.sum(index == e)) for e in range(8, 12)] == groups
+    assert sum(-(-g // 128) * 128 for g in groups) == used
+    weight = rng.uniform(0.05, 0.4, (n, K)).astype(np.float32)
+    weights = _held_weights(rng)
+    (y,), grads, probe = _share_on_given_routing(index, weight, x, weights,
+                                                 8)
+
+    def want(x, weight, p):
+        out = jnp.zeros_like(x)
+        for e in range(HELD):
+            mask = jnp.sum(jnp.where(index == 8 + e, weight, 0), -1,
+                           keepdims=True)
+            hidden = jax.nn.silu(x @ p["e.gate.w"][e]) * (x @ p["e.up.w"][e])
+            out = out + mask * (hidden @ p["e.down.w"][e])
+        return out
+
+    with jax.default_matmul_precision("highest"):
+        assert rel_err(y, want(x, weight, weights)) < RTOL
+        gx, gweight, gp = jax.grad(
+            lambda a, b, c: jnp.sum(want(a, b, c) * probe), (0, 1, 2))(
+                x, weight, weights)
+    assert rel_err(grads["x"], gx) < RTOL
+    assert rel_err(grads["weight"], gweight) < RTOL
+    for name in weights:
+        assert rel_err(grads[name], gp[name]) < RTOL, name
+
+
+def test_two_runs_of_one_step_give_the_same_bits():
+    """The token-side sums add a token's rows in the layout's order, expert
+    by expert: nothing is accumulated in an order that a run chooses."""
+    rng = np.random.RandomState(12)
+    n = 160
+    x = rng.randn(n, D).astype(np.float32)
+    index = _uneven(rng, n).astype(np.int32)
+    index[::3, 0] = 2               # some choices on experts held elsewhere
+    weight = rng.uniform(0.05, 0.4, (n, K)).astype(np.float32)
+    weights = _held_weights(rng)
+    runs = [_share_on_given_routing(index, weight, x, weights, 8)
+            for _ in range(2)]
+    assert np.array_equal(runs[0][0][0], runs[1][0][0])
+    assert np.any(runs[0][0][0])
+    for name, g in runs[0][1].items():
+        assert np.array_equal(g, runs[1][1][name]), name
+
+
+def _share_movements(n, index, feed):
+    """`moe_dispatch` and `moe_combine` of a share with nothing between
+    them: the combine reads a fed `y` in the layout, the dispatch's rows
+    meet a fed probe `p_rows`, so a test chooses what lies in the rows that
+    no held group uses. Returns Out, the gradients of x, y and weight, and
+    the used rows."""
+    from paddle_tpu.layer_helper import LayerHelper
+    rows = n * K + HELD * 128
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        d = {name: layers.data(name=name, shape=shape, dtype=dtype,
+                               append_batch_size=False,
+                               stop_gradient=dtype != "float32")
+             for name, shape, dtype in (
+                 ("x", [n, D], "float32"), ("weight", [n, K], "float32"),
+                 ("y", [rows, D], "float32"), ("index", [n, K], "int32"),
+                 ("counts", [16], "int32"), ("p_rows", [rows, D], "float32"),
+                 ("p_out", [n, D], "float32"))}
+        helper = LayerHelper("share_movements")
+        new = helper.create_variable_for_type_inference
+        x_sorted, out = new("float32"), new("float32")
+        slot, source, sizes = (new("int32", stop_gradient=True)
+                               for _ in range(3))
+        share = {"first_expert": 8, "experts_held": HELD}
+        helper.append_op(
+            "moe_dispatch",
+            inputs={"X": [d["x"].name], "TopKIndex": [d["index"].name],
+                    "TokensPerExpert": [d["counts"].name]},
+            outputs={"XSorted": [x_sorted.name], "Slot": [slot.name],
+                     "Source": [source.name], "GroupSizes": [sizes.name]},
+            attrs={"row_tile": 128, **share})
+        helper.append_op(
+            "moe_combine",
+            inputs={"Y": [d["y"].name], "TopKWeight": [d["weight"].name],
+                    "Slot": [slot.name], "Source": [source.name],
+                    "GroupSizes": [sizes.name]},
+            outputs={"Out": [out.name]}, attrs=share)
+        loss = layers.elementwise_add(
+            layers.reduce_sum(layers.elementwise_mul(x_sorted, d["p_rows"])),
+            layers.reduce_sum(layers.elementwise_mul(out, d["p_out"])))
+        fluid.append_backward(loss)
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = fluid.Scope()
+    exe.run(startup, scope=scope)
+    counts = np.bincount(index.reshape(-1), minlength=16).astype(np.int32)
+    got = exe.run(main, feed={**feed, "index": index, "counts": counts},
+                  fetch_list=[out.name, "x@GRAD", "y@GRAD", "weight@GRAD",
+                              sizes.name], scope=scope)
+    return got[:4], int(np.sum(got[4]))
+
+
+def test_rows_that_no_held_group_uses_are_never_read():
+    """NaN in every row of `Y` and of `dXSorted` behind the used tiles: the
+    output and every gradient are finite, and bit for bit the clean run's.
+    (Nothing multiplies those rows by a zero; no movement visits them.)"""
+    rng = np.random.RandomState(13)
+    n = 96
+    rows = n * K + HELD * 128
+    index = np.stack([rng.choice(16, K, replace=False) for _ in range(n)]) \
+        .astype(np.int32)
+    feed = {"x": rng.randn(n, D).astype(np.float32),
+            "weight": rng.uniform(0.05, 0.4, (n, K)).astype(np.float32),
+            "y": rng.randn(rows, D).astype(np.float32),
+            "p_rows": rng.randn(rows, D).astype(np.float32),
+            "p_out": rng.randn(n, D).astype(np.float32)}
+    clean, used = _share_movements(n, index, feed)
+    assert 0 < used < rows - 128
+    poisoned = dict(feed, y=feed["y"].copy(), p_rows=feed["p_rows"].copy())
+    poisoned["y"][used:] = np.nan
+    poisoned["p_rows"][used:] = np.nan
+    dirty, _ = _share_movements(n, index, poisoned)
+    for name, a, b in zip(("out", "d_x", "d_y", "d_weight"), clean, dirty):
+        assert np.all(np.isfinite(b)), name
+        assert np.array_equal(a, b), name
+        assert np.any(a), name
+    # a weight's gradient where its assignment has a row, and only there
+    held = (index >= 8) & (index < 12)
+    assert np.array_equal(clean[3] != 0, held)
+
+
 # -- the whole tiny model ----------------------------------------------------------
 
 def _program(optimizer=None, **sizes):
@@ -655,6 +815,9 @@ def test_compile_event_carries_the_census():
     # noted by `moe_dispatch`'s rule under the trace: 2 x 128 tokens x 4
     # choices + 4 held experts x 128
     assert detail["moe_row_buffer_rows"] == 2 * 128 * 4 + 4 * 128
+    # dispatch, combine and their grads in each of the four layers, lowered
+    # over the rows the held groups use (`ops/moe.py::_over_used_rows`)
+    assert detail["moe_share_bounded_moves"] == 4 * 4
     assert detail["grad_fanin_max"] == 1
     # the startup program has neither mixers nor experts
     assert "layer_kinds" not in observe.observatory().latest(
@@ -780,6 +943,17 @@ def test_olmoe_program_is_unchanged_op_for_op():
             n_expert=8, top_k=2, d_expert=32)
         fluid.optimizer.Adam(learning_rate=1e-3).minimize(fetches["loss"])
     assert _program_digest(main) == OLMOE_DIGEST
+    # and its movements are the static ones: every row is an assignment
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    rng = np.random.RandomState(0)
+    exe.run(main, feed={n: rng.randint(0, 128, (1, 128)).astype(np.int64)
+                        for n in feeds}, fetch_list=[fetches["loss"]],
+            scope=scope)
+    detail = observe.observatory().latest(main._uid).detail
+    assert "moe_share_bounded_moves" not in detail
+    assert "moe_row_buffer_rows" not in detail
 
 
 OLMOE_DIGEST = (207, "66f91545d51fe5df3453129dda39ac43"
