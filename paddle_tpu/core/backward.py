@@ -231,29 +231,71 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     """What kinds of mixer and expert layer the program holds, read back
     from the global block's forward ops: `layer_kinds`, the layers by their
     mixer (`linear_attention`: a `gated_delta_rule` op, `full_attention`: a
-    `fused_attention` op); and where it has expert layers,
-    `moe_experts_routed` (the router's width) and `moe_experts_held` (the
-    experts whose weights live here: fewer under a share). Empty for a
-    program with neither. (`moe_row_buffer_rows`, the rows of the expert
-    layer's layout, follows the batch: `moe_dispatch`'s rule notes it on
-    the same event under the trace, `LoweringContext.note`.)"""
+    `fused_attention` op, `latent_attention`: a `fused_attention` whose value
+    heads are narrower or wider than its key heads, with
+    `attention_qk_width` and `attention_value_width` beside it);
+    `dense_ffn_layers`, the `swiglu` feed-forwards built under a
+    `name_scope` that holds no router, where the program has expert layers
+    too; and where it has those,
+    `moe_experts_routed` (the router's width), `moe_experts_held` (the
+    experts whose weights live here: fewer under a share),
+    `moe_router_score` where the router's scores are not a softmax, and
+    `moe_router_bias_updates`, the routers whose selection bias a later op
+    of the step writes again, with `moe_router_bias_vars`, for each of them
+    the bias and the persistable variable that keeps the step's counts
+    (what the step log reads). Empty for a program with neither.
+    (`moe_row_buffer_rows`, the rows of the expert layer's layout, follows
+    the batch: `moe_dispatch`'s rule notes it on the same event under the
+    trace, `LoweringContext.note`.)"""
     block = program.global_block()
-    kinds = {"linear_attention": 0, "full_attention": 0}
+    kinds = {"linear_attention": 0, "full_attention": 0,
+             "latent_attention": 0}
     out: Dict[str, object] = {}
+    # `assign` ops both ways: result -> what it copied, and the reverse
+    copies: Dict[str, str] = {}
+    kept: Dict[str, str] = {}
+    biases = []                     # (a router's bias, its counts)
+    gated, routed = [], set()       # name scopes of `swiglu`s, of routers
     for op in block.ops:
         if op.attrs.get("__role__") is not None:
             continue
         if op.type == "gated_delta_rule":
             kinds["linear_attention"] += 1
         elif op.type == "fused_attention":
-            kinds["full_attention"] += 1
+            wide = block.var(op.input("K")[0]).shape[-1]
+            value = block.var(op.input("V")[0]).shape[-1]
+            if wide == value:
+                kinds["full_attention"] += 1
+            else:
+                kinds["latent_attention"] += 1
+                out["attention_qk_width"] = wide
+                out["attention_value_width"] = value
+        elif op.type == "swiglu":
+            gated.append(op.attrs.get(ir.NAME_SCOPE_ATTR))
+        elif op.type == "assign":
+            copies[op.output("Out")[0]] = op.input("X")[0]
+            kept[op.input("X")[0]] = op.output("Out")[0]
         elif op.type == "moe_router":
             out["moe_experts_routed"] = block.var(op.input("W")[0]).shape[-1]
+            routed.add(op.attrs.get(ir.NAME_SCOPE_ATTR))
+            if op.attrs.get("score_func"):
+                out["moe_router_score"] = op.attrs["score_func"]
+            for name in op.inputs.get("Bias", []):
+                biases.append((copies.get(name, name),
+                               op.output("TokensPerExpert")[0]))
         elif op.type == "moe_dispatch":
             out["moe_experts_held"] = op.attrs.get(
                 "experts_held", out.get("moe_experts_routed"))
     if any(kinds.values()):
         out["layer_kinds"] = {k: n for k, n in kinds.items() if n}
+    dense = sum(1 for scope in gated if scope not in routed)
+    if routed and dense:
+        out["dense_ffn_layers"] = dense
+    updated = [[bias, kept.get(counts)] for bias, counts in biases
+               if bias in copies]
+    if updated:
+        out["moe_router_bias_updates"] = len(updated)
+        out["moe_router_bias_vars"] = updated
     return out
 
 

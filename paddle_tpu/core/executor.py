@@ -746,6 +746,9 @@ class PreparedProgram:
                 fetches, new_state = entry.run_with_state(
                     self.scope, feed_arrays, mut, const, counter, spans)
             self._state.commit(entry, self.scope, new_state)
+            if spans.observing:
+                spans.facts = _steplog.router_bias_facts(program._uid,
+                                                         new_state)
             if return_numpy:
                 # the host transfer np.asarray forces; no span with
                 # return_numpy=False — the async-dispatch overlap the fast

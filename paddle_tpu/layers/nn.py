@@ -1368,15 +1368,20 @@ def gated_rms_norm(input, gate, epsilon=1e-6, param_attr=None, name=None):
     return out
 
 
-def rotary_embedding(input, theta=10000.0, rotary_dim=None, name=None):
+def rotary_embedding(input, theta=10000.0, rotary_dim=None,
+                     interleaved=False, name=None):
     """Rotary position embedding (rotate-half convention) on
     `[batch, heads, seq, head_dim]`, positions 0..seq-1; with `rotary_dim`
-    on the first `rotary_dim` dims of a head only, the others pass through."""
+    on the first `rotary_dim` dims of a head only, the others pass through.
+    `interleaved`: the pairs are `(x[2i], x[2i + 1])`; the rotated dims come
+    out laid `[evens | odds]` (DeepSeek-V3's `rope_interleave`)."""
     helper = LayerHelper("rotary_embedding", name=name)
     out = helper.create_variable_for_type_inference(input.dtype)
     attrs = {"theta": float(theta)}
     if rotary_dim is not None:
         attrs["rotary_dim"] = int(rotary_dim)
+    if interleaved:
+        attrs["interleaved"] = True
     helper.append_op("rotary_embedding", inputs={"X": [input.name]},
                      outputs={"Out": [out.name]}, attrs=attrs)
     return out
@@ -1464,7 +1469,8 @@ def fused_attention(q, k, v, causal=False, sm_scale=None, dropout_rate=0.0,
                     is_test=False, name=None):
     """Softmax attention on `[batch, heads, seq, head_dim]` through the
     flash kernels (`ops/pallas_attention.py`): O(seq) memory, dropout on the
-    attention weights inside the kernel.
+    attention weights inside the kernel. `v` may have a head width of its
+    own (latent attention: q, k at 192, v at 128); the result has `v`'s.
 
     The op has a second output, `Lse`: the forward kernel's log-sum-exp of
     every score row, float32 `[batch * heads, 1, seq]`, the kernels' own
@@ -1487,14 +1493,34 @@ def fused_attention(q, k, v, causal=False, sm_scale=None, dropout_rate=0.0,
 
 
 def moe_router(input, num_experts, k, param_attr=None, norm_topk_prob=False,
-               name=None):
-    """Softmax top-k router over `input` [tokens, width]: float32 logits and
-    softmax over all `num_experts`, the k largest probabilities used as they
-    are, or with `norm_topk_prob` divided by their sum (over all k, whichever
-    chip holds the chosen experts). Returns a dict: `weight` and `index`
+               name=None, score_func="softmax", bias_attr=None,
+               bias_update_rate=None, norm_eps=None, scaling_factor=None):
+    """Top-k router over `input` [tokens, width]: float32 logits and scores
+    over all `num_experts` (`score_func`: "softmax" over the experts, or each
+    expert's "sigmoid"), the k largest scores used as they are, or with
+    `norm_topk_prob` divided by their sum (over all k, whichever chip holds
+    the chosen experts; plus `norm_eps` where given), then times
+    `scaling_factor` where given. Returns a dict: `weight` and `index`
     [tokens, k], `tokens_per_expert` [num_experts] (int32 counts of the
-    assignments), `probs` [tokens, num_experts] and `logsumexp` [tokens]
-    (what the load-balancing loss and the z-loss are built from)."""
+    assignments), `probs` [tokens, num_experts] (the scores) and `logsumexp`
+    [tokens] (what the load-balancing loss and the z-loss are built from).
+
+    `bias_attr` gives the router a selection bias `b` [num_experts] (the
+    `e_score_correction_bias` of DeepSeek-V3's `noaux_tc` routing; dict key
+    `bias`): the experts are chosen by `score + b`, the weights are the
+    scores without it. `b` is a float32 persistable variable that starts at 0
+    and is not trained: no gradient, no optimizer state, float32 under AMP,
+    saved and loaded with the weights. With `bias_update_rate` gamma the step
+    itself rewrites it from this step's counts c, after the choice and
+    outside the gradient: `b <- b + gamma * sign(mean(c) - c)`, an expert with
+    more than the mean load becomes less likely to be chosen. The router
+    reads a copy taken before the update, so the backward pass, which reads
+    the scope's values, differentiates the choice the forward pass made.
+    The step's counts stay behind in `<bias name>.load` (int32, persistable,
+    dict key `load`): what the step log reads when `observe` is on."""
+    from ..param_attr import ParamAttr
+    from . import ops as _ops
+    from . import tensor as _tensor
     helper = LayerHelper("moe_router", **locals())
     w = helper.create_parameter(param_attr, [input.shape[-1], num_experts],
                                 "float32")
@@ -1506,12 +1532,46 @@ def moe_router(input, num_experts, k, param_attr=None, norm_topk_prob=False,
     attrs = {"k": int(k)}
     if norm_topk_prob:
         attrs["norm_topk_prob"] = True
-    helper.append_op("moe_router", inputs={"X": [input.name], "W": [w.name]},
+    if score_func != "softmax":
+        attrs["score_func"] = str(score_func)
+    if norm_eps is not None:
+        attrs["norm_eps"] = float(norm_eps)
+    if scaling_factor is not None:
+        attrs["scaling_factor"] = float(scaling_factor)
+    inputs = {"X": [input.name], "W": [w.name]}
+    bias = None
+    if bias_attr is not None:
+        attr = ParamAttr._to_attr(bias_attr)
+        attr.trainable = False
+        bias = helper.create_parameter(
+            attr, [num_experts], "float32", stop_gradient=True,
+            default_initializer=init.ConstantInitializer(0.0))
+        chosen_by = _tensor.assign(bias)
+        chosen_by.stop_gradient = True
+        inputs["Bias"] = [chosen_by.name]
+    helper.append_op("moe_router", inputs=inputs,
                      outputs={s: [v.name] for s, v in outs.items()},
                      attrs=attrs)
-    return {"weight": outs["TopKWeight"], "index": outs["TopKIndex"],
-            "tokens_per_expert": outs["TokensPerExpert"],
-            "probs": outs["Probs"], "logsumexp": outs["LogSumExp"]}
+    routing = {"weight": outs["TopKWeight"], "index": outs["TopKIndex"],
+               "tokens_per_expert": outs["TokensPerExpert"],
+               "probs": outs["Probs"], "logsumexp": outs["LogSumExp"]}
+    if bias is not None:
+        routing["bias"] = bias
+        if bias_update_rate:
+            counts = _tensor.cast(outs["TokensPerExpert"], "float32")
+            over = _ops.sign(_ops.elementwise_sub(counts, reduce_mean(counts)))
+            moved = _tensor.sums([chosen_by,
+                                  scale(over, scale=-float(bias_update_rate))])
+            _tensor.assign(moved, output=bias)
+            load = helper.create_global_variable(
+                name=bias.name + ".load", shape=[num_experts], dtype="int32",
+                persistable=True)
+            helper.set_variable_initializer(load,
+                                            init.ConstantInitializer(0))
+            load.stop_gradient = True
+            routing["load"] = _tensor.assign(outs["TokensPerExpert"],
+                                             output=load)
+    return routing
 
 
 def moe_experts(input, routing, num_experts, expert_size, param_attr=None,

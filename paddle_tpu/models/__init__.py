@@ -7,7 +7,11 @@ Ouro: a looped decoder LM (the first model that uses a weight more than once
 a step: one stack of layers applied four times, four heads, an exit gate),
 and Qwen3-Next: gated-delta-rule linear-attention layers beside gated softmax
 attention, over one chip's share of a renormalised top-k expert layer with a
-shared expert (the first model built for a share of a stated deployment)."""
+shared expert (the first model built for a share of a stated deployment),
+and Kanana-2: latent attention (MLA, query/key heads of 192 over value heads
+of 128 through the flash kernels), a leading dense layer, and a sigmoid
+router with a selection bias that the step itself rewrites (the first LM
+with non-trainable state beside its weights)."""
 
 from . import mnist  # noqa: F401
 from . import resnet  # noqa: F401
@@ -21,3 +25,4 @@ from . import tiny_lm  # noqa: F401
 from . import olmoe  # noqa: F401
 from . import ouro  # noqa: F401
 from . import qwen3_next  # noqa: F401
+from . import kanana2  # noqa: F401

@@ -97,21 +97,52 @@ span = jax.profiler.TraceAnnotation
 class StepStats:
     """Host-side phase wall times (seconds) of one run()."""
 
-    __slots__ = ("program_uid", "source", "ts", "phases", "total")
+    __slots__ = ("program_uid", "source", "ts", "phases", "total", "facts")
 
     def __init__(self, program_uid: int, source: str, ts: float,
-                 phases: Dict[str, float]):
+                 phases: Dict[str, float], facts: Optional[dict] = None):
         self.program_uid = program_uid
         self.source = source          # "executor" | "parallel"
         self.ts = ts
         self.phases = phases
         self.total = sum(phases.values())
+        # what the step left in its state that a log line is worth
+        # (`router_bias_facts`); None for a program with nothing such
+        self.facts = facts
 
     def as_dict(self) -> dict:
-        return {"program_uid": self.program_uid, "source": self.source,
-                "ts": self.ts, "total_us": round(self.total * 1e6, 2),
-                "phases_us": {k: round(v * 1e6, 2)
-                              for k, v in self.phases.items()}}
+        out = {"program_uid": self.program_uid, "source": self.source,
+               "ts": self.ts, "total_us": round(self.total * 1e6, 2),
+               "phases_us": {k: round(v * 1e6, 2)
+                             for k, v in self.phases.items()}}
+        if self.facts:
+            out.update(self.facts)
+        return out
+
+
+def router_bias_facts(program_uid: int, state) -> Optional[dict]:
+    """Where the program's routers carry a selection bias that the step
+    rewrites (`moe_router_bias_vars` on its compile event): the largest
+    `|b|` after the step and the largest and smallest count of assignments
+    an expert took in it, over all such layers, read from the step's new
+    `state` (name -> array). Reading them waits for the step, so it is asked
+    for only while `observe` is on."""
+    detail = getattr(observatory().latest(program_uid), "detail", None)
+    pairs = detail.get("moe_router_bias_vars") \
+        if isinstance(detail, dict) else None
+    if not pairs:
+        return None
+    import numpy as np
+    biases = [np.asarray(state[b]) for b, _ in pairs if b in state]
+    loads = [np.asarray(state[c]) for _, c in pairs if c in state]
+    facts = {}
+    if biases:
+        facts["router_bias_abs_max"] = float(max(np.abs(b).max()
+                                                 for b in biases))
+    if loads:
+        facts["router_load_max"] = int(max(c.max() for c in loads))
+        facts["router_load_min"] = int(min(c.min() for c in loads))
+    return facts or None
 
 
 class StepLog:
@@ -207,13 +238,13 @@ class RunSpans:
     the `observe` flag on, the same boundaries fill a `StepStats`,
     recorded after the run span has closed unless the body raised."""
 
-    __slots__ = ("observing", "program_uid", "source", "which", "_run",
-                 "_child", "_phases", "_key", "_t")
+    __slots__ = ("observing", "program_uid", "source", "which", "facts",
+                 "_run", "_child", "_phases", "_key", "_t")
 
     def __init__(self, program_uid: int, source: str, step: int):
         self.observing = _flags.get_flag("observe")
         self.program_uid, self.source = program_uid, source
-        self.which = self._child = None
+        self.which = self._child = self.facts = None
         self._run = span("paddle_tpu:run", step=step, program=program_uid,
                          source=source)
         _building.run = self
@@ -249,7 +280,8 @@ class RunSpans:
             self._tick(None)
             if exc_type is None:
                 _steplog.record(StepStats(self.program_uid, self.source,
-                                          time.time(), self._phases))
+                                          time.time(), self._phases,
+                                          self.facts))
         return False
 
 

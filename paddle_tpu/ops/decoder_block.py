@@ -1,7 +1,7 @@
 """The pieces of a 2024 decoder block that are not attention or a plain
 matmul: RMSNorm (plain, zero-centred, and gated over a head), rotary position
-embedding (on a whole head or its first dims), the silu-gated product, and a
-looped LM's exit gate.
+embedding (on a whole head or its first dims, rotate-half or interleaved
+pairs), the silu-gated product, and a looped LM's exit gate.
 
 No reference analog (the reference predates all three); the equations are
 those of the public `olmoe` / `llama`-style model code. Each is plain jnp,
@@ -63,7 +63,11 @@ def _rotary_embedding(ctx, X):
     """X `[..., T, D]` (heads already split): position t rotates the pair
     `(x[i], x[i + R/2])` by `t * theta^(-2i/R)` over the first R =
     `rotary_dim` dims of a head (all D where the attribute is absent); the
-    other D - R pass through. Positions are 0..T-1."""
+    other D - R pass through. Positions are 0..T-1. With the attribute
+    `interleaved` the pair is `(x[2i], x[2i + 1])` (DeepSeek-V3's
+    `rope_interleave`): the R dims are first laid out `[evens | odds]`, as
+    the public code does, and stay so in the output (queries and keys alike,
+    so their products agree)."""
     T, D = X.shape[-2], X.shape[-1]
     R = int(ctx.attr("rotary_dim") or D)
     if R % 2 or R > D:
@@ -72,6 +76,8 @@ def _rotary_embedding(ctx, X):
     cos, sin = rotary_tables(T, R, float(ctx.attr("theta", 10000.0)))
     x32 = X.astype(jnp.float32)
     head = x32 if R == D else x32[..., :R]
+    if ctx.attr("interleaved", False):
+        head = jnp.concatenate([head[..., 0::2], head[..., 1::2]], axis=-1)
     x1, x2 = head[..., : R // 2], head[..., R // 2:]
     out = head * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
     if R < D:

@@ -1,7 +1,11 @@
-"""Sparse-expert (mixture-of-experts) ops: a softmax top-k router and a
-dropless expert layer in four pieces.
+"""Sparse-expert (mixture-of-experts) ops: a top-k router (softmax scores, or
+sigmoid scores with a selection bias) and a dropless expert layer in four
+pieces.
 
-    router:    X [N, D], W [D, E]  ->  top-k weights / indices, counts
+    router:    X [N, D], W [D, E] (, Bias [E])  ->  top-k weights / indices,
+               counts. Scores by `score_func` (softmax over the experts;
+               sigmoid of each); with `Bias` the choice is by score + bias and
+               the weights are the scores without it (`_moe_router`)
     dispatch:  the N*k assignments sorted by expert, their rows gathered
                into groups that start and end on a row tile
     grouped_matmul (x3) + swiglu: one matmul per projection over the
@@ -96,21 +100,40 @@ _MOVE_ROWS = 512
 
 
 @register_op("moe_router", propagate_seqlen=False)
-def _moe_router(ctx, X, W):
-    """X [N, D], W [D, E]. Logits, softmax over ALL E experts and both
+def _moe_router(ctx, X, W, Bias=None):
+    """X [N, D], W [D, E]. Logits, the scores of ALL E experts and both
     router losses' inputs in float32 (AMP_F32_OPS; the product at HIGHEST,
     since a TPU's default float32 product rounds its inputs to bf16 and a
-    near-tie between experts flips on that). The k weights are the
-    probabilities as they are, or, with the attribute `norm_topk_prob`,
-    divided by their sum over all k chosen experts (wherever those live)."""
+    near-tie between experts flips on that). `Probs` is the score: the
+    softmax over the experts, or with the attribute `score_func` "sigmoid"
+    each expert's own sigmoid (DeepSeek-V3's router). The k experts are the
+    largest scores, or with `Bias` [E] (float32, no gradient) the largest of
+    `score + Bias`: the bias moves the choice alone, the k weights are the
+    chosen experts' scores without it. The weights are the scores as they
+    are, or, with `norm_topk_prob`, divided by their sum over all k chosen
+    experts (wherever those live; plus the attribute `norm_eps` where it is
+    given), and then times `scaling_factor` where that is given. A program
+    that sets none of these lowers to the ops it had."""
     k = int(ctx.attr("k"))
     logits = jnp.dot(X.astype(jnp.float32), W.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    probs = jnp.exp(logits - lse[:, None])
-    weight, index = lax.top_k(probs, k)
+    if ctx.attr("score_func", "softmax") == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        probs = jnp.exp(logits - lse[:, None])
+    if Bias is None:
+        weight, index = lax.top_k(probs, k)
+    else:
+        _, index = lax.top_k(probs + Bias.astype(jnp.float32), k)
+        weight = jnp.take_along_axis(probs, index, axis=-1)
     if ctx.attr("norm_topk_prob", False):
-        weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+        total = jnp.sum(weight, axis=-1, keepdims=True)
+        if ctx.attr("norm_eps") is not None:
+            total = total + float(ctx.attr("norm_eps"))
+        weight = weight / total
+    if ctx.attr("scaling_factor") is not None:
+        weight = weight * float(ctx.attr("scaling_factor"))
     experts = jnp.arange(W.shape[1], dtype=index.dtype)
     counts = jnp.sum(index[:, :, None] == experts, axis=(0, 1),
                      dtype=jnp.int32)

@@ -43,6 +43,16 @@ and a debt wherever the rule holds a custom call, which XLA does not merge:
 such an op pays a second call a step. `fused_attention` is the only op on a
 training path whose rule holds one, and it has its `grad_lower`.
 
+The value heads have a width of their own. Q and K are `[B, H, T, D]`, V is
+`[B, H, T, Dv]`: `Out`, `dOut`, `dV` and the output accumulators are `Dv`
+wide, Q, K, dQ, dK and their accumulators `D` wide, `Lse` is the same row
+either way, in every kernel (one-pass and streaming forward, fused backward,
+split pair). Latent attention (MLA) is the case that differs: query/key heads
+of 192 (128 without position + 64 rotary) over value heads of 128; a 192-wide
+block is the array's whole last axis, which is a legal block. Where `Dv == D`
+every kernel is the instructions it was. `_bwd_plan` reads `D`: the resident
+dQ row is query-wide.
+
 On a CPU backend the same kernels run under the Pallas interpreter when
 PADDLE_TPU_PALLAS_INTERPRET=1 (used by the CPU test suite); otherwise a
 pure-jnp reference path takes over there. On the TPU there is no second
@@ -234,7 +244,7 @@ def _score_tile(q_ref, k_ref, qi, kj, sm_scale, causal):
 
 
 def _weights_times_v(p, v_ref, seed_ref, bh, qi, kj, dropout_rate):
-    """dropout(p) v for one tile, float32 [blk_q, D]."""
+    """dropout(p) v for one tile, float32 [blk_q, Dv]."""
     if dropout_rate:
         keep = _dropout_mask(seed_ref, bh, qi, kj, p.shape, dropout_rate)
         p = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
@@ -301,7 +311,7 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     nk = pl.num_programs(2)
     blk_q = q_ref.shape[1]
     blk_k = k_ref.shape[1]
-    D = q_ref.shape[2]
+    Dv = v_ref.shape[2]
 
     @pl.when(kj == 0)
     def _init():
@@ -320,14 +330,14 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         p = jnp.exp(s - _lanes(m_new, blk_k))
         alpha = jnp.exp(m - m_new)
         l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_sc[...] = acc_sc[...] * _lanes(alpha, D) + _weights_times_v(
+        acc_sc[...] = acc_sc[...] * _lanes(alpha, Dv) + _weights_times_v(
             p, v_ref, seed_ref, bh, qi, kj, dropout_rate)
         m_sc[...] = m_new
 
     @pl.when(kj == nk - 1)
     def _finalize():
         l = jnp.maximum(l_sc[...], 1e-20)
-        o_ref[0] = (acc_sc[...] / _lanes(l, D)).astype(o_ref.dtype)
+        o_ref[0] = (acc_sc[...] / _lanes(l, Dv)).astype(o_ref.dtype)
         lse_ref[0, 0] = (m_sc[...] + jnp.log(l))[:, 0]
 
 
@@ -555,10 +565,11 @@ def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0):
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, D = q.shape
+    Dv = v.shape[-1]
     BQ, BK = _blk(T, causal)
     q3 = q.reshape(B * H, T, D)
     k3 = k.reshape(B * H, T, D)
-    v3 = v.reshape(B * H, T, D)
+    v3 = v.reshape(B * H, T, Dv)
     attrs = dict(sm_scale=sm_scale, causal=causal, dropout_rate=dropout_rate)
     if _fwd_plan(T, BK) == "onepass":
         # its name holds `flash_fwd`: the benchmark's metrics of that name
@@ -571,7 +582,7 @@ def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0):
         kernel = functools.partial(_flash_fwd_kernel, **attrs)
         scratch = [pltpu.VMEM((BQ, _LANES), jnp.float32),
                    pltpu.VMEM((BQ, _LANES), jnp.float32),
-                   pltpu.VMEM((BQ, D), jnp.float32)]
+                   pltpu.VMEM((BQ, Dv), jnp.float32)]
 
     # the one-pass grid has no kj axis: its one K block is block 0
     def at_q(bh, qi, kj=0):
@@ -587,14 +598,14 @@ def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0):
             pl.BlockSpec((1, 1), lambda *g: (0, 0)),
             pl.BlockSpec((1, BQ, D), at_q),
             pl.BlockSpec((1, BK, D), at_k),
-            pl.BlockSpec((1, BK, D), at_k),
+            pl.BlockSpec((1, BK, Dv), at_k),
         ],
         out_specs=[
-            pl.BlockSpec((1, BQ, D), at_q),
+            pl.BlockSpec((1, BQ, Dv), at_q),
             pl.BlockSpec((1, 1, BQ), lambda bh, qi, kj=0: (bh, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, T, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32),
         ],
         scratch_shapes=scratch,
@@ -602,14 +613,14 @@ def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0):
         interpret=_interpret(),
         name=name,
     )(_seed_arr(seed), q3, k3, v3)
-    return out.reshape(B, H, T, D), lse
+    return out.reshape(B, H, T, Dv), lse
 
 
 def _flash_backward(q, k, v, o, lse, g, causal, sm_scale, dropout_rate, seed):
     B, H, T, D = q.shape
-    q3, k3, v3 = (x.reshape(B * H, T, D) for x in (q, k, v))
-    o3 = o.reshape(B * H, T, D)
-    g3 = g.reshape(B * H, T, D)
+    Dv = v.shape[-1]
+    q3, k3 = (x.reshape(B * H, T, D) for x in (q, k))
+    v3, o3, g3 = (x.reshape(B * H, T, Dv) for x in (v, o, g))
     delta = jnp.sum(g3.astype(jnp.float32) * o3.astype(jnp.float32),
                     axis=-1)[:, None, :]
     BQ, BK = _blk(T, causal)
@@ -618,13 +629,14 @@ def _flash_backward(q, k, v, o, lse, g, causal, sm_scale, dropout_rate, seed):
     grads = run((_seed_arr(seed), q3, k3, v3, g3, lse, delta), BQ, BK,
                 dict(sm_scale=sm_scale, causal=causal,
                      dropout_rate=dropout_rate))
-    return tuple(d.reshape(B, H, T, D) for d in grads)
+    return tuple(d.reshape(x.shape) for d, x in zip(grads, (q, k, v)))
 
 
-def _bwd_specs(BQ, BK, D, q_axis):
+def _bwd_specs(BQ, BK, D, Dv, q_axis):
     """Block specs of (seed, q, k, v, dO, lse, delta) for a backward grid
     (bh, ., .) whose q-block index is grid axis `q_axis` (1 or 2) and whose
-    k-block index is the other; and the index maps of a q and a k block."""
+    k-block index is the other; and the index maps of a q and a k block.
+    q and k are `D` wide, v and dO `Dv`."""
     from jax.experimental import pallas as pl
 
     def at_q(*g):
@@ -640,8 +652,8 @@ def _bwd_specs(BQ, BK, D, q_axis):
         pl.BlockSpec((1, 1), lambda *g: (0, 0)),
         pl.BlockSpec((1, BQ, D), at_q),
         pl.BlockSpec((1, BK, D), at_k),
-        pl.BlockSpec((1, BK, D), at_k),
-        pl.BlockSpec((1, BQ, D), at_q),
+        pl.BlockSpec((1, BK, Dv), at_k),
+        pl.BlockSpec((1, BQ, Dv), at_q),
         pl.BlockSpec((1, 1, BQ), row_q),
         pl.BlockSpec((1, 1, BQ), row_q),
     ], at_q, at_k
@@ -655,9 +667,10 @@ def _flash_bwd_fused(args, BQ, BK, attrs):
 
     q3, k3, v3 = args[1:4]
     BH, T, D = q3.shape
-    in_specs, at_q, at_k = _bwd_specs(BQ, BK, D, q_axis=2)
+    Dv = v3.shape[2]
+    in_specs, at_q, at_k = _bwd_specs(BQ, BK, D, Dv, q_axis=2)
     scratch = [pltpu.VMEM((BK, D), jnp.float32),
-               pltpu.VMEM((BK, D), jnp.float32)]
+               pltpu.VMEM((BK, Dv), jnp.float32)]
     if T == BK:
         dq_spec = pl.BlockSpec((1, BQ, D), at_q)
     else:
@@ -669,8 +682,8 @@ def _flash_bwd_fused(args, BQ, BK, attrs):
         grid=(BH, T // BK, T // BQ),
         in_specs=in_specs,
         out_specs=[dq_spec, pl.BlockSpec((1, BK, D), at_k),
-                   pl.BlockSpec((1, BK, D), at_k)],
-        out_shape=[jax.ShapeDtypeStruct((BH, T, D), x.dtype)
+                   pl.BlockSpec((1, BK, Dv), at_k)],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
                    for x in (q3, k3, v3)],
         scratch_shapes=scratch,
         compiler_params=_compiler_params(carried=1 if T == BK else 2),
@@ -688,7 +701,8 @@ def _flash_bwd_split(args, BQ, BK, attrs):
 
     q3, k3, v3 = args[1:4]
     BH, T, D = q3.shape
-    in_specs, at_q, _ = _bwd_specs(BQ, BK, D, q_axis=1)
+    Dv = v3.shape[2]
+    in_specs, at_q, _ = _bwd_specs(BQ, BK, D, Dv, q_axis=1)
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, **attrs),
         grid=(BH, T // BQ, T // BK),
@@ -700,17 +714,17 @@ def _flash_bwd_split(args, BQ, BK, attrs):
         interpret=_interpret(),
         name="flash_dq",
     )(*args)
-    in_specs, _, at_k = _bwd_specs(BQ, BK, D, q_axis=2)
+    in_specs, _, at_k = _bwd_specs(BQ, BK, D, Dv, q_axis=2)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, **attrs),
         grid=(BH, T // BK, T // BQ),
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((1, BK, D), at_k),
-                   pl.BlockSpec((1, BK, D), at_k)],
+                   pl.BlockSpec((1, BK, Dv), at_k)],
         out_shape=[jax.ShapeDtypeStruct((BH, T, D), k3.dtype),
-                   jax.ShapeDtypeStruct((BH, T, D), v3.dtype)],
+                   jax.ShapeDtypeStruct((BH, T, Dv), v3.dtype)],
         scratch_shapes=[pltpu.VMEM((BK, D), jnp.float32),
-                        pltpu.VMEM((BK, D), jnp.float32)],
+                        pltpu.VMEM((BK, Dv), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
         name="flash_dkv",
@@ -718,17 +732,21 @@ def _flash_bwd_split(args, BQ, BK, attrs):
     return dq, dk, dv
 
 
-def _pallas_ok(q, dropout_rate=0.0):
+def _pallas_ok(q, dropout_rate=0.0, v=None):
     """Kernel or reference? The reference is a CPU-only path; on the TPU
     a shape outside the kernels' envelope raises instead of quietly
-    materializing the [T, T] scores."""
+    materializing the [T, T] scores. `v` where its heads have a width of
+    their own (`Dv`; the query's `D` otherwise)."""
     B, H, T, D = q.shape
-    supported = T % 128 == 0 and D <= 256
+    Dv = D if v is None else v.shape[-1]
+    supported = T % 128 == 0 and D <= 256 and Dv <= 256
     if jax.default_backend() != "cpu":
         if not supported:
             raise ValueError(
                 f"flash attention on the {jax.default_backend()!r} backend "
-                f"needs T % 128 == 0 and D <= 256, got q shape {q.shape}")
+                f"needs T % 128 == 0, D <= 256 and Dv <= 256, got q shape "
+                f"{q.shape} (query/key heads of D = {D}) and value heads of "
+                f"Dv = {Dv}")
         return True
     if not _interpret():
         return False
@@ -743,7 +761,7 @@ def _pallas_ok(q, dropout_rate=0.0):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def _flash_out_lse(q, k, v, seed, causal, sm_scale, dropout_rate):
-    """The forward kernel's two results: `out` [B, H, T, D] and the rows'
+    """The forward kernel's two results: `out` [B, H, T, Dv] and the rows'
     log-sum-exp, float32 [B*H, 1, T] (the layout the backward kernels read;
     no gradient flows through it)."""
     return _flash_forward(q, k, v, causal, sm_scale, dropout_rate, seed)
@@ -769,7 +787,7 @@ def flash_attention(q, k, v, seed, causal=False, sm_scale=1.0,
     """seed: int32 scalar (traced) driving attention-weight dropout. For
     direct callers (tools, tests): under `jax.grad` the forward kernel is
     the residual pass and the backward kernels follow."""
-    if _pallas_ok(q, dropout_rate):
+    if _pallas_ok(q, dropout_rate, v):
         return _flash_out_lse(q, k, v, seed, causal, sm_scale,
                               dropout_rate)[0]
     return _attention_reference(q, k, v, causal, sm_scale, dropout_rate, seed)
@@ -796,16 +814,18 @@ def _fused_attention_infer(ctx, structs):
     """Build-time shapes without a trace of the rule: a machine with no TPU
     takes the reference path, which has no `Lse`, and the program it builds
     may run on one that has."""
-    Q = structs["Q"][0]
+    Q, V = structs["Q"][0], structs["V"][0]
     B, H, T, _ = Q.shape
-    return {"Out": jax.ShapeDtypeStruct(Q.shape, Q.dtype),
+    return {"Out": jax.ShapeDtypeStruct(Q.shape[:3] + V.shape[3:], Q.dtype),
             "Lse": jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32)}
 
 
 @register_op("fused_attention", infer=_fused_attention_infer,
              propagate_seqlen=False, needs_rng=True)
 def _fused_attention(ctx, Q, K, V):
-    """Q/K/V: [B, H, T, Dh]. attrs: causal, sm_scale, dropout_rate, is_test.
+    """Q, K: [B, H, T, D]; V: [B, H, T, Dv], the value heads' own width
+    (latent attention: 192 over 128), `Dv == D` in the plain case; Out is
+    [B, H, T, Dv]. attrs: causal, sm_scale, dropout_rate, is_test.
 
     Replaces the reference's matmul+softmax+dropout+matmul composition
     (nets.py:329) with one O(T)-memory kernel. Dropout is applied to the
@@ -830,10 +850,15 @@ def _fused_attention(ctx, Q, K, V):
                 f"sequence length {Q.shape[2]} is not divisible by the "
                 f"{mesh.shape['sp']}-way 'sp' mesh axis; pad the sequence "
                 f"or choose an sp that divides it")
+        if V.shape[-1] != Q.shape[-1]:
+            raise NotImplementedError(
+                f"ring attention under the 'sp' mesh axis carries one head "
+                f"width; got query/key heads of {Q.shape[-1]} and value "
+                f"heads of {V.shape[-1]}")
         return {"Out": ring_attention(Q, K, V, mesh, axis="sp",
                                       causal=causal, sm_scale=sm_scale)}
     seed = _dropout_seed(ctx, rate)
-    if _pallas_ok(Q, rate):
+    if _pallas_ok(Q, rate, V):
         out, lse = _flash_out_lse(Q, K, V, seed, causal, sm_scale, rate)
         return {"Out": out, "Lse": lse}
     return {"Out": _attention_reference(Q, K, V, causal, sm_scale, rate,

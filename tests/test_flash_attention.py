@@ -37,26 +37,74 @@ def interpret_kernels(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
 
 
+# query/key width and value width: the plain case, latent attention's
+# (192 over 128, no multiple of a vreg's lanes), and values wider than keys
+WIDTHS = [(64, 64), (192, 128), (64, 128)]
+WIDTH_IDS = [f"D{d}-Dv{dv}" for d, dv in WIDTHS]
+
+
+def _qkv(rng, B, H, T, D, Dv):
+    q, k = (jnp.asarray(rng.randn(B, H, T, D), jnp.float32)
+            for _ in range(2))
+    return q, k, jnp.asarray(rng.randn(B, H, T, Dv), jnp.float32)
+
+
+@pytest.mark.parametrize("plan", ["onepass", "stream"])
+@pytest.mark.parametrize("D,Dv", WIDTHS, ids=WIDTH_IDS)
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_kernel_matches_reference(interpret_kernels, causal):
+def test_flash_kernel_matches_reference(interpret_kernels, monkeypatch,
+                                        causal, D, Dv, plan):
+    """Both forward kernels; `Out` has the value heads' width."""
     rng = np.random.RandomState(0)
-    B, H, T, D = 1, 2, 256, 64
-    q, k, v = (jnp.asarray(rng.randn(B, H, T, D), jnp.float32)
-               for _ in range(3))
+    B, H, T = 1, 2, 256
+    if plan == "stream":
+        monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", (128, 128))
+    assert pallas_attention._fwd_plan(
+        T, pallas_attention._blk(T, causal)[1]) == plan
+    q, k, v = _qkv(rng, B, H, T, D, Dv)
     seed = jnp.int32(0)
     out = flash_attention(q, k, v, seed, causal, D ** -0.5, 0.0)
     ref = _attention_reference(q, k, v, causal, D ** -0.5)
+    assert out.shape == (B, H, T, Dv)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("kernels", ["fused", "fused_resident_row", "split"])
+@pytest.mark.parametrize("D,Dv", WIDTHS, ids=WIDTH_IDS)
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_backward_matches_reference(interpret_kernels, causal):
+def test_backward_kernels_match_reference_at_every_width(
+        interpret_kernels, monkeypatch, causal, D, Dv, kernels):
+    """The fused backward (a row one K block; a row of several, its dQ
+    resident) and the split pair, each against the einsum reference's
+    gradients: dQ and dK at the query/key width, dV at the value width."""
+    rng = np.random.RandomState(4)
+    B, H, T = 1, 2, 256
+    if kernels != "fused":
+        monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", (128, 128))
+    if kernels == "split":
+        monkeypatch.setattr(pallas_attention, "_bwd_plan",
+                            lambda *a: "split")
+    q, k, v = _qkv(rng, B, H, T, D, Dv)
+    g = jnp.asarray(rng.randn(B, H, T, Dv), jnp.float32)
+    out, lse = pallas_attention._flash_forward(q, k, v, causal, D ** -0.5)
+    got = pallas_attention._flash_backward(q, k, v, out, lse, g, causal,
+                                           D ** -0.5, 0.0, 0)
+    _, vjp = jax.vjp(lambda *a: _attention_reference(*a, causal, D ** -0.5),
+                     q, k, v)
+    for a, b, name in zip(got, vjp(g), "qkv"):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
+                                   rtol=1e-4, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("D,Dv", WIDTHS, ids=WIDTH_IDS)
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_backward_matches_reference(interpret_kernels, causal, D, Dv):
     rng = np.random.RandomState(1)
-    B, H, T, D = 1, 2, 256, 64
-    q, k, v = (jnp.asarray(rng.randn(B, H, T, D), jnp.float32)
-               for _ in range(3))
-    g = jnp.asarray(rng.randn(B, H, T, D), jnp.float32)
+    B, H, T = 1, 2, 256
+    q, k, v = _qkv(rng, B, H, T, D, Dv)
+    g = jnp.asarray(rng.randn(B, H, T, Dv), jnp.float32)
     seed = jnp.int32(0)
 
     def f(q, k, v):
@@ -216,16 +264,20 @@ def test_grad_op_on_saved_lse_is_bitwise_the_generic_path(
     assert [kernel_calls(text_g, k) for k in KERNELS] == [0, 2, 1, 0, 0]
 
 
+@pytest.mark.parametrize("D,Dv", WIDTHS, ids=WIDTH_IDS)
 @pytest.mark.parametrize("causal", [False, True])
 def test_grad_op_on_saved_lse_matches_reference(interpret_kernels,
-                                                monkeypatch, causal):
+                                                monkeypatch, causal, D, Dv):
     """Float32, no AMP: the grad op's dQ/dK/dV against `jax.grad` of the
-    jnp reference, over 2 x 2 blocks a row."""
+    jnp reference, over 2 x 2 blocks a row; the op's `Out` and `V@GRAD` at
+    the value heads' width, `Q@GRAD` and `K@GRAD` at the keys'."""
     monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", (128, 128))
-    feed = qkv_feed(("q", "k", "v"), shape=(1, 2, 256, 64))
+    feed = qkv_feed(("q", "k"), shape=(1, 2, 256, D))
+    feed.update(qkv_feed(("v",), shape=(1, 2, 256, Dv), seed=6))
     out, grads, _ = attention_grads(feed, causal, amp=False)
+    assert out.shape == (1, 2, 256, Dv)
     want = jax.grad(lambda q, k, v: (_attention_reference(
-        q, k, v, causal, 64 ** -0.5) * feed["probe"]).sum(), (0, 1, 2))(
+        q, k, v, causal, D ** -0.5) * feed["probe"]).sum(), (0, 1, 2))(
             *(jnp.asarray(feed[n]) for n in "qkv"))
     for n, w in zip("qkv", want):
         np.testing.assert_allclose(grads[n], np.asarray(w), atol=1e-4,

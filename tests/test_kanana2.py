@@ -1,0 +1,629 @@
+"""Kanana-2 (latent attention through `fused_attention` with value heads of
+their own width, a leading dense layer, one chip's share of a sigmoid-routed
+expert layer whose selection bias the step rewrites, two shared experts)
+through `layers` -> Program IR -> `Executor`, against the plain reference
+(`tests/kanana2_reference.py`: a masked softmax over the assembled heads, a
+loop over the held experts, `next_bias`). Seeded random weights, float32, AMP
+off unless a test says otherwise."""
+
+import filecmp
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu import io, layers, models, observe
+from paddle_tpu.core import ir, registry
+
+import kanana2_reference as ref
+from test_olmoe import rel_err, run_piece
+from test_qwen3_next import OLMOE_DIGEST, _program_digest, frob
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+GAMMA = 0.001
+TINY = dict(vocab_size=64, seq_len=128, n_layer=3, n_dense_layer=1,
+            d_model=32, d_dense=48, n_head=4, kv_rank=16, qk_nope_dim=16,
+            qk_rope_dim=8, v_head_dim=16, rope_theta=1e4, n_expert=16,
+            top_k=3, d_expert=16, n_shared=2, routed_scaling_factor=2.448,
+            bias_update_rate=GAMMA, first_expert=4, experts_held=4)
+REF_KW = {k: TINY[k] for k in (
+    "n_layer", "n_head", "qk_nope_dim", "qk_rope_dim", "v_head_dim",
+    "rope_theta", "top_k", "first_expert", "routed_scaling_factor")}
+RTOL = 2e-5
+
+
+# -- rotary in interleaved pairs ------------------------------------------------
+
+def test_interleaved_rotary_is_the_public_codes_deinterleave_form():
+    """`rotary_embedding(interleaved=True)` against `rotary_interleaved`
+    (view as pairs, transpose, rotate halves), forward and gradient; and
+    against the definition: the pair (x[2i], x[2i+1]) turned by
+    t * theta^(-2i/r), laid [evens | odds]."""
+    rng = np.random.RandomState(0)
+    x = rng.randn(2, 3, 16, 8).astype(np.float32)
+    (y,), grads, probe = run_piece(
+        lambda d: [layers.rotary_embedding(d["x"], theta=100.0,
+                                           interleaved=True)], {"x": x})
+    want = ref.rotary_interleaved(jnp.asarray(x), 100.0)
+    assert rel_err(y, want) < RTOL
+    gx = jax.grad(lambda a: jnp.sum(ref.rotary_interleaved(a, 100.0)
+                                    * probe))(jnp.asarray(x))
+    assert rel_err(grads["x"], gx) < RTOL
+    t = np.arange(16)[:, None]
+    angle = t * 100.0 ** (-np.arange(0, 8, 2) / 8)
+    even, odd = x[..., 0::2], x[..., 1::2]
+    by_hand = np.concatenate([even * np.cos(angle) - odd * np.sin(angle),
+                              odd * np.cos(angle) + even * np.sin(angle)], -1)
+    assert rel_err(y, by_hand) < RTOL
+    # and it is not the rotate-half pairing (x[i], x[i + r/2])
+    (plain,), _, _ = run_piece(
+        lambda d: [layers.rotary_embedding(d["x"], theta=100.0)], {"x": x})
+    assert rel_err(plain, by_hand) > 0.1
+
+
+# -- the router: sigmoid scores, a bias that moves the choice alone -----------------
+
+def _planted(name, value):
+    """A bias that starts at `value`: `run_piece` takes the gradient of every
+    parameter it is handed, and the bias has none."""
+    return fluid.ParamAttr(
+        name=name, initializer=fluid.initializer.NumpyArrayInitializer(value))
+
+
+def _route(x, w, b, k=3, **kw):
+    attrs = dict(norm_topk_prob=True, score_func="sigmoid", norm_eps=1e-20,
+                 scaling_factor=2.448)
+    attrs.update(kw)
+
+    def build(d):
+        r = layers.moe_router(
+            d["x"], w.shape[1], k, param_attr=fluid.ParamAttr(name="w"),
+            bias_attr=None if b is None else _planted("b", b), **attrs)
+        return [r["weight"], r["index"], r["probs"], r["tokens_per_expert"]]
+
+    return run_piece(build, {"x": x}, {"w": w})
+
+
+def test_a_planted_bias_changes_the_choice_and_not_the_weights():
+    """Chosen by `s + b`, weighted by `s`: with a bias that lifts two experts
+    above everything, every token goes to them (and its best other expert),
+    and the weights are those experts' own sigmoids renormalised and scaled:
+    `b` is nowhere in them."""
+    rng = np.random.RandomState(1)
+    x = rng.randn(24, 8).astype(np.float32)
+    w = rng.randn(8, 16).astype(np.float32)
+    b = np.zeros(16, np.float32)
+    b[[5, 11]] = 10.0
+    (weight, index, probs, counts), grads, probe = _route(x, w, b)
+    (_, plain_index, _, _), _, _ = _route(x, w, None)
+    assert np.all(np.sort(index, -1)[:, -2:] == [5, 11]) or \
+        np.all((index == 5).sum(1) + (index == 11).sum(1) == 2)
+    assert not np.array_equal(np.sort(index, -1), np.sort(plain_index, -1))
+    assert counts[5] == 24 and counts[11] == 24 and counts.sum() == 72
+    s = 1 / (1 + np.exp(-(x.astype(np.float64) @ w)))
+    assert rel_err(probs, s) < 1e-5
+    picked = np.take_along_axis(s, index, -1)
+    want = 2.448 * picked / picked.sum(-1, keepdims=True)
+    assert rel_err(weight, want) < 1e-5
+    assert np.all(weight < 2.448) and np.allclose(weight.sum(-1), 2.448,
+                                                  rtol=1e-5)
+    with jax.default_matmul_precision("highest"):
+        gx, gw = jax.grad(
+            lambda a, c: jnp.sum(ref.route(a, c, jnp.asarray(b), 3, 2.448)[0]
+                                 * probe), (0, 1))(x, w)
+    assert rel_err(grads["x"], gx) < 1e-4 and rel_err(grads["w"], gw) < 1e-4
+
+
+def test_sigmoid_router_without_a_bias_is_the_reference_at_zero_bias():
+    rng = np.random.RandomState(2)
+    x = rng.randn(24, 8).astype(np.float32)
+    w = rng.randn(8, 16).astype(np.float32)
+    (weight, index, _, _), _, _ = _route(x, w, None)
+    with jax.default_matmul_precision("highest"):
+        want, want_index, _ = ref.route(x, w, jnp.zeros(16), 3, 2.448)
+    assert np.array_equal(index, want_index)
+    assert rel_err(weight, want) < RTOL
+
+
+def test_softmax_router_takes_no_new_attribute_by_default():
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = layers.data(name="x", shape=[8, 8], dtype="float32",
+                        append_batch_size=False)
+        layers.moe_router(x, 4, 2, norm_topk_prob=True)
+    (op,) = [o for o in main.global_block().ops if o.type == "moe_router"]
+    assert set(op.attrs) - {ir.NAME_SCOPE_ATTR} <= {"k", "norm_topk_prob"}
+    assert set(op.inputs) == {"X", "W"}
+    assert [o.type for o in main.global_block().ops] == ["moe_router"]
+
+
+# -- the shares add up -----------------------------------------------------------------
+
+N_EXPERT, HELD, K, D, F = 16, 2, 3, 16, 12
+
+
+@pytest.mark.parametrize("path", ["ragged_dot", "pallas_interpreted"])
+def test_the_eight_shares_add_up_to_the_whole_layer(path, monkeypatch):
+    """The routed parts that all 8 shares give, plus the shared experts
+    once, are the uncut reference's whole layer: forward, the gradient of
+    the router and of the layer's input. With a planted non-zero `b`, so
+    that choosing by `s + b` and weighting by `s` cannot be confused."""
+    if path == "pallas_interpreted":
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    rng = np.random.RandomState(5)
+    x = rng.randn(40, D).astype(np.float32)
+    whole = {"router.w": rng.randn(D, N_EXPERT),
+             "router.bias": rng.randn(N_EXPERT) * 0.3,
+             "experts.gate.w": rng.randn(N_EXPERT, D, F) * 0.3,
+             "experts.up.w": rng.randn(N_EXPERT, D, F) * 0.3,
+             "experts.down.w": rng.randn(N_EXPERT, F, D) * 0.3,
+             "shared.gate.w": rng.randn(D, 2 * F) * 0.3,
+             "shared.up.w": rng.randn(D, 2 * F) * 0.3,
+             "shared.down.w": rng.randn(2 * F, D) * 0.3}
+    whole = {n: v.astype(np.float32) for n, v in whole.items()}
+    shares = N_EXPERT // HELD
+    cut = {f"s{j}.{which}.w":
+           whole[f"experts.{which}.w"][j * HELD:(j + 1) * HELD]
+           for j in range(shares) for which in ("gate", "up", "down")}
+
+    def build(d):
+        routing = layers.moe_router(
+            d["x"], N_EXPERT, K, norm_topk_prob=True, score_func="sigmoid",
+            norm_eps=1e-20, scaling_factor=2.448,
+            param_attr=fluid.ParamAttr(name="router.w"),
+            bias_attr=_planted("router.bias", whole["router.bias"]))
+        parts = [layers.moe_experts(
+            d["x"], routing, N_EXPERT, F, name=f"s{j}",
+            first_expert=j * HELD, experts_held=HELD)
+            for j in range(shares)]
+
+        def fc(v, size, name):
+            return layers.fc(v, size, bias_attr=False,
+                             param_attr=fluid.ParamAttr(name=name))
+
+        hidden = layers.swiglu(fc(d["x"], 2 * F, "shared.gate.w"),
+                               fc(d["x"], 2 * F, "shared.up.w"))
+        return [layers.sums(parts + [fc(hidden, D, "shared.down.w")])] + parts
+
+    params = {**{n: v for n, v in whole.items()
+                 if not n.startswith(("experts.", "router.bias"))}, **cut}
+    outs, grads, probe = run_piece(build, {"x": x}, params)
+    kw = dict(top_k=K, routed_scaling_factor=2.448)
+
+    def want(x, router_w):
+        return ref.sparse_experts({**whole, "router.w": router_w}, x,
+                                  first_expert=0, **kw)[0]
+
+    with jax.default_matmul_precision("highest"):
+        assert rel_err(outs[0], want(x, whole["router.w"])) < RTOL
+        gx, gr = jax.grad(lambda a, b: jnp.sum(want(a, b) * probe),
+                          (0, 1))(x, whole["router.w"])
+        none = {n: v[:0] for n, v in whole.items() if n.startswith("experts.")}
+        shared = ref.sparse_experts({**whole, **none}, x, first_expert=0,
+                                    **kw)[0]
+        # and each share alone is the reference given that share
+        for j, part in enumerate(outs[1:]):
+            held = {n: (v[j * HELD:(j + 1) * HELD]
+                        if n.startswith("experts.") else v)
+                    for n, v in whole.items()}
+            alone = ref.sparse_experts(held, x, first_expert=j * HELD,
+                                       **kw)[0]
+            assert rel_err(part, alone - shared) < 1e-4, j
+        # the bias mattered: at b = 0 the layer is another function
+        unbiased = ref.sparse_experts(
+            {**whole, "router.bias": np.zeros(N_EXPERT, np.float32)}, x,
+            first_expert=0, **kw)[0]
+        assert rel_err(unbiased, want(x, whole["router.w"])) > 0.05
+    assert rel_err(grads["x"], gx) < 1e-4
+    assert rel_err(grads["router.w"], gr) < 1e-4
+
+
+# -- the model ----------------------------------------------------------------------------
+
+def _program(optimizer=None, **sizes):
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        feeds, fetches = models.kanana2.build(**{**TINY, **sizes})
+        if optimizer is None:
+            pairs = fluid.append_backward(fetches["loss"])
+        else:
+            optimizer.minimize(fetches["loss"])
+            pairs = []
+    main.random_seed = startup.random_seed = 7
+    return main, startup, fetches, pairs
+
+
+def _batch(seed=0, batch=2):
+    rng = np.random.RandomState(seed)
+    shape = (batch, TINY["seq_len"])
+    return {"tokens": rng.randint(0, TINY["vocab_size"], shape)
+            .astype(np.int32),
+            "labels": rng.randint(0, TINY["vocab_size"], shape)
+            .astype(np.int32)}
+
+
+def _parameter_names(main):
+    return [p.name for p in main.global_block().all_parameters()]
+
+
+def _seeded_weights(scope, names, seed=3):
+    """Weights far from their initial values, so that no term of the
+    comparison is small by construction: norm weights in [0.5, 1.5], a
+    router five times as sharp, a planted bias of std 0.2 (the sigmoids'
+    spread is about 0.25), matrices of std 0.1 (five times the initial)."""
+    rng = np.random.RandomState(seed)
+    for name in sorted(names):
+        shape = np.shape(scope.find_var(name))
+        if name.endswith("router.bias"):
+            value = rng.randn(*shape) * 0.2
+        elif "norm" in name:
+            value = rng.uniform(0.5, 1.5, shape)
+        elif name.endswith("router.w"):
+            value = rng.randn(*shape) * 0.5
+        else:
+            value = rng.randn(*shape) * 0.1
+        scope.set_var(name, jnp.asarray(value.astype(np.float32)))
+
+
+FETCHES = ["loss", "ce", "logits", "tokens_per_expert"]
+
+
+def _run_tiny(amp, seeded=True):
+    main, startup, fetches, pairs = _program()
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace(), amp=amp)
+    exe.run(startup, scope=scope)
+    names = _parameter_names(main)
+    if seeded:
+        _seeded_weights(scope, names)
+    params = {n: np.asarray(scope.find_var(n)) for n in names}
+    feed = _batch()
+    out = exe.run(main, feed=feed,
+                  fetch_list=[fetches[n] for n in FETCHES]
+                  + [g for _, g in pairs], scope=scope)
+    got = dict(zip(FETCHES, out))
+    grads = dict(zip((p.name for p, _ in pairs), out[len(FETCHES):]))
+    after = {n: np.asarray(scope.find_var(n)) for n in names
+             if n.endswith("router.bias")}
+    return main, params, feed, got, grads, after
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    main, params, feed, got, grads, after = _run_tiny(amp=False)
+    tokens, labels = jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"])
+    want, want_grads = ref.loss_and_grads(
+        params, tokens, labels, last=TINY["seq_len"], **REF_KW)
+    return dict(main=main, params=params, tokens=tokens, labels=labels,
+                got=got, grads=grads, after=after, want=want,
+                want_grads=want_grads)
+
+
+MLA = ["in_norm.w", "post_norm.w", "mla.q.w", "mla.kv_a.w", "mla.kv_norm.w",
+       "mla.kv_b.w", "mla.o.w"]
+DENSE = ["mlp.gate.w", "mlp.up.w", "mlp.down.w"]
+MOE = ["router.w", "experts.gate.w", "experts.up.w", "experts.down.w",
+       "shared.gate.w", "shared.up.w", "shared.down.w"]
+TRAINED = (["embed.w", "final_norm.w", "head.w"]
+           + [f"l{i}.{n}" for i in range(3)
+              for n in MLA + (DENSE if i == 0 else MOE)])
+BIASES = ["l1.router.bias", "l2.router.bias"]
+
+
+def test_tiny_model_has_the_reference_parameters(tiny):
+    assert sorted(tiny["params"]) == sorted(TRAINED + BIASES)
+    assert tiny["params"]["l1.experts.gate.w"].shape == (4, 32, 16)
+    assert tiny["params"]["l1.router.w"].shape == (32, 16)
+    assert tiny["params"]["l1.router.bias"].shape == (16,)
+    assert tiny["params"]["l0.mla.q.w"].shape == (32, 4 * (16 + 8))
+    assert tiny["params"]["l0.mla.kv_a.w"].shape == (32, 16 + 8)
+    assert tiny["params"]["l0.mla.kv_b.w"].shape == (16, 4 * (16 + 16))
+    assert tiny["params"]["l1.shared.gate.w"].shape == (32, 2 * 16)
+    assert tiny["params"]["l0.mlp.gate.w"].shape == (32, 48)
+    # a gradient for every trained parameter and for no bias
+    assert sorted(tiny["grads"]) == sorted(TRAINED)
+
+
+@pytest.mark.parametrize("name", FETCHES)
+def test_tiny_model_output_matches_reference(tiny, name):
+    if name == "tokens_per_expert":
+        assert np.array_equal(tiny["got"][name], tiny["want"][name])
+    else:
+        want = np.asarray(tiny["want"][name])
+        assert rel_err(np.reshape(tiny["got"][name], want.shape), want) < 1e-4
+
+
+def test_tiny_routing_sends_most_assignments_elsewhere(tiny):
+    counts = tiny["got"]["tokens_per_expert"]
+    assert counts.shape == (2, 16) and np.all(counts.sum(1) == 2 * 128 * 3)
+    held = counts[:, 4:8].sum(1)
+    assert np.all(held > 0) and np.all(held < counts.sum(1) / 2)
+
+
+@pytest.mark.parametrize("name", TRAINED)
+def test_tiny_model_gradient_matches_reference(tiny, name):
+    assert frob(tiny["grads"][name], tiny["want_grads"][name]) < 2e-4
+
+
+@pytest.mark.parametrize("layer", [1, 2])
+def test_one_step_moves_the_bias_as_next_bias_does(tiny, layer):
+    name = f"l{layer}.router.bias"
+    want = ref.next_bias(tiny["params"][name],
+                         tiny["got"]["tokens_per_expert"][layer - 1], GAMMA)
+    assert np.array_equal(tiny["after"][name], np.asarray(want))
+    moved = tiny["after"][name] - tiny["params"][name]
+    assert np.all(np.isclose(np.abs(moved), GAMMA, rtol=1e-3)
+                  | (moved == 0)) and np.any(moved != 0)
+
+
+def test_reference_in_blocks_is_the_reference(tiny):
+    """`q_block` and `remat` are the reference's memory, not its
+    mathematics."""
+    parts, grads = ref.loss_and_grads(
+        tiny["params"], tiny["tokens"], tiny["labels"],
+        wrt=["l0.mla.q.w", "l1.mla.kv_b.w", "l2.router.w", "embed.w"],
+        q_block=32, remat=True, **REF_KW)
+    assert abs(float(parts["loss"]) - float(tiny["want"]["loss"])) < 1e-5
+    for name, g in grads.items():
+        assert frob(g, tiny["want_grads"][name]) < 1e-5, name
+
+
+def test_reference_last_positions_equal_the_full_pass(tiny):
+    parts = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
+                           last=16, **REF_KW)
+    assert rel_err(parts["logits"], tiny["want"]["logits"][:, -16:]) < 1e-6
+
+
+def test_reference_in_bfloat16_is_another_number(tiny):
+    low = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
+                         dtype=jnp.bfloat16, **REF_KW)
+    assert low["loss"].dtype == jnp.bfloat16
+    assert abs(float(low["loss"]) - float(tiny["want"]["loss"])) > 1e-4
+
+
+# -- the bias as state -------------------------------------------------------------------
+
+@pytest.mark.parametrize("amp", [False, True])
+def test_three_adam_steps_move_the_bias_exactly(amp, tmp_path):
+    """`b` after three steps is `next_bias` applied three times to the
+    system's own counts, bit for bit; it has no gradient and no moments,
+    stays float32 under AMP, and a checkpoint carries it."""
+    main, startup, fetches, _ = _program(
+        fluid.optimizer.Adam(learning_rate=1e-3))
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace(), amp=amp)
+    exe.run(startup, scope=scope)
+    assert np.all(np.asarray(scope.find_var("l1.router.bias")) == 0)
+    want = {n: np.zeros(16, np.float32) for n in BIASES}
+    for step in range(3):
+        (counts,) = exe.run(main, feed=_batch(step),
+                            fetch_list=[fetches["tokens_per_expert"]],
+                            scope=scope)
+        for i, n in enumerate(BIASES):
+            want[n] = np.asarray(ref.next_bias(want[n], counts[i], GAMMA))
+            assert np.array_equal(
+                np.asarray(scope.find_var(n + ".load")), counts[i])
+    for n in BIASES:
+        b = scope.find_var(n)
+        assert b.dtype == jnp.float32 and np.array_equal(np.asarray(b),
+                                                         want[n])
+        assert np.abs(want[n]).max() > 0
+    block = main.global_block()
+    assert not block.has_var("l1.router.bias@GRAD")
+    state = set(scope.local_var_names())
+    assert any(n.startswith("l1.router.w_moment") for n in state)
+    assert not any(n.startswith("l1.router.bias_") for n in state)
+    assert block.var("l1.router.bias").trainable is False
+    assert block.var("l1.router.bias").persistable
+    # saved with the weights, loaded into a fresh scope
+    io.save_persistables(exe, str(tmp_path), main_program=main, scope=scope)
+    fresh = fluid.Scope()
+    exe.run(startup, scope=fresh)
+    assert np.all(np.asarray(fresh.find_var("l2.router.bias")) == 0)
+    io.load_persistables(exe, str(tmp_path), main_program=main, scope=fresh)
+    for n in BIASES:
+        assert np.array_equal(np.asarray(fresh.find_var(n)), want[n])
+
+
+def test_the_backward_pass_differentiates_the_choice_the_forward_made():
+    """The update overwrites `b` before the grad ops run, and a grad op
+    reads the scope's values: the router reads a copy taken before. With a
+    huge update rate, after which the overwritten bias would choose other
+    experts, the router's gradient is still the reference's at the old b."""
+    main, startup, fetches, pairs = _program(bias_update_rate=5.0, n_layer=2)
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    names = _parameter_names(main)
+    _seeded_weights(scope, names)
+    params = {n: np.asarray(scope.find_var(n)) for n in names}
+    feed = _batch()
+    grad = next(g for p, g in pairs if p.name == "l1.router.w")
+    (got,) = exe.run(main, feed=feed, fetch_list=[grad], scope=scope)
+    assert np.abs(np.asarray(scope.find_var("l1.router.bias"))).max() > 4
+    _, want = ref.loss_and_grads(
+        params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
+        wrt=["l1.router.w"], **{**REF_KW, "n_layer": 2})
+    assert frob(got, want["l1.router.w"]) < 2e-4
+
+
+def test_tiny_model_amp_within_bf16_of_reference():
+    """Under AMP the residual stream, the projections, attention and the
+    experts are bf16; the router's scores, `b`, every norm's statistics and
+    rotary's trigonometry stay float32. At the initial weights (a sharper
+    router flips a few assignments under bf16 inputs)."""
+    _, params, feed, got, grads, after = _run_tiny(amp=True, seeded=False)
+    want, want_grads = ref.loss_and_grads(
+        params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
+        last=TINY["seq_len"], **REF_KW)
+    assert abs(float(got["loss"][0]) - float(want["loss"])) < 0.002
+    assert got["logits"].dtype == jnp.bfloat16
+    err = np.abs(np.asarray(got["logits"], np.float32)
+                 - np.asarray(want["logits"]))
+    std = float(np.std(want["logits"]))
+    assert err.mean() < 0.02 * std and err.max() < 0.1 * std
+    for name in ("l0.mla.q.w", "l0.mla.kv_a.w", "l0.mla.kv_b.w",
+                 "l2.mla.o.w", "l0.mlp.gate.w", "l1.experts.gate.w",
+                 "l1.shared.up.w", "embed.w"):
+        assert grads[name].dtype == np.float32
+        # a routed expert's gradient feels every assignment that a bf16
+        # input flips to another expert (a whole row of it)
+        limit = 0.08 if ".experts." in name else 0.04
+        assert frob(grads[name], want_grads[name]) < limit, name
+    for name in BIASES:
+        assert after[name].dtype == np.float32
+
+
+def test_amp_lists_hold_the_router_and_attention():
+    assert "moe_router" in registry.AMP_F32_OPS
+    assert "reduce_mean" in registry.AMP_F32_OPS
+    assert "fused_attention" in registry.AMP_BF16_OPS
+    for op in ("assign", "sign", "scale", "cast", "sum", "rotary_embedding",
+               "rms_norm"):
+        assert op not in registry.AMP_F32_OPS | registry.AMP_BF16_OPS
+
+
+def test_five_adam_steps_lower_the_loss():
+    main, startup, fetches, _ = _program(
+        fluid.optimizer.Adam(learning_rate=3e-3))
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    feed = _batch()
+    losses = [float(exe.run(main, feed=feed, fetch_list=[fetches["loss"]],
+                            scope=scope)[0][0]) for _ in range(6)]
+    assert np.all(np.isfinite(losses)) and losses[-1] < losses[0] - 0.05
+
+
+def test_attention_out_has_the_value_width():
+    main, _, _, _ = _program(n_layer=1)
+    block = main.global_block()
+    (op,) = [o for o in block.ops if o.type == "fused_attention"]
+    assert block.var(op.input("Q")[0]).shape[1:] == (4, 128, 24)
+    assert block.var(op.input("K")[0]).shape[1:] == (4, 128, 24)
+    assert block.var(op.input("V")[0]).shape[1:] == (4, 128, 16)
+    assert block.var(op.output("Out")[0]).shape[1:] == (4, 128, 16)
+    assert op.attrs["sm_scale"] == 24 ** -0.5
+
+
+# -- spans and counters ---------------------------------------------------------------------
+
+def test_compile_event_carries_the_census():
+    main, startup, fetches, _ = _program(
+        fluid.optimizer.SGD(learning_rate=1e-3))
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    exe.run(main, feed=_batch(), fetch_list=[fetches["loss"]], scope=scope)
+    detail = observe.observatory().latest(main._uid).detail
+    assert detail["layer_kinds"] == {"latent_attention": 3}
+    assert detail["attention_qk_width"] == 24
+    assert detail["attention_value_width"] == 16
+    assert detail["dense_ffn_layers"] == 1
+    assert detail["moe_router_score"] == "sigmoid"
+    assert detail["moe_router_bias_updates"] == 2
+    assert detail["moe_router_bias_vars"] == [
+        [n, n + ".load"] for n in BIASES]
+    assert detail["moe_experts_routed"] == 16
+    assert detail["moe_experts_held"] == 4
+    assert detail["moe_row_buffer_rows"] == 2 * 128 * 3 + 4 * 128
+    assert detail["moe_share_bounded_moves"] == 2 * 4
+    assert "layer_kinds" not in observe.observatory().latest(
+        startup._uid).detail
+
+
+def test_step_log_carries_the_bias_and_the_load_while_observing():
+    main, startup, fetches, _ = _program(
+        fluid.optimizer.SGD(learning_rate=1e-3))
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    fluid.flags.set_flag("observe", True)
+    try:
+        (counts,) = exe.run(main, feed=_batch(),
+                            fetch_list=[fetches["tokens_per_expert"]],
+                            scope=scope)
+        last = [s for s in observe.get_steplog().recent(4)
+                if s.program_uid == main._uid][-1].as_dict()
+    finally:
+        fluid.flags.set_flag("observe", False)
+    assert last["router_bias_abs_max"] == pytest.approx(GAMMA)
+    assert last["router_load_max"] == counts.max()
+    assert last["router_load_min"] == counts.min()
+
+
+def test_every_layer_is_built_under_its_name_scopes(tiny):
+    scopes = {}
+    for op in tiny["main"].global_block().ops:
+        if op.attrs.get("__role__") is None:
+            scopes.setdefault(op.attrs.get(ir.NAME_SCOPE_ATTR), set()) \
+                .add(op.type)
+    assert {"l0.mla", "l1.mla", "l2.mla", "l0.mlp", "l1.moe",
+            "l2.moe"} <= set(scopes)
+    assert "l0.moe" not in scopes and "l1.mlp" not in scopes
+    assert {"fused_attention", "rotary_embedding", "concat", "expand",
+            "rms_norm"} <= scopes["l1.mla"]
+    assert "swiglu" in scopes["l0.mlp"]
+    assert {"moe_router", "moe_dispatch", "grouped_matmul", "moe_combine",
+            "sign", "assign"} <= scopes["l2.moe"]
+
+
+# -- the others are what they were -------------------------------------------------------------
+
+QWEN3_NEXT_DIGEST = (542, "cbc1de6c08cb78225be52b50867e6fc6"
+                          "0fa186f02d8b105d72ae412204b91fef")
+
+
+@pytest.mark.parametrize("model", ["olmoe", "qwen3_next"])
+def test_softmax_routed_programs_are_unchanged_op_for_op(model):
+    """The router took a score function, a bias and a scaling factor,
+    rotary an interleaved pairing and `fused_attention` a value width in
+    this file's PR; a program that passes none of them is the program it
+    was: the digests were taken on the parent commit."""
+    from test_qwen3_next import TINY as QWEN3_NEXT_TINY
+    build, sizes, digest = {
+        "olmoe": (models.olmoe.build, dict(
+            vocab_size=128, seq_len=128, n_layer=2, d_model=64, n_head=2,
+            n_expert=8, top_k=2, d_expert=32), OLMOE_DIGEST),
+        "qwen3_next": (models.qwen3_next.build, QWEN3_NEXT_TINY,
+                       QWEN3_NEXT_DIGEST)}[model]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        _, fetches = build(**sizes)
+        fluid.optimizer.Adam(learning_rate=1e-3).minimize(fetches["loss"])
+    assert _program_digest(main) == digest
+    routers = [o for o in main.global_block().ops if o.type == "moe_router"]
+    assert routers and all(
+        set(o.inputs) == {"X", "W"} and not
+        {"score_func", "norm_eps", "scaling_factor"} & set(o.attrs)
+        for o in routers)
+
+
+def test_the_two_copies_of_the_reference_are_identical():
+    assert filecmp.cmp(
+        os.path.join(HERE, "kanana2_reference.py"),
+        os.path.join(ROOT, "benchmark", "references",
+                     "kanana2_reference.py"), shallow=False)
+
+
+def test_the_tiny_block_runs_through_the_benchmark():
+    """`run.py --tiny` on the cell: the configuration's tiny block through
+    the harness's own rehearsal, the in-run reference comparison
+    included."""
+    out = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
+         "--workload", "kanana_2_30b_a3b.bs1", "--seed", "3000000019",
+         "--seconds", "1", "--trace", "0", "--tiny"],
+        capture_output=True, text=True, timeout=600, cwd=ROOT,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    assert "REHEARSAL" in out.stdout and "reference check after" in out.stdout
+    line = json.loads(out.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["rehearsal"] is True

@@ -94,3 +94,32 @@ def test_share_movements_compile_at_the_cells_shapes(one_chip):
     assert " while(" in combine.as_text()
     # no 40960-row gather of the layout is left in either
     assert f"[{n * k},{width}]" not in text + combine.as_text()
+
+
+def test_flash_kernels_compile_at_latent_attentions_widths(one_chip,
+                                                           monkeypatch):
+    """The streaming forward and the split backward pair as
+    `kanana_2_30b_a3b.bs1` calls them: bf16 q, k `[1, 32, 4096, 192]` over
+    v `[1, 32, 4096, 128]`. A 192-wide block is the array's whole last axis
+    (one and a half vregs of lanes): Mosaic takes it; `Out` and `dV` leave at
+    128, `dQ` and `dK` at 192, and no 192-wide value is anywhere."""
+    from paddle_tpu.ops import pallas_attention as pa
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, v = arg((1, 32, 4096, 192)), arg((1, 32, 4096, 128))
+    assert pa._fwd_plan(4096, pa._blk(4096, True)[1]) == "stream"
+    assert pa._bwd_plan(4096, 192, pa._blk(4096, True)[1]) == "split"
+    fwd = jax.jit(lambda q, k, v: pa._flash_forward(
+        q, k, v, True, 192 ** -0.5)).lower(q, q, v).compile()
+    (call,) = _custom_calls(fwd, "flash_fwd")
+    assert "(bf16[32,4096,128]{" in call and "f32[32,1,4096]{" in call
+    bwd = jax.jit(lambda q, k, v, o, lse, g: pa._flash_backward(
+        q, k, v, o, lse, g, True, 192 ** -0.5, 0.0, 0)).lower(
+            q, q, v, v, arg((32, 1, 4096), jnp.float32), v).compile()
+    (dq,) = _custom_calls(bwd, "flash_dq")
+    (dkv,) = _custom_calls(bwd, "flash_dkv")
+    assert "= bf16[32,4096,192]{" in dq
+    assert "(bf16[32,4096,192]{" in dkv and ", bf16[32,4096,128]{" in dkv
