@@ -1,7 +1,8 @@
 """Linear attention by the gated delta rule (Gated DeltaNet; the mixer of
 three layers in four of `qwen3_next`), and the two small ops around it.
 
-    causal_conv1d:      a depthwise convolution over time, then silu
+    causal_conv1d:      a depthwise convolution over time, then silu (two
+                        Pallas kernels too: the end of this docstring)
     delta_rule_gates:   g = -exp(A_log) * softplus(a + dt_bias) <= 0 (the log
                         of a head's decay), beta = sigmoid(b)
     gated_delta_rule:   per value head a state S [key, value], S_0 = 0:
@@ -64,6 +65,30 @@ is registered (`gated_delta_rule_grad`): on the saved `States` it runs
 form. q, k, v arrive in bf16 under AMP; none of the three ops is on an AMP
 list but `delta_rule_gates`, which is on AMP_F32_OPS so that a and b are
 widened before the softplus.
+
+`causal_conv1d` makes one pass over its arrays each way where channels
+fill lanes and tokens sublane tiles (`_conv_plan`: C a multiple of 128, T
+of 16, at most 9 taps: the published 8192 channels, 4096 tokens, 4 taps),
+as two more Pallas kernels; the widened tile, the shifted taps, the
+pre-activation, silu and its derivative stay in VMEM:
+
+    causal_conv_fwd   grid (batch, channel block, time block); reads a
+                      block of X as it arrives and the 16 rows before it (a
+                      second block of the same array; zeros at t = 0), W as
+                      [K, Cb] float32; the K taps summed in float32, silu,
+                      Out in X's dtype. Saves nothing.
+    causal_conv_bwd   grid (channel block, batch, time block), the time
+                      blocks last to first; reads X (with the rows before),
+                      dOut and W; makes the pre-activation again, dpre =
+                      dOut silu'(pre) in float32, dX[t] = sum_j W[j]
+                      dpre[t + K-1-j] with dpre's first rows of the block
+                      after carried in scratch (zeros after the end), and
+                      dW [K, Cb] float32, resident while the batch and the
+                      time blocks add to it.
+
+Inside a time block both work on 64 rows at a time, so a loop step's tiles
+stay in vregs. Elsewhere the op is the jnp form `_conv_xla` and the grad op
+(`causal_conv1d_grad`, registered) its `jax.vjp`.
 """
 
 from __future__ import annotations
@@ -78,19 +103,49 @@ from ..core.registry import call_rule, get_op_def, register_grad, register_op
 from .pallas_attention import _interpret
 
 
-@register_op("causal_conv1d")
-def _causal_conv1d(ctx, X, W):
-    """X [B, T, C], W [C, K]: `y[t, c] = sum_j W[c, j] x[t - (K-1) + j, c]`
-    (zeros before t = 0, so output t reads inputs <= t only), then silu
-    unless `activation` is empty. Float32 sums, the input's dtype out."""
+def _conv_xla(X, W, silu):
+    """The convolution as plain jnp, and the form `jax.vjp` differentiates
+    outside the kernels' envelope."""
     K = W.shape[1]
     T = X.shape[1]
     x32 = jnp.pad(X.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
     w32 = W.astype(jnp.float32)
     y = sum(x32[:, j:j + T] * w32[:, j] for j in range(K))
-    if ctx.attr("activation", "silu") == "silu":
+    if silu:
         y = jax.nn.silu(y)
-    return {"Out": y.astype(X.dtype)}
+    return y.astype(X.dtype)
+
+
+@register_op("causal_conv1d")
+def _causal_conv1d(ctx, X, W):
+    """X [B, T, C], W [C, K]: `y[t, c] = sum_j W[c, j] x[t - (K-1) + j, c]`
+    (zeros before t = 0, so output t reads inputs <= t only), then silu
+    unless `activation` is empty. Float32 sums, the input's dtype out. One
+    pass over X as `causal_conv_fwd` where `_conv_plan` gives the kernels."""
+    silu = ctx.attr("activation", "silu") == "silu"
+    if _conv_kernels_run(X.shape[1], X.shape[2], W.shape[1]):
+        return {"Out": _conv_forward(X, W, silu)}
+    return {"Out": _conv_xla(X, W, silu)}
+
+
+@register_grad("causal_conv1d")
+def _causal_conv1d_grad(ctx, ins, out_grads):
+    """dX and dW from X, W and dOut alone (the forward saves nothing): one
+    pass as `causal_conv_bwd`, which makes the pre-activation again in VMEM;
+    outside the envelope `jax.vjp` of the jnp form, as the generic grad
+    lowering would."""
+    d_out = out_grads["Out"][0]
+    if d_out is None:
+        return {}
+    X, W = ins["X"][0], ins["W"][0]
+    d_out = d_out.astype(X.dtype)
+    silu = ctx.attr("activation", "silu") == "silu"
+    if _conv_kernels_run(X.shape[1], X.shape[2], W.shape[1]):
+        dX, dW = _conv_backward(X, W, d_out, silu)
+    else:
+        _, vjp = jax.vjp(lambda x, w: _conv_xla(x, w, silu), X, W)
+        dX, dW = vjp(d_out)
+    return {"X": dX, "W": dW.astype(W.dtype)}
 
 
 @register_op("delta_rule_gates")
@@ -186,12 +241,16 @@ def _on_chip():
     return jax.default_backend() != "cpu"
 
 
-def _kernels_run(Dk, Dv, chunk):
-    """Whether this backend takes the kernels for a shape `_plan` gives
+def _backend_takes_kernels():
+    """Whether this backend takes the kernels for a shape a plan gives
     them: always on a TPU; on a CPU backend only under the interpreter's
     rehearsal switch (`pallas_attention._interpret`, refused on the chip),
     since a model interpreted at the cell's widths never ends."""
-    return _plan(Dk, Dv, chunk) == "kernel" and (_on_chip() or _interpret())
+    return _on_chip() or _interpret()
+
+
+def _kernels_run(Dk, Dv, chunk):
+    return _plan(Dk, Dv, chunk) == "kernel" and _backend_takes_kernels()
 
 
 def _dot(a, b, dims, full=False):
@@ -500,6 +559,211 @@ def _gdn_backward(Q, K, V, g, beta, states, d_out, chunk):
     return (dq.reshape(Q.shape), dk.reshape(K.shape), dv.reshape(V.shape),
             dg.reshape(g.shape).astype(g.dtype),
             per_token(dbeta).reshape(beta.shape).astype(beta.dtype))
+
+
+# ---------------------------------------------------------------------------
+# the causal convolution's two kernels: one pass over X each way
+# ---------------------------------------------------------------------------
+
+_HALO = 16      # rows a step reads before its time block: a packed bf16 tile
+_PAD = 8        # float32 sublanes of them that the taps can reach: K - 1 <= 8
+_CONV_ROWS = 64     # rows a loop step inside a time block works on
+
+
+def _conv_plan(T, C, K):
+    """"kernel": channels in whole lanes of 128, tokens in whole sublane
+    tiles (16 rows: bf16 packs two to a sublane) and the K - 1 rows a tap
+    reaches back inside one float32 tile. "xla": anything else (the CPU
+    tests' `X (2, 6, 3)`), which keeps `_conv_xla` and its vjp. The choice
+    reads the shape alone."""
+    if C % 128 == 0 and T % _HALO == 0 and 1 <= K <= _PAD + 1:
+        return "kernel"
+    return "xla"
+
+
+def _conv_kernels_run(T, C, K):
+    return _conv_plan(T, C, K) == "kernel" and _backend_takes_kernels()
+
+
+def _conv_blocks(T, C):
+    """(time block, channel block, rows a loop step works on): long blocks
+    of 256 lanes (a chip probe over sixteen choices at `[1, 4096, 8192]`:
+    (2048, 256) forward 0.27 ms, backward 0.48; (512, 512) 0.30 and 0.55;
+    (512, 128) 0.44 and 0.63), a loop step small enough that its tiles stay
+    in vregs (64 rows; 16 and 128 read 10-15% slower)."""
+    Tb = next(b for b in (2048, 1024, 512, 256, 128, 64, 32, _HALO)
+              if T % b == 0)
+    Cb = 256 if C % 256 == 0 else 128
+    return Tb, Cb, min(Tb, _CONV_ROWS)
+
+
+def _taps(xx, K):
+    """xx [_PAD + R, Cb] float32, R rows with the `_PAD` rows before them
+    -> the K shifted views `x[t - (K-1) + j]`, each [R, Cb]."""
+    R = xx.shape[0] - _PAD
+    first = _PAD - (K - 1)
+    return [xx[first + j:first + j + R] for j in range(K)]
+
+
+def _weighted(views, w):
+    return sum(x * wj for x, wj in zip(views, w))
+
+
+def _conv_chunks(x_ref, halo_ref, at_start, rows, chunk, carry, reverse):
+    """`carry = chunk(xx, r0, carry)` over a time block's row chunks (last
+    to first under `reverse`), `xx` the chunk's rows of X widened with the
+    `_PAD` rows before them: from the block itself, and for the block's
+    first chunk from the `_HALO` rows before the block (zeros where the
+    block starts the sequence)."""
+    from jax.experimental import pallas as pl
+
+    n = x_ref.shape[1] // rows
+
+    def first(carry):
+        before = halo_ref[0].astype(jnp.float32)[_HALO - _PAD:]
+        before = jnp.where(at_start, 0.0, before)
+        cur = x_ref[0, 0:rows, :].astype(jnp.float32)
+        return chunk(jnp.concatenate([before, cur], axis=0), 0, carry)
+
+    def later(i, carry):
+        c = n - i if reverse else i                     # 1 .. n - 1
+        r0 = pl.multiple_of(c * rows, rows)
+        xx = x_ref[0, pl.ds(r0 - _HALO, _HALO + rows), :]
+        return chunk(xx.astype(jnp.float32)[_HALO - _PAD:], r0, carry)
+
+    if n == 1:
+        return first(carry)
+    if reverse:
+        return first(lax.fori_loop(1, n, later, carry))
+    return lax.fori_loop(1, n, later, first(carry))
+
+
+def _conv_fwd_kernel(x_ref, halo_ref, w_ref, o_ref, *, K, rows, silu):
+    """One (batch, channel block, time block) step: the K taps summed in
+    float32, silu, the block written in X's dtype."""
+    from jax.experimental import pallas as pl
+
+    w = [w_ref[j:j + 1, :] for j in range(K)]
+
+    def chunk(xx, r0, carry):
+        y = _weighted(_taps(xx, K), w)
+        if silu:
+            y = y * jax.nn.sigmoid(y)
+        o_ref[0, pl.ds(r0, rows), :] = y.astype(o_ref.dtype)
+        return carry
+
+    _conv_chunks(x_ref, halo_ref, pl.program_id(2) == 0, rows, chunk, 0,
+                 reverse=False)
+
+
+def _conv_bwd_kernel(x_ref, halo_ref, do_ref, w_ref, dx_ref, dw_ref, head_sc,
+                     acc_sc, *, K, rows, silu):
+    """One (channel block, batch, time block) step, the time blocks and the
+    chunks inside one taken last to first: `dpre = dOut * silu'(pre)` with
+    the pre-activation made again, `dX[t] = sum_j W[j] dpre[t + K-1-j]`
+    with dpre's first `_PAD` rows of the chunk after (carried; across time
+    blocks in `head_sc`; zeros after the end), and `dW[j] += sum_t dpre[t]
+    x[t - (K-1) + j]`, kept as 8 sublanes of partial sums in `acc_sc` and
+    added to the resident `[K, Cb]` block once a step."""
+    from jax.experimental import pallas as pl
+
+    t = pl.program_id(2)
+    last = pl.num_programs(2) - 1
+
+    @pl.when((pl.program_id(1) == 0) & (t == 0))
+    def _init_dw():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    @pl.when(t == 0)                    # the sequence's last block
+    def _init_head():
+        head_sc[...] = jnp.zeros_like(head_sc)
+
+    acc_sc[...] = jnp.zeros_like(acc_sc)
+    w = [w_ref[j:j + 1, :] for j in range(K)]
+
+    def chunk(xx, r0, after):
+        taps = _taps(xx, K)
+        dpre = do_ref[0, pl.ds(r0, rows), :].astype(jnp.float32)
+        if silu:
+            pre = _weighted(taps, w)
+            s = jax.nn.sigmoid(pre)
+            dpre = dpre * (s * (1.0 + pre * (1.0 - s)))
+        dd = jnp.concatenate([dpre, after], axis=0)
+        dx = _weighted([dd[K - 1 - j:K - 1 - j + rows] for j in range(K)], w)
+        dx_ref[0, pl.ds(r0, rows), :] = dx.astype(dx_ref.dtype)
+        for j in range(K):
+            p = dpre * taps[j]
+            acc_sc[j] += sum(p[i:i + 8] for i in range(0, rows, 8))
+        return dpre[:_PAD]
+
+    head_sc[...] = _conv_chunks(x_ref, halo_ref, t == last, rows, chunk,
+                                head_sc[...], reverse=True)
+    for j in range(K):
+        dw_ref[j:j + 1, :] += jnp.sum(acc_sc[j], axis=0, keepdims=True)
+
+
+def _conv_specs(Tb, Cb, K, at):
+    """The blocks both kernels read: X's time block, the `_HALO` rows
+    before it (the first block reads its own first rows and zeroes them)
+    and the weight's channel block as `[K, Cb]`; `at(*grid)` gives (batch,
+    time block, channel block)."""
+    from jax.experimental import pallas as pl
+
+    def halo(*g):
+        b, t, c = at(*g)
+        return b, jnp.maximum(t * (Tb // _HALO) - 1, 0), c
+
+    return (pl.BlockSpec((1, Tb, Cb), at), pl.BlockSpec((1, _HALO, Cb), halo),
+            pl.BlockSpec((K, Cb), lambda *g: (0, at(*g)[2])))
+
+
+def _conv_forward(X, W, silu):
+    """`causal_conv_fwd`: X [B, T, C] as it arrives, W [C, K] -> Out in X's
+    dtype. Every intermediate stays in VMEM."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (B, T, C), K = X.shape, W.shape[1]
+    Tb, Cb, rows = _conv_blocks(T, C)
+    x_spec, halo_spec, w_spec = _conv_specs(Tb, Cb, K,
+                                            lambda b, c, t: (b, t, c))
+    return pl.pallas_call(
+        functools.partial(_conv_fwd_kernel, K=K, rows=rows, silu=silu),
+        name="causal_conv_fwd", grid=(B, C // Cb, T // Tb),
+        in_specs=[x_spec, halo_spec, w_spec], out_specs=x_spec,
+        out_shape=jax.ShapeDtypeStruct(X.shape, X.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
+        interpret=_interpret(),
+    )(X, X, W.astype(jnp.float32).T)
+
+
+def _conv_backward(X, W, d_out, silu):
+    """`causal_conv_bwd`: (dX in X's dtype, dW [C, K] float32) from X, W and
+    dOut. The channel blocks lead the grid, so a block of dW stays resident
+    while the batch and the time blocks (last to first) add to it."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (B, T, C), K = X.shape, W.shape[1]
+    Tb, Cb, rows = _conv_blocks(T, C)
+    n = T // Tb
+    x_spec, halo_spec, w_spec = _conv_specs(
+        Tb, Cb, K, lambda c, b, t: (b, n - 1 - t, c))
+    dX, dW = pl.pallas_call(
+        functools.partial(_conv_bwd_kernel, K=K, rows=rows, silu=silu),
+        name="causal_conv_bwd", grid=(C // Cb, B, n),
+        in_specs=[x_spec, halo_spec, x_spec, w_spec],
+        out_specs=[x_spec, w_spec],
+        out_shape=[jax.ShapeDtypeStruct(X.shape, X.dtype),
+                   jax.ShapeDtypeStruct((K, C), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((_PAD, Cb), jnp.float32),
+                        pltpu.VMEM((K, 8, Cb), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=_interpret(),
+    )(X, X, d_out, W.astype(jnp.float32).T)
+    return dX, dW.T
 
 
 # ---------------------------------------------------------------------------

@@ -67,6 +67,33 @@ def test_gdn_kernels_compile_at_the_cells_shapes(one_chip, monkeypatch):
     assert "(f32[1,32,64,1,64]{" in call and "tpu_custom_call" in call
 
 
+def test_causal_conv_kernels_compile_at_the_cells_shape(one_chip,
+                                                        monkeypatch):
+    """`causal_conv_fwd` and `causal_conv_bwd` as `qwen3_next_80b_a3b.bs1`
+    calls them: X and dOut bf16 `[1, 4096, 8192]`, W float32 `[8192, 4]`;
+    the misaligned sublane slices of the taps and the 16-row block before a
+    time block are what the interpreter cannot refuse. One Mosaic custom
+    call each, named for the benchmark's pattern; the float32 form too."""
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    assert la._conv_plan(4096, 8192, 4) == "kernel"
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    w = arg((8192, 4), jnp.float32)
+    for dtype, short in ((jnp.bfloat16, "bf16"), (jnp.float32, "f32")):
+        x = arg((1, 4096, 8192), dtype)
+        fwd = jax.jit(lambda x, w: la._conv_forward(x, w, True)).lower(
+            x, w).compile()
+        (call,) = _custom_calls(fwd, "causal_conv_fwd")
+        assert f"= {short}[1,4096,8192]{{" in call
+        assert "tpu_custom_call" in call
+        bwd = jax.jit(lambda x, w, d: la._conv_backward(x, w, d, True)).lower(
+            x, w, x).compile()
+        (call,) = _custom_calls(bwd, "causal_conv_bwd")
+        assert f"= ({short}[1,4096,8192]{{" in call and ", f32[4,8192]{" in call
+
+
 def test_share_movements_compile_at_the_cells_shapes(one_chip):
     """A share's layout and a token-side movement as `qwen3_next_80b_a3b.bs1`
     runs them (4096 tokens, top 10 of 512, experts 64..95 held, 2048 wide):
