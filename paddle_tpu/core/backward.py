@@ -233,7 +233,12 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     mixer (`linear_attention`: a `gated_delta_rule` op, `full_attention`: a
     `fused_attention` op, `latent_attention`: a `fused_attention` whose value
     heads are narrower or wider than its key heads, with
-    `attention_qk_width` and `attention_value_width` beside it);
+    `attention_qk_width` and `attention_value_width` beside it,
+    `window_attention`: a `fused_attention` whose `window` is shorter than
+    its sequence, with `attention_window_layers`, their count again as a
+    flat number, and `attention_window` beside it); `attention_kv_group`
+    where a windowed program's keys are a `layers.expand` of fewer heads
+    (the query heads one key-value head serves);
     `dense_ffn_layers`, the `swiglu` feed-forwards built under a
     `name_scope` that holds no router, where the program has expert layers
     too; and where it has those,
@@ -249,7 +254,7 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     trace, `LoweringContext.note`.)"""
     block = program.global_block()
     kinds = {"linear_attention": 0, "full_attention": 0,
-             "latent_attention": 0}
+             "latent_attention": 0, "window_attention": 0}
     out: Dict[str, object] = {}
     # `assign` ops both ways: result -> what it copied, and the reverse
     copies: Dict[str, str] = {}
@@ -262,9 +267,17 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
         if op.type == "gated_delta_rule":
             kinds["linear_attention"] += 1
         elif op.type == "fused_attention":
-            wide = block.var(op.input("K")[0]).shape[-1]
+            keys = block.var(op.input("K")[0])
+            wide = keys.shape[-1]
             value = block.var(op.input("V")[0]).shape[-1]
-            if wide == value:
+            window = op.attrs.get("window")
+            if window is not None and window < keys.shape[-2]:
+                kinds["window_attention"] += 1
+                out["attention_window"] = window
+                group = _expanded_by(block, op.input("K")[0])
+                if group > 1:
+                    out["attention_kv_group"] = group
+            elif wide == value:
                 kinds["full_attention"] += 1
             else:
                 kinds["latent_attention"] += 1
@@ -288,6 +301,8 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
                 "experts_held", out.get("moe_experts_routed"))
     if any(kinds.values()):
         out["layer_kinds"] = {k: n for k, n in kinds.items() if n}
+    if kinds["window_attention"]:
+        out["attention_window_layers"] = kinds["window_attention"]
     dense = sum(1 for scope in gated if scope not in routed)
     if routed and dense:
         out["dense_ffn_layers"] = dense
@@ -297,6 +312,21 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
         out["moe_router_bias_updates"] = len(updated)
         out["moe_router_bias_vars"] = updated
     return out
+
+
+def _expanded_by(block, name) -> int:
+    """How many times an `expand` op repeated the heads behind `name`: the
+    op that wrote it, looked for through the `reshape`s between."""
+    by_output = {n: op for op in block.ops for n in op.output_arg_names}
+    op = by_output.get(name)
+    while op is not None and op.type in ("reshape", "reshape2"):
+        op = by_output.get(op.input("X")[0])
+    if op is None or op.type != "expand":
+        return 1
+    times = 1
+    for t in op.attrs.get("expand_times", []):
+        times *= int(t)
+    return times
 
 
 def program_detail(program: ir.Program) -> Dict[str, object]:

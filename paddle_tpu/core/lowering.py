@@ -50,9 +50,9 @@ class BlockLowerer:
         # ops are therefore covered at the control-flow op's boundary
         # (its outputs are checked at depth 1)
         self._block_depth = 0
-        # ops that counted themselves on the compile event, by key
+        # what each op counted on the compile event, by key
         # (`LoweringContext.tally`)
-        self.tallies: Dict[str, set] = {}
+        self.tallies: Dict[str, dict] = {}
 
     def run_block(self, block_idx: int, env: Dict[str, Any], key) -> Dict[str, Any]:
         """Execute all ops of `block_idx` on `env` (name -> jnp array),

@@ -150,16 +150,18 @@ class LoweringContext:
         if event is not None and isinstance(event.detail, dict):
             event.detail.update(facts)
 
-    def tally(self, key: str):
+    def tally(self, key: str, amount: int = 1):
         """Count this op under `key` on the compile event: how many of the
-        program's ops took a path that only their rule knows of. `note`
-        overwrites, so the lowerer keeps which ops have counted: an op counts
-        once however often the step is traced."""
+        program's ops took a path that only their rule knows of, or with an
+        `amount` the sum of what each of them counts (the score tiles a
+        windowed attention call computes). `note` overwrites, so the lowerer
+        keeps what each op has counted: an op counts once however often the
+        step is traced."""
         if self.lowerer is None:
             return
-        counted = self.lowerer.tallies.setdefault(key, set())
-        counted.add(id(self.op))
-        self.note(**{key: len(counted)})
+        counted = self.lowerer.tallies.setdefault(key, {})
+        counted[id(self.op)] = amount
+        self.note(**{key: sum(counted.values())})
 
 
 # AMP policy (torch-autocast style; reference analog:

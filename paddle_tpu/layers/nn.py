@@ -1369,12 +1369,19 @@ def gated_rms_norm(input, gate, epsilon=1e-6, param_attr=None, name=None):
 
 
 def rotary_embedding(input, theta=10000.0, rotary_dim=None,
-                     interleaved=False, name=None):
+                     interleaved=False, scaling=None, name=None):
     """Rotary position embedding (rotate-half convention) on
     `[batch, heads, seq, head_dim]`, positions 0..seq-1; with `rotary_dim`
     on the first `rotary_dim` dims of a head only, the others pass through.
     `interleaved`: the pairs are `(x[2i], x[2i + 1])`; the rotated dims come
-    out laid `[evens | odds]` (DeepSeek-V3's `rope_interleave`)."""
+    out laid `[evens | odds]` (DeepSeek-V3's `rope_interleave`). `scaling`:
+    a YaRN block (`factor`, `original_max_position_embeddings`, and where
+    they differ from 32, 1 and `0.1 ln(factor) + 1`: `beta_fast`,
+    `beta_slow`, `attention_factor`) gives other frequencies than
+    `theta^(-2i/R)` and tables times `attention_factor`
+    (`ops/decoder_block.py::rotary_frequencies`), at every length; float32
+    tables either way."""
+    from ..ops.decoder_block import rotary_frequencies
     helper = LayerHelper("rotary_embedding", name=name)
     out = helper.create_variable_for_type_inference(input.dtype)
     attrs = {"theta": float(theta)}
@@ -1382,6 +1389,10 @@ def rotary_embedding(input, theta=10000.0, rotary_dim=None,
         attrs["rotary_dim"] = int(rotary_dim)
     if interleaved:
         attrs["interleaved"] = True
+    if scaling is not None:
+        rotary_frequencies(int(rotary_dim or input.shape[-1]), float(theta),
+                           scaling)                # refuses at build time
+        attrs["scaling"] = {k: float(v) for k, v in dict(scaling).items()}
     helper.append_op("rotary_embedding", inputs={"X": [input.name]},
                      outputs={"Out": [out.name]}, attrs=attrs)
     return out
@@ -1466,11 +1477,20 @@ def exit_gate(input, param_attr=None, bias_attr=None, name=None):
 
 
 def fused_attention(q, k, v, causal=False, sm_scale=None, dropout_rate=0.0,
-                    is_test=False, name=None):
+                    is_test=False, window=None, name=None):
     """Softmax attention on `[batch, heads, seq, head_dim]` through the
     flash kernels (`ops/pallas_attention.py`): O(seq) memory, dropout on the
     attention weights inside the kernel. `v` may have a head width of its
     own (latent attention: q, k at 192, v at 128); the result has `v`'s.
+
+    `window=W` (with `causal=True` only): key j is visible to query i iff
+    `0 <= i - j < W`, sliding-window attention. The kernels then cover the
+    score tiles that meet that band and no others, where a causal call
+    covers the triangle's (45 tiles of 512 x 512 a head at 8192 tokens and
+    W = 1024, against 136), and fetch no block for a tile they skip. Any
+    `W >= 1` runs, aligned to a tile or not; `W >= seq` is plain causal.
+    Not under sequence parallelism (ring attention) and not in the paged
+    kernels: both raise.
 
     The op has a second output, `Lse`: the forward kernel's log-sum-exp of
     every score row, float32 `[batch * heads, 1, seq]`, the kernels' own
@@ -1484,11 +1504,16 @@ def fused_attention(q, k, v, causal=False, sm_scale=None, dropout_rate=0.0,
                                                     stop_gradient=True)
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
+    attrs = {"causal": causal, "sm_scale": sm_scale,
+             "dropout_rate": dropout_rate, "is_test": is_test}
+    if window is not None:
+        from ..ops.pallas_attention import _check_window
+        _check_window(window, causal)       # refuses at build time
+        attrs["window"] = int(window)
     helper.append_op("fused_attention",
                      inputs={"Q": [q.name], "K": [k.name], "V": [v.name]},
                      outputs={"Out": [out.name], "Lse": [lse.name]},
-                     attrs={"causal": causal, "sm_scale": sm_scale,
-                            "dropout_rate": dropout_rate, "is_test": is_test})
+                     attrs=attrs)
     return out
 
 

@@ -11,7 +11,11 @@ shared expert (the first model built for a share of a stated deployment),
 and Kanana-2: latent attention (MLA, query/key heads of 192 over value heads
 of 128 through the flash kernels), a leading dense layer, and a sigmoid
 router with a selection bias that the step itself rewrites (the first LM
-with non-trainable state beside its weights)."""
+with non-trainable state beside its weights), and Mellum2: sliding-window
+and full attention layers mixed 3:1 through the flash kernels, each kind
+with rotary tables of its own (YaRN on the full layers), 32 query heads
+over 4 key-value heads (the first model whose attention layers differ in
+their mask)."""
 
 from . import mnist  # noqa: F401
 from . import resnet  # noqa: F401
@@ -26,3 +30,4 @@ from . import olmoe  # noqa: F401
 from . import ouro  # noqa: F401
 from . import qwen3_next  # noqa: F401
 from . import kanana2  # noqa: F401
+from . import mellum2  # noqa: F401

@@ -1,7 +1,7 @@
 """The pieces of a 2024 decoder block that are not attention or a plain
 matmul: RMSNorm (plain, zero-centred, and gated over a head), rotary position
 embedding (on a whole head or its first dims, rotate-half or interleaved
-pairs), the silu-gated product, and a looped LM's exit gate.
+pairs, plain frequencies or YaRN's), the silu-gated product, and a looped LM's exit gate.
 
 No reference analog (the reference predates all three); the equations are
 those of the public `olmoe` / `llama`-style model code. Each is plain jnp,
@@ -11,6 +11,8 @@ bf16), and the result returns in the input's dtype.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -48,14 +50,60 @@ def _gated_rms_norm(ctx, X, Gate, Scale):
     return {"Y": (y * jax.nn.silu(Gate.astype(jnp.float32))).astype(X.dtype)}
 
 
-def rotary_tables(seq_len, dim, theta):
-    """cos and sin `[seq_len, dim]` of the rotate-half convention: the
-    frequencies `theta^(-2i/dim)`, i < dim/2, repeated over both halves."""
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32)
-                                / dim))
+YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast",
+             "beta_slow", "attention_factor")
+
+
+def rotary_frequencies(dim, theta, scaling=None):
+    """(the `dim / 2` frequencies of a rotary head, the factor its tables
+    are multiplied by). Plain: `theta^(-2i/dim)` and 1. With a YaRN block
+    `scaling` (arXiv:2309.00071, as the public `rope_type: yarn` code
+    computes it, applied at every length): dimension i turns
+    `original_max_position_embeddings * theta^(-2i/dim) / (2 pi)` times over
+    the original context; those that turn more than `beta_fast` times keep
+    their frequency, those that turn fewer than `beta_slow` times have it
+    divided by `factor`, a linear ramp over the dimensions between; cos and
+    sin are both scaled by `attention_factor` (`0.1 ln(factor) + 1` where
+    the block has none)."""
+    pos_freq = theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    if scaling is None:
+        return 1.0 / pos_freq, 1.0
+    unknown = sorted(set(scaling) - set(YARN_KEYS))
+    if unknown or "factor" not in scaling \
+            or "original_max_position_embeddings" not in scaling:
+        raise ValueError(
+            f"rotary_embedding's scaling is a YaRN block with keys among "
+            f"{YARN_KEYS} ('factor' and 'original_max_position_embeddings' "
+            f"required), got {dict(scaling)}")
+    factor = float(scaling["factor"])
+    original = float(scaling["original_max_position_embeddings"])
+
+    def turns_at(turns):        # the dimension that turns `turns` times
+        return dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns_at(float(scaling.get("beta_fast", 32)))), 0)
+    high = min(math.ceil(turns_at(float(scaling.get("beta_slow", 1)))),
+               dim - 1)
+    ramp = jnp.clip(
+        (jnp.arange(dim // 2, dtype=jnp.float32) - low)
+        / max(high - low, 0.001), 0.0, 1.0)
+    inv_freq = ramp / (factor * pos_freq) + (1.0 - ramp) / pos_freq
+    attention_factor = scaling.get("attention_factor")
+    if attention_factor is None:
+        attention_factor = 0.1 * math.log(factor) + 1.0
+    return inv_freq, float(attention_factor)
+
+
+def rotary_tables(seq_len, inv_freq, factor=1.0):
+    """cos and sin `[seq_len, 2 * len(inv_freq)]` of the rotate-half
+    convention: the frequencies (`rotary_frequencies`) repeated over both
+    halves, both tables times `factor`. Float32."""
     angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * inv_freq
     angles = jnp.concatenate([angles, angles], axis=-1)
-    return jnp.cos(angles), jnp.sin(angles)
+    if factor == 1.0:
+        return jnp.cos(angles), jnp.sin(angles)
+    return jnp.cos(angles) * factor, jnp.sin(angles) * factor
 
 
 @register_op("rotary_embedding", propagate_seqlen=False)
@@ -67,13 +115,15 @@ def _rotary_embedding(ctx, X):
     `interleaved` the pair is `(x[2i], x[2i + 1])` (DeepSeek-V3's
     `rope_interleave`): the R dims are first laid out `[evens | odds]`, as
     the public code does, and stay so in the output (queries and keys alike,
-    so their products agree)."""
+    so their products agree). With the attribute `scaling` (a YaRN block)
+    the frequencies and the tables' factor are `rotary_frequencies`'."""
     T, D = X.shape[-2], X.shape[-1]
     R = int(ctx.attr("rotary_dim") or D)
     if R % 2 or R > D:
         raise ValueError(f"rotary_embedding needs an even rotary size within "
                          f"the head, got {R} of {D}")
-    cos, sin = rotary_tables(T, R, float(ctx.attr("theta", 10000.0)))
+    cos, sin = rotary_tables(T, *rotary_frequencies(
+        R, float(ctx.attr("theta", 10000.0)), ctx.attr("scaling")))
     x32 = X.astype(jnp.float32)
     head = x32 if R == D else x32[..., :R]
     if ctx.attr("interleaved", False):

@@ -304,6 +304,16 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_lens,
 # registered ops (the decode/prefill program building blocks)
 # ---------------------------------------------------------------------------
 
+def _no_window(ctx):
+    """The paged kernels attend over every block of a sequence's table."""
+    if ctx.attr("window") is not None:
+        raise NotImplementedError(
+            f"a window ({ctx.attr('window')}) is not supported by the paged "
+            f"attention ops: the decode kernel walks a sequence's whole block "
+            f"table and the cache frees no block that has left the window; "
+            f"windowed layers train through fused_attention only")
+
+
 @register_op("paged_attention", propagate_seqlen=False)
 def _paged_attention_op(ctx, Q, K, V, KCache, VCache, BlockTables, SeqLens):
     """One decode step. Q/K/V: [slots, d_model] — this step's token per
@@ -311,6 +321,7 @@ def _paged_attention_op(ctx, Q, K, V, KCache, VCache, BlockTables, SeqLens):
     alias the cache vars, so the executor donates the HBM buffers), then
     attends over [0, seq_len) through the block table. attrs: num_heads,
     sm_scale."""
+    _no_window(ctx)
     H = int(ctx.attr("num_heads", 1))
     S, D = Q.shape
     Dh = D // H
@@ -331,6 +342,7 @@ def _prefill_attention_op(ctx, Q, K, V, KCache, VCache, BlockTables,
     invisible to valid positions under the causal mask) and scatters each
     row's K/V into its blocks in the same step. attrs: num_heads,
     sm_scale."""
+    _no_window(ctx)
     H = int(ctx.attr("num_heads", 1))
     B, T, D = Q.shape
     Dh = D // H
@@ -508,6 +520,7 @@ def _paged_attention_q8_op(ctx, Q, K, V, KCache, VCache, KScale, VScale,
     paged_attention plus per-block scale vars ([num_blocks] f32, updated
     in place alongside their cache) and a [1] int32 requant-event
     counter the serve engine meters."""
+    _no_window(ctx)
     H = int(ctx.attr("num_heads", 1))
     S, D = Q.shape
     Dh = D // H
@@ -533,6 +546,7 @@ def _prefill_attention_q8_op(ctx, Q, K, V, KCache, VCache, KScale, VScale,
     bit-identical to the fp cache), quantization happens only at the
     residency write. No requant counter: prefill always owns the blocks
     it writes."""
+    _no_window(ctx)
     H = int(ctx.attr("num_heads", 1))
     B, T, D = Q.shape
     Dh = D // H

@@ -53,6 +53,19 @@ block is the array's whole last axis, which is a legal block. Where `Dv == D`
 every kernel is the instructions it was. `_bwd_plan` reads `D`: the resident
 dQ row is query-wide.
 
+A causal call may carry a `window` W: key j is visible to query i iff
+`0 <= i - j < W` (sliding-window attention). The mask gains its lower edge and
+so does the liveness predicate, in one pair of functions that all five kernels
+share. The windowed calls shorten the grid's inner axis to the band's width in
+tiles (`_band_steps`): a q-block's K tiles are counted from the lowest tile of
+its band (the forward and dQ), a k-block's Q tiles up to the highest of its
+band (dK/dV and the fused backward), the few steps that fall outside the band
+compute nothing and their index maps stay on the live neighbour, so no block
+is fetched that is not used. They carry names of their own (`swa_flash_fwd`,
+`swa_flash_dq`, ...): the device track tells a windowed layer from a full one.
+`W >= T` is plain causal and runs the plain kernels; without a window every
+kernel is the instructions it was.
+
 On a CPU backend the same kernels run under the Pallas interpreter when
 PADDLE_TPU_PALLAS_INTERPRET=1 (used by the CPU test suite); otherwise a
 pure-jnp reference path takes over there. On the TPU there is no second
@@ -104,7 +117,7 @@ def _table_blk(T, causal):
     return None
 
 
-def _blk(T, causal=False):
+def _blk(T, causal=False, window=None):
     """Block sizes (BQ, BK) for sequence length T. Tuned by the chained
     sweeps on v5e (tools/flash_block_sweep.py, docs/PERF.md): the
     per-(seq, causal) table above where measured, else the biggest
@@ -112,11 +125,26 @@ def _blk(T, causal=False):
     winner; bigger streamed BK means fewer sequential grid steps to
     pipeline). Since the kernels stream K/V (resp. Q) through the grid's
     innermost dimension, VMEM per program is O(blk_q * blk_k + blk * D)
-    regardless of T — no sequence-length cap (validated to seq 32768)."""
+    regardless of T — no sequence-length cap (validated to seq 32768).
+
+    Under a `window` shorter than T the tiles are square and at most half
+    the window (128 at least): a band of W keys a row meets about W + b keys
+    of b-wide tiles, so 1024 x 1024 tiles at W = 1024 compute twice the
+    visible pairs and 512 x 512 one and a half times. Measured at one shape
+    only, [32, 8192, 128] bf16 with W = 1024, forward + split backward a
+    layer (chip run, PR 40): 8.30 ms at 1024^2, 7.04 at 512^2, 11.31 at
+    256^2 (8.4-9.8 at the four mixed shapes). That shape's result is 512;
+    every other length and window takes the rule as a default that no run
+    has tried, and does not consult the sweep table above, whose entries
+    were measured without a window."""
     if _BLOCK_OVERRIDE is not None:
         bq, bk = _BLOCK_OVERRIDE
         if T % bq == 0 and T % bk == 0:
             return bq, bk
+    if window is not None and window < T:
+        for b in (1024, 512, 256, 128):
+            if T % b == 0 and b <= max(window // 2, 128):
+                return b, b
     tbl = _table_blk(T, causal)
     if tbl is not None and T % tbl[0] == 0 and T % tbl[1] == 0:
         return tbl
@@ -173,13 +201,15 @@ def _interpret():
 # ---------------------------------------------------------------------------
 
 def _attention_reference(q, k, v, causal, sm_scale, dropout_rate=0.0,
-                         seed=None):
+                         seed=None, window=None):
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * sm_scale
     if causal:
         Tq, Tk = s.shape[-2], s.shape[-1]
         row = jnp.arange(Tq)[:, None]
         col = jnp.arange(Tk)[None, :]
         s = jnp.where(col > row, NEG_INF, s)
+        if window is not None:
+            s = jnp.where(row - col >= window, NEG_INF, s)
     p = jax.nn.softmax(s, axis=-1)
     if dropout_rate:
         key = jax.random.key(seed if seed is not None else 0)
@@ -199,19 +229,96 @@ _HASH_A = int(np.int32(np.uint32(2654435761)))
 _HASH_B = 40503
 
 
-def _causal_live(qi, kj, blk_q, blk_k):
-    """Whether the (qi, kj) block intersects the causal lower triangle.
-    Shared by all the kernels — block coverage and dropout-mask seeding
-    are keyed to the same (qi, kj) indices, so the fwd/dQ/dKV predicates
-    must be structurally identical."""
-    return kj * blk_k <= qi * blk_q + blk_q - 1
+def _causal_live(qi, kj, blk_q, blk_k, window=None):
+    """Whether the (qi, kj) block intersects the causal lower triangle and,
+    under a `window` W, the band `0 <= row - col < W` inside it: the block's
+    last key has to reach the lowest key its first query sees. A tile that
+    is not live is not computed, so coverage is the triangle's tiles without
+    a window and the band's with one (at T = 8192, W = 1024 and tiles of
+    512: 45 of the triangle's 136). Shared by all the kernels — block coverage and
+    dropout-mask seeding are keyed to the same (qi, kj) indices, so the
+    fwd/dQ/dKV predicates must be structurally identical. `qi` or `kj` may
+    lie outside the array on a windowed grid's spare steps: those are not
+    live by the same two inequalities."""
+    live = kj * blk_k <= qi * blk_q + blk_q - 1
+    if window is not None:
+        live = live & (kj * blk_k + blk_k - 1 > qi * blk_q - window)
+    return live
 
 
-def _apply_causal_mask(s, qi, kj, blk_q, blk_k):
-    """Mask strictly-above-diagonal entries of one score tile."""
+def _apply_causal_mask(s, qi, kj, blk_q, blk_k, window=None):
+    """Mask strictly-above-diagonal entries of one score tile, and under a
+    `window` W those W or more below it."""
     row = qi * blk_q + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
     col = kj * blk_k + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-    return jnp.where(col > row, NEG_INF, s)
+    s = jnp.where(col > row, NEG_INF, s)
+    if window is not None:
+        s = jnp.where(row - col >= window, NEG_INF, s)
+    return s
+
+
+# -- the band of a window over tiles ----------------------------------------
+# A q-block's band runs from K tile `_first_k` to its diagonal tile
+# `_last_k`; a k-block's from Q tile `_first_q` (its diagonal) to `_last_q`.
+# The same arithmetic on Python ints (the grid's width) and on traced int32
+# (program ids in a kernel, grid indices in an index map).
+
+def _int_ops(x):
+    """(max, min, floor division of non-negatives) for `x`'s kind."""
+    if isinstance(x, int):
+        return max, min, lambda a, b: a // b
+    return jnp.maximum, jnp.minimum, lambda a, b: lax.div(a, jnp.int32(b))
+
+
+def _first_k(qi, blk_q, blk_k, window):
+    mx, _, div = _int_ops(qi)
+    return div(mx(qi * blk_q - window + 1, 0), blk_k)
+
+
+def _last_k(qi, blk_q, blk_k):
+    return _int_ops(qi)[2](qi * blk_q + blk_q - 1, blk_k)
+
+
+def _first_q(kj, blk_q, blk_k):
+    return _int_ops(kj)[2](kj * blk_k, blk_q)
+
+
+def _last_q(kj, blk_q, blk_k, window, nq):
+    _, mn, div = _int_ops(kj)
+    return mn(div(kj * blk_k + blk_k + window - 2, blk_q), nq - 1)
+
+
+def _band_steps(T, blk_q, blk_k, window):
+    """(K tiles the widest band of a q-block spans, Q tiles the widest band
+    of a k-block spans): the inner axes of the windowed grids."""
+    nq, nk = T // blk_q, T // blk_k
+    nkw = max(_last_k(qi, blk_q, blk_k) - _first_k(qi, blk_q, blk_k, window)
+              for qi in range(nq)) + 1
+    nqw = max(_last_q(kj, blk_q, blk_k, window, nq)
+              - _first_q(kj, blk_q, blk_k) for kj in range(nk)) + 1
+    return nkw, nqw
+
+
+def _band_kj(qi, step, blk_q, blk_k, window):
+    """The K tile of inner step `step` of q-block `qi`: counted up from the
+    band's lowest tile; steps past the diagonal are not live."""
+    return _first_k(qi, blk_q, blk_k, window) + step
+
+
+def _band_qi(kj, step, steps, blk_q, blk_k, window, nq):
+    """The Q tile of inner step `step` (of `steps`) of k-block `kj`: counted
+    so that the last step is the band's highest tile; steps before the
+    diagonal are not live."""
+    return _last_q(kj, blk_q, blk_k, window, nq) - (steps - 1) + step
+
+
+def window_tiles(T, window):
+    """Score tiles a windowed forward call computes a head: what
+    `fused_attention` tallies on the compile event, times its batch and
+    heads."""
+    bq, bk = _blk(T, True, window)
+    return sum(_last_k(qi, bq, bk) + 1 - _first_k(qi, bq, bk, window)
+               for qi in range(T // bq))
 
 
 def _dropout_mask(seed_ref, bh, qi, kj, shape, rate):
@@ -229,7 +336,7 @@ def _dropout_mask(seed_ref, bh, qi, kj, shape, rate):
     return bits >= jnp.int32(thresh)
 
 
-def _score_tile(q_ref, k_ref, qi, kj, sm_scale, causal):
+def _score_tile(q_ref, k_ref, qi, kj, sm_scale, causal, window=None):
     """One float32 [blk_q, blk_k] tile of q k^T * sm_scale, the causal mask
     applied in-register. The dots run in the INPUT dtype (bf16 under AMP ->
     full MXU rate; the round-3 kernels upcast to f32 first, quartering
@@ -239,7 +346,8 @@ def _score_tile(q_ref, k_ref, qi, kj, sm_scale, causal):
     s = lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32) * sm_scale
     if causal:
-        s = _apply_causal_mask(s, qi, kj, q_ref.shape[1], k_ref.shape[1])
+        s = _apply_causal_mask(s, qi, kj, q_ref.shape[1], k_ref.shape[1],
+                               window)
     return s
 
 
@@ -254,7 +362,7 @@ def _weights_times_v(p, v_ref, seed_ref, bh, qi, kj, dropout_rate):
 
 
 def _flash_fwd_onepass_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                              *, sm_scale, causal, dropout_rate):
+                              *, sm_scale, causal, dropout_rate, window=None):
     """A row is one K block (`_fwd_plan`): the softmax of a q-block is
     whole in its one grid step, so there is no running maximum to correct,
     nothing carried in scratch and no branch. The row statistics keep the
@@ -266,7 +374,7 @@ def _flash_fwd_onepass_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     bh = pl.program_id(0)
     qi = pl.program_id(1)
-    s = _score_tile(q_ref, k_ref, qi, 0, sm_scale, causal)
+    s = _score_tile(q_ref, k_ref, qi, 0, sm_scale, causal, window)
     m = jnp.max(s, axis=1, keepdims=True)              # [blk_q, 1]
     p = jnp.exp(s - m)
     l = jnp.maximum(jnp.sum(p, axis=1, keepdims=True), 1e-20)
@@ -293,7 +401,7 @@ def _lanes(x, n):
 
 def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                       m_sc, l_sc, acc_sc, *,
-                      sm_scale, causal, dropout_rate):
+                      sm_scale, causal, dropout_rate, window=None):
     """A row has several K blocks. K/V STREAM through the grid's innermost
     ("arbitrary") dimension: each program sees one [blk_k, D] K/V block,
     with the online-softmax state carried in VMEM scratch across kj
@@ -302,29 +410,34 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     8192 with D=128). The running maximum and sum are [blk_q, _LANES]
     arrays, lane-replicated: the reductions of the score tile keep their
     dimension and broadcast into them along lanes, and the only relayout
-    is the `Lse` row written after the last block."""
+    is the `Lse` row written after the last block. Under a `window` the
+    inner axis counts the tiles of the q-block's band (`_band_kj`), lowest
+    first: a row whose window has not reached into a tile adds `exp(0)`s to
+    a state that the diagonal tile, always the last live one, scales by
+    `exp(NEG_INF - m) = 0`."""
     from jax.experimental import pallas as pl
 
     bh = pl.program_id(0)
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    step = pl.program_id(2)
     nk = pl.num_programs(2)
     blk_q = q_ref.shape[1]
     blk_k = k_ref.shape[1]
     Dv = v_ref.shape[2]
+    kj = step if window is None else _band_kj(qi, step, blk_q, blk_k, window)
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         m_sc[...] = jnp.full_like(m_sc, NEG_INF)
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
     # causal: blocks entirely above the diagonal contribute nothing
-    live = _causal_live(qi, kj, blk_q, blk_k) if causal else True
+    live = _causal_live(qi, kj, blk_q, blk_k, window) if causal else True
 
     @pl.when(live)
     def _update():
-        s = _score_tile(q_ref, k_ref, qi, kj, sm_scale, causal)
+        s = _score_tile(q_ref, k_ref, qi, kj, sm_scale, causal, window)
         m = m_sc[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - _lanes(m_new, blk_k))
@@ -334,7 +447,7 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             p, v_ref, seed_ref, bh, qi, kj, dropout_rate)
         m_sc[...] = m_new
 
-    @pl.when(kj == nk - 1)
+    @pl.when(step == nk - 1)
     def _finalize():
         l = jnp.maximum(l_sc[...], 1e-20)
         o_ref[0] = (acc_sc[...] / _lanes(l, Dv)).astype(o_ref.dtype)
@@ -343,23 +456,24 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                      delta_ref, dq_ref, dq_sc, *, sm_scale, causal,
-                     dropout_rate):
+                     dropout_rate, window=None):
     """dQ with K/V streamed through the innermost grid dim (see
     _flash_fwd_kernel); the dQ accumulator lives in VMEM scratch."""
     from jax.experimental import pallas as pl
 
     bh = pl.program_id(0)
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    step = pl.program_id(2)
     nk = pl.num_programs(2)
     blk_q = q_ref.shape[1]
     blk_k = k_ref.shape[1]
+    kj = step if window is None else _band_kj(qi, step, blk_q, blk_k, window)
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
-    live = _causal_live(qi, kj, blk_q, blk_k) if causal else True
+    live = _causal_live(qi, kj, blk_q, blk_k, window) if causal else True
 
     @pl.when(live)
     def _update():
@@ -372,7 +486,7 @@ def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * sm_scale
         if causal:
-            s = _apply_causal_mask(s, qi, kj, blk_q, blk_k)
+            s = _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
         w = jnp.exp(s - lse[:, None])                  # normalized weights
         dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
@@ -387,32 +501,37 @@ def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(kj == nk - 1)
+    @pl.when(step == nk - 1)
     def _finalize():
         dq_ref[0] = dq_sc[...].astype(dq_ref.dtype)
 
 
 def _flash_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                       delta_ref, dk_ref, dv_ref, dk_sc, dv_sc, *,
-                      sm_scale, causal, dropout_rate):
+                      sm_scale, causal, dropout_rate, window=None,
+                      q_tiles=None):
     """dK/dV with Q/dOut/lse/delta streamed through the innermost grid
-    dim (grid = (BH, kj, qi)); accumulators in VMEM scratch."""
+    dim (grid = (BH, kj, qi)); accumulators in VMEM scratch. Under a
+    `window` the inner axis counts the Q tiles of the k-block's band
+    (`_band_qi`; `q_tiles` is the row's whole count)."""
     from jax.experimental import pallas as pl
 
     bh = pl.program_id(0)
     kj = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(2)
     nq = pl.num_programs(2)
     blk_q = q_ref.shape[1]
     blk_k = k_ref.shape[1]
+    qi = step if window is None else _band_qi(kj, step, nq, blk_q, blk_k,
+                                              window, q_tiles)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
 
     # causal: q blocks strictly above this k block see none of it
-    live = _causal_live(qi, kj, blk_q, blk_k) if causal else True
+    live = _causal_live(qi, kj, blk_q, blk_k, window) if causal else True
 
     @pl.when(live)
     def _update():
@@ -425,7 +544,7 @@ def _flash_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * sm_scale
         if causal:
-            s = _apply_causal_mask(s, qi, kj, blk_q, blk_k)
+            s = _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
         w = jnp.exp(s - lse[:, None])                  # [blk_q, blk_k]
         dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
@@ -444,7 +563,7 @@ def _flash_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == nq - 1)
     def _finalize():
         dk_ref[0] = dk_sc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
@@ -452,7 +571,8 @@ def _flash_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _flash_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                       delta_ref, dq_ref, dk_ref, dv_ref, dk_sc, dv_sc,
-                      *dq_sc, sm_scale, causal, dropout_rate):
+                      *dq_sc, sm_scale, causal, dropout_rate, window=None,
+                      q_tiles=None):
     """dQ, dK and dV from one pass: `_flash_dkv_kernel` (grid (BH, kj, qi),
     q innermost) with one product more, this tile's share of dQ from the
     `ds` it has already formed. Without `dq_sc` a row is one K block and
@@ -460,30 +580,33 @@ def _flash_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     block. With it a row has several: the row's dQ accumulates over kj in
     float32 `[T, D]` scratch (in ascending kj for every q-block, as
     `_flash_dq_kernel` adds them) and is cast and written after the row's
-    last tile."""
+    last tile. Under a `window` the inner axis counts the Q tiles of the
+    k-block's band, as in `_flash_dkv_kernel`."""
     from jax.experimental import pallas as pl
 
     dq_sc = dq_sc[0] if dq_sc else None
     bh = pl.program_id(0)
     kj = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(2)
     nk = pl.num_programs(1)
     nq = pl.num_programs(2)
     blk_q = q_ref.shape[1]
     blk_k = k_ref.shape[1]
+    qi = step if window is None else _band_qi(kj, step, nq, blk_q, blk_k,
+                                              window, q_tiles)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
 
     if dq_sc is not None:
-        @pl.when((kj == 0) & (qi == 0))
+        @pl.when((kj == 0) & (step == 0))
         def _init_row():
             dq_sc[...] = jnp.zeros_like(dq_sc)
 
     # with one K block a row (kj == 0) every tile is live
-    live = _causal_live(qi, kj, blk_q, blk_k) if causal else True
+    live = _causal_live(qi, kj, blk_q, blk_k, window) if causal else True
 
     @pl.when(live)
     def _update():
@@ -496,7 +619,7 @@ def _flash_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * sm_scale
         if causal:
-            s = _apply_causal_mask(s, qi, kj, blk_q, blk_k)
+            s = _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
         w = jnp.exp(s - lse[:, None])                  # [blk_q, blk_k]
         dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
@@ -522,13 +645,13 @@ def _flash_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             rows = pl.ds(pl.multiple_of(qi * blk_q, blk_q), blk_q)
             dq_sc[rows, :] = dq_sc[rows, :] + dq
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == nq - 1)
     def _finalize():
         dk_ref[0] = dk_sc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
     if dq_sc is not None:
-        @pl.when((kj == nk - 1) & (qi == nq - 1))
+        @pl.when((kj == nk - 1) & (step == nq - 1))
         def _finalize_row():
             dq_ref[0] = dq_sc[...].astype(dq_ref.dtype)
 
@@ -560,17 +683,31 @@ def _compiler_params(carried=1):
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0):
+def _window_of(window, T):
+    """A window that reaches over the whole row is plain causal."""
+    return None if window is None or window >= T else int(window)
+
+
+def _named(name, window):
+    """Windowed calls under names of their own (`swa_...`)."""
+    return name if window is None else "swa_" + name
+
+
+def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0,
+                   window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, D = q.shape
     Dv = v.shape[-1]
-    BQ, BK = _blk(T, causal)
+    window = _window_of(window, T)
+    BQ, BK = _blk(T, causal, window)
     q3 = q.reshape(B * H, T, D)
     k3 = k.reshape(B * H, T, D)
     v3 = v.reshape(B * H, T, Dv)
     attrs = dict(sm_scale=sm_scale, causal=causal, dropout_rate=dropout_rate)
+    if window is not None:
+        attrs["window"] = window
     if _fwd_plan(T, BK) == "onepass":
         # its name holds `flash_fwd`: the benchmark's metrics of that name
         # read it as they read the streaming kernel
@@ -578,7 +715,9 @@ def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0):
         kernel = functools.partial(_flash_fwd_onepass_kernel, **attrs)
         scratch = []
     else:
-        name, grid, carried = "flash_fwd", (B * H, T // BQ, T // BK), 1
+        steps = T // BK if window is None else \
+            _band_steps(T, BQ, BK, window)[0]
+        name, grid, carried = "flash_fwd", (B * H, T // BQ, steps), 1
         kernel = functools.partial(_flash_fwd_kernel, **attrs)
         scratch = [pltpu.VMEM((BQ, _LANES), jnp.float32),
                    pltpu.VMEM((BQ, _LANES), jnp.float32),
@@ -589,7 +728,12 @@ def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0):
         return (bh, qi, 0)
 
     def at_k(bh, qi, kj=0):
-        return (bh, kj, 0)
+        if window is None or carried == 0:
+            return (bh, kj, 0)
+        # the band's tile of this step; a spare step stays on the diagonal
+        # tile, the one before it, so nothing is fetched for it
+        return (bh, jnp.minimum(_band_kj(qi, kj, BQ, BK, window),
+                                _last_k(qi, BQ, BK)), 0)
 
     out, lse = pl.pallas_call(
         kernel,
@@ -611,42 +755,64 @@ def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0):
         scratch_shapes=scratch,
         compiler_params=_compiler_params(carried),
         interpret=_interpret(),
-        name=name,
+        name=_named(name, window),
     )(_seed_arr(seed), q3, k3, v3)
     return out.reshape(B, H, T, Dv), lse
 
 
-def _flash_backward(q, k, v, o, lse, g, causal, sm_scale, dropout_rate, seed):
+def _flash_backward(q, k, v, o, lse, g, causal, sm_scale, dropout_rate, seed,
+                    window=None):
     B, H, T, D = q.shape
     Dv = v.shape[-1]
     q3, k3 = (x.reshape(B * H, T, D) for x in (q, k))
     v3, o3, g3 = (x.reshape(B * H, T, Dv) for x in (v, o, g))
     delta = jnp.sum(g3.astype(jnp.float32) * o3.astype(jnp.float32),
                     axis=-1)[:, None, :]
-    BQ, BK = _blk(T, causal)
+    window = _window_of(window, T)
+    BQ, BK = _blk(T, causal, window)
     run = (_flash_bwd_fused if _bwd_plan(T, D, BK) == "fused"
            else _flash_bwd_split)
-    grads = run((_seed_arr(seed), q3, k3, v3, g3, lse, delta), BQ, BK,
-                dict(sm_scale=sm_scale, causal=causal,
-                     dropout_rate=dropout_rate))
+    attrs = dict(sm_scale=sm_scale, causal=causal, dropout_rate=dropout_rate)
+    if window is not None:
+        attrs["window"] = window
+    grads = run((_seed_arr(seed), q3, k3, v3, g3, lse, delta), BQ, BK, attrs)
     return tuple(d.reshape(x.shape) for d, x in zip(grads, (q, k, v)))
 
 
-def _bwd_specs(BQ, BK, D, Dv, q_axis):
+def _bwd_specs(BQ, BK, D, Dv, q_axis, band=None):
     """Block specs of (seed, q, k, v, dO, lse, delta) for a backward grid
     (bh, ., .) whose q-block index is grid axis `q_axis` (1 or 2) and whose
     k-block index is the other; and the index maps of a q and a k block.
-    q and k are `D` wide, v and dO `Dv`."""
+    q and k are `D` wide, v and dO `Dv`. `band` = (window, T) where the
+    inner axis (2) counts the tiles of a band: its spare steps stay on the
+    band's nearest tile, so nothing is fetched for them."""
     from jax.experimental import pallas as pl
 
+    if band is not None:
+        window, T = band
+        q_steps = _band_steps(T, BQ, BK, window)[1]
+
+    def q_of(g):
+        if band is None or q_axis == 1:
+            return g[q_axis]
+        return jnp.maximum(
+            _band_qi(g[1], g[2], q_steps, BQ, BK, window, T // BQ),
+            _first_q(g[1], BQ, BK))
+
+    def k_of(g):
+        if band is None or q_axis == 2:
+            return g[3 - q_axis]
+        return jnp.minimum(_band_kj(g[1], g[2], BQ, BK, window),
+                           _last_k(g[1], BQ, BK))
+
     def at_q(*g):
-        return (g[0], g[q_axis], 0)
+        return (g[0], q_of(g), 0)
 
     def at_k(*g):
-        return (g[0], g[3 - q_axis], 0)
+        return (g[0], k_of(g), 0)
 
     def row_q(*g):
-        return (g[0], 0, g[q_axis])
+        return (g[0], 0, q_of(g))
 
     return [
         pl.BlockSpec((1, 1), lambda *g: (0, 0)),
@@ -668,7 +834,13 @@ def _flash_bwd_fused(args, BQ, BK, attrs):
     q3, k3, v3 = args[1:4]
     BH, T, D = q3.shape
     Dv = v3.shape[2]
-    in_specs, at_q, at_k = _bwd_specs(BQ, BK, D, Dv, q_axis=2)
+    window = attrs.get("window")
+    band = None if window is None else (window, T)
+    in_specs, at_q, at_k = _bwd_specs(BQ, BK, D, Dv, q_axis=2, band=band)
+    steps = T // BQ
+    if window is not None:
+        steps = _band_steps(T, BQ, BK, window)[1]
+        attrs = dict(attrs, q_tiles=T // BQ)
     scratch = [pltpu.VMEM((BK, D), jnp.float32),
                pltpu.VMEM((BK, Dv), jnp.float32)]
     if T == BK:
@@ -679,7 +851,7 @@ def _flash_bwd_fused(args, BQ, BK, attrs):
         scratch.append(pltpu.VMEM((T, D), jnp.float32))
     return pl.pallas_call(
         functools.partial(_flash_bwd_kernel, **attrs),
-        grid=(BH, T // BK, T // BQ),
+        grid=(BH, T // BK, steps),
         in_specs=in_specs,
         out_specs=[dq_spec, pl.BlockSpec((1, BK, D), at_k),
                    pl.BlockSpec((1, BK, Dv), at_k)],
@@ -688,7 +860,7 @@ def _flash_bwd_fused(args, BQ, BK, attrs):
         scratch_shapes=scratch,
         compiler_params=_compiler_params(carried=1 if T == BK else 2),
         interpret=_interpret(),
-        name="flash_dq_flash_dkv",
+        name=_named("flash_dq_flash_dkv", window),
     )(*args)
 
 
@@ -702,22 +874,28 @@ def _flash_bwd_split(args, BQ, BK, attrs):
     q3, k3, v3 = args[1:4]
     BH, T, D = q3.shape
     Dv = v3.shape[2]
-    in_specs, at_q, _ = _bwd_specs(BQ, BK, D, Dv, q_axis=1)
+    window = attrs.get("window")
+    band = None if window is None else (window, T)
+    k_steps, q_steps = (T // BK, T // BQ) if window is None else \
+        _band_steps(T, BQ, BK, window)
+    in_specs, at_q, _ = _bwd_specs(BQ, BK, D, Dv, q_axis=1, band=band)
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, **attrs),
-        grid=(BH, T // BQ, T // BK),
+        grid=(BH, T // BQ, k_steps),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, BQ, D), at_q),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q3.dtype),
         scratch_shapes=[pltpu.VMEM((BQ, D), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
-        name="flash_dq",
+        name=_named("flash_dq", window),
     )(*args)
-    in_specs, _, at_k = _bwd_specs(BQ, BK, D, Dv, q_axis=2)
+    in_specs, _, at_k = _bwd_specs(BQ, BK, D, Dv, q_axis=2, band=band)
+    if window is not None:
+        attrs = dict(attrs, q_tiles=T // BQ)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, **attrs),
-        grid=(BH, T // BK, T // BQ),
+        grid=(BH, T // BK, q_steps),
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((1, BK, D), at_k),
                    pl.BlockSpec((1, BK, Dv), at_k)],
@@ -727,16 +905,33 @@ def _flash_bwd_split(args, BQ, BK, attrs):
                         pltpu.VMEM((BK, Dv), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
-        name="flash_dkv",
+        name=_named("flash_dkv", window),
     )(*args)
     return dq, dk, dv
 
 
-def _pallas_ok(q, dropout_rate=0.0, v=None):
+def _check_window(window, causal):
+    """A window is a whole number of keys, at least the query's own, below a
+    causal diagonal."""
+    if window is None:
+        return
+    if not causal:
+        raise ValueError(
+            f"flash attention takes a window ({window}) on a causal call "
+            f"only: key j is visible to query i iff 0 <= i - j < window")
+    if int(window) != window or window < 1:
+        raise ValueError(
+            f"flash attention needs a window of at least 1 key (the query's "
+            f"own position), a whole number; got window = {window!r}")
+
+
+def _pallas_ok(q, dropout_rate=0.0, v=None, window=None):
     """Kernel or reference? The reference is a CPU-only path; on the TPU
     a shape outside the kernels' envelope raises instead of quietly
     materializing the [T, T] scores. `v` where its heads have a width of
-    their own (`Dv`; the query's `D` otherwise)."""
+    their own (`Dv`; the query's `D` otherwise). A `window` does not narrow
+    the envelope (any W >= 1 runs, aligned to a tile or not); it is named
+    in the message so that a refused shape is not put down to it."""
     B, H, T, D = q.shape
     Dv = D if v is None else v.shape[-1]
     supported = T % 128 == 0 and D <= 256 and Dv <= 256
@@ -746,7 +941,10 @@ def _pallas_ok(q, dropout_rate=0.0, v=None):
                 f"flash attention on the {jax.default_backend()!r} backend "
                 f"needs T % 128 == 0, D <= 256 and Dv <= 256, got q shape "
                 f"{q.shape} (query/key heads of D = {D}) and value heads of "
-                f"Dv = {Dv}")
+                f"Dv = {Dv}"
+                + ("" if window is None else
+                   f"; the window of {window} is not why: any window of at "
+                   f"least 1 runs at a supported shape"))
         return True
     if not _interpret():
         return False
@@ -759,23 +957,26 @@ def _pallas_ok(q, dropout_rate=0.0, v=None):
 # public entry: custom_vjp so program autodiff gets the Pallas backward
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash_out_lse(q, k, v, seed, causal, sm_scale, dropout_rate):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_out_lse(q, k, v, seed, causal, sm_scale, dropout_rate,
+                   window=None):
     """The forward kernel's two results: `out` [B, H, T, Dv] and the rows'
     log-sum-exp, float32 [B*H, 1, T] (the layout the backward kernels read;
     no gradient flows through it)."""
-    return _flash_forward(q, k, v, causal, sm_scale, dropout_rate, seed)
+    return _flash_forward(q, k, v, causal, sm_scale, dropout_rate, seed,
+                          window)
 
 
-def _fol_fwd(q, k, v, seed, causal, sm_scale, dropout_rate):
-    out, lse = _flash_forward(q, k, v, causal, sm_scale, dropout_rate, seed)
+def _fol_fwd(q, k, v, seed, causal, sm_scale, dropout_rate, window=None):
+    out, lse = _flash_forward(q, k, v, causal, sm_scale, dropout_rate, seed,
+                              window)
     return (out, lse), (q, k, v, out, lse, seed)
 
 
-def _fol_bwd(causal, sm_scale, dropout_rate, res, g):
+def _fol_bwd(causal, sm_scale, dropout_rate, window, res, g):
     q, k, v, o, lse, seed = res
     dq, dk, dv = _flash_backward(q, k, v, o, lse, g[0], causal, sm_scale,
-                                 dropout_rate, seed)
+                                 dropout_rate, seed, window)
     return dq, dk, dv, np.zeros(jnp.shape(seed), jax.dtypes.float0)
 
 
@@ -783,21 +984,26 @@ _flash_out_lse.defvjp(_fol_fwd, _fol_bwd)
 
 
 def flash_attention(q, k, v, seed, causal=False, sm_scale=1.0,
-                    dropout_rate=0.0):
+                    dropout_rate=0.0, window=None):
     """seed: int32 scalar (traced) driving attention-weight dropout. For
     direct callers (tools, tests): under `jax.grad` the forward kernel is
-    the residual pass and the backward kernels follow."""
-    if _pallas_ok(q, dropout_rate, v):
+    the residual pass and the backward kernels follow. `window`: see the
+    module's docstring."""
+    _check_window(window, causal)
+    if _pallas_ok(q, dropout_rate, v, window):
         return _flash_out_lse(q, k, v, seed, causal, sm_scale,
-                              dropout_rate)[0]
-    return _attention_reference(q, k, v, causal, sm_scale, dropout_rate, seed)
+                              dropout_rate, window)[0]
+    return _attention_reference(q, k, v, causal, sm_scale, dropout_rate, seed,
+                                window)
 
 
 def _attrs(ctx, Q):
-    """(sm_scale, causal, dropout rate) of a fused_attention op."""
+    """(sm_scale, causal, dropout rate, window) of a fused_attention op."""
     rate = 0.0 if ctx.attr("is_test", False) else ctx.attr("dropout_rate", 0.0)
-    return (ctx.attr("sm_scale", 1.0 / math.sqrt(Q.shape[-1])),
-            ctx.attr("causal", False), float(rate))
+    causal, window = ctx.attr("causal", False), ctx.attr("window")
+    _check_window(window, causal)
+    return (ctx.attr("sm_scale", 1.0 / math.sqrt(Q.shape[-1])), causal,
+            float(rate), window)
 
 
 def _dropout_seed(ctx, rate):
@@ -825,7 +1031,10 @@ def _fused_attention_infer(ctx, structs):
 def _fused_attention(ctx, Q, K, V):
     """Q, K: [B, H, T, D]; V: [B, H, T, Dv], the value heads' own width
     (latent attention: 192 over 128), `Dv == D` in the plain case; Out is
-    [B, H, T, Dv]. attrs: causal, sm_scale, dropout_rate, is_test.
+    [B, H, T, Dv]. attrs: causal, sm_scale, dropout_rate, is_test, and
+    `window` (causal only): key j is visible to query i iff 0 <= i - j <
+    window; the op tallies the score tiles its forward grid computes
+    (`window_tiles_computed` on the compile event).
 
     Replaces the reference's matmul+softmax+dropout+matmul composition
     (nets.py:329) with one O(T)-memory kernel. Dropout is applied to the
@@ -833,10 +1042,16 @@ def _fused_attention(ctx, Q, K, V):
     functional PRNG. On the kernel path the rule also returns `Lse`, the
     forward kernel's log-sum-exp (float32 [B*H, 1, T]), which the grad op
     reads back instead of running the forward kernel again."""
-    sm_scale, causal, rate = _attrs(ctx, Q)
+    sm_scale, causal, rate, window = _attrs(ctx, Q)
     mesh = getattr(ctx.lowerer, "mesh", None) if ctx.lowerer else None
     if (mesh is not None and "sp" in mesh.axis_names
             and mesh.shape["sp"] > 1):
+        if window is not None:
+            raise NotImplementedError(
+                f"a window ({window}) is not supported under sequence "
+                f"parallelism: ring attention over the 'sp' mesh axis rotates "
+                f"every K/V shard past every query shard and has no band; "
+                f"run windowed layers without an 'sp' axis")
         # sequence parallelism: the ParallelExecutor shards the seq dim
         # over 'sp', so attention becomes Ring Attention — K/V shards
         # rotate over ICI while the online softmax accumulates.
@@ -858,11 +1073,16 @@ def _fused_attention(ctx, Q, K, V):
         return {"Out": ring_attention(Q, K, V, mesh, axis="sp",
                                       causal=causal, sm_scale=sm_scale)}
     seed = _dropout_seed(ctx, rate)
-    if _pallas_ok(Q, rate, V):
-        out, lse = _flash_out_lse(Q, K, V, seed, causal, sm_scale, rate)
+    if window is not None and ctx.op is not None \
+            and ctx.op.type == "fused_attention":   # not its grad op's trace
+        ctx.tally("window_tiles_computed", Q.shape[0] * Q.shape[1]
+                  * window_tiles(Q.shape[2], window))
+    if _pallas_ok(Q, rate, V, window):
+        out, lse = _flash_out_lse(Q, K, V, seed, causal, sm_scale, rate,
+                                  window)
         return {"Out": out, "Lse": lse}
     return {"Out": _attention_reference(Q, K, V, causal, sm_scale, rate,
-                                        seed)}
+                                        seed, window)}
 
 
 @register_grad("fused_attention")
@@ -890,10 +1110,10 @@ def _fused_attention_grad(ctx, ins, out_grads):
     else:
         cast = amp_cast(opdef, ctx, {s: [x] for s, x in zip(slots, raw)})
         q, k, v = (cast[s][0] for s in slots)
-        sm_scale, causal, rate = _attrs(ctx, q)
+        sm_scale, causal, rate, window = _attrs(ctx, q)
         grads = _flash_backward(q, k, v, out, lse, g.astype(out.dtype),
                                 causal, sm_scale, rate,
-                                _dropout_seed(ctx, rate))
+                                _dropout_seed(ctx, rate), window)
     return {s: d.astype(x.dtype) for s, d, x in zip(slots, grads, raw)}
 
 

@@ -547,3 +547,238 @@ def test_streaming_statistics_reach_every_head_width(interpret_kernels,
         np.asarray(out), np.asarray(_attention_reference(q, k, v, True,
                                                          D ** -0.5)),
         atol=3e-5, rtol=3e-5)
+
+
+# -- a window: key j visible to query i iff 0 <= i - j < W -----------------------
+
+def _masked_softmax(q, k, v, window, scale):
+    """The band written as its two inequalities, independent of the op."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    i = jnp.arange(q.shape[2])[:, None]
+    j = jnp.arange(k.shape[2])[None, :]
+    s = jnp.where((j <= i) & (i - j < window), s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+
+
+# T 512 in tiles of 128: below a tile, a tile, across three tiles (two whole
+# and two part ones), no multiple of 128 nor of 8, one key, all but one
+WINDOWS = [1, 37, 128, 300, 384, 511]
+
+
+@pytest.mark.parametrize("plan", ["onepass", "stream"])
+@pytest.mark.parametrize("window", WINDOWS)
+def test_windowed_forward_is_the_masked_softmax(interpret_kernels,
+                                                monkeypatch, window, plan):
+    rng = np.random.RandomState(7)
+    B, H, T, D = 1, 2, 512, 32
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE",
+                        (128, 512) if plan == "onepass" else (128, 128))
+    assert pallas_attention._fwd_plan(
+        T, pallas_attention._blk(T, True)[1]) == plan
+    q, k, v = _qkv(rng, B, H, T, D, D)
+    out = flash_attention(q, k, v, jnp.int32(0), True, D ** -0.5, 0.0, window)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_masked_softmax(q, k, v, window,
+                                                    D ** -0.5)),
+        atol=2e-5, rtol=2e-5)
+    # and the CPU path's reference is the same function
+    ref = _attention_reference(q, k, v, True, D ** -0.5, window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5,
+                               rtol=2e-5)
+
+
+@pytest.mark.parametrize("kernels", ["fused", "fused_resident_row", "split"])
+@pytest.mark.parametrize("window", WINDOWS)
+def test_windowed_backward_plans_match_the_masked_softmax(
+        interpret_kernels, monkeypatch, window, kernels):
+    """The fused backward (a row one K block; a row of several, its dQ
+    resident) and the split pair under a window, against `jax.vjp` of the
+    masked softmax."""
+    rng = np.random.RandomState(8)
+    B, H, T, D = 1, 2, 512, 32
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE",
+                        (128, 512) if kernels == "fused" else (128, 128))
+    if kernels == "split":
+        monkeypatch.setattr(pallas_attention, "_bwd_plan",
+                            lambda *a: "split")
+    q, k, v = _qkv(rng, B, H, T, D, D)
+    g = jnp.asarray(rng.randn(B, H, T, D), jnp.float32)
+    out, lse = pallas_attention._flash_forward(q, k, v, True, D ** -0.5,
+                                               window=window)
+    got = pallas_attention._flash_backward(q, k, v, out, lse, g, True,
+                                           D ** -0.5, 0.0, 0, window)
+    _, vjp = jax.vjp(lambda *a: _masked_softmax(*a, window, D ** -0.5),
+                     q, k, v)
+    for a, b, name in zip(got, vjp(g), "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
+                                   rtol=1e-4, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("tiles", [(128, 256), (256, 128)],
+                         ids=["bq128-bk256", "bq256-bk128"])
+@pytest.mark.parametrize("window", [37, 300])
+def test_window_over_tiles_that_are_not_square(interpret_kernels, monkeypatch,
+                                               window, tiles):
+    """The band's arithmetic does not assume BQ == BK."""
+    rng = np.random.RandomState(9)
+    B, H, T, D = 1, 1, 512, 32
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
+    monkeypatch.setattr(pallas_attention, "_bwd_plan", lambda *a: "split")
+    q, k, v = _qkv(rng, B, H, T, D, D)
+    g = jnp.asarray(rng.randn(B, H, T, D), jnp.float32)
+
+    def f(*a):
+        return (flash_attention(*a, jnp.int32(0), True, D ** -0.5, 0.0,
+                                window) * g).sum()
+
+    def r(*a):
+        return (_masked_softmax(*a, window, D ** -0.5) * g).sum()
+
+    for a, b, name in zip(jax.grad(f, (0, 1, 2))(q, k, v),
+                          jax.grad(r, (0, 1, 2))(q, k, v), "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
+                                   rtol=1e-4, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("kernels", ["fused_resident_row", "split"])
+def test_window_with_a_value_width_of_its_own(interpret_kernels, monkeypatch,
+                                              kernels):
+    """Latent attention's widths (192 over 128) under a window."""
+    rng = np.random.RandomState(10)
+    B, H, T, D, Dv, window = 1, 2, 384, 192, 128, 200
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", (128, 128))
+    if kernels == "split":
+        monkeypatch.setattr(pallas_attention, "_bwd_plan",
+                            lambda *a: "split")
+    q, k, v = _qkv(rng, B, H, T, D, Dv)
+    g = jnp.asarray(rng.randn(B, H, T, Dv), jnp.float32)
+    out, vjp = jax.vjp(lambda *a: flash_attention(
+        *a, jnp.int32(0), True, D ** -0.5, 0.0, window), q, k, v)
+    want, want_vjp = jax.vjp(
+        lambda *a: _masked_softmax(*a, window, D ** -0.5), q, k, v)
+    assert out.shape == (B, H, T, Dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5,
+                               rtol=2e-5)
+    for a, b, name in zip(vjp(g), want_vjp(g), "qkv"):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4,
+                                   rtol=2e-4, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("window", [512, 513, 100000])
+def test_a_window_over_the_whole_row_is_bitwise_plain_causal(
+        interpret_kernels, monkeypatch, window):
+    rng = np.random.RandomState(11)
+    B, H, T, D = 1, 2, 512, 32
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", (128, 128))
+    q, k, v = _qkv(rng, B, H, T, D, D)
+    g = jnp.asarray(rng.randn(B, H, T, D), jnp.float32)
+
+    def run(w):
+        return jax.vjp(lambda *a: flash_attention(
+            *a, jnp.int32(0), True, D ** -0.5, 0.0, w), q, k, v)
+
+    (out, vjp), (plain, plain_vjp) = run(window), run(None)
+    assert np.array_equal(np.asarray(out), np.asarray(plain))
+    for a, b in zip(vjp(g), plain_vjp(g)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    # and the step's text names the plain kernels
+    text = jax.jit(lambda *a: flash_attention(
+        *a, jnp.int32(0), True, D ** -0.5, 0.0, window)).lower(
+            q, k, v).as_text()
+    assert "swa_" not in text
+
+
+def test_window_with_dropout_takes_the_causal_paths_fallback():
+    """On the CPU a dropout rate sends the call to the jnp reference, with
+    or without a window, and the window is applied there: a weight outside
+    the band stays 0 whatever the mask drops."""
+    rng = np.random.RandomState(12)
+    B, H, T, D, window = 1, 2, 128, 16, 20
+    q, k, _ = _qkv(rng, B, H, T, D, D)
+    v = jnp.eye(T, dtype=jnp.float32)[None, None].repeat(H, 1)  # Out = P
+    assert not pallas_attention._pallas_ok(q, 0.5, v, window)
+    out = np.asarray(flash_attention(q, k, v, jnp.int32(3), True, D ** -0.5,
+                                     0.5, window))
+    i, j = np.arange(T)[:, None], np.arange(T)[None, :]
+    outside = ~((j <= i) & (i - j < window))
+    assert np.all(out[..., outside] == 0) and np.isfinite(out).all()
+    kept = out[..., ~outside]
+    assert 0.3 < np.mean(kept == 0) < 0.7       # about half are dropped
+    base = flash_attention(q, k, v, jnp.int32(3), True, D ** -0.5, 0.0,
+                           window)
+    np.testing.assert_allclose(
+        np.asarray(base), np.asarray(_masked_softmax(q, k, v, window,
+                                                     D ** -0.5)), atol=1e-5)
+
+
+@pytest.mark.parametrize("T,tiles,window,band,causal", [
+    (8192, None, 1024, 45, 136),        # the cell: 512 x 512 tiles
+    (8192, (1024, 1024), 1024, 15, 36),
+    (512, (128, 128), 128, 7, 10),
+    (512, (128, 128), 129, 7, 10),
+    (512, (128, 128), 130, 9, 10),
+    (512, (128, 128), 1, 4, 10)])
+def test_windowed_grids_cover_the_bands_tiles(monkeypatch, T, tiles, window,
+                                              band, causal):
+    """`window_tiles`: the tiles the forward grid computes a head, of the
+    `causal` a call without the window has at the same tiles;
+    `_band_steps`: the inner axes are the band's width."""
+    if tiles:
+        monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
+    assert pallas_attention.window_tiles(T, window) == band
+    bq, bk = pallas_attention._blk(T, True, window)
+    nkw, nqw = pallas_attention._band_steps(T, bq, bk, window)
+    live = [[bool(pallas_attention._causal_live(qi, kj, bq, bk, window))
+             for kj in range(T // bk)] for qi in range(T // bq)]
+    assert sum(map(sum, live)) == band
+    assert sum(bool(pallas_attention._causal_live(qi, kj, bq, bk))
+               for qi in range(T // bq) for kj in range(T // bk)) == causal
+    assert nkw == max(map(sum, live)) and nqw == max(map(sum, zip(*live)))
+    # every live tile is visited once by each windowed grid, no dead one
+    for qi in range(T // bq):
+        seen = [pallas_attention._band_kj(qi, s, bq, bk, window)
+                for s in range(nkw)]
+        assert [kj for kj in seen if 0 <= kj < T // bk and live[qi][kj]] \
+            == [kj for kj in range(T // bk) if live[qi][kj]]
+    for kj in range(T // bk):
+        seen = [pallas_attention._band_qi(kj, s, nqw, bq, bk, window,
+                                          T // bq) for s in range(nqw)]
+        assert [qi for qi in seen if 0 <= qi < T // bq and live[qi][kj]] \
+            == [qi for qi in range(T // bq) if live[qi][kj]]
+
+
+@pytest.mark.parametrize("case", ["not_causal", "zero", "fraction",
+                                  "sequence_parallel", "paged"])
+def test_a_window_that_cannot_run_is_refused_by_name(case):
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        q = layers.data(name="q", shape=[1, 2, 128, 16], dtype="float32",
+                        append_batch_size=False)
+        if case == "not_causal":
+            with pytest.raises(ValueError, match="window.*causal call only"):
+                layers.fused_attention(q, q, q, causal=False, window=8)
+        elif case == "zero":
+            with pytest.raises(ValueError, match="window of at least 1 key"):
+                layers.fused_attention(q, q, q, causal=True, window=0)
+        elif case == "fraction":
+            with pytest.raises(ValueError, match="window of at least 1 key.*whole number"):
+                layers.fused_attention(q, q, q, causal=True, window=2.5)
+        elif case == "sequence_parallel":
+            from paddle_tpu.parallel.mesh import make_mesh
+            mesh = make_mesh([2], ["sp"], jax.devices()[:2])
+            ctx = registry.LoweringContext(
+                {"causal": True, "window": 8}, lowerer=type(
+                    "L", (), {"mesh": mesh, "program": main,
+                              "tallies": {}})())
+            x = jnp.zeros((1, 2, 128, 16))
+            with pytest.raises(NotImplementedError,
+                               match="window.*sequence parallelism"):
+                registry.get_op_def("fused_attention").lower(ctx, x, x, x)
+        else:
+            from paddle_tpu.ops import paged_attention
+            ctx = registry.LoweringContext({"window": 8, "num_heads": 2})
+            with pytest.raises(NotImplementedError,
+                               match="window.*paged attention"):
+                paged_attention._no_window(ctx)
+            paged_attention._no_window(registry.LoweringContext({}))

@@ -1,0 +1,595 @@
+"""Mellum2 (sliding-window and full causal attention layers mixed 3:1 through
+`fused_attention(window=...)`, 32/4-style grouped heads, plain rotary on the
+window layers and YaRN on the full ones, one chip's share of a renormalised
+top-k expert layer in every layer) through `layers` -> Program IR ->
+`Executor`, against the plain reference (`tests/mellum2_reference.py`: a
+masked softmax whose mask is two inequalities, `jnp.repeat`, YaRN from its
+formulas, a loop over the held experts). Seeded random weights, float32, AMP
+off unless a test says otherwise."""
+
+import filecmp
+import json
+import math
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu import io, layers, models, observe
+from paddle_tpu.core import ir, registry
+from paddle_tpu.ops import decoder_block
+
+import mellum2_reference as ref
+from test_olmoe import rel_err, run_piece
+from test_qwen3_next import OLMOE_DIGEST, _program_digest, frob
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+# a YaRN block that bends the frequencies of a 16-wide head: low 0, high 3
+TINY_YARN = {"factor": 4.0, "original_max_position_embeddings": 64,
+             "beta_fast": 32.0, "beta_slow": 1.0}
+# the published pattern; a window shorter than the sequence and no multiple
+# of 128; a group of 2; a share that starts above expert 0
+TINY = dict(vocab_size=64, seq_len=256, n_layer=4, d_model=32, n_head=4,
+            n_kv_head=2, head_dim=16, sliding_window=96, rope_theta=1e4,
+            rope_scaling=TINY_YARN, n_expert=16, top_k=3, d_expert=16,
+            first_expert=4, experts_held=4)
+REF_KW = {k: TINY[k] for k in (
+    "n_layer", "n_head", "n_kv_head", "head_dim", "sliding_window",
+    "rope_theta", "rope_scaling", "top_k", "first_expert")}
+RTOL = 2e-5
+PUBLISHED_YARN = models.mellum2.YARN
+
+
+# -- YaRN's tables ----------------------------------------------------------------
+
+def _yarn_by_hand(dim, theta, s):
+    """The issue's formulas in numpy float64."""
+    j = np.arange(dim // 2, dtype=np.float64)
+    pos = theta ** (2 * j / dim)
+    length = s["original_max_position_embeddings"]
+    c = lambda r: dim * math.log(length / (2 * math.pi * r)) \
+        / (2 * math.log(theta))
+    low = max(math.floor(c(s["beta_fast"])), 0)
+    high = min(math.ceil(c(s["beta_slow"])), dim - 1)
+    ramp = np.clip((j - low) / (high - low), 0, 1)
+    return ramp / (s["factor"] * pos) + (1 - ramp) / pos, low, high
+
+
+@pytest.mark.parametrize("dim,low,high", [(128, 18, 35), (64, 9, 18)])
+def test_yarn_frequencies_are_the_formulas(dim, low, high):
+    """`rotary_frequencies` under the published block at the published head
+    size (low 18, high 35, as the issue works out) and at half of it; the
+    reference's own `yarn_frequencies` agrees; the dims below `low` keep
+    their frequency and those above `high` have it divided by 16."""
+    want, got_low, got_high = _yarn_by_hand(dim, 5e5, PUBLISHED_YARN)
+    assert (got_low, got_high) == (low, high)
+    inv_freq, factor = decoder_block.rotary_frequencies(dim, 5e5,
+                                                        PUBLISHED_YARN)
+    assert factor == 1.2772588722239782
+    np.testing.assert_allclose(np.asarray(inv_freq), want, rtol=1e-5)
+    theirs, their_factor = ref.yarn_frequencies(dim, 5e5, PUBLISHED_YARN)
+    np.testing.assert_allclose(np.asarray(theirs), want, rtol=1e-5)
+    assert their_factor == factor
+    plain, one = decoder_block.rotary_frequencies(dim, 5e5)
+    assert one == 1.0
+    np.testing.assert_allclose(np.asarray(inv_freq[:low + 1]),
+                               np.asarray(plain[:low + 1]), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(inv_freq[high:]) * 16,
+                               np.asarray(plain[high:]), rtol=1e-5)
+    assert np.all(np.asarray(inv_freq[low + 1:high]) <
+                  np.asarray(plain[low + 1:high]))
+
+
+def test_yarn_attention_factor_defaults_to_a_tenth_of_ln_factor_plus_one():
+    block = {k: v for k, v in PUBLISHED_YARN.items()
+             if k != "attention_factor"}
+    _, factor = decoder_block.rotary_frequencies(128, 5e5, block)
+    assert factor == pytest.approx(0.1 * math.log(16) + 1)
+    assert factor == pytest.approx(PUBLISHED_YARN["attention_factor"])
+
+
+@pytest.mark.parametrize("scaling", [None, TINY_YARN],
+                         ids=["plain", "yarn"])
+def test_rotary_op_is_the_reference_in_both_regimes(scaling):
+    rng = np.random.RandomState(0)
+    x = rng.randn(2, 3, 32, 16).astype(np.float32)
+    (y,), grads, probe = run_piece(
+        lambda d: [layers.rotary_embedding(d["x"], theta=1e4,
+                                           scaling=scaling)], {"x": x})
+    want = ref.rotary(jnp.asarray(x), 1e4, scaling)
+    assert rel_err(y, want) < RTOL
+    gx = jax.grad(lambda a: jnp.sum(ref.rotary(a, 1e4, scaling) * probe))(
+        jnp.asarray(x))
+    assert rel_err(grads["x"], gx) < RTOL
+    if scaling:     # and it is another function than plain rotary
+        assert rel_err(y, ref.rotary(jnp.asarray(x), 1e4)) > 0.1
+
+
+def test_rotary_without_scaling_takes_no_new_attribute():
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = layers.data(name="x", shape=[1, 2, 8, 16], dtype="float32",
+                        append_batch_size=False)
+        layers.rotary_embedding(x, theta=1e4)
+        layers.rotary_embedding(x, theta=1e4, scaling=PUBLISHED_YARN)
+        with pytest.raises(ValueError, match="YaRN block"):
+            layers.rotary_embedding(x, scaling={"factor": 2.0})
+        with pytest.raises(ValueError, match="YaRN block"):
+            layers.rotary_embedding(x, scaling=dict(PUBLISHED_YARN, mscale=1))
+    plain, scaled = main.global_block().ops
+    assert set(plain.attrs) - {ir.NAME_SCOPE_ATTR} == {"theta"}
+    assert scaled.attrs["scaling"] == {k: float(v)
+                                       for k, v in PUBLISHED_YARN.items()}
+
+
+# -- the shares add up -----------------------------------------------------------------
+
+N_EXPERT, HELD, K, D, F = 16, 2, 3, 16, 12
+
+
+@pytest.mark.parametrize("path", ["ragged_dot", "pallas_interpreted"])
+def test_the_eight_shares_add_up_to_the_whole_layer(path, monkeypatch):
+    """The routed parts that all 8 shares give are the uncut reference's
+    whole layer (softmax over all 16, top-3 renormalised over all three
+    chosen, whichever share holds them): forward, the gradient of the router
+    and of the layer's input; and each share alone is the reference given
+    that share."""
+    if path == "pallas_interpreted":
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    rng = np.random.RandomState(5)
+    x = rng.randn(40, D).astype(np.float32)
+    whole = {"router.w": rng.randn(D, N_EXPERT),
+             "experts.gate.w": rng.randn(N_EXPERT, D, F) * 0.3,
+             "experts.up.w": rng.randn(N_EXPERT, D, F) * 0.3,
+             "experts.down.w": rng.randn(N_EXPERT, F, D) * 0.3}
+    whole = {n: v.astype(np.float32) for n, v in whole.items()}
+    shares = N_EXPERT // HELD
+    cut = {f"s{j}.{which}.w":
+           whole[f"experts.{which}.w"][j * HELD:(j + 1) * HELD]
+           for j in range(shares) for which in ("gate", "up", "down")}
+
+    def build(d):
+        routing = layers.moe_router(
+            d["x"], N_EXPERT, K, norm_topk_prob=True,
+            param_attr=fluid.ParamAttr(name="router.w"))
+        parts = [layers.moe_experts(
+            d["x"], routing, N_EXPERT, F, name=f"s{j}",
+            first_expert=j * HELD, experts_held=HELD)
+            for j in range(shares)]
+        return [layers.sums(parts)] + parts
+
+    outs, grads, probe = run_piece(
+        build, {"x": x}, {"router.w": whole["router.w"], **cut})
+
+    def want(x, router_w):
+        return ref.sparse_experts({**whole, "router.w": router_w}, x,
+                                  top_k=K, first_expert=0)[0]
+
+    with jax.default_matmul_precision("highest"):
+        assert rel_err(outs[0], want(x, whole["router.w"])) < RTOL
+        gx, gr = jax.grad(lambda a, b: jnp.sum(want(a, b) * probe),
+                          (0, 1))(x, whole["router.w"])
+        for j, part in enumerate(outs[1:]):
+            held = {n: (v[j * HELD:(j + 1) * HELD]
+                        if n.startswith("experts.") else v)
+                    for n, v in whole.items()}
+            alone = ref.sparse_experts(held, x, top_k=K,
+                                       first_expert=j * HELD)[0]
+            assert rel_err(part, alone) < 1e-4, j
+            assert float(jnp.abs(alone).max()) > 0
+    assert rel_err(grads["x"], gx) < 1e-4
+    assert rel_err(grads["router.w"], gr) < 1e-4
+
+
+# -- the model ----------------------------------------------------------------------------
+
+def _program(optimizer=None, **sizes):
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        feeds, fetches = models.mellum2.build(**{**TINY, **sizes})
+        if optimizer is None:
+            pairs = fluid.append_backward(fetches["loss"])
+        else:
+            optimizer.minimize(fetches["loss"])
+            pairs = []
+    main.random_seed = startup.random_seed = 7
+    return main, startup, fetches, pairs
+
+
+def _batch(seed=0, batch=2, seq_len=TINY["seq_len"]):
+    rng = np.random.RandomState(seed)
+    shape = (batch, seq_len)
+    return {"tokens": rng.randint(0, TINY["vocab_size"], shape)
+            .astype(np.int32),
+            "labels": rng.randint(0, TINY["vocab_size"], shape)
+            .astype(np.int32)}
+
+
+def _parameter_names(main):
+    return [p.name for p in main.global_block().all_parameters()]
+
+
+def _seeded_weights(scope, names, seed=3):
+    """Weights far from their initial values, so that no term of the
+    comparison is small by construction: norm weights in [0.5, 1.5], a
+    router five times as sharp, matrices of std 0.1 (five times the
+    initial)."""
+    rng = np.random.RandomState(seed)
+    for name in sorted(names):
+        shape = np.shape(scope.find_var(name))
+        if "norm" in name:
+            value = rng.uniform(0.5, 1.5, shape)
+        elif name.endswith("router.w"):
+            value = rng.randn(*shape) * 0.5
+        else:
+            value = rng.randn(*shape) * 0.1
+        scope.set_var(name, jnp.asarray(value.astype(np.float32)))
+
+
+FETCHES = ["loss", "ce", "load_balance", "logits", "tokens_per_expert"]
+
+
+def _run_tiny(amp, seeded=True):
+    main, startup, fetches, pairs = _program()
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace(), amp=amp)
+    exe.run(startup, scope=scope)
+    names = _parameter_names(main)
+    if seeded:
+        _seeded_weights(scope, names)
+    params = {n: np.asarray(scope.find_var(n)) for n in names}
+    feed = _batch()
+    out = exe.run(main, feed=feed,
+                  fetch_list=[fetches[n] for n in FETCHES]
+                  + [g for _, g in pairs], scope=scope)
+    got = dict(zip(FETCHES, out))
+    grads = dict(zip((p.name for p, _ in pairs), out[len(FETCHES):]))
+    return main, params, feed, got, grads
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    main, params, feed, got, grads = _run_tiny(amp=False)
+    tokens, labels = jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"])
+    want, want_grads = ref.loss_and_grads(
+        params, tokens, labels, last=TINY["seq_len"], **REF_KW)
+    return dict(main=main, params=params, tokens=tokens, labels=labels,
+                got=got, grads=grads, want=want, want_grads=want_grads)
+
+
+LAYER = ["in_norm.w", "post_norm.w", "attn.q.w", "attn.k.w", "attn.v.w",
+         "attn.q_norm.w", "attn.k_norm.w", "attn.o.w", "router.w",
+         "experts.gate.w", "experts.up.w", "experts.down.w"]
+TRAINED = (["embed.w", "final_norm.w", "head.w"]
+           + [f"l{i}.{n}" for i in range(4) for n in LAYER])
+
+
+def test_tiny_model_has_the_reference_parameters(tiny):
+    assert sorted(tiny["params"]) == sorted(TRAINED)
+    assert tiny["params"]["l0.attn.q.w"].shape == (32, 4 * 16)
+    assert tiny["params"]["l0.attn.k.w"].shape == (32, 2 * 16)
+    assert tiny["params"]["l3.attn.v.w"].shape == (32, 2 * 16)
+    assert tiny["params"]["l0.attn.q_norm.w"].shape == (16,)
+    assert tiny["params"]["l1.experts.gate.w"].shape == (4, 32, 16)
+    assert tiny["params"]["l1.router.w"].shape == (32, 16)
+    assert sorted(tiny["grads"]) == sorted(TRAINED)
+
+
+@pytest.mark.parametrize("name", FETCHES)
+def test_tiny_model_output_matches_reference(tiny, name):
+    if name == "tokens_per_expert":
+        assert np.array_equal(tiny["got"][name], tiny["want"][name])
+    else:
+        want = np.asarray(tiny["want"][name])
+        assert rel_err(np.reshape(tiny["got"][name], want.shape), want) < 1e-4
+
+
+@pytest.mark.parametrize("name", TRAINED)
+def test_tiny_model_gradient_matches_reference(tiny, name):
+    assert frob(tiny["grads"][name], tiny["want_grads"][name]) < 2e-4
+
+
+# what each planted fault has to move, at least: the loss by 1e-4 or a
+# gradient by 1% where the true reference is met within 2e-4
+@pytest.mark.parametrize("fault", sorted(ref.FAULTS))
+def test_each_planted_fault_is_refused(tiny, fault):
+    """The comparison that passes the reference refuses each fault: the
+    logits, the loss or a mixer's gradient moves by far more than the
+    system's distance from the true reference."""
+    wrt = ["l0.attn.q.w", "l0.attn.k.w", "l0.attn.v.w", "l3.attn.q.w",
+           "l3.attn.k.w"]
+    bad, bad_grads = ref.loss_and_grads(
+        tiny["params"], tiny["tokens"], tiny["labels"], wrt=wrt,
+        last=TINY["seq_len"], fault=fault, **REF_KW)
+    moved = [rel_err(tiny["got"]["logits"], bad["logits"])] \
+        + [frob(tiny["grads"][n], bad_grads[n]) for n in wrt]
+    held = [rel_err(tiny["got"]["logits"], tiny["want"]["logits"])] \
+        + [frob(tiny["grads"][n], tiny["want_grads"][n]) for n in wrt]
+    assert max(held) < 2e-4
+    assert max(moved) > 50 * 2e-4, (fault, moved)
+    # a fault on one kind of layer leaves the other kind's rotary and mask
+    # alone, but everything downstream still feels it
+    assert abs(float(bad["loss"]) - float(tiny["want"]["loss"])) > 1e-4
+
+
+def test_an_unknown_fault_is_refused(tiny):
+    with pytest.raises(ValueError, match="fault is one of"):
+        ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
+                       fault="no_such", **REF_KW)
+
+
+def test_interpreted_kernels_give_the_reference_too(monkeypatch):
+    """The same program with the flash kernels under the Pallas interpreter
+    (the windowed one-pass forward and the fused backward at 256 tokens)
+    instead of the CPU path's jnp reference."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    _, params, feed, got, grads = _run_tiny(amp=False)
+    want, want_grads = ref.loss_and_grads(
+        params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
+        wrt=["l0.attn.q.w", "l1.attn.k.w", "l2.attn.v.w", "l3.attn.q.w"],
+        last=TINY["seq_len"], **REF_KW)
+    assert rel_err(got["logits"], want["logits"]) < 1e-4
+    for name, g in want_grads.items():
+        assert frob(grads[name], g) < 2e-4, name
+
+
+def test_reference_in_blocks_is_the_reference(tiny):
+    """`q_block` and `remat` are the reference's memory, not its
+    mathematics."""
+    parts, grads = ref.loss_and_grads(
+        tiny["params"], tiny["tokens"], tiny["labels"],
+        wrt=["l0.attn.q.w", "l3.attn.k.w", "l2.router.w", "embed.w"],
+        q_block=32, remat=True, **REF_KW)
+    assert abs(float(parts["loss"]) - float(tiny["want"]["loss"])) < 1e-5
+    for name, g in grads.items():
+        assert frob(g, tiny["want_grads"][name]) < 1e-5, name
+
+
+def test_reference_last_positions_equal_the_full_pass(tiny):
+    parts = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
+                           last=16, **REF_KW)
+    assert rel_err(parts["logits"], tiny["want"]["logits"][:, -16:]) < 1e-6
+
+
+def test_reference_in_bfloat16_is_another_number(tiny):
+    low = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
+                         dtype=jnp.bfloat16, **REF_KW)
+    assert low["loss"].dtype == jnp.bfloat16
+    assert abs(float(low["loss"]) - float(tiny["want"]["loss"])) > 1e-4
+
+
+def test_layer_types_repeat_as_a_period():
+    kinds = models.mellum2.layer_kinds
+    assert kinds(8) == (["sliding_attention"] * 3 + ["full_attention"]) * 2
+    assert kinds(3, ["full_attention", "sliding_attention"]) == [
+        "full_attention", "sliding_attention", "full_attention"]
+    assert models.mellum2.PERIOD == ref.PERIOD
+    with pytest.raises(ValueError, match="layer_types holds"):
+        kinds(2, ["sliding_attention", "linear_attention"])
+    main, _, _, _ = _program(n_layer=2, layer_types=["full_attention",
+                                                     "sliding_attention"])
+    windows = [op.attrs.get("window") for op in main.global_block().ops
+               if op.type == "fused_attention"]
+    assert windows == [None, 96]
+
+
+def test_tiny_model_amp_within_bf16_of_reference():
+    """Under AMP the residual stream, the projections, attention and the
+    experts are bf16; the router, every norm's statistics and rotary's
+    trigonometry stay float32. At the initial weights (a sharper router
+    flips a few assignments under bf16 inputs)."""
+    _, params, feed, got, grads = _run_tiny(amp=True, seeded=False)
+    want, want_grads = ref.loss_and_grads(
+        params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
+        last=TINY["seq_len"], **REF_KW)
+    assert abs(float(got["loss"][0]) - float(want["loss"])) < 0.002
+    assert got["logits"].dtype == jnp.bfloat16
+    err = np.abs(np.asarray(got["logits"], np.float32)
+                 - np.asarray(want["logits"]))
+    std = float(np.std(want["logits"]))
+    assert err.mean() < 0.02 * std and err.max() < 0.15 * std
+    for name in ("l0.attn.q.w", "l0.attn.k.w", "l0.attn.v.w",
+                 "l0.attn.q_norm.w", "l3.attn.q.w", "l3.attn.k.w",
+                 "l1.experts.gate.w", "embed.w"):
+        assert grads[name].dtype == np.float32
+        limit = 0.08 if ".experts." in name else 0.04
+        assert frob(grads[name], want_grads[name]) < limit, name
+
+
+def test_five_adam_steps_lower_the_loss():
+    main, startup, fetches, _ = _program(
+        fluid.optimizer.Adam(learning_rate=3e-3))
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    feed = _batch()
+    losses = [float(exe.run(main, feed=feed, fetch_list=[fetches["loss"]],
+                            scope=scope)[0][0]) for _ in range(6)]
+    assert np.all(np.isfinite(losses)) and losses[-1] < losses[0] - 0.05
+
+
+def test_save_and_load_carry_the_weights_and_the_new_attributes(tmp_path):
+    """A checkpoint into a fresh scope gives the same loss; the program
+    written out and parsed back keeps `window` and `scaling`, and runs to
+    the same loss."""
+    main, startup, fetches, _ = _program(
+        fluid.optimizer.Adam(learning_rate=1e-3))
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    feed = _batch()
+    exe.run(main, feed=feed, fetch_list=[fetches["loss"]], scope=scope)
+    io.save_persistables(exe, str(tmp_path), main_program=main, scope=scope)
+    (loss,) = exe.run(main, feed=feed, fetch_list=[fetches["loss"]],
+                      scope=scope)
+    fresh = fluid.Scope()
+    exe.run(startup, scope=fresh)
+    io.load_persistables(exe, str(tmp_path), main_program=main, scope=fresh)
+    (again,) = exe.run(main, feed=feed, fetch_list=[fetches["loss"]],
+                       scope=fresh)
+    assert np.array_equal(loss, again)
+    parsed = fluid.Program.parse_from_string(main.serialize_to_string())
+    attention = [op for op in parsed.global_block().ops
+                 if op.type == "fused_attention"]
+    assert [op.attrs.get("window") for op in attention] == [96, 96, 96, None]
+    rotary = [op.attrs.get("scaling") for op in parsed.global_block().ops
+              if op.type == "rotary_embedding"]
+    assert rotary == [None] * 6 + [TINY_YARN] * 2
+    third = fluid.Scope()
+    exe.run(startup, scope=third)
+    io.load_persistables(exe, str(tmp_path), main_program=main, scope=third)
+    (parsed_loss,) = exe.run(parsed, feed=feed,
+                             fetch_list=[fetches["loss"].name], scope=third)
+    assert np.array_equal(loss, parsed_loss)
+
+
+def test_attention_ops_have_the_groups_shapes():
+    main, _, _, _ = _program(n_layer=1)
+    block = main.global_block()
+    (op,) = [o for o in block.ops if o.type == "fused_attention"]
+    for slot in ("Q", "K", "V"):
+        assert block.var(op.input(slot)[0]).shape[1:] == (4, 256, 16)
+    assert op.attrs["sm_scale"] == 16 ** -0.5 and op.attrs["window"] == 96
+    expands = [o for o in block.ops if o.type == "expand"]
+    assert [o.attrs["expand_times"] for o in expands] == [[1, 1, 2, 1, 1]] * 2
+
+
+# -- spans and counters ---------------------------------------------------------------------
+
+def test_compile_event_carries_the_census():
+    main, startup, fetches, _ = _program(
+        fluid.optimizer.SGD(learning_rate=1e-3))
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    exe.run(main, feed=_batch(), fetch_list=[fetches["loss"]], scope=scope)
+    detail = observe.observatory().latest(main._uid).detail
+    assert detail["layer_kinds"] == {"window_attention": 3,
+                                     "full_attention": 1}
+    assert detail["attention_window_layers"] == 3
+    assert detail["attention_window"] == 96
+    assert detail["attention_kv_group"] == 2
+    assert detail["moe_experts_routed"] == 16
+    assert detail["moe_experts_held"] == 4
+    assert detail["moe_share_bounded_moves"] == 4 * 4
+    # batch 2 x 4 heads x 3 layers, 128 x 128 tiles under a window of 96:
+    # all three of the triangle's meet the band
+    assert detail["window_tiles_computed"] == 72
+    assert "layer_kinds" not in observe.observatory().latest(
+        startup._uid).detail
+
+
+def test_the_tally_follows_the_tiles(monkeypatch):
+    """At tiles of 128 a window of 96 over 512 tokens meets 7 of the
+    triangle's 10 tiles: the counter
+    is the forward grid's, summed over the windowed ops, and a full layer
+    adds nothing to it."""
+    from paddle_tpu.ops import pallas_attention
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", (128, 128))
+    main, startup, fetches, _ = _program(
+        fluid.optimizer.SGD(learning_rate=1e-3), seq_len=512, n_layer=4)
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    exe.run(main, feed=_batch(seq_len=512), fetch_list=[fetches["loss"]],
+            scope=scope)
+    detail = observe.observatory().latest(main._uid).detail
+    assert detail["window_tiles_computed"] == 3 * 2 * 4 * 7
+
+
+def test_a_window_over_the_whole_sequence_is_counted_as_full():
+    main, startup, fetches, _ = _program(
+        fluid.optimizer.SGD(learning_rate=1e-3), sliding_window=256,
+        n_layer=2, layer_types=["sliding_attention"])
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    exe.run(main, feed=_batch(), fetch_list=[fetches["loss"]], scope=scope)
+    detail = observe.observatory().latest(main._uid).detail
+    assert detail["layer_kinds"] == {"full_attention": 2}
+    assert "attention_window_layers" not in detail
+
+
+def test_every_layer_is_built_under_its_name_scopes(tiny):
+    scopes = {}
+    for op in tiny["main"].global_block().ops:
+        if op.attrs.get("__role__") is None:
+            scopes.setdefault(op.attrs.get(ir.NAME_SCOPE_ATTR), set()) \
+                .add(op.type)
+    assert {"l0.swa", "l1.swa", "l2.swa", "l3.attn", "l0.moe",
+            "l3.moe"} <= set(scopes)
+    assert "l3.swa" not in scopes and "l0.attn" not in scopes
+    for name in ("l0.swa", "l3.attn"):
+        assert {"fused_attention", "rotary_embedding", "expand", "rms_norm",
+                "mul"} <= scopes[name]
+    assert {"moe_router", "moe_dispatch", "grouped_matmul",
+            "moe_combine"} <= scopes["l2.moe"]
+
+
+def test_amp_lists_hold_the_router_and_attention():
+    assert "moe_router" in registry.AMP_F32_OPS
+    assert "fused_attention" in registry.AMP_BF16_OPS
+    for op in ("rotary_embedding", "rms_norm", "expand"):
+        assert op not in registry.AMP_F32_OPS | registry.AMP_BF16_OPS
+
+
+# -- the others are what they were -------------------------------------------------------------
+
+QWEN3_NEXT_DIGEST = (542, "cbc1de6c08cb78225be52b50867e6fc6"
+                          "0fa186f02d8b105d72ae412204b91fef")
+KANANA2_DIGEST = (328, "ab310075d32c636a5aac316592eb8b67"
+                       "745945596d32bf261ed41f49554fbb5e")
+
+
+@pytest.mark.parametrize("model", ["olmoe", "qwen3_next", "kanana2"])
+def test_programs_without_a_window_are_unchanged_op_for_op(model):
+    """`fused_attention` took a window and `rotary_embedding` a scaling
+    block in this file's PR; a program that passes neither is the program
+    it was, op for op and attribute for attribute: the digests were taken
+    on the parent commit."""
+    from test_kanana2 import TINY as KANANA2_TINY
+    from test_qwen3_next import TINY as QWEN3_NEXT_TINY
+    build, sizes, digest = {
+        "olmoe": (models.olmoe.build, dict(
+            vocab_size=128, seq_len=128, n_layer=2, d_model=64, n_head=2,
+            n_expert=8, top_k=2, d_expert=32), OLMOE_DIGEST),
+        "qwen3_next": (models.qwen3_next.build, QWEN3_NEXT_TINY,
+                       QWEN3_NEXT_DIGEST),
+        "kanana2": (models.kanana2.build, KANANA2_TINY, KANANA2_DIGEST),
+    }[model]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        _, fetches = build(**sizes)
+        fluid.optimizer.Adam(learning_rate=1e-3).minimize(fetches["loss"])
+    assert _program_digest(main) == digest
+    for op in main.global_block().ops:
+        assert "window" not in op.attrs and "scaling" not in op.attrs
+
+
+def test_the_two_copies_of_the_reference_are_identical():
+    assert filecmp.cmp(
+        os.path.join(HERE, "mellum2_reference.py"),
+        os.path.join(ROOT, "benchmark", "references",
+                     "mellum2_reference.py"), shallow=False)
+
+
+def test_the_tiny_block_runs_through_the_benchmark():
+    """`run.py --tiny` on the cell: the configuration's tiny block through
+    the harness's own rehearsal, the in-run reference comparison
+    included."""
+    out = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
+         "--workload", "mellum2_12b_a2_5b.s8192", "--seed", "3000000019",
+         "--seconds", "1", "--trace", "0", "--tiny"],
+        capture_output=True, text=True, timeout=600, cwd=ROOT,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    assert "REHEARSAL" in out.stdout and "reference check after" in out.stdout
+    line = json.loads(out.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["rehearsal"] is True

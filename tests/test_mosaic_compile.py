@@ -150,3 +150,119 @@ def test_flash_kernels_compile_at_latent_attentions_widths(one_chip,
     (dkv,) = _custom_calls(bwd, "flash_dkv")
     assert "= bf16[32,4096,192]{" in dq
     assert "(bf16[32,4096,192]{" in dkv and ", bf16[32,4096,128]{" in dkv
+
+
+@pytest.mark.parametrize("window,tile,steps", [(1024, 512, 3),
+                                               (1000, 256, 5)])
+def test_windowed_flash_kernels_compile_at_the_cells_shape(one_chip,
+                                                           monkeypatch,
+                                                           window, tile,
+                                                           steps):
+    """The streaming forward and the split backward pair under a window as
+    `mellum2_12b_a2_5b.s8192` calls them: bf16 `[1, 32, 8192, 128]`, tiles of
+    512 x 512 (half the window), the inner grid axis three tiles long; at a
+    window of 1000, no multiple of 128, tiles of 256 and five steps; index
+    maps with a clamp and a division in them. One Mosaic custom call each, under the names the
+    benchmark's patterns tell from the full layer's."""
+    from paddle_tpu.ops import pallas_attention as pa
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q = arg((1, 32, 8192, 128))
+    assert pa._blk(8192, True) == (1024, 1024)
+    assert pa._blk(8192, True, window) == (tile, tile)
+    assert pa._band_steps(8192, tile, tile, window) == (steps, steps)
+    assert pa._bwd_plan(8192, 128, tile) == "split"
+    fwd = jax.jit(lambda q, k, v: pa._flash_forward(
+        q, k, v, True, 128 ** -0.5, window=window)).lower(q, q, q).compile()
+    (call,) = _custom_calls(fwd, "swa_flash_fwd")
+    assert "(bf16[32,8192,128]{" in call and "f32[32,1,8192]{" in call
+    assert not _custom_calls(fwd, "flash_fwd")
+    bwd = jax.jit(lambda q, k, v, o, lse, g: pa._flash_backward(
+        q, k, v, o, lse, g, True, 128 ** -0.5, 0.0, 0, window)).lower(
+            q, q, q, q, arg((32, 1, 8192), jnp.float32), q).compile()
+    (dq,) = _custom_calls(bwd, "swa_flash_dq")
+    (dkv,) = _custom_calls(bwd, "swa_flash_dkv")
+    assert "= bf16[32,8192,128]{" in dq and "(bf16[32,8192,128]{" in dkv
+    assert not _custom_calls(bwd, "flash_dq")
+
+
+def _lowered_digest(lowered):
+    """sha256 of a lowering's StableHLO with every Mosaic kernel in it
+    written out as MLIR without debug locations (the serialized body holds
+    this file's paths and line numbers)."""
+    import base64
+    import hashlib
+    import json
+    import re
+    from jax._src.interpreters import mlir as jmlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    kernels = []
+
+    def kernel(m):
+        config = json.loads(m.group(1).replace("\\22", '"'))
+        body = base64.b64decode(config["custom_call_config"].pop("body"))
+        ctx = jmlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            kernels.append(ir.Module.parse(body).operation.get_asm(
+                enable_debug_info=False))
+        return "backend_config = " + json.dumps(config, sort_keys=True)
+
+    text = re.sub(r'backend_config = "(\{.*?\})"', kernel, lowered.as_text())
+    return hashlib.sha256("\n".join([text] + kernels).encode()).hexdigest()
+
+
+# (q and k shape, v shape, causal) -> digests of the forward and of the
+# backward, taken on the commit before `window` (d5581d9) by this function
+PLAIN_FLASH = {
+    "olmoe_4096x128_causal": (
+        (1, 16, 4096, 128), (1, 16, 4096, 128), True,
+        "c74cd9e83480b7bd919027837268d805",
+        "c5e46afbf70c17696a21e97400f057a3"),
+    "kanana_4096x192_128_causal": (
+        (1, 32, 4096, 192), (1, 32, 4096, 128), True,
+        "b534a07c8ad5c36ee22a8efdf12551f6",
+        "249279561578b05acd1351396018ba03"),
+    "seq256_noncausal_64": (
+        (96, 8, 256, 64), (96, 8, 256, 64), False,
+        "4f106a79fd103fdd6da57d71a7591996",
+        "15e0af9f3b332318675ffebc72a4ad92"),
+    "seq2048_causal_64": (
+        (12, 8, 2048, 64), (12, 8, 2048, 64), True,
+        "b67dd88f3f453671ff69751b4ff054cf",
+        "250549d0a6115d9f734b2d64a7e792a0"),
+    "long_8192x128_causal": (
+        (1, 32, 8192, 128), (1, 32, 8192, 128), True,
+        "2a35fa0ae5900bd56fef1888c4ad2f43",
+        "32d85d454ed487083bac5d73d736a4f8"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PLAIN_FLASH))
+def test_flash_kernels_without_a_window_lower_to_the_text_they_did(
+        one_chip, monkeypatch, case):
+    """`window` absent: the one-pass and streaming forward, the fused
+    backward and the split pair are the instructions they were, at the
+    shapes the six cells that run them use: the lowered text (kernels'
+    MLIR included, debug locations left out) has the parent commit's
+    digest."""
+    from paddle_tpu.ops import pallas_attention as pa
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    qs, vs, causal, fwd_digest, bwd_digest = PLAIN_FLASH[case]
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, v, scale = arg(qs), arg(vs), qs[-1] ** -0.5
+    fwd = jax.jit(lambda q, k, v: pa._flash_forward(
+        q, k, v, causal, scale)).lower(q, q, v)
+    assert _lowered_digest(fwd)[:32] == fwd_digest
+    bwd = jax.jit(lambda q, k, v, o, lse, g: pa._flash_backward(
+        q, k, v, o, lse, g, causal, scale, 0.0, 0)).lower(
+            q, q, v, v, arg((qs[0] * qs[1], 1, qs[2]), jnp.float32), v)
+    assert _lowered_digest(bwd)[:32] == bwd_digest
