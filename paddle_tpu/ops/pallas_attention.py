@@ -20,9 +20,14 @@ backward recomputes the attention weights from the saved logsumexp, once a
 innermost, gives dK and dV from its scratch accumulators and dQ from the
 same `ds` tile (five products a tile). dQ is written straight out where a
 row is one K block, and accumulates in a VMEM-resident float32 row where it
-has several. Only a row too long to stay resident (`_bwd_plan`) takes the
-FlashAttention-2 pair instead, one kernel for dQ gridded over query blocks
-and one for dK/dV over key blocks, each recomputing the tile (seven).
+has several: the row's `[T, D]` accumulator and its `(1, T, D)` output block
+stay in VMEM over the row's whole grid, and the kernel asks for the scoped
+VMEM they and a step's tiles need (`_fused_bwd_vmem`: 32 MiB at every
+benchmark cell's row, 84 at 65536 x 128). Only a row whose need is over the
+budget (`_bwd_plan`: 96 MiB of a v5e core's 128; a bf16 row of 65536 x 256)
+takes the FlashAttention-2 pair instead, one kernel for dQ gridded over query
+blocks and one for dK/dV over key blocks, each recomputing the tile (seven);
+the pair is also the tests' bitwise oracle for the fused kernel.
 
 Attention-weight dropout runs inside the kernel using the TPU PRNG
 (pltpu.prng_seed / prng_random_bits), re-seeded per (batch·head, q-block,
@@ -50,8 +55,8 @@ either way, in every kernel (one-pass and streaming forward, fused backward,
 split pair). Latent attention (MLA) is the case that differs: query/key heads
 of 192 (128 without position + 64 rotary) over value heads of 128; a 192-wide
 block is the array's whole last axis, which is a legal block. Where `Dv == D`
-every kernel is the instructions it was. `_bwd_plan` reads `D`: the resident
-dQ row is query-wide.
+every kernel is the instructions it was. `_bwd_plan` reads both: the resident
+dQ row is query-wide (its 192 lanes held as 256), dO, v and dV value-wide.
 
 A causal call may carry a `window` W: key j is visible to query i iff
 `0 <= i - j < W` (sliding-window attention). The mask gains its lower edge and
@@ -154,21 +159,64 @@ def _blk(T, causal=False, window=None):
     raise ValueError(f"flash attention needs T % 128 == 0, got {T}")
 
 
-# What the fused backward kernel may keep resident for its dQ accumulator:
-# one (batch, head) row of float32 [T, D] where a row has several K blocks
-# (2 MiB = OLMoE's 4096 x 128; its output block of the same rows rides
-# beside it). Above it the row does not stay in VMEM and dQ takes its own
-# kernel again (32768 x 128 x 4 B = 16 MiB).
-_DQ_ROW_VMEM_BYTES = 2 * 1024 * 1024
+# One vreg of lanes: the last axis of an array in VMEM is held in whole
+# multiples of it (a 192-wide float32 row takes the room of a 256-wide one).
+_LANES = 128
+
+# The fused backward's scoped VMEM. PR 31 sent a row to the fused kernel only
+# while its float32 dQ accumulator was within 2 MiB, the one shape that PR
+# had (OLMoE's 4096 x 128); that was the constant's limit, not the chip's (a
+# v5e core has 128 MiB of VMEM). The kernel asks for what its row needs
+# (`_fused_bwd_vmem`), never less than the 32 MiB the executor gives the
+# step's other ops and it asked before, and takes every row whose need is
+# within the budget; the need counts `_SCORE_TILES` float32 score-sized
+# temporaries a grid step (s, p, dp, ds; more than any shape compiled for a
+# described v5e wanted: the least limits that compiled left 2 to 3).
+# Measured (TPU v5 lite, libtpu 0.0.34, bf16, causal, the backward alone, ms
+# a call split -> fused at the limit asked; dQ, dK, dV bitwise the pair's in
+# every row; chip run, PR 41):
+#   [16, 4096, 128]  (OLMoE, Ouro)        1.899 -> 1.249 at 32 MiB
+#   [32, 4096, 192] over 128 (Kanana-2)   5.542 -> 3.999 at 32
+#   [32, 8192, 128]  (Mellum2, full)     14.189 -> 9.299 at 32
+#   the same under window 1024, 512^2     4.602 -> 3.311 at 32
+#   [16, 4096, 256]  (Qwen3-Next)         3.483 -> 2.385 at 32
+#   [4, 16384, 128]                       6.373 -> 4.147 at 36
+#   [1, 32768, 128]                       6.143 -> 3.973 at 52
+#   [1, 65536, 128]                      24.157 -> 15.596 at 84
+# The limit asked is not free: the first three read 1.249, 4.000, 9.298 at
+# 24 MiB and 1.314, 4.088, 9.579 at 96, so a row asks for its need and not
+# for the budget. Over the budget (a bf16 row of 65536 x 256: 152 MiB) a row
+# takes the split pair, which keeps nothing of a row.
+_SCORE_TILES = 4
+_SCOPED_VMEM_FLOOR_BYTES = 32 * 1024 * 1024
+_VMEM_BUDGET_BYTES = 96 * 1024 * 1024
 
 
-def _bwd_plan(T, D, BK):
+def _fused_bwd_vmem(T, D, Dv, BQ, BK, itemsize):
+    """Bytes of scoped VMEM the fused backward asks for at a row of several
+    K blocks: the row's dQ (the float32 `[T, D]` accumulator and the
+    `(1, T, D)` output block in the input's dtype, double-buffered, both
+    held over the row's whole grid), a step's blocks (q, dO; k, v, dK, dV;
+    double-buffered), the float32 dK and dV accumulators and the score
+    temporaries."""
+    d, dv = (-(-x // _LANES) * _LANES for x in (D, Dv))
+    row = T * d * (4 + 2 * itemsize)
+    blocks = 2 * itemsize * (BQ + 2 * BK) * (d + dv)
+    accumulators = 4 * BK * (d + dv)
+    scores = _SCORE_TILES * 4 * BQ * BK
+    return max(row + blocks + accumulators + scores,
+               _SCOPED_VMEM_FLOOR_BYTES)
+
+
+def _bwd_plan(T, D, Dv, BQ, BK, itemsize):
     """"fused": one kernel gives dQ, dK and dV from one pass over the score
-    tiles. "split": dQ and dK/dV each recompute them, where a row's dQ
-    accumulator is more than the budget above. With one K block a row
-    (every attention block of both transformer cells) a q-block's dQ is
-    complete in its one grid step and nothing is kept."""
-    if T == BK or T * D * 4 <= _DQ_ROW_VMEM_BYTES:
+    tiles. "split": dQ and dK/dV each recompute them, where the row's dQ and
+    a step's tiles are more VMEM than the budget above. With one K block a
+    row (every attention block of both transformer cells) a q-block's dQ is
+    complete in its one grid step and nothing is kept. The choice reads the
+    input's shape and item size and the tile alone."""
+    if T == BK or _fused_bwd_vmem(T, D, Dv, BQ, BK,
+                                  itemsize) <= _VMEM_BUDGET_BYTES:
         return "fused"
     return "split"
 
@@ -383,14 +431,11 @@ def _flash_fwd_onepass_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     lse_ref[0, 0] = (m + jnp.log(l))[:, 0]
 
 
-# Width of the streaming forward's row statistics in scratch: one vreg of
-# lanes, every lane of a row holding the row's value (as jax's own TPU
-# flash kernel keeps `m` and `l`). A 1-D `(blk_q,)` scratch wants the rows
-# along lanes and a `(blk_q, 1)` one stores a lane of each vreg; both cost
-# more than the products of a short tile (PERF.md section 7, PR 33).
-_LANES = 128
-
-
+# The streaming forward's row statistics in scratch are `_LANES` wide, every
+# lane of a row holding the row's value (as jax's own TPU flash kernel keeps
+# `m` and `l`). A 1-D `(blk_q,)` scratch wants the rows along lanes and a
+# `(blk_q, 1)` one stores a lane of each vreg; both cost more than the
+# products of a short tile (PERF.md section 7, PR 33).
 def _lanes(x, n):
     """A lane-replicated [rows, _LANES] statistic against [rows, n]."""
     reps = -(-n // _LANES)
@@ -660,7 +705,7 @@ def _seed_arr(seed):
     return jnp.asarray(seed, jnp.int32).reshape(1, 1)
 
 
-def _compiler_params(carried=1):
+def _compiler_params(carried=1, vmem_bytes=None):
     """The last `carried` grid dims iterate sequentially (they carry
     scratch accumulators); the ones before are parallel. 0: the one-pass
     forward, a two-axis grid whose steps share nothing. 1: a three-axis
@@ -668,9 +713,10 @@ def _compiler_params(carried=1):
     forward, the split backward pair, the fused backward where a row is
     one K block). 2: the middle one carries an accumulator too (the fused
     backward's dQ row), and the kernel asks for the scoped VMEM that row
-    needs itself (the executor's 32 MiB,
-    core/executor.py::resolve_compiler_options, which a caller under plain
-    `jax.jit` does not have)."""
+    needs itself, `vmem_bytes` (`_fused_bwd_vmem`): on this call alone, not
+    through the executor's option for the whole step
+    (core/executor.py::resolve_compiler_options, which a caller under plain
+    `jax.jit` does not have either)."""
     from jax.experimental.pallas import tpu as pltpu
     if carried == 0:
         return pltpu.CompilerParams(
@@ -678,7 +724,7 @@ def _compiler_params(carried=1):
     if carried == 2:
         return pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=32 * 1024 * 1024)
+            vmem_limit_bytes=vmem_bytes)
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
@@ -770,8 +816,9 @@ def _flash_backward(q, k, v, o, lse, g, causal, sm_scale, dropout_rate, seed,
                     axis=-1)[:, None, :]
     window = _window_of(window, T)
     BQ, BK = _blk(T, causal, window)
-    run = (_flash_bwd_fused if _bwd_plan(T, D, BK) == "fused"
-           else _flash_bwd_split)
+    run = (_flash_bwd_fused
+           if _bwd_plan(T, D, v.shape[-1], BQ, BK, q.dtype.itemsize)
+           == "fused" else _flash_bwd_split)
     attrs = dict(sm_scale=sm_scale, causal=causal, dropout_rate=dropout_rate)
     if window is not None:
         attrs["window"] = window
@@ -845,10 +892,13 @@ def _flash_bwd_fused(args, BQ, BK, attrs):
                pltpu.VMEM((BK, Dv), jnp.float32)]
     if T == BK:
         dq_spec = pl.BlockSpec((1, BQ, D), at_q)
+        params = _compiler_params()
     else:
         # the row's dQ stays in VMEM over both inner grid axes
         dq_spec = pl.BlockSpec((1, T, D), lambda bh, kj, qi: (bh, 0, 0))
         scratch.append(pltpu.VMEM((T, D), jnp.float32))
+        params = _compiler_params(carried=2, vmem_bytes=_fused_bwd_vmem(
+            T, D, Dv, BQ, BK, q3.dtype.itemsize))
     return pl.pallas_call(
         functools.partial(_flash_bwd_kernel, **attrs),
         grid=(BH, T // BK, steps),
@@ -858,7 +908,7 @@ def _flash_bwd_fused(args, BQ, BK, attrs):
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
                    for x in (q3, k3, v3)],
         scratch_shapes=scratch,
-        compiler_params=_compiler_params(carried=1 if T == BK else 2),
+        compiler_params=params,
         interpret=_interpret(),
         name=_named("flash_dq_flash_dkv", window),
     )(*args)

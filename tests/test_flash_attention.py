@@ -286,7 +286,7 @@ def test_grad_op_on_saved_lse_matches_reference(interpret_kernels,
 
 # -- one backward kernel: dQ, dK and dV from one pass over the score tiles
 #    (ops/pallas_attention.py::_flash_bwd_kernel); the split pair is its
-#    oracle and what rows above `_DQ_ROW_VMEM_BYTES` still take ------------
+#    oracle and what rows over the VMEM budget (`_bwd_plan`) still take ------
 
 @pytest.mark.parametrize("inputs", ["float32", "amp_float32_out_grad"])
 @pytest.mark.parametrize("tiles", [None, (128, 128)],
@@ -331,25 +331,76 @@ def test_fused_backward_is_bitwise_the_split_kernels(
                                    rtol=tol, err_msg=n)
 
 
-@pytest.mark.parametrize("shape,causal,plan", [
-    ((96, 8, 256, 64), False, "fused"),       # transformer_base.seq256
-    ((96, 8, 256, 64), True, "fused"),
-    ((12, 8, 2048, 64), False, "fused"),      # transformer_base.seq2048
-    ((12, 8, 2048, 64), True, "fused"),
-    ((1, 16, 4096, 128), True, "fused"),      # olmoe_1b_7b.bs1: 2 MiB row
-    ((1, 4, 8192, 128), True, "split"),       # 4 MiB
-    ((1, 1, 32768, 64), True, "split"),       # tests/test_long_context_tpu
-    ((1, 1, 32768, 128), False, "split"),     # 16 MiB cannot be resident
+@pytest.mark.parametrize("kernel,D,Dv,window", [
+    ("flash", 192, 128, None),      # latent attention's widths
+    ("swa_flash", 64, 64, 200),     # a band narrower than the row
+    ("swa_flash", 192, 128, 130),
+], ids=["D192-Dv128", "window200", "D192-Dv128-window130"])
+def test_resident_row_backward_is_bitwise_the_split_pair(
+        interpret_kernels, monkeypatch, kernel, D, Dv, window):
+    """A row of four K blocks, its dQ accumulated in the resident float32
+    row over ascending kj: dQ, dK and dV bitwise the split pair's, where the
+    query/key heads have a width of their own and under a window."""
+    rng = np.random.RandomState(12)
+    B, H, T = 1, 2, 512
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", (128, 128))
+    assert pallas_attention._bwd_plan(T, D, Dv, 128, 128, 4) == "fused"
+    q, k, v = _qkv(rng, B, H, T, D, Dv)
+    g = jnp.asarray(rng.randn(B, H, T, Dv), jnp.float32)
+    out, lse = pallas_attention._flash_forward(q, k, v, True, D ** -0.5,
+                                               window=window)
+
+    def run():
+        """A trace of its own for each plan: jax keeps one a function."""
+        def backward(*a):
+            return pallas_attention._flash_backward(
+                *a, out, lse, g, True, D ** -0.5, 0.0, 0, window)
+        return str(jax.make_jaxpr(backward)(q, k, v)), backward(q, k, v)
+
+    text, fused = run()
+    monkeypatch.setattr(pallas_attention, "_bwd_plan", lambda *a: "split")
+    text_s, split = run()
+    names = [f"{kernel}_{n}" for n in ("dq_flash_dkv", "dq", "dkv")]
+    assert [kernel_calls(text, n) for n in names] == [1, 0, 0]
+    assert [kernel_calls(text_s, n) for n in names] == [0, 1, 1]
+    for a, b, name in zip(fused, split, "qkv"):
+        assert np.abs(np.asarray(a)).max() > 0
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("shape,causal,window,plan,asked_mib", [
+    ((96, 8, 256, 64), False, None, "fused", None),  # transformer_base.seq256
+    ((96, 8, 256, 64), True, None, "fused", None),
+    ((12, 8, 2048, 64), False, None, "fused", None),        # .seq2048
+    ((12, 8, 2048, 64), True, None, "fused", None),
+    ((1, 16, 4096, 128), True, None, "fused", 32),  # olmoe_1b_7b.bs1, ouro
+    ((1, 32, 4096, 192), True, None, "fused", 32),  # kanana_2_30b_a3b.bs1
+    ((1, 32, 8192, 128), True, None, "fused", 32),  # mellum2_12b_a2_5b.s8192
+    ((1, 32, 8192, 128), True, 1024, "fused", 32),  # its windowed layers
+    ((1, 16, 4096, 256), True, None, "fused", 32),  # qwen3_next_80b_a3b.bs1
+    ((1, 4, 16384, 128), True, None, "fused", 36),
+    ((1, 1, 32768, 64), True, None, "fused", 52),  # test_long_context_tpu
+    ((1, 1, 32768, 128), False, None, "fused", 54.5),
+    ((1, 1, 65536, 128), True, None, "fused", 84),  # the longest run (PR 41)
+    ((1, 1, 65536, 256), True, None, "split", 152),
+    ((1, 1, 131072, 128), True, None, "split", 148),
 ], ids=lambda x: "x".join(map(str, x)) if isinstance(x, tuple) else str(x))
-def test_backward_plan_follows_the_resident_dq_row(shape, causal, plan):
-    """Fused wherever a row is one K block or its float32 `[T, D]` dQ
-    accumulator is within the budget; the choice reads T, D and the tile
-    alone."""
+def test_backward_plan_follows_the_resident_dq_row(shape, causal, window,
+                                                   plan, asked_mib):
+    """Fused wherever a row is one K block, or its dQ (the float32
+    accumulator and the double-buffered output block, lanes padded) and a
+    step's tiles are within the budget; the choice reads the shape, the item
+    size and the tile alone. The kernel asks for what the row needs, 32 MiB
+    at the least: the cells' rows ask what the kernel always asked, the long
+    rows what they were run at on the chip (PR 41)."""
     _, _, T, D = shape
-    _, BK = pallas_attention._blk(T, causal)
-    assert pallas_attention._bwd_plan(T, D, BK) == plan
+    BQ, BK = pallas_attention._blk(T, causal, window)
+    assert pallas_attention._bwd_plan(T, D, D, BQ, BK, 2) == plan
     if T != BK:
-        assert (T * D * 4 <= pallas_attention._DQ_ROW_VMEM_BYTES) == (
+        asked = pallas_attention._fused_bwd_vmem(T, D, D, BQ, BK, 2)
+        assert asked == asked_mib * 2 ** 20
+        assert (asked <= pallas_attention._VMEM_BUDGET_BYTES) == (
             plan == "fused")
 
 

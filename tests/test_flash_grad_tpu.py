@@ -28,12 +28,15 @@ pytestmark = pytest.mark.skipif(
     reason="Mosaic kernels and in-kernel dropout need real TPU hardware")
 
 # (q shape, causal, the tiles `_blk` gives it): transformer_base.seq256,
-# .seq2048 (encoder self and cross, decoder self), olmoe_1b_7b.bs1
+# .seq2048 (encoder self and cross, decoder self), olmoe_1b_7b.bs1,
+# mellum2_12b_a2_5b.s8192's full layer, qwen3_next_80b_a3b.bs1
 CELLS = [((96, 8, 256, 64), False, (256, 256)),
          ((96, 8, 256, 64), True, (256, 256)),
          ((12, 8, 2048, 64), False, (512, 2048)),
          ((12, 8, 2048, 64), True, (256, 2048)),
-         ((1, 16, 4096, 128), True, (1024, 1024))]
+         ((1, 16, 4096, 128), True, (1024, 1024)),
+         ((1, 32, 8192, 128), True, (1024, 1024)),
+         ((1, 16, 4096, 256), True, (1024, 1024))]
 KERNELS = ("flash_fwd_onepass", "flash_fwd", "flash_dq_flash_dkv",
            "flash_dq", "flash_dkv")
 
@@ -97,11 +100,12 @@ def test_saved_lse_grad_is_bitwise_the_generic_path(monkeypatch, shape,
 def test_fused_backward_is_bitwise_the_split_kernels(monkeypatch, shape,
                                                      causal, tiles, inputs):
     """Every cell's shape takes the fused kernel (one K block a row in the
-    transformer cells, a resident `[4096, 128]` float32 dQ row in OLMoE's);
+    transformer cells, a resident float32 dQ row of `[4096, 128]` in OLMoE's,
+    `[8192, 128]` in Mellum2's, `[4096, 256]` in Qwen3-Next's);
     with the plan forced to the split pair the same program gives the same
     dQ/dK/dV, dropout 0.1."""
     _, _, T, D = shape
-    assert pallas_attention._bwd_plan(T, D, tiles[1]) == "fused"
+    assert pallas_attention._bwd_plan(T, D, D, *tiles, 2) == "fused"
     feed, after = _feed_for(inputs, shape, monkeypatch)
     place = fluid.TPUPlace(0)
     out, grads, text = attention_grads(feed, causal, amp=True, rate=0.1,

@@ -125,11 +125,13 @@ def test_share_movements_compile_at_the_cells_shapes(one_chip):
 
 def test_flash_kernels_compile_at_latent_attentions_widths(one_chip,
                                                            monkeypatch):
-    """The streaming forward and the split backward pair as
+    """The streaming forward and the fused backward as
     `kanana_2_30b_a3b.bs1` calls them: bf16 q, k `[1, 32, 4096, 192]` over
     v `[1, 32, 4096, 128]`. A 192-wide block is the array's whole last axis
-    (one and a half vregs of lanes): Mosaic takes it; `Out` and `dV` leave at
-    128, `dQ` and `dK` at 192, and no 192-wide value is anywhere."""
+    (one and a half vregs of lanes): Mosaic takes it, and the row's float32
+    dQ (4096 x 192, held as 256 lanes) stays resident within the 32 MiB the
+    kernel asks for; `Out` and `dV` leave at 128, `dQ` and `dK` at 192, and
+    no 192-wide value is anywhere."""
     from paddle_tpu.ops import pallas_attention as pa
     monkeypatch.setattr(pa, "_interpret", lambda: False)
 
@@ -138,7 +140,8 @@ def test_flash_kernels_compile_at_latent_attentions_widths(one_chip,
 
     q, v = arg((1, 32, 4096, 192)), arg((1, 32, 4096, 128))
     assert pa._fwd_plan(4096, pa._blk(4096, True)[1]) == "stream"
-    assert pa._bwd_plan(4096, 192, pa._blk(4096, True)[1]) == "split"
+    assert pa._bwd_plan(4096, 192, 128, *pa._blk(4096, True), 2) == "fused"
+    assert pa._fused_bwd_vmem(4096, 192, 128, 1024, 1024, 2) == 32 * 2 ** 20
     fwd = jax.jit(lambda q, k, v: pa._flash_forward(
         q, k, v, True, 192 ** -0.5)).lower(q, q, v).compile()
     (call,) = _custom_calls(fwd, "flash_fwd")
@@ -146,10 +149,11 @@ def test_flash_kernels_compile_at_latent_attentions_widths(one_chip,
     bwd = jax.jit(lambda q, k, v, o, lse, g: pa._flash_backward(
         q, k, v, o, lse, g, True, 192 ** -0.5, 0.0, 0)).lower(
             q, q, v, v, arg((32, 1, 4096), jnp.float32), v).compile()
-    (dq,) = _custom_calls(bwd, "flash_dq")
-    (dkv,) = _custom_calls(bwd, "flash_dkv")
-    assert "= bf16[32,4096,192]{" in dq
-    assert "(bf16[32,4096,192]{" in dkv and ", bf16[32,4096,128]{" in dkv
+    (call,) = _custom_calls(bwd, "flash_dq_flash_dkv")
+    results = call.split(" custom-call(")[0]
+    assert (results.count("bf16[32,4096,192]{") == 2
+            and results.count("bf16[32,4096,128]{") == 1)
+    assert not _custom_calls(bwd, "flash_dkv")
 
 
 @pytest.mark.parametrize("window,tile,steps", [(1024, 512, 3),
@@ -158,7 +162,7 @@ def test_windowed_flash_kernels_compile_at_the_cells_shape(one_chip,
                                                            monkeypatch,
                                                            window, tile,
                                                            steps):
-    """The streaming forward and the split backward pair under a window as
+    """The streaming forward and the fused backward under a window as
     `mellum2_12b_a2_5b.s8192` calls them: bf16 `[1, 32, 8192, 128]`, tiles of
     512 x 512 (half the window), the inner grid axis three tiles long; at a
     window of 1000, no multiple of 128, tiles of 256 and five steps; index
@@ -174,7 +178,7 @@ def test_windowed_flash_kernels_compile_at_the_cells_shape(one_chip,
     assert pa._blk(8192, True) == (1024, 1024)
     assert pa._blk(8192, True, window) == (tile, tile)
     assert pa._band_steps(8192, tile, tile, window) == (steps, steps)
-    assert pa._bwd_plan(8192, 128, tile) == "split"
+    assert pa._bwd_plan(8192, 128, 128, tile, tile, 2) == "fused"
     fwd = jax.jit(lambda q, k, v: pa._flash_forward(
         q, k, v, True, 128 ** -0.5, window=window)).lower(q, q, q).compile()
     (call,) = _custom_calls(fwd, "swa_flash_fwd")
@@ -183,9 +187,9 @@ def test_windowed_flash_kernels_compile_at_the_cells_shape(one_chip,
     bwd = jax.jit(lambda q, k, v, o, lse, g: pa._flash_backward(
         q, k, v, o, lse, g, True, 128 ** -0.5, 0.0, 0, window)).lower(
             q, q, q, q, arg((32, 1, 8192), jnp.float32), q).compile()
-    (dq,) = _custom_calls(bwd, "swa_flash_dq")
-    (dkv,) = _custom_calls(bwd, "swa_flash_dkv")
-    assert "= bf16[32,8192,128]{" in dq and "(bf16[32,8192,128]{" in dkv
+    (call,) = _custom_calls(bwd, "swa_flash_dq_flash_dkv")
+    assert call.split(" custom-call(")[0].count("bf16[32,8192,128]{") == 3
+    assert not _custom_calls(bwd, "swa_flash_dkv")
     assert not _custom_calls(bwd, "flash_dq")
 
 
@@ -218,28 +222,34 @@ def _lowered_digest(lowered):
 
 
 # (q and k shape, v shape, causal) -> digests of the forward and of the
-# backward, taken on the commit before `window` (d5581d9) by this function
+# backward, taken on the commit before `window` (d5581d9) by this function.
+# The two shapes whose backward was the split pair then take the fused kernel
+# since `_bwd_plan` follows what VMEM holds: their pair's digest stays as the
+# oracle's, under the plan forced to it, beside the fused kernel's, taken on
+# the commit that changed the plan
 PLAIN_FLASH = {
     "olmoe_4096x128_causal": (
         (1, 16, 4096, 128), (1, 16, 4096, 128), True,
         "c74cd9e83480b7bd919027837268d805",
-        "c5e46afbf70c17696a21e97400f057a3"),
+        {"fused": "c5e46afbf70c17696a21e97400f057a3"}),
     "kanana_4096x192_128_causal": (
         (1, 32, 4096, 192), (1, 32, 4096, 128), True,
         "b534a07c8ad5c36ee22a8efdf12551f6",
-        "249279561578b05acd1351396018ba03"),
+        {"fused": "e205bb004c4bd12846f309bef5b31593",
+         "split": "249279561578b05acd1351396018ba03"}),
     "seq256_noncausal_64": (
         (96, 8, 256, 64), (96, 8, 256, 64), False,
         "4f106a79fd103fdd6da57d71a7591996",
-        "15e0af9f3b332318675ffebc72a4ad92"),
+        {"fused": "15e0af9f3b332318675ffebc72a4ad92"}),
     "seq2048_causal_64": (
         (12, 8, 2048, 64), (12, 8, 2048, 64), True,
         "b67dd88f3f453671ff69751b4ff054cf",
-        "250549d0a6115d9f734b2d64a7e792a0"),
+        {"fused": "250549d0a6115d9f734b2d64a7e792a0"}),
     "long_8192x128_causal": (
         (1, 32, 8192, 128), (1, 32, 8192, 128), True,
         "2a35fa0ae5900bd56fef1888c4ad2f43",
-        "32d85d454ed487083bac5d73d736a4f8"),
+        {"fused": "3ee1674305dca884d73928f751648c1d",
+         "split": "32d85d454ed487083bac5d73d736a4f8"}),
 }
 
 
@@ -249,11 +259,11 @@ def test_flash_kernels_without_a_window_lower_to_the_text_they_did(
     """`window` absent: the one-pass and streaming forward, the fused
     backward and the split pair are the instructions they were, at the
     shapes the six cells that run them use: the lowered text (kernels'
-    MLIR included, debug locations left out) has the parent commit's
-    digest."""
+    MLIR included, debug locations left out, the scoped VMEM a kernel asks
+    for among its parameters) has the recorded digest."""
     from paddle_tpu.ops import pallas_attention as pa
     monkeypatch.setattr(pa, "_interpret", lambda: False)
-    qs, vs, causal, fwd_digest, bwd_digest = PLAIN_FLASH[case]
+    qs, vs, causal, fwd_digest, bwd_digests = PLAIN_FLASH[case]
 
     def arg(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -262,7 +272,12 @@ def test_flash_kernels_without_a_window_lower_to_the_text_they_did(
     fwd = jax.jit(lambda q, k, v: pa._flash_forward(
         q, k, v, causal, scale)).lower(q, q, v)
     assert _lowered_digest(fwd)[:32] == fwd_digest
-    bwd = jax.jit(lambda q, k, v, o, lse, g: pa._flash_backward(
-        q, k, v, o, lse, g, causal, scale, 0.0, 0)).lower(
-            q, q, v, v, arg((qs[0] * qs[1], 1, qs[2]), jnp.float32), v)
-    assert _lowered_digest(bwd)[:32] == bwd_digest
+    assert pa._bwd_plan(qs[2], qs[3], vs[3], *pa._blk(qs[2], causal),
+                        2) == "fused"
+    for plan, digest in bwd_digests.items():
+        if plan == "split":
+            monkeypatch.setattr(pa, "_bwd_plan", lambda *a: "split")
+        bwd = jax.jit(lambda q, k, v, o, lse, g: pa._flash_backward(
+            q, k, v, o, lse, g, causal, scale, 0.0, 0)).lower(
+                q, q, v, v, arg((qs[0] * qs[1], 1, qs[2]), jnp.float32), v)
+        assert _lowered_digest(bwd)[:32] == digest, plan
