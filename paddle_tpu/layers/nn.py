@@ -1452,11 +1452,15 @@ def gated_delta_rule(q, k, v, a, b, a_log_attr=None, dt_bias_attr=None,
     return out
 
 
-def swiglu(gate, up, name=None):
-    """`silu(gate) * up`."""
+def swiglu(gate, up, name=None, group_sizes=None):
+    """`silu(gate) * up`. With `group_sizes` (the `GroupSizes` of an expert
+    layer's share, whose rows `gate` and `up` are) over the rows those
+    groups use only."""
     helper = LayerHelper("swiglu", name=name)
     out = helper.create_variable_for_type_inference(gate.dtype)
-    helper.append_op("swiglu", inputs={"Gate": [gate.name], "Up": [up.name]},
+    used = {} if group_sizes is None else {"GroupSizes": [group_sizes.name]}
+    helper.append_op("swiglu",
+                     inputs={"Gate": [gate.name], "Up": [up.name], **used},
                      outputs={"Out": [out.name]})
     return out
 
@@ -1624,7 +1628,14 @@ def moe_experts(input, routing, num_experts, expert_size, param_attr=None,
     only, a chunk at a time, so a share's time grows with the assignments
     that fall on it (at one chip's even share ~6% of the rows are used; with
     every assignment on a held expert the movements cost 1.3 times what
-    static gathers over all the rows would)."""
+    static gathers over all the rows would). What stands between them
+    follows the held rows too: the gate's and the up projection's products
+    are ONE `grouped_matmul` op under a share (`W: [<name>.gate.w,
+    <name>.up.w]` -> two `Out`s; the parameters are the same two), so the
+    rows' gradient is one variable that the op's grad sums over the used
+    rows (two ops would have `append_backward` insert a `sum` over all of
+    them), and `swiglu` takes `GroupSizes` and visits the used rows only,
+    as its grad does."""
     from ..ops.moe import ROW_TILE
     from ..param_attr import ParamAttr
     helper = LayerHelper("moe_experts", **locals())
@@ -1664,17 +1675,23 @@ def moe_experts(input, routing, num_experts, expert_size, param_attr=None,
                               "GroupSizes": [sizes.name]},
                      attrs={"row_tile": ROW_TILE, **share})
 
-    def grouped(x, w):
-        out = new(dtype)
+    def grouped(x, *ws):
+        outs = [new(dtype) for _ in ws]
         helper.append_op(
             "grouped_matmul",
-            inputs={"X": [x.name], "W": [w.name],
+            inputs={"X": [x.name], "W": [w.name for w in ws],
                     "GroupSizes": [sizes.name]},
-            outputs={"Out": [out.name]})
-        return out
+            outputs={"Out": [out.name for out in outs]})
+        return outs
 
-    hidden = swiglu(grouped(x_sorted, w_gate), grouped(x_sorted, w_up))
-    y_sorted = grouped(hidden, w_down)
+    if share:
+        # one op for the two projections of `x_sorted`: its gradient is one
+        # variable, summed over the used rows by the op's grad, and the
+        # silu product follows the held groups too
+        hidden = swiglu(*grouped(x_sorted, w_gate, w_up), group_sizes=sizes)
+    else:
+        hidden = swiglu(*grouped(x_sorted, w_gate), *grouped(x_sorted, w_up))
+    y_sorted, = grouped(hidden, w_down)
     out = new(dtype)
     # under a share the movements follow the held groups, as the kernels do
     used = {"GroupSizes": [sizes.name]} if share else {}

@@ -18,7 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..core.registry import register_op
+from ..core.registry import register_grad, register_op
+from .moe import map_used_rows
 
 
 @register_op("rms_norm")
@@ -135,12 +136,51 @@ def _rotary_embedding(ctx, X):
     return {"Out": out.astype(X.dtype)}
 
 
+def _silu_product(gate, up):
+    return (jax.nn.silu(gate.astype(jnp.float32))
+            * up.astype(jnp.float32)).astype(gate.dtype)
+
+
+def _silu_product_grads(gate, up, g):
+    """(dGate, dUp) of `_silu_product` under the cotangent g."""
+    return jax.vjp(_silu_product, gate, up)[1](g)
+
+
 @register_op("swiglu")
-def _swiglu(ctx, Gate, Up):
-    """`silu(gate) * up`, the gated feed-forward's elementwise middle."""
-    g32 = Gate.astype(jnp.float32)
-    return {"Out": (jax.nn.silu(g32) * Up.astype(jnp.float32))
-            .astype(Gate.dtype)}
+def _swiglu(ctx, Gate, Up, GroupSizes=None):
+    """`silu(gate) * up`, the gated feed-forward's elementwise middle. With
+    `GroupSizes` (the hidden rows of an expert layer's share, `ops/moe.py`)
+    the same product over the rows the held groups use, a chunk at a time,
+    written into an allocation of the layout's shape: the rows behind them
+    are not visited."""
+    if GroupSizes is None:
+        return {"Out": _silu_product(Gate, Up)}
+    ctx.tally("moe_share_bounded_ops")
+    out, = map_used_rows(lambda gate, up: (_silu_product(gate, up),),
+                         GroupSizes, Gate, Up)
+    return {"Out": out}
+
+
+@register_grad("swiglu")
+def _swiglu_grad(ctx, ins, out_grads):
+    """The product's own vjp: on the whole arrays what the generic grad op
+    traces, and with `GroupSizes` the same on each chunk of the used rows
+    (a used row's dGate and dUp are bitwise the static form's)."""
+    gate, up = ins["Gate"][0], ins["Up"][0]
+    g = out_grads["Out"][0]
+    if g is None:
+        return {}
+    g = g.astype(gate.dtype)
+    if not ins.get("GroupSizes"):
+        d_gate, d_up = _silu_product_grads(gate, up, g)
+    else:
+        ctx.tally("moe_share_bounded_ops")
+        # the experts' hidden rows are read by this op last: their two
+        # gradients take their buffers
+        d_gate, d_up = map_used_rows(_silu_product_grads,
+                                     ins["GroupSizes"][0], gate, up, g,
+                                     in_place=2)
+    return {"Gate": d_gate, "Up": d_up}
 
 
 @register_op("exit_gate")

@@ -10,6 +10,8 @@ pieces.
                into groups that start and end on a row tile
     grouped_matmul (x3) + swiglu: one matmul per projection over the
                stacked expert weights [E, K, F], rows grouped by expert
+               (under a share the gate's and the up projection's are one
+               op of two weights)
     combine:   rows back in token order, weighted sum over the k slots
 
 Dropless: every assignment is computed; a group holds whatever the router
@@ -60,13 +62,22 @@ chip in sixteen (4096 tokens, top 10 of 512, 32 held: ~2560 assignments in
 4096-4224 used rows of 45056) the four movements of a layer take 1.7 ms
 alone on a v5e where gathers over the static rows took 9.1; with every
 assignment on a held expert (40960, the worst case) they take 12.3 ms
-against 9.1 (PERF.md section 6, PR 37). The rows behind the used ones are
-never written and never read: their buffers are allocated, not filled, and
-hold whatever was in memory. Nothing does arithmetic on them or on a padding
-row that reaches a result, not even times a zero weight (0 x NaN is NaN): a
-row's `Source` and an assignment's `Slot` are -1 where there is nothing, and
-a movement applies `Source` with a select or drops the row by an index out
-of range.
+against 9.1 (PERF.md section 6, PR 37). What stands between dispatch and
+combine is elementwise and goes over the used rows the same way, in larger
+steps (`map_used_rows`, `_ELEMENTWISE_ROWS`: no gather, so a chunk is plain
+traffic): the silu product of the hidden rows and its grad (`swiglu` given
+`GroupSizes`, `ops/decoder_block.py`), and the sum of the gate's and the up
+projection's input gradients, which `grouped_matmul`'s grad adds in place
+where one op holds both weights (two ops would leave the sum to
+`append_backward`'s `sum` op, over all the rows). Each of the three
+counts itself on the compile event, `moe_share_bounded_ops`. The rows behind
+the used ones are never written and never read: their buffers are
+allocated, not filled, and hold whatever was in memory. Nothing does
+arithmetic on them (but for what the last chunk of a loop reaches past the
+used rows, whose results no one reads) or on a padding row that reaches a
+result, not even times a zero weight (0 x NaN is NaN): a row's `Source` and
+an assignment's `Slot` are -1 where there is nothing, and a movement
+applies `Source` with a select or drops the row by an index out of range.
 
 Where every expert is held all N*k assignments have a row and the movements
 are static gathers, token-major `[N, k, D]` (`_rows_of_slots`): the faster
@@ -97,6 +108,14 @@ _GMM_BLOCK = 2048 * 1024
 _TGMM_BLOCK = (1024, 1024)
 # Rows a step of a share's row movement carries (`_over_used_rows`).
 _MOVE_ROWS = 512
+# Rows a step of an elementwise pass over a share's used rows carries
+# (`map_used_rows`): no gather, so a chunk is plain traffic and a larger one
+# pays a `while` iteration's cost less often, but reaches further past the
+# used rows. On a v5e the three passes of a layer together read 183 / 179 /
+# 203 / 260 us at 512 / 1024 / 2048 / 4096 rows over 4224 used rows of
+# [45056, 512 and 2048], and 698 / 604 us at 512 / 1024 over 9216 of
+# [66560, 896 and 2304] (PERF.md section 6, PR 43).
+_ELEMENTWISE_ROWS = 1024
 
 
 @register_op("moe_router", propagate_seqlen=False)
@@ -186,17 +205,43 @@ def _moe_dispatch(ctx, X, TopKIndex, TokensPerExpert):
             "Slot": slot, "Source": source, "GroupSizes": sizes}
 
 
-def _over_used_rows(sizes, rows, step, init):
+def _over_used_rows(sizes, rows, step, init, at_most=_MOVE_ROWS):
     """`step(start, chunk, carry)` for every chunk of the rows that the held
     groups of a share use, in ascending order: `chunk` rows from `start` on,
     as many chunks as `sizes` (whole tiles, from row 0 on) reach into. A
     `lax.while_loop`: its trip count is the routing's, as the grouped
-    kernels' grid is. `chunk` divides the buffer's `rows`, so the last chunk
-    never leaves it; what it holds behind the used rows has `Source` -1."""
-    chunk = math.gcd(rows, _MOVE_ROWS)
+    kernels' grid is. `chunk` (`at_most` rows) divides the buffer's `rows`,
+    so the last chunk never leaves it; what it holds behind the used rows
+    has `Source` -1."""
+    chunk = math.gcd(rows, at_most)
     steps = -(-jnp.sum(sizes.astype(jnp.int32)) // chunk)
     return lax.fori_loop(
         0, steps, lambda i, carry: step(i * chunk, chunk, carry), init)
+
+
+def map_used_rows(fn, sizes, *operands, in_place=0):
+    """An elementwise pass over a share's used rows: `fn` of every chunk of
+    the operands' rows that `sizes` reach into (`_ELEMENTWISE_ROWS` a
+    step), its results (a tuple, one `[chunk, ...]` each) written into
+    buffers as long as the layout: the first `in_place` of them over the
+    used rows of the operands at their places (of the results' shape and
+    dtype, and read by nobody afterwards: XLA then takes the buffer as it
+    is), the others into allocations. The rows behind the last chunk are
+    not visited and keep what the buffer held."""
+    def step(start, chunk, outs):
+        values = fn(*(lax.dynamic_slice_in_dim(a, start, chunk)
+                      for a in outs[:in_place] + operands[in_place:]))
+        return tuple(lax.dynamic_update_slice_in_dim(out, v, start, 0)
+                     for out, v in zip(outs, values))
+
+    # elementwise over rows: on the whole operands `fn` has the results'
+    # whole shapes
+    fresh = jax.eval_shape(fn, *operands)[in_place:]
+    return _over_used_rows(
+        sizes, operands[0].shape[0], step,
+        operands[:in_place] + tuple(lax.empty(v.shape, v.dtype)
+                                    for v in fresh),
+        at_most=_ELEMENTWISE_ROWS)
 
 
 def _dispatch_share(X, TopKIndex, TokensPerExpert, tile, first, held):
@@ -344,36 +389,61 @@ def _grouped_dot(x, w, sizes, transpose_w=False):
 @register_op("grouped_matmul", propagate_seqlen=False)
 def _grouped_matmul(ctx, X, W, GroupSizes):
     """X [M, K] with rows grouped by expert, W [E, K, F], GroupSizes [E]
-    (sums to M; `moe_dispatch`'s padded groups in the expert layer): rows
-    of group e times W[e]."""
-    return {"Out": _grouped_dot(X, W, GroupSizes)}
+    (sums to M, or under a share to the rows that are used;
+    `moe_dispatch`'s padded groups in the expert layer): rows of group e
+    times W[e]. Several `W` give as many `Out`s, each the product with one
+    of them: the projections that share their rows are one op, so the rows'
+    gradient is one variable and `_grouped_matmul_grad` sums its parts."""
+    return {"Out": [_grouped_dot(X, w, GroupSizes)
+                    for w in (W if isinstance(W, list) else [W])]}
+
+
+def _grouped_dot_grads(x, w, g, sizes):
+    """(dX, dW) of `_grouped_dot(x, w, sizes)` under the cotangent g."""
+    kernel = _kernel()
+    if kernel is None:
+        _, vjp = jax.vjp(lambda a, b: lax.ragged_dot(a, b, sizes), x, w)
+        return vjp(g)
+    d_x = _grouped_dot(g, w, sizes, transpose_w=True)
+    d_w = kernel.tgmm(x.swapaxes(0, 1), g, sizes,
+                      preferred_element_type=g.dtype,
+                      tiling=(_row_tile(x.shape[0]),
+                              min(x.shape[1], _TGMM_BLOCK[0]),
+                              min(g.shape[1], _TGMM_BLOCK[1])),
+                      num_actual_groups=w.shape[0],
+                      interpret=_interpret())
+    return d_x, d_w
 
 
 @register_grad("grouped_matmul")
 def _grouped_matmul_grad(ctx, ins, out_grads):
     """dX = rows of dOut times W[e]^T; dW[e] = X_e^T dOut_e. The grad op sees
     the scope's values, so the float32 master weights are cast here, as
-    AMP_BF16_OPS casts them for the forward rule."""
-    X, W, sizes = ins["X"][0], ins["W"][0], ins["GroupSizes"][0]
-    g = out_grads["Out"][0]
-    if g is None:
+    AMP_BF16_OPS casts them for the forward rule. With several `W` dX is
+    the sum of their parts over the rows that `GroupSizes` uses, added in
+    place and in dX's dtype, as the `sum` op would add them (the op counts
+    itself, `moe_share_bounded_ops`): the rows behind them are not
+    visited."""
+    X, sizes = ins["X"][0], ins["GroupSizes"][0].astype(jnp.int32)
+    d_x, d_ws = None, []
+    for W, g in zip(ins["W"], out_grads["Out"]):
+        if g is None:
+            d_ws.append(None)
+            continue
+        part, d_w = _grouped_dot_grads(X.astype(g.dtype), W.astype(g.dtype),
+                                       g, sizes)
+        part = part.astype(X.dtype)
+        if d_x is None:
+            d_x = part
+        else:
+            d_x, = map_used_rows(lambda a, b: (a + b,), sizes, d_x, part,
+                                 in_place=1)
+        d_ws.append(d_w.astype(W.dtype))
+    if d_x is None:
         return {}
-    sizes = sizes.astype(jnp.int32)
-    x, w = X.astype(g.dtype), W.astype(g.dtype)
-    kernel = _kernel()
-    if kernel is None:
-        _, vjp = jax.vjp(lambda a, b: lax.ragged_dot(a, b, sizes), x, w)
-        d_x, d_w = vjp(g)
-    else:
-        d_x = _grouped_dot(g, w, sizes, transpose_w=True)
-        d_w = kernel.tgmm(x.swapaxes(0, 1), g, sizes,
-                          preferred_element_type=g.dtype,
-                          tiling=(_row_tile(x.shape[0]),
-                                  min(x.shape[1], _TGMM_BLOCK[0]),
-                                  min(g.shape[1], _TGMM_BLOCK[1])),
-                          num_actual_groups=W.shape[0],
-                          interpret=_interpret())
-    return {"X": d_x.astype(X.dtype), "W": d_w.astype(W.dtype)}
+    if len(ins["W"]) > 1:
+        ctx.tally("moe_share_bounded_ops")
+    return {"X": d_x, "W": d_ws}
 
 
 @register_op("moe_combine", propagate_seqlen=False)

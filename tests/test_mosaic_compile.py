@@ -6,6 +6,7 @@ result or a time. Every such compile lives in this one file: the worker
 that is given it loads the TPU's library, and keeps it."""
 
 import os
+import re
 
 import pytest
 
@@ -121,6 +122,51 @@ def test_share_movements_compile_at_the_cells_shapes(one_chip):
     assert " while(" in combine.as_text()
     # no 40960-row gather of the layout is left in either
     assert f"[{n * k},{width}]" not in text + combine.as_text()
+
+
+def test_share_elementwise_passes_compile_at_the_cells_shapes(one_chip):
+    """What stands between a share's dispatch and combine as
+    `mellum2_12b_a2_5b.s8192` runs it (66560 rows, experts 896 wide, the
+    model 2304): the silu product, its grad and the sum of two input
+    gradients are `lax.while_loop`s over the used rows whose results are
+    `AllocateBuffer`s or, in place, the operands this op reads last (the
+    two gradients over gate and up, the sum over its first operand), with
+    no temporary as large as a result and no static pass over the layout."""
+    from paddle_tpu.ops import decoder_block, moe
+    rows, expert_width, width, held = 8192 * 8 + 8 * moe.ROW_TILE, 896, 2304, 8
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    hidden, sizes = arg((rows, expert_width)), arg((held,), jnp.int32)
+    product = jax.jit(lambda gate, up, sizes: moe.map_used_rows(
+        lambda a, b: (decoder_block._silu_product(a, b),), sizes, gate, up)
+    ).lower(hidden, hidden, sizes).compile()
+    grad = jax.jit(lambda gate, up, g, sizes: moe.map_used_rows(
+        decoder_block._silu_product_grads, sizes, gate, up, g, in_place=2),
+        donate_argnums=(0, 1)).lower(
+            hidden, hidden, hidden, sizes).compile()
+    total = jax.jit(lambda a, b, sizes: moe.map_used_rows(
+        lambda a, b: (a + b,), sizes, a, b, in_place=1),
+        donate_argnums=0).lower(
+            arg((rows, width)), arg((rows, width)), sizes).compile()
+    for compiled, allocations in ((product, 1), (grad, 0), (total, 0)):
+        text = compiled.as_text()
+        assert " while(" in text
+        assert text.count('custom_call_target="AllocateBuffer"') \
+            == allocations
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+        # whatever has the layout's rows is a carried buffer or a chunk's
+        # in-place write into one: no arithmetic has that many rows
+        for kind, opcode in re.findall(
+                r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([\w\-]+)\(", text, re.M):
+            if f"[{rows}," in kind:
+                assert opcode in ("parameter", "get-tuple-element", "while",
+                                  "tuple", "fusion", "custom-call",
+                                  "dynamic-update-slice"), (opcode, kind)
+    assert total.memory_analysis().alias_size_in_bytes == rows * width * 2
+    assert grad.memory_analysis().alias_size_in_bytes \
+        == 2 * rows * expert_width * 2
 
 
 def test_flash_kernels_compile_at_latent_attentions_widths(one_chip,

@@ -635,6 +635,277 @@ def test_rows_that_no_held_group_uses_are_never_read():
     assert np.array_equal(clean[3] != 0, held)
 
 
+# -- between dispatch and combine: the elementwise passes of a share -----------------
+
+# (top_k, experts_held, expert width, model width) of the three cells that
+# hold a share, the widths a sixteenth of theirs, and as many tokens as make
+# the layout's rows a whole number of elementwise chunks
+SHARE_CELLS = {"qwen3_next": (10, 32, 32, 128, 512),
+               "mellum2": (8, 8, 56, 144, 384),
+               "kanana2": (6, 16, 48, 128, 1024)}
+
+
+def _layout(cell):
+    """(rows of the cell's layout at its small size, the elementwise chunk)."""
+    k, held, _, _, n = SHARE_CELLS[cell]
+    rows = n * k + held * moe.ROW_TILE
+    chunk = math.gcd(rows, moe._ELEMENTWISE_ROWS)
+    assert chunk >= 1024 and rows >= 2 * chunk
+    return rows, chunk
+
+
+def _sizes_using(held, used):
+    """`held` group sizes, whole tiles, uneven, that sum to `used`."""
+    sizes = np.zeros(held, np.int32)
+    for tile in range(used // moe.ROW_TILE):
+        sizes[(tile * tile) % held] += moe.ROW_TILE
+    assert sizes.sum() == used
+    return sizes
+
+
+def _run_ops(build, feed):
+    """`build(data)` -> (a result whose sum times `probe` is the loss, the
+    names to fetch); every value of `feed` a data variable, the float ones
+    with a gradient."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        data = {name: layers.data(name=name, shape=list(v.shape),
+                                  dtype=str(v.dtype), append_batch_size=False,
+                                  stop_gradient=v.dtype.kind == "i")
+                for name, v in feed.items()}
+        result, fetch = build(data)
+        loss = layers.reduce_sum(layers.elementwise_mul(result,
+                                                        data["probe"]))
+        fluid.append_backward(loss)
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    got = exe.run(main, feed=feed, fetch_list=[result.name] + fetch,
+                  scope=scope)
+    return [np.asarray(v) for v in got], main
+
+
+def _silu_product_piece(feed, bounded):
+    def build(d):
+        out = layers.swiglu(d["gate"], d["up"],
+                            group_sizes=d["sizes"] if bounded else None)
+        return out, ["gate@GRAD", "up@GRAD"]
+    return _run_ops(build, feed)[0]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("where", ["inside", "on_the_edge", "one_tile_over",
+                                   "none"])
+@pytest.mark.parametrize("cell", sorted(SHARE_CELLS))
+def test_bounded_silu_product_is_the_static_one_on_the_used_rows(
+        cell, where, dtype, monkeypatch):
+    """`swiglu` with `GroupSizes` and its registered grad against the static
+    product under the generic vjp grad op (the parent's pair): the used
+    rows' Out, dGate and dUp bit for bit, with NaN in every row behind
+    them; where the used rows end one tile inside a chunk, exactly at its
+    edge and one tile over it, and where there are none."""
+    from paddle_tpu.core import registry
+    _, held, width, _, _ = SHARE_CELLS[cell]
+    rows, chunk = _layout(cell)
+    used = {"inside": chunk - moe.ROW_TILE, "on_the_edge": chunk,
+            "one_tile_over": chunk + moe.ROW_TILE, "none": 0}[where]
+    rng = np.random.RandomState(14)
+    as_dtype = jnp.dtype(dtype)
+    feed = {name: np.asarray(rng.randn(rows, width) * 2, as_dtype)
+            for name in ("gate", "up", "probe")}
+    feed["sizes"] = _sizes_using(held, used)
+    with monkeypatch.context() as patch:
+        patch.setattr(registry.get_op_def("swiglu"), "grad_lower", None)
+        static = _silu_product_piece(feed, bounded=False)
+    registered = _silu_product_piece(feed, bounded=False)
+    poisoned = {name: v.copy() for name, v in feed.items()}
+    for name in ("gate", "up", "probe"):
+        poisoned[name][used:] = np.nan
+    bounded = _silu_product_piece(poisoned, bounded=True)
+    for name, want, whole, got in zip(("out", "d_gate", "d_up"), static,
+                                      registered, bounded):
+        assert want.dtype == got.dtype == as_dtype, name
+        # without `GroupSizes` the registered grad is the generic one
+        assert np.array_equal(want, whole), name
+        assert np.array_equal(want[:used], got[:used]), name
+        assert np.all(np.isfinite(got[:used].astype(np.float32))), name
+        assert used == 0 or np.any(got[:used]), name
+        # behind the last chunk nothing was visited: the product is an
+        # allocation (zeros on the CPU), the two gradients are written over
+        # gate and up, whose rows there stay the NaN they were
+        last = -(-used // chunk) * chunk
+        if name == "out":
+            assert not np.any(got[last:]), name
+        else:
+            assert np.all(np.isnan(got[last:].astype(np.float32))), name
+
+
+def _projections_piece(feed, joined):
+    """The gate's and the up projection's `grouped_matmul` on fed rows, as
+    one op of two weights (`joined`) or as the two ops whose input gradients
+    `append_backward` sums; the loss reads both products."""
+    from paddle_tpu.layer_helper import LayerHelper
+
+    def build(d):
+        helper = LayerHelper("projections")
+        outs = [helper.create_variable_for_type_inference(d["x"].dtype)
+                for _ in range(2)]
+        groups = [(("w_gate", "w_up"), outs)] if joined else \
+            [(("w_gate",), outs[:1]), (("w_up",), outs[1:])]
+        for ws, results in groups:
+            helper.append_op(
+                "grouped_matmul",
+                inputs={"X": [d["x"].name], "W": [d[w].name for w in ws],
+                        "GroupSizes": [d["sizes"].name]},
+                outputs={"Out": [r.name for r in results]})
+        both = layers.elementwise_add(
+            outs[0], layers.elementwise_mul(outs[1], d["probe_up"]))
+        return both, [outs[1].name, "x@GRAD", "w_gate@GRAD", "w_up@GRAD"]
+
+    got, main = _run_ops(build, feed)
+    types = [op.type for op in main.global_block().ops]
+    assert types.count("grouped_matmul") == (1 if joined else 2)
+    assert types.count("grouped_matmul_grad") == (1 if joined else 2)
+    # the two ops' input gradients meet in a `sum`; the one op's do not
+    sums = [op for op in main.global_block().ops if op.type == "sum"
+            and op.output("Out") == ["x@GRAD"]]
+    assert len(sums) == (0 if joined else 1)
+    return got
+
+
+@pytest.mark.parametrize("cell,path", [
+    *((cell, "ragged_dot") for cell in sorted(SHARE_CELLS)),
+    # the interpreted kernels once, at the smallest layout
+    ("mellum2", "pallas_interpreted")])
+def test_two_weight_grouped_matmul_sums_its_input_gradients_on_the_used_rows(
+        cell, path, monkeypatch):
+    """One `grouped_matmul` with `W: [gate, up]` against the parent's two
+    ops and the `sum` of their input gradients: both products, `X@GRAD` on
+    the used rows and both `W@GRAD`s bit for bit, with NaN in `X` and in
+    the cotangents behind the used rows."""
+    _, held, width, d_model, _ = SHARE_CELLS[cell]
+    rows, chunk = _layout(cell)
+    if path == "pallas_interpreted":
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    used = chunk + 3 * moe.ROW_TILE
+    rng = np.random.RandomState(15)
+    feed = {"x": rng.randn(rows, d_model).astype(np.float32),
+            "w_gate": rng.randn(held, d_model, width).astype(np.float32) * .3,
+            "w_up": rng.randn(held, d_model, width).astype(np.float32) * .3,
+            "probe": rng.randn(rows, width).astype(np.float32),
+            "probe_up": rng.randn(rows, width).astype(np.float32),
+            "sizes": _sizes_using(held, used)}
+    apart = _projections_piece(feed, joined=False)
+    poisoned = {name: v.copy() for name, v in feed.items()}
+    for name in ("x", "probe", "probe_up"):
+        poisoned[name][used:] = np.nan
+    joined = _projections_piece(poisoned, joined=True)
+    names = ("gate + up x probe", "up", "d_x", "d_w_gate", "d_w_up")
+    for name, want, got in zip(names, apart, joined):
+        by_row = want.shape[0] == rows
+        want, got = (want[:used], got[:used]) if by_row else (want, got)
+        assert np.all(np.isfinite(got)) and np.any(got), name
+        assert np.array_equal(want, got), name
+
+
+def _behind_the_used_rows(value, sizes):
+    used = jnp.sum(sizes.astype(jnp.int32))
+    row = jax.lax.iota(jnp.int32, value.shape[0])
+    shape = (-1,) + (1,) * (value.ndim - 1)
+    return jnp.where((row >= used).reshape(shape), jnp.nan, value)
+
+
+def test_rows_that_no_held_group_uses_are_never_read_by_the_whole_share(
+        monkeypatch):
+    """`moe_experts` under a share with NaN behind the used rows of every
+    intermediate: each allocation (`lax.empty`: `XSorted`, the silu
+    product, `dY`) filled with NaN, and every grouped
+    product and input gradient given NaN behind the rows its groups use
+    (where the chip's kernels leave what the buffer held). Out and all five
+    gradients are finite and bit for bit the clean run's."""
+    rng = np.random.RandomState(16)
+    n = 96
+    index = np.stack([rng.choice(16, K, replace=False) for _ in range(n)]) \
+        .astype(np.int32)
+    x = rng.randn(n, D).astype(np.float32)
+    weight = rng.uniform(0.05, 0.4, (n, K)).astype(np.float32)
+    weights = _held_weights(rng)
+    (clean,), clean_grads, _ = _share_on_given_routing(index, weight, x,
+                                                       weights, 8)
+    filled = []
+
+    def nan_filled(shape, dtype):
+        filled.append(tuple(shape))
+        return jnp.full(shape, jnp.nan, dtype)
+
+    dot, dot_grads = moe._grouped_dot, moe._grouped_dot_grads
+
+    def poisoned_dot(x, w, sizes, **kw):
+        return _behind_the_used_rows(dot(x, w, sizes, **kw), sizes)
+
+    def poisoned_dot_grads(x, w, g, sizes):
+        d_x, d_w = dot_grads(x, w, g, sizes)
+        return _behind_the_used_rows(d_x, sizes), d_w
+
+    monkeypatch.setattr(moe.lax, "empty", nan_filled)
+    monkeypatch.setattr(moe, "_grouped_dot", poisoned_dot)
+    monkeypatch.setattr(moe, "_grouped_dot_grads", poisoned_dot_grads)
+    (dirty,), dirty_grads, _ = _share_on_given_routing(index, weight, x,
+                                                       weights, 8)
+    rows = n * K + HELD * moe.ROW_TILE
+    # the layout and dY by the movements, and the silu product (its two
+    # gradients are written over gate and up)
+    assert sorted(filled) == sorted([(rows, D)] * 2 + [(rows, F)])
+    assert np.all(np.isfinite(dirty)) and np.array_equal(clean, dirty)
+    assert np.any(clean)
+    assert sorted(clean_grads) == ["e.down.w", "e.gate.w", "e.up.w",
+                                   "weight", "x"]
+    for name, g in clean_grads.items():
+        assert np.all(np.isfinite(dirty_grads[name])), name
+        assert np.array_equal(g, dirty_grads[name]), name
+        assert np.any(g), name
+
+
+@pytest.mark.parametrize("held,bounded", [(HELD, 3), (None, None)],
+                         ids=["a_share", "every_expert_held"])
+def test_a_share_counts_its_three_bounded_ops_a_layer(held, bounded):
+    """Two expert layers in one program: under a share `swiglu`, its grad
+    and the two-weight `grouped_matmul`'s grad each count themselves on the
+    compile event, three a layer, beside the four movements; where every
+    expert is held there is no such count."""
+    rng = np.random.RandomState(17)
+    x = rng.randn(64, D).astype(np.float32)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        d = layers.data(name="x", shape=[64, D], dtype="float32",
+                        append_batch_size=False, stop_gradient=False)
+        h = d
+        for layer in range(2):
+            routing = layers.moe_router(h, N_EXPERT, K)
+            h = layers.moe_experts(
+                h, routing, N_EXPERT, F, name=f"e{layer}",
+                first_expert=None if held is None else 4, experts_held=held)
+        fluid.append_backward(layers.reduce_sum(h))
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    for _ in range(2):          # a second run of the step counts nothing twice
+        exe.run(main, feed={"x": x}, fetch_list=["x@GRAD"], scope=scope)
+    detail = observe.observatory().latest(main._uid).detail
+    types = [op.type for op in main.global_block().ops]
+    if held is None:
+        assert "moe_share_bounded_ops" not in detail
+        assert "moe_share_bounded_moves" not in detail
+        assert types.count("grouped_matmul") == 3 * 2
+    else:
+        assert detail["moe_share_bounded_ops"] == bounded * 2
+        assert detail["moe_share_bounded_moves"] == 4 * 2
+        assert types.count("grouped_matmul") == 2 * 2
+    with_sizes = [op for op in main.global_block().ops
+                  if op.type == "swiglu" and op.inputs.get("GroupSizes")]
+    assert len(with_sizes) == (0 if held is None else 2)
+
+
 # -- the whole tiny model ----------------------------------------------------------
 
 def _program(optimizer=None, **sizes):
