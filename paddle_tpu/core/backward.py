@@ -246,9 +246,7 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     experts whose weights live here: fewer under a share),
     `moe_router_score` where the router's scores are not a softmax, and
     `moe_router_bias_updates`, the routers whose selection bias a later op
-    of the step writes again, with `moe_router_bias_vars`, for each of them
-    the bias and the persistable variable that keeps the step's counts
-    (what the step log reads). Empty for a program with neither.
+    of the step writes again. Empty for a program with neither.
     (`moe_row_buffer_rows`, the rows of the expert layer's layout, follows
     the batch: `moe_dispatch`'s rule notes it on the same event under the
     trace, `LoweringContext.note`.)"""
@@ -256,10 +254,8 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     kinds = {"linear_attention": 0, "full_attention": 0,
              "latent_attention": 0, "window_attention": 0}
     out: Dict[str, object] = {}
-    # `assign` ops both ways: result -> what it copied, and the reverse
-    copies: Dict[str, str] = {}
-    kept: Dict[str, str] = {}
-    biases = []                     # (a router's bias, its counts)
+    copies: Dict[str, str] = {}     # an `assign` op's result -> what it copied
+    biases = []                     # the routers' selection biases
     gated, routed = [], set()       # name scopes of `swiglu`s, of routers
     for op in block.ops:
         if op.attrs.get("__role__") is not None:
@@ -287,15 +283,13 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
             gated.append(op.attrs.get(ir.NAME_SCOPE_ATTR))
         elif op.type == "assign":
             copies[op.output("Out")[0]] = op.input("X")[0]
-            kept[op.input("X")[0]] = op.output("Out")[0]
         elif op.type == "moe_router":
             out["moe_experts_routed"] = block.var(op.input("W")[0]).shape[-1]
             routed.add(op.attrs.get(ir.NAME_SCOPE_ATTR))
             if op.attrs.get("score_func"):
                 out["moe_router_score"] = op.attrs["score_func"]
-            for name in op.inputs.get("Bias", []):
-                biases.append((copies.get(name, name),
-                               op.output("TokensPerExpert")[0]))
+            biases += [copies.get(name, name)
+                       for name in op.inputs.get("Bias", [])]
         elif op.type == "moe_dispatch":
             out["moe_experts_held"] = op.attrs.get(
                 "experts_held", out.get("moe_experts_routed"))
@@ -306,11 +300,9 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     dense = sum(1 for scope in gated if scope not in routed)
     if routed and dense:
         out["dense_ffn_layers"] = dense
-    updated = [[bias, kept.get(counts)] for bias, counts in biases
-               if bias in copies]
+    updated = sum(1 for bias in biases if bias in copies)
     if updated:
-        out["moe_router_bias_updates"] = len(updated)
-        out["moe_router_bias_vars"] = updated
+        out["moe_router_bias_updates"] = updated
     return out
 
 

@@ -18,10 +18,12 @@ dispatch.
 from __future__ import annotations
 
 import contextlib
+import functools
 import itertools
 import logging
 import weakref
-from typing import Any, Dict, List, Optional, Sequence, Union
+from typing import (Any, Dict, List, NamedTuple, Optional, Sequence, Tuple,
+                    Union)
 
 import jax
 import jax.numpy as jnp
@@ -239,16 +241,13 @@ class _StateCache:
 def resolve_compiler_options(platform: str, program=None):
     """Per-executable XLA options from the `xla_compiler_options` flag.
 
-    "auto" applies the measured-good TPU set from the round-5 compiler
-    flag sweep (docs/PERF.md): a 32 MiB scoped-VMEM budget lets the
-    fusion merger form larger fusions (fewer HBM round-trips between
-    them) — worth ~9% end-to-end on transformer-base. The same budget
-    measured ~7% SLOWER on ResNet-50 (conv fusions are already at the
-    HBM roofline; the bigger budget regroups them badly), so "auto"
-    applies only to conv-free programs — the boundary the interleaved
-    A/Bs actually support. An explicit k=v list applies unconditionally.
-    Non-TPU backends get None (the names are TPU-only and other backends
-    reject unknown options)."""
+    "auto" applies a 32 MiB scoped-VMEM budget (room for the fusion merger
+    to form larger fusions: fewer HBM round-trips between them) to
+    conv-free programs on the TPU and nothing to a program with a
+    convolution. The set came from a sweep on an installation this repo
+    no longer runs on and is unmeasured on this chip (ROADMAP S6). An
+    explicit k=v list applies unconditionally. Non-TPU backends get None
+    (the names are TPU-only and other backends reject unknown options)."""
     val = _flags.get_flag("xla_compiler_options")
     if val == "auto":
         if platform != "tpu":
@@ -328,11 +327,15 @@ def _needs_device_ctx(device, entry: "_CompiledProgram") -> bool:
     `jax.default_device(device)`. Entering that context per step costs
     ~hundreds of µs (it defeats pjit's C++ fast path) and it is part of
     jax's trace-cache key, so it is entered only where it decides
-    something: the ctx can be skipped when the device IS the process
-    default and the step reads scope state. jit outputs are UNCOMMITTED, so
-    a stateful step with numpy feeds would otherwise migrate to jax's
-    global default backend (e.g. CPUPlace selected in a TPU-default
-    process): place selection must hold even without the per-step ctx."""
+    something: never for a handle without a single device (a mesh's: the
+    arguments' shardings place the step), and the ctx can be skipped when
+    the device IS the process default and the step reads scope state. jit
+    outputs are UNCOMMITTED, so a stateful step with numpy feeds would
+    otherwise migrate to jax's global default backend (e.g. CPUPlace
+    selected in a TPU-default process): place selection must hold even
+    without the per-step ctx."""
+    if device is None:
+        return False
     try:
         default_dev = (jax.config.jax_default_device
                        or jax.local_devices()[0])
@@ -345,7 +348,7 @@ def _step_device_ctx(device, entry: "_CompiledProgram"):
     """The context a run() calls `entry`'s step under: a lowering made
     under the same one finds what jax cached for the running step (its
     trace, its lowering and, through them, its executable)."""
-    if device is not None and _needs_device_ctx(device, entry):
+    if _needs_device_ctx(device, entry):
         return jax.default_device(device)
     return contextlib.nullcontext()
 
@@ -368,17 +371,15 @@ def lower_step(entry: "_CompiledProgram", feeds: Dict[str, Any], scope: Scope):
         jax.ShapeDtypeStruct((), np.uint32))
 
 
-def offer_step_text(program_uid: int, entry: "_CompiledProgram",
-                    feed_arrays: Dict[str, Any], scope: Scope, device=None):
-    """Put a lazy `compiled_text()` on the compile event just recorded for
-    `entry`: the feeds' abstract signature is noted here, once, the state's
-    is read from the scope at the ask (by then the committed outputs of a
-    step), and nothing is lowered until someone asks. The closure holds the
-    entry, the avals and a weak reference to the scope; no arrays."""
+def offer_step_text(entry: "_CompiledProgram", feed_arrays: Dict[str, Any],
+                    scope: Scope, device=None):
+    """Put a lazy `compiled_text()` on `entry.event`, the compile event it
+    was built with: the feeds' abstract signature is noted here, once, the
+    state's is read from the scope at the ask (by then the committed
+    outputs of a step), and nothing is lowered until someone asks. The
+    closure holds the entry, the avals and a weak reference to the scope;
+    no arrays."""
     entry.feed_avals = {n: _abstract(v) for n, v in feed_arrays.items()}
-    event = _steplog.observatory().latest(program_uid)
-    if event is None:
-        return
     scope_ref = weakref.ref(scope)
 
     def text():
@@ -389,26 +390,85 @@ def offer_step_text(program_uid: int, entry: "_CompiledProgram",
             return lower_step(entry, entry.feed_avals,
                               scope).compile().as_text()
 
-    event.offer_text(text)
+    entry.event.offer_text(text)
+
+
+class StepKey(NamedTuple):
+    """What a compiled step bakes in: the key of the compile cache, and
+    what the recompilation observatory compares to name a compile's cause
+    (`steplog.CAUSE_OF_FIELD` has one for every field after the first, and
+    a test fails for a field without). Made by `step_key` only; read by
+    name everywhere."""
+    program_uid: int
+    program_version: int
+    feeds: Optional[Tuple[str, ...]]     # sorted feed names
+    fetches: Tuple[str, ...]
+    scope_uid: int
+    amp: bool
+    check_nan_inf: Optional[bool]
+    copts: Optional[Tuple[Tuple[str, str], ...]]   # XLA compiler options
+    seed: Optional[int]       # program.random_seed: baked into the trace
+    mesh: Any                 # None on one chip
+
+
+def step_key(program, feeds, fetches, scope, amp, check_nan_inf, copts,
+             mesh) -> StepKey:
+    """The record of one step. `Executor`'s memo of handles keys on it
+    too, with None where a handle binds later (the feed names at its
+    first run) or resolves from the flags (`copts`; `check_nan_inf` as
+    the executor holds it, None = the flag): the flag registry's version
+    is kept on the handle beside it."""
+    return StepKey(program._uid, program._version,
+                   None if feeds is None else tuple(sorted(feeds)),
+                   tuple(fetches), scope._uid, amp, check_nan_inf,
+                   tuple(sorted(copts.items())) if copts else None,
+                   program.random_seed, mesh)
+
+
+def _fetch_names(fetch_list) -> Tuple[str, ...]:
+    return tuple(f.name if isinstance(f, ir.Variable) else str(f)
+                 for f in (fetch_list or ()))
+
+
+def _fetch_numpy(f):
+    """A fetch on the host. A global array spanning other processes'
+    devices (a multi-host mesh) cannot be np.asarray'd directly — read
+    the local copy when replicated, allgather otherwise (every process
+    calls fetch symmetrically, so the collective is safe)."""
+    if isinstance(f, jax.Array) and not f.is_fully_addressable:
+        if f.sharding.is_fully_replicated:
+            return np.asarray(f.addressable_shards[0].data)
+        from jax.experimental import multihost_utils
+        return np.asarray(multihost_utils.process_allgather(f, tiled=True))
+    return np.asarray(f)
 
 
 class _CompiledProgram:
-    """One lowered+jitted step for a (program version, feed/fetch set)."""
+    """One lowered+jitted step: what `key` (a `StepKey`) says, against
+    `scope`. `event` is the step's compile event: the rules write on its
+    `detail` what they know only under the trace (`LoweringContext.note`),
+    whenever the step is traced, and a run() names it to the observatory,
+    so a compile jax reports inside the jitted call lands there too."""
 
-    def __init__(self, program: ir.Program, feed_names, fetch_names, scope: Scope,
-                 donate: bool, amp: bool = False, check_nan_inf: bool = False,
-                 mesh=None, compiler_options=None, rng_stream: int = 0):
+    def __init__(self, program: ir.Program, key: StepKey, scope: Scope,
+                 event=None, rng_stream: int = 0):
         self.program = program
-        self.feed_names = list(feed_names)
-        self.fetch_names = list(fetch_names)
-        self.check_nan_inf = check_nan_inf
+        self.key = key
+        self.feed_names = list(key.feeds)
+        self.fetch_names = list(key.fetches)
+        self.check_nan_inf = key.check_nan_inf
         self._nan_meta = []
+        self.event = event
         # abstract signature of the feeds the entry was bound with
         # (`offer_step_text`): what `lower_step` needs instead of a feed
         self.feed_avals: Optional[Dict[str, Any]] = None
+        # feed signatures the step has run with (`steplog.track_shapes`)
+        self.shape_sigs = set()
         block = program.global_block()
-        lowerer = BlockLowerer(program, amp=amp, check_nan_inf=check_nan_inf,
-                               mesh=mesh)
+        lowerer = BlockLowerer(program, amp=key.amp,
+                               check_nan_inf=key.check_nan_inf,
+                               mesh=key.mesh,
+                               detail=event.detail if event else None)
 
         # Statically determine which scope vars the block reads/writes.
         written: List[str] = []
@@ -445,13 +505,10 @@ class _CompiledProgram:
             raise RuntimeError(
                 f"variables {missing} are read by the program but not initialized "
                 f"in the scope — run the startup program first")
-        self.state_read = read
-        self.state_written = written
         self.mut_names = [n for n in read if n in set(written)]
         self.const_names = [n for n in read if n not in set(written)]
-        self.new_names = [n for n in written if n not in set(read)]
 
-        seed = program.random_seed if program.random_seed is not None else 0
+        seed = key.seed if key.seed is not None else 0
         # unseeded programs additionally fold in their executor-local
         # ordinal (`rng_stream`): with the per-program run counters, two
         # distinct unseeded programs run through ONE executor would
@@ -462,7 +519,7 @@ class _CompiledProgram:
         # of how many programs OTHER code built first. Explicitly seeded
         # programs keep the pure-counter derivation — that is the
         # cross-executor reproducibility contract.
-        uid_mix = None if program.random_seed is not None or not rng_stream \
+        uid_mix = None if key.seed is not None or not rng_stream \
             else np.uint32(rng_stream)
 
         def step(feeds, mut_state, const_state, counter):
@@ -487,18 +544,14 @@ class _CompiledProgram:
                      if lowerer.check_nan_inf else [])
             return fetches, new_state, flags
 
-        donate_args = (1,) if donate and donation_safe() else ()
-        self._step = jax.jit(step, donate_argnums=donate_args,
-                             compiler_options=compiler_options or None)
+        self._step = jax.jit(step,
+                             donate_argnums=(1,) if donation_safe() else (),
+                             compiler_options=dict(key.copts or ()) or None)
 
     def gather_state(self, scope: Scope):
         mut = {n: scope.find_var(n) for n in self.mut_names}
         const = {n: scope.find_var(n) for n in self.const_names}
         return mut, const
-
-    def run(self, scope: Scope, feeds: Dict[str, Any], counter):
-        mut, const = self.gather_state(scope)
-        return self.run_with_state(scope, feeds, mut, const, counter)[0]
 
     def run_with_state(self, scope: Scope, feeds, mut, const, counter,
                        spans=None):
@@ -542,32 +595,23 @@ _MAX_TRACKED_PROGRAMS = 4096
 _MAX_PREPARED_HANDLES = 64
 
 
-def _evict_stale_versions(cache: Dict[tuple, Any], uid: int, version: int):
-    """Drop cache entries for older versions of a (mutated) program before
-    inserting the current version's — keyed caches would otherwise grow one
-    entry per mutation in long-lived processes (advisor r5). Keys must lead
-    with (program uid, program version)."""
-    stale = [k for k in cache if k[0] == uid and k[1] != version]
+def _evict_stale_versions(cache: Dict[StepKey, Any], program):
+    """Drop a cache's entries for older versions of a (mutated) program
+    before the current version's goes in — a cache keyed by `StepKey`
+    would otherwise grow one entry per mutation in a long-lived process
+    (advisor r5)."""
+    stale = [k for k in cache if k.program_uid == program._uid
+             and k.program_version != program._version]
     for k in stale:
         del cache[k]
 
 
-def _evict_superseded(cache: Dict[tuple, Any], key: tuple, prefix: int = 4):
-    """Drop memo entries that agree with `key` on its first `prefix`
-    fields but differ beyond them (a flag flip re-keys the memo for the
-    same program/feed/fetch/scope — the superseded entry would otherwise
-    leak one handle per flip)."""
-    stale = [k for k in cache if k[:prefix] == key[:prefix] and k != key]
-    for k in stale:
-        del cache[k]
-
-
-# (program uid, version, feed/fetch sig, mode) keys already validated:
-# Executor.run rebuilds PreparedProgram handles on scope churn / flag
-# flips / memo eviction, and re-sweeping an unchanged program each time
-# would defeat PR 1's cheap-rebuild contract. Errors are never cached
-# (they raise); a mutation bumps the version and re-validates.
-_validated: Dict[tuple, bool] = {}
+# program uid -> (program version, the (feed names, fetch names, mode) already
+# validated at it): Executor.run rebuilds PreparedProgram handles on scope
+# churn / flag flips / memo eviction, and re-sweeping an unchanged program
+# each time would defeat PR 1's cheap-rebuild contract. Errors are never
+# cached (they raise); a mutation bumps the version and replaces the entry.
+_validated: Dict[int, tuple] = {}
 
 
 def _validate_program(program, mode, feed_names, fetch_names):
@@ -582,9 +626,9 @@ def _validate_program(program, mode, feed_names, fetch_names):
     if mode not in ("error", "warn"):
         raise ValueError(f"validate must be 'error', 'warn' or 'off', "
                          f"got {mode!r}")
-    key = (program._uid, program._version,
-           tuple(feed_names or ()), tuple(fetch_names or ()), mode)
-    if _validated.get(key):
+    sig = (tuple(feed_names or ()), tuple(fetch_names or ()), mode)
+    hit = _validated.get(program._uid)
+    if hit is not None and hit[0] == program._version and sig in hit[1]:
         return
     from .. import analysis
     # listen_and_serv programs are host services, not XLA computations
@@ -598,10 +642,11 @@ def _validate_program(program, mode, feed_names, fetch_names):
         if diags:
             logger.warning("program validation findings:\n%s",
                            analysis.format_diagnostics(diags))
-    _evict_stale_versions(_validated, program._uid, program._version)
-    if len(_validated) >= _MAX_TRACKED_PROGRAMS:
-        _validated.pop(next(iter(_validated)))
-    _validated[key] = True
+    if hit is None or hit[0] != program._version:
+        if hit is None and len(_validated) >= _MAX_TRACKED_PROGRAMS:
+            _validated.pop(next(iter(_validated)))
+        hit = _validated[program._uid] = (program._version, set())
+    hit[1].add(sig)
 
 
 class PreparedProgram:
@@ -623,8 +668,7 @@ class PreparedProgram:
                  fetch_list, scope: Scope, feed_names=None, validate=None):
         self._exe = executor
         self.program = program
-        self.fetch_names = [f.name if isinstance(f, ir.Variable) else str(f)
-                            for f in (fetch_list or [])]
+        self.fetch_names = list(_fetch_names(fetch_list))
         self.feed_names = list(feed_names) if feed_names else None
         self.scope = scope
         self._block = program.global_block()
@@ -633,21 +677,35 @@ class PreparedProgram:
         # provenance instead of a tracer error inside XLA at first run
         _validate_program(program, validate, self.feed_names,
                           self.fetch_names)
-        self._device = executor.place.jax_device()
+        # what the executor's owner runs on: one device, or a mesh (then no
+        # single device: the arguments' shardings place the step) with its
+        # own placement of the feeds, `feed dict -> arrays`
+        self._mesh = executor._mesh
+        self._device = executor.place.jax_device() \
+            if self._mesh is None else None
+        self._convert = executor._place_feeds or functools.partial(
+            _convert_feed_dict, self._block)
         self._program_version = program._version
         # flag-derived settings are baked at bind time; Executor.run's memo
-        # keys on the flag-registry version, so a set_flag() flip yields a
-        # fresh handle on the next run() (direct handle holders keep the
-        # settings they prepared with — re-prepare to pick up flag flips)
+        # compares the flag-registry version the handle was made at, so a
+        # set_flag() flip yields a fresh handle on the next run() (direct
+        # handle holders keep the settings they prepared with — re-prepare
+        # to pick up flag flips)
+        self.flags_version = _flags.version()
         self._check_nan_inf = executor.check_nan_inf
-        self._copts = resolve_compiler_options(self._device.platform, program)
+        self._copts = resolve_compiler_options(
+            (self._device or self._mesh.devices.flat[0]).platform, program)
         ls = [op for op in self._block.ops if op.type == "listen_and_serv"]
         self._serve_attrs = ls[0].attrs if ls else None
         # telemetry attribution: the serving layer (serve/) re-tags its
         # handles "serving" so step stats and compile events separate
         # request traffic from training, and shape misses attribute as
         # `padding_bucket` (mis-sized bucket ladder) not `feed_shape`
-        self.telemetry_source = "executor"
+        self.telemetry_source = executor._source
+        # the executor-wide compile cache the entries are looked up and
+        # kept in; None for a handle that keeps them nowhere
+        # (`Executor._run_uncached`)
+        self._cache = executor._cache
         self._entries: Dict[tuple, _CompiledProgram] = {}
         self._entry: Optional[_CompiledProgram] = None
         self._entry_keys = frozenset()
@@ -718,7 +776,7 @@ class PreparedProgram:
                         val = val.astype(dt)
                     feed_arrays[name] = val
             else:
-                feed_arrays = _convert_feed_dict(self._block, feed)
+                feed_arrays = self._convert(feed)
             entry = self._entry
             if entry is None or feed_arrays.keys() != self._entry_keys:
                 # binding (validation, feed plan, cache lookup) is its own
@@ -731,6 +789,7 @@ class PreparedProgram:
                 # bound entry means jax.jit retraces + XLA recompiles
                 _steplog.track_shapes(entry, program._uid, feed_arrays,
                                       source=self.telemetry_source)
+            spans.event = entry.event
             spans.phase(_steplog.STATE_GATHER)
             counter = self._exe._count_run(program._uid)
             mut, const = self._state.get(entry, self.scope)
@@ -746,21 +805,21 @@ class PreparedProgram:
                 fetches, new_state = entry.run_with_state(
                     self.scope, feed_arrays, mut, const, counter, spans)
             self._state.commit(entry, self.scope, new_state)
-            if spans.observing:
-                spans.facts = _steplog.router_bias_facts(program._uid,
-                                                         new_state)
             if return_numpy:
                 # the host transfer np.asarray forces; no span with
                 # return_numpy=False — the async-dispatch overlap the fast
                 # path is built on
                 spans.phase(_steplog.FETCH)
-                fetches = [np.asarray(f) for f in fetches]
+                fetches = [_fetch_numpy(f) for f in fetches]
         return fetches
 
     def _build_feed_plan(self, feed):
         """Per-name target dtype for the bound feed set, resolved once.
         LoD feeds ((data, lengths) tuples) keep the generic conversion —
-        they expand into @SEQLEN companions the plan doesn't model."""
+        they expand into @SEQLEN companions the plan doesn't model — and
+        so does an owner's own placement of the feeds (a mesh's)."""
+        if self._exe._place_feeds is not None:
+            return None
         plan = {}
         for name, val in feed.items():
             var = self._block.vars.get(name)
@@ -776,54 +835,56 @@ class PreparedProgram:
     def _bind(self, feed, feed_arrays) -> _CompiledProgram:
         """Resolve the compiled entry for this feed signature, consulting
         the executor-wide compile cache so re-preparing (e.g. after an
-        unrelated flag flip) never recompiles an unchanged step."""
+        unrelated flag flip) never recompiles an unchanged step. A miss
+        there is a new XLA executable: `_build_entry`."""
         sig = tuple(sorted(feed_arrays))
         entry = self._entries.get(sig)
         if entry is None:
-            exe, program = self._exe, self.program
-            copts = self._copts
-            cache_key = (program._uid, program._version, sig,
-                         tuple(self.fetch_names), self.scope._uid, exe.amp,
-                         self._check_nan_inf,
-                         tuple(sorted(copts.items())) if copts else None,
-                         program.random_seed)  # seed is baked into the trace
-            entry = exe._cache.get(cache_key)
+            key = step_key(self.program, sig, self.fetch_names, self.scope,
+                           self._exe.amp, self._check_nan_inf, self._copts,
+                           self._mesh)
+            entry = self._cache.get(key) if self._cache is not None else None
             if entry is None:
-                # recompilation observatory: a compile-cache miss means a
-                # new XLA executable — record it with its attributed cause
-                # (first_call / program_version / copts_change / ...)
-                _steplog.observatory().note_entry_build(
-                    program._uid, program._version, sig,
-                    tuple(self.fetch_names),
-                    tuple(sorted(copts.items())) if copts else None,
-                    source=self.telemetry_source, scope_uid=self.scope._uid,
-                    detail=program_detail(program))
-                if _flags.get_flag("observe"):
-                    # fluid-pulse memory observatory: a compile costs
-                    # seconds, the concrete-shape walk costs milliseconds
-                    # — estimate this program's peak HBM at the shapes it
-                    # is about to compile for (never raises)
-                    from ..observe import memory as _obs_memory
-                    _obs_memory.note_program(
-                        program, feed_arrays, source=self.telemetry_source)
-                stream = exe._stream_for(program._uid)
-                with jax.default_device(self._device):
-                    entry = _CompiledProgram(
-                        program, sig, self.fetch_names, self.scope,
-                        donate=True, amp=exe.amp,
-                        check_nan_inf=self._check_nan_inf,
-                        compiler_options=copts, rng_stream=stream)
-                offer_step_text(program._uid, entry, feed_arrays, self.scope,
-                                self._device)
-                _evict_stale_versions(exe._cache, program._uid,
-                                      program._version)
-                exe._cache[cache_key] = entry
+                entry = self._build_entry(key, feed_arrays)
             self._entries[sig] = entry
         self._entry = entry
         self._entry_keys = frozenset(sig)
         self._use_device_ctx = _needs_device_ctx(self._device, entry)
         self._feed_plan = self._build_feed_plan(feed)
         self._plan_keys = frozenset(feed)
+        return entry
+
+    def _build_entry(self, key: StepKey, feed_arrays) -> _CompiledProgram:
+        """The one way a step is built: record the compile with its cause
+        and the dict the rules' facts will land on, build the
+        `_CompiledProgram`, offer its text to the event, and put it in the
+        compile cache. A handle without one (use_program_cache=False) is
+        recorded as its own cause, outside the observatory's attribution
+        state for cached runs, and its entry is kept nowhere."""
+        program, source, cache = self.program, self.telemetry_source, \
+            self._cache
+        detail = {"version": key.program_version, "feeds": list(key.feeds),
+                  "fetches": list(key.fetches), **program_detail(program)}
+        if cache is None:
+            event = _steplog.observatory().record(key.program_uid, "uncached",
+                                                  source, detail)
+        else:
+            event = _steplog.observatory().note_entry_build(key, source,
+                                                            detail)
+        if _flags.get_flag("observe"):
+            # fluid-pulse memory observatory: a compile costs seconds, the
+            # concrete-shape walk costs milliseconds — estimate this
+            # program's peak HBM at the shapes it is about to compile for
+            # (never raises)
+            from ..observe import memory as _obs_memory
+            _obs_memory.note_program(program, feed_arrays, source=source)
+        entry = _CompiledProgram(
+            program, key, self.scope, event,
+            rng_stream=self._exe._stream_for(key.program_uid))
+        offer_step_text(entry, feed_arrays, self.scope, self._device)
+        if cache is not None:
+            _evict_stale_versions(cache, program)
+            cache[key] = entry
         return entry
 
 
@@ -848,8 +909,16 @@ class Executor:
         # set_flag("check_nan_inf", True) takes effect on the next run
         # (a new cache entry compiles with the checks baked in).
         self._check_nan_inf = check_nan_inf
-        self._cache: Dict[tuple, _CompiledProgram] = {}
-        self._prepared: Dict[tuple, PreparedProgram] = {}
+        # what a handle is given beside the program. One device (`place`),
+        # the feeds converted as they come, and this source on spans and
+        # compile events, unless an owner that runs on a mesh
+        # (ParallelExecutor) sets, before the first handle: its mesh, its
+        # placement of a feed dict on it, and its source.
+        self._mesh = None
+        self._place_feeds = None
+        self._source = "executor"
+        self._cache: Dict[StepKey, _CompiledProgram] = {}
+        self._prepared: Dict[StepKey, PreparedProgram] = {}
         self._run_counts: Dict[int, int] = {}  # program uid -> runs so far
         self._prog_order: Dict[int, int] = {}  # program uid -> ordinal
         self._next_stream = 0  # monotone ordinal source (survives eviction)
@@ -926,25 +995,25 @@ class Executor:
         if not use_program_cache:
             return self._run_uncached(program, feed, fetch_list, scope,
                                       return_numpy)
-        # Thin wrapper over a memoized PreparedProgram: existing callers
-        # get the prepared fast path for free. The memo key is everything
-        # a handle bakes in — program identity+version (covers random_seed
-        # mutation), fetch set, scope, executor settings, and the flag
-        # registry version (one int compare standing in for the per-step
-        # flag reads the old path did).
-        fetch_names = tuple(f.name if isinstance(f, ir.Variable) else str(f)
-                            for f in (fetch_list or ()))
-        key = (program._uid, program._version, fetch_names, scope._uid,
-               self.amp, self._check_nan_inf, _flags.version())
+        return self._handle_for(program, fetch_list, scope).run(
+            feed, return_numpy=return_numpy)
+
+    def _handle_for(self, program, fetch_list, scope) -> PreparedProgram:
+        """run() is a thin wrapper over a memoized PreparedProgram:
+        existing callers get the prepared fast path for free. The memo key
+        is everything a handle bakes in — program identity+version and
+        seed, fetch set, scope, executor settings (`step_key`, less what
+        binds later) — and the handle keeps the flag registry version it
+        was made at (one int compare standing in for the per-step flag
+        reads the old path did)."""
+        key = step_key(program, None, _fetch_names(fetch_list), scope,
+                       self.amp, self._check_nan_inf, None, self._mesh)
         prepared = self._prepared.get(key)
-        if prepared is None:
-            prepared = PreparedProgram(self, program, fetch_names, scope)
-            _evict_stale_versions(self._prepared, program._uid,
-                                  program._version)
-            # a flag flip (or check_nan_inf toggle) re-keys the memo for
-            # the SAME (program, fetch set, scope) — drop the superseded
-            # handle (the compiled entries live in self._cache and reuse)
-            _evict_superseded(self._prepared, key)
+        if prepared is None or prepared.flags_version != _flags.version():
+            prepared = PreparedProgram(self, program, key.fetches, scope)
+            # a flag flip keeps the key: the new handle takes the old one's
+            # place (the compiled entries live in self._cache and reuse)
+            _evict_stale_versions(self._prepared, program)
             # hard cap (FIFO): a handle pins its scope AND the gathered
             # state arrays, so per-call temporary scopes (exe.run(prog,
             # scope=Scope()) in a serving loop) would otherwise keep one
@@ -953,44 +1022,15 @@ class Executor:
             if len(self._prepared) >= _MAX_PREPARED_HANDLES:
                 self._prepared.pop(next(iter(self._prepared)))
             self._prepared[key] = prepared
-        return prepared.run(feed, return_numpy=return_numpy)
+        return prepared
 
     def _run_uncached(self, program, feed, fetch_list, scope, return_numpy):
         """use_program_cache=False: compile fresh, bypass both caches
-        (reference semantics; used by tests probing recompilation)."""
-        fetch_names = [f.name if isinstance(f, ir.Variable) else str(f)
-                       for f in (fetch_list or [])]
-        block = program.global_block()
-        ls = [op for op in block.ops if op.type == "listen_and_serv"]
-        if ls:
-            from ..pserver.server import ParameterServer
-            ps = ParameterServer(ls[0].attrs["endpoint"],
-                                 trainers=ls[0].attrs.get("trainers", 1))
-            ps.serve_forever()
-            return []
-        feed = feed or {}
-        if not feed and getattr(program, "_py_reader", None) is not None:
-            feed = program._py_reader.next_feed()
-        feed_arrays = _convert_feed_dict(block, feed)
-        copts = resolve_compiler_options(self.place.jax_device().platform,
-                                         program)
-        # deliberate cache bypass: recorded as its own cause, without
-        # polluting the observatory's attribution state for cached runs
-        _steplog.observatory().record(program._uid, "uncached", "executor")
-        stream = self._stream_for(program._uid)
-        with jax.default_device(self.place.jax_device()):
-            compiled = _CompiledProgram(program, sorted(feed_arrays),
-                                        fetch_names, scope, donate=True,
-                                        amp=self.amp,
-                                        check_nan_inf=self.check_nan_inf,
-                                        compiler_options=copts,
-                                        rng_stream=stream)
-        counter = self._count_run(program._uid)
-        with jax.default_device(self.place.jax_device()):
-            fetches = compiled.run(scope, feed_arrays, counter)
-        if return_numpy:
-            fetches = [np.asarray(f) for f in fetches]
-        return fetches
+        (reference semantics; used by tests probing recompilation): a
+        handle of its own, whose entry goes into no cache."""
+        handle = PreparedProgram(self, program, fetch_list, scope)
+        handle._cache = None
+        return handle.run(feed, return_numpy=return_numpy)
 
     def compiled_step(self, program: Optional[ir.Program] = None,
                       scope: Optional[Scope] = None):
@@ -1003,11 +1043,9 @@ class Executor:
         signature."""
         program = program or ir.default_main_program()
         scope = scope or global_scope()
-        # cache keys lead with (program uid, version, feed names, fetch
-        # names, scope uid): PreparedProgram._bind
         entries = [e for k, e in self._cache.items()
-                   if (k[0], k[1], k[4]) == (program._uid, program._version,
-                                             scope._uid)]
+                   if (k.program_uid, k.program_version, k.scope_uid)
+                   == (program._uid, program._version, scope._uid)]
         if not entries:
             raise RuntimeError("compiled_step requires a prior run() of the "
                                "program against this scope")
@@ -1025,9 +1063,6 @@ class Executor:
         self._prepared.clear()
 
 
-import contextlib as _contextlib
-
-
 def _switch_scope(scope: Scope) -> Scope:
     """Swap the process-global scope, returning the previous one
     (reference executor.py _switch_scope)."""
@@ -1037,7 +1072,7 @@ def _switch_scope(scope: Scope) -> Scope:
     return prev
 
 
-@_contextlib.contextmanager
+@contextlib.contextmanager
 def scope_guard(scope: Scope):
     """Run a `with` region against `scope` as the global scope (reference
     executor.py scope_guard)."""

@@ -14,7 +14,7 @@ required in the IR.
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Sequence, Set
+from typing import Any, Dict, List, Optional, Sequence, Set
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +29,8 @@ class BlockLowerer:
     """Lowers a Block's op list into a pure function over an env dict."""
 
     def __init__(self, program: ir.Program, amp: bool = False,
-                 check_nan_inf: bool = False, mesh=None):
+                 check_nan_inf: bool = False, mesh=None,
+                 detail: Optional[dict] = None):
         self.program = program
         # bf16 mixed precision for MXU ops (registry.AMP_OPS); params stay
         # fp32, accumulation is fp32 on the MXU.
@@ -50,8 +51,12 @@ class BlockLowerer:
         # ops are therefore covered at the control-flow op's boundary
         # (its outputs are checked at depth 1)
         self._block_depth = 0
-        # what each op counted on the compile event, by key
-        # (`LoweringContext.tally`)
+        # the `detail` of the compile event of the step being lowered: the
+        # executor's dict, so what a rule notes under the trace
+        # (`LoweringContext.note`) is on the event of this step and no
+        # other; a dict nobody reads where no executor gave one
+        self.detail: dict = detail if detail is not None else {}
+        # what each op counted there, by key (`LoweringContext.tally`)
         self.tallies: Dict[str, dict] = {}
 
     def run_block(self, block_idx: int, env: Dict[str, Any], key) -> Dict[str, Any]:

@@ -141,14 +141,11 @@ class LoweringContext:
 
     def note(self, **facts):
         """What a rule knows only under the trace (a static row count that
-        follows the batch), onto the detail of the program's newest compile
-        event. Nothing where a rule is called outside a program's lowering."""
-        if self.lowerer is None:
-            return
-        from ..observe import steplog
-        event = steplog.observatory().latest(self.lowerer.program._uid)
-        if event is not None and isinstance(event.detail, dict):
-            event.detail.update(facts)
+        follows the batch), onto the detail of the compile event of the
+        step being traced: the lowerer holds that dict. Nothing where a
+        rule is called outside a program's lowering."""
+        if self.lowerer is not None:
+            self.lowerer.detail.update(facts)
 
     def tally(self, key: str, amount: int = 1):
         """Count this op under `key` on the compile event: how many of the

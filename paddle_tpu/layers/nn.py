@@ -1546,7 +1546,7 @@ def moe_router(input, num_experts, k, param_attr=None, norm_topk_prob=False,
     reads a copy taken before the update, so the backward pass, which reads
     the scope's values, differentiates the choice the forward pass made.
     The step's counts stay behind in `<bias name>.load` (int32, persistable,
-    dict key `load`): what the step log reads when `observe` is on."""
+    dict key `load`): fetch it, or read it from the scope after the step."""
     from ..param_attr import ParamAttr
     from . import ops as _ops
     from . import tensor as _tensor

@@ -33,7 +33,8 @@ events are recorded regardless — they are never hot and they are what
 `tools/telemetry_dump.py --assert-no-recompiles` audits in CI.
 
 One thing is on at DEFAULT flags: the host spans of a run. Every
-`PreparedProgram.run` / `ParallelExecutor.run` opens `paddle_tpu:run` and,
+`PreparedProgram.run` (an `Executor`'s steps and a `ParallelExecutor`'s
+alike) opens `paddle_tpu:run` and,
 inside it, `paddle_tpu:feed_convert`, `:bind` (a step that binds),
 `:state_gather`, `:jit_call`, `:write_back`, `:fetch` (with
 `return_numpy=True`) as `jax.profiler.TraceAnnotation`s; `py_reader` and

@@ -3,8 +3,8 @@
 Two runtime questions dominate TPU cost and were previously invisible:
 
 1. *Where does a step's host time go?* `RunSpans` opens one
-   `paddle_tpu:run` span per `PreparedProgram.run` / `ParallelExecutor.run`
-   and one child span per host phase around the jitted call — feed
+   `paddle_tpu:run` span per `PreparedProgram.run` (both executors' one
+   run loop) and one child span per host phase around the jitted call — feed
    conversion, bind, state gather, the jitted call, state write-back,
    fetch transfer — as `jax.profiler.TraceAnnotation`s: on at default
    flags, on the profiler's clock, beside the device track of any
@@ -55,8 +55,8 @@ Two runtime questions dominate TPU cost and were previously invisible:
    it (what its keys cannot see: with `observe` off a new feed shape; on
    the TPU the SECOND call of every step, whose state the startup program
    left uncommitted and the first call committed — chip run, PR 25), the
-   durations go to the program's latest event, whose `backend_compiles`
-   then reads 2. No cause is invented for it.
+   durations go to the event of the entry that run() called, whose
+   `backend_compiles` then reads 2. No cause is invented for it.
 """
 
 from __future__ import annotations
@@ -97,52 +97,21 @@ span = jax.profiler.TraceAnnotation
 class StepStats:
     """Host-side phase wall times (seconds) of one run()."""
 
-    __slots__ = ("program_uid", "source", "ts", "phases", "total", "facts")
+    __slots__ = ("program_uid", "source", "ts", "phases", "total")
 
     def __init__(self, program_uid: int, source: str, ts: float,
-                 phases: Dict[str, float], facts: Optional[dict] = None):
+                 phases: Dict[str, float]):
         self.program_uid = program_uid
-        self.source = source          # "executor" | "parallel"
+        self.source = source          # "executor" | "parallel" | "serving"
         self.ts = ts
         self.phases = phases
         self.total = sum(phases.values())
-        # what the step left in its state that a log line is worth
-        # (`router_bias_facts`); None for a program with nothing such
-        self.facts = facts
 
     def as_dict(self) -> dict:
-        out = {"program_uid": self.program_uid, "source": self.source,
-               "ts": self.ts, "total_us": round(self.total * 1e6, 2),
-               "phases_us": {k: round(v * 1e6, 2)
-                             for k, v in self.phases.items()}}
-        if self.facts:
-            out.update(self.facts)
-        return out
-
-
-def router_bias_facts(program_uid: int, state) -> Optional[dict]:
-    """Where the program's routers carry a selection bias that the step
-    rewrites (`moe_router_bias_vars` on its compile event): the largest
-    `|b|` after the step and the largest and smallest count of assignments
-    an expert took in it, over all such layers, read from the step's new
-    `state` (name -> array). Reading them waits for the step, so it is asked
-    for only while `observe` is on."""
-    detail = getattr(observatory().latest(program_uid), "detail", None)
-    pairs = detail.get("moe_router_bias_vars") \
-        if isinstance(detail, dict) else None
-    if not pairs:
-        return None
-    import numpy as np
-    biases = [np.asarray(state[b]) for b, _ in pairs if b in state]
-    loads = [np.asarray(state[c]) for _, c in pairs if c in state]
-    facts = {}
-    if biases:
-        facts["router_bias_abs_max"] = float(max(np.abs(b).max()
-                                                 for b in biases))
-    if loads:
-        facts["router_load_max"] = int(max(c.max() for c in loads))
-        facts["router_load_min"] = int(min(c.min() for c in loads))
-    return facts or None
+        return {"program_uid": self.program_uid, "source": self.source,
+                "ts": self.ts, "total_us": round(self.total * 1e6, 2),
+                "phases_us": {k: round(v * 1e6, 2)
+                              for k, v in self.phases.items()}}
 
 
 class StepLog:
@@ -221,7 +190,7 @@ class StepLog:
 
 
 class RunSpans:
-    """The host spans of one `PreparedProgram.run` / `ParallelExecutor.run`.
+    """The host spans of one `PreparedProgram.run` (both executors' one).
 
         with RunSpans(program_uid, source, step) as spans:
             spans.phase(FEED_CONVERT); ...
@@ -232,19 +201,20 @@ class RunSpans:
     the k-th module execution on the device's track), `program` and
     `source`. `phase()` ends the open child span and starts the next, so
     the children are leaves that tile the run. At default flags that is
-    all it does, but for noting the run as this thread's current one (for
-    a compile that jax reports inside it, `_on_duration`): a
+    all it does, but for noting the run as this thread's current one and,
+    in `event`, the compile event of the entry it calls (for a compile that
+    jax reports inside it, `_on_duration`): a
     `TraceAnnotation` costs one atomic check while no profile is taken. With
     the `observe` flag on, the same boundaries fill a `StepStats`,
     recorded after the run span has closed unless the body raised."""
 
-    __slots__ = ("observing", "program_uid", "source", "which", "facts",
+    __slots__ = ("observing", "program_uid", "source", "which", "event",
                  "_run", "_child", "_phases", "_key", "_t")
 
     def __init__(self, program_uid: int, source: str, step: int):
         self.observing = _flags.get_flag("observe")
         self.program_uid, self.source = program_uid, source
-        self.which = self._child = self.facts = None
+        self.which = self._child = self.event = None
         self._run = span("paddle_tpu:run", step=step, program=program_uid,
                          source=source)
         _building.run = self
@@ -280,8 +250,7 @@ class RunSpans:
             self._tick(None)
             if exc_type is None:
                 _steplog.record(StepStats(self.program_uid, self.source,
-                                          time.time(), self._phases,
-                                          self.facts))
+                                          time.time(), self._phases))
         return False
 
 
@@ -325,8 +294,8 @@ class RecompileEvent:
 
     def compiled_text(self) -> Optional[str]:
         """Optimized HLO of the step this event built, as it runs in steady
-        state; None where no executor offered one (a shape miss, an uncached
-        run) or its scope is gone. Made at every ask and not kept (a step's
+        state; None where no executor offered one (a shape miss) or its scope
+        is gone. Made at every ask and not kept (a step's
         text runs to tens of MB; `op_map` keeps what is read from it): once
         the step has run with the signature asked for, jax answers the
         trace, the lowering and the executable from what it cached for the
@@ -388,15 +357,34 @@ class RecompileEvent:
 EXPECTED_CAUSES = ("first_call", "warmup")
 
 
+# The cause of a compile-cache miss, by the first field of the step's record
+# (`core.executor.StepKey`), in this order of priority, that holds a value
+# this program was never built with. Every field after `program_uid` has an
+# entry (tests/test_prepared_executor.py fails for one without).
+CAUSE_OF_FIELD = {
+    "program_version": "program_version",
+    "copts": "copts_change",
+    "feeds": "feed_names",
+    "fetches": "fetch_set",
+    "scope_uid": "new_scope",
+    "amp": "options_change",
+    "check_nan_inf": "options_change",
+    "seed": "options_change",
+    "mesh": "options_change",
+}
+
+
 class RecompilationObservatory:
     """Records every executor-level compile with an attributed cause.
 
-    Attribution compares the miss against what this process has already
-    compiled for the same program uid, in priority order: new version →
-    ``program_version``; new compiler options → ``copts_change``; new
-    feed-name set → ``feed_names``; new fetch list → ``fetch_set``; new
-    scope → ``new_scope``; anything else that re-keyed the compile cache
-    (amp / check_nan_inf / random_seed flips) → ``options_change``.
+    Attribution compares the miss's record, field by field in
+    `CAUSE_OF_FIELD`'s order, against what this process has already
+    compiled for the same program uid: new version → ``program_version``;
+    new compiler options → ``copts_change``; new feed-name set →
+    ``feed_names``; new fetch list → ``fetch_set``; new scope →
+    ``new_scope``; a new amp / check_nan_inf / random_seed / mesh →
+    ``options_change``, which is also the name where every value was seen
+    before (another executor built the same step, or a new combination).
     Run-time shape tracking (flag-gated, see note in the module
     docstring) reports jax-level retraces of an already-bound entry as
     ``feed_shape``."""
@@ -404,54 +392,36 @@ class RecompilationObservatory:
     def __init__(self, capacity: int = 256):
         self._events: deque = deque(maxlen=capacity)
         self._lock = threading.Lock()
-        # program uid -> {"versions", "copts", "feed_sigs", "fetch_sigs",
-        #                 "scopes"}
-        self._seen: Dict[int, dict] = {}
+        # program uid -> {field of CAUSE_OF_FIELD: the values built with}
+        self._seen: Dict[int, Dict[str, set]] = {}
 
-    def note_entry_build(self, program_uid: int, version: int,
-                         feed_sig: Tuple, fetch_sig: Tuple, copts_sig,
-                         source: str = "executor",
-                         scope_uid=None, detail=None) -> str:
+    def note_entry_build(self, key, source: str,
+                         detail: dict) -> "RecompileEvent":
         """Called on every executor compile-cache miss (a new
-        _CompiledProgram is about to be built). Returns the cause.
-        `detail`: what else the caller knows of the program (the executors
-        pass `backward.program_detail`: the sharing counters and the census
-        of mixer and expert layers), kept on the event."""
+        _CompiledProgram is about to be built for `key`, its `StepKey`).
+        Returns the event, whose cause it found. `detail` is kept as it
+        is, not copied: the builder (`core.executor.PreparedProgram._build_entry`) puts the
+        version, the feed and fetch names and `backward.program_detail`
+        (the sharing counters and the census of mixer and expert layers)
+        there and hands the same dict to the step's lowerer, so what a
+        rule notes under the trace lands on this event."""
         with self._lock:
-            s = self._seen.get(program_uid)
-            if s is None:
+            seen = self._seen.get(key.program_uid)
+            if seen is None:
                 cause = "first_call"
-                s = self._seen[program_uid] = {
-                    "versions": set(), "copts": set(),
-                    "feed_sigs": set(), "fetch_sigs": set(),
-                    "scopes": set()}
-            elif version not in s["versions"]:
-                cause = "program_version"
-            elif copts_sig not in s["copts"]:
-                cause = "copts_change"
-            elif feed_sig not in s["feed_sigs"]:
-                cause = "feed_names"
-            elif fetch_sig not in s["fetch_sigs"]:
-                cause = "fetch_set"
-            elif scope_uid is not None and scope_uid not in s["scopes"]:
-                cause = "new_scope"
+                seen = self._seen[key.program_uid] = {
+                    f: set() for f in CAUSE_OF_FIELD}
             else:
-                # every observed key dimension matched, so the re-key came
-                # from an executor-setting flip (amp / check_nan_inf /
-                # random_seed)
-                cause = "options_change"
-            s["versions"].add(version)
-            s["copts"].add(copts_sig)
-            s["feed_sigs"].add(feed_sig)
-            s["fetch_sigs"].add(fetch_sig)
-            if scope_uid is not None:
-                s["scopes"].add(scope_uid)
-            self._events.append(RecompileEvent(
-                time.time(), program_uid, cause, source,
-                {"version": version, "feeds": list(feed_sig),
-                 "fetches": list(fetch_sig), **(detail or {})}))
+                cause = next((c for f, c in CAUSE_OF_FIELD.items()
+                              if getattr(key, f) not in seen[f]),
+                             "options_change")
+            for f, values in seen.items():
+                values.add(getattr(key, f))
+            event = RecompileEvent(time.time(), key.program_uid, cause,
+                                   source, detail)
+            self._events.append(event)
         self._emit_metric(cause, source)
-        return cause
+        return event
 
     def note_shape_miss(self, program_uid: int, shape_sig, source: str,
                         cause: str = "feed_shape"):
@@ -462,20 +432,18 @@ class RecompilationObservatory:
         bucket planner should have padded the request onto a warmed rung,
         so a miss means the ladder is mis-sized, not that the jit cache
         misbehaved."""
-        with self._lock:
-            self._events.append(RecompileEvent(
-                time.time(), program_uid, cause, source,
-                {"shapes": {n: list(shp)
-                            for n, shp, _ in shape_sig}}))
-        self._emit_metric(cause, source)
+        self.record(program_uid, cause, source,
+                    {"shapes": {n: list(shp) for n, shp, _ in shape_sig}})
 
     def record(self, program_uid: int, cause: str, source: str,
-               detail=None):
+               detail=None) -> "RecompileEvent":
         """Direct record without attribution (e.g. `uncached` runs)."""
+        event = RecompileEvent(time.time(), program_uid, cause, source,
+                               detail)
         with self._lock:
-            self._events.append(RecompileEvent(
-                time.time(), program_uid, cause, source, detail))
+            self._events.append(event)
         self._emit_metric(cause, source)
+        return event
 
     def latest(self, program_uid: int) -> Optional[RecompileEvent]:
         """The program's most recent compile event, if the ring holds one."""
@@ -531,17 +499,14 @@ def _on_duration(event, seconds, **_):
     building = getattr(_building, "event", None)
     if building is None:
         # jax compiles and the executor recorded no cause. Inside the
-        # jitted call of a run() it is that program's step being built
-        # again: its latest event takes the cost for the rest of this
-        # run(). Anywhere else (a user's own jnp code) it is not the
+        # jitted call of a run() it is that run's entry being built
+        # again: the entry's own event takes the cost for the rest of
+        # this run(). Anywhere else (a user's own jnp code) it is not the
         # executor's to record.
         run = getattr(_building, "run", None)
-        if run is None or run.which is not JIT_CALL:
+        if run is None or run.which is not JIT_CALL or run.event is None:
             return
-        building = _observatory.latest(run.program_uid)
-        if building is None:
-            return
-        _building.event = building
+        building = _building.event = run.event
     building.add_stage(stage, seconds)
 
 
@@ -582,9 +547,7 @@ def track_shapes(entry, program_uid: int, feed_arrays: Dict,
     steady-state shape was warmed ahead of time), a `padding_bucket`
     miss."""
     sig = shape_sig(feed_arrays)
-    seen = getattr(entry, "_shape_sigs", None)
-    if seen is None:
-        seen = entry._shape_sigs = set()
+    seen = entry.shape_sigs
     if sig not in seen:
         if seen:
             cause = "padding_bucket" if source == "serving" else "feed_shape"
@@ -600,8 +563,4 @@ def preseed_shapes(entry, feed_arrays: Dict):
     keeps the tracker from re-flagging the warmed shapes as misses —
     including when warmup ran with the `observe` flag off and the flag is
     flipped on later."""
-    sig = shape_sig(feed_arrays)
-    seen = getattr(entry, "_shape_sigs", None)
-    if seen is None:
-        seen = entry._shape_sigs = set()
-    seen.add(sig)
+    entry.shape_sigs.add(shape_sig(feed_arrays))

@@ -7,7 +7,9 @@ python/paddle/fluid/parallel_executor.py).
 TPU-native redesign: the reference replicates the program per GPU, builds an
 SSA dependency graph, and hand-inserts NCCL AllReduce ops on gradients
 (details/all_reduce_op_handle.cc:47). Here the SAME single-program lowering
-used by Executor is compiled once under a `jax.sharding.Mesh`: feeds are
+used by Executor is compiled once under a `jax.sharding.Mesh`, by the same
+code (`core/executor.py`: an `Executor` of its own that is told the mesh and
+how feeds are placed on it): feeds are
 placed batch-sharded over the 'dp' axis, parameters replicated (kAllReduce
 analog), and XLA GSPMD inserts the gradient all-reduces over ICI. The
 `BuildStrategy.ReduceStrategy.Reduce` mode (sharded optimizer updates,
@@ -18,18 +20,15 @@ reference details/reduce_op_handle.cc) maps to sharding optimizer state over
 from __future__ import annotations
 
 import enum
-from typing import Dict, Optional, Sequence
+from typing import Optional
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..core import ir
-from ..core.backward import program_detail
-from ..core.executor import (Scope, _CompiledProgram, _StateCache,
-                             _evict_stale_versions, _evict_superseded,
-                             global_scope, lower_step, offer_step_text)
-from ..observe import steplog as _steplog
+from ..core.executor import (Executor, _convert_feed_dict, _fetch_numpy,
+                             global_scope, lower_step)
 from . import mesh as mesh_lib
 
 
@@ -87,17 +86,13 @@ class ParallelExecutor:
                                 else global_scope())
         self._mesh = mesh or mesh_lib.get_default_mesh()
         self._build_strategy = build_strategy or BuildStrategy()
-        self._exec_strategy = exec_strategy or ExecutionStrategy()
-        self._loss_name = loss_name
-        self._cache: Dict[tuple, _CompiledProgram] = {}
-        # prepared fast path (the Executor.prepare analog): memoizes the
-        # full cache-key build + flag reads per (program version, feed
-        # signature, fetch set, flag registry version), and caches the
-        # O(params) scope state gather against the scope version counter
-        self._fast: Dict[tuple, _CompiledProgram] = {}
-        self._state_cache = _StateCache()
-        self._last_key = None
-        self._run_counter = 0
+        # the one way from a Program to a running step: an Executor's
+        # handles, memo and compile cache, given what only a mesh has
+        self._exe = Executor(amp=self._build_strategy.amp)
+        self._exe._mesh = self._mesh
+        self._exe._place_feeds = self._convert_feeds
+        self._exe._source = "parallel"
+        self._last = None       # the handle of the last run()
         self._replicated = NamedSharding(self._mesh, PartitionSpec())
         # fluid-wire: rewrite BEFORE the first compile/bcast — the
         # residual vars are materialized straight into this executor's
@@ -135,7 +130,7 @@ class ParallelExecutor:
             # are): keep it if the sharding already matches, else localize
             if val.sharding == sharding:
                 return val
-            val = self._fetch_numpy(val)
+            val = _fetch_numpy(val)
         val = np.asarray(val)
         idx_map = sharding.addressable_devices_indices_map(val.shape)
         shards = [jax.device_put(val[idx], d) for d, idx in idx_map.items()]
@@ -178,142 +173,43 @@ class ParallelExecutor:
         return self._mesh.devices.size
 
     def run(self, fetch_list, feed=None, feed_dict=None, return_numpy=True):
-        from .. import flags as _flags
-        # host spans at default flags, StepStats when observing: the same
-        # helper and boundaries as PreparedProgram.run (observe/steplog.py)
-        with _steplog.RunSpans(self._program._uid, "parallel",
-                               self._run_counter) as spans:
-            spans.phase(_steplog.FEED_CONVERT)
-            feed = feed if feed is not None else feed_dict or {}
-            if isinstance(feed, (list, tuple)):
-                merged: Dict[str, np.ndarray] = {}
-                for d in feed:
-                    for k, v in d.items():
-                        merged.setdefault(k, []).append(np.asarray(v))
-                feed = {k: np.concatenate(v, axis=0)
-                        for k, v in merged.items()}
-            fetch_names = [f.name if isinstance(f, ir.Variable) else str(f)
-                           for f in fetch_list]
-            feed_arrays = self._convert_feeds(feed)
-
-            fast_key = (self._program._uid, self._program._version,
-                        frozenset(feed_arrays), tuple(fetch_names),
-                        _flags.version())
-            hit = self._fast.get(fast_key)
-            if hit is None:
-                # one-shot memo resolution / build, kept out of the
-                # steady-state feed_convert numbers
-                spans.phase(_steplog.BIND)
-                hit = self._fast[fast_key] = self._bind(
-                    fast_key, feed_arrays, fetch_names)
-            compiled, self._last_key = hit
-
-            if spans.observing:
-                _steplog.track_shapes(compiled, self._program._uid,
-                                      feed_arrays, source="parallel")
-            spans.phase(_steplog.STATE_GATHER)
-            # per-program run counter (see Executor.run): deterministic
-            # trajectories from seeded init, per-step mask variation
-            counter = np.uint32(self._run_counter)
-            self._run_counter += 1
-            mut, const = self._state_cache.get(compiled, self._scope)
-            spans.phase(_steplog.JIT_CALL)  # run_with_state: -> write_back
-            fetches, new_state = compiled.run_with_state(
-                self._scope, feed_arrays, mut, const, counter, spans)
-            self._state_cache.commit(compiled, self._scope, new_state)
-            if return_numpy:
-                spans.phase(_steplog.FETCH)
-                fetches = [self._fetch_numpy(f) for f in fetches]
-        return fetches
-
-    def _bind(self, fast_key, feed_arrays, fetch_names):
-        """(compiled step, its cache key) for a feed signature and fetch
-        set the fast memo has not seen: from the compile cache, or built."""
-        from ..core.executor import resolve_compiler_options
-        copts = resolve_compiler_options(
-            self._mesh.devices.flat[0].platform, self._program)
-        copts_sig = tuple(sorted(copts.items())) if copts else None
-        feed_sig = tuple(sorted(feed_arrays))
-        key = (self._program._uid, self._program._version, feed_sig,
-               tuple(fetch_names), copts_sig)
-        compiled = self._cache.get(key)
-        if compiled is None:
-            _steplog.observatory().note_entry_build(
-                self._program._uid, self._program._version, feed_sig,
-                tuple(fetch_names), copts_sig, source="parallel",
-                scope_uid=self._scope._uid,
-                detail=program_detail(self._program))
-            compiled = _CompiledProgram(self._program, sorted(feed_arrays),
-                                        fetch_names, self._scope,
-                                        donate=True,
-                                        amp=self._build_strategy.amp,
-                                        mesh=self._mesh,
-                                        compiler_options=copts)
-            offer_step_text(self._program._uid, compiled, feed_arrays,
-                            self._scope)
-            _evict_stale_versions(self._cache, self._program._uid,
-                                  self._program._version)
-            self._cache[key] = compiled
-        _evict_stale_versions(self._fast, self._program._uid,
-                              self._program._version)
-        # a flag flip re-keys the memo for the same (program, feed
-        # signature, fetch set) — drop the superseded entry
-        _evict_superseded(self._fast, fast_key)
-        return compiled, key
-
-    @staticmethod
-    def _fetch_numpy(f):
-        """Multi-host fetch: a global array spanning remote devices cannot
-        be np.asarray'd directly — read the local copy when replicated,
-        allgather otherwise (every process calls fetch symmetrically, so
-        the collective is safe)."""
-        if isinstance(f, jax.Array) and not f.is_fully_addressable:
-            if f.sharding.is_fully_replicated:
-                return np.asarray(f.addressable_shards[0].data)
-            from jax.experimental import multihost_utils
-            return np.asarray(multihost_utils.process_allgather(f,
-                                                                tiled=True))
-        return np.asarray(f)
+        feed = feed if feed is not None else feed_dict or {}
+        if isinstance(feed, (list, tuple)):     # one dict per device
+            merged = {}
+            for d in feed:
+                for k, v in d.items():
+                    merged.setdefault(k, []).append(np.asarray(v))
+            feed = {k: np.concatenate(v, axis=0) for k, v in merged.items()}
+        self._last = self._exe._handle_for(self._program, fetch_list,
+                                           self._scope)
+        return self._last.run(feed, return_numpy=return_numpy)
 
     def _convert_feeds(self, feed):
+        """The mesh's feed placement: the Executor's conversion (the
+        implicit cast, @SEQLEN companions of LoD feeds), then each array
+        under its sharding."""
         block = self._program.global_block()
-        feed_arrays = {}
-        for name, val in feed.items():
-            var = block.vars.get(name)
-            if isinstance(val, (tuple, list)) and len(val) == 2 and var is not None \
-                    and var.lod_level > 0:
-                data, lens = val
-                feed_arrays[name] = self._shard_feed(data, var)
-                if isinstance(lens, (tuple, list)) and len(lens) == 2 \
-                        and not np.isscalar(lens[0]):
-                    # nested LoD: (outer counts [B], inner lengths [B, S])
-                    feed_arrays[ir.seqlen_var_name(name)] = self._shard_feed(
-                        np.asarray(lens[0], np.int32), var)
-                    feed_arrays[ir.seqlen_var_name(name, 1)] = \
-                        self._shard_feed(np.asarray(lens[1], np.int32), var)
-                else:
-                    feed_arrays[ir.seqlen_var_name(name)] = self._shard_feed(
-                        np.asarray(lens, np.int32), var)
-            else:
-                feed_arrays[name] = self._shard_feed(val, var)
-        return feed_arrays
+        return {name: self._shard_feed(
+                    val, block.vars.get(name.split(ir.SEQLEN_SUFFIX)[0]))
+                for name, val in _convert_feed_dict(block, feed).items()}
 
     def _entry_for(self, feed, what):
         """(cache entry, converted feeds) of the step a run() with this
         feed's names went through."""
-        if not self._cache:
+        cache = self._exe._cache
+        if not cache:
             raise RuntimeError(f"{what} requires a prior run()")
         feeds = self._convert_feeds(feed)
         names = tuple(sorted(feeds))
-        cands = [k for k in self._cache
-                 if k[2] == names and k[1] == self._program._version]
+        cands = [k for k in cache if k.feeds == names
+                 and k.program_version == self._program._version]
         if not cands:
             raise RuntimeError(
                 f"no compiled step matches feed names {sorted(feeds)}; "
                 f"run() with this feed first")
         # prefer the step the LAST run used (disambiguates fetch lists)
-        key = self._last_key if self._last_key in cands else cands[-1]
-        return self._cache[key], feeds
+        last = self._last._entry.key
+        return cache[last if last in cands else cands[-1]], feeds
 
     def lowered_text(self, feed) -> str:
         """StableHLO text of the step this feed shape ran through — the

@@ -529,34 +529,12 @@ def test_compile_event_carries_the_census():
     assert detail["dense_ffn_layers"] == 1
     assert detail["moe_router_score"] == "sigmoid"
     assert detail["moe_router_bias_updates"] == 2
-    assert detail["moe_router_bias_vars"] == [
-        [n, n + ".load"] for n in BIASES]
     assert detail["moe_experts_routed"] == 16
     assert detail["moe_experts_held"] == 4
     assert detail["moe_row_buffer_rows"] == 2 * 128 * 3 + 4 * 128
     assert detail["moe_share_bounded_moves"] == 2 * 4
     assert "layer_kinds" not in observe.observatory().latest(
         startup._uid).detail
-
-
-def test_step_log_carries_the_bias_and_the_load_while_observing():
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.SGD(learning_rate=1e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    fluid.flags.set_flag("observe", True)
-    try:
-        (counts,) = exe.run(main, feed=_batch(),
-                            fetch_list=[fetches["tokens_per_expert"]],
-                            scope=scope)
-        last = [s for s in observe.get_steplog().recent(4)
-                if s.program_uid == main._uid][-1].as_dict()
-    finally:
-        fluid.flags.set_flag("observe", False)
-    assert last["router_bias_abs_max"] == pytest.approx(GAMMA)
-    assert last["router_load_max"] == counts.max()
-    assert last["router_load_min"] == counts.min()
 
 
 def test_every_layer_is_built_under_its_name_scopes(tiny):

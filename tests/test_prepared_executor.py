@@ -9,7 +9,10 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu.core.executor import resolve_compiler_options
+from paddle_tpu import layers, observe
+from paddle_tpu.core.executor import StepKey, resolve_compiler_options
+from paddle_tpu.observe.steplog import CAUSE_OF_FIELD
+from paddle_tpu.parallel.mesh import make_mesh
 
 
 def _build_mlp(seed=None, dropout=True):
@@ -177,8 +180,8 @@ def test_program_mutation_evicts_stale_cache_entries():
         exe.run(main, feed=f, fetch_list=[loss], scope=scope)
     assert len(exe._cache) == n_cache
     assert len(exe._prepared) == n_prepared
-    stale = [k for k in exe._cache
-             if k[0] == main._uid and k[1] != main._version]
+    stale = [k for k in exe._cache if k.program_uid == main._uid
+             and k.program_version != main._version]
     assert not stale
 
 
@@ -235,3 +238,195 @@ def test_donation_dropped_while_compile_cache_configured_on_cpu():
         assert donation_safe() is True
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
+
+
+# -- one way from a Program to a running step -------------------------------
+# Executor and ParallelExecutor bind, build and run through the same code
+# (core/executor.py): what a step bakes in is one record (`StepKey`), each
+# field of which names the cause of a compile that it alone brought about.
+
+KINDS = ["executor", "parallel"]
+
+
+def _share_program():
+    """An expert layer under a share (its rules note `moe_row_buffer_rows`
+    and tally `moe_share_bounded_moves` on the compile event) and a second
+    thing to fetch."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = layers.data(name="x", shape=[8], dtype="float32")
+        routing = layers.moe_router(x, num_experts=4, k=2)
+        y = layers.moe_experts(x, routing, num_experts=4, expert_size=8,
+                               first_expert=0, experts_held=2)
+        loss = layers.mean(y)
+        other = layers.mean(x)
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    return main, startup, loss, other
+
+
+def _rig(kind, main, startup, scope=None, amp=False, ndev=4):
+    """A new executor of `kind` over `main` with the startup program run:
+    (step(feed, fetch_list), its compile cache, itself)."""
+    scope = scope or fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace(), amp=amp)
+    exe.run(startup, scope=scope)
+    if kind == "executor":
+        return (lambda feed, fetch: exe.run(main, feed=feed, fetch_list=fetch,
+                                            scope=scope)), exe._cache, exe
+    strategy = fluid.BuildStrategy()
+    strategy.amp = amp
+    pe = fluid.ParallelExecutor(
+        main_program=main, scope=scope, build_strategy=strategy,
+        mesh=make_mesh([ndev], ["dp"], jax.devices()[:ndev]))
+    return (lambda feed, fetch: pe.run(fetch_list=fetch, feed=feed)), \
+        pe._exe._cache, pe
+
+
+def _events(main):
+    return [e for e in observe.observatory().events()
+            if e.program_uid == main._uid]
+
+
+@pytest.mark.parametrize("field", StepKey._fields[1:])
+def test_every_field_of_the_record_names_a_cause(field):
+    """A field added to the record without a cause fails here."""
+    assert sorted(CAUSE_OF_FIELD) == sorted(StepKey._fields[1:])
+    base = StepKey(program_uid=10 ** 9, program_version=1, feeds=("x",),
+                   fetches=("loss",), scope_uid=2, amp=False,
+                   check_nan_inf=False, copts=None, seed=None, mesh=None)
+    other = dict(program_version=2, feeds=("x", "y"), fetches=("acc",),
+                 scope_uid=3, amp=True, check_nan_inf=True,
+                 copts=(("a", "1"),), seed=5, mesh="a mesh")
+    obs = observe.observatory()
+    detail = {"version": 1}
+    first = obs.note_entry_build(base, "executor", detail)
+    assert first.cause == "first_call" and first.detail is detail
+    again = obs.note_entry_build(base._replace(**{field: other[field]}),
+                                 "executor", {})
+    assert again.cause == CAUSE_OF_FIELD[field]
+    # every value seen before: a second executor building the same step
+    assert obs.note_entry_build(base, "parallel", {}).cause \
+        == "options_change"
+
+
+# `seed` is missing: setting a program's random_seed bumps its version
+_REACHABLE = ["program_version", "copts", "feeds", "fetches", "scope_uid",
+              "amp", "check_nan_inf"]
+
+
+@pytest.mark.parametrize("kind,field", [(k, f) for k in KINDS
+                                        for f in _REACHABLE]
+                         + [("parallel", "mesh")])
+def test_changing_one_setting_builds_one_entry_named_for_it(kind, field):
+    main, startup, loss, other = _share_program()
+    scope = fluid.Scope()
+    step, cache, _ = _rig(kind, main, startup, scope)
+    feed, fetch = {"x": np.ones((8, 8), np.float32)}, [loss.name]
+    step(feed, fetch)
+    (base,) = [k for k in cache if k.program_uid == main._uid]
+    flag = {"copts": ("xla_compiler_options", "auto",
+                      "xla_backend_optimization_level=0"),
+            "check_nan_inf": ("check_nan_inf", False, True)}.get(field)
+    if field == "program_version":
+        main._bump()
+    elif field == "feeds":
+        feed = dict(feed, extra=np.ones((8, 1), np.float32))
+    elif field == "fetches":
+        fetch = [loss.name, other.name]
+    elif field == "scope_uid":
+        step, cache, _ = _rig(kind, main, startup)
+    elif field == "amp":
+        step, cache, _ = _rig(kind, main, startup, scope, amp=True)
+    elif field == "mesh":
+        step, cache, _ = _rig(kind, main, startup, scope, ndev=2)
+    else:
+        fluid.set_flag(flag[0], flag[2])
+    try:
+        step(feed, fetch)
+        step(feed, fetch)               # steady: no third event
+    finally:
+        if flag:
+            fluid.set_flag(flag[0], flag[1])
+    assert [e.cause for e in _events(main)] \
+        == ["first_call", CAUSE_OF_FIELD[field]]
+    (new,) = [k for k in cache if k.program_uid == main._uid and k != base]
+    assert {f for f in StepKey._fields
+            if getattr(new, f) != getattr(base, f)} == {field}
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_rules_facts_land_on_the_event_of_the_entry_it_traces(kind):
+    """Two entries of one program; the first is traced again (a new batch)
+    after the second was bound: what its rules note is on ITS event."""
+    main, startup, loss, other = _share_program()
+    step, _, _ = _rig(kind, main, startup)
+    step({"x": np.ones((8, 8), np.float32)}, [loss.name])
+    step({"x": np.ones((8, 8), np.float32)}, [loss.name, other.name])
+    first, second = _events(main)
+    rows = 8 * 2 + 2 * 128              # tokens x k + experts held x tile
+    assert first.detail["moe_row_buffer_rows"] == rows
+    assert second.detail["moe_row_buffer_rows"] == rows
+    step({"x": np.ones((16, 8), np.float32)}, [loss.name])
+    assert first.detail["moe_row_buffer_rows"] == rows + 8 * 2
+    assert second.detail["moe_row_buffer_rows"] == rows
+    # and so is what the second build cost (no cause is invented for it)
+    assert [e.as_dict()["backend_compiles"] for e in (first, second)] \
+        == [2, 1]
+    # a tally counts an op once however often its step is traced, per entry
+    assert first.detail["moe_share_bounded_moves"] \
+        == second.detail["moe_share_bounded_moves"] == 3
+    assert [e.cause for e in _events(main)] == ["first_call", "fetch_set"]
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_check_nan_inf_holds_on_a_mesh_as_on_one_chip(kind):
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = layers.data(name="x", shape=[3], dtype="float32")
+        loss = layers.mean(layers.log(x))       # negative input -> NaN
+    step, _, _ = _rig(kind, main, startup)
+    bad = {"x": -np.ones((4, 3), np.float32)}
+    assert not np.isfinite(step(bad, [loss.name])[0]).all()  # off: silent
+    fluid.set_flag("check_nan_inf", True)
+    try:
+        with pytest.raises(RuntimeError, match=r"NaN/Inf.*'log'"):
+            step(bad, [loss.name])
+        out, = step({"x": np.ones((4, 3), np.float32)}, [loss.name])
+    finally:
+        fluid.set_flag("check_nan_inf", False)
+    assert np.isfinite(out).all()
+
+
+@pytest.mark.parametrize("runs,picked", [("a,ab", "ab"), ("a,ab,a", "a"),
+                                         ("ab,a,ab", "ab")])
+def test_compiled_text_is_of_the_entry_the_last_run_used(runs, picked):
+    main, startup, loss, other = _share_program()
+    step, _, pe = _rig("parallel", main, startup)
+    feed = {"x": np.ones((8, 8), np.float32)}
+    fetch = {"a": [loss.name], "ab": [loss.name, other.name]}
+    for r in runs.split(","):
+        step(feed, fetch[r])
+    entry, _ = pe._entry_for(feed, "compiled_text")
+    assert entry.fetch_names == fetch[picked]
+    assert pe.compiled_text(feed) == entry._hlo_text
+    assert "HloModule" in pe.lowered_text(feed) or "module" in \
+        pe.lowered_text(feed)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_py_reader_program_runs_without_a_feed(kind):
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        reader, (xv,) = fluid.reader.py_reader(
+            capacity=4, shapes=[[-1, 4]], dtypes=["float32"])
+        loss = layers.mean(layers.fc(input=xv, size=2))
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    reader.decorate_tensor_provider(lambda: (
+        {xv.name: np.full((8, 4), i, np.float32)} for i in range(3)))
+    step, _, _ = _rig(kind, main, startup)
+    reader.start()
+    losses = [step(None, [loss.name])[0].item() for _ in range(3)]
+    with pytest.raises(fluid.EOFException):
+        step(None, [loss.name])
+    reader.reset()
+    assert len(set(losses)) == 3

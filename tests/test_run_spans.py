@@ -123,6 +123,31 @@ def test_step_stats_share_the_span_boundaries_when_observing(kind, tmp_path):
     assert 0.8 <= sorted(ratios)[3] <= 1.05, ratios
 
 
+@pytest.mark.parametrize("return_numpy", [False, True])
+def test_both_executors_run_the_same_spans_and_note_the_same_detail(
+        return_numpy, tmp_path):
+    """One run loop (core/executor.py::PreparedProgram.run): the span
+    names of a binding and of a steady step, in order, and the keys of the
+    compile event's detail do not depend on who owns the handle."""
+    seen = {}
+    for kind in ("executor", "parallel"):
+        main, step = _stepper(kind)
+        feed = {"x": np.ones((8, 4), np.float32)}
+        spans = _profiled(tmp_path / kind, lambda: [
+            step(feed, return_numpy) for _ in range(2)])
+        event, = [e for e in observe.observatory().events()
+                  if e.program_uid == main._uid]
+        assert event.source == kind
+        seen[kind] = ([s[0] for s in spans], sorted(event.detail))
+    assert seen["executor"] == seen["parallel"]
+    names, keys = seen["executor"]
+    steady = ["paddle_tpu:run", "paddle_tpu:feed_convert",
+              "paddle_tpu:state_gather", "paddle_tpu:jit_call",
+              "paddle_tpu:write_back"] + ["paddle_tpu:fetch"] * return_numpy
+    assert names == steady[:2] + ["paddle_tpu:bind"] + steady[2:] + steady
+    assert {"version", "feeds", "fetches", "parameters"} <= set(keys)
+
+
 def _attention_program():
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = startup.random_seed = 3
