@@ -88,8 +88,7 @@ def test_sums_over_the_kernels_read_the_one_pass_kernel(metric):
         1400, sorted([ONEPASS, ONEPASS_IN_VJP, STREAM, BWD]))
 
 
-@pytest.mark.parametrize("metric", ["flash_dq_ms.train", "flash_dkv_ms.train",
-                                    "flash_bwd_ms.train",
+@pytest.mark.parametrize("metric", ["flash_bwd_ms.train",
                                     "flash_bwd_calls.train"])
 def test_backward_metrics_do_not_read_it(metric):
     assert tr.sum_matching(BY_NAME, pattern(metric)) == (700, [BWD])
@@ -98,7 +97,9 @@ def test_backward_metrics_do_not_read_it(metric):
 def test_benchmark_json_lists_the_metric_for_the_transformer_cells():
     with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = bench["per_layer"][-1]
+    # found by name: later PRs append their own entries after it
+    entry, = [m for m in bench["per_layer"]
+              if m["name"] == "flash_fwd_onepass_calls.train"]
     assert entry == {
         "name": "flash_fwd_onepass_calls.train", "unit": "count",
         "better": "higher", "source": "device_trace",

@@ -270,20 +270,18 @@ def test_new_entries_are_listed_for_the_cell_alone():
            "mla_moe_layout_op_ms.train", "router_bias_updates.train"}
     listed = {m["name"]: m for m in bench["per_layer"] if m["name"] in new}
     assert set(listed) == new
-    assert [m["name"] for m in bench["per_layer"][-9:]] == \
-        [m["name"] for m in bench["per_layer"] if m["name"] in new]
     for m in listed.values():
         assert m["workloads"] == [CELL] and \
             m["moves"] == "train_examples_per_s", m["name"]
         assert os.path.exists(os.path.join(BENCH, "metrics",
                                            m["name"] + ".json"))
     assert listed["router_bias_updates.train"]["better"] == "higher"
-    for m in bench["per_layer"]:
-        if m["name"] not in new:        # no accepted metric took the cell in
-            assert CELL not in m.get("workloads", []), m["name"]
-    assert bench["workloads"][-1] == load("workloads", CELL + ".json")
-    assert len(bench["workloads"]) == 7 and len(bench["configs"]) == 6
-    assert len(bench["workloads"][-1]["why"]) <= 200
+    # found by name: later PRs append entries, later metrics may take the
+    # cell in, later configurations bring cells of their own
+    cell, = [w for w in bench["workloads"] if w["name"] == CELL]
+    assert cell == load("workloads", CELL + ".json")
+    assert sum(w["config"] == cell["config"] for w in bench["workloads"]) == 1
+    assert len(cell["why"]) <= 200
 
 
 def test_bias_update_reader_on_a_hand_made_observatory(monkeypatch):

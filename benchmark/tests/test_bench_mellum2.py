@@ -15,6 +15,7 @@ import importlib
 import json
 import os
 import re
+import re
 import subprocess
 import sys
 import types
@@ -377,9 +378,7 @@ def test_new_entries_are_listed_for_the_cell_alone_found_by_name():
                 ("%", "higher")
     assert listed["window_attention_layers.train"]["source"] == \
         "program_counter"
-    for m in bench["per_layer"]:
-        if m["name"] not in NEW:        # no accepted metric took the cell in
-            assert CELL not in m.get("workloads", []), m["name"]
+    # later metrics may take the cell in (moe_share_bounded_ops.train did)
     cell, = [w for w in bench["workloads"] if w["name"] == CELL]
     assert cell == load("workloads", CELL + ".json")
     assert len(cell["why"]) <= 200 and cell["chips"] == 1
@@ -501,14 +500,16 @@ def test_config_holds_the_catalog_numbers_and_lists_its_three_cuts():
     assert entry["file"] == "benchmark/configs/" + CONFIG + ".json"
 
 
-def test_traffic_is_kanana2s_but_for_the_length_and_the_reference():
+def test_traffic_is_kanana2s_but_for_the_length_the_pool_and_the_reference():
     old = load("traffic", "steady_b1_s4096_kanana2.json")
     new = load("traffic", TRAFFIC + ".json")
-    for key in ("generator", "batch", "pool_batches", "feed", "in_flight",
-                "warmup", "traced"):
+    for key in ("generator", "batch", "feed", "in_flight", "warmup",
+                "traced"):
         assert new[key] == old[key], key
     assert new["build_args"] == {"seq_len": 8192}
-    assert set(new) == set(old)
+    assert set(new) == set(old) | {"pool_batches_why"}
+    assert len(new["pool_batches_why"]) > 200
+    assert "TO BE READ" not in new["pool_batches_why"]
     # the in-run comparison says what it cannot refuse
     assert "DOES NOT HOLD: THE WINDOW" in \
         new["reference_check"]["loss_atol_why"]
@@ -519,6 +520,23 @@ def test_traffic_is_kanana2s_but_for_the_length_and_the_reference():
     cell = load("workloads", CELL + ".json")
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         (CONFIG, TRAFFIC, 1)
+
+
+def test_the_pool_is_what_the_cells_why_says():
+    """PR 45 widened the pool from 8 to 128: a run that sees 8 batches ~35
+    times each memorises them, the router drifts to the held experts and a
+    step slows through the window by an amount that follows the seed's draw
+    (the driver read spreads of 1.35-2.06% against the 1% bound). The number
+    in the cell's `why`, in BENCHMARK.json's copy of it and in the traffic
+    file's own reason is the traffic file's."""
+    traffic = load("traffic", TRAFFIC + ".json")
+    assert traffic["pool_batches"] == 128
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry, = [w for w in json.load(f)["workloads"] if w["name"] == CELL]
+    for why in (load("workloads", CELL + ".json")["why"], entry["why"],
+                traffic["pool_batches_why"]):
+        said = re.search(r"pool of (\d+)", why)
+        assert said and int(said.group(1)) == traffic["pool_batches"], why
 
 
 @pytest.mark.parametrize("trace", [0, 1])

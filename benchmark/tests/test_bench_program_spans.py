@@ -128,13 +128,14 @@ def test_flash_kernel_metrics_by_pallas_name(hand_made):
     ctx = hand_made(EVENTS)
     # flash_fwd: FWD 150 + 100, FWD_IN_GRAD 100 = 350 ns over 2 steps;
     # the get-tuple-element that reads %flash_fwd.4 is not counted
-    for name, expect_ns in (("flash_fwd_ms.train", 350),
-                            ("flash_dq_ms.train", 70),
-                            ("flash_dkv_ms.train", 100)):
-        reader, args = metric(name)
-        assert reader == "trace_ops"
-        assert trace_ops.read(ctx, **args) == pytest.approx(
-            expect_ns / 1e6 / 2)
+    reader, args = metric("flash_fwd_ms.train")
+    assert reader == "trace_ops"
+    assert trace_ops.read(ctx, **args) == pytest.approx(350 / 1e6 / 2)
+    # the backward here is the split pair (DQ 70, DKV 100), which the fused
+    # kernel's two metrics do not read: nothing, not 0
+    for name, module in (("flash_bwd_ms.train", trace_ops),
+                         ("flash_bwd_calls.train", trace_calls)):
+        assert module.read(ctx, **metric(name)[1]) is None
     # two call sites: the forward op's and the one inside the grad op
     reader, args = metric("flash_fwd_calls.train")
     assert reader == "trace_calls"
@@ -165,8 +166,8 @@ def test_a_program_without_spans_names_or_stages_gives_nothing(hand_made):
            for e in EVENTS if not e.name.startswith("paddle_tpu:")]
     ctx = hand_made(old)
     for name in ("exe_run_us.train", "exe_state_us.train",
-                 "flash_fwd_ms.train", "flash_dq_ms.train",
-                 "flash_dkv_ms.train", "flash_fwd_calls.train",
+                 "flash_fwd_ms.train", "flash_bwd_ms.train",
+                 "flash_bwd_calls.train", "flash_fwd_calls.train",
                  "idle_inside_run_pct.train"):
         reader, args = metric(name)
         module = {"program_spans": program_spans, "trace_ops": trace_ops,
