@@ -379,7 +379,8 @@ def _matmul_flops(op, env, block, default_dim) -> float:
 
 
 def _attention_flops(op, env, block, default_dim) -> float:
-    """fused_attention [B,H,Tq,Dh]x[B,H,Tk,Dh]: the two dots QK^T and
+    """fused_attention [B,H,Tq,Dh]x[B,H,Tk,Dh] (or [B,Tq,H,Dh]x[B,Tk,H,Dh]
+    under `layout` "BTHD"): the two dots QK^T and
     W·V (2·M·K·N each => 4·Dh per score) plus softmax's ~3
     non-transcendental flops per score — what XLA counts for the
     equivalent unfused chain, so fused and unfused programs cost the
@@ -394,8 +395,9 @@ def _attention_flops(op, env, block, default_dim) -> float:
                    None)
         out_shape = _shape_of(env, block, out, default_dim) if out else None
         return 2.0 * _nelems(out_shape) if out_shape else 0.0
-    return ((4.0 * q_shape[-1] + 3.0)
-            * _nelems(q_shape[:-1]) * float(k_shape[-2]))
+    # keys a query meets: the sequence axis, which a "BTHD" op holds second
+    keys = k_shape[1] if op.attrs.get("layout") == "BTHD" else k_shape[-2]
+    return (4.0 * q_shape[-1] + 3.0) * _nelems(q_shape[:-1]) * float(keys)
 
 
 def _op_flops(op, env, block, default_dim, fwd_by_out) -> float:

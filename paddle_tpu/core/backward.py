@@ -267,7 +267,8 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
             wide = keys.shape[-1]
             value = block.var(op.input("V")[0]).shape[-1]
             window = op.attrs.get("window")
-            if window is not None and window < keys.shape[-2]:
+            seq = keys.shape[1 if op.attrs.get("layout") == "BTHD" else -2]
+            if window is not None and window < seq:
                 kinds["window_attention"] += 1
                 out["attention_window"] = window
                 group = _expanded_by(block, op.input("K")[0])

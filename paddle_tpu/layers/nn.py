@@ -1481,11 +1481,19 @@ def exit_gate(input, param_attr=None, bias_attr=None, name=None):
 
 
 def fused_attention(q, k, v, causal=False, sm_scale=None, dropout_rate=0.0,
-                    is_test=False, window=None, name=None):
-    """Softmax attention on `[batch, heads, seq, head_dim]` through the
-    flash kernels (`ops/pallas_attention.py`): O(seq) memory, dropout on the
-    attention weights inside the kernel. `v` may have a head width of its
-    own (latent attention: q, k at 192, v at 128); the result has `v`'s.
+                    is_test=False, window=None, layout="BHTD", name=None):
+    """Softmax attention through the flash kernels
+    (`ops/pallas_attention.py`): O(seq) memory, dropout on the attention
+    weights inside the kernel. `layout` says how the operands lie, and the
+    result lies the same way: "BHTD", `[batch, heads, seq, head_dim]`, or
+    "BTHD", `[batch, seq, heads, head_dim]`, which is what a projection's
+    `[batch, seq, heads * head_dim]` output is under a free reshape. The
+    kernels read "BTHD" operands as they are, a head being a range of lanes
+    picked by their block specs, several heads a grid step; no transpose
+    stands on either side of the op, forward or backward. It needs `v`'s
+    head width to be `q`'s. Under "BHTD" `v` may have a head width of its
+    own (latent attention: q, k at 192, v
+    at 128); the result has `v`'s.
 
     `window=W` (with `causal=True` only): key j is visible to query i iff
     `0 <= i - j < W`, sliding-window attention. The kernels then cover the
@@ -1497,11 +1505,16 @@ def fused_attention(q, k, v, causal=False, sm_scale=None, dropout_rate=0.0,
     kernels: both raise.
 
     The op has a second output, `Lse`: the forward kernel's log-sum-exp of
-    every score row, float32 `[batch * heads, 1, seq]`, the kernels' own
-    layout. `fused_attention_grad` reads `Out` and `Lse` back and runs the
-    two backward kernels alone. Where the forward op wrote no `Lse` (under
-    sequence parallelism, on the CPU reference path, in a program built
-    without the slot) the grad op traces the forward again under `jax.vjp`."""
+    every score row, float32 `[batch * heads, 1, seq]` in either layout,
+    the kernels' own. `fused_attention_grad` reads `Out` and `Lse` back and
+    runs the backward kernel alone. Where the forward op wrote no `Lse`
+    (under sequence parallelism, on the CPU reference path, in a program
+    built without the slot) the grad op traces the forward again under
+    `jax.vjp`."""
+    from ..ops.pallas_attention import LAYOUTS, _check_window
+    if layout not in LAYOUTS:
+        raise ValueError(f"fused_attention: layout {layout!r} is none of "
+                         f"{LAYOUTS}")
     helper = LayerHelper("fused_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     lse = helper.create_variable_for_type_inference("float32",
@@ -1510,8 +1523,9 @@ def fused_attention(q, k, v, causal=False, sm_scale=None, dropout_rate=0.0,
         sm_scale = q.shape[-1] ** -0.5
     attrs = {"causal": causal, "sm_scale": sm_scale,
              "dropout_rate": dropout_rate, "is_test": is_test}
+    if layout != "BHTD":        # a head-major op is the op it was
+        attrs["layout"] = layout
     if window is not None:
-        from ..ops.pallas_attention import _check_window
         _check_window(window, causal)       # refuses at build time
         attrs["window"] = int(window)
     helper.append_op("fused_attention",

@@ -51,17 +51,21 @@ def multi_head_attention(q_in, kv_in, d_model, num_heads, dropout_rate=0.0,
     v = layers.fc(input=kv_in, size=d_model, num_flatten_dims=2, bias_attr=False,
                   param_attr=_shard((None, "mp")), name=name + "_v")
 
-    def split_heads(x):
-        r = layers.reshape(x, shape=[0, 0, num_heads, d_head])
-        return layers.transpose(r, perm=[0, 2, 1, 3])
-
-    qh, kh, vh = split_heads(q), split_heads(k), split_heads(v)
     if fused:
+        # the projections' own layout, heads a free reshape apart: the flash
+        # kernels pick a head by its lanes (`layout="BTHD"`)
+        qh, kh, vh = (layers.reshape(x, shape=[0, 0, num_heads, d_head])
+                      for x in (q, k, v))
         ctx = layers.fused_attention(qh, kh, vh, causal=causal,
                                      sm_scale=d_head ** -0.5,
                                      dropout_rate=dropout_rate,
-                                     is_test=is_test)
+                                     is_test=is_test, layout="BTHD")
     else:
+        def split_heads(x):
+            r = layers.reshape(x, shape=[0, 0, num_heads, d_head])
+            return layers.transpose(r, perm=[0, 2, 1, 3])
+
+        qh, kh, vh = split_heads(q), split_heads(k), split_heads(v)
         scores = layers.matmul(qh, kh, transpose_y=True, alpha=d_head ** -0.5)
         if causal:
             mask_var = _causal_mask(scores.shape[-1])
@@ -71,8 +75,7 @@ def multi_head_attention(q_in, kv_in, d_model, num_heads, dropout_rate=0.0,
             weights = layers.dropout(weights, dropout_prob=dropout_rate,
                                      is_test=is_test,
                                      dropout_implementation="upscale_in_train")
-        ctx = layers.matmul(weights, vh)
-    ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
+        ctx = layers.transpose(layers.matmul(weights, vh), perm=[0, 2, 1, 3])
     merged = layers.reshape(ctx, shape=[0, 0, d_model])
     return layers.fc(input=merged, size=d_model, num_flatten_dims=2,
                      bias_attr=False, param_attr=_shard(("mp", None)),

@@ -58,6 +58,25 @@ block is the array's whole last axis, which is a legal block. Where `Dv == D`
 every kernel is the instructions it was. `_bwd_plan` reads both: the resident
 dQ row is query-wide (its 192 lanes held as 256), dO, v and dV value-wide.
 
+The operands have one of two layouts (the op's `layout`). "BHTD", `[B, H, T,
+D]`: the kernels see `[B*H, T, D]` and a grid step's blocks are one head's.
+"BTHD", `[B, T, H, D]`, token-major: what a projection's `[B, T, H*D]` output
+is under a free reshape. The kernels see that array as it is; a head is a
+range of lanes, which the block specs' index maps and a dynamic lane slice
+pick, a block holds several heads of one row block (`_token_major_heads`: as
+many as the scoped VMEM of the call holds, all eight of 64 lanes at seq 256),
+and the grid is (batch, head block, tiles). Heads narrower than a vreg's 128
+lanes share a lane group: the products contract over the group's lanes with
+the neighbours' zeroed, which at D = 64 costs the MXU pass the head-major
+product costs, and results are written under the head's mask. `Out`, dQ, dK
+and dV leave in the operands' layout, so no transpose and no second copy of
+`Out` stands on either side, forward or backward; `Lse` and the dropout
+masks' keys (b * H + h, q tile, k tile) are the head-major call's, and so
+are the results, bit for bit on the chip. Both layouts run the same five
+kernel bodies: a head-major step has one head, its blocks whole (the
+instructions it always was), a token-major step loops over its heads
+(`_each_head`).
+
 A causal call may carry a `window` W: key j is visible to query i iff
 `0 <= i - j < W` (sliding-window attention). The mask gains its lower edge and
 so does the liveness predicate, in one pair of functions that all five kernels
@@ -83,6 +102,7 @@ from __future__ import annotations
 import functools
 import math
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -384,14 +404,136 @@ def _dropout_mask(seed_ref, bh, qi, kj, shape, rate):
     return bits >= jnp.int32(thresh)
 
 
-def _score_tile(q_ref, k_ref, qi, kj, sm_scale, causal, window=None):
+# -- the heads of a grid step ------------------------------------------------
+# A head-major call hands the kernels `[B*H, T, D]`: a grid step's blocks are
+# one head's, whole. A token-major call hands them `[B, T, H*D]`, the
+# projections' own layout: a head is a lane range, a block holds `step` heads
+# of one row block, and a grid step computes them all (`_each_head`). A
+# block's last dimension is a multiple of a vreg's 128 lanes (or the whole
+# axis), so heads narrower than that come in groups (two of 64 lanes): a
+# head's operands are its group's lanes with the other heads' zeroed (`_own`)
+# where the product contracts over lanes, and its results are written under
+# the same mask (`_put`, `_rmw`). At D = 64 a product already fills half of a
+# 128-deep MXU pass, so the group's 128 lanes cost the pass the head's 64
+# cost; what a head adds is the selects, on `[blk_q, 128]` arrays beside a
+# `[blk_q, blk_k]` score tile.
+
+class _Heads(NamedTuple):
+    """How a token-major call's blocks hold heads: `total` heads of `width`
+    lanes a token, `step` of them a block, in groups `lanes` wide."""
+    total: int
+    step: int
+    width: int
+    lanes: int
+
+    @property
+    def group(self):
+        """Heads that share a group's lanes."""
+        return self.lanes // self.width
+
+
+class _Head(NamedTuple):
+    """One head of a grid step, as indices into the step's blocks."""
+    bh: object      # b * H + h: what the dropout mask is keyed by
+    blk: object     # into a [1, rows, lanes] block: the head's group
+    row: object     # into an Lse or delta block: the head's row
+    stat: object    # into the row statistics in scratch
+    acc: object     # into a [rows, lanes] accumulator in scratch
+    lanes: object   # the group's lanes
+    mask: object    # the head's lanes among its group's; None: all of them
+
+    @property
+    def whole(self):
+        """The step's blocks are this head's alone (a head-major call)."""
+        return self.blk == 0
+
+
+def _each_head(heads, first, body):
+    """`body(head)` for every head of this grid step; `first` is grid axis
+    0's id (the (batch x head) row of a head-major call) or the step's first
+    head of a token-major one (`_grid_ids`). The lane groups of a
+    token-major block run under a `fori_loop`, a group's lanes a dynamic
+    slice at a multiple of its width, and the heads of a group are unrolled
+    in the loop's body with masks that are constants. What jax traces and
+    Mosaic compiles is one group's instructions however many a block
+    holds (all eight heads unrolled over a 512 x 2048 score tile took
+    Mosaic 15 s where a head-major call takes 1); the two heads of a group
+    side by side leave the scheduler one head's MXU work to put beside the
+    other's VPU work, which a loop over single heads does not (chip runs,
+    PR 46: the one-pass forward at seq 256 0.55 ms a call against 0.63, the
+    backward 0.58 against 0.72; head-major 0.64 and 0.86)."""
+    from jax.experimental import pallas as pl
+
+    if heads is None:
+        return body(_Head(first, 0, (0, 0), ..., ..., slice(None), None))
+
+    def group(g, carry=None):
+        at = g * heads.lanes
+        lanes = pl.ds(at if isinstance(g, int)
+                      else pl.multiple_of(at, heads.lanes), heads.lanes)
+        for sub in range(heads.group):
+            i = g * heads.group + sub
+            mask = None
+            if heads.group > 1:
+                lane = lax.broadcasted_iota(jnp.int32, (1, heads.lanes), 1)
+                mask = (lane >= sub * heads.width) \
+                    & (lane < (sub + 1) * heads.width)
+            body(_Head(first + i, (0, slice(None), lanes), (i, 0), i,
+                       (slice(None), lanes), lanes, mask))
+
+    groups = heads.step // heads.group
+    if groups == 1:
+        return group(0)
+    lax.fori_loop(0, groups, group, None)
+
+
+def _own(hd, x):
+    """`x` with the lanes of the group's other heads zeroed."""
+    return x if hd.mask is None else jnp.where(hd.mask, x, jnp.zeros_like(x))
+
+
+def _put(hd, ref, x):
+    """Write the head's lanes of its group in an output block."""
+    ref[hd.blk] = x if hd.mask is None else jnp.where(hd.mask, x, ref[hd.blk])
+
+
+def _rmw(hd, ref, idx, f):
+    """`ref[idx] = f(ref[idx])` on the head's lanes of its group."""
+    old = ref[idx]
+    new = f(old)
+    ref[idx] = new if hd.mask is None else jnp.where(hd.mask, new, old)
+
+
+def _delta(hd, delta_ref, do):
+    """rowsum(dOut * Out) of the head's rows, float32. A head-major call is
+    handed it, a row of the `Lse`-shaped delta block (XLA sums over `[B*H,
+    T, Dv]` beside the call). A token-major call is handed `Out`'s block in
+    its place and sums here, a column, over the group's lanes of `do`, which
+    holds zeros in the other heads': the sum over `[B, T, H, Dv]` would be
+    an XLA op of an operand's size between the projections and the kernel,
+    as the copies were (and its fusion took XLA longer to compile than the
+    kernel takes Mosaic)."""
+    if hd.whole:
+        return delta_ref[hd.row]
+    return jnp.sum(do.astype(jnp.float32)
+                   * delta_ref[hd.blk].astype(jnp.float32),
+                   axis=1, keepdims=True)
+
+
+def _col(x):
+    """A row statistic against a [blk_q, blk_k] tile."""
+    return x[:, None] if x.ndim == 1 else x
+
+
+def _score_tile(q_ref, k_ref, hd, qi, kj, sm_scale, causal, window=None):
     """One float32 [blk_q, blk_k] tile of q k^T * sm_scale, the causal mask
     applied in-register. The dots run in the INPUT dtype (bf16 under AMP ->
     full MXU rate; the round-3 kernels upcast to f32 first, quartering
     matmul throughput) with f32 accumulation via preferred_element_type;
     sm_scale is applied to the f32 product so no operand precision is
     spent on it."""
-    s = lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+    s = lax.dot_general(_own(hd, q_ref[hd.blk]), k_ref[hd.blk],
+                        (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32) * sm_scale
     if causal:
         s = _apply_causal_mask(s, qi, kj, q_ref.shape[1], k_ref.shape[1],
@@ -399,18 +541,20 @@ def _score_tile(q_ref, k_ref, qi, kj, sm_scale, causal, window=None):
     return s
 
 
-def _weights_times_v(p, v_ref, seed_ref, bh, qi, kj, dropout_rate):
-    """dropout(p) v for one tile, float32 [blk_q, Dv]."""
+def _weights_times_v(p, v_ref, hd, seed_ref, qi, kj, dropout_rate):
+    """dropout(p) v for one tile, float32 [blk_q, Dv] (the group's lanes:
+    the caller keeps the head's)."""
     if dropout_rate:
-        keep = _dropout_mask(seed_ref, bh, qi, kj, p.shape, dropout_rate)
+        keep = _dropout_mask(seed_ref, hd.bh, qi, kj, p.shape, dropout_rate)
         p = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
-    v = v_ref[0]
+    v = v_ref[hd.blk]
     return lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                            preferred_element_type=jnp.float32)
 
 
 def _flash_fwd_onepass_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                              *, sm_scale, causal, dropout_rate, window=None):
+                              *, sm_scale, causal, dropout_rate, window=None,
+                              heads=None):
     """A row is one K block (`_fwd_plan`): the softmax of a q-block is
     whole in its one grid step, so there is no running maximum to correct,
     nothing carried in scratch and no branch. The row statistics keep the
@@ -420,15 +564,18 @@ def _flash_fwd_onepass_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     first step scales a zero state by `exp(NEG_INF - m) = 0`."""
     from jax.experimental import pallas as pl
 
-    bh = pl.program_id(0)
-    qi = pl.program_id(1)
-    s = _score_tile(q_ref, k_ref, qi, 0, sm_scale, causal, window)
-    m = jnp.max(s, axis=1, keepdims=True)              # [blk_q, 1]
-    p = jnp.exp(s - m)
-    l = jnp.maximum(jnp.sum(p, axis=1, keepdims=True), 1e-20)
-    acc = _weights_times_v(p, v_ref, seed_ref, bh, qi, 0, dropout_rate)
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0, 0] = (m + jnp.log(l))[:, 0]
+    first, qi = _grid_ids(heads, tile_axes=1)
+
+    def head(hd):
+        s = _score_tile(q_ref, k_ref, hd, qi, 0, sm_scale, causal, window)
+        m = jnp.max(s, axis=1, keepdims=True)              # [blk_q, 1]
+        p = jnp.exp(s - m)
+        l = jnp.maximum(jnp.sum(p, axis=1, keepdims=True), 1e-20)
+        acc = _weights_times_v(p, v_ref, hd, seed_ref, qi, 0, dropout_rate)
+        _put(hd, o_ref, (acc / l).astype(o_ref.dtype))
+        lse_ref[hd.row] = (m + jnp.log(l))[:, 0]
+
+    _each_head(heads, first, head)
 
 
 # The streaming forward's row statistics in scratch are `_LANES` wide, every
@@ -444,9 +591,27 @@ def _lanes(x, n):
     return x if x.shape[1] == n else x[:, :n]
 
 
+def _grid_ids(heads, tile_axes=2):
+    """(what `_each_head` counts the step's heads from, the id of the
+    grid's outer tile axis; and of a grid with two, the id of the inner,
+    how many steps the inner has, and the outer)."""
+    from jax.experimental import pallas as pl
+
+    first = pl.program_id(0)
+    ax = 1
+    if heads is not None:
+        first = first * heads.total + pl.program_id(1) * heads.step
+        ax = 2
+    if tile_axes == 1:
+        return first, pl.program_id(ax)
+    return (first, pl.program_id(ax), pl.program_id(ax + 1),
+            lambda: pl.num_programs(ax + 1), lambda: pl.num_programs(ax))
+
+
 def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                       m_sc, l_sc, acc_sc, *,
-                      sm_scale, causal, dropout_rate, window=None):
+                      sm_scale, causal, dropout_rate, window=None,
+                      heads=None):
     """A row has several K blocks. K/V STREAM through the grid's innermost
     ("arbitrary") dimension: each program sees one [blk_k, D] K/V block,
     with the online-softmax state carried in VMEM scratch across kj
@@ -462,13 +627,11 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     `exp(NEG_INF - m) = 0`."""
     from jax.experimental import pallas as pl
 
-    bh = pl.program_id(0)
-    qi = pl.program_id(1)
-    step = pl.program_id(2)
-    nk = pl.num_programs(2)
+    first, qi, step, inner, _ = _grid_ids(heads)
+    nk = inner()
     blk_q = q_ref.shape[1]
     blk_k = k_ref.shape[1]
-    Dv = v_ref.shape[2]
+    Dv = v_ref.shape[2] if heads is None else heads.lanes
     kj = step if window is None else _band_kj(qi, step, blk_q, blk_k, window)
 
     @pl.when(step == 0)
@@ -482,34 +645,42 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(live)
     def _update():
-        s = _score_tile(q_ref, k_ref, qi, kj, sm_scale, causal, window)
-        m = m_sc[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - _lanes(m_new, blk_k))
-        alpha = jnp.exp(m - m_new)
-        l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_sc[...] = acc_sc[...] * _lanes(alpha, Dv) + _weights_times_v(
-            p, v_ref, seed_ref, bh, qi, kj, dropout_rate)
-        m_sc[...] = m_new
+        def head(hd):
+            s = _score_tile(q_ref, k_ref, hd, qi, kj, sm_scale, causal,
+                            window)
+            m = m_sc[hd.stat]
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - _lanes(m_new, blk_k))
+            alpha = jnp.exp(m - m_new)
+            l_sc[hd.stat] = l_sc[hd.stat] * alpha \
+                + jnp.sum(p, axis=1, keepdims=True)
+            _rmw(hd, acc_sc, hd.acc,
+                 lambda acc: acc * _lanes(alpha, Dv) + _weights_times_v(
+                     p, v_ref, hd, seed_ref, qi, kj, dropout_rate))
+            m_sc[hd.stat] = m_new
+
+        _each_head(heads, first, head)
 
     @pl.when(step == nk - 1)
     def _finalize():
-        l = jnp.maximum(l_sc[...], 1e-20)
-        o_ref[0] = (acc_sc[...] / _lanes(l, Dv)).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_sc[...] + jnp.log(l))[:, 0]
+        def head(hd):
+            l = jnp.maximum(l_sc[hd.stat], 1e-20)
+            _put(hd, o_ref,
+                 (acc_sc[hd.acc] / _lanes(l, Dv)).astype(o_ref.dtype))
+            lse_ref[hd.row] = (m_sc[hd.stat] + jnp.log(l))[:, 0]
+
+        _each_head(heads, first, head)
 
 
 def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                      delta_ref, dq_ref, dq_sc, *, sm_scale, causal,
-                     dropout_rate, window=None):
+                     dropout_rate, window=None, heads=None):
     """dQ with K/V streamed through the innermost grid dim (see
     _flash_fwd_kernel); the dQ accumulator lives in VMEM scratch."""
     from jax.experimental import pallas as pl
 
-    bh = pl.program_id(0)
-    qi = pl.program_id(1)
-    step = pl.program_id(2)
-    nk = pl.num_programs(2)
+    first, qi, step, inner, _ = _grid_ids(heads)
+    nk = inner()
     blk_q = q_ref.shape[1]
     blk_k = k_ref.shape[1]
     kj = step if window is None else _band_kj(qi, step, blk_q, blk_k, window)
@@ -522,29 +693,32 @@ def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     @pl.when(live)
     def _update():
-        q = q_ref[0]
-        do = do_ref[0]                                 # [blk_q, D]
-        lse = lse_ref[0, 0]                            # [blk_q]
-        delta = delta_ref[0, 0]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            s = _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
-        w = jnp.exp(s - lse[:, None])                  # normalized weights
-        dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-        if dropout_rate:
-            keep = _dropout_mask(seed_ref, bh, qi, kj, (blk_q, blk_k),
-                                 dropout_rate)
-            dw = jnp.where(keep, dpv / (1.0 - dropout_rate), 0.0)
-        else:
-            dw = dpv
-        ds = w * (dw - delta[:, None]) * sm_scale
-        dq_sc[...] = dq_sc[...] + lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        def head(hd):
+            q = _own(hd, q_ref[hd.blk])
+            do = _own(hd, do_ref[hd.blk])                  # [blk_q, D]
+            lse = lse_ref[hd.row]                          # [blk_q]
+            delta = _delta(hd, delta_ref, do)
+            k = k_ref[hd.blk]
+            v = v_ref[hd.blk]
+            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * sm_scale
+            if causal:
+                s = _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
+            w = jnp.exp(s - lse[:, None])                  # normalized weights
+            dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+            if dropout_rate:
+                keep = _dropout_mask(seed_ref, hd.bh, qi, kj, (blk_q, blk_k),
+                                     dropout_rate)
+                dw = jnp.where(keep, dpv / (1.0 - dropout_rate), 0.0)
+            else:
+                dw = dpv
+            ds = w * (dw - _col(delta)) * sm_scale
+            _rmw(hd, dq_sc, hd.acc, lambda dq: dq + lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+
+        _each_head(heads, first, head)
 
     @pl.when(step == nk - 1)
     def _finalize():
@@ -554,17 +728,15 @@ def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _flash_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                       delta_ref, dk_ref, dv_ref, dk_sc, dv_sc, *,
                       sm_scale, causal, dropout_rate, window=None,
-                      q_tiles=None):
+                      q_tiles=None, heads=None):
     """dK/dV with Q/dOut/lse/delta streamed through the innermost grid
     dim (grid = (BH, kj, qi)); accumulators in VMEM scratch. Under a
     `window` the inner axis counts the Q tiles of the k-block's band
     (`_band_qi`; `q_tiles` is the row's whole count)."""
     from jax.experimental import pallas as pl
 
-    bh = pl.program_id(0)
-    kj = pl.program_id(1)
-    step = pl.program_id(2)
-    nq = pl.num_programs(2)
+    first, kj, step, inner, _ = _grid_ids(heads)
+    nq = inner()
     blk_q = q_ref.shape[1]
     blk_k = k_ref.shape[1]
     qi = step if window is None else _band_qi(kj, step, nq, blk_q, blk_k,
@@ -580,33 +752,36 @@ def _flash_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     @pl.when(live)
     def _update():
-        k = k_ref[0]                                   # [blk_k, D]
-        v = v_ref[0]
-        q = q_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            s = _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
-        w = jnp.exp(s - lse[:, None])                  # [blk_q, blk_k]
-        dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-        if dropout_rate:
-            keep = _dropout_mask(seed_ref, bh, qi, kj, (blk_q, blk_k),
-                                 dropout_rate)
-            w_drop = jnp.where(keep, w / (1.0 - dropout_rate), 0.0)
-            dw = jnp.where(keep, dpv / (1.0 - dropout_rate), 0.0)
-        else:
-            w_drop, dw = w, dpv
-        dv_sc[...] = dv_sc[...] + lax.dot_general(
-            w_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = w * (dw - delta[:, None]) * sm_scale
-        dk_sc[...] = dk_sc[...] + lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        def head(hd):
+            k = k_ref[hd.blk]                              # [blk_k, D]
+            v = v_ref[hd.blk]
+            q = _own(hd, q_ref[hd.blk])
+            do = _own(hd, do_ref[hd.blk])
+            lse = lse_ref[hd.row]
+            delta = _delta(hd, delta_ref, do)
+            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * sm_scale
+            if causal:
+                s = _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
+            w = jnp.exp(s - lse[:, None])                  # [blk_q, blk_k]
+            dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+            if dropout_rate:
+                keep = _dropout_mask(seed_ref, hd.bh, qi, kj, (blk_q, blk_k),
+                                     dropout_rate)
+                w_drop = jnp.where(keep, w / (1.0 - dropout_rate), 0.0)
+                dw = jnp.where(keep, dpv / (1.0 - dropout_rate), 0.0)
+            else:
+                w_drop, dw = w, dpv
+            _rmw(hd, dv_sc, hd.acc, lambda dv: dv + lax.dot_general(
+                w_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+            ds = w * (dw - _col(delta)) * sm_scale
+            _rmw(hd, dk_sc, hd.acc, lambda dk: dk + lax.dot_general(
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+
+        _each_head(heads, first, head)
 
     @pl.when(step == nq - 1)
     def _finalize():
@@ -617,7 +792,7 @@ def _flash_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _flash_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                       delta_ref, dq_ref, dk_ref, dv_ref, dk_sc, dv_sc,
                       *dq_sc, sm_scale, causal, dropout_rate, window=None,
-                      q_tiles=None):
+                      q_tiles=None, heads=None):
     """dQ, dK and dV from one pass: `_flash_dkv_kernel` (grid (BH, kj, qi),
     q innermost) with one product more, this tile's share of dQ from the
     `ds` it has already formed. Without `dq_sc` a row is one K block and
@@ -630,11 +805,9 @@ def _flash_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     from jax.experimental import pallas as pl
 
     dq_sc = dq_sc[0] if dq_sc else None
-    bh = pl.program_id(0)
-    kj = pl.program_id(1)
-    step = pl.program_id(2)
-    nk = pl.num_programs(1)
-    nq = pl.num_programs(2)
+    first, kj, step, inner, outer = _grid_ids(heads)
+    nk = outer()
+    nq = inner()
     blk_q = q_ref.shape[1]
     blk_k = k_ref.shape[1]
     qi = step if window is None else _band_qi(kj, step, nq, blk_q, blk_k,
@@ -655,40 +828,43 @@ def _flash_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     @pl.when(live)
     def _update():
-        k = k_ref[0]                                   # [blk_k, D]
-        v = v_ref[0]
-        q = q_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            s = _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
-        w = jnp.exp(s - lse[:, None])                  # [blk_q, blk_k]
-        dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-        if dropout_rate:
-            keep = _dropout_mask(seed_ref, bh, qi, kj, (blk_q, blk_k),
-                                 dropout_rate)
-            w_drop = jnp.where(keep, w / (1.0 - dropout_rate), 0.0)
-            dw = jnp.where(keep, dpv / (1.0 - dropout_rate), 0.0)
-        else:
-            w_drop, dw = w, dpv
-        dv_sc[...] = dv_sc[...] + lax.dot_general(
-            w_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (w * (dw - delta[:, None]) * sm_scale).astype(q.dtype)
-        dk_sc[...] = dk_sc[...] + lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dq = lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        if dq_sc is None:
-            dq_ref[0] = dq.astype(dq_ref.dtype)
-        else:
-            rows = pl.ds(pl.multiple_of(qi * blk_q, blk_q), blk_q)
-            dq_sc[rows, :] = dq_sc[rows, :] + dq
+        def head(hd):
+            k = k_ref[hd.blk]                              # [blk_k, D]
+            v = v_ref[hd.blk]
+            q = _own(hd, q_ref[hd.blk])
+            do = _own(hd, do_ref[hd.blk])
+            lse = lse_ref[hd.row]
+            delta = _delta(hd, delta_ref, do)
+            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * sm_scale
+            if causal:
+                s = _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
+            w = jnp.exp(s - lse[:, None])                  # [blk_q, blk_k]
+            dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+            if dropout_rate:
+                keep = _dropout_mask(seed_ref, hd.bh, qi, kj, (blk_q, blk_k),
+                                     dropout_rate)
+                w_drop = jnp.where(keep, w / (1.0 - dropout_rate), 0.0)
+                dw = jnp.where(keep, dpv / (1.0 - dropout_rate), 0.0)
+            else:
+                w_drop, dw = w, dpv
+            _rmw(hd, dv_sc, hd.acc, lambda dv: dv + lax.dot_general(
+                w_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+            ds = (w * (dw - _col(delta)) * sm_scale).astype(q.dtype)
+            _rmw(hd, dk_sc, hd.acc, lambda dk: dk + lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+            dq = lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            if dq_sc is None:
+                _put(hd, dq_ref, dq.astype(dq_ref.dtype))
+            else:
+                rows = pl.ds(pl.multiple_of(qi * blk_q, blk_q), blk_q)
+                _rmw(hd, dq_sc, (rows, hd.lanes), lambda acc: acc + dq)
+
+        _each_head(heads, first, head)
 
     @pl.when(step == nq - 1)
     def _finalize():
@@ -705,7 +881,7 @@ def _seed_arr(seed):
     return jnp.asarray(seed, jnp.int32).reshape(1, 1)
 
 
-def _compiler_params(carried=1, vmem_bytes=None):
+def _compiler_params(carried=1, vmem_bytes=None, heads=None):
     """The last `carried` grid dims iterate sequentially (they carry
     scratch accumulators); the ones before are parallel. 0: the one-pass
     forward, a two-axis grid whose steps share nothing. 1: a three-axis
@@ -716,17 +892,17 @@ def _compiler_params(carried=1, vmem_bytes=None):
     needs itself, `vmem_bytes` (`_fused_bwd_vmem`): on this call alone, not
     through the executor's option for the whole step
     (core/executor.py::resolve_compiler_options, which a caller under plain
-    `jax.jit` does not have either)."""
+    `jax.jit` does not have either). A token-major call (`heads`) has one
+    parallel axis more in front, batch and head block apart, and always
+    says what its blocks of several heads need."""
     from jax.experimental.pallas import tpu as pltpu
-    if carried == 0:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"))
-    if carried == 2:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=vmem_bytes)
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+    semantics = {0: ("parallel", "parallel"),
+                 1: ("parallel", "parallel", "arbitrary"),
+                 2: ("parallel", "arbitrary", "arbitrary")}[carried]
+    if heads is not None:
+        semantics = ("parallel",) + semantics
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=vmem_bytes)
 
 
 def _window_of(window, T):
@@ -739,182 +915,351 @@ def _named(name, window):
     return name if window is None else "swa_" + name
 
 
+class _Rows(NamedTuple):
+    """Where a call's rows lie in HBM, for its block specs. Head-major
+    (`heads` None): `[B*H, T, D]`, grid axis 0 the (batch x head) row.
+    Token-major: `[B, T, H*D]`, grid axes 0 and 1 batch and head block, a
+    block `heads.step` heads wide at lane block `g[1]`. `Lse` and delta are
+    `[B*H, 1, T]` either way: a token-major block holds its heads' rows."""
+    B: int
+    H: int
+    heads: object
+
+    @property
+    def axes(self):
+        return 1 if self.heads is None else 2
+
+    @property
+    def grid(self):
+        if self.heads is None:
+            return (self.B * self.H,)
+        return (self.B, self.H // self.heads.step)
+
+    def of(self, x):
+        """`x` as the kernels read it: a free reshape either way."""
+        if self.heads is None:
+            return x.reshape(self.B * self.H, x.shape[2], x.shape[3])
+        return x.reshape(self.B, x.shape[1], self.H * x.shape[3])
+
+    def lanes(self, x3):
+        """Lanes of a block of `x3` (`of` an operand): its heads' widths."""
+        return x3.shape[2] // (1 if self.heads is None else self.grid[1])
+
+    def blk(self, g, tile):
+        return (g[0], tile, 0 if self.heads is None else g[1])
+
+    def row(self, g, tile):
+        if self.heads is None:
+            return (g[0], 0, tile)
+        return (g[0] * self.grid[1] + g[1], 0, tile)
+
+    def row_block(self, BQ):
+        return (1 if self.heads is None else self.heads.step, 1, BQ)
+
+
+def _token_major_heads(H, D, need):
+    """How a token-major call's blocks hold its H heads of D lanes. A group
+    is the fewest heads whose lanes are whole vregs (one head of 128 or
+    256 lanes, two of 64), or all of them where H * D has no such part (a
+    block is then the whole axis). A grid step computes as many groups as
+    divide H and whose blocks, scratch and score temporaries
+    `need(lanes)` are within the scoped VMEM every call asks for anyway, one
+    group where nothing is: fewer grid steps (a step costs ~0.2 us beyond
+    its work) and longer contiguous rows a DMA. Returns that and the scoped
+    VMEM the call asks for: its need, the floor at the least."""
+    lanes = math.lcm(D, _LANES)
+    if (H * D) % lanes:
+        lanes = H * D
+    group = lanes // D
+    options = [n for n in range(group, H + 1, group) if H % n == 0]
+    fits = [n for n in options if need(n * D) <= _SCOPED_VMEM_FLOOR_BYTES]
+    step = max(fits) if fits else options[0]
+    return (_Heads(H, step, D, lanes),
+            max(need(step * D), _SCOPED_VMEM_FLOOR_BYTES))
+
+
+def _fwd_vmem(lanes, heads, BQ, BK, itemsize, stream):
+    """Bytes of scoped VMEM a forward step of `heads` heads needs: q, o, k
+    and v blocks double-buffered, the streaming state, the score
+    temporaries of one head."""
+    blocks = 2 * itemsize * 2 * (BQ + BK) * lanes
+    state = 4 * BQ * (lanes + 2 * heads * _LANES) if stream else 0
+    return blocks + state + _SCORE_TILES * 4 * BQ * BK
+
+
+def _shape_of(q, token_major):
+    """(B, H, T, D) of a `[B, H, T, D]` or a `[B, T, H, D]` operand."""
+    if token_major:
+        B, T, H, D = q.shape
+        return B, H, T, D
+    return q.shape
+
+
+class _Plan(NamedTuple):
+    """What a call's shapes (and a test's overrides) decide before its
+    kernels are built: the tiles, the kernel ("onepass" | "stream" of the
+    forward, "fused" | "split" of the backward), how a token-major call's
+    blocks hold heads, the scoped VMEM it asks for, whether the kernels are
+    interpreted. Hashable: a token-major call's kernels are traced and
+    lowered once for each plan and each set of operand shapes and attributes
+    (`_token_major_forward`, `_token_major_backward`), not once a call."""
+    tiles: tuple
+    kernel: str
+    heads: object = None
+    vmem: object = None
+    interpret: bool = False
+
+
 def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0,
-                   window=None):
+                   window=None, token_major=False):
+    B, H, T, D = _shape_of(q, token_major)
+    window = _window_of(window, T)
+    BQ, BK = _blk(T, causal, window)
+    kernel = _fwd_plan(T, BK)
+    if not token_major:
+        return _forward(q, k, v, seed, causal, sm_scale, dropout_rate, window,
+                        _Plan((BQ, BK), kernel, interpret=_interpret()))
+
+    def need(lanes):
+        return _fwd_vmem(lanes, lanes // D, BQ, BK, q.dtype.itemsize,
+                         kernel == "stream")
+    heads, vmem = _token_major_heads(H, D, need)
+    return _token_major_forward(
+        q, k, v, jnp.asarray(seed, jnp.int32), causal, sm_scale,
+        dropout_rate, window, _Plan((BQ, BK), kernel, heads, vmem,
+                                    _interpret()))
+
+
+def _forward(q, k, v, seed, causal, sm_scale, dropout_rate, window, plan):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, T, D = q.shape
-    Dv = v.shape[-1]
-    window = _window_of(window, T)
-    BQ, BK = _blk(T, causal, window)
-    q3 = q.reshape(B * H, T, D)
-    k3 = k.reshape(B * H, T, D)
-    v3 = v.reshape(B * H, T, Dv)
+    (BQ, BK), heads, vmem = plan.tiles, plan.heads, plan.vmem
+    B, H, T, _ = _shape_of(q, heads is not None)
+    rows = _Rows(B, H, heads)
+    q3, k3, v3 = rows.of(q), rows.of(k), rows.of(v)
     attrs = dict(sm_scale=sm_scale, causal=causal, dropout_rate=dropout_rate)
     if window is not None:
         attrs["window"] = window
-    if _fwd_plan(T, BK) == "onepass":
+    if heads is not None:
+        attrs["heads"] = heads
+    if plan.kernel == "onepass":
         # its name holds `flash_fwd`: the benchmark's metrics of that name
         # read it as they read the streaming kernel
-        name, grid, carried = "flash_fwd_onepass", (B * H, T // BQ), 0
+        name, grid, carried = "flash_fwd_onepass", (T // BQ,), 0
         kernel = functools.partial(_flash_fwd_onepass_kernel, **attrs)
         scratch = []
     else:
         steps = T // BK if window is None else \
             _band_steps(T, BQ, BK, window)[0]
-        name, grid, carried = "flash_fwd", (B * H, T // BQ, steps), 1
+        name, grid, carried = "flash_fwd", (T // BQ, steps), 1
         kernel = functools.partial(_flash_fwd_kernel, **attrs)
-        scratch = [pltpu.VMEM((BQ, _LANES), jnp.float32),
-                   pltpu.VMEM((BQ, _LANES), jnp.float32),
-                   pltpu.VMEM((BQ, Dv), jnp.float32)]
+        stat = (BQ, _LANES) if heads is None else (heads.step, BQ, _LANES)
+        scratch = [pltpu.VMEM(stat, jnp.float32),
+                   pltpu.VMEM(stat, jnp.float32),
+                   pltpu.VMEM((BQ, rows.lanes(v3)), jnp.float32)]
+    ax = rows.axes
+
+    def at_q(*g):
+        return rows.blk(g, g[ax])
 
     # the one-pass grid has no kj axis: its one K block is block 0
-    def at_q(bh, qi, kj=0):
-        return (bh, qi, 0)
-
-    def at_k(bh, qi, kj=0):
-        if window is None or carried == 0:
-            return (bh, kj, 0)
+    def at_k(*g):
+        if carried == 0:
+            return rows.blk(g, 0)
+        if window is None:
+            return rows.blk(g, g[ax + 1])
         # the band's tile of this step; a spare step stays on the diagonal
         # tile, the one before it, so nothing is fetched for it
-        return (bh, jnp.minimum(_band_kj(qi, kj, BQ, BK, window),
-                                _last_k(qi, BQ, BK)), 0)
+        return rows.blk(g, jnp.minimum(
+            _band_kj(g[ax], g[ax + 1], BQ, BK, window),
+            _last_k(g[ax], BQ, BK)))
 
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=rows.grid + grid,
         in_specs=[
             pl.BlockSpec((1, 1), lambda *g: (0, 0)),
-            pl.BlockSpec((1, BQ, D), at_q),
-            pl.BlockSpec((1, BK, D), at_k),
-            pl.BlockSpec((1, BK, Dv), at_k),
+            pl.BlockSpec((1, BQ, rows.lanes(q3)), at_q),
+            pl.BlockSpec((1, BK, rows.lanes(k3)), at_k),
+            pl.BlockSpec((1, BK, rows.lanes(v3)), at_k),
         ],
         out_specs=[
-            pl.BlockSpec((1, BQ, Dv), at_q),
-            pl.BlockSpec((1, 1, BQ), lambda bh, qi, kj=0: (bh, 0, qi)),
+            pl.BlockSpec((1, BQ, rows.lanes(v3)), at_q),
+            pl.BlockSpec(rows.row_block(BQ), lambda *g: rows.row(g, g[ax])),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, Dv), q.dtype),
+            jax.ShapeDtypeStruct(v3.shape, q.dtype),
             jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32),
         ],
         scratch_shapes=scratch,
-        compiler_params=_compiler_params(carried),
-        interpret=_interpret(),
+        compiler_params=_compiler_params(carried, vmem, heads),
+        interpret=plan.interpret,
         name=_named(name, window),
     )(_seed_arr(seed), q3, k3, v3)
-    return out.reshape(B, H, T, Dv), lse
+    return out.reshape(v.shape), lse
+
+
+# A token-major call under a `jax.jit` of its own, the plan and the
+# attributes static: the 18 attention blocks of a transformer step hold two
+# forward and two backward kernels between them (causal or not), and jax
+# traces and lowers a jitted function once for each signature. The head-major
+# calls stay inline, the lowered text they always were.
+_token_major_forward = jax.jit(_forward, static_argnums=(4, 5, 6, 7, 8))
 
 
 def _flash_backward(q, k, v, o, lse, g, causal, sm_scale, dropout_rate, seed,
-                    window=None):
-    B, H, T, D = q.shape
+                    window=None, token_major=False):
+    B, H, T, D = _shape_of(q, token_major)
     Dv = v.shape[-1]
-    q3, k3 = (x.reshape(B * H, T, D) for x in (q, k))
-    v3, o3, g3 = (x.reshape(B * H, T, Dv) for x in (v, o, g))
-    delta = jnp.sum(g3.astype(jnp.float32) * o3.astype(jnp.float32),
-                    axis=-1)[:, None, :]
     window = _window_of(window, T)
     BQ, BK = _blk(T, causal, window)
-    run = (_flash_bwd_fused
-           if _bwd_plan(T, D, v.shape[-1], BQ, BK, q.dtype.itemsize)
-           == "fused" else _flash_bwd_split)
+    itemsize = q.dtype.itemsize
+    if not token_major:
+        return _backward(q, k, v, o, lse, g, seed, causal, sm_scale,
+                         dropout_rate, window, _Plan(
+                             (BQ, BK), _bwd_plan(T, D, Dv, BQ, BK, itemsize),
+                             interpret=_interpret()))
+
+    # a row of one K block keeps a q-block's dQ, not the row's; `Out`'s
+    # block comes in beside dOut's (`_delta`)
+    def need(lanes):
+        return _fused_bwd_vmem(T if T != BK else BQ, lanes, lanes, BQ, BK,
+                               itemsize) + 2 * itemsize * BQ * lanes
+    heads, vmem = _token_major_heads(H, D, need)
+    lanes = heads.step * D
+    return _token_major_backward(
+        q, k, v, o, lse, g, jnp.asarray(seed, jnp.int32), causal, sm_scale,
+        dropout_rate, window, _Plan(
+            (BQ, BK), _bwd_plan(T, lanes, lanes, BQ, BK, itemsize), heads,
+            vmem, _interpret()))
+
+
+def _backward(q, k, v, o, lse, g, seed, causal, sm_scale, dropout_rate,
+              window, plan):
+    heads = plan.heads
+    B, H, _, _ = _shape_of(q, heads is not None)
+    rows = _Rows(B, H, heads)
+    q3, k3 = (rows.of(x) for x in (q, k))
+    v3, o3, g3 = (rows.of(x) for x in (v, o, g))
+    if heads is None:
+        delta = jnp.sum(g3.astype(jnp.float32) * o3.astype(jnp.float32),
+                        axis=-1)[:, None, :]
+    else:
+        delta = o3      # the kernels sum a head's dOut * Out themselves
+    run = _flash_bwd_fused if plan.kernel == "fused" else _flash_bwd_split
     attrs = dict(sm_scale=sm_scale, causal=causal, dropout_rate=dropout_rate)
     if window is not None:
         attrs["window"] = window
-    grads = run((_seed_arr(seed), q3, k3, v3, g3, lse, delta), BQ, BK, attrs)
+    if heads is not None:
+        attrs["heads"] = heads
+    grads = run((_seed_arr(seed), q3, k3, v3, g3, lse, delta), rows, plan,
+                attrs)
     return tuple(d.reshape(x.shape) for d, x in zip(grads, (q, k, v)))
 
 
-def _bwd_specs(BQ, BK, D, Dv, q_axis, band=None):
-    """Block specs of (seed, q, k, v, dO, lse, delta) for a backward grid
-    (bh, ., .) whose q-block index is grid axis `q_axis` (1 or 2) and whose
-    k-block index is the other; and the index maps of a q and a k block.
-    q and k are `D` wide, v and dO `Dv`. `band` = (window, T) where the
-    inner axis (2) counts the tiles of a band: its spare steps stay on the
-    band's nearest tile, so nothing is fetched for them."""
+_token_major_backward = jax.jit(_backward, static_argnums=(7, 8, 9, 10, 11))
+
+
+def _bwd_specs(rows, BQ, BK, lanes, lanes_v, q_axis, band=None):
+    """Block specs of (seed, q, k, v, dO, lse, delta or Out) for a backward grid
+    (rows.., ., .) whose q-block index is the first (`q_axis` 1) or the
+    second (2) of the two tile axes and whose k-block index is the other;
+    and the index maps of a q and a k block. Blocks of q and k are `lanes`
+    wide, of v and dO `lanes_v`. `band` = (window, T) where the inner axis counts the tiles
+    of a band: its spare steps stay on the band's nearest tile, so nothing
+    is fetched for them."""
     from jax.experimental import pallas as pl
 
     if band is not None:
         window, T = band
         q_steps = _band_steps(T, BQ, BK, window)[1]
+    first = rows.axes - 1          # tile axis `a` is grid axis `first + a`
 
     def q_of(g):
+        outer, inner = g[first + 1], g[first + 2]
         if band is None or q_axis == 1:
-            return g[q_axis]
+            return g[first + q_axis]
         return jnp.maximum(
-            _band_qi(g[1], g[2], q_steps, BQ, BK, window, T // BQ),
-            _first_q(g[1], BQ, BK))
+            _band_qi(outer, inner, q_steps, BQ, BK, window, T // BQ),
+            _first_q(outer, BQ, BK))
 
     def k_of(g):
+        outer, inner = g[first + 1], g[first + 2]
         if band is None or q_axis == 2:
-            return g[3 - q_axis]
-        return jnp.minimum(_band_kj(g[1], g[2], BQ, BK, window),
-                           _last_k(g[1], BQ, BK))
+            return g[first + 3 - q_axis]
+        return jnp.minimum(_band_kj(outer, inner, BQ, BK, window),
+                           _last_k(outer, BQ, BK))
 
     def at_q(*g):
-        return (g[0], q_of(g), 0)
+        return rows.blk(g, q_of(g))
 
     def at_k(*g):
-        return (g[0], k_of(g), 0)
+        return rows.blk(g, k_of(g))
 
     def row_q(*g):
-        return (g[0], 0, q_of(g))
+        return rows.row(g, q_of(g))
 
     return [
         pl.BlockSpec((1, 1), lambda *g: (0, 0)),
-        pl.BlockSpec((1, BQ, D), at_q),
-        pl.BlockSpec((1, BK, D), at_k),
-        pl.BlockSpec((1, BK, Dv), at_k),
-        pl.BlockSpec((1, BQ, Dv), at_q),
-        pl.BlockSpec((1, 1, BQ), row_q),
-        pl.BlockSpec((1, 1, BQ), row_q),
+        pl.BlockSpec((1, BQ, lanes), at_q),
+        pl.BlockSpec((1, BK, lanes), at_k),
+        pl.BlockSpec((1, BK, lanes_v), at_k),
+        pl.BlockSpec((1, BQ, lanes_v), at_q),
+        pl.BlockSpec(rows.row_block(BQ), row_q),
+        # delta's rows; `Out`'s block of a token-major call (`_delta`)
+        pl.BlockSpec(rows.row_block(BQ), row_q) if rows.heads is None
+        else pl.BlockSpec((1, BQ, lanes_v), at_q),
     ], at_q, at_k
 
 
-def _flash_bwd_fused(args, BQ, BK, attrs):
+def _flash_bwd_fused(args, rows, plan, attrs):
     """One call for dQ, dK and dV. Its name holds both `flash_dq` and
     `flash_dkv`: the benchmark's metrics of those names each read it."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     q3, k3, v3 = args[1:4]
-    BH, T, D = q3.shape
-    Dv = v3.shape[2]
+    T = q3.shape[1]
+    (BQ, BK), heads = plan.tiles, plan.heads
+    lanes, lanes_v = rows.lanes(q3), rows.lanes(v3)
     window = attrs.get("window")
     band = None if window is None else (window, T)
-    in_specs, at_q, at_k = _bwd_specs(BQ, BK, D, Dv, q_axis=2, band=band)
+    in_specs, at_q, at_k = _bwd_specs(rows, BQ, BK, lanes, lanes_v, q_axis=2,
+                                      band=band)
     steps = T // BQ
     if window is not None:
         steps = _band_steps(T, BQ, BK, window)[1]
         attrs = dict(attrs, q_tiles=T // BQ)
-    scratch = [pltpu.VMEM((BK, D), jnp.float32),
-               pltpu.VMEM((BK, Dv), jnp.float32)]
+    scratch = [pltpu.VMEM((BK, lanes), jnp.float32),
+               pltpu.VMEM((BK, lanes_v), jnp.float32)]
     if T == BK:
-        dq_spec = pl.BlockSpec((1, BQ, D), at_q)
-        params = _compiler_params()
+        dq_spec = pl.BlockSpec((1, BQ, lanes), at_q)
+        params = _compiler_params(vmem_bytes=plan.vmem, heads=heads)
     else:
         # the row's dQ stays in VMEM over both inner grid axes
-        dq_spec = pl.BlockSpec((1, T, D), lambda bh, kj, qi: (bh, 0, 0))
-        scratch.append(pltpu.VMEM((T, D), jnp.float32))
-        params = _compiler_params(carried=2, vmem_bytes=_fused_bwd_vmem(
-            T, D, Dv, BQ, BK, q3.dtype.itemsize))
+        dq_spec = pl.BlockSpec((1, T, lanes), lambda *g: rows.blk(g, 0))
+        scratch.append(pltpu.VMEM((T, lanes), jnp.float32))
+        params = _compiler_params(
+            carried=2, heads=heads,
+            vmem_bytes=plan.vmem or _fused_bwd_vmem(
+                T, lanes, lanes_v, BQ, BK, q3.dtype.itemsize))
     return pl.pallas_call(
         functools.partial(_flash_bwd_kernel, **attrs),
-        grid=(BH, T // BK, steps),
+        grid=rows.grid + (T // BK, steps),
         in_specs=in_specs,
-        out_specs=[dq_spec, pl.BlockSpec((1, BK, D), at_k),
-                   pl.BlockSpec((1, BK, Dv), at_k)],
+        out_specs=[dq_spec, pl.BlockSpec((1, BK, lanes), at_k),
+                   pl.BlockSpec((1, BK, lanes_v), at_k)],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
                    for x in (q3, k3, v3)],
         scratch_shapes=scratch,
         compiler_params=params,
-        interpret=_interpret(),
+        interpret=plan.interpret,
         name=_named("flash_dq_flash_dkv", window),
     )(*args)
 
 
-def _flash_bwd_split(args, BQ, BK, attrs):
+def _flash_bwd_split(args, rows, plan, attrs):
     """dQ gridded over query blocks, then dK/dV over key blocks: each
     recomputes the score tiles. For rows whose dQ the fused kernel cannot
     keep resident, and the tests' oracle for the fused kernel."""
@@ -922,39 +1267,47 @@ def _flash_bwd_split(args, BQ, BK, attrs):
     from jax.experimental.pallas import tpu as pltpu
 
     q3, k3, v3 = args[1:4]
-    BH, T, D = q3.shape
-    Dv = v3.shape[2]
+    T = q3.shape[1]
+    (BQ, BK), heads = plan.tiles, plan.heads
+    lanes, lanes_v = rows.lanes(q3), rows.lanes(v3)
     window = attrs.get("window")
     band = None if window is None else (window, T)
     k_steps, q_steps = (T // BK, T // BQ) if window is None else \
         _band_steps(T, BQ, BK, window)
-    in_specs, at_q, _ = _bwd_specs(BQ, BK, D, Dv, q_axis=1, band=band)
+    # a token-major plan's VMEM counts a resident dQ row the pair does
+    # not keep: more than it needs, within the budget
+    params = _compiler_params(
+        vmem_bytes=plan.vmem and min(plan.vmem, _VMEM_BUDGET_BYTES),
+        heads=heads)
+    in_specs, at_q, _ = _bwd_specs(rows, BQ, BK, lanes, lanes_v, q_axis=1,
+                                   band=band)
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, **attrs),
-        grid=(BH, T // BQ, k_steps),
+        grid=rows.grid + (T // BQ, k_steps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, BQ, D), at_q),
-        out_shape=jax.ShapeDtypeStruct((BH, T, D), q3.dtype),
-        scratch_shapes=[pltpu.VMEM((BQ, D), jnp.float32)],
-        compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        out_specs=pl.BlockSpec((1, BQ, lanes), at_q),
+        out_shape=jax.ShapeDtypeStruct(q3.shape, q3.dtype),
+        scratch_shapes=[pltpu.VMEM((BQ, lanes), jnp.float32)],
+        compiler_params=params,
+        interpret=plan.interpret,
         name=_named("flash_dq", window),
     )(*args)
-    in_specs, _, at_k = _bwd_specs(BQ, BK, D, Dv, q_axis=2, band=band)
+    in_specs, _, at_k = _bwd_specs(rows, BQ, BK, lanes, lanes_v, q_axis=2,
+                                   band=band)
     if window is not None:
         attrs = dict(attrs, q_tiles=T // BQ)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, **attrs),
-        grid=(BH, T // BK, q_steps),
+        grid=rows.grid + (T // BK, q_steps),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, BK, D), at_k),
-                   pl.BlockSpec((1, BK, Dv), at_k)],
-        out_shape=[jax.ShapeDtypeStruct((BH, T, D), k3.dtype),
-                   jax.ShapeDtypeStruct((BH, T, Dv), v3.dtype)],
-        scratch_shapes=[pltpu.VMEM((BK, D), jnp.float32),
-                        pltpu.VMEM((BK, Dv), jnp.float32)],
-        compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        out_specs=[pl.BlockSpec((1, BK, lanes), at_k),
+                   pl.BlockSpec((1, BK, lanes_v), at_k)],
+        out_shape=[jax.ShapeDtypeStruct(k3.shape, k3.dtype),
+                   jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
+        scratch_shapes=[pltpu.VMEM((BK, lanes), jnp.float32),
+                        pltpu.VMEM((BK, lanes_v), jnp.float32)],
+        compiler_params=params,
+        interpret=plan.interpret,
         name=_named("flash_dkv", window),
     )(*args)
     return dq, dk, dv
@@ -975,21 +1328,28 @@ def _check_window(window, causal):
             f"own position), a whole number; got window = {window!r}")
 
 
-def _pallas_ok(q, dropout_rate=0.0, v=None, window=None):
+def _pallas_ok(q, dropout_rate=0.0, v=None, window=None, token_major=False):
     """Kernel or reference? The reference is a CPU-only path; on the TPU
     a shape outside the kernels' envelope raises instead of quietly
     materializing the [T, T] scores. `v` where its heads have a width of
     their own (`Dv`; the query's `D` otherwise). A `window` does not narrow
     the envelope (any W >= 1 runs, aligned to a tile or not); it is named
-    in the message so that a refused shape is not put down to it."""
-    B, H, T, D = q.shape
+    in the message so that a refused shape is not put down to it. A
+    token-major call's heads are lane ranges of one width, the values' as
+    the queries'."""
+    B, H, T, D = _shape_of(q, token_major)
     Dv = D if v is None else v.shape[-1]
     supported = T % 128 == 0 and D <= 256 and Dv <= 256
+    needs = "T % 128 == 0, D <= 256 and Dv <= 256"
+    if token_major:
+        supported = supported and Dv == D
+        needs += (", and of [batch, seq, heads, head_dim] operands value "
+                  "heads as wide as the query's (Dv == D)")
     if jax.default_backend() != "cpu":
         if not supported:
             raise ValueError(
                 f"flash attention on the {jax.default_backend()!r} backend "
-                f"needs T % 128 == 0, D <= 256 and Dv <= 256, got q shape "
+                f"needs {needs}, got q shape "
                 f"{q.shape} (query/key heads of D = {D}) and value heads of "
                 f"Dv = {Dv}"
                 + ("" if window is None else
@@ -1007,44 +1367,73 @@ def _pallas_ok(q, dropout_rate=0.0, v=None, window=None):
 # public entry: custom_vjp so program autodiff gets the Pallas backward
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _flash_out_lse(q, k, v, seed, causal, sm_scale, dropout_rate,
-                   window=None):
-    """The forward kernel's two results: `out` [B, H, T, Dv] and the rows'
+                   window=None, token_major=False):
+    """The forward kernel's two results: `out` in the operands' layout
+    ([B, H, T, Dv], or [B, T, H, Dv] of token-major ones) and the rows'
     log-sum-exp, float32 [B*H, 1, T] (the layout the backward kernels read;
     no gradient flows through it)."""
     return _flash_forward(q, k, v, causal, sm_scale, dropout_rate, seed,
-                          window)
+                          window, token_major)
 
 
-def _fol_fwd(q, k, v, seed, causal, sm_scale, dropout_rate, window=None):
+def _fol_fwd(q, k, v, seed, causal, sm_scale, dropout_rate, window=None,
+             token_major=False):
     out, lse = _flash_forward(q, k, v, causal, sm_scale, dropout_rate, seed,
-                              window)
+                              window, token_major)
     return (out, lse), (q, k, v, out, lse, seed)
 
 
-def _fol_bwd(causal, sm_scale, dropout_rate, window, res, g):
+def _fol_bwd(causal, sm_scale, dropout_rate, window, token_major, res, g):
     q, k, v, o, lse, seed = res
     dq, dk, dv = _flash_backward(q, k, v, o, lse, g[0], causal, sm_scale,
-                                 dropout_rate, seed, window)
+                                 dropout_rate, seed, window, token_major)
     return dq, dk, dv, np.zeros(jnp.shape(seed), jax.dtypes.float0)
 
 
 _flash_out_lse.defvjp(_fol_fwd, _fol_bwd)
 
 
+def _reference(q, k, v, causal, sm_scale, dropout_rate, seed, window,
+               token_major):
+    """`_attention_reference` on operands of either layout: token-major
+    ones are transposed to the reference's `[B, H, T, D]` and its result
+    back, so both layouts draw one mask."""
+    if not token_major:
+        return _attention_reference(q, k, v, causal, sm_scale, dropout_rate,
+                                    seed, window)
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    return _attention_reference(q, k, v, causal, sm_scale, dropout_rate,
+                                seed, window).transpose(0, 2, 1, 3)
+
+
 def flash_attention(q, k, v, seed, causal=False, sm_scale=1.0,
-                    dropout_rate=0.0, window=None):
+                    dropout_rate=0.0, window=None, token_major=False):
     """seed: int32 scalar (traced) driving attention-weight dropout. For
     direct callers (tools, tests): under `jax.grad` the forward kernel is
     the residual pass and the backward kernels follow. `window`: see the
-    module's docstring."""
+    module's docstring. `token_major`: the operands (and the result) are
+    `[B, T, H, D]`, not `[B, H, T, D]`."""
     _check_window(window, causal)
-    if _pallas_ok(q, dropout_rate, v, window):
+    if _pallas_ok(q, dropout_rate, v, window, token_major):
         return _flash_out_lse(q, k, v, seed, causal, sm_scale,
-                              dropout_rate, window)[0]
-    return _attention_reference(q, k, v, causal, sm_scale, dropout_rate, seed,
-                                window)
+                              dropout_rate, window, token_major)[0]
+    return _reference(q, k, v, causal, sm_scale, dropout_rate, seed, window,
+                      token_major)
+
+
+LAYOUTS = ("BHTD", "BTHD")
+
+
+def _token_major(ctx):
+    """Whether the op's operands are `[batch, seq, heads, head_dim]`
+    (`layout` "BTHD") and not `[batch, heads, seq, head_dim]` ("BHTD")."""
+    layout = ctx.attr("layout", "BHTD")
+    if layout not in LAYOUTS:
+        raise ValueError(f"fused_attention: layout {layout!r} is none of "
+                         f"{LAYOUTS}")
+    return layout == "BTHD"
 
 
 def _attrs(ctx, Q):
@@ -1071,7 +1460,7 @@ def _fused_attention_infer(ctx, structs):
     takes the reference path, which has no `Lse`, and the program it builds
     may run on one that has."""
     Q, V = structs["Q"][0], structs["V"][0]
-    B, H, T, _ = Q.shape
+    B, H, T, _ = _shape_of(Q, _token_major(ctx))
     return {"Out": jax.ShapeDtypeStruct(Q.shape[:3] + V.shape[3:], Q.dtype),
             "Lse": jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32)}
 
@@ -1081,18 +1470,24 @@ def _fused_attention_infer(ctx, structs):
 def _fused_attention(ctx, Q, K, V):
     """Q, K: [B, H, T, D]; V: [B, H, T, Dv], the value heads' own width
     (latent attention: 192 over 128), `Dv == D` in the plain case; Out is
-    [B, H, T, Dv]. attrs: causal, sm_scale, dropout_rate, is_test, and
-    `window` (causal only): key j is visible to query i iff 0 <= i - j <
-    window; the op tallies the score tiles its forward grid computes
+    [B, H, T, Dv]. Under `layout` "BTHD" all four are token-major, [B, T,
+    H, D]: a projection's output under a free reshape, which the kernels
+    read through their block specs (no transpose on either side). attrs:
+    causal, sm_scale, dropout_rate, is_test, layout, and `window` (causal
+    only): key j is visible to query i iff 0 <= i - j < window; the op
+    tallies the score tiles its forward grid computes
     (`window_tiles_computed` on the compile event).
 
     Replaces the reference's matmul+softmax+dropout+matmul composition
     (nets.py:329) with one O(T)-memory kernel. Dropout is applied to the
     attention weights inside the kernel, keyed from the executor's
     functional PRNG. On the kernel path the rule also returns `Lse`, the
-    forward kernel's log-sum-exp (float32 [B*H, 1, T]), which the grad op
-    reads back instead of running the forward kernel again."""
+    forward kernel's log-sum-exp (float32 [B*H, 1, T] in either layout),
+    which the grad op reads back instead of running the forward kernel
+    again."""
     sm_scale, causal, rate, window = _attrs(ctx, Q)
+    token_major = _token_major(ctx)
+    B, H, T, _ = _shape_of(Q, token_major)
     mesh = getattr(ctx.lowerer, "mesh", None) if ctx.lowerer else None
     if (mesh is not None and "sp" in mesh.axis_names
             and mesh.shape["sp"] > 1):
@@ -1110,9 +1505,9 @@ def _fused_attention(ctx, Q, K, V):
                 "attention-weight dropout is not supported under sequence "
                 "parallelism; build the model with dropout_rate=0 (or move "
                 "dropout outside the attention op)")
-        if Q.shape[2] % mesh.shape["sp"] != 0:
+        if T % mesh.shape["sp"] != 0:
             raise ValueError(
-                f"sequence length {Q.shape[2]} is not divisible by the "
+                f"sequence length {T} is not divisible by the "
                 f"{mesh.shape['sp']}-way 'sp' mesh axis; pad the sequence "
                 f"or choose an sp that divides it")
         if V.shape[-1] != Q.shape[-1]:
@@ -1120,25 +1515,28 @@ def _fused_attention(ctx, Q, K, V):
                 f"ring attention under the 'sp' mesh axis carries one head "
                 f"width; got query/key heads of {Q.shape[-1]} and value "
                 f"heads of {V.shape[-1]}")
-        return {"Out": ring_attention(Q, K, V, mesh, axis="sp",
-                                      causal=causal, sm_scale=sm_scale)}
+        if token_major:     # the ring is written over [B, H, T/sp, D] shards
+            Q, K, V = (x.transpose(0, 2, 1, 3) for x in (Q, K, V))
+        out = ring_attention(Q, K, V, mesh, axis="sp", causal=causal,
+                             sm_scale=sm_scale)
+        return {"Out": out.transpose(0, 2, 1, 3) if token_major else out}
     seed = _dropout_seed(ctx, rate)
     if window is not None and ctx.op is not None \
             and ctx.op.type == "fused_attention":   # not its grad op's trace
-        ctx.tally("window_tiles_computed", Q.shape[0] * Q.shape[1]
-                  * window_tiles(Q.shape[2], window))
-    if _pallas_ok(Q, rate, V, window):
+        ctx.tally("window_tiles_computed", B * H * window_tiles(T, window))
+    if _pallas_ok(Q, rate, V, window, token_major):
         out, lse = _flash_out_lse(Q, K, V, seed, causal, sm_scale, rate,
-                                  window)
+                                  window, token_major)
         return {"Out": out, "Lse": lse}
-    return {"Out": _attention_reference(Q, K, V, causal, sm_scale, rate,
-                                        seed, window)}
+    return {"Out": _reference(Q, K, V, causal, sm_scale, rate, seed, window,
+                              token_major)}
 
 
 @register_grad("fused_attention")
 def _fused_attention_grad(ctx, ins, out_grads):
     """dQ/dK/dV from the backward kernel alone, on the forward op's saved
-    `Out` and `Lse`. Which path runs is read off the environment: where the
+    `Out` and `Lse`, in the operands' layout. Which path runs is read off
+    the environment: where the
     forward op left no `Lse` (a program built without the slot, the ring
     path, the CPU reference path) the forward rule is traced again under
     `jax.vjp`, as the generic grad lowering does for every op without a
@@ -1163,7 +1561,8 @@ def _fused_attention_grad(ctx, ins, out_grads):
         sm_scale, causal, rate, window = _attrs(ctx, q)
         grads = _flash_backward(q, k, v, out, lse, g.astype(out.dtype),
                                 causal, sm_scale, rate,
-                                _dropout_seed(ctx, rate), window)
+                                _dropout_seed(ctx, rate), window,
+                                _token_major(ctx))
     return {s: d.astype(x.dtype) for s, d, x in zip(slots, grads, raw)}
 
 

@@ -41,11 +41,11 @@ def qkv_feed(names, shape=(2, 2, 256, 64), seed=5, dtype=np.float32):
 
 
 def attention_grads(feed, causal, amp, rate=0.0, after=None, strip_lse=False,
-                    place=None):
+                    place=None, layout="BHTD"):
     """A program of one `fused_attention` over data vars (one var in all
     three slots if the feed has only `q`), loss = sum(after(out) * probe):
     returns Out, the fetched input gradients by name, and the traced step's
-    text."""
+    text. `layout` is the op's: how the feed's arrays lie."""
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup), fluid.unique_name.guard():
         data = {n: layers.data(name=n, shape=list(x.shape),
@@ -55,7 +55,8 @@ def attention_grads(feed, causal, amp, rate=0.0, after=None, strip_lse=False,
         # an op in front, so that the attention op is not the block's op 0
         q = layers.scale(data["q"], scale=1.0)
         out = layers.fused_attention(q, data.get("k", q), data.get("v", q),
-                                     causal=causal, dropout_rate=rate)
+                                     causal=causal, dropout_rate=rate,
+                                     layout=layout)
         if strip_lse:
             del main.global_block().ops[-1].outputs["Lse"]
         probe = layers.data(name="probe", shape=list(feed["probe"].shape),
@@ -74,15 +75,48 @@ def attention_grads(feed, causal, amp, rate=0.0, after=None, strip_lse=False,
                                                               scope, feed)
 
 
+class _StepText(str):
+    """A step's jaxpr as text, the jaxpr itself beside it (`kernel_calls`
+    counts call sites on it: the text prints a jitted function's body once
+    however many equations call it)."""
+    jaxpr = None
+
+
 def step_text(exe, main, scope, feed):
     """The jaxpr of the executor's jitted step for `main`, as text: every
     `pallas_call` appears in it with its `name=`."""
     compiled, = [c for c in exe._cache.values() if c.program is main]
-    return str(compiled._step.trace(
+    closed = compiled._step.trace(
         feed, {n: scope.find_var(n) for n in compiled.mut_names},
         {n: scope.find_var(n) for n in compiled.const_names},
-        np.uint32(0)).jaxpr)
+        np.uint32(0)).jaxpr
+    text = _StepText(closed)
+    text.jaxpr = closed
+    return text
+
+
+def _pallas_calls(jaxpr, kernel):
+    """Equations of `jaxpr` that are a `pallas_call` named `kernel`, those
+    of the jaxprs its equations call counted once for each call."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    count = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params.get("name_and_src_info") or eqn.params.get(
+                "name")
+            count += getattr(name, "name", name) == kernel
+            continue
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (tuple, list))
+                        else (value,)):
+                if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                    count += _pallas_calls(sub, kernel)
+    return count
 
 
 def kernel_calls(text, kernel):
+    """Call sites of the `pallas_call` named `kernel` in a step
+    (`step_text`), or its occurrences in a jaxpr's plain text."""
+    if getattr(text, "jaxpr", None) is not None:
+        return _pallas_calls(text.jaxpr, kernel)
     return text.count(f"name={kernel}\n") + text.count(f"name={kernel} ")

@@ -833,3 +833,199 @@ def test_a_window_that_cannot_run_is_refused_by_name(case):
                                match="window.*paged attention"):
                 paged_attention._no_window(ctx)
             paged_attention._no_window(registry.LoweringContext({}))
+
+
+# -- operands as the projections leave them: `layout="BTHD"`, a head a range
+#    of lanes picked by the kernels' block specs, several heads a grid step --
+
+def _tile_keep(seed, bh, qi, kj, shape, rate):
+    """A keep-mask that is a function of (seed, b * H + h, q tile, k tile,
+    row, column) alone, in int32 arithmetic the interpreter has: stands in
+    for the TPU's PRNG (`_dropout_mask`), which it has not."""
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    x = ((seed * 1103515245 + bh) * 1664525 + qi) * 1013904223 + kj
+    x = (x * 69069 + row) * 1103515245 + col
+    x = x ^ (x >> 15)
+    x = x * 739981 + 12345
+    x = x ^ (x >> 13)
+    return ((x >> 4) & 0xFFFF) >= int(rate * 65536)
+
+
+def _reference_under_tile_masks(q, k, v, causal, scale, rate, seed, tiles):
+    """`_attention_reference`'s softmax on `[B, H, T, D]` with the keep-mask
+    `_tile_keep` gives every (b * H + h, q tile, k tile)."""
+    B, H, T, _ = q.shape
+    bq, bk = tiles
+    keep = jnp.stack([
+        jnp.block([[_tile_keep(seed, bh, qi, kj, (bq, bk), rate)
+                    for kj in range(T // bk)] for qi in range(T // bq)])
+        for bh in range(B * H)]).reshape(B, H, T, T)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    if causal:
+        s = jnp.where(jnp.arange(T)[None, :] > jnp.arange(T)[:, None],
+                      pallas_attention.NEG_INF, s)
+    p = jnp.where(keep, jax.nn.softmax(s, axis=-1) / (1.0 - rate), 0.0)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+@pytest.mark.parametrize("slots", [("q", "k", "v"), ("q",)],
+                         ids=["cross", "self"])
+@pytest.mark.parametrize("plan", ["onepass", "stream"])
+@pytest.mark.parametrize("H,D", [(8, 64), (4, 128)],
+                         ids=["8_heads_of_64", "4_heads_of_128"])
+@pytest.mark.parametrize("rate", [0.0, 0.1], ids=["no_dropout", "dropout"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_token_major_op_matches_the_reference_and_the_head_major_op(
+        interpret_kernels, monkeypatch, causal, rate, H, D, plan, slots):
+    """The `layout="BTHD"` op and its grad op, through a program, under the
+    interpreter: `Out` and dQ, dK, dV (their sum where one var feeds all
+    three slots) against the reference on the transposed operands and its
+    `jax.grad`, and against the head-major op on the same values. With
+    dropout both layouts run the kernels under a stand-in for the chip's
+    PRNG keyed as `_dropout_mask` is, by (b * H + h, q tile, k tile): the
+    token-major call draws the head-major call's masks, and the reference
+    is given the same ones. `Out` is bitwise the head-major op's at heads
+    of 128 lanes, a head being its own lane group, and to float rounding at
+    64, where a product contracts over the pair's 128 lanes with the other
+    head's zeroed (bitwise on the chip, tests/test_flash_grad_tpu.py: XLA's
+    CPU dot sums 128 terms in another order than 64); the gradients are to
+    float rounding either way."""
+    B, T = 1, 256
+    tiles = (256, 256) if plan == "onepass" else (128, 128)
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
+    assert pallas_attention._fwd_plan(T, tiles[1]) == plan
+    if rate:
+        monkeypatch.setattr(
+            pallas_attention, "_dropout_mask",
+            lambda seed_ref, *a: _tile_keep(seed_ref[0, 0], *a))
+        monkeypatch.setattr(pallas_attention, "_pallas_ok",
+                            lambda *a, **k: True)
+    feed = qkv_feed(slots, shape=(B, T, H, D))
+
+    def head_major(x):
+        return np.ascontiguousarray(np.transpose(x, (0, 2, 1, 3)))
+
+    out, grads, text = attention_grads(feed, causal, amp=False, rate=rate,
+                                       layout="BTHD")
+    out_h, grads_h, text_h = attention_grads(
+        {n: head_major(x) for n, x in feed.items()}, causal, amp=False,
+        rate=rate)
+    want_calls = ([1, 0] if plan == "onepass" else [0, 1]) + [1, 0, 0]
+    for t in (text, text_h):
+        assert [kernel_calls(t, k) for k in KERNELS] == want_calls
+    assert not any(eqn.primitive.name == "transpose"
+                   for eqn in text.jaxpr.jaxpr.eqns)
+
+    step_key = jax.random.fold_in(jax.random.key(7), np.uint32(0))
+    seed = jax.random.key_data(jax.random.fold_in(step_key, 1)).reshape(
+        -1)[0].astype(jnp.int32)              # the op is op 1 of its block
+    scale = D ** -0.5
+
+    def reference(*qkv):
+        q, k, v = (jnp.transpose(x, (0, 2, 1, 3))
+                   for x in (qkv if len(qkv) == 3 else qkv * 3))
+        if rate:
+            ref = _reference_under_tile_masks(q, k, v, causal, scale, rate,
+                                              seed, tiles)
+        else:
+            ref = _attention_reference(q, k, v, causal, scale)
+        return jnp.transpose(ref, (0, 2, 1, 3))
+
+    args = [jnp.asarray(feed[n]) for n in slots]
+    np.testing.assert_allclose(out, np.asarray(reference(*args)), atol=2e-5,
+                               rtol=2e-5)
+    if rate:
+        assert not np.allclose(out, np.asarray(_attention_reference(
+            *(jnp.transpose(x, (0, 2, 1, 3)) for x in (args * 3)[:3]),
+            causal, scale)).transpose(0, 2, 1, 3), atol=1e-2)
+    want = jax.grad(lambda *a: (reference(*a) * feed["probe"]).sum(),
+                    tuple(range(len(args))))(*args)
+    for n, w in zip(slots, want):
+        np.testing.assert_allclose(grads[n], np.asarray(w), atol=2e-4,
+                                   rtol=2e-4, err_msg=f"d{n}")
+        np.testing.assert_allclose(grads[n], grads_h[n].transpose(0, 2, 1, 3),
+                                   atol=2e-5, rtol=2e-5, err_msg=f"d{n}")
+    if D == 128:
+        np.testing.assert_array_equal(out, out_h.transpose(0, 2, 1, 3))
+    else:
+        np.testing.assert_allclose(out, out_h.transpose(0, 2, 1, 3),
+                                   atol=2e-6, rtol=2e-6)
+
+
+def _transposed_heads_attention(q_in, kv_in, d_model, num_heads,
+                                dropout_rate=0.0, causal=False, is_test=False,
+                                name="", fused=True):
+    """`models.transformer.multi_head_attention` as it was before the op
+    took `layout="BTHD"`: a head-split transpose on each operand, the
+    head-major op, a transpose back."""
+    d_head = d_model // num_heads
+
+    def project(x, tag):
+        return layers.fc(input=x, size=d_model, num_flatten_dims=2,
+                         bias_attr=False, name=name + tag)
+
+    def split_heads(x):
+        r = layers.reshape(x, shape=[0, 0, num_heads, d_head])
+        return layers.transpose(r, perm=[0, 2, 1, 3])
+
+    ctx = layers.fused_attention(
+        split_heads(project(q_in, "_q")), split_heads(project(kv_in, "_k")),
+        split_heads(project(kv_in, "_v")), causal=causal,
+        sm_scale=d_head ** -0.5, dropout_rate=dropout_rate, is_test=is_test)
+    merged = layers.reshape(layers.transpose(ctx, perm=[0, 2, 1, 3]),
+                            shape=[0, 0, d_model])
+    return project(merged, "_o")
+
+
+def test_transformer_program_has_no_transpose_around_its_attention_ops(
+        interpret_kernels, monkeypatch):
+    """The Program `models/transformer.py` builds with `fused_attention=True`
+    holds 18 `fused_attention` ops, token-major, none fed by or feeding a
+    `transpose` op (the reshapes on either side are free), and no
+    `transpose` op at all; its first loss at a tiny size, the kernels
+    interpreted, is the loss of the same model built with transposes around
+    a head-major op."""
+    def build(patched):
+        if patched:
+            monkeypatch.setattr(models.transformer, "multi_head_attention",
+                                _transposed_heads_attention)
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup), fluid.unique_name.guard():
+            _, fetches = models.transformer.build(
+                src_vocab_size=64, trg_vocab_size=64, seq_len=128, n_layer=6,
+                n_head=2, d_model=128, d_inner=64, dropout_rate=0.0)
+        main.random_seed = startup.random_seed = 7
+        return main, startup, fetches["loss"]
+
+    def first_loss(main, startup, loss):
+        scope = fluid.Scope()
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup, scope=scope)
+        rng = np.random.RandomState(0)
+        feed = {k: rng.randint(1, 64, (2, 128)).astype(np.int64)
+                for k in ("src_word", "trg_word", "lbl_word")}
+        value = exe.run(main, feed=feed, fetch_list=[loss], scope=scope)[0]
+        assert kernel_calls(step_text(exe, main, scope, feed),
+                            "flash_fwd_onepass") == 18
+        return float(np.asarray(value).reshape(-1)[0])
+
+    main, startup, loss = build(patched=False)
+    ops = main.global_block().ops
+    attention = [op for op in ops if op.type == "fused_attention"]
+    assert len(attention) == 18
+    assert all(op.attrs["layout"] == "BTHD" for op in attention)
+    assert not [op for op in ops if op.type.startswith("transpose")]
+    by_output = {n: op for op in ops for n in op.output_arg_names}
+    for op in attention:
+        assert {by_output[n].type for n in op.input_arg_names} == {"reshape"}
+        out = op.output("Out")[0]
+        assert {o.type for o in ops if out in o.input_arg_names} == {
+            "reshape"}
+    token_major = first_loss(main, startup, loss)
+    main_h, startup_h, loss_h = build(patched=True)
+    assert sum(op.type == "transpose"
+               for op in main_h.global_block().ops) == 18 * 4
+    assert np.isfinite(token_major)
+    np.testing.assert_allclose(token_major, first_loss(main_h, startup_h,
+                                                       loss_h), rtol=1e-6)

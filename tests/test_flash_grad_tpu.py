@@ -7,7 +7,10 @@ row has the blocks a test gives it; what only the chip can say is that the
 Mosaic kernels, the hardware PRNG's masks re-seeded per tile, and `Lse`
 written and read through 4, 8 or 16 q-blocks give the same bits both ways.
 And that the fused backward kernel (dQ, dK and dV from one pass over the
-score tiles) gives the bits of the split pair it replaced, masks and all."""
+score tiles) gives the bits of the split pair it replaced, masks and all.
+And that the kernels on `[batch, seq, heads, head_dim]` operands, a head a
+range of lanes and several heads a grid step, give what the head-major
+kernels give on the transposed operands: `Out`, `Lse` and dV bit for bit."""
 
 import numpy as np
 import pytest
@@ -168,3 +171,75 @@ def test_onepass_forward_is_bitwise_the_streaming_kernel(monkeypatch, shape,
         a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
         assert np.isfinite(a).all() and np.abs(a).max() > 0, name
         np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+TOKEN_MAJOR = [((96, 256, 8, 64), False), ((96, 256, 8, 64), True),
+               ((12, 2048, 8, 64), False), ((12, 2048, 8, 64), True),
+               ((2, 2048, 4, 128), True)]
+
+
+@pytest.mark.parametrize("shape,causal", TOKEN_MAJOR,
+                         ids=[f"{s[1]}x{s[2]}x{s[3]}_"
+                              f"{'causal' if c else 'full'}"
+                              for s, c in TOKEN_MAJOR])
+def test_token_major_kernels_give_what_the_head_major_ones_give(shape, causal):
+    """`[batch, seq, heads, head_dim]` operands at both transformer cells'
+    shapes (and a head of a whole vreg's lanes, two K blocks a row), dropout
+    0.1: `Out`, `Lse` and dV are the bits the head-major kernels give on the
+    transposed operands, dQ and dK theirs to a bf16 rounding or two (the
+    row sums of dOut * Out are summed in another place), so both draw the
+    mask of tile (b * H + h, qi, kj) and a head's lanes take nothing from
+    its neighbour's; and without dropout both agree with the reference."""
+    B, T, H, D = shape
+    if D == 128:
+        pallas_attention._BLOCK_OVERRIDE = (1024, 1024)
+    try:
+        rng = np.random.RandomState(T + causal)
+        q, k, v, g = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+                      for _ in range(4))
+
+        def run(token_major, rate):
+            def both(q, k, v, g):
+                out, lse = pallas_attention._flash_forward(
+                    q, k, v, causal, D ** -0.5, rate, 77,
+                    token_major=token_major)
+                return (out, lse) + pallas_attention._flash_backward(
+                    q, k, v, out, lse, g, causal, D ** -0.5, rate, 77,
+                    token_major=token_major)
+            args = (q, k, v, g) if token_major else tuple(
+                x.transpose(0, 2, 1, 3) for x in (q, k, v, g))
+            got = jax.jit(both).lower(*args).compile(
+                compiler_options=resolve_compiler_options("tpu"))(*args)
+            return [np.asarray(x if token_major or x.ndim == 3
+                               else x.transpose(0, 2, 1, 3), np.float32)
+                    for x in got]
+
+        names = ("Out", "Lse", "dQ", "dK", "dV")
+        dropped = run(True, 0.1)
+        for a, b, name in zip(dropped, run(False, 0.1), names):
+            assert np.isfinite(a).all() and np.abs(a).max() > 0, name
+            if name in ("dQ", "dK"):
+                # through delta = rowsum(dOut * Out): the token-major
+                # kernels sum it themselves over a head's lanes (`_delta`),
+                # XLA sums it for the head-major ones over [B*H, T, D]
+                np.testing.assert_allclose(a, b, rtol=2 ** -6,
+                                           atol=2 ** -9 * np.abs(b).max(),
+                                           err_msg=name)
+            else:
+                np.testing.assert_array_equal(a, b, err_msg=name)
+        plain = run(True, 0.0)
+        assert not np.array_equal(plain[0], dropped[0])
+
+        def reference(q, k, v):
+            return pallas_attention._reference(
+                q, k, v, causal, D ** -0.5, 0.0, 0, None, True)
+
+        f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+        want, vjp = jax.vjp(reference, *f32)
+        for a, b, name in zip([plain[0]] + plain[2:],
+                              (want,) + vjp(g.astype(jnp.float32)),
+                              ("Out", "dQ", "dK", "dV")):
+            np.testing.assert_allclose(a, np.asarray(b), atol=0.08,
+                                       rtol=0.05, err_msg=name)
+    finally:
+        pallas_attention._BLOCK_OVERRIDE = None

@@ -327,3 +327,43 @@ def test_flash_kernels_without_a_window_lower_to_the_text_they_did(
             q, k, v, o, lse, g, causal, scale, 0.0, 0)).lower(
                 q, q, v, v, arg((qs[0] * qs[1], 1, qs[2]), jnp.float32), v)
         assert _lowered_digest(bwd)[:32] == digest, plan
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("shape", [(96, 256, 8, 64), (12, 2048, 8, 64)],
+                         ids=["seq256", "seq2048"])
+def test_token_major_flash_kernels_compile_at_the_cells_shapes(
+        one_chip, monkeypatch, shape, causal):
+    """The one-pass forward and the fused backward as both transformer
+    cells call them since the op takes `[batch, seq, heads, head_dim]`:
+    bf16, eight heads of 64 lanes, dropout 0.1 (the PRNG in the kernel),
+    several heads a grid step out of a block of `[1, rows, heads * 64]`
+    whose lanes the index maps pick. One Mosaic custom call each, under the
+    names the benchmark's patterns know, its results in the operands' own
+    layout: no transpose and no copy of an operand's size in either
+    program."""
+    from paddle_tpu.ops import pallas_attention as pa
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    B, T, H, D = shape
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, lse = arg(shape), arg((B * H, 1, T), jnp.float32)
+    seed = arg((), jnp.int32)
+    assert pa._fwd_plan(T, pa._blk(T, causal)[1]) == "onepass"
+    fwd = jax.jit(lambda q, k, v, seed: pa._flash_forward(
+        q, k, v, causal, D ** -0.5, 0.1, seed, token_major=True)).lower(
+            q, q, q, seed).compile()
+    (call,) = _custom_calls(fwd, "flash_fwd_onepass")
+    assert f"(bf16[{B},{T},{H * D}]{{" in call and f"f32[{B * H},1,{T}]{{" in call
+    bwd = jax.jit(lambda q, k, v, o, lse, g, seed: pa._flash_backward(
+        q, k, v, o, lse, g, causal, D ** -0.5, 0.1, seed,
+        token_major=True)).lower(q, q, q, q, lse, q, seed).compile()
+    (call,) = _custom_calls(bwd, "flash_dq_flash_dkv")
+    assert call.split(" custom-call(")[0].count(
+        f"bf16[{B},{T},{H * D}]{{") == 3
+    for compiled in (fwd, bwd):
+        text = compiled.as_text()
+        assert " transpose(" not in text
+        assert not re.search(r" copy\(.*bf16\[", text)

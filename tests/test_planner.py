@@ -384,6 +384,26 @@ def test_cost_model_fused_attention_flops_match_unfused_chain():
     assert 0.85 <= ratio <= 1.15, f"fused/unfused flops ratio {ratio:.3f}"
 
 
+@pytest.mark.parametrize("layout,shape", [("BHTD", [2, 128, 16]),
+                                          ("BTHD", [128, 2, 16])])
+def test_cost_model_reads_the_keys_of_a_fused_attention_by_its_layout(
+        layout, shape):
+    """2 heads of 16 over 128 keys cost the same scores whichever way the
+    operands lie: the sequence axis is the second of a "BTHD" op's and the
+    third of a "BHTD" op's; so does the census' window test."""
+    from paddle_tpu.core import backward
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        q = layers.data(name="q", shape=shape, dtype="float32")
+        layers.fused_attention(q, q, q, causal=True, window=64, layout=layout)
+    cost = cost_model.estimate_cost(main, {"q": tuple([4] + shape)})
+    assert cost.by_type()["fused_attention"]["flops"] == (
+        (4.0 * 16 + 3.0) * 4 * 2 * 128 * 128)
+    census = backward.layer_census(main)
+    assert census["attention_window"] == 64
+    assert census["layer_kinds"]["window_attention"] == 1
+
+
 def test_shape_env_exposes_concrete_shapes():
     main, _, _, feed_shapes = _dryrun_transformer()
     env = cost_model.shape_env(main, feed_shapes)
