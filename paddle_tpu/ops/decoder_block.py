@@ -4,10 +4,41 @@ embedding (on a whole head or its first dims, rotate-half or interleaved
 pairs, plain frequencies or YaRN's), the silu-gated product, and a looped LM's exit gate.
 
 No reference analog (the reference predates all three); the equations are
-those of the public `olmoe` / `llama`-style model code. Each is plain jnp,
-so XLA fuses it into its neighbours; statistics and trigonometry run in
-float32 whatever dtype flows through (under AMP the residual stream is
-bf16), and the result returns in the input's dtype.
+those of the public `olmoe` / `llama`-style model code. The norms, the
+gated product and the exit gate are plain jnp, so XLA fuses them into their
+neighbours; statistics and trigonometry run in float32 whatever dtype flows
+through (under AMP the residual stream is bf16), and the result returns in
+the input's dtype.
+
+Rotary is the exception. As jnp, XLA writes the float32 halves of every
+head to HBM and reads them back (the swap of a head's halves is no lane
+rotation to it), five to fifteen times the traffic of one pass. Where
+`_rotary_plan` gives a kernel the op is one Pallas call each way:
+
+    rotary_fwd   grid (token block, head block), the heads innermost, so a
+                 block of the two float32 tables `[T, D]` is fetched once and
+                 serves every head; reads a block of X `[heads, tokens, D]`
+                 in its own dtype, `(x A) * cos + (x B) * sin` in float32 in
+                 VMEM, head by head, `Out` in X's dtype. No array of X's
+                 size in float32 reaches HBM.
+    rotary_bwd   the same kernel on dOut with the rotation transposed (its
+                 inverse): the registered grad `rotary_embedding_grad` reads
+                 dOut alone and saves nothing from the forward. Left to the
+                 generic vjp grad op, the Mosaic call of the forward would
+                 run again in every backward pass.
+
+`x A` is the head as the rotation lays it out and `x B` its partner
+`[-x2 | x1]`. "roll" (a whole head of whole lanes, rotate-half: Mellum2,
+Ouro, OLMoE at D = 128): A is the identity and `x B` the lanes turned half
+way round (`pltpu.roll`) with B's signs folded into the sin table. "dot"
+(interleaved pairs at R = D = 64: Kanana-2): A and B are `[64, 64]` matrices
+of 0 and +-1, `[evens | odds]` being a permutation; a product of bf16 rows
+with one +-1 a column under a float32 accumulator is exact (float32 rows at
+HIGHEST). The tables stay `rotary_tables` of `rotary_frequencies`, float32,
+YaRN's factor in both; one rounding, to X's dtype, at the end. Elsewhere (a
+rotary part inside a wider head: Qwen3-Next's 64 of 256; the CPU tests'
+heads of 8 and 16; a CPU backend unless the Pallas interpreter is asked for)
+the op is the jnp form `_rotary_xla` and the grad op its `jax.vjp`.
 """
 
 from __future__ import annotations
@@ -16,10 +47,13 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..core.registry import register_grad, register_op
+from .linear_attention import _backend_takes_kernels
 from .moe import map_used_rows
+from .pallas_attention import _interpret
 
 
 @register_op("rms_norm")
@@ -107,6 +141,154 @@ def rotary_tables(seq_len, inv_freq, factor=1.0):
     return jnp.cos(angles) * factor, jnp.sin(angles) * factor
 
 
+def _rotary_xla(X, R, interleaved, cos, sin):
+    """The op as plain jnp: what runs outside the kernel's envelope, and the
+    form the kernel is held to (`jax.vjp` of it is the grad there)."""
+    D = X.shape[-1]
+    x32 = X.astype(jnp.float32)
+    head = x32 if R == D else x32[..., :R]
+    if interleaved:
+        head = jnp.concatenate([head[..., 0::2], head[..., 1::2]], axis=-1)
+    x1, x2 = head[..., : R // 2], head[..., R // 2:]
+    out = head * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+    if R < D:
+        out = jnp.concatenate([out, x32[..., R:]], axis=-1)
+    return out.astype(X.dtype)
+
+
+_ROTARY_BLOCK = 1 << 20     # bytes of X a grid step takes
+
+
+def _rotary_plan(shape, dtype, R, interleaved):
+    """"roll": a whole head of whole lanes turns by a lane rotation (R = D,
+    a multiple of 128, rotate-half). "dot": the pairs are brought together
+    by a product with a 0 / +-1 matrix of the head's size (interleaved pairs
+    at R = D = 64, whose `[evens | odds]` layout is a permutation). Both are
+    one Pallas call each way. "xla": anything else (a rotary part inside a
+    wider head, the CPU tests' heads of 8 and 16, tokens that do not fill a
+    packed sublane tile of 16), which keeps `_rotary_xla` and its vjp. The
+    choice reads the operand's shape and dtype alone."""
+    if len(shape) < 3 or dtype not in (jnp.bfloat16, jnp.float32):
+        return "xla"
+    T, D = shape[-2], shape[-1]
+    if T % 16 or R != D:
+        return "xla"
+    if not interleaved and D % 128 == 0:
+        return "roll"
+    if interleaved and D == 64:
+        return "dot"
+    return "xla"
+
+
+def _rotary_kernel_runs(shape, dtype, R, interleaved):
+    return _rotary_plan(shape, dtype, R, interleaved) != "xla" \
+        and _backend_takes_kernels()
+
+
+def _rotary_blocks(N, T, D, itemsize):
+    """(heads, tokens) of a grid step's block of X `[N, T, D]`: at most 512
+    tokens of as many heads as `_ROTARY_BLOCK` bytes hold, so a step's two
+    table blocks serve every head of it (a chip probe at `bf16[32, 8192,
+    128]`, ten chained calls on the host's clock: 0.57 ms a call at one head
+    a block, 0.41 at two, 0.33 at four, 0.28-0.30 at eight and sixteen; 1024
+    and 2048 tokens read like 512; a block taken whole 0.281, in loop steps
+    of 64 rows 0.297, of 16 rows 0.411)."""
+    Tb = next(b for b in (512, 256, 128, 64, 32, 16) if T % b == 0)
+    most = max(_ROTARY_BLOCK // (Tb * D * itemsize), 1)
+    Hb = next(h for h in range(min(N, most), 0, -1) if N % h == 0)
+    return Hb, Tb
+
+
+def _pair_matrices(R, backward):
+    """(A, B), `[R, R]` of 0 and +-1, for interleaved pairs: `x @ A` is the
+    head laid out `[evens | odds]` and `x @ B` its partner `[-x2 | x1]`.
+    `backward`: the transposes, which take a cotangent back to x's layout."""
+    half = R // 2
+    j = np.arange(half)
+    swap = np.zeros((R, R), np.float32)
+    swap[j + half, j], swap[j, j + half] = -1.0, 1.0
+    lay = np.zeros((R, R), np.float32)
+    lay[2 * j, j] = lay[2 * j + 1, j + half] = 1.0
+    return (lay.T, swap.T @ lay.T) if backward else (lay, lay @ swap)
+
+
+def _rotary_kernel(x_ref, c_ref, s_ref, *more):
+    """One (token block, head block) step: `(x A) * c + (x B) * s` in
+    float32, head by head against one block of the tables, written in X's
+    dtype. Without matrices ("roll") A is the identity and `x B` the lanes
+    turned half way round, B's signs being in `s`."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    *matrices, o_ref = more
+    full = x_ref.dtype == jnp.float32
+
+    def dot(x, m_ref):      # exact: one +-1 a column, a float32 accumulator
+        return jnp.dot(x, m_ref[...], preferred_element_type=jnp.float32,
+                       precision=lax.Precision.HIGHEST if full else None)
+
+    c, s = c_ref[...], s_ref[...]
+    for h in range(x_ref.shape[0]):
+        x = x_ref[h]
+        if matrices:
+            head, partner = (dot(x, m_ref) for m_ref in matrices)
+        else:
+            head = x.astype(jnp.float32)
+            partner = pltpu.roll(head, x.shape[-1] // 2, 1)
+        o_ref[h] = (head * c + partner * s).astype(o_ref.dtype)
+
+
+def _rotary_call(X, cos, sin, interleaved, backward):
+    """`rotary_fwd` / `rotary_bwd`: X (or dOut) `[..., T, D]` as it arrives,
+    of a shape `_rotary_plan` gives a kernel, against the float32 tables
+    `[T, D]` -> the rotated (or the inversely rotated) array in X's dtype.
+    The token blocks lead the grid, so a block of the tables is fetched once
+    and serves every head."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    T, D = X.shape[-2:]
+    half = D // 2
+    if not interleaved:     # "roll": B's signs; its transpose is -B
+        sin = jnp.concatenate([-sin[:, :half], sin[:, half:]], axis=-1)
+        sin = -sin if backward else sin
+        matrices = ()
+    else:                   # "dot"
+        if backward:    # the tables in x's own layout
+            cos, sin = (jnp.repeat(t[:, :half], 2, axis=-1)
+                        for t in (cos, sin))
+        matrices = tuple(jnp.asarray(m, X.dtype)
+                         for m in _pair_matrices(D, backward))
+    x = X.reshape((-1, T, D))
+    N = x.shape[0]
+    Hb, Tb = _rotary_blocks(N, T, D, X.dtype.itemsize)
+    x_spec = pl.BlockSpec((Hb, Tb, D), lambda t, n: (n, t, 0))
+    table = pl.BlockSpec((Tb, D), lambda t, n: (t, 0))
+    whole = pl.BlockSpec((D, D), lambda t, n: (0, 0))
+    out = pl.pallas_call(
+        _rotary_kernel, name="rotary_bwd" if backward else "rotary_fwd",
+        grid=(T // Tb, N // Hb),
+        in_specs=[x_spec, table, table] + [whole] * len(matrices),
+        out_specs=x_spec, out_shape=jax.ShapeDtypeStruct(x.shape, X.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=_interpret(),
+    )(x, cos, sin, *matrices)
+    return out.reshape(X.shape)
+
+
+def _rotary_attrs(ctx, X):
+    """(R, interleaved, cos, sin) of the op on X: the rotary size, checked,
+    and the float32 tables `[T, R]`."""
+    T, D = X.shape[-2], X.shape[-1]
+    R = int(ctx.attr("rotary_dim") or D)
+    if R % 2 or R > D:
+        raise ValueError(f"rotary_embedding needs an even rotary size within "
+                         f"the head, got {R} of {D}")
+    cos, sin = rotary_tables(T, *rotary_frequencies(
+        R, float(ctx.attr("theta", 10000.0)), ctx.attr("scaling")))
+    return R, bool(ctx.attr("interleaved", False)), cos, sin
+
+
 @register_op("rotary_embedding", propagate_seqlen=False)
 def _rotary_embedding(ctx, X):
     """X `[..., T, D]` (heads already split): position t rotates the pair
@@ -117,23 +299,33 @@ def _rotary_embedding(ctx, X):
     `rope_interleave`): the R dims are first laid out `[evens | odds]`, as
     the public code does, and stay so in the output (queries and keys alike,
     so their products agree). With the attribute `scaling` (a YaRN block)
-    the frequencies and the tables' factor are `rotary_frequencies`'."""
-    T, D = X.shape[-2], X.shape[-1]
-    R = int(ctx.attr("rotary_dim") or D)
-    if R % 2 or R > D:
-        raise ValueError(f"rotary_embedding needs an even rotary size within "
-                         f"the head, got {R} of {D}")
-    cos, sin = rotary_tables(T, *rotary_frequencies(
-        R, float(ctx.attr("theta", 10000.0)), ctx.attr("scaling")))
-    x32 = X.astype(jnp.float32)
-    head = x32 if R == D else x32[..., :R]
-    if ctx.attr("interleaved", False):
-        head = jnp.concatenate([head[..., 0::2], head[..., 1::2]], axis=-1)
-    x1, x2 = head[..., : R // 2], head[..., R // 2:]
-    out = head * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
-    if R < D:
-        out = jnp.concatenate([out, x32[..., R:]], axis=-1)
-    return {"Out": out.astype(X.dtype)}
+    the frequencies and the tables' factor are `rotary_frequencies`'. One
+    pass over X as `rotary_fwd` where `_rotary_plan` gives a kernel."""
+    R, interleaved, cos, sin = _rotary_attrs(ctx, X)
+    if _rotary_kernel_runs(X.shape, X.dtype, R, interleaved):
+        ctx.tally("rotary_kernel_ops")
+        return {"Out": _rotary_call(X, cos, sin, interleaved, False)}
+    return {"Out": _rotary_xla(X, R, interleaved, cos, sin)}
+
+
+@register_grad("rotary_embedding")
+def _rotary_embedding_grad(ctx, ins, out_grads):
+    """dX from dOut alone: a rotation's transpose is its inverse, so the
+    grad is the same pass with sin negated (`g * cos - rot(g) * sin`, the
+    `[evens | odds]` layout undone) as `rotary_bwd`; outside the envelope
+    `jax.vjp` of the jnp form, as the generic grad lowering would. Of X it
+    reads the shape and the dtype."""
+    d_out = out_grads["Out"][0]
+    if d_out is None:
+        return {}
+    X = ins["X"][0]
+    d_out = d_out.astype(X.dtype)
+    R, interleaved, cos, sin = _rotary_attrs(ctx, X)
+    if _rotary_kernel_runs(X.shape, X.dtype, R, interleaved):
+        ctx.tally("rotary_kernel_ops")
+        return {"X": _rotary_call(d_out, cos, sin, interleaved, True)}
+    _, vjp = jax.vjp(lambda x: _rotary_xla(x, R, interleaved, cos, sin), X)
+    return {"X": vjp(d_out)[0]}
 
 
 def _silu_product(gate, up):

@@ -95,6 +95,34 @@ def test_causal_conv_kernels_compile_at_the_cells_shape(one_chip,
         assert f"= ({short}[1,4096,8192]{{" in call and ", f32[4,8192]{" in call
 
 
+@pytest.mark.parametrize("shape,interleaved,plan", [
+    ((1, 32, 8192, 128), False, "roll"), ((1, 4, 8192, 128), False, "roll"),
+    ((1, 32, 4096, 64), True, "dot")],
+    ids=["mellum2_q", "mellum2_k", "kanana2_q"])
+def test_rotary_kernels_compile_at_the_cells_shapes(one_chip, monkeypatch,
+                                                    shape, interleaved, plan):
+    """`rotary_fwd` and `rotary_bwd` as `mellum2_12b_a2_5b.s8192` and
+    `kanana_2_30b_a3b.bs1` call them: bf16 q and k against float32 tables;
+    the lane rotation of a whole head, the 64-wide blocks and their products
+    with a `[64, 64]` matrix, and the room a block of eight heads takes are
+    what the interpreter cannot refuse. One Mosaic custom call each, named
+    for the benchmark's pattern, X's shape and dtype out."""
+    from paddle_tpu.ops import decoder_block as db
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    T, D = shape[-2:]
+    assert db._rotary_plan(shape, jnp.dtype(jnp.bfloat16), D,
+                           interleaved) == plan
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((T, D), jnp.float32, sharding=one_chip)
+    for backward, name in ((False, "rotary_fwd"), (True, "rotary_bwd")):
+        compiled = jax.jit(lambda x, cos, sin: db._rotary_call(
+            x, cos, sin, interleaved, backward)).lower(
+                x, table, table).compile()
+        (call,) = _custom_calls(compiled, name)
+        assert f"= bf16[{shape[1]},{T},{D}]{{" in call
+        assert "tpu_custom_call" in call
+
+
 def test_share_movements_compile_at_the_cells_shapes(one_chip):
     """A share's layout and a token-side movement as `qwen3_next_80b_a3b.bs1`
     runs them (4096 tokens, top 10 of 512, experts 64..95 held, 2048 wide):
