@@ -4,16 +4,48 @@ embedding (on a whole head or its first dims, rotate-half or interleaved
 pairs, plain frequencies or YaRN's), the silu-gated product, and a looped LM's exit gate.
 
 No reference analog (the reference predates all three); the equations are
-those of the public `olmoe` / `llama`-style model code. The norms, the
+those of the public `olmoe` / `llama`-style model code. The plain norms, the
 gated product and the exit gate are plain jnp, so XLA fuses them into their
 neighbours; statistics and trigonometry run in float32 whatever dtype flows
 through (under AMP the residual stream is bf16), and the result returns in
 the input's dtype.
 
-Rotary is the exception. As jnp, XLA writes the float32 halves of every
-head to HBM and reads them back (the swap of a head's halves is no lane
-rotation to it), five to fifteen times the traffic of one pass. Where
-`_rotary_plan` gives a kernel the op is one Pallas call each way:
+The gated norm and rotary are the exceptions: each stands between Pallas
+calls, which take row-major operands, and as jnp each has float32
+intermediates of its operand's size that XLA lays out for its own
+reductions, writes to HBM and copies back.
+
+The gated norm (`gated_rms_norm`, on `gdn_fwd`'s output and the gate `z`,
+in front of the output projection) is one Pallas call each way where
+`_gated_norm_plan` gives the kernels (a head of whole 128-lane tiles,
+tokens in whole 16-row tiles, bf16 or float32):
+
+    gated_norm_fwd   grid (batch, token block, head block); reads a block of
+                     X and of Gate `[tokens, heads * D]` in their dtypes (the
+                     bytes of `[B, T, H, D]`, as `gdn_fwd` writes them) and
+                     Scale `[1, D]` float32; head by head, over the head's
+                     own lanes, `r = rsqrt(mean(x^2) + eps)`, `x r` rounded
+                     to X's dtype, `w * that * silu(gate)` in float32; Y in
+                     X's dtype. Saves nothing.
+    gated_norm_bwd   the same blocks of X, Gate and dY; makes r, `n = x r`
+                     and silu(gate) again; `dn = dy w silu(g)`, `dx =
+                     r (dn - n mean(dn n))`, `dg = dy w normed silu'(g)` in
+                     their operands' dtypes, and a grid step's part of
+                     dScale `sum dy normed silu(g)` as `[8, D]` float32
+                     partial sums, which one small XLA sum finishes. The
+                     registered grad `gated_rms_norm_grad` runs it alone.
+
+Nothing float32 of X's size reaches HBM either way, and dX is what
+`gdn_bwd` reads as dO. The normed value's rounding is passed straight
+through by the backward (`astype`'s vjp would round its cotangent too; the
+kernel keeps float32). Elsewhere (the CPU tests' heads of 6 and 8, 24
+tokens; a CPU backend unless the Pallas interpreter is asked for) the op is
+the jnp form `_gated_norm_xla` and the grad op its `jax.vjp`.
+
+Rotary: as jnp, XLA writes the float32 halves of every head to HBM and
+reads them back (the swap of a head's halves is no lane rotation to it),
+five to fifteen times the traffic of one pass. Where `_rotary_plan` gives a
+kernel the op is one Pallas call each way:
 
     rotary_fwd   grid (token block, head block), the heads innermost, so a
                  block of the two float32 tables `[T, D]` is fetched once and
@@ -43,6 +75,7 @@ the op is the jnp form `_rotary_xla` and the grad op its `jax.vjp`.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -71,18 +104,182 @@ def _rms_norm(ctx, X, Scale):
     return {"Y": y.astype(X.dtype)}
 
 
+def _gated_norm_xla(X, Gate, Scale, eps):
+    """The op as plain jnp: what runs outside the kernels' envelope, and the
+    form the kernels are held to (`jax.vjp` of it is the grad there)."""
+    x32 = X.astype(jnp.float32)
+    ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    normed = (x32 * lax.rsqrt(ms + eps)).astype(X.dtype)
+    y = Scale.astype(jnp.float32) * normed.astype(jnp.float32)
+    return (y * jax.nn.silu(Gate.astype(jnp.float32))).astype(X.dtype)
+
+
+_BLOCK_BYTES = 1 << 20      # bytes of X a grid step of these kernels takes
+
+
+def _heads_a_block(N, head_bytes):
+    """The most of N heads, a divisor of N, that `_BLOCK_BYTES` hold."""
+    most = max(_BLOCK_BYTES // head_bytes, 1)
+    return next(h for h in range(min(N, most), 0, -1) if N % h == 0)
+
+
+def _gated_norm_plan(shape, dtype):
+    """"kernel": `[..., tokens, heads, dim]` whose head is whole lanes (dim a
+    multiple of 128: the published 128) and whose tokens fill packed sublane
+    tiles of 16, in bf16 or float32: one Pallas call each way. "xla":
+    anything else (the CPU tests' widths of 6 and 8, 24 tokens), which keeps
+    `_gated_norm_xla` and its vjp. The choice reads the operand's shape and
+    dtype alone."""
+    if len(shape) < 3 or dtype not in (jnp.bfloat16, jnp.float32):
+        return "xla"
+    T, D = shape[-3], shape[-1]
+    return "kernel" if T % 16 == 0 and D % 128 == 0 else "xla"
+
+
+def _gated_norm_kernels_run(shape, dtype):
+    return _gated_norm_plan(shape, dtype) == "kernel" \
+        and _backend_takes_kernels()
+
+
+def _gated_norm_blocks(T, H, D, itemsize, backward):
+    """(tokens, heads) of a grid step's block of X `[B, T, H * D]`: at most
+    128 tokens (256 backward) of as many whole heads as `_BLOCK_BYTES`
+    hold; a head's tile `[tokens, D]` is worked on whole. A chip probe at
+    `bf16[1, 4096, 32, 128]`, twenty chained calls on the host's clock, ms a
+    call forward / backward: (128, 32) 0.149 / 0.282, (256, 16) 0.166 /
+    0.259, (256, 8) 0.203 / 0.312 (whole rows of X are the longest
+    transfers; the backward's longer dependency chains want the taller
+    tile); the same blocks in loop steps of 32 rows 0.154 / 0.41, of 64
+    0.149 / 0.34; the row means as products with a `[D, D]` matrix of 1 / D
+    on the idle MXU 0.18-0.20 / 0.34-0.43."""
+    most = 256 if backward else 128
+    Tb = next(b for b in (256, 128, 64, 32, 16) if b <= most and T % b == 0)
+    return Tb, _heads_a_block(H, Tb * D * itemsize)
+
+
+def _gated_norm_tile(x, g, eps):
+    """A head's rows `[tokens, D]` as they arrive -> float32: each row's
+    factor r, x r, that value as the op rounds it (to X's dtype), the gate
+    and its sigmoid."""
+    x32 = x.astype(jnp.float32)
+    r = lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    n = x32 * r
+    g32 = g.astype(jnp.float32)
+    return r, n, n.astype(x.dtype).astype(jnp.float32), g32, \
+        jax.nn.sigmoid(g32)
+
+
+def _gated_norm_fwd_kernel(x_ref, g_ref, w_ref, y_ref, *, D, eps):
+    """One (batch, token block, head block) step, head by head: the rule's
+    arithmetic in float32 on a `[tokens, D]` tile, its one rounding, Y in
+    X's dtype."""
+    w = w_ref[...]
+    for h in range(x_ref.shape[2] // D):
+        lanes = slice(h * D, (h + 1) * D)
+        _, _, normed, g, s = _gated_norm_tile(x_ref[0, :, lanes],
+                                              g_ref[0, :, lanes], eps)
+        y_ref[0, :, lanes] = ((w * normed) * (g * s)).astype(y_ref.dtype)
+
+
+def _gated_norm_bwd_kernel(x_ref, g_ref, dy_ref, w_ref, dx_ref, dg_ref,
+                           dw_ref, *, D, eps):
+    """The same step on dY: r, x r and silu(gate) made again; with
+    `dn = dy w silu(g)`: `dx = r (dn - n mean(dn n))`, `dg = dy w normed
+    silu'(g)`, and the block's part of dScale, `sum dy normed silu(g)`, as
+    eight sublanes of partial sums."""
+    w = w_ref[...]
+    acc = jnp.zeros((8, D), jnp.float32)
+    for h in range(x_ref.shape[2] // D):
+        lanes = slice(h * D, (h + 1) * D)
+        r, n, normed, g, s = _gated_norm_tile(x_ref[0, :, lanes],
+                                              g_ref[0, :, lanes], eps)
+        dy = dy_ref[0, :, lanes].astype(jnp.float32)
+        silu = g * s
+        dyw = dy * w
+        dn = dyw * silu
+        dx = r * (dn - n * jnp.mean(dn * n, axis=-1, keepdims=True))
+        dx_ref[0, :, lanes] = dx.astype(dx_ref.dtype)
+        dg = dyw * normed * (s * (1.0 + g * (1.0 - s)))
+        dg_ref[0, :, lanes] = dg.astype(dg_ref.dtype)
+        p = dy * normed * silu
+        acc = acc + sum(p[i:i + 8] for i in range(0, p.shape[0], 8))
+    dw_ref[0, 0, 0] = acc
+
+
+def _gated_norm_call(X, Gate, Scale, eps, d_y=None):
+    """`gated_norm_fwd` (Y), or with `d_y` `gated_norm_bwd` (dX, dGate in
+    their dtypes, dScale float32): X, Gate and dY `[..., T, H, D]` as they
+    arrive, read as `[B, T, H * D]` (the same bytes), a head's lanes chosen
+    inside a block. A grid step's part of dScale is a block `[8, D]` of its
+    own (`[*grid, 8, D]` in all), and one small XLA sum over the steps
+    finishes it: the same order every run."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    T, H, D = X.shape[-3:]
+    flat = (-1, T, H * D)
+    x, gate = X.reshape(flat), Gate.reshape(flat)
+    Tb, Hb = _gated_norm_blocks(T, H, D, X.dtype.itemsize, d_y is not None)
+    grid = (x.shape[0], T // Tb, H // Hb)
+    block = pl.BlockSpec((1, Tb, Hb * D), lambda b, t, h: (b, t, h))
+    weight = pl.BlockSpec((1, D), lambda b, t, h: (0, 0))
+    w = Scale.astype(jnp.float32).reshape(1, D)
+    params = dict(
+        grid=grid,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
+        interpret=_interpret())
+    if d_y is None:
+        return pl.pallas_call(
+            functools.partial(_gated_norm_fwd_kernel, D=D, eps=eps),
+            name="gated_norm_fwd", in_specs=[block, block, weight],
+            out_specs=block, out_shape=jax.ShapeDtypeStruct(x.shape, X.dtype),
+            **params)(x, gate, w).reshape(X.shape)
+    dX, dGate, dW = pl.pallas_call(
+        functools.partial(_gated_norm_bwd_kernel, D=D, eps=eps),
+        name="gated_norm_bwd", in_specs=[block, block, block, weight],
+        out_specs=[block, block, pl.BlockSpec(
+            (1, 1, 1, 8, D), lambda b, t, h: (b, t, h, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, X.dtype),
+                   jax.ShapeDtypeStruct(x.shape, Gate.dtype),
+                   jax.ShapeDtypeStruct(grid + (8, D), jnp.float32)],
+        **params)(x, gate, d_y.reshape(flat), w)
+    return dX.reshape(X.shape), dGate.reshape(Gate.shape), \
+        dW.sum(range(4))
+
+
 @register_op("gated_rms_norm")
 def _gated_rms_norm(ctx, X, Gate, Scale):
     """`x * rsqrt(mean(x^2) + eps) * w * silu(gate)` over the last axis (a
     head): the output norm of a gated-delta-rule layer. The normed value is
     rounded to the input's dtype before the gate multiplies it in float32,
-    as the public `qwen3_next` code does."""
+    as the public `qwen3_next` code does. One pass over X and Gate as
+    `gated_norm_fwd` where `_gated_norm_plan` gives the kernels."""
     eps = ctx.attr("epsilon", 1e-6)
-    x32 = X.astype(jnp.float32)
-    ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    normed = (x32 * lax.rsqrt(ms + eps)).astype(X.dtype)
-    y = Scale.astype(jnp.float32) * normed.astype(jnp.float32)
-    return {"Y": (y * jax.nn.silu(Gate.astype(jnp.float32))).astype(X.dtype)}
+    if _gated_norm_kernels_run(X.shape, X.dtype):
+        return {"Y": _gated_norm_call(X, Gate, Scale, eps)}
+    return {"Y": _gated_norm_xla(X, Gate, Scale, eps)}
+
+
+@register_grad("gated_rms_norm")
+def _gated_rms_norm_grad(ctx, ins, out_grads):
+    """dX, dGate and dScale from X, Gate, Scale and dY alone (the forward
+    saves nothing): one pass as `gated_norm_bwd`, which makes r, x r and
+    silu(gate) again in VMEM; outside the envelope `jax.vjp` of the jnp
+    form, as the generic grad lowering would."""
+    d_y = out_grads["Y"][0]
+    if d_y is None:
+        return {}
+    X, Gate, Scale = (ins[s][0] for s in ("X", "Gate", "Scale"))
+    d_y = d_y.astype(X.dtype)
+    eps = ctx.attr("epsilon", 1e-6)
+    if _gated_norm_kernels_run(X.shape, X.dtype):
+        dX, dGate, dScale = _gated_norm_call(X, Gate, Scale, eps, d_y)
+    else:
+        _, vjp = jax.vjp(functools.partial(_gated_norm_xla, eps=eps),
+                         X, Gate, Scale)
+        dX, dGate, dScale = vjp(d_y)
+    return {"X": dX, "Gate": dGate, "Scale": dScale.astype(Scale.dtype)}
 
 
 YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast",
@@ -156,9 +353,6 @@ def _rotary_xla(X, R, interleaved, cos, sin):
     return out.astype(X.dtype)
 
 
-_ROTARY_BLOCK = 1 << 20     # bytes of X a grid step takes
-
-
 def _rotary_plan(shape, dtype, R, interleaved):
     """"roll": a whole head of whole lanes turns by a lane rotation (R = D,
     a multiple of 128, rotate-half). "dot": the pairs are brought together
@@ -187,16 +381,14 @@ def _rotary_kernel_runs(shape, dtype, R, interleaved):
 
 def _rotary_blocks(N, T, D, itemsize):
     """(heads, tokens) of a grid step's block of X `[N, T, D]`: at most 512
-    tokens of as many heads as `_ROTARY_BLOCK` bytes hold, so a step's two
+    tokens of as many heads as `_BLOCK_BYTES` hold, so a step's two
     table blocks serve every head of it (a chip probe at `bf16[32, 8192,
     128]`, ten chained calls on the host's clock: 0.57 ms a call at one head
     a block, 0.41 at two, 0.33 at four, 0.28-0.30 at eight and sixteen; 1024
     and 2048 tokens read like 512; a block taken whole 0.281, in loop steps
     of 64 rows 0.297, of 16 rows 0.411)."""
     Tb = next(b for b in (512, 256, 128, 64, 32, 16) if T % b == 0)
-    most = max(_ROTARY_BLOCK // (Tb * D * itemsize), 1)
-    Hb = next(h for h in range(min(N, most), 0, -1) if N % h == 0)
-    return Hb, Tb
+    return _heads_a_block(N, Tb * D * itemsize), Tb
 
 
 def _pair_matrices(R, backward):

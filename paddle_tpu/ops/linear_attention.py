@@ -31,7 +31,11 @@ takes the key head's value heads in turn, so q, k and their Gram tiles
               beta; keeps S [Dk, Dv] float32 in VMEM scratch across the
               chunks; makes the l2-norms, D, A, T, u, w, v' and the scores in
               VMEM and writes none of them: only o and `States`, S as each
-              chunk found it (float32 [chunks, B, Hv, Dk, Dv]).
+              chunk found it (float32 [chunks, B, Hv, Dk, Dv]). o
+              [B, T, Hv * Dv] is read where it lies by `gated_norm_fwd`
+              (`ops/decoder_block.py`: the layer's output norm), whose
+              backward `gated_norm_bwd` writes `gdn_bwd`'s dO the same way:
+              no XLA op and no layout copy stands between the two pairs.
     gdn_bwd   the chunks last to first, dS [Dk, Dv] float32 in scratch;
               computes the chunk's factors again from its inputs and its
               saved state; with X = [u | w] = T R: dR = T^T dX,

@@ -86,3 +86,25 @@ def test_paged_kernel_keeps_its_name():
         .compile().as_text()
     names = _custom_calls(text)
     assert names and all("paged_attention" in n for n in names), names
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_gated_norm_kernels_keep_their_names(dtype):
+    """`gated_rms_norm` and its registered grad at the cell's value heads
+    (32 of 128) are one custom call each, `%gated_norm_fwd` and
+    `%gated_norm_bwd`, whose first results (Y; dX) have no shape that
+    `gdn_scan_ms.train`'s pattern finds."""
+    from paddle_tpu.ops import decoder_block as db
+    x = jnp.ones((1, 256, 32, 128), dtype)
+    w = jnp.ones((128,), jnp.float32)
+    text = jax.jit(lambda x, w: (
+        db._gated_norm_call(x, x, w, 1e-6),
+        db._gated_norm_call(x, x, w, 1e-6, x))).lower(x, w) \
+        .compile().as_text()
+    names = _custom_calls(text)
+    assert sorted(n.split(".")[0] for n in names) == [
+        "gated_norm_bwd", "gated_norm_fwd"], names
+    short = {"bfloat16": "bf16", "float32": "f32"}[dtype]
+    assert re.search(rf"%gated_norm_fwd[\w.]* = {short}\[1,256,4096\]", text)
+    assert re.search(rf"%gated_norm_bwd[\w.]* = \({short}\[1,256,4096\]",
+                     text)

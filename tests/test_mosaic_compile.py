@@ -123,6 +123,35 @@ def test_rotary_kernels_compile_at_the_cells_shapes(one_chip, monkeypatch,
         assert "tpu_custom_call" in call
 
 
+@pytest.mark.parametrize("dtype,short", [(jnp.bfloat16, "bf16"),
+                                         (jnp.float32, "f32")],
+                         ids=["bf16", "f32"])
+def test_gated_norm_kernels_compile_at_the_cells_shape(one_chip, monkeypatch,
+                                                       dtype, short):
+    """`gated_norm_fwd` and `gated_norm_bwd` as `qwen3_next_80b_a3b.bs1`
+    calls them: X, Gate and dY `[1, 4096, 32, 128]` read as
+    `[1, 4096, 4096]`, Scale float32 `[128]`; a head's lanes sliced out of a
+    block, the row sums over them and the room both blocks' buffers take
+    are what the interpreter cannot refuse. One Mosaic custom call each,
+    named for the benchmark's pattern, whose first result (Y; dX) has no
+    shape that `gdn_scan_ms.train`'s pattern finds."""
+    from paddle_tpu.ops import decoder_block as db
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    shape = (1, 4096, 32, 128)
+    assert db._gated_norm_plan(shape, jnp.dtype(dtype)) == "kernel"
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((128,), jnp.float32, sharding=one_chip)
+    fwd = jax.jit(lambda x, g, w: db._gated_norm_call(x, g, w, 1e-6)).lower(
+        x, x, w).compile()
+    (call,) = _custom_calls(fwd, "gated_norm_fwd")
+    assert f"= {short}[1,4096,4096]{{" in call and "tpu_custom_call" in call
+    bwd = jax.jit(lambda x, g, w, d: db._gated_norm_call(
+        x, g, w, 1e-6, d)).lower(x, x, w, x).compile()
+    (call,) = _custom_calls(bwd, "gated_norm_bwd")
+    assert f"= ({short}[1,4096,4096]{{" in call and "tpu_custom_call" in call
+    assert re.search(r", f32\[1,16,\d,8,128\]\{", call.split(" custom-call(")[0])
+
+
 def test_share_movements_compile_at_the_cells_shapes(one_chip):
     """A share's layout and a token-side movement as `qwen3_next_80b_a3b.bs1`
     runs them (4096 tokens, top 10 of 512, experts 64..95 held, 2048 wide):
