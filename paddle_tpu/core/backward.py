@@ -246,7 +246,15 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     experts whose weights live here: fewer under a share),
     `moe_router_score` where the router's scores are not a softmax, and
     `moe_router_bias_updates`, the routers whose selection bias a later op
-    of the step writes again. Empty for a program with neither.
+    of the step writes again. Of the softmax-attention layers, by what else
+    their `name_scope` holds: `attention_rotary_layers`, those with a
+    `rotary_embedding` op, and where the program has such layers,
+    `attention_unrotated_layers`, those without (no positions at all);
+    `attention_gated_layers`, those with a `sigmoid` op (an output gate on
+    the context). `residual_out_norms`: the `rms_norm` ops whose result goes
+    straight into a residual `elementwise_add`, a sublayer normed on the way
+    out (two a layer where a layer has four norms). Empty for a program with
+    none of these.
     (`moe_row_buffer_rows`, the rows of the expert layer's layout, follows
     the batch: `moe_dispatch`'s rule notes it on the same event under the
     trace, `LoweringContext.note`.)"""
@@ -257,12 +265,23 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     copies: Dict[str, str] = {}     # an `assign` op's result -> what it copied
     biases = []                     # the routers' selection biases
     gated, routed = [], set()       # name scopes of `swiglu`s, of routers
+    mixers = []                     # name scopes of `fused_attention`s
+    held_by = {"rotary_embedding": set(), "sigmoid": set()}  # name scopes
+    normed, added = set(), set()    # `rms_norm` results, residual addends
     for op in block.ops:
         if op.attrs.get("__role__") is not None:
             continue
+        scope = op.attrs.get(ir.NAME_SCOPE_ATTR)
         if op.type == "gated_delta_rule":
             kinds["linear_attention"] += 1
+        elif op.type in held_by:
+            held_by[op.type].add(scope)
+        elif op.type == "rms_norm":
+            normed.update(op.output("Y"))
+        elif op.type == "elementwise_add":
+            added.update(op.input_arg_names)
         elif op.type == "fused_attention":
+            mixers.append(scope)
             keys = block.var(op.input("K")[0])
             wide = keys.shape[-1]
             value = block.var(op.input("V")[0]).shape[-1]
@@ -281,12 +300,12 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
                 out["attention_qk_width"] = wide
                 out["attention_value_width"] = value
         elif op.type == "swiglu":
-            gated.append(op.attrs.get(ir.NAME_SCOPE_ATTR))
+            gated.append(scope)
         elif op.type == "assign":
             copies[op.output("Out")[0]] = op.input("X")[0]
         elif op.type == "moe_router":
             out["moe_experts_routed"] = block.var(op.input("W")[0]).shape[-1]
-            routed.add(op.attrs.get(ir.NAME_SCOPE_ATTR))
+            routed.add(scope)
             if op.attrs.get("score_func"):
                 out["moe_router_score"] = op.attrs["score_func"]
             biases += [copies.get(name, name)
@@ -304,6 +323,17 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     updated = sum(1 for bias in biases if bias in copies)
     if updated:
         out["moe_router_bias_updates"] = updated
+    turned = sum(1 for scope in mixers if scope in held_by["rotary_embedding"])
+    if turned:
+        out["attention_rotary_layers"] = turned
+        if turned < len(mixers):
+            out["attention_unrotated_layers"] = len(mixers) - turned
+    gated_mixers = sum(1 for scope in mixers if scope in held_by["sigmoid"])
+    if gated_mixers:
+        out["attention_gated_layers"] = gated_mixers
+    out_norms = len(normed & added)
+    if out_norms:
+        out["residual_out_norms"] = out_norms
     return out
 
 

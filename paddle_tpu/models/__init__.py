@@ -15,7 +15,11 @@ with non-trainable state beside its weights), and Mellum2: sliding-window
 and full attention layers mixed 3:1 through the flash kernels, each kind
 with rotary tables of its own (YaRN on the full layers), 32 query heads
 over 4 key-value heads (the first model whose attention layers differ in
-their mask)."""
+their mask), and Trinity-Mini: window layers that turn by rotary beside full
+layers that carry no positions at all, an output gate from a projection of
+its own, a norm on both sides of every sublayer with experts inside, a scaled
+embedding (the first model whose attention kinds differ in whether they
+turn)."""
 
 from . import mnist  # noqa: F401
 from . import resnet  # noqa: F401
@@ -31,3 +35,4 @@ from . import ouro  # noqa: F401
 from . import qwen3_next  # noqa: F401
 from . import kanana2  # noqa: F401
 from . import mellum2  # noqa: F401
+from . import trinity  # noqa: F401

@@ -152,22 +152,27 @@ def _blk(T, causal=False, window=None):
     innermost dimension, VMEM per program is O(blk_q * blk_k + blk * D)
     regardless of T — no sequence-length cap (validated to seq 32768).
 
-    Under a `window` shorter than T the tiles are square and at most half
-    the window (128 at least): a band of W keys a row meets about W + b keys
-    of b-wide tiles, so 1024 x 1024 tiles at W = 1024 compute twice the
-    visible pairs and 512 x 512 one and a half times. Measured at one shape
-    only, [32, 8192, 128] bf16 with W = 1024, forward + split backward a
-    layer (chip run, PR 40): 8.30 ms at 1024^2, 7.04 at 512^2, 11.31 at
-    256^2 (8.4-9.8 at the four mixed shapes). That shape's result is 512;
-    every other length and window takes the rule as a default that no run
-    has tried, and does not consult the sweep table above, whose entries
-    were measured without a window."""
+    Under a `window` shorter than T the tiles are square, at most half the
+    window and at most 512 (128 at least): a band of W keys a row meets about
+    W + b keys of b-wide tiles, so 1024 x 1024 tiles at W = 1024 compute
+    twice the visible pairs and 512 x 512 one and a half times. Measured at
+    two shapes, bf16, forward + backward a layer: [32, 8192, 128] with W =
+    1024 (split backward; chip run, PR 40): 8.30 ms at 1024^2, 7.04 at 512^2,
+    11.31 at 256^2 (8.4-9.8 at the four mixed shapes); [32, 4096, 128] with
+    W = 2048 (fused backward; chip run, PR 49, where the rule was "half the
+    window" and gave 1024^2: 9 of the causal 10 tiles): 3.84 ms at 1024^2,
+    3.67 at 512^2 (30 of 36), 6.29 at 256^2, 3.95-5.00 at the four mixed
+    shapes (without a window there 4.31 at 1024^2, 4.35 at 512^2: the smaller
+    tile costs a hundredth, the band's edges a twentieth). Both shapes'
+    result is 512; every other length and window takes the rule as a
+    default that no run has tried, and does not consult the sweep table
+    above, whose entries were measured without a window."""
     if _BLOCK_OVERRIDE is not None:
         bq, bk = _BLOCK_OVERRIDE
         if T % bq == 0 and T % bk == 0:
             return bq, bk
     if window is not None and window < T:
-        for b in (1024, 512, 256, 128):
+        for b in (512, 256, 128):
             if T % b == 0 and b <= max(window // 2, 128):
                 return b, b
     tbl = _table_blk(T, causal)

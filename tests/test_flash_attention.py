@@ -766,6 +766,8 @@ def test_window_with_dropout_takes_the_causal_paths_fallback():
 @pytest.mark.parametrize("T,tiles,window,band,causal", [
     (8192, None, 1024, 45, 136),        # the cell: 512 x 512 tiles
     (8192, (1024, 1024), 1024, 15, 36),
+    (4096, None, 2048, 30, 36),         # Trinity-Mini's: 512 x 512 tiles
+    (4096, (1024, 1024), 2048, 9, 10),
     (512, (128, 128), 128, 7, 10),
     (512, (128, 128), 129, 7, 10),
     (512, (128, 128), 130, 9, 10),
@@ -797,6 +799,70 @@ def test_windowed_grids_cover_the_bands_tiles(monkeypatch, T, tiles, window,
                                           T // bq) for s in range(nqw)]
         assert [qi for qi in seen if 0 <= qi < T // bq and live[qi][kj]] \
             == [qi for qi in range(T // bq) if live[qi][kj]]
+
+
+# -- the second window width: 2048 keys over 4096 tokens (Trinity-Mini) -----------
+
+W2048 = dict(B=1, H=1, T=4096, D=32, window=2048)
+
+
+def test_the_tile_rule_at_a_window_of_2048():
+    """Square tiles of half the window and no more than 512 (PR 49 measured
+    1024 x 1024, what half the window gave, a twentieth slower): 512 x 512
+    at 4096 tokens, where a row of keys is eight K blocks (the streaming
+    forward; the fused backward keeps the row's dQ resident)."""
+    T, window = W2048["T"], W2048["window"]
+    assert pallas_attention._blk(T, True, window) == (512, 512)
+    assert pallas_attention._blk(T, True, 1024) == (512, 512)
+    assert pallas_attention._blk(16384, True, 4096) == (512, 512)
+    assert pallas_attention._blk(T, True, 600) == (256, 256)
+    assert pallas_attention._blk(T, True) == (1024, 1024)
+    assert pallas_attention._fwd_plan(T, 512) == "stream"
+    assert pallas_attention._band_steps(T, 512, 512, window) == (5, 5)
+
+
+@pytest.mark.parametrize("tiles", [None, (1024, 1024)],
+                         ids=["rule", "1024x1024"])
+def test_windowed_forward_at_2048_is_the_masked_softmax(interpret_kernels,
+                                                        monkeypatch, tiles):
+    rng = np.random.RandomState(17)
+    B, H, T, D, window = W2048.values()
+    if tiles:
+        monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
+    q, k, v = _qkv(rng, B, H, T, D, D)
+    out = flash_attention(q, k, v, jnp.int32(0), True, D ** -0.5, 0.0, window)
+    want = _masked_softmax(q, k, v, window, D ** -0.5)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5,
+                               rtol=2e-5)
+    # one key more in the window is another function
+    off = _masked_softmax(q, k, v, window + 1, D ** -0.5)
+    assert float(jnp.abs(out - off).max()) > 1e-4
+
+
+@pytest.mark.parametrize("kernels", ["fused", "fused_resident_row", "split"])
+def test_windowed_backward_plans_at_2048_match_the_masked_softmax(
+        interpret_kernels, monkeypatch, kernels):
+    """Every backward plan under the window of 2048: the fused kernel where
+    a row is one K block, the fused kernel with the row's dQ resident (what
+    the tile rule gives), and the split pair."""
+    rng = np.random.RandomState(18)
+    B, H, T, D, window = W2048.values()
+    if kernels == "fused":
+        monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", (1024, T))
+    if kernels == "split":
+        monkeypatch.setattr(pallas_attention, "_bwd_plan",
+                            lambda *a: "split")
+    q, k, v = _qkv(rng, B, H, T, D, D)
+    g = jnp.asarray(rng.randn(B, H, T, D), jnp.float32)
+    out, lse = pallas_attention._flash_forward(q, k, v, True, D ** -0.5,
+                                               window=window)
+    got = pallas_attention._flash_backward(q, k, v, out, lse, g, True,
+                                           D ** -0.5, 0.0, 0, window)
+    _, vjp = jax.vjp(lambda *a: _masked_softmax(*a, window, D ** -0.5),
+                     q, k, v)
+    for a, b, name in zip(got, vjp(g), "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
+                                   rtol=1e-4, err_msg=f"d{name}")
 
 
 @pytest.mark.parametrize("case", ["not_causal", "zero", "fraction",

@@ -296,6 +296,44 @@ def test_windowed_flash_kernels_compile_at_the_cells_shape(one_chip,
     assert not _custom_calls(bwd, "flash_dq")
 
 
+@pytest.mark.parametrize("tiles,steps", [(None, 5), ((1024, 1024), 3)],
+                         ids=["rule_512", "1024"])
+def test_windowed_flash_kernels_compile_at_a_window_of_2048(one_chip,
+                                                            monkeypatch,
+                                                            tiles, steps):
+    """The second window width, as `trinity_mini_26b_a3b.s4096` calls the
+    kernels: bf16 `[1, 32, 4096, 128]` under a window of 2048, where the tile
+    rule (half the window, 512 at most) gives 512 x 512 and an inner grid
+    axis five tiles long; and at 1024 x 1024, three long, what the rule gave
+    before PR 49 measured both. The streaming forward and the fused
+    backward, one Mosaic custom call each, under the windowed names."""
+    from paddle_tpu.ops import pallas_attention as pa
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    assert pa._blk(4096, True, 2048) == (512, 512)
+    if tiles:
+        monkeypatch.setattr(pa, "_BLOCK_OVERRIDE", tiles)
+    tile = pa._blk(4096, True, 2048)[0]
+    assert pa._band_steps(4096, tile, tile, 2048) == (steps, steps)
+    assert pa._bwd_plan(4096, 128, 128, tile, tile, 2) == "fused"
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q = arg((1, 32, 4096, 128))
+    fwd = jax.jit(lambda q, k, v: pa._flash_forward(
+        q, k, v, True, 128 ** -0.5, window=2048)).lower(q, q, q).compile()
+    (call,) = _custom_calls(fwd, "swa_flash_fwd")
+    assert "(bf16[32,4096,128]{" in call and "f32[32,1,4096]{" in call
+    assert not _custom_calls(fwd, "flash_fwd")
+    bwd = jax.jit(lambda q, k, v, o, lse, g: pa._flash_backward(
+        q, k, v, o, lse, g, True, 128 ** -0.5, 0.0, 0, 2048)).lower(
+            q, q, q, q, arg((32, 1, 4096), jnp.float32), q).compile()
+    (call,) = _custom_calls(bwd, "swa_flash_dq_flash_dkv")
+    assert call.split(" custom-call(")[0].count("bf16[32,4096,128]{") == 3
+    assert not _custom_calls(bwd, "swa_flash_dkv")
+    assert not _custom_calls(bwd, "flash_dq")
+
+
 def _lowered_digest(lowered):
     """sha256 of a lowering's StableHLO with every Mosaic kernel in it
     written out as MLIR without debug locations (the serialized body holds
