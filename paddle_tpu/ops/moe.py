@@ -49,19 +49,28 @@ rule notes the count on the program's compile event, `moe_row_buffer_rows`).
 `GroupSizes` sums to what is used, and everything that touches a row follows
 it. The kernels' grid is as long as the tiles they visit (megablox counts
 them from the group sizes). The four row movements (dispatch, combine and
-their grads) are `lax.while_loop`s over the used rows, `_MOVE_ROWS` a step,
-`sum(GroupSizes) / _MOVE_ROWS` steps (`_over_used_rows`; each op counts
-itself on the compile event, `moe_share_bounded_moves`): the two that write
-the layout gather a chunk's rows by token and write them in place
-(`_dispatch_share`, which finds each chunk's `Source` in the same step, and
-`_combine_share_grad`), the two that read it add a
-chunk's rows to their tokens in a float32 accumulator (`_tokens_from_rows`:
-a token's experts are summed in expert order, the same order every run).
+their grads) go over the used rows only, `sum(GroupSizes)` of them, each op
+counting itself on the compile event (`moe_share_bounded_moves`). The two
+that write the layout are `lax.while_loop`s of `_MOVE_ROWS` rows a step
+(`_over_used_rows`): they gather a chunk's rows by token and write them in
+place (`_dispatch_share`, which finds each chunk's `Source` in the same
+step, and `_combine_share_grad`). The two that read it (`moe_combine` and
+`moe_dispatch_grad`, both of which run once a step: the one has a registered
+grad, the other is one) add every used row to its token's row in float32, a
+token's experts in expert order, the same order every run
+(`_tokens_from_rows`): on a TPU one Pallas call, `moe_token_sum`
+(`_token_sum_call`: the rows streamed in chunks, a float32 accumulator of
+all the tokens resident in VMEM, a row's token and router weight read from
+SMEM where it is added, the result written once in its own dtype; 0.018-0.024
+us a used row on a v5e where the loop took 0.10-0.12, PERF.md section 6, PR
+50); on a CPU backend, and for a shape outside `_token_sum_plan`, a
+`lax.while_loop` that scatter-adds a chunk into a float32 `[N, width]`
+array (`_token_sum_loop`), which is also what the kernel is tested against.
 So the step's time follows the held assignments: at the even load of one
 chip in sixteen (4096 tokens, top 10 of 512, 32 held: ~2560 assignments in
-4096-4224 used rows of 45056) the four movements of a layer take 1.7 ms
-alone on a v5e where gathers over the static rows took 9.1; with every
-assignment on a held expert (40960, the worst case) they take 12.3 ms
+4096-4224 used rows of 45056) the four movements of a layer took 1.7 ms
+alone on a v5e as four loops where gathers over the static rows took 9.1;
+with every assignment on a held expert (40960, the worst case) 12.3 ms
 against 9.1 (PERF.md section 6, PR 37). What stands between dispatch and
 combine is elementwise and goes over the used rows the same way, in larger
 steps (`map_used_rows`, `_ELEMENTWISE_ROWS`: no gather, so a chunk is plain
@@ -77,7 +86,9 @@ arithmetic on them (but for what the last chunk of a loop reaches past the
 used rows, whose results no one reads) or on a padding row that reaches a
 result, not even times a zero weight (0 x NaN is NaN): a row's `Source` and
 an assignment's `Slot` are -1 where there is nothing, and a movement
-applies `Source` with a select or drops the row by an index out of range.
+applies `Source` with a select or drops the row by an index out of range
+(`moe_token_sum` adds it to a spare row of its accumulator, behind the
+tokens', that nobody reads).
 
 Where every expert is held all N*k assignments have a row and the movements
 are static gathers, token-major `[N, k, D]` (`_rows_of_slots`): the faster
@@ -86,6 +97,7 @@ layout there (PERF.md section 6, PR 34). The attribute chooses.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -93,6 +105,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.registry import register_grad, register_op
+from .linear_attention import _backend_takes_kernels
 from .pallas_attention import _interpret
 
 # Rows a group is padded to in `moe_dispatch`, and the row tile of the
@@ -116,6 +129,21 @@ _MOVE_ROWS = 512
 # [45056, 512 and 2048], and 698 / 604 us at 512 / 1024 over 9216 of
 # [66560, 896 and 2304] (PERF.md section 6, PR 43).
 _ELEMENTWISE_ROWS = 1024
+# `moe_token_sum` (a share's token-side sums as one Pallas call): the most
+# the tokens' float32 accumulator `[tokens, columns]` may take of a v5e's 128
+# MiB of VMEM, which decides how many column blocks a call makes
+# (`_token_sum_plan`), and the most its scalar-prefetch operands (`Source`
+# and the router weights) may take of the 1 MiB of SMEM. A row costs about
+# as much per column block it is visited in as it has vregs (0.015 us at 9
+# vregs, 0.019 at 18: the chain load, add, store of a token's row), so one
+# block of 2304 columns over 8192 tokens (72 MiB) is a third faster than two
+# of 1152 (v5e, the call alone, PERF.md section 6, PR 50).
+_TOKEN_SUM_ACC_BYTES = 72 * 2 ** 20
+_TOKEN_SUM_SMEM_BYTES = 768 * 2 ** 10
+# Token rows of the result a trailing grid step of `moe_token_sum` casts and
+# hands to the pipeline, and source rows a step of its row loop is unrolled by.
+_TOKEN_SUM_OUT_ROWS = 512
+_TOKEN_SUM_UNROLL = 8
 
 
 @register_op("moe_router", propagate_seqlen=False)
@@ -305,12 +333,14 @@ def _dispatch_share(X, TopKIndex, TokensPerExpert, tile, first, held):
             "GroupSizes": sizes}
 
 
-def _tokens_from_rows(moved, source, k, n, sizes, scale=None):
-    """A share's token-side movement, [n, width] in float32: every used row
-    r of `moved` (times `scale` [N*k] at r's assignment) added to its
-    token's row, in row order: by expert, then by token, the same in every
-    run. A padding row's token is `n`, out of range, and is dropped by its
-    index; it is not multiplied by a zero."""
+def _token_sum_loop(moved, source, k, n, sizes, scale=None):
+    """A share's token-side movement as a `lax.while_loop`, [n, width] in
+    float32: every used row r of `moved` (times `scale` [N*k] at r's
+    assignment) added to its token's row, in row order: by expert, then by
+    token, the same in every run. A padding row's token is `n`, out of
+    range, and is dropped by its index; it is not multiplied by a zero. The
+    path on a CPU backend and for a shape outside `_token_sum_plan`, and the
+    plain form `moe_token_sum` is held against."""
     rows, width = moved.shape
 
     def step(start, chunk, acc):
@@ -324,6 +354,142 @@ def _tokens_from_rows(moved, source, k, n, sizes, scale=None):
 
     return _over_used_rows(sizes, rows, step,
                            jnp.zeros((n, width), jnp.float32))
+
+
+def _token_sum_plan(n, k, rows, width, dtype):
+    """(columns of a block, rows of a chunk, token rows of a written block)
+    of `moe_token_sum` for `rows` x `width` layout rows of `dtype` summed
+    into `n` tokens of `k` assignments each, or None where the loop stays:
+    a width that is not whole 128-lane tiles, chunks or written blocks
+    (`gcd` of the rows with `_MOVE_ROWS`, of the tokens with
+    `_TOKEN_SUM_OUT_ROWS`) that are not whole sublane tiles of `dtype`
+    (fewer than 8 rows of float32, 16 of bf16), an accumulator that does
+    not fit `_TOKEN_SUM_ACC_BYTES` at 128 columns, `Source` and the weights
+    over `_TOKEN_SUM_SMEM_BYTES`. The columns are the widest whole-lane
+    divisor of the width whose accumulator fits: 2048 of 2048 at 4096
+    tokens, 2304 of 2304 at 8192. The choice reads the shapes alone."""
+    dtype = jnp.dtype(dtype)
+    if dtype not in (jnp.bfloat16, jnp.float32) or width % 128:
+        return None
+    tile = 8 * 4 // dtype.itemsize
+    chunk = math.gcd(rows, _MOVE_ROWS)
+    written = math.gcd(n, _TOKEN_SUM_OUT_ROWS)
+    if chunk % tile or written % tile \
+            or 4 * (rows + n * k) > _TOKEN_SUM_SMEM_BYTES:
+        return None
+    lanes = width // 128
+    for blocks in range(1, lanes + 1):
+        if lanes % blocks == 0 \
+                and n * (width // blocks) * 4 <= _TOKEN_SUM_ACC_BYTES:
+            return width // blocks, chunk, written
+    return None
+
+
+def _token_sum_kernel(used_ref, source_ref, *refs, k, n, steps, scaled):
+    """One (column block, step) of `moe_token_sum`. The first `steps` steps
+    are the layout's chunks: a chunk the held groups reach into is widened
+    to float32 and its rows are added, in row order, each to its token's row
+    of the accumulator; a padding row's token is `n`, the row behind the
+    tokens', which nobody reads. The steps after them write the accumulator
+    out, a block of token rows each, in the result's dtype."""
+    from jax.experimental import pallas as pl
+
+    if scaled:
+        scale_ref, rows_ref, out_ref, acc_ref, wide_ref = refs
+    else:
+        rows_ref, out_ref, acc_ref, wide_ref = refs
+    step = pl.program_id(1)
+    chunk, columns = rows_ref.shape
+    written = out_ref.shape[0]
+
+    @pl.when(step == 0)
+    def _():
+        def clear(b, carry):
+            acc_ref[pl.ds(pl.multiple_of(b * written, written), written), :] \
+                = jnp.zeros((written, columns), jnp.float32)
+            return carry
+        lax.fori_loop(0, n // written, clear, 0)
+
+    @pl.when(step < used_ref[0])
+    def _():
+        wide_ref[...] = rows_ref[...].astype(jnp.float32)
+
+        def add(b, carry):
+            first = pl.multiple_of(b * _TOKEN_SUM_UNROLL, _TOKEN_SUM_UNROLL)
+            for r in range(_TOKEN_SUM_UNROLL):
+                src = source_ref[step * chunk + first + r]
+                token = jnp.where(src >= 0, lax.div(src, k), n)
+                value = wide_ref[pl.ds(first + r, 1), :]
+                if scaled:
+                    value = value * scale_ref[jnp.maximum(src, 0)]
+                acc_ref[pl.ds(token, 1), :] += value
+            return carry
+        lax.fori_loop(0, chunk // _TOKEN_SUM_UNROLL, add, 0)
+
+    @pl.when(step >= steps)
+    def _():
+        block = pl.multiple_of((step - steps) * written, written)
+        out_ref[...] = acc_ref[pl.ds(block, written), :].astype(out_ref.dtype)
+
+
+def _token_sum_call(moved, source, k, n, sizes, dtype, plan, scale=None):
+    """`moe_token_sum`: `_token_sum_loop`'s sums as one Pallas call, the
+    same additions in the same order, [n, width] in `dtype`. Grid (column
+    blocks, the layout's chunks and then the result's blocks of token rows),
+    the second axis in turn. A column block's float32 accumulator
+    `[n + 8, columns]` stays in VMEM from its first step to its last, so no
+    float32 `[n, width]` array is in HBM and no cast follows. `Source` and
+    the flat router weights are scalar-prefetch operands: a row's token and
+    weight are read from SMEM where the row is added, not gathered into
+    `[rows]` arrays first. The number of chunks the held groups reach into
+    goes in front of them: the steps behind it do nothing, and the rows'
+    index map stays on the last used chunk there, so the pipeline fetches
+    nothing again. The call asks for the VMEM it needs."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, width = moved.shape
+    columns, chunk, written = plan
+    steps = rows // chunk
+    used = -(-jnp.sum(sizes.astype(jnp.int32)) // chunk)
+    scalars = (used.reshape(1), source) \
+        + (() if scale is None else (scale,))
+    item = moved.dtype.itemsize
+    vmem = (n + 8) * columns * 4 + chunk * columns * (4 + 2 * item) \
+        + 2 * written * columns * jnp.dtype(dtype).itemsize + 2 ** 20
+    return pl.pallas_call(
+        functools.partial(_token_sum_kernel, k=k, n=n, steps=steps,
+                          scaled=scale is not None),
+        name="moe_token_sum",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(width // columns, steps + n // written),
+            in_specs=[pl.BlockSpec(
+                (chunk, columns), lambda j, i, used, *_: (
+                    jnp.minimum(i, jnp.maximum(used[0] - 1, 0)), j))],
+            out_specs=pl.BlockSpec(
+                (written, columns),
+                lambda j, i, *_: (jnp.maximum(i - steps, 0), j)),
+            scratch_shapes=[pltpu.VMEM((n + 8, columns), jnp.float32),
+                            pltpu.VMEM((chunk, columns), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((n, width), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem),
+        interpret=_interpret())(*scalars, moved)
+
+
+def _tokens_from_rows(moved, source, k, n, sizes, dtype, scale=None):
+    """A share's token-side movement, [n, width] in `dtype`: every used row
+    r of `moved` (times `scale` [N*k] at r's assignment) added to its
+    token's row in float32, in row order, one rounding at the end. One
+    Pallas call where the backend takes kernels and `_token_sum_plan` gives
+    one, else the loop and a cast."""
+    plan = _token_sum_plan(n, k, *moved.shape, moved.dtype)
+    if plan is not None and _backend_takes_kernels():
+        return _token_sum_call(moved, source, k, n, sizes, dtype, plan,
+                               scale)
+    return _token_sum_loop(moved, source, k, n, sizes, scale).astype(dtype)
 
 
 def _rows_of_slots(rows, slot, n, k):
@@ -341,9 +507,9 @@ def _moe_dispatch_grad(ctx, ins, out_grads):
     n, k = index.shape
     if ctx.attr("experts_held") is not None:
         ctx.tally("moe_share_bounded_moves")
-        d_x = _tokens_from_rows(g, ctx.fwd_outs["Source"][0], k, n,
-                                ctx.fwd_outs["GroupSizes"][0])
-        return {"X": d_x.astype(X.dtype)}
+        return {"X": _tokens_from_rows(g, ctx.fwd_outs["Source"][0], k, n,
+                                       ctx.fwd_outs["GroupSizes"][0],
+                                       X.dtype)}
     per_slot = _rows_of_slots(g, ctx.fwd_outs["Slot"][0], n, k)
     return {"X": jnp.sum(per_slot.astype(jnp.float32), axis=1)
             .astype(X.dtype)}
@@ -352,7 +518,7 @@ def _moe_dispatch_grad(ctx, ins, out_grads):
 def _kernel():
     """The megablox module where its kernels are the path (a TPU backend,
     or the CPU under the Pallas interpreter), else None."""
-    if jax.default_backend() == "cpu" and not _interpret():
+    if not _backend_takes_kernels():
         return None
     import importlib
     # the package re-exports a function under the submodule's name
@@ -457,9 +623,8 @@ def _moe_combine(ctx, Y, TopKWeight, Slot, Source, GroupSizes=None):
     weight = TopKWeight.astype(jnp.float32)
     if ctx.attr("experts_held") is not None:
         ctx.tally("moe_share_bounded_moves")
-        out = _tokens_from_rows(Y, Source, k, n, GroupSizes,
-                                scale=weight.reshape(-1))
-        return {"Out": out.astype(Y.dtype)}
+        return {"Out": _tokens_from_rows(Y, Source, k, n, GroupSizes,
+                                         Y.dtype, scale=weight.reshape(-1))}
     per_slot = _rows_of_slots(Y, Slot, n, k)
     out = jnp.sum(per_slot.astype(jnp.float32) * weight[:, :, None], axis=1)
     return {"Out": out.astype(Y.dtype)}

@@ -5,6 +5,8 @@ suite checks the Fluid op scopes in the lowered text
 (tests/test_run_spans.py); what the chip's compiler names the custom calls
 only the chip can say."""
 
+import json
+import os
 import re
 
 import numpy as np
@@ -108,3 +110,50 @@ def test_gated_norm_kernels_keep_their_names(dtype):
     assert re.search(rf"%gated_norm_fwd[\w.]* = {short}\[1,256,4096\]", text)
     assert re.search(rf"%gated_norm_bwd[\w.]* = \({short}\[1,256,4096\]",
                      text)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("scaled", [True, False],
+                         ids=["moe_combine", "moe_dispatch_grad"])
+def test_token_sum_keeps_its_name_and_is_the_loop(scaled, dtype):
+    """A share's token-side sums (`ops/moe.py::_tokens_from_rows`, 512
+    tokens x top 4, 4 of 16 experts held, 256 wide) are one custom call,
+    `%moe_token_sum`, which `moe_token_sum_kernel_calls.train`'s pattern
+    finds; its result is the loop's bitwise, float32 rows times a weight
+    too (the chip has no fused multiply-add to contract them into), with
+    NaN in every row no assignment holds."""
+    from paddle_tpu.ops import moe
+    n, k, experts, first, held, width = 512, 4, 16, 4, 4, 256
+    rng = np.random.RandomState(0)
+    index = np.stack([rng.permutation(experts)[:k] for _ in range(n)])
+    counts = np.bincount(index.reshape(-1), minlength=experts)
+    layout = moe._dispatch_share(
+        jnp.zeros((n, width), dtype), jnp.asarray(index, jnp.int32),
+        jnp.asarray(counts, jnp.int32), moe.ROW_TILE, first, held)
+    source, sizes = layout["Source"], layout["GroupSizes"]
+    moved = rng.randn(source.shape[0], width).astype(np.float32)
+    moved[np.asarray(source) < 0] = np.nan
+    moved = jnp.asarray(moved, dtype)
+    scale = (jnp.asarray(rng.rand(n * k), jnp.float32),) if scaled else ()
+    assert moe._token_sum_plan(n, k, *moved.shape, moved.dtype)
+    sums = jax.jit(lambda m, s, z, *scale: moe._tokens_from_rows(
+        m, s, k, n, z, m.dtype, *scale))
+    text = sums.lower(moved, source, sizes, *scale).compile().as_text()
+    assert [name.split(".")[0] for name in _custom_calls(text)] \
+        == ["moe_token_sum"]
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "metrics",
+            "moe_token_sum_kernel_calls.train.json")) as f:
+        pattern = json.load(f)["args"]["pattern"]
+    # an op's event in a trace is its instruction line; here the call is
+    # the jitted function's ROOT
+    lines = [line for line in (re.sub(r"^\s*(ROOT )?", "", raw)
+                               for raw in text.splitlines())
+             if re.search(pattern, line)]
+    assert len(lines) == 1 and "tpu_custom_call" in lines[0]
+    got = sums(moved, source, sizes, *scale)
+    want = jax.jit(lambda m, s, z, *scale: moe._token_sum_loop(
+        m, s, k, n, z, *scale).astype(m.dtype))(moved, source, sizes, *scale)
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    assert np.isfinite(got).all() and got.any()
+    np.testing.assert_array_equal(got, want)

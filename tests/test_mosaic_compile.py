@@ -153,11 +153,11 @@ def test_gated_norm_kernels_compile_at_the_cells_shape(one_chip, monkeypatch,
 
 
 def test_share_movements_compile_at_the_cells_shapes(one_chip):
-    """A share's layout and a token-side movement as `qwen3_next_80b_a3b.bs1`
-    runs them (4096 tokens, top 10 of 512, experts 64..95 held, 2048 wide):
-    `lax.while_loop`s over the used rows, the 45056-row buffer an
-    `AllocateBuffer` that the loop takes as it is (no fill, no copy: the
-    program needs no temporary of the buffer's size beside its result)."""
+    """A share's layout as `qwen3_next_80b_a3b.bs1` runs it (4096 tokens,
+    top 10 of 512, experts 64..95 held, 2048 wide): a `lax.while_loop` over
+    the used rows, the 45056-row buffer an `AllocateBuffer` that the loop
+    takes as it is (no fill, no copy: the program needs no temporary of the
+    buffer's size beside its result)."""
     from paddle_tpu.ops import moe
     n, k, width, held = 4096, 10, 2048, 32
     rows = n * k + held * moe.ROW_TILE
@@ -172,13 +172,47 @@ def test_share_movements_compile_at_the_cells_shapes(one_chip):
     text = layout.as_text()
     assert 'custom_call_target="AllocateBuffer"' in text and " while(" in text
     assert layout.memory_analysis().temp_size_in_bytes < rows * width * 2
-    combine = jax.jit(lambda y, source, sizes, weight: moe._tokens_from_rows(
-        y, source, k, n, sizes, scale=weight)).lower(
-            arg((rows, width), jnp.bfloat16), arg((rows,), jnp.int32),
-            arg((held,), jnp.int32), arg((n * k,), jnp.float32)).compile()
-    assert " while(" in combine.as_text()
-    # no 40960-row gather of the layout is left in either
-    assert f"[{n * k},{width}]" not in text + combine.as_text()
+    # no 40960-row gather of the layout is left in it
+    assert f"[{n * k},{width}]" not in text
+
+
+@pytest.mark.parametrize("scaled", [True, False],
+                         ids=["moe_combine", "moe_dispatch_grad"])
+@pytest.mark.parametrize("n,k,held,width", [
+    (4096, 10, 32, 2048), (4096, 6, 16, 2048), (4096, 8, 8, 2048),
+    (8192, 8, 8, 2304)], ids=["qwen3_next", "kanana2", "trinity", "mellum2"])
+def test_token_sum_compiles_at_the_cells_shapes(one_chip, monkeypatch, n, k,
+                                                held, width, scaled):
+    """A share's token-side sums as the four share cells run them (bf16
+    layouts of `[45056, 2048]`, `[26624, 2048]`, `[33792, 2048]` and
+    `[66560, 2304]` rows; with the router weights as `moe_combine` calls
+    it, without as `moe_dispatch_grad` does): `_tokens_from_rows` is one
+    Mosaic custom call named for the benchmark's pattern, whose result is
+    the tokens' rows in bf16. What the interpreter cannot refuse: `Source`
+    and the weights (up to 528 KB) in SMEM, a float32 accumulator of 32 or
+    72 MiB in VMEM under the limit the call asks for, the dynamic-row
+    update. No `while` is left, and no float32 `[tokens, width]` array:
+    nothing in HBM beside the result."""
+    from paddle_tpu.ops import moe
+    monkeypatch.setattr(la, "_on_chip", lambda: True)
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    rows = n * k + held * moe.ROW_TILE
+    assert moe._token_sum_plan(n, k, rows, width, jnp.bfloat16) \
+        == (width, 512, 512)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [arg((rows, width), jnp.bfloat16), arg((rows,), jnp.int32),
+            arg((held,), jnp.int32)] \
+        + ([arg((n * k,), jnp.float32)] if scaled else [])
+    compiled = jax.jit(lambda y, source, sizes, *scale: moe._tokens_from_rows(
+        y, source, k, n, sizes, jnp.bfloat16, *scale)).lower(*args).compile()
+    (call,) = _custom_calls(compiled, "moe_token_sum")
+    assert f"= bf16[{n},{width}]{{" in call and "tpu_custom_call" in call
+    text = compiled.as_text()
+    assert " while(" not in text and f"f32[{n},{width}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
 def test_share_elementwise_passes_compile_at_the_cells_shapes(one_chip):
