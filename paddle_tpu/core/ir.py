@@ -22,6 +22,7 @@ import os
 import sys
 from typing import Any, Dict, List, Optional, Sequence
 
+from ..observe import steplog as _steplog
 from . import types
 from .types import VarKind
 
@@ -524,8 +525,19 @@ def switch_startup_program(program: Program) -> Program:
     return prev
 
 
+def _census(program: Program) -> dict:
+    return {"ops": sum(len(b.ops) for b in program.blocks),
+            "variables": sum(len(b.vars) for b in program.blocks),
+            "parameters": len(program.global_block().all_parameters())}
+
+
 class program_guard:
-    """`with program_guard(main, startup):` context (reference framework.py:1911)."""
+    """`with program_guard(main, startup):` context (reference framework.py:1911).
+
+    Its body is the set-up phase `paddle_tpu:program_build` of the main
+    program (observe/steplog.py): how long the Program took to build, what
+    it holds at exit, and of that the seconds and calls inside
+    `registry.infer_op_shapes`."""
 
     def __init__(self, main_program: Program, startup_program: Optional[Program] = None):
         self._main = main_program
@@ -535,12 +547,22 @@ class program_guard:
         self._prev_main = switch_main_program(self._main)
         if self._startup is not None:
             self._prev_startup = switch_startup_program(self._startup)
+        self._phase = _steplog.Phase(
+            _steplog.PROGRAM_BUILD, self._main._uid,
+            detail={"infer_shapes_s": 0.0, "infer_shapes_calls": 0})
+        self._phase.__enter__()
         return self
 
     def __exit__(self, *exc):
         switch_main_program(self._prev_main)
         if self._startup is not None:
             switch_startup_program(self._prev_startup)
+        detail = self._phase.detail
+        detail["main"] = _census(self._main)
+        if self._startup is not None:
+            detail["startup"] = _census(self._startup)
+            detail["startup_uid"] = self._startup._uid
+        self._phase.__exit__(*exc)
         return False
 
 

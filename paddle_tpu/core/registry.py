@@ -15,12 +15,14 @@ both compile-time shapes and runtime values.
 from __future__ import annotations
 
 import inspect
+import time
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..observe import steplog as _steplog
 from . import types
 
 # Sentinel size substituted for -1 (unknown batch) dims during build-time shape
@@ -333,7 +335,22 @@ def infer_op_shapes(op_type: str, attrs: Dict[str, Any],
     (shape, dtype) pairs. -1 dims are substituted with a sentinel and
     traced through the lowering rule abstractly; a second trace with a
     different sentinel identifies which output dims derive from the
-    dynamic inputs (those map back to -1)."""
+    dynamic inputs (those map back to -1).
+
+    While a set-up phase is open on this thread (the body of a
+    `program_guard`) the call's seconds are summed on it: the phase's
+    `infer_shapes_s` / `infer_shapes_calls` (observe/steplog.py)."""
+    phase = _steplog.open_phase()
+    if phase is None:
+        return _infer_op_shapes(op_type, attrs, ins_by_slot)
+    t0 = time.perf_counter()
+    try:
+        return _infer_op_shapes(op_type, attrs, ins_by_slot)
+    finally:
+        phase.add("infer_shapes", time.perf_counter() - t0)
+
+
+def _infer_op_shapes(op_type, attrs, ins_by_slot):
     opdef = get_op_def(op_type)
     key = _infer_cache_key(op_type, attrs, ins_by_slot)
     hit = _infer_cache.get(key) if key is not None else None

@@ -8,7 +8,8 @@ Three cooperating pieces (see docs/OBSERVABILITY.md):
   chrome://tracing export; absorbs the profiler's host-event table
 - `observe.steplog`  — the spans inside run() (`RunSpans`), per-run()
   StepStats phase timings + the recompilation observatory (every jit
-  cache miss, with attributed cause and the seconds each stage cost)
+  cache miss, with attributed cause and the seconds each stage cost, and
+  the set-up store: `Phase`s of program builds and of first runs)
 - `observe.xray`     — W3C trace contexts across processes (round 11)
 - `observe.flight`   — the crash flight recorder (round 11)
 - `observe.pulse`    — per-process HTTP health endpoint: /metrics,
@@ -28,11 +29,19 @@ AsyncFeeder, pserver RPC) is gated on the `observe` flag:
     fluid.set_flag("observe", True)        # or PADDLE_TPU_OBSERVE=1
 
 With the flag off, the prepared-program fast path performs ZERO registry
-writes per step (one flag read + branch only). Compile-time recompile
-events are recorded regardless — they are never hot and they are what
+writes per step: one flag read and branch, and five reads of
+`time.perf_counter()` into the run's own slots (one at its start, one at
+each phase), which a steady step drops with the run — no allocation, no
+lock, no write to any store. Compile-time recompile events are recorded
+regardless — they are never hot and they are what
 `tools/telemetry_dump.py --assert-no-recompiles` audits in CI.
 
-One thing is on at DEFAULT flags: the host spans of a run. Every
+Two things are on at DEFAULT flags: the set-up store and the host spans of
+a run. The store (`observatory().phases()`) keeps, on `perf_counter()`,
+the body of every `program_guard` (`paddle_tpu:program_build`, two clock
+reads an op for the seconds inside shape inference), `minimize` inside it,
+and every run() that binds or compiles, with its phases and the compiles
+that fell outside its jitted call: a few records a program, bounded. Every
 `PreparedProgram.run` (an `Executor`'s steps and a `ParallelExecutor`'s
 alike) opens `paddle_tpu:run` and,
 inside it, `paddle_tpu:feed_convert`, `:bind` (a step that binds),

@@ -66,10 +66,7 @@ def status_document() -> dict:
         "ts": time.time(),
         "metrics": _metrics.default_registry().snapshot(),
         "steps": _steplog.get_steplog().phase_summary(),
-        "recompiles": {
-            "counts": _steplog.observatory().counts(),
-            "events": [e.as_dict() for e in _steplog.observatory().events()],
-        },
+        "recompiles": _steplog.observatory().as_dict(),
         "memory": _memory.report(),
         # evaluate, don't just read: /status is a pull-evaluation point
         # like /healthz, so both bodies agree even with the ticker off

@@ -1,6 +1,7 @@
-"""Per-step phase accounting + the recompilation observatory.
+"""Per-step phase accounting, the recompilation observatory and the
+set-up store.
 
-Two runtime questions dominate TPU cost and were previously invisible:
+Three runtime questions dominate TPU cost and were previously invisible:
 
 1. *Where does a step's host time go?* `RunSpans` opens one
    `paddle_tpu:run` span per `PreparedProgram.run` (both executors' one
@@ -9,7 +10,9 @@ Two runtime questions dominate TPU cost and were previously invisible:
    fetch transfer — as `jax.profiler.TraceAnnotation`s: on at default
    flags, on the profiler's clock, beside the device track of any
    capture, and free when no capture runs. With the `observe` flag on the
-   same boundaries also fill a `StepStats` in the bounded `StepLog`.
+   same boundaries also fill a `StepStats` in the bounded `StepLog`. One
+   clock serves both and the set-up store: `time.perf_counter()` read once
+   at each boundary into the run's own slots.
 
 2. *Why did XLA recompile?* The static lint (analysis/, PR 2) can only
    WARN about feed-shape recompile hazards; the observatory closes the
@@ -56,11 +59,26 @@ Two runtime questions dominate TPU cost and were previously invisible:
    the TPU the SECOND call of every step, whose state the startup program
    left uncommitted and the first call committed — chip run, PR 25), the
    durations go to the event of the entry that run() called, whose
-   `backend_compiles` then reads 2. No cause is invented for it.
+   `backend_compiles` then reads 2. No cause is invented for it. A cache
+   miss reads `cache_retrieval` 0 s: nothing was read.
+
+3. *Where does a set-up go?* A set-up is never under a profile, so its
+   phases are kept as `Phase` records in the observatory, on
+   `time.perf_counter()`, the clock of the stage intervals above: the body
+   of a `program_guard` (`paddle_tpu:program_build`, with the seconds
+   inside `registry.infer_op_shapes`), `Optimizer.minimize` inside it, and
+   every run() that binds or during which jax reports a compile, with its
+   phases (`RunSpans.keep`). A compile jax reports inside a run() but
+   outside its jitted call, or inside a set-up phase with no run open, is
+   counted there (`EagerCompiles`), not dropped and not given a cause. On
+   at default flags, as the compile events are. A steady step keeps
+   nothing: it pays the clock reads, no allocation, no lock, no write to
+   any store.
 """
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
@@ -74,24 +92,33 @@ from . import flight as _flight
 from . import metrics as _metrics
 from . import tracer as _tracer
 
-# (span in the profiler's trace, StepStats key) of each host phase of a
-# run(); constants, so a step builds no string. `device_compute` is the
-# host wall of the jitted call: dispatch only under async dispatch (the
-# device runs on after it returns), trace + lower + compile on a first call.
-FEED_CONVERT = ("paddle_tpu:feed_convert", "feed_convert")
-BIND = ("paddle_tpu:bind", "bind")
-STATE_GATHER = ("paddle_tpu:state_gather", "state_gather")
-JIT_CALL = ("paddle_tpu:jit_call", "device_compute")
-WRITE_BACK = ("paddle_tpu:write_back", "write_back")
-FETCH = ("paddle_tpu:fetch", "fetch")
+# (span in the profiler's trace, StepStats key, the RunSpans slot that keeps
+# its start) of each host phase of a run(); constants, so a step builds no
+# string. `device_compute` is the host wall of the jitted call: dispatch only
+# under async dispatch (the device runs on after it returns), trace + lower +
+# compile on a first call.
+FEED_CONVERT = ("paddle_tpu:feed_convert", "feed_convert", "_t_feed_convert")
+BIND = ("paddle_tpu:bind", "bind", "_t_bind")
+STATE_GATHER = ("paddle_tpu:state_gather", "state_gather", "_t_state_gather")
+JIT_CALL = ("paddle_tpu:jit_call", "device_compute", "_t_jit_call")
+WRITE_BACK = ("paddle_tpu:write_back", "write_back", "_t_write_back")
+FETCH = ("paddle_tpu:fetch", "fetch", "_t_fetch")
+# in the order a run() passes through them
+RUN_PHASES = (FEED_CONVERT, BIND, STATE_GATHER, JIT_CALL, WRITE_BACK, FETCH)
+RUN = "paddle_tpu:run"
+# the set-up phases outside a run(): the body of `program_guard`, and
+# `Optimizer.minimize` inside it
+PROGRAM_BUILD = "paddle_tpu:program_build"
+MINIMIZE = "paddle_tpu:minimize"
 # the two places a host-fed loop waits outside the phases above
 READER_POP = "paddle_tpu:reader_pop"    # py_reader.next_feed()
 FEEDER_PUT = "paddle_tpu:feeder_put"    # AsyncFeeder's device transfer
 
-PHASES = tuple(key for _, key in (FEED_CONVERT, STATE_GATHER, JIT_CALL,
-                                  WRITE_BACK, FETCH, BIND))
+PHASES = tuple(w[1] for w in (FEED_CONVERT, STATE_GATHER, JIT_CALL,
+                              WRITE_BACK, FETCH, BIND))
 
 span = jax.profiler.TraceAnnotation
+_now = time.perf_counter     # the one clock of phases, stages and runs
 
 
 class StepStats:
@@ -189,6 +216,126 @@ class StepLog:
             self._count = 0
 
 
+class _Building(threading.local):
+    """What is open on this thread. `event`: the compile event last recorded
+    here; it takes the stage durations jax reports until the run() that built
+    it returns (RunSpans) or the thread records another. `run`: the RunSpans
+    in progress, if any. `phase`: the innermost open `Phase`."""
+    event = run = phase = None
+
+
+_building = _Building()
+
+
+class EagerCompiles:
+    """The compiles jax reported on this thread that built no step: inside a
+    run() but outside its jitted call (eager `jnp` calls in the executor's
+    own host code, or in a reader's), or inside a set-up phase with no run
+    open (a layer that computes a table with `jnp` while the Program is
+    built). Counted where they fell (`where`: the run's phase, or the
+    set-up phase's name) under the name jax gives the compiled function
+    (`names`), never given a cause. It takes what a `RecompileEvent` takes
+    from the listeners."""
+
+    __slots__ = ("compiles", "compile_s", "cache_hits", "cache_misses",
+                 "where", "names", "at")
+
+    def __init__(self):
+        self.compiles = self.cache_hits = self.cache_misses = 0
+        self.compile_s = 0.0
+        self.where: Dict[str, int] = {}
+        self.names: Dict[str, int] = {}
+        self.at = None
+
+    def add_stage(self, stage: str, seconds: float, name=None):
+        if stage == "backend":
+            self.compiles += 1
+            self.compile_s += seconds
+            self.where[self.at] = self.where.get(self.at, 0) + 1
+            self.names[name] = self.names.get(name, 0) + 1
+
+    def as_dict(self) -> dict:
+        return {"eager_compiles": self.compiles,
+                "eager_compile_s": round(self.compile_s, 6),
+                "eager_cache_hits": self.cache_hits,
+                "eager_cache_misses": self.cache_misses,
+                "eager_where": dict(self.where),
+                "eager_names": dict(self.names)}
+
+
+class Phase:
+    """One interval of a set-up on `time.perf_counter()`: the clock of
+    `RecompileEvent.add_stage`'s intervals, so phases, compile stages and a
+    caller's own stamps lie on one line.
+
+        with Phase(PROGRAM_BUILD, main._uid) as phase:
+            ...
+            phase.detail["ops"] = ...
+
+    opens a `TraceAnnotation` of that name (a profile taken over a whole
+    script shows the phase beside the device track) and, at exit, leaves the
+    record in the observatory: `name`, `program_uid` (the spans of one
+    Program share it), `parent` (the `id` of the phase open on this thread
+    when it started), `start`, `end`, `detail`. A recorded run() and its
+    phases are `Phase`s too, made by `RunSpans` from the times it kept; a
+    run's `event` is the compile event of the entry it called."""
+
+    __slots__ = ("id", "name", "program_uid", "parent", "start", "end",
+                 "detail", "event", "eager", "_outer", "_annotation")
+    _ids = itertools.count(1)
+
+    def __init__(self, name: str, program_uid: int, parent=None, start=None,
+                 end=None, detail=None, event=None, eager=None):
+        self.id = next(Phase._ids)
+        self.name, self.program_uid, self.parent = name, program_uid, parent
+        self.start, self.end = start, end
+        self.detail = {} if detail is None else detail
+        self.event, self.eager = event, eager
+
+    def __enter__(self):
+        self._outer = _building.phase
+        if self._outer is not None:
+            self.parent = self._outer.id
+        _building.phase = self
+        self._annotation = span(self.name, program=self.program_uid)
+        self.start = _now()
+        return self
+
+    def __exit__(self, *exc):
+        self.end = _now()
+        self._annotation.__exit__(None, None, None)
+        _building.phase = self._outer
+        _observatory.note_phases((self,))
+        return False
+
+    def add(self, key: str, seconds: float):
+        """`seconds` more, and one call more, of `key` on this phase and on
+        every phase it is inside: `<key>_s` and `<key>_calls` of `detail`."""
+        phase = self
+        while phase is not None:
+            d = phase.detail
+            d[key + "_s"] = d.get(key + "_s", 0.0) + seconds
+            d[key + "_calls"] = d.get(key + "_calls", 0) + 1
+            phase = phase._outer
+
+    def as_dict(self) -> dict:
+        detail = dict(self.detail)
+        if self.eager is not None:
+            detail.update(self.eager.as_dict())
+        return {"id": self.id, "name": self.name,
+                "program_uid": self.program_uid, "parent": self.parent,
+                "start": self.start, "end": self.end, "detail": detail}
+
+    def __repr__(self):
+        return (f"Phase({self.name!r}, uid={self.program_uid}, "
+                f"{(self.end or 0) - (self.start or 0):.6f} s)")
+
+
+def open_phase() -> Optional[Phase]:
+    """The innermost set-up phase open on this thread, if any."""
+    return _building.phase
+
+
 class RunSpans:
     """The host spans of one `PreparedProgram.run` (both executors' one).
 
@@ -200,27 +347,31 @@ class RunSpans:
     per-program run counter: what all spans of one step share, and by order
     the k-th module execution on the device's track), `program` and
     `source`. `phase()` ends the open child span and starts the next, so
-    the children are leaves that tile the run. At default flags that is
-    all it does, but for noting the run as this thread's current one and,
-    in `event`, the compile event of the entry it calls (for a compile that
-    jax reports inside it, `_on_duration`): a
-    `TraceAnnotation` costs one atomic check while no profile is taken. With
-    the `observe` flag on, the same boundaries fill a `StepStats`,
-    recorded after the run span has closed unless the body raised."""
+    the children are leaves that tile the run. At default flags a steady
+    step does that, notes itself as this thread's current run and, in
+    `event`, the compile event of the entry it calls (for a compile that
+    jax reports inside it, `_taker`), and reads `time.perf_counter()` once
+    at each boundary into a slot of its own; it keeps nothing. A
+    `TraceAnnotation` costs one atomic check while no profile is taken.
 
-    __slots__ = ("observing", "program_uid", "source", "which", "event",
-                 "_run", "_child", "_phases", "_key", "_t")
+    A run that binds, or during which jax reports a compile, is a first run
+    (`keep`): at exit the same times go to the observatory as `Phase`s, the
+    run and its phases. With the `observe` flag on they also fill a
+    `StepStats`. Both after the run span has closed, unless the body
+    raised."""
+
+    __slots__ = ("observing", "program_uid", "source", "step", "which",
+                 "event", "keep", "eager", "_run", "_child", "_t_run"
+                 ) + tuple(w[2] for w in RUN_PHASES)
 
     def __init__(self, program_uid: int, source: str, step: int):
         self.observing = _flags.get_flag("observe")
-        self.program_uid, self.source = program_uid, source
-        self.which = self._child = self.event = None
-        self._run = span("paddle_tpu:run", step=step, program=program_uid,
-                         source=source)
+        self.program_uid, self.source, self.step = program_uid, source, step
+        self.which = self._child = self.event = self.eager = None
+        self.keep = False
+        self._t_run = _now()
+        self._run = span(RUN, step=step, program=program_uid, source=source)
         _building.run = self
-        if self.observing:
-            self._phases: Dict[str, float] = {}
-            self._key = None
 
     def __enter__(self):
         return self
@@ -229,16 +380,21 @@ class RunSpans:
         if self._child is not None:
             self._child.__exit__(None, None, None)
         self.which = which
+        if which is BIND:
+            self.keep = True
+        setattr(self, which[2], _now())
         self._child = span(which[0])
-        if self.observing:
-            self._tick(which[1])
 
-    def _tick(self, key):
-        now = time.perf_counter()
-        if self._key is not None:
-            self._phases[self._key] = (self._phases.get(self._key, 0.0)
-                                       + now - self._t)
-        self._key, self._t = key, now
+    def _intervals(self, end):
+        """[(phase constant, start, end)] of the phases this run opened:
+        each ends where the next began, the last with the run."""
+        out = []
+        for which in reversed(RUN_PHASES):
+            start = getattr(self, which[2], None)
+            if start is not None:
+                out.append((which, start, end))
+                end = start
+        return out[::-1]
 
     def __exit__(self, exc_type, exc, tb):
         if self._child is not None:
@@ -246,11 +402,15 @@ class RunSpans:
         self._run.__exit__(None, None, None)
         # the compile event this run built stops taking stage durations
         _building.run = _building.event = None
-        if self.observing:
-            self._tick(None)
-            if exc_type is None:
-                _steplog.record(StepStats(self.program_uid, self.source,
-                                          time.time(), self._phases))
+        if (self.keep or self.observing) and exc_type is None:
+            end = _now()
+            phases = self._intervals(end)
+            if self.keep:
+                _observatory.note_run(self, phases, end)
+            if self.observing:
+                _steplog.record(StepStats(
+                    self.program_uid, self.source, time.time(),
+                    {which[1]: e - s for which, s, e in phases}))
         return False
 
 
@@ -263,12 +423,6 @@ _STAGES = {
 }
 _CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
                  "/jax/compilation_cache/cache_misses": "cache_misses"}
-
-# `event`: the compile event last recorded on this thread; it takes the
-# stage durations jax reports until the run() that built it returns
-# (RunSpans) or the thread records another. `run`: the RunSpans in progress
-# on this thread, if any.
-_building = threading.local()
 
 
 class RecompileEvent:
@@ -312,11 +466,12 @@ class RecompileEvent:
                 self._op_map = build(text)
         return self._op_map
 
-    def add_stage(self, stage: str, seconds: float):
+    def add_stage(self, stage: str, seconds: float, name=None):
         """jax reports a duration when it ends, and a traced function that
         calls jitted ones reports theirs inside its own: keep the union
-        (durations arrive in order of their ends)."""
-        end = time.perf_counter()
+        (durations arrive in order of their ends). `name`, jax's of the
+        function, is not kept: the event is one step's."""
+        end = _now()
         start = end - seconds
         merged = self._stage_spans.setdefault(stage, [])
         while merged and merged[-1][0] >= start:
@@ -333,12 +488,20 @@ class RecompileEvent:
         return {stage: sum(e - s for s, e in merged)
                 for stage, merged in self._stage_spans.items()}
 
+    def stage_intervals(self) -> Dict[str, List[Tuple[float, float]]]:
+        """The merged `(start, end)` of each stage, on `perf_counter()`."""
+        return {stage: [(s, e) for s, e in merged]
+                for stage, merged in self._stage_spans.items()}
+
     def as_dict(self) -> dict:
         return {"ts": self.ts, "program_uid": self.program_uid,
                 "cause": self.cause, "source": self.source,
                 "detail": self.detail,
                 "stages_s": {k: round(v, 6)
                              for k, v in self.stages_s.items()},
+                "stage_intervals": {
+                    stage: [[round(s, 6), round(e, 6)] for s, e in spans]
+                    for stage, spans in self.stage_intervals().items()},
                 # disjoint backend intervals: 2 = the step was built twice
                 "backend_compiles": len(self._stage_spans.get("backend", ())),
                 "cache_hits": self.cache_hits,
@@ -389,8 +552,10 @@ class RecompilationObservatory:
     docstring) reports jax-level retraces of an already-bound entry as
     ``feed_shape``."""
 
-    def __init__(self, capacity: int = 256):
+    def __init__(self, capacity: int = 256, phase_capacity: int = 1024):
         self._events: deque = deque(maxlen=capacity)
+        # the set-up store: `Phase`s of program builds and of first runs
+        self._phases: deque = deque(maxlen=phase_capacity)
         self._lock = threading.Lock()
         # program uid -> {field of CAUSE_OF_FIELD: the values built with}
         self._seen: Dict[int, Dict[str, set]] = {}
@@ -445,6 +610,30 @@ class RecompilationObservatory:
         self._emit_metric(cause, source)
         return event
 
+    def note_phases(self, phases):
+        """`Phase`s that have closed (an inner one before its outer)."""
+        with self._lock:
+            self._phases.extend(phases)
+
+    def note_run(self, spans: "RunSpans", intervals, end: float):
+        """A first run (`RunSpans.keep`): its `paddle_tpu:run` interval and,
+        under it, `intervals` (`RunSpans._intervals`), with the compile
+        event it fed and the compiles that fell outside its jitted call."""
+        outer = _building.phase
+        run = Phase(RUN, spans.program_uid,
+                    parent=None if outer is None else outer.id,
+                    start=spans._t_run, end=end,
+                    detail={"source": spans.source, "step": spans.step},
+                    event=spans.event, eager=spans.eager)
+        self.note_phases([run] + [
+            Phase(which[0], spans.program_uid, parent=run.id, start=s, end=e)
+            for which, s, e in intervals])
+
+    def phases(self) -> List["Phase"]:
+        """The set-up store: program builds, first runs and their phases."""
+        with self._lock:
+            return list(self._phases)
+
     def latest(self, program_uid: int) -> Optional[RecompileEvent]:
         """The program's most recent compile event, if the ring holds one."""
         with self._lock:
@@ -482,9 +671,16 @@ class RecompilationObservatory:
         --assert-no-recompiles fails on."""
         return [e for e in self.events() if e.cause not in EXPECTED_CAUSES]
 
+    def as_dict(self) -> dict:
+        """What `observe.summary()` / `/status` carry under `recompiles`."""
+        return {"counts": self.counts(),
+                "events": [e.as_dict() for e in self.events()],
+                "phases": [p.as_dict() for p in self.phases()]}
+
     def clear(self):
         with self._lock:
             self._events.clear()
+            self._phases.clear()
             self._seen.clear()
 
 
@@ -492,29 +688,54 @@ _steplog = StepLog()
 _observatory = RecompilationObservatory()
 
 
-def _on_duration(event, seconds, **_):
+def _eager(holder, at) -> EagerCompiles:
+    """The `EagerCompiles` of a run or an open phase, made at its first
+    compile; `at` is where the next one falls."""
+    if holder.eager is None:
+        holder.eager = EagerCompiles()
+    holder.eager.at = at
+    return holder.eager
+
+
+def _taker():
+    """What takes the compile jax reports on this thread now. Inside the
+    jitted call of a run() it is a step being built: the event the thread
+    last recorded, or, where the executor recorded no cause, the event of
+    the entry that run() called, for the rest of the run. Elsewhere inside a
+    run() it is host code compiling on its own: the run's `EagerCompiles`.
+    With no run open, the event being built (a serving warm-up) or the open
+    set-up phase's `EagerCompiles`; else nothing (a user's own jnp code)."""
+    run = _building.run
+    if run is not None:
+        run.keep = True
+        if run.which is not JIT_CALL:
+            return _eager(run, run.which[1] if run.which else "run")
+        if _building.event is None:
+            _building.event = run.event
+        return _building.event
+    if _building.event is not None:
+        return _building.event
+    phase = _building.phase
+    return None if phase is None else _eager(phase, phase.name)
+
+
+def _on_duration(event, seconds, fun_name=None, **_):
     stage = _STAGES.get(event)
-    if stage is None:
-        return
-    building = getattr(_building, "event", None)
-    if building is None:
-        # jax compiles and the executor recorded no cause. Inside the
-        # jitted call of a run() it is that run's entry being built
-        # again: the entry's own event takes the cost for the rest of
-        # this run(). Anywhere else (a user's own jnp code) it is not the
-        # executor's to record.
-        run = getattr(_building, "run", None)
-        if run is None or run.which is not JIT_CALL or run.event is None:
-            return
-        building = _building.event = run.event
-    building.add_stage(stage, seconds)
+    if stage is not None:
+        taker = _taker()
+        if taker is not None:
+            taker.add_stage(stage, seconds, fun_name)
 
 
 def _on_event(event, **_):
     attr = _CACHE_EVENTS.get(event)
-    building = getattr(_building, "event", None)
-    if attr is not None and building is not None:
-        setattr(building, attr, getattr(building, attr) + 1)
+    if attr is not None:
+        taker = _taker()
+        if taker is not None:
+            setattr(taker, attr, getattr(taker, attr) + 1)
+            if attr == "cache_misses":
+                # nothing was read from the cache: 0 s beside the stages
+                taker.add_stage("cache_retrieval", 0.0)
 
 
 # they fire only when something compiles, never in a steady step

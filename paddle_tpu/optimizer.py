@@ -17,6 +17,7 @@ from typing import Dict, List, Optional
 
 from .core import ir
 from .core.backward import append_backward
+from .observe import steplog as _steplog
 from .layer_helper import LayerHelper
 from . import initializer as init
 from . import unique_name
@@ -102,15 +103,17 @@ class Optimizer:
                  no_grad_set=None):
         """append_backward + regularization + clip + update ops
         (reference optimizer.py:245)."""
-        block = loss.block.program.global_block()
+        program = loss.block.program
+        block = program.global_block()
         n0 = len(block.ops)
-        params_grads = append_backward(loss, parameter_list=parameter_list,
-                                       no_grad_set=no_grad_set)
-        params_grads = append_gradient_clip_ops(params_grads)
-        params_grads = append_regularization_ops(params_grads,
-                                                 self.regularization)
-        optimize_ops = self._create_optimization_pass(params_grads, loss,
-                                                      startup_program)
+        with _steplog.Phase(_steplog.MINIMIZE, program._uid):
+            params_grads = append_backward(
+                loss, parameter_list=parameter_list, no_grad_set=no_grad_set)
+            params_grads = append_gradient_clip_ops(params_grads)
+            params_grads = append_regularization_ops(params_grads,
+                                                     self.regularization)
+            optimize_ops = self._create_optimization_pass(
+                params_grads, loss, startup_program)
         # role-tag everything minimize appended (clip/reg/lr/update ops);
         # grad ops were already tagged "backward" by append_backward. Eval
         # clones strip by role (ir._set_inference_mode).

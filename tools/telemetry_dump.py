@@ -96,6 +96,7 @@ def print_status_table(doc):
               f"persistent cache "
               f"{e.get('cache_hits', 0)} hit(s), "
               f"{e.get('cache_misses', 0)} miss(es)")
+    print_setup_timeline(doc["recompiles"])
     mem = doc.get("memory") or {}
     if mem.get("programs"):
         print(f"memory: peak est {mem['estimate_peak_bytes'] / 1e6:.2f} MB "
@@ -113,6 +114,38 @@ def print_status_table(doc):
     for line in wire_table_from_snapshot(doc["metrics"]):
         print(line)
     print("metrics:", ", ".join(sorted(doc["metrics"])))
+
+
+def print_setup_timeline(recompiles):
+    """The set-up store on one line of time: every program build, first run
+    and phase of it (`observe/steplog.py::Phase`), from the first one's
+    start, a run's compile stages inside it."""
+    phases = sorted(recompiles.get("phases", []), key=lambda p: p["start"])
+    if not phases:
+        return
+    t0 = phases[0]["start"]
+    depth = {}
+    print(f"set-up timeline ({len(phases)} phases, seconds from the first):")
+    for p in phases:
+        d = depth[p["id"]] = depth.get(p["parent"], 0) + 1
+        detail = {k: v for k, v in p["detail"].items()
+                  if v not in (0, {}, None)}
+        print(f"{'  ' * d}{p['start'] - t0:9.4f} - {p['end'] - t0:9.4f}  "
+              f"{p['name']} program {p['program_uid']}"
+              + (f"  {detail}" if detail else ""))
+        if p["name"] != "paddle_tpu:run":
+            continue
+        for e in recompiles.get("events", []):
+            if e["program_uid"] != p["program_uid"]:
+                continue
+            for stage, spans in e.get("stage_intervals", {}).items():
+                inside = [(s, t) for s, t in spans
+                          if p["start"] <= s and t <= p["end"]]
+                if inside:
+                    print(f"{'  ' * (d + 1)}{inside[0][0] - t0:9.4f} - "
+                          f"{inside[-1][1] - t0:9.4f}  {e['cause']} {stage} "
+                          f"{sum(t - s for s, t in inside):.4f} s in "
+                          f"{len(inside)} interval(s)")
 
 
 def _fetch(url: str, timeout: float = 10.0):
