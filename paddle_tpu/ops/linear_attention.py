@@ -23,30 +23,54 @@ Every exponent is <= 0, so nothing overflows however negative g is.
 
 Where a chunk's tiles fill vregs (`_plan`: chunk 64, both head dims
 multiples of 128: the published widths) the rule is two Pallas kernels on a
-grid of (batch, key head, chunk), the chunk axis sequential; a grid step
-takes the key head's value heads in turn, so q, k and their Gram tiles
-`k k^T`, `q k^T` are made once for them and no head is repeated in HBM.
+grid of (batch, key head, step of `p` chunks), the last axis sequential;
+`_plan` takes `p` = 2 consecutive chunks a step where the chunk count is
+even (and the pair's blocks fit the VMEM a call has unasked), 1 otherwise,
+through one kernel body each way. A grid step takes the key head's value
+heads together, so q, k and their Gram tiles `k k^T`, `q k^T` are made once
+for them and no head is repeated in HBM. Of a chunk's chain everything up
+to u and w reads no state, so a step first makes those factors for its `p`
+chunks and `r` heads at once (`_Chunks`): the `[64, 64]` tiles of the two
+chunks side by side in one `[64, 128]` tile, a vreg's 128 lanes full, for
+all that is elementwise (D, A, P and their gradients); the substitution's
+diagonal blocks of every head and chunk side by side in one tile, so its
+row updates run once a step; and one MXU product for the pair wherever a
+product is due, its operands the chunks' rows stacked `[128, n]` and, for
+T, P and the Gram tiles' gradients, the pair's tiles as the diagonal blocks
+of a `[128, 128]` matrix (a `[64, 64]` operand took the MXU as long). Then
+the state passes through the step's chunks in order: `v' = u - w S`,
+`S <- S exp(G_last) + k_tail^T v'`, four dependent one-pass products a
+pair, all that is left of the chain; a single chunk is `p = 1` of the same
+body.
 
-    gdn_fwd   reads a chunk's q, k, v (as they arrive: bf16 under AMP), G and
-              beta; keeps S [Dk, Dv] float32 in VMEM scratch across the
-              chunks; makes the l2-norms, D, A, T, u, w, v' and the scores in
+    gdn_fwd   reads the step's q, k, v (as they arrive: bf16 under AMP), G
+              and beta; keeps S [Dk, Dv] float32 in VMEM scratch across the
+              steps; makes the l2-norms, D, A, T, u, w, v' and the scores in
               VMEM and writes none of them: only o and `States`, S as each
-              chunk found it (float32 [chunks, B, Hv, Dk, Dv]). o
+              chunk found it, the second of a pair inside its step (float32
+              [chunks, B, Hv, Dk, Dv]). o
               [B, T, Hv * Dv] is read where it lies by `gated_norm_fwd`
               (`ops/decoder_block.py`: the layer's output norm), whose
               backward `gated_norm_bwd` writes `gdn_bwd`'s dO the same way:
               no XLA op and no layout copy stands between the two pairs.
-    gdn_bwd   the chunks last to first, dS [Dk, Dv] float32 in scratch;
-              computes the chunk's factors again from its inputs and its
-              saved state; with X = [u | w] = T R: dR = T^T dX,
-              dA = -strict_lower(dR X^T); writes dv, dq and dk (summed over
-              a key head's value heads, through the l2-norm), dbeta, and dG
-              per token, whose reverse running sum inside a chunk (one
-              small XLA op, like the running sum G itself) is g's gradient.
+    gdn_bwd   the steps, and the chunks inside one, last to first, dS
+              [Dk, Dv] float32 in scratch; computes the chunks' factors
+              again from their inputs and their saved states (every chunk's
+              state is saved, so only dS passes from chunk to chunk: two
+              dependent products a chunk); with X = [u | w] = T R: dR =
+              T^T dX, dA = -strict_lower(dR X^T); writes dv, dq and dk
+              (summed over a key head's value heads, through the l2-norm),
+              dbeta, and dG per token, whose reverse running sum inside a
+              chunk (one small XLA op, like the running sum G itself) is
+              g's gradient.
 
-T comes from blocked forward substitution (`_unit_lower_inverse`): the
-32-wide diagonal blocks row by row in float32 on the VPU, then merged pair
-by pair; no series in A, which would lose digits where |A| is near 1.
+The op and its grad op tally the grid steps of their calls on the compile
+event (`gdn_grid_steps`: batch x key heads x chunks / `p`, summed).
+
+T comes from blocked forward substitution (`_Chunks.inverses`): the
+16-wide diagonal blocks column by column in float32 on the VPU (15 rank-one
+updates of one tile that holds every block of the step), then merged pair
+by pair, twice; no series in A, which would lose digits where |A| is near 1.
 Float32 whatever dtype flows through: g, beta, G, D, the l2-norms, A, T, the
 state and dS, every accumulator and every product's result
 (`preferred_element_type=float32` on every `dot`). `precision=HIGHEST`
@@ -227,18 +251,27 @@ _HI = lax.Precision.HIGHEST
 _NN = ((1,), (0,))      # a b
 _NT = ((1,), (1,))      # a b^T
 _TN = ((0,), (0,))      # a^T b
-_SUB = 32               # the diagonal blocks the substitution inverts by rows
+_SUB = 16               # the diagonal blocks the substitution inverts on the VPU
+_VMEM = 12 << 20        # of the 16 MiB a call has unasked, what blocks may take
 
 
-def _plan(Dk, Dv, chunk):
-    """"kernel": a chunk's tiles fill vregs (head dims whole lanes of 128,
-    the chunk the 64 tokens the blocked substitution is laid out for).
-    "xla": anything else (the tiny head dims of the CPU tests), which keeps
-    `chunked_gated_delta_rule` and its vjp. One algorithm either way; the
-    choice reads the shape alone."""
-    if chunk == 64 and Dk % 128 == 0 and Dv % 128 == 0:
-        return "kernel"
-    return "xla"
+def _plan(Dk, Dv, chunk, chunks=1, r=1):
+    """("kernel", p): a chunk's tiles fill vregs (head dims whole lanes of
+    128, the chunk the 64 tokens the blocked substitution is laid out for),
+    and a grid step takes `p` consecutive chunks of a key head: 2 where the
+    chunks pair up and the pair's blocks (twice, the pipeline holds two of
+    each) and the state fit the VMEM a call has without asking for more, 1
+    otherwise. ("xla", 0): anything else (the tiny head dims of the CPU
+    tests), which keeps `chunked_gated_delta_rule` and its vjp. One
+    algorithm either way; the choice reads the shape alone."""
+    if not (chunk == 64 and Dk % 128 == 0 and Dv % 128 == 0):
+        return "xla", 0
+
+    def vmem(p):    # the backward's blocks (the larger), float32 operands
+        tokens = 4 * p * chunk * (4 * Dk + 3 * r * Dv)  # q k v dO dv dq dk
+        return 2 * (tokens + 4 * p * r * Dk * Dv) + 4 * r * Dk * Dv
+
+    return "kernel", 2 if chunks % 2 == 0 and vmem(2) <= _VMEM else 1
 
 
 def _on_chip():
@@ -254,7 +287,7 @@ def _backend_takes_kernels():
 
 
 def _kernels_run(Dk, Dv, chunk):
-    return _plan(Dk, Dv, chunk) == "kernel" and _backend_takes_kernels()
+    return _plan(Dk, Dv, chunk)[0] == "kernel" and _backend_takes_kernels()
 
 
 def _dot(a, b, dims, full=False):
@@ -273,11 +306,11 @@ def _dot(a, b, dims, full=False):
 
 
 def _rows(x):
-    return jnp.sum(x, axis=1, keepdims=True)            # [C, n] -> [C, 1]
+    return jnp.sum(x, axis=1, keepdims=True)            # [n, m] -> [n, 1]
 
 
 def _cols(x):
-    return jnp.sum(x, axis=0, keepdims=True)            # [C, n] -> [1, n]
+    return jnp.sum(x, axis=0, keepdims=True)            # [n, m] -> [1, m]
 
 
 def _l2(x_ref):
@@ -292,171 +325,271 @@ def _l2_grad(y, r, dy):
     return r * (dy - y * _rows(y * dy))
 
 
-def _unit_lower_inverse(a, a_t, row, col):
-    """(I + a)^-1 of a strictly lower [C, C] tile (`a_t` its transpose), by
-    blocked forward substitution. The `_SUB`-wide diagonal blocks row by
-    row on the VPU, all blocks at once: row i of a block is `-a_i -
-    sum_{j<i} a_ij row_j`. Then pairs of blocks merged level by level,
-    `[[T1, 0], [-T2 a21 T1, T2]]`, with HIGHEST products."""
-    C = a.shape[0]
-    shift = _SUB.bit_length() - 1
+class _Chunks:
+    """What both kernels compute of one (batch, key head, `p` chunks) grid
+    step before they part. Two layouts of the `p` chunks' tokens:
 
-    def at(x):                                          # place in its block
-        return jnp.bitwise_and(x, _SUB - 1)
+      stacked   [p C, n]: chunk i in rows i C .. (i + 1) C - 1. q (normalised,
+                scaled), k (normalised), v, u, w, every per-token column
+                [p C, 1]: what a product takes or gives by rows.
+      beside    [C, p C]: a [C, C] tile of each chunk side by side, chunk i in
+                lanes i C .. (i + 1) C - 1, so the pair fills a vreg's 128
+                lanes: the Gram tiles, D, A, P and all that is elementwise
+                on them.
 
-    same = jnp.right_shift(row, shift) == jnp.right_shift(col, shift)
-    y = jnp.where(same, -a, 0.0)
-    n_t = jnp.where(same, -a_t, 0.0)
-    for i in range(1, _SUB):
-        # row i's multipliers, of every block, down the sublanes
-        m = _rows(jnp.where(at(col) == i, n_t, 0.0))
-        y = y + jnp.where(same & (at(row) == i), _cols(m * y), 0.0)
-    t = y + jnp.where(row == col, 1.0, 0.0)
-    while (1 << shift) < C:
-        below = (jnp.right_shift(row, shift)
-                 == jnp.right_shift(col, shift) + 1) \
-            & (jnp.right_shift(row, shift + 1)
-               == jnp.right_shift(col, shift + 1))
-        t = t - _dot(_dot(t, jnp.where(below, a, 0.0), _NN, full=True), t,
-                     _NN, full=True)
-        shift += 1
-    return t
+    `beside` takes a product of stacked operands [p C, p C] to its diagonal
+    blocks side by side, `apart` a beside tile to the block-diagonal [p C,
+    p C] matrix a product reads, so one MXU product serves the `p` chunks;
+    what the blocks off the diagonal hold is dropped or zero. `heads(...)`
+    gives the factors of the key head's value heads that read no state."""
 
-
-class _Chunk:
-    """What both kernels compute of one (batch, key head, chunk) grid step
-    before they part: q (normalised, scaled) and k (normalised), their two
-    Gram tiles, the index masks; `head(j, ...)` then gives the factors of
-    the key head's value head j."""
-
-    def __init__(self, q_ref, k_ref, g_ref, beta_ref, hk, r):
-        C, Dk = q_ref.shape[1], q_ref.shape[2]
+    def __init__(self, q_ref, k_ref, g_ref, beta_ref, hk, r, p):
+        n, Dk = q_ref.shape[1], q_ref.shape[2]
+        self.r, self.p, self.C = r, p, n // p
+        C = self.C
         self.first_head = hk * r
         self.qn, self.rq = _l2(q_ref)
         self.k, self.rk = _l2(k_ref)
         self.scale = Dk ** -0.5
         self.q = self.qn * self.scale
-        self.row = lax.broadcasted_iota(jnp.int32, (C, C), 0)
-        self.col = lax.broadcasted_iota(jnp.int32, (C, C), 1)
-        self.kk = _dot(self.k, self.k, _NT, full=True)      # k k^T
-        self.qk = _dot(self.q, self.k, _NT)                 # q k^T
-        self.G_tile, self.beta_tile = g_ref[0, 0], beta_ref[0, 0]  # [C, Hv]
+        lane = lax.broadcasted_iota(jnp.int32, (C, n), 1)
+        self.row = lax.broadcasted_iota(jnp.int32, (C, n), 0)
+        self.col = jnp.bitwise_and(lane, C - 1)         # place in its chunk
+        self.part = jnp.right_shift(lane, C.bit_length() - 1)   # its chunk
+        self.kk = self.beside(_dot(self.k, self.k, _NT, full=True))  # k k^T
+        self.qk = self.beside(_dot(self.q, self.k, _NT))             # q k^T
+        self.G_tile, self.beta_tile = g_ref[0], beta_ref[0]      # [p C, Hv]
+
+    def of(self, i, x):                                 # chunk i's rows
+        return x[i * self.C:(i + 1) * self.C]
+
+    def each(self, fn):
+        """`fn(i)` [C, n] of every chunk, stacked."""
+        return jnp.concatenate([fn(i) for i in range(self.p)], axis=0)
+
+    def beside(self, x):
+        """Stacked [p C, m] -> [C, p C] or [C, 1]: chunk i's rows in chunk
+        i's lanes (a product's diagonal blocks; a column, ready to be
+        broadcast along its chunk's lanes)."""
+        out = self.of(0, x)
+        for i in range(1, self.p):
+            out = jnp.where(self.part == i, self.of(i, x), out)
+        return out
+
+    def apart(self, tile):
+        """Beside [C, p C] -> block diagonal [p C, p C]."""
+        return self.each(lambda i: jnp.where(self.part == i, tile, 0.0))
+
+    def rows(self, tile):
+        """Each chunk's row sums of a beside tile, stacked [p C, 1]."""
+        return self.each(lambda i: _rows(jnp.where(self.part == i, tile,
+                                                   0.0)))
+
+    def as_row(self, column):
+        """A stacked column [p C, 1] as a beside row [1, p C]."""
+        return _cols(jnp.where(self.row == self.col, self.beside(column),
+                               0.0))
 
     def _column(self, tile, j):
         lane = lax.broadcasted_iota(jnp.int32, tile.shape, 1)
         return _rows(jnp.where(lane == self.first_head + j, tile, 0.0))
 
-    def as_row(self, column):                           # [C, 1] -> [1, C]
-        return _cols(jnp.where(self.row == self.col, column, 0.0))
-
-    def head(self, j, v_ref, S):
-        """The chunk's factors for value head j, from the state S [Dk, Dv]
-        it starts from (module docstring's names)."""
+    def inverses(self, a):
+        """(I + a)^-1 of each chunk's strictly lower [C, C] tile, for every
+        value head at once (`a`: the heads' tiles, each beside), by blocked
+        forward substitution; block diagonal [p C, p C] a head. The
+        `_SUB`-wide diagonal blocks of all heads and chunks side by side
+        in one [_SUB, r p C] tile, identity to start with, column by
+        column on the VPU: `y[m] -= a[m, i] y[i]` for the rows under row i,
+        the multipliers `a[:, i]` spread along their block's lanes by a
+        gather. Then pairs of blocks merged level by level, `[[T1, 0],
+        [-T2 a21 T1, T2]]`, with HIGHEST products."""
         row, col = self.row, self.col
-        C, Dv = row.shape[0], S.shape[1]
-        G = self._column(self.G_tile, j)                # running sum, <= 0
-        beta = self._column(self.beta_tile, j)
-        G_row = self.as_row(G)
-        # exponents <= 0 where they are kept; an overflow above (below) the
-        # diagonal is dropped by the select, nothing is differentiated here
-        D = jnp.where(row >= col, jnp.exp(G - G_row), 0.0)
-        kkD = self.kk * D
-        a = jnp.where(row > col, kkD * beta, 0.0)
-        a_t = jnp.where(row < col, self.kk * jnp.exp(G_row - G)
-                        * self.as_row(beta), 0.0)
-        t = _unit_lower_inverse(a, a_t, row, col)
-        eg = jnp.exp(G)
-        v = v_ref[0, :, j * Dv:(j + 1) * Dv].astype(jnp.float32)
-        k_beg = self.k * (beta * eg)
-        u = _dot(t, v * beta, _NN, full=True)
-        w = _dot(t, k_beg, _NN, full=True)
-        last = G[C - 1:C, :]                            # [1, 1]
-        tail = jnp.exp(last - G)
-        return dict(beta=beta, D=D, kkD=kkD, a=a, t=t, eg=eg, v=v, tail=tail,
-                    k_beg=k_beg, u=u, w=w, v_new=u - _dot(w, S, _NN),
-                    P=self.qk * D, qg=self.q * eg, k_tail=self.k * tail,
-                    e_last=jnp.exp(last))
+        n = self.p * self.C
+        shift = _SUB.bit_length() - 1
+        same = jnp.right_shift(row, shift) == jnp.right_shift(col, shift)
+        blocks = range(self.C // _SUB)
+
+        def folded(x):      # its diagonal blocks, each in its own lanes
+            x = jnp.where(same, x, 0.0)
+            return sum(x[b * _SUB:(b + 1) * _SUB] for b in blocks)
+
+        minus = [-folded(x) for x in a]
+        fill = -(len(a) * n) % 128                      # whole vregs of lanes
+        if fill:
+            minus.append(jnp.zeros((_SUB, fill), jnp.float32))
+        minus = jnp.concatenate(minus, axis=1)
+        lane = lax.broadcasted_iota(jnp.int32, (_SUB, 128), 1)
+        first = jnp.bitwise_and(lane, -_SUB)            # its block's lane 0
+        eye = jnp.where(lax.broadcasted_iota(jnp.int32, (_SUB, 128), 0)
+                        == lane - first, 1.0, 0.0)
+        minus = [minus[:, x:x + 128] for x in range(0, minus.shape[1], 128)]
+        y = [eye] * len(minus)              # a vreg of lanes each, in step
+        for i in range(_SUB - 1):
+            y = [y_at + jnp.take_along_axis(m, first + i, axis=1)
+                 * y_at[i:i + 1] for m, y_at in zip(minus, y)]
+        y = jnp.concatenate(y, axis=1)
+        t = [self.apart(jnp.where(same, jnp.concatenate(
+            [y[:, j * n:(j + 1) * n]] * len(blocks), axis=0), 0.0))
+            for j in range(len(a))]
+        row = lax.broadcasted_iota(jnp.int32, (n, n), 0)
+        col = lax.broadcasted_iota(jnp.int32, (n, n), 1)
+        a = [self.apart(x) for x in a]
+        while (1 << shift) < self.C:
+            below = (jnp.right_shift(row, shift)
+                     == jnp.right_shift(col, shift) + 1) \
+                & (jnp.right_shift(row, shift + 1)
+                   == jnp.right_shift(col, shift + 1))
+            x = [_dot(tj, jnp.where(below, aj, 0.0), _NN, full=True)
+                 for tj, aj in zip(t, a)]
+            t = [tj - _dot(xj, tj, _NN, full=True) for tj, xj in zip(t, x)]
+            shift += 1
+        return t
+
+    def heads(self, v_ref, Dv):
+        """The chunks' factors that read no state, of the key head's `r`
+        value heads stage by stage (module docstring's names): the
+        per-token columns, v, u, w, qg, k_tail stacked; D, kkD, a, P beside;
+        t block diagonal; a chunk's last decay `e_last[i]` [1, 1]."""
+        row, col, C = self.row, self.col, self.C
+        heads = []
+        for j in range(self.r):
+            G = self._column(self.G_tile, j)            # running sum, <= 0
+            beta = self._column(self.beta_tile, j)
+            beta_b = self.beside(beta)
+            # exponents <= 0 where they are kept; an overflow above the
+            # diagonal is dropped by the select, nothing is differentiated
+            D = jnp.where(row >= col,
+                          jnp.exp(self.beside(G) - self.as_row(G)), 0.0)
+            kkD = self.kk * D
+            heads.append(dict(G=G, beta=beta, beta_b=beta_b, D=D, kkD=kkD,
+                              a=jnp.where(row > col, kkD * beta_b, 0.0)))
+        for j, (f, t) in enumerate(zip(heads,
+                                       self.inverses([f["a"] for f in heads]))):
+            G, beta = f["G"], f["beta"]
+            eg = jnp.exp(G)
+            v = v_ref[0, :, j * Dv:(j + 1) * Dv].astype(jnp.float32)
+            k_beg = self.k * (beta * eg)
+            last = [G[(i + 1) * C - 1:(i + 1) * C, :] for i in range(self.p)]
+            tail = jnp.exp(self.each(lambda i: jnp.broadcast_to(last[i],
+                                                                (C, 1))) - G)
+            f.update(t=t, eg=eg, v=v, tail=tail, k_beg=k_beg,
+                     u=_dot(t, v * beta, _NN, full=True),
+                     w=_dot(t, k_beg, _NN, full=True), P=self.qk * f["D"],
+                     qg=self.q * eg, k_tail=self.k * tail,
+                     e_last=[jnp.exp(x) for x in last])
+        return heads
 
 
 def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, o_ref,
-                    s_sc, *, r):
-    """One (batch, key head, chunk) step, the key head's `r` value heads in
-    turn: writes the state as the chunk found it and the chunk's outputs,
-    and carries the state in scratch to the next chunk."""
+                    s_sc, *, r, p):
+    """One (batch, key head, `p` chunks) step for the key head's `r` value
+    heads: the factors that read no state for the `p` chunks at once, then
+    the state through the chunks in order (written as each found it,
+    carried in scratch to the next step), then the chunks' outputs."""
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
         s_sc[...] = jnp.zeros_like(s_sc)
 
-    ch = _Chunk(q_ref, k_ref, g_ref, beta_ref, pl.program_id(1), r)
+    ch = _Chunks(q_ref, k_ref, g_ref, beta_ref, pl.program_id(1), r, p)
     Dv = s_sc.shape[2]
-    for j in range(r):
-        S = s_sc[j]
-        states_ref[0, 0, j] = S
-        f = ch.head(j, v_ref, S)
-        o = _dot(f["qg"], S, _NN) + _dot(f["P"], f["v_new"], _NN)
+    heads = ch.heads(v_ref, Dv)
+    S = [s_sc[j] for j in range(r)]
+    v_new, from_state = [[] for _ in heads], [[] for _ in heads]
+    for i in range(p):
+        for j, f in enumerate(heads):
+            states_ref[i, 0, j] = S[j]
+            v_new[j].append(ch.of(i, f["u"])
+                            - _dot(ch.of(i, f["w"]), S[j], _NN))
+            from_state[j].append(_dot(ch.of(i, f["qg"]), S[j], _NN))
+            S[j] = S[j] * f["e_last"][i] \
+                + _dot(ch.of(i, f["k_tail"]), v_new[j][i], _TN)
+    for j, f in enumerate(heads):
+        s_sc[j] = S[j]
+        o = jnp.concatenate(from_state[j], axis=0) \
+            + _dot(ch.apart(f["P"]), jnp.concatenate(v_new[j], axis=0), _NN)
         o_ref[0, :, j * Dv:(j + 1) * Dv] = o.astype(o_ref.dtype)
-        s_sc[j] = S * f["e_last"] + _dot(f["k_tail"], f["v_new"], _TN)
 
 
 def _gdn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
-                    dG_ref, dbeta_ref, dv_ref, dq_ref, dk_ref, ds_sc, *, r):
-    """The same step with the chunks taken last to first. dS, the gradient
-    of the state a chunk hands on, is carried in scratch; the chunk's
-    factors are computed again from its inputs and its saved state. dG is
-    the gradient of the running sum at each token (g's is its reverse
-    running sum inside a chunk, taken outside)."""
+                    dG_ref, dbeta_ref, dv_ref, dq_ref, dk_ref, ds_sc, *, r,
+                    p):
+    """The same step with the steps, and the chunks inside one, taken last
+    to first. dS, the gradient of the state a chunk hands on, is carried in
+    scratch and passes through the step's chunks; the chunks' factors are
+    computed again from their inputs and their saved states, for the `p`
+    at once. dG is the gradient of the running sum at each token (g's is
+    its reverse running sum inside a chunk, taken outside)."""
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
         ds_sc[...] = jnp.zeros_like(ds_sc)
 
-    ch = _Chunk(q_ref, k_ref, g_ref, beta_ref, pl.program_id(1), r)
-    C, Dv = q_ref.shape[1], ds_sc.shape[2]
+    ch = _Chunks(q_ref, k_ref, g_ref, beta_ref, pl.program_id(1), r, p)
+    C, Dv = ch.C, ds_sc.shape[2]
     strict = ch.row > ch.col
-    at_last = lax.broadcasted_iota(jnp.int32, (1, C), 1) == C - 1
-    dkk = dqk = dq = dk = 0.0
-    for j in range(r):
-        S = states_ref[0, 0, j]
-        f = ch.head(j, v_ref, S)
+    at_last = (ch.col == C - 1)[:1]                     # [1, p C]
+    heads = ch.heads(v_ref, Dv)
+    # o = qg S + P v';  S' = e_last S + k_tail^T v';  v' = u - w S
+    for j, f in enumerate(heads):
+        S = [states_ref[i, 0, j] for i in range(p)]
         dO = do_ref[0, :, j * Dv:(j + 1) * Dv].astype(jnp.float32)
-        dS = ds_sc[j]
-        beta, eg, D = f["beta"], f["eg"], f["D"]
-        # o = qg S + P v';  S' = e_last S + k_tail^T v';  v' = u - w S
-        dv_new = _dot(f["P"], dO, _TN) + _dot(f["k_tail"], dS, _NN)
-        dP = _dot(dO, f["v_new"], _NT)      # read only times P or D: lower
-        dqg = _dot(dO, S, _NT)
-        dk_tail = _dot(f["v_new"], dS, _NT)
-        dw = -_dot(dv_new, S, _NT)
-        ds_sc[j] = _dot(f["qg"], dO, _TN) + dS * f["e_last"] \
-            - _dot(f["w"], dv_new, _TN)
-        d_last = _cols(_rows(S * dS)) * f["e_last"]      # [1, 1]
+        f.update(S=S, dO=dO, dS=[None] * p + [ds_sc[j]],   # dS[i + 1]: of
+                 dv_new=[None] * p,                     # what chunk i gives
+                 v_new=f["u"] - ch.each(
+                     lambda i: _dot(ch.of(i, f["w"]), S[i], _NN)),
+                 from_out=_dot(ch.apart(f["P"]), dO, _TN))
+    for i in reversed(range(p)):
+        for f in heads:
+            dS = f["dS"]
+            f["dv_new"][i] = ch.of(i, f["from_out"]) \
+                + _dot(ch.of(i, f["k_tail"]), dS[i + 1], _NN)
+            dS[i] = _dot(ch.of(i, f["qg"]), ch.of(i, f["dO"]), _TN) \
+                + dS[i + 1] * f["e_last"][i] \
+                - _dot(ch.of(i, f["w"]), f["dv_new"][i], _TN)
+    dkk = dqk = dq = dk = 0.0
+    for j, f in enumerate(heads):
+        S, dS, dO, v_new = f["S"], f["dS"], f["dO"], f["v_new"]
+        beta, eg, D, P = f["beta"], f["eg"], f["D"], f["P"]
+        ds_sc[j] = dS[0]
+        dv_new = jnp.concatenate(f["dv_new"], axis=0)
+        dP = ch.beside(_dot(dO, v_new, _NT))  # read only times P or D: lower
+        dqg = ch.each(lambda i: _dot(ch.of(i, dO), S[i], _NT))
+        dk_tail = ch.each(lambda i: _dot(ch.of(i, v_new), dS[i + 1], _NT))
+        dw = -ch.each(lambda i: _dot(ch.of(i, dv_new), S[i], _NT))
+        d_last = [_rows(_cols(S[i] * dS[i + 1])) * f["e_last"][i]
+                  for i in range(p)]                    # [1, 1] each
         # [u | w] = T [beta v | beta exp(G) k]:  dR = T^T dX,
         # dA = -strict_lower(dR X^T)
         dRu = _dot(f["t"], dv_new, _TN, full=True)
         dRw = _dot(f["t"], dw, _TN, full=True)
-        dA = -jnp.where(strict, _dot(dRu, f["u"], _NT, full=True)
-                        + _dot(dRw, f["w"], _NT, full=True), 0.0)
+        dA = -jnp.where(strict, ch.beside(
+            _dot(dRu, f["u"], _NT, full=True)
+            + _dot(dRw, f["w"], _NT, full=True)), 0.0)
         dv_ref[0, :, j * Dv:(j + 1) * Dv] = (dRu * beta).astype(dv_ref.dtype)
-        dbeta = _rows(dRu * f["v"]) + _rows(dRw * ch.k) * eg \
-            + _rows(dA * f["kkD"])
-        dbeta_ref[0, j, 0] = ch.as_row(dbeta)
+        through_w = _rows(dRw * ch.k) * eg              # w's rows: beta eg k
+        dbeta = _rows(dRu * f["v"]) + through_w + ch.rows(dA * f["kkD"])
         # G enters through D (dD * D = dA * A + dP * P), exp(G) and the
         # tail's exp(G_last - G); G_last also through e_last
-        M = dA * f["a"] + dP * f["P"]
-        d_tail = _rows(dk_tail * f["k_tail"])
-        dG = _rows(M) + _rows(dRw * f["k_beg"]) + _rows(dqg * f["qg"]) \
-            - d_tail
-        dG_ref[0, j, 0] = ch.as_row(dG) - _cols(M) \
-            + jnp.where(at_last, _cols(d_tail) + d_last, 0.0)
-        dkk = dkk + dA * (D * beta)
+        M = dA * f["a"] + dP * P
+        d_tail = dk_tail * f["k_tail"]
+        dG = ch.rows(M) + through_w * beta + _rows(dqg * f["qg"] - d_tail)
+        ends = ch.beside(ch.each(lambda i: jnp.broadcast_to(
+            _rows(_cols(ch.of(i, d_tail))) + d_last[i], (C, 1))))[:1]
+        dG_row = ch.as_row(dG) - _cols(M) + jnp.where(at_last, ends, 0.0)
+        dbeta_row = ch.as_row(dbeta)
+        for i in range(p):
+            dG_ref[0, j, i] = dG_row[:, i * C:(i + 1) * C]
+            dbeta_ref[0, j, i] = dbeta_row[:, i * C:(i + 1) * C]
+        dkk = dkk + dA * (D * f["beta_b"])
         dqk = dqk + dP * D
         dq = dq + dqg * eg
         dk = dk + dRw * (beta * eg) + dk_tail * f["tail"]
-    dk = dk + _dot(dkk, ch.k, _NN, full=True) \
-        + _dot(dkk, ch.k, _TN, full=True) + _dot(dqk, ch.q, _TN)
+    dkk, dqk = ch.apart(dkk), ch.apart(dqk)
+    dk = dk + _dot(dkk + dkk.T, ch.k, _NN, full=True) + _dot(dqk, ch.q, _TN)
     dq = (dq + _dot(dqk, ch.k, _NN)) * ch.scale
     dq_ref[0] = _l2_grad(ch.qn, ch.rq, dq).astype(dq_ref.dtype)
     dk_ref[0] = _l2_grad(ch.k, ch.rk, dk).astype(dk_ref.dtype)
@@ -464,10 +597,6 @@ def _gdn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
 
 def _flat(x):           # [B, T, H, D] -> [B, T, H * D]: the same bytes
     return x.reshape(x.shape[0], x.shape[1], -1)
-
-
-def _by_chunk(x, chunk):    # [B, T, Hv] -> [B, chunks, chunk, Hv]
-    return x.reshape(x.shape[0], -1, chunk, x.shape[2])
 
 
 def _fwd_shapes(Q, V, chunk):
@@ -491,36 +620,45 @@ def _bwd_shapes(Q, V, chunk):
             jax.ShapeDtypeStruct((B, T, Hk * Dk), Q.dtype))
 
 
+def _grid(Q, V, chunk):
+    """(batch, key heads, steps of `p` chunks) and `p` (`_plan`)."""
+    B, T, Hk, Dk = Q.shape
+    chunks = T // chunk
+    p = _plan(Dk, V.shape[3], chunk, chunks, V.shape[2] // Hk)[1]
+    return (B, Hk, chunks // p), p
+
+
 def _gdn_call(kernel, name, Q, K, V, G, beta, more, out_shape, out_blocks,
-              reverse):
-    """Both kernels' grid and blocks: (batch, key head, chunk), the chunk
-    axis sequential. q, k, v and their like are read where they lie, as
-    [B, T, heads * dim] with a head's lanes chosen by the block index (a
-    key head's `r` value heads are `r * Dv` adjacent lanes, so nothing is
-    repeated); G and beta as [B, chunks, C, Hv], every head of a chunk in
-    one block."""
+              chunk, reverse):
+    """Both kernels' grid and blocks: (batch, key head, step of `p`
+    chunks), the last axis sequential. q, k, v and their like are read where
+    they lie, as [B, T, heads * dim] with a head's lanes chosen by the block
+    index (a key head's `r` value heads are `r * Dv` adjacent lanes, so
+    nothing is repeated); G and beta as [B, T, Hv], every head of the step's
+    tokens in one block."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, T, Hk, Dk = Q.shape
-    Hv, Dv = V.shape[2], V.shape[3]
-    r, C = Hv // Hk, T // G.shape[1]
-    n = G.shape[1]
+    Dk, Dv, r = Q.shape[3], V.shape[3], V.shape[2] // Q.shape[2]
+    grid, p = _grid(Q, V, chunk)
+    rows = p * chunk
 
-    def at(c):                          # the chunk a grid step works on
-        return n - 1 - c if reverse else c
+    def at(c):                          # the chunks a grid step works on
+        return grid[2] - 1 - c if reverse else c
 
     blocks = {
-        "key": pl.BlockSpec((1, C, Dk), lambda b, h, c: (b, at(c), h)),
-        "value": pl.BlockSpec((1, C, r * Dv), lambda b, h, c: (b, at(c), h)),
-        "gates": pl.BlockSpec((1, 1, C, Hv), lambda b, h, c: (b, at(c), 0, 0)),
-        "states": pl.BlockSpec((1, 1, r, Dk, Dv),
+        "key": pl.BlockSpec((1, rows, Dk), lambda b, h, c: (b, at(c), h)),
+        "value": pl.BlockSpec((1, rows, r * Dv),
+                              lambda b, h, c: (b, at(c), h)),
+        "gates": pl.BlockSpec((1, rows, V.shape[2]),
+                              lambda b, h, c: (b, at(c), 0)),
+        "states": pl.BlockSpec((p, 1, r, Dk, Dv),
                                lambda b, h, c: (at(c), b, h, 0, 0)),
-        "gate_rows": pl.BlockSpec((1, r, 1, 1, C),
+        "gate_rows": pl.BlockSpec((1, r, p, 1, chunk),
                                   lambda b, h, c: (b, h, at(c), 0, 0))}
     ins = ["key", "key", "value", "gates", "gates"] + [x for x, _ in more]
     return pl.pallas_call(
-        functools.partial(kernel, r=r), name=name, grid=(B, Hk, n),
+        functools.partial(kernel, r=r, p=p), name=name, grid=grid,
         in_specs=[blocks[x] for x in ins],
         out_specs=[blocks[x] for x in out_blocks], out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((r, Dk, Dv), jnp.float32)],
@@ -530,8 +668,10 @@ def _gdn_call(kernel, name, Q, K, V, G, beta, more, out_shape, out_blocks,
     )(_flat(Q), _flat(K), _flat(V), G, beta, *[x for _, x in more])
 
 
-def _running_sum(g, chunk):
-    return jnp.cumsum(_by_chunk(g.astype(jnp.float32), chunk), axis=2)
+def _running_sum(g, chunk):     # [B, T, Hv], the sum starting at each chunk
+    g = g.astype(jnp.float32)
+    by_chunk = g.reshape(g.shape[0], -1, chunk, g.shape[2])
+    return jnp.cumsum(by_chunk, axis=2).reshape(g.shape)
 
 
 def _gdn_forward(Q, K, V, g, beta, chunk):
@@ -541,8 +681,8 @@ def _gdn_forward(Q, K, V, g, beta, chunk):
     it."""
     states, out = _gdn_call(
         _gdn_fwd_kernel, "gdn_fwd", Q, K, V, _running_sum(g, chunk),
-        _by_chunk(beta.astype(jnp.float32), chunk), [],
-        _fwd_shapes(Q, V, chunk), ["states", "value"], reverse=False)
+        beta.astype(jnp.float32), [], _fwd_shapes(Q, V, chunk),
+        ["states", "value"], chunk, reverse=False)
     return out.reshape(V.shape), states
 
 
@@ -551,10 +691,11 @@ def _gdn_backward(Q, K, V, g, beta, states, d_out, chunk):
     [B, T, Hv, Dv], each in its input's shape and dtype."""
     dG, dbeta, dv, dq, dk = _gdn_call(
         _gdn_bwd_kernel, "gdn_bwd", Q, K, V, _running_sum(g, chunk),
-        _by_chunk(beta.astype(jnp.float32), chunk),
+        beta.astype(jnp.float32),
         [("states", states), ("value", _flat(d_out.astype(V.dtype)))],
         _bwd_shapes(Q, V, chunk),
-        ["gate_rows", "gate_rows", "value", "key", "key"], reverse=True)
+        ["gate_rows", "gate_rows", "value", "key", "key"], chunk,
+        reverse=True)
 
     def per_token(x):   # [B, Hv, chunks, 1, C] -> [B, chunks, C, Hv]
         return jnp.transpose(x[:, :, :, 0, :], (0, 2, 3, 1))
@@ -783,6 +924,14 @@ def _check(Q, V, chunk):
                          f"and {Hv}")
 
 
+def _tally_grid(ctx, Q, V, chunk):
+    """The grid steps this op's kernel call runs, onto the compile event
+    (`gdn_grid_steps`, summed over the program's ops and grad ops): what
+    says how many chunks a step took."""
+    (B, Hk, steps), _ = _grid(Q, V, chunk)
+    ctx.tally("gdn_grid_steps", B * Hk * steps)
+
+
 def _gated_delta_rule_infer(ctx, structs):
     """Build-time shapes without a trace of the rule: a machine with no TPU
     takes the XLA form, which saves no `States`, and the program it builds
@@ -810,6 +959,7 @@ def _gated_delta_rule(ctx, Q, K, V, G, Beta):
     kernels = _kernels_run(Dk, V.shape[3], chunk)
     ctx.note(gdn_plan="kernel" if kernels else "xla")
     if kernels:
+        _tally_grid(ctx, Q, V, chunk)
         out, states = _gdn_forward(Q, K, V, G, Beta, chunk)
         return {"Out": out, "States": states}
     q = l2_normalize(Q.astype(jnp.float32)) * Dk ** -0.5
@@ -843,6 +993,7 @@ def _gated_delta_rule_grad(ctx, ins, out_grads):
             *raw)
         grads = vjp(d_out.astype(out.dtype))
     else:
-        grads = _gdn_backward(*raw, states, d_out,
-                              int(ctx.attr("chunk", 64)))
+        chunk = int(ctx.attr("chunk", 64))
+        _tally_grid(ctx, raw[0], raw[2], chunk)
+        grads = _gdn_backward(*raw, states, d_out, chunk)
     return {s: d.astype(x.dtype) for s, d, x in zip(slots, grads, raw)}

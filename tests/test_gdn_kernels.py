@@ -4,7 +4,9 @@ that fill a vreg: against `jax.vjp` of `chunked_gated_delta_rule` (the XLA
 form the op keeps outside the kernels' envelope) and against the
 token-by-token recurrence of `tests/qwen3_next_reference.py`; the saved
 states; the op through a Program with and without the kernels; the plan's
-table; and the names and shapes the benchmark's patterns find the kernels
+table, with the chunks a grid step takes (`p`: an even count of chunks runs
+the paired body, an odd one the same body at p = 1); the grid steps the op
+tallies; and the names and shapes the benchmark's patterns find the kernels
 by (`benchmark/metrics/gdn_*.json`)."""
 
 import json
@@ -48,6 +50,11 @@ def _inputs(t, regime, seed=0, dk=D, dv=D):
     return q, k, v, g.astype(np.float32), beta.astype(np.float32)
 
 
+def _shapes(chunks, dk=D, dv=D):
+    return (jax.ShapeDtypeStruct((B, chunks * CHUNK, HK, dk), jnp.float32),
+            jax.ShapeDtypeStruct((B, chunks * CHUNK, HV, dv), jnp.float32))
+
+
 def _prepared(q, k):
     """What the op does before either oracle: l2-norm, scale, a key head
     repeated over its value heads."""
@@ -64,11 +71,14 @@ def _recurrence(q, k, v, g, beta):
     return ref.delta_rule(*_prepared(q, k), v, g, beta, token_block=64)
 
 
-@pytest.mark.parametrize("chunks", [2, 3])
+@pytest.mark.parametrize("chunks", [1, 2, 3, 4])
 @pytest.mark.parametrize("regime", sorted(REGIMES))
 def test_kernels_match_both_oracles(regime, chunks, interpreted):
     """Forward and all five input gradients: strong, weak and mixed decay,
-    write strengths near 0 and near 1."""
+    write strengths near 0 and near 1; one pair of chunks a call, the odd
+    count that keeps one chunk a step, two pairs (the state handed from a
+    step to the next), and a single chunk."""
+    assert la._grid(*_shapes(chunks), CHUNK)[1] == (1 if chunks % 2 else 2)
     args = _inputs(chunks * CHUNK, regime)
     probe = np.random.RandomState(9).randn(
         B, chunks * CHUNK, HV, D).astype(np.float32)
@@ -101,20 +111,24 @@ def test_kernels_at_unequal_head_dims(interpreted):
             assert frob(got, w) < 2e-4, (name, frob(got, w))
 
 
+@pytest.mark.parametrize("chunks", [3, 4])
 @pytest.mark.parametrize("regime", ["g_near_0", "g_strongly_negative",
                                     "mixed"])
-def test_saved_states_are_the_recurrence_states(regime, interpreted):
+def test_saved_states_are_the_recurrence_states(regime, chunks, interpreted):
     """`States[c]` is the recurrence's state after the tokens before chunk
-    c. The recurrence gives no state away, so it is read through it: after
-    a prefix, Dk more tokens that neither decay nor write (g = 0,
-    beta = 0) and ask with the unit vectors: `o_t = S^T e_t` is row t."""
-    q, k, v, g, beta = _inputs(3 * CHUNK, regime)
+    c: at a grid step's start and, with four chunks in two pairs, the state
+    the second chunk of a pair finds inside its step (c = 1, 3). The
+    recurrence gives no state away, so it is read through it: after a
+    prefix, Dk more tokens that neither decay nor write (g = 0, beta = 0)
+    and ask with the unit vectors: `o_t = S^T e_t` is row t."""
+    q, k, v, g, beta = _inputs(chunks * CHUNK, regime)
     _, states = la._gdn_forward(q, k, v, g, beta, CHUNK)
-    assert states.shape == (3, B, HV, D, D) and states.dtype == jnp.float32
+    assert states.shape == (chunks, B, HV, D, D)
+    assert states.dtype == jnp.float32
     np.testing.assert_array_equal(states[0], 0.0)
     q_n, k_n = _prepared(q, k)
     ask = jnp.broadcast_to(jnp.eye(D)[None, :, None, :], (B, D, HV, D))
-    for c in (1, 2):
+    for c in range(1, chunks):
         cut = c * CHUNK
 
         def grown(x, tail):
@@ -189,7 +203,49 @@ def test_head_dims_that_fill_no_vreg_keep_the_xla_form(interpreted):
     (64, 128, 64, "xla"), (128, 64, 64, "xla"), (192, 128, 64, "xla"),
     (128, 128, 32, "xla"), (128, 128, 128, "xla")])
 def test_plan_reads_the_shape_alone(dk, dv, chunk, plan):
-    assert la._plan(dk, dv, chunk) == plan
+    assert la._plan(dk, dv, chunk)[0] == plan
+    assert la._plan(dk, dv, chunk, chunks=64, r=2)[0] == plan
+
+
+@pytest.mark.parametrize("dk,dv,chunks,r,p", [
+    (128, 128, 64, 2, 2),       # the cell: 64 chunks in 32 pairs
+    (128, 128, 2, 2, 2), (128, 128, 4, 1, 2), (256, 128, 6, 2, 2),
+    (128, 128, 1, 2, 1), (128, 128, 3, 2, 1), (128, 128, 63, 2, 1),
+    (128, 256, 64, 8, 2),       # 11.5 MiB of blocks and state
+    (256, 256, 64, 8, 1),       # 17.0 MiB: more than a call has unasked
+    (8, 8, 64, 2, 0), (128, 64, 2, 2, 0)])
+def test_plan_pairs_the_chunks_where_they_pair_up_and_fit(dk, dv, chunks, r,
+                                                          p):
+    """`p`, the chunks a grid step takes, from the chunk count, the value
+    heads a key head serves and the head dims alone; 0 where no kernel
+    runs."""
+    assert la._plan(dk, dv, 64, chunks, r)[1] == p
+    if p:
+        q = jax.ShapeDtypeStruct((3, chunks * 64, 2, dk), jnp.bfloat16)
+        v = jax.ShapeDtypeStruct((3, chunks * 64, 2 * r, dv), jnp.bfloat16)
+        assert la._grid(q, v, 64) == ((3, 2, chunks // p), p)
+
+
+def _tallied():
+    steps = [e.detail.get("gdn_grid_steps")
+             for e in observe.observatory().events()
+             if isinstance(e.detail, dict)]
+    return [n for n in steps if n is not None]
+
+
+@pytest.mark.parametrize("chunks,steps", [(2, 1), (3, 3), (4, 2)])
+def test_the_op_tallies_its_grid_steps_forward_and_grad(chunks, steps,
+                                                        interpreted):
+    """`gdn_grid_steps` on the compile event: batch x key heads x steps of
+    `p` chunks, once from the op and once from its grad op; nothing where
+    the rule keeps the XLA form."""
+    feed, params = _layer_feed(t=chunks * CHUNK)
+    _layer(feed, params)
+    assert _tallied()[-1] == 2 * (B * HK * steps)
+    before = len(_tallied())
+    feed, params = _layer_feed(t=chunks * CHUNK, d=8)
+    _layer(feed, params)
+    assert len(_tallied()) == before
 
 
 def test_a_cpu_backend_takes_the_kernels_only_when_interpreted(monkeypatch):
@@ -274,6 +330,30 @@ def test_the_kernels_stay_inside_the_benchmarks_pattern():
         pattern = _metric(metric)["args"]["pattern"]
         for line in lines.values():
             assert not re.search(pattern, line), (metric, line)
+
+
+def test_the_grid_steps_metric_loads_and_reads_the_tally():
+    """`gdn_grid_steps.train` is a data file over the reader that was there
+    (`compile_detail`), under the key the op tallies; the cell's grid is 32
+    pairs of chunks a key head, both ways, three layers."""
+    spec = _metric("gdn_grid_steps.train")
+    assert spec["reader"] == "compile_detail"
+    assert spec["args"] == {"key": "gdn_grid_steps"}
+    assert os.path.isfile(os.path.join(ROOT, "benchmark", "readers",
+                                       "compile_detail.py"))
+    q = jax.ShapeDtypeStruct((1, 4096, 16, 128), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 4096, 32, 128), jnp.bfloat16)
+    grid, p = la._grid(q, v, 64)
+    assert (grid, p) == ((1, 16, 32), 2)
+    assert 3 * 2 * grid[0] * grid[1] * grid[2] == 3072
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        (entry,) = [m for m in json.load(f)["per_layer"]
+                    if m["name"] == "gdn_grid_steps.train"]
+    assert entry == {"name": "gdn_grid_steps.train", "unit": "count",
+                     "better": "lower", "source": "program_counter",
+                     "layer": "linear attention",
+                     "moves": "train_examples_per_s",
+                     "workloads": ["qwen3_next_80b_a3b.bs1"]}
 
 
 @pytest.mark.parametrize("metric,reader", [

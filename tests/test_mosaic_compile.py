@@ -58,6 +58,7 @@ def test_gdn_kernels_compile_at_the_cells_shapes(one_chip, monkeypatch):
     v = arg((1, 4096, 32, 128), jnp.bfloat16)
     g = arg((1, 4096, 32), jnp.float32)
     states = arg((64, 1, 32, 128, 128), jnp.float32)
+    assert la._grid(q, v, 64) == ((1, 16, 32), 2)      # two chunks a step
     fwd = jax.jit(lambda *a: la._gdn_forward(*a, 64)).lower(
         q, q, v, g, g).compile()
     (call,) = _custom_calls(fwd, "gdn_fwd")
