@@ -254,13 +254,19 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     the context). `residual_out_norms`: the `rms_norm` ops whose result goes
     straight into a residual `elementwise_add`, a sublayer normed on the way
     out (two a layer where a layer has four norms). Empty for a program with
-    none of these.
+    none of these. `sparse_attention`: a `fused_attention` that is handed a
+    kept set (a learned selection of keys), with `dsa_layers`, their count
+    again as a flat number. `frozen_parameters`: the trainable parameters
+    that no update op names, in a program that has update ops: what the loss
+    cannot reach and `minimize` therefore left alone, without moments (an
+    indexer behind a selection that carries no gradient).
     (`moe_row_buffer_rows`, the rows of the expert layer's layout, follows
     the batch: `moe_dispatch`'s rule notes it on the same event under the
     trace, `LoweringContext.note`.)"""
     block = program.global_block()
     kinds = {"linear_attention": 0, "full_attention": 0,
-             "latent_attention": 0, "window_attention": 0}
+             "latent_attention": 0, "window_attention": 0,
+             "sparse_attention": 0}
     out: Dict[str, object] = {}
     copies: Dict[str, str] = {}     # an `assign` op's result -> what it copied
     biases = []                     # the routers' selection biases
@@ -287,7 +293,9 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
             value = block.var(op.input("V")[0]).shape[-1]
             window = op.attrs.get("window")
             seq = keys.shape[1 if op.attrs.get("layout") == "BTHD" else -2]
-            if window is not None and window < seq:
+            if op.inputs.get("Kept"):
+                kinds["sparse_attention"] += 1
+            elif window is not None and window < seq:
                 kinds["window_attention"] += 1
                 out["attention_window"] = window
                 group = _expanded_by(block, op.input("K")[0])
@@ -317,6 +325,15 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
         out["layer_kinds"] = {k: n for k, n in kinds.items() if n}
     if kinds["window_attention"]:
         out["attention_window_layers"] = kinds["window_attention"]
+    if kinds["sparse_attention"]:
+        out["dsa_layers"] = kinds["sparse_attention"]
+    updated_params = {n for op in block.ops
+                      if op.attrs.get("__role__") == "optimize"
+                      for n in op.inputs.get("Param", [])}
+    frozen = sum(1 for p in block.all_parameters()
+                 if p.trainable and p.name not in updated_params)
+    if updated_params and frozen:
+        out["frozen_parameters"] = frozen
     dense = sum(1 for scope in gated if scope not in routed)
     if routed and dense:
         out["dense_ffn_layers"] = dense

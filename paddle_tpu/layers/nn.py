@@ -1481,7 +1481,8 @@ def exit_gate(input, param_attr=None, bias_attr=None, name=None):
 
 
 def fused_attention(q, k, v, causal=False, sm_scale=None, dropout_rate=0.0,
-                    is_test=False, window=None, layout="BHTD", name=None):
+                    is_test=False, window=None, layout="BHTD", name=None,
+                    kept=None, topk=None):
     """Softmax attention through the flash kernels
     (`ops/pallas_attention.py`): O(seq) memory, dropout on the attention
     weights inside the kernel. `layout` says how the operands lie, and the
@@ -1503,6 +1504,15 @@ def fused_attention(q, k, v, causal=False, sm_scale=None, dropout_rate=0.0,
     `W >= 1` runs, aligned to a tile or not; `W >= seq` is plain causal.
     Not under sequence parallelism (ring attention) and not in the paged
     kernels: both raise.
+
+    `kept` (with `causal=True`, "BHTD", no window, no dropout): an int8
+    `[batch, seq, seq]` variable, the keys each query keeps of those below
+    the diagonal, one set for all heads (`layers.dsa_select`); the softmax is
+    over the kept keys alone. It carries no gradient. The flash kernels read
+    its tiles beside the score tiles, forward and backward, under names of
+    their own (`dsa_flash_fwd`, `dsa_flash_dq_flash_dkv`); every causal tile
+    is computed. `topk`: how many keys a row keeps at most, for the op's
+    count of kept pairs on the compile event (`dsa_keys_kept`).
 
     The op has a second output, `Lse`: the forward kernel's log-sum-exp of
     every score row, float32 `[batch * heads, 1, seq]` in either layout,
@@ -1528,10 +1538,50 @@ def fused_attention(q, k, v, causal=False, sm_scale=None, dropout_rate=0.0,
     if window is not None:
         _check_window(window, causal)       # refuses at build time
         attrs["window"] = int(window)
-    helper.append_op("fused_attention",
-                     inputs={"Q": [q.name], "K": [k.name], "V": [v.name]},
+    inputs = {"Q": [q.name], "K": [k.name], "V": [v.name]}
+    if kept is not None:
+        if not causal or window is not None or layout != "BHTD" \
+                or dropout_rate:
+            raise ValueError(
+                "fused_attention takes a kept set on a causal \"BHTD\" call "
+                "without a window or dropout")
+        inputs["Kept"] = [kept.name]
+        if topk is not None:
+            attrs["topk"] = int(topk)
+    helper.append_op("fused_attention", inputs=inputs,
                      outputs={"Out": [out.name], "Lse": [lse.name]},
                      attrs=attrs)
+    return out
+
+
+def dsa_index_scores(q, k, w, scale, tile=512, name=None):
+    """The index scores of a learned key selection (DeepSeek-Sparse-Attention;
+    `ops/sparse_attention.py`): `q` `[batch, index_heads, seq, index_dim]`,
+    `k` `[batch, 1, seq, index_dim]`, `w` `[batch, seq, index_heads]` give
+    `scale * sum_j w[t, j] * relu(q[j, t] . k[s])` for `s <= t`, float32
+    `[batch, seq, seq]`, minus infinity above the diagonal, computed in tiles
+    of `tile` x `tile`. No gradient passes it."""
+    helper = LayerHelper("dsa_index_scores", name=name)
+    out = helper.create_variable_for_type_inference("float32",
+                                                    stop_gradient=True)
+    helper.append_op("dsa_index_scores",
+                     inputs={"Q": [q.name], "K": [k.name], "W": [w.name]},
+                     outputs={"Scores": [out.name]},
+                     attrs={"scale": float(scale), "tile": int(tile)})
+    return out
+
+
+def dsa_select(scores, topk, name=None):
+    """The kept set of index scores `[batch, seq, seq]`: int8, row t holds 1
+    at its `min(t + 1, topk)` keys of largest score below the diagonal (of
+    equal scores the lower index) and 0 elsewhere; what
+    `fused_attention(kept=...)` reads. No gradient passes it."""
+    helper = LayerHelper("dsa_select", name=name)
+    out = helper.create_variable_for_type_inference("int8",
+                                                    stop_gradient=True)
+    helper.append_op("dsa_select", inputs={"Scores": [scores.name]},
+                     outputs={"Kept": [out.name]},
+                     attrs={"topk": int(topk)})
     return out
 
 

@@ -19,7 +19,9 @@ their mask), and Trinity-Mini: window layers that turn by rotary beside full
 layers that carry no positions at all, an output gate from a projection of
 its own, a norm on both sides of every sublayer with experts inside, a scaled
 embedding (the first model whose attention kinds differ in whether they
-turn)."""
+turn), and Keye-VL-2.0's language model: an indexer that picks the keys each
+query attends to, the flash kernels masked by that set (the first model whose
+mask is data, and the first with parameters that the loss cannot reach)."""
 
 from . import mnist  # noqa: F401
 from . import resnet  # noqa: F401
@@ -36,3 +38,4 @@ from . import qwen3_next  # noqa: F401
 from . import kanana2  # noqa: F401
 from . import mellum2  # noqa: F401
 from . import trinity  # noqa: F401
+from . import keye_vl2  # noqa: F401

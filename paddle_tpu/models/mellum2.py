@@ -93,7 +93,9 @@ def layer_kinds(n_layer, layer_types=PERIOD):
 
 
 def _attention(x, n_head, n_kv_head, head_dim, rope_theta, rope_scaling,
-               window, rms_eps, name):
+               window, rms_eps, name, kept=None, topk=None):
+    """`kept`: the keys each query keeps (`layers.dsa_select`), of at most
+    `topk` a row, where a layer chooses them (`models/keye_vl2.py`)."""
     def heads(t, n):        # [B, T, n * Dh] -> [B, n, T, Dh]
         return layers.reshape(t, shape=[0, 0, n, head_dim])
 
@@ -118,7 +120,7 @@ def _attention(x, n_head, n_kv_head, head_dim, rope_theta, rope_scaling,
 
     ctx = layers.fused_attention(q, serve_group(k), serve_group(v),
                                  causal=True, sm_scale=head_dim ** -0.5,
-                                 window=window)
+                                 window=window, kept=kept, topk=topk)
     ctx = layers.reshape(layers.transpose(ctx, perm=[0, 2, 1, 3]),
                          shape=[0, 0, n_head * head_dim])
     return _linear(ctx, x.shape[-1], name + ".o")
@@ -176,7 +178,15 @@ def mellum2(vocab_size=98304, seq_len=8192, n_layer=28, d_model=2304,
         routings.append(routing)
     x = _norm(x, rms_eps, "final_norm")
     logits = _linear(x, vocab_size, "head")
+    return ({"tokens": tokens, "labels": labels},
+            _balanced_loss(logits, labels, routings, n_expert, top_k,
+                           aux_coef))
 
+
+def _balanced_loss(logits, labels, routings, n_expert, top_k, aux_coef):
+    """The fetches of a step: mean cross-entropy plus `aux_coef` times the
+    load-balance term over all layers' router rows."""
+    n_layer = len(routings)
     ce = layers.mean(layers.softmax_with_cross_entropy(logits=logits,
                                                        label=labels))
     # all layers' router rows taken together, as `models/olmoe.py` does:
@@ -196,9 +206,8 @@ def mellum2(vocab_size=98304, seq_len=8192, n_layer=28, d_model=2304,
     loss = layers.sums([ce, layers.scale(load_balance, scale=aux_coef)])
     tokens_per_expert = layers.stack(
         [r["tokens_per_expert"] for r in routings], axis=0)
-    return ({"tokens": tokens, "labels": labels},
-            {"loss": loss, "ce": ce, "load_balance": load_balance,
-             "logits": logits, "tokens_per_expert": tokens_per_expert})
+    return {"loss": loss, "ce": ce, "load_balance": load_balance,
+            "logits": logits, "tokens_per_expert": tokens_per_expert}
 
 
 def build(**kw):

@@ -274,7 +274,7 @@ def _interpret():
 # ---------------------------------------------------------------------------
 
 def _attention_reference(q, k, v, causal, sm_scale, dropout_rate=0.0,
-                         seed=None, window=None):
+                         seed=None, window=None, kept=None):
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * sm_scale
     if causal:
         Tq, Tk = s.shape[-2], s.shape[-1]
@@ -283,6 +283,8 @@ def _attention_reference(q, k, v, causal, sm_scale, dropout_rate=0.0,
         s = jnp.where(col > row, NEG_INF, s)
         if window is not None:
             s = jnp.where(row - col >= window, NEG_INF, s)
+    if kept is not None:            # [B, T, T]: one set for all heads
+        s = jnp.where(kept[:, None] != 0, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     if dropout_rate:
         key = jax.random.key(seed if seed is not None else 0)
@@ -328,6 +330,16 @@ def _apply_causal_mask(s, qi, kj, blk_q, blk_k, window=None):
     if window is not None:
         s = jnp.where(row - col >= window, NEG_INF, s)
     return s
+
+
+def _apply_kept(s, kept_ref):
+    """Mask one score tile by the kept set's tile of the same rows and keys
+    (int8 `[1, blk_q, blk_k]`, one for all heads of its batch row): a key
+    whose entry is 0 is not seen. Widened to int32 first: a v5e's vector
+    unit compares no bytes. A tile of ones leaves `s` the bits it had."""
+    if kept_ref is None:
+        return s
+    return jnp.where(kept_ref[0].astype(jnp.int32) != 0, s, NEG_INF)
 
 
 # -- the band of a window over tiles ----------------------------------------
@@ -392,6 +404,21 @@ def window_tiles(T, window):
     bq, bk = _blk(T, True, window)
     return sum(_last_k(qi, bq, bk) + 1 - _first_k(qi, bq, bk, window)
                for qi in range(T // bq))
+
+
+def causal_tiles(T):
+    """Score tiles a causal forward call without a window computes a head:
+    those that meet the triangle (what a call under a kept set computes
+    too: its tiles are masked, none is skipped)."""
+    bq, bk = _blk(T, True)
+    return sum(_last_k(qi, bq, bk) + 1 for qi in range(T // bq))
+
+
+def kept_pairs(T, topk):
+    """Pairs (query, key) a selection of the `topk` largest below the
+    diagonal keeps in a sequence of T: `min(t + 1, topk)` a row."""
+    k = min(int(topk), T)
+    return k * (k + 1) // 2 + (T - k) * k
 
 
 def _dropout_mask(seed_ref, bh, qi, kj, shape, rate):
@@ -530,7 +557,8 @@ def _col(x):
     return x[:, None] if x.ndim == 1 else x
 
 
-def _score_tile(q_ref, k_ref, hd, qi, kj, sm_scale, causal, window=None):
+def _score_tile(q_ref, k_ref, hd, qi, kj, sm_scale, causal, window=None,
+                kept_ref=None):
     """One float32 [blk_q, blk_k] tile of q k^T * sm_scale, the causal mask
     applied in-register. The dots run in the INPUT dtype (bf16 under AMP ->
     full MXU rate; the round-3 kernels upcast to f32 first, quartering
@@ -543,7 +571,7 @@ def _score_tile(q_ref, k_ref, hd, qi, kj, sm_scale, causal, window=None):
     if causal:
         s = _apply_causal_mask(s, qi, kj, q_ref.shape[1], k_ref.shape[1],
                                window)
-    return s
+    return _apply_kept(s, kept_ref)
 
 
 def _weights_times_v(p, v_ref, hd, seed_ref, qi, kj, dropout_rate):
@@ -559,7 +587,7 @@ def _weights_times_v(p, v_ref, hd, seed_ref, qi, kj, dropout_rate):
 
 def _flash_fwd_onepass_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                               *, sm_scale, causal, dropout_rate, window=None,
-                              heads=None):
+                              heads=None, kept_ref=None):
     """A row is one K block (`_fwd_plan`): the softmax of a q-block is
     whole in its one grid step, so there is no running maximum to correct,
     nothing carried in scratch and no branch. The row statistics keep the
@@ -572,7 +600,8 @@ def _flash_fwd_onepass_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     first, qi = _grid_ids(heads, tile_axes=1)
 
     def head(hd):
-        s = _score_tile(q_ref, k_ref, hd, qi, 0, sm_scale, causal, window)
+        s = _score_tile(q_ref, k_ref, hd, qi, 0, sm_scale, causal, window,
+                        kept_ref)
         m = jnp.max(s, axis=1, keepdims=True)              # [blk_q, 1]
         p = jnp.exp(s - m)
         l = jnp.maximum(jnp.sum(p, axis=1, keepdims=True), 1e-20)
@@ -616,7 +645,7 @@ def _grid_ids(heads, tile_axes=2):
 def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                       m_sc, l_sc, acc_sc, *,
                       sm_scale, causal, dropout_rate, window=None,
-                      heads=None):
+                      heads=None, kept_ref=None):
     """A row has several K blocks. K/V STREAM through the grid's innermost
     ("arbitrary") dimension: each program sees one [blk_k, D] K/V block,
     with the online-softmax state carried in VMEM scratch across kj
@@ -652,7 +681,7 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _update():
         def head(hd):
             s = _score_tile(q_ref, k_ref, hd, qi, kj, sm_scale, causal,
-                            window)
+                            window, kept_ref)
             m = m_sc[hd.stat]
             m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - _lanes(m_new, blk_k))
@@ -679,7 +708,7 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                      delta_ref, dq_ref, dq_sc, *, sm_scale, causal,
-                     dropout_rate, window=None, heads=None):
+                     dropout_rate, window=None, heads=None, kept_ref=None):
     """dQ with K/V streamed through the innermost grid dim (see
     _flash_fwd_kernel); the dQ accumulator lives in VMEM scratch."""
     from jax.experimental import pallas as pl
@@ -709,6 +738,7 @@ def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                                 preferred_element_type=jnp.float32) * sm_scale
             if causal:
                 s = _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
+            s = _apply_kept(s, kept_ref)
             w = jnp.exp(s - lse[:, None])                  # normalized weights
             dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
@@ -733,7 +763,7 @@ def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _flash_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                       delta_ref, dk_ref, dv_ref, dk_sc, dv_sc, *,
                       sm_scale, causal, dropout_rate, window=None,
-                      q_tiles=None, heads=None):
+                      q_tiles=None, heads=None, kept_ref=None):
     """dK/dV with Q/dOut/lse/delta streamed through the innermost grid
     dim (grid = (BH, kj, qi)); accumulators in VMEM scratch. Under a
     `window` the inner axis counts the Q tiles of the k-block's band
@@ -768,6 +798,7 @@ def _flash_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                                 preferred_element_type=jnp.float32) * sm_scale
             if causal:
                 s = _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
+            s = _apply_kept(s, kept_ref)
             w = jnp.exp(s - lse[:, None])                  # [blk_q, blk_k]
             dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
@@ -797,7 +828,7 @@ def _flash_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _flash_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                       delta_ref, dq_ref, dk_ref, dv_ref, dk_sc, dv_sc,
                       *dq_sc, sm_scale, causal, dropout_rate, window=None,
-                      q_tiles=None, heads=None):
+                      q_tiles=None, heads=None, kept_ref=None):
     """dQ, dK and dV from one pass: `_flash_dkv_kernel` (grid (BH, kj, qi),
     q innermost) with one product more, this tile's share of dQ from the
     `ds` it has already formed. Without `dq_sc` a row is one K block and
@@ -844,6 +875,7 @@ def _flash_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                                 preferred_element_type=jnp.float32) * sm_scale
             if causal:
                 s = _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
+            s = _apply_kept(s, kept_ref)
             w = jnp.exp(s - lse[:, None])                  # [blk_q, blk_k]
             dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
@@ -915,9 +947,53 @@ def _window_of(window, T):
     return None if window is None or window >= T else int(window)
 
 
-def _named(name, window):
-    """Windowed calls under names of their own (`swa_...`)."""
+def _named(name, window, kept=None):
+    """Windowed calls under names of their own (`swa_...`), and so the
+    calls that take a kept set (`dsa_...`)."""
+    if kept is not None:
+        return "dsa_" + name
     return name if window is None else "swa_" + name
+
+
+def _check_kept(kept, q, causal, window, token_major):
+    """A kept set is int8 `[B, T, T]`, one for all heads of a batch row, on
+    a causal head-major call without a window."""
+    if kept is None:
+        return
+    B, _, T, _ = _shape_of(q, token_major)
+    if not causal or window is not None or token_major:
+        raise ValueError(
+            "flash attention takes a kept set on a causal call of "
+            "[batch, heads, seq, head_dim] operands without a window; got "
+            f"causal = {causal}, window = {window}, token-major = "
+            f"{token_major}")
+    if kept.shape != (B, T, T) or kept.dtype != jnp.int8:
+        raise ValueError(
+            f"flash attention needs a kept set of int8 [{B}, {T}, {T}] "
+            f"(batch, query, key), got {kept.dtype} {tuple(kept.shape)}")
+
+
+def _kept_vmem(BQ, BK):
+    """Bytes of scoped VMEM a kept set's tile adds to a grid step: the int8
+    block double-buffered and its int32 widening."""
+    return (2 + 4) * BQ * BK
+
+
+def _kept_spec(H, BQ, BK, at_q, at_k):
+    """The kept set's block of a grid step: the (q-block, k-block) tile of
+    the row's batch (grid axis 0 is the (batch x head) row)."""
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec(
+        (1, BQ, BK), lambda *g: (g[0] // H, at_q(*g)[1], at_k(*g)[1]))
+
+
+def _takes_kept(kernel, at):
+    """`kernel` with the kept set's ref, operand `at` of the call, handed
+    over by name: the kernels' positional refs stay what they are."""
+    def with_kept(*refs, **kw):
+        return kernel(*refs[:at], *refs[at + 1:], kept_ref=refs[at], **kw)
+    return with_kept
 
 
 class _Rows(NamedTuple):
@@ -1016,11 +1092,19 @@ class _Plan(NamedTuple):
 
 
 def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0,
-                   window=None, token_major=False):
+                   window=None, token_major=False, kept=None):
     B, H, T, D = _shape_of(q, token_major)
     window = _window_of(window, T)
     BQ, BK = _blk(T, causal, window)
     kernel = _fwd_plan(T, BK)
+    if kept is not None:
+        _check_kept(kept, q, causal, window, token_major)
+        vmem = _fwd_vmem(D, 1, BQ, BK, q.dtype.itemsize, kernel == "stream") \
+            + _kept_vmem(BQ, BK)
+        return _forward(q, k, v, seed, causal, sm_scale, dropout_rate, window,
+                        _Plan((BQ, BK), kernel, interpret=_interpret(),
+                              vmem=max(vmem, _SCOPED_VMEM_FLOOR_BYTES)),
+                        kept)
     if not token_major:
         return _forward(q, k, v, seed, causal, sm_scale, dropout_rate, window,
                         _Plan((BQ, BK), kernel, interpret=_interpret()))
@@ -1035,7 +1119,8 @@ def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0,
                                     _interpret()))
 
 
-def _forward(q, k, v, seed, causal, sm_scale, dropout_rate, window, plan):
+def _forward(q, k, v, seed, causal, sm_scale, dropout_rate, window, plan,
+             kept=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1080,15 +1165,21 @@ def _forward(q, k, v, seed, causal, sm_scale, dropout_rate, window, plan):
             _band_kj(g[ax], g[ax + 1], BQ, BK, window),
             _last_k(g[ax], BQ, BK)))
 
+    in_specs = [
+        pl.BlockSpec((1, 1), lambda *g: (0, 0)),
+        pl.BlockSpec((1, BQ, rows.lanes(q3)), at_q),
+        pl.BlockSpec((1, BK, rows.lanes(k3)), at_k),
+        pl.BlockSpec((1, BK, rows.lanes(v3)), at_k),
+    ]
+    operands = (_seed_arr(seed), q3, k3, v3)
+    if kept is not None:
+        kernel = _takes_kept(kernel, len(operands))
+        in_specs.append(_kept_spec(H, BQ, BK, at_q, at_k))
+        operands += (kept,)
     out, lse = pl.pallas_call(
         kernel,
         grid=rows.grid + grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda *g: (0, 0)),
-            pl.BlockSpec((1, BQ, rows.lanes(q3)), at_q),
-            pl.BlockSpec((1, BK, rows.lanes(k3)), at_k),
-            pl.BlockSpec((1, BK, rows.lanes(v3)), at_k),
-        ],
+        in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, BQ, rows.lanes(v3)), at_q),
             pl.BlockSpec(rows.row_block(BQ), lambda *g: rows.row(g, g[ax])),
@@ -1100,8 +1191,8 @@ def _forward(q, k, v, seed, causal, sm_scale, dropout_rate, window, plan):
         scratch_shapes=scratch,
         compiler_params=_compiler_params(carried, vmem, heads),
         interpret=plan.interpret,
-        name=_named(name, window),
-    )(_seed_arr(seed), q3, k3, v3)
+        name=_named(name, window, kept),
+    )(*operands)
     return out.reshape(v.shape), lse
 
 
@@ -1114,12 +1205,20 @@ _token_major_forward = jax.jit(_forward, static_argnums=(4, 5, 6, 7, 8))
 
 
 def _flash_backward(q, k, v, o, lse, g, causal, sm_scale, dropout_rate, seed,
-                    window=None, token_major=False):
+                    window=None, token_major=False, kept=None):
     B, H, T, D = _shape_of(q, token_major)
     Dv = v.shape[-1]
     window = _window_of(window, T)
     BQ, BK = _blk(T, causal, window)
     itemsize = q.dtype.itemsize
+    if kept is not None:
+        _check_kept(kept, q, causal, window, token_major)
+        vmem = _fused_bwd_vmem(T if T != BK else BQ, D, Dv, BQ, BK, itemsize) \
+            + _kept_vmem(BQ, BK)
+        return _backward(q, k, v, o, lse, g, seed, causal, sm_scale,
+                         dropout_rate, window, _Plan(
+                             (BQ, BK), _bwd_plan(T, D, Dv, BQ, BK, itemsize),
+                             vmem=vmem, interpret=_interpret()), kept)
     if not token_major:
         return _backward(q, k, v, o, lse, g, seed, causal, sm_scale,
                          dropout_rate, window, _Plan(
@@ -1141,7 +1240,7 @@ def _flash_backward(q, k, v, o, lse, g, causal, sm_scale, dropout_rate, seed,
 
 
 def _backward(q, k, v, o, lse, g, seed, causal, sm_scale, dropout_rate,
-              window, plan):
+              window, plan, kept=None):
     heads = plan.heads
     B, H, _, _ = _shape_of(q, heads is not None)
     rows = _Rows(B, H, heads)
@@ -1158,8 +1257,8 @@ def _backward(q, k, v, o, lse, g, seed, causal, sm_scale, dropout_rate,
         attrs["window"] = window
     if heads is not None:
         attrs["heads"] = heads
-    grads = run((_seed_arr(seed), q3, k3, v3, g3, lse, delta), rows, plan,
-                attrs)
+    args = (_seed_arr(seed), q3, k3, v3, g3, lse, delta)
+    grads = run(args if kept is None else args + (kept,), rows, plan, attrs)
     return tuple(d.reshape(x.shape) for d, x in zip(grads, (q, k, v)))
 
 
@@ -1218,6 +1317,11 @@ def _bwd_specs(rows, BQ, BK, lanes, lanes_v, q_axis, band=None):
     ], at_q, at_k
 
 
+def _kept_of(args):
+    """The kept set, the operand after a backward call's seven, or None."""
+    return args[7] if len(args) > 7 else None
+
+
 def _flash_bwd_fused(args, rows, plan, attrs):
     """One call for dQ, dK and dV. Its name holds both `flash_dq` and
     `flash_dkv`: the benchmark's metrics of those names each read it."""
@@ -1236,6 +1340,11 @@ def _flash_bwd_fused(args, rows, plan, attrs):
     if window is not None:
         steps = _band_steps(T, BQ, BK, window)[1]
         attrs = dict(attrs, q_tiles=T // BQ)
+    kernel = functools.partial(_flash_bwd_kernel, **attrs)
+    kept = _kept_of(args)
+    if kept is not None:
+        kernel = _takes_kept(kernel, 7)
+        in_specs.append(_kept_spec(rows.H, BQ, BK, at_q, at_k))
     scratch = [pltpu.VMEM((BK, lanes), jnp.float32),
                pltpu.VMEM((BK, lanes_v), jnp.float32)]
     if T == BK:
@@ -1250,7 +1359,7 @@ def _flash_bwd_fused(args, rows, plan, attrs):
             vmem_bytes=plan.vmem or _fused_bwd_vmem(
                 T, lanes, lanes_v, BQ, BK, q3.dtype.itemsize))
     return pl.pallas_call(
-        functools.partial(_flash_bwd_kernel, **attrs),
+        kernel,
         grid=rows.grid + (T // BK, steps),
         in_specs=in_specs,
         out_specs=[dq_spec, pl.BlockSpec((1, BK, lanes), at_k),
@@ -1260,7 +1369,7 @@ def _flash_bwd_fused(args, rows, plan, attrs):
         scratch_shapes=scratch,
         compiler_params=params,
         interpret=plan.interpret,
-        name=_named("flash_dq_flash_dkv", window),
+        name=_named("flash_dq_flash_dkv", window, kept),
     )(*args)
 
 
@@ -1284,10 +1393,18 @@ def _flash_bwd_split(args, rows, plan, attrs):
     params = _compiler_params(
         vmem_bytes=plan.vmem and min(plan.vmem, _VMEM_BUDGET_BYTES),
         heads=heads)
-    in_specs, at_q, _ = _bwd_specs(rows, BQ, BK, lanes, lanes_v, q_axis=1,
-                                   band=band)
+    in_specs, at_q, at_k = _bwd_specs(rows, BQ, BK, lanes, lanes_v, q_axis=1,
+                                      band=band)
+    kept = _kept_of(args)
+
+    def kernel_of(body, **more):
+        body = functools.partial(body, **attrs, **more)
+        return body if kept is None else _takes_kept(body, 7)
+
+    if kept is not None:
+        in_specs.append(_kept_spec(rows.H, BQ, BK, at_q, at_k))
     dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, **attrs),
+        kernel_of(_flash_dq_kernel),
         grid=rows.grid + (T // BQ, k_steps),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, BQ, lanes), at_q),
@@ -1295,14 +1412,15 @@ def _flash_bwd_split(args, rows, plan, attrs):
         scratch_shapes=[pltpu.VMEM((BQ, lanes), jnp.float32)],
         compiler_params=params,
         interpret=plan.interpret,
-        name=_named("flash_dq", window),
+        name=_named("flash_dq", window, kept),
     )(*args)
-    in_specs, _, at_k = _bwd_specs(rows, BQ, BK, lanes, lanes_v, q_axis=2,
-                                   band=band)
-    if window is not None:
-        attrs = dict(attrs, q_tiles=T // BQ)
+    in_specs, at_q, at_k = _bwd_specs(rows, BQ, BK, lanes, lanes_v, q_axis=2,
+                                      band=band)
+    if kept is not None:
+        in_specs.append(_kept_spec(rows.H, BQ, BK, at_q, at_k))
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, **attrs),
+        kernel_of(_flash_dkv_kernel,
+                  **({} if window is None else {"q_tiles": T // BQ})),
         grid=rows.grid + (T // BK, q_steps),
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((1, BK, lanes), at_k),
@@ -1313,7 +1431,7 @@ def _flash_bwd_split(args, rows, plan, attrs):
                         pltpu.VMEM((BK, lanes_v), jnp.float32)],
         compiler_params=params,
         interpret=plan.interpret,
-        name=_named("flash_dkv", window),
+        name=_named("flash_dkv", window, kept),
     )(*args)
     return dq, dk, dv
 
@@ -1400,27 +1518,57 @@ def _fol_bwd(causal, sm_scale, dropout_rate, window, token_major, res, g):
 _flash_out_lse.defvjp(_fol_fwd, _fol_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dsa_out_lse(q, k, v, kept, sm_scale):
+    """`_flash_out_lse` of a causal head-major call under a kept set (int8
+    `[B, T, T]`, no gradient): the `dsa_` kernels, forward and backward."""
+    return _flash_forward(q, k, v, True, sm_scale, kept=kept)
+
+
+def _dol_fwd(q, k, v, kept, sm_scale):
+    out, lse = _flash_forward(q, k, v, True, sm_scale, kept=kept)
+    return (out, lse), (q, k, v, kept, out, lse)
+
+
+def _dol_bwd(sm_scale, res, g):
+    q, k, v, kept, o, lse = res
+    dq, dk, dv = _flash_backward(q, k, v, o, lse, g[0], True, sm_scale, 0.0,
+                                 0, kept=kept)
+    return dq, dk, dv, np.zeros(kept.shape, jax.dtypes.float0)
+
+
+_dsa_out_lse.defvjp(_dol_fwd, _dol_bwd)
+
+
 def _reference(q, k, v, causal, sm_scale, dropout_rate, seed, window,
-               token_major):
+               token_major, kept=None):
     """`_attention_reference` on operands of either layout: token-major
     ones are transposed to the reference's `[B, H, T, D]` and its result
     back, so both layouts draw one mask."""
     if not token_major:
         return _attention_reference(q, k, v, causal, sm_scale, dropout_rate,
-                                    seed, window)
+                                    seed, window, kept)
     q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     return _attention_reference(q, k, v, causal, sm_scale, dropout_rate,
                                 seed, window).transpose(0, 2, 1, 3)
 
 
 def flash_attention(q, k, v, seed, causal=False, sm_scale=1.0,
-                    dropout_rate=0.0, window=None, token_major=False):
+                    dropout_rate=0.0, window=None, token_major=False,
+                    kept=None):
     """seed: int32 scalar (traced) driving attention-weight dropout. For
     direct callers (tools, tests): under `jax.grad` the forward kernel is
     the residual pass and the backward kernels follow. `window`: see the
     module's docstring. `token_major`: the operands (and the result) are
-    `[B, T, H, D]`, not `[B, H, T, D]`."""
+    `[B, T, H, D]`, not `[B, H, T, D]`. `kept`: int8 `[B, T, T]`, the keys
+    each query keeps of those below the diagonal (no dropout with it)."""
     _check_window(window, causal)
+    if kept is not None:
+        _check_kept(kept, q, causal, window, token_major)
+        if _pallas_ok(q, dropout_rate, v):
+            return _dsa_out_lse(q, k, v, kept, sm_scale)[0]
+        return _reference(q, k, v, causal, sm_scale, dropout_rate, seed,
+                          window, token_major, kept)
     if _pallas_ok(q, dropout_rate, v, window, token_major):
         return _flash_out_lse(q, k, v, seed, causal, sm_scale,
                               dropout_rate, window, token_major)[0]
@@ -1472,7 +1620,7 @@ def _fused_attention_infer(ctx, structs):
 
 @register_op("fused_attention", infer=_fused_attention_infer,
              propagate_seqlen=False, needs_rng=True)
-def _fused_attention(ctx, Q, K, V):
+def _fused_attention(ctx, Q, K, V, Kept=None):
     """Q, K: [B, H, T, D]; V: [B, H, T, Dv], the value heads' own width
     (latent attention: 192 over 128), `Dv == D` in the plain case; Out is
     [B, H, T, Dv]. Under `layout` "BTHD" all four are token-major, [B, T,
@@ -1481,7 +1629,12 @@ def _fused_attention(ctx, Q, K, V):
     causal, sm_scale, dropout_rate, is_test, layout, and `window` (causal
     only): key j is visible to query i iff 0 <= i - j < window; the op
     tallies the score tiles its forward grid computes
-    (`window_tiles_computed` on the compile event).
+    (`window_tiles_computed` on the compile event). `Kept` (optional): int8
+    [B, T, T], the keys each query keeps of those below the diagonal, one
+    set for all heads; no gradient, no dropout, not with a window; the
+    `dsa_` kernels read its tiles beside the score tiles, and the op tallies
+    `dsa_keys_kept` (by closed form: `min(t + 1, topk)` a row, `topk` its
+    attribute) and `dsa_tiles_computed`.
 
     Replaces the reference's matmul+softmax+dropout+matmul composition
     (nets.py:329) with one O(T)-memory kernel. Dropout is applied to the
@@ -1526,6 +1679,20 @@ def _fused_attention(ctx, Q, K, V):
                              sm_scale=sm_scale)
         return {"Out": out.transpose(0, 2, 1, 3) if token_major else out}
     seed = _dropout_seed(ctx, rate)
+    if Kept is not None:
+        if rate:
+            raise NotImplementedError(
+                "attention-weight dropout is not supported under a kept set")
+        _check_kept(Kept, Q, causal, window, token_major)
+        if ctx.op is not None and ctx.op.type == "fused_attention":
+            ctx.tally("dsa_keys_kept",
+                      B * kept_pairs(T, ctx.attr("topk", T)))
+            ctx.tally("dsa_tiles_computed", B * H * causal_tiles(T))
+        if _pallas_ok(Q, rate, V):
+            out, lse = _dsa_out_lse(Q, K, V, Kept, sm_scale)
+            return {"Out": out, "Lse": lse}
+        return {"Out": _reference(Q, K, V, causal, sm_scale, rate, seed,
+                                  window, token_major, Kept)}
     if window is not None and ctx.op is not None \
             and ctx.op.type == "fused_attention":   # not its grad op's trace
         ctx.tally("window_tiles_computed", B * H * window_tiles(T, window))
@@ -1554,11 +1721,14 @@ def _fused_attention_grad(ctx, ins, out_grads):
     opdef = get_op_def("fused_attention")
     slots = ("Q", "K", "V")
     raw = [ins[s][0] for s in slots]
+    kept = ins.get("Kept", [None])[0]       # carries no gradient
+    rest = {} if kept is None else {"Kept": [kept]}
     out, lse = ctx.fwd_outs["Out"][0], ctx.fwd_outs.get("Lse", [None])[0]
     if lse is None:
         out, vjp = jax.vjp(
             lambda q, k, v: call_rule(
-                opdef, ctx, {"Q": [q], "K": [k], "V": [v]})["Out"][0], *raw)
+                opdef, ctx, {"Q": [q], "K": [k], "V": [v], **rest})["Out"][0],
+            *raw)
         grads = vjp(g.astype(out.dtype))
     else:
         cast = amp_cast(opdef, ctx, {s: [x] for s, x in zip(slots, raw)})
@@ -1567,7 +1737,7 @@ def _fused_attention_grad(ctx, ins, out_grads):
         grads = _flash_backward(q, k, v, out, lse, g.astype(out.dtype),
                                 causal, sm_scale, rate,
                                 _dropout_seed(ctx, rate), window,
-                                _token_major(ctx))
+                                _token_major(ctx), kept)
     return {s: d.astype(x.dtype) for s, d, x in zip(slots, grads, raw)}
 
 

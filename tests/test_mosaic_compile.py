@@ -497,3 +497,50 @@ def test_token_major_flash_kernels_compile_at_the_cells_shapes(
         text = compiled.as_text()
         assert " transpose(" not in text
         assert not re.search(r" copy\(.*bf16\[", text)
+
+
+@pytest.mark.parametrize("kernel", ["dsa_flash_fwd", "dsa_flash_dq_flash_dkv",
+                                    "dsa_index_scores", "dsa_select"])
+def test_sparse_attention_kernels_compile_at_the_cells_shapes(
+        one_chip, monkeypatch, kernel):
+    """The four kernels of a learned key selection as
+    `keye_vl_2_30b_a3b.s8192` calls them: the flash forward and the fused
+    backward under an int8 kept set `[1, 8192, 8192]` at bf16
+    `[1, 32, 8192, 128]` (the widening of a byte tile and the room it takes
+    beside the resident dQ row), the index scores of 16 heads of 64 in tiles
+    of 512 (a lane slice of the weights, products of depth 64), and the
+    selection of 2048 keys a row (loops of a traced length, the ordered bits,
+    the int8 result): what the interpreter cannot refuse. One Mosaic custom
+    call each, named for the benchmark's patterns."""
+    from paddle_tpu.ops import pallas_attention as pa
+    from paddle_tpu.ops import sparse_attention as sa
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    monkeypatch.setattr(sa, "_interpret", lambda: False)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    T = 8192
+    q, kept = arg((1, 32, T, 128)), arg((1, T, T), jnp.int8)
+    if kernel == "dsa_flash_fwd":
+        compiled = jax.jit(lambda q, k, v, kept: pa._flash_forward(
+            q, k, v, True, 128 ** -0.5, kept=kept)).lower(q, q, q, kept)
+    elif kernel == "dsa_flash_dq_flash_dkv":
+        assert pa._bwd_plan(T, 128, 128, *pa._blk(T, True), 2) == "fused"
+        compiled = jax.jit(lambda q, k, v, o, lse, g, kept: pa._flash_backward(
+            q, k, v, o, lse, g, True, 128 ** -0.5, 0.0, 0, kept=kept)).lower(
+                q, q, q, q, arg((32, 1, T), jnp.float32), q, kept)
+    elif kernel == "dsa_index_scores":
+        compiled = jax.jit(lambda q, k, w: sa.index_scores_kernel(
+            q, k, w, 2 ** -5, 512)).lower(
+                arg((1, 16, T, 64)), arg((1, 1, T, 64)), arg((1, T, 16)))
+    else:
+        compiled = jax.jit(lambda s: sa.select_kernel(s, 2048)).lower(
+            arg((1, T, T), jnp.float32))
+    (call,) = _custom_calls(compiled.compile(), kernel)
+    assert "tpu_custom_call" in call
+    result = {"dsa_flash_fwd": "= (bf16[32,8192,128]{",
+              "dsa_flash_dq_flash_dkv": "= (bf16[32,8192,128]{",
+              "dsa_index_scores": "= f32[1,8192,8192]{",
+              "dsa_select": "= s8[1,8192,8192]{"}[kernel]
+    assert result in call

@@ -621,6 +621,9 @@ WAIVED = {
     "beam_backtrack": "beam state machine; tests/test_machine_translation.py",
     "tile_beam": "beam plumbing; tests/test_machine_translation.py",
     "fused_attention": "pallas kernel; tests/test_flash_attention.py",
+    "dsa_index_scores": "no gradient by design, minus infinity above the "
+                        "diagonal; tests/test_keye_vl2.py",
+    "dsa_select": "int8 result, no gradient; tests/test_keye_vl2.py",
     "paged_attention": "stateful KV-cache step; tests/test_decode.py",
     "prefill_attention": "stateful KV-cache step; tests/test_decode.py",
     "paged_attention_q8": "stateful int8-KV step; tests/test_torrent.py "
