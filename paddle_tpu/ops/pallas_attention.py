@@ -90,6 +90,21 @@ is fetched that is not used. They carry names of their own (`swa_flash_fwd`,
 `W >= T` is plain causal and runs the plain kernels; without a window every
 kernel is the instructions it was.
 
+Under a window or a kept set the causal mask is applied where it can change
+a score. A live tile that lies wholly under the diagonal and, under a
+window, inside the band is *interior* (`_causal_interior`): the four kernels
+that stream tiles run it in a body without the mask's compare-and-select,
+and the tiles an edge crosses (the diagonal, the band's lower one) in the
+body that has it (`_on_live_tile`): 28 of a head's 36 tiles at 8192 tokens
+are interior, 15 of 45 under a window of 1024 in tiles of 512, 18 of 30
+under 2048 over 4096. Leaving out a select whose predicate is false in every
+element changes no bit. A kept set is data and masks every tile of its call;
+the causal mask stays on that call's edge tiles, so its meaning does not
+rest on the set lying under the diagonal. A plain causal call keeps the mask
+on every live tile, the instructions it was: at its 1024 x 1024 tiles the
+pass hides behind the products and a second body is a cost
+(`_interior_apart`); so does every call whose row is one K block.
+
 On a CPU backend the same kernels run under the Pallas interpreter when
 PADDLE_TPU_PALLAS_INTERPRET=1 (used by the CPU test suite); otherwise a
 pure-jnp reference path takes over there. On the TPU there is no second
@@ -321,6 +336,59 @@ def _causal_live(qi, kj, blk_q, blk_k, window=None):
     return live
 
 
+def _causal_interior(qi, kj, blk_q, blk_k, window=None):
+    """Whether the mask can change no score of the (qi, kj) block: its last
+    key is not above its first query and, under a `window` W, its farthest
+    pair (last query, first key) is inside the band. `col > row` and
+    `row - col >= W` are then false in every element, and the selects of
+    `_apply_causal_mask` would hand back the bits they were given. An
+    interior tile is live. On Python ints (`interior_tiles`) and on traced
+    int32 alike; plain `False`, not traced, where no tile of these sizes fits
+    inside the window. Shared by the four kernels that stream tiles, as
+    `_causal_live` is."""
+    if window is not None and blk_q + blk_k - 2 >= window:
+        return False
+    inside = kj * blk_k + blk_k - 1 <= qi * blk_q
+    if window is not None:
+        inside = inside & (qi * blk_q + blk_q - 1 - kj * blk_k < window)
+    return inside
+
+
+def _interior_apart(window, kept):
+    """Whether a causal call's interior tiles run in a body of their own,
+    without the causal mask: under a `window` or a `kept` set (its ref or
+    its array). There other vector work stands beside the mask's and the
+    pass shows: 1.9% of a `dsa_` layer's kernels at 8192 x 128, 4.5-4.8% of
+    a windowed one's at tiles of 512. A plain causal call at 1024 x 1024
+    tiles hides the whole pass behind its products, the mask on no tile at
+    all is no faster, and a second body costs it 0.3-0.5% (chip runs, PR 55,
+    `tools/interior_mask_probe.py`): it keeps its one body, the instructions
+    it was."""
+    return window is not None or kept is not None
+
+
+def _on_live_tile(update, causal, qi, kj, blk_q, blk_k, window, apart):
+    """`update(masked)` if the (qi, kj) tile is live. Where interior tiles
+    run `apart` (`_interior_apart`, and a row of several K blocks): in a
+    body without the causal mask where the tile is interior, in the masked
+    body where an edge (the diagonal, the band's lower one) crosses it. Two
+    `pl.when` bodies, each straight-line, and not a conditional on the
+    score tile, which cuts a body's products from its vector work (a fourth
+    slower than the mask on every tile). Elsewhere, and where the window is
+    narrower than a tile, one masked body; a call that is not causal has no
+    mask and no condition."""
+    from jax.experimental import pallas as pl
+
+    if not causal:
+        return update(False)
+    live = _causal_live(qi, kj, blk_q, blk_k, window)
+    interior = apart and _causal_interior(qi, kj, blk_q, blk_k, window)
+    if interior is False:
+        return pl.when(live)(lambda: update(True))
+    pl.when(interior)(lambda: update(False))
+    pl.when(live & jnp.logical_not(interior))(lambda: update(True))
+
+
 def _apply_causal_mask(s, qi, kj, blk_q, blk_k, window=None):
     """Mask strictly-above-diagonal entries of one score tile, and under a
     `window` W those W or more below it."""
@@ -412,6 +480,21 @@ def causal_tiles(T):
     too: its tiles are masked, none is skipped)."""
     bq, bk = _blk(T, True)
     return sum(_last_k(qi, bq, bk) + 1 for qi in range(T // bq))
+
+
+def interior_tiles(T, window=None):
+    """Score tiles of a causal forward call that the causal mask cannot
+    change, a head (`_causal_interior`): what `fused_attention` tallies as
+    `flash_tiles_unmasked`, times its batch and heads, for the calls whose
+    kernels run them without the mask (`_interior_apart`). None where a row
+    is one K block, and none at a length outside the kernels' envelope,
+    which the reference path runs."""
+    if T % _LANES:
+        return 0
+    window = _window_of(window, T)
+    bq, bk = _blk(T, True, window)
+    return sum(_causal_interior(qi, kj, bq, bk, window)
+               for qi in range(T // bq) for kj in range(T // bk))
 
 
 def kept_pairs(T, topk):
@@ -557,10 +640,12 @@ def _col(x):
     return x[:, None] if x.ndim == 1 else x
 
 
-def _score_tile(q_ref, k_ref, hd, qi, kj, sm_scale, causal, window=None,
+def _score_tile(q_ref, k_ref, hd, qi, kj, sm_scale, masked, window=None,
                 kept_ref=None):
     """One float32 [blk_q, blk_k] tile of q k^T * sm_scale, the causal mask
-    applied in-register. The dots run in the INPUT dtype (bf16 under AMP ->
+    applied in-register where the tile is `masked` (a causal call's edge
+    tiles, `_on_live_tile`; every tile of a one-pass call), the kept set's
+    on every tile. The dots run in the INPUT dtype (bf16 under AMP ->
     full MXU rate; the round-3 kernels upcast to f32 first, quartering
     matmul throughput) with f32 accumulation via preferred_element_type;
     sm_scale is applied to the f32 product so no operand precision is
@@ -568,7 +653,7 @@ def _score_tile(q_ref, k_ref, hd, qi, kj, sm_scale, causal, window=None,
     s = lax.dot_general(_own(hd, q_ref[hd.blk]), k_ref[hd.blk],
                         (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32) * sm_scale
-    if causal:
+    if masked:
         s = _apply_causal_mask(s, qi, kj, q_ref.shape[1], k_ref.shape[1],
                                window)
     return _apply_kept(s, kept_ref)
@@ -674,13 +759,9 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    # causal: blocks entirely above the diagonal contribute nothing
-    live = _causal_live(qi, kj, blk_q, blk_k, window) if causal else True
-
-    @pl.when(live)
-    def _update():
+    def _update(masked):
         def head(hd):
-            s = _score_tile(q_ref, k_ref, hd, qi, kj, sm_scale, causal,
+            s = _score_tile(q_ref, k_ref, hd, qi, kj, sm_scale, masked,
                             window, kept_ref)
             m = m_sc[hd.stat]
             m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
@@ -694,6 +775,10 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             m_sc[hd.stat] = m_new
 
         _each_head(heads, first, head)
+
+    # causal: blocks entirely above the diagonal contribute nothing
+    _on_live_tile(_update, causal, qi, kj, blk_q, blk_k, window,
+                  _interior_apart(window, kept_ref))
 
     @pl.when(step == nk - 1)
     def _finalize():
@@ -723,10 +808,7 @@ def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     def _init():
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
-    live = _causal_live(qi, kj, blk_q, blk_k, window) if causal else True
-
-    @pl.when(live)
-    def _update():
+    def _update(masked):
         def head(hd):
             q = _own(hd, q_ref[hd.blk])
             do = _own(hd, do_ref[hd.blk])                  # [blk_q, D]
@@ -736,7 +818,7 @@ def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             v = v_ref[hd.blk]
             s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
-            if causal:
+            if masked:
                 s = _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
             s = _apply_kept(s, kept_ref)
             w = jnp.exp(s - lse[:, None])                  # normalized weights
@@ -754,6 +836,9 @@ def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 preferred_element_type=jnp.float32))
 
         _each_head(heads, first, head)
+
+    _on_live_tile(_update, causal, qi, kj, blk_q, blk_k, window,
+                  _interior_apart(window, kept_ref))
 
     @pl.when(step == nk - 1)
     def _finalize():
@@ -782,11 +867,7 @@ def _flash_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
 
-    # causal: q blocks strictly above this k block see none of it
-    live = _causal_live(qi, kj, blk_q, blk_k, window) if causal else True
-
-    @pl.when(live)
-    def _update():
+    def _update(masked):
         def head(hd):
             k = k_ref[hd.blk]                              # [blk_k, D]
             v = v_ref[hd.blk]
@@ -796,7 +877,7 @@ def _flash_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             delta = _delta(hd, delta_ref, do)
             s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
-            if causal:
+            if masked:
                 s = _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
             s = _apply_kept(s, kept_ref)
             w = jnp.exp(s - lse[:, None])                  # [blk_q, blk_k]
@@ -818,6 +899,10 @@ def _flash_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 preferred_element_type=jnp.float32))
 
         _each_head(heads, first, head)
+
+    # causal: q blocks strictly above this k block see none of it
+    _on_live_tile(_update, causal, qi, kj, blk_q, blk_k, window,
+                  _interior_apart(window, kept_ref))
 
     @pl.when(step == nq - 1)
     def _finalize():
@@ -859,11 +944,7 @@ def _flash_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         def _init_row():
             dq_sc[...] = jnp.zeros_like(dq_sc)
 
-    # with one K block a row (kj == 0) every tile is live
-    live = _causal_live(qi, kj, blk_q, blk_k, window) if causal else True
-
-    @pl.when(live)
-    def _update():
+    def _update(masked):
         def head(hd):
             k = k_ref[hd.blk]                              # [blk_k, D]
             v = v_ref[hd.blk]
@@ -873,7 +954,7 @@ def _flash_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             delta = _delta(hd, delta_ref, do)
             s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
-            if causal:
+            if masked:
                 s = _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
             s = _apply_kept(s, kept_ref)
             w = jnp.exp(s - lse[:, None])                  # [blk_q, blk_k]
@@ -902,6 +983,10 @@ def _flash_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 _rmw(hd, dq_sc, (rows, hd.lanes), lambda acc: acc + dq)
 
         _each_head(heads, first, head)
+
+    # with one K block a row (kj == 0) every tile is live, and none interior
+    _on_live_tile(_update, causal, qi, kj, blk_q, blk_k, window,
+                  dq_sc is not None and _interior_apart(window, kept_ref))
 
     @pl.when(step == nq - 1)
     def _finalize():
@@ -1629,7 +1714,9 @@ def _fused_attention(ctx, Q, K, V, Kept=None):
     causal, sm_scale, dropout_rate, is_test, layout, and `window` (causal
     only): key j is visible to query i iff 0 <= i - j < window; the op
     tallies the score tiles its forward grid computes
-    (`window_tiles_computed` on the compile event). `Kept` (optional): int8
+    (`window_tiles_computed` on the compile event) and, as an op under a
+    kept set does, those of them that run without the causal mask
+    (`flash_tiles_unmasked`). `Kept` (optional): int8
     [B, T, T], the keys each query keeps of those below the diagonal, one
     set for all heads; no gradient, no dropout, not with a window; the
     `dsa_` kernels read its tiles beside the score tiles, and the op tallies
@@ -1679,12 +1766,16 @@ def _fused_attention(ctx, Q, K, V, Kept=None):
                              sm_scale=sm_scale)
         return {"Out": out.transpose(0, 2, 1, 3) if token_major else out}
     seed = _dropout_seed(ctx, rate)
+    # the tallies are the forward grid's: not its grad op's trace
+    forward_op = ctx.op is not None and ctx.op.type == "fused_attention"
+    if forward_op and _interior_apart(window, Kept):
+        ctx.tally("flash_tiles_unmasked", B * H * interior_tiles(T, window))
     if Kept is not None:
         if rate:
             raise NotImplementedError(
                 "attention-weight dropout is not supported under a kept set")
         _check_kept(Kept, Q, causal, window, token_major)
-        if ctx.op is not None and ctx.op.type == "fused_attention":
+        if forward_op:
             ctx.tally("dsa_keys_kept",
                       B * kept_pairs(T, ctx.attr("topk", T)))
             ctx.tally("dsa_tiles_computed", B * H * causal_tiles(T))
@@ -1693,8 +1784,7 @@ def _fused_attention(ctx, Q, K, V, Kept=None):
             return {"Out": out, "Lse": lse}
         return {"Out": _reference(Q, K, V, causal, sm_scale, rate, seed,
                                   window, token_major, Kept)}
-    if window is not None and ctx.op is not None \
-            and ctx.op.type == "fused_attention":   # not its grad op's trace
+    if window is not None and forward_op:
         ctx.tally("window_tiles_computed", B * H * window_tiles(T, window))
     if _pallas_ok(Q, rate, V, window, token_major):
         out, lse = _flash_out_lse(Q, K, V, seed, causal, sm_scale, rate,

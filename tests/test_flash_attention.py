@@ -1095,3 +1095,165 @@ def test_transformer_program_has_no_transpose_around_its_attention_ops(
     assert np.isfinite(token_major)
     np.testing.assert_allclose(token_major, first_loss(main_h, startup_h,
                                                        loss_h), rtol=1e-6)
+
+
+# -- interior tiles run without the causal mask -----------------------------------
+# A live tile that lies wholly under the diagonal and inside the window is
+# *interior* (`_causal_interior`): the mask's selects would hand back the bits
+# they were given, and under a window or a kept set (`_interior_apart`) the
+# kernels run such a tile in a body without them. The oracle is the same
+# kernels with the predicate answering "edge" for every tile: the mask on
+# every live tile, one body, the form they had. A plain causal call is that
+# form still: the pass hides behind its products on the chip.
+
+def _brute_visible(T, window):
+    row, col = np.arange(T)[:, None], np.arange(T)[None, :]
+    visible = col <= row
+    return visible if window is None else visible & (row - col < window)
+
+
+@pytest.mark.parametrize("window", [None, 1, 96, 128, 255, 256, 257, 384,
+                                    640])
+@pytest.mark.parametrize("tiles", [(bq, bk) for bq in (128, 256, 512)
+                                   for bk in (128, 256, 512)],
+                         ids=lambda t: f"{t[0]}x{t[1]}")
+def test_interior_is_a_tile_whose_mask_hides_nothing(monkeypatch, tiles,
+                                                     window):
+    """Against the `[T, T]` mask itself: a tile is interior iff every pair
+    in it is visible, and live iff any is; an interior tile is live;
+    `interior_tiles` counts them."""
+    T, (bq, bk) = 1024, tiles
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
+    visible = _brute_visible(T, window)
+    count = 0
+    for qi in range(T // bq):
+        for kj in range(T // bk):
+            tile = visible[qi * bq:(qi + 1) * bq, kj * bk:(kj + 1) * bk]
+            interior = pallas_attention._causal_interior(qi, kj, bq, bk,
+                                                         window)
+            assert bool(interior) == bool(tile.all()), (qi, kj)
+            assert bool(pallas_attention._causal_live(
+                qi, kj, bq, bk, window)) == bool(tile.any()), (qi, kj)
+            # the same answer on traced int32 as on Python ints
+            traced = pallas_attention._causal_interior(
+                jnp.int32(qi), jnp.int32(kj), bq, bk, window)
+            assert bool(traced) == bool(interior)
+            count += bool(interior)
+    assert pallas_attention.interior_tiles(T, window) == count
+
+
+@pytest.mark.parametrize("T,window,tiles,interior", [
+    (8192, None, (1024, 1024), 28),     # Keye's and Mellum2's full layer: of 36
+    (4096, None, (1024, 1024), 6),      # Ouro, Kanana-2, OLMoE, ...: of 10
+    (8192, 1024, (512, 512), 15),       # Mellum2's windowed layers: of 45
+    (4096, 2048, (512, 512), 18),       # Trinity-Mini's: of 30
+    (2048, None, (256, 2048), 0),       # seq 2048: one K block a row
+    (256, None, (256, 256), 0),
+    (200, None, None, 0)],              # the reference path's
+    ids=["8192", "4096", "8192_w1024", "4096_w2048", "2048", "256", "200"])
+def test_interior_tiles_at_the_cells_lengths(T, window, tiles, interior):
+    if tiles:
+        assert pallas_attention._blk(T, True, window) == tiles
+    assert pallas_attention.interior_tiles(T, window) == interior
+
+
+def _selected(rng, B, T, topk):
+    from paddle_tpu.ops import sparse_attention
+    return sparse_attention.select_xla(
+        jnp.asarray(rng.randn(B, T, T), jnp.float32), topk)
+
+
+# name -> T, tiles, (D, Dv), window, kept set, dtype
+INTERIOR_CASES = {
+    # Ouro's, OLMoE's pattern, four tiles a row, six of ten interior, and
+    # Kanana-2's, value heads narrower than the query's, bf16 as under AMP:
+    # plain causal calls, one body
+    "causal_4x4": (512, (128, 128), (64, 64), None, None, jnp.float32),
+    "causal_4x4_D192_Dv128": (512, (128, 128), (192, 128), None, None,
+                              jnp.bfloat16),
+    "window_D192_Dv128": (512, (128, 128), (192, 128), 300, None,
+                          jnp.bfloat16),
+    # Mellum2's: a window of two tiles; Trinity-Mini's: of four
+    "window_2_tiles": (1024, (128, 128), (64, 64), 256, None, jnp.float32),
+    "window_4_tiles": (1024, (128, 128), (64, 64), 512, None, jnp.float32),
+    "window_off_the_tiles": (1024, (128, 128), (64, 64), 300, None,
+                             jnp.float32),
+    # Keye's: a kept set from the selection, the causal mask on the
+    # diagonal tiles alone
+    "kept_selected": (512, (128, 128), (64, 64), None, "selected",
+                      jnp.float32),
+    # a set with ones above the diagonal: the diagonal tiles still hide them
+    "kept_ones_above": (512, (128, 128), (64, 64), None, "ones",
+                        jnp.float32),
+    "tiles_256x128": (1024, (256, 128), (64, 64), None, "selected",
+                      jnp.float32),
+    "tiles_128x256": (1024, (128, 256), (64, 64), 600, None, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("plan", ["fused", "split"])
+@pytest.mark.parametrize("case", sorted(INTERIOR_CASES))
+def test_unmasked_interior_is_bitwise_the_mask_on_every_tile(
+        interpret_kernels, monkeypatch, case, plan):
+    """`Out`, `Lse`, dQ, dK and dV of the streaming forward, the fused
+    backward and the split pair, `==` the same kernels' with the mask on
+    every live tile (a select whose predicate is false in every element
+    changes no bit), at tiles among which are interior, diagonal and
+    band-edge ones; and close to the einsum reference. The scale is a power
+    of two: XLA:CPU, which runs the interpreted bodies, contracts `s * scale
+    - m` into one fused multiply-add where no select stands between the two,
+    and the product has to be exact for that to round as the pair does (the
+    gated `tests/test_flash_grad_tpu.py` holds the chip's own arithmetic at
+    the cells' scales)."""
+    T, tiles, (D, Dv), window, kept, dtype = INTERIOR_CASES[case]
+    rng = np.random.RandomState(21)
+    B, H, scale = 1, 2, 2.0 ** round(np.log2(D ** -0.5))
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
+    if plan == "split":
+        monkeypatch.setattr(pallas_attention, "_bwd_plan",
+                            lambda *a: "split")
+    bq, bk = tiles
+    kinds = {(bool(pallas_attention._causal_interior(qi, kj, bq, bk, window)),
+              bool(pallas_attention._causal_live(qi, kj, bq, bk, window)))
+             for qi in range(T // bq) for kj in range(T // bk)}
+    assert kinds == {(True, True), (False, True), (False, False)}
+    q, k, v = (x.astype(dtype) for x in _qkv(rng, B, H, T, D, Dv))
+    g = jnp.asarray(rng.randn(B, H, T, Dv), dtype)
+    if kept == "selected":
+        kept = _selected(rng, B, T, 200)
+    elif kept == "ones":
+        kept = jnp.ones((B, T, T), jnp.int8)
+
+    def run():
+        """A trace of its own for each form: jax keeps one a function."""
+        def both(q, k, v):
+            out, lse = pallas_attention._flash_forward(
+                q, k, v, True, scale, window=window, kept=kept)
+            return (out, lse) + tuple(pallas_attention._flash_backward(
+                q, k, v, out, lse, g, True, scale, 0.0, 0, window, kept=kept))
+        return str(jax.make_jaxpr(both)(q, k, v)).count("cond["), \
+            both(q, k, v)
+
+    conds, got = run()
+    monkeypatch.setattr(pallas_attention, "_causal_interior",
+                        lambda *a, **kw: False)
+    conds_masked, want = run()
+    # under a window or a kept set a body more in each kernel (the forward,
+    # and one or two backward); a plain causal call is the one form
+    apart = window is not None or kept is not None
+    assert conds - conds_masked == apart * (2 if plan == "fused" else 3)
+    for a, b, name in zip(got, want, ("Out", "Lse", "dQ", "dK", "dV")):
+        assert np.abs(np.asarray(a, np.float32)).max() > 0, name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+    mask = None if kept is None else kept * jnp.asarray(
+        _brute_visible(T, None), jnp.int8)[None]
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    ref, vjp = jax.vjp(lambda *a: _attention_reference(
+        *a, True, scale, window=window, kept=mask), *f32)
+    tol = 1e-4 if dtype == jnp.float32 else 6e-2
+    for a, b, name in zip((got[0],) + got[2:],
+                          (ref,) + vjp(g.astype(jnp.float32)),
+                          ("Out", "dQ", "dK", "dV")):
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
+                                   atol=tol, rtol=tol, err_msg=name)

@@ -10,7 +10,9 @@ And that the fused backward kernel (dQ, dK and dV from one pass over the
 score tiles) gives the bits of the split pair it replaced, masks and all.
 And that the kernels on `[batch, seq, heads, head_dim]` operands, a head a
 range of lanes and several heads a grid step, give what the head-major
-kernels give on the transposed operands: `Out`, `Lse` and dV bit for bit."""
+kernels give on the transposed operands: `Out`, `Lse` and dV bit for bit.
+And that the kernels which run a causal call's interior tiles without the
+mask give the bits of the mask on every tile, at the cells' own scales."""
 
 import numpy as np
 import pytest
@@ -168,6 +170,63 @@ def test_onepass_forward_is_bitwise_the_streaming_kernel(monkeypatch, shape,
     assert not np.array_equal(np.asarray(one[0], np.float32),
                               np.asarray(no_drop[0], np.float32))
     for a, b, name in zip(one, stream, ("Out", "Lse", "dQ", "dK", "dV")):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a).all() and np.abs(a).max() > 0, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+# (q shape, value width, window, kept set): mellum2_12b_a2_5b.s8192's
+# windowed layers, trinity_mini_26b_a3b.s4096's, a window over latent
+# attention's widths, keye_vl_2_30b_a3b.s8192: the calls whose interior tiles
+# run without the causal mask (`_interior_apart`)
+INTERIOR = {"8192x128_w1024": ((1, 32, 8192, 128), 128, 1024, False),
+            "4096x128_w2048": ((1, 32, 4096, 128), 128, 2048, False),
+            "4096x192_128_w2048": ((1, 32, 4096, 192), 128, 2048, False),
+            "8192x128_kept": ((1, 32, 8192, 128), 128, None, True)}
+
+
+@pytest.mark.parametrize("plan", ["fused", "split"])
+@pytest.mark.parametrize("case", sorted(INTERIOR))
+def test_unmasked_interior_is_bitwise_the_mask_on_every_tile(monkeypatch,
+                                                             case, plan):
+    """At the cells' shapes, tiles and scales (`head_dim ** -0.5`, no power
+    of two at 128 and 192), by Mosaic's own arithmetic: `Out`, `Lse`, dQ, dK
+    and dV of the kernels that run interior tiles without the causal mask
+    are the bits of the same kernels with the mask on every live tile (the
+    interior predicate answering "edge" always), under a window and under a
+    kept set."""
+    shape, Dv, window, kept = INTERIOR[case]
+    B, H, T, D = shape
+    assert pallas_attention.interior_tiles(T, window) > 0
+    if plan == "split":
+        monkeypatch.setattr(pallas_attention, "_bwd_plan",
+                            lambda *a: "split")
+    rng = np.random.RandomState(T + D)
+    q, k = (jnp.asarray(rng.randn(*shape), jnp.bfloat16) for _ in range(2))
+    v, g = (jnp.asarray(rng.randn(B, H, T, Dv), jnp.bfloat16)
+            for _ in range(2))
+    if kept:        # a third of the keys below the diagonal, and a row's own
+        kept = jnp.asarray(np.tril(rng.rand(B, T, T) < 0.3)
+                           | np.eye(T, dtype=bool), jnp.int8)
+    else:
+        kept = None
+
+    def run(q, k, v, g):
+        out, lse = pallas_attention._flash_forward(
+            q, k, v, True, D ** -0.5, window=window, kept=kept)
+        return (out, lse) + pallas_attention._flash_backward(
+            q, k, v, out, lse, g, True, D ** -0.5, 0.0, 0, window, kept=kept)
+
+    def compiled_run():
+        """`run` traced afresh: the predicate in force is read."""
+        return jax.jit(lambda *a: run(*a)).lower(q, k, v, g).compile(
+            compiler_options=resolve_compiler_options("tpu"))(q, k, v, g)
+
+    got = compiled_run()
+    monkeypatch.setattr(pallas_attention, "_causal_interior",
+                        lambda *a, **kw: False)
+    want = compiled_run()
+    for a, b, name in zip(got, want, ("Out", "Lse", "dQ", "dK", "dV")):
         a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
         assert np.isfinite(a).all() and np.abs(a).max() > 0, name
         np.testing.assert_array_equal(a, b, err_msg=name)

@@ -614,6 +614,8 @@ def test_compile_event_carries_the_census():
     assert int(ROW_KEEPS.sum()) == pa.kept_pairs(T, K) == 14368
     # batch 2 x 4 heads x 3 layers, one 256 x 256 tile a head
     assert detail["dsa_tiles_computed"] == 2 * 4 * 3 * pa.causal_tiles(T)
+    # that one tile holds the diagonal: none runs without the causal mask
+    assert detail["flash_tiles_unmasked"] == 0 == pa.interior_tiles(T)
     assert detail["frozen_parameters"] == len(FROZEN)
     assert "window_tiles_computed" not in detail
     assert "dsa_layers" not in observe.observatory().latest(
@@ -632,6 +634,29 @@ def test_kept_pairs_by_closed_form(seq, topk, pairs):
 def test_causal_tiles_at_the_cells_length():
     assert pa._blk(8192, True) == (1024, 1024)
     assert pa.causal_tiles(8192) == 36
+    # 28 of them lie wholly under the diagonal: the kept set alone masks them
+    assert pa.interior_tiles(8192) == 28
+    assert 4 * 32 * pa.interior_tiles(8192) == 3584     # the cell's tally
+
+
+def test_the_unmasked_tally_follows_the_tiles(monkeypatch):
+    """At tiles of 128 a head's triangle over 256 tokens is three tiles, of
+    which the one under the diagonal runs without the causal mask, under the
+    kept set alone: the counter is the forward ops', summed over the layers,
+    and the grad ops' traces add nothing to it."""
+    monkeypatch.setattr(pa, "_BLOCK_OVERRIDE", (128, 128))
+    main, startup, fetches, _ = _program(
+        fluid.optimizer.SGD(learning_rate=1e-3))
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    exe.run(main, feed=_batch(), fetch_list=[fetches["loss"]], scope=scope)
+    detail = observe.observatory().latest(main._uid).detail
+    assert pa.causal_tiles(T) == 3 and pa.interior_tiles(T) == 1
+    assert detail["dsa_tiles_computed"] == 2 * 4 * 3 * 3
+    assert detail["flash_tiles_unmasked"] == 2 * 4 * 3 * 1
+    assert "flash_tiles_unmasked" not in observe.observatory().latest(
+        startup._uid).detail
 
 
 def test_every_layer_is_built_under_its_name_scopes(tiny):

@@ -504,6 +504,34 @@ def test_the_tally_follows_the_tiles(monkeypatch):
     assert detail["window_tiles_computed"] == 3 * 2 * 4 * 7
 
 
+@pytest.mark.parametrize("window,windowed,band", [(96, 0, 7), (300, 3, 10)])
+def test_the_unmasked_tally_follows_the_tiles(monkeypatch, window, windowed,
+                                              band):
+    """`flash_tiles_unmasked`: the tiles that run without the causal mask,
+    summed over the windowed ops, the grad ops' traces adding nothing. At
+    tiles of 128 over 512 tokens a window of 96 is narrower than a tile and
+    has none, a window of 300 holds the three next to the diagonal; the
+    full layer's six under the diagonal keep the mask (a plain causal call:
+    `_interior_apart`) and add nothing."""
+    from paddle_tpu.ops import pallas_attention
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", (128, 128))
+    assert pallas_attention.interior_tiles(512) == 6
+    assert pallas_attention.interior_tiles(512, window) == windowed
+    main, startup, fetches, _ = _program(
+        fluid.optimizer.SGD(learning_rate=1e-3), seq_len=512, n_layer=4,
+        sliding_window=window)
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    exe.run(main, feed=_batch(seq_len=512), fetch_list=[fetches["loss"]],
+            scope=scope)
+    detail = observe.observatory().latest(main._uid).detail
+    assert detail["window_tiles_computed"] == 3 * 2 * 4 * band
+    assert detail["flash_tiles_unmasked"] == 2 * 4 * 3 * windowed
+    assert "flash_tiles_unmasked" not in observe.observatory().latest(
+        startup._uid).detail
+
+
 def test_a_window_over_the_whole_sequence_is_counted_as_full():
     main, startup, fetches, _ = _program(
         fluid.optimizer.SGD(learning_rate=1e-3), sliding_window=256,

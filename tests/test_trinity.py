@@ -521,7 +521,9 @@ CENSUS = {"layer_kinds": {"window_attention": 4, "full_attention": 1},
           "moe_share_bounded_moves": 4 * 4, "moe_share_bounded_ops": 3 * 4,
           # batch 2 x 4 heads x 4 layers, 128 x 128 tiles under a window of
           # 96: all three of the triangle's meet the band
-          "window_tiles_computed": 2 * 4 * 4 * 3}
+          "window_tiles_computed": 2 * 4 * 4 * 3,
+          # none of them lies wholly under the diagonal and inside the window
+          "flash_tiles_unmasked": 0}
 
 
 @pytest.fixture(scope="module")
@@ -541,6 +543,27 @@ def test_compile_event_carries_the_census(census, key):
     detail, startup_detail = census
     assert detail[key] == CENSUS[key]
     assert key not in startup_detail
+
+
+def test_the_unmasked_tally_follows_the_tiles(monkeypatch):
+    """At tiles of 128 over 512 tokens and a window of 300 a windowed layer
+    runs the three tiles next to the diagonal without the causal mask:
+    `flash_tiles_unmasked` sums the windowed ops, forward ops only; the full
+    layer's six under the diagonal keep the mask and add nothing."""
+    from paddle_tpu.ops import pallas_attention
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", (128, 128))
+    monkeypatch.setitem(TINY, "seq_len", 512)
+    main, startup, fetches, _ = _program(
+        fluid.optimizer.SGD(learning_rate=1e-3), sliding_window=300)
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    exe.run(main, feed=_batch(), fetch_list=[fetches["loss"]], scope=scope)
+    detail = observe.observatory().latest(main._uid).detail
+    assert detail["layer_kinds"] == CENSUS["layer_kinds"]
+    assert pallas_attention.interior_tiles(512, 300) == 3
+    assert detail["window_tiles_computed"] == 2 * 4 * 4 * 10
+    assert detail["flash_tiles_unmasked"] == 2 * 4 * 4 * 3
 
 
 @pytest.mark.parametrize("model,want", [
