@@ -260,18 +260,27 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     that no update op names, in a program that has update ops: what the loss
     cannot reach and `minimize` therefore left alone, without moments (an
     indexer behind a selection that carries no gradient).
+    `state_space`: an `ssd_scan` op (a Mamba-2 mixer), with
+    `state_space_layers`, their count again as a flat number; a program
+    that has them reports its softmax-attention layers' `attention_kv_group`
+    and `attention_unrotated_layers` whether or not another layer is
+    windowed or turns (its layers are a mixer or a feed-forward part alone,
+    and none of its attention layers carries positions).
+    `moe_expert_activation`: `relu2` where the routed experts are two
+    matrices around a `relu2` op (absent for gated silu experts).
     (`moe_row_buffer_rows`, the rows of the expert layer's layout, follows
     the batch: `moe_dispatch`'s rule notes it on the same event under the
     trace, `LoweringContext.note`.)"""
     block = program.global_block()
     kinds = {"linear_attention": 0, "full_attention": 0,
              "latent_attention": 0, "window_attention": 0,
-             "sparse_attention": 0}
+             "sparse_attention": 0, "state_space": 0}
     out: Dict[str, object] = {}
     copies: Dict[str, str] = {}     # an `assign` op's result -> what it copied
     biases = []                     # the routers' selection biases
     gated, routed = [], set()       # name scopes of `swiglu`s, of routers
     mixers = []                     # name scopes of `fused_attention`s
+    full_keys = []                  # the full-attention ops' K
     held_by = {"rotary_embedding": set(), "sigmoid": set()}  # name scopes
     normed, added = set(), set()    # `rms_norm` results, residual addends
     for op in block.ops:
@@ -280,6 +289,10 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
         scope = op.attrs.get(ir.NAME_SCOPE_ATTR)
         if op.type == "gated_delta_rule":
             kinds["linear_attention"] += 1
+        elif op.type == "ssd_scan":
+            kinds["state_space"] += 1
+        elif op.type == "relu2" and scope in routed:
+            out["moe_expert_activation"] = "relu2"
         elif op.type in held_by:
             held_by[op.type].add(scope)
         elif op.type == "rms_norm":
@@ -303,6 +316,7 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
                     out["attention_kv_group"] = group
             elif wide == value:
                 kinds["full_attention"] += 1
+                full_keys.append(op.input("K")[0])
             else:
                 kinds["latent_attention"] += 1
                 out["attention_qk_width"] = wide
@@ -327,6 +341,11 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
         out["attention_window_layers"] = kinds["window_attention"]
     if kinds["sparse_attention"]:
         out["dsa_layers"] = kinds["sparse_attention"]
+    if kinds["state_space"]:
+        out["state_space_layers"] = kinds["state_space"]
+        group = max([_expanded_by(block, k) for k in full_keys], default=1)
+        if group > 1:
+            out["attention_kv_group"] = group
     updated_params = {n for op in block.ops
                       if op.attrs.get("__role__") == "optimize"
                       for n in op.inputs.get("Param", [])}
@@ -343,8 +362,8 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     turned = sum(1 for scope in mixers if scope in held_by["rotary_embedding"])
     if turned:
         out["attention_rotary_layers"] = turned
-        if turned < len(mixers):
-            out["attention_unrotated_layers"] = len(mixers) - turned
+    if (turned or kinds["state_space"]) and turned < len(mixers):
+        out["attention_unrotated_layers"] = len(mixers) - turned
     gated_mixers = sum(1 for scope in mixers if scope in held_by["sigmoid"])
     if gated_mixers:
         out["attention_gated_layers"] = gated_mixers

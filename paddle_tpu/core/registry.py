@@ -200,7 +200,12 @@ AMP_F32_OPS = frozenset({"log_softmax", "cross_entropy",
                          # sigmoid in float32; `gated_delta_rule` then keeps
                          # its sums, norms and state in float32 inside its
                          # rule, like rms_norm, and needs no entry
-                         "delta_rule_gates"})
+                         "delta_rule_gates",
+                         # a state-space layer's step size and log-decay
+                         # from a bf16 projection, likewise; `ssd_scan`
+                         # keeps its sums, decays and state in float32
+                         # inside its rule
+                         "ssd_gates"})
 # Mixed-dtype elementwise ops downcast the f32 side to bf16 instead of
 # letting numpy promotion upcast the bf16 side: one f32 mask/bias/table
 # leaking into the residual or attention-score stream would otherwise

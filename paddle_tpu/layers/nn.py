@@ -1352,19 +1352,43 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, zero_centered=False,
     return out
 
 
-def gated_rms_norm(input, gate, epsilon=1e-6, param_attr=None, name=None):
+def gated_rms_norm(input, gate, epsilon=1e-6, param_attr=None, name=None,
+                   gate_first=False, group_size=None):
     """`x * rsqrt(mean(x^2) + epsilon) * w * silu(gate)` over the last axis,
     `gate` of `input`'s shape, a learned weight of that width (initialised
-    to 1): the output norm of a gated-delta-rule layer, over a head."""
+    to 1): the output norm of a gated-delta-rule layer, over a head.
+
+    `gate_first` (a Mamba-2 layer's output norm, `norm_before_gate` false):
+    `u = x * silu(gate)` first, then `u * rsqrt(mean(u^2) + epsilon) * w`,
+    the mean over each run of `group_size` elements of the last axis
+    (default: all of it) and w as wide as that whole axis, every lane its
+    own. Either way `input` and `gate` `[..., tokens, width]` in any float
+    dtype (bf16 under AMP), the statistics, the gate's silu and the products
+    float32, the normed value rounded once to `input`'s dtype before the
+    last product, the result in `input`'s dtype and shape, w float32."""
     helper = LayerHelper("gated_rms_norm", **locals())
     scale = helper.create_parameter(
         param_attr, [input.shape[-1]], "float32",
         default_initializer=init.ConstantInitializer(1.0))
+    attrs = {"epsilon": epsilon}
+    x, z = input, gate
+    if gate_first:
+        attrs["gate_first"] = True
+        width = input.shape[-1]
+        group = int(group_size or width)
+        if width % group:
+            raise ValueError(f"groups of {group} do not divide {width}")
+        x, z = (reshape(t, shape=[0] * (len(input.shape) - 1)
+                        + [width // group, group]) for t in (input, gate))
+    elif group_size is not None:
+        raise ValueError("group_size goes with gate_first")
     out = helper.create_variable_for_type_inference(input.dtype)
     helper.append_op("gated_rms_norm",
-                     inputs={"X": [input.name], "Gate": [gate.name],
+                     inputs={"X": [x.name], "Gate": [z.name],
                              "Scale": [scale.name]},
-                     outputs={"Y": [out.name]}, attrs={"epsilon": epsilon})
+                     outputs={"Y": [out.name]}, attrs=attrs)
+    if gate_first:
+        out = reshape(out, shape=[0] * (len(input.shape) - 1) + [width])
     return out
 
 
@@ -1398,16 +1422,26 @@ def rotary_embedding(input, theta=10000.0, rotary_dim=None,
     return out
 
 
-def causal_conv1d(input, kernel_size, param_attr=None, name=None):
+def causal_conv1d(input, kernel_size, param_attr=None, name=None,
+                  bias_attr=None):
     """Depthwise causal convolution over time on `[batch, seq, channels]`,
-    no bias, then silu: output t reads inputs t - kernel_size + 1 .. t of
-    its own channel. The weight is `[channels, kernel_size]`."""
+    then silu: output t reads inputs t - kernel_size + 1 .. t of its own
+    channel. The weight is `[channels, kernel_size]`, float32; `bias_attr`
+    gives the op a bias `[channels]` (float32, starts at 0) that is added
+    before the silu, none without it. `input` in any float dtype (bf16 under
+    AMP), the taps' sum, the bias and the silu float32, the result in
+    `input`'s dtype and shape."""
     helper = LayerHelper("causal_conv1d", **locals())
     w = helper.create_parameter(param_attr, [input.shape[-1], kernel_size],
                                 "float32")
+    inputs = {"X": [input.name], "W": [w.name]}
+    if bias_attr is not None and bias_attr is not False:
+        bias = helper.create_parameter(
+            bias_attr, [input.shape[-1]], "float32",
+            default_initializer=init.ConstantInitializer(0.0))
+        inputs["Bias"] = [bias.name]
     out = helper.create_variable_for_type_inference(input.dtype)
-    helper.append_op("causal_conv1d",
-                     inputs={"X": [input.name], "W": [w.name]},
+    helper.append_op("causal_conv1d", inputs=inputs,
                      outputs={"Out": [out.name]},
                      attrs={"activation": "silu"})
     return out
@@ -1449,6 +1483,65 @@ def gated_delta_rule(q, k, v, a, b, a_log_attr=None, dt_bias_attr=None,
                              "G": [g.name], "Beta": [beta.name]},
                      outputs={"Out": [out.name], "States": [states.name]},
                      attrs={"chunk": int(chunk)})
+    return out
+
+
+def ssd_scan(x, b, c, dt_raw, a_log_attr=None, dt_bias_attr=None, d_attr=None,
+             chunk=128, name=None):
+    """The selective scan of a Mamba-2 layer (`ops/state_space.py`) on x
+    `[batch, seq, heads, head_dim]` and b, c `[batch, seq, groups, state]`
+    (a group's b and c serve `heads / groups` heads); `dt_raw` `[batch, seq,
+    heads]` makes a head's step size `dt = softplus(dt_raw + dt_bias)` and
+    log-decay `a = -exp(A_log) * dt` in float32 (`ssd_gates`, AMP_F32_OPS),
+    with the learned `A_log`, `dt_bias` and the skip `D` `[heads]`
+    (`a_log_attr`, `dt_bias_attr`, `d_attr`; float32, `D` starts at 1). Per
+    head a float32 state `[head_dim, state]` from 0: `S_t = exp(a_t) S_{t-1}
+    + dt_t x_t b_t^T`, `y_t = S_t c_t + D x_t`, computed in chunks of
+    `chunk` tokens; seq must be a multiple of it. x, b, c in any float
+    dtype (bf16 under AMP: the products take bf16 operands into float32
+    sums on the chip); dt, a, their running sums, the decays and the state
+    float32. Returns `[batch, seq, heads, head_dim]` in x's dtype.
+
+    The op has a second output, `States`: float32 `[seq / chunk, batch,
+    heads, head_dim, state]`, the state each chunk started from, as the
+    forward kernel `ssd_fwd` saves it. `ssd_scan_grad` reads it back and
+    runs `ssd_bwd` alone. Where the forward op wrote none (head dims that do
+    not fill a vreg, a CPU backend: the XLA form) the grad op traces the
+    scan again under `jax.vjp`."""
+    helper = LayerHelper("ssd_scan", name=name)
+    heads = x.shape[2]
+    a_log = helper.create_parameter(a_log_attr, [heads], "float32")
+    dt_bias = helper.create_parameter(
+        dt_bias_attr, [heads], "float32",
+        default_initializer=init.ConstantInitializer(0.0))
+    skip = helper.create_parameter(
+        d_attr, [heads], "float32",
+        default_initializer=init.ConstantInitializer(1.0))
+    new = helper.create_variable_for_type_inference
+    dt, a = new("float32"), new("float32")
+    helper.append_op("ssd_gates",
+                     inputs={"DtRaw": [dt_raw.name],
+                             "DtBias": [dt_bias.name], "ALog": [a_log.name]},
+                     outputs={"Dt": [dt.name], "A": [a.name]})
+    out = new(x.dtype)
+    states = new("float32", stop_gradient=True)
+    helper.append_op("ssd_scan",
+                     inputs={"X": [x.name], "Dt": [dt.name], "A": [a.name],
+                             "B": [b.name], "C": [c.name], "D": [skip.name]},
+                     outputs={"Out": [out.name], "States": [states.name]},
+                     attrs={"chunk": int(chunk)})
+    return out
+
+
+def relu2(x, name=None, group_sizes=None):
+    """`relu(x)^2` (`mlp_hidden_act: relu2`), float32 inside, x's dtype out.
+    With `group_sizes` (the `GroupSizes` of an expert layer's share, whose
+    rows x is) over the rows those groups use only."""
+    helper = LayerHelper("relu2", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    used = {} if group_sizes is None else {"GroupSizes": [group_sizes.name]}
+    helper.append_op("relu2", inputs={"X": [x.name], **used},
+                     outputs={"Out": [out.name]})
     return out
 
 
@@ -1668,7 +1761,8 @@ def moe_router(input, num_experts, k, param_attr=None, norm_topk_prob=False,
 
 
 def moe_experts(input, routing, num_experts, expert_size, param_attr=None,
-                name=None, first_expert=None, experts_held=None):
+                name=None, first_expert=None, experts_held=None, gated=True,
+                activation="silu", down_attr=None):
     """Dropless gated-silu experts on `input` [tokens, width] under
     `routing` (what `moe_router` returned): every assignment is computed,
     `down_e(silu(gate_e(x)) * up_e(x))` summed over a token's experts with
@@ -1677,7 +1771,8 @@ def moe_experts(input, routing, num_experts, expert_size, param_attr=None,
     routing where every expert is held. The weights are stacked over experts,
     `<name>.gate.w` / `<name>.up.w` [experts, width, expert_size] and
     `<name>.down.w` [experts, expert_size, width]; `param_attr` gives their
-    initializer.
+    initializer, `down_attr` another one for `<name>.down.w` alone where the
+    way back into the residual stream starts smaller.
 
     All `num_experts` experts are held unless `experts_held` is given: then
     this is one chip's share of an expert-parallel layer. The router chose
@@ -1699,7 +1794,16 @@ def moe_experts(input, routing, num_experts, expert_size, param_attr=None,
     rows' gradient is one variable that the op's grad sums over the used
     rows (two ops would have `append_backward` insert a `sum` over all of
     them), and `swiglu` takes `GroupSizes` and visits the used rows only,
-    as its grad does."""
+    as its grad does.
+
+    `gated=False, activation="relu2"`: experts of two matrices,
+    `down_e(relu(up_e(x))^2)`: no `<name>.gate.w`; one `grouped_matmul` for
+    `up`, `relu2` over the rows (with `GroupSizes` under a share: the used
+    rows only), one for `down`; layout, movements and sums as above. Either
+    way `input` `[tokens, width]` in any float dtype (bf16 under AMP, where
+    the grouped products take bf16 operands into float32 sums and the
+    activation is float32 inside); the weights float32; the result in
+    `input`'s dtype and shape."""
     from ..ops.moe import ROW_TILE
     from ..param_attr import ParamAttr
     helper = LayerHelper("moe_experts", **locals())
@@ -1709,15 +1813,21 @@ def moe_experts(input, routing, num_experts, expert_size, param_attr=None,
     dtype = input.dtype
     new = helper.create_variable_for_type_inference
 
-    def weight(which, shape):
+    def weight(which, shape, attr=base):
         return helper.create_parameter(
             ParamAttr(name=f"{prefix}.{which}.w",
-                      initializer=base.initializer), shape, "float32")
+                      initializer=attr.initializer), shape, "float32")
 
+    if (gated, activation) not in ((True, "silu"), (False, "relu2")):
+        raise ValueError(f"no expert layer with gated={gated} and "
+                         f"activation {activation!r}: gated silu or ungated "
+                         f"relu2")
     stacked = num_experts if experts_held is None else experts_held
-    w_gate = weight("gate", [stacked, width, expert_size])
+    if gated:
+        w_gate = weight("gate", [stacked, width, expert_size])
     w_up = weight("up", [stacked, width, expert_size])
-    w_down = weight("down", [stacked, expert_size, width])
+    w_down = weight("down", [stacked, expert_size, width],
+                    base if down_attr is None else ParamAttr._to_attr(down_attr))
     x_sorted = new(dtype)
     slot = new("int32", stop_gradient=True)
     source = new("int32", stop_gradient=True)
@@ -1748,7 +1858,10 @@ def moe_experts(input, routing, num_experts, expert_size, param_attr=None,
             outputs={"Out": [out.name for out in outs]})
         return outs
 
-    if share:
+    if not gated:
+        hidden = relu2(*grouped(x_sorted, w_up),
+                       group_sizes=sizes if share else None)
+    elif share:
         # one op for the two projections of `x_sorted`: its gradient is one
         # variable, summed over the used rows by the op's grad, and the
         # silu product follows the held groups too

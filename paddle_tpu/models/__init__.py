@@ -21,7 +21,11 @@ its own, a norm on both sides of every sublayer with experts inside, a scaled
 embedding (the first model whose attention kinds differ in whether they
 turn), and Keye-VL-2.0's language model: an indexer that picks the keys each
 query attends to, the flash kernels masked by that set (the first model whose
-mask is data, and the first with parameters that the loss cannot reach)."""
+mask is data, and the first with parameters that the loss cannot reach),
+and Nemotron-H: Mamba-2 state-space mixers (a chunked selective scan), softmax
+attention without positions and two-matrix relu² experts under a sigmoid
+router, one sublayer a layer by a pattern string (the first model whose
+layers are a mixer or a feed-forward part alone)."""
 
 from . import mnist  # noqa: F401
 from . import resnet  # noqa: F401
@@ -39,3 +43,4 @@ from . import kanana2  # noqa: F401
 from . import mellum2  # noqa: F401
 from . import trinity  # noqa: F401
 from . import keye_vl2  # noqa: F401
+from . import nemotron_h  # noqa: F401

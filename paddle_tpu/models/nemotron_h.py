@@ -1,0 +1,241 @@
+"""Nemotron-H (`model_type: nemotron_h`; NVIDIA-Nemotron-3-Nano-30B-A3B): a
+decoder-only LM whose layers are ONE sublayer each, a mixer or a feed-forward
+part alone, laid out by a pattern string over `M` (a Mamba-2 state-space
+mixer), `E` (a sparse-expert layer) and `*` (softmax attention). Block as in
+Nemotron-H (arXiv:2504.03624) and the public `nemotron_h` model code, mixer
+as in Mamba-2 (Dao & Gu 2024, arXiv:2405.21060), router as DeepSeek-V3's
+(`models/kanana2.py`). Built for ONE CHIP'S SHARE of an expert-parallel
+deployment: the router chooses among all `n_expert` experts, this chip holds
+`experts_held` of them from `first_expert` on and computes their part.
+
+    N_w(x) = x * rsqrt(mean(x^2) + eps) * w      every RMSNorm: a plain
+             weight that starts at 1, eps 1e-5
+    layer l:  x = x + F_l(N(x));  F_l is ONE of M, E, * by `layer_pattern[l]`;
+              after the last layer N, then the untied head
+    M:  [z | u | dt_raw] = x W_in      widths d_inner | d_inner + 2 G N | H
+                                       (d_inner = H * P), no bias
+        u = silu(conv(u) + b_conv)     depthwise, causal, `conv_kernel` taps
+        [xs | B | C] = u               d_inner (H heads of P) | G x N | G x N
+        dt = softplus(dt_raw + dt_bias)   float32, a head;  a = -exp(A_log) dt
+                                          (`time_step_limit` (0, inf): no clamp)
+        S_t = exp(a_t) S_{t-1} + dt_t xs_t B_t^T    S [P, N] a head, float32,
+                                          S_0 = 0; head h reads group h // (H/G)
+        y_t = S_t C_t + D xs_t            D a head; in chunks of `chunk` tokens
+        y = N_w(y * silu(z))              the gate BEFORE the norm, the mean
+                                          over each group of d_inner / G
+        out = y W_out
+    *:  q = x W_q (`n_head` heads), k = x W_k, v = x W_v (`n_kv_head` heads),
+        no bias, NO rotary, no QK-norm; causal softmax(q k^T head_dim^-0.5) v,
+        key-value head g serves query heads g * group .. (g + 1) * group - 1
+        (repeated in the Program); out = ctx W_o
+    E:  s = sigmoid(x W_r) in float32 over all experts; idx = top-k of s + b
+        (one group: n_group 1), b the selection bias [n_expert], float32,
+        NOT a parameter of the loss;  w = s[idx] / (sum_k s[idx] + 1e-20)
+        (`norm_topk_prob`) * routed_scaling_factor
+        routed = sum over the chosen experts held here of w_k *
+        down_e(relu(up_e x)^2), dropless: TWO matrices an expert, no gate
+        out = routed + down_s(relu(up_s x)^2)    the shared expert, `d_shared`
+    loss = mean cross-entropy (no auxiliary term: `noaux_tc` routing)
+    after the forward pass of a step, per E layer, outside the gradient:
+        c_e = assignments to expert e in this step (all experts);
+        b_e <- b_e + bias_update_rate * sign(mean(c) - c_e)   (b from 0)
+
+ASSUMED, the config having no key for them: no positions in the attention
+layers (Nemotron-H and the public code: `rope_theta` is read by nothing); the
+gate before the grouped norm (the public `MambaRMSNormGated`,
+`norm_before_gate` false); the order of `W_in`'s columns; the public Mamba-2
+initialisation (`A_log` = log(1..H), `D` = 1, `dt_bias` the inverse softplus
+of a log-uniform draw in [`time_step_min`, `time_step_max`] floored at
+`time_step_floor`, the convolution's weight uniform(+-`conv_kernel`^-0.5),
+its bias 0); weights normal(0, 0.02), the out projections of every sublayer
+divided by sqrt(`rescale_layers`) (`rescale_prenorm_residual`: the PUBLISHED
+depth, whatever `layer_pattern` holds here); the bias rule's form and rate
+(DeepSeek-V3, arXiv:2412.19437, section 2.1.2; 0.001). Float32 under AMP: the
+router (`moe_router`, AMP_F32_OPS), `b` and its update, dt and a (`ssd_gates`,
+AMP_F32_OPS), and inside their rules the running sums, decays and state of
+`ssd_scan`, the convolution's sums and every norm's statistics. Built from
+`fluid.layers` only; parameter names are fixed (`l0.norm.w`, `l0.mamba.in.w`,
+`l0.mamba.conv.w`, `l0.mamba.conv.b`, `l0.mamba.A_log`, `l0.mamba.dt_bias`,
+`l0.mamba.D`, `l0.mamba.norm.w`, `l0.mamba.out.w`, `l5.attn.q.w`,
+`l1.router.w`, `l1.router.bias`, `l1.experts.up.w`, `l1.experts.down.w`,
+`l1.shared.up.w`, ...) so that a reference can be handed the same weights by
+name. A layer's ops (its norm included) carry `fluid.name_scope("l<i>.mamba"
+| "l<i>.attn" | "l<i>.moe")`.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+from .. import initializer as init
+from .. import layers
+from ..core.ir import name_scope
+from ..param_attr import ParamAttr
+from .kanana2 import INIT_STD, _last, _linear, _norm, _w
+
+KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
+NEMOTRON_3_NANO = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+
+
+def _out_linear(x, size, name, rescale_layers):
+    """A sublayer's projection back into the residual stream: its weight
+    starts `sqrt(rescale_layers)` times smaller."""
+    return layers.fc(
+        input=x, size=size, num_flatten_dims=2, bias_attr=False,
+        param_attr=ParamAttr(name=name + ".w", initializer=_out_init(
+            rescale_layers)))
+
+
+def _out_init(rescale_layers):
+    return init.NormalInitializer(0.0, INIT_STD / rescale_layers ** 0.5)
+
+
+def dt_bias_init(heads, seed, dt_min=0.001, dt_max=0.1, dt_floor=1e-4):
+    """The public Mamba-2 draw: dt log-uniform in [dt_min, dt_max], floored,
+    and the bias its inverse softplus; drawn here so that the startup
+    program holds the values."""
+    u = np.random.RandomState(seed).uniform(size=heads)
+    dt = np.exp(u * (np.log(dt_max) - np.log(dt_min)) + np.log(dt_min))
+    dt = np.maximum(dt, dt_floor)
+    return (dt + np.log(-np.expm1(-dt))).astype("float32")
+
+
+def _mamba(x, n_head, head_dim, n_groups, state, conv_kernel, chunk, rms_eps,
+           time_step, rescale_layers, name, seed):
+    inner, bc = n_head * head_dim, n_groups * state
+    mixed = _linear(x, 2 * inner + 2 * bc + n_head, name + ".in")
+    z = _last(mixed, 0, inner)
+    u = layers.causal_conv1d(
+        _last(mixed, inner, 2 * inner + 2 * bc), conv_kernel,
+        param_attr=ParamAttr(
+            name=name + ".conv.w",
+            initializer=init.UniformInitializer(-conv_kernel ** -0.5,
+                                                conv_kernel ** -0.5)),
+        bias_attr=ParamAttr(name=name + ".conv.b"))
+    dt_raw = _last(mixed, 2 * inner + 2 * bc, 2 * inner + 2 * bc + n_head)
+    xs = layers.reshape(_last(u, 0, inner), shape=[0, 0, n_head, head_dim])
+    b = layers.reshape(_last(u, inner, inner + bc),
+                       shape=[0, 0, n_groups, state])
+    c = layers.reshape(_last(u, inner + bc, inner + 2 * bc),
+                       shape=[0, 0, n_groups, state])
+    y = layers.ssd_scan(
+        xs, b, c, dt_raw, chunk=chunk,
+        a_log_attr=ParamAttr(
+            name=name + ".A_log", initializer=init.NumpyArrayInitializer(
+                np.log(np.arange(1, n_head + 1)).astype("float32"))),
+        dt_bias_attr=ParamAttr(
+            name=name + ".dt_bias", initializer=init.NumpyArrayInitializer(
+                dt_bias_init(n_head, seed, *time_step))),
+        d_attr=ParamAttr(name=name + ".D"))
+    y = layers.gated_rms_norm(
+        layers.reshape(y, shape=[0, 0, inner]), z, epsilon=rms_eps,
+        param_attr=ParamAttr(name=name + ".norm.w"), gate_first=True,
+        group_size=inner // n_groups)
+    return _out_linear(y, x.shape[-1], name + ".out", rescale_layers)
+
+
+def _attention(x, n_head, n_kv_head, head_dim, rescale_layers, name):
+    def heads_first(t, n):      # [B, T, n * Dh] -> [B, n, T, Dh]
+        return layers.transpose(
+            layers.reshape(t, shape=[0, 0, n, head_dim]), perm=[0, 2, 1, 3])
+
+    q = heads_first(_linear(x, n_head * head_dim, name + ".q"), n_head)
+    k = heads_first(_linear(x, n_kv_head * head_dim, name + ".k"), n_kv_head)
+    v = heads_first(_linear(x, n_kv_head * head_dim, name + ".v"), n_kv_head)
+
+    def serve_group(t):     # [B, kv, T, Dh] -> [B, heads, T, Dh], h // group
+        group = n_head // n_kv_head
+        t = layers.expand(layers.unsqueeze(t, axes=[2]),
+                          expand_times=[1, 1, group, 1, 1])
+        return layers.reshape(t, shape=[0, n_head, -1, head_dim])
+
+    ctx = layers.fused_attention(q, serve_group(k), serve_group(v),
+                                 causal=True, sm_scale=head_dim ** -0.5)
+    ctx = layers.reshape(layers.transpose(ctx, perm=[0, 2, 1, 3]),
+                         shape=[0, 0, n_head * head_dim])
+    return _out_linear(ctx, x.shape[-1], name + ".o", rescale_layers)
+
+
+def _sparse_experts(x, seq_len, n_expert, top_k, d_expert, d_shared,
+                    first_expert, experts_held, routed_scaling_factor,
+                    bias_update_rate, rescale_layers, name):
+    d_model = x.shape[-1]
+    tokens = layers.reshape(x, shape=[-1, d_model])
+    routing = layers.moe_router(
+        tokens, n_expert, top_k, param_attr=_w(name + ".router.w"),
+        norm_topk_prob=True, score_func="sigmoid",
+        bias_attr=ParamAttr(name=name + ".router.bias"),
+        bias_update_rate=bias_update_rate, norm_eps=1e-20,
+        scaling_factor=routed_scaling_factor)
+    routed = layers.moe_experts(
+        tokens, routing, n_expert, d_expert,
+        param_attr=init.NormalInitializer(0.0, INIT_STD),
+        down_attr=_out_init(rescale_layers), gated=False,
+        activation="relu2", name=name + ".experts",
+        first_expert=first_expert, experts_held=experts_held)
+    shared = _out_linear(
+        layers.relu2(_linear(x, d_shared, name + ".shared.up")), d_model,
+        name + ".shared.down", rescale_layers)
+    out = layers.elementwise_add(
+        layers.reshape(routed, shape=[-1, seq_len, d_model]), shared)
+    return out, routing
+
+
+def nemotron_h(vocab_size=131072, seq_len=2048, layer_pattern=NEMOTRON_3_NANO,
+               d_model=2688, mamba_heads=64, mamba_head_dim=64, n_groups=8,
+               ssm_state=128, conv_kernel=4, chunk=128,
+               time_step=(0.001, 0.1, 1e-4), n_head=32, n_kv_head=2,
+               head_dim=128, n_expert=128, top_k=6, d_expert=1856,
+               d_shared=3712, routed_scaling_factor=2.5,
+               bias_update_rate=0.001, first_expert=0, experts_held=None,
+               rms_eps=1e-5, rescale_layers=52):
+    """Returns (feeds, fetches) of one training step on `[batch, seq_len]`
+    token ids and next-token labels. `layer_pattern`: one of `M`, `E`, `*` a
+    layer. `time_step`: (min, max, floor) of the draw behind `dt_bias`.
+    `rescale_layers`: the depth the out projections' initial scale follows
+    (the published 52). `experts_held` None holds all `n_expert` experts."""
+    unknown = set(layer_pattern) - set(KINDS)
+    if unknown or not layer_pattern:
+        raise ValueError(f"a layer pattern is a string over M, E and *, got "
+                         f"{layer_pattern!r}")
+    tokens = layers.data(name="tokens", shape=[-1, seq_len], dtype="int64",
+                         append_batch_size=False)
+    labels = layers.data(name="labels", shape=[-1, seq_len], dtype="int64",
+                         append_batch_size=False)
+
+    x = layers.embedding(tokens, size=[vocab_size, d_model],
+                         param_attr=_w("embed.w"))
+    routings = []
+    for i, kind in enumerate(layer_pattern):
+        name = f"l{i}"
+        with name_scope(f"{name}.{KINDS[kind]}"):
+            normed = _norm(x, rms_eps, name + ".norm")
+            if kind == "M":
+                part = _mamba(normed, mamba_heads, mamba_head_dim, n_groups,
+                              ssm_state, conv_kernel, chunk, rms_eps,
+                              time_step, rescale_layers, name + ".mamba",
+                              seed=i)
+            elif kind == "*":
+                part = _attention(normed, n_head, n_kv_head, head_dim,
+                                  rescale_layers, name + ".attn")
+            else:
+                part, routing = _sparse_experts(
+                    normed, seq_len, n_expert, top_k, d_expert, d_shared,
+                    first_expert, experts_held, routed_scaling_factor,
+                    bias_update_rate, rescale_layers, name)
+                routings.append(routing)
+        x = layers.elementwise_add(x, part)
+    x = _norm(x, rms_eps, "final_norm")
+    logits = _linear(x, vocab_size, "head")
+
+    ce = layers.mean(layers.softmax_with_cross_entropy(logits=logits,
+                                                       label=labels))
+    fetches = {"loss": ce, "ce": ce, "logits": logits}
+    if routings:
+        fetches["tokens_per_expert"] = layers.stack(
+            [r["tokens_per_expert"] for r in routings], axis=0)
+    return {"tokens": tokens, "labels": labels}, fetches
+
+
+def build(**kw):
+    return nemotron_h(**kw)

@@ -19,4 +19,5 @@ from . import extra_nn  # noqa: F401
 from . import decoder_block  # noqa: F401
 from . import moe  # noqa: F401
 from . import linear_attention  # noqa: F401
+from . import state_space  # noqa: F401
 from . import sparse_attention  # noqa: F401

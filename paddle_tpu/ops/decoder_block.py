@@ -206,6 +206,26 @@ def _gated_norm_bwd_kernel(x_ref, g_ref, dy_ref, w_ref, dx_ref, dg_ref,
     dw_ref[0, 0, 0] = acc
 
 
+def _gated_norm_layout(X, backward):
+    """What both forms of the gated norm's calls share: X `[..., T, H, D]`
+    read as `flat` = `[B, T, H * D]`, the heads (or groups) a block holds,
+    D, the grid (batch, token block, head block), the block of X, Gate and
+    dY, and the call's grid and compiler parameters."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    T, H, D = X.shape[-3:]
+    Tb, Hb = _gated_norm_blocks(T, H, D, X.dtype.itemsize, backward)
+    grid = (math.prod(X.shape[:-3]), T // Tb, H // Hb)
+    block = pl.BlockSpec((1, Tb, Hb * D), lambda b, t, h: (b, t, h))
+    params = dict(
+        grid=grid,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
+        interpret=_interpret())
+    return (-1, T, H * D), Hb, D, grid, block, params
+
+
 def _gated_norm_call(X, Gate, Scale, eps, d_y=None):
     """`gated_norm_fwd` (Y), or with `d_y` `gated_norm_bwd` (dX, dGate in
     their dtypes, dScale float32): X, Gate and dY `[..., T, H, D]` as they
@@ -214,21 +234,11 @@ def _gated_norm_call(X, Gate, Scale, eps, d_y=None):
     own (`[*grid, 8, D]` in all), and one small XLA sum over the steps
     finishes it: the same order every run."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
-    T, H, D = X.shape[-3:]
-    flat = (-1, T, H * D)
+    flat, Hb, D, grid, block, params = _gated_norm_layout(X, d_y is not None)
     x, gate = X.reshape(flat), Gate.reshape(flat)
-    Tb, Hb = _gated_norm_blocks(T, H, D, X.dtype.itemsize, d_y is not None)
-    grid = (x.shape[0], T // Tb, H // Hb)
-    block = pl.BlockSpec((1, Tb, Hb * D), lambda b, t, h: (b, t, h))
     weight = pl.BlockSpec((1, D), lambda b, t, h: (0, 0))
     w = Scale.astype(jnp.float32).reshape(1, D)
-    params = dict(
-        grid=grid,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel")),
-        interpret=_interpret())
     if d_y is None:
         return pl.pallas_call(
             functools.partial(_gated_norm_fwd_kernel, D=D, eps=eps),
@@ -248,17 +258,114 @@ def _gated_norm_call(X, Gate, Scale, eps, d_y=None):
         dW.sum(range(4))
 
 
+def _gate_first_norm_xla(X, Gate, Scale, eps):
+    """The op with the gate before the norm, as plain jnp: `u = x
+    silu(gate)`, `u rsqrt(mean(u^2) + eps)` over the last axis (a group),
+    rounded to X's dtype, times the weight, which is as wide as all the
+    groups together (`[..., groups, width]` against `[groups * width]`)."""
+    u = X.astype(jnp.float32) * jax.nn.silu(Gate.astype(jnp.float32))
+    ms = jnp.mean(u * u, axis=-1, keepdims=True)
+    normed = (u * lax.rsqrt(ms + eps)).astype(X.dtype)
+    w = Scale.astype(jnp.float32).reshape(X.shape[-2:])
+    return (w * normed.astype(jnp.float32)).astype(X.dtype)
+
+
+def _gate_first_tile(x, g, eps):
+    """A group's rows `[tokens, D]` as they arrive -> float32: x, the gate,
+    its sigmoid, each row's factor r of `u = x silu(g)`, u r, and that value
+    as the op rounds it (to X's dtype)."""
+    x32, g32 = x.astype(jnp.float32), g.astype(jnp.float32)
+    s = jax.nn.sigmoid(g32)
+    u = x32 * (g32 * s)
+    r = lax.rsqrt(jnp.mean(u * u, axis=-1, keepdims=True) + eps)
+    n = u * r
+    return x32, g32, s, r, n, n.astype(x.dtype).astype(jnp.float32)
+
+
+def _gate_first_fwd_kernel(x_ref, g_ref, w_ref, y_ref, *, D, eps):
+    """One (batch, token block, group block) step, group by group: Y in X's
+    dtype."""
+    for h in range(x_ref.shape[2] // D):
+        lanes = slice(h * D, (h + 1) * D)
+        *_, normed = _gate_first_tile(x_ref[0, :, lanes], g_ref[0, :, lanes],
+                                      eps)
+        y_ref[0, :, lanes] = (w_ref[:, lanes] * normed).astype(y_ref.dtype)
+
+
+def _gate_first_bwd_kernel(x_ref, g_ref, dy_ref, w_ref, dx_ref, dg_ref,
+                           dw_ref, *, D, eps):
+    """The same step on dY: with `dn = dy w`, `du = r (dn - n mean(dn n))`,
+    `dx = du silu(g)`, `dg = du x silu'(g)`, and the block's part of dScale,
+    `sum dy normed` a lane, as eight sublanes of partial sums."""
+    for h in range(x_ref.shape[2] // D):
+        lanes = slice(h * D, (h + 1) * D)
+        x, g, s, r, n, normed = _gate_first_tile(x_ref[0, :, lanes],
+                                                 g_ref[0, :, lanes], eps)
+        dy = dy_ref[0, :, lanes].astype(jnp.float32)
+        dn = dy * w_ref[:, lanes]
+        du = r * (dn - n * jnp.mean(dn * n, axis=-1, keepdims=True))
+        dx_ref[0, :, lanes] = (du * (g * s)).astype(dx_ref.dtype)
+        dg_ref[0, :, lanes] = (du * x * (s * (1.0 + g * (1.0 - s)))) \
+            .astype(dg_ref.dtype)
+        p = dy * normed
+        dw_ref[0, 0, :, lanes] = sum(p[i:i + 8]
+                                     for i in range(0, p.shape[0], 8))
+
+
+def _gate_first_norm_call(X, Gate, Scale, eps, d_y=None):
+    """`gated_norm_fwd` / `gated_norm_bwd` with the gate before the norm:
+    `_gated_norm_call`'s blocks, a group `[tokens, D]` where it has a head,
+    and the weight's lanes following the block's groups. A grid step's part
+    of dScale is `[8, groups * D]`, summed outside."""
+    from jax.experimental import pallas as pl
+
+    flat, Hb, D, grid, block, params = _gated_norm_layout(X, d_y is not None)
+    x, gate = X.reshape(flat), Gate.reshape(flat)
+    weight = pl.BlockSpec((1, Hb * D), lambda b, t, h: (0, h))
+    w = Scale.astype(jnp.float32).reshape(1, flat[2])
+    if d_y is None:
+        return pl.pallas_call(
+            functools.partial(_gate_first_fwd_kernel, D=D, eps=eps),
+            name="gated_norm_fwd", in_specs=[block, block, weight],
+            out_specs=block, out_shape=jax.ShapeDtypeStruct(x.shape, X.dtype),
+            **params)(x, gate, w).reshape(X.shape)
+    dX, dGate, dW = pl.pallas_call(
+        functools.partial(_gate_first_bwd_kernel, D=D, eps=eps),
+        name="gated_norm_bwd", in_specs=[block, block, block, weight],
+        out_specs=[block, block, pl.BlockSpec(
+            (1, 1, 8, Hb * D), lambda b, t, h: (b, t, 0, h))],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, X.dtype),
+                   jax.ShapeDtypeStruct(x.shape, Gate.dtype),
+                   jax.ShapeDtypeStruct(grid[:2] + (8, flat[2]), jnp.float32)],
+        **params)(x, gate, d_y.reshape(flat), w)
+    return dX.reshape(X.shape), dGate.reshape(Gate.shape), \
+        dW.sum(range(3))
+
+
+def _gated_norm_forms(ctx):
+    """(the jnp form, the kernels' call) of the op as its attributes have
+    it: Qwen3-Next's (the norm over a head, then the gate) or, with
+    `gate_first`, Mamba-2's (the gate, then the norm over a group)."""
+    if ctx.attr("gate_first", False):
+        return _gate_first_norm_xla, _gate_first_norm_call
+    return _gated_norm_xla, _gated_norm_call
+
+
 @register_op("gated_rms_norm")
 def _gated_rms_norm(ctx, X, Gate, Scale):
     """`x * rsqrt(mean(x^2) + eps) * w * silu(gate)` over the last axis (a
     head): the output norm of a gated-delta-rule layer. The normed value is
     rounded to the input's dtype before the gate multiplies it in float32,
-    as the public `qwen3_next` code does. One pass over X and Gate as
-    `gated_norm_fwd` where `_gated_norm_plan` gives the kernels."""
+    as the public `qwen3_next` code does. With `gate_first` the gate
+    multiplies x before the statistics are taken and the weight is as wide
+    as all the groups (`_gate_first_norm_xla`: a Mamba-2 layer's). One pass
+    over X and Gate as `gated_norm_fwd` where `_gated_norm_plan` gives the
+    kernels."""
     eps = ctx.attr("epsilon", 1e-6)
+    xla, call = _gated_norm_forms(ctx)
     if _gated_norm_kernels_run(X.shape, X.dtype):
-        return {"Y": _gated_norm_call(X, Gate, Scale, eps)}
-    return {"Y": _gated_norm_xla(X, Gate, Scale, eps)}
+        return {"Y": call(X, Gate, Scale, eps)}
+    return {"Y": xla(X, Gate, Scale, eps)}
 
 
 @register_grad("gated_rms_norm")
@@ -273,11 +380,11 @@ def _gated_rms_norm_grad(ctx, ins, out_grads):
     X, Gate, Scale = (ins[s][0] for s in ("X", "Gate", "Scale"))
     d_y = d_y.astype(X.dtype)
     eps = ctx.attr("epsilon", 1e-6)
+    xla, call = _gated_norm_forms(ctx)
     if _gated_norm_kernels_run(X.shape, X.dtype):
-        dX, dGate, dScale = _gated_norm_call(X, Gate, Scale, eps, d_y)
+        dX, dGate, dScale = call(X, Gate, Scale, eps, d_y)
     else:
-        _, vjp = jax.vjp(functools.partial(_gated_norm_xla, eps=eps),
-                         X, Gate, Scale)
+        _, vjp = jax.vjp(functools.partial(xla, eps=eps), X, Gate, Scale)
         dX, dGate, dScale = vjp(d_y)
     return {"X": dX, "Gate": dGate, "Scale": dScale.astype(Scale.dtype)}
 
@@ -565,6 +672,45 @@ def _swiglu_grad(ctx, ins, out_grads):
                                      ins["GroupSizes"][0], gate, up, g,
                                      in_place=2)
     return {"Gate": d_gate, "Up": d_up}
+
+
+def _relu2(x):
+    y = jnp.maximum(x.astype(jnp.float32), 0.0)
+    return (y * y).astype(x.dtype)
+
+
+def _relu2_grad(x, g):
+    return jax.vjp(_relu2, x)[1](g)
+
+
+@register_op("relu2")
+def _relu_squared(ctx, X, GroupSizes=None):
+    """`relu(x)^2`, the middle of an ungated two-matrix feed-forward
+    (`mlp_hidden_act: relu2`); float32 inside, X's dtype out. With
+    `GroupSizes` (the hidden rows of an expert layer's share, `ops/moe.py`)
+    over the rows the held groups use only, a chunk at a time, as `swiglu`
+    does."""
+    if GroupSizes is None:
+        return {"Out": _relu2(X)}
+    ctx.tally("moe_share_bounded_ops")
+    out, = map_used_rows(lambda x: (_relu2(x),), GroupSizes, X)
+    return {"Out": out}
+
+
+@register_grad("relu2")
+def _relu_squared_grad(ctx, ins, out_grads):
+    """`2 relu(x) g`: on the whole array what the generic grad op traces,
+    with `GroupSizes` the same on each chunk of the used rows, written over
+    the hidden rows, which this op reads last."""
+    x, g = ins["X"][0], out_grads["Out"][0]
+    if g is None:
+        return {}
+    g = g.astype(x.dtype)
+    if not ins.get("GroupSizes"):
+        return {"X": _relu2_grad(x, g)[0]}
+    ctx.tally("moe_share_bounded_ops")
+    d_x, = map_used_rows(_relu2_grad, ins["GroupSizes"][0], x, g, in_place=1)
+    return {"X": d_x}
 
 
 @register_op("exit_gate")

@@ -131,7 +131,7 @@ from ..core.registry import call_rule, get_op_def, register_grad, register_op
 from .pallas_attention import _interpret
 
 
-def _conv_xla(X, W, silu):
+def _conv_xla(X, W, silu, bias=None):
     """The convolution as plain jnp, and the form `jax.vjp` differentiates
     outside the kernels' envelope."""
     K = W.shape[1]
@@ -139,41 +139,51 @@ def _conv_xla(X, W, silu):
     x32 = jnp.pad(X.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
     w32 = W.astype(jnp.float32)
     y = sum(x32[:, j:j + T] * w32[:, j] for j in range(K))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     if silu:
         y = jax.nn.silu(y)
     return y.astype(X.dtype)
 
 
 @register_op("causal_conv1d")
-def _causal_conv1d(ctx, X, W):
+def _causal_conv1d(ctx, X, W, Bias=None):
     """X [B, T, C], W [C, K]: `y[t, c] = sum_j W[c, j] x[t - (K-1) + j, c]`
-    (zeros before t = 0, so output t reads inputs <= t only), then silu
-    unless `activation` is empty. Float32 sums, the input's dtype out. One
-    pass over X as `causal_conv_fwd` where `_conv_plan` gives the kernels."""
+    (zeros before t = 0, so output t reads inputs <= t only), plus `Bias`
+    [C] where given, then silu unless `activation` is empty. Float32 sums,
+    the input's dtype out. One pass over X as `causal_conv_fwd` where
+    `_conv_plan` gives the kernels."""
     silu = ctx.attr("activation", "silu") == "silu"
     if _conv_kernels_run(X.shape[1], X.shape[2], W.shape[1]):
-        return {"Out": _conv_forward(X, W, silu)}
-    return {"Out": _conv_xla(X, W, silu)}
+        return {"Out": _conv_forward(X, W, silu, Bias)}
+    return {"Out": _conv_xla(X, W, silu, Bias)}
 
 
 @register_grad("causal_conv1d")
 def _causal_conv1d_grad(ctx, ins, out_grads):
-    """dX and dW from X, W and dOut alone (the forward saves nothing): one
-    pass as `causal_conv_bwd`, which makes the pre-activation again in VMEM;
-    outside the envelope `jax.vjp` of the jnp form, as the generic grad
-    lowering would."""
+    """dX and dW (and dBias, the pre-activation's gradient summed over
+    batch and time, where the op has a bias) from X, W and dOut alone (the
+    forward saves nothing): one pass as `causal_conv_bwd`, which makes the
+    pre-activation again in VMEM; outside the envelope `jax.vjp` of the jnp
+    form, as the generic grad lowering would."""
     d_out = out_grads["Out"][0]
     if d_out is None:
         return {}
     X, W = ins["X"][0], ins["W"][0]
+    bias = ins["Bias"][0] if ins.get("Bias") else None
     d_out = d_out.astype(X.dtype)
     silu = ctx.attr("activation", "silu") == "silu"
+    more = () if bias is None else (bias,)
     if _conv_kernels_run(X.shape[1], X.shape[2], W.shape[1]):
-        dX, dW = _conv_backward(X, W, d_out, silu)
+        dX, dW, *d_bias = _conv_backward(X, W, d_out, silu, *more)
     else:
-        _, vjp = jax.vjp(lambda x, w: _conv_xla(x, w, silu), X, W)
-        dX, dW = vjp(d_out)
-    return {"X": dX, "W": dW.astype(W.dtype)}
+        _, vjp = jax.vjp(lambda x, w, *b: _conv_xla(x, w, silu, *b), X, W,
+                         *more)
+        dX, dW, *d_bias = vjp(d_out)
+    grads = {"X": dX, "W": dW.astype(W.dtype)}
+    if d_bias:
+        grads["Bias"] = d_bias[0].astype(bias.dtype)
+    return grads
 
 
 @register_op("delta_rule_gates")
@@ -783,15 +793,19 @@ def _conv_chunks(x_ref, halo_ref, at_start, rows, chunk, carry, reverse):
     return lax.fori_loop(1, n, later, first(carry))
 
 
-def _conv_fwd_kernel(x_ref, halo_ref, w_ref, o_ref, *, K, rows, silu):
+def _conv_fwd_kernel(x_ref, halo_ref, w_ref, o_ref, *, K, rows, silu,
+                     bias=False):
     """One (batch, channel block, time block) step: the K taps summed in
-    float32, silu, the block written in X's dtype."""
+    float32 (plus the bias, row K of the weight block, where there is one),
+    silu, the block written in X's dtype."""
     from jax.experimental import pallas as pl
 
     w = [w_ref[j:j + 1, :] for j in range(K)]
 
     def chunk(xx, r0, carry):
         y = _weighted(_taps(xx, K), w)
+        if bias:
+            y = y + w_ref[K:K + 1, :]
         if silu:
             y = y * jax.nn.sigmoid(y)
         o_ref[0, pl.ds(r0, rows), :] = y.astype(o_ref.dtype)
@@ -802,14 +816,16 @@ def _conv_fwd_kernel(x_ref, halo_ref, w_ref, o_ref, *, K, rows, silu):
 
 
 def _conv_bwd_kernel(x_ref, halo_ref, do_ref, w_ref, dx_ref, dw_ref, head_sc,
-                     acc_sc, *, K, rows, silu):
+                     acc_sc, *, K, rows, silu, bias=False):
     """One (channel block, batch, time block) step, the time blocks and the
     chunks inside one taken last to first: `dpre = dOut * silu'(pre)` with
     the pre-activation made again, `dX[t] = sum_j W[j] dpre[t + K-1-j]`
     with dpre's first `_PAD` rows of the chunk after (carried; across time
     blocks in `head_sc`; zeros after the end), and `dW[j] += sum_t dpre[t]
     x[t - (K-1) + j]`, kept as 8 sublanes of partial sums in `acc_sc` and
-    added to the resident `[K, Cb]` block once a step."""
+    added to the resident `[K, Cb]` block once a step. With a bias (row K of
+    the weight block) the block has a row K too: dBias, dpre's column
+    sum."""
     from jax.experimental import pallas as pl
 
     t = pl.program_id(2)
@@ -831,6 +847,8 @@ def _conv_bwd_kernel(x_ref, halo_ref, do_ref, w_ref, dx_ref, dw_ref, head_sc,
         dpre = do_ref[0, pl.ds(r0, rows), :].astype(jnp.float32)
         if silu:
             pre = _weighted(taps, w)
+            if bias:
+                pre = pre + w_ref[K:K + 1, :]
             s = jax.nn.sigmoid(pre)
             dpre = dpre * (s * (1.0 + pre * (1.0 - s)))
         dd = jnp.concatenate([dpre, after], axis=0)
@@ -839,19 +857,22 @@ def _conv_bwd_kernel(x_ref, halo_ref, do_ref, w_ref, dx_ref, dw_ref, head_sc,
         for j in range(K):
             p = dpre * taps[j]
             acc_sc[j] += sum(p[i:i + 8] for i in range(0, rows, 8))
+        if bias:
+            acc_sc[K] += sum(dpre[i:i + 8] for i in range(0, rows, 8))
         return dpre[:_PAD]
 
     head_sc[...] = _conv_chunks(x_ref, halo_ref, t == last, rows, chunk,
                                 head_sc[...], reverse=True)
-    for j in range(K):
+    for j in range(K + bias):
         dw_ref[j:j + 1, :] += jnp.sum(acc_sc[j], axis=0, keepdims=True)
 
 
 def _conv_specs(Tb, Cb, K, at):
     """The blocks both kernels read: X's time block, the `_HALO` rows
     before it (the first block reads its own first rows and zeroes them)
-    and the weight's channel block as `[K, Cb]`; `at(*grid)` gives (batch,
-    time block, channel block)."""
+    and the weight's channel block as `[K, Cb]` (K the taps, and one row
+    more where the op has a bias); `at(*grid)` gives (batch, time block,
+    channel block)."""
     from jax.experimental import pallas as pl
 
     def halo(*g):
@@ -862,53 +883,70 @@ def _conv_specs(Tb, Cb, K, at):
             pl.BlockSpec((K, Cb), lambda *g: (0, at(*g)[2])))
 
 
-def _conv_forward(X, W, silu):
-    """`causal_conv_fwd`: X [B, T, C] as it arrives, W [C, K] -> Out in X's
-    dtype. Every intermediate stays in VMEM."""
+def _weight_rows(W, bias):
+    """W [C, K] as the kernels read it, `[K, C]` float32, the bias where
+    there is one as row K."""
+    w = W.astype(jnp.float32).T
+    if bias is None:
+        return w
+    return jnp.concatenate([w, bias.astype(jnp.float32)[None]], axis=0)
+
+
+def _conv_forward(X, W, silu, bias=None):
+    """`causal_conv_fwd`: X [B, T, C] as it arrives, W [C, K] (and a bias
+    [C]) -> Out in X's dtype. Every intermediate stays in VMEM."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     (B, T, C), K = X.shape, W.shape[1]
     Tb, Cb, rows = _conv_blocks(T, C)
-    x_spec, halo_spec, w_spec = _conv_specs(Tb, Cb, K,
+    more = {} if bias is None else {"bias": True}
+    x_spec, halo_spec, w_spec = _conv_specs(Tb, Cb, K + len(more),
                                             lambda b, c, t: (b, t, c))
     return pl.pallas_call(
-        functools.partial(_conv_fwd_kernel, K=K, rows=rows, silu=silu),
+        functools.partial(_conv_fwd_kernel, K=K, rows=rows, silu=silu,
+                          **more),
         name="causal_conv_fwd", grid=(B, C // Cb, T // Tb),
         in_specs=[x_spec, halo_spec, w_spec], out_specs=x_spec,
         out_shape=jax.ShapeDtypeStruct(X.shape, X.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=_interpret(),
-    )(X, X, W.astype(jnp.float32).T)
+    )(X, X, _weight_rows(W, bias))
 
 
-def _conv_backward(X, W, d_out, silu):
-    """`causal_conv_bwd`: (dX in X's dtype, dW [C, K] float32) from X, W and
-    dOut. The channel blocks lead the grid, so a block of dW stays resident
-    while the batch and the time blocks (last to first) add to it."""
+def _conv_backward(X, W, d_out, silu, bias=None):
+    """`causal_conv_bwd`: (dX in X's dtype, dW [C, K] float32, and with a
+    bias dBias [C] float32) from X, W (and the bias) and dOut. The channel blocks
+    lead the grid, so a block of dW stays resident while the batch and the
+    time blocks (last to first) add to it."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     (B, T, C), K = X.shape, W.shape[1]
     Tb, Cb, rows = _conv_blocks(T, C)
     n = T // Tb
+    more = {} if bias is None else {"bias": True}
+    Kb = K + len(more)
     x_spec, halo_spec, w_spec = _conv_specs(
-        Tb, Cb, K, lambda c, b, t: (b, n - 1 - t, c))
+        Tb, Cb, Kb, lambda c, b, t: (b, n - 1 - t, c))
     dX, dW = pl.pallas_call(
-        functools.partial(_conv_bwd_kernel, K=K, rows=rows, silu=silu),
+        functools.partial(_conv_bwd_kernel, K=K, rows=rows, silu=silu,
+                          **more),
         name="causal_conv_bwd", grid=(C // Cb, B, n),
         in_specs=[x_spec, halo_spec, x_spec, w_spec],
         out_specs=[x_spec, w_spec],
         out_shape=[jax.ShapeDtypeStruct(X.shape, X.dtype),
-                   jax.ShapeDtypeStruct((K, C), jnp.float32)],
+                   jax.ShapeDtypeStruct((Kb, C), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((_PAD, Cb), jnp.float32),
-                        pltpu.VMEM((K, 8, Cb), jnp.float32)],
+                        pltpu.VMEM((Kb, 8, Cb), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=_interpret(),
-    )(X, X, d_out, W.astype(jnp.float32).T)
-    return dX, dW.T
+    )(X, X, d_out, _weight_rows(W, bias))
+    if bias is None:
+        return dX, dW.T
+    return dX, dW[:K].T, dW[K]
 
 
 # ---------------------------------------------------------------------------

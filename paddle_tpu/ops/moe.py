@@ -534,8 +534,17 @@ def _row_tile(rows):
 
 
 def _gmm_tiles(rows, contraction, columns):
-    return (_row_tile(rows), contraction,
-            min(columns, max(128, _GMM_BLOCK // contraction)))
+    """(row tile, the whole contraction, columns a step): all the columns
+    where `_GMM_BLOCK` holds them (every gated-silu cell); where it does not
+    (2688 x 1856, 1856 x 2688: the two-matrix experts), whole 128-lane
+    tiles, as few column blocks as it takes and as even as they come (640 of
+    1856, 896 of 2688), the last one past the edge where they do not
+    divide."""
+    most = max(128, _GMM_BLOCK // contraction)
+    if columns > most:
+        blocks = -(-columns // (most // 128 * 128))
+        most = -(-columns // (blocks * 128)) * 128
+    return _row_tile(rows), contraction, min(columns, most)
 
 
 def _grouped_dot(x, w, sizes, transpose_w=False):

@@ -59,7 +59,8 @@ def pytest_collection_modifyitems(config, items):
                                            "test_gdn_kernels_tpu",
                                            "test_causal_conv_kernels_tpu",
                                            "test_rotary_kernels_tpu",
-                                           "test_gated_norm_kernels_tpu")):
+                                           "test_gated_norm_kernels_tpu",
+                                           "test_ssd_kernels_tpu")):
                 item.add_marker(skip)
     # under pytest-xdist, serialize each subprocess-spawning file into one
     # worker (`--dist loadgroup`): they fork whole jax worlds / embedded

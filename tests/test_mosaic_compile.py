@@ -544,3 +544,99 @@ def test_sparse_attention_kernels_compile_at_the_cells_shapes(
               "dsa_index_scores": "= f32[1,8192,8192]{",
               "dsa_select": "= s8[1,8192,8192]{"}[kernel]
     assert result in call
+
+
+# the parameter-less calls of the two kernel pairs that gained a parameter in
+# PR 56 (`causal_conv1d`'s bias, `gated_rms_norm`'s gate-first grouped form),
+# as `qwen3_next_80b_a3b.bs1` calls them: digests taken on the commit before
+# (22d36e3) by `_lowered_digest`
+UNCHANGED_CALLS = {
+    "conv_bf16": ("8f78b59f275d2e07fe446c624d942dff",
+                  "1b739580d44b6f76c908796c354185ad"),
+    "conv_f32": ("c432a3e7a0ddbcce44908db8aafc6d99",
+                 "097e1ec833031593702023940ef2734e"),
+    "gated_norm_bf16": ("45634ec82f48cdf2f715b63eb259f13e",
+                        "e1b30a15127a6881cfc6d0f47aa0d873"),
+    "gated_norm_f32": ("d88cf86b40aab135956fce4928a7c372",
+                       "7e661029d88f69b9269b2e0058b45db4"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(UNCHANGED_CALLS))
+def test_conv_and_gated_norm_without_the_new_parameters_lower_as_they_did(
+        one_chip, monkeypatch, case):
+    """No bias, the norm before the gate: both kernel pairs are the
+    instructions they were (`[1, 4096, 8192]` with 4 taps; `[1, 4096, 32,
+    128]`)."""
+    from paddle_tpu.ops import decoder_block as db
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    dtype = jnp.bfloat16 if case.endswith("bf16") else jnp.float32
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if case.startswith("conv"):
+        x, w = arg((1, 4096, 8192), dtype), arg((8192, 4), jnp.float32)
+        fwd = jax.jit(lambda x, w: la._conv_forward(x, w, True)).lower(x, w)
+        bwd = jax.jit(lambda x, w, d: la._conv_backward(
+            x, w, d, True)).lower(x, w, x)
+    else:
+        x, w = arg((1, 4096, 32, 128), dtype), arg((128,), jnp.float32)
+        fwd = jax.jit(lambda x, g, w: db._gated_norm_call(
+            x, g, w, 1e-6)).lower(x, x, w)
+        bwd = jax.jit(lambda x, g, w, d: db._gated_norm_call(
+            x, g, w, 1e-6, d)).lower(x, x, w, x)
+    assert (_lowered_digest(fwd)[:32], _lowered_digest(bwd)[:32]) \
+        == UNCHANGED_CALLS[case]
+
+
+def test_nemotron_h_kernels_compile_at_the_cells_shapes(one_chip,
+                                                        monkeypatch):
+    """What `nemotron_3_nano_30b_a3b.s2048` calls and no other cell does:
+    `ssd_fwd` / `ssd_bwd` on bf16 x `[1, 2048, 64, 64]` and B, C `[1, 2048,
+    8, 128]` (a `[1, 1]` spread both ways, the 8-lane column blocks and the
+    heads' half-tile selects are what the interpreter cannot refuse); the
+    convolution WITH a bias at `[1, 2048, 6144]`; the gated norm with the
+    gate first over 8 groups of 512. One Mosaic custom call each, the
+    scan's first result the saved states."""
+    from paddle_tpu.ops import decoder_block as db
+    from paddle_tpu.ops import state_space as ss
+    monkeypatch.setattr(la, "_on_chip", lambda: True)
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    assert ss._plan(64, 128, 8, 128) == "kernel"
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32 = jnp.float32
+    x, bc = arg((1, 2048, 64, 64)), arg((1, 2048, 8, 128))
+    gate, skip = arg((1, 2048, 64), f32), arg((64,), f32)
+    fwd = jax.jit(lambda *a: ss._ssd_forward(*a, 128)).lower(
+        x, gate, gate, bc, bc, skip).compile()
+    (call,) = _custom_calls(fwd, "ssd_fwd")
+    assert "(f32[16,1,8,512,128]{" in call and "tpu_custom_call" in call
+    bwd = jax.jit(lambda *a: ss._ssd_backward(*a, 128)).lower(
+        x, gate, gate, bc, bc, skip, arg((16, 1, 64, 64, 128), f32),
+        x).compile()
+    (call,) = _custom_calls(bwd, "ssd_bwd")
+    assert "(bf16[1,2048,4096]{" in call and "tpu_custom_call" in call
+
+    u, w, bias = arg((1, 2048, 6144)), arg((6144, 4), f32), arg((6144,), f32)
+    conv = jax.jit(lambda x, w, b: la._conv_forward(x, w, True, b)).lower(
+        u, w, bias).compile()
+    (call,) = _custom_calls(conv, "causal_conv_fwd")
+    assert "= bf16[1,2048,6144]{" in call
+    conv = jax.jit(lambda x, w, b, d: la._conv_backward(
+        x, w, d, True, b)).lower(u, w, bias, u).compile()
+    (call,) = _custom_calls(conv, "causal_conv_bwd")
+    assert ", f32[5,6144]{" in call
+
+    y, scale = arg((1, 2048, 8, 512)), arg((4096,), f32)
+    norm = jax.jit(lambda x, g, w: db._gate_first_norm_call(
+        x, g, w, 1e-5)).lower(y, y, scale).compile()
+    (call,) = _custom_calls(norm, "gated_norm_fwd")
+    assert "= bf16[1,2048,4096]{" in call
+    norm = jax.jit(lambda x, g, w, d: db._gate_first_norm_call(
+        x, g, w, 1e-5, d)).lower(y, y, scale, y).compile()
+    (call,) = _custom_calls(norm, "gated_norm_bwd")
+    assert "= (bf16[1,2048,4096]{" in call

@@ -570,6 +570,15 @@ SPECS.update({
                                      "G": T(1, 8, 2, lo=-1.0, hi=-0.05),
                                      "Beta": T(1, 8, 2, lo=0.1, hi=0.9)},
                              attrs={"chunk": 4}),
+    "relu2": Spec(inputs={"X": T(3, 4)}),
+    "ssd_gates": Spec(inputs={"DtRaw": T(2, 4, 3), "DtBias": T(3),
+                              "ALog": T(3)}, outs=("Dt", "A")),
+    # two chunks of four tokens, a group serving two heads
+    "ssd_scan": Spec(inputs={"X": T(1, 8, 2, 3),
+                             "Dt": T(1, 8, 2, lo=0.05, hi=0.5),
+                             "A": T(1, 8, 2, lo=-1.0, hi=-0.05),
+                             "B": T(1, 8, 1, 4), "C": T(1, 8, 1, 4),
+                             "D": T(2)}, attrs={"chunk": 4}),
     "moe_router": Spec(inputs={"X": T(6, 5), "W": T(5, 4) * 2},
                        attrs={"k": 2},
                        outs=("TopKWeight", "TopKIndex", "TokensPerExpert",
@@ -772,6 +781,23 @@ def _build_and_run(op_type, spec, amp):
 @pytest.mark.parametrize("op_type", sorted(SPECS))
 def test_op(op_type):
     _build_and_run(op_type, SPECS[op_type], amp=False)
+
+
+# a second form of an op that has a spec above: (op type, spec)
+VARIANTS = {
+    "causal_conv1d+bias": ("causal_conv1d", Spec(
+        inputs={"X": T(2, 6, 3), "W": T(3, 4), "Bias": T(3)},
+        attrs={"activation": "silu"})),
+    # the gate before the norm, three groups of four, a weight a lane
+    "gated_rms_norm+gate_first": ("gated_rms_norm", Spec(
+        inputs={"X": T(3, 3, 4), "Gate": T(3, 3, 4), "Scale": POS(12)},
+        attrs={"gate_first": True, "epsilon": 1e-5}, outs=("Y",))),
+}
+
+
+@pytest.mark.parametrize("variant", sorted(VARIANTS))
+def test_op_variant(variant):
+    _build_and_run(*VARIANTS[variant], amp=False)
 
 
 @pytest.mark.parametrize("k,p,s,d", [(3, 1, 2, 1), (4, 1, 2, 1),
