@@ -1799,7 +1799,10 @@ def moe_experts(input, routing, num_experts, expert_size, param_attr=None,
     `gated=False, activation="relu2"`: experts of two matrices,
     `down_e(relu(up_e(x))^2)`: no `<name>.gate.w`; one `grouped_matmul` for
     `up`, `relu2` over the rows (with `GroupSizes` under a share: the used
-    rows only), one for `down`; layout, movements and sums as above. Either
+    rows only), one for `down`; layout, movements and sums as above. The
+    stacks' shapes are as documented whatever the widths; which way the
+    grouped kernels are handed one follows its widths
+    (`ops/moe.py::_held_lane_major`). Either
     way `input` `[tokens, width]` in any float dtype (bf16 under AMP, where
     the grouped products take bf16 operands into float32 sums and the
     activation is float32 inside); the weights float32; the result in

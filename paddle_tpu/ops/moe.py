@@ -38,6 +38,21 @@ interpreter, PADDLE_TPU_PALLAS_INTERPRET=1). Dispatch and combine are
 permutations where every expert is held: their hand-written grads gather
 through the inverse permutation instead of scatter-adding.
 
+The kernels read a stack row-major, and the chip holds one so unless its
+last axis is no whole number of 128-lane tiles while its middle axis is:
+then the TPU client puts the middle axis minor-most, for the parameter and
+its optimizer moments alike. Of the two-matrix experts' stacks (2688 wide,
+experts of 1856) that is `up` `[held, 2688, 1856]`; `down` `[held, 1856,
+2688]` and every gated-silu stack (last axes 512 to 2048) lie as written.
+Such a stack's three products (forward, the rows' gradient, the weight's)
+go through `swapaxes(1, 2)`, a bitcast on an array held that way, with the
+kernel's other `transpose_rhs` and the weight gradient swapped back: the
+same sums on the operand where it lies, in place of a transposing copy of
+the stack and of both moments each way, every step (`_held_lane_major`;
+the op counts itself on the compile event, `moe_lane_major_stacks`). The
+parameter's shape, the op's slots and `ragged_dot`'s path know nothing of
+it.
+
 Under a share (`moe_dispatch` with `experts_held`: one chip of an
 expert-parallel layer; the router still chooses among all E experts and the
 weights are `[experts_held, K, F]`) the layout holds the assignments to the
@@ -539,7 +554,9 @@ def _gmm_tiles(rows, contraction, columns):
     (2688 x 1856, 1856 x 2688: the two-matrix experts), whole 128-lane
     tiles, as few column blocks as it takes and as even as they come (640 of
     1856, 896 of 2688), the last one past the edge where they do not
-    divide."""
+    divide. The tiles are the product's, whichever way its stack is handed
+    to the kernel (`_held_lane_major`): `up` `[8, 2688, 1856]` goes in as
+    `[8, 1856, 2688]`, the shape `down` has, and takes the tiles it took."""
     most = max(128, _GMM_BLOCK // contraction)
     if columns > most:
         blocks = -(-columns // (most // 128 * 128))
@@ -547,15 +564,35 @@ def _gmm_tiles(rows, contraction, columns):
     return _row_tile(rows), contraction, min(columns, most)
 
 
+def _held_lane_major(w):
+    """Whether the kernels take the stack w [E, K, F] through its transpose
+    [E, F, K]: where they are the path, F is no whole number of 128-lane
+    tiles and K is one. The TPU client's default layout of such an array
+    (and of its Adam moments) puts the 128-multiple axis minor-most
+    (`f32[8,2688,1856]{1,2,0}`), the kernels read their operands row-major,
+    and XLA bridges the two with a transposing copy of the whole stack each
+    way, parameter and both moments (six a layer a step, 0.51 ms each at
+    160 MB: PERF.md section 6, PR 57). On an array held so `swapaxes(1, 2)`
+    is a bitcast, so the transposed product reads the stack where it lies
+    and the weight gradient is written where Adam wants it. The choice
+    reads the shape alone."""
+    return _kernel() is not None \
+        and w.shape[2] % 128 != 0 and w.shape[1] % 128 == 0
+
+
 def _grouped_dot(x, w, sizes, transpose_w=False):
     """Rows of group e of x [M, K] times w[e] [K, F] (its transpose if
-    `transpose_w`, w then being [E, F, K]) -> [M, F]."""
+    `transpose_w`, w then being [E, F, K]) -> [M, F]. The kernel is handed
+    w as it is, or swapped and with the other `transpose_rhs` where the
+    chip holds it swapped (`_held_lane_major`): the same product."""
     sizes = sizes.astype(jnp.int32)
     kernel = _kernel()
     if kernel is None:
         return lax.ragged_dot(x, w.swapaxes(1, 2) if transpose_w else w,
                               sizes)
     columns = w.shape[1] if transpose_w else w.shape[2]
+    if _held_lane_major(w):
+        w, transpose_w = w.swapaxes(1, 2), not transpose_w
     return kernel.gmm(x, w, sizes, preferred_element_type=x.dtype,
                       tiling=_gmm_tiles(x.shape[0], x.shape[1], columns),
                       transpose_rhs=transpose_w, interpret=_interpret())
@@ -568,26 +605,35 @@ def _grouped_matmul(ctx, X, W, GroupSizes):
     `moe_dispatch`'s padded groups in the expert layer): rows of group e
     times W[e]. Several `W` give as many `Out`s, each the product with one
     of them: the projections that share their rows are one op, so the rows'
-    gradient is one variable and `_grouped_matmul_grad` sums its parts."""
-    return {"Out": [_grouped_dot(X, w, GroupSizes)
-                    for w in (W if isinstance(W, list) else [W])]}
+    gradient is one variable and `_grouped_matmul_grad` sums its parts. An
+    op with a stack that the kernels take through its transpose counts
+    itself on the compile event (`moe_lane_major_stacks`), as its grad
+    does."""
+    stacks = W if isinstance(W, list) else [W]
+    if any(_held_lane_major(w) for w in stacks):
+        ctx.tally("moe_lane_major_stacks")
+    return {"Out": [_grouped_dot(X, w, GroupSizes) for w in stacks]}
 
 
 def _grouped_dot_grads(x, w, g, sizes):
-    """(dX, dW) of `_grouped_dot(x, w, sizes)` under the cotangent g."""
+    """(dX, dW) of `_grouped_dot(x, w, sizes)` under the cotangent g. dW[e]
+    = X_e^T g_e, or where the chip holds w swapped (`_held_lane_major`) its
+    transpose g_e^T X_e, swapped back: a bitcast there."""
     kernel = _kernel()
     if kernel is None:
         _, vjp = jax.vjp(lambda a, b: lax.ragged_dot(a, b, sizes), x, w)
         return vjp(g)
     d_x = _grouped_dot(g, w, sizes, transpose_w=True)
-    d_w = kernel.tgmm(x.swapaxes(0, 1), g, sizes,
+    swapped = _held_lane_major(w)
+    lhs, rhs = (g, x) if swapped else (x, g)
+    d_w = kernel.tgmm(lhs.swapaxes(0, 1), rhs, sizes,
                       preferred_element_type=g.dtype,
                       tiling=(_row_tile(x.shape[0]),
-                              min(x.shape[1], _TGMM_BLOCK[0]),
-                              min(g.shape[1], _TGMM_BLOCK[1])),
+                              min(lhs.shape[1], _TGMM_BLOCK[0]),
+                              min(rhs.shape[1], _TGMM_BLOCK[1])),
                       num_actual_groups=w.shape[0],
                       interpret=_interpret())
-    return d_x, d_w
+    return d_x, d_w.swapaxes(1, 2) if swapped else d_w
 
 
 @register_grad("grouped_matmul")
@@ -598,7 +644,8 @@ def _grouped_matmul_grad(ctx, ins, out_grads):
     the sum of their parts over the rows that `GroupSizes` uses, added in
     place and in dX's dtype, as the `sum` op would add them (the op counts
     itself, `moe_share_bounded_ops`): the rows behind them are not
-    visited."""
+    visited. A stack the kernels take through its transpose is counted
+    here as in the forward op (`moe_lane_major_stacks`)."""
     X, sizes = ins["X"][0], ins["GroupSizes"][0].astype(jnp.int32)
     d_x, d_ws = None, []
     for W, g in zip(ins["W"], out_grads["Out"]):
@@ -618,6 +665,8 @@ def _grouped_matmul_grad(ctx, ins, out_grads):
         return {}
     if len(ins["W"]) > 1:
         ctx.tally("moe_share_bounded_ops")
+    if any(_held_lane_major(W) for W in ins["W"]):
+        ctx.tally("moe_lane_major_stacks")
     return {"X": d_x, "W": d_ws}
 
 

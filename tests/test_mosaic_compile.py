@@ -383,7 +383,10 @@ def _lowered_digest(lowered):
     kernels = []
 
     def kernel(m):
-        config = json.loads(m.group(1).replace("\\22", '"'))
+        # the escapes MLIR prints a string with: a quote, and the newlines
+        # of a cost estimate
+        config = json.loads(m.group(1).replace("\\22", '"')
+                            .replace("\\0A", "\n"))
         body = base64.b64decode(config["custom_call_config"].pop("body"))
         ctx = jmlir.make_ir_context()
         tpu.register_dialect(ctx)
@@ -640,3 +643,106 @@ def test_nemotron_h_kernels_compile_at_the_cells_shapes(one_chip,
         x, g, w, 1e-5, d)).lower(y, y, scale, y).compile()
     (call,) = _custom_calls(norm, "gated_norm_bwd")
     assert "= (bf16[1,2048,4096]{" in call
+
+
+def _expert_step(moe, gated):
+    """One expert layer's step on `_grouped_dot` / `_grouped_dot_grads` as
+    the Program runs them under AMP: float32 stacks cast to bf16 at their
+    use, the products and their gradients, Adam on each stack with its two
+    moments. relu² over one first stack, or gated silu over two."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+
+    def adam(p, g, m, v):
+        m, v = 0.9 * m + 0.1 * g, 0.999 * v + 0.001 * g * g
+        return p - 1e-3 * m / (jnp.sqrt(v) + 1e-8), m, v
+
+    def step(x, sizes, g, *state):
+        firsts = [w.astype(bf16) for w in state[:-3:3]]
+        down = state[-3].astype(bf16)
+        hs = [moe._grouped_dot(x, w, sizes).astype(f32) for w in firsts]
+        if gated:
+            act, d_act = jax.vjp(lambda a, b: jax.nn.silu(a) * b, *hs)
+        else:
+            act, d_act = jax.vjp(lambda a: jnp.square(jax.nn.relu(a)), *hs)
+        act = act.astype(bf16)
+        y = moe._grouped_dot(act, down, sizes)
+        d_a, d_down = moe._grouped_dot_grads(act, down, g, sizes)
+        d_x, grads = 0, []
+        for w, d_h in zip(firsts, d_act(d_a.astype(f32))):
+            part, d_w = moe._grouped_dot_grads(x, w, d_h.astype(bf16), sizes)
+            d_x = d_x + part
+            grads.append(d_w)
+        new = [adam(state[3 * i], d.astype(f32), *state[3 * i + 1:3 * i + 3])
+               for i, d in enumerate(grads + [d_down])]
+        return (y, d_x) + tuple(a for triple in new for a in triple)
+
+    return step
+
+
+def _lowered_expert_step(moe, one_chip, width, expert_size, gated, rows=2048):
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    first, down = (8, width, expert_size), (8, expert_size, width)
+    state = [arg(first)] * (6 if gated else 3) + [arg(down)] * 3
+    x = arg((rows, width), jnp.bfloat16)
+    return jax.jit(_expert_step(moe, gated),
+                   donate_argnums=tuple(range(3, 3 + len(state)))).lower(
+                       x, arg((8,), jnp.int32), x, *state)
+
+
+@pytest.fixture
+def megablox(monkeypatch):
+    """`ops/moe.py` with the megablox kernels as its path, as on a chip."""
+    from paddle_tpu.ops import moe
+    monkeypatch.setattr(la, "_on_chip", lambda: True)
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    assert moe._kernel() is not None
+    return moe
+
+
+def test_two_matrix_experts_step_copies_no_stack(one_chip, megablox):
+    """An E layer of `nemotron_3_nano_30b_a3b.s2048` (relu² experts, `up`
+    `[8, 2688, 1856]`, `down` `[8, 1856, 2688]`, float32 with their Adam
+    moments donated, 2048 rows): the TPU client holds `up` and its moments
+    with the 2688 axis minor-most (`{1,2,0}`: 1856 is no whole number of
+    lane tiles), and the products take the stack through its transpose, a
+    bitcast there, so the compiled step holds no `copy` of a whole stack
+    (the kernels handed `up` row-major had six: parameter and two moments,
+    in and out). If a later jax lays `up` out otherwise, the first
+    assertion says so."""
+    moe = megablox
+    assert moe._held_lane_major(jnp.zeros((8, 2688, 1856)))
+    assert not moe._held_lane_major(jnp.zeros((8, 1856, 2688)))
+    compiled = _lowered_expert_step(moe, one_chip, 2688, 1856,
+                                    gated=False).compile()
+    text = compiled.as_text()
+    (layout,) = re.findall(r"entry_computation_layout=\{\((.*?)\)->", text)
+    assert layout.count("f32[8,2688,1856]{1,2,0:") == 3
+    assert layout.count("f32[8,1856,2688]{2,1,0:") == 3
+    assert len(_custom_calls(compiled, "gmm")) == 4
+    assert len(_custom_calls(compiled, "tgmm")) == 2
+    copies = re.findall(
+        r"= f32\[8,(?:2688,1856|1856,2688)\]\{[^}]*\} copy\(", text)
+    assert not copies
+    # nor a bf16 one: the cast fuses with the bitcast
+    assert not re.findall(
+        r"= bf16\[8,(?:2688,1856|1856,2688)\]\{[^}]*\} copy\(", text)
+
+
+# the step of a gated-silu layer at Kanana-2's and Keye-VL-2's widths
+# (`[8, 2048, 768]` / `[8, 768, 2048]`), lowered by `_lowered_expert_step` on
+# the commit before `_held_lane_major` (bb85f6d)
+GATED_SILU_STEP = "90b5660139060bf73fced8c3ed92c2f8"
+
+
+def test_gated_silu_experts_step_lowers_as_it_did(one_chip, megablox):
+    """Every gated-silu cell's stacks end on a whole number of lane tiles
+    (768, 1024, 896, 512 / 2048): nothing is swapped and the step is the
+    instructions it was."""
+    moe = megablox
+    for shape in ((8, 2048, 768), (8, 768, 2048), (64, 2048, 1024),
+                  (8, 2304, 896), (32, 2048, 512), (4, 64, 24)):
+        assert not moe._held_lane_major(jnp.zeros(shape)), shape
+    lowered = _lowered_expert_step(moe, one_chip, 2048, 768, gated=True)
+    assert _lowered_digest(lowered)[:32] == GATED_SILU_STEP

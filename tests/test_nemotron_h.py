@@ -364,6 +364,94 @@ def test_the_shares_add_up_to_the_whole_layer(n_expert, held, k, width):
     assert rel_err(grads["router.w"], gr) < 1e-4
 
 
+@pytest.mark.parametrize("transpose_w", [False, True],
+                         ids=["rows_times_stack", "rows_times_transpose"])
+def test_a_lane_major_stack_is_multiplied_through_its_transpose(
+        monkeypatch, transpose_w):
+    """A stack `[4, 128, 96]` (the last axis off the 128-lane tile, the
+    middle one on it: `experts.up.w` `[8, 2688, 1856]` in small) goes to the
+    megablox kernels swapped, with the other `transpose_rhs`, and its weight
+    gradient comes back swapped: under the Pallas interpreter `Out`, dX and
+    dW are `lax.ragged_dot`'s and its `jax.vjp`'s, with uneven groups, an
+    empty one and a share's unused rows behind them. `[4, 96, 128]` and
+    `[4, 128, 128]` go as they are, and without the kernels nothing is
+    swapped."""
+    from paddle_tpu.ops import moe
+    rng = np.random.RandomState(6)
+    w = jnp.asarray(rng.randn(4, 128, 96) * 0.3, jnp.float32)
+    assert not moe._held_lane_major(w)              # ragged_dot's path
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    assert moe._held_lane_major(w)
+    assert not moe._held_lane_major(w.swapaxes(1, 2))
+    assert not moe._held_lane_major(jnp.zeros((4, 128, 128)))
+    assert not moe._held_lane_major(jnp.zeros((4, 64, 24)))
+    sizes = jnp.asarray([128, 0, 256, 128], jnp.int32)
+    rows, used = 640, 512
+    x = jnp.asarray(rng.randn(rows, 96 if transpose_w else 128), jnp.float32)
+    plain = w.swapaxes(1, 2) if transpose_w else w
+    want, vjp = jax.vjp(lambda a, b: jax.lax.ragged_dot(a, b, sizes),
+                        x, plain)
+    got = moe._grouped_dot(x, w, sizes, transpose_w=transpose_w)
+    assert got.shape == want.shape
+    assert rel_err(got[:used], want[:used]) < RTOL
+    if transpose_w:
+        return      # the rows' gradient's form; the grads are the other case's
+    g = jnp.asarray(rng.randn(*want.shape), jnp.float32)
+    d_x, d_w = moe._grouped_dot_grads(x, w, g, sizes)
+    want_x, want_w = vjp(g.at[used:].set(0))
+    assert d_w.shape == w.shape
+    assert rel_err(d_x[:used], want_x[:used]) < RTOL
+    assert rel_err(d_w, want_w) < RTOL
+    assert not np.any(np.asarray(d_w[1]))           # nobody chose expert 1
+
+
+@pytest.mark.parametrize("expert_size,ops", [(96, 2), (128, 0)],
+                         ids=["up_128x96", "up_128x128"])
+def test_a_lane_major_stack_counts_its_ops(monkeypatch, expert_size, ops):
+    """A relu² layer 128 wide over experts of 96: `up` `[4, 128, 96]` takes
+    the swapped orientation in its `grouped_matmul` and in that op's grad,
+    one count each on the compile event (`moe_lane_major_stacks`); `down`
+    `[4, 96, 128]` does not, and experts of 128 leave no such key. The
+    layer's output and every gradient are what `ragged_dot`'s path gives."""
+    rng = np.random.RandomState(7)
+    n, d, n_expert, k = 64, 128, 4, 2
+    x = rng.randn(n, d).astype(np.float32)
+    index = np.stack([rng.permutation(n_expert)[:k] for _ in range(n)]) \
+        .astype(np.int32)
+    index[index == 1] = 3                           # expert 1 stays empty
+    index[:, 1] = np.where(index[:, 0] == index[:, 1], 0, index[:, 1])
+    feed = {"x": x, "index": index,
+            "weight": rng.uniform(0.05, 0.4, (n, k)).astype(np.float32),
+            "counts": np.bincount(index.reshape(-1), minlength=n_expert)
+            .astype(np.int32)}
+    weights = {
+        "e.up.w": rng.randn(n_expert, d, expert_size).astype(np.float32) * .1,
+        "e.down.w": rng.randn(n_expert, expert_size, d).astype(np.float32)
+        * .1}
+    programs = []
+
+    def build(data):
+        routing = {"weight": data["weight"], "index": data["index"],
+                   "tokens_per_expert": data["counts"]}
+        out = layers.moe_experts(data["x"], routing, n_expert, expert_size,
+                                 name="e", gated=False, activation="relu2")
+        programs.append(out.block.program)
+        return [out]
+
+    (want,), want_grads, _ = run_piece(build, feed, weights)
+    assert "moe_lane_major_stacks" not in observe.observatory().latest(
+        programs[-1]._uid).detail
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    (got,), grads, _ = run_piece(build, feed, weights)
+    detail = observe.observatory().latest(programs[-1]._uid).detail
+    assert detail.get("moe_lane_major_stacks", 0) == ops
+    assert rel_err(got, want) < RTOL
+    assert sorted(grads) == ["e.down.w", "e.up.w", "weight", "x"]
+    for name, g in want_grads.items():
+        assert grads[name].shape == g.shape
+        assert rel_err(grads[name], g) < RTOL, name
+
+
 def test_an_expert_layer_is_gated_silu_or_ungated_relu2():
     with fluid.program_guard(fluid.Program(), fluid.Program()):
         x = layers.data(name="x", shape=[8, 16], dtype="float32",
