@@ -30,9 +30,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import ir, registry
-from .backward import program_detail
 from .. import flags as _flags
 from ..observe import steplog as _steplog
+from ..observe.census import program_detail
 from .lowering import BlockLowerer
 
 logger = logging.getLogger(__name__)

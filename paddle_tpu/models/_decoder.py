@@ -1,0 +1,220 @@
+"""What two or more of the decoder models (`olmoe`, `ouro`, `qwen3_next`,
+`kanana2`, `mellum2`, `trinity`, `keye_vl2`, `nemotron_h`) build the same
+way, written once: named weights and projections, the token feeds, the
+heads-first reshape and its inverse, a key-value head serving its group of
+query heads, the gated MLP, the routed half of an expert layer, the period of
+layer kinds, the grouped-query block, and the losses. Nothing here asks which
+model calls it: a model whose form differs keeps its own (OLMoE norms q and k
+before the heads split, Nemotron-H's out projections start smaller). Each
+model keeps its mixer's composition, its layer loop, its defaults
+and `build`. Built from `fluid.layers` only; parameter names are the
+caller's.
+"""
+
+from __future__ import annotations
+
+from .. import initializer as init
+from .. import layers
+from ..param_attr import ParamAttr
+
+INIT_STD = 0.02
+
+KINDS = ("sliding_attention", "full_attention")
+PERIOD = (KINDS[0],) * 3 + (KINDS[1],)      # the published `layer_types`
+
+
+def normal():
+    return init.NormalInitializer(0.0, INIT_STD)
+
+
+def w(name):
+    return ParamAttr(name=name, initializer=normal())
+
+
+def linear(x, size, name):
+    return layers.fc(input=x, size=size, num_flatten_dims=2, bias_attr=False,
+                     param_attr=w(name + ".w"))
+
+
+def norm(x, rms_eps, name, zero_centered=False):
+    return layers.rms_norm(x, epsilon=rms_eps, zero_centered=zero_centered,
+                           param_attr=ParamAttr(name=name + ".w"))
+
+
+def last(x, first, end):
+    """x[..., first:end]."""
+    axis = len(x.shape) - 1
+    return layers.slice(x, axes=[axis], starts=[first], ends=[end])
+
+
+def token_feeds(seq_len):
+    """The `[batch, seq_len]` token ids and next-token labels of a step."""
+    return [layers.data(name=name, shape=[-1, seq_len], dtype="int64",
+                        append_batch_size=False)
+            for name in ("tokens", "labels")]
+
+
+def embed(tokens, vocab_size, d_model):
+    return layers.embedding(tokens, size=[vocab_size, d_model],
+                            param_attr=w("embed.w"))
+
+
+def split_heads(t, n, head_dim):    # [B, T, n * Dh] -> [B, T, n, Dh]
+    return layers.reshape(t, shape=[0, 0, n, head_dim])
+
+
+def heads_first(t):                 # [B, T, n, Dh] <-> [B, n, T, Dh]
+    return layers.transpose(t, perm=[0, 2, 1, 3])
+
+
+def merge_heads(ctx, width):        # [B, n, T, Dh] -> [B, T, n * Dh]
+    return layers.reshape(heads_first(ctx), shape=[0, 0, width])
+
+
+def serve_group(t, n_head, n_kv_head, head_dim):
+    """[B, kv, T, Dh] -> [B, heads, T, Dh]: key-value head h // group
+    serves query head h (the heads are repeated in the Program)."""
+    group = n_head // n_kv_head
+    t = layers.expand(layers.unsqueeze(t, axes=[2]),
+                      expand_times=[1, 1, group, 1, 1])
+    return layers.reshape(t, shape=[0, n_head, -1, head_dim])
+
+
+def gated_mlp(x, width, name):
+    hidden = layers.swiglu(linear(x, width, name + ".gate"),
+                           linear(x, width, name + ".up"))
+    return linear(hidden, x.shape[-1], name + ".down")
+
+
+def expert_rows(x, n_expert, top_k, d_expert, name, router=None,
+                experts=None):
+    """x [B, T, D] as rows [B * T, D] through the router (`name.router.w`)
+    and the experts (`name.experts.*`). `router` and `experts` are the
+    keywords of `layers.moe_router` and `layers.moe_experts` as the caller
+    states them. Returns the routed rows and the routing."""
+    tokens = layers.reshape(x, shape=[-1, x.shape[-1]])
+    routing = layers.moe_router(tokens, n_expert, top_k,
+                                param_attr=w(name + ".router.w"),
+                                **(router or {}))
+    routed = layers.moe_experts(tokens, routing, n_expert, d_expert,
+                                param_attr=normal(), name=name + ".experts",
+                                **(experts or {}))
+    return routed, routing
+
+
+def routed_experts(x, seq_len, n_expert, top_k, d_expert, name, router=None,
+                   experts=None):
+    """The routed half of an expert layer: `expert_rows` and back to
+    [B, T, D]. A model adds its own shared expert (one whose Program holds
+    it before the reshape back calls `expert_rows`)."""
+    routed, routing = expert_rows(x, n_expert, top_k, d_expert, name, router,
+                                  experts)
+    return layers.reshape(routed, shape=[-1, seq_len, x.shape[-1]]), routing
+
+
+def noaux_router(name, bias_update_rate, scaling_factor):
+    """`router` of `routed_experts` for DeepSeek-V3's `noaux_tc` routing:
+    sigmoid scores, the choice moved by a selection bias (`name.router.bias`)
+    that the step itself rewrites, the chosen scores renormalised and
+    scaled."""
+    return dict(norm_topk_prob=True, score_func="sigmoid",
+                bias_attr=ParamAttr(name=name + ".router.bias"),
+                bias_update_rate=bias_update_rate, norm_eps=1e-20,
+                scaling_factor=scaling_factor)
+
+
+def noaux_experts(x, seq_len, n_expert, top_k, d_expert, d_shared,
+                  first_expert, experts_held, scaling_factor,
+                  bias_update_rate, name):
+    """An expert layer under `noaux_router`: this chip's share of the routed
+    experts plus a shared gated MLP of width `d_shared`, no gate on it."""
+    routed, routing = routed_experts(
+        x, seq_len, n_expert, top_k, d_expert, name,
+        router=noaux_router(name, bias_update_rate, scaling_factor),
+        experts=dict(first_expert=first_expert, experts_held=experts_held))
+    out = layers.elementwise_add(routed,
+                                 gated_mlp(x, d_shared, name + ".shared"))
+    return out, routing
+
+
+def layer_kinds(n_layer, layer_types=PERIOD):
+    """The kind of each of `n_layer` layers: `layer_types` (a list of
+    `KINDS`) repeated as a period."""
+    unknown = sorted(set(layer_types) - set(KINDS))
+    if unknown or not layer_types:
+        raise ValueError(f"layer_types holds {KINDS}, got {layer_types!r}")
+    return [layer_types[i % len(layer_types)] for i in range(n_layer)]
+
+
+def grouped_attention(x, n_head, n_kv_head, head_dim, rope_theta,
+                      rope_scaling, window, rms_eps, name, kept=None,
+                      topk=None):
+    """Causal softmax attention of `n_head` query heads over `n_kv_head`
+    key-value heads, q and k normed over a head and turned by rotary.
+    `window`: a causal band of that many keys (None: all). `kept`: the keys
+    each query keeps (`layers.dsa_select`), of at most `topk` a row, where a
+    layer chooses them."""
+    def turned(t, n, norm_name):
+        t = heads_first(norm(split_heads(t, n, head_dim), rms_eps, norm_name))
+        return layers.rotary_embedding(t, theta=rope_theta,
+                                       scaling=rope_scaling)
+
+    q = turned(linear(x, n_head * head_dim, name + ".q"), n_head,
+               name + ".q_norm")
+    k = turned(linear(x, n_kv_head * head_dim, name + ".k"), n_kv_head,
+               name + ".k_norm")
+    v = heads_first(split_heads(
+        linear(x, n_kv_head * head_dim, name + ".v"), n_kv_head, head_dim))
+    ctx = layers.fused_attention(
+        q, serve_group(k, n_head, n_kv_head, head_dim),
+        serve_group(v, n_head, n_kv_head, head_dim), causal=True,
+        sm_scale=head_dim ** -0.5, window=window, kept=kept, topk=topk)
+    return linear(merge_heads(ctx, n_head * head_dim), x.shape[-1],
+                  name + ".o")
+
+
+def mean_cross_entropy(logits, labels):
+    return layers.mean(layers.softmax_with_cross_entropy(logits=logits,
+                                                         label=labels))
+
+
+def tokens_per_expert(routings):
+    """[expert layers, n_expert]: every layer's assignments per expert."""
+    return layers.stack([r["tokens_per_expert"] for r in routings], axis=0)
+
+
+def cross_entropy_fetches(logits, labels, routings):
+    """The fetches of a step whose loss is the mean cross-entropy alone."""
+    ce = mean_cross_entropy(logits, labels)
+    fetches = {"loss": ce, "ce": ce, "logits": logits}
+    if routings:
+        fetches["tokens_per_expert"] = tokens_per_expert(routings)
+    return fetches
+
+
+def load_balance(routings, n_expert, top_k):
+    """`n_expert * sum_e f_e * P_e` over all layers' router rows taken
+    together, as the `olmoe` code does: f_e = assignments to e / rows, P_e =
+    mean probability of e, over all `n_expert` experts wherever they
+    live."""
+    counts = layers.sums([layers.cast(r["tokens_per_expert"], "float32")
+                          for r in routings])
+    rows = layers.scale(layers.reduce_sum(counts), scale=1.0 / top_k)
+    share = layers.elementwise_div(counts, rows)
+    share.stop_gradient = True      # counts: nothing to differentiate
+    mean_prob = layers.scale(
+        layers.sums([layers.reduce_mean(r["probs"], dim=0)
+                     for r in routings]), scale=1.0 / len(routings))
+    return layers.scale(
+        layers.reduce_sum(layers.elementwise_mul(share, mean_prob)),
+        scale=float(n_expert))
+
+
+def balanced_loss(logits, labels, routings, n_expert, top_k, aux_coef):
+    """The fetches of a step: mean cross-entropy plus `aux_coef` times the
+    load-balance term."""
+    ce = mean_cross_entropy(logits, labels)
+    balance = load_balance(routings, n_expert, top_k)
+    loss = layers.sums([ce, layers.scale(balance, scale=aux_coef)])
+    return {"loss": loss, "ce": ce, "load_balance": balance,
+            "logits": logits, "tokens_per_expert": tokens_per_expert(routings)}

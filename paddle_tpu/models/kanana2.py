@@ -61,95 +61,40 @@ reference can be handed the same weights by name. Each layer's ops carry
 
 from __future__ import annotations
 
-from .. import initializer as init
 from .. import layers
 from ..core.ir import name_scope
-from ..param_attr import ParamAttr
-
-INIT_STD = 0.02
-
-
-def _normal():
-    return init.NormalInitializer(0.0, INIT_STD)
-
-
-def _w(name):
-    return ParamAttr(name=name, initializer=_normal())
-
-
-def _linear(x, size, name):
-    return layers.fc(input=x, size=size, num_flatten_dims=2, bias_attr=False,
-                     param_attr=_w(name + ".w"))
-
-
-def _norm(x, rms_eps, name):
-    return layers.rms_norm(x, epsilon=rms_eps,
-                           param_attr=ParamAttr(name=name + ".w"))
-
-
-def _last(x, first, end):
-    """x[..., first:end]."""
-    axis = len(x.shape) - 1
-    return layers.slice(x, axes=[axis], starts=[first], ends=[end])
+from ._decoder import (cross_entropy_fetches, embed, gated_mlp, heads_first,
+                       last, linear, merge_heads, noaux_experts, norm,
+                       split_heads, token_feeds)
 
 
 def _latent_attention(x, n_head, kv_rank, qk_nope_dim, qk_rope_dim,
                       v_head_dim, rope_theta, rms_eps, name):
     qk_dim = qk_nope_dim + qk_rope_dim
 
-    def heads_first(t):
-        return layers.transpose(t, perm=[0, 2, 1, 3])
-
     def rotary(t):
         return layers.rotary_embedding(t, theta=rope_theta, interleaved=True)
 
-    q = heads_first(layers.reshape(_linear(x, n_head * qk_dim, name + ".q"),
-                                   shape=[0, 0, n_head, qk_dim]))
-    q = layers.concat([_last(q, 0, qk_nope_dim),
-                       rotary(_last(q, qk_nope_dim, qk_dim))], axis=3)
-    kv_a = _linear(x, kv_rank + qk_rope_dim, name + ".kv_a")
-    latent = _norm(_last(kv_a, 0, kv_rank), rms_eps, name + ".kv_norm")
+    q = heads_first(split_heads(linear(x, n_head * qk_dim, name + ".q"),
+                                n_head, qk_dim))
+    q = layers.concat([last(q, 0, qk_nope_dim),
+                       rotary(last(q, qk_nope_dim, qk_dim))], axis=3)
+    kv_a = linear(x, kv_rank + qk_rope_dim, name + ".kv_a")
+    latent = norm(last(kv_a, 0, kv_rank), rms_eps, name + ".kv_norm")
     # one rotary key head, [B, 1, T, rope], serves every query head
-    k_rope = rotary(layers.unsqueeze(_last(kv_a, kv_rank,
-                                           kv_rank + qk_rope_dim), axes=[1]))
-    kv = heads_first(layers.reshape(
-        _linear(latent, n_head * (qk_nope_dim + v_head_dim), name + ".kv_b"),
-        shape=[0, 0, n_head, qk_nope_dim + v_head_dim]))
+    k_rope = rotary(layers.unsqueeze(last(kv_a, kv_rank,
+                                          kv_rank + qk_rope_dim), axes=[1]))
+    kv = heads_first(split_heads(
+        linear(latent, n_head * (qk_nope_dim + v_head_dim), name + ".kv_b"),
+        n_head, qk_nope_dim + v_head_dim))
     k = layers.concat(
-        [_last(kv, 0, qk_nope_dim),
+        [last(kv, 0, qk_nope_dim),
          layers.expand(k_rope, expand_times=[1, n_head, 1, 1])], axis=3)
-    v = _last(kv, qk_nope_dim, qk_nope_dim + v_head_dim)
+    v = last(kv, qk_nope_dim, qk_nope_dim + v_head_dim)
     ctx = layers.fused_attention(q, k, v, causal=True,
                                  sm_scale=qk_dim ** -0.5)
-    ctx = layers.reshape(heads_first(ctx), shape=[0, 0, n_head * v_head_dim])
-    return _linear(ctx, x.shape[-1], name + ".o")
-
-
-def _gated_mlp(x, width, name):
-    hidden = layers.swiglu(_linear(x, width, name + ".gate"),
-                           _linear(x, width, name + ".up"))
-    return _linear(hidden, x.shape[-1], name + ".down")
-
-
-def _sparse_experts(x, seq_len, n_expert, top_k, d_expert, d_shared,
-                    first_expert, experts_held, routed_scaling_factor,
-                    bias_update_rate, name):
-    d_model = x.shape[-1]
-    tokens = layers.reshape(x, shape=[-1, d_model])
-    routing = layers.moe_router(
-        tokens, n_expert, top_k, param_attr=_w(name + ".router.w"),
-        norm_topk_prob=True, score_func="sigmoid",
-        bias_attr=ParamAttr(name=name + ".router.bias"),
-        bias_update_rate=bias_update_rate, norm_eps=1e-20,
-        scaling_factor=routed_scaling_factor)
-    routed = layers.moe_experts(
-        tokens, routing, n_expert, d_expert, param_attr=_normal(),
-        name=name + ".experts", first_expert=first_expert,
-        experts_held=experts_held)
-    out = layers.elementwise_add(
-        layers.reshape(routed, shape=[-1, seq_len, d_model]),
-        _gated_mlp(x, d_shared, name + ".shared"))
-    return out, routing
+    return linear(merge_heads(ctx, n_head * v_head_dim), x.shape[-1],
+                  name + ".o")
 
 
 def kanana2(vocab_size=128256, seq_len=4096, n_layer=48, n_dense_layer=1,
@@ -162,44 +107,33 @@ def kanana2(vocab_size=128256, seq_len=4096, n_layer=48, n_dense_layer=1,
     token ids and next-token labels. `n_layer` counts the `n_dense_layer`
     leading dense layers too. `experts_held` None holds all `n_expert`
     experts."""
-    tokens = layers.data(name="tokens", shape=[-1, seq_len], dtype="int64",
-                         append_batch_size=False)
-    labels = layers.data(name="labels", shape=[-1, seq_len], dtype="int64",
-                         append_batch_size=False)
-
-    x = layers.embedding(tokens, size=[vocab_size, d_model],
-                         param_attr=_w("embed.w"))
+    tokens, labels = token_feeds(seq_len)
+    x = embed(tokens, vocab_size, d_model)
     routings = []
     for i in range(n_layer):
         name = f"l{i}"
         with name_scope(name + ".mla"):
             mixed = _latent_attention(
-                _norm(x, rms_eps, name + ".in_norm"), n_head, kv_rank,
+                norm(x, rms_eps, name + ".in_norm"), n_head, kv_rank,
                 qk_nope_dim, qk_rope_dim, v_head_dim, rope_theta, rms_eps,
                 name + ".mla")
         x = layers.elementwise_add(x, mixed)
-        normed = _norm(x, rms_eps, name + ".post_norm")
+        normed = norm(x, rms_eps, name + ".post_norm")
         if i < n_dense_layer:
             with name_scope(name + ".mlp"):
-                fed = _gated_mlp(normed, d_dense, name + ".mlp")
+                fed = gated_mlp(normed, d_dense, name + ".mlp")
         else:
             with name_scope(name + ".moe"):
-                fed, routing = _sparse_experts(
+                fed, routing = noaux_experts(
                     normed, seq_len, n_expert, top_k, d_expert,
                     n_shared * d_expert, first_expert, experts_held,
                     routed_scaling_factor, bias_update_rate, name)
             routings.append(routing)
         x = layers.elementwise_add(x, fed)
-    x = _norm(x, rms_eps, "final_norm")
-    logits = _linear(x, vocab_size, "head")
-
-    ce = layers.mean(layers.softmax_with_cross_entropy(logits=logits,
-                                                       label=labels))
-    fetches = {"loss": ce, "ce": ce, "logits": logits}
-    if routings:
-        fetches["tokens_per_expert"] = layers.stack(
-            [r["tokens_per_expert"] for r in routings], axis=0)
-    return {"tokens": tokens, "labels": labels}, fetches
+    x = norm(x, rms_eps, "final_norm")
+    logits = linear(x, vocab_size, "head")
+    return ({"tokens": tokens, "labels": labels},
+            cross_entropy_fetches(logits, labels, routings))
 
 
 def build(**kw):

@@ -4,7 +4,8 @@ small indexer (16 heads of 64 over one key head) scores the keys below a
 query's diagonal, the `topk` (2048) best are kept, and the softmax runs over
 those alone (`sa_config`: DeepSeek-Sparse-Attention, DeepSeek-V3.2-Exp report,
 here over grouped heads: 32 query heads over 4 key-value heads of 128). The
-rest of the block is the Qwen3-MoE family's, which `models/mellum2.py` has:
+rest of the block is the Qwen3-MoE family's, as `models/mellum2.py` builds it
+(`_decoder.grouped_attention`, `routed_experts`, `balanced_loss`):
 QK-norm, rotary, a renormalised softmax top-8 router over 128 experts, no
 shared expert, no dense layer. Built for ONE CHIP'S SHARE of an
 expert-parallel deployment: the router chooses among all `n_expert` experts,
@@ -30,7 +31,7 @@ this chip holds `experts_held` of them from `first_expert` on.
             t < topk; of equal scores the lower index)
     ctx[t, h] = sum over s in S_t of softmax_{s in S_t}(q[t, h] . k[s, g(h)]
             * head_dim^-0.5) v[s, g(h)];  out = ctx W_o
-    MoE, loss: Mellum2's (`_sparse_experts`, `_balanced_loss`)
+    MoE, loss: Mellum2's (`_decoder.routed_experts`, `balanced_loss`)
 
 No gradient passes the selection: `dsa_index_scores` and `dsa_select` carry
 none and the kept set is int8, so `append_backward` writes no grad op for the
@@ -64,8 +65,8 @@ from __future__ import annotations
 from .. import layers
 from ..core.ir import name_scope
 from ..param_attr import ParamAttr
-from .mellum2 import (_attention, _balanced_loss, _linear, _norm,
-                      _sparse_experts, _w)
+from ._decoder import (balanced_loss, embed, grouped_attention, heads_first,
+                       linear, norm, routed_experts, split_heads, token_feeds)
 
 LN_EPS = 1e-6
 
@@ -74,17 +75,16 @@ def _indexer(x, n_index_head, index_dim, rope_theta, topk, tile, name):
     """The kept set of one layer from its normed input x [B, T, D]: int8
     [B, T, T]."""
     def turned(t, n):       # [B, T, n * Di] -> [B, n, T, Di], rotary
-        t = layers.transpose(layers.reshape(t, shape=[0, 0, n, index_dim]),
-                             perm=[0, 2, 1, 3])
-        return layers.rotary_embedding(t, theta=rope_theta)
+        return layers.rotary_embedding(
+            heads_first(split_heads(t, n, index_dim)), theta=rope_theta)
 
-    q = turned(_linear(x, n_index_head * index_dim, name + ".q"),
+    q = turned(linear(x, n_index_head * index_dim, name + ".q"),
                n_index_head)
     k = layers.layer_norm(
-        _linear(x, index_dim, name + ".k"), begin_norm_axis=2,
+        linear(x, index_dim, name + ".k"), begin_norm_axis=2,
         epsilon=LN_EPS, param_attr=ParamAttr(name=name + ".k_norm.w"),
         bias_attr=ParamAttr(name=name + ".k_norm.b"))
-    weights = _linear(x, n_index_head, name + ".w")
+    weights = linear(x, n_index_head, name + ".w")
     scores = layers.dsa_index_scores(
         q, turned(k, 1), weights,
         scale=index_dim ** -0.5 * n_index_head ** -0.5, tile=tile)
@@ -100,37 +100,34 @@ def keye_vl2(vocab_size=151936, seq_len=8192, n_layer=48, d_model=2048,
     """Returns (feeds, fetches) of one training step on `[batch, seq_len]`
     token ids and next-token labels; `fetches["l<i>.kept"]` is layer i's
     kept set. `experts_held` None holds all `n_expert` experts."""
-    tokens = layers.data(name="tokens", shape=[-1, seq_len], dtype="int64",
-                         append_batch_size=False)
-    labels = layers.data(name="labels", shape=[-1, seq_len], dtype="int64",
-                         append_batch_size=False)
-
-    x = layers.embedding(tokens, size=[vocab_size, d_model],
-                         param_attr=_w("embed.w"))
+    tokens, labels = token_feeds(seq_len)
+    x = embed(tokens, vocab_size, d_model)
     routings, kept_sets = [], {}
     for i in range(n_layer):
         name = f"l{i}"
         with name_scope(name + ".dsa"):
-            normed = _norm(x, rms_eps, name + ".in_norm")
+            normed = norm(x, rms_eps, name + ".in_norm")
             kept = _indexer(normed, n_index_head, index_dim, rope_theta,
                             topk, index_tile, name + ".index")
-            mixed = _attention(normed, n_head, n_kv_head, head_dim,
-                               rope_theta, None, None, rms_eps,
-                               name + ".attn", kept=kept, topk=topk)
+            mixed = grouped_attention(normed, n_head, n_kv_head, head_dim,
+                                      rope_theta, None, None, rms_eps,
+                                      name + ".attn", kept=kept, topk=topk)
         kept_sets[name + ".kept"] = kept
         x = layers.elementwise_add(x, mixed)
         with name_scope(name + ".moe"):
-            moe, routing = _sparse_experts(
-                _norm(x, rms_eps, name + ".post_norm"), seq_len, n_expert,
-                top_k, d_expert, first_expert, experts_held, norm_topk_prob,
-                name)
+            moe, routing = routed_experts(
+                norm(x, rms_eps, name + ".post_norm"), seq_len, n_expert,
+                top_k, d_expert, name,
+                router=dict(norm_topk_prob=norm_topk_prob),
+                experts=dict(first_expert=first_expert,
+                             experts_held=experts_held))
         x = layers.elementwise_add(x, moe)
         routings.append(routing)
-    x = _norm(x, rms_eps, "final_norm")
-    logits = _linear(x, vocab_size, "head")
+    x = norm(x, rms_eps, "final_norm")
+    logits = linear(x, vocab_size, "head")
     return ({"tokens": tokens, "labels": labels},
-            {**_balanced_loss(logits, labels, routings, n_expert, top_k,
-                              aux_coef), **kept_sets})
+            {**balanced_loss(logits, labels, routings, n_expert, top_k,
+                             aux_coef), **kept_sets})
 
 
 def build(**kw):

@@ -71,7 +71,9 @@ from .. import initializer as init
 from .. import layers
 from ..core.ir import name_scope
 from ..param_attr import ParamAttr
-from .kanana2 import INIT_STD, _last, _linear, _norm, _w
+from ._decoder import (INIT_STD, cross_entropy_fetches, embed, expert_rows,
+                       heads_first, last, linear, merge_heads, noaux_router,
+                       norm, serve_group, split_heads, token_feeds)
 
 KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
 NEMOTRON_3_NANO = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
@@ -103,20 +105,20 @@ def dt_bias_init(heads, seed, dt_min=0.001, dt_max=0.1, dt_floor=1e-4):
 def _mamba(x, n_head, head_dim, n_groups, state, conv_kernel, chunk, rms_eps,
            time_step, rescale_layers, name, seed):
     inner, bc = n_head * head_dim, n_groups * state
-    mixed = _linear(x, 2 * inner + 2 * bc + n_head, name + ".in")
-    z = _last(mixed, 0, inner)
+    mixed = linear(x, 2 * inner + 2 * bc + n_head, name + ".in")
+    z = last(mixed, 0, inner)
     u = layers.causal_conv1d(
-        _last(mixed, inner, 2 * inner + 2 * bc), conv_kernel,
+        last(mixed, inner, 2 * inner + 2 * bc), conv_kernel,
         param_attr=ParamAttr(
             name=name + ".conv.w",
             initializer=init.UniformInitializer(-conv_kernel ** -0.5,
                                                 conv_kernel ** -0.5)),
         bias_attr=ParamAttr(name=name + ".conv.b"))
-    dt_raw = _last(mixed, 2 * inner + 2 * bc, 2 * inner + 2 * bc + n_head)
-    xs = layers.reshape(_last(u, 0, inner), shape=[0, 0, n_head, head_dim])
-    b = layers.reshape(_last(u, inner, inner + bc),
+    dt_raw = last(mixed, 2 * inner + 2 * bc, 2 * inner + 2 * bc + n_head)
+    xs = layers.reshape(last(u, 0, inner), shape=[0, 0, n_head, head_dim])
+    b = layers.reshape(last(u, inner, inner + bc),
                        shape=[0, 0, n_groups, state])
-    c = layers.reshape(_last(u, inner + bc, inner + 2 * bc),
+    c = layers.reshape(last(u, inner + bc, inner + 2 * bc),
                        shape=[0, 0, n_groups, state])
     y = layers.ssd_scan(
         xs, b, c, dt_raw, chunk=chunk,
@@ -135,46 +137,32 @@ def _mamba(x, n_head, head_dim, n_groups, state, conv_kernel, chunk, rms_eps,
 
 
 def _attention(x, n_head, n_kv_head, head_dim, rescale_layers, name):
-    def heads_first(t, n):      # [B, T, n * Dh] -> [B, n, T, Dh]
-        return layers.transpose(
-            layers.reshape(t, shape=[0, 0, n, head_dim]), perm=[0, 2, 1, 3])
+    def heads(t, n):            # [B, T, n * Dh] -> [B, n, T, Dh]
+        return heads_first(split_heads(t, n, head_dim))
 
-    q = heads_first(_linear(x, n_head * head_dim, name + ".q"), n_head)
-    k = heads_first(_linear(x, n_kv_head * head_dim, name + ".k"), n_kv_head)
-    v = heads_first(_linear(x, n_kv_head * head_dim, name + ".v"), n_kv_head)
-
-    def serve_group(t):     # [B, kv, T, Dh] -> [B, heads, T, Dh], h // group
-        group = n_head // n_kv_head
-        t = layers.expand(layers.unsqueeze(t, axes=[2]),
-                          expand_times=[1, 1, group, 1, 1])
-        return layers.reshape(t, shape=[0, n_head, -1, head_dim])
-
-    ctx = layers.fused_attention(q, serve_group(k), serve_group(v),
-                                 causal=True, sm_scale=head_dim ** -0.5)
-    ctx = layers.reshape(layers.transpose(ctx, perm=[0, 2, 1, 3]),
-                         shape=[0, 0, n_head * head_dim])
-    return _out_linear(ctx, x.shape[-1], name + ".o", rescale_layers)
+    q = heads(linear(x, n_head * head_dim, name + ".q"), n_head)
+    k = heads(linear(x, n_kv_head * head_dim, name + ".k"), n_kv_head)
+    v = heads(linear(x, n_kv_head * head_dim, name + ".v"), n_kv_head)
+    ctx = layers.fused_attention(
+        q, serve_group(k, n_head, n_kv_head, head_dim),
+        serve_group(v, n_head, n_kv_head, head_dim), causal=True,
+        sm_scale=head_dim ** -0.5)
+    return _out_linear(merge_heads(ctx, n_head * head_dim), x.shape[-1],
+                       name + ".o", rescale_layers)
 
 
 def _sparse_experts(x, seq_len, n_expert, top_k, d_expert, d_shared,
                     first_expert, experts_held, routed_scaling_factor,
                     bias_update_rate, rescale_layers, name):
     d_model = x.shape[-1]
-    tokens = layers.reshape(x, shape=[-1, d_model])
-    routing = layers.moe_router(
-        tokens, n_expert, top_k, param_attr=_w(name + ".router.w"),
-        norm_topk_prob=True, score_func="sigmoid",
-        bias_attr=ParamAttr(name=name + ".router.bias"),
-        bias_update_rate=bias_update_rate, norm_eps=1e-20,
-        scaling_factor=routed_scaling_factor)
-    routed = layers.moe_experts(
-        tokens, routing, n_expert, d_expert,
-        param_attr=init.NormalInitializer(0.0, INIT_STD),
-        down_attr=_out_init(rescale_layers), gated=False,
-        activation="relu2", name=name + ".experts",
-        first_expert=first_expert, experts_held=experts_held)
+    routed, routing = expert_rows(
+        x, n_expert, top_k, d_expert, name,
+        router=noaux_router(name, bias_update_rate, routed_scaling_factor),
+        experts=dict(down_attr=_out_init(rescale_layers), gated=False,
+                     activation="relu2", first_expert=first_expert,
+                     experts_held=experts_held))
     shared = _out_linear(
-        layers.relu2(_linear(x, d_shared, name + ".shared.up")), d_model,
+        layers.relu2(linear(x, d_shared, name + ".shared.up")), d_model,
         name + ".shared.down", rescale_layers)
     out = layers.elementwise_add(
         layers.reshape(routed, shape=[-1, seq_len, d_model]), shared)
@@ -198,18 +186,13 @@ def nemotron_h(vocab_size=131072, seq_len=2048, layer_pattern=NEMOTRON_3_NANO,
     if unknown or not layer_pattern:
         raise ValueError(f"a layer pattern is a string over M, E and *, got "
                          f"{layer_pattern!r}")
-    tokens = layers.data(name="tokens", shape=[-1, seq_len], dtype="int64",
-                         append_batch_size=False)
-    labels = layers.data(name="labels", shape=[-1, seq_len], dtype="int64",
-                         append_batch_size=False)
-
-    x = layers.embedding(tokens, size=[vocab_size, d_model],
-                         param_attr=_w("embed.w"))
+    tokens, labels = token_feeds(seq_len)
+    x = embed(tokens, vocab_size, d_model)
     routings = []
     for i, kind in enumerate(layer_pattern):
         name = f"l{i}"
         with name_scope(f"{name}.{KINDS[kind]}"):
-            normed = _norm(x, rms_eps, name + ".norm")
+            normed = norm(x, rms_eps, name + ".norm")
             if kind == "M":
                 part = _mamba(normed, mamba_heads, mamba_head_dim, n_groups,
                               ssm_state, conv_kernel, chunk, rms_eps,
@@ -225,16 +208,10 @@ def nemotron_h(vocab_size=131072, seq_len=2048, layer_pattern=NEMOTRON_3_NANO,
                     bias_update_rate, rescale_layers, name)
                 routings.append(routing)
         x = layers.elementwise_add(x, part)
-    x = _norm(x, rms_eps, "final_norm")
-    logits = _linear(x, vocab_size, "head")
-
-    ce = layers.mean(layers.softmax_with_cross_entropy(logits=logits,
-                                                       label=labels))
-    fetches = {"loss": ce, "ce": ce, "logits": logits}
-    if routings:
-        fetches["tokens_per_expert"] = layers.stack(
-            [r["tokens_per_expert"] for r in routings], axis=0)
-    return {"tokens": tokens, "labels": labels}, fetches
+    x = norm(x, rms_eps, "final_norm")
+    logits = linear(x, vocab_size, "head")
+    return ({"tokens": tokens, "labels": labels},
+            cross_entropy_fetches(logits, labels, routings))
 
 
 def build(**kw):

@@ -42,61 +42,36 @@ the same weights by name.
 
 from __future__ import annotations
 
-from .. import initializer as init
 from .. import layers
 from ..core.ir import name_scope
 from ..param_attr import ParamAttr
-
-INIT_STD = 0.02
-
-
-def _w(name):
-    return ParamAttr(name=name,
-                     initializer=init.NormalInitializer(0.0, INIT_STD))
-
-
-def _linear(x, size, name):
-    return layers.fc(input=x, size=size, num_flatten_dims=2, bias_attr=False,
-                     param_attr=_w(name + ".w"))
-
-
-def _norm(x, rms_eps, name):
-    return layers.rms_norm(x, epsilon=rms_eps,
-                           param_attr=ParamAttr(name=name + ".w"))
+from ._decoder import (embed, gated_mlp, heads_first, linear, merge_heads,
+                       norm, split_heads, token_feeds, w)
 
 
 def _attention(x, d_model, n_head, rope_theta, name):
     d_head = d_model // n_head
 
     def heads(t):
-        t = layers.reshape(t, shape=[0, 0, n_head, d_head])
-        return layers.transpose(t, perm=[0, 2, 1, 3])
+        return heads_first(split_heads(t, n_head, d_head))
 
-    q = layers.rotary_embedding(heads(_linear(x, d_model, name + ".q")),
+    q = layers.rotary_embedding(heads(linear(x, d_model, name + ".q")),
                                 theta=rope_theta)
-    k = layers.rotary_embedding(heads(_linear(x, d_model, name + ".k")),
+    k = layers.rotary_embedding(heads(linear(x, d_model, name + ".k")),
                                 theta=rope_theta)
-    v = heads(_linear(x, d_model, name + ".v"))
+    v = heads(linear(x, d_model, name + ".v"))
     ctx = layers.fused_attention(q, k, v, causal=True, sm_scale=d_head ** -0.5)
-    ctx = layers.reshape(layers.transpose(ctx, perm=[0, 2, 1, 3]),
-                         shape=[0, 0, d_model])
-    return _linear(ctx, d_model, name + ".o")
-
-
-def _mlp(x, d_model, d_ff, name):
-    hidden = layers.swiglu(_linear(x, d_ff, name + ".gate"),
-                           _linear(x, d_ff, name + ".up"))
-    return _linear(hidden, d_model, name + ".down")
+    return linear(merge_heads(ctx, d_model), d_model, name + ".o")
 
 
 def _layer(x, name, d_model, n_head, d_ff, rope_theta, rms_eps):
-    attn = _attention(_norm(x, rms_eps, name + ".attn_norm"), d_model, n_head,
+    attn = _attention(norm(x, rms_eps, name + ".attn_norm"), d_model, n_head,
                       rope_theta, name)
-    x = layers.elementwise_add(x, _norm(attn, rms_eps,
-                                        name + ".attn_post_norm"))
-    mlp = _mlp(_norm(x, rms_eps, name + ".mlp_norm"), d_model, d_ff, name)
-    return layers.elementwise_add(x, _norm(mlp, rms_eps,
-                                           name + ".mlp_post_norm"))
+    x = layers.elementwise_add(x, norm(attn, rms_eps,
+                                       name + ".attn_post_norm"))
+    mlp = gated_mlp(norm(x, rms_eps, name + ".mlp_norm"), d_ff, name)
+    return layers.elementwise_add(x, norm(mlp, rms_eps,
+                                          name + ".mlp_post_norm"))
 
 
 def ouro(vocab_size=49152, seq_len=4096, n_layer=48, d_model=2048, n_head=16,
@@ -104,13 +79,8 @@ def ouro(vocab_size=49152, seq_len=4096, n_layer=48, d_model=2048, n_head=16,
     """Returns (feeds, fetches) of one training step on `[batch, seq_len]`
     token ids and next-token labels. `exit_probs` is the mean of p_t over
     the tokens, `[n_loop]`; `logits` are the last pass's."""
-    tokens = layers.data(name="tokens", shape=[-1, seq_len], dtype="int64",
-                         append_batch_size=False)
-    labels = layers.data(name="labels", shape=[-1, seq_len], dtype="int64",
-                         append_batch_size=False)
-
-    x = layers.embedding(tokens, size=[vocab_size, d_model],
-                         param_attr=_w("embed.w"))
+    tokens, labels = token_feeds(seq_len)
+    x = embed(tokens, vocab_size, d_model)
     ce, log_p = [], []          # per pass, float32 [batch, seq_len, 1]
     log_stay = None             # sum_{j<t} log(1 - lambda_j)
     for t in range(1, n_loop + 1):
@@ -118,13 +88,13 @@ def ouro(vocab_size=49152, seq_len=4096, n_layer=48, d_model=2048, n_head=16,
             for i in range(n_layer):
                 x = _layer(x, f"l{i}", d_model, n_head, d_ff, rope_theta,
                            rms_eps)
-            x = _norm(x, rms_eps, "final_norm")
-            logits = _linear(x, vocab_size, "head")
+            x = norm(x, rms_eps, "final_norm")
+            logits = linear(x, vocab_size, "head")
             ce.append(layers.softmax_with_cross_entropy(logits=logits,
                                                         label=labels))
             if t == n_loop:     # no gate of its own: it takes what is left
                 break
-            z = layers.exit_gate(x, param_attr=_w("exit_gate.w"),
+            z = layers.exit_gate(x, param_attr=w("exit_gate.w"),
                                  bias_attr=ParamAttr(name="exit_gate.b"))
             log_exit = layers.logsigmoid(z)
             log_p.append(log_exit if log_stay is None else

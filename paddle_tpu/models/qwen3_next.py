@@ -56,32 +56,13 @@ from .. import initializer as init
 from .. import layers
 from ..core.ir import name_scope
 from ..param_attr import ParamAttr
-
-INIT_STD = 0.02
-
-
-def _normal():
-    return init.NormalInitializer(0.0, INIT_STD)
-
-
-def _w(name):
-    return ParamAttr(name=name, initializer=_normal())
-
-
-def _linear(x, size, name):
-    return layers.fc(input=x, size=size, num_flatten_dims=2, bias_attr=False,
-                     param_attr=_w(name + ".w"))
+from ._decoder import (balanced_loss, embed, expert_rows, gated_mlp,
+                       heads_first, last, linear, norm, serve_group,
+                       split_heads, token_feeds)
 
 
 def _norm(x, rms_eps, name):
-    return layers.rms_norm(x, epsilon=rms_eps, zero_centered=True,
-                           param_attr=ParamAttr(name=name + ".w"))
-
-
-def _last(x, first, end):
-    """x[..., first:end]."""
-    axis = len(x.shape) - 1
-    return layers.slice(x, axes=[axis], starts=[first], ends=[end])
+    return norm(x, rms_eps, name, zero_centered=True)
 
 
 def layer_kind(i, full_attention_interval):
@@ -91,39 +72,26 @@ def layer_kind(i, full_attention_interval):
 
 def _attention(x, n_head, n_kv_head, head_dim, rotary_dim, rope_theta,
                rms_eps, name):
-    qg = layers.reshape(_linear(x, n_head * 2 * head_dim, name + ".q"),
-                        shape=[0, 0, n_head, 2 * head_dim])
-    q, gate = _last(qg, 0, head_dim), _last(qg, head_dim, 2 * head_dim)
-
-    def kv_heads(t):
-        return layers.reshape(t, shape=[0, 0, n_kv_head, head_dim])
-
-    k = kv_heads(_linear(x, n_kv_head * head_dim, name + ".k"))
-    v = kv_heads(_linear(x, n_kv_head * head_dim, name + ".v"))
-
-    def heads_first(t):
-        return layers.transpose(t, perm=[0, 2, 1, 3])
-
+    qg = split_heads(linear(x, n_head * 2 * head_dim, name + ".q"), n_head,
+                     2 * head_dim)
+    q, gate = last(qg, 0, head_dim), last(qg, head_dim, 2 * head_dim)
+    k = split_heads(linear(x, n_kv_head * head_dim, name + ".k"), n_kv_head,
+                    head_dim)
+    v = split_heads(linear(x, n_kv_head * head_dim, name + ".v"), n_kv_head,
+                    head_dim)
     q = layers.rotary_embedding(
         heads_first(_norm(q, rms_eps, name + ".q_norm")), theta=rope_theta,
         rotary_dim=rotary_dim)
     k = layers.rotary_embedding(
         heads_first(_norm(k, rms_eps, name + ".k_norm")), theta=rope_theta,
         rotary_dim=rotary_dim)
-
-    def serve_group(t):     # [B, kv, T, Dh] -> [B, heads, T, Dh], h // group
-        group = n_head // n_kv_head
-        t = layers.expand(layers.unsqueeze(t, axes=[2]),
-                          expand_times=[1, 1, group, 1, 1])
-        return layers.reshape(t, shape=[0, n_head, -1, head_dim])
-
-    ctx = layers.fused_attention(q, serve_group(k),
-                                 serve_group(heads_first(v)), causal=True,
+    k = serve_group(k, n_head, n_kv_head, head_dim)
+    v = serve_group(heads_first(v), n_head, n_kv_head, head_dim)
+    ctx = layers.fused_attention(q, k, v, causal=True,
                                  sm_scale=head_dim ** -0.5)
-    ctx = layers.elementwise_mul(layers.transpose(ctx, perm=[0, 2, 1, 3]),
-                                 layers.sigmoid(gate))
+    ctx = layers.elementwise_mul(heads_first(ctx), layers.sigmoid(gate))
     ctx = layers.reshape(ctx, shape=[0, 0, n_head * head_dim])
-    return _linear(ctx, x.shape[-1], name + ".o")
+    return linear(ctx, x.shape[-1], name + ".o")
 
 
 def _a_log(heads, seed):
@@ -139,59 +107,53 @@ def _gated_delta_net(x, n_key_head, n_value_head, key_dim, value_dim,
     r = n_value_head // n_key_head
     wide_k, wide_v = n_key_head * key_dim, n_value_head * value_dim
     per_head = 2 * key_dim + 2 * r * value_dim
-    mixed = layers.reshape(_linear(x, n_key_head * per_head, name + ".qkvz"),
+    mixed = layers.reshape(linear(x, n_key_head * per_head, name + ".qkvz"),
                            shape=[0, 0, n_key_head, per_head])
-    ba = layers.reshape(_linear(x, n_key_head * 2 * r, name + ".ba"),
+    ba = layers.reshape(linear(x, n_key_head * 2 * r, name + ".ba"),
                         shape=[0, 0, n_key_head, 2 * r])
 
     def flat(t, width):
         return layers.reshape(t, shape=[0, 0, width])
 
     qkv = layers.concat(
-        [flat(_last(mixed, 0, key_dim), wide_k),
-         flat(_last(mixed, key_dim, 2 * key_dim), wide_k),
-         flat(_last(mixed, 2 * key_dim, 2 * key_dim + r * value_dim), wide_v)],
+        [flat(last(mixed, 0, key_dim), wide_k),
+         flat(last(mixed, key_dim, 2 * key_dim), wide_k),
+         flat(last(mixed, 2 * key_dim, 2 * key_dim + r * value_dim), wide_v)],
         axis=2)
-    z = layers.reshape(_last(mixed, 2 * key_dim + r * value_dim, per_head),
+    z = layers.reshape(last(mixed, 2 * key_dim + r * value_dim, per_head),
                        shape=[0, 0, n_value_head, value_dim])
     qkv = layers.causal_conv1d(
         qkv, conv_kernel, param_attr=ParamAttr(
             name=name + ".conv.w",
             initializer=init.UniformInitializer(-conv_kernel ** -0.5,
                                                 conv_kernel ** -0.5)))
-    q = layers.reshape(_last(qkv, 0, wide_k),
+    q = layers.reshape(last(qkv, 0, wide_k),
                        shape=[0, 0, n_key_head, key_dim])
-    k = layers.reshape(_last(qkv, wide_k, 2 * wide_k),
+    k = layers.reshape(last(qkv, wide_k, 2 * wide_k),
                        shape=[0, 0, n_key_head, key_dim])
-    v = layers.reshape(_last(qkv, 2 * wide_k, 2 * wide_k + wide_v),
+    v = layers.reshape(last(qkv, 2 * wide_k, 2 * wide_k + wide_v),
                        shape=[0, 0, n_value_head, value_dim])
     o = layers.gated_delta_rule(
-        q, k, v, a=flat(_last(ba, r, 2 * r), n_value_head),
-        b=flat(_last(ba, 0, r), n_value_head),
+        q, k, v, a=flat(last(ba, r, 2 * r), n_value_head),
+        b=flat(last(ba, 0, r), n_value_head),
         a_log_attr=ParamAttr(name=name + ".A_log",
                              initializer=_a_log(n_value_head, seed)),
         dt_bias_attr=ParamAttr(name=name + ".dt_bias"))
     o = layers.gated_rms_norm(o, z, epsilon=rms_eps,
                               param_attr=ParamAttr(name=name + ".norm.w"))
-    return _linear(flat(o, wide_v), x.shape[-1], name + ".out")
+    return linear(flat(o, wide_v), x.shape[-1], name + ".out")
 
 
 def _sparse_experts(x, seq_len, n_expert, top_k, d_expert, d_shared,
                     first_expert, experts_held, norm_topk_prob, name):
     d_model = x.shape[-1]
-    tokens = layers.reshape(x, shape=[-1, d_model])
-    routing = layers.moe_router(tokens, n_expert, top_k,
-                                param_attr=_w(name + ".router.w"),
-                                norm_topk_prob=norm_topk_prob)
-    routed = layers.moe_experts(
-        tokens, routing, n_expert, d_expert, param_attr=_normal(),
-        name=name + ".experts", first_expert=first_expert,
-        experts_held=experts_held)
-    hidden = layers.swiglu(_linear(x, d_shared, name + ".shared.gate"),
-                           _linear(x, d_shared, name + ".shared.up"))
+    routed, routing = expert_rows(
+        x, n_expert, top_k, d_expert, name,
+        router=dict(norm_topk_prob=norm_topk_prob),
+        experts=dict(first_expert=first_expert, experts_held=experts_held))
     shared = layers.elementwise_mul(
-        _linear(hidden, d_model, name + ".shared.down"),
-        layers.sigmoid(_linear(x, 1, name + ".shared_gate")))
+        gated_mlp(x, d_shared, name + ".shared"),
+        layers.sigmoid(linear(x, 1, name + ".shared_gate")))
     out = layers.elementwise_add(
         layers.reshape(routed, shape=[-1, seq_len, d_model]), shared)
     return out, routing
@@ -207,13 +169,8 @@ def qwen3_next(vocab_size=151936, seq_len=4096, n_layer=48, d_model=2048,
     """Returns (feeds, fetches) of one training step on `[batch, seq_len]`
     token ids and next-token labels. `experts_held` None holds all
     `n_expert` experts."""
-    tokens = layers.data(name="tokens", shape=[-1, seq_len], dtype="int64",
-                         append_batch_size=False)
-    labels = layers.data(name="labels", shape=[-1, seq_len], dtype="int64",
-                         append_batch_size=False)
-
-    x = layers.embedding(tokens, size=[vocab_size, d_model],
-                         param_attr=_w("embed.w"))
+    tokens, labels = token_feeds(seq_len)
+    x = embed(tokens, vocab_size, d_model)
     routings = []
     for i in range(n_layer):
         name = f"l{i}"
@@ -237,30 +194,11 @@ def qwen3_next(vocab_size=151936, seq_len=4096, n_layer=48, d_model=2048,
         x = layers.elementwise_add(x, moe)
         routings.append(routing)
     x = _norm(x, rms_eps, "final_norm")
-    logits = _linear(x, vocab_size, "head")
+    logits = linear(x, vocab_size, "head")
 
-    ce = layers.mean(layers.softmax_with_cross_entropy(logits=logits,
-                                                       label=labels))
-    # all layers' router rows taken together, as `models/olmoe.py` does:
-    # f_e = assignments to e / rows, P_e = mean probability of e, over all
-    # `n_expert` experts wherever they live
-    counts = layers.sums([layers.cast(r["tokens_per_expert"], "float32")
-                          for r in routings])
-    rows = layers.scale(layers.reduce_sum(counts), scale=1.0 / top_k)
-    share = layers.elementwise_div(counts, rows)
-    share.stop_gradient = True      # counts: nothing to differentiate
-    mean_prob = layers.scale(
-        layers.sums([layers.reduce_mean(r["probs"], dim=0)
-                     for r in routings]), scale=1.0 / n_layer)
-    load_balance = layers.scale(
-        layers.reduce_sum(layers.elementwise_mul(share, mean_prob)),
-        scale=float(n_expert))
-    loss = layers.sums([ce, layers.scale(load_balance, scale=aux_coef)])
-    tokens_per_expert = layers.stack(
-        [r["tokens_per_expert"] for r in routings], axis=0)
     return ({"tokens": tokens, "labels": labels},
-            {"loss": loss, "ce": ce, "load_balance": load_balance,
-             "logits": logits, "tokens_per_expert": tokens_per_expert})
+            balanced_loss(logits, labels, routings, n_expert, top_k,
+                          aux_coef))
 
 
 def build(**kw):

@@ -69,63 +69,36 @@ from __future__ import annotations
 
 from .. import layers
 from ..core.ir import name_scope
-from ..param_attr import ParamAttr
-from .kanana2 import _gated_mlp, _linear, _norm, _normal, _w
-from .mellum2 import PERIOD, layer_kinds
+from ._decoder import (PERIOD, cross_entropy_fetches, embed, gated_mlp,
+                       heads_first, layer_kinds, linear, merge_heads,
+                       noaux_experts, norm, serve_group, split_heads,
+                       token_feeds)
 
 
 def _gated_attention(x, n_head, n_kv_head, head_dim, rope_theta, window,
                      rotary, rms_eps, name):
-    def heads_first(t, n, norm_name=None):  # [B, T, n * Dh] -> [B, n, T, Dh]
-        t = layers.reshape(t, shape=[0, 0, n, head_dim])
+    def heads(t, n, norm_name=None):    # [B, T, n * Dh] -> [B, n, T, Dh]
+        t = split_heads(t, n, head_dim)
         if norm_name is not None:
-            t = _norm(t, rms_eps, norm_name)
-        t = layers.transpose(t, perm=[0, 2, 1, 3])
+            t = norm(t, rms_eps, norm_name)
+        t = heads_first(t)
         if norm_name is not None and rotary:
             t = layers.rotary_embedding(t, theta=rope_theta)
         return t
 
-    q = heads_first(_linear(x, n_head * head_dim, name + ".q"), n_head,
-                    name + ".q_norm")
-    k = heads_first(_linear(x, n_kv_head * head_dim, name + ".k"), n_kv_head,
-                    name + ".k_norm")
-    v = heads_first(_linear(x, n_kv_head * head_dim, name + ".v"), n_kv_head)
-    gate = _linear(x, n_head * head_dim, name + ".gate")
-
-    def serve_group(t):     # [B, kv, T, Dh] -> [B, heads, T, Dh], h // group
-        group = n_head // n_kv_head
-        t = layers.expand(layers.unsqueeze(t, axes=[2]),
-                          expand_times=[1, 1, group, 1, 1])
-        return layers.reshape(t, shape=[0, n_head, -1, head_dim])
-
-    ctx = layers.fused_attention(q, serve_group(k), serve_group(v),
-                                 causal=True, sm_scale=head_dim ** -0.5,
-                                 window=window)
-    ctx = layers.reshape(layers.transpose(ctx, perm=[0, 2, 1, 3]),
-                         shape=[0, 0, n_head * head_dim])
-    ctx = layers.elementwise_mul(ctx, layers.sigmoid(gate))
-    return _linear(ctx, x.shape[-1], name + ".o")
-
-
-def _sparse_experts(x, seq_len, n_expert, top_k, d_expert, d_shared,
-                    first_expert, experts_held, route_scale,
-                    bias_update_rate, name):
-    d_model = x.shape[-1]
-    tokens = layers.reshape(x, shape=[-1, d_model])
-    routing = layers.moe_router(
-        tokens, n_expert, top_k, param_attr=_w(name + ".router.w"),
-        norm_topk_prob=True, score_func="sigmoid",
-        bias_attr=ParamAttr(name=name + ".router.bias"),
-        bias_update_rate=bias_update_rate, norm_eps=1e-20,
-        scaling_factor=route_scale)
-    routed = layers.moe_experts(
-        tokens, routing, n_expert, d_expert, param_attr=_normal(),
-        name=name + ".experts", first_expert=first_expert,
-        experts_held=experts_held)
-    out = layers.elementwise_add(
-        layers.reshape(routed, shape=[-1, seq_len, d_model]),
-        _gated_mlp(x, d_shared, name + ".shared"))
-    return out, routing
+    q = heads(linear(x, n_head * head_dim, name + ".q"), n_head,
+              name + ".q_norm")
+    k = heads(linear(x, n_kv_head * head_dim, name + ".k"), n_kv_head,
+              name + ".k_norm")
+    v = heads(linear(x, n_kv_head * head_dim, name + ".v"), n_kv_head)
+    gate = linear(x, n_head * head_dim, name + ".gate")
+    ctx = layers.fused_attention(
+        q, serve_group(k, n_head, n_kv_head, head_dim),
+        serve_group(v, n_head, n_kv_head, head_dim), causal=True,
+        sm_scale=head_dim ** -0.5, window=window)
+    ctx = layers.elementwise_mul(merge_heads(ctx, n_head * head_dim),
+                                 layers.sigmoid(gate))
+    return linear(ctx, x.shape[-1], name + ".o")
 
 
 def trinity(vocab_size=200192, seq_len=4096, n_layer=32, n_dense_layer=2,
@@ -137,15 +110,10 @@ def trinity(vocab_size=200192, seq_len=4096, n_layer=32, n_dense_layer=2,
     """Returns (feeds, fetches) of one training step on `[batch, seq_len]`
     token ids and next-token labels. `layer_types`: the kind of every layer,
     repeated as a period where it is shorter than `n_layer`
-    (`mellum2.layer_kinds`). `n_layer` counts the `n_dense_layer` leading
+    (`_decoder.layer_kinds`). `n_layer` counts the `n_dense_layer` leading
     dense layers too. `experts_held` None holds all `n_expert` experts."""
-    tokens = layers.data(name="tokens", shape=[-1, seq_len], dtype="int64",
-                         append_batch_size=False)
-    labels = layers.data(name="labels", shape=[-1, seq_len], dtype="int64",
-                         append_batch_size=False)
-
-    x = layers.embedding(tokens, size=[vocab_size, d_model],
-                         param_attr=_w("embed.w"))
+    tokens, labels = token_feeds(seq_len)
+    x = embed(tokens, vocab_size, d_model)
     x = layers.scale(x, scale=d_model ** 0.5)
     routings = []
     for i, kind in enumerate(layer_kinds(n_layer, layer_types)):
@@ -153,34 +121,28 @@ def trinity(vocab_size=200192, seq_len=4096, n_layer=32, n_dense_layer=2,
         sliding = kind == "sliding_attention"
         with name_scope(name + (".swa" if sliding else ".attn")):
             mixed = _gated_attention(
-                _norm(x, rms_eps, name + ".in_norm"), n_head, n_kv_head,
+                norm(x, rms_eps, name + ".in_norm"), n_head, n_kv_head,
                 head_dim, rope_theta, sliding_window if sliding else None,
                 sliding, rms_eps, name + ".attn")
-            mixed = _norm(mixed, rms_eps, name + ".post_attn_norm")
+            mixed = norm(mixed, rms_eps, name + ".post_attn_norm")
         x = layers.elementwise_add(x, mixed)
         dense = i < n_dense_layer
         with name_scope(name + (".mlp" if dense else ".moe")):
-            normed = _norm(x, rms_eps, name + ".pre_mlp_norm")
+            normed = norm(x, rms_eps, name + ".pre_mlp_norm")
             if dense:
-                fed = _gated_mlp(normed, d_dense, name + ".mlp")
+                fed = gated_mlp(normed, d_dense, name + ".mlp")
             else:
-                fed, routing = _sparse_experts(
+                fed, routing = noaux_experts(
                     normed, seq_len, n_expert, top_k, d_expert,
                     n_shared * d_expert, first_expert, experts_held,
                     route_scale, bias_update_rate, name)
                 routings.append(routing)
-            fed = _norm(fed, rms_eps, name + ".post_mlp_norm")
+            fed = norm(fed, rms_eps, name + ".post_mlp_norm")
         x = layers.elementwise_add(x, fed)
-    x = _norm(x, rms_eps, "final_norm")
-    logits = _linear(x, vocab_size, "head")
-
-    ce = layers.mean(layers.softmax_with_cross_entropy(logits=logits,
-                                                       label=labels))
-    fetches = {"loss": ce, "ce": ce, "logits": logits}
-    if routings:
-        fetches["tokens_per_expert"] = layers.stack(
-            [r["tokens_per_expert"] for r in routings], axis=0)
-    return {"tokens": tokens, "labels": labels}, fetches
+    x = norm(x, rms_eps, "final_norm")
+    logits = linear(x, vocab_size, "head")
+    return ({"tokens": tokens, "labels": labels},
+            cross_entropy_fetches(logits, labels, routings))
 
 
 def build(**kw):

@@ -566,7 +566,7 @@ class RecompilationObservatory:
         _CompiledProgram is about to be built for `key`, its `StepKey`).
         Returns the event, whose cause it found. `detail` is kept as it
         is, not copied: the builder (`core.executor.PreparedProgram._build_entry`) puts the
-        version, the feed and fetch names and `backward.program_detail`
+        version, the feed and fetch names and `census.program_detail`
         (the sharing counters and the census of mixer and expert layers)
         there and hands the same dict to the step's lowerer, so what a
         rule notes under the trace lands on this event."""

@@ -84,9 +84,8 @@ import numpy as np
 from jax import lax
 
 from ..core.registry import register_grad, register_op
-from .linear_attention import _backend_takes_kernels
+from . import _kernels
 from .moe import map_used_rows
-from .pallas_attention import _interpret
 
 
 @register_op("rms_norm")
@@ -138,7 +137,7 @@ def _gated_norm_plan(shape, dtype):
 
 def _gated_norm_kernels_run(shape, dtype):
     return _gated_norm_plan(shape, dtype) == "kernel" \
-        and _backend_takes_kernels()
+        and _kernels.backend_takes_kernels()
 
 
 def _gated_norm_blocks(T, H, D, itemsize, backward):
@@ -222,7 +221,7 @@ def _gated_norm_layout(X, backward):
         grid=grid,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
-        interpret=_interpret())
+        interpret=_kernels.interpret())
     return (-1, T, H * D), Hb, D, grid, block, params
 
 
@@ -483,7 +482,7 @@ def _rotary_plan(shape, dtype, R, interleaved):
 
 def _rotary_kernel_runs(shape, dtype, R, interleaved):
     return _rotary_plan(shape, dtype, R, interleaved) != "xla" \
-        and _backend_takes_kernels()
+        and _kernels.backend_takes_kernels()
 
 
 def _rotary_blocks(N, T, D, itemsize):
@@ -570,7 +569,7 @@ def _rotary_call(X, cos, sin, interleaved, backward):
         out_specs=x_spec, out_shape=jax.ShapeDtypeStruct(x.shape, X.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=_interpret(),
+        interpret=_kernels.interpret(),
     )(x, cos, sin, *matrices)
     return out.reshape(X.shape)
 

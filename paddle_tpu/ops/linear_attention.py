@@ -128,7 +128,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.registry import call_rule, get_op_def, register_grad, register_op
-from .pallas_attention import _interpret
+from . import _kernels
+from ._kernels import _NN, _NT, _TN, _cols, _dot, _rows, _running_sum
 
 
 def _conv_xla(X, W, silu, bias=None):
@@ -257,10 +258,6 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk):
 # the two Pallas kernels (module docstring: what stays in VMEM, precisions)
 # ---------------------------------------------------------------------------
 
-_HI = lax.Precision.HIGHEST
-_NN = ((1,), (0,))      # a b
-_NT = ((1,), (1,))      # a b^T
-_TN = ((0,), (0,))      # a^T b
 _SUB = 16               # the diagonal blocks the substitution inverts on the VPU
 _VMEM = 12 << 20        # of the 16 MiB a call has unasked, what blocks may take
 
@@ -284,43 +281,9 @@ def _plan(Dk, Dv, chunk, chunks=1, r=1):
     return "kernel", 2 if chunks % 2 == 0 and vmem(2) <= _VMEM else 1
 
 
-def _on_chip():
-    return jax.default_backend() != "cpu"
-
-
-def _backend_takes_kernels():
-    """Whether this backend takes the kernels for a shape a plan gives
-    them: always on a TPU; on a CPU backend only under the interpreter's
-    rehearsal switch (`pallas_attention._interpret`, refused on the chip),
-    since a model interpreted at the cell's widths never ends."""
-    return _on_chip() or _interpret()
-
-
 def _kernels_run(Dk, Dv, chunk):
-    return _plan(Dk, Dv, chunk)[0] == "kernel" and _backend_takes_kernels()
-
-
-def _dot(a, b, dims, full=False):
-    """The float32 product of two float32 tiles. `full`: HIGHEST, the MXU's
-    float32 passes. Otherwise the backend's DEFAULT for float32 operands,
-    spelled out: on the chip XLA rounds them to bf16 and makes one pass
-    into a float32 accumulator, so the kernel does; under the interpreter
-    on a CPU they stay float32, as that backend's dots do."""
-    if full:
-        return lax.dot_general(a, b, (dims, ((), ())), precision=_HI,
-                               preferred_element_type=jnp.float32)
-    if _on_chip():
-        a, b = a.astype(jnp.bfloat16), b.astype(jnp.bfloat16)
-    return lax.dot_general(a, b, (dims, ((), ())),
-                           preferred_element_type=jnp.float32)
-
-
-def _rows(x):
-    return jnp.sum(x, axis=1, keepdims=True)            # [n, m] -> [n, 1]
-
-
-def _cols(x):
-    return jnp.sum(x, axis=0, keepdims=True)            # [n, m] -> [1, m]
+    return _plan(Dk, Dv, chunk)[0] == "kernel" \
+        and _kernels.backend_takes_kernels()
 
 
 def _l2(x_ref):
@@ -674,14 +637,8 @@ def _gdn_call(kernel, name, Q, K, V, G, beta, more, out_shape, out_blocks,
         scratch_shapes=[pltpu.VMEM((r, Dk, Dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_kernels.interpret(),
     )(_flat(Q), _flat(K), _flat(V), G, beta, *[x for _, x in more])
-
-
-def _running_sum(g, chunk):     # [B, T, Hv], the sum starting at each chunk
-    g = g.astype(jnp.float32)
-    by_chunk = g.reshape(g.shape[0], -1, chunk, g.shape[2])
-    return jnp.cumsum(by_chunk, axis=2).reshape(g.shape)
 
 
 def _gdn_forward(Q, K, V, g, beta, chunk):
@@ -737,7 +694,8 @@ def _conv_plan(T, C, K):
 
 
 def _conv_kernels_run(T, C, K):
-    return _conv_plan(T, C, K) == "kernel" and _backend_takes_kernels()
+    return _conv_plan(T, C, K) == "kernel" \
+        and _kernels.backend_takes_kernels()
 
 
 def _conv_blocks(T, C):
@@ -911,7 +869,7 @@ def _conv_forward(X, W, silu, bias=None):
         out_shape=jax.ShapeDtypeStruct(X.shape, X.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
-        interpret=_interpret(),
+        interpret=_kernels.interpret(),
     )(X, X, _weight_rows(W, bias))
 
 
@@ -942,7 +900,7 @@ def _conv_backward(X, W, d_out, silu, bias=None):
                         pltpu.VMEM((Kb, 8, Cb), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_kernels.interpret(),
     )(X, X, d_out, _weight_rows(W, bias))
     if bias is None:
         return dX, dW.T

@@ -120,8 +120,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.registry import register_grad, register_op
-from .linear_attention import _backend_takes_kernels
-from .pallas_attention import _interpret
+from . import _kernels
 
 # Rows a group is padded to in `moe_dispatch`, and the row tile of the
 # kernels: equal, so that no tile holds rows of two groups.
@@ -491,7 +490,7 @@ def _token_sum_call(moved, source, k, n, sizes, dtype, plan, scale=None):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=vmem),
-        interpret=_interpret())(*scalars, moved)
+        interpret=_kernels.interpret())(*scalars, moved)
 
 
 def _tokens_from_rows(moved, source, k, n, sizes, dtype, scale=None):
@@ -501,7 +500,7 @@ def _tokens_from_rows(moved, source, k, n, sizes, dtype, scale=None):
     Pallas call where the backend takes kernels and `_token_sum_plan` gives
     one, else the loop and a cast."""
     plan = _token_sum_plan(n, k, *moved.shape, moved.dtype)
-    if plan is not None and _backend_takes_kernels():
+    if plan is not None and _kernels.backend_takes_kernels():
         return _token_sum_call(moved, source, k, n, sizes, dtype, plan,
                                scale)
     return _token_sum_loop(moved, source, k, n, sizes, scale).astype(dtype)
@@ -533,7 +532,7 @@ def _moe_dispatch_grad(ctx, ins, out_grads):
 def _kernel():
     """The megablox module where its kernels are the path (a TPU backend,
     or the CPU under the Pallas interpreter), else None."""
-    if not _backend_takes_kernels():
+    if not _kernels.backend_takes_kernels():
         return None
     import importlib
     # the package re-exports a function under the submodule's name
@@ -595,7 +594,8 @@ def _grouped_dot(x, w, sizes, transpose_w=False):
         w, transpose_w = w.swapaxes(1, 2), not transpose_w
     return kernel.gmm(x, w, sizes, preferred_element_type=x.dtype,
                       tiling=_gmm_tiles(x.shape[0], x.shape[1], columns),
-                      transpose_rhs=transpose_w, interpret=_interpret())
+                      transpose_rhs=transpose_w,
+                      interpret=_kernels.interpret())
 
 
 @register_op("grouped_matmul", propagate_seqlen=False)
@@ -632,7 +632,7 @@ def _grouped_dot_grads(x, w, g, sizes):
                               min(lhs.shape[1], _TGMM_BLOCK[0]),
                               min(rhs.shape[1], _TGMM_BLOCK[1])),
                       num_actual_groups=w.shape[0],
-                      interpret=_interpret())
+                      interpret=_kernels.interpret())
     return d_x, d_w.swapaxes(1, 2) if swapped else d_w
 
 

@@ -49,7 +49,8 @@ import numpy as np
 from jax import lax
 
 from ..core.registry import register_op
-from .pallas_attention import NEG_INF, _interpret, flash_attention
+from . import _kernels
+from .pallas_attention import NEG_INF, flash_attention
 
 _LANES = 128
 
@@ -59,8 +60,8 @@ def _pallas_ok(q, k_cache):
     the kernel's blocks must be whole tiles — head_dim a multiple of the
     128 lanes, block_size * heads a multiple of the cache dtype's sublane
     tile (8 for float32, 32 for int8) — and anything else raises."""
-    if jax.default_backend() == "cpu":
-        return _interpret()
+    if not _kernels.on_chip():
+        return _kernels.interpret()
     _, H, Dh = q.shape
     rows = k_cache.shape[1] * H
     sublanes = 32 // k_cache.dtype.itemsize
@@ -272,7 +273,7 @@ def _paged_call(q, k_cache, v_cache, block_tables, seq_lens, sm_scale,
         out_shape=jax.ShapeDtypeStruct((S, H, Dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_kernels.interpret(),
         name="paged_attention",
     )(seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32),
       *(sc.astype(jnp.float32) for sc in scales),

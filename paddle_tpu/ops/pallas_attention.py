@@ -116,7 +116,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 from typing import NamedTuple
 
 import jax
@@ -126,6 +125,7 @@ from jax import lax
 
 from ..core.registry import (amp_cast, call_rule, get_op_def, register_grad,
                              register_op)
+from . import _kernels
 
 NEG_INF = -1e30
 
@@ -270,18 +270,6 @@ def _fwd_plan(T, BK):
     algorithm whose bookkeeping is needed or not by what the input is;
     the choice reads the shape alone."""
     return "onepass" if T == BK else "stream"
-
-
-def _interpret():
-    """The CPU rehearsal switch. Refused on any other backend: a kernel
-    quietly interpreted on the chip would pass every check and prove
-    nothing about Mosaic."""
-    on = os.environ.get("PADDLE_TPU_PALLAS_INTERPRET", "0") == "1"
-    if on and jax.default_backend() != "cpu":
-        raise RuntimeError(
-            f"PADDLE_TPU_PALLAS_INTERPRET=1 is a CPU rehearsal switch; "
-            f"refused on the {jax.default_backend()!r} backend — unset it")
-    return on
 
 
 # ---------------------------------------------------------------------------
@@ -1187,12 +1175,13 @@ def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0,
         vmem = _fwd_vmem(D, 1, BQ, BK, q.dtype.itemsize, kernel == "stream") \
             + _kept_vmem(BQ, BK)
         return _forward(q, k, v, seed, causal, sm_scale, dropout_rate, window,
-                        _Plan((BQ, BK), kernel, interpret=_interpret(),
+                        _Plan((BQ, BK), kernel, interpret=_kernels.interpret(),
                               vmem=max(vmem, _SCOPED_VMEM_FLOOR_BYTES)),
                         kept)
     if not token_major:
         return _forward(q, k, v, seed, causal, sm_scale, dropout_rate, window,
-                        _Plan((BQ, BK), kernel, interpret=_interpret()))
+                        _Plan((BQ, BK), kernel,
+                              interpret=_kernels.interpret()))
 
     def need(lanes):
         return _fwd_vmem(lanes, lanes // D, BQ, BK, q.dtype.itemsize,
@@ -1201,7 +1190,7 @@ def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0,
     return _token_major_forward(
         q, k, v, jnp.asarray(seed, jnp.int32), causal, sm_scale,
         dropout_rate, window, _Plan((BQ, BK), kernel, heads, vmem,
-                                    _interpret()))
+                                    _kernels.interpret()))
 
 
 def _forward(q, k, v, seed, causal, sm_scale, dropout_rate, window, plan,
@@ -1303,12 +1292,12 @@ def _flash_backward(q, k, v, o, lse, g, causal, sm_scale, dropout_rate, seed,
         return _backward(q, k, v, o, lse, g, seed, causal, sm_scale,
                          dropout_rate, window, _Plan(
                              (BQ, BK), _bwd_plan(T, D, Dv, BQ, BK, itemsize),
-                             vmem=vmem, interpret=_interpret()), kept)
+                             vmem=vmem, interpret=_kernels.interpret()), kept)
     if not token_major:
         return _backward(q, k, v, o, lse, g, seed, causal, sm_scale,
                          dropout_rate, window, _Plan(
                              (BQ, BK), _bwd_plan(T, D, Dv, BQ, BK, itemsize),
-                             interpret=_interpret()))
+                             interpret=_kernels.interpret()))
 
     # a row of one K block keeps a q-block's dQ, not the row's; `Out`'s
     # block comes in beside dOut's (`_delta`)
@@ -1321,7 +1310,7 @@ def _flash_backward(q, k, v, o, lse, g, causal, sm_scale, dropout_rate, seed,
         q, k, v, o, lse, g, jnp.asarray(seed, jnp.int32), causal, sm_scale,
         dropout_rate, window, _Plan(
             (BQ, BK), _bwd_plan(T, lanes, lanes, BQ, BK, itemsize), heads,
-            vmem, _interpret()))
+            vmem, _kernels.interpret()))
 
 
 def _backward(q, k, v, o, lse, g, seed, causal, sm_scale, dropout_rate,
@@ -1553,7 +1542,7 @@ def _pallas_ok(q, dropout_rate=0.0, v=None, window=None, token_major=False):
         supported = supported and Dv == D
         needs += (", and of [batch, seq, heads, head_dim] operands value "
                   "heads as wide as the query's (Dv == D)")
-    if jax.default_backend() != "cpu":
+    if _kernels.on_chip():
         if not supported:
             raise ValueError(
                 f"flash attention on the {jax.default_backend()!r} backend "
@@ -1564,7 +1553,7 @@ def _pallas_ok(q, dropout_rate=0.0, v=None, window=None, token_major=False):
                    f"; the window of {window} is not why: any window of at "
                    f"least 1 runs at a supported shape"))
         return True
-    if not _interpret():
+    if not _kernels.interpret():
         return False
     if dropout_rate:
         return False  # pltpu.prng_* has no interpreter implementation

@@ -43,7 +43,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.registry import register_op
-from .pallas_attention import _interpret
+from . import _kernels
 
 INT_MIN = -2 ** 31
 _LANES = 128
@@ -57,14 +57,14 @@ def _on_kernels(T, tile=None):
     second path."""
     supported = T % _LANES == 0 and (tile is None or (
         tile % _LANES == 0 and T % tile == 0))
-    if jax.default_backend() != "cpu":
+    if _kernels.on_chip():
         if not supported:
             raise ValueError(
                 f"the index kernels on the {jax.default_backend()!r} backend "
                 f"need T % 128 == 0 and tiles of a multiple of 128 that "
                 f"divide T, got T = {T}, tile = {tile}")
         return True
-    return _interpret() and supported
+    return _kernels.interpret() and supported
 
 
 # ---------------------------------------------------------------------------
@@ -169,7 +169,7 @@ def index_scores_kernel(q, k, w, scale, tile):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
             vmem_limit_bytes=_VMEM_BYTES),
-        interpret=_interpret(),
+        interpret=_kernels.interpret(),
         name="dsa_index_scores",
     )(q, k[:, 0], w)
 
@@ -272,7 +272,7 @@ def select_kernel(scores, topk):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_VMEM_BYTES),
-        interpret=_interpret(),
+        interpret=_kernels.interpret(),
         name="dsa_select",
     )(scores)
 

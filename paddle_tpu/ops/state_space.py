@@ -80,9 +80,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.registry import call_rule, get_op_def, register_grad, register_op
-from .linear_attention import _NN, _NT, _TN, _backend_takes_kernels, _cols, \
-    _dot, _rows, _running_sum
-from .pallas_attention import _interpret
+from . import _kernels
+from ._kernels import _NN, _NT, _TN, _cols, _dot, _rows, _running_sum
 
 
 @register_op("ssd_gates")
@@ -150,7 +149,8 @@ def _plan(P, N, r, chunk):
 
 
 def _kernels_run(P, N, r, chunk):
-    return _plan(P, N, r, chunk) == "kernel" and _backend_takes_kernels()
+    return _plan(P, N, r, chunk) == "kernel" \
+        and _kernels.backend_takes_kernels()
 
 
 class _Step:
@@ -381,7 +381,7 @@ def _ssd_call(kernel, name, X, Dt, A, Bm, Cm, D, more, out_shape, out_blocks,
         scratch_shapes=[pltpu.VMEM((r * P, N), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_kernels.interpret(),
     )(X.reshape(B, T, H * P), Bm.reshape(B, T, G * N),
       Cm.reshape(B, T, G * N), rows(L), cols(L), rows(dt), cols(dt), skip,
       *[v for _, v in more])

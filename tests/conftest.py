@@ -2,6 +2,7 @@
 sharding logic is exercised without TPU hardware (the driver separately
 dry-runs the multichip path)."""
 
+import fnmatch
 import os
 
 # PADDLE_TPU_TEST_ON_TPU=1 keeps the real chip — use it ONLY to run the
@@ -43,24 +44,19 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 
+def _chip_file(path):
+    """The chip's own test files are the glob `tests/test_*_tpu.py`: a new
+    one is selected under PADDLE_TPU_TEST_ON_TPU by its name alone."""
+    return fnmatch.fnmatch(os.path.basename(path), "test_*_tpu.py")
+
+
 def pytest_collection_modifyitems(config, items):
     if _ON_TPU and len(jax.devices()) < 8:
         skip = pytest.mark.skip(reason="PADDLE_TPU_TEST_ON_TPU: suite "
                                 "needs the 8-device virtual CPU mesh")
         for item in items:
             path = str(item.fspath)
-            if not any(t in path for t in ("test_flash_dropout_tpu",
-                                           "test_long_context_tpu",
-                                           "test_paged_attention_tpu",
-                                           "test_kernel_names_tpu",
-                                           "test_olmoe_tpu",
-                                           "test_ouro_tpu",
-                                           "test_flash_grad_tpu",
-                                           "test_gdn_kernels_tpu",
-                                           "test_causal_conv_kernels_tpu",
-                                           "test_rotary_kernels_tpu",
-                                           "test_gated_norm_kernels_tpu",
-                                           "test_ssd_kernels_tpu")):
+            if not _chip_file(path):
                 item.add_marker(skip)
     # under pytest-xdist, serialize each subprocess-spawning file into one
     # worker (`--dist loadgroup`): they fork whole jax worlds / embedded
@@ -77,7 +73,7 @@ def pytest_collection_modifyitems(config, items):
                 break
         # the TPU-gated files share ONE group: a chip belongs to one
         # process at a time
-        if "_tpu" in path:
+        if _chip_file(path):
             item.add_marker(pytest.mark.xdist_group("tpu"))
     # schedule the compile-heavy tests FIRST so a late-starting 300s test
     # can't extend the tail (xdist pops in collection order)

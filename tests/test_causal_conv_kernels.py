@@ -14,6 +14,7 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import layers, models
+from paddle_tpu.ops import _kernels
 from paddle_tpu.ops import linear_attention as la
 
 import qwen3_next_reference as ref
@@ -120,9 +121,9 @@ def test_plan_reads_the_shape_alone(t, c, k, plan):
 
 
 def test_a_cpu_backend_takes_the_kernels_only_when_interpreted(monkeypatch):
-    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(_kernels, "interpret", lambda: False)
     assert not la._conv_kernels_run(4096, 8192, 4)
-    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(_kernels, "interpret", lambda: True)
     assert la._conv_kernels_run(4096, 8192, 4)
     assert not la._conv_kernels_run(6, 3, 4)
 

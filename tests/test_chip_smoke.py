@@ -16,6 +16,7 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu.analysis import planner
 from paddle_tpu.core import executor
+from paddle_tpu.ops import _kernels
 from paddle_tpu.ops import paged_attention as pa
 from paddle_tpu.ops import pallas_attention as fa
 
@@ -71,10 +72,10 @@ def test_cpuplace_is_a_cpu_device():
 
 def test_interpreter_switch_is_refused_off_cpu(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-    assert fa._interpret() is True
+    assert _kernels.interpret() is True
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with pytest.raises(RuntimeError, match="CPU rehearsal switch"):
-        fa._interpret()
+        _kernels.interpret()
 
 
 def test_unsupported_kernel_shapes_raise_on_a_tpu_backend(monkeypatch):
@@ -111,3 +112,32 @@ def test_compile_cache_is_placed_by_one_rule(tmp_path):
     out = _run("-c", probe,
                env=dict(env, JAX_COMPILATION_CACHE_DIR=outside))
     assert out.stdout.strip().splitlines()[-1] == outside
+
+
+def test_a_new_chip_file_is_selected_on_the_chip_by_its_name(monkeypatch):
+    """No list names the chip's test files: under PADDLE_TPU_TEST_ON_TPU on
+    a one-chip backend `conftest.py` keeps every `tests/test_*_tpu.py`, one
+    that was never typed anywhere too, and skips the rest."""
+    import glob
+
+    import conftest
+
+    class Item:
+        def __init__(self, path):
+            self.fspath, self.name, self.marks = path, "test_it", []
+
+        def add_marker(self, mark):
+            self.marks.append(mark.name)
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    on_disk = sorted(glob.glob(os.path.join(here, "test_*_tpu.py")))
+    assert len(on_disk) >= 12
+    others = [os.path.join(here, name) for name in (
+        "test_olmoe.py", "test_tpu_place.py", "olmoe_tpu.py",
+        "test_olmoe_tpu_helpers.py")]
+    items = [Item(p) for p in on_disk + others
+             + [os.path.join(here, "test_never_typed_tpu.py")]]
+    monkeypatch.setattr(conftest, "_ON_TPU", True)
+    monkeypatch.setattr(conftest.jax, "devices", lambda: [object()])
+    conftest.pytest_collection_modifyitems(None, items)
+    assert {i.fspath for i in items if "skip" in i.marks} == set(others)

@@ -14,6 +14,7 @@ import paddle_tpu as fluid
 from paddle_tpu import layers, models
 from paddle_tpu.core import registry
 from paddle_tpu.core.lowering import FWD_OP_ATTR
+from paddle_tpu.ops import _kernels
 from paddle_tpu.ops import decoder_block as db
 
 from attention_program import kernel_calls, step_text
@@ -132,9 +133,9 @@ def test_plan_reads_shape_and_dtype_alone(shape, dtype, plan):
 
 def test_a_cpu_backend_takes_the_kernels_only_when_interpreted(monkeypatch):
     cell = ((1, 4096, 32, 128), jnp.dtype("bfloat16"))
-    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(_kernels, "interpret", lambda: False)
     assert not db._gated_norm_kernels_run(*cell)
-    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(_kernels, "interpret", lambda: True)
     assert db._gated_norm_kernels_run(*cell)
     assert not db._gated_norm_kernels_run((2, 7, 4, 8), jnp.dtype("float32"))
 

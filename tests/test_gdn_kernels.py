@@ -20,6 +20,7 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import layers, observe
+from paddle_tpu.ops import _kernels
 from paddle_tpu.ops import linear_attention as la
 
 import qwen3_next_reference as ref
@@ -249,9 +250,9 @@ def test_the_op_tallies_its_grid_steps_forward_and_grad(chunks, steps,
 
 
 def test_a_cpu_backend_takes_the_kernels_only_when_interpreted(monkeypatch):
-    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(_kernels, "interpret", lambda: False)
     assert not la._kernels_run(128, 128, 64)
-    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(_kernels, "interpret", lambda: True)
     assert la._kernels_run(128, 128, 64)
     assert not la._kernels_run(8, 8, 64)
 

@@ -23,7 +23,7 @@ from paddle_tpu.core import ir, registry
 
 import kanana2_reference as ref
 from test_olmoe import rel_err, run_piece
-from test_qwen3_next import OLMOE_DIGEST, _program_digest, frob
+from test_qwen3_next import frob
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -555,31 +555,15 @@ def test_every_layer_is_built_under_its_name_scopes(tiny):
 
 # -- the others are what they were -------------------------------------------------------------
 
-# taken again in PR 43, whose share emits one `grouped_matmul` for the gate
-# and the up projection and no `sum` of their input gradients: three ops
-# fewer in each of the four expert layers (542 before), nothing else
-QWEN3_NEXT_DIGEST = (530, "5b16301ff31786e7590009dfb89caaa9"
-                          "d5b90076495d979ca8117a8cbd8578ac")
-
-
 @pytest.mark.parametrize("model", ["olmoe", "qwen3_next"])
 def test_softmax_routed_programs_are_unchanged_op_for_op(model):
     """The router took a score function, a bias and a scaling factor,
     rotary an interleaved pairing and `fused_attention` a value width in
     this file's PR; a program that passes none of them is the program it
-    was: the digests were taken on the parent commit."""
-    from test_qwen3_next import TINY as QWEN3_NEXT_TINY
-    build, sizes, digest = {
-        "olmoe": (models.olmoe.build, dict(
-            vocab_size=128, seq_len=128, n_layer=2, d_model=64, n_head=2,
-            n_expert=8, top_k=2, d_expert=32), OLMOE_DIGEST),
-        "qwen3_next": (models.qwen3_next.build, QWEN3_NEXT_TINY,
-                       QWEN3_NEXT_DIGEST)}[model]
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        _, fetches = build(**sizes)
-        fluid.optimizer.Adam(learning_rate=1e-3).minimize(fetches["loss"])
-    assert _program_digest(main) == digest
+    was (`test_decoder_models.DIGESTS`)."""
+    from test_decoder_models import DIGESTS, build_program, program_digest
+    main, startup, _, _ = build_program(model)
+    assert program_digest(main, startup) == DIGESTS[model]
     routers = [o for o in main.global_block().ops if o.type == "moe_router"]
     assert routers and all(
         set(o.inputs) == {"X", "W"} and not

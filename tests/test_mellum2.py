@@ -25,9 +25,8 @@ from paddle_tpu.core import ir, registry
 from paddle_tpu.ops import decoder_block
 
 import mellum2_reference as ref
-from test_kanana2 import QWEN3_NEXT_DIGEST
 from test_olmoe import rel_err, run_piece
-from test_qwen3_next import OLMOE_DIGEST, _program_digest, frob
+from test_qwen3_next import frob
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -570,33 +569,15 @@ def test_amp_lists_hold_the_router_and_attention():
 
 # -- the others are what they were -------------------------------------------------------------
 
-# the shares' programs were taken again in PR 43 (`QWEN3_NEXT_DIGEST` in
-# `test_kanana2.py`: three ops fewer an expert layer; 328 before here)
-KANANA2_DIGEST = (322, "5748d09c7304bb28294cb2d2eea24d9c"
-                       "5e3033028eeb234e54bd6660f7dca1c3")
-
-
 @pytest.mark.parametrize("model", ["olmoe", "qwen3_next", "kanana2"])
 def test_programs_without_a_window_are_unchanged_op_for_op(model):
     """`fused_attention` took a window and `rotary_embedding` a scaling
     block in this file's PR; a program that passes neither is the program
-    it was, op for op and attribute for attribute: the digests were taken
-    on the parent commit."""
-    from test_kanana2 import TINY as KANANA2_TINY
-    from test_qwen3_next import TINY as QWEN3_NEXT_TINY
-    build, sizes, digest = {
-        "olmoe": (models.olmoe.build, dict(
-            vocab_size=128, seq_len=128, n_layer=2, d_model=64, n_head=2,
-            n_expert=8, top_k=2, d_expert=32), OLMOE_DIGEST),
-        "qwen3_next": (models.qwen3_next.build, QWEN3_NEXT_TINY,
-                       QWEN3_NEXT_DIGEST),
-        "kanana2": (models.kanana2.build, KANANA2_TINY, KANANA2_DIGEST),
-    }[model]
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        _, fetches = build(**sizes)
-        fluid.optimizer.Adam(learning_rate=1e-3).minimize(fetches["loss"])
-    assert _program_digest(main) == digest
+    it was, op for op and attribute for attribute
+    (`test_decoder_models.DIGESTS`)."""
+    from test_decoder_models import DIGESTS, build_program, program_digest
+    main, startup, _, _ = build_program(model)
+    assert program_digest(main, startup) == DIGESTS[model]
     for op in main.global_block().ops:
         assert "window" not in op.attrs and "scaling" not in op.attrs
 

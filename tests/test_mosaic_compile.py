@@ -13,6 +13,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.ops import _kernels
 from paddle_tpu.ops import linear_attention as la
 
 
@@ -48,7 +49,7 @@ def test_gdn_kernels_compile_at_the_cells_shapes(one_chip, monkeypatch):
     q, k `[1, 4096, 16, 128]`, v `[1, 4096, 32, 128]`, the chip's one-pass
     products; each is one Mosaic custom call whose first result is the one
     the benchmark's pattern knows."""
-    monkeypatch.setattr(la, "_on_chip", lambda: True)
+    monkeypatch.setattr(_kernels, "on_chip", lambda: True)
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
 
     def arg(shape, dtype):
@@ -195,7 +196,7 @@ def test_token_sum_compiles_at_the_cells_shapes(one_chip, monkeypatch, n, k,
     update. No `while` is left, and no float32 `[tokens, width]` array:
     nothing in HBM beside the result."""
     from paddle_tpu.ops import moe
-    monkeypatch.setattr(la, "_on_chip", lambda: True)
+    monkeypatch.setattr(_kernels, "on_chip", lambda: True)
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
     rows = n * k + held * moe.ROW_TILE
     assert moe._token_sum_plan(n, k, rows, width, jnp.bfloat16) \
@@ -271,7 +272,7 @@ def test_flash_kernels_compile_at_latent_attentions_widths(one_chip,
     kernel asks for; `Out` and `dV` leave at 128, `dQ` and `dK` at 192, and
     no 192-wide value is anywhere."""
     from paddle_tpu.ops import pallas_attention as pa
-    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    monkeypatch.setattr(_kernels, "interpret", lambda: False)
 
     def arg(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -307,7 +308,7 @@ def test_windowed_flash_kernels_compile_at_the_cells_shape(one_chip,
     maps with a clamp and a division in them. One Mosaic custom call each, under the names the
     benchmark's patterns tell from the full layer's."""
     from paddle_tpu.ops import pallas_attention as pa
-    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    monkeypatch.setattr(_kernels, "interpret", lambda: False)
 
     def arg(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -343,7 +344,7 @@ def test_windowed_flash_kernels_compile_at_a_window_of_2048(one_chip,
     before PR 49 measured both. The streaming forward and the fused
     backward, one Mosaic custom call each, under the windowed names."""
     from paddle_tpu.ops import pallas_attention as pa
-    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    monkeypatch.setattr(_kernels, "interpret", lambda: False)
     assert pa._blk(4096, True, 2048) == (512, 512)
     if tiles:
         monkeypatch.setattr(pa, "_BLOCK_OVERRIDE", tiles)
@@ -441,7 +442,7 @@ def test_flash_kernels_without_a_window_lower_to_the_text_they_did(
     MLIR included, debug locations left out, the scoped VMEM a kernel asks
     for among its parameters) has the recorded digest."""
     from paddle_tpu.ops import pallas_attention as pa
-    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    monkeypatch.setattr(_kernels, "interpret", lambda: False)
     qs, vs, causal, fwd_digest, bwd_digests = PLAIN_FLASH[case]
 
     def arg(shape, dtype=jnp.bfloat16):
@@ -476,7 +477,7 @@ def test_token_major_flash_kernels_compile_at_the_cells_shapes(
     layout: no transpose and no copy of an operand's size in either
     program."""
     from paddle_tpu.ops import pallas_attention as pa
-    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    monkeypatch.setattr(_kernels, "interpret", lambda: False)
     B, T, H, D = shape
 
     def arg(shape, dtype=jnp.bfloat16):
@@ -517,8 +518,7 @@ def test_sparse_attention_kernels_compile_at_the_cells_shapes(
     call each, named for the benchmark's patterns."""
     from paddle_tpu.ops import pallas_attention as pa
     from paddle_tpu.ops import sparse_attention as sa
-    monkeypatch.setattr(pa, "_interpret", lambda: False)
-    monkeypatch.setattr(sa, "_interpret", lambda: False)
+    monkeypatch.setattr(_kernels, "interpret", lambda: False)
 
     def arg(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -604,7 +604,7 @@ def test_nemotron_h_kernels_compile_at_the_cells_shapes(one_chip,
     scan's first result the saved states."""
     from paddle_tpu.ops import decoder_block as db
     from paddle_tpu.ops import state_space as ss
-    monkeypatch.setattr(la, "_on_chip", lambda: True)
+    monkeypatch.setattr(_kernels, "on_chip", lambda: True)
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
     assert ss._plan(64, 128, 8, 128) == "kernel"
 
@@ -695,7 +695,7 @@ def _lowered_expert_step(moe, one_chip, width, expert_size, gated, rows=2048):
 def megablox(monkeypatch):
     """`ops/moe.py` with the megablox kernels as its path, as on a chip."""
     from paddle_tpu.ops import moe
-    monkeypatch.setattr(la, "_on_chip", lambda: True)
+    monkeypatch.setattr(_kernels, "on_chip", lambda: True)
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
     assert moe._kernel() is not None
     return moe

@@ -29,7 +29,8 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import layers, models, observe
-from paddle_tpu.core import backward, ir, registry
+from paddle_tpu.core import ir, registry
+from paddle_tpu.observe import census
 from paddle_tpu.ops import decoder_block as db
 from paddle_tpu.ops import linear_attention as la
 from paddle_tpu.ops import state_space as ss
@@ -802,7 +803,7 @@ def test_layer_census_reads_the_issues_counts():
     scores and 4 bias updates."""
     main, _, _, _ = _program(fluid.optimizer.SGD(learning_rate=1e-3),
                              **CENSUS_SIZES)
-    got = backward.layer_census(main)
+    got = census.layer_census(main)
     assert got == CENSUS
     assert "attention_rotary_layers" not in got
     assert "dense_ffn_layers" not in got
@@ -876,7 +877,7 @@ def test_the_census_of_the_other_models_is_what_it_was(model, want):
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup), fluid.unique_name.guard():
         getattr(models, model).build(**sizes)
-    got = backward.layer_census(main)
+    got = census.layer_census(main)
     keys = ("attention_rotary_layers", "attention_unrotated_layers",
             "state_space_layers", "moe_expert_activation")
     assert {k: got[k] for k in keys if k in got} == want

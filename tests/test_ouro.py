@@ -15,7 +15,7 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu import layers, models, observe
 from paddle_tpu.core import ir
-from paddle_tpu.core.backward import parameter_sharing
+from paddle_tpu.observe.census import parameter_sharing
 
 import ouro_reference as ref
 

@@ -391,7 +391,7 @@ def test_cost_model_reads_the_keys_of_a_fused_attention_by_its_layout(
     """2 heads of 16 over 128 keys cost the same scores whichever way the
     operands lie: the sequence axis is the second of a "BTHD" op's and the
     third of a "BHTD" op's; so does the census' window test."""
-    from paddle_tpu.core import backward
+    from paddle_tpu.observe.census import layer_census
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup), fluid.unique_name.guard():
         q = layers.data(name="q", shape=shape, dtype="float32")
@@ -399,7 +399,7 @@ def test_cost_model_reads_the_keys_of_a_fused_attention_by_its_layout(
     cost = cost_model.estimate_cost(main, {"q": tuple([4] + shape)})
     assert cost.by_type()["fused_attention"]["flops"] == (
         (4.0 * 16 + 3.0) * 4 * 2 * 128 * 128)
-    census = backward.layer_census(main)
+    census = layer_census(main)
     assert census["attention_window"] == 64
     assert census["layer_kinds"]["window_attention"] == 1
 

@@ -6,7 +6,6 @@ recurrence, a loop over the held experts). Seeded random weights,
 float32, AMP off unless a test says otherwise."""
 
 import filecmp
-import hashlib
 import math
 import os
 
@@ -1189,31 +1188,13 @@ def test_a_log_starts_as_the_log_of_a_uniform_draw_and_dt_bias_at_one():
 
 # -- OLMoE is what it was ------------------------------------------------------------
 
-def _program_digest(main):
-    """The global block op for op: type, attributes (but the generated
-    names) and the shapes of what it writes."""
-    block = main.global_block()
-    lines = []
-    for op in block.ops:
-        attrs = sorted((k, repr(v)) for k, v in op.attrs.items()
-                       if not k.startswith("__") or k == "__role__")
-        outs = [tuple(block.var(n).shape) for n in op.output_arg_names
-                if block.has_var(n)]
-        lines.append(f"{op.type} {attrs} {outs}")
-    return len(lines), hashlib.sha256("\n".join(lines).encode()).hexdigest()
-
-
 def test_olmoe_program_is_unchanged_op_for_op():
     """The expert layer, the router, rms_norm and rotary_embedding took new
     attributes in this file's PR; a program that passes none of them is the
-    program it was: the digest was taken on the parent commit."""
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        feeds, fetches = models.olmoe.build(
-            vocab_size=128, seq_len=128, n_layer=2, d_model=64, n_head=2,
-            n_expert=8, top_k=2, d_expert=32)
-        fluid.optimizer.Adam(learning_rate=1e-3).minimize(fetches["loss"])
-    assert _program_digest(main) == OLMOE_DIGEST
+    program it was (`test_decoder_models.DIGESTS`)."""
+    from test_decoder_models import DIGESTS, build_program, program_digest
+    main, startup, feeds, fetches = build_program("olmoe")
+    assert program_digest(main, startup) == DIGESTS["olmoe"]
     # and its movements are the static ones: every row is an assignment
     scope = fluid.Scope()
     exe = fluid.Executor(fluid.CPUPlace())
@@ -1225,10 +1206,6 @@ def test_olmoe_program_is_unchanged_op_for_op():
     detail = observe.observatory().latest(main._uid).detail
     assert "moe_share_bounded_moves" not in detail
     assert "moe_row_buffer_rows" not in detail
-
-
-OLMOE_DIGEST = (207, "66f91545d51fe5df3453129dda39ac43"
-                     "d1e76220a3e88cb2fb176615ea2e6151")
 
 
 def test_the_two_copies_of_the_reference_are_identical():

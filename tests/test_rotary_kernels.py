@@ -13,6 +13,7 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import layers, models, observe
+from paddle_tpu.ops import _kernels
 from paddle_tpu.ops import decoder_block as db
 
 from attention_program import kernel_calls, step_text
@@ -135,9 +136,9 @@ def test_plan_reads_shape_and_dtype_alone(shape, dtype, R, interleaved,
 
 def test_a_cpu_backend_takes_the_kernel_only_when_interpreted(monkeypatch):
     q = ((1, 32, 8192, 128), jnp.dtype("bfloat16"), 128, False)
-    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(_kernels, "interpret", lambda: False)
     assert not db._rotary_kernel_runs(*q)
-    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(_kernels, "interpret", lambda: True)
     assert db._rotary_kernel_runs(*q)
     assert not db._rotary_kernel_runs((2, 2, 6, 8), jnp.dtype("float32"), 8,
                                       False)

@@ -583,8 +583,8 @@ def test_the_new_census_keys_on_the_other_models(model, want):
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup), fluid.unique_name.guard():
         getattr(models, model).build(**sizes)
-    from paddle_tpu.core import backward
-    got = backward.layer_census(main)
+    from paddle_tpu.observe import census
+    got = census.layer_census(main)
     new = ("attention_rotary_layers", "attention_unrotated_layers",
            "attention_gated_layers", "residual_out_norms")
     assert {k: got[k] for k in new if k in got} == want
