@@ -594,15 +594,11 @@ def estimate_peak_hbm(program: ir.Program,
 def xla_flops(exe, scope, feed_arrays) -> float:
     """Ground truth for the cross-check: FLOPs XLA counts for the largest
     step compiled in `exe` (the program must have run once with
-    `feed_arrays`). Same private-API dance as tools/_common.py's
-    compile_main_step, inlined so the package has no tools/ dependency."""
+    `feed_arrays`), lowered as the executor runs it (`lower_step`)."""
+    from ..core.executor import lower_step
     compiled = max(exe._cache.values(),
                    key=lambda c: len(c.program.global_block().ops))
-    mut = {n: scope.find_var(n) for n in compiled.mut_names}
-    const = {n: scope.find_var(n) for n in compiled.const_names}
-    feeds = {k: feed_arrays[k] for k in sorted(feed_arrays)}
-    ca = (compiled._step.lower(feeds, mut, const, np.uint32(0))
-          .compile().cost_analysis())
+    ca = lower_step(compiled, feed_arrays, scope).compile().cost_analysis()
     if isinstance(ca, (list, tuple)):   # older jax: one dict per partition
         ca = ca[0] if ca else {}
     return float(ca.get("flops", 0.0))

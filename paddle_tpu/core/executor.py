@@ -33,7 +33,7 @@ from . import ir, registry
 from .. import flags as _flags
 from ..observe import steplog as _steplog
 from ..observe.census import program_detail
-from .lowering import BlockLowerer
+from .lowering import BlockLowerer, cast_masters
 
 logger = logging.getLogger(__name__)
 
@@ -211,30 +211,45 @@ class _StateCache:
     """Scope-version-keyed cache of a compiled step's (mut, const) state
     gather. The gather is O(state vars) of find_var walks — pure per-step
     host overhead once the program is steady — so it is rebuilt only when
-    the scope tree reports a mutation the executor didn't make itself."""
+    the scope tree reports a mutation the executor didn't make itself.
+
+    Beside the gathered state it holds the step's AMP shadows (the bf16
+    form of the parameters `_CompiledProgram.shadow_names` lists, which the
+    step updates and reads in bf16): made from the gathered masters by one
+    jitted cast at every gather (the first run, and after any hand other
+    than the executor's own moved the scope: `set_var`,
+    `load_persistables`, another program's write-back), and from then on
+    the ones the step itself wrote. They are never in the `Scope`: nothing
+    saves, loads, lists or fetches them."""
 
     def __init__(self):
         self._entry = None
         self._version = -1
         self._mut: Optional[Dict[str, Any]] = None
         self._const: Optional[Dict[str, Any]] = None
+        self._shadows: Optional[Dict[str, Any]] = None
 
-    def get(self, entry: "_CompiledProgram", scope: Scope):
+    def get(self, entry: "_CompiledProgram", scope: Scope, device=None):
         if (entry is not self._entry or self._mut is None
                 or scope.version() != self._version):
             self._mut, self._const = entry.gather_state(scope)
+            with _step_device_ctx(device, entry):
+                self._shadows = entry.make_shadows(self._mut)
             self._entry = entry
-        return self._mut, self._const
+        return self._mut, self._const, self._shadows
 
-    def commit(self, entry: "_CompiledProgram", scope: Scope, new_state):
-        """Refresh after a step: the mut arrays were donated (dead); swap
-        in the step's outputs, then adopt the scope version the write-back
-        produced so our own set_var calls don't invalidate the cache."""
+    def commit(self, entry: "_CompiledProgram", scope: Scope, new_state,
+               new_shadows):
+        """Refresh after a step: the mut arrays and the shadows were
+        donated (dead); swap in the step's outputs, then adopt the scope
+        version the write-back produced so our own set_var calls don't
+        invalidate the cache."""
         mut = self._mut
         for n in entry.mut_names:
             v = new_state.get(n)
             if v is not None:
                 mut[n] = v
+        self._shadows = new_shadows
         self._version = scope.version()
 
 
@@ -361,14 +376,17 @@ def lower_step(entry: "_CompiledProgram", feeds: Dict[str, Any], scope: Scope):
     run gives the executable that runs: lowered under the context a run
     calls the step in (`_step_device_ctx`), jax finds the running step's
     own trace, lowering and executable in its in-process caches, and
-    nothing is compiled again. The one place the executors and the tools
-    get a step's text."""
+    nothing is compiled again. The step's AMP shadows are signed as the
+    masters they shadow (`abstract_shadows`). The one place the executors
+    and the tools get a step's text."""
     mut, const = entry.gather_state(scope)
+    mut = {n: _abstract(v) for n, v in mut.items()}
     return entry._step.lower(
         {n: _abstract(feeds[n]) for n in sorted(feeds)},
-        {n: _abstract(v) for n, v in mut.items()},
+        mut,
         {n: _abstract(v) for n, v in const.items()},
-        jax.ShapeDtypeStruct((), np.uint32))
+        jax.ShapeDtypeStruct((), np.uint32),
+        entry.abstract_shadows(mut))
 
 
 def offer_step_text(entry: "_CompiledProgram", feed_arrays: Dict[str, Any],
@@ -423,6 +441,11 @@ def step_key(program, feeds, fetches, scope, amp, check_nan_inf, copts,
                    tuple(fetches), scope._uid, amp, check_nan_inf,
                    tuple(sorted(copts.items())) if copts else None,
                    program.random_seed, mesh)
+
+
+@jax.jit
+def _cast_bf16(masters: Dict[str, Any]) -> Dict[str, Any]:
+    return {n: v.astype(jnp.bfloat16) for n, v in masters.items()}
 
 
 def _fetch_names(fetch_list) -> Tuple[str, ...]:
@@ -507,6 +530,18 @@ class _CompiledProgram:
                 f"in the scope — run the startup program first")
         self.mut_names = [n for n in read if n in set(written)]
         self.const_names = [n for n in read if n not in set(written)]
+        # AMP's shadows: the float32 parameters the step updates and whose
+        # bf16 form it carries (`lowering.cast_masters`, from the Program)
+        self.shadow_names = cast_masters(program, self.mut_names) \
+            if key.amp else []
+        if event is not None and self.shadow_names:
+            held = [scope.find_var(n) for n in self.shadow_names]
+            event.detail.update(
+                amp_shadowed_params=len(held),
+                amp_shadowed_mb=round(
+                    sum(2 * int(np.prod(np.shape(v))) for v in held) / 1e6,
+                    3),
+                amp_plain_master_casts=0)
 
         seed = key.seed if key.seed is not None else 0
         # unseeded programs additionally fold in their executor-local
@@ -522,7 +557,7 @@ class _CompiledProgram:
         uid_mix = None if key.seed is not None or not rng_stream \
             else np.uint32(rng_stream)
 
-        def step(feeds, mut_state, const_state, counter):
+        def step(feeds, mut_state, const_state, counter, shadows=None):
             # key derivation INSIDE the jit: an eager fold_in would
             # dispatch 2-4 tiny device programs per run (visible in the
             # profiler as jit__threefry_* modules), pure host overhead
@@ -534,6 +569,7 @@ class _CompiledProgram:
             env.update(mut_state)
             env.update(feeds)
             lowerer.nan_flags = []
+            lowerer.enter_step(mut_state, shadows)
             lowerer.run_block(0, env, key)
             fetches = [env[n] for n in self.fetch_names]
             new_state = {n: env[n] for n in written if n in env}
@@ -542,10 +578,12 @@ class _CompiledProgram:
             self._nan_meta = [(t, n) for t, n, _ in lowerer.nan_flags]
             flags = ([f for _, _, f in lowerer.nan_flags]
                      if lowerer.check_nan_inf else [])
-            return fetches, new_state, flags
+            return fetches, new_state, flags, lowerer.shadows
 
+        # the shadows are donated with the state they shadow: the update
+        # writes the next ones over them
         self._step = jax.jit(step,
-                             donate_argnums=(1,) if donation_safe() else (),
+                             donate_argnums=(1, 4) if donation_safe() else (),
                              compiler_options=dict(key.copts or ()) or None)
 
     def gather_state(self, scope: Scope):
@@ -553,14 +591,31 @@ class _CompiledProgram:
         const = {n: scope.find_var(n) for n in self.const_names}
         return mut, const
 
-    def run_with_state(self, scope: Scope, feeds, mut, const, counter,
-                       spans=None):
+    def make_shadows(self, mut: Dict[str, Any]) -> Dict[str, Any]:
+        """The shadows of a freshly gathered `mut`: one jitted cast of the
+        masters (under a mesh each takes its master's sharding), and
+        nothing for a step that shadows none."""
+        if not self.shadow_names:
+            return {}
+        return _cast_bf16({n: mut[n] for n in self.shadow_names})
+
+    def abstract_shadows(self, mut_avals: Dict[str, Any]) -> Dict[str, Any]:
+        """What the step's cache key sees of the shadows, from that of the
+        masters (`_abstract`)."""
+        return {n: jax.ShapeDtypeStruct(mut_avals[n].shape, jnp.bfloat16,
+                                        sharding=mut_avals[n].sharding)
+                for n in self.shadow_names}
+
+    def run_with_state(self, scope: Scope, feeds, mut, const, shadows,
+                       counter, spans=None):
         """One step against pre-gathered state dicts; returns (fetches,
-        new_state) so callers holding a state cache can refresh their mut
-        entries (the mut arrays were donated to XLA and are dead after the
-        call). `spans` (a `steplog.RunSpans` in its jit_call phase) moves
-        to write_back once the jitted step has returned."""
-        fetches, new_state, flags = self._step(feeds, mut, const, counter)
+        new_state, new_shadows) so callers holding a state cache can
+        refresh their mut entries and shadows (the mut arrays and the
+        shadows were donated to XLA and are dead after the call). `spans`
+        (a `steplog.RunSpans` in its jit_call phase) moves to write_back
+        once the jitted step has returned."""
+        fetches, new_state, flags, new_shadows = self._step(
+            feeds, mut, const, counter, shadows)
         if spans is not None:
             spans.phase(_steplog.WRITE_BACK)
         # bulk write-back: one dict update + one version bump (set_var per
@@ -577,7 +632,7 @@ class _CompiledProgram:
                     f"NaN/Inf detected in output {var!r} of op "
                     f"{op_type!r} (check_nan_inf mode; reference "
                     f"CheckTensorNANOrInf, operator.cc:622)")
-        return fetches, new_state
+        return fetches, new_state, new_shadows
 
 
 # leak backstop for the per-program uid maps (run counters / rng ordinals):
@@ -792,19 +847,22 @@ class PreparedProgram:
             spans.event = entry.event
             spans.phase(_steplog.STATE_GATHER)
             counter = self._exe._count_run(program._uid)
-            mut, const = self._state.get(entry, self.scope)
+            mut, const, shadows = self._state.get(entry, self.scope,
+                                                  self._device)
             # jit_call ends when the jitted step returns (under async
             # dispatch the device runs on); run_with_state opens
             # write_back before its scope update
             spans.phase(_steplog.JIT_CALL)
             if self._use_device_ctx:
                 with jax.default_device(self._device):
-                    fetches, new_state = entry.run_with_state(
-                        self.scope, feed_arrays, mut, const, counter, spans)
+                    fetches, new_state, shadows = entry.run_with_state(
+                        self.scope, feed_arrays, mut, const, shadows,
+                        counter, spans)
             else:
-                fetches, new_state = entry.run_with_state(
-                    self.scope, feed_arrays, mut, const, counter, spans)
-            self._state.commit(entry, self.scope, new_state)
+                fetches, new_state, shadows = entry.run_with_state(
+                    self.scope, feed_arrays, mut, const, shadows, counter,
+                    spans)
+            self._state.commit(entry, self.scope, new_state, shadows)
             if return_numpy:
                 # the host transfer np.asarray forces; no span with
                 # return_numpy=False — the async-dispatch overlap the fast

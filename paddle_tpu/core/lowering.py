@@ -58,6 +58,54 @@ class BlockLowerer:
         self.detail: dict = detail if detail is not None else {}
         # what each op counted there, by key (`LoweringContext.tally`)
         self.tallies: Dict[str, dict] = {}
+        # AMP's shadows, for the length of one trace of a step
+        # (`enter_step`): name -> the bf16 form of a float32 parameter the
+        # step updates, and name -> the float32 value that form was made
+        # from; and where `master_as` cast one of them the plain way
+        self.shadows: Dict[str, Any] = {}
+        self._shadow_source: Dict[str, Any] = {}
+        self._plain_casts: Set[tuple] = set()
+
+    # -- AMP shadows -----------------------------------------------------
+    def enter_step(self, mut_state: Dict[str, Any],
+                   shadows: Optional[Dict[str, Any]]):
+        """Start one trace of the step: `shadows` are the bf16 forms the
+        step was entered with, of the masters in `mut_state` of the same
+        names (none: a step lowered without them casts as it always did)."""
+        self.shadows = dict(shadows or {})
+        self._shadow_source = {n: mut_state[n] for n in self.shadows}
+
+    def shadow_of(self, name, env, value):
+        """The shadow of the variable `name`, if the step carries one and
+        `env` still binds the name to the value it was made from (the one
+        the step was entered with, or the block's last write); else None:
+        a sub-block's carried value, a name the rule was not handed by."""
+        shadow = self.shadows.get(name)
+        if shadow is None or env is None \
+                or env.get(name) is not self._shadow_source[name] \
+                or shadow.shape != value.shape:
+            return None
+        return shadow
+
+    def note_plain_cast(self, name, site):
+        """`registry.master_as` cast the float32 variable `name` the plain
+        way at `site`: counted, once however often the step is traced, if
+        the step carries a shadow of it. A step lowered without its
+        shadows counts nothing."""
+        if name in self.shadows:
+            self._plain_casts.add(site)
+            self.detail["amp_plain_master_casts"] = len(self._plain_casts)
+
+    def _reshadow(self, op: ir.Operator, env: Dict[str, Any]):
+        """After a top-level op: the next step's shadow of every shadowed
+        variable the op wrote, cast where it is written (inside the op's
+        named scope, so the convert joins the update's own fusion and
+        carries its name). The block's last write wins."""
+        for name in op.output_arg_names:
+            if name in self.shadows \
+                    and env[name] is not self._shadow_source[name]:
+                self._shadow_source[name] = env[name]
+                self.shadows[name] = env[name].astype(jnp.bfloat16)
 
     def run_block(self, block_idx: int, env: Dict[str, Any], key) -> Dict[str, Any]:
         """Execute all ops of `block_idx` on `env` (name -> jnp array),
@@ -80,16 +128,21 @@ class BlockLowerer:
             # compiled module and a device trace, from the forward op's
             with _named_scope(op.type, op.attrs[FWD_OP_ATTR]["attrs"]):
                 self._run_grad_op(block, op, env, key)
+                if self.shadows and self._block_depth == 1:
+                    self._reshadow(op, env)
             if self.check_nan_inf and self._block_depth == 1:
                 self._record_nan_flags_env(op, env)
             return
         opdef = registry.get_op_def(op.type)
         op_key = jax.random.fold_in(key, _op_seed(op, op_idx)) if opdef.needs_rng else None
         ins = _gather_inputs(op.inputs, env, op.type)
-        ctx = LoweringContext(op.attrs, key=op_key, lowerer=self, op=op, env=env)
+        ctx = LoweringContext(op.attrs, key=op_key, lowerer=self, op=op,
+                              env=env, inputs=op.inputs)
         with _named_scope(op.type, op.attrs):
             outs = registry.call_rule(opdef, ctx, ins)
-        _scatter_outputs(op, outs, env)
+            _scatter_outputs(op, outs, env)
+            if self.shadows and self._block_depth == 1:
+                self._reshadow(op, env)
         if opdef.propagate_seqlen:
             _propagate_seqlen(op, env)
         if self.check_nan_inf and self._block_depth == 1:
@@ -133,7 +186,8 @@ class BlockLowerer:
             # of recomputing
             fwd_outs = {slot: [env.get(n) for n in names]
                         for slot, names in fwd_outputs.items()}
-            ctx = LoweringContext(fwd_attrs, key=op_key, lowerer=self, op=op)
+            ctx = LoweringContext(fwd_attrs, key=op_key, lowerer=self, op=op,
+                                  inputs=fwd_inputs, block_env=env)
             ctx.fwd_outs = fwd_outs
             grads = opdef.grad_lower(ctx, ins, out_grads)
             _write_input_grads(op, fwd_inputs, grads, env)
@@ -167,7 +221,8 @@ class BlockLowerer:
             for (slot, pos, name), v in zip(diff_entries, vals):
                 ins[slot][pos] = v
                 env2[name] = v
-            ctx = LoweringContext(fwd_attrs, key=op_key, lowerer=self, env=env2)
+            ctx = LoweringContext(fwd_attrs, key=op_key, lowerer=self,
+                                  env=env2, inputs=fwd_inputs, block_env=env)
             outs = registry.call_rule(opdef, ctx, ins)
             flat = []
             for slot, names in out_slots:
@@ -201,6 +256,38 @@ class BlockLowerer:
         for name, g in acc.items():
             if name in declared_by_base:
                 env[declared_by_base[name]] = g
+
+
+def cast_masters(program: ir.Program, mut_names: Sequence[str]) -> List[str]:
+    """Which of `mut_names` (the persistable variables a step updates) get
+    a shadow under AMP, read off the Program alone: a float32 variable that
+    an op of `registry.AMP_SHADOW_OPS` (or its grad op) reads, in any
+    block, and whose bf16 form is at least `AMP_SHADOW_MIN_BYTES` (why
+    those: the comment over them). Dense weights, embedding tables, norm
+    and router weights, biases and small expert stacks get none: they are
+    cast where they are read."""
+    mut = set(mut_names)
+    block = program.global_block()
+    found: List[str] = []
+    for blk in program.blocks:
+        for op in blk.ops:
+            fwd = op.attrs.get(FWD_OP_ATTR) \
+                if op.type.endswith(GRAD_OP_SUFFIX) else None
+            op_type, inputs = (fwd["type"], fwd["inputs"]) if fwd \
+                else (op.type, op.inputs)
+            if op_type not in registry.AMP_SHADOW_OPS:
+                continue
+            for names in inputs.values():
+                for n in names:
+                    if n not in mut or n in found:
+                        continue
+                    var = block._find_var_recursive(n)
+                    if var is not None and var.dtype \
+                            and jnp.dtype(var.dtype) == jnp.float32 \
+                            and 2 * int(np.prod(var.shape)) \
+                            >= registry.AMP_SHADOW_MIN_BYTES:
+                        found.append(n)
+    return found
 
 
 def _named_scope(op_type: str, attrs: Dict[str, Any]):

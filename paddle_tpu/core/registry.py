@@ -131,12 +131,19 @@ class LoweringContext:
     dim_sentinel = DIM_SENTINEL
 
     def __init__(self, attrs: Dict[str, Any], key=None, lowerer=None, op=None,
-                 env=None):
+                 env=None, inputs=None, block_env=None):
         self.attrs = attrs
         self.key = key
         self.lowerer = lowerer   # BlockLowerer, for control-flow sub-blocks
         self.op = op
         self.env = env           # live env dict (control-flow ops only)
+        # {slot: names} of the variables whose values the rule is handed
+        # (a grad op's: its forward op's inputs), and the env of the block
+        # the op stands in, which binds them: `env` itself, except under
+        # the generic grad's `jax.vjp`, where `env` is a copy patched with
+        # the vjp's own tracers. `master_as` looks a master up by them.
+        self.inputs = inputs
+        self.block_env = block_env if block_env is not None else env
 
     def attr(self, name: str, default=None):
         return self.attrs.get(name, default)
@@ -169,12 +176,38 @@ class LoweringContext:
 # flow through the network in bf16 and never round-trip f32 in HBM (a cast
 # feeding a conv cannot fuse on TPU, so per-op up/down-casts cost a full
 # read+write of every activation).  Numerically sensitive ops upcast bf16
-# inputs to f32.  Everything else runs in whatever dtype reaches it; the
-# f32 master params are cast at their point of use, so the vjp delivers
-# f32 grads to the optimizer automatically.
+# inputs to f32.  Everything else runs in whatever dtype reaches it.  The
+# f32 master params become bf16 in one place, `master_as`, at their point
+# of use, so the vjp delivers f32 grads to the optimizer.  One kind of
+# parameter is not cast there at all: a large expert stack that a Pallas
+# call reads (AMP_SHADOW_OPS and AMP_SHADOW_MIN_BYTES below), whose cast XLA
+# can neither fold into its consumer nor keep in fast memory, a whole-array
+# pass of 4 bytes read and 2 written a parameter a step.  The step carries
+# that parameter's bf16 form (its "shadow") from one run to the next beside
+# the donated state (core/executor.py::_StateCache), the op that writes the
+# parameter writes the next shadow in the same fusion
+# (lowering.py::BlockLowerer._reshadow), and `master_as` hands the shadow
+# out under a `custom_vjp` whose backward is the cast's own transpose.
 AMP_BF16_OPS = frozenset({"conv2d", "depthwise_conv2d", "conv2d_transpose",
                           "mul", "matmul", "lstm", "gru", "fc",
                           "fused_attention", "grouped_matmul"})
+# Which parameters get a shadow (lowering.py::cast_masters reads both off the
+# Program). The consumer: an op of AMP_BF16_OPS whose rule hands the bf16
+# form to a Pallas call. XLA cannot fold the cast into a custom call, so it
+# is a pass of its own over the whole array; a dot or a convolution takes
+# the cast into its own fusion or keeps no bf16 copy from forward to
+# backward, so a dense parameter's shadow is 2 bytes a parameter of HBM that
+# nothing held before (+0.33 to +0.57 GB in three cells for 0.2-1.2% of
+# step: PERF.md section 6, PR 59). The size: a bf16 form that the chip's
+# fast memory cannot hold (128 MiB of VMEM on a v5e). XLA casts a smaller
+# stack straight into fast memory, for the 4 bytes a parameter the cast
+# reads, and the kernels read it from there; its shadow lives in HBM, is
+# read from HBM by both products and costs the update 2 bytes more
+# (Nemotron-3-Nano's 80 MB stacks: +0.92 ms on a 60.96 ms step). A larger
+# one is cast HBM to HBM, 6 bytes a parameter, every step (OLMoE's 268 MB
+# stacks: 1.22 ms each), which is what its shadow takes out.
+AMP_SHADOW_OPS = frozenset({"grouped_matmul"})
+AMP_SHADOW_MIN_BYTES = 128 << 20
 # NOTE: plain `softmax` deliberately NOT f32-listed: jax.nn.softmax is
 # max-subtracted so bf16 is safe, and an f32 round trip on [B,H,T,T]
 # attention weights doubles the dominant HBM traffic of unfused attention.
@@ -225,6 +258,41 @@ def _cast_to(v, dt_from, dt_to):
     return v
 
 
+@jax.custom_vjp
+def _shadowed(master, shadow):
+    """`master.astype(bf16)`, read from `shadow`, which holds it already."""
+    return shadow
+
+
+_shadowed.defvjp(lambda master, shadow: (shadow, None),
+                 lambda _, g: (g.astype(jnp.float32), None))
+
+
+def master_as(ctx: LoweringContext, slot: str, pos: int, v, dtype):
+    """`v`, the value a rule was handed for input `pos` of `slot`, in
+    `dtype`. The one place a float32 master becomes bf16: `amp_cast` and
+    the registered grads that cast their masters themselves come through
+    here. Where the step carries a shadow of the variable of that name and
+    the block still binds the name to the value the shadow was made from
+    (`BlockLowerer.shadow_of`: by name, since the generic grad re-traces
+    the forward rule on `jax.vjp`'s own tracers), the shadow is the result
+    and the gradient reaches the master as the cast's transpose delivers
+    it. Anything else is a plain cast; one of a shadowed variable is
+    counted on the step's compile event (`amp_plain_master_casts`)."""
+    if not hasattr(v, "dtype") or v.dtype == dtype:
+        return v
+    lowerer = ctx.lowerer
+    if (lowerer is not None and ctx.inputs is not None
+            and v.dtype == jnp.float32 and dtype == jnp.bfloat16):
+        names = ctx.inputs.get(slot, ())
+        name = names[pos] if pos < len(names) else None
+        shadow = lowerer.shadow_of(name, ctx.block_env, v)
+        if shadow is not None:
+            return _shadowed(v, shadow)
+        lowerer.note_plain_cast(name, (id(ctx.inputs), slot, pos))
+    return v.astype(dtype)
+
+
 def amp_cast(opdef: OpDef, ctx: LoweringContext,
              ins_by_slot: Dict[str, List[Any]]) -> Dict[str, List[Any]]:
     """`ins_by_slot` as AMP hands it to the op's rule: float32 -> bf16 for
@@ -234,18 +302,24 @@ def amp_cast(opdef: OpDef, ctx: LoweringContext,
     it itself, so both see the same dtypes."""
     if ctx.lowerer is None or not getattr(ctx.lowerer, "amp", False):
         return ins_by_slot
-    to_bf16 = opdef.type in AMP_BF16_OPS
-    to_f32 = opdef.type in AMP_F32_OPS
-    if not to_bf16 and not to_f32 and opdef.type in AMP_DOWNCAST_OPS:
+    if opdef.type in AMP_BF16_OPS:
+        # an MXU op's operands: the masters among them through `master_as`
+        return {slot: [master_as(ctx, slot, i, v, jnp.bfloat16)
+                       if getattr(v, "dtype", None) == jnp.float32 else v
+                       for i, v in enumerate(vals)]
+                for slot, vals in ins_by_slot.items() if vals}
+    if opdef.type in AMP_F32_OPS:
+        pair = (jnp.bfloat16, jnp.float32)
+    elif opdef.type in AMP_DOWNCAST_OPS:
         dtypes = {jnp.dtype(v.dtype)
                   for vals in ins_by_slot.values() for v in vals
                   if hasattr(v, "dtype")}
-        to_bf16 = (jnp.dtype(jnp.bfloat16) in dtypes
-                   and jnp.dtype(jnp.float32) in dtypes)
-    if to_bf16:
+        if not (jnp.dtype(jnp.bfloat16) in dtypes
+                and jnp.dtype(jnp.float32) in dtypes):
+            return ins_by_slot
+        # the float32 side of a mixed elementwise op: the cast fuses into
+        # the op itself, so there is no pass over the array to take out
         pair = (jnp.float32, jnp.bfloat16)
-    elif to_f32:
-        pair = (jnp.bfloat16, jnp.float32)
     else:
         return ins_by_slot
     return {slot: [_cast_to(v, *pair) for v in vals]

@@ -119,7 +119,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..core.registry import register_grad, register_op
+from ..core.registry import master_as, register_grad, register_op
 from . import _kernels
 
 # Rows a group is padded to in `moe_dispatch`, and the row tile of the
@@ -639,20 +639,22 @@ def _grouped_dot_grads(x, w, g, sizes):
 @register_grad("grouped_matmul")
 def _grouped_matmul_grad(ctx, ins, out_grads):
     """dX = rows of dOut times W[e]^T; dW[e] = X_e^T dOut_e. The grad op sees
-    the scope's values, so the float32 master weights are cast here, as
-    AMP_BF16_OPS casts them for the forward rule. With several `W` dX is
-    the sum of their parts over the rows that `GroupSizes` uses, added in
+    the scope's values, so the float32 master weights become bf16 here, as
+    AMP_BF16_OPS has them for the forward rule: through `master_as`, which
+    hands out the step's shadow of a stack where it carries one and casts
+    nothing then. With several `W` dX is the sum of their parts over the rows that `GroupSizes` uses, added in
     place and in dX's dtype, as the `sum` op would add them (the op counts
     itself, `moe_share_bounded_ops`): the rows behind them are not
     visited. A stack the kernels take through its transpose is counted
     here as in the forward op (`moe_lane_major_stacks`)."""
     X, sizes = ins["X"][0], ins["GroupSizes"][0].astype(jnp.int32)
     d_x, d_ws = None, []
-    for W, g in zip(ins["W"], out_grads["Out"]):
+    for i, (W, g) in enumerate(zip(ins["W"], out_grads["Out"])):
         if g is None:
             d_ws.append(None)
             continue
-        part, d_w = _grouped_dot_grads(X.astype(g.dtype), W.astype(g.dtype),
+        part, d_w = _grouped_dot_grads(X.astype(g.dtype),
+                                       master_as(ctx, "W", i, W, g.dtype),
                                        g, sizes)
         part = part.astype(X.dtype)
         if d_x is None:

@@ -645,11 +645,15 @@ def test_nemotron_h_kernels_compile_at_the_cells_shapes(one_chip,
     assert "= (bf16[1,2048,4096]{" in call
 
 
-def _expert_step(moe, gated):
+def _expert_step(moe, gated, shadowed=False):
     """One expert layer's step on `_grouped_dot` / `_grouped_dot_grads` as
     the Program runs them under AMP: float32 stacks cast to bf16 at their
     use, the products and their gradients, Adam on each stack with its two
-    moments. relu² over one first stack, or gated silu over two."""
+    moments. relu² over one first stack, or gated silu over two. `shadowed`:
+    as the executor runs a step with AMP's shadows (PR 59), the stacks'
+    bf16 forms come in behind the state (one a stack, in the stacks' order)
+    and the update writes the next ones, which go out behind the new
+    state."""
     bf16, f32 = jnp.bfloat16, jnp.float32
 
     def adam(p, g, m, v):
@@ -657,8 +661,13 @@ def _expert_step(moe, gated):
         return p - 1e-3 * m / (jnp.sqrt(v) + 1e-8), m, v
 
     def step(x, sizes, g, *state):
-        firsts = [w.astype(bf16) for w in state[:-3:3]]
-        down = state[-3].astype(bf16)
+        if shadowed:
+            stacks = len(state) // 4
+            state, shadows = state[:3 * stacks], state[3 * stacks:]
+            firsts, down = list(shadows[:-1]), shadows[-1]
+        else:
+            firsts = [w.astype(bf16) for w in state[:-3:3]]
+            down = state[-3].astype(bf16)
         hs = [moe._grouped_dot(x, w, sizes).astype(f32) for w in firsts]
         if gated:
             act, d_act = jax.vjp(lambda a, b: jax.nn.silu(a) * b, *hs)
@@ -674,19 +683,26 @@ def _expert_step(moe, gated):
             grads.append(d_w)
         new = [adam(state[3 * i], d.astype(f32), *state[3 * i + 1:3 * i + 3])
                for i, d in enumerate(grads + [d_down])]
-        return (y, d_x) + tuple(a for triple in new for a in triple)
+        out = (y, d_x) + tuple(a for triple in new for a in triple)
+        if shadowed:
+            out += tuple(p.astype(bf16) for p, _, _ in new)
+        return out
 
     return step
 
 
-def _lowered_expert_step(moe, one_chip, width, expert_size, gated, rows=2048):
+def _lowered_expert_step(moe, one_chip, width, expert_size, gated, rows=2048,
+                         shadowed=False):
     def arg(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     first, down = (8, width, expert_size), (8, expert_size, width)
     state = [arg(first)] * (6 if gated else 3) + [arg(down)] * 3
+    if shadowed:
+        state += [arg(first, jnp.bfloat16)] * (2 if gated else 1) \
+            + [arg(down, jnp.bfloat16)]
     x = arg((rows, width), jnp.bfloat16)
-    return jax.jit(_expert_step(moe, gated),
+    return jax.jit(_expert_step(moe, gated, shadowed),
                    donate_argnums=tuple(range(3, 3 + len(state)))).lower(
                        x, arg((8,), jnp.int32), x, *state)
 
@@ -728,6 +744,48 @@ def test_two_matrix_experts_step_copies_no_stack(one_chip, megablox):
     # nor a bf16 one: the cast fuses with the bitcast
     assert not re.findall(
         r"= bf16\[8,(?:2688,1856|1856,2688)\]\{[^}]*\} copy\(", text)
+
+
+def test_two_matrix_experts_step_with_shadows_copies_no_stack(one_chip,
+                                                              megablox):
+    """The same E layer's step with AMP's shadows (PR 59): the bf16 forms
+    of `up` and `down` come in as arguments of their own (donated) and the
+    update writes the next ones. `_held_lane_major` reads the shape alone,
+    so what it says of `f32[8,2688,1856]` has to hold of the bf16 array
+    too: the TPU client holds `bf16[8,2688,1856]`, argument and result,
+    with the 2688 axis minor-most as it holds the float32 one, the kernels
+    take it through its transpose, and no copy of a stack, float32 or
+    bf16, is in the step; nor is a pass that casts a float32 stack that
+    came in: the only converts to a stack's bf16 shape are the update's
+    own, fused with it. (These 80 MB stacks are under
+    `registry.AMP_SHADOW_MIN_BYTES`, so the cell's step carries none; the
+    layout question is the shape's, whatever the number of experts.)"""
+    moe = megablox
+    assert moe._held_lane_major(jnp.zeros((8, 2688, 1856), jnp.bfloat16))
+    assert not moe._held_lane_major(jnp.zeros((8, 1856, 2688), jnp.bfloat16))
+    compiled = _lowered_expert_step(moe, one_chip, 2688, 1856, gated=False,
+                                    shadowed=True).compile()
+    text = compiled.as_text()
+    (ins, outs), = re.findall(
+        r"entry_computation_layout=\{\((.*?)\)->\((.*?)\)\}, allow_spmd", text)
+    for layout in (ins, outs):
+        assert layout.count("f32[8,2688,1856]{1,2,0:") == 3
+        assert layout.count("f32[8,1856,2688]{2,1,0:") == 3
+        assert layout.count("bf16[8,2688,1856]{1,2,0:") == 1
+        assert layout.count("bf16[8,1856,2688]{2,1,0:") == 1
+    assert len(_custom_calls(compiled, "gmm")) == 4
+    assert len(_custom_calls(compiled, "tgmm")) == 2
+    stack = r"\[8,(?:2688,1856|1856,2688)\]\{[^}]*\}"
+    assert not re.findall(rf"= (?:f32|bf16){stack} copy\(", text)
+    # the only converts to a stack's bf16 shape are the update's, one a
+    # stack, of the parameter it has just computed (never of a parameter
+    # of the computation: a float32 stack that came in), in the fusion
+    # that writes that parameter and its moments
+    casts = re.findall(rf"= bf16{stack} convert\(%?([\w.-]+)\)", text)
+    assert len(casts) == 2 and all(c.startswith("sub") for c in casts), casts
+    assert len(re.findall(
+        rf"ROOT %?[\w.-]+ = \(bf16{stack}(?:, f32{stack}){{3}}\) tuple\(",
+        text)) == 2
 
 
 # the step of a gated-silu layer at Kanana-2's and Keye-VL-2's widths

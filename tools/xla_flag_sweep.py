@@ -301,22 +301,24 @@ def _make_lowered_runner(exe, scope, batch):
     deleted arrays (each call invalidates the buffers it was handed)."""
     compiled = max(exe._cache.values(),
                    key=lambda c: len(c.program.global_block().ops))
-    mut0 = {n: scope.find_var(n) for n in compiled.mut_names}
-    const = {n: scope.find_var(n) for n in compiled.const_names}
+    mut0, const = compiled.gather_state(scope)
     feeds = {k: batch[k] for k in sorted(batch)}
-    lowered = compiled._step.lower(feeds, mut0, const, np.uint32(0))
-    state = {"mut": dict(mut0)}
+    # the step as the executor runs it: with its AMP shadows, donated too
+    state = {"mut": dict(mut0), "shadows": compiled.make_shadows(mut0)}
+    lowered = compiled._step.lower(feeds, mut0, const, np.uint32(0),
+                                   state["shadows"])
 
     def make_window(c):
         def window(n):
-            mut = state["mut"]
+            mut, shadows = state["mut"], state["shadows"]
             t0 = time.perf_counter()
             for _ in range(n):
-                fetches, new_state, _ = c(feeds, mut, const, np.uint32(0))
+                fetches, new_state, _, shadows = c(feeds, mut, const,
+                                                   np.uint32(0), shadows)
                 mut = {k: new_state[k] for k in mut}
             np.asarray(fetches[0])
             dt = time.perf_counter() - t0
-            state["mut"] = mut
+            state["mut"], state["shadows"] = mut, shadows
             return dt
 
         return window
