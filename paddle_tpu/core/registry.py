@@ -238,7 +238,12 @@ AMP_F32_OPS = frozenset({"log_softmax", "cross_entropy",
                          # from a bf16 projection, likewise; `ssd_scan`
                          # keeps its sums, decays and state in float32
                          # inside its rule
-                         "ssd_gates"})
+                         "ssd_gates",
+                         # a KDA layer's log-decay per key channel and write
+                         # strength from two bf16 projections: exp and both
+                         # sigmoids in float32; `kda_delta_rule` keeps its
+                         # sums, norms and state in float32 inside its rule
+                         "kda_gates"})
 # Mixed-dtype elementwise ops downcast the f32 side to bf16 instead of
 # letting numpy promotion upcast the bf16 side: one f32 mask/bias/table
 # leaking into the residual or attention-score stream would otherwise

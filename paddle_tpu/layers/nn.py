@@ -1353,10 +1353,13 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, zero_centered=False,
 
 
 def gated_rms_norm(input, gate, epsilon=1e-6, param_attr=None, name=None,
-                   gate_first=False, group_size=None):
+                   gate_first=False, group_size=None, activation="silu"):
     """`x * rsqrt(mean(x^2) + epsilon) * w * silu(gate)` over the last axis,
     `gate` of `input`'s shape, a learned weight of that width (initialised
     to 1): the output norm of a gated-delta-rule layer, over a head.
+    `activation="sigmoid"`: `* sigmoid(gate)` in silu's place (a Kimi Delta
+    Attention layer's output gate; the norm first only), same shapes, dtypes
+    and precisions: `[..., tokens, heads, dim]`, the sigmoid float32.
 
     `gate_first` (a Mamba-2 layer's output norm, `norm_before_gate` false):
     `u = x * silu(gate)` first, then `u * rsqrt(mean(u^2) + epsilon) * w`,
@@ -1371,6 +1374,12 @@ def gated_rms_norm(input, gate, epsilon=1e-6, param_attr=None, name=None,
         param_attr, [input.shape[-1]], "float32",
         default_initializer=init.ConstantInitializer(1.0))
     attrs = {"epsilon": epsilon}
+    if activation != "silu":
+        if activation != "sigmoid" or gate_first:
+            raise ValueError(f"the gate's activation is silu, or sigmoid "
+                             f"with the norm first, got {activation!r} with "
+                             f"gate_first={gate_first}")
+        attrs["activation"] = activation
     x, z = input, gate
     if gate_first:
         attrs["gate_first"] = True
@@ -1482,6 +1491,50 @@ def gated_delta_rule(q, k, v, a, b, a_log_attr=None, dt_bias_attr=None,
                      inputs={"Q": [q.name], "K": [k.name], "V": [v.name],
                              "G": [g.name], "Beta": [beta.name]},
                      outputs={"Out": [out.name], "States": [states.name]},
+                     attrs={"chunk": int(chunk)})
+    return out
+
+
+def kda_delta_rule(q, k, v, f, b, a_log_attr=None, dt_bias_attr=None,
+                   lower_bound=-5.0, chunk=64, name=None):
+    """Kimi Delta Attention's rule (`ops/linear_attention.py`): the delta
+    rule under a decay per KEY CHANNEL, on q, k `[batch, seq, heads,
+    key_dim]` and v `[batch, seq, heads, value_dim]` (as many value heads as
+    key heads). `f` `[batch, seq, heads * key_dim]` and `b` `[batch, seq,
+    heads]` make a channel's log-decay `g = lower_bound * sigmoid(exp(A_log_h)
+    * (f + dt_bias))` in (`lower_bound`, 0) and the write strength
+    `sigmoid(b)`, float32 (`kda_gates`, AMP_F32_OPS), with the learned
+    `A_log` `[heads]` and `dt_bias` `[heads * key_dim]` (`a_log_attr`,
+    `dt_bias_attr`; float32). Per head a float32 state `[key_dim, value_dim]`
+    from 0: `S <- Diag(exp(g_t)) S;  d = beta_t (v_t - S^T k_t);  S <- S +
+    k_t d^T;  o_t = S^T q_t`, computed in chunks of `chunk` tokens (seq a
+    multiple of it) whose tiles are made in blocks of 16 rows, each relative
+    to a row of its own, so that no exponent above `-8 lower_bound` = 40 is
+    formed (`lower_bound` is what makes that a bound). q and k are
+    l2-normalised over a head inside the op, q then scaled by
+    `key_dim^-0.5`. q, k, v in any float dtype (bf16 under AMP); g, beta,
+    their running sums, every `exp`, the l2-norms, the solve and the state
+    float32. Returns `[batch, seq, heads, value_dim]` in v's dtype. The grad
+    op is registered (`kda_delta_rule_grad`) and returns g's gradient per
+    channel."""
+    helper = LayerHelper("kda_delta_rule", name=name)
+    heads, key_dim = q.shape[2], q.shape[3]
+    a_log = helper.create_parameter(a_log_attr, [heads], "float32")
+    dt_bias = helper.create_parameter(
+        dt_bias_attr, [heads * key_dim], "float32",
+        default_initializer=init.ConstantInitializer(0.0))
+    new = helper.create_variable_for_type_inference
+    g, beta = new("float32"), new("float32")
+    helper.append_op("kda_gates",
+                     inputs={"F": [f.name], "B": [b.name],
+                             "ALog": [a_log.name], "DtBias": [dt_bias.name]},
+                     outputs={"G": [g.name], "Beta": [beta.name]},
+                     attrs={"lower_bound": float(lower_bound)})
+    out = new(v.dtype)
+    helper.append_op("kda_delta_rule",
+                     inputs={"Q": [q.name], "K": [k.name], "V": [v.name],
+                             "G": [g.name], "Beta": [beta.name]},
+                     outputs={"Out": [out.name]},
                      attrs={"chunk": int(chunk)})
     return out
 
@@ -1680,7 +1733,8 @@ def dsa_select(scores, topk, name=None):
 
 def moe_router(input, num_experts, k, param_attr=None, norm_topk_prob=False,
                name=None, score_func="softmax", bias_attr=None,
-               bias_update_rate=None, norm_eps=None, scaling_factor=None):
+               bias_update_rate=None, norm_eps=None, scaling_factor=None,
+               n_group=None, topk_group=None):
     """Top-k router over `input` [tokens, width]: float32 logits and scores
     over all `num_experts` (`score_func`: "softmax" over the experts, or each
     expert's "sigmoid"), the k largest scores used as they are, or with
@@ -1703,7 +1757,15 @@ def moe_router(input, num_experts, k, param_attr=None, norm_topk_prob=False,
     reads a copy taken before the update, so the backward pass, which reads
     the scope's values, differentiates the choice the forward pass made.
     The step's counts stay behind in `<bias name>.load` (int32, persistable,
-    dict key `load`): fetch it, or read it from the scope after the step."""
+    dict key `load`): fetch it, or read it from the scope after the step.
+
+    `n_group`, `topk_group` (DeepSeek-V3's group-limited choice): the experts
+    are `n_group` groups of `num_experts / n_group` consecutive ones; a
+    group's score is the sum of its two largest `score + b` (float32, [tokens,
+    n_group]), the `topk_group` best groups stay, and the k experts are the
+    largest `score + b` among THEIR experts alone; weights, counts and the
+    bias's rewrite as above. `n_group` 1 or None: one group, the program and
+    the lowering it had."""
     from ..param_attr import ParamAttr
     from . import ops as _ops
     from . import tensor as _tensor
@@ -1724,6 +1786,16 @@ def moe_router(input, num_experts, k, param_attr=None, norm_topk_prob=False,
         attrs["norm_eps"] = float(norm_eps)
     if scaling_factor is not None:
         attrs["scaling_factor"] = float(scaling_factor)
+    groups = int(n_group or 1)
+    if groups > 1:
+        kept, size = int(topk_group or groups), num_experts // groups
+        if num_experts % groups or not 1 <= kept <= groups or size < 2 \
+                or kept * size < int(k):
+            raise ValueError(
+                f"{num_experts} experts in {groups} groups of which "
+                f"{kept} stay: the groups are equal, of two experts or "
+                f"more, and those that stay hold at least k = {k}")
+        attrs["n_group"], attrs["topk_group"] = groups, kept
     inputs = {"X": [input.name], "W": [w.name]}
     bias = None
     if bias_attr is not None:

@@ -25,7 +25,12 @@ mask is data, and the first with parameters that the loss cannot reach),
 and Nemotron-H: Mamba-2 state-space mixers (a chunked selective scan), softmax
 attention without positions and two-matrix relu² experts under a sigmoid
 router, one sublayer a layer by a pattern string (the first model whose
-layers are a mixer or a feed-forward part alone)."""
+layers are a mixer or a feed-forward part alone), and Ling-3.0-flash-VL's
+language model: Kimi Delta Attention (a delta rule whose decay is per key
+channel) in five layers of six beside latent attention under a head-wise
+gate, over a group-limited sigmoid router (the first model built as a run of
+its published layers from a stated index on, and the first whose router
+chooses its groups before its experts)."""
 
 from . import mnist  # noqa: F401
 from . import resnet  # noqa: F401
@@ -44,3 +49,4 @@ from . import mellum2  # noqa: F401
 from . import trinity  # noqa: F401
 from . import keye_vl2  # noqa: F401
 from . import nemotron_h  # noqa: F401
+from . import ling3  # noqa: F401

@@ -1,10 +1,12 @@
 """What two or more of the decoder models (`olmoe`, `ouro`, `qwen3_next`,
-`kanana2`, `mellum2`, `trinity`, `keye_vl2`, `nemotron_h`) build the same
+`kanana2`, `mellum2`, `trinity`, `keye_vl2`, `nemotron_h`, `ling3`) build the same
 way, written once: named weights and projections, the token feeds, the
 heads-first reshape and its inverse, a key-value head serving its group of
 query heads, the gated MLP, the routed half of an expert layer, the period of
 layer kinds, the grouped-query block, and the losses. Nothing here asks which
-model calls it: a model whose form differs keeps its own (OLMoE norms q and k
+model calls it (latent attention, built by `kanana2` and `ling3`, takes
+the one thing they differ in, a head-wise gate, as a parameter): a model
+whose form differs keeps its own (OLMoE norms q and k
 before the heads split, Nemotron-H's out projections start smaller). Each
 model keeps its mixer's composition, its layer loop, its defaults
 and `build`. Built from `fluid.layers` only; parameter names are the
@@ -112,29 +114,73 @@ def routed_experts(x, seq_len, n_expert, top_k, d_expert, name, router=None,
     return layers.reshape(routed, shape=[-1, seq_len, x.shape[-1]]), routing
 
 
-def noaux_router(name, bias_update_rate, scaling_factor):
+def noaux_router(name, bias_update_rate, scaling_factor, n_group=None,
+                 topk_group=None):
     """`router` of `routed_experts` for DeepSeek-V3's `noaux_tc` routing:
     sigmoid scores, the choice moved by a selection bias (`name.router.bias`)
     that the step itself rewrites, the chosen scores renormalised and
-    scaled."""
+    scaled; among the experts of the `topk_group` best of `n_group` groups
+    where those are given (one group otherwise)."""
     return dict(norm_topk_prob=True, score_func="sigmoid",
                 bias_attr=ParamAttr(name=name + ".router.bias"),
                 bias_update_rate=bias_update_rate, norm_eps=1e-20,
-                scaling_factor=scaling_factor)
+                scaling_factor=scaling_factor, n_group=n_group,
+                topk_group=topk_group)
 
 
 def noaux_experts(x, seq_len, n_expert, top_k, d_expert, d_shared,
                   first_expert, experts_held, scaling_factor,
-                  bias_update_rate, name):
+                  bias_update_rate, name, n_group=None, topk_group=None):
     """An expert layer under `noaux_router`: this chip's share of the routed
     experts plus a shared gated MLP of width `d_shared`, no gate on it."""
     routed, routing = routed_experts(
         x, seq_len, n_expert, top_k, d_expert, name,
-        router=noaux_router(name, bias_update_rate, scaling_factor),
+        router=noaux_router(name, bias_update_rate, scaling_factor, n_group,
+                            topk_group),
         experts=dict(first_expert=first_expert, experts_held=experts_held))
     out = layers.elementwise_add(routed,
                                  gated_mlp(x, d_shared, name + ".shared"))
     return out, routing
+
+
+def latent_attention(x, n_head, kv_rank, qk_nope_dim, qk_rope_dim,
+                      v_head_dim, rope_theta, rms_eps, name, head_gate=False):
+    """Latent attention (MLA) as `models/kanana2.py`'s docstring writes it
+    out: keys and values out of one `kv_rank`-wide normed row a token, one
+    interleaved rotary key head for all query heads, heads of `qk_nope_dim +
+    qk_rope_dim` over values of `v_head_dim` through `fused_attention`.
+    `head_gate`: the context of head h times `sigmoid(x W_gate)_h`, one
+    scalar a head and token (`name.gate.w`, `[width, n_head]`), before W_o
+    (`models/ling3.py`'s latent layers; Kanana-2's have none)."""
+    qk_dim = qk_nope_dim + qk_rope_dim
+
+    def rotary(t):
+        return layers.rotary_embedding(t, theta=rope_theta, interleaved=True)
+
+    q = heads_first(split_heads(linear(x, n_head * qk_dim, name + ".q"),
+                                n_head, qk_dim))
+    q = layers.concat([last(q, 0, qk_nope_dim),
+                       rotary(last(q, qk_nope_dim, qk_dim))], axis=3)
+    kv_a = linear(x, kv_rank + qk_rope_dim, name + ".kv_a")
+    latent = norm(last(kv_a, 0, kv_rank), rms_eps, name + ".kv_norm")
+    # one rotary key head, [B, 1, T, rope], serves every query head
+    k_rope = rotary(layers.unsqueeze(last(kv_a, kv_rank,
+                                          kv_rank + qk_rope_dim), axes=[1]))
+    kv = heads_first(split_heads(
+        linear(latent, n_head * (qk_nope_dim + v_head_dim), name + ".kv_b"),
+        n_head, qk_nope_dim + v_head_dim))
+    k = layers.concat(
+        [last(kv, 0, qk_nope_dim),
+         layers.expand(k_rope, expand_times=[1, n_head, 1, 1])], axis=3)
+    v = last(kv, qk_nope_dim, qk_nope_dim + v_head_dim)
+    ctx = layers.fused_attention(q, k, v, causal=True,
+                                 sm_scale=qk_dim ** -0.5)
+    if head_gate:
+        gate = layers.sigmoid(linear(x, n_head, name + ".gate"))
+        ctx = heads_first(layers.elementwise_mul(
+            heads_first(ctx), layers.unsqueeze(gate, axes=[3])))
+    return linear(merge_heads(ctx, n_head * v_head_dim), x.shape[-1],
+                  name + ".o")
 
 
 def layer_kinds(n_layer, layer_types=PERIOD):

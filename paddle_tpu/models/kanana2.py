@@ -63,38 +63,9 @@ from __future__ import annotations
 
 from .. import layers
 from ..core.ir import name_scope
-from ._decoder import (cross_entropy_fetches, embed, gated_mlp, heads_first,
-                       last, linear, merge_heads, noaux_experts, norm,
-                       split_heads, token_feeds)
-
-
-def _latent_attention(x, n_head, kv_rank, qk_nope_dim, qk_rope_dim,
-                      v_head_dim, rope_theta, rms_eps, name):
-    qk_dim = qk_nope_dim + qk_rope_dim
-
-    def rotary(t):
-        return layers.rotary_embedding(t, theta=rope_theta, interleaved=True)
-
-    q = heads_first(split_heads(linear(x, n_head * qk_dim, name + ".q"),
-                                n_head, qk_dim))
-    q = layers.concat([last(q, 0, qk_nope_dim),
-                       rotary(last(q, qk_nope_dim, qk_dim))], axis=3)
-    kv_a = linear(x, kv_rank + qk_rope_dim, name + ".kv_a")
-    latent = norm(last(kv_a, 0, kv_rank), rms_eps, name + ".kv_norm")
-    # one rotary key head, [B, 1, T, rope], serves every query head
-    k_rope = rotary(layers.unsqueeze(last(kv_a, kv_rank,
-                                          kv_rank + qk_rope_dim), axes=[1]))
-    kv = heads_first(split_heads(
-        linear(latent, n_head * (qk_nope_dim + v_head_dim), name + ".kv_b"),
-        n_head, qk_nope_dim + v_head_dim))
-    k = layers.concat(
-        [last(kv, 0, qk_nope_dim),
-         layers.expand(k_rope, expand_times=[1, n_head, 1, 1])], axis=3)
-    v = last(kv, qk_nope_dim, qk_nope_dim + v_head_dim)
-    ctx = layers.fused_attention(q, k, v, causal=True,
-                                 sm_scale=qk_dim ** -0.5)
-    return linear(merge_heads(ctx, n_head * v_head_dim), x.shape[-1],
-                  name + ".o")
+from ._decoder import (cross_entropy_fetches, embed, gated_mlp,
+                       latent_attention, linear, noaux_experts, norm,
+                       token_feeds)
 
 
 def kanana2(vocab_size=128256, seq_len=4096, n_layer=48, n_dense_layer=1,
@@ -113,7 +84,7 @@ def kanana2(vocab_size=128256, seq_len=4096, n_layer=48, n_dense_layer=1,
     for i in range(n_layer):
         name = f"l{i}"
         with name_scope(name + ".mla"):
-            mixed = _latent_attention(
+            mixed = latent_attention(
                 norm(x, rms_eps, name + ".in_norm"), n_head, kv_rank,
                 qk_nope_dim, qk_rope_dim, v_head_dim, rope_theta, rms_eps,
                 name + ".mla")

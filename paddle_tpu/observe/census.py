@@ -83,18 +83,26 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     and none of its attention layers carries positions).
     `moe_expert_activation`: `relu2` where the routed experts are two
     matrices around a `relu2` op (absent for gated silu experts).
+    `kda`: a `kda_delta_rule` op (a Kimi Delta Attention mixer: the delta
+    rule under a decay per key channel), with `kda_layers`, their count
+    again as a flat number. `latent_attention_gated_layers`: the latent-
+    attention layers whose `name_scope` holds a `sigmoid` op (a head-wise
+    gate on the context). `moe_router_groups` and `moe_router_groups_kept`:
+    a group-limited router's `n_group` and `topk_group` (absent for one
+    group).
     (`moe_row_buffer_rows`, the rows of the expert layer's layout, follows
     the batch: `moe_dispatch`'s rule notes it on the same event under the
     trace, `LoweringContext.note`.)"""
     block = program.global_block()
     kinds = {"linear_attention": 0, "full_attention": 0,
              "latent_attention": 0, "window_attention": 0,
-             "sparse_attention": 0, "state_space": 0}
+             "sparse_attention": 0, "state_space": 0, "kda": 0}
     out: Dict[str, object] = {}
     copies: Dict[str, str] = {}     # an `assign` op's result -> what it copied
     biases = []                     # the routers' selection biases
     gated, routed = [], set()       # name scopes of `swiglu`s, of routers
     mixers = []                     # name scopes of `fused_attention`s
+    latent = []                     # of those, the latent-attention ones
     full_keys = []                  # the full-attention ops' K
     held_by = {"rotary_embedding": set(), "sigmoid": set()}  # name scopes
     normed, added = set(), set()    # `rms_norm` results, residual addends
@@ -106,6 +114,8 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
             kinds["linear_attention"] += 1
         elif op.type == "ssd_scan":
             kinds["state_space"] += 1
+        elif op.type == "kda_delta_rule":
+            kinds["kda"] += 1
         elif op.type == "relu2" and scope in routed:
             out["moe_expert_activation"] = "relu2"
         elif op.type in held_by:
@@ -134,6 +144,7 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
                 full_keys.append(op.input("K")[0])
             else:
                 kinds["latent_attention"] += 1
+                latent.append(scope)
                 out["attention_qk_width"] = wide
                 out["attention_value_width"] = value
         elif op.type == "swiglu":
@@ -145,6 +156,9 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
             routed.add(scope)
             if op.attrs.get("score_func"):
                 out["moe_router_score"] = op.attrs["score_func"]
+            if op.attrs.get("n_group"):
+                out["moe_router_groups"] = op.attrs["n_group"]
+                out["moe_router_groups_kept"] = op.attrs["topk_group"]
             biases += [copies.get(name, name)
                        for name in op.inputs.get("Bias", [])]
         elif op.type == "moe_dispatch":
@@ -156,6 +170,8 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
         out["attention_window_layers"] = kinds["window_attention"]
     if kinds["sparse_attention"]:
         out["dsa_layers"] = kinds["sparse_attention"]
+    if kinds["kda"]:
+        out["kda_layers"] = kinds["kda"]
     if kinds["state_space"]:
         out["state_space_layers"] = kinds["state_space"]
         group = max([_expanded_by(block, k) for k in full_keys], default=1)
@@ -182,6 +198,9 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     gated_mixers = sum(1 for scope in mixers if scope in held_by["sigmoid"])
     if gated_mixers:
         out["attention_gated_layers"] = gated_mixers
+    gated_latent = sum(1 for scope in latent if scope in held_by["sigmoid"])
+    if gated_latent:
+        out["latent_attention_gated_layers"] = gated_latent
     out_norms = len(normed & added)
     if out_norms:
         out["residual_out_norms"] = out_norms

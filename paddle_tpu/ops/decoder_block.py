@@ -103,14 +103,16 @@ def _rms_norm(ctx, X, Scale):
     return {"Y": y.astype(X.dtype)}
 
 
-def _gated_norm_xla(X, Gate, Scale, eps):
+def _gated_norm_xla(X, Gate, Scale, eps, sigmoid=False):
     """The op as plain jnp: what runs outside the kernels' envelope, and the
-    form the kernels are held to (`jax.vjp` of it is the grad there)."""
+    form the kernels are held to (`jax.vjp` of it is the grad there).
+    `sigmoid`: the gate's sigmoid in its silu's place."""
     x32 = X.astype(jnp.float32)
     ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
     normed = (x32 * lax.rsqrt(ms + eps)).astype(X.dtype)
     y = Scale.astype(jnp.float32) * normed.astype(jnp.float32)
-    return (y * jax.nn.silu(Gate.astype(jnp.float32))).astype(X.dtype)
+    act = jax.nn.sigmoid if sigmoid else jax.nn.silu
+    return (y * act(Gate.astype(jnp.float32))).astype(X.dtype)
 
 
 _BLOCK_BYTES = 1 << 20      # bytes of X a grid step of these kernels takes
@@ -168,24 +170,27 @@ def _gated_norm_tile(x, g, eps):
         jax.nn.sigmoid(g32)
 
 
-def _gated_norm_fwd_kernel(x_ref, g_ref, w_ref, y_ref, *, D, eps):
+def _gated_norm_fwd_kernel(x_ref, g_ref, w_ref, y_ref, *, D, eps,
+                           sigmoid=False):
     """One (batch, token block, head block) step, head by head: the rule's
     arithmetic in float32 on a `[tokens, D]` tile, its one rounding, Y in
-    X's dtype."""
+    X's dtype. `sigmoid`: the gate's sigmoid in its silu's place."""
     w = w_ref[...]
     for h in range(x_ref.shape[2] // D):
         lanes = slice(h * D, (h + 1) * D)
         _, _, normed, g, s = _gated_norm_tile(x_ref[0, :, lanes],
                                               g_ref[0, :, lanes], eps)
-        y_ref[0, :, lanes] = ((w * normed) * (g * s)).astype(y_ref.dtype)
+        y = (w * normed) * (s if sigmoid else g * s)
+        y_ref[0, :, lanes] = y.astype(y_ref.dtype)
 
 
 def _gated_norm_bwd_kernel(x_ref, g_ref, dy_ref, w_ref, dx_ref, dg_ref,
-                           dw_ref, *, D, eps):
+                           dw_ref, *, D, eps, sigmoid=False):
     """The same step on dY: r, x r and silu(gate) made again; with
     `dn = dy w silu(g)`: `dx = r (dn - n mean(dn n))`, `dg = dy w normed
     silu'(g)`, and the block's part of dScale, `sum dy normed silu(g)`, as
-    eight sublanes of partial sums."""
+    eight sublanes of partial sums. `sigmoid`: the gate's sigmoid and its
+    derivative `s (1 - s)` in silu's places."""
     w = w_ref[...]
     acc = jnp.zeros((8, D), jnp.float32)
     for h in range(x_ref.shape[2] // D):
@@ -193,12 +198,13 @@ def _gated_norm_bwd_kernel(x_ref, g_ref, dy_ref, w_ref, dx_ref, dg_ref,
         r, n, normed, g, s = _gated_norm_tile(x_ref[0, :, lanes],
                                               g_ref[0, :, lanes], eps)
         dy = dy_ref[0, :, lanes].astype(jnp.float32)
-        silu = g * s
+        silu = s if sigmoid else g * s
         dyw = dy * w
         dn = dyw * silu
         dx = r * (dn - n * jnp.mean(dn * n, axis=-1, keepdims=True))
         dx_ref[0, :, lanes] = dx.astype(dx_ref.dtype)
-        dg = dyw * normed * (s * (1.0 + g * (1.0 - s)))
+        dg = dyw * normed * (s * (1.0 - s) if sigmoid
+                             else s * (1.0 + g * (1.0 - s)))
         dg_ref[0, :, lanes] = dg.astype(dg_ref.dtype)
         p = dy * normed * silu
         acc = acc + sum(p[i:i + 8] for i in range(0, p.shape[0], 8))
@@ -225,7 +231,7 @@ def _gated_norm_layout(X, backward):
     return (-1, T, H * D), Hb, D, grid, block, params
 
 
-def _gated_norm_call(X, Gate, Scale, eps, d_y=None):
+def _gated_norm_call(X, Gate, Scale, eps, d_y=None, sigmoid=False):
     """`gated_norm_fwd` (Y), or with `d_y` `gated_norm_bwd` (dX, dGate in
     their dtypes, dScale float32): X, Gate and dY `[..., T, H, D]` as they
     arrive, read as `[B, T, H * D]` (the same bytes), a head's lanes chosen
@@ -238,14 +244,15 @@ def _gated_norm_call(X, Gate, Scale, eps, d_y=None):
     x, gate = X.reshape(flat), Gate.reshape(flat)
     weight = pl.BlockSpec((1, D), lambda b, t, h: (0, 0))
     w = Scale.astype(jnp.float32).reshape(1, D)
+    more = {"sigmoid": True} if sigmoid else {}
     if d_y is None:
         return pl.pallas_call(
-            functools.partial(_gated_norm_fwd_kernel, D=D, eps=eps),
+            functools.partial(_gated_norm_fwd_kernel, D=D, eps=eps, **more),
             name="gated_norm_fwd", in_specs=[block, block, weight],
             out_specs=block, out_shape=jax.ShapeDtypeStruct(x.shape, X.dtype),
             **params)(x, gate, w).reshape(X.shape)
     dX, dGate, dW = pl.pallas_call(
-        functools.partial(_gated_norm_bwd_kernel, D=D, eps=eps),
+        functools.partial(_gated_norm_bwd_kernel, D=D, eps=eps, **more),
         name="gated_norm_bwd", in_specs=[block, block, block, weight],
         out_specs=[block, block, pl.BlockSpec(
             (1, 1, 1, 8, D), lambda b, t, h: (b, t, h, 0, 0))],
@@ -347,6 +354,9 @@ def _gated_norm_forms(ctx):
     `gate_first`, Mamba-2's (the gate, then the norm over a group)."""
     if ctx.attr("gate_first", False):
         return _gate_first_norm_xla, _gate_first_norm_call
+    if ctx.attr("activation", "silu") == "sigmoid":     # a KDA layer's
+        return (functools.partial(_gated_norm_xla, sigmoid=True),
+                functools.partial(_gated_norm_call, sigmoid=True))
     return _gated_norm_xla, _gated_norm_call
 
 

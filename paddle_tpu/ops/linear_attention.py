@@ -1,5 +1,7 @@
 """Linear attention by the gated delta rule (Gated DeltaNet; the mixer of
-three layers in four of `qwen3_next`), and the two small ops around it.
+three layers in four of `qwen3_next`), the two small ops around it, and the
+same rule under a decay per key channel (Kimi Delta Attention: the end of
+this docstring).
 
     causal_conv1d:      a depthwise convolution over time, then silu (two
                         Pallas kernels too: the end of this docstring)
@@ -117,6 +119,58 @@ pre-activation, silu and its derivative stay in VMEM:
 Inside a time block both work on 64 rows at a time, so a loop step's tiles
 stay in vregs. Elsewhere the op is the jnp form `_conv_xla` and the grad op
 (`causal_conv1d_grad`, registered) its `jax.vjp`.
+
+The delta rule under a decay PER KEY CHANNEL (Kimi Delta Attention, the mixer
+of five layers in six of `ling3`):
+
+    kda_gates:        g = lower_bound * sigmoid(exp(A_log_h) (f + dt_bias)),
+                      [.., H, Dk] in (lower_bound, 0): a head AND key channel;
+                      beta = sigmoid(b)
+    kda_delta_rule:   per head a state S [key, value], S_0 = 0:
+                        S <- Diag(exp(g_t)) S;  d = beta_t (v_t - S^T k_t);
+                        S <- S + k_t d^T;  o_t = S^T q_t
+
+In chunks of `chunk` tokens (`chunked_kda_rule`), with G the running sum of g
+inside a chunk, `[chunk, Dk]` a head, and S the state the chunk starts from:
+
+    A = strict_lower(beta_i sum_c k_i[c] k_j[c] exp(G_i[c] - G_j[c]))
+    P = lower(sum_c q_i[c] k_j[c] exp(G_i[c] - G_j[c]));   T = (I + A)^-1
+    u = T (beta v);  w = T (beta k * exp(G));  v' = u - w S
+    o = (q * exp(G)) S + P v'
+    S <- Diag(exp(G_last)) S + (k * exp(G_last - G))^T v'
+
+The decay sits inside the contraction over the key channels, so the scalar
+rule's `k k^T * D` is gone: each side of a Gram tile carries its half of the
+decay relative to a reference row r, `(x_i * exp(G_i - G_r)) . (k_j * exp(G_r
+- G_j))`, and one of the halves has a positive exponent wherever r does not lie
+between j and i. That is what the bound on g is for (`kda_safe_gate`: g >= -5
+a token). A chunk's tiles are made in blocks of 16 rows (`_KDA_BLOCK`). Against
+the keys of the blocks before it a block takes r = its own first row: both
+exponents <= 0, whatever g. Against its own 16 keys it takes r = its middle
+row: both within +-8 x 5 = 40, `exp(40) = 2.4e17`, far from float32's ends on
+both sides. (r = the block's first row would do for the forward pass, at
+exponents up to 75 < 88; but then the other half reaches `exp(-75)`, and the
+backward's products of it with a small cotangent fall under float32's
+smallest normal number and are flushed: g's gradient read 1% off at g = -5
+everywhere, 1e-4 with the middle row. A 64-token chunk relative to one row,
+320, would overflow outright.) No gradient is passed through r: the tiles do
+not depend on it. The other exponents, `exp(G)`, `exp(G_last - G)` and
+`exp(G_last)`, are <= 0 as in the scalar rule. No `[chunk, chunk, Dk]` array
+is formed: a block's rows times the keys it reaches is one product of two
+`[.., Dk]` operands.
+
+The op is that XLA form on every backend (`kda_plan: "xla"` on the compile
+event): A, the solve, u and w for all chunks at once, a `lax.scan` over the
+chunks' states, the outputs for all chunks at once; A and the solve at
+HIGHEST, the rest at the default precision. Float32 whatever dtype flows
+through: g, beta, G, every `exp`, the l2-norms, A, T and the state. The grad
+op is registered (`kda_delta_rule_grad`): `jax.vjp` of the same form, which
+makes a chunk's factors again from the op's inputs, so the forward saves
+nothing and returns dG per channel. Both tally the rule's chunk steps (batch
+x heads x chunks, `kda_grid_steps`). A pair of Pallas kernels on `gdn_fwd` /
+`gdn_bwd`'s grid (the state and dS in VMEM scratch, the states saved a chunk)
+is what ROADMAP M6 queues; `_Chunks`' blocked substitution and `_plan`'s
+envelope carry over, the Gram tiles' two-sided blocks do not exist there yet.
 """
 
 from __future__ import annotations
@@ -250,6 +304,117 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk):
     S_in, v_new = jnp.moveaxis(S_in, 0, 2), jnp.moveaxis(v_new, 0, 2)
     scores = jnp.einsum("bhnid,bhnjd->bhnij", q, k) * decay
     o = jnp.einsum("bhnck,bhnkv->bhncv", q * jnp.exp(G)[..., None], S_in) \
+        + jnp.einsum("bhnij,bhnjv->bhniv", scores, v_new)
+    return jnp.moveaxis(o, 1, 3).reshape(B, T, H, v.shape[-1])
+
+
+# ---------------------------------------------------------------------------
+# the delta rule under a decay per key channel (Kimi Delta Attention)
+# ---------------------------------------------------------------------------
+
+_KDA_BLOCK = 16     # rows of a Gram tile that share one reference row
+
+
+@register_op("kda_gates")
+def _kda_gates(ctx, F, B, ALog, DtBias):
+    """F [..., H * Dk] or [..., H, Dk] and B [..., H] (two projections of the
+    layer's input), ALog [H], DtBias [H * Dk] -> G [..., H, Dk] =
+    `lower_bound * sigmoid(exp(ALog_h) * (F + DtBias))`, the log of a head's
+    decay per KEY CHANNEL, in (`lower_bound`, 0) (the attribute, -5 as
+    published: `kda_safe_gate`), and Beta = sigmoid(B); both float32
+    (AMP_F32_OPS)."""
+    heads = ALog.shape[0]
+    f32 = F.astype(jnp.float32).reshape(B.shape[:-1] + (heads, -1))
+    rate = jnp.exp(ALog.astype(jnp.float32))[:, None]
+    bias = DtBias.astype(jnp.float32).reshape(heads, -1)
+    g = float(ctx.attr("lower_bound", -5.0)) \
+        * jax.nn.sigmoid(rate * (f32 + bias))
+    return {"G": g, "Beta": jax.nn.sigmoid(B.astype(jnp.float32))}
+
+
+def chunked_kda_rule(q, k, v, g, beta, chunk):
+    """q, k [B, T, H, Dk] (already normalised and scaled), v [B, T, H, Dv],
+    g [B, T, H, Dk] (a channel's log-decay, bounded below), beta [B, T, H],
+    all float32, T a multiple of `chunk` -> o [B, T, H, Dv] float32 (module
+    docstring: the per-channel chunk algebra). No exponent above `-8 min(g)`
+    is formed (40 at the published bound), and none above 0 outside the
+    diagonal 16 x 16 blocks of a chunk's tiles."""
+    B, T, H, Dk = q.shape
+    n = T // chunk
+    # a chunk that 16-row blocks do not divide (the tests' 4) is one block
+    block = chunk if chunk % _KDA_BLOCK else _KDA_BLOCK
+    nb = chunk // block
+
+    def chunks(x):      # [B, T, H, ...] -> [B, H, n, chunk, ...]
+        x = x.reshape((B, n, chunk) + x.shape[2:])
+        return jnp.moveaxis(x, 3, 1)
+
+    q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
+    G = jnp.cumsum(g, axis=3)                             # [B, H, n, C, Dk]
+
+    def blocks(x):      # [.., C, Dk] -> [.., nb, block, Dk]
+        return x.reshape(x.shape[:-2] + (nb, block, Dk))
+
+    # A block of `block` rows reads its Gram tiles relative to a reference
+    # row r: (x_i exp(G_i - G_r)) . (k_j exp(G_r - G_j)). Against the keys of
+    # the blocks before it r is the block's first row: both exponents <= 0.
+    # Against its own keys r is its middle row: both within +-(block / 2)
+    # |min g|, 40 as published, so that neither half nears float32's ends (at
+    # r = the first row a half reaches exp(-75) and its gradient's products
+    # are flushed). The tiles do not depend on r, so no gradient passes
+    # through it: the two halves' parts, equal and opposite, would only
+    # leave their rounding behind.
+    Gb = blocks(G)
+    first = lax.stop_gradient(Gb[..., :1, :])             # [.., nb, 1, Dk]
+    middle = lax.stop_gradient(Gb[..., block // 2:block // 2 + 1, :])
+    before = lax.broadcasted_iota(jnp.int32, (nb, chunk, 1), 1) \
+        < block * lax.broadcasted_iota(jnp.int32, (nb, chunk, 1), 0)
+    diff = first - G[..., None, :, :]                     # [.., nb, C, Dk]
+    k_before = jnp.where(before, jnp.exp(jnp.where(before, diff, 0.0)), 0.0) \
+        * k[..., None, :, :]
+    k_own = blocks(k) * jnp.exp(middle - Gb)              # [.., nb, block, Dk]
+    to_first, to_middle = jnp.exp(Gb - first), jnp.exp(Gb - middle)
+    own = jnp.eye(nb, dtype=jnp.float32)[:, None, :, None]
+
+    def tiles(x, precision=None):   # rows x [.., C, Dk] -> [.., C, C]
+        x = blocks(x)
+        t = jnp.einsum("bhnrad,bhnrjd->bhnraj", x * to_first, k_before,
+                       precision=precision)
+        d = jnp.einsum("bhnrad,bhnrjd->bhnraj", x * to_middle, k_own,
+                       precision=precision)
+        d = (d[..., None, :] * own).reshape(t.shape)  # on the block diagonal
+        return (t + d).reshape(t.shape[:-3] + (chunk, chunk))
+
+    row = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    k_beta = k * beta[..., None]
+    a = jnp.where(row > col, tiles(k_beta, lax.Precision.HIGHEST), 0.0)
+    eg = jnp.exp(G)
+    rhs = jnp.concatenate([v * beta[..., None], k_beta * eg], axis=-1)
+    with jax.default_matmul_precision("highest"):
+        solved = lax.linalg.triangular_solve(
+            a, rhs, left_side=True, lower=True, unit_diagonal=True)
+    u, w = solved[..., : v.shape[-1]], solved[..., v.shape[-1]:]
+    g_last = G[..., -1, :]                                # [B, H, n, Dk]
+    k_tail = k * jnp.exp(g_last[..., None, :] - G)
+
+    def step(S, xs):
+        u_i, w_i, k_i, last_i = xs
+        v_new = u_i - jnp.einsum("bhck,bhkv->bhcv", w_i, S)
+        S_next = S * jnp.exp(last_i)[..., None] \
+            + jnp.einsum("bhck,bhcv->bhkv", k_i, v_new)
+        return S_next, (S, v_new)
+
+    def by_chunk(x):    # [B, H, n, ...] -> [n, B, H, ...]
+        return jnp.moveaxis(x, 2, 0)
+
+    S0 = jnp.zeros((B, H, Dk, v.shape[-1]), jnp.float32)
+    _, (S_in, v_new) = lax.scan(
+        step, S0, (by_chunk(u), by_chunk(w), by_chunk(k_tail),
+                   by_chunk(g_last)))
+    S_in, v_new = jnp.moveaxis(S_in, 0, 2), jnp.moveaxis(v_new, 0, 2)
+    scores = jnp.where(row >= col, tiles(q), 0.0)
+    o = jnp.einsum("bhnck,bhnkv->bhncv", q * eg, S_in) \
         + jnp.einsum("bhnij,bhnjv->bhniv", scores, v_new)
     return jnp.moveaxis(o, 1, 3).reshape(B, T, H, v.shape[-1])
 
@@ -993,3 +1158,61 @@ def _gated_delta_rule_grad(ctx, ins, out_grads):
         _tally_grid(ctx, raw[0], raw[2], chunk)
         grads = _gdn_backward(*raw, states, d_out, chunk)
     return {s: d.astype(x.dtype) for s, d, x in zip(slots, grads, raw)}
+
+
+_KDA_SLOTS = ("Q", "K", "V", "G", "Beta")
+
+
+def _kda_rule(Q, K, V, G, Beta, chunk):
+    """The op on its operands as they arrive: q and k l2-normalised over a
+    head in float32 (q then scaled by `Dk^-0.5`), the chunked rule, Out in
+    V's dtype."""
+    q = l2_normalize(Q.astype(jnp.float32)) * Q.shape[3] ** -0.5
+    k = l2_normalize(K.astype(jnp.float32))
+    out = chunked_kda_rule(q, k, V.astype(jnp.float32),
+                           G.astype(jnp.float32), Beta.astype(jnp.float32),
+                           chunk)
+    return out.astype(V.dtype)
+
+
+def _kda_check(ctx, Q, V, G):
+    """The chunk, after the shapes are checked and the rule's chunk steps
+    (batch x heads x chunks, summed over the program's ops and grad ops) are
+    tallied onto the compile event as `kda_grid_steps`."""
+    chunk = int(ctx.attr("chunk", 64))
+    B, T, H, Dk = Q.shape
+    if T % chunk or V.shape[2] != H or G.shape != Q.shape:
+        raise ValueError(f"kda_delta_rule needs a length that is a multiple "
+                         f"of the chunk ({chunk}), as many value heads as "
+                         f"key heads and a decay of q's shape, got q "
+                         f"{Q.shape}, v {V.shape}, g {G.shape}")
+    ctx.note(kda_plan="xla")
+    ctx.tally("kda_grid_steps", B * H * (T // chunk))
+    return chunk
+
+
+@register_op("kda_delta_rule", propagate_seqlen=False)
+def _kda_delta_rule(ctx, Q, K, V, G, Beta):
+    """Q, K [B, T, H, Dk], V [B, T, H, Dv], G [B, T, H, Dk] (the log-decay of
+    every key channel, float32, bounded below: `kda_gates`), Beta [B, T, H]
+    -> Out [B, T, H, Dv] in V's dtype: per head a state S [Dk, Dv] from 0,
+    `S <- Diag(exp(g_t)) S;  d = beta_t (v_t - S^T k_t);  S <- S + k_t d^T;
+    o_t = S^T q_t`, in chunks of `chunk` tokens (`chunked_kda_rule`). q and k
+    are l2-normalised over a head (`x * rsqrt(sum x^2 + 1e-6)`), q then
+    scaled by `Dk^-0.5`."""
+    return {"Out": _kda_rule(Q, K, V, G, Beta, _kda_check(ctx, Q, V, G))}
+
+
+@register_grad("kda_delta_rule")
+def _kda_delta_rule_grad(ctx, ins, out_grads):
+    """The five input gradients (G's per key channel) by `jax.vjp` of the
+    chunked form, which makes a chunk's factors again from the op's inputs:
+    the forward saves nothing."""
+    d_out = out_grads["Out"][0]
+    if d_out is None:
+        return {}
+    raw = [ins[s][0] for s in _KDA_SLOTS]
+    chunk = _kda_check(ctx, raw[0], raw[2], raw[3])
+    out, vjp = jax.vjp(functools.partial(_kda_rule, chunk=chunk), *raw)
+    return {s: d.astype(x.dtype)
+            for s, d, x in zip(_KDA_SLOTS, vjp(d_out.astype(out.dtype)), raw)}

@@ -160,6 +160,21 @@ _TOKEN_SUM_OUT_ROWS = 512
 _TOKEN_SUM_UNROLL = 8
 
 
+def _kept_groups(choice, groups, kept):
+    """`choice` [N, E] with every expert outside the token's `kept` best of
+    `groups` groups of consecutive experts at -inf: a group's score is the
+    sum of its two largest entries (DeepSeek-V3's group-limited routing, the
+    public `deepseek_v3` code). Float32; nothing here is differentiated (the
+    weights are gathered from the scores by the indices)."""
+    n, e = choice.shape
+    two_best, _ = lax.top_k(choice.reshape(n, groups, e // groups), 2)
+    _, best = lax.top_k(jnp.sum(two_best, axis=-1), kept)      # [N, kept]
+    stays = jnp.any(best[:, :, None] == jnp.arange(groups, dtype=best.dtype),
+                    axis=1)                                     # [N, groups]
+    return jnp.where(jnp.repeat(stays, e // groups, axis=1), choice,
+                     -jnp.inf)
+
+
 @register_op("moe_router", propagate_seqlen=False)
 def _moe_router(ctx, X, W, Bias=None):
     """X [N, D], W [D, E]. Logits, the scores of ALL E experts and both
@@ -173,8 +188,11 @@ def _moe_router(ctx, X, W, Bias=None):
     chosen experts' scores without it. The weights are the scores as they
     are, or, with `norm_topk_prob`, divided by their sum over all k chosen
     experts (wherever those live; plus the attribute `norm_eps` where it is
-    given), and then times `scaling_factor` where that is given. A program
-    that sets none of these lowers to the ops it had."""
+    given), and then times `scaling_factor` where that is given. With the
+    attributes `n_group` > 1 and `topk_group` the choice is group-limited
+    (`_kept_groups`): the k largest of `score + Bias` among the experts of
+    the token's best groups. A program that sets none of these lowers to the
+    ops it had."""
     k = int(ctx.attr("k"))
     logits = jnp.dot(X.astype(jnp.float32), W.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
@@ -183,7 +201,13 @@ def _moe_router(ctx, X, W, Bias=None):
         probs = jax.nn.sigmoid(logits)
     else:
         probs = jnp.exp(logits - lse[:, None])
-    if Bias is None:
+    groups = int(ctx.attr("n_group", 1))
+    if groups > 1:
+        choice = probs if Bias is None else probs + Bias.astype(jnp.float32)
+        _, index = lax.top_k(_kept_groups(
+            choice, groups, int(ctx.attr("topk_group", groups))), k)
+        weight = jnp.take_along_axis(probs, index, axis=-1)
+    elif Bias is None:
         weight, index = lax.top_k(probs, k)
     else:
         _, index = lax.top_k(probs + Bias.astype(jnp.float32), k)

@@ -645,6 +645,57 @@ def test_nemotron_h_kernels_compile_at_the_cells_shapes(one_chip,
     assert "= (bf16[1,2048,4096]{" in call
 
 
+# the two kernel pairs as `ling_3_0_flash_vl.s2048` calls them and no other
+# cell does (the convolution at 12288 channels, the gated norm with the
+# gate's sigmoid): digests by `_lowered_digest` as PR 60 recorded them
+LING3_CALLS = {"conv": ("0dc82162ea73cf3adf5ee5640a4ecd5b",
+                        "dd2695abd0b9d84df555e4bde81ca3dc"),
+               "sigmoid_norm": ("f091d355f947fe7ff1d6a5a8aed27bc3",
+                                "75a5876f95414738d7c76f3aa4bb7f66")}
+
+
+@pytest.mark.parametrize("case", sorted(LING3_CALLS))
+def test_ling3_kernels_compile_at_the_cells_shapes(one_chip, monkeypatch,
+                                                   case):
+    """`causal_conv_fwd` / `causal_conv_bwd` over bf16 `[1, 2048, 12288]`
+    (KDA's [q | k | v], 4 taps, no bias) and `gated_norm_fwd` /
+    `gated_norm_bwd` with `activation="sigmoid"` over `[1, 2048, 32, 128]`:
+    one Mosaic custom call each for a described v5e, lowered to the recorded
+    text; the sigmoid form is not silu's text. (The rule itself is XLA ops:
+    no kernel of its own to compile.)"""
+    from paddle_tpu.ops import decoder_block as db
+    monkeypatch.setattr(_kernels, "on_chip", lambda: True)
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    f32 = jnp.float32
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if case == "conv":
+        x, w = arg((1, 2048, 12288)), arg((12288, 4), f32)
+        fwd = jax.jit(lambda x, w: la._conv_forward(x, w, True)).lower(x, w)
+        bwd = jax.jit(lambda x, w, d: la._conv_backward(
+            x, w, d, True)).lower(x, w, x)
+        names = ("causal_conv_fwd", "causal_conv_bwd")
+        results = ("= bf16[1,2048,12288]{", ", f32[4,12288]{")
+    else:
+        x, w = arg((1, 2048, 32, 128)), arg((128,), f32)
+        fwd = jax.jit(lambda x, g, w: db._gated_norm_call(
+            x, g, w, 1e-6, sigmoid=True)).lower(x, x, w)
+        bwd = jax.jit(lambda x, g, w, d: db._gated_norm_call(
+            x, g, w, 1e-6, d, sigmoid=True)).lower(x, x, w, x)
+        names = ("gated_norm_fwd", "gated_norm_bwd")
+        results = ("= bf16[1,2048,4096]{", "= (bf16[1,2048,4096]{")
+        silu = jax.jit(lambda x, g, w: db._gated_norm_call(
+            x, g, w, 1e-6)).lower(x, x, w)
+        assert _lowered_digest(silu) != _lowered_digest(fwd)
+    for lowered, name, result in zip((fwd, bwd), names, results):
+        (call,) = _custom_calls(lowered.compile(), name)
+        assert "tpu_custom_call" in call and result in call
+    got = (_lowered_digest(fwd)[:32], _lowered_digest(bwd)[:32])
+    assert got == LING3_CALLS[case], got
+
+
 def _expert_step(moe, gated, shadowed=False):
     """One expert layer's step on `_grouped_dot` / `_grouped_dot_grads` as
     the Program runs them under AMP: float32 stacks cast to bf16 at their

@@ -571,6 +571,15 @@ SPECS.update({
                                      "Beta": T(1, 8, 2, lo=0.1, hi=0.9)},
                              attrs={"chunk": 4}),
     "relu2": Spec(inputs={"X": T(3, 4)}),
+    "kda_gates": Spec(inputs={"F": T(2, 4, 6), "B": T(2, 4, 2),
+                              "ALog": T(2), "DtBias": T(6)},
+                      attrs={"lower_bound": -5.0}, outs=("G", "Beta")),
+    # two chunks of four tokens, two heads, a decay per key channel
+    "kda_delta_rule": Spec(inputs={"Q": T(1, 8, 2, 4), "K": T(1, 8, 2, 4),
+                                   "V": T(1, 8, 2, 3),
+                                   "G": T(1, 8, 2, 4, lo=-5.0, hi=-0.05),
+                                   "Beta": T(1, 8, 2, lo=0.1, hi=0.9)},
+                           attrs={"chunk": 4}),
     "ssd_gates": Spec(inputs={"DtRaw": T(2, 4, 3), "DtBias": T(3),
                               "ALog": T(3)}, outs=("Dt", "A")),
     # two chunks of four tokens, a group serving two heads
@@ -792,6 +801,17 @@ VARIANTS = {
     "gated_rms_norm+gate_first": ("gated_rms_norm", Spec(
         inputs={"X": T(3, 3, 4), "Gate": T(3, 3, 4), "Scale": POS(12)},
         attrs={"gate_first": True, "epsilon": 1e-5}, outs=("Y",))),
+    # a KDA layer's output norm: the gate's sigmoid in its silu's place
+    "gated_rms_norm+sigmoid": ("gated_rms_norm", Spec(
+        inputs={"X": T(3, 3, 4), "Gate": T(3, 3, 4), "Scale": POS(4)},
+        attrs={"activation": "sigmoid", "epsilon": 1e-6}, outs=("Y",))),
+    # four groups of two experts of which two stay, a selection bias
+    "moe_router+groups": ("moe_router", Spec(
+        inputs={"X": T(6, 5), "W": T(5, 8) * 2, "Bias": T(8) * 0.1},
+        attrs={"k": 2, "score_func": "sigmoid", "norm_topk_prob": True,
+               "n_group": 4, "topk_group": 2},
+        outs=("TopKWeight", "TopKIndex", "TokensPerExpert", "Probs",
+              "LogSumExp"))),
 }
 
 
