@@ -1516,7 +1516,14 @@ def kda_delta_rule(q, k, v, f, b, a_log_attr=None, dt_bias_attr=None,
     their running sums, every `exp`, the l2-norms, the solve and the state
     float32. Returns `[batch, seq, heads, value_dim]` in v's dtype. The grad
     op is registered (`kda_delta_rule_grad`) and returns g's gradient per
-    channel."""
+    channel.
+
+    The op has a second output, `States`: float32 `[seq / chunk, batch,
+    heads, key_dim, value_dim]`, the state each chunk started from, as the
+    forward kernel `kda_fwd` saves it. `kda_delta_rule_grad` reads it back
+    and runs `kda_bwd` alone. Where the forward op wrote none (head dims that
+    do not fill a vreg, a CPU backend: the XLA form) the grad op traces the
+    rule again under `jax.vjp`."""
     helper = LayerHelper("kda_delta_rule", name=name)
     heads, key_dim = q.shape[2], q.shape[3]
     a_log = helper.create_parameter(a_log_attr, [heads], "float32")
@@ -1531,10 +1538,11 @@ def kda_delta_rule(q, k, v, f, b, a_log_attr=None, dt_bias_attr=None,
                      outputs={"G": [g.name], "Beta": [beta.name]},
                      attrs={"lower_bound": float(lower_bound)})
     out = new(v.dtype)
+    states = new("float32", stop_gradient=True)
     helper.append_op("kda_delta_rule",
                      inputs={"Q": [q.name], "K": [k.name], "V": [v.name],
                              "G": [g.name], "Beta": [beta.name]},
-                     outputs={"Out": [out.name]},
+                     outputs={"Out": [out.name], "States": [states.name]},
                      attrs={"chunk": int(chunk)})
     return out
 

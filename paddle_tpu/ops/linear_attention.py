@@ -159,18 +159,46 @@ not depend on it. The other exponents, `exp(G)`, `exp(G_last - G)` and
 is formed: a block's rows times the keys it reaches is one product of two
 `[.., Dk]` operands.
 
-The op is that XLA form on every backend (`kda_plan: "xla"` on the compile
-event): A, the solve, u and w for all chunks at once, a `lax.scan` over the
-chunks' states, the outputs for all chunks at once; A and the solve at
-HIGHEST, the rest at the default precision. Float32 whatever dtype flows
-through: g, beta, G, every `exp`, the l2-norms, A, T and the state. The grad
-op is registered (`kda_delta_rule_grad`): `jax.vjp` of the same form, which
-makes a chunk's factors again from the op's inputs, so the forward saves
-nothing and returns dG per channel. Both tally the rule's chunk steps (batch
-x heads x chunks, `kda_grid_steps`). A pair of Pallas kernels on `gdn_fwd` /
-`gdn_bwd`'s grid (the state and dS in VMEM scratch, the states saved a chunk)
-is what ROADMAP M6 queues; `_Chunks`' blocked substitution and `_plan`'s
-envelope carry over, the Gram tiles' two-sided blocks do not exist there yet.
+Where a chunk's tiles fill vregs (`_plan`'s envelope, as for the scalar rule:
+chunk 64, head dims multiples of 128) the rule is two more Pallas kernels on
+`gdn_fwd` / `gdn_bwd`'s grid (`_gdn_call`: batch, head, step of `p` = 2
+chunks; q, k, v, o and here G `[B, T, H * Dk]` read where they lie), one head
+a step, `_KdaChunks` in `_Chunks`' two layouts:
+
+    kda_fwd   S [Dk, Dv] float32 in scratch across a head's steps; per step
+              the exponentials of G relative to each 16-row block's first
+              and middle row ([p C, Dk] tiles), the Gram tiles by row block
+              (a block's rows of both chunks against the zero-padded keys
+              before it: one product of [p 16, Dk] by [p C, Dk]; the diagonal
+              blocks of all rows: one product of the stacked pair), A at
+              HIGHEST and P at the default precision; T by
+              `_Chunks.inverses`; u, w; then the state through the chunks,
+              its rows scaled by the column `exp(G_last)` [Dk, 1]. Writes o
+              and `States`, as `gdn_fwd` does.
+    kda_bwd   the steps and chunks last to first, dS in scratch, the factors
+              made again from the inputs and the saved states; dA and dP go
+              back through `tiles_grad`, each half of a tile giving G its
+              part (`x dx` to the rows, `-k dk` to the keys, none through a
+              reference row); dG leaves per token and channel [B, T, H * Dk]
+              float32 (through `exp(G)`, the tail's `exp(G_last - G)`,
+              `exp(G_last)` at a chunk's last token, and the tiles), and g's
+              gradient is its reverse running sum inside a chunk: that sum
+              and the running sum G itself stay XLA ops (`_running_sum`).
+
+Precisions are the XLA form's: float32 for g, G, beta, every `exp`, the
+l2-norms, A, T, the state, dS and every accumulator; HIGHEST for A's products
+(and their transposes in the backward), the merges of T and `T [beta v |
+beta k exp(G)]`; the backend's default for `w S`, `k_tail^T v'`, `q S`, P's
+products and `P v'`. Outside the envelope, and on a CPU backend unless the
+interpreter is asked for, the op keeps `chunked_kda_rule`: A, the solve, u
+and w for all chunks at once, a `lax.scan` over the chunks' states, the
+outputs for all chunks at once. The grad op is registered
+(`kda_delta_rule_grad`): on the saved `States` it runs `kda_bwd` alone; where
+the forward saved none it is `jax.vjp` of the XLA form. On the compile event:
+`kda_plan` (`"kernel"` or `"xla"`), the rule's chunk steps whatever runs them
+(`kda_grid_steps`: batch x heads x chunks, the op and its grad op) and the
+grid steps the two kernel calls ran (`kda_kernel_grid_steps`: batch x heads x
+chunks / `p`).
 """
 
 from __future__ import annotations
@@ -482,6 +510,14 @@ class _Chunks:
     gives the factors of the key head's value heads that read no state."""
 
     def __init__(self, q_ref, k_ref, g_ref, beta_ref, hk, r, p):
+        self._operands(q_ref, k_ref, hk, r, p)
+        self.kk = self.beside(_dot(self.k, self.k, _NT, full=True))  # k k^T
+        self.qk = self.beside(_dot(self.q, self.k, _NT))             # q k^T
+        self.G_tile, self.beta_tile = g_ref[0], beta_ref[0]      # [p C, Hv]
+
+    def _operands(self, q_ref, k_ref, hk, r, p):
+        """q and k of the step, normalised, and the two layouts' indices:
+        what the per-channel rule's step (`_KdaChunks`) starts from too."""
         n, Dk = q_ref.shape[1], q_ref.shape[2]
         self.r, self.p, self.C = r, p, n // p
         C = self.C
@@ -494,9 +530,6 @@ class _Chunks:
         self.row = lax.broadcasted_iota(jnp.int32, (C, n), 0)
         self.col = jnp.bitwise_and(lane, C - 1)         # place in its chunk
         self.part = jnp.right_shift(lane, C.bit_length() - 1)   # its chunk
-        self.kk = self.beside(_dot(self.k, self.k, _NT, full=True))  # k k^T
-        self.qk = self.beside(_dot(self.q, self.k, _NT))             # q k^T
-        self.G_tile, self.beta_tile = g_ref[0], beta_ref[0]      # [p C, Hv]
 
     def of(self, i, x):                                 # chunk i's rows
         return x[i * self.C:(i + 1) * self.C]
@@ -767,13 +800,14 @@ def _grid(Q, V, chunk):
 
 
 def _gdn_call(kernel, name, Q, K, V, G, beta, more, out_shape, out_blocks,
-              chunk, reverse):
-    """Both kernels' grid and blocks: (batch, key head, step of `p`
-    chunks), the last axis sequential. q, k, v and their like are read where
-    they lie, as [B, T, heads * dim] with a head's lanes chosen by the block
-    index (a key head's `r` value heads are `r * Dv` adjacent lanes, so
+              chunk, reverse, decay="gates"):
+    """Both kernels' grid and blocks, of both rules: (batch, key head, step
+    of `p` chunks), the last axis sequential. q, k, v and their like are read
+    where they lie, as [B, T, heads * dim] with a head's lanes chosen by the
+    block index (a key head's `r` value heads are `r * Dv` adjacent lanes, so
     nothing is repeated); G and beta as [B, T, Hv], every head of the step's
-    tokens in one block."""
+    tokens in one block; the per-channel rule's G [B, T, H * Dk] is read like
+    a key (`decay="key"`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -794,7 +828,7 @@ def _gdn_call(kernel, name, Q, K, V, G, beta, more, out_shape, out_blocks,
                                lambda b, h, c: (at(c), b, h, 0, 0)),
         "gate_rows": pl.BlockSpec((1, r, p, 1, chunk),
                                   lambda b, h, c: (b, h, at(c), 0, 0))}
-    ins = ["key", "key", "value", "gates", "gates"] + [x for x, _ in more]
+    ins = ["key", "key", "value", decay, "gates"] + [x for x, _ in more]
     return pl.pallas_call(
         functools.partial(kernel, r=r, p=p), name=name, grid=grid,
         in_specs=[blocks[x] for x in ins],
@@ -818,6 +852,10 @@ def _gdn_forward(Q, K, V, g, beta, chunk):
     return out.reshape(V.shape), states
 
 
+def _per_token(x):      # gate rows [B, Hv, chunks, 1, C] -> [B, chunks, C, Hv]
+    return jnp.transpose(x[:, :, :, 0, :], (0, 2, 3, 1))
+
+
 def _gdn_backward(Q, K, V, g, beta, states, d_out, chunk):
     """The five input gradients from the saved states and `d_out`
     [B, T, Hv, Dv], each in its input's shape and dtype."""
@@ -828,14 +866,271 @@ def _gdn_backward(Q, K, V, g, beta, states, d_out, chunk):
         _bwd_shapes(Q, V, chunk),
         ["gate_rows", "gate_rows", "value", "key", "key"], chunk,
         reverse=True)
-
-    def per_token(x):   # [B, Hv, chunks, 1, C] -> [B, chunks, C, Hv]
-        return jnp.transpose(x[:, :, :, 0, :], (0, 2, 3, 1))
-
-    dg = lax.cumsum(per_token(dG), axis=2, reverse=True)
+    dg = lax.cumsum(_per_token(dG), axis=2, reverse=True)
     return (dq.reshape(Q.shape), dk.reshape(K.shape), dv.reshape(V.shape),
             dg.reshape(g.shape).astype(g.dtype),
-            per_token(dbeta).reshape(beta.shape).astype(beta.dtype))
+            _per_token(dbeta).reshape(beta.shape).astype(beta.dtype))
+
+
+# ---------------------------------------------------------------------------
+# the per-channel rule's two kernels, on the same grid (module docstring)
+# ---------------------------------------------------------------------------
+
+class _KdaChunks(_Chunks):
+    """One (batch, head, `p` chunks) grid step of the rule under a decay per
+    key channel, in `_Chunks`' two layouts (`r` = 1: as many value heads as
+    key heads). G is [p C, Dk], so the decay cannot leave the Gram tiles'
+    contraction: a tile's two operands each carry their half of it,
+    relative to a reference row of the 16-row block (`_KDA_BLOCK`) the
+    tile's rows lie in, as `chunked_kda_rule` has it: against the keys of
+    the blocks before it the block's first row (`to_first`, `before[b]`:
+    every exponent <= 0), against the block's own keys its middle row
+    (`to_mid`, `from_mid`: within +-8 |min g|). No gradient passes through a
+    reference."""
+
+    def __init__(self, q_ref, k_ref, g_ref, beta_ref, h, p):
+        self._operands(q_ref, k_ref, h, 1, p)
+        C, B, Dk = self.C, _KDA_BLOCK, q_ref.shape[2]
+        n = p * C
+        self.nb = C // B
+        G = self.G = g_ref[0]                   # running sum, <= 0
+        self.beta = self._column(beta_ref[0], 0)
+        self.beta_b = self.beside(self.beta)
+        shift = B.bit_length() - 1
+        self.same = jnp.right_shift(self.row, shift) \
+            == jnp.right_shift(self.col, shift)     # a tile's diagonal blocks
+        lane = lax.broadcasted_iota(jnp.int32, (B, n), 1)   # of a block's rows
+        self.block_col = jnp.bitwise_and(lane, C - 1)
+        self.block_part = jnp.right_shift(lane, C.bit_length() - 1)
+
+        def spread(at):     # row `at` of every block, over the block's rows
+            return jnp.concatenate(
+                [jnp.broadcast_to(G[r0 + at:r0 + at + 1], (B, Dk))
+                 for r0 in range(0, n, B)], axis=0)
+
+        middle = spread(B // 2)
+        self.to_first = jnp.exp(G - spread(0))
+        self.to_mid, self.from_mid = jnp.exp(G - middle), jnp.exp(middle - G)
+        self.k_own = self.k * self.from_mid
+
+        def before(b):      # exp(first_b - G_j) of the keys before block b
+            parts = []
+            for c0 in range(0, n, C):
+                first = G[c0 + b * B:c0 + b * B + 1]
+                parts += [jnp.exp(first - G[c0:c0 + b * B]),
+                          jnp.zeros((C - b * B, Dk), jnp.float32)]
+            return jnp.concatenate(parts, axis=0)
+
+        self.before = [None] + [before(b) for b in range(1, self.nb)]
+        self.k_before = [None] + [self.k * x for x in self.before[1:]]
+
+    def block(self, x, b):
+        """Stacked [p C, m] -> block b of every chunk, stacked [p 16, m]."""
+        B = _KDA_BLOCK
+        return jnp.concatenate(
+            [self.of(i, x)[b * B:(b + 1) * B] for i in range(self.p)], axis=0)
+
+    def unblock(self, blocks):
+        """`block`'s inverse: `blocks[b]` [p 16, m] -> stacked [p C, m]."""
+        B = _KDA_BLOCK
+        return jnp.concatenate([x[i * B:(i + 1) * B] for i in range(self.p)
+                                for x in blocks], axis=0)
+
+    def tiles(self, x, full):
+        """Rows x [p C, Dk] -> beside [C, p C]: `sum_c x_i[c] k_j[c]
+        exp(G_i[c] - G_j[c])` for the keys j up to the end of row i's block
+        (zeros after it). A block's rows of both chunks against the padded
+        keys before it are one product; the diagonal blocks of all rows
+        another."""
+        B, part = _KDA_BLOCK, self.block_part
+        xf = x * self.to_first
+        rows = [jnp.zeros((B, self.p * self.C), jnp.float32)]
+        for b in range(1, self.nb):
+            t = _dot(self.block(xf, b), self.k_before[b], _NT, full=full)
+            out = t[:B]                         # chunk i's rows, its lanes
+            for i in range(1, self.p):
+                out = jnp.where(part == i, t[i * B:(i + 1) * B], out)
+            rows.append(out)
+        own = self.beside(_dot(x * self.to_mid, self.k_own, _NT, full=full))
+        return jnp.concatenate(rows, axis=0) + jnp.where(self.same, own, 0.0)
+
+    def tiles_grad(self, x, dz, full):
+        """The gradient dz (beside, zero above the diagonal) of `tiles(x)`
+        to its operands: (dx, dk, dG), each [p C, Dk]. Each half of a tile
+        gives G its part, `x dx` to the rows and `-k dk` to the keys."""
+        B, part, col = _KDA_BLOCK, self.block_part, self.block_col
+        xf = x * self.to_first
+        dxf = [jnp.zeros((self.p * B, x.shape[1]), jnp.float32)]
+        dk = dG = 0.0
+        for b in range(1, self.nb):
+            rows = dz[b * B:(b + 1) * B]
+            d = jnp.concatenate(
+                [jnp.where((part == i) & (col < b * B), rows, 0.0)
+                 for i in range(self.p)], axis=0)           # [p 16, p C]
+            dxf.append(_dot(d, self.k_before[b], _NN, full=full))
+            dkr = _dot(d, self.block(xf, b), _TN, full=full)
+            dk = dk + dkr * self.before[b]
+            dG = dG - dkr * self.k_before[b]
+        dxf = self.unblock(dxf)
+        d = self.apart(jnp.where(self.same, dz, 0.0))
+        xm = x * self.to_mid
+        dxm = _dot(d, self.k_own, _NN, full=full)
+        dkr = _dot(d, xm, _TN, full=full)
+        return (dxf * self.to_first + dxm * self.to_mid,
+                dk + dkr * self.from_mid,
+                dG + xf * dxf + xm * dxm - dkr * self.k_own)
+
+    def transposed(self, x):
+        """A row [1, Dk] as a column [Dk, 1], or back."""
+        n = max(x.shape)
+        eye = lax.broadcasted_iota(jnp.int32, (n, n), 0) \
+            == lax.broadcasted_iota(jnp.int32, (n, n), 1)
+        spread = jnp.where(eye, jnp.broadcast_to(x, (n, n)), 0.0)
+        return _rows(spread) if x.shape[0] == 1 else _cols(spread)
+
+    def factors(self, v_ref):
+        """The chunks' factors that read no state (module docstring's
+        names): v, u, w, qg, k_tail, eg, tail stacked; kkD, P beside; t
+        block diagonal; a chunk's last decay as a row `e_last[i]` [1, Dk]
+        and as the column `e_col[i]` [Dk, 1] that scales the state's
+        rows."""
+        C, G, beta = self.C, self.G, self.beta
+        kkD = self.tiles(self.k, True)
+        a = jnp.where(self.row > self.col, kkD * self.beta_b, 0.0)
+        P = jnp.where(self.row >= self.col, self.tiles(self.q, False), 0.0)
+        (t,) = self.inverses([a])
+        eg = jnp.exp(G)
+        v = v_ref[0].astype(jnp.float32)
+        k_beg = self.k * (beta * eg)
+        last = [G[(i + 1) * C - 1:(i + 1) * C] for i in range(self.p)]
+        tail = jnp.exp(self.each(
+            lambda i: jnp.broadcast_to(last[i], (C, G.shape[1]))) - G)
+        e_last = [jnp.exp(x) for x in last]
+        return dict(kkD=kkD, P=P, t=t, eg=eg, v=v, tail=tail,
+                    u=_dot(t, v * beta, _NN, full=True),
+                    w=_dot(t, k_beg, _NN, full=True), qg=self.q * eg,
+                    k_tail=self.k * tail, e_last=e_last,
+                    e_col=[self.transposed(x) for x in e_last])
+
+
+def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, o_ref,
+                    s_sc, *, r, p):
+    """`_gdn_fwd_kernel`'s step for one head (`r` is 1) under the per-channel
+    decay: the state's rows decay each by their own channel's
+    `exp(G_last)`."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        s_sc[...] = jnp.zeros_like(s_sc)
+
+    ch = _KdaChunks(q_ref, k_ref, g_ref, beta_ref, pl.program_id(1), p)
+    f = ch.factors(v_ref)
+    S = s_sc[0]
+    v_new, from_state = [], []
+    for i in range(p):
+        states_ref[i, 0, 0] = S
+        v_new.append(ch.of(i, f["u"]) - _dot(ch.of(i, f["w"]), S, _NN))
+        from_state.append(_dot(ch.of(i, f["qg"]), S, _NN))
+        S = S * f["e_col"][i] + _dot(ch.of(i, f["k_tail"]), v_new[i], _TN)
+    s_sc[0] = S
+    o = jnp.concatenate(from_state, axis=0) \
+        + _dot(ch.apart(f["P"]), jnp.concatenate(v_new, axis=0), _NN)
+    o_ref[0] = o.astype(o_ref.dtype)
+
+
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
+                    dG_ref, dbeta_ref, dv_ref, dq_ref, dk_ref, ds_sc, *, r,
+                    p):
+    """`_gdn_bwd_kernel`'s step for one head under the per-channel decay. dG
+    is [p C, Dk]: the gradient of the running sum at each token and channel,
+    through `exp(G)`, the tail's `exp(G_last - G)`, `exp(G_last)` (at a
+    chunk's last token) and both halves of every Gram tile (g's is its
+    reverse running sum inside a chunk, taken outside)."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        ds_sc[...] = jnp.zeros_like(ds_sc)
+
+    ch = _KdaChunks(q_ref, k_ref, g_ref, beta_ref, pl.program_id(1), p)
+    C, beta = ch.C, ch.beta
+    f = ch.factors(v_ref)
+    eg, tail, u, w = f["eg"], f["tail"], f["u"], f["w"]
+    # o = qg S + P v';  S' = Diag(e_last) S + k_tail^T v';  v' = u - w S
+    S = [states_ref[i, 0, 0] for i in range(p)]
+    dO = do_ref[0].astype(jnp.float32)
+    v_new = u - ch.each(lambda i: _dot(ch.of(i, w), S[i], _NN))
+    from_out = _dot(ch.apart(f["P"]), dO, _TN)
+    dS = [None] * p + [ds_sc[0]]            # dS[i + 1]: of what chunk i gives
+    dv_new = [None] * p
+    for i in reversed(range(p)):
+        dv_new[i] = ch.of(i, from_out) \
+            + _dot(ch.of(i, f["k_tail"]), dS[i + 1], _NN)
+        dS[i] = _dot(ch.of(i, f["qg"]), ch.of(i, dO), _TN) \
+            + dS[i + 1] * f["e_col"][i] - _dot(ch.of(i, w), dv_new[i], _TN)
+    ds_sc[0] = dS[0]
+    dv_new = jnp.concatenate(dv_new, axis=0)
+    dP = jnp.where(ch.row >= ch.col, ch.beside(_dot(dO, v_new, _NT)), 0.0)
+    dqg = ch.each(lambda i: _dot(ch.of(i, dO), S[i], _NT))
+    dk_tail = ch.each(lambda i: _dot(ch.of(i, v_new), dS[i + 1], _NT))
+    dw = -ch.each(lambda i: _dot(ch.of(i, dv_new), S[i], _NT))
+    # [u | w] = T [beta v | beta exp(G) k]:  dR = T^T dX,
+    # dA = -strict_lower(dR X^T)
+    dRu = _dot(f["t"], dv_new, _TN, full=True)
+    dRw = _dot(f["t"], dw, _TN, full=True)
+    dA = -jnp.where(ch.row > ch.col, ch.beside(
+        _dot(dRu, u, _NT, full=True) + _dot(dRw, w, _NT, full=True)), 0.0)
+    dv_ref[0] = (dRu * beta).astype(dv_ref.dtype)
+    through_w = dRw * ch.k * eg                     # w's rows: beta eg k
+    dbeta_row = ch.as_row(_rows(dRu * f["v"] + through_w)
+                          + ch.rows(dA * f["kkD"]))
+    d_tail = dk_tail * f["k_tail"]
+    dkx, dk_a, dG_a = ch.tiles_grad(ch.k, dA * ch.beta_b, True)
+    dqx, dk_p, dG_p = ch.tiles_grad(ch.q, dP, False)
+    dG = through_w * beta + dqg * f["qg"] - d_tail + dG_a + dG_p
+    # G_last, a chunk's last row, also through the tail and e_last
+    at = lax.broadcasted_iota(jnp.int32, dG.shape, 0)
+    for i in range(p):
+        d_last = _cols(ch.of(i, d_tail)) \
+            + ch.transposed(_rows(S[i] * dS[i + 1])) * f["e_last"][i]
+        dG = dG + jnp.where(at == (i + 1) * C - 1, d_last, 0.0)
+        dbeta_ref[0, 0, i] = dbeta_row[:, i * C:(i + 1) * C]
+    dG_ref[0] = dG
+    dq = (dqg * eg + dqx) * ch.scale
+    dk = dRw * (beta * eg) + dk_tail * tail + dkx + dk_a + dk_p
+    dq_ref[0] = _l2_grad(ch.qn, ch.rq, dq).astype(dq_ref.dtype)
+    dk_ref[0] = _l2_grad(ch.k, ch.rk, dk).astype(dk_ref.dtype)
+
+
+def _kda_forward(Q, K, V, g, beta, chunk):
+    """Q, K, V [B, T, H, D] as they arrive (not normalised), g [B, T, H, Dk],
+    beta [B, T, H] -> out in V's shape and dtype and the states [chunks, B,
+    H, Dk, Dv] float32, each as its chunk found it."""
+    states, out = _gdn_call(
+        _kda_fwd_kernel, "kda_fwd", Q, K, V, _running_sum(_flat(g), chunk),
+        beta.astype(jnp.float32), [], _fwd_shapes(Q, V, chunk),
+        ["states", "value"], chunk, reverse=False, decay="key")
+    return out.reshape(V.shape), states
+
+
+def _kda_backward(Q, K, V, g, beta, states, d_out, chunk):
+    """The five input gradients (g's per key channel) from the saved states
+    and `d_out`, each in its input's shape and dtype."""
+    B, T, H, Dk = Q.shape
+    _, dbeta, dv, dq, dk = _bwd_shapes(Q, V, chunk)
+    dG = jax.ShapeDtypeStruct((B, T, H * Dk), jnp.float32)
+    dG, dbeta, dv, dq, dk = _gdn_call(
+        _kda_bwd_kernel, "kda_bwd", Q, K, V, _running_sum(_flat(g), chunk),
+        beta.astype(jnp.float32),
+        [("states", states), ("value", _flat(d_out.astype(V.dtype)))],
+        (dG, dbeta, dv, dq, dk), ["key", "gate_rows", "value", "key", "key"],
+        chunk, reverse=True, decay="key")
+    dg = lax.cumsum(dG.reshape(B, T // chunk, chunk, H * Dk), axis=2,
+                    reverse=True)
+    return (dq.reshape(Q.shape), dk.reshape(K.shape), dv.reshape(V.shape),
+            dg.reshape(g.shape).astype(g.dtype),
+            _per_token(dbeta).reshape(beta.shape).astype(beta.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -1094,9 +1389,9 @@ def _tally_grid(ctx, Q, V, chunk):
 
 
 def _gated_delta_rule_infer(ctx, structs):
-    """Build-time shapes without a trace of the rule: a machine with no TPU
-    takes the XLA form, which saves no `States`, and the program it builds
-    may run on one that has."""
+    """Build-time shapes without a trace of the rule, of both rules' ops: a
+    machine with no TPU takes the XLA form, which saves no `States`, and the
+    program it builds may run on one that has."""
     Q, V = structs["Q"][0], structs["V"][0]
     states, _ = _fwd_shapes(Q, V, int(ctx.attr("chunk", 64)))
     return {"Out": jax.ShapeDtypeStruct(V.shape, V.dtype), "States": states}
@@ -1175,10 +1470,14 @@ def _kda_rule(Q, K, V, G, Beta, chunk):
     return out.astype(V.dtype)
 
 
-def _kda_check(ctx, Q, V, G):
-    """The chunk, after the shapes are checked and the rule's chunk steps
-    (batch x heads x chunks, summed over the program's ops and grad ops) are
-    tallied onto the compile event as `kda_grid_steps`."""
+def _kda_check(ctx, Q, V, G, kernels=None):
+    """(chunk, whether a kernel runs: by the shape and the backend, unless
+    the caller knows), after the shapes are checked and the call is counted
+    on the compile event: `kda_plan`, the rule's chunk steps
+    (`kda_grid_steps`: batch x heads x chunks, summed over the program's ops
+    and grad ops, whatever runs them) and, where a kernel runs, the grid
+    steps its call takes (`kda_kernel_grid_steps`: batch x heads x chunks /
+    `p`)."""
     chunk = int(ctx.attr("chunk", 64))
     B, T, H, Dk = Q.shape
     if T % chunk or V.shape[2] != H or G.shape != Q.shape:
@@ -1186,33 +1485,50 @@ def _kda_check(ctx, Q, V, G):
                          f"of the chunk ({chunk}), as many value heads as "
                          f"key heads and a decay of q's shape, got q "
                          f"{Q.shape}, v {V.shape}, g {G.shape}")
-    ctx.note(kda_plan="xla")
+    if kernels is None:
+        kernels = _kernels_run(Dk, V.shape[3], chunk)
+    ctx.note(kda_plan="kernel" if kernels else "xla")
     ctx.tally("kda_grid_steps", B * H * (T // chunk))
-    return chunk
+    if kernels:
+        ctx.tally("kda_kernel_grid_steps", B * H * _grid(Q, V, chunk)[0][2])
+    return chunk, kernels
 
 
-@register_op("kda_delta_rule", propagate_seqlen=False)
+@register_op("kda_delta_rule", infer=_gated_delta_rule_infer,
+             propagate_seqlen=False)
 def _kda_delta_rule(ctx, Q, K, V, G, Beta):
     """Q, K [B, T, H, Dk], V [B, T, H, Dv], G [B, T, H, Dk] (the log-decay of
     every key channel, float32, bounded below: `kda_gates`), Beta [B, T, H]
     -> Out [B, T, H, Dv] in V's dtype: per head a state S [Dk, Dv] from 0,
     `S <- Diag(exp(g_t)) S;  d = beta_t (v_t - S^T k_t);  S <- S + k_t d^T;
-    o_t = S^T q_t`, in chunks of `chunk` tokens (`chunked_kda_rule`). q and k
-    are l2-normalised over a head (`x * rsqrt(sum x^2 + 1e-6)`), q then
-    scaled by `Dk^-0.5`."""
-    return {"Out": _kda_rule(Q, K, V, G, Beta, _kda_check(ctx, Q, V, G))}
+    o_t = S^T q_t`, in chunks of `chunk` tokens. q and k are l2-normalised
+    over a head (`x * rsqrt(sum x^2 + 1e-6)`), q then scaled by `Dk^-0.5`.
+    On the kernel path (`_plan`: `kda_fwd`) the rule also returns `States`
+    [T / chunk, B, H, Dk, Dv] float32, the state each chunk started from,
+    which the grad op reads back; elsewhere it is `chunked_kda_rule`."""
+    chunk, kernels = _kda_check(ctx, Q, V, G)
+    if kernels:
+        out, states = _kda_forward(Q, K, V, G, Beta, chunk)
+        return {"Out": out, "States": states}
+    return {"Out": _kda_rule(Q, K, V, G, Beta, chunk)}
 
 
 @register_grad("kda_delta_rule")
 def _kda_delta_rule_grad(ctx, ins, out_grads):
-    """The five input gradients (G's per key channel) by `jax.vjp` of the
-    chunked form, which makes a chunk's factors again from the op's inputs:
-    the forward saves nothing."""
+    """The five input gradients (G's per key channel). Where the forward op
+    saved its `States`, `kda_bwd` alone on them; where it saved none (the
+    XLA form, a program built without the slot) `jax.vjp` of the chunked
+    form, which makes a chunk's factors again from the op's inputs."""
     d_out = out_grads["Out"][0]
     if d_out is None:
         return {}
     raw = [ins[s][0] for s in _KDA_SLOTS]
-    chunk = _kda_check(ctx, raw[0], raw[2], raw[3])
-    out, vjp = jax.vjp(functools.partial(_kda_rule, chunk=chunk), *raw)
-    return {s: d.astype(x.dtype)
-            for s, d, x in zip(_KDA_SLOTS, vjp(d_out.astype(out.dtype)), raw)}
+    states = ctx.fwd_outs.get("States", [None])[0]
+    chunk, kernels = _kda_check(ctx, raw[0], raw[2], raw[3],
+                                kernels=states is not None)
+    if kernels:
+        grads = _kda_backward(*raw, states, d_out, chunk)
+    else:
+        out, vjp = jax.vjp(functools.partial(_kda_rule, chunk=chunk), *raw)
+        grads = vjp(d_out.astype(out.dtype))
+    return {s: d.astype(x.dtype) for s, d, x in zip(_KDA_SLOTS, grads, raw)}

@@ -865,9 +865,13 @@ def test_layer_census_reads_the_issues_counts():
 
 def test_the_compile_event_counts_the_rules_chunk_steps(tiny):
     """`kda_plan` and `kda_grid_steps` on the main program's compile event:
-    5 layers x (the op and its grad op) x batch 2 x 4 heads x 4 chunks."""
+    5 layers x (the op and its grad op) x batch 2 x 4 heads x 4 chunks. Heads
+    of 16 fill no vreg, so the rule keeps the XLA form here and no kernel
+    grid step is tallied (`tests/test_kda_kernels.py` holds `"kernel"` and
+    `kda_kernel_grid_steps` at heads of 128)."""
     assert tiny["details"]
     detail = tiny["details"][-1]
     assert detail["kda_plan"] == "xla"
+    assert "kda_kernel_grid_steps" not in detail
     assert detail["kda_grid_steps"] == 5 * 2 * 2 * 4 * 4
     assert detail["kda_layers"] == 5

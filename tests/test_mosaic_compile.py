@@ -70,6 +70,67 @@ def test_gdn_kernels_compile_at_the_cells_shapes(one_chip, monkeypatch):
     assert "(f32[1,32,64,1,64]{" in call and "tpu_custom_call" in call
 
 
+# `gdn_fwd` / `gdn_bwd` as `qwen3_next_80b_a3b.bs1` calls them: digests by
+# `_lowered_digest` taken on the commit before the per-channel rule's kernels
+# came to share `_Chunks`, `_plan` and `_gdn_call` with them (e20fc17, PR 61)
+GDN_CALLS = ("b16ac51de9d9bce6e64e6ba38b4e7a4e",
+             "2e9c9902e19d98025ce25bdc340717a5")
+
+
+def test_gdn_kernels_lower_as_they_did_before_the_kda_pair(one_chip,
+                                                           monkeypatch):
+    """The scalar rule's two kernels are the instructions they were: what
+    the two rules share changed no line of their text."""
+    monkeypatch.setattr(_kernels, "on_chip", lambda: True)
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q = arg((1, 4096, 16, 128), jnp.bfloat16)
+    v = arg((1, 4096, 32, 128), jnp.bfloat16)
+    g = arg((1, 4096, 32), jnp.float32)
+    states = arg((64, 1, 32, 128, 128), jnp.float32)
+    fwd = jax.jit(lambda *a: la._gdn_forward(*a, 64)).lower(q, q, v, g, g)
+    bwd = jax.jit(lambda *a: la._gdn_backward(*a, 64)).lower(
+        q, q, v, g, g, states, v)
+    assert (_lowered_digest(fwd)[:32], _lowered_digest(bwd)[:32]) \
+        == GDN_CALLS
+
+
+def test_kda_kernels_compile_at_the_cells_shapes(one_chip, monkeypatch):
+    """`kda_fwd` and `kda_bwd` as `ling_3_0_flash_vl.s2048` calls them: bf16
+    q, k, v `[1, 2048, 32, 128]`, g float32 `[1, 2048, 32, 128]`, beta
+    `[1, 2048, 32]`, the chip's one-pass products; each is one Mosaic custom
+    call. What the interpreter cannot refuse here: the 16-row blocks sliced
+    out of a chunk's rows, a row spread over a block, a `[1, 128]` row
+    turned into a `[128, 1]` column, the room ~100 float32 `[128, 128]`
+    tiles take."""
+    monkeypatch.setattr(_kernels, "on_chip", lambda: True)
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x = arg((1, 2048, 32, 128), jnp.bfloat16)
+    g = arg((1, 2048, 32, 128), jnp.float32)
+    beta = arg((1, 2048, 32), jnp.float32)
+    states = arg((32, 1, 32, 128, 128), jnp.float32)
+    assert la._grid(x, x, 64) == ((1, 32, 16), 2)       # two chunks a step
+    fwd = jax.jit(lambda *a: la._kda_forward(*a, 64)).lower(
+        x, x, x, g, beta).compile()
+    (call,) = _custom_calls(fwd, "kda_fwd")
+    assert "(f32[32,1,32,128,128]{" in call and "tpu_custom_call" in call
+    assert ", bf16[1,2048,4096]{" in call
+    bwd = jax.jit(lambda *a: la._kda_backward(*a, 64)).lower(
+        x, x, x, g, beta, states, x).compile()
+    (call,) = _custom_calls(bwd, "kda_bwd")
+    assert "(f32[1,2048,4096]{" in call and "tpu_custom_call" in call
+    assert call.split(" custom-call(")[0].count("bf16[1,2048,4096]{") == 3
+    assert not _custom_calls(fwd, "gdn_fwd")
+    assert not _custom_calls(bwd, "gdn_bwd")
+
+
 def test_causal_conv_kernels_compile_at_the_cells_shape(one_chip,
                                                         monkeypatch):
     """`causal_conv_fwd` and `causal_conv_bwd` as `qwen3_next_80b_a3b.bs1`
@@ -661,8 +722,8 @@ def test_ling3_kernels_compile_at_the_cells_shapes(one_chip, monkeypatch,
     (KDA's [q | k | v], 4 taps, no bias) and `gated_norm_fwd` /
     `gated_norm_bwd` with `activation="sigmoid"` over `[1, 2048, 32, 128]`:
     one Mosaic custom call each for a described v5e, lowered to the recorded
-    text; the sigmoid form is not silu's text. (The rule itself is XLA ops:
-    no kernel of its own to compile.)"""
+    text; the sigmoid form is not silu's text. (The rule's own pair:
+    `test_kda_kernels_compile_at_the_cells_shapes`.)"""
     from paddle_tpu.ops import decoder_block as db
     monkeypatch.setattr(_kernels, "on_chip", lambda: True)
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
