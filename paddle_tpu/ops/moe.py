@@ -117,6 +117,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..core.registry import master_as, register_grad, register_op
@@ -224,6 +225,86 @@ def _moe_router(ctx, X, W, Bias=None):
                      dtype=jnp.int32)
     return {"TopKWeight": weight, "TopKIndex": index.astype(jnp.int32),
             "TokensPerExpert": counts, "Probs": probs, "LogSumExp": lse}
+
+
+@register_grad("moe_router")
+def _moe_router_grad(ctx, ins, out_grads):
+    """dX and dW from the forward's saved `Probs`, `TopKIndex` and
+    `TopKWeight`: no second trace of the rule, so no `top_k`, no scatter and
+    no element gather of its own. The generic vjp's transpose of `top_k` /
+    `take_along_axis` is a scatter-add of N*k updates into [N, E], which a
+    TPU walks one update at a time; a row's k indices are distinct, so the
+    same array is a compare and select over [N, k, E] summed over k, one
+    fused pass. A gather of N*k elements is walked the same way (0.5-0.7 ms
+    a router on a v5e where the rest of the grad takes 0.15-0.45), so the
+    chosen scores are gathered again only where the forward's own gather is
+    there to merge with (PERF.md section 6, PR 62). The choice (the bias,
+    `_kept_groups`) carries no gradient, as under `jax.vjp`. The grad op
+    sees the scope's values, so AMP_F32_OPS' casts are made here. The op
+    counts itself on the compile event (`moe_router_direct_grads`)."""
+    X, W = ins["X"][0], ins["W"][0]
+    d_weight = out_grads["TopKWeight"][0]
+    d_probs, d_lse = out_grads["Probs"][0], out_grads["LogSumExp"][0]
+    if d_weight is None and d_probs is None and d_lse is None:
+        return {}
+    ctx.tally("moe_router_direct_grads")
+    f32 = jnp.float32
+    index, probs = ctx.fwd_outs["TopKIndex"][0], ctx.fwd_outs["Probs"][0]
+    if d_weight is not None:
+        d_raw, scale = d_weight.astype(f32), ctx.attr("scaling_factor")
+        if scale is not None:
+            d_raw = d_raw * float(scale)
+        experts = jnp.arange(probs.shape[1], dtype=index.dtype)
+        hit = index[:, :, None] == experts                      # [N, k, E]
+        norm = bool(ctx.attr("norm_topk_prob", False))
+        eps = float(ctx.attr("norm_eps") or 0.0)
+        gathered = bool(ins.get("Bias")) or int(ctx.attr("n_group", 1)) > 1
+        if norm and gathered:
+            # the forward gathered the chosen scores: the same gather
+            # again, which XLA merges with the forward's
+            raw = jnp.take_along_axis(probs, index, axis=-1)
+            total = jnp.sum(raw, axis=-1, keepdims=True) + eps
+            d_raw = (d_raw - jnp.sum(d_raw * (raw / total), axis=-1,
+                                     keepdims=True)) / total
+        if gathered or not norm:
+            chosen = jnp.sum(jnp.where(hit, d_raw[:, :, None], 0.0), axis=1)
+        else:
+            # they were `top_k`'s own values and there is no gather to
+            # merge with: the saved weights are their quotients, and their
+            # sum comes out of the pass over `hit` (a variadic reduce: XLA
+            # does not merge two) as the scores at the chosen experts
+            w_norm = ctx.fwd_outs["TopKWeight"][0]
+            if scale is not None:
+                w_norm = w_norm / float(scale)
+            d_raw = d_raw - jnp.sum(d_raw * w_norm, axis=-1, keepdims=True)
+            chosen, is_chosen = lax.reduce(
+                (jnp.where(hit, d_raw[:, :, None], 0.0), hit),
+                (np.float32(0), np.bool_(False)),
+                lambda a, b: (a[0] + b[0], a[1] | b[1]), dimensions=(1,))
+            chosen = chosen / (jnp.sum(jnp.where(is_chosen, probs, 0.0),
+                                       axis=-1, keepdims=True) + eps)
+        d_probs = chosen if d_probs is None else d_probs.astype(f32) + chosen
+    x32, w32 = X.astype(f32), W.astype(f32)
+    if ctx.attr("score_func", "softmax") == "sigmoid":
+        d_logits = 0.0 if d_probs is None \
+            else d_probs * probs * (1.0 - probs)
+        if d_lse is not None:
+            # the forward's own expressions: XLA merges the two products
+            logits = jnp.dot(x32, w32, precision=lax.Precision.HIGHEST)
+            lse = ctx.fwd_outs["LogSumExp"][0]
+            d_logits = d_logits + d_lse.astype(f32)[:, None] \
+                * jnp.exp(logits - lse[:, None])
+    else:
+        d_logits = 0.0 if d_probs is None else probs * (
+            d_probs - jnp.sum(d_probs * probs, axis=-1, keepdims=True))
+        if d_lse is not None:
+            d_logits = d_logits + d_lse.astype(f32)[:, None] * probs
+    d_x = jnp.dot(d_logits, w32.T, precision=lax.Precision.HIGHEST)
+    d_w = jnp.dot(x32.T, d_logits, precision=lax.Precision.HIGHEST)
+    grads = {"X": d_x.astype(X.dtype), "W": d_w.astype(W.dtype)}
+    if ins.get("Bias"):
+        grads["Bias"] = jnp.zeros_like(ins["Bias"][0])
+    return grads
 
 
 def _padded_groups(counts, rows, tile):

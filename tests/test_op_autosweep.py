@@ -812,6 +812,14 @@ VARIANTS = {
                "n_group": 4, "topk_group": 2},
         outs=("TopKWeight", "TopKIndex", "TokensPerExpert", "Probs",
               "LogSumExp"))),
+    # the softmax's weights over their sum plus an epsilon, then scaled: an
+    # epsilon large enough that the mean of the weights has a gradient
+    "moe_router+softmax_norm_eps": ("moe_router", Spec(
+        inputs={"X": T(6, 5), "W": T(5, 4) * 2},
+        attrs={"k": 2, "norm_topk_prob": True, "norm_eps": 0.5,
+               "scaling_factor": 2.5},
+        outs=("TopKWeight", "TopKIndex", "TokensPerExpert", "Probs",
+              "LogSumExp"))),
 }
 
 
