@@ -1457,15 +1457,18 @@ def causal_conv1d(input, kernel_size, param_attr=None, name=None,
 
 
 def gated_delta_rule(q, k, v, a, b, a_log_attr=None, dt_bias_attr=None,
-                     chunk=64, name=None):
+                     chunk=64, name=None, beta_scale=1.0):
     """Linear attention by the gated delta rule (`ops/linear_attention.py`)
     on q, k `[batch, seq, key_heads, key_dim]` and v `[batch, seq,
     value_heads, value_dim]`; `a`, `b` `[batch, seq, value_heads]` make a
     head's log-decay `g = -exp(A_log) * softplus(a + dt_bias)` and write
-    strength `sigmoid(b)` in float32, with the learned `A_log` and `dt_bias`
-    `[value_heads]` (`a_log_attr`, `dt_bias_attr`). q and k are l2-normalised
-    over a head inside the op; seq must be a multiple of `chunk`. Returns
-    `[batch, seq, value_heads, value_dim]`.
+    strength `beta_scale * sigmoid(b)` in float32, with the learned `A_log`
+    and `dt_bias` `[value_heads]` (`a_log_attr`, `dt_bias_attr`). `beta_scale`
+    2 lets beta reach 2 (`allow_neg_eigval`: with beta > 1 a token's
+    transition `exp(g) (I - beta k k^T)` has the negative eigenvalue `exp(g)
+    (1 - beta)`); the gates op carries it as an attribute, none at 1. q and
+    k are l2-normalised over a head inside the op; seq must be a multiple of
+    `chunk`. Returns `[batch, seq, value_heads, value_dim]`.
 
     The op has a second output, `States`: float32 `[seq / chunk, batch,
     value_heads, key_dim, value_dim]`, the state each chunk started from, as
@@ -1484,7 +1487,9 @@ def gated_delta_rule(q, k, v, a, b, a_log_attr=None, dt_bias_attr=None,
     helper.append_op("delta_rule_gates",
                      inputs={"A": [a.name], "B": [b.name],
                              "ALog": [a_log.name], "DtBias": [dt_bias.name]},
-                     outputs={"G": [g.name], "Beta": [beta.name]})
+                     outputs={"G": [g.name], "Beta": [beta.name]},
+                     attrs=None if beta_scale == 1
+                     else {"beta_scale": float(beta_scale)})
     out = new(v.dtype)
     states = new("float32", stop_gradient=True)
     helper.append_op("gated_delta_rule",
@@ -1636,7 +1641,7 @@ def exit_gate(input, param_attr=None, bias_attr=None, name=None):
 
 def fused_attention(q, k, v, causal=False, sm_scale=None, dropout_rate=0.0,
                     is_test=False, window=None, layout="BHTD", name=None,
-                    kept=None, topk=None):
+                    kept=None, topk=None, heads_total=None):
     """Softmax attention through the flash kernels
     (`ops/pallas_attention.py`): O(seq) memory, dropout on the attention
     weights inside the kernel. `layout` says how the operands lie, and the
@@ -1667,6 +1672,10 @@ def fused_attention(q, k, v, causal=False, sm_scale=None, dropout_rate=0.0,
     their own (`dsa_flash_fwd`, `dsa_flash_dq_flash_dkv`); every causal tile
     is computed. `topk`: how many keys a row keeps at most, for the op's
     count of kept pairs on the compile event (`dsa_keys_kept`).
+
+    `heads_total`: where the operands hold one chip's share of a layer's
+    heads, how many the layer has (`models/olmo_hybrid.py`); the op carries
+    it for the compile event's census and computes nothing from it.
 
     The op has a second output, `Lse`: the forward kernel's log-sum-exp of
     every score row, float32 `[batch * heads, 1, seq]` in either layout,
@@ -1702,6 +1711,8 @@ def fused_attention(q, k, v, causal=False, sm_scale=None, dropout_rate=0.0,
         inputs["Kept"] = [kept.name]
         if topk is not None:
             attrs["topk"] = int(topk)
+    if heads_total is not None:
+        attrs["heads_total"] = int(heads_total)
     helper.append_op("fused_attention", inputs=inputs,
                      outputs={"Out": [out.name], "Lse": [lse.name]},
                      attrs=attrs)

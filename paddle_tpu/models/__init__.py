@@ -30,7 +30,11 @@ language model: Kimi Delta Attention (a delta rule whose decay is per key
 channel) in five layers of six beside latent attention under a head-wise
 gate, over a group-limited sigmoid router (the first model built as a run of
 its published layers from a stated index on, and the first whose router
-chooses its groups before its experts)."""
+chooses its groups before its experts), and Olmo-Hybrid: a dense 3:1 hybrid
+of the gated delta rule with beta in (0, 2) (a transition with a negative
+eigenvalue, heads of 96 / 192) and OLMo's whole-projection QK-norm attention,
+every sublayer normed on the way out only (the first model built for a share
+of a layer's HEADS)."""
 
 from . import mnist  # noqa: F401
 from . import resnet  # noqa: F401
@@ -50,3 +54,4 @@ from . import trinity  # noqa: F401
 from . import keye_vl2  # noqa: F401
 from . import nemotron_h  # noqa: F401
 from . import ling3  # noqa: F401
+from . import olmo_hybrid  # noqa: F401

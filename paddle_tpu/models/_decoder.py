@@ -1,19 +1,23 @@
 """What two or more of the decoder models (`olmoe`, `ouro`, `qwen3_next`,
-`kanana2`, `mellum2`, `trinity`, `keye_vl2`, `nemotron_h`, `ling3`) build the same
-way, written once: named weights and projections, the token feeds, the
-heads-first reshape and its inverse, a key-value head serving its group of
-query heads, the gated MLP, the routed half of an expert layer, the period of
-layer kinds, the grouped-query block, and the losses. Nothing here asks which
+`kanana2`, `mellum2`, `trinity`, `keye_vl2`, `nemotron_h`, `ling3`,
+`olmo_hybrid`) build the same way, written once: named weights and
+projections, the token feeds, the heads-first reshape and its inverse, a
+key-value head serving its group of query heads, the gated MLP, the routed
+half of an expert layer, the period of layer kinds, the grouped-query block,
+q and k normed over the whole projection before the heads split (`olmoe`,
+`olmo_hybrid`), a gated-delta-rule layer from its convolution to its gated
+norm (`qwen3_next`, `olmo_hybrid`), and the losses. Nothing here asks which
 model calls it (latent attention, built by `kanana2` and `ling3`, takes
 the one thing they differ in, a head-wise gate, as a parameter): a model
-whose form differs keeps its own (OLMoE norms q and k
-before the heads split, Nemotron-H's out projections start smaller). Each
-model keeps its mixer's composition, its layer loop, its defaults
-and `build`. Built from `fluid.layers` only; parameter names are the
+whose form differs keeps its own (Nemotron-H's out projections start
+smaller). Each model keeps its mixer's composition, its layer loop, its
+defaults and `build`. Built from `fluid.layers` only; parameter names are the
 caller's.
 """
 
 from __future__ import annotations
+
+import numpy as np
 
 from .. import initializer as init
 from .. import layers
@@ -183,12 +187,63 @@ def latent_attention(x, n_head, kv_rank, qk_nope_dim, qk_rope_dim,
                   name + ".o")
 
 
-def layer_kinds(n_layer, layer_types=PERIOD):
+def qk_normed_projections(x, width, rms_eps, name):
+    """q, k, v `[B, T, width]` of an OLMo attention layer: three projections
+    without bias, q and k each through an RMSNorm with a learned weight over
+    the WHOLE `width` (every head this chip holds), before the heads split
+    (`name.q.w`, `name.q_norm.w`, ...). Under a share of the heads `width`
+    is what is held, and so is the norm's mean."""
+    q = norm(linear(x, width, name + ".q"), rms_eps, name + ".q_norm")
+    k = norm(linear(x, width, name + ".k"), rms_eps, name + ".k_norm")
+    return q, k, linear(x, width, name + ".v")
+
+
+def a_log_init(heads, seed):
+    """log of uniform(0, 16), as the public gated-delta-rule code initialises
+    `A_log`; drawn here so that the startup program holds the values."""
+    draws = np.random.RandomState(seed).uniform(0.0, 16.0, size=heads)
+    return init.NumpyArrayInitializer(
+        np.log(np.maximum(draws, 1e-3)).astype("float32"))
+
+
+def conv_heads(qkv, n_key_head, n_value_head, key_dim, value_dim,
+               conv_kernel, name):
+    """`[q | k | v]` `[B, T, channels]` of a gated-delta-rule layer through
+    its causal depthwise convolution and silu (`name.conv.w`, no bias), then
+    apart and by heads: q, k `[B, T, key heads, key_dim]`, v `[B, T, value
+    heads, value_dim]`."""
+    wide_k, wide_v = n_key_head * key_dim, n_value_head * value_dim
+    qkv = layers.causal_conv1d(
+        qkv, conv_kernel, param_attr=ParamAttr(
+            name=name + ".conv.w",
+            initializer=init.UniformInitializer(-conv_kernel ** -0.5,
+                                                conv_kernel ** -0.5)))
+    return (split_heads(last(qkv, 0, wide_k), n_key_head, key_dim),
+            split_heads(last(qkv, wide_k, 2 * wide_k), n_key_head, key_dim),
+            split_heads(last(qkv, 2 * wide_k, 2 * wide_k + wide_v),
+                        n_value_head, value_dim))
+
+
+def delta_rule_normed(q, k, v, z, a, b, rms_eps, name, a_log, dt_bias=None,
+                      beta_scale=1.0):
+    """The gated delta rule on `conv_heads`' q, k, v under the gates that
+    `a`, `b` `[B, T, value heads]` make with the learned `name.A_log` and
+    `name.dt_bias` (initialisers `a_log`, `dt_bias`: 1 by default), then the
+    layer's output norm over a head, gated by `silu(z)` (`name.norm.w`)."""
+    o = layers.gated_delta_rule(
+        q, k, v, a=a, b=b, beta_scale=beta_scale,
+        a_log_attr=ParamAttr(name=name + ".A_log", initializer=a_log),
+        dt_bias_attr=ParamAttr(name=name + ".dt_bias", initializer=dt_bias))
+    return layers.gated_rms_norm(o, z, epsilon=rms_eps,
+                                 param_attr=ParamAttr(name=name + ".norm.w"))
+
+
+def layer_kinds(n_layer, layer_types=PERIOD, kinds=KINDS):
     """The kind of each of `n_layer` layers: `layer_types` (a list of
-    `KINDS`) repeated as a period."""
-    unknown = sorted(set(layer_types) - set(KINDS))
+    `kinds`) repeated as a period."""
+    unknown = sorted(set(layer_types) - set(kinds))
     if unknown or not layer_types:
-        raise ValueError(f"layer_types holds {KINDS}, got {layer_types!r}")
+        raise ValueError(f"layer_types holds {kinds}, got {layer_types!r}")
     return [layer_types[i % len(layer_types)] for i in range(n_layer)]
 
 

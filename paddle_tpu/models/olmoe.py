@@ -27,15 +27,13 @@ from __future__ import annotations
 from .. import layers
 from ._decoder import (embed, heads_first, linear, load_balance,
                        mean_cross_entropy, merge_heads, norm,
-                       routed_experts, split_heads, token_feeds,
-                       tokens_per_expert)
+                       qk_normed_projections, routed_experts, split_heads,
+                       token_feeds, tokens_per_expert)
 
 
 def _attention(x, d_model, n_head, rope_theta, rms_eps, name):
     d_head = d_model // n_head
-    q = norm(linear(x, d_model, name + ".q"), rms_eps, name + ".q_norm")
-    k = norm(linear(x, d_model, name + ".k"), rms_eps, name + ".k_norm")
-    v = linear(x, d_model, name + ".v")
+    q, k, v = qk_normed_projections(x, d_model, rms_eps, name)
 
     def heads(t):
         return heads_first(split_heads(t, n_head, d_head))

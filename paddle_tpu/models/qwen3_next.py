@@ -50,13 +50,10 @@ reference can be handed the same weights by name. Each layer's ops carry
 
 from __future__ import annotations
 
-import numpy as np
-
-from .. import initializer as init
 from .. import layers
 from ..core.ir import name_scope
-from ..param_attr import ParamAttr
-from ._decoder import (balanced_loss, embed, expert_rows, gated_mlp,
+from ._decoder import (a_log_init, balanced_loss, conv_heads,
+                       delta_rule_normed, embed, expert_rows, gated_mlp,
                        heads_first, last, linear, norm, serve_group,
                        split_heads, token_feeds)
 
@@ -94,14 +91,6 @@ def _attention(x, n_head, n_kv_head, head_dim, rotary_dim, rope_theta,
     return linear(ctx, x.shape[-1], name + ".o")
 
 
-def _a_log(heads, seed):
-    """log of uniform(0, 16), as the public code initialises `A_log`; drawn
-    here so that the startup program holds the values."""
-    draws = np.random.RandomState(seed).uniform(0.0, 16.0, size=heads)
-    return init.NumpyArrayInitializer(
-        np.log(np.maximum(draws, 1e-3)).astype("float32"))
-
-
 def _gated_delta_net(x, n_key_head, n_value_head, key_dim, value_dim,
                      conv_kernel, rms_eps, name, seed):
     r = n_value_head // n_key_head
@@ -122,25 +111,12 @@ def _gated_delta_net(x, n_key_head, n_value_head, key_dim, value_dim,
         axis=2)
     z = layers.reshape(last(mixed, 2 * key_dim + r * value_dim, per_head),
                        shape=[0, 0, n_value_head, value_dim])
-    qkv = layers.causal_conv1d(
-        qkv, conv_kernel, param_attr=ParamAttr(
-            name=name + ".conv.w",
-            initializer=init.UniformInitializer(-conv_kernel ** -0.5,
-                                                conv_kernel ** -0.5)))
-    q = layers.reshape(last(qkv, 0, wide_k),
-                       shape=[0, 0, n_key_head, key_dim])
-    k = layers.reshape(last(qkv, wide_k, 2 * wide_k),
-                       shape=[0, 0, n_key_head, key_dim])
-    v = layers.reshape(last(qkv, 2 * wide_k, 2 * wide_k + wide_v),
-                       shape=[0, 0, n_value_head, value_dim])
-    o = layers.gated_delta_rule(
-        q, k, v, a=flat(last(ba, r, 2 * r), n_value_head),
-        b=flat(last(ba, 0, r), n_value_head),
-        a_log_attr=ParamAttr(name=name + ".A_log",
-                             initializer=_a_log(n_value_head, seed)),
-        dt_bias_attr=ParamAttr(name=name + ".dt_bias"))
-    o = layers.gated_rms_norm(o, z, epsilon=rms_eps,
-                              param_attr=ParamAttr(name=name + ".norm.w"))
+    q, k, v = conv_heads(qkv, n_key_head, n_value_head, key_dim, value_dim,
+                         conv_kernel, name)
+    o = delta_rule_normed(
+        q, k, v, z, a=flat(last(ba, r, 2 * r), n_value_head),
+        b=flat(last(ba, 0, r), n_value_head), rms_eps=rms_eps, name=name,
+        a_log=a_log_init(n_value_head, seed))
     return linear(flat(o, wide_v), x.shape[-1], name + ".out")
 
 

@@ -112,6 +112,10 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
         scope = op.attrs.get(ir.NAME_SCOPE_ATTR)
         if op.type == "gated_delta_rule":
             kinds["linear_attention"] += 1
+            out["linear_attention_head_dims"] = [
+                block.var(op.input(slot)[0]).shape[-1] for slot in "KV"]
+        elif op.type == "delta_rule_gates" and "beta_scale" in op.attrs:
+            out["delta_rule_beta_scale"] = op.attrs["beta_scale"]
         elif op.type == "ssd_scan":
             kinds["state_space"] += 1
         elif op.type == "kda_delta_rule":
@@ -130,7 +134,12 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
             wide = keys.shape[-1]
             value = block.var(op.input("V")[0]).shape[-1]
             window = op.attrs.get("window")
-            seq = keys.shape[1 if op.attrs.get("layout") == "BTHD" else -2]
+            token_major = op.attrs.get("layout") == "BTHD"
+            seq = keys.shape[1 if token_major else -2]
+            if "heads_total" in op.attrs:
+                out["attention_heads_held"] = keys.shape[2 if token_major
+                                                         else 1]
+                out["attention_heads"] = op.attrs["heads_total"]
             if op.inputs.get("Kept"):
                 kinds["sparse_attention"] += 1
             elif window is not None and window < seq:

@@ -6,7 +6,11 @@ this docstring).
     causal_conv1d:      a depthwise convolution over time, then silu (two
                         Pallas kernels too: the end of this docstring)
     delta_rule_gates:   g = -exp(A_log) * softplus(a + dt_bias) <= 0 (the log
-                        of a head's decay), beta = sigmoid(b)
+                        of a head's decay), beta = sigmoid(b), times the op's
+                        `beta_scale` where it has one (2 in `olmo_hybrid`: with
+                        beta > 1 a token's transition exp(g) (I - beta k k^T)
+                        has the eigenvalue exp(g) (1 - beta) < 0; the rule
+                        takes Beta as it comes, so nothing below changes)
     gated_delta_rule:   per value head a state S [key, value], S_0 = 0:
                           S <- exp(g_t) S;  d = beta_t (v_t - S^T k_t);
                           S <- S + k_t d^T;  o_t = S^T q_t
@@ -273,11 +277,15 @@ def _causal_conv1d_grad(ctx, ins, out_grads):
 def _delta_rule_gates(ctx, A, B, ALog, DtBias):
     """A, B [..., H] (two projections of the layer's input), ALog, DtBias
     [H] -> G = -exp(ALog) * softplus(A + DtBias) and Beta = sigmoid(B), both
-    float32 (AMP_F32_OPS)."""
+    float32 (AMP_F32_OPS). `beta_scale` (absent: 1) multiplies Beta: at 2 it
+    lies in (0, 2), and above 1 the rule's transition has a negative
+    eigenvalue (module docstring)."""
     a32, b32 = A.astype(jnp.float32), B.astype(jnp.float32)
     g = -jnp.exp(ALog.astype(jnp.float32)) \
         * jax.nn.softplus(a32 + DtBias.astype(jnp.float32))
-    return {"G": g, "Beta": jax.nn.sigmoid(b32)}
+    beta = jax.nn.sigmoid(b32)
+    scale = ctx.attr("beta_scale", 1.0)
+    return {"G": g, "Beta": beta if scale == 1 else beta * scale}
 
 
 def l2_normalize(x, eps=1e-6):

@@ -1,4 +1,4 @@
-"""The eight decoder models' Programs are what they were, and the seams a
+"""The decoder models' Programs are what they were, and the seams a
 new architecture crosses stay where they are.
 
 `program_digest` holds a Program op for op (type, attributes, the name scope
@@ -7,8 +7,10 @@ writes by name) and parameter for parameter (name, shape, dtype, trainable), mai
 startup: what a checkpoint and the benchmark's `trace_scopes` reader find
 things by. `DIGESTS` and `CENSUS` were taken on the commit before
 `models/_decoder.py`, `observe/census.py` and `ops/_kernels.py` existed
-(PR 58's parent) at the models' own tests' tiny sizes, forward, backward and
-Adam; after a deliberate change to a model take them again with
+(PR 58's parent; `olmo_hybrid`'s on PR 63, which added the model and gave the
+census of a program with `gated_delta_rule` ops the key
+`linear_attention_head_dims`) at the models' own tests' tiny sizes, forward,
+backward and Adam; after a deliberate change to a model take them again with
 `program_digest(*build_program(model)[:2])` and
 `census.program_detail(build_program(model)[0])`.
 """
@@ -28,6 +30,7 @@ from test_kanana2 import TINY as KANANA2_TINY
 from test_keye_vl2 import TINY as KEYE_VL2_TINY
 from test_mellum2 import TINY as MELLUM2_TINY
 from test_nemotron_h import TINY as NEMOTRON_H_TINY
+from test_olmo_hybrid import TINY as OLMO_HYBRID_TINY
 from test_olmoe import TINY as OLMOE_TINY
 from test_ouro import TINY as OURO_TINY
 from test_qwen3_next import TINY as QWEN3_NEXT_TINY
@@ -36,7 +39,8 @@ from test_trinity import TINY as TRINITY_TINY
 HERE = os.path.dirname(os.path.abspath(__file__))
 PACKAGE = os.path.join(HERE, "..", "paddle_tpu")
 
-SIZES = {"olmoe": OLMOE_TINY, "ouro": OURO_TINY,
+SIZES = {"olmoe": OLMOE_TINY, "olmo_hybrid": OLMO_HYBRID_TINY,
+         "ouro": OURO_TINY,
          "qwen3_next": QWEN3_NEXT_TINY, "kanana2": KANANA2_TINY,
          "mellum2": MELLUM2_TINY, "trinity": TRINITY_TINY,
          "keye_vl2": KEYE_VL2_TINY, "nemotron_h": NEMOTRON_H_TINY}
@@ -89,6 +93,8 @@ DIGESTS = {
                         "a3444ab5db5c1fccba256058024defa9"),
     "olmoe": (397, "d8847a00387005278bfc31daf8556e30"
                    "1eb7d918126f76aafef7dc06b6d29cb9"),
+    "olmo_hybrid": (725, "3d6b51bdb3f5b30704e77b84ef566b09"       # PR 63's own
+                         "25752028f333b5369d0c325de1a10c8e"),
     "ouro": (790, "6ea230c9082de14ccaa4df13364540aa"
                   "790498e29ea98a844998f066efecfee8"),
     "qwen3_next": (1021, "e431cca320eca95789aee1fbdb3a8e2f"
@@ -127,6 +133,12 @@ CENSUS = {
         "parameters": 27, "parameter_uses": 27, "grad_fanin_max": 1,
         "moe_experts_routed": 8, "moe_experts_held": 8,
         "layer_kinds": {"full_attention": 2}, "attention_rotary_layers": 2},
+    "olmo_hybrid": {
+        "parameters": 62, "parameter_uses": 62, "grad_fanin_max": 1,
+        "delta_rule_beta_scale": 2.0, "linear_attention_head_dims": [12, 24],
+        "attention_heads_held": 2, "attention_heads": 4,
+        "layer_kinds": {"linear_attention": 3, "full_attention": 1},
+        "residual_out_norms": 8},
     "ouro": {
         "parameters": 27, "parameter_uses": 103, "grad_fanin_max": 4,
         "layer_kinds": {"full_attention": 8}, "attention_rotary_layers": 8,
@@ -135,6 +147,7 @@ CENSUS = {
         "parameters": 70, "parameter_uses": 70, "grad_fanin_max": 1,
         "moe_experts_routed": 16, "moe_experts_held": 4,
         "layer_kinds": {"linear_attention": 3, "full_attention": 1},
+        "linear_attention_head_dims": [8, 8],       # a key since PR 63
         "attention_rotary_layers": 1, "attention_gated_layers": 1},
     "trinity": {
         "parameters": 93, "parameter_uses": 93, "grad_fanin_max": 1,

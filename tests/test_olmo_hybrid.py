@@ -1,0 +1,654 @@
+"""Olmo-Hybrid (gated-delta-rule layers whose write strength reaches 2 beside
+OLMo's whole-projection QK-norm attention, every sublayer normed on the way
+out, one chip's share of each layer's heads) through `layers` -> Program IR
+-> `Executor`, against the plain reference (`tests/olmo_hybrid_reference.py`:
+the delta rule as its token-by-token recurrence). Seeded random weights,
+float32, AMP off unless a test says otherwise."""
+
+import filecmp
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu import layers, models, observe
+from paddle_tpu.core import ir, registry
+from paddle_tpu.models import olmo_hybrid as model
+from paddle_tpu.ops import linear_attention as la
+
+import olmo_hybrid_reference as ref
+from test_olmoe import rel_err, run_piece
+from test_qwen3_next import RTOL, frob
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+TINY = dict(vocab_size=128, seq_len=128, n_layer=4, d_model=64, d_ff=96,
+            n_head=4, heads_held=2, head_dim=16, key_dim=12, value_dim=24,
+            conv_kernel=4)
+REF_KW = {k: TINY[k] for k in ("n_layer", "head_dim", "key_dim",
+                               "value_dim")}
+
+
+# -- the rule with beta on both sides of 1 --------------------------------------
+
+def _rule_inputs(t, heads=3, dk=12, dv=24, seed=0, batch=2):
+    """Raw q and k, g from a mixed decay, beta uniform over (0.1, 1.9)."""
+    rng = np.random.RandomState(seed)
+    q = rng.randn(batch, t, heads, dk).astype(np.float32)
+    k = rng.randn(batch, t, heads, dk).astype(np.float32)
+    v = rng.randn(batch, t, heads, dv).astype(np.float32)
+    g = -np.exp(rng.uniform(-1, 2.5, heads)) \
+        * np.log1p(np.exp(rng.randn(batch, t, heads)))
+    beta = rng.uniform(0.1, 1.9, (batch, t, heads))
+    return q, k, v, g.astype(np.float32), beta.astype(np.float32)
+
+
+def _prepared(q, k):
+    return (la.l2_normalize(jnp.asarray(q)) * q.shape[-1] ** -0.5,
+            la.l2_normalize(jnp.asarray(k)))
+
+
+def _chunked(q, k, v, g, beta, chunk=64):
+    return la.chunked_gated_delta_rule(*_prepared(q, k), v, g, beta, chunk)
+
+
+def _recurrence(q, k, v, g, beta):
+    return ref.delta_rule(*_prepared(q, k), v, g, beta, token_block=64)
+
+
+@pytest.mark.parametrize("t,chunk", [(64, 64), (128, 64), (256, 64),
+                                     (512, 64), (128, 32)])
+def test_chunked_rule_is_the_recurrence_with_beta_up_to_two(t, chunk):
+    """The XLA form at key heads = value heads and Dk != Dv (12 / 24):
+    forward and the gradient of every input. With beta > 1 the entries of A
+    reach 2 |k_i . k_j| and the substitution's T grows faster."""
+    args = _rule_inputs(t)
+    assert args[4].min() < 0.3 and args[4].max() > 1.7
+    probe = np.random.RandomState(9).randn(2, t, 3, 24).astype(np.float32)
+    with jax.default_matmul_precision("highest"):
+        want, vjp = jax.vjp(_recurrence, *args)
+        got, got_vjp = jax.vjp(
+            lambda *a: _chunked(*a, chunk=chunk), *args)
+        assert np.all(np.isfinite(got))
+        assert frob(got, want) < RTOL
+        for name, g, w in zip("q k v g beta".split(),
+                              got_vjp(jnp.asarray(probe)),
+                              vjp(jnp.asarray(probe))):
+            assert np.all(np.isfinite(g)), name
+            assert frob(g, w) < 2e-4, (name, frob(g, w))
+
+
+def test_beta_above_one_gives_a_transition_with_a_negative_eigenvalue():
+    """What `allow_neg_eigval` turns on: along k the state's component is
+    multiplied by exp(g) (1 - beta) < 0."""
+    k = np.zeros((1, 2, 1, 4), np.float32)
+    k[..., 0] = 1.0
+    v = np.zeros((1, 2, 1, 2), np.float32)
+    v[0, 0, 0] = [1.0, 2.0]                        # written once, at t = 0
+    g = np.full((1, 2, 1), -0.5, np.float32)
+    beta = np.asarray([[[1.0], [1.6]]], np.float32)
+    out = ref.delta_rule(jnp.asarray(k), jnp.asarray(k), jnp.asarray(v),
+                         jnp.asarray(g), jnp.asarray(beta))
+    factor = np.exp(-0.5) * (1 - 1.6)
+    assert factor < 0
+    np.testing.assert_allclose(out[0, 1, 0], factor * np.asarray([1.0, 2.0]),
+                               rtol=1e-6)
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+
+
+@pytest.mark.parametrize("chunks", [2, 3])
+def test_kernels_match_both_oracles_with_beta_up_to_two(chunks, interpreted):
+    """`gdn_fwd` / `gdn_bwd` under the interpreter at a kernel-envelope
+    size, as many value heads as key heads (r = 1) at Dk != Dv (128 / 256),
+    beta over (0.1, 1.9): against `jax.vjp` of the XLA form and of the
+    token-by-token recurrence."""
+    t = chunks * 64
+    args = _rule_inputs(t, heads=2, dk=128, dv=256, batch=1, seed=3)
+    assert la._plan(128, 256, 64)[0] == "kernel"
+    probe = np.random.RandomState(9).randn(1, t, 2, 256).astype(np.float32)
+    out, states = la._gdn_forward(*args, 64)
+    assert states.shape == (chunks, 1, 2, 128, 256)
+    grads = la._gdn_backward(*args, states, probe, 64)
+    with jax.default_matmul_precision("highest"):
+        for oracle in (_chunked, _recurrence):
+            want, vjp = jax.vjp(oracle, *args)
+            assert frob(out, want) < RTOL, oracle.__name__
+            for name, got, w in zip("q k v g beta".split(), grads,
+                                    vjp(jnp.asarray(probe))):
+                assert np.all(np.isfinite(got)), name
+                assert frob(got, w) < 2e-4, (oracle.__name__, name,
+                                             frob(got, w))
+
+
+def test_the_published_head_dims_keep_the_xla_form():
+    assert la._plan(96, 192, 64, chunks=64) == ("xla", 0)
+
+
+@pytest.mark.parametrize("scale", [1.0, 2.0])
+def test_rule_layer_carries_the_factor_on_beta_as_an_attribute(scale):
+    """`layers.gated_delta_rule(beta_scale=...)`: the gates op carries the
+    factor (none at 1: Qwen3-Next's op is the op it was), r = 1 at Dk != Dv;
+    forward and the gradient of every input and parameter against the
+    recurrence."""
+    rng = np.random.RandomState(2)
+    b, t, h, dk, dv = 2, 128, 2, 12, 24
+    feed = {"q": rng.randn(b, t, h, dk), "k": rng.randn(b, t, h, dk),
+            "v": rng.randn(b, t, h, dv), "a": rng.randn(b, t, h),
+            "b": rng.randn(b, t, h) * 1.5}
+    feed = {n: x.astype(np.float32) for n, x in feed.items()}
+    params = {"A_log": np.log(rng.uniform(0.1, 4, h)).astype(np.float32),
+              "dt_bias": rng.uniform(-1, 1, h).astype(np.float32)}
+    seen = {}
+
+    def build(d):
+        out = layers.gated_delta_rule(
+            d["q"], d["k"], d["v"], d["a"], d["b"], beta_scale=scale,
+            a_log_attr=fluid.ParamAttr(name="A_log"),
+            dt_bias_attr=fluid.ParamAttr(name="dt_bias"))
+        gates = next(op for op in out.block.ops
+                     if op.type == "delta_rule_gates")
+        seen.update(gates.attrs)
+        return [out]
+
+    (y,), grads, probe = run_piece(build, feed, params)
+    assert ("beta_scale" in seen) == (scale != 1)
+    assert seen.get("beta_scale", 1.0) == scale
+
+    def want(q, k, v, a, b_in, a_log, dt_bias):
+        g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
+        beta = scale * jax.nn.sigmoid(b_in)
+        return ref.delta_rule(*_prepared(q, k), v, g, beta)
+
+    args = [feed[n] for n in "qkvab"] + [params["A_log"], params["dt_bias"]]
+    with jax.default_matmul_precision("highest"):
+        assert frob(y, want(*args)) < RTOL
+        want_grads = jax.grad(lambda *a: jnp.sum(want(*a) * probe),
+                              argnums=tuple(range(7)))(*args)
+    for name, w in zip(list("qkvab") + ["A_log", "dt_bias"], want_grads):
+        assert frob(grads[name], w) < 2e-4, name
+
+
+# -- the whole tiny model ------------------------------------------------------------
+
+def _program(optimizer=None, **sizes):
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        feeds, fetches = models.olmo_hybrid.build(**{**TINY, **sizes})
+        if optimizer is None:
+            pairs = fluid.append_backward(fetches["loss"])
+        else:
+            optimizer.minimize(fetches["loss"])
+            pairs = []
+    main.random_seed = startup.random_seed = 7
+    return main, startup, fetches, pairs
+
+
+def _batch(seed=0, batch=2):
+    rng = np.random.RandomState(seed)
+    shape = (batch, TINY["seq_len"])
+    return {n: rng.randint(0, TINY["vocab_size"], shape).astype(np.int32)
+            for n in ("tokens", "labels")}
+
+
+def _seeded_weights(scope, names, seed=3):
+    """Weights far from their initial values, so that no term of the
+    comparison is small by construction: norm weights in [0.5, 1.5], a decay
+    that forgets slowly (A in [0.05, 1]), an embedding of unit size and `W_b`
+    at std 0.25, so that beta = 2 sigmoid(x W_b) lies on both sides of 1 in
+    every layer, the first among them; the other matrices at std 0.1."""
+    rng = np.random.RandomState(seed)
+    for name in sorted(names):
+        shape = np.shape(scope.find_var(name))
+        if "norm" in name:
+            value = rng.uniform(0.5, 1.5, shape)
+        elif name.endswith("A_log"):
+            value = np.log(rng.uniform(0.05, 1.0, shape))
+        elif name.endswith("dt_bias"):
+            value = rng.uniform(-1.0, 1.0, shape)
+        elif name.endswith("conv.w"):
+            value = rng.uniform(-0.5, 0.5, shape)
+        elif name == "embed.w":
+            value = rng.randn(*shape)
+        elif name.endswith("gdn.b.w"):
+            value = rng.randn(*shape) * 0.25
+        else:
+            value = rng.randn(*shape) * 0.1
+        scope.set_var(name, jnp.asarray(value.astype(np.float32)))
+
+
+FETCHES = ["loss", "ce", "logits"]
+
+
+def _run_tiny(amp, seeded=True, **sizes):
+    main, startup, fetches, pairs = _program(**sizes)
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace(), amp=amp)
+    exe.run(startup, scope=scope)
+    if seeded:
+        _seeded_weights(scope, [p.name for p, _ in pairs])
+    params = {p.name: np.asarray(scope.find_var(p.name)) for p, _ in pairs}
+    feed = _batch()
+    out = exe.run(main, feed=feed,
+                  fetch_list=[fetches[n] for n in FETCHES]
+                  + [g for _, g in pairs], scope=scope)
+    got = dict(zip(FETCHES, out))
+    grads = dict(zip((p.name for p, _ in pairs), out[len(FETCHES):]))
+    return main, params, feed, got, grads
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    main, params, feed, got, grads = _run_tiny(amp=False)
+    tokens, labels = jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"])
+    want, want_grads = ref.loss_and_grads(
+        params, tokens, labels, last=TINY["seq_len"], **REF_KW)
+    return dict(main=main, params=params, tokens=tokens, labels=labels,
+                got=got, grads=grads, want=want, want_grads=want_grads)
+
+
+BLOCK = ["mixer_norm.w", "mlp_norm.w", "mlp.gate.w", "mlp.up.w",
+         "mlp.down.w"]
+GDN = ["gdn." + n for n in ("q.w", "k.w", "v.w", "g.w", "a.w", "b.w",
+                            "conv.w", "A_log", "dt_bias", "norm.w", "o.w")]
+ATTN = ["attn." + n for n in ("q.w", "k.w", "v.w", "o.w", "q_norm.w",
+                              "k_norm.w")]
+PARAM_NAMES = (["embed.w", "final_norm.w", "head.w"]
+               + [f"l{i}.{n}" for i in range(4)
+                  for n in (ATTN if i == 3 else GDN) + BLOCK])
+
+
+def test_tiny_model_has_the_reference_parameters_at_the_shares_widths(tiny):
+    shapes = {n: v.shape for n, v in tiny["params"].items()}
+    assert sorted(shapes) == sorted(PARAM_NAMES)
+    assert shapes["l0.gdn.q.w"] == shapes["l0.gdn.k.w"] == (64, 2 * 12)
+    assert shapes["l0.gdn.v.w"] == shapes["l0.gdn.g.w"] == (64, 2 * 24)
+    assert shapes["l0.gdn.a.w"] == shapes["l0.gdn.b.w"] == (64, 2)
+    assert shapes["l0.gdn.conv.w"] == (2 * (12 + 12 + 24), 4)
+    assert shapes["l0.gdn.norm.w"] == (24,)
+    assert shapes["l0.gdn.o.w"] == (2 * 24, 64)
+    assert shapes["l3.attn.q.w"] == shapes["l3.attn.v.w"] == (64, 2 * 16)
+    assert shapes["l3.attn.q_norm.w"] == shapes["l3.attn.k_norm.w"] == (32,)
+    assert shapes["l3.attn.o.w"] == (32, 64)
+    assert shapes["l0.mlp.gate.w"] == (64, 96)      # the feed-forward whole
+
+
+def test_seeded_beta_lies_on_both_sides_of_one_in_the_first_layer(tiny):
+    p = tiny["params"]
+    x = p["embed.w"][np.asarray(tiny["tokens"])]
+    beta = 2 / (1 + np.exp(-(x @ p["l0.gdn.b.w"])))
+    assert beta.min() < 0.3 and beta.max() > 1.7
+    assert 0.25 < np.mean(beta > 1) < 0.75
+
+
+@pytest.mark.parametrize("name", FETCHES)
+def test_tiny_model_output_matches_reference(tiny, name):
+    want = np.asarray(tiny["want"][name])
+    assert rel_err(np.reshape(tiny["got"][name], want.shape), want) < 1e-4
+
+
+@pytest.mark.parametrize("name", PARAM_NAMES)
+def test_tiny_model_gradient_matches_reference(tiny, name):
+    assert frob(tiny["grads"][name], tiny["want_grads"][name]) < 2e-4
+
+
+@pytest.mark.parametrize("kind", model.KINDS)
+def test_one_layer_of_each_kind_matches_reference(kind):
+    sizes = dict(n_layer=1, layer_types=[kind])
+    main, startup, fetches, pairs = _program(**sizes)
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    _seeded_weights(scope, [p.name for p, _ in pairs], seed=11)
+    params = {p.name: np.asarray(scope.find_var(p.name)) for p, _ in pairs}
+    assert any(n.startswith("l0.gdn.") for n in params) \
+        == (kind == "linear_attention")
+    feed = _batch(seed=4)
+    out = exe.run(main, feed=feed, fetch_list=[fetches["loss"],
+                                               fetches["logits"]]
+                  + [g for _, g in pairs], scope=scope)
+    want, want_grads = ref.loss_and_grads(
+        params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
+        last=TINY["seq_len"], **{**REF_KW, **sizes})
+    assert abs(float(out[0][0]) - float(want["loss"])) < 1e-5
+    assert rel_err(out[1], want["logits"]) < 1e-4
+    for (p, _), g in zip(pairs, out[2:]):
+        assert frob(g, want_grads[p.name]) < 2e-4, p.name
+    assert observe.observatory().latest(main._uid).detail["layer_kinds"] \
+        == {kind: 1}
+
+
+@pytest.mark.parametrize("sizes", [
+    dict(allow_neg_eigval=False), dict(rope_theta=500000.0),
+    dict(heads_held=None), dict(heads_held=1)],
+    ids=["beta_below_one", "rotary_at_olmo3s_theta", "every_head",
+         "one_head_of_four"])
+def test_the_other_readings_match_the_reference_too(sizes):
+    """What `assumed` leaves as a build argument: sigmoid alone, the full
+    layers turned at OLMo 3's theta, the whole layer and another share."""
+    _, params, feed, got, grads = _run_tiny(amp=False, **sizes)
+    kw = {k: v for k, v in sizes.items() if k != "heads_held"}
+    want, want_grads = ref.loss_and_grads(
+        params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
+        last=TINY["seq_len"], **REF_KW, **kw)
+    held = TINY["n_head"] if sizes.get("heads_held", 2) is None \
+        else sizes.get("heads_held", 2)
+    assert params["l3.attn.q.w"].shape == (64, held * 16)
+    assert abs(float(got["loss"][0]) - float(want["loss"])) < 1e-5
+    assert rel_err(got["logits"], want["logits"]) < 1e-4
+    for name in ("l0.gdn.b.w", "l0.gdn.q.w", "l3.attn.q.w", "l3.attn.k.w",
+                 "embed.w"):
+        assert frob(grads[name], want_grads[name]) < 2e-4, name
+
+
+def test_reference_in_blocks_is_the_reference(tiny):
+    """`q_block`, `token_block` and `remat` are the reference's memory, not
+    its mathematics."""
+    parts, grads = ref.loss_and_grads(
+        tiny["params"], tiny["tokens"], tiny["labels"],
+        wrt=["l0.gdn.q.w", "l0.gdn.b.w", "l3.attn.q.w", "embed.w"],
+        q_block=32, token_block=16, remat=True, **REF_KW)
+    assert abs(float(parts["loss"]) - float(tiny["want"]["loss"])) < 1e-5
+    for name, g in grads.items():
+        assert frob(g, tiny["want_grads"][name]) < 5e-5, name
+
+
+def test_reference_last_positions_equal_the_full_pass(tiny):
+    parts = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
+                           last=16, **REF_KW)
+    assert rel_err(parts["logits"], tiny["want"]["logits"][:, -16:]) < 1e-5
+
+
+@pytest.mark.parametrize("fault", sorted(ref.FAULTS))
+def test_every_planted_fault_is_another_function(tiny, fault):
+    """A fault the reference can plant moves the logits by far more than
+    the system differs from the reference."""
+    bad = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
+                         last=TINY["seq_len"], fault=fault, **REF_KW)
+    assert rel_err(bad["logits"], tiny["want"]["logits"]) > 1e-2
+
+
+def test_the_reference_refuses_a_fault_it_does_not_know(tiny):
+    with pytest.raises(ValueError, match="fault is one of"):
+        ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
+                       fault="no_such_fault", **REF_KW)
+
+
+# -- one chip's share of a layer's heads -----------------------------------------------
+
+def _layer_weights(kind, seed=5):
+    """A whole layer's mixer weights (4 heads) under the reference's names,
+    far from their initial values."""
+    rng = np.random.RandomState(seed)
+    d, heads = 64, 4
+
+    def mat(*shape, std=0.1):
+        return (rng.randn(*shape) * std).astype(np.float32)
+
+    if kind == "full_attention":
+        wide = heads * 16
+        return {"q.w": mat(d, wide), "k.w": mat(d, wide), "v.w": mat(d, wide),
+                "o.w": mat(wide, d),
+                "q_norm.w": rng.uniform(0.5, 1.5, wide).astype(np.float32),
+                "k_norm.w": rng.uniform(0.5, 1.5, wide).astype(np.float32)}
+    wk, wv = heads * 12, heads * 24
+    return {"q.w": mat(d, wk), "k.w": mat(d, wk), "v.w": mat(d, wv),
+            "g.w": mat(d, wv), "a.w": mat(d, heads),
+            "b.w": mat(d, heads, std=0.25), "o.w": mat(wv, d),
+            "conv.w": rng.uniform(-0.5, 0.5, (2 * wk + wv, 4))
+            .astype(np.float32),
+            "A_log": np.log(rng.uniform(0.05, 1, heads)).astype(np.float32),
+            "dt_bias": rng.uniform(-1, 1, heads).astype(np.float32),
+            "norm.w": rng.uniform(0.5, 1.5, 24).astype(np.float32)}
+
+
+def _share_of(whole, kind, first, held, heads=4):
+    """The weights of heads `first .. first + held - 1`: every per-head
+    projection's columns (`W_o`'s rows), the convolution's channels of q, k
+    and v, the gates' heads; one head's norm weight whole."""
+    per = {"full_attention": {n: 16 for n in ("q.w", "k.w", "v.w", "o.w",
+                                              "q_norm.w", "k_norm.w")},
+           "linear_attention": {"q.w": 12, "k.w": 12, "v.w": 24, "g.w": 24,
+                                "a.w": 1, "b.w": 1, "o.w": 24, "A_log": 1,
+                                "dt_bias": 1}}[kind]
+    out = {}
+    for name, value in whole.items():
+        if name in per:
+            cut = slice(first * per[name], (first + held) * per[name])
+            out[name] = value[cut] if name == "o.w" or value.ndim == 1 \
+                else value[:, cut]
+        elif name == "conv.w":
+            wk, wv = heads * 12, heads * 24
+            out[name] = np.concatenate(
+                [value[base + first * w:base + (first + held) * w]
+                 for base, w in ((0, 12), (wk, 12), (2 * wk, 24))])
+        else:
+            out[name] = value
+    return out
+
+
+def _system_mixers(kind, x, shares):
+    """The model's own mixer, built once a share on the same x (before the
+    out-norm), each under the share's weights."""
+    def build(d):
+        outs = []
+        for j, _ in enumerate(shares):
+            name = f"s{j}"
+            if kind == "full_attention":
+                outs.append(model._attention(d["x"], 4, 2, 16, None, 1e-6,
+                                             name))
+            else:
+                outs.append(model._gated_delta_net(
+                    d["x"], 2, 12, 24, 4, 2.0, 1e-6, name, seed=j))
+        return [layers.sums(outs)] + outs
+
+    params = {f"s{j}.{n}": v for j, w in enumerate(shares)
+              for n, v in w.items()}
+    return run_piece(build, {"x": x}, params)
+
+
+def test_the_two_shares_of_a_delta_rule_layer_add_up_to_the_whole_layer():
+    """From x: the mixer outputs (before the out-norm) that the two shares
+    give, heads 0-1 and 2-3, are the uncut reference's whole layer; forward
+    and the gradient of the layer's input. Nothing crosses heads in this
+    layer but the out projection's sum."""
+    rng = np.random.RandomState(7)
+    x = rng.randn(2, 128, 64).astype(np.float32)
+    whole = _layer_weights("linear_attention")
+    shares = [_share_of(whole, "linear_attention", f, 2) for f in (0, 2)]
+    outs, grads, probe = _system_mixers("linear_attention", x, shares)
+
+    def want(x, w):
+        return ref.gated_delta_net(w, x, key_dim=12, value_dim=24, eps=1e-6)
+
+    with jax.default_matmul_precision("highest"):
+        full = want(x, whole)
+        assert rel_err(outs[0], full) < 1e-4
+        for j, share in enumerate(shares):      # each is the reference's share
+            assert rel_err(outs[1 + j], want(x, share)) < 1e-4
+        assert rel_err(outs[1], full) > 0.1     # and no share is the whole
+        gx = jax.grad(lambda a: jnp.sum(want(a, whole) * probe))(x)
+    assert rel_err(grads["x"], gx) < 2e-4
+
+
+def test_the_two_attention_shares_add_up_given_the_whole_layers_statistic():
+    """The QK-norm's mean is over the whole projection, so a share on its
+    own norms over what it holds (and is the reference's share); handed the
+    whole layer's normed q and k (the one statistic the absent chip would
+    have sent: a sum of squares a token for q and one for k), the two
+    shares' `W_o` outputs add up to the whole layer's."""
+    rng = np.random.RandomState(8)
+    x = rng.randn(2, 128, 64).astype(np.float32)
+    whole = _layer_weights("full_attention")
+    shares = [_share_of(whole, "full_attention", f, 2) for f in (0, 2)]
+    outs, _, _ = _system_mixers("full_attention", x, shares)
+    kw = dict(head_dim=16, theta=None, eps=1e-6)
+    with jax.default_matmul_precision("highest"):
+        for j, share in enumerate(shares):      # the system's share is the
+            assert rel_err(outs[1 + j],         # reference's, held statistic
+                           ref.attention(share, x, **kw)) < 1e-4
+        full = ref.attention(whole, x, **kw)
+        q = ref.rms_norm(x @ whole["q.w"], whole["q_norm.w"], 1e-6)
+        k = ref.rms_norm(x @ whole["k.w"], whole["k_norm.w"], 1e-6)
+        parts = [ref.attention(share, x, qk=(q[..., f * 16:(f + 2) * 16],
+                                             k[..., f * 16:(f + 2) * 16]),
+                               **kw)
+                 for share, f in zip(shares, (0, 2))]
+        assert rel_err(parts[0] + parts[1], full) < 1e-5
+        # without the statistic the shares do not add up: the norm is not
+        # a head's own
+        assert rel_err(outs[0], full) > 1e-2
+
+
+def test_heads_held_is_checked_when_the_program_is_built():
+    for bad in (0, 5):
+        with pytest.raises(ValueError, match="heads_held"):
+            _program(heads_held=bad)
+    with pytest.raises(ValueError, match="layer_types"):
+        _program(layer_types=["sliding_attention"])
+
+
+# -- what the Program says of itself --------------------------------------------------
+
+def test_compile_event_carries_the_census():
+    main, startup, fetches, _ = _program(
+        fluid.optimizer.SGD(learning_rate=1e-3))
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    exe.run(main, feed=_batch(), fetch_list=[fetches["loss"]], scope=scope)
+    detail = observe.observatory().latest(main._uid).detail
+    assert detail["layer_kinds"] == {"linear_attention": 3,
+                                     "full_attention": 1}
+    assert detail["linear_attention_head_dims"] == [12, 24]
+    assert detail["delta_rule_beta_scale"] == 2
+    assert detail["attention_heads_held"] == 2
+    assert detail["attention_heads"] == 4
+    assert detail["residual_out_norms"] == 8
+    assert detail["gdn_plan"] == "xla"
+    assert detail["grad_fanin_max"] == 1
+    assert "attention_rotary_layers" not in detail
+    assert "moe_experts_routed" not in detail
+
+
+def test_the_whole_layer_says_nothing_of_a_share():
+    main, _, _, _ = _program(heads_held=None, allow_neg_eigval=False)
+    from paddle_tpu.observe import census
+    detail = census.program_detail(main)
+    assert "attention_heads" not in detail
+    assert "delta_rule_beta_scale" not in detail
+    assert detail["linear_attention_head_dims"] == [12, 24]
+
+
+def test_every_layer_is_built_under_its_name_scopes(tiny):
+    scopes = {}
+    for op in tiny["main"].global_block().ops:
+        if op.attrs.get("__role__") is None:
+            scopes.setdefault(op.attrs.get(ir.NAME_SCOPE_ATTR), set()) \
+                .add(op.type)
+    assert {"l0.gdn", "l1.gdn", "l2.gdn", "l3.attn", "l0.mlp", "l1.mlp",
+            "l2.mlp", "l3.mlp"} <= set(scopes)
+    assert "l3.gdn" not in scopes and "l0.attn" not in scopes
+    assert {"gated_delta_rule", "delta_rule_gates", "causal_conv1d",
+            "gated_rms_norm"} <= scopes["l0.gdn"]
+    assert "fused_attention" in scopes["l3.attn"]
+    assert "rotary_embedding" not in scopes["l3.attn"]
+    assert "transpose" not in scopes["l3.attn"]     # token-major as it lies
+    assert "swiglu" in scopes["l2.mlp"]
+
+
+def test_tiny_model_amp_within_bf16_of_reference():
+    """Under AMP the residual stream and the projections are bf16; g, beta,
+    the rule's sums and state and every norm's statistics stay float32.
+    Against the float32 reference that is bf16 rounding compounded over four
+    post-norm layers (every sublayer's output is brought to the residual's
+    size, so its rounding passes on whole), beta on both sides of 1. At key
+    heads of 48, not the other tests' 12: a head of 12 channels after silu
+    is now and then nearly 0, the l2-norm's gradient is 1 / |q| there, and
+    the bf16 rounding of the convolution's sum then moves q's and k's
+    gradients by several times their size (the reference computed in
+    bfloat16 throughout misses them by 0.3 too); the published 96 has no
+    such tokens."""
+    sizes = dict(key_dim=48, value_dim=96)
+    _, params, feed, got, grads = _run_tiny(amp=True, **sizes)
+    want, want_grads = ref.loss_and_grads(
+        params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
+        last=TINY["seq_len"], **{**REF_KW, **sizes})
+    assert abs(float(got["loss"][0]) - float(want["loss"])) < 0.01
+    assert got["logits"].dtype == jnp.bfloat16
+    err = np.abs(np.asarray(got["logits"], np.float32)
+                 - np.asarray(want["logits"]))
+    std = float(np.std(want["logits"]))
+    assert err.mean() < 0.04 * std and err.max() < 0.5 * std
+    for name in ("l0.gdn.q.w", "l0.gdn.b.w", "l0.gdn.conv.w", "l0.gdn.o.w",
+                 "l3.attn.q.w", "l3.attn.q_norm.w", "l0.mlp.up.w",
+                 "l0.mixer_norm.w", "embed.w", "head.w"):
+        assert grads[name].dtype == np.float32
+        assert frob(grads[name], want_grads[name]) < 0.3, name
+
+
+def test_amp_keeps_the_gates_in_float32():
+    main, _, _, _ = _program(n_layer=1)
+    block = main.global_block()
+    gates = next(o for o in block.ops if o.type == "delta_rule_gates")
+    assert "delta_rule_gates" in registry.AMP_F32_OPS
+    assert gates.attrs["beta_scale"] == 2.0
+    assert block.var(gates.output("Beta")[0]).dtype == "float32"
+
+
+def test_five_adam_steps_lower_the_loss():
+    main, startup, fetches, _ = _program(
+        fluid.optimizer.Adam(learning_rate=3e-3))
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    feed = _batch()
+    losses = [float(exe.run(main, feed=feed, fetch_list=[fetches["loss"]],
+                            scope=scope)[0][0]) for _ in range(6)]
+    assert np.all(np.isfinite(losses)) and losses[-1] < losses[0] - 0.05
+
+
+def test_the_initial_values_are_the_public_codes():
+    """`A_log` = log of uniform(0, 16), `dt_bias` the inverse softplus of a
+    log-uniform draw in [0.001, 0.1], the convolution uniform(+-0.5), the
+    norms' weights 1, matrices of std 0.02."""
+    main, startup, _, _ = _program(n_layer=1, layer_types=model.KINDS[:1])
+    scope = fluid.Scope()
+    fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
+    value = lambda n: np.asarray(scope.find_var(n))
+    a = np.exp(value("l0.gdn.A_log"))
+    assert a.shape == (2,) and np.all(a > 0) and np.all(a < 16)
+    dt = np.log1p(np.exp(value("l0.gdn.dt_bias")))
+    assert np.all(dt >= 0.001 - 1e-6) and np.all(dt <= 0.1 + 1e-6)
+    assert np.abs(value("l0.gdn.conv.w")).max() <= 0.5
+    for n in ("l0.gdn.norm.w", "l0.mixer_norm.w", "l0.mlp_norm.w",
+              "final_norm.w"):
+        assert np.all(value(n) == 1), n
+    assert 0.015 < value("l0.mlp.gate.w").std() < 0.025
+
+
+# -- the models whose code moved are what they were -----------------------------------
+
+@pytest.mark.parametrize("other", ["olmoe", "qwen3_next"])
+def test_the_models_that_share_the_moved_code_are_unchanged_op_for_op(other):
+    """OLMoE's whole-projection QK-norm and Qwen3-Next's layer from its
+    convolution to its gated norm are written once in `models/_decoder.py`
+    since this file's PR; their Programs are the Programs they were, and
+    Qwen3-Next's gates op carries no factor."""
+    from test_decoder_models import DIGESTS, build_program, program_digest
+    main, startup, _, _ = build_program(other)
+    assert program_digest(main, startup) == DIGESTS[other]
+    assert not any("beta_scale" in op.attrs or "heads_total" in op.attrs
+                   for op in main.global_block().ops)
+
+
+def test_the_two_copies_of_the_reference_are_identical():
+    assert filecmp.cmp(
+        os.path.join(HERE, "olmo_hybrid_reference.py"),
+        os.path.join(HERE, "..", "benchmark", "references",
+                     "olmo_hybrid_reference.py"), shallow=False)
