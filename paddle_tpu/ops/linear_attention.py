@@ -27,10 +27,15 @@ D[i, j] = exp(G_i - G_j) for i >= j:
 
 Every exponent is <= 0, so nothing overflows however negative g is.
 
-Where a chunk's tiles fill vregs (`_plan`: chunk 64, both head dims
-multiples of 128: the published widths) the rule is two Pallas kernels on a
-grid of (batch, key head, step of `p` chunks), the last axis sequential;
-`_plan` takes `p` = 2 consecutive chunks a step where the chunk count is
+Where a chunk's tiles fill vregs (`_plan`: chunk 64, both head dims at
+least 64; a head dim that is not a whole number of 128-lane tiles, as
+`olmo_hybrid`'s 96 / 192, is filled out with zero channels to the next one
+around the calls, `_filled_out`: zero key channels add nothing to `k k^T`,
+`q k^T` or the l2-norms and a zero value column stays zero through the solve
+and the state, so the kernels are told the rule's `Dk^-0.5` and nothing
+else changes; `States` keeps the given `[Dk, Dv]`) the rule is two Pallas
+kernels on a grid of (batch, key head, step of `p` chunks), the last axis
+sequential; `_plan` takes `p` = 2 consecutive chunks a step where the chunk count is
 even (and the pair's blocks fit the VMEM a call has unasked), 1 otherwise,
 through one kernel body each way. A grid step takes the key head's value
 heads together, so q, k and their Gram tiles `k k^T`, `q k^T` are made once
@@ -71,7 +76,9 @@ body.
               g's gradient.
 
 The op and its grad op tally the grid steps of their calls on the compile
-event (`gdn_grid_steps`: batch x key heads x chunks / `p`, summed).
+event (`gdn_grid_steps`: batch x key heads x chunks / `p`, summed), beside
+`gdn_plan` (`"kernel"` or `"xla"`) and, where channels were filled in,
+`gdn_lanes_filled` (`[32, 64]`: a key and a value head's).
 
 T comes from blocked forward substitution (`_Chunks.inverses`): the
 16-wide diagonal blocks column by column in float32 on the VPU (15 rank-one
@@ -88,8 +95,8 @@ backward) take the backend's DEFAULT for float32 operands (`_dot`: on the
 chip the operands rounded to bf16, one pass, as XLA's default does there;
 float32 under the interpreter on a CPU).
 
-Outside the envelope (the tiny head dims of the CPU tests), and on a CPU
-backend unless the Pallas interpreter is asked for
+Outside the envelope (the tiny head dims of the CPU tests, another chunk),
+and on a CPU backend unless the Pallas interpreter is asked for
 (`PADDLE_TPU_PALLAS_INTERPRET=1`), the op keeps the XLA form
 `chunked_gated_delta_rule`: A, the solve, u and w for all chunks at once, a
 `lax.scan` over the chunks' states, then the outputs for all chunks at once;
@@ -460,20 +467,38 @@ def chunked_kda_rule(q, k, v, g, beta, chunk):
 # ---------------------------------------------------------------------------
 
 _SUB = 16               # the diagonal blocks the substitution inverts on the VPU
+_LANES = 128            # a vreg's lanes: what a head dim is filled out to
 _VMEM = 12 << 20        # of the 16 MiB a call has unasked, what blocks may take
 
 
+def _filled(d):
+    """`d` channels filled out with zeros to whole tiles of 128 lanes."""
+    return -(-d // _LANES) * _LANES
+
+
 def _plan(Dk, Dv, chunk, chunks=1, r=1):
-    """("kernel", p): a chunk's tiles fill vregs (head dims whole lanes of
-    128, the chunk the 64 tokens the blocked substitution is laid out for),
-    and a grid step takes `p` consecutive chunks of a key head: 2 where the
-    chunks pair up and the pair's blocks (twice, the pipeline holds two of
+    """("kernel", p): the chunk is the 64 tokens the blocked substitution is
+    laid out for and both head dims are at least half a lane tile (64). A
+    head dim that is not a whole number of 128-lane tiles (Olmo-Hybrid's 96
+    / 192) is filled out with zero channels to the next one (128 / 256:
+    `_filled`; `_gdn_forward` / `_gdn_backward` fill q, k, v and dO and cut
+    Out, dq, dk, dv back), because the kernels' time is mostly the
+    `[64, 64]` tiles of a chunk (D, A, T, P and their gradients), which do
+    not depend on the head dims, while the XLA form spends its time in a
+    `while` over the chunks' states and a batched triangular solve whatever
+    the heads are: one layer at `[1, 4096, 15, 96 / 192]` took 8.9 ms
+    forward and backward as XLA ops and 5.1 through the filled-out kernels
+    (`tools/gdn_offtile_probe.py`). Under 64 more than half of every
+    operand tile would be zeros and nothing was measured. A grid step takes
+    `p` consecutive chunks of a key head: 2 where the chunks pair up and the
+    pair's blocks AT THE FILLED WIDTHS (twice, the pipeline holds two of
     each) and the state fit the VMEM a call has without asking for more, 1
-    otherwise. ("xla", 0): anything else (the tiny head dims of the CPU
-    tests), which keeps `chunked_gated_delta_rule` and its vjp. One
-    algorithm either way; the choice reads the shape alone."""
-    if not (chunk == 64 and Dk % 128 == 0 and Dv % 128 == 0):
+    otherwise. ("xla", 0): anything else (another chunk, the tiny head dims
+    of the CPU tests), which keeps `chunked_gated_delta_rule` and its vjp.
+    One algorithm either way; the choice reads the shape alone."""
+    if chunk != 64 or min(Dk, Dv) < _LANES // 2:
         return "xla", 0
+    Dk, Dv = _filled(Dk), _filled(Dv)
 
     def vmem(p):    # the backward's blocks (the larger), float32 operands
         tokens = 4 * p * chunk * (4 * Dk + 3 * r * Dv)  # q k v dO dv dq dk
@@ -517,22 +542,25 @@ class _Chunks:
     what the blocks off the diagonal hold is dropped or zero. `heads(...)`
     gives the factors of the key head's value heads that read no state."""
 
-    def __init__(self, q_ref, k_ref, g_ref, beta_ref, hk, r, p):
-        self._operands(q_ref, k_ref, hk, r, p)
+    def __init__(self, q_ref, k_ref, g_ref, beta_ref, hk, r, p, scale):
+        self._operands(q_ref, k_ref, hk, r, p, scale)
         self.kk = self.beside(_dot(self.k, self.k, _NT, full=True))  # k k^T
         self.qk = self.beside(_dot(self.q, self.k, _NT))             # q k^T
         self.G_tile, self.beta_tile = g_ref[0], beta_ref[0]      # [p C, Hv]
 
-    def _operands(self, q_ref, k_ref, hk, r, p):
-        """q and k of the step, normalised, and the two layouts' indices:
-        what the per-channel rule's step (`_KdaChunks`) starts from too."""
-        n, Dk = q_ref.shape[1], q_ref.shape[2]
+    def _operands(self, q_ref, k_ref, hk, r, p, scale):
+        """q and k of the step, normalised, q then scaled by `scale` (a
+        Python float: the rule's `Dk^-0.5` at the head dim the op was given,
+        which the block's width is not where zero channels fill it out),
+        and the two layouts' indices: what the per-channel rule's step
+        (`_KdaChunks`) starts from too."""
+        n = q_ref.shape[1]
         self.r, self.p, self.C = r, p, n // p
         C = self.C
         self.first_head = hk * r
         self.qn, self.rq = _l2(q_ref)
         self.k, self.rk = _l2(k_ref)
-        self.scale = Dk ** -0.5
+        self.scale = scale
         self.q = self.qn * self.scale
         lane = lax.broadcasted_iota(jnp.int32, (C, n), 1)
         self.row = lax.broadcasted_iota(jnp.int32, (C, n), 0)
@@ -660,8 +688,26 @@ class _Chunks:
         return heads
 
 
+def _state_cut(S, states_ref):
+    """A state [Dk, Dv] at the kernel's widths as `States` holds it: without
+    the zero rows and columns of the channels that were filled in (the
+    block's last two dims are the head dims the op was given)."""
+    dk, dv = states_ref.shape[3:]
+    return S if S.shape == (dk, dv) else S[:dk, :dv]
+
+
+def _state_filled(S, scratch):
+    """A saved state at the widths the kernel works at (`scratch`'s): the
+    filled-in channels' rows and columns are zeros, as the forward had
+    them."""
+    Dk, Dv = scratch.shape[1:]
+    if S.shape == (Dk, Dv):
+        return S
+    return jnp.pad(S, ((0, Dk - S.shape[0]), (0, Dv - S.shape[1])))
+
+
 def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, o_ref,
-                    s_sc, *, r, p):
+                    s_sc, *, r, p, scale):
     """One (batch, key head, `p` chunks) step for the key head's `r` value
     heads: the factors that read no state for the `p` chunks at once, then
     the state through the chunks in order (written as each found it,
@@ -672,14 +718,14 @@ def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, o_ref,
     def _init():
         s_sc[...] = jnp.zeros_like(s_sc)
 
-    ch = _Chunks(q_ref, k_ref, g_ref, beta_ref, pl.program_id(1), r, p)
+    ch = _Chunks(q_ref, k_ref, g_ref, beta_ref, pl.program_id(1), r, p, scale)
     Dv = s_sc.shape[2]
     heads = ch.heads(v_ref, Dv)
     S = [s_sc[j] for j in range(r)]
     v_new, from_state = [[] for _ in heads], [[] for _ in heads]
     for i in range(p):
         for j, f in enumerate(heads):
-            states_ref[i, 0, j] = S[j]
+            states_ref[i, 0, j] = _state_cut(S[j], states_ref)
             v_new[j].append(ch.of(i, f["u"])
                             - _dot(ch.of(i, f["w"]), S[j], _NN))
             from_state[j].append(_dot(ch.of(i, f["qg"]), S[j], _NN))
@@ -694,7 +740,7 @@ def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, o_ref,
 
 def _gdn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
                     dG_ref, dbeta_ref, dv_ref, dq_ref, dk_ref, ds_sc, *, r,
-                    p):
+                    p, scale):
     """The same step with the steps, and the chunks inside one, taken last
     to first. dS, the gradient of the state a chunk hands on, is carried in
     scratch and passes through the step's chunks; the chunks' factors are
@@ -707,14 +753,14 @@ def _gdn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
     def _init():
         ds_sc[...] = jnp.zeros_like(ds_sc)
 
-    ch = _Chunks(q_ref, k_ref, g_ref, beta_ref, pl.program_id(1), r, p)
+    ch = _Chunks(q_ref, k_ref, g_ref, beta_ref, pl.program_id(1), r, p, scale)
     C, Dv = ch.C, ds_sc.shape[2]
     strict = ch.row > ch.col
     at_last = (ch.col == C - 1)[:1]                     # [1, p C]
     heads = ch.heads(v_ref, Dv)
     # o = qg S + P v';  S' = e_last S + k_tail^T v';  v' = u - w S
     for j, f in enumerate(heads):
-        S = [states_ref[i, 0, j] for i in range(p)]
+        S = [_state_filled(states_ref[i, 0, j], ds_sc) for i in range(p)]
         dO = do_ref[0, :, j * Dv:(j + 1) * Dv].astype(jnp.float32)
         f.update(S=S, dO=dO, dS=[None] * p + [ds_sc[j]],   # dS[i + 1]: of
                  dv_new=[None] * p,                     # what chunk i gives
@@ -808,18 +854,22 @@ def _grid(Q, V, chunk):
 
 
 def _gdn_call(kernel, name, Q, K, V, G, beta, more, out_shape, out_blocks,
-              chunk, reverse, decay="gates"):
+              chunk, reverse, decay="gates", dims=None):
     """Both kernels' grid and blocks, of both rules: (batch, key head, step
     of `p` chunks), the last axis sequential. q, k, v and their like are read
     where they lie, as [B, T, heads * dim] with a head's lanes chosen by the
     block index (a key head's `r` value heads are `r * Dv` adjacent lanes, so
     nothing is repeated); G and beta as [B, T, Hv], every head of the step's
     tokens in one block; the per-channel rule's G [B, T, H * Dk] is read like
-    a key (`decay="key"`)."""
+    a key (`decay="key"`). `dims` are the head dims `(Dk, Dv)` of the rule
+    where they are not the operands' (`_filled_out`): q's scale is their
+    `Dk^-0.5` and a saved state is `[Dk, Dv]` in HBM, a block whose last two
+    dims are the whole array's."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     Dk, Dv, r = Q.shape[3], V.shape[3], V.shape[2] // Q.shape[2]
+    dims = dims or (Dk, Dv)
     grid, p = _grid(Q, V, chunk)
     rows = p * chunk
 
@@ -832,13 +882,14 @@ def _gdn_call(kernel, name, Q, K, V, G, beta, more, out_shape, out_blocks,
                               lambda b, h, c: (b, at(c), h)),
         "gates": pl.BlockSpec((1, rows, V.shape[2]),
                               lambda b, h, c: (b, at(c), 0)),
-        "states": pl.BlockSpec((p, 1, r, Dk, Dv),
+        "states": pl.BlockSpec((p, 1, r) + dims,
                                lambda b, h, c: (at(c), b, h, 0, 0)),
         "gate_rows": pl.BlockSpec((1, r, p, 1, chunk),
                                   lambda b, h, c: (b, h, at(c), 0, 0))}
     ins = ["key", "key", "value", decay, "gates"] + [x for x, _ in more]
     return pl.pallas_call(
-        functools.partial(kernel, r=r, p=p), name=name, grid=grid,
+        functools.partial(kernel, r=r, p=p, scale=dims[0] ** -0.5),
+        name=name, grid=grid,
         in_specs=[blocks[x] for x in ins],
         out_specs=[blocks[x] for x in out_blocks], out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((r, Dk, Dv), jnp.float32)],
@@ -848,16 +899,50 @@ def _gdn_call(kernel, name, Q, K, V, G, beta, more, out_shape, out_blocks,
     )(_flat(Q), _flat(K), _flat(V), G, beta, *[x for _, x in more])
 
 
+def _fill(x, width):
+    """`x` [..., d] with zero channels after its own up to `width`; `x`
+    itself where it has them all."""
+    d = x.shape[-1]
+    if d == width:
+        return x
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, width - d),))
+
+
+def _cut(x, like):
+    """A kernel's [B, T, heads * width] result in `like`'s shape [B, T,
+    heads, d]: the filled-in channels of every head dropped."""
+    x = x.reshape(like.shape[:3] + (-1,))
+    return x if x.shape == like.shape else x[..., :like.shape[3]]
+
+
+def _filled_out(Q, K, V, d_out=None):
+    """(q, k, v, dO) as the kernels take them, their channels filled out
+    with zeros to whole lanes (`_plan`: 96 / 192 -> 128 / 256; nothing
+    where a head dim is whole tiles already), and the head dims the op was
+    given, which `_gdn_call` takes q's scale and a saved state's shape
+    from. Zero key channels add nothing to `k k^T`, `q k^T` or the
+    l2-norms, and a zero value column stays zero through the solve and the
+    state, so nothing else of the rule depends on the fill."""
+    Dk, Dv = Q.shape[3], V.shape[3]
+    wide = [_fill(Q, _filled(Dk)), _fill(K, _filled(Dk)),
+            _fill(V, _filled(Dv)),
+            None if d_out is None
+            else _fill(d_out.astype(V.dtype), _filled(Dv))]
+    return wide, (Dk, Dv)
+
+
 def _gdn_forward(Q, K, V, g, beta, chunk):
     """Q, K [B, T, Hk, Dk] and V [B, T, Hv, Dv] as they arrive (not
     normalised), g, beta [B, T, Hv] -> out [B, T, Hv, Dv] in V's dtype and
     the states [chunks, B, Hv, Dk, Dv] float32, each as its chunk found
     it."""
+    (q, k, v, _), dims = _filled_out(Q, K, V)
     states, out = _gdn_call(
-        _gdn_fwd_kernel, "gdn_fwd", Q, K, V, _running_sum(g, chunk),
-        beta.astype(jnp.float32), [], _fwd_shapes(Q, V, chunk),
-        ["states", "value"], chunk, reverse=False)
-    return out.reshape(V.shape), states
+        _gdn_fwd_kernel, "gdn_fwd", q, k, v, _running_sum(g, chunk),
+        beta.astype(jnp.float32), [],
+        (_fwd_shapes(Q, V, chunk)[0], _fwd_shapes(q, v, chunk)[1]),
+        ["states", "value"], chunk, reverse=False, dims=dims)
+    return _cut(out, V), states
 
 
 def _per_token(x):      # gate rows [B, Hv, chunks, 1, C] -> [B, chunks, C, Hv]
@@ -867,15 +952,16 @@ def _per_token(x):      # gate rows [B, Hv, chunks, 1, C] -> [B, chunks, C, Hv]
 def _gdn_backward(Q, K, V, g, beta, states, d_out, chunk):
     """The five input gradients from the saved states and `d_out`
     [B, T, Hv, Dv], each in its input's shape and dtype."""
+    (q, k, v, d_out), dims = _filled_out(Q, K, V, d_out)
     dG, dbeta, dv, dq, dk = _gdn_call(
-        _gdn_bwd_kernel, "gdn_bwd", Q, K, V, _running_sum(g, chunk),
+        _gdn_bwd_kernel, "gdn_bwd", q, k, v, _running_sum(g, chunk),
         beta.astype(jnp.float32),
-        [("states", states), ("value", _flat(d_out.astype(V.dtype)))],
-        _bwd_shapes(Q, V, chunk),
+        [("states", states), ("value", _flat(d_out))],
+        _bwd_shapes(q, v, chunk),
         ["gate_rows", "gate_rows", "value", "key", "key"], chunk,
-        reverse=True)
+        reverse=True, dims=dims)
     dg = lax.cumsum(_per_token(dG), axis=2, reverse=True)
-    return (dq.reshape(Q.shape), dk.reshape(K.shape), dv.reshape(V.shape),
+    return (_cut(dq, Q), _cut(dk, K), _cut(dv, V),
             dg.reshape(g.shape).astype(g.dtype),
             _per_token(dbeta).reshape(beta.shape).astype(beta.dtype))
 
@@ -896,8 +982,8 @@ class _KdaChunks(_Chunks):
     (`to_mid`, `from_mid`: within +-8 |min g|). No gradient passes through a
     reference."""
 
-    def __init__(self, q_ref, k_ref, g_ref, beta_ref, h, p):
-        self._operands(q_ref, k_ref, h, 1, p)
+    def __init__(self, q_ref, k_ref, g_ref, beta_ref, h, p, scale):
+        self._operands(q_ref, k_ref, h, 1, p, scale)
         C, B, Dk = self.C, _KDA_BLOCK, q_ref.shape[2]
         n = p * C
         self.nb = C // B
@@ -1022,7 +1108,7 @@ class _KdaChunks(_Chunks):
 
 
 def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, o_ref,
-                    s_sc, *, r, p):
+                    s_sc, *, r, p, scale):
     """`_gdn_fwd_kernel`'s step for one head (`r` is 1) under the per-channel
     decay: the state's rows decay each by their own channel's
     `exp(G_last)`."""
@@ -1032,7 +1118,8 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, o_ref,
     def _init():
         s_sc[...] = jnp.zeros_like(s_sc)
 
-    ch = _KdaChunks(q_ref, k_ref, g_ref, beta_ref, pl.program_id(1), p)
+    ch = _KdaChunks(q_ref, k_ref, g_ref, beta_ref, pl.program_id(1), p,
+                    scale)
     f = ch.factors(v_ref)
     S = s_sc[0]
     v_new, from_state = [], []
@@ -1049,7 +1136,7 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, o_ref,
 
 def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
                     dG_ref, dbeta_ref, dv_ref, dq_ref, dk_ref, ds_sc, *, r,
-                    p):
+                    p, scale):
     """`_gdn_bwd_kernel`'s step for one head under the per-channel decay. dG
     is [p C, Dk]: the gradient of the running sum at each token and channel,
     through `exp(G)`, the tail's `exp(G_last - G)`, `exp(G_last)` (at a
@@ -1061,7 +1148,8 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
     def _init():
         ds_sc[...] = jnp.zeros_like(ds_sc)
 
-    ch = _KdaChunks(q_ref, k_ref, g_ref, beta_ref, pl.program_id(1), p)
+    ch = _KdaChunks(q_ref, k_ref, g_ref, beta_ref, pl.program_id(1), p,
+                    scale)
     C, beta = ch.C, ch.beta
     f = ch.factors(v_ref)
     eg, tail, u, w = f["eg"], f["tail"], f["u"], f["w"]
@@ -1424,6 +1512,9 @@ def _gated_delta_rule(ctx, Q, K, V, G, Beta):
     ctx.note(gdn_plan="kernel" if kernels else "xla")
     if kernels:
         _tally_grid(ctx, Q, V, chunk)
+        filled = [_filled(d) - d for d in (Dk, V.shape[3])]
+        if any(filled):     # zero channels a key and a value head gained
+            ctx.note(gdn_lanes_filled=filled)
         out, states = _gdn_forward(Q, K, V, G, Beta, chunk)
         return {"Out": out, "States": states}
     q = l2_normalize(Q.astype(jnp.float32)) * Dk ** -0.5
@@ -1493,8 +1584,9 @@ def _kda_check(ctx, Q, V, G, kernels=None):
                          f"of the chunk ({chunk}), as many value heads as "
                          f"key heads and a decay of q's shape, got q "
                          f"{Q.shape}, v {V.shape}, g {G.shape}")
-    if kernels is None:
-        kernels = _kernels_run(Dk, V.shape[3], chunk)
+    if kernels is None:     # whole lanes only: nothing fills this rule's out
+        kernels = Dk % _LANES == 0 and V.shape[3] % _LANES == 0 \
+            and _kernels_run(Dk, V.shape[3], chunk)
     ctx.note(kda_plan="kernel" if kernels else "xla")
     ctx.tally("kda_grid_steps", B * H * (T // chunk))
     if kernels:

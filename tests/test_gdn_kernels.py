@@ -1,6 +1,7 @@
 """The gated delta rule's two Pallas kernels (`ops/linear_attention.py`:
 `gdn_fwd`, `gdn_bwd`) under the Pallas interpreter on the CPU, at head dims
-that fill a vreg: against `jax.vjp` of `chunked_gated_delta_rule` (the XLA
+that fill a vreg and at ones that zero channels fill out to whole lanes (96
+/ 192, 64 / 192): against `jax.vjp` of `chunked_gated_delta_rule` (the XLA
 form the op keeps outside the kernels' envelope) and against the
 token-by-token recurrence of `tests/qwen3_next_reference.py`; the saved
 states; the op through a Program with and without the kernels; the plan's
@@ -19,12 +20,12 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu import layers, observe
+from paddle_tpu import layers
 from paddle_tpu.ops import _kernels
 from paddle_tpu.ops import linear_attention as la
 
 import qwen3_next_reference as ref
-from test_olmoe import run_piece
+from test_olmoe import piece_noted, run_piece
 from test_qwen3_next import REGIMES, RTOL, frob
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -37,17 +38,20 @@ def interpreted(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
 
 
-def _inputs(t, regime, seed=0, dk=D, dv=D):
-    """Raw q and k (the kernels normalise them) at 2 key / 4 value heads."""
+def _inputs(t, regime, seed=0, dk=D, dv=D, hv=HV, beta_over=None):
+    """Raw q and k (the kernels normalise them) at 2 key / `hv` value heads;
+    beta a sigmoid, or uniform over `beta_over`."""
     rng = np.random.RandomState(seed)
     (gs, go), (bs, bo) = REGIMES[regime]
     q = rng.randn(B, t, HK, dk).astype(np.float32)
     k = rng.randn(B, t, HK, dk).astype(np.float32)
-    v = rng.randn(B, t, HV, dv).astype(np.float32)
-    a = rng.randn(B, t, HV).astype(np.float32) * gs + go
-    g = -np.exp(rng.uniform(-1, 2.5, HV)).astype(np.float32) \
+    v = rng.randn(B, t, hv, dv).astype(np.float32)
+    a = rng.randn(B, t, hv).astype(np.float32) * gs + go
+    g = -np.exp(rng.uniform(-1, 2.5, hv)).astype(np.float32) \
         * np.log1p(np.exp(a))
-    beta = 1 / (1 + np.exp(-(rng.randn(B, t, HV) * bs + bo)))
+    beta = 1 / (1 + np.exp(-(rng.randn(B, t, hv) * bs + bo)))
+    if beta_over:
+        beta = rng.uniform(*beta_over, (B, t, hv))
     return q, k, v, g.astype(np.float32), beta.astype(np.float32)
 
 
@@ -56,20 +60,36 @@ def _shapes(chunks, dk=D, dv=D):
             jax.ShapeDtypeStruct((B, chunks * CHUNK, HV, dv), jnp.float32))
 
 
-def _prepared(q, k):
+def _prepared(q, k, hv=HV):
     """What the op does before either oracle: l2-norm, scale, a key head
     repeated over its value heads."""
     q = la.l2_normalize(jnp.asarray(q)) * q.shape[-1] ** -0.5
     k = la.l2_normalize(jnp.asarray(k))
-    return jnp.repeat(q, HV // HK, 2), jnp.repeat(k, HV // HK, 2)
+    return jnp.repeat(q, hv // HK, 2), jnp.repeat(k, hv // HK, 2)
 
 
 def _chunked(q, k, v, g, beta):
-    return la.chunked_gated_delta_rule(*_prepared(q, k), v, g, beta, CHUNK)
+    return la.chunked_gated_delta_rule(*_prepared(q, k, v.shape[2]), v, g,
+                                       beta, CHUNK)
 
 
 def _recurrence(q, k, v, g, beta):
-    return ref.delta_rule(*_prepared(q, k), v, g, beta, token_block=64)
+    return ref.delta_rule(*_prepared(q, k, v.shape[2]), v, g, beta,
+                          token_block=64)
+
+
+def _held_to(oracles, args, probe, out, grads):
+    """`out` and the five gradients against each oracle's `jax.vjp` at
+    HIGHEST."""
+    with jax.default_matmul_precision("highest"):
+        for oracle in oracles:
+            want, vjp = jax.vjp(oracle, *args)
+            assert frob(out, want) < RTOL, oracle.__name__
+            for name, got, w in zip(SLOTS, grads, vjp(jnp.asarray(probe))):
+                assert np.all(np.isfinite(got)), name
+                assert got.shape == w.shape
+                assert frob(got, w) < 2e-4, (oracle.__name__, name,
+                                             frob(got, w))
 
 
 @pytest.mark.parametrize("chunks", [1, 2, 3, 4])
@@ -86,15 +106,7 @@ def test_kernels_match_both_oracles(regime, chunks, interpreted):
     out, states = la._gdn_forward(*args, CHUNK)
     grads = la._gdn_backward(*args, states, probe, CHUNK)
     assert np.all(np.isfinite(out))
-    with jax.default_matmul_precision("highest"):
-        for oracle in (_chunked, _recurrence):
-            want, vjp = jax.vjp(oracle, *args)
-            assert frob(out, want) < RTOL, oracle.__name__
-            for name, got, w in zip(SLOTS, grads, vjp(jnp.asarray(probe))):
-                assert np.all(np.isfinite(got)), name
-                assert got.shape == w.shape
-                assert frob(got, w) < 2e-4, (oracle.__name__, name,
-                                             frob(got, w))
+    _held_to((_chunked, _recurrence), args, probe, out, grads)
 
 
 def test_kernels_at_unequal_head_dims(interpreted):
@@ -105,11 +117,55 @@ def test_kernels_at_unequal_head_dims(interpreted):
     out, states = la._gdn_forward(*args, CHUNK)
     assert states.shape == (2, B, HV, 256, D)
     grads = la._gdn_backward(*args, states, probe, CHUNK)
-    with jax.default_matmul_precision("highest"):
-        want, vjp = jax.vjp(_chunked, *args)
-        assert frob(out, want) < RTOL
-        for name, got, w in zip(SLOTS, grads, vjp(jnp.asarray(probe))):
-            assert frob(got, w) < 2e-4, (name, frob(got, w))
+    _held_to((_chunked,), args, probe, out, grads)
+
+
+@pytest.mark.parametrize("chunks", [2, 3])
+@pytest.mark.parametrize("r", [1, 2])
+@pytest.mark.parametrize("dk,dv", [(96, 192), (64, 192)])
+def test_kernels_off_the_lane_tile_match_both_oracles(dk, dv, r, chunks,
+                                                      interpreted):
+    """Head dims that are not whole tiles of 128 lanes (Olmo-Hybrid's 96 /
+    192, and 64 / 192): the pair on operands filled out with zero channels
+    (`_filled_out`), q's scale the given head dim's, the results cut back.
+    `Out`, the five gradients and the saved states at the GIVEN dims, beta
+    over (0.1, 1.9) (a negative eigenvalue above 1), one and two value
+    heads a key head, a pair of chunks a step and one."""
+    t, hv = chunks * CHUNK, r * HK
+    q = jax.ShapeDtypeStruct((B, t, HK, dk), jnp.float32)
+    v = jax.ShapeDtypeStruct((B, t, hv, dv), jnp.float32)
+    assert la._grid(q, v, CHUNK)[1] == (1 if chunks % 2 else 2)
+    args = _inputs(t, "mixed", seed=5, dk=dk, dv=dv, hv=hv,
+                   beta_over=(0.1, 1.9))
+    assert args[4].max() > 1.5 and args[4].min() < 0.5
+    probe = np.random.RandomState(9).randn(B, t, hv, dv).astype(np.float32)
+    out, states = la._gdn_forward(*args, CHUNK)
+    assert out.shape == (B, t, hv, dv)
+    assert states.shape == (chunks, B, hv, dk, dv)
+    assert states.shape == la._fwd_shapes(q, v, CHUNK)[0].shape
+    grads = la._gdn_backward(*args, states, probe, CHUNK)
+    _held_to((_chunked, _recurrence), args, probe, out, grads)
+
+
+def test_saved_states_off_the_lane_tile_are_the_padded_kernels_states(
+        interpreted):
+    """What `States` holds at 96 / 192 is the `[96, 192]` corner of the
+    state the kernel carries at 128 / 256, whose other rows and columns are
+    zeros: the same call on hand-padded operands gives it (no q enters a
+    state, so its scale does not)."""
+    q, k, v, g, beta = _inputs(4 * CHUNK, "mixed", dk=96, dv=192)
+    _, states = la._gdn_forward(q, k, v, g, beta, CHUNK)
+
+    def pad(x, width):
+        return np.pad(x, ((0, 0),) * 3 + ((0, width - x.shape[-1]),))
+
+    _, wide = la._gdn_forward(pad(q, 128), pad(k, 128), pad(v, 256), g, beta,
+                              CHUNK)
+    assert wide.shape == (4, B, HV, 128, 256)
+    np.testing.assert_array_equal(wide[..., 96:, :], 0.0)
+    np.testing.assert_array_equal(wide[..., :, 192:], 0.0)
+    np.testing.assert_array_equal(states, wide[..., :96, :192])
+    assert float(jnp.abs(states[1:]).max()) > 0
 
 
 @pytest.mark.parametrize("chunks", [3, 4])
@@ -153,20 +209,14 @@ def _layer(feed, params, chunk=CHUNK):
         feed, params)
 
 
-def _layer_feed(t=2 * CHUNK, d=D):
+def _layer_feed(t=2 * CHUNK, d=D, dv=None):
     rng = np.random.RandomState(2)
     feed = {"q": rng.randn(B, t, HK, d), "k": rng.randn(B, t, HK, d),
-            "v": rng.randn(B, t, HV, d), "a": rng.randn(B, t, HV),
+            "v": rng.randn(B, t, HV, dv or d), "a": rng.randn(B, t, HV),
             "b": rng.randn(B, t, HV)}
     params = {"A_log": np.log(rng.uniform(0.1, 4, HV)).astype(np.float32),
               "dt_bias": rng.uniform(0.5, 1.5, HV).astype(np.float32)}
     return {n: x.astype(np.float32) for n, x in feed.items()}, params
-
-
-def _plan_noted():
-    plans = [e.detail.get("gdn_plan") for e in observe.observatory().events()
-             if isinstance(e.detail, dict)]
-    return [p for p in plans if p][-1]
 
 
 def test_the_op_gives_the_same_numbers_with_and_without_the_kernels(
@@ -177,12 +227,12 @@ def test_the_op_gives_the_same_numbers_with_and_without_the_kernels(
     (the rule does not depend on how it is cut)."""
     feed, params = _layer_feed()
     (xla,), xla_grads, _ = _layer(feed, params)
-    assert _plan_noted() == "xla"
+    assert piece_noted("gdn_plan") == "xla"
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
     (kernel,), kernel_grads, _ = _layer(feed, params)
-    assert _plan_noted() == "kernel"
+    assert piece_noted("gdn_plan") == "kernel"
     (cut32,), cut32_grads, _ = _layer(feed, params, chunk=32)
-    assert _plan_noted() == "xla"
+    assert piece_noted("gdn_plan") == "xla"
     for out, grads in ((kernel, kernel_grads), (cut32, cut32_grads)):
         assert frob(out, xla) < RTOL
         assert sorted(grads) == sorted(xla_grads)
@@ -190,10 +240,36 @@ def test_the_op_gives_the_same_numbers_with_and_without_the_kernels(
             assert frob(grads[name], w) < 2e-4, name
 
 
+@pytest.mark.parametrize("dk,dv,filled", [(96, 192, [32, 64]),
+                                          (64, 192, [64, 64]),
+                                          (128, 128, None)])
+def test_the_compile_event_says_what_was_filled(dk, dv, filled, monkeypatch):
+    """`gdn_plan` and `gdn_lanes_filled` (the zero channels a key and a value
+    head gained; absent where the head dims are whole lanes, and where the
+    XLA form runs), and the op off the lane tile gives the XLA form's
+    numbers through a Program, `States` declared at the given dims."""
+    feed, params = _layer_feed(d=dk, dv=dv)
+    (xla,), xla_grads, _ = _layer(feed, params)
+    assert piece_noted("gdn_plan") == "xla"
+    assert piece_noted("gdn_lanes_filled") is None
+    assert piece_noted("gdn_grid_steps") is None
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    (kernel,), kernel_grads, _ = _layer(feed, params)
+    assert piece_noted("gdn_plan") == "kernel"
+    assert piece_noted("gdn_lanes_filled") == filled
+    assert piece_noted("gdn_grid_steps") == 2 * (B * HK * 1)
+    assert kernel.shape == xla.shape == (B, 2 * CHUNK, HV, dv)
+    assert frob(kernel, xla) < RTOL
+    for name, w in xla_grads.items():
+        assert kernel_grads[name].shape == w.shape
+        assert frob(kernel_grads[name], w) < 2e-4, name
+
+
 def test_head_dims_that_fill_no_vreg_keep_the_xla_form(interpreted):
     feed, params = _layer_feed(d=8)
     (out,), grads, _ = _layer(feed, params)
-    assert _plan_noted() == "xla"
+    assert piece_noted("gdn_plan") == "xla"
+    assert piece_noted("gdn_lanes_filled") is None
     assert np.all(np.isfinite(out)) and sorted(grads) == sorted(
         ["q", "k", "v", "a", "b", "A_log", "dt_bias"])
 
@@ -201,11 +277,22 @@ def test_head_dims_that_fill_no_vreg_keep_the_xla_form(interpreted):
 @pytest.mark.parametrize("dk,dv,chunk,plan", [
     (128, 128, 64, "kernel"), (256, 128, 64, "kernel"),
     (128, 256, 64, "kernel"), (8, 8, 64, "xla"), (32, 16, 64, "xla"),
-    (64, 128, 64, "xla"), (128, 64, 64, "xla"), (192, 128, 64, "xla"),
-    (128, 128, 32, "xla"), (128, 128, 128, "xla")])
+    (96, 192, 64, "kernel"), (64, 192, 64, "kernel"),
+    (64, 128, 64, "kernel"), (128, 64, 64, "kernel"),
+    (192, 128, 64, "kernel"), (63, 128, 64, "xla"), (128, 56, 64, "xla"),
+    (96, 192, 32, "xla"), (128, 128, 32, "xla"), (128, 128, 128, "xla")])
 def test_plan_reads_the_shape_alone(dk, dv, chunk, plan):
+    """Head dims of at least half a lane tile run the kernels, filled out
+    to whole tiles where they are not; under that, and at another chunk,
+    the XLA form."""
     assert la._plan(dk, dv, chunk)[0] == plan
     assert la._plan(dk, dv, chunk, chunks=64, r=2)[0] == plan
+
+
+@pytest.mark.parametrize("d,filled", [(64, 128), (96, 128), (128, 128),
+                                      (129, 256), (192, 256), (256, 256)])
+def test_a_head_dim_is_filled_out_to_whole_lanes(d, filled):
+    assert la._filled(d) == filled
 
 
 @pytest.mark.parametrize("dk,dv,chunks,r,p", [
@@ -214,7 +301,10 @@ def test_plan_reads_the_shape_alone(dk, dv, chunk, plan):
     (128, 128, 1, 2, 1), (128, 128, 3, 2, 1), (128, 128, 63, 2, 1),
     (128, 256, 64, 8, 2),       # 11.5 MiB of blocks and state
     (256, 256, 64, 8, 1),       # 17.0 MiB: more than a call has unasked
-    (8, 8, 64, 2, 0), (128, 64, 2, 2, 0)])
+    (96, 192, 64, 1, 2),        # Olmo-Hybrid's, planned at 128 / 256: 1.9 MiB
+    (96, 192, 63, 1, 1), (64, 192, 4, 2, 2), (128, 64, 2, 2, 2),
+    (192, 256, 64, 8, 1),       # 17.0 MiB at the filled 256 / 256
+    (8, 8, 64, 2, 0), (32, 64, 2, 2, 0)])
 def test_plan_pairs_the_chunks_where_they_pair_up_and_fit(dk, dv, chunks, r,
                                                           p):
     """`p`, the chunks a grid step takes, from the chunk count, the value
@@ -227,13 +317,6 @@ def test_plan_pairs_the_chunks_where_they_pair_up_and_fit(dk, dv, chunks, r,
         assert la._grid(q, v, 64) == ((3, 2, chunks // p), p)
 
 
-def _tallied():
-    steps = [e.detail.get("gdn_grid_steps")
-             for e in observe.observatory().events()
-             if isinstance(e.detail, dict)]
-    return [n for n in steps if n is not None]
-
-
 @pytest.mark.parametrize("chunks,steps", [(2, 1), (3, 3), (4, 2)])
 def test_the_op_tallies_its_grid_steps_forward_and_grad(chunks, steps,
                                                         interpreted):
@@ -242,18 +325,19 @@ def test_the_op_tallies_its_grid_steps_forward_and_grad(chunks, steps,
     the rule keeps the XLA form."""
     feed, params = _layer_feed(t=chunks * CHUNK)
     _layer(feed, params)
-    assert _tallied()[-1] == 2 * (B * HK * steps)
-    before = len(_tallied())
+    assert piece_noted("gdn_grid_steps") == 2 * (B * HK * steps)
     feed, params = _layer_feed(t=chunks * CHUNK, d=8)
     _layer(feed, params)
-    assert len(_tallied()) == before
+    assert piece_noted("gdn_grid_steps") is None
 
 
 def test_a_cpu_backend_takes_the_kernels_only_when_interpreted(monkeypatch):
     monkeypatch.setattr(_kernels, "interpret", lambda: False)
     assert not la._kernels_run(128, 128, 64)
+    assert not la._kernels_run(96, 192, 64)
     monkeypatch.setattr(_kernels, "interpret", lambda: True)
     assert la._kernels_run(128, 128, 64)
+    assert la._kernels_run(96, 192, 64)
     assert not la._kernels_run(8, 8, 64)
 
 
@@ -273,6 +357,26 @@ def test_the_program_declares_the_saved_states():
     assert tuple(states.shape) == (4, 1, 4, 128, 128)
     assert states.dtype == "float32" and states.stop_gradient
     assert tuple(out.shape) == (1, 256, 4, 128)
+
+
+def test_the_program_declares_the_saved_states_at_the_given_head_dims():
+    """Off the lane tile too `States` is `[T / chunk, B, Hv, Dk, Dv]` at the
+    dims the op was given (the variable is built from it on any backend):
+    the kernels' fill is theirs."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        q = layers.data(name="q", shape=[1, 256, 2, 96], dtype="float32",
+                        append_batch_size=False)
+        v = layers.data(name="v", shape=[1, 256, 2, 192], dtype="float32",
+                        append_batch_size=False)
+        a = layers.data(name="a", shape=[1, 256, 2], dtype="float32",
+                        append_batch_size=False)
+        out = layers.gated_delta_rule(q, q, v, a, a)
+    (op,) = [o for o in main.global_block().ops
+             if o.type == "gated_delta_rule"]
+    states = main.global_block().var(op.output("States")[0])
+    assert tuple(states.shape) == (4, 1, 2, 96, 192)
+    assert tuple(out.shape) == (1, 256, 2, 192)
 
 
 # -- what the benchmark finds the kernels by ----------------------------------

@@ -19,13 +19,13 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu import layers, observe
+from paddle_tpu import layers
 from paddle_tpu.layers.nn import LayerHelper
 from paddle_tpu.models.nemotron_h import dt_bias_init
 from paddle_tpu.ops import linear_attention as la
 
 import ling3_reference as ref
-from test_olmoe import run_piece
+from test_olmoe import piece_noted, run_piece
 from test_qwen3_next import frob
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -168,12 +168,6 @@ def _layer_feed(t=2 * CHUNK, d=D):
     return feed, params
 
 
-def _noted(key):
-    seen = [e.detail.get(key) for e in observe.observatory().events()
-            if isinstance(e.detail, dict)]
-    return [x for x in seen if x is not None]
-
-
 def test_the_op_gives_the_same_numbers_with_and_without_the_kernels(
         monkeypatch):
     """One op, one grad op (`kda_delta_rule_grad`): the kernels and their
@@ -182,12 +176,12 @@ def test_the_op_gives_the_same_numbers_with_and_without_the_kernels(
     (the rule does not depend on how it is cut)."""
     feed, params = _layer_feed()
     (xla,), xla_grads, _ = _layer(feed, params)
-    assert _noted("kda_plan")[-1] == "xla"
+    assert piece_noted("kda_plan") == "xla"
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
     (kernel,), kernel_grads, _ = _layer(feed, params)
-    assert _noted("kda_plan")[-1] == "kernel"
+    assert piece_noted("kda_plan") == "kernel"
     (cut32,), cut32_grads, _ = _layer(feed, params, chunk=32)
-    assert _noted("kda_plan")[-1] == "xla"
+    assert piece_noted("kda_plan") == "xla"
     for out, grads in ((kernel, kernel_grads), (cut32, cut32_grads)):
         assert frob(out, xla) < RTOL
         assert sorted(grads) == sorted(xla_grads)
@@ -197,10 +191,9 @@ def test_the_op_gives_the_same_numbers_with_and_without_the_kernels(
 
 def test_head_dims_that_fill_no_vreg_keep_the_xla_form(interpreted):
     feed, params = _layer_feed(d=8)
-    kernel_steps = len(_noted("kda_kernel_grid_steps"))
     (out,), grads, _ = _layer(feed, params)
-    assert _noted("kda_plan")[-1] == "xla"
-    assert len(_noted("kda_kernel_grid_steps")) == kernel_steps
+    assert piece_noted("kda_plan") == "xla"
+    assert piece_noted("kda_kernel_grid_steps") is None
     assert np.all(np.isfinite(out)) and sorted(grads) == sorted(
         ["q", "k", "v", "f", "b", "A_log", "dt_bias"])
 
@@ -214,9 +207,9 @@ def test_the_compile_event_holds_the_plan_and_both_tallies(chunks, steps,
     once from its grad op."""
     feed, params = _layer_feed(t=chunks * CHUNK)
     _layer(feed, params)
-    assert _noted("kda_plan")[-1] == "kernel"
-    assert _noted("kda_grid_steps")[-1] == 2 * (B * H * chunks)
-    assert _noted("kda_kernel_grid_steps")[-1] == 2 * (B * H * steps)
+    assert piece_noted("kda_plan") == "kernel"
+    assert piece_noted("kda_grid_steps") == 2 * (B * H * chunks)
+    assert piece_noted("kda_kernel_grid_steps") == 2 * (B * H * steps)
 
 
 def _rule_alone(states):
@@ -246,11 +239,11 @@ def test_a_program_built_without_the_slot_still_trains(interpreted):
     feed = {n: np.asarray(x) for n, x in
             zip(SLOTS, _inputs(2 * CHUNK, "whole_range", seed=3))}
     (with_slot,), kernel_grads, _ = run_piece(_rule_alone(True), feed)
-    assert _noted("kda_plan")[-1] == "kernel"
-    kernel_steps = _noted("kda_kernel_grid_steps")[-1]
+    assert piece_noted("kda_plan") == "kernel"
+    kernel_steps = piece_noted("kda_kernel_grid_steps")
     (without,), vjp_grads, _ = run_piece(_rule_alone(False), feed)
-    assert _noted("kda_plan")[-1] == "xla"      # the grad op's, the last
-    assert _noted("kda_kernel_grid_steps")[-1] == kernel_steps // 2
+    assert piece_noted("kda_plan") == "xla"      # the grad op's, the last
+    assert piece_noted("kda_kernel_grid_steps") == kernel_steps // 2
     assert frob(without, with_slot) == 0.0      # the same forward kernel
     assert sorted(vjp_grads) == sorted(kernel_grads) == sorted(SLOTS)
     for name, w in vjp_grads.items():

@@ -98,6 +98,45 @@ def test_gdn_kernels_lower_as_they_did_before_the_kda_pair(one_chip,
         == GDN_CALLS
 
 
+def test_gdn_kernels_compile_off_the_lane_tile(one_chip, monkeypatch):
+    """`gdn_fwd` and `gdn_bwd` as `olmo_hybrid_7b.s4096` calls them since PR
+    64: bf16 q, k `[1, 4096, 15, 96]`, v and dO `[1, 4096, 15, 192]`, filled
+    out to 128 / 256 lanes around the calls. Each way one Mosaic custom call
+    at the filled widths and no `while` (the XLA form's loops over the
+    chunks' states), the states written and read at `[.., 96, 192]`: what
+    the interpreter cannot refuse is the state block whose last two dims
+    are the array's and no whole tiles, its cut on the way out and its fill
+    on the way in."""
+    monkeypatch.setattr(_kernels, "on_chip", lambda: True)
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q = arg((1, 4096, 15, 96), jnp.bfloat16)
+    v = arg((1, 4096, 15, 192), jnp.bfloat16)
+    g = arg((1, 4096, 15), jnp.float32)
+    states = arg((64, 1, 15, 96, 192), jnp.float32)
+    assert la._grid(q, v, 64) == ((1, 15, 32), 2)      # two chunks a step
+    fwd = jax.jit(lambda *a: la._gdn_forward(*a, 64)).lower(
+        q, q, v, g, g).compile()
+    (call,) = _custom_calls(fwd, "gdn_fwd")
+    assert "(f32[64,1,15,96,192]{" in call and "tpu_custom_call" in call
+    assert ", bf16[1,4096,3840]{" in call               # 15 heads of 256
+    bwd = jax.jit(lambda *a: la._gdn_backward(*a, 64)).lower(
+        q, q, v, g, g, states, v).compile()
+    (call,) = _custom_calls(bwd, "gdn_bwd")
+    assert "(f32[1,15,64,1,64]{" in call and "tpu_custom_call" in call
+    assert call.split(" custom-call(")[0].count("bf16[1,4096,1920]{") == 2
+    for compiled in (fwd, bwd):
+        text = compiled.as_text()
+        assert " while(" not in text
+        assert "InvertDiagBlocksLowerTriangular" not in text
+    out, saved = fwd.out_info
+    assert out.shape == (1, 4096, 15, 192)
+    assert saved.shape == (64, 1, 15, 96, 192)
+
+
 def test_kda_kernels_compile_at_the_cells_shapes(one_chip, monkeypatch):
     """`kda_fwd` and `kda_bwd` as `ling_3_0_flash_vl.s2048` calls them: bf16
     q, k, v `[1, 2048, 32, 128]`, g float32 `[1, 2048, 32, 128]`, beta

@@ -20,7 +20,7 @@ from paddle_tpu.models import olmo_hybrid as model
 from paddle_tpu.ops import linear_attention as la
 
 import olmo_hybrid_reference as ref
-from test_olmoe import rel_err, run_piece
+from test_olmoe import piece_noted, rel_err, run_piece
 from test_qwen3_next import RTOL, frob
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -126,8 +126,48 @@ def test_kernels_match_both_oracles_with_beta_up_to_two(chunks, interpreted):
                                              frob(got, w))
 
 
-def test_the_published_head_dims_keep_the_xla_form():
-    assert la._plan(96, 192, 64, chunks=64) == ("xla", 0)
+def test_the_published_head_dims_run_the_kernels_filled_out():
+    """96 / 192 at the cell's 64 chunks: the kernel pair, two chunks a step,
+    on 128 / 256 lanes (since PR 64; the XLA form before)."""
+    assert la._plan(96, 192, 64, chunks=64) == ("kernel", 2)
+    assert [la._filled(d) for d in (96, 192)] == [128, 256]
+
+
+def _rule_layer(feed, params, scale=2.0):
+    return run_piece(
+        lambda d: [layers.gated_delta_rule(
+            d["q"], d["k"], d["v"], d["a"], d["b"], beta_scale=scale,
+            a_log_attr=fluid.ParamAttr(name="A_log"),
+            dt_bias_attr=fluid.ParamAttr(name="dt_bias"))], feed, params)
+
+
+def test_rule_layer_at_the_published_head_dims_runs_the_kernels(monkeypatch):
+    """The layer as the model writes it (beta up to 2, as many value heads
+    as key heads) at heads of 96 / 192: the XLA form on a CPU backend, the
+    filled-out kernels under the interpreter, the same output and the same
+    gradient of every input and parameter."""
+    rng = np.random.RandomState(4)
+    b, t, h, dk, dv = 1, 128, 3, 96, 192
+    feed = {"q": rng.randn(b, t, h, dk), "k": rng.randn(b, t, h, dk),
+            "v": rng.randn(b, t, h, dv), "a": rng.randn(b, t, h),
+            "b": rng.randn(b, t, h) * 1.5}
+    feed = {n: x.astype(np.float32) for n, x in feed.items()}
+    params = {"A_log": np.log(rng.uniform(0.1, 4, h)).astype(np.float32),
+              "dt_bias": rng.uniform(-1, 1, h).astype(np.float32)}
+    (xla,), xla_grads, _ = _rule_layer(feed, params)
+    assert piece_noted("gdn_plan") == "xla"
+    assert piece_noted("gdn_lanes_filled") is None
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    (kernel,), kernel_grads, _ = _rule_layer(feed, params)
+    assert piece_noted("gdn_plan") == "kernel"
+    assert piece_noted("gdn_lanes_filled") == [32, 64]
+    assert piece_noted("gdn_grid_steps") == 2 * (b * h * 1)
+    assert kernel.shape == (b, t, h, dv)
+    assert frob(kernel, xla) < RTOL
+    assert sorted(kernel_grads) == sorted(xla_grads) == sorted(
+        ["q", "k", "v", "a", "b", "A_log", "dt_bias"])
+    for name, w in xla_grads.items():
+        assert frob(kernel_grads[name], w) < 2e-4, name
 
 
 @pytest.mark.parametrize("scale", [1.0, 2.0])

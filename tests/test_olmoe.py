@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu import layers, models
+from paddle_tpu import layers, models, observe
 
 import olmoe_reference as ref
 
@@ -51,6 +51,7 @@ def run_piece(build, feed, params=None):
                             dtype="float32", append_batch_size=False)
         loss = layers.reduce_sum(layers.elementwise_mul(first, probe))
         fluid.append_backward(loss)
+    run_piece.program_uid = main._uid       # whose event `piece_noted` reads
     scope = fluid.Scope()
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(startup, scope=scope)
@@ -65,6 +66,15 @@ def run_piece(build, feed, params=None):
                       scope=scope)
     return (fetched[:len(outs)], dict(zip(wrt, fetched[len(outs):])),
             probe_value)
+
+
+def piece_noted(key):
+    """What the rules noted under `key` on the compile event of the program
+    `run_piece` ran last, None where none did: that program's own event, not
+    the last of the observatory's list, which is the process's (every test
+    file of an xdist worker writes it, and it holds 256 events)."""
+    return observe.observatory().latest(
+        run_piece.program_uid).detail.get(key)
 
 
 # -- ops against their piece of the reference ---------------------------------

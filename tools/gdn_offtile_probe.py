@@ -1,22 +1,29 @@
 """The gated delta rule at head dims off the lane tile (Olmo-Hybrid's 96 / 192,
-15 heads held, 4096 tokens, bf16): the XLA form the op keeps there
-(`ops/linear_attention.py::_plan` answers "xla") against `gdn_fwd` / `gdn_bwd`
-on operands zero-padded to whole lanes (q, k to 128, v and dO to 256), the
-pads and the slices back counted. What a `perf_opt` PR that widens `_plan`'s
-envelope would start from. TPU-only.
+15 heads held, 4096 tokens, bf16), one layer alone: the XLA form
+(`chunked_gated_delta_rule` and its `jax.vjp`, what the op ran there until
+PR 64 and still runs on a CPU backend) against the op's own kernel path
+(`ops/linear_attention.py::_gdn_forward` / `_gdn_backward`: `gdn_fwd` /
+`gdn_bwd` on q, k, v and dO filled out with zero channels to 128 / 256
+lanes, the fills and the cuts back counted, q's scale the true `96^-0.5`,
+`States` at `[.., 96, 192]`). The before and after of PR 64. TPU-only.
 
     python tools/gdn_offtile_probe.py
 
-Zero key channels add nothing to `k k^T`, `q k^T` or the l2-norms, and a
-zero value column stays zero through the solve and the state, so the padded
-kernels compute the same rule but for q's scale, which they take from the
-padded width (128^-0.5 where the rule has 96^-0.5): the probe multiplies
-their output by sqrt(128 / 96) before it compares, and a PR that ships them
-hands the kernels the scale. Each form: forward alone (the op), forward and
-backward under `jax.vjp` (the grad op of the XLA form traces the rule again;
-the kernels' runs `gdn_bwd` alone on the saved states), ms a call as the
-median of ten after a warm-up, on the host's clock around
-`block_until_ready`.
+What it read (TPU v5 lite, ms a layer):
+  PR 63 (call 2; the kernels' side was hand-made pads around the kernels and a
+         `sqrt(128 / 96)` correction of q's scale, `_plan` answered "xla"):
+         XLA form forward 3.70, forward + backward 9.00; padded kernels 2.50
+         and 5.01; within 0.0034-0.0050 (Frobenius) of the XLA form.
+  PR 64 (call 1; the op's own path, `_plan` answers ("kernel", 2)): XLA form
+         forward 3.655, forward + backward 8.864; kernels 2.453 and 5.085;
+         `Out` within 0.0024 of the XLA form, the gradients 0.0021-0.0034. In
+         the cell's step the pair takes 3.25 ms a layer (`gdn_fwd` 1.24,
+         `gdn_bwd` 2.01) where the XLA form took 9.2: `PERF.md` section 5.
+
+Each form: forward alone (the op), forward and backward (the XLA form's grad
+op traces the rule again under `jax.vjp`; the kernels' runs `gdn_bwd` alone
+on the saved states), ms a call as the median of ten after a warm-up, on
+the host's clock around `block_until_ready`.
 """
 
 from __future__ import annotations
@@ -29,7 +36,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 B, T, H, DK, DV, CHUNK = 1, 4096, 15, 96, 192, 64
-PAD_K, PAD_V = 128, 256
 
 
 def main():
@@ -53,21 +59,12 @@ def main():
         return la.chunked_gated_delta_rule(
             qn, kn, v.astype(jnp.float32), g, beta, CHUNK).astype(v.dtype)
 
-    def pad(x, width):
-        return jnp.pad(x, ((0, 0),) * 3 + ((0, width - x.shape[-1]),))
-
     def kernels_forward(q, k, v, g, beta):
-        out, states = la._gdn_forward(pad(q, PAD_K), pad(k, PAD_K),
-                                      pad(v, PAD_V), g, beta, CHUNK)
-        return out[..., :DV], states
+        return la._gdn_forward(q, k, v, g, beta, CHUNK)
 
     def kernels_both(q, k, v, g, beta, d_out):
-        qp, kp, vp = pad(q, PAD_K), pad(k, PAD_K), pad(v, PAD_V)
-        out, states = la._gdn_forward(qp, kp, vp, g, beta, CHUNK)
-        dq, dk, dv, dg, dbeta = la._gdn_backward(
-            qp, kp, vp, g, beta, states, pad(d_out, PAD_V), CHUNK)
-        return out[..., :DV], (dq[..., :DK], dk[..., :DK], dv[..., :DV], dg,
-                               dbeta)
+        out, states = la._gdn_forward(q, k, v, g, beta, CHUNK)
+        return out, la._gdn_backward(q, k, v, g, beta, states, d_out, CHUNK)
 
     def xla_both(q, k, v, g, beta, d_out):
         out, vjp = jax.vjp(xla, q, k, v, g, beta)
@@ -88,26 +85,24 @@ def main():
         return float(np.linalg.norm(a - b) / (np.linalg.norm(b) + 1e-30))
 
     args = (q, k, v, g, beta)
-    assert la._plan(DK, DV, CHUNK, T // CHUNK) == ("xla", 0)
-    print(f"padded plan {la._plan(PAD_K, PAD_V, CHUNK, T // CHUNK)}, "
+    print(f"plan {la._plan(DK, DV, CHUNK, T // CHUNK)}, "
           f"[{B}, {T}, {H}, {DK} / {DV}] bf16", flush=True)
     rows = [("xla forward (the op)", ms(xla, *args)),
             ("xla forward + backward (the grad op)",
              ms(xla_both, *args, d_out)),
-            ("padded kernels forward", ms(kernels_forward, *args)),
-            ("padded kernels forward + backward", ms(kernels_both, *args,
-                                                     d_out))]
+            ("kernels forward (the op)", ms(kernels_forward, *args)),
+            ("kernels forward + backward (the grad op)",
+             ms(kernels_both, *args, d_out))]
     for what, value in rows:
         print(f"gdn_offtile_probe: {what}: {value:.3f} ms a layer",
               flush=True)
-    fix = (PAD_K / DK) ** 0.5
     want, want_grads = jax.jit(xla_both)(*args, d_out)
     got, got_grads = jax.jit(kernels_both)(*args, d_out)
     print(f"gdn_offtile_probe: kernels against the xla form, Frobenius: out "
-          f"{frob(np.asarray(got, np.float32) * fix, want):.4f}, "
-          + ", ".join(f"d{n} {frob(np.asarray(a, np.float32) * fix, b):.4f}"
-                      for n, a, b in zip("q k v g beta".split(), got_grads,
-                                         want_grads)), flush=True)
+          f"{frob(got, want):.4f}, "
+          + ", ".join(f"d{n} {frob(a, b):.4f}" for n, a, b
+                      in zip("q k v g beta".split(), got_grads, want_grads)),
+          flush=True)
     return 0
 
 
