@@ -34,7 +34,12 @@ chooses its groups before its experts), and Olmo-Hybrid: a dense 3:1 hybrid
 of the gated delta rule with beta in (0, 2) (a transition with a negative
 eigenvalue, heads of 96 / 192) and OLMo's whole-projection QK-norm attention,
 every sublayer normed on the way out only (the first model built for a share
-of a layer's HEADS)."""
+of a layer's HEADS), and Granite 4.0-H: Mamba-2 mixers of ONE group of 64
+heads at chunk 256 beside unrotated attention at a published softmax scale, a
+gated feed-forward in every layer, scalar multipliers on the embedding, the
+residual branches and the logits (the first model whose head is its
+embedding's table, and the first whose every layer is a mixer AND a
+feed-forward under a list of layer types with Mamba in it)."""
 
 from . import mnist  # noqa: F401
 from . import resnet  # noqa: F401
@@ -55,3 +60,4 @@ from . import keye_vl2  # noqa: F401
 from . import nemotron_h  # noqa: F401
 from . import ling3  # noqa: F401
 from . import olmo_hybrid  # noqa: F401
+from . import granite_hybrid  # noqa: F401

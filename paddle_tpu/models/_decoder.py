@@ -1,17 +1,22 @@
 """What two or more of the decoder models (`olmoe`, `ouro`, `qwen3_next`,
 `kanana2`, `mellum2`, `trinity`, `keye_vl2`, `nemotron_h`, `ling3`,
-`olmo_hybrid`) build the same way, written once: named weights and
-projections, the token feeds, the heads-first reshape and its inverse, a
-key-value head serving its group of query heads, the gated MLP, the routed
-half of an expert layer, the period of layer kinds, the grouped-query block,
-q and k normed over the whole projection before the heads split (`olmoe`,
-`olmo_hybrid`), a gated-delta-rule layer from its convolution to its gated
-norm (`qwen3_next`, `olmo_hybrid`), and the losses. Nothing here asks which
-model calls it (latent attention, built by `kanana2` and `ling3`, takes
-the one thing they differ in, a head-wise gate, as a parameter): a model
-whose form differs keeps its own (Nemotron-H's out projections start
-smaller). Each model keeps its mixer's composition, its layer loop, its
-defaults and `build`. Built from `fluid.layers` only; parameter names are the
+`olmo_hybrid`, `granite_hybrid`) build the same way, written once: named
+weights and projections, the token feeds, the heads-first reshape and its
+inverse, a key-value head serving its group of query heads, the gated MLP,
+the routed half of an expert layer, the period of layer kinds, the
+grouped-query block, q and k normed over the whole projection before the
+heads split (`olmoe`, `olmo_hybrid`), a gated-delta-rule layer from its
+convolution to its gated norm (`qwen3_next`, `olmo_hybrid`), a Mamba-2 mixer
+from its in projection to its out projection and grouped-query attention
+without positions (`nemotron_h`, `granite_hybrid`), and the losses. `tied_head` has one caller and is here
+because it is `embed`'s other half: the head that reads the table `embed`
+made. Nothing here asks which model calls it (latent attention, built by
+`kanana2` and `ling3`, takes the one thing they differ in, a head-wise gate,
+as a parameter; the Mamba-2 mixer and the unrotated attention take their out
+projection's initialiser, which Nemotron-H makes smaller, and the attention
+its softmax scale, which Granite 4.0-H publishes): a model whose form differs keeps its own.
+Each model keeps its mixer's composition, its layer loop, its defaults and
+`build`. Built from `fluid.layers` only; parameter names are the
 caller's.
 """
 
@@ -42,6 +47,15 @@ def linear(x, size, name):
                      param_attr=w(name + ".w"))
 
 
+def out_linear(x, size, name, initializer=None):
+    """A sublayer's projection back into the residual stream, `name.w`,
+    under the caller's initialiser (normal at INIT_STD by default;
+    Nemotron-H's start smaller)."""
+    return layers.fc(input=x, size=size, num_flatten_dims=2, bias_attr=False,
+                     param_attr=ParamAttr(name=name + ".w",
+                                          initializer=initializer or normal()))
+
+
 def norm(x, rms_eps, name, zero_centered=False):
     return layers.rms_norm(x, epsilon=rms_eps, zero_centered=zero_centered,
                            param_attr=ParamAttr(name=name + ".w"))
@@ -63,6 +77,21 @@ def token_feeds(seq_len):
 def embed(tokens, vocab_size, d_model):
     return layers.embedding(tokens, size=[vocab_size, d_model],
                             param_attr=w("embed.w"))
+
+
+def tied_head(x, vocab_size):
+    """Logits `x E^T` against the table `embed` made (`embed.w` `[vocab,
+    width]`): the one parameter read twice, by `lookup_table` as rows and
+    here transposed, so its gradient is the sum of a row scatter and a dense
+    product (`core/backward.py`'s fan-in sum) and no `head.w` exists. Under
+    AMP the product casts the table to bf16 (`matmul`, AMP_BF16_OPS) while
+    the look-up reads the float32 rows."""
+    block = x.block.program.global_block()
+    table = block.var("embed.w")
+    if tuple(table.shape) != (vocab_size, x.shape[-1]):
+        raise ValueError(f"the tied head reads embed.w as [{vocab_size}, "
+                         f"{x.shape[-1]}], found {tuple(table.shape)}")
+    return layers.matmul(x, table, transpose_y=True)
 
 
 def split_heads(t, n, head_dim):    # [B, T, n * Dh] -> [B, T, n, Dh]
@@ -238,6 +267,59 @@ def delta_rule_normed(q, k, v, z, a, b, rms_eps, name, a_log, dt_bias=None,
                                  param_attr=ParamAttr(name=name + ".norm.w"))
 
 
+def dt_bias_init(heads, seed, dt_min=0.001, dt_max=0.1, dt_floor=1e-4):
+    """The public Mamba-2 draw: dt log-uniform in [dt_min, dt_max], floored,
+    and the bias its inverse softplus; drawn here so that the startup
+    program holds the values."""
+    u = np.random.RandomState(seed).uniform(size=heads)
+    dt = np.exp(u * (np.log(dt_max) - np.log(dt_min)) + np.log(dt_min))
+    dt = np.maximum(dt, dt_floor)
+    return (dt + np.log(-np.expm1(-dt))).astype("float32")
+
+
+def mamba_mixer(x, n_head, head_dim, n_groups, state, conv_kernel, chunk,
+                rms_eps, time_step, name, seed, out_init=None):
+    """A Mamba-2 mixer on the normed x `[B, T, D]`, as `models/nemotron_h.py`
+    writes it out: `[z | xs B C | dt_raw] = x W_in` (`name.in.w`), the causal
+    depthwise convolution with a bias and silu over `[xs | B | C]`
+    (`name.conv.w` / `.b`), `ssd_scan` at `chunk` over `n_head` heads of
+    `head_dim` reading `n_groups` groups of B and C of width `state`
+    (`name.A_log`, `.dt_bias` drawn from `time_step` = (min, max, floor) and
+    `seed`, `.D`), the gate before the norm over each group of `n_head *
+    head_dim / n_groups` lanes (`name.norm.w`), and `name.out.w` back to D,
+    initialised by `out_init` (normal at INIT_STD by default)."""
+    inner, bc = n_head * head_dim, n_groups * state
+    mixed = linear(x, 2 * inner + 2 * bc + n_head, name + ".in")
+    z = last(mixed, 0, inner)
+    u = layers.causal_conv1d(
+        last(mixed, inner, 2 * inner + 2 * bc), conv_kernel,
+        param_attr=ParamAttr(
+            name=name + ".conv.w",
+            initializer=init.UniformInitializer(-conv_kernel ** -0.5,
+                                                conv_kernel ** -0.5)),
+        bias_attr=ParamAttr(name=name + ".conv.b"))
+    dt_raw = last(mixed, 2 * inner + 2 * bc, 2 * inner + 2 * bc + n_head)
+    xs = layers.reshape(last(u, 0, inner), shape=[0, 0, n_head, head_dim])
+    b = layers.reshape(last(u, inner, inner + bc),
+                       shape=[0, 0, n_groups, state])
+    c = layers.reshape(last(u, inner + bc, inner + 2 * bc),
+                       shape=[0, 0, n_groups, state])
+    y = layers.ssd_scan(
+        xs, b, c, dt_raw, chunk=chunk,
+        a_log_attr=ParamAttr(
+            name=name + ".A_log", initializer=init.NumpyArrayInitializer(
+                np.log(np.arange(1, n_head + 1)).astype("float32"))),
+        dt_bias_attr=ParamAttr(
+            name=name + ".dt_bias", initializer=init.NumpyArrayInitializer(
+                dt_bias_init(n_head, seed, *time_step))),
+        d_attr=ParamAttr(name=name + ".D"))
+    y = layers.gated_rms_norm(
+        layers.reshape(y, shape=[0, 0, inner]), z, epsilon=rms_eps,
+        param_attr=ParamAttr(name=name + ".norm.w"), gate_first=True,
+        group_size=inner // n_groups)
+    return out_linear(y, x.shape[-1], name + ".out", out_init)
+
+
 def layer_kinds(n_layer, layer_types=PERIOD, kinds=KINDS):
     """The kind of each of `n_layer` layers: `layer_types` (a list of
     `kinds`) repeated as a period."""
@@ -272,6 +354,27 @@ def grouped_attention(x, n_head, n_kv_head, head_dim, rope_theta,
         sm_scale=head_dim ** -0.5, window=window, kept=kept, topk=topk)
     return linear(merge_heads(ctx, n_head * head_dim), x.shape[-1],
                   name + ".o")
+
+
+def unrotated_attention(x, n_head, n_kv_head, head_dim, sm_scale, name,
+                        out_init=None):
+    """Causal softmax attention of `n_head` query heads over `n_kv_head`
+    key-value heads with NO positions and no QK-norm (`models/nemotron_h.py`,
+    `models/granite_hybrid.py`): the scores times `sm_scale` as the caller
+    states it, `name.o.w` initialised by `out_init` (normal at INIT_STD by
+    default)."""
+    def heads(t, n):            # [B, T, n * Dh] -> [B, n, T, Dh]
+        return heads_first(split_heads(t, n, head_dim))
+
+    q = heads(linear(x, n_head * head_dim, name + ".q"), n_head)
+    k = heads(linear(x, n_kv_head * head_dim, name + ".k"), n_kv_head)
+    v = heads(linear(x, n_kv_head * head_dim, name + ".v"), n_kv_head)
+    ctx = layers.fused_attention(
+        q, serve_group(k, n_head, n_kv_head, head_dim),
+        serve_group(v, n_head, n_kv_head, head_dim), causal=True,
+        sm_scale=sm_scale)
+    return out_linear(merge_heads(ctx, n_head * head_dim), x.shape[-1],
+                      name + ".o", out_init)
 
 
 def mean_cross_entropy(logits, labels):

@@ -76,10 +76,9 @@ from .. import initializer as init
 from .. import layers
 from ..core.ir import name_scope
 from ..param_attr import ParamAttr
-from ._decoder import (cross_entropy_fetches, embed, gated_mlp, last,
-                       latent_attention, linear, noaux_experts, norm,
+from ._decoder import (cross_entropy_fetches, dt_bias_init, embed, gated_mlp,
+                       last, latent_attention, linear, noaux_experts, norm,
                        split_heads, token_feeds)
-from .nemotron_h import dt_bias_init
 
 
 def layer_kind(p, layer_group_size):

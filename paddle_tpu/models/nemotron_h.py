@@ -65,90 +65,19 @@ name. A layer's ops (its norm included) carry `fluid.name_scope("l<i>.mamba"
 
 from __future__ import annotations
 
-import numpy as np
-
 from .. import initializer as init
 from .. import layers
 from ..core.ir import name_scope
-from ..param_attr import ParamAttr
 from ._decoder import (INIT_STD, cross_entropy_fetches, embed, expert_rows,
-                       heads_first, last, linear, merge_heads, noaux_router,
-                       norm, serve_group, split_heads, token_feeds)
+                       linear, mamba_mixer, noaux_router, norm, out_linear,
+                       token_feeds, unrotated_attention)
 
 KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
 NEMOTRON_3_NANO = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 
 
-def _out_linear(x, size, name, rescale_layers):
-    """A sublayer's projection back into the residual stream: its weight
-    starts `sqrt(rescale_layers)` times smaller."""
-    return layers.fc(
-        input=x, size=size, num_flatten_dims=2, bias_attr=False,
-        param_attr=ParamAttr(name=name + ".w", initializer=_out_init(
-            rescale_layers)))
-
-
 def _out_init(rescale_layers):
     return init.NormalInitializer(0.0, INIT_STD / rescale_layers ** 0.5)
-
-
-def dt_bias_init(heads, seed, dt_min=0.001, dt_max=0.1, dt_floor=1e-4):
-    """The public Mamba-2 draw: dt log-uniform in [dt_min, dt_max], floored,
-    and the bias its inverse softplus; drawn here so that the startup
-    program holds the values."""
-    u = np.random.RandomState(seed).uniform(size=heads)
-    dt = np.exp(u * (np.log(dt_max) - np.log(dt_min)) + np.log(dt_min))
-    dt = np.maximum(dt, dt_floor)
-    return (dt + np.log(-np.expm1(-dt))).astype("float32")
-
-
-def _mamba(x, n_head, head_dim, n_groups, state, conv_kernel, chunk, rms_eps,
-           time_step, rescale_layers, name, seed):
-    inner, bc = n_head * head_dim, n_groups * state
-    mixed = linear(x, 2 * inner + 2 * bc + n_head, name + ".in")
-    z = last(mixed, 0, inner)
-    u = layers.causal_conv1d(
-        last(mixed, inner, 2 * inner + 2 * bc), conv_kernel,
-        param_attr=ParamAttr(
-            name=name + ".conv.w",
-            initializer=init.UniformInitializer(-conv_kernel ** -0.5,
-                                                conv_kernel ** -0.5)),
-        bias_attr=ParamAttr(name=name + ".conv.b"))
-    dt_raw = last(mixed, 2 * inner + 2 * bc, 2 * inner + 2 * bc + n_head)
-    xs = layers.reshape(last(u, 0, inner), shape=[0, 0, n_head, head_dim])
-    b = layers.reshape(last(u, inner, inner + bc),
-                       shape=[0, 0, n_groups, state])
-    c = layers.reshape(last(u, inner + bc, inner + 2 * bc),
-                       shape=[0, 0, n_groups, state])
-    y = layers.ssd_scan(
-        xs, b, c, dt_raw, chunk=chunk,
-        a_log_attr=ParamAttr(
-            name=name + ".A_log", initializer=init.NumpyArrayInitializer(
-                np.log(np.arange(1, n_head + 1)).astype("float32"))),
-        dt_bias_attr=ParamAttr(
-            name=name + ".dt_bias", initializer=init.NumpyArrayInitializer(
-                dt_bias_init(n_head, seed, *time_step))),
-        d_attr=ParamAttr(name=name + ".D"))
-    y = layers.gated_rms_norm(
-        layers.reshape(y, shape=[0, 0, inner]), z, epsilon=rms_eps,
-        param_attr=ParamAttr(name=name + ".norm.w"), gate_first=True,
-        group_size=inner // n_groups)
-    return _out_linear(y, x.shape[-1], name + ".out", rescale_layers)
-
-
-def _attention(x, n_head, n_kv_head, head_dim, rescale_layers, name):
-    def heads(t, n):            # [B, T, n * Dh] -> [B, n, T, Dh]
-        return heads_first(split_heads(t, n, head_dim))
-
-    q = heads(linear(x, n_head * head_dim, name + ".q"), n_head)
-    k = heads(linear(x, n_kv_head * head_dim, name + ".k"), n_kv_head)
-    v = heads(linear(x, n_kv_head * head_dim, name + ".v"), n_kv_head)
-    ctx = layers.fused_attention(
-        q, serve_group(k, n_head, n_kv_head, head_dim),
-        serve_group(v, n_head, n_kv_head, head_dim), causal=True,
-        sm_scale=head_dim ** -0.5)
-    return _out_linear(merge_heads(ctx, n_head * head_dim), x.shape[-1],
-                       name + ".o", rescale_layers)
 
 
 def _sparse_experts(x, seq_len, n_expert, top_k, d_expert, d_shared,
@@ -161,9 +90,9 @@ def _sparse_experts(x, seq_len, n_expert, top_k, d_expert, d_shared,
         experts=dict(down_attr=_out_init(rescale_layers), gated=False,
                      activation="relu2", first_expert=first_expert,
                      experts_held=experts_held))
-    shared = _out_linear(
+    shared = out_linear(
         layers.relu2(linear(x, d_shared, name + ".shared.up")), d_model,
-        name + ".shared.down", rescale_layers)
+        name + ".shared.down", _out_init(rescale_layers))
     out = layers.elementwise_add(
         layers.reshape(routed, shape=[-1, seq_len, d_model]), shared)
     return out, routing
@@ -194,13 +123,14 @@ def nemotron_h(vocab_size=131072, seq_len=2048, layer_pattern=NEMOTRON_3_NANO,
         with name_scope(f"{name}.{KINDS[kind]}"):
             normed = norm(x, rms_eps, name + ".norm")
             if kind == "M":
-                part = _mamba(normed, mamba_heads, mamba_head_dim, n_groups,
-                              ssm_state, conv_kernel, chunk, rms_eps,
-                              time_step, rescale_layers, name + ".mamba",
-                              seed=i)
+                part = mamba_mixer(
+                    normed, mamba_heads, mamba_head_dim, n_groups, ssm_state,
+                    conv_kernel, chunk, rms_eps, time_step, name + ".mamba",
+                    seed=i, out_init=_out_init(rescale_layers))
             elif kind == "*":
-                part = _attention(normed, n_head, n_kv_head, head_dim,
-                                  rescale_layers, name + ".attn")
+                part = unrotated_attention(
+                    normed, n_head, n_kv_head, head_dim, head_dim ** -0.5,
+                    name + ".attn", out_init=_out_init(rescale_layers))
             else:
                 part, routing = _sparse_experts(
                     normed, seq_len, n_expert, top_k, d_expert, d_shared,

@@ -63,10 +63,9 @@ from .. import initializer as init
 from .. import layers
 from ..core.ir import name_scope
 from ._decoder import (a_log_init, conv_heads, cross_entropy_fetches,
-                       delta_rule_normed, embed, gated_mlp, heads_first,
-                       layer_kinds, linear, merge_heads, norm,
+                       delta_rule_normed, dt_bias_init, embed, gated_mlp,
+                       heads_first, layer_kinds, linear, merge_heads, norm,
                        qk_normed_projections, split_heads, token_feeds)
-from .nemotron_h import dt_bias_init
 
 KINDS = ("linear_attention", "full_attention")
 PERIOD = (KINDS[0],) * 3 + (KINDS[1],)      # the published `layer_types`

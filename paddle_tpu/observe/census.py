@@ -81,6 +81,16 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     and `attention_unrotated_layers` whether or not another layer is
     windowed or turns (its layers are a mixer or a feed-forward part alone,
     and none of its attention layers carries positions).
+    With them `state_space_groups`, `state_space_heads_per_group` and
+    `state_space_chunk`: the scan's groups of B and C, the heads that read
+    one group and the op's `chunk` attribute (8, 8 and 128 for Nemotron-H; 1,
+    64 and 256 for Granite 4.0-H, which `ops/state_space.py::_plan` leaves to
+    the XLA form; `ssd_plan`, the form the scan ran in, is noted by the op's
+    rule on the same event). `tied_heads`: the embedding tables (a
+    `lookup_table`'s W) that a `matmul` reads as well, a head tied to its
+    embedding. `residual_scaled_sublayers`: the `scale` ops under a
+    `name_scope` whose result goes straight into a residual
+    `elementwise_add`, a sublayer's branch under a residual multiplier.
     `moe_expert_activation`: `relu2` where the routed experts are two
     matrices around a `relu2` op (absent for gated silu experts).
     `kda`: a `kda_delta_rule` op (a Kimi Delta Attention mixer: the delta
@@ -106,6 +116,8 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     full_keys = []                  # the full-attention ops' K
     held_by = {"rotary_embedding": set(), "sigmoid": set()}  # name scopes
     normed, added = set(), set()    # `rms_norm` results, residual addends
+    scaled = set()                  # results of `scale` ops under a scope
+    tables, multiplied = set(), set()   # `lookup_table`s' W, `matmul`s' Y
     for op in block.ops:
         if op.attrs.get("__role__") is not None:
             continue
@@ -118,6 +130,17 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
             out["delta_rule_beta_scale"] = op.attrs["beta_scale"]
         elif op.type == "ssd_scan":
             kinds["state_space"] += 1
+            heads = block.var(op.input("X")[0]).shape[2]
+            groups = block.var(op.input("B")[0]).shape[2]
+            out["state_space_groups"] = groups
+            out["state_space_heads_per_group"] = heads // groups
+            out["state_space_chunk"] = op.attrs.get("chunk", 128)
+        elif op.type == "lookup_table":
+            tables.update(op.input("W"))
+        elif op.type == "matmul":
+            multiplied.update(op.input("Y"))
+        elif op.type == "scale" and scope is not None:
+            scaled.update(op.output("Out"))
         elif op.type == "kda_delta_rule":
             kinds["kda"] += 1
         elif op.type == "relu2" and scope in routed:
@@ -213,6 +236,10 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     out_norms = len(normed & added)
     if out_norms:
         out["residual_out_norms"] = out_norms
+    if tables & multiplied:
+        out["tied_heads"] = len(tables & multiplied)
+    if scaled & added:
+        out["residual_scaled_sublayers"] = len(scaled & added)
     return out
 
 

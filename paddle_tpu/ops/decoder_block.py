@@ -144,9 +144,10 @@ def _gated_norm_kernels_run(shape, dtype):
 
 def _gated_norm_blocks(T, H, D, itemsize, backward):
     """(tokens, heads) of a grid step's block of X `[B, T, H * D]`: at most
-    128 tokens (256 backward) of as many whole heads as `_BLOCK_BYTES`
-    hold; a head's tile `[tokens, D]` is worked on whole. A chip probe at
-    `bf16[1, 4096, 32, 128]`, twenty chained calls on the host's clock, ms a
+    128 tokens (256 backward), fewer where one head's float32 tile at that
+    height is past half of `_BLOCK_BYTES`, of as many whole heads as
+    `_BLOCK_BYTES` hold; a head's tile `[tokens, D]` is worked on whole. A
+    chip probe at `bf16[1, 4096, 32, 128]`, twenty chained calls on the host's clock, ms a
     call forward / backward: (128, 32) 0.149 / 0.282, (256, 16) 0.166 /
     0.259, (256, 8) 0.203 / 0.312 (whole rows of X are the longest
     transfers; the backward's longer dependency chains want the taller
@@ -154,6 +155,11 @@ def _gated_norm_blocks(T, H, D, itemsize, backward):
     0.149 / 0.34; the row means as products with a `[D, D]` matrix of 1 / D
     on the idle MXU 0.18-0.20 / 0.34-0.43."""
     most = 256 if backward else 128
+    # the kernels hold about ten float32 values of a head's tile at once:
+    # half a MiB each (256 rows of the published 512 lanes) fits the 16 MiB
+    # of scoped VMEM beside the blocks, so a wider head (Granite 4.0-H's one
+    # group of 4096 lanes) takes fewer rows: 32
+    most = min(most, max(_BLOCK_BYTES // 2 // (4 * D), 16))
     Tb = next(b for b in (256, 128, 64, 32, 16) if b <= most and T % b == 0)
     return Tb, _heads_a_block(H, Tb * D * itemsize)
 

@@ -59,8 +59,20 @@ backend's DEFAULT for float32 operands (`linear_attention._dot`: on the chip
 the operands rounded to bf16, one pass into a float32 accumulator, as XLA's
 default does there; float32 under the interpreter on a CPU).
 
-Outside the envelope (the small head dims of the CPU tests), and on a CPU
-backend unless the Pallas interpreter is asked for
+Which published shapes the plan takes: Nemotron-3-Nano's (`nemotron_h`: 8
+groups of 8 heads of 64 over a state of 128, chunk 128) runs the kernel pair.
+Granite 4.0-H's (`granite_hybrid`: ONE group of 64 heads of 64 over a state of
+128, chunk 256) does not: at chunk 256 the plan answers "xla", and a grid step
+that takes a group's heads together would hold `[256, 4096]` blocks of x, y
+and their gradients beside 2 MB each of state and dS at 64 heads. It runs the
+XLA form on the chip, which makes the `[chunks, heads, chunk, chunk]` float32
+decay tiles in HBM (134 MB a copy a layer at 2048 tokens). What taking it
+would need: a grid axis over blocks of a group's heads with B's and C's block
+index held, or a chunk of 128 inside the kernels under the op's attribute of
+256 (the recurrence does not depend on the chunk).
+
+Outside the envelope (that shape, the small head dims of the CPU tests), and
+on a CPU backend unless the Pallas interpreter is asked for
 (`PADDLE_TPU_PALLAS_INTERPRET=1`), the op keeps the XLA form `chunked_ssd`:
 the decay tiles and both in-chunk products for all chunks at once, a
 `lax.scan` over the chunks' states, then the states' part of y for all
@@ -140,9 +152,10 @@ def chunked_ssd(x, dt, a, Bm, Cm, D, chunk):
 def _plan(P, N, r, chunk):
     """"kernel": a chunk of 128 tokens, a state of whole 128-lane tiles and
     a group whose heads pair up into whole tiles (two heads of 64 side by
-    side; the published 8 heads of 64 over a state of 128). "xla": anything
-    else (the small head dims of the CPU tests), which keeps `chunked_ssd`
-    and its vjp. The choice reads the shape alone."""
+    side; Nemotron-3-Nano's 8 heads of 64 over a state of 128). "xla":
+    anything else (Granite 4.0-H's chunk of 256 over one group of 64 heads,
+    the small head dims of the CPU tests), which keeps `chunked_ssd` and its
+    vjp. The choice reads the shape alone."""
     if chunk == 128 and N % 128 == 0 and 2 * P == 128 and r % 2 == 0:
         return "kernel"
     return "xla"

@@ -9,7 +9,10 @@ things by. `DIGESTS` and `CENSUS` were taken on the commit before
 `models/_decoder.py`, `observe/census.py` and `ops/_kernels.py` existed
 (PR 58's parent; `olmo_hybrid`'s on PR 63, which added the model and gave the
 census of a program with `gated_delta_rule` ops the key
-`linear_attention_head_dims`) at the models' own tests' tiny sizes, forward,
+`linear_attention_head_dims`; `granite_hybrid`'s on PR 65, which added the
+model, moved the Mamba-2 mixer from `nemotron_h.py` to `_decoder.py` with
+Nemotron-H's digest unmoved, and gave the census of a program with `ssd_scan`
+ops its groups, heads a group and chunk) at the models' own tests' tiny sizes, forward,
 backward and Adam; after a deliberate change to a model take them again with
 `program_digest(*build_program(model)[:2])` and
 `census.program_detail(build_program(model)[0])`.
@@ -26,6 +29,7 @@ from paddle_tpu import models
 from paddle_tpu.core import registry
 from paddle_tpu.observe import census
 
+from test_granite_hybrid import TINY as GRANITE_HYBRID_TINY
 from test_kanana2 import TINY as KANANA2_TINY
 from test_keye_vl2 import TINY as KEYE_VL2_TINY
 from test_mellum2 import TINY as MELLUM2_TINY
@@ -39,7 +43,8 @@ from test_trinity import TINY as TRINITY_TINY
 HERE = os.path.dirname(os.path.abspath(__file__))
 PACKAGE = os.path.join(HERE, "..", "paddle_tpu")
 
-SIZES = {"olmoe": OLMOE_TINY, "olmo_hybrid": OLMO_HYBRID_TINY,
+SIZES = {"granite_hybrid": GRANITE_HYBRID_TINY,
+         "olmoe": OLMOE_TINY, "olmo_hybrid": OLMO_HYBRID_TINY,
          "ouro": OURO_TINY,
          "qwen3_next": QWEN3_NEXT_TINY, "kanana2": KANANA2_TINY,
          "mellum2": MELLUM2_TINY, "trinity": TRINITY_TINY,
@@ -83,6 +88,8 @@ def program_digest(*programs):
 
 
 DIGESTS = {
+    "granite_hybrid": (1670, "b8d7a55da93effa62a1255980f4075f3"   # PR 65's own
+                             "07c6385913c2ee7311fc1c317fe9f87d"),
     "kanana2": (618, "7e1a4d0a35d9e8c487e85c5fd2d5ca8f"
                      "8a05a524c1f8174aa3de0527033685df"),
     "keye_vl2": (655, "0649d664f592fadd5d847958da86b217"
@@ -104,6 +111,14 @@ DIGESTS = {
 }
 
 CENSUS = {
+    "granite_hybrid": {
+        "parameters": 128, "parameter_uses": 129, "grad_fanin_max": 2,
+        "state_space_groups": 1, "state_space_heads_per_group": 4,
+        "state_space_chunk": 64,
+        "layer_kinds": {"full_attention": 1, "state_space": 9},
+        "state_space_layers": 9, "attention_kv_group": 2,
+        "attention_unrotated_layers": 1, "tied_heads": 1,
+        "residual_scaled_sublayers": 20},
     "kanana2": {
         "parameters": 43, "parameter_uses": 43, "grad_fanin_max": 1,
         "attention_qk_width": 24, "attention_value_width": 16,
@@ -128,6 +143,8 @@ CENSUS = {
         "moe_experts_held": 4, "moe_expert_activation": "relu2",
         "layer_kinds": {"full_attention": 1, "state_space": 4},
         "state_space_layers": 4, "attention_kv_group": 2,
+        "state_space_groups": 2, "state_space_heads_per_group": 2,
+        "state_space_chunk": 128,                   # keys since PR 65
         "moe_router_bias_updates": 4, "attention_unrotated_layers": 1},
     "olmoe": {
         "parameters": 27, "parameter_uses": 27, "grad_fanin_max": 1,

@@ -145,7 +145,12 @@ def test_a_cpu_backend_takes_the_kernels_only_when_interpreted(monkeypatch):
     (4096, 32, 128, 4, (128, 16), (256, 8)),
     (48, 2, 128, 2, (16, 2), (16, 2)), (96, 32, 128, 4, (32, 32), (32, 32)),
     (64, 4, 256, 2, (64, 4), (64, 4)), (512, 7, 128, 2, (128, 7), (256, 7)),
-    (128, 6, 2048, 4, (128, 1), (128, 1))])
+    # a head whose float32 tile is past half a MiB at that height takes
+    # fewer rows (PR 65: at 128 rows of 2048 float32 lanes the kernels' ten
+    # tiles are 10 MiB beside 10 MiB of blocks, past the scoped VMEM; the
+    # first published shape that wide is Granite 4.0-H's group of 4096)
+    (128, 6, 2048, 4, (64, 2), (64, 2)),
+    (2048, 1, 4096, 2, (32, 1), (32, 1))])
 def test_a_block_is_whole_heads_of_at_most_a_mebibyte(T, H, D, itemsize, fwd,
                                                       bwd):
     assert db._gated_norm_blocks(T, H, D, itemsize, False) == fwd

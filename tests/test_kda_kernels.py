@@ -21,7 +21,7 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu import layers
 from paddle_tpu.layers.nn import LayerHelper
-from paddle_tpu.models.nemotron_h import dt_bias_init
+from paddle_tpu.models._decoder import dt_bias_init
 from paddle_tpu.ops import linear_attention as la
 
 import ling3_reference as ref

@@ -745,6 +745,52 @@ def test_nemotron_h_kernels_compile_at_the_cells_shapes(one_chip,
     assert "= (bf16[1,2048,4096]{" in call
 
 
+@pytest.mark.parametrize("case", ["conv", "gated_norm"])
+def test_granite4_kernels_compile_at_the_cells_shapes(one_chip, monkeypatch,
+                                                      case):
+    """What `granite_4_0_h_micro.s2048` calls and no other cell does: the
+    convolution WITH a bias at 4352 channels (34 lane tiles) and the gated
+    norm with the gate first over ONE group of 4096 lanes, whose float32
+    tiles fit the scoped VMEM only at 32 rows a grid step
+    (`_gated_norm_blocks`; at the accepted shapes' 256 rows the backward asked
+    for 20 MiB of the 16). One Mosaic custom call each way. The scan at one
+    group of 64 heads and chunk 256 is off its kernels' plan and is XLA
+    ops."""
+    from paddle_tpu.ops import decoder_block as db
+    from paddle_tpu.ops import state_space as ss
+    monkeypatch.setattr(_kernels, "on_chip", lambda: True)
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    assert not ss._kernels_run(64, 128, 64, 256)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32 = jnp.float32
+    if case == "conv":
+        assert la._conv_plan(2048, 4352, 4) == "kernel"
+        u, w, bias = arg((1, 2048, 4352)), arg((4352, 4), f32), \
+            arg((4352,), f32)
+        conv = jax.jit(lambda x, w, b: la._conv_forward(x, w, True, b)).lower(
+            u, w, bias).compile()
+        (call,) = _custom_calls(conv, "causal_conv_fwd")
+        assert "= bf16[1,2048,4352]{" in call
+        conv = jax.jit(lambda x, w, b, d: la._conv_backward(
+            x, w, d, True, b)).lower(u, w, bias, u).compile()
+        (call,) = _custom_calls(conv, "causal_conv_bwd")
+        assert ", f32[5,4352]{" in call
+        return
+    assert db._gated_norm_plan((1, 2048, 1, 4096), jnp.bfloat16) == "kernel"
+    y, scale = arg((1, 2048, 1, 4096)), arg((4096,), f32)
+    norm = jax.jit(lambda x, g, w: db._gate_first_norm_call(
+        x, g, w, 1e-5)).lower(y, y, scale).compile()
+    (call,) = _custom_calls(norm, "gated_norm_fwd")
+    assert "= bf16[1,2048,4096]{" in call
+    norm = jax.jit(lambda x, g, w, d: db._gate_first_norm_call(
+        x, g, w, 1e-5, d)).lower(y, y, scale, y).compile()
+    (call,) = _custom_calls(norm, "gated_norm_bwd")
+    assert "= (bf16[1,2048,4096]{" in call and "f32[1,64,8,4096]" in call
+
+
 # the two kernel pairs as `ling_3_0_flash_vl.s2048` calls them and no other
 # cell does (the convolution at 12288 channels, the gated norm with the
 # gate's sigmoid): digests by `_lowered_digest` as PR 60 recorded them

@@ -789,7 +789,9 @@ def test_every_layer_is_one_sublayer_under_its_own_scope(tiny, layer):
 
 
 CENSUS = {"layer_kinds": {"state_space": 4, "full_attention": 1},
-          "state_space_layers": 4, "attention_unrotated_layers": 1,
+          "state_space_layers": 4, "state_space_groups": 2,
+          "state_space_heads_per_group": 2, "state_space_chunk": 128,
+          "attention_unrotated_layers": 1,
           "attention_kv_group": 16, "moe_router_score": "sigmoid",
           "moe_router_bias_updates": 4, "moe_experts_routed": 128,
           "moe_experts_held": 8, "moe_expert_activation": "relu2"}
