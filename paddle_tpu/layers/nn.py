@@ -1568,8 +1568,9 @@ def ssd_scan(x, b, c, dt_raw, a_log_attr=None, dt_bias_attr=None, d_attr=None,
     sums on the chip); dt, a, their running sums, the decays and the state
     float32. Returns `[batch, seq, heads, head_dim]` in x's dtype.
 
-    The op has a second output, `States`: float32 `[seq / chunk, batch,
-    heads, head_dim, state]`, the state each chunk started from, as the
+    The op has a second output, `States`: float32 `[seq / 128, batch,
+    heads, head_dim, state]`, the state each of the kernels' steps of 128
+    tokens started from (whatever whole number of them `chunk` is), as the
     forward kernel `ssd_fwd` saves it. `ssd_scan_grad` reads it back and
     runs `ssd_bwd` alone. Where the forward op wrote none (head dims that do
     not fill a vreg, a CPU backend: the XLA form) the grad op traces the

@@ -84,9 +84,9 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     With them `state_space_groups`, `state_space_heads_per_group` and
     `state_space_chunk`: the scan's groups of B and C, the heads that read
     one group and the op's `chunk` attribute (8, 8 and 128 for Nemotron-H; 1,
-    64 and 256 for Granite 4.0-H, which `ops/state_space.py::_plan` leaves to
-    the XLA form; `ssd_plan`, the form the scan ran in, is noted by the op's
-    rule on the same event). `tied_heads`: the embedding tables (a
+    64 and 256 for Granite 4.0-H, which `ops/state_space.py::_grid` takes in
+    eight blocks of 8 heads and steps of 128 tokens; `ssd_plan`, the form
+    the scan ran in, is noted by the op's rule on the same event). `tied_heads`: the embedding tables (a
     `lookup_table`'s W) that a `matmul` reads as well, a head tied to its
     embedding. `residual_scaled_sublayers`: the `scale` ops under a
     `name_scope` whose result goes straight into a residual

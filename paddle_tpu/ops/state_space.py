@@ -20,17 +20,23 @@ the chunk starts from:
 Every exponent is <= 0, so nothing overflows however negative a is. `C B^T`
 is one `[chunk, chunk]` tile a group, shared by the group's heads.
 
-Where a chunk's tiles fill vregs (`_plan`: chunk 128, a state of whole
-128-lane tiles, a group's heads an even number whose head dims pair up to
-whole tiles: the published 8 heads of 64 over a state of 128) the scan is
-two Pallas kernels on `linear_attention._gdn_call`'s plan, a grid of (batch,
-group, chunk), the last axis sequential. A grid step takes the group's heads
-together: x, y and their gradients as `[chunk, heads * head_dim]` tiles (512
-lanes), the state of all its heads stacked `[heads * head_dim, state]`, so
-the products that read or write the state (`C S^T`, `(x w)^T B` and their
-transposes) are one MXU product a step for the whole group, and only the
-`[chunk, chunk]` decay tile and its two products are made head by head (two
-heads side by side in a 128-lane tile).
+Where a chunk's tiles fill vregs (`_plan`: a chunk attribute of whole
+128-token steps, a state of whole 128-lane tiles, head dims that pair up to
+whole tiles, a group's heads an even number of at most 8 or whole blocks of
+8: the published heads of 64 over a state of 128) the scan is two Pallas
+kernels on `linear_attention._gdn_call`'s plan, a grid of (batch, head block,
+chunk), the last axis sequential (`_grid`). A head block is a group's heads,
+or 8 of them where the group has more: block k reads B's and C's tiles of
+group k // (blocks a group), so a group's `[chunk, state]` tiles are read by
+each of its blocks. The kernels' chunk is 128 tokens whatever whole number of
+them the op's attribute is: the recurrence does not depend on the chunk, and
+L, the decay tiles and the saved states are the kernels' own. A grid step
+takes the block's heads together: x, y and their gradients as `[chunk, heads
+* head_dim]` tiles (512 lanes), the state of all its heads stacked `[heads *
+head_dim, state]`, so the products that read or write the state (`C S^T`, `(x
+w)^T B` and their transposes) are one MXU product a step for the whole
+block, and only the `[chunk, chunk]` decay tile and its two products are
+made head by head (two heads side by side in a 128-lane tile).
 
     ssd_fwd   reads the step's x, B, C (as they arrive: bf16 under AMP), L
               and dt (float32, as rows `[heads, chunk]` and as columns
@@ -38,20 +44,25 @@ heads side by side in a 128-lane tile).
               lanes; keeps S [heads * head_dim, state] float32 in VMEM
               scratch across a sequence's chunks; makes C B^T, the decay
               tiles and every product in VMEM and writes none of them: only
-              y and `States`, S as each chunk found it (float32 [chunks, B,
-              H, head_dim, state]).
+              y and `States`, S as each chunk found it (float32 [T / 128,
+              B, H, head_dim, state]).
     ssd_bwd   the chunks last to first, dS (the gradient of the state a
               chunk hands on) float32 in scratch; computes C B^T, the decay
               tiles and `C S^T` again from the chunk's inputs and its saved
-              state; writes dx, dB, dC (summed over the group's heads), the
+              state; writes dx, dB, dC (summed over the block's heads), the
               gradient of dt and of L per token, and a step's part of dD;
               L's reverse running sum inside a chunk (one small XLA op, like
-              the running sum itself) is a's gradient.
+              the running sum itself) is a's gradient. dB and dC are sums
+              over ALL of a group's heads: a group of one block writes them
+              in B's dtype; a group of several writes each block's part in
+              float32 (`[B, T, blocks * state]`) and one XLA op adds a
+              group's parts before the cast, so every sum stays float32
+              until its last add.
 
 Between forward and backward nothing of size `[T, T]` or `[chunks, heads,
 chunk, chunk]` is kept: the saved states are all. The op and its grad op
 tally the grid steps of their calls on the compile event (`ssd_grid_steps`:
-batch x groups x chunks, summed).
+batch x head blocks x chunks of 128, summed).
 
 Float32 whatever dtype flows through: dt, a, L, the decays, the state and
 dS, every accumulator and every product's result. The products take the
@@ -59,19 +70,23 @@ backend's DEFAULT for float32 operands (`linear_attention._dot`: on the chip
 the operands rounded to bf16, one pass into a float32 accumulator, as XLA's
 default does there; float32 under the interpreter on a CPU).
 
-Which published shapes the plan takes: Nemotron-3-Nano's (`nemotron_h`: 8
-groups of 8 heads of 64 over a state of 128, chunk 128) runs the kernel pair.
-Granite 4.0-H's (`granite_hybrid`: ONE group of 64 heads of 64 over a state of
-128, chunk 256) does not: at chunk 256 the plan answers "xla", and a grid step
-that takes a group's heads together would hold `[256, 4096]` blocks of x, y
-and their gradients beside 2 MB each of state and dS at 64 heads. It runs the
-XLA form on the chip, which makes the `[chunks, heads, chunk, chunk]` float32
-decay tiles in HBM (134 MB a copy a layer at 2048 tokens). What taking it
-would need: a grid axis over blocks of a group's heads with B's and C's block
-index held, or a chunk of 128 inside the kernels under the op's attribute of
-256 (the recurrence does not depend on the chunk).
+Which published shapes the plan takes, both at x `[1, 2048, 64, 64]` over a
+state of 128 in their cells: Nemotron-3-Nano's (`nemotron_h`: 8 groups of 8
+heads, chunk 128) is a head block a group at the attribute's chunk, the grid
+(1, 8, 16), B's and C's gradients written by the kernel in bf16.
+Granite 4.0-H's (`granite_hybrid`: ONE group of 64 heads, chunk 256) is eight
+blocks of 8 heads that all read group 0's B and C, in steps of 128 tokens
+under the attribute of 256: the same grid (1, 8, 16), `States` `[16, 1, 64,
+64, 128]` (33.5 MB a layer), dB's and dC's parts `[1, 2048, 1024]` float32 (8
+MB each a layer) summed over the eight blocks outside. A grid step that held
+all 64 heads at chunk 256 would hold `[256, 4096]` blocks of x, y and their
+gradients beside 2 MB each of state and dS; the XLA form at this shape
+makes the `[chunks, heads, chunk, chunk]` float32 decay tiles in HBM (134 MB
+a copy a layer). What the plan leaves to the XLA form: a group of more than
+8 heads that is no whole blocks of 8 (12, 20), a chunk attribute that is no
+whole steps of 128 (64, 192), head dims other than 64.
 
-Outside the envelope (that shape, the small head dims of the CPU tests), and
+Outside the envelope (those, the small head dims of the CPU tests), and
 on a CPU backend unless the Pallas interpreter is asked for
 (`PADDLE_TPU_PALLAS_INTERPRET=1`), the op keeps the XLA form `chunked_ssd`:
 the decay tiles and both in-chunk products for all chunks at once, a
@@ -149,14 +164,20 @@ def chunked_ssd(x, dt, a, Bm, Cm, D, chunk):
 # the two Pallas kernels (module docstring: what stays in VMEM, precisions)
 # ---------------------------------------------------------------------------
 
+_CHUNK = 128    # the tokens a grid step of the kernels takes
+_HEADS = 8      # the most heads of a group it takes together (512 lanes)
+
+
 def _plan(P, N, r, chunk):
-    """"kernel": a chunk of 128 tokens, a state of whole 128-lane tiles and
-    a group whose heads pair up into whole tiles (two heads of 64 side by
-    side; Nemotron-3-Nano's 8 heads of 64 over a state of 128). "xla":
-    anything else (Granite 4.0-H's chunk of 256 over one group of 64 heads,
-    the small head dims of the CPU tests), which keeps `chunked_ssd` and its
-    vjp. The choice reads the shape alone."""
-    if chunk == 128 and N % 128 == 0 and 2 * P == 128 and r % 2 == 0:
+    """"kernel": a chunk attribute of whole 128-token steps, a state of
+    whole 128-lane tiles and a group whose heads pair up into whole tiles
+    (two heads of 64 side by side) and come as one block of at most 8 or as
+    whole blocks of 8 (Nemotron-3-Nano's 8 heads a group at chunk 128,
+    Granite 4.0-H's 64 at chunk 256). "xla": anything else (the small head
+    dims of the CPU tests, a chunk of 64, 12 heads a group), which keeps
+    `chunked_ssd` and its vjp. The choice reads the shape alone."""
+    if chunk % _CHUNK == 0 and N % 128 == 0 and 2 * P == 128 \
+            and r % 2 == 0 and (r <= _HEADS or r % _HEADS == 0):
         return "kernel"
     return "xla"
 
@@ -166,9 +187,23 @@ def _kernels_run(P, N, r, chunk):
         and _kernels.backend_takes_kernels()
 
 
+def _grid(X, Bm, chunk):
+    """(head blocks, heads a block, tokens a step) of the kernels' grid at
+    the op's `chunk` attribute: a group's heads in blocks of at most
+    `_HEADS`, steps of `_CHUNK` tokens whatever whole number of them the
+    attribute is (the recurrence does not depend on the chunk). A state is
+    saved every step. Off the plan, where the XLA form runs and saves none,
+    the attribute's chunk."""
+    H, r = X.shape[2], X.shape[2] // Bm.shape[2]
+    if _plan(X.shape[3], Bm.shape[3], r, chunk) != "kernel":
+        return Bm.shape[2], r, chunk
+    r = min(r, _HEADS)
+    return H // r, r, _CHUNK
+
+
 class _Step:
-    """What both kernels compute of one (batch, group, chunk) grid step
-    before they part. The group's `r` heads lie side by side in the lanes
+    """What both kernels compute of one (batch, head block, chunk) grid step
+    before they part. The block's `r` heads lie side by side in the lanes
     of x (`[C, r P]`), two heads a 128-lane tile; a head's per-token values
     come as a column `[C, 1]` (of the `[C, r]` blocks) and as a row `[1, C]`
     (of the `[r, C]` blocks)."""
@@ -238,7 +273,7 @@ class _Step:
 
 def _ssd_fwd_kernel(x_ref, b_ref, c_ref, l_rows, l_cols, dt_rows, dt_cols,
                     d_ref, states_ref, y_ref, s_sc, *, r, P):
-    """One (batch, group, chunk) step for the group's `r` heads: the state
+    """One (batch, head block, chunk) step for the block's `r` heads: the state
     written as the chunk found it, the chunk's outputs, the state moved on
     in scratch."""
     from jax.experimental import pallas as pl
@@ -349,46 +384,56 @@ def _ssd_bwd_kernel(x_ref, b_ref, c_ref, l_rows, l_cols, dt_rows, dt_cols,
 
 def _ssd_call(kernel, name, X, Dt, A, Bm, Cm, D, more, out_shape, out_blocks,
               chunk, reverse):
-    """Both kernels' grid and blocks: (batch, group, chunk), the last axis
-    sequential. x and its like are read where they lie, as `[B, T, H * P]`
-    with a group's heads' lanes chosen by the block index; B and C as `[B,
-    T, G * N]`; L (a's running sum inside a chunk) and dt twice, as `[B, H,
-    T]` (a head's tokens a row) and as `[B, G, T, r]` (a column): small
-    float32 arrays that XLA lays out; D spread over its head's lanes."""
+    """Both kernels' grid and blocks (`_grid`): (batch, head block, chunk),
+    the last axis sequential. A head block is a group's heads, or `_HEADS`
+    of them where it has more: block k reads B and C of group k // (blocks a
+    group), so a group's tiles are read by each of its blocks. x and its
+    like are read where they lie, as `[B, T, H * P]` with a block's heads'
+    lanes chosen by the block index; B and C as `[B, T, G * N]`; L (a's
+    running sum inside a chunk) and dt twice, as `[B, H, T]` (a head's
+    tokens a row) and as `[B, blocks, T, heads a block]` (a column): small
+    float32 arrays that XLA lays out; D spread over its head's lanes. "part"
+    is a block's share of dB or dC, `[B, T, blocks * N]`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, H, P = X.shape
     G, N = Bm.shape[2], Bm.shape[3]
-    r, n = H // G, T // chunk
+    K, r, chunk = _grid(X, Bm, chunk)
+    n, per_group = T // chunk, K // G
 
     def at(c):                          # the chunk a grid step works on
         return n - 1 - c if reverse else c
 
+    def group(k):                       # of head block k
+        return k if per_group == 1 else k // per_group
+
     blocks = {
-        "x": pl.BlockSpec((1, chunk, r * P), lambda b, g, c: (b, at(c), g)),
-        "bc": pl.BlockSpec((1, chunk, N), lambda b, g, c: (b, at(c), g)),
-        "rows": pl.BlockSpec((1, r, chunk), lambda b, g, c: (b, g, at(c))),
+        "x": pl.BlockSpec((1, chunk, r * P), lambda b, k, c: (b, at(c), k)),
+        "bc": pl.BlockSpec((1, chunk, N),
+                           lambda b, k, c: (b, at(c), group(k))),
+        "part": pl.BlockSpec((1, chunk, N), lambda b, k, c: (b, at(c), k)),
+        "rows": pl.BlockSpec((1, r, chunk), lambda b, k, c: (b, k, at(c))),
         "cols": pl.BlockSpec((1, 1, chunk, r),
-                             lambda b, g, c: (b, g, at(c), 0)),
-        "skip": pl.BlockSpec((1, r * P), lambda b, g, c: (0, g)),
+                             lambda b, k, c: (b, k, at(c), 0)),
+        "skip": pl.BlockSpec((1, r * P), lambda b, k, c: (0, k)),
         "states": pl.BlockSpec((1, 1, 1, r * P, N),
-                               lambda b, g, c: (at(c), b, g, 0, 0)),
-        "skip_sum": pl.BlockSpec((1, 1, r * P), lambda b, g, c: (b, 0, g))}
+                               lambda b, k, c: (at(c), b, k, 0, 0)),
+        "skip_sum": pl.BlockSpec((1, 1, r * P), lambda b, k, c: (b, 0, k))}
     L = _running_sum(A, chunk)
     dt = Dt.astype(jnp.float32)
 
     def rows(v):        # [B, T, H] -> [B, H, T]
         return jnp.swapaxes(v, 1, 2)
 
-    def cols(v):        # [B, T, H] -> [B, G, T, r]
-        return jnp.swapaxes(v.reshape(B, T, G, r), 1, 2)
+    def cols(v):        # [B, T, H] -> [B, K, T, r]
+        return jnp.swapaxes(v.reshape(B, T, K, r), 1, 2)
 
     skip = jnp.repeat(D.astype(jnp.float32), P).reshape(1, H * P)
     ins = ["x", "bc", "bc", "rows", "cols", "rows", "cols", "skip"] \
         + [k for k, _ in more]
     return pl.pallas_call(
-        functools.partial(kernel, r=r, P=P), name=name, grid=(B, G, n),
+        functools.partial(kernel, r=r, P=P), name=name, grid=(B, K, n),
         in_specs=[blocks[k] for k in ins],
         out_specs=[blocks[k] for k in out_blocks], out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((r * P, N), jnp.float32)],
@@ -402,49 +447,56 @@ def _ssd_call(kernel, name, X, Dt, A, Bm, Cm, D, more, out_shape, out_blocks,
 
 def _ssd_forward(X, Dt, A, Bm, Cm, D, chunk):
     """X [B, T, H, P] as it arrives, Dt, A [B, T, H], Bm, Cm [B, T, G, N],
-    D [H] -> out in X's shape and dtype and the states [chunks, B, H, P, N]
-    float32, each as its chunk found it."""
+    D [H], the op's `chunk` attribute -> out in X's shape and dtype and the
+    states [steps, B, H, P, N] float32, each as its step of the grid
+    (`_grid`) found it."""
     B, T, H, P = X.shape
-    G, N = Bm.shape[2], Bm.shape[3]
+    N = Bm.shape[3]
+    K, r, step = _grid(X, Bm, chunk)
     states, out = _ssd_call(
         _ssd_fwd_kernel, "ssd_fwd", X, Dt, A, Bm, Cm, D, [],
-        (jax.ShapeDtypeStruct((T // chunk, B, G, H // G * P, N), jnp.float32),
+        (jax.ShapeDtypeStruct((T // step, B, K, r * P, N), jnp.float32),
          jax.ShapeDtypeStruct((B, T, H * P), X.dtype)),
         ["states", "x"], chunk, reverse=False)
-    return out.reshape(X.shape), states.reshape(T // chunk, B, H, P, N)
+    return out.reshape(X.shape), states.reshape(T // step, B, H, P, N)
 
 
 def _ssd_backward(X, Dt, A, Bm, Cm, D, states, d_out, chunk):
     """The six input gradients from the saved states and `d_out` [B, T, H,
     P], each in its input's shape (X's, B's and C's in their dtypes, the
-    others float32)."""
+    others float32). dB and dC are sums over all of a group's heads: where
+    a group is several head blocks each writes its part in float32, and
+    they are added here before the cast."""
     B, T, H, P = X.shape
     G, N = Bm.shape[2], Bm.shape[3]
-    r = H // G
+    K, r, step = _grid(X, Bm, chunk)
     f32 = jnp.float32
     per_row = jax.ShapeDtypeStruct((B, H, T), f32)
-    per_col = jax.ShapeDtypeStruct((B, G, T, r), f32)
+    per_col = jax.ShapeDtypeStruct((B, K, T, r), f32)
+    part = jax.ShapeDtypeStruct((B, T, K * N), Bm.dtype if K == G else f32)
     dx, db, dc, dl_r, dl_c, ddt_r, ddt_c, dd = _ssd_call(
         _ssd_bwd_kernel, "ssd_bwd", X, Dt, A, Bm, Cm, D,
-        [("states", states.reshape(T // chunk, B, G, r * P, N)),
+        [("states", states.reshape(T // step, B, K, r * P, N)),
          ("x", d_out.astype(X.dtype).reshape(B, T, H * P))],
-        (jax.ShapeDtypeStruct((B, T, H * P), X.dtype),
-         jax.ShapeDtypeStruct((B, T, G * N), Bm.dtype),
-         jax.ShapeDtypeStruct((B, T, G * N), Cm.dtype),
+        (jax.ShapeDtypeStruct((B, T, H * P), X.dtype), part, part,
          per_row, per_col, per_row, per_col,
          jax.ShapeDtypeStruct((B, 1, H * P), f32)),
-        ["x", "bc", "bc", "rows", "cols", "rows", "cols", "skip_sum"], chunk,
-        reverse=True)
+        ["x", "part", "part", "rows", "cols", "rows", "cols", "skip_sum"],
+        chunk, reverse=True)
 
     def per_token(rows, cols):  # both parts -> [B, T, H]
         return jnp.swapaxes(rows, 1, 2) \
             + jnp.swapaxes(cols, 1, 2).reshape(B, T, H)
 
-    dL = per_token(dl_r, dl_c).reshape(B, T // chunk, chunk, H)
+    def of_group(parts):        # [B, T, K * N] -> [B, T, G, N]
+        if K > G:
+            parts = parts.reshape(B, T, G, K // G, N).sum(3)
+        return parts.reshape(B, T, G, N).astype(Bm.dtype)
+
+    dL = per_token(dl_r, dl_c).reshape(B, T // step, step, H)
     dA = lax.cumsum(dL, axis=2, reverse=True).reshape(B, T, H)
     return (dx.reshape(X.shape), per_token(ddt_r, ddt_c), dA,
-            db.reshape(Bm.shape), dc.reshape(Cm.shape),
-            dd.reshape(B, H, P).sum((0, 2)))
+            of_group(db), of_group(dc), dd.reshape(B, H, P).sum((0, 2)))
 
 
 # ---------------------------------------------------------------------------
@@ -461,21 +513,23 @@ def _check(X, Bm, chunk):
 
 def _states_shape(X, Bm, chunk):
     B, T, H, P = X.shape
-    return jax.ShapeDtypeStruct((T // chunk, B, H, P, Bm.shape[3]),
-                                jnp.float32)
+    return jax.ShapeDtypeStruct((T // _grid(X, Bm, chunk)[2], B, H, P,
+                                 Bm.shape[3]), jnp.float32)
 
 
 def _tally_grid(ctx, X, Bm, chunk):
     """The grid steps this op's kernel call runs, onto the compile event
     (`ssd_grid_steps`, summed over the program's ops and grad ops)."""
-    ctx.tally("ssd_grid_steps", X.shape[0] * Bm.shape[2]
-              * (X.shape[1] // chunk))
+    blocks, _, step = _grid(X, Bm, chunk)
+    ctx.tally("ssd_grid_steps", X.shape[0] * blocks * (X.shape[1] // step))
 
 
 def _ssd_scan_infer(ctx, structs):
     """Build-time shapes without a trace of the scan: a machine with no TPU
     takes the XLA form, which saves no `States`, and the program it builds
-    may run on one that has."""
+    may run on one that has. `States` is declared as the chip's kernels
+    write it: a state every step of their grid (`_grid`) where the plan
+    takes the shape, whatever the chunk attribute."""
     X, Bm = structs["X"][0], structs["B"][0]
     return {"Out": jax.ShapeDtypeStruct(X.shape, X.dtype),
             "States": _states_shape(X, Bm, int(ctx.attr("chunk", 128)))}
@@ -490,8 +544,8 @@ def _ssd_scan(ctx, X, Dt, A, B, C, D):
     [H] -> Out [B, T, H, P] in X's dtype. H is a multiple of G: group g
     serves heads g * H/G .. (g + 1) * H/G - 1. T must be a multiple of
     `chunk`. On the kernel path (`_plan`) the scan also returns `States`
-    [T / chunk, B, H, P, N] float32, the state each chunk started from,
-    which the grad op reads back."""
+    [T / 128, B, H, P, N] float32, the state each of the kernels' chunks
+    started from, which the grad op reads back."""
     chunk = int(ctx.attr("chunk", 128))
     _check(X, B, chunk)
     kernels = _kernels_run(X.shape[3], B.shape[3], X.shape[2] // B.shape[2],

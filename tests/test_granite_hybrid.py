@@ -35,8 +35,9 @@ from paddle_tpu.ops import decoder_block as db
 from paddle_tpu.ops import state_space as ss
 
 import granite_hybrid_reference as ref
-from test_nemotron_h import (SCAN_NAMES, _forward_ops_by_scope, _recurrence,
-                             _scan_inputs, _scan_layer)
+from test_nemotron_h import (SCAN_NAMES, _forward_ops_by_scope,
+                             _published_scan, _recurrence, _scan_inputs,
+                             _scan_layer)
 from test_olmoe import rel_err, run_piece
 from test_qwen3_next import frob
 
@@ -130,22 +131,85 @@ def test_one_group_is_not_a_group_a_head():
 
 
 @pytest.mark.parametrize("P,N,r,chunk,plan", [
-    (64, 128, 64, 256, "xla"),      # Granite 4.0-H: chunk 256, 64 heads
-    (64, 128, 8, 256, "xla"),       # Nemotron-H's group at that chunk
+    (64, 128, 64, 256, "kernel"),   # Granite 4.0-H: chunk 256, 64 heads
+    (64, 128, 8, 256, "kernel"),    # Nemotron-H's group at that chunk
     (64, 128, 8, 128, "kernel"),    # Nemotron-H
+    (64, 128, 12, 128, "xla"),      # more than 8 heads, no whole blocks of 8
+    (64, 128, 8, 192, "xla"),       # a chunk that is no whole steps of 128
     (16, 16, 4, 64, "xla")],        # the tiny block
-    ids=["granite", "chunk_256", "nemotron", "tiny"])
+    ids=["granite", "chunk_256", "nemotron", "twelve_heads", "chunk_192",
+         "tiny"])
 def test_the_plan_reads_the_shape_alone(P, N, r, chunk, plan):
     assert ss._plan(P, N, r, chunk) == plan
 
 
-def test_the_published_scan_compiles_as_the_xla_form(monkeypatch):
-    """At the published shape on a chip the op takes `chunked_ssd`: no
-    kernel is asked for, and the op saves no `States`."""
+def test_the_published_scan_takes_the_kernels(monkeypatch):
+    """At the published shape on a chip the op takes the kernel pair, eight
+    blocks of 8 heads in steps of 128 tokens under the chunk attribute of
+    256, and a Program built on a machine without a TPU declares `States`
+    as the chip's kernels write it: a state every 128 tokens."""
     from paddle_tpu.ops import _kernels
+    assert not ss._kernels_run(64, 128, 64, 256)        # a CPU, no interpreter
     monkeypatch.setattr(_kernels, "on_chip", lambda: True)
-    assert not ss._kernels_run(64, 128, 64, 256)
+    assert ss._kernels_run(64, 128, 64, 256)
     assert ss._kernels_run(64, 128, 8, 128)
+    x = jax.ShapeDtypeStruct((1, 2048, 64, 64), jnp.bfloat16)
+    bc = jax.ShapeDtypeStruct((1, 2048, 1, 128), jnp.bfloat16)
+    assert ss._grid(x, bc, 256) == (8, 8, 128)
+    states = ss._states_shape(x, bc, 256)
+    assert states.shape == (16, 1, 64, 64, 128)
+    assert states.dtype == jnp.float32
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()):
+        data = {n: layers.data(name=n, shape=list(s), dtype="float32",
+                               append_batch_size=False)
+                for n, s in (("x", (1, 512, 64, 64)), ("b", (1, 512, 1, 128)),
+                             ("c", (1, 512, 1, 128)), ("dt_raw", (1, 512, 64)))}
+        layers.ssd_scan(data["x"], data["b"], data["c"], data["dt_raw"],
+                        chunk=256)
+    (op,) = [o for o in main.global_block().ops if o.type == "ssd_scan"]
+    declared = main.global_block().var(op.outputs["States"][0])
+    assert tuple(declared.shape) == (4, 1, 64, 64, 128)
+    # off the plan the XLA form's chunk is the attribute's
+    tiny = jax.ShapeDtypeStruct((1, 128, 4, 16), jnp.float32)
+    assert ss._grid(tiny, jax.ShapeDtypeStruct((1, 128, 1, 16), jnp.float32),
+                    64) == (1, 4, 64)
+
+
+def test_interpreted_kernels_take_a_group_in_blocks_of_heads(monkeypatch):
+    """`ssd_fwd` / `ssd_bwd` under the Pallas interpreter at ONE group of 16
+    heads (two blocks of 8 that read the same B and C), 512 tokens, chunk
+    attribute 256 (four steps of 128 inside), float32, against the XLA form
+    at chunk 256 and its `jax.vjp`: the output, the states' shape (one every
+    128 tokens, the first zero) and all six gradients. dB and dC are the
+    sums over both blocks' parts. Forward
+    within RTOL: the kernels' running sums restart every 128 tokens, the
+    XLA form's every 256, as between two chunks of the XLA form (first
+    reading 1.04e-5 of the largest value)."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    assert ss._plan(64, 128, 16, 256) == "kernel"
+    args = _published_scan(seed=5, T=512, heads=16)
+    assert ss._grid(args[0], args[3], 256) == (2, 8, 128)
+    out, states = ss._ssd_forward(*args, 256)
+    want, vjp = jax.vjp(lambda *a: ss.chunked_ssd(*a, 256), *args)
+    assert states.shape == (4, 1, 16, 64, 128)
+    assert np.all(np.asarray(states[0]) == 0)
+    assert rel_err(out, want) < RTOL
+    # the same scan at the attribute 128 is the same call
+    again, _ = ss._ssd_forward(*args, 128)
+    assert np.array_equal(np.asarray(out), np.asarray(again))
+    d_out = jnp.asarray(np.random.RandomState(6).randn(*out.shape),
+                        jnp.float32)
+    got = ss._ssd_backward(*args, states, d_out, 256)
+    for name, g, w in zip(ss._SLOTS, got, vjp(d_out)):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        assert frob(g, w) < 1e-4, \
+            f"{name} (B's and C's are the sums over the group's head blocks)"
+    # one block's part alone is not the group's gradient
+    x, dt, a, b, c, D = args
+    half = ss._ssd_backward(x[:, :, :8], dt[:, :, :8], a[:, :, :8], b, c,
+                            D[:8], states[:, :, :8], d_out[:, :, :8], 256)
+    assert frob(half[3], got[3]) > 0.1 and frob(half[4], got[4]) > 0.1
 
 
 # -- the gated norm over ONE group as wide as the mixer ------------------------------------------

@@ -745,7 +745,25 @@ def test_nemotron_h_kernels_compile_at_the_cells_shapes(one_chip,
     assert "= (bf16[1,2048,4096]{" in call
 
 
-@pytest.mark.parametrize("case", ["conv", "gated_norm"])
+def _scan_lowerings(one_chip, groups, chunk):
+    """`_ssd_forward` and `_ssd_backward` lowered at bf16 x `[1, 2048, 64,
+    64]`, B and C `[1, 2048, groups, 128]` and the op's `chunk`."""
+    from paddle_tpu.ops import state_space as ss
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32 = jnp.float32
+    x, bc = arg((1, 2048, 64, 64)), arg((1, 2048, groups, 128))
+    gate, skip = arg((1, 2048, 64), f32), arg((64,), f32)
+    return (jax.jit(lambda *a: ss._ssd_forward(*a, chunk)).lower(
+                x, gate, gate, bc, bc, skip),
+            jax.jit(lambda *a: ss._ssd_backward(*a, chunk)).lower(
+                x, gate, gate, bc, bc, skip,
+                arg((16, 1, 64, 64, 128), f32), x))
+
+
+@pytest.mark.parametrize("case", ["conv", "gated_norm", "scan"])
 def test_granite4_kernels_compile_at_the_cells_shapes(one_chip, monkeypatch,
                                                       case):
     """What `granite_4_0_h_micro.s2048` calls and no other cell does: the
@@ -753,19 +771,29 @@ def test_granite4_kernels_compile_at_the_cells_shapes(one_chip, monkeypatch,
     norm with the gate first over ONE group of 4096 lanes, whose float32
     tiles fit the scoped VMEM only at 32 rows a grid step
     (`_gated_norm_blocks`; at the accepted shapes' 256 rows the backward asked
-    for 20 MiB of the 16). One Mosaic custom call each way. The scan at one
-    group of 64 heads and chunk 256 is off its kernels' plan and is XLA
-    ops."""
+    for 20 MiB of the 16); `ssd_fwd` / `ssd_bwd` on bf16 x `[1, 2048, 64,
+    64]` and B, C `[1, 2048, 1, 128]` at chunk 256: ONE group of 64 heads as
+    eight blocks of 8 that read the same B and C, sixteen steps of 128
+    tokens, each block's part of dB and dC in float32. One Mosaic custom
+    call each way."""
     from paddle_tpu.ops import decoder_block as db
     from paddle_tpu.ops import state_space as ss
     monkeypatch.setattr(_kernels, "on_chip", lambda: True)
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
-    assert not ss._kernels_run(64, 128, 64, 256)
 
     def arg(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     f32 = jnp.float32
+    if case == "scan":
+        assert ss._kernels_run(64, 128, 64, 256)
+        fwd, bwd = _scan_lowerings(one_chip, 1, 256)
+        (call,) = _custom_calls(fwd.compile(), "ssd_fwd")
+        assert "(f32[16,1,8,512,128]{" in call and "tpu_custom_call" in call
+        (call,) = _custom_calls(bwd.compile(), "ssd_bwd")
+        assert "(bf16[1,2048,4096]{" in call and "tpu_custom_call" in call
+        assert call.count("f32[1,2048,1024]{") == 2     # dB's and dC's parts
+        return
     if case == "conv":
         assert la._conv_plan(2048, 4352, 4) == "kernel"
         u, w, bias = arg((1, 2048, 4352)), arg((4352, 4), f32), \
@@ -789,6 +817,25 @@ def test_granite4_kernels_compile_at_the_cells_shapes(one_chip, monkeypatch,
         x, g, w, 1e-5, d)).lower(y, y, scale, y).compile()
     (call,) = _custom_calls(norm, "gated_norm_bwd")
     assert "= (bf16[1,2048,4096]{" in call and "f32[1,64,8,4096]" in call
+
+
+# `ssd_fwd` / `ssd_bwd` as `nemotron_3_nano_30b_a3b.s2048` calls them (8
+# groups of 8 heads, chunk 128: one head block a group, the step the
+# attribute's chunk): digests taken on the commit before the grid's axis over
+# head blocks (aa1920d) by `_lowered_digest`
+NEMOTRON_SCAN = {"forward": "a6f2680a3e0e89523dacf81f050d5028",
+                 "backward": "24cdb44a6f191450ea26d930757777f3"}
+
+
+@pytest.mark.parametrize("way", sorted(NEMOTRON_SCAN))
+def test_nemotron_h_scan_lowers_as_it_did_before_head_blocks(one_chip,
+                                                             monkeypatch,
+                                                             way):
+    monkeypatch.setattr(_kernels, "on_chip", lambda: True)
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    lowered = dict(zip(("forward", "backward"),
+                       _scan_lowerings(one_chip, 8, 128)))[way]
+    assert _lowered_digest(lowered)[:32] == NEMOTRON_SCAN[way]
 
 
 # the two kernel pairs as `ling_3_0_flash_vl.s2048` calls them and no other

@@ -143,17 +143,17 @@ def test_scan_refuses_a_length_off_the_chunk():
         run_piece(_scan_layer(64), feed, params)
 
 
-def _published_scan(seed=2, T=256):
+def _published_scan(seed=2, T=256, heads=8):
     """One group at the published head shapes: 8 heads of 64 over a state of
     128, chunk 128."""
     rng = np.random.RandomState(seed)
     f = jnp.float32
-    x = jnp.asarray(rng.randn(1, T, 8, 64), f)
+    x = jnp.asarray(rng.randn(1, T, heads, 64), f)
     b = jnp.asarray(rng.randn(1, T, 1, 128) * 0.3, f)
     c = jnp.asarray(rng.randn(1, T, 1, 128) * 0.3, f)
-    dt = jax.nn.softplus(jnp.asarray(rng.randn(1, T, 8) - 1.0, f))
-    a = -jnp.asarray(rng.uniform(1, 8, 8), f) * dt
-    D = jnp.asarray(rng.uniform(0.5, 1.5, 8), f)
+    dt = jax.nn.softplus(jnp.asarray(rng.randn(1, T, heads) - 1.0, f))
+    a = -jnp.asarray(rng.uniform(1, 8, heads), f) * dt
+    D = jnp.asarray(rng.uniform(0.5, 1.5, heads), f)
     return x, dt, a, b, c, D
 
 

@@ -1,12 +1,19 @@
 """TPU-only: the selective scan's Mosaic kernels (`ssd_fwd`, `ssd_bwd`,
-`ops/state_space.py`) at the shapes of `nemotron_3_nano_30b_a3b.s2048`, x `[1,
-2048, 64, 64]`, B and C `[1, 2048, 8, 128]` in bf16, against `jax.vjp` of the
-XLA form; beside them the two kernel pairs this configuration calls in a form
-of its own: the causal convolution with a bias at `[1, 2048, 6144]` and the
-gated norm with the gate first over 8 groups of 512. The CPU suite holds all
-of them to their jnp forms under the Pallas interpreter in float32
-(`tests/test_nemotron_h.py`); what only the chip can say is that their
-one-pass products read no worse than XLA's at its default precision."""
+`ops/state_space.py`) at the shapes of the two cells that run them, x `[1,
+2048, 64, 64]` in bf16 both: `nemotron_3_nano_30b_a3b.s2048`'s B and C `[1,
+2048, 8, 128]` at chunk 128 (a head block a group) and
+`granite_4_0_h_micro.s2048`'s `[1, 2048, 1, 128]` at chunk 256 (ONE group in
+eight head blocks, steps of 128 tokens inside, dB and dC summed over the
+blocks), against `jax.vjp` of the XLA form at the cell's chunk; beside them
+the two kernel pairs Nemotron's configuration calls in a form of its own:
+the causal convolution with a bias at `[1, 2048, 6144]` and the gated norm
+with the gate first over 8 groups of 512. The CPU suite holds all of them to
+their jnp forms under the Pallas interpreter in float32
+(`tests/test_nemotron_h.py`, `tests/test_granite_hybrid.py`); what only the
+chip can say is that their one-pass products read no worse than XLA's at its
+default precision."""
+
+import functools
 
 import numpy as np
 import pytest
@@ -22,7 +29,8 @@ pytestmark = pytest.mark.skipif(
     jax.default_backend() != "tpu",
     reason="Mosaic kernels need real TPU hardware")
 
-B, T, H, P, G, N, CHUNK = 1, 2048, 64, 64, 8, 128, 128
+B, T, H, P, N = 1, 2048, 64, 64, 128
+CELLS = {"nemotron": (8, 128), "granite": (1, 256)}     # groups, chunk
 SLOTS = list(ss._SLOTS)
 
 
@@ -31,18 +39,17 @@ def _frob(got, want):
     return np.linalg.norm(got - want) / (np.linalg.norm(want) + 1e-30)
 
 
-def _xla_form(*args):
-    return ss.chunked_ssd(*(a.astype(jnp.float32) for a in args), CHUNK)
-
-
-@jax.jit
-def _xla_value_and_grads(args, d_out):
-    out, vjp = jax.vjp(_xla_form, *args)
+@functools.partial(jax.jit, static_argnums=2)
+def _xla_value_and_grads(args, d_out, chunk):
+    out, vjp = jax.vjp(lambda *v: ss.chunked_ssd(
+        *(a.astype(jnp.float32) for a in v), chunk), *args)
     return out, vjp(d_out.astype(jnp.float32))
 
 
-@pytest.fixture(scope="module")
-def readings():
+@pytest.fixture(scope="module", params=sorted(CELLS))
+def readings(request):
+    G, CHUNK = CELLS[request.param]
+    assert ss._plan(P, N, H // G, CHUNK) == "kernel"
     rng = np.random.RandomState(0)
     bf16 = jnp.bfloat16
     x = jnp.asarray(rng.randn(B, T, H, P), bf16)
@@ -58,20 +65,22 @@ def readings():
     out, states = jax.jit(lambda *v: ss._ssd_forward(*v, CHUNK))(*args)
     grads = jax.jit(lambda *v: ss._ssd_backward(*v, CHUNK))(
         *args, states, d_out)
-    xla = _xla_value_and_grads(args, d_out)
+    xla = _xla_value_and_grads(args, d_out, CHUNK)
     with jax.default_matmul_precision("highest"):
-        exact = _xla_value_and_grads(args, d_out)
-    return dict(out=out, states=states, grads=grads, xla=xla, exact=exact)
+        exact = _xla_value_and_grads(args, d_out, CHUNK)
+    return dict(out=out, states=states, grads=grads, xla=xla, exact=exact,
+                groups=G)
 
 
 def test_outputs_keep_their_inputs_shapes_and_dtypes(readings):
     assert readings["out"].shape == (B, T, H, P)
     assert readings["out"].dtype == jnp.bfloat16
-    assert readings["states"].shape == (T // CHUNK, B, H, P, N)
+    assert readings["states"].shape == (T // 128, B, H, P, N)
     assert readings["states"].dtype == jnp.float32
     dx, ddt, da, d_b, d_c, d_skip = readings["grads"]
     assert dx.shape == (B, T, H, P) and dx.dtype == jnp.bfloat16
-    assert d_b.shape == d_c.shape == (B, T, G, N) and d_b.dtype == jnp.bfloat16
+    assert d_b.shape == d_c.shape == (B, T, readings["groups"], N)
+    assert d_b.dtype == d_c.dtype == jnp.bfloat16
     assert ddt.shape == da.shape == (B, T, H) and da.dtype == jnp.float32
     assert d_skip.shape == (H,)
 
