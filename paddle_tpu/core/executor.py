@@ -487,6 +487,8 @@ class _CompiledProgram:
         self.feed_avals: Optional[Dict[str, Any]] = None
         # feed signatures the step has run with (`steplog.track_shapes`)
         self.shape_sigs = set()
+        # the pace of this entry's steps, handed to each run's `RunSpans`
+        self.pace = _steplog.Pace()
         block = program.global_block()
         lowerer = BlockLowerer(program, amp=key.amp,
                                check_nan_inf=key.check_nan_inf,
@@ -802,9 +804,11 @@ class PreparedProgram:
         # host spans on the profiler's clock at default flags; StepStats on
         # the same boundaries only when observing, so the prepared fast
         # path still performs zero registry writes (observe/steplog.py)
+        entry = self._entry
         with _steplog.RunSpans(
                 program._uid, self.telemetry_source,
-                self._exe._run_counts.get(program._uid, 0)) as spans:
+                self._exe._run_counts.get(program._uid, 0),
+                None if entry is None else entry.pace) as spans:
             feed = feed or {}
             # py_reader-fed program: no feed -> pop the next queued batch
             # (raises EOFException at end of pass, reference read-op
@@ -832,7 +836,6 @@ class PreparedProgram:
                     feed_arrays[name] = val
             else:
                 feed_arrays = self._convert(feed)
-            entry = self._entry
             if entry is None or feed_arrays.keys() != self._entry_keys:
                 # binding (validation, feed plan, cache lookup) is its own
                 # one-shot phase: it never pollutes steady-state

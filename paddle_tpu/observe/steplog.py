@@ -14,6 +14,16 @@ Three runtime questions dominate TPU cost and were previously invisible:
    clock serves both and the set-up store: `time.perf_counter()` read once
    at each boundary into the run's own slots.
 
+   The same slots keep the pace of an entry's steps (`Pace`), and every
+   step interval that runs long (`is_long`) leaves a record of where the
+   host was and what the process did meanwhile (`stall_record`,
+   `RecompilationObservatory.stalls()`): no profile covers the untraced
+   run in which a stall of seconds falls. A steady step pays for it one
+   more clock read at the run's end, a compare and a write into a ring of
+   floats, and every eighth one `getrusage(RUSAGE_THREAD)` and one
+   `process_time()` (two system calls, 6 us each on the chip's host: chip
+   run, PR 67); it takes no lock, writes to no store and logs nothing.
+
 2. *Why did XLA recompile?* The static lint (analysis/, PR 2) can only
    WARN about feed-shape recompile hazards; the observatory closes the
    loop by recording every actual jit cache miss with its attributed
@@ -72,13 +82,17 @@ Three runtime questions dominate TPU cost and were previously invisible:
    outside its jitted call, or inside a set-up phase with no run open, is
    counted there (`EagerCompiles`), not dropped and not given a cause. On
    at default flags, as the compile events are. A steady step keeps
-   nothing: it pays the clock reads, no allocation, no lock, no write to
-   any store.
+   nothing here: it pays the clock reads and the samples of question 1, no
+   lock, no write to any store.
 """
 
 from __future__ import annotations
 
+import functools
+import gc
 import itertools
+import logging
+import statistics
 import threading
 import time
 from collections import deque
@@ -119,6 +133,28 @@ PHASES = tuple(w[1] for w in (FEED_CONVERT, STATE_GATHER, JIT_CALL,
 
 span = jax.profiler.TraceAnnotation
 _now = time.perf_counter     # the one clock of phases, stages and runs
+_process_cpu = time.process_time    # CPU seconds of all threads, so far
+logger = logging.getLogger("paddle_tpu.observe")
+
+
+def _thread_usage():
+    """`getrusage(RUSAGE_THREAD)` as a call without arguments, or None where
+    the platform has no `resource` module or no per-thread usage: a stall's
+    record then holds None where this thread's numbers would be."""
+    try:
+        import resource
+        return functools.partial(resource.getrusage, resource.RUSAGE_THREAD)
+    except (ImportError, AttributeError):
+        return None
+
+
+_usage = _thread_usage()
+
+
+def _sample(t: float) -> tuple:
+    """(t, this thread, its usage or None, the process's CPU seconds)"""
+    return (t, threading.get_ident(),
+            None if _usage is None else _usage(), _process_cpu())
 
 
 class StepStats:
@@ -220,8 +256,12 @@ class _Building(threading.local):
     """What is open on this thread. `event`: the compile event last recorded
     here; it takes the stage durations jax reports until the run() that built
     it returns (RunSpans) or the thread records another. `run`: the RunSpans
-    in progress, if any. `phase`: the innermost open `Phase`."""
+    in progress, if any. `phase`: the innermost open `Phase`. `run_s`: the
+    seconds this thread has spent inside run()s so far, of any program: what
+    grew between two runs of one entry, less the first run itself, was
+    inside other programs' runs."""
     event = run = phase = None
+    run_s = 0.0
 
 
 _building = _Building()
@@ -336,6 +376,179 @@ def open_phase() -> Optional[Phase]:
     return _building.phase
 
 
+class _Process:
+    """What the whole process did so far that can hold a step up, as two
+    tuples that are replaced, never changed, so that a run samples each with
+    one read: `gc` = collections by generation, then seconds inside them by
+    generation (from a `gc.callbacks` hook: nothing runs between
+    collections, and a collection stops every thread); `compiles` = backend
+    compiles, persistent-cache hits, misses that jax reported on any thread
+    (the listeners below). Collections never overlap, so `gc` needs no lock;
+    two threads may compile at once."""
+
+    __slots__ = ("gc", "compiles", "_gc_start", "_lock")
+
+    def __init__(self):
+        self.gc = (0, 0, 0, 0.0, 0.0, 0.0)
+        self.compiles = (0, 0, 0)
+        self._gc_start = None
+        self._lock = threading.Lock()
+
+    def on_gc(self, phase, info):
+        if phase == "start":
+            self._gc_start = _now()
+        elif self._gc_start is not None:    # hooked in mid-collection: skip
+            tally, gen = list(self.gc), info["generation"]
+            tally[gen] += 1
+            tally[3 + gen] += _now() - self._gc_start
+            self.gc = tuple(tally)
+
+    def count_compile(self, which: int):
+        with self._lock:
+            tally = list(self.compiles)
+            tally[which] += 1
+            self.compiles = tuple(tally)
+
+
+_process = _Process()
+gc.callbacks.append(_process.on_gc)
+
+# a kept interval's parts beside the phases of the run it starts with (each
+# of those under its span's name less the prefix): run()'s start to its first
+# phase, where a py_reader program waits for its next batch; the seconds
+# inside other programs' runs on this thread; and the rest, the caller's: its
+# wait for a result, a reader, a feeder
+RUN_ENTRY = "run_entry"
+OTHER_RUNS = "other_runs"
+OUTSIDE_RUN = "outside_run"
+PACE_RING = 32
+# runs of an entry between two samples of the thread's and the process's
+# usage: the two system calls are 12.3 of the 14.1 us that a run's spans cost
+# on the chip's host (815 us a run in the cell of the shortest one), so a
+# stall's usage is differenced over the interval and up to seven steady
+# steps before it (`usage_over_s`)
+USAGE_EVERY = 8
+STALLS_KEPT = 64
+STALL_LINES = 16               # lines on the logger, in a process
+
+
+def is_long(interval: float, median: float) -> bool:
+    """The one rule for a step interval that is kept, in seconds: it
+    exceeds the entry's median by more than a quarter of it and by more
+    than 5 ms. Steady intervals of one entry agree within 0.2% (PERF.md
+    section 2), so a quarter keeps far fewer than one in a thousand of a
+    window without stalls, and still keeps the smallest stall the records
+    hold, 39.5 ms on a step of 60.9 ms, and the ~100 ms late returns on the
+    longest step, 231 ms. Under 5 ms a step of microseconds (a small
+    program on the CPU) would be kept for the scheduler's jitter."""
+    excess = interval - median
+    return excess > 0.005 and excess > 0.25 * median
+
+
+class Pace:
+    """The pace of one compiled entry's steps, held by the entry and handed
+    to each `RunSpans` of it: `prev`, the last run if it may start an
+    interval (it ran to its end, on a steady step), a ring of the last
+    `PACE_RING` intervals and their `median`, None until the ring has been
+    full once and recomputed whenever it wraps, so that a pace that changes
+    for good is the new median within two rings. An interval is start of
+    run k to start of run k + 1 on one thread, less what lay inside other
+    programs' runs between them. `usage`: the last `_sample`, taken at the
+    start of every `USAGE_EVERY`-th run (`until` counts down to it)."""
+
+    __slots__ = ("prev", "ring", "at", "median", "usage", "until")
+
+    def __init__(self):
+        self.prev = self.median = self.usage = None
+        self.ring = [0.0] * PACE_RING
+        self.at = self.until = 0
+
+
+def stall_record(prev: "RunSpans", cur: "RunSpans", median: float,
+                 before: tuple, after: tuple) -> dict:
+    """What is kept of the interval from the start of `prev` to the start of
+    `cur`, two consecutive runs of one entry on one thread: its parts, which
+    sum to it (`parts_s`, the largest named in `where`), the collections and
+    compiles inside it, and what the thread and the process used up from
+    `before`, the entry's last `_sample` (at the start of `prev` or of one
+    of the seven runs before it), to `after`, one taken now: `usage_over_s`
+    of wall, the interval and those steady steps. Wall far above
+    `thread_cpu_s` with involuntary switches: the thread was taken off the
+    CPU; with voluntary ones: it was blocked (a lock, a file, the runtime's
+    queue); CPU near wall: the process was working, and a collection or a
+    compile says so itself; major faults: paging; `process_cpu_s` far above
+    the thread's: another thread of ours was busy. `step` is what the
+    `paddle_tpu:run` span of `prev` carries in a trace; `start` and `end`
+    are on `time.perf_counter()`."""
+    interval = cur._t_run - prev._t_run
+    run_s = prev._t_end - prev._t_run
+    others = cur._in_runs - prev._in_runs - run_s
+    phases = prev._intervals(prev._t_end)
+    parts = {RUN_ENTRY:
+             (phases[0][1] if phases else prev._t_end) - prev._t_run}
+    parts.update((which[0].split(":")[1], e - s) for which, s, e in phases)
+    parts[OTHER_RUNS] = others
+    parts[OUTSIDE_RUN] = interval - run_s - others
+    a, b = before[2], after[2]
+    if a is None or b is None or before[1] != after[1]:
+        thread = dict.fromkeys(("thread_cpu_s", "voluntary_switches",
+                                "involuntary_switches", "major_faults",
+                                "minor_faults"))
+    else:
+        thread = {
+            "thread_cpu_s": ((b.ru_utime - a.ru_utime)
+                             + (b.ru_stime - a.ru_stime)),
+            "voluntary_switches": b.ru_nvcsw - a.ru_nvcsw,
+            "involuntary_switches": b.ru_nivcsw - a.ru_nivcsw,
+            "major_faults": b.ru_majflt - a.ru_majflt,
+            "minor_faults": b.ru_minflt - a.ru_minflt}
+    collected = [y - x for x, y in zip(prev._gc, cur._gc)]
+    compiles, hits, misses = (
+        y - x for x, y in zip(prev._compiles, cur._compiles))
+    return {"program_uid": prev.program_uid, "source": prev.source,
+            "step": prev.step, "start": prev._t_run, "end": cur._t_run,
+            "interval_s": interval, "median_s": median,
+            "where": max(parts, key=parts.get), "parts_s": parts,
+            "usage_over_s": after[0] - before[0], **thread,
+            "process_cpu_s": after[3] - before[3],
+            "gc_collections": collected[:3], "gc_s": collected[3:],
+            "compiles": compiles, "cache_hits": hits,
+            "cache_misses": misses}
+
+
+def stall_line(record: dict) -> str:
+    """A stall's record on one line, every number in it, milliseconds, the
+    parts from the largest down to 0.05 ms: the logger's,
+    `tools/telemetry_dump.py`'s and the benchmark's."""
+    def ms(seconds):
+        return "n/a" if seconds is None else f"{seconds * 1e3:.1f}"
+
+    def n(count, sign=""):
+        return "n/a" if count is None else f"{sign}{count}"
+
+    said = {OUTSIDE_RUN: "{} outside run()",
+            OTHER_RUNS: "{} inside other programs' runs"}
+    parts = sorted(((name, seconds)
+                    for name, seconds in record["parts_s"].items()
+                    if seconds >= 5e-5), key=lambda kv: -kv[1])
+    return (
+        f"paddle_tpu: step interval {ms(record['interval_s'])} ms at run "
+        f"{record['step']} of program {record['program_uid']} (median "
+        f"{ms(record['median_s'])}): "
+        + ", ".join(said.get(name, name + " {}").format(ms(seconds))
+                    for name, seconds in parts)
+        + f"; over {ms(record['usage_over_s'])} ms thread CPU "
+        f"{ms(record['thread_cpu_s'])} ms, process CPU "
+        f"{ms(record['process_cpu_s'])} ms; switches "
+        f"{n(record['voluntary_switches'], '+')} voluntary "
+        f"{n(record['involuntary_switches'], '+')} involuntary; faults "
+        f"{n(record['major_faults'])} major {n(record['minor_faults'])} "
+        f"minor; gc {'+'.join(map(str, record['gc_collections']))} by "
+        f"generation ({ms(sum(record['gc_s']))} ms); compiles "
+        f"{record['compiles']}, cache hits {record['cache_hits']} misses "
+        f"{record['cache_misses']}")
+
+
 class RunSpans:
     """The host spans of one `PreparedProgram.run` (both executors' one).
 
@@ -351,27 +564,67 @@ class RunSpans:
     step does that, notes itself as this thread's current run and, in
     `event`, the compile event of the entry it calls (for a compile that
     jax reports inside it, `_taker`), and reads `time.perf_counter()` once
-    at each boundary into a slot of its own; it keeps nothing. A
+    at each boundary, and at its end, into a slot of its own. A
     `TraceAnnotation` costs one atomic check while no profile is taken.
+
+    Given the `pace` of the entry it is about to call (`Pace`; None where the
+    handle has bound none yet), it also reads `_process`'s tallies at its
+    start and closes the interval that the entry's last run opened: into
+    the ring and, if `is_long`, into the observatory (`stall_record`); every
+    `USAGE_EVERY`-th run of the entry then samples this thread's usage and
+    the process's CPU seconds (`_sample`). A steady step keeps nothing but
+    that float.
 
     A run that binds, or during which jax reports a compile, is a first run
     (`keep`): at exit the same times go to the observatory as `Phase`s, the
-    run and its phases. With the `observe` flag on they also fill a
-    `StepStats`. Both after the run span has closed, unless the body
-    raised."""
+    run and its phases, and it opens no interval. With the `observe` flag
+    on they also fill a `StepStats`. Both after the run span has closed,
+    unless the body raised."""
 
     __slots__ = ("observing", "program_uid", "source", "step", "which",
-                 "event", "keep", "eager", "_run", "_child", "_t_run"
-                 ) + tuple(w[2] for w in RUN_PHASES)
+                 "event", "keep", "eager", "pace", "_run", "_child",
+                 "_t_run", "_t_end", "_thread", "_gc", "_compiles",
+                 "_in_runs") + tuple(w[2] for w in RUN_PHASES)
 
-    def __init__(self, program_uid: int, source: str, step: int):
+    def __init__(self, program_uid: int, source: str, step: int, pace=None):
         self.observing = _flags.get_flag("observe")
         self.program_uid, self.source, self.step = program_uid, source, step
         self.which = self._child = self.event = self.eager = None
         self.keep = False
+        self.pace = pace
         self._t_run = _now()
+        if pace is not None:
+            self._thread = threading.get_ident()
+            self._gc, self._compiles = _process.gc, _process.compiles
+            self._in_runs = _building.run_s
+            prev, pace.prev = pace.prev, None
+            if prev is not None and prev._thread == self._thread:
+                self._close_interval(pace, prev)
+            if pace.until:
+                pace.until -= 1
+            else:
+                pace.until = USAGE_EVERY - 1
+                pace.usage = _sample(self._t_run)
         self._run = span(RUN, step=step, program=program_uid, source=source)
         _building.run = self
+
+    def _close_interval(self, pace, prev):
+        """The interval `prev` opened ends at this run's start."""
+        own = self._t_run - prev._t_run - (
+            self._in_runs - prev._in_runs - (prev._t_end - prev._t_run))
+        median = pace.median
+        if median is not None and is_long(own, median):
+            after = _sample(self._t_run)
+            _observatory.note_stall(stall_record(
+                prev, self, median, pace.usage, after))
+            pace.usage, pace.until = after, USAGE_EVERY
+        at = pace.at
+        pace.ring[at] = own
+        if at + 1 == PACE_RING:
+            pace.at = 0
+            pace.median = statistics.median(pace.ring)
+        else:
+            pace.at = at + 1
 
     def __enter__(self):
         return self
@@ -402,8 +655,11 @@ class RunSpans:
         self._run.__exit__(None, None, None)
         # the compile event this run built stops taking stage durations
         _building.run = _building.event = None
+        end = self._t_end = _now()
+        _building.run_s += end - self._t_run
+        if self.pace is not None and not self.keep and exc_type is None:
+            self.pace.prev = self
         if (self.keep or self.observing) and exc_type is None:
-            end = _now()
             phases = self._intervals(end)
             if self.keep:
                 _observatory.note_run(self, phases, end)
@@ -556,6 +812,10 @@ class RecompilationObservatory:
         self._events: deque = deque(maxlen=capacity)
         # the set-up store: `Phase`s of program builds and of first runs
         self._phases: deque = deque(maxlen=phase_capacity)
+        # the step intervals that ran long (`stall_record`), newest kept,
+        # and how many there were: the first `STALL_LINES` are logged
+        self._stalls: deque = deque(maxlen=STALLS_KEPT)
+        self._stalls_noted = 0
         self._lock = threading.Lock()
         # program uid -> {field of CAUSE_OF_FIELD: the values built with}
         self._seen: Dict[int, Dict[str, set]] = {}
@@ -634,6 +894,23 @@ class RecompilationObservatory:
         with self._lock:
             return list(self._phases)
 
+    def note_stall(self, record: dict):
+        """A step interval that ran long: kept here, in the flight ring (the
+        dump of a process that is killed holds it) and, the first
+        `STALL_LINES` of a process, as a line on the logger."""
+        with self._lock:
+            self._stalls.append(record)
+            self._stalls_noted += 1
+            noted = self._stalls_noted
+        _flight.note("stall", **record)
+        if noted <= STALL_LINES:
+            logger.warning(stall_line(record))
+
+    def stalls(self) -> List[dict]:
+        """The last `STALLS_KEPT` step intervals that ran long."""
+        with self._lock:
+            return list(self._stalls)
+
     def latest(self, program_uid: int) -> Optional[RecompileEvent]:
         """The program's most recent compile event, if the ring holds one."""
         with self._lock:
@@ -675,12 +952,15 @@ class RecompilationObservatory:
         """What `observe.summary()` / `/status` carry under `recompiles`."""
         return {"counts": self.counts(),
                 "events": [e.as_dict() for e in self.events()],
-                "phases": [p.as_dict() for p in self.phases()]}
+                "phases": [p.as_dict() for p in self.phases()],
+                "stalls": self.stalls()}
 
     def clear(self):
         with self._lock:
             self._events.clear()
             self._phases.clear()
+            self._stalls.clear()
+            self._stalls_noted = 0
             self._seen.clear()
 
 
@@ -722,6 +1002,8 @@ def _taker():
 def _on_duration(event, seconds, fun_name=None, **_):
     stage = _STAGES.get(event)
     if stage is not None:
+        if stage == "backend":
+            _process.count_compile(0)
         taker = _taker()
         if taker is not None:
             taker.add_stage(stage, seconds, fun_name)
@@ -730,6 +1012,7 @@ def _on_duration(event, seconds, fun_name=None, **_):
 def _on_event(event, **_):
     attr = _CACHE_EVENTS.get(event)
     if attr is not None:
+        _process.count_compile(1 if attr == "cache_hits" else 2)
         taker = _taker()
         if taker is not None:
             setattr(taker, attr, getattr(taker, attr) + 1)

@@ -97,6 +97,7 @@ def print_status_table(doc):
               f"{e.get('cache_hits', 0)} hit(s), "
               f"{e.get('cache_misses', 0)} miss(es)")
     print_setup_timeline(doc["recompiles"])
+    print_stalls(doc["recompiles"])
     mem = doc.get("memory") or {}
     if mem.get("programs"):
         print(f"memory: peak est {mem['estimate_peak_bytes'] / 1e6:.2f} MB "
@@ -146,6 +147,19 @@ def print_setup_timeline(recompiles):
                           f"{inside[-1][1] - t0:9.4f}  {e['cause']} {stage} "
                           f"{sum(t - s for s, t in inside):.4f} s in "
                           f"{len(inside)} interval(s)")
+
+
+def print_stalls(recompiles):
+    """The step intervals that ran long (`steplog.stall_record`), one line
+    each in the form of the process's own log line; nothing where the
+    document has none, or comes from a process that kept none."""
+    stalls = recompiles.get("stalls")
+    if not stalls:
+        return
+    from paddle_tpu.observe.steplog import stall_line
+    print(f"step intervals that ran long ({len(stalls)} kept):")
+    for record in stalls:
+        print("  " + stall_line(record))
 
 
 def _fetch(url: str, timeout: float = 10.0):
