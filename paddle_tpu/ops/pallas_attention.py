@@ -100,7 +100,10 @@ are interior, 15 of 45 under a window of 1024 in tiles of 512, 18 of 30
 under 2048 over 4096. Leaving out a select whose predicate is false in every
 element changes no bit. A kept set is data and masks every tile of its call;
 the causal mask stays on that call's edge tiles, so its meaning does not
-rest on the set lying under the diagonal. A plain causal call keeps the mask
+rest on the set lying under the diagonal. Its int8 tile is fetched for the
+steps that compute one and for no other: on a step above the diagonal the
+set's index map stays on the live tile beside it (`_kept_spec`), where a
+fetch would have nothing to hide behind. A plain causal call keeps the mask
 on every live tile, the instructions it was: at its 1024 x 1024 tiles the
 pass hides behind the products and a second body is a cost
 (`_interior_apart`); so does every call whose row is one K block.
@@ -1052,13 +1055,31 @@ def _kept_vmem(BQ, BK):
     return (2 + 4) * BQ * BK
 
 
-def _kept_spec(H, BQ, BK, at_q, at_k):
+def _kept_spec(H, BQ, BK, at_q, at_k, q_inner=False):
     """The kept set's block of a grid step: the (q-block, k-block) tile of
-    the row's batch (grid axis 0 is the (batch x head) row)."""
+    the row's batch (grid axis 0 is the (batch x head) row). A step wholly
+    above the diagonal computes nothing and has no products to hide a fetch
+    behind: it stays on the live tile beside it in grid order, the row's
+    last where the k blocks run innermost and the column's first where the
+    q blocks do (`q_inner`), so nothing of the set is fetched for it.
+    Measured (TPU v5 lite, [32, 8192, 128] bf16, 1024 x 1024 tiles, 28 of a
+    head's 64 steps dead; chip runs, PR 68): with the set's own tile on
+    every step the forward is 6.477 ms a call and the fused backward 11.029
+    in `keye_vl_2_30b_a3b.s8192`'s trace, held 5.537 and 9.799; alone and
+    chained on the host's clock (`tools/kept_set_probe.py`) 6.31 / 11.21
+    against 5.43 / 9.96, and 5.33 / 9.77 without a set. Applying the tile
+    costs nothing (5.15 / 9.93 applied from one tile never fetched again)."""
     from jax.experimental import pallas as pl
 
-    return pl.BlockSpec(
-        (1, BQ, BK), lambda *g: (g[0] // H, at_q(*g)[1], at_k(*g)[1]))
+    def tile(*g):
+        qi, kj = at_q(*g)[1], at_k(*g)[1]
+        mx, mn, _ = _int_ops(qi)
+        if q_inner:
+            qi = mx(qi, _first_q(kj, BQ, BK))
+        else:
+            kj = mn(kj, _last_k(qi, BQ, BK))
+        return g[0] // H, qi, kj
+    return pl.BlockSpec((1, BQ, BK), tile)
 
 
 def _takes_kept(kernel, at):
@@ -1418,7 +1439,7 @@ def _flash_bwd_fused(args, rows, plan, attrs):
     kept = _kept_of(args)
     if kept is not None:
         kernel = _takes_kept(kernel, 7)
-        in_specs.append(_kept_spec(rows.H, BQ, BK, at_q, at_k))
+        in_specs.append(_kept_spec(rows.H, BQ, BK, at_q, at_k, q_inner=True))
     scratch = [pltpu.VMEM((BK, lanes), jnp.float32),
                pltpu.VMEM((BK, lanes_v), jnp.float32)]
     if T == BK:
@@ -1491,7 +1512,7 @@ def _flash_bwd_split(args, rows, plan, attrs):
     in_specs, at_q, at_k = _bwd_specs(rows, BQ, BK, lanes, lanes_v, q_axis=2,
                                       band=band)
     if kept is not None:
-        in_specs.append(_kept_spec(rows.H, BQ, BK, at_q, at_k))
+        in_specs.append(_kept_spec(rows.H, BQ, BK, at_q, at_k, q_inner=True))
     dk, dv = pl.pallas_call(
         kernel_of(_flash_dkv_kernel,
                   **({} if window is None else {"q_tiles": T // BQ})),

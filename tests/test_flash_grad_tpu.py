@@ -194,7 +194,8 @@ def test_unmasked_interior_is_bitwise_the_mask_on_every_tile(monkeypatch,
     and dV of the kernels that run interior tiles without the causal mask
     are the bits of the same kernels with the mask on every live tile (the
     interior predicate answering "edge" always), under a window and under a
-    kept set."""
+    kept set (whose tile a step above the diagonal does not fetch:
+    `_kept_spec`)."""
     shape, Dv, window, kept = INTERIOR[case]
     B, H, T, D = shape
     assert pallas_attention.interior_tiles(T, window) > 0
@@ -225,6 +226,49 @@ def test_unmasked_interior_is_bitwise_the_mask_on_every_tile(monkeypatch,
     got = compiled_run()
     monkeypatch.setattr(pallas_attention, "_causal_interior",
                         lambda *a, **kw: False)
+    want = compiled_run()
+    for a, b, name in zip(got, want, ("Out", "Lse", "dQ", "dK", "dV")):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a).all() and np.abs(a).max() > 0, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+@pytest.mark.parametrize("plan", ["fused", "split"])
+def test_a_kept_set_held_on_dead_steps_gives_the_bits_of_one_fetched_there(
+        monkeypatch, plan):
+    """`keye_vl_2_30b_a3b.s8192`'s call, `[1, 32, 8192, 128]` bf16 under an
+    int8 `[1, 8192, 8192]`, by Mosaic's own arithmetic: `Out`, `Lse`, dQ, dK
+    and dV of the kernels whose kept set stays on the live tile beside a
+    step above the diagonal (28 of a head's 64 steps) are the bits of the
+    same kernels with the set's own tile fetched on every step."""
+    from jax.experimental import pallas as pl
+    shape, Dv, _, _ = INTERIOR["8192x128_kept"]
+    B, H, T, D = shape
+    if plan == "split":
+        monkeypatch.setattr(pallas_attention, "_bwd_plan",
+                            lambda *a: "split")
+    rng = np.random.RandomState(68)
+    q, k, v, g = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+                  for _ in range(4))
+    kept = jnp.asarray(np.tril(rng.rand(B, T, T) < 0.3)
+                       | np.eye(T, dtype=bool), jnp.int8)
+
+    def compiled_run():
+        """Traced afresh: the block spec in force is read."""
+        def run(q, k, v, g):
+            out, lse = pallas_attention._flash_forward(
+                q, k, v, True, D ** -0.5, kept=kept)
+            return (out, lse) + pallas_attention._flash_backward(
+                q, k, v, out, lse, g, True, D ** -0.5, 0.0, 0, kept=kept)
+        return jax.jit(run).lower(q, k, v, g).compile(
+            compiler_options=resolve_compiler_options("tpu"))(q, k, v, g)
+
+    def every_step(H, BQ, BK, at_q, at_k, q_inner=False):
+        return pl.BlockSpec((1, BQ, BK), lambda *g: (
+            g[0] // H, at_q(*g)[1], at_k(*g)[1]))
+
+    got = compiled_run()
+    monkeypatch.setattr(pallas_attention, "_kept_spec", every_step)
     want = compiled_run()
     for a, b, name in zip(got, want, ("Out", "Lse", "dQ", "dK", "dV")):
         a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)

@@ -212,6 +212,40 @@ def test_a_kept_set_of_all_ones_is_bitwise_the_causal_call(monkeypatch, plan):
         assert np.array_equal(got, want)
 
 
+@pytest.mark.parametrize("q_inner", [False, True], ids=["k_inner", "q_inner"])
+@pytest.mark.parametrize("seq,bq,bk", [(8192, 1024, 1024), (512, 128, 256),
+                                       (512, 256, 128)])
+def test_a_dead_step_fetches_no_tile_of_the_kept_set(seq, bq, bk, q_inner):
+    """The kept set's index map over a causal grid in the order its steps
+    run, k blocks innermost (the forward, dQ) or q blocks (the fused
+    backward, dK/dV): a live step reads its own tile, a step wholly above
+    the diagonal the tile of the live step beside it, so the block index
+    changes (a tile is fetched) once a live tile and never for a dead step:
+    32 x 36 times a call at the cell's 8192 tokens where the grid has 32 x
+    64 steps."""
+    heads, rows = 2, 4      # two batch rows of two heads
+    nq, nk = seq // bq, seq // bk
+    # grid axes of the q block and the k block; the inner one is axis 2
+    q_ax, k_ax = (2, 1) if q_inner else (1, 2)
+    spec = pa._kept_spec(heads, bq, bk, lambda *g: (g[0], g[q_ax], 0),
+                         lambda *g: (g[0], g[k_ax], 0), q_inner=q_inner)
+    outer, inner = (nk, nq) if q_inner else (nq, nk)
+    steps = [(r, a, b) for r in range(rows) for a in range(outer)
+             for b in range(inner)]
+    fetched, at, live = 0, None, 0
+    for g in steps:
+        block = tuple(int(i) for i in spec.index_map(*g))
+        qi, kj = g[q_ax], g[k_ax]
+        if pa._causal_live(qi, kj, bq, bk):
+            live += 1
+            assert block == (g[0] // heads, qi, kj)
+        fetched, at = fetched + (block != at), block
+    assert live == rows * sum(pa._last_k(qi, bq, bk) + 1 for qi in range(nq))
+    assert fetched == live < len(steps)
+    if seq == 8192:
+        assert (live, len(steps)) == (rows * 36, rows * 64)
+
+
 @pytest.mark.parametrize("why,kw", [
     ("not causal", dict(causal=False)),
     ("a window", dict(causal=True, window=64)),

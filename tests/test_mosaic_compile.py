@@ -563,6 +563,67 @@ def test_flash_kernels_without_a_window_lower_to_the_text_they_did(
         assert _lowered_digest(bwd)[:32] == digest, plan
 
 
+# the calls no case of `PLAIN_FLASH` lowers, digests of the forward and of the
+# backward by `_lowered_digest`, taken on the commit before PR 68 (3e18790),
+# which changed what a call under a kept set fetches: (shape, token-major,
+# causal, dropout, window). Token-major `[batch, seq, heads, head_dim]` with
+# the PRNG in the kernel, both transformer cells' four calls; head-major
+# under a window, Mellum2's three layers and Trinity-Mini's four
+OTHER_FLASH = {
+    "seq256_full": ((96, 256, 8, 64), True, False, 0.1, None,
+                    "f46f957f51c9475aeb008882eca387de",
+                    "def484d5d1c1d70344473516eedef273"),
+    "seq256_causal": ((96, 256, 8, 64), True, True, 0.1, None,
+                      "595fe101fc5f2e9e7e4f2196d38728e4",
+                      "faaf830e1fe541ff6efde44b000ae283"),
+    "seq2048_full": ((12, 2048, 8, 64), True, False, 0.1, None,
+                     "14876bdca8eae6430afa8cbf94954504",
+                     "a31970ca9cc3e9cee1b00bd065c1160f"),
+    "seq2048_causal": ((12, 2048, 8, 64), True, True, 0.1, None,
+                       "9fb4c623f0c3fe33b4d5391de5af1c7b",
+                       "eef4d3b2f61fb24b9f527fa9a4d4eb0c"),
+    "mellum2_8192_w1024": ((1, 32, 8192, 128), False, True, 0.0, 1024,
+                           "748d4ea7cd6cdbeef0e64b0111842fea",
+                           "5c3fccd69cf6335452d419a7b5f659ae"),
+    "trinity_4096_w2048": ((1, 32, 4096, 128), False, True, 0.0, 2048,
+                           "7d2f4d37cf3224658c1b0c8771bca2ba",
+                           "3223e3e31a6ad3dfa18a47a85de6ff09"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(OTHER_FLASH))
+def test_token_major_and_windowed_flash_kernels_lower_to_the_text_they_did(
+        one_chip, monkeypatch, case):
+    """A call without a kept set is the instructions it was where its
+    blocks hold several heads in their lanes (`_each_head`'s groups, the
+    one-pass forward and the fused backward with dropout, as both
+    transformer cells call them) and where its grid follows a band (the
+    streaming forward and the fused backward with clamped index maps): no
+    case of `PLAIN_FLASH` is token-major or windowed, and the calls under a
+    kept set share `_forward`, `_bwd_specs` and the kernels' bodies with
+    these."""
+    from paddle_tpu.ops import pallas_attention as pa
+    monkeypatch.setattr(_kernels, "interpret", lambda: False)
+    shape, token_major, causal, rate, window, fwd_digest, bwd_digest = \
+        OTHER_FLASH[case]
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    B, H, T, D = pa._shape_of(arg(shape), token_major)
+    q, seed = arg(shape), arg((), jnp.int32)
+    # without dropout the op hands the kernels the constant 0 for a seed
+    fwd = jax.jit(lambda q, k, v, seed: pa._flash_forward(
+        q, k, v, causal, D ** -0.5, rate, seed if rate else 0, window,
+        token_major)).lower(q, q, q, seed)
+    assert _lowered_digest(fwd)[:32] == fwd_digest
+    bwd = jax.jit(lambda q, k, v, o, lse, g, seed: pa._flash_backward(
+        q, k, v, o, lse, g, causal, D ** -0.5, rate, seed if rate else 0,
+        window, token_major)).lower(
+            q, q, q, q, arg((B * H, 1, T), jnp.float32), q, seed)
+    assert _lowered_digest(bwd)[:32] == bwd_digest
+
+
 @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
 @pytest.mark.parametrize("shape", [(96, 256, 8, 64), (12, 2048, 8, 64)],
                          ids=["seq256", "seq2048"])
