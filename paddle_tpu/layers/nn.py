@@ -1432,14 +1432,19 @@ def rotary_embedding(input, theta=10000.0, rotary_dim=None,
 
 
 def causal_conv1d(input, kernel_size, param_attr=None, name=None,
-                  bias_attr=None):
+                  bias_attr=None, activation="silu"):
     """Depthwise causal convolution over time on `[batch, seq, channels]`,
-    then silu: output t reads inputs t - kernel_size + 1 .. t of its own
-    channel. The weight is `[channels, kernel_size]`, float32; `bias_attr`
-    gives the op a bias `[channels]` (float32, starts at 0) that is added
-    before the silu, none without it. `input` in any float dtype (bf16 under
-    AMP), the taps' sum, the bias and the silu float32, the result in
-    `input`'s dtype and shape."""
+    then `activation`: output t reads inputs t - kernel_size + 1 .. t of its
+    own channel. `activation` is "silu" (what every scan's and delta rule's
+    convolution takes) or None: the taps' sum as it is (LFM2's short
+    convolution, whose gates stand outside it). The weight is `[channels,
+    kernel_size]`, float32; `bias_attr` gives the op a bias `[channels]`
+    (float32, starts at 0) that is added before the activation, none without
+    it. `input` in any float dtype (bf16 under AMP), the taps' sum, the bias
+    and the silu float32, the result in `input`'s dtype and shape."""
+    if activation not in ("silu", None):
+        raise ValueError(f"activation is \"silu\" or None, got "
+                         f"{activation!r}")
     helper = LayerHelper("causal_conv1d", **locals())
     w = helper.create_parameter(param_attr, [input.shape[-1], kernel_size],
                                 "float32")
@@ -1452,7 +1457,7 @@ def causal_conv1d(input, kernel_size, param_attr=None, name=None,
     out = helper.create_variable_for_type_inference(input.dtype)
     helper.append_op("causal_conv1d", inputs=inputs,
                      outputs={"Out": [out.name]},
-                     attrs={"activation": "silu"})
+                     attrs={"activation": activation or ""})
     return out
 
 

@@ -39,7 +39,13 @@ heads at chunk 256 beside unrotated attention at a published softmax scale, a
 gated feed-forward in every layer, scalar multipliers on the embedding, the
 residual branches and the logits (the first model whose head is its
 embedding's table, and the first whose every layer is a mixer AND a
-feed-forward under a list of layer types with Mamba in it)."""
+feed-forward under a list of layer types with Mamba in it), and LFM2-MoE:
+gated short convolutions (three causal taps a channel with NO activation,
+between two gates that are projections of the same input) in three layers of
+four beside QK-normed rotary attention, routed experts with no shared expert
+under a sigmoid router whose renormalisation publishes its epsilon, a tied
+table (the first model most of whose mixers hold neither attention nor a
+recurrence)."""
 
 from . import mnist  # noqa: F401
 from . import resnet  # noqa: F401
@@ -61,3 +67,4 @@ from . import nemotron_h  # noqa: F401
 from . import ling3  # noqa: F401
 from . import olmo_hybrid  # noqa: F401
 from . import granite_hybrid  # noqa: F401
+from . import lfm2_moe  # noqa: F401

@@ -1,23 +1,24 @@
 """What two or more of the decoder models (`olmoe`, `ouro`, `qwen3_next`,
 `kanana2`, `mellum2`, `trinity`, `keye_vl2`, `nemotron_h`, `ling3`,
-`olmo_hybrid`, `granite_hybrid`) build the same way, written once: named
-weights and projections, the token feeds, the heads-first reshape and its
-inverse, a key-value head serving its group of query heads, the gated MLP,
-the routed half of an expert layer, the period of layer kinds, the
+`olmo_hybrid`, `granite_hybrid`, `lfm2_moe`) build the same way, written
+once: named weights and projections, the token feeds, the heads-first reshape
+and its inverse, a key-value head serving its group of query heads, the gated
+MLP, the routed half of an expert layer, the period of layer kinds, the
 grouped-query block, q and k normed over the whole projection before the
 heads split (`olmoe`, `olmo_hybrid`), a gated-delta-rule layer from its
 convolution to its gated norm (`qwen3_next`, `olmo_hybrid`), a Mamba-2 mixer
 from its in projection to its out projection and grouped-query attention
-without positions (`nemotron_h`, `granite_hybrid`), and the losses. `tied_head` has one caller and is here
-because it is `embed`'s other half: the head that reads the table `embed`
-made. Nothing here asks which model calls it (latent attention, built by
-`kanana2` and `ling3`, takes the one thing they differ in, a head-wise gate,
-as a parameter; the Mamba-2 mixer and the unrotated attention take their out
-projection's initialiser, which Nemotron-H makes smaller, and the attention
-its softmax scale, which Granite 4.0-H publishes): a model whose form differs keeps its own.
-Each model keeps its mixer's composition, its layer loop, its defaults and
-`build`. Built from `fluid.layers` only; parameter names are the
-caller's.
+without positions (`nemotron_h`, `granite_hybrid`), `embed`'s other half, the
+head that reads the table `embed` made (`granite_hybrid`, `lfm2_moe`), and
+the losses. Nothing here asks which model calls it (latent attention, built
+by `kanana2` and `ling3`, takes the one thing they differ in, a head-wise
+gate, as a parameter; the Mamba-2 mixer and the unrotated attention take
+their out projection's initialiser, which Nemotron-H makes smaller, and the
+attention its softmax scale, which Granite 4.0-H publishes; `noaux_router`
+the epsilon of its renormalisation, which LFM2 publishes): a model whose form
+differs keeps its own. Each model keeps its mixer's composition, its layer
+loop, its defaults and `build`. Built from `fluid.layers` only; parameter
+names are the caller's.
 """
 
 from __future__ import annotations
@@ -148,15 +149,16 @@ def routed_experts(x, seq_len, n_expert, top_k, d_expert, name, router=None,
 
 
 def noaux_router(name, bias_update_rate, scaling_factor, n_group=None,
-                 topk_group=None):
+                 topk_group=None, norm_eps=1e-20):
     """`router` of `routed_experts` for DeepSeek-V3's `noaux_tc` routing:
     sigmoid scores, the choice moved by a selection bias (`name.router.bias`)
-    that the step itself rewrites, the chosen scores renormalised and
-    scaled; among the experts of the `topk_group` best of `n_group` groups
-    where those are given (one group otherwise)."""
+    that the step itself rewrites, the chosen scores renormalised (their sum
+    plus `norm_eps`: LFM2 publishes 1e-6) and scaled; among the experts of
+    the `topk_group` best of `n_group` groups where those are given (one
+    group otherwise)."""
     return dict(norm_topk_prob=True, score_func="sigmoid",
                 bias_attr=ParamAttr(name=name + ".router.bias"),
-                bias_update_rate=bias_update_rate, norm_eps=1e-20,
+                bias_update_rate=bias_update_rate, norm_eps=norm_eps,
                 scaling_factor=scaling_factor, n_group=n_group,
                 topk_group=topk_group)
 

@@ -100,13 +100,26 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     gate on the context). `moe_router_groups` and `moe_router_groups_kept`:
     a group-limited router's `n_group` and `topk_group` (absent for one
     group).
+    `short_conv`: a `causal_conv1d` op with NO activation under a
+    `name_scope` whose layer holds no `ssd_scan`, `gated_delta_rule` or
+    `kda_delta_rule` (LFM2's gated short convolution: the mixer itself, not
+    the convolution in front of a scan or a delta rule, which takes silu),
+    with `short_conv_layers`, their count again as a flat number,
+    `short_conv_taps`, the weight's taps a channel, and `short_conv_gates`,
+    the `elementwise_mul`s under those scopes with an operand that is a
+    `slice` of a projection (two a layer: the gate before the convolution and
+    the one after it); a program that has them reports its softmax-attention
+    layers' `attention_kv_group` as one with scans does (`causal_conv_plan`,
+    the form the convolution ran in, is noted by the op's rule on the same
+    event).
     (`moe_row_buffer_rows`, the rows of the expert layer's layout, follows
     the batch: `moe_dispatch`'s rule notes it on the same event under the
     trace, `LoweringContext.note`.)"""
     block = program.global_block()
     kinds = {"linear_attention": 0, "full_attention": 0,
              "latent_attention": 0, "window_attention": 0,
-             "sparse_attention": 0, "state_space": 0, "kda": 0}
+             "sparse_attention": 0, "state_space": 0, "kda": 0,
+             "short_conv": 0}
     out: Dict[str, object] = {}
     copies: Dict[str, str] = {}     # an `assign` op's result -> what it copied
     biases = []                     # the routers' selection biases
@@ -118,11 +131,28 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     normed, added = set(), set()    # `rms_norm` results, residual addends
     scaled = set()                  # results of `scale` ops under a scope
     tables, multiplied = set(), set()   # `lookup_table`s' W, `matmul`s' Y
+    bare_convs, recurrent = [], set()   # (layer, taps) of the convolutions
+    #                                     without an activation, a layer the
+    #                                     first part of a scope; the layers
+    #                                     of scans and delta rules
+    sliced, products = set(), []        # `slice` results; (layer, operands)
+    #                                     of the `elementwise_mul`s
     for op in block.ops:
         if op.attrs.get("__role__") is not None:
             continue
         scope = op.attrs.get(ir.NAME_SCOPE_ATTR)
-        if op.type == "gated_delta_rule":
+        layer = scope.split("/")[0] if scope else None
+        if op.type in ("gated_delta_rule", "ssd_scan", "kda_delta_rule"):
+            recurrent.add(layer)
+        if op.type == "causal_conv1d" and not op.attrs.get("activation",
+                                                           "silu"):
+            bare_convs.append((layer,
+                               block.var(op.input("W")[0]).shape[-1]))
+        elif op.type == "slice":
+            sliced.update(op.output_arg_names)
+        elif op.type == "elementwise_mul":
+            products.append((layer, op.input_arg_names))
+        elif op.type == "gated_delta_rule":
             kinds["linear_attention"] += 1
             out["linear_attention_head_dims"] = [
                 block.var(op.input(slot)[0]).shape[-1] for slot in "KV"]
@@ -196,16 +226,25 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
         elif op.type == "moe_dispatch":
             out["moe_experts_held"] = op.attrs.get(
                 "experts_held", out.get("moe_experts_routed"))
-    if any(kinds.values()):
-        out["layer_kinds"] = {k: n for k, n in kinds.items() if n}
     if kinds["window_attention"]:
         out["attention_window_layers"] = kinds["window_attention"]
     if kinds["sparse_attention"]:
         out["dsa_layers"] = kinds["sparse_attention"]
     if kinds["kda"]:
         out["kda_layers"] = kinds["kda"]
+    short = dict(c for c in bare_convs if c[0] not in recurrent)
+    kinds["short_conv"] = len(short)
+    if any(kinds.values()):
+        out["layer_kinds"] = {k: n for k, n in kinds.items() if n}
+    if short:
+        out["short_conv_layers"] = len(short)
+        out["short_conv_taps"] = max(short.values())
+        out["short_conv_gates"] = sum(
+            1 for layer, operands in products
+            if layer in short and sliced.intersection(operands))
     if kinds["state_space"]:
         out["state_space_layers"] = kinds["state_space"]
+    if kinds["state_space"] or kinds["short_conv"]:
         group = max([_expanded_by(block, k) for k in full_keys], default=1)
         if group > 1:
             out["attention_kv_group"] = group

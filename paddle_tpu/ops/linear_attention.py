@@ -246,9 +246,12 @@ def _causal_conv1d(ctx, X, W, Bias=None):
     (zeros before t = 0, so output t reads inputs <= t only), plus `Bias`
     [C] where given, then silu unless `activation` is empty. Float32 sums,
     the input's dtype out. One pass over X as `causal_conv_fwd` where
-    `_conv_plan` gives the kernels."""
+    `_conv_plan` gives the kernels; which form ran goes on the compile event
+    as `causal_conv_plan` ("kernel" or "xla")."""
     silu = ctx.attr("activation", "silu") == "silu"
-    if _conv_kernels_run(X.shape[1], X.shape[2], W.shape[1]):
+    kernels = _conv_kernels_run(X.shape[1], X.shape[2], W.shape[1])
+    ctx.note(causal_conv_plan="kernel" if kernels else "xla")
+    if kernels:
         return {"Out": _conv_forward(X, W, silu, Bias)}
     return {"Out": _conv_xla(X, W, silu, Bias)}
 

@@ -12,7 +12,10 @@ census of a program with `gated_delta_rule` ops the key
 `linear_attention_head_dims`; `granite_hybrid`'s on PR 65, which added the
 model, moved the Mamba-2 mixer from `nemotron_h.py` to `_decoder.py` with
 Nemotron-H's digest unmoved, and gave the census of a program with `ssd_scan`
-ops its groups, heads a group and chunk) at the models' own tests' tiny sizes, forward,
+ops its groups, heads a group and chunk; `lfm2_moe`'s on PR 69, which added
+the model, `layers.causal_conv1d(activation=)`, `noaux_router(norm_eps=)` and
+the census kind `short_conv`, with every other digest and census unmoved) at
+the models' own tests' tiny sizes, forward,
 backward and Adam; after a deliberate change to a model take them again with
 `program_digest(*build_program(model)[:2])` and
 `census.program_detail(build_program(model)[0])`.
@@ -31,6 +34,7 @@ from paddle_tpu.observe import census
 
 from test_granite_hybrid import TINY as GRANITE_HYBRID_TINY
 from test_kanana2 import TINY as KANANA2_TINY
+from test_lfm2_moe import TINY as LFM2_MOE_TINY
 from test_keye_vl2 import TINY as KEYE_VL2_TINY
 from test_mellum2 import TINY as MELLUM2_TINY
 from test_nemotron_h import TINY as NEMOTRON_H_TINY
@@ -43,7 +47,7 @@ from test_trinity import TINY as TRINITY_TINY
 HERE = os.path.dirname(os.path.abspath(__file__))
 PACKAGE = os.path.join(HERE, "..", "paddle_tpu")
 
-SIZES = {"granite_hybrid": GRANITE_HYBRID_TINY,
+SIZES = {"granite_hybrid": GRANITE_HYBRID_TINY, "lfm2_moe": LFM2_MOE_TINY,
          "olmoe": OLMOE_TINY, "olmo_hybrid": OLMO_HYBRID_TINY,
          "ouro": OURO_TINY,
          "qwen3_next": QWEN3_NEXT_TINY, "kanana2": KANANA2_TINY,
@@ -94,6 +98,8 @@ DIGESTS = {
                      "8a05a524c1f8174aa3de0527033685df"),
     "keye_vl2": (655, "0649d664f592fadd5d847958da86b217"
                       "95f2689bda87d28ed6e4b2dfe0ed6e23"),
+    "lfm2_moe": (700, "232d480348c1f734b566184cf830617a"          # PR 69's own
+                      "648afc881d999dcea62b73969ab340ee"),
     "mellum2": (747, "63f94049afbd0dd88ed8281da85f666f"
                      "5379fe7da48e1c9c99f130000d3af739"),
     "nemotron_h": (957, "bb4a9beb840fe95d57a8b1bee2a34470"
@@ -131,6 +137,15 @@ CENSUS = {
         "moe_experts_routed": 16, "moe_experts_held": 4,
         "layer_kinds": {"sparse_attention": 3}, "dsa_layers": 3,
         "frozen_parameters": 15, "attention_rotary_layers": 3},
+    "lfm2_moe": {
+        "parameters": 53, "parameter_uses": 54, "grad_fanin_max": 2,
+        "short_conv_taps": 3, "moe_experts_routed": 16,
+        "moe_router_score": "sigmoid", "moe_experts_held": 4,
+        "layer_kinds": {"full_attention": 1, "short_conv": 4},
+        "short_conv_layers": 4, "short_conv_gates": 8,
+        "attention_kv_group": 2, "dense_ffn_layers": 1,
+        "moe_router_bias_updates": 4, "attention_rotary_layers": 1,
+        "tied_heads": 1},
     "mellum2": {
         "parameters": 51, "parameter_uses": 51, "grad_fanin_max": 1,
         "attention_window": 96, "attention_kv_group": 2,

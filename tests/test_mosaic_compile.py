@@ -1109,3 +1109,57 @@ def test_gated_silu_experts_step_lowers_as_it_did(one_chip, megablox):
         assert not moe._held_lane_major(jnp.zeros(shape)), shape
     lowered = _lowered_expert_step(moe, one_chip, 2048, 768, gated=True)
     assert _lowered_digest(lowered)[:32] == GATED_SILU_STEP
+
+
+# -- lfm2_8b_a1b.s4096 (PR 69) -------------------------------------------------------
+
+def test_short_conv_kernels_compile_at_the_cells_shape(one_chip, monkeypatch):
+    """`causal_conv_fwd` and `causal_conv_bwd` as `lfm2_8b_a1b.s4096` calls
+    them: the first published shape with THREE taps, no silu and no bias, X
+    and dOut bf16 `[1, 4096, 2048]`, W float32 `[2048, 3]`. One Mosaic custom
+    call each under the names the benchmark's pattern reads, eight channel
+    blocks of 256 by two time blocks of 2048."""
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    assert la._conv_plan(4096, 2048, 3) == "kernel"
+    assert la._conv_blocks(4096, 2048) == (2048, 256, 64)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x, w = arg((1, 4096, 2048), jnp.bfloat16), arg((2048, 3), jnp.float32)
+    fwd = jax.jit(lambda x, w: la._conv_forward(x, w, False)).lower(x, w)
+    (call,) = _custom_calls(fwd.compile(), "causal_conv_fwd")
+    assert "= bf16[1,4096,2048]{" in call and "tpu_custom_call" in call
+    bwd = jax.jit(lambda x, w, d: la._conv_backward(x, w, d, False)).lower(
+        x, w, x)
+    (call,) = _custom_calls(bwd.compile(), "causal_conv_bwd")
+    assert "= (bf16[1,4096,2048]{" in call and ", f32[3,2048]{" in call
+    # without silu the kernels are other kernels than the scans' convolution
+    silu = jax.jit(lambda x, w: la._conv_forward(x, w, True)).lower(x, w)
+    assert _lowered_digest(silu) != _lowered_digest(fwd)
+
+
+def test_experts_of_1792_compile_at_the_cells_shapes(one_chip, megablox):
+    """An expert layer of `lfm2_8b_a1b.s4096`: the first gated-silu experts
+    whose `[2048, 1792]` block `_GMM_BLOCK` does not hold. `gate` and `up`
+    `[8, 2048, 1792]` take two column blocks of 896, `down` `[8, 1792, 2048]`
+    two of 1024; 1792 is 14 lane tiles, so nothing goes in transposed. The
+    whole step (six products, their nine gradients' calls, Adam on the three
+    stacks with their moments donated) compiles over the layer's 17408-row
+    buffer (4096 x 4 + 8 x 128) and holds no copy of a stack."""
+    moe = megablox
+    rows = 4096 * 4 + 8 * moe.ROW_TILE
+    assert moe._gmm_tiles(rows, 2048, 1792)[1:] == (2048, 896)
+    assert moe._gmm_tiles(rows, 1792, 2048)[1:] == (1792, 1024)
+    assert not moe._held_lane_major(jnp.zeros((8, 2048, 1792)))
+    assert not moe._held_lane_major(jnp.zeros((8, 1792, 2048)))
+    compiled = _lowered_expert_step(moe, one_chip, 2048, 1792, gated=True,
+                                    rows=rows).compile()
+    text = compiled.as_text()
+    (layout,) = re.findall(r"entry_computation_layout=\{\((.*?)\)->", text)
+    assert layout.count("f32[8,2048,1792]{2,1,0:") == 6
+    assert layout.count("f32[8,1792,2048]{2,1,0:") == 3
+    assert len(_custom_calls(compiled, "gmm")) == 6
+    assert len(_custom_calls(compiled, "tgmm")) == 3
+    assert not re.findall(
+        r"= (?:f32|bf16)\[8,(?:2048,1792|1792,2048)\]\{[^}]*\} copy\(", text)
