@@ -88,7 +88,23 @@ compute nothing and their index maps stay on the live neighbour, so no block
 is fetched that is not used. They carry names of their own (`swa_flash_fwd`,
 `swa_flash_dq`, ...): the device track tells a windowed layer from a full one.
 `W >= T` is plain causal and runs the plain kernels; without a window every
-kernel is the instructions it was.
+kernel's body is the instructions it was.
+
+A causal grid without a window keeps its shape, (T / BQ) x (T / BK) steps a
+head, and the steps wholly above the diagonal compute nothing
+(`_dead_steps`: 28 of 64 at 8192 tokens in tiles of 1024, 6 of 16 at 4096).
+Such a step is not free where an index map moves on it: it has no products
+to hide a fetch behind. So the map of every operand that rides the inner
+axis stays on the live tile beside the step in grid order, without a window
+exactly as under one: K and V on the row's diagonal tile where the k blocks
+run innermost (the streaming forward, dQ), and Q, dOut, `Lse` and delta
+(`Out`'s block of a token-major call) on the column's first q block where
+the q blocks do (the fused backward, dK/dV). A held index changes which
+block lies in VMEM during a step that reads none: `Out`, `Lse`, dQ, dK and
+dV are the bits they were. The grid is not folded into its live steps,
+which would change the order in which the fused backward sums dQ. A call
+that is not causal and a causal row of one K block have no such step and
+lower to the text they did.
 
 Under a window or a kept set the causal mask is applied where it can change
 a score. A live tile that lies wholly under the diagonal and, under a
@@ -101,9 +117,8 @@ under 2048 over 4096. Leaving out a select whose predicate is false in every
 element changes no bit. A kept set is data and masks every tile of its call;
 the causal mask stays on that call's edge tiles, so its meaning does not
 rest on the set lying under the diagonal. Its int8 tile is fetched for the
-steps that compute one and for no other: on a step above the diagonal the
-set's index map stays on the live tile beside it (`_kept_spec`), where a
-fetch would have nothing to hide behind. A plain causal call keeps the mask
+steps that compute one and for no other: its index map reads the call's
+held maps (`_kept_spec`). A plain causal call keeps the mask
 on every live tile, the instructions it was: at its 1024 x 1024 tiles the
 pass hides behind the products and a second body is a cost
 (`_interior_apart`); so does every call whose row is one K block.
@@ -471,6 +486,25 @@ def causal_tiles(T):
     too: its tiles are masked, none is skipped)."""
     bq, bk = _blk(T, True)
     return sum(_last_k(qi, bq, bk) + 1 for qi in range(T // bq))
+
+
+def _dead_steps(T, blk_q, blk_k):
+    """Steps of a causal call's (T / blk_q) x (T / blk_k) grid that lie
+    wholly above the diagonal, a head: 28 of 64 at 8192 tokens in 1024 x
+    1024 tiles, 6 of 16 at 4096, none where a row is one K block. They
+    compute nothing, and every index map of the call that would move on one
+    is held on the live tile beside it (`_forward`, `_bwd_specs`); a call
+    that has none gets no clamp, the maps it had.
+    Measured (TPU v5 lite, bf16, the kernels alone and chained on the host's
+    clock, `tools/kept_set_probe.py`; chip run, PR 70), ms a call forward /
+    backward, each operand's own block on every step -> held: [32, 8192,
+    128] 5.314 / 9.749 -> 5.118 / 9.373, under a kept set (its tile held
+    either way) 5.431 / 9.973 -> 5.302 / 9.626; [32, 4096, 128] 1.606 /
+    2.850 -> 1.572 / 2.735. What the dead steps still cost once they fetch
+    nothing, the live tiles on a folded grid without them: 0.09 / 0.24 of
+    the 8192 call, 0.03 / 0.09 of the 4096 one."""
+    return sum(T // blk_k - 1 - _last_k(qi, blk_q, blk_k)
+               for qi in range(T // blk_q))
 
 
 def interior_tiles(T, window=None):
@@ -1055,13 +1089,13 @@ def _kept_vmem(BQ, BK):
     return (2 + 4) * BQ * BK
 
 
-def _kept_spec(H, BQ, BK, at_q, at_k, q_inner=False):
+def _kept_spec(H, BQ, BK, at_q, at_k):
     """The kept set's block of a grid step: the (q-block, k-block) tile of
-    the row's batch (grid axis 0 is the (batch x head) row). A step wholly
-    above the diagonal computes nothing and has no products to hide a fetch
-    behind: it stays on the live tile beside it in grid order, the row's
-    last where the k blocks run innermost and the column's first where the
-    q blocks do (`q_inner`), so nothing of the set is fetched for it.
+    the row's batch (grid axis 0 is the (batch x head) row) that the call's
+    own maps `at_q` and `at_k` name. Those hold a step wholly above the
+    diagonal on the live tile beside it in grid order (`_dead_steps`), so
+    nothing of the set is fetched for a step that computes nothing and has
+    no products to hide a fetch behind.
     Measured (TPU v5 lite, [32, 8192, 128] bf16, 1024 x 1024 tiles, 28 of a
     head's 64 steps dead; chip runs, PR 68): with the set's own tile on
     every step the forward is 6.477 ms a call and the fused backward 11.029
@@ -1071,15 +1105,8 @@ def _kept_spec(H, BQ, BK, at_q, at_k, q_inner=False):
     costs nothing (5.15 / 9.93 applied from one tile never fetched again)."""
     from jax.experimental import pallas as pl
 
-    def tile(*g):
-        qi, kj = at_q(*g)[1], at_k(*g)[1]
-        mx, mn, _ = _int_ops(qi)
-        if q_inner:
-            qi = mx(qi, _first_q(kj, BQ, BK))
-        else:
-            kj = mn(kj, _last_k(qi, BQ, BK))
-        return g[0] // H, qi, kj
-    return pl.BlockSpec((1, BQ, BK), tile)
+    return pl.BlockSpec((1, BQ, BK), lambda *g: (
+        g[0] // H, at_q(*g)[1], at_k(*g)[1]))
 
 
 def _takes_kept(kernel, at):
@@ -1244,6 +1271,9 @@ def _forward(q, k, v, seed, causal, sm_scale, dropout_rate, window, plan,
                    pltpu.VMEM(stat, jnp.float32),
                    pltpu.VMEM((BQ, rows.lanes(v3)), jnp.float32)]
     ax = rows.axes
+    # a step past the diagonal tile (a band's spare one, a causal grid's
+    # dead ones) stays on it, so nothing is fetched for it
+    held = window is not None or (causal and _dead_steps(T, BQ, BK))
 
     def at_q(*g):
         return rows.blk(g, g[ax])
@@ -1252,13 +1282,11 @@ def _forward(q, k, v, seed, causal, sm_scale, dropout_rate, window, plan,
     def at_k(*g):
         if carried == 0:
             return rows.blk(g, 0)
-        if window is None:
-            return rows.blk(g, g[ax + 1])
-        # the band's tile of this step; a spare step stays on the diagonal
-        # tile, the one before it, so nothing is fetched for it
-        return rows.blk(g, jnp.minimum(
-            _band_kj(g[ax], g[ax + 1], BQ, BK, window),
-            _last_k(g[ax], BQ, BK)))
+        kj = g[ax + 1] if window is None else \
+            _band_kj(g[ax], g[ax + 1], BQ, BK, window)
+        if held:
+            kj = jnp.minimum(kj, _last_k(g[ax], BQ, BK))
+        return rows.blk(g, kj)
 
     in_specs = [
         pl.BlockSpec((1, 1), lambda *g: (0, 0)),
@@ -1360,35 +1388,38 @@ def _backward(q, k, v, o, lse, g, seed, causal, sm_scale, dropout_rate,
 _token_major_backward = jax.jit(_backward, static_argnums=(7, 8, 9, 10, 11))
 
 
-def _bwd_specs(rows, BQ, BK, lanes, lanes_v, q_axis, band=None):
+def _bwd_specs(rows, T, BQ, BK, lanes, lanes_v, q_axis, causal, window):
     """Block specs of (seed, q, k, v, dO, lse, delta or Out) for a backward grid
     (rows.., ., .) whose q-block index is the first (`q_axis` 1) or the
     second (2) of the two tile axes and whose k-block index is the other;
     and the index maps of a q and a k block. Blocks of q and k are `lanes`
-    wide, of v and dO `lanes_v`. `band` = (window, T) where the inner axis counts the tiles
-    of a band: its spare steps stay on the band's nearest tile, so nothing
-    is fetched for them."""
+    wide, of v and dO `lanes_v`. Where the inner axis has steps that compute
+    nothing (under a `window` it counts the tiles of a band and has spare
+    ones; on a `causal` grid of rows `T` long those above the diagonal,
+    `_dead_steps`) the inner block's map stays on the nearest live tile, so
+    nothing is fetched for them."""
     from jax.experimental import pallas as pl
 
-    if band is not None:
-        window, T = band
+    if window is not None:
         q_steps = _band_steps(T, BQ, BK, window)[1]
+    held = window is not None or (causal and _dead_steps(T, BQ, BK))
     first = rows.axes - 1          # tile axis `a` is grid axis `first + a`
 
     def q_of(g):
         outer, inner = g[first + 1], g[first + 2]
-        if band is None or q_axis == 1:
-            return g[first + q_axis]
-        return jnp.maximum(
-            _band_qi(outer, inner, q_steps, BQ, BK, window, T // BQ),
-            _first_q(outer, BQ, BK))
+        if q_axis == 1:
+            return outer
+        qi = inner if window is None else \
+            _band_qi(outer, inner, q_steps, BQ, BK, window, T // BQ)
+        return jnp.maximum(qi, _first_q(outer, BQ, BK)) if held else qi
 
     def k_of(g):
         outer, inner = g[first + 1], g[first + 2]
-        if band is None or q_axis == 2:
-            return g[first + 3 - q_axis]
-        return jnp.minimum(_band_kj(outer, inner, BQ, BK, window),
-                           _last_k(outer, BQ, BK))
+        if q_axis == 2:
+            return outer
+        kj = inner if window is None else \
+            _band_kj(outer, inner, BQ, BK, window)
+        return jnp.minimum(kj, _last_k(outer, BQ, BK)) if held else kj
 
     def at_q(*g):
         return rows.blk(g, q_of(g))
@@ -1428,9 +1459,9 @@ def _flash_bwd_fused(args, rows, plan, attrs):
     (BQ, BK), heads = plan.tiles, plan.heads
     lanes, lanes_v = rows.lanes(q3), rows.lanes(v3)
     window = attrs.get("window")
-    band = None if window is None else (window, T)
-    in_specs, at_q, at_k = _bwd_specs(rows, BQ, BK, lanes, lanes_v, q_axis=2,
-                                      band=band)
+    in_specs, at_q, at_k = _bwd_specs(
+        rows, T, BQ, BK, lanes, lanes_v, q_axis=2, causal=attrs["causal"],
+        window=window)
     steps = T // BQ
     if window is not None:
         steps = _band_steps(T, BQ, BK, window)[1]
@@ -1439,7 +1470,7 @@ def _flash_bwd_fused(args, rows, plan, attrs):
     kept = _kept_of(args)
     if kept is not None:
         kernel = _takes_kept(kernel, 7)
-        in_specs.append(_kept_spec(rows.H, BQ, BK, at_q, at_k, q_inner=True))
+        in_specs.append(_kept_spec(rows.H, BQ, BK, at_q, at_k))
     scratch = [pltpu.VMEM((BK, lanes), jnp.float32),
                pltpu.VMEM((BK, lanes_v), jnp.float32)]
     if T == BK:
@@ -1480,7 +1511,6 @@ def _flash_bwd_split(args, rows, plan, attrs):
     (BQ, BK), heads = plan.tiles, plan.heads
     lanes, lanes_v = rows.lanes(q3), rows.lanes(v3)
     window = attrs.get("window")
-    band = None if window is None else (window, T)
     k_steps, q_steps = (T // BK, T // BQ) if window is None else \
         _band_steps(T, BQ, BK, window)
     # a token-major plan's VMEM counts a resident dQ row the pair does
@@ -1488,8 +1518,9 @@ def _flash_bwd_split(args, rows, plan, attrs):
     params = _compiler_params(
         vmem_bytes=plan.vmem and min(plan.vmem, _VMEM_BUDGET_BYTES),
         heads=heads)
-    in_specs, at_q, at_k = _bwd_specs(rows, BQ, BK, lanes, lanes_v, q_axis=1,
-                                      band=band)
+    in_specs, at_q, at_k = _bwd_specs(
+        rows, T, BQ, BK, lanes, lanes_v, q_axis=1, causal=attrs["causal"],
+        window=window)
     kept = _kept_of(args)
 
     def kernel_of(body, **more):
@@ -1509,10 +1540,11 @@ def _flash_bwd_split(args, rows, plan, attrs):
         interpret=plan.interpret,
         name=_named("flash_dq", window, kept),
     )(*args)
-    in_specs, at_q, at_k = _bwd_specs(rows, BQ, BK, lanes, lanes_v, q_axis=2,
-                                      band=band)
+    in_specs, at_q, at_k = _bwd_specs(
+        rows, T, BQ, BK, lanes, lanes_v, q_axis=2, causal=attrs["causal"],
+        window=window)
     if kept is not None:
-        in_specs.append(_kept_spec(rows.H, BQ, BK, at_q, at_k, q_inner=True))
+        in_specs.append(_kept_spec(rows.H, BQ, BK, at_q, at_k))
     dk, dv = pl.pallas_call(
         kernel_of(_flash_dkv_kernel,
                   **({} if window is None else {"q_tiles": T // BQ})),
@@ -1726,7 +1758,9 @@ def _fused_attention(ctx, Q, K, V, Kept=None):
     tallies the score tiles its forward grid computes
     (`window_tiles_computed` on the compile event) and, as an op under a
     kept set does, those of them that run without the causal mask
-    (`flash_tiles_unmasked`). `Kept` (optional): int8
+    (`flash_tiles_unmasked`); a causal op without a window tallies the steps
+    of its forward grid above the diagonal, for which nothing is fetched
+    (`flash_dead_steps_held`). `Kept` (optional): int8
     [B, T, T], the keys each query keeps of those below the diagonal, one
     set for all heads; no gradient, no dropout, not with a window; the
     `dsa_` kernels read its tiles beside the score tiles, and the op tallies
@@ -1780,6 +1814,10 @@ def _fused_attention(ctx, Q, K, V, Kept=None):
     forward_op = ctx.op is not None and ctx.op.type == "fused_attention"
     if forward_op and _interior_apart(window, Kept):
         ctx.tally("flash_tiles_unmasked", B * H * interior_tiles(T, window))
+    if forward_op and causal and _window_of(window, T) is None \
+            and T % _LANES == 0:
+        ctx.tally("flash_dead_steps_held",
+                  B * H * _dead_steps(T, *_blk(T, True)))
     if Kept is not None:
         if rate:
             raise NotImplementedError(

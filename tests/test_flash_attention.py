@@ -801,6 +801,265 @@ def test_windowed_grids_cover_the_bands_tiles(monkeypatch, T, tiles, window,
             == [qi for qi in range(T // bq) if live[qi][kj]]
 
 
+# -- a causal grid's dead steps fetch nothing -------------------------------------
+
+def flash_calls(monkeypatch, T, tiles, causal=True, token_major=False,
+                kept=False, split=False, every_step=False):
+    """name -> (grid, input specs) of the forward and the backward calls of
+    one row length, as `_forward` and `_bwd_specs` build them: `pallas_call`
+    is stood in for, nothing runs. `every_step`: the maps without the hold,
+    every operand's own block on every grid step."""
+    from jax.experimental import pallas as pl
+
+    calls = {}
+
+    def pallas_call(kernel, *, grid, in_specs, out_shape, name, **kw):
+        calls[name] = (grid, in_specs)
+        return lambda *operands: jax.tree.map(
+            lambda x: jnp.zeros(x.shape, x.dtype), out_shape)
+
+    B, H, D = 2, 2, 128
+    x = jax.ShapeDtypeStruct((B, T, H, D) if token_major else (B, H, T, D),
+                             jnp.float32)
+    the_set = jnp.ones((B, T, T), jnp.int8) if kept else None
+
+    def both(q, k, v, g):
+        out, lse = pallas_attention._flash_forward(
+            q, k, v, causal, 1.0, token_major=token_major, kept=the_set)
+        return pallas_attention._flash_backward(
+            q, k, v, out, lse, g, causal, 1.0, 0.0, 0,
+            token_major=token_major, kept=the_set)
+
+    with monkeypatch.context() as patch:
+        patch.setattr(pl, "pallas_call", pallas_call)
+        patch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
+        # a jitted call keeps its trace: the stand-in has to be called
+        patch.setattr(pallas_attention, "_token_major_forward",
+                      pallas_attention._forward)
+        patch.setattr(pallas_attention, "_token_major_backward",
+                      pallas_attention._backward)
+        patch.setattr(pallas_attention, "_bwd_plan",
+                      lambda *a: "split" if split else "fused")
+        if every_step:
+            patch.setattr(pallas_attention, "_dead_steps", lambda *a: 0)
+        jax.eval_shape(both, x, x, x, x)
+    return calls
+
+
+def walk_the_grid(name, call, every, bq, bk):
+    """The input blocks of the causal call `name` over its grid in the
+    order the steps run (the last axis fastest), beside the `every`-step
+    maps, which give each operand's own block: (live steps, steps, the
+    blocks each operand fetches, the blocks the live steps alone would
+    fetch). A block is fetched where the index moves. A live step reads its
+    own blocks."""
+    (grid, specs), (_, own_specs) = call, every
+    # operands (seed, q, k, v[, dO, lse, delta or Out][, kept]): which tile
+    # axis each one's block follows
+    follows = "-qkk" if "fwd" in name else "-qkkqqq"
+    follows += "b" * (len(specs) - len(follows))
+    live = steps = 0
+    fetched, needed = ([[] for _ in specs] for _ in range(2))
+    for g in np.ndindex(*grid):
+        qi, kj = g[-2:][::-1] if "dkv" in name else g[-2:]
+        is_live = bool(pallas_attention._causal_live(qi, kj, bq, bk))
+        live, steps = live + is_live, steps + 1
+        for n, (spec, own_spec) in enumerate(zip(specs, own_specs)):
+            block = tuple(int(i) for i in spec.index_map(*g))
+            own = tuple(int(i) for i in own_spec.index_map(*g))
+            # `Lse`'s and delta's blocks are (rows, 1, tile)
+            tile = own[-1 if spec.block_shape[1] == 1 else 1]
+            assert {"-": own == (0, 0), "q": tile == qi, "k": tile == kj,
+                    "b": own[1:] == (qi, kj)}[follows[n]], (n, g, own)
+            assert not is_live or block == own, (n, g, block, own)
+            if fetched[n][-1:] != [block]:
+                fetched[n].append(block)
+            if is_live and needed[n][-1:] != [own]:
+                needed[n].append(own)
+    return live, steps, fetched, needed
+
+
+@pytest.mark.parametrize("layout", ["BHTD", "BTHD"])
+@pytest.mark.parametrize("split", [False, True], ids=["fused", "split"])
+@pytest.mark.parametrize("T,bq,bk", [
+    (8192, 1024, 1024), (4096, 1024, 1024), (512, 128, 256), (512, 256, 128)])
+def test_no_operand_is_fetched_for_a_step_above_the_diagonal(
+        monkeypatch, T, bq, bk, split, layout):
+    """K and V of the forward and of dQ (k blocks innermost), and Q, dOut,
+    `Lse` and delta (`Out`'s block of a token-major call) of the fused
+    backward and of dK/dV (q blocks innermost): on a causal grid without a
+    window a live step reads its own block and a dead step the block of the
+    live step beside it (the row's last, the column's first), so every
+    operand is fetched as a grid of the live steps alone would fetch it: 36
+    of a head's 64 at 8192 tokens. With each operand's own block on every
+    step (the maps before PR 70) the inner ones moved on every step."""
+    kw = dict(token_major=layout == "BTHD", split=split)
+    held = flash_calls(monkeypatch, T, (bq, bk), **kw)
+    every = flash_calls(monkeypatch, T, (bq, bk), every_step=True, **kw)
+    assert sorted(held) == (
+        ["flash_dkv", "flash_dq", "flash_fwd"] if split
+        else ["flash_dq_flash_dkv", "flash_fwd"])
+    per_head = sum(pallas_attention._last_k(qi, bq, bk) + 1
+                   for qi in range(T // bq))
+    dead = pallas_attention._dead_steps(T, bq, bk)
+    assert per_head + dead == (T // bq) * (T // bk) and dead > 0
+    if T == 8192:
+        assert (per_head, dead) == (36, 28)
+    for name in held:
+        heads = int(np.prod(held[name][0][:-2]))
+        live, steps, fetched, needed = walk_the_grid(
+            name, held[name], every[name], bq, bk)
+        assert (live, steps) == (heads * per_head, heads * (per_head + dead))
+        assert fetched == needed
+        assert max(map(len, fetched)) <= live
+        _, _, moved, _ = walk_the_grid(name, every[name], every[name], bq, bk)
+        inner = [n for n, blocks in enumerate(moved) if len(blocks) > live]
+        assert inner == ([1, 4, 5, 6] if "dkv" in name else [2, 3])
+        assert all(len(moved[n]) > len(fetched[n]) for n in inner)
+
+
+@pytest.mark.parametrize("why,T,tiles,causal", [
+    ("not causal", 512, (128, 128), False),
+    ("one K block a row", 2048, None, True),
+    ("one K block a row, wide q blocks", 512, (256, 512), True)])
+@pytest.mark.parametrize("layout", ["BHTD", "BTHD"])
+def test_a_grid_without_a_dead_step_keeps_the_maps_it_had(
+        monkeypatch, why, T, tiles, causal, layout):
+    """A call that is not causal and a causal row of one K block (every
+    attention call at 2048 tokens under `_BLOCK_TABLE`'s (256, 2048)) have
+    no step above the diagonal: their index maps are each step's own block,
+    with no clamp in them, the text they lowered to before the hold."""
+    kw = dict(causal=causal, token_major=layout == "BTHD")
+    bq, bk = tiles or pallas_attention._blk(T, causal)
+    assert not causal or pallas_attention._dead_steps(T, bq, bk) == 0
+    for split in (False, True):
+        held = flash_calls(monkeypatch, T, tiles, split=split, **kw)
+        every = flash_calls(monkeypatch, T, tiles, split=split,
+                            every_step=True, **kw)
+        assert len(held) == 2 + split
+        for name, (grid, specs) in held.items():
+            for spec, own in zip(specs, every[name][1]):
+                maps = [str(jax.make_jaxpr(s.index_map)(*[0] * len(grid)))
+                        for s in (spec, own)]
+                assert maps[0] == maps[1]
+                assert "min" not in maps[0] and "max" not in maps[0]
+
+
+# name -> layout, (D, Dv), kept set, backward plan
+HELD_CASES = {
+    "fused": ("BHTD", (64, 64), False, "fused"),
+    "split": ("BHTD", (64, 64), False, "split"),
+    "fused_D192_Dv128": ("BHTD", (192, 128), False, "fused"),
+    "kept_fused": ("BHTD", (64, 64), True, "fused"),
+    "kept_split": ("BHTD", (64, 64), True, "split"),
+    "token_major_fused": ("BTHD", (64, 64), False, "fused"),
+    "token_major_split": ("BTHD", (128, 128), False, "split"),
+}
+
+
+@pytest.mark.parametrize("tiles", [(128, 128), (128, 256), (256, 128)],
+                         ids=lambda t: "x".join(map(str, t)))
+@pytest.mark.parametrize("case", sorted(HELD_CASES))
+def test_held_dead_steps_give_the_bits_of_blocks_fetched_there(
+        interpret_kernels, monkeypatch, case, tiles):
+    """`Out`, `Lse`, dQ, dK and dV of a causal call whose dead steps stay on
+    the live neighbour's blocks, `==` those of the same kernels with every
+    operand's own block on every step (the maps before PR 70): a step that
+    reads nothing does not care what lies in VMEM. Head-major and
+    token-major, fused and split, with and without a kept set, under the
+    interpreter (`tests/test_flash_grad_tpu.py` holds it on the chip at the
+    cells' shapes, with dropout too)."""
+    layout, (D, Dv), kept, plan = HELD_CASES[case]
+    rng = np.random.RandomState(23)
+    B, H, T, scale = 1, 2, 512, D ** -0.5
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
+    monkeypatch.setattr(pallas_attention, "_bwd_plan", lambda *a: plan)
+    # a jitted call keeps the trace of the first form
+    monkeypatch.setattr(pallas_attention, "_token_major_forward",
+                        pallas_attention._forward)
+    monkeypatch.setattr(pallas_attention, "_token_major_backward",
+                        pallas_attention._backward)
+    assert pallas_attention._dead_steps(T, *tiles) > 0
+    q, k, v = _qkv(rng, B, H, T, D, Dv)
+    g = jnp.asarray(rng.randn(B, H, T, Dv), jnp.float32)
+    token_major = layout == "BTHD"
+    if token_major:
+        q, k, v, g = (x.transpose(0, 2, 1, 3) for x in (q, k, v, g))
+    the_set = _selected(rng, B, T, 200) if kept else None
+
+    def run():
+        out, lse = pallas_attention._flash_forward(
+            q, k, v, True, scale, token_major=token_major, kept=the_set)
+        return (out, lse) + tuple(pallas_attention._flash_backward(
+            q, k, v, out, lse, g, True, scale, 0.0, 0,
+            token_major=token_major, kept=the_set))
+
+    got = run()
+    monkeypatch.setattr(pallas_attention, "_dead_steps", lambda *a: 0)
+    want = run()
+    for a, b, name in zip(got, want, ("Out", "Lse", "dQ", "dK", "dV")):
+        assert np.abs(np.asarray(a)).max() > 0, name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+    f32 = [x.transpose(0, 2, 1, 3) if token_major else x for x in (q, k, v)]
+    mask = None if the_set is None else the_set * jnp.asarray(
+        _brute_visible(T, None), jnp.int8)[None]
+    ref = _attention_reference(*f32, True, scale, kept=mask)
+    np.testing.assert_allclose(
+        np.asarray(got[0].transpose(0, 2, 1, 3) if token_major else got[0]),
+        np.asarray(ref), atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("T,tiles,dead", [
+    (8192, None, 28), (4096, None, 6), (2048, None, 0), (256, None, 0),
+    (512, (128, 128), 6), (512, (128, 256), 2), (512, (256, 128), 2),
+    (512, (512, 128), 0), (512, (128, 512), 0)])
+def test_dead_steps_at_the_cells_lengths(monkeypatch, T, tiles, dead):
+    """The steps of a causal grid above the diagonal, a head: the grid's
+    less the triangle's tiles; none where a row is one K block or one Q
+    block reaches over every K block."""
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
+    bq, bk = pallas_attention._blk(T, True)
+    assert pallas_attention._dead_steps(T, bq, bk) == dead \
+        == (T // bq) * (T // bk) - pallas_attention.causal_tiles(T)
+    assert dead == sum(
+        not pallas_attention._causal_live(qi, kj, bq, bk)
+        for qi in range(T // bq) for kj in range(T // bk))
+
+
+@pytest.mark.parametrize("why,kw,tiles,held", [
+    ("causal", dict(causal=True), (128, 128), 2 * 2 * 6),
+    ("causal, token-major", dict(causal=True, layout="BTHD"), (128, 128),
+     2 * 2 * 6),
+    ("a window over the whole row", dict(causal=True, window=512),
+     (128, 128), 2 * 2 * 6),
+    ("one K block a row", dict(causal=True), None, 0),
+    ("a window", dict(causal=True, window=300), (128, 128), None),
+    ("not causal", dict(causal=False), (128, 128), None)])
+def test_the_op_tallies_the_dead_steps_it_holds(monkeypatch, why, kw, tiles,
+                                                held):
+    """`flash_dead_steps_held` on the compile event: batch x heads x the
+    forward grid's steps above the diagonal, for a causal op without a
+    window (0 where its rows are one K block: both transformer cells);
+    a windowed op and one that is not causal do not count, and the grad
+    op's trace adds nothing."""
+    from paddle_tpu import observe
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
+    B, H, T, D = 2, 2, 512, 64
+    shape = [B, T, H, D] if kw.get("layout") == "BTHD" else [B, H, T, D]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        q = layers.data(name="q", shape=shape, dtype="float32",
+                        append_batch_size=False, stop_gradient=False)
+        loss = layers.reduce_sum(layers.fused_attention(q, q, q, **kw))
+        fluid.append_backward(loss)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(main, feed={"q": np.ones(shape, np.float32)}, fetch_list=[loss],
+            scope=fluid.Scope())
+    detail = observe.observatory().latest(main._uid).detail
+    assert detail.get("flash_dead_steps_held") == held
+
+
 # -- the second window width: 2048 keys over 4096 tokens (Trinity-Mini) -----------
 
 W2048 = dict(B=1, H=1, T=4096, D=32, window=2048)

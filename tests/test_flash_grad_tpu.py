@@ -12,7 +12,10 @@ And that the kernels on `[batch, seq, heads, head_dim]` operands, a head a
 range of lanes and several heads a grid step, give what the head-major
 kernels give on the transposed operands: `Out`, `Lse` and dV bit for bit.
 And that the kernels which run a causal call's interior tiles without the
-mask give the bits of the mask on every tile, at the cells' own scales."""
+mask give the bits of the mask on every tile, at the cells' own scales. And
+that a causal call whose index maps hold every operand on the live
+neighbour of a step above the diagonal gives the bits of the call that
+fetches each one's own block there."""
 
 import numpy as np
 import pytest
@@ -233,15 +236,39 @@ def test_unmasked_interior_is_bitwise_the_mask_on_every_tile(monkeypatch,
         np.testing.assert_array_equal(a, b, err_msg=name)
 
 
+def _held_against_every_step(monkeypatch, run, operands):
+    """`run(*operands)` compiled with the index maps in force, then with
+    every operand's own block on every grid step (`_dead_steps` answering
+    "none": the maps before PR 70, and under a kept set its tile fetched on
+    every step as before PR 68): the same bits, name by name."""
+    def compiled_run():
+        """Traced afresh: the maps in force are read."""
+        return jax.jit(lambda *a: run(*a)).lower(*operands).compile(
+            compiler_options=resolve_compiler_options("tpu"))(*operands)
+
+    got = compiled_run()
+    monkeypatch.setattr(pallas_attention, "_dead_steps", lambda *a: 0)
+    # a token-major call is jitted and keeps the trace of the first form
+    monkeypatch.setattr(pallas_attention, "_token_major_forward",
+                        pallas_attention._forward)
+    monkeypatch.setattr(pallas_attention, "_token_major_backward",
+                        pallas_attention._backward)
+    want = compiled_run()
+    for a, b, name in zip(got, want, ("Out", "Lse", "dQ", "dK", "dV")):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a).all() and np.abs(a).max() > 0, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
 @pytest.mark.parametrize("plan", ["fused", "split"])
 def test_a_kept_set_held_on_dead_steps_gives_the_bits_of_one_fetched_there(
         monkeypatch, plan):
     """`keye_vl_2_30b_a3b.s8192`'s call, `[1, 32, 8192, 128]` bf16 under an
     int8 `[1, 8192, 8192]`, by Mosaic's own arithmetic: `Out`, `Lse`, dQ, dK
-    and dV of the kernels whose kept set stays on the live tile beside a
-    step above the diagonal (28 of a head's 64 steps) are the bits of the
-    same kernels with the set's own tile fetched on every step."""
-    from jax.experimental import pallas as pl
+    and dV of the kernels whose kept set, K, V, Q, dOut, `Lse` and delta stay
+    on the live tile beside a step above the diagonal (28 of a head's 64
+    steps) are the bits of the same kernels with each one's own block
+    fetched on every step."""
     shape, Dv, _, _ = INTERIOR["8192x128_kept"]
     B, H, T, D = shape
     if plan == "split":
@@ -253,27 +280,59 @@ def test_a_kept_set_held_on_dead_steps_gives_the_bits_of_one_fetched_there(
     kept = jnp.asarray(np.tril(rng.rand(B, T, T) < 0.3)
                        | np.eye(T, dtype=bool), jnp.int8)
 
-    def compiled_run():
-        """Traced afresh: the block spec in force is read."""
-        def run(q, k, v, g):
-            out, lse = pallas_attention._flash_forward(
-                q, k, v, True, D ** -0.5, kept=kept)
-            return (out, lse) + pallas_attention._flash_backward(
-                q, k, v, out, lse, g, True, D ** -0.5, 0.0, 0, kept=kept)
-        return jax.jit(run).lower(q, k, v, g).compile(
-            compiler_options=resolve_compiler_options("tpu"))(q, k, v, g)
+    def run(q, k, v, g):
+        out, lse = pallas_attention._flash_forward(
+            q, k, v, True, D ** -0.5, kept=kept)
+        return (out, lse) + pallas_attention._flash_backward(
+            q, k, v, out, lse, g, True, D ** -0.5, 0.0, 0, kept=kept)
 
-    def every_step(H, BQ, BK, at_q, at_k, q_inner=False):
-        return pl.BlockSpec((1, BQ, BK), lambda *g: (
-            g[0] // H, at_q(*g)[1], at_k(*g)[1]))
+    _held_against_every_step(monkeypatch, run, (q, k, v, g))
 
-    got = compiled_run()
-    monkeypatch.setattr(pallas_attention, "_kept_spec", every_step)
-    want = compiled_run()
-    for a, b, name in zip(got, want, ("Out", "Lse", "dQ", "dK", "dV")):
-        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-        assert np.isfinite(a).all() and np.abs(a).max() > 0, name
-        np.testing.assert_array_equal(a, b, err_msg=name)
+
+# name -> (q shape, value width, token-major, dropout): the full layer of
+# mellum2_12b_a2_5b.s8192 (and Keye's call without its set), kanana_2_30b_a3b
+# .bs1's latent heads, ouro_2_6b.bs1's and olmoe_1b_7b.bs1's, a token-major
+# call of heads a vreg wide forced to two K blocks a row
+HELD = {"8192x128": ((1, 32, 8192, 128), 128, False, 0.0),
+        "8192x128_dropout": ((1, 32, 8192, 128), 128, False, 0.1),
+        "4096x192_128": ((1, 32, 4096, 192), 128, False, 0.0),
+        "4096x128": ((1, 16, 4096, 128), 128, False, 0.0),
+        "token_major_2048x4x128": ((2, 2048, 4, 128), 128, True, 0.0),
+        "token_major_2048x4x128_dropout": ((2, 2048, 4, 128), 128, True, 0.1)}
+
+
+@pytest.mark.parametrize("plan", ["fused", "split"])
+@pytest.mark.parametrize("case", sorted(HELD))
+def test_held_dead_steps_give_the_bits_of_blocks_fetched_there(
+        monkeypatch, case, plan):
+    """A plain causal call at the cells' shapes and tiles, by Mosaic's own
+    arithmetic and with the hardware PRNG's masks: `Out`, `Lse`, dQ, dK and
+    dV of the kernels whose K and V (the forward, dQ) and Q, dOut, `Lse`
+    and delta (the fused backward, dK/dV) stay on the live neighbour's
+    block over the steps above the diagonal (28 of a head's 64 at 8192
+    tokens, 6 of 16 at 4096, 1 of 4 at 2048 in forced tiles) are the bits of
+    the same kernels with each operand's own block fetched on every step."""
+    shape, Dv, token_major, rate = HELD[case]
+    B, H, T, D = pallas_attention._shape_of(
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16), token_major)
+    if token_major:
+        monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", (1024, 1024))
+    assert pallas_attention._dead_steps(
+        T, *pallas_attention._blk(T, True)) > 0
+    monkeypatch.setattr(pallas_attention, "_bwd_plan", lambda *a: plan)
+    rng = np.random.RandomState(T + D)
+    q, k = (jnp.asarray(rng.randn(*shape), jnp.bfloat16) for _ in range(2))
+    v, g = (jnp.asarray(rng.randn(*shape[:3], Dv), jnp.bfloat16)
+            for _ in range(2))
+
+    def run(q, k, v, g):
+        out, lse = pallas_attention._flash_forward(
+            q, k, v, True, D ** -0.5, rate, 77, token_major=token_major)
+        return (out, lse) + pallas_attention._flash_backward(
+            q, k, v, out, lse, g, True, D ** -0.5, rate, 77,
+            token_major=token_major)
+
+    _held_against_every_step(monkeypatch, run, (q, k, v, g))
 
 
 TOKEN_MAJOR = [((96, 256, 8, 64), False), ((96, 256, 8, 64), True),

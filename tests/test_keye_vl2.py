@@ -27,6 +27,7 @@ from paddle_tpu.ops import pallas_attention as pa
 from paddle_tpu.ops import sparse_attention as sa
 
 import keye_vl2_reference as ref
+from test_flash_attention import flash_calls
 from test_olmoe import rel_err, run_piece
 from test_qwen3_next import frob
 
@@ -215,35 +216,36 @@ def test_a_kept_set_of_all_ones_is_bitwise_the_causal_call(monkeypatch, plan):
 @pytest.mark.parametrize("q_inner", [False, True], ids=["k_inner", "q_inner"])
 @pytest.mark.parametrize("seq,bq,bk", [(8192, 1024, 1024), (512, 128, 256),
                                        (512, 256, 128)])
-def test_a_dead_step_fetches_no_tile_of_the_kept_set(seq, bq, bk, q_inner):
+def test_a_dead_step_fetches_no_tile_of_the_kept_set(monkeypatch, seq, bq, bk,
+                                                     q_inner):
     """The kept set's index map over a causal grid in the order its steps
     run, k blocks innermost (the forward, dQ) or q blocks (the fused
-    backward, dK/dV): a live step reads its own tile, a step wholly above
-    the diagonal the tile of the live step beside it, so the block index
-    changes (a tile is fetched) once a live tile and never for a dead step:
-    32 x 36 times a call at the cell's 8192 tokens where the grid has 32 x
-    64 steps."""
-    heads, rows = 2, 4      # two batch rows of two heads
-    nq, nk = seq // bq, seq // bk
-    # grid axes of the q block and the k block; the inner one is axis 2
-    q_ax, k_ax = (2, 1) if q_inner else (1, 2)
-    spec = pa._kept_spec(heads, bq, bk, lambda *g: (g[0], g[q_ax], 0),
-                         lambda *g: (g[0], g[k_ax], 0), q_inner=q_inner)
-    outer, inner = (nk, nq) if q_inner else (nq, nk)
-    steps = [(r, a, b) for r in range(rows) for a in range(outer)
-             for b in range(inner)]
-    fetched, at, live = 0, None, 0
-    for g in steps:
-        block = tuple(int(i) for i in spec.index_map(*g))
-        qi, kj = g[q_ax], g[k_ax]
-        if pa._causal_live(qi, kj, bq, bk):
-            live += 1
-            assert block == (g[0] // heads, qi, kj)
-        fetched, at = fetched + (block != at), block
-    assert live == rows * sum(pa._last_k(qi, bq, bk) + 1 for qi in range(nq))
-    assert fetched == live < len(steps)
-    if seq == 8192:
-        assert (live, len(steps)) == (rows * 36, rows * 64)
+    backward, dK/dV), through the maps every operand of the call shares
+    (`_forward`, `_bwd_specs`; since PR 70 the set's has no clamp of its
+    own): a live step reads its own tile, a step wholly above the diagonal
+    the tile of the live step beside it, so the block index changes (a tile
+    is fetched) once a live tile and never for a dead step: 32 x 36 times a
+    call at the cell's 8192 tokens where the grid has 32 x 64 steps."""
+    calls = flash_calls(monkeypatch, seq, (bq, bk), kept=True, split=True)
+    assert sorted(calls) == ["dsa_flash_dkv", "dsa_flash_dq", "dsa_flash_fwd"]
+    heads, nq = 2, seq // bq        # `flash_calls`: two batch rows of two
+    for name in ("dsa_flash_dkv",) if q_inner else ("dsa_flash_fwd",
+                                                    "dsa_flash_dq"):
+        grid, specs = calls[name]
+        q_ax, k_ax = (2, 1) if q_inner else (1, 2)
+        fetched, at, live = 0, None, 0
+        for g in np.ndindex(*grid):
+            block = tuple(int(i) for i in specs[-1].index_map(*g))
+            qi, kj = g[q_ax], g[k_ax]
+            if pa._causal_live(qi, kj, bq, bk):
+                live += 1
+                assert block == (g[0] // heads, qi, kj)
+            fetched, at = fetched + (block != at), block
+        assert live == grid[0] * sum(pa._last_k(qi, bq, bk) + 1
+                                     for qi in range(nq))
+        assert fetched == live < int(np.prod(grid))
+        if seq == 8192:
+            assert (live, int(np.prod(grid))) == (grid[0] * 36, grid[0] * 64)
 
 
 @pytest.mark.parametrize("why,kw", [
@@ -650,6 +652,8 @@ def test_compile_event_carries_the_census():
     assert detail["dsa_tiles_computed"] == 2 * 4 * 3 * pa.causal_tiles(T)
     # that one tile holds the diagonal: none runs without the causal mask
     assert detail["flash_tiles_unmasked"] == 0 == pa.interior_tiles(T)
+    # and its grid has no step above the diagonal to hold
+    assert detail["flash_dead_steps_held"] == 0
     assert detail["frozen_parameters"] == len(FROZEN)
     assert "window_tiles_computed" not in detail
     assert "dsa_layers" not in observe.observatory().latest(
@@ -671,6 +675,8 @@ def test_causal_tiles_at_the_cells_length():
     # 28 of them lie wholly under the diagonal: the kept set alone masks them
     assert pa.interior_tiles(8192) == 28
     assert 4 * 32 * pa.interior_tiles(8192) == 3584     # the cell's tally
+    # and 28 of the grid's 64 steps wholly above it: they fetch nothing
+    assert 4 * 32 * pa._dead_steps(8192, 1024, 1024) == 3584
 
 
 def test_the_unmasked_tally_follows_the_tiles(monkeypatch):
@@ -689,6 +695,8 @@ def test_the_unmasked_tally_follows_the_tiles(monkeypatch):
     assert pa.causal_tiles(T) == 3 and pa.interior_tiles(T) == 1
     assert detail["dsa_tiles_computed"] == 2 * 4 * 3 * 3
     assert detail["flash_tiles_unmasked"] == 2 * 4 * 3 * 1
+    # one of the grid's four steps lies above the diagonal
+    assert detail["flash_dead_steps_held"] == 2 * 4 * 3 * 1
     assert "flash_tiles_unmasked" not in observe.observatory().latest(
         startup._uid).detail
 

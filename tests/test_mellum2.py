@@ -527,6 +527,8 @@ def test_the_unmasked_tally_follows_the_tiles(monkeypatch, window, windowed,
     detail = observe.observatory().latest(main._uid).detail
     assert detail["window_tiles_computed"] == 3 * 2 * 4 * band
     assert detail["flash_tiles_unmasked"] == 2 * 4 * 3 * windowed
+    # the full layer's grid has six steps above the diagonal, a head
+    assert detail["flash_dead_steps_held"] == 2 * 4 * 1 * 6
     assert "flash_tiles_unmasked" not in observe.observatory().latest(
         startup._uid).detail
 
