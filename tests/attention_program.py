@@ -1,6 +1,7 @@
 """One `fused_attention` op in a program of its own, for the tests that hold
 its grad op (tests/test_flash_attention.py on the CPU under the Pallas
-interpreter, tests/test_flash_grad_tpu.py on the chip)."""
+interpreter, tests/test_flash_grad_tpu.py on the chip), and the flash calls'
+grids and index maps with nothing run (`flash_calls`)."""
 
 import jax
 import jax.numpy as jnp
@@ -10,6 +11,7 @@ import paddle_tpu as fluid
 from paddle_tpu import layers
 from paddle_tpu.core import registry
 from paddle_tpu.layer_helper import LayerHelper
+from paddle_tpu.ops import pallas_attention
 
 
 def float32_grad_layer(monkeypatch):
@@ -120,3 +122,46 @@ def kernel_calls(text, kernel):
     if getattr(text, "jaxpr", None) is not None:
         return _pallas_calls(text.jaxpr, kernel)
     return text.count(f"name={kernel}\n") + text.count(f"name={kernel} ")
+
+
+def flash_calls(monkeypatch, T, tiles, causal=True, token_major=False,
+                kept=False, split=False, every_step=False):
+    """name -> (grid, input specs) of the forward and the backward calls of
+    one row length, as `_forward` and `_bwd_specs` build them: `pallas_call`
+    is stood in for, nothing runs. `every_step`: the maps without the hold,
+    every operand's own block on every grid step."""
+    from jax.experimental import pallas as pl
+
+    calls = {}
+
+    def pallas_call(kernel, *, grid, in_specs, out_shape, name, **kw):
+        calls[name] = (grid, in_specs)
+        return lambda *operands: jax.tree.map(
+            lambda x: jnp.zeros(x.shape, x.dtype), out_shape)
+
+    B, H, D = 2, 2, 128
+    x = jax.ShapeDtypeStruct((B, T, H, D) if token_major else (B, H, T, D),
+                             jnp.float32)
+    the_set = jnp.ones((B, T, T), jnp.int8) if kept else None
+
+    def both(q, k, v, g):
+        out, lse = pallas_attention._flash_forward(
+            q, k, v, causal, 1.0, token_major=token_major, kept=the_set)
+        return pallas_attention._flash_backward(
+            q, k, v, out, lse, g, causal, 1.0, 0.0, 0,
+            token_major=token_major, kept=the_set)
+
+    with monkeypatch.context() as patch:
+        patch.setattr(pl, "pallas_call", pallas_call)
+        patch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
+        # a jitted call keeps its trace: the stand-in has to be called
+        patch.setattr(pallas_attention, "_token_major_forward",
+                      pallas_attention._forward)
+        patch.setattr(pallas_attention, "_token_major_backward",
+                      pallas_attention._backward)
+        patch.setattr(pallas_attention, "_bwd_plan",
+                      lambda *a: "split" if split else "fused")
+        if every_step:
+            patch.setattr(pallas_attention, "_dead_steps", lambda *a: 0)
+        jax.eval_shape(both, x, x, x, x)
+    return calls

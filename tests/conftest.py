@@ -3,6 +3,7 @@ sharding logic is exercised without TPU hardware (the driver separately
 dry-runs the multichip path)."""
 
 import fnmatch
+import gc
 import os
 
 # PADDLE_TPU_TEST_ON_TPU=1 keeps the real chip — use it ONLY to run the
@@ -84,6 +85,17 @@ def pytest_collection_modifyitems(config, items):
                    "test_async_pserver_deepfm_two_trainers")
     items.sort(key=lambda it: 0 if any(h in it.name for h in heavy_tests)
                else 1)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _release_the_compiled_programs():
+    """After each test file, drop what jax keeps of it: every live XLA:CPU
+    executable holds memory maps, jax's caches keep every one alive, and at
+    `vm.max_map_count` (65530) a worker's next compile segfaults (PR 69:
+    32 k maps after `test_nemotron_h.py`, 54 k after `test_lfm2_moe.py`)."""
+    yield
+    jax.clear_caches()
+    gc.collect()
 
 
 @pytest.fixture(autouse=True)

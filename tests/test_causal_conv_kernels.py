@@ -19,8 +19,9 @@ from paddle_tpu.ops import linear_attention as la
 
 import qwen3_next_reference as ref
 from attention_program import kernel_calls, step_text
-from test_olmoe import run_piece
-from test_qwen3_next import RTOL, TINY, frob
+from decoder_case import RTOL, frob, run_piece, tiny_args
+
+TINY = tiny_args("qwen3_next")
 
 B, C = 2, 128
 # T -> (time block, rows a loop step takes), by `_conv_blocks`: one block of

@@ -1,11 +1,11 @@
 """The decoder models' Programs are what they were, and the seams a
 new architecture crosses stay where they are.
 
-`program_digest` holds a Program op for op (type, attributes, the name scope
-among them, the shapes it writes, the persistable variables it reads and
+`decoder_case.program_digest` holds a Program op for op (type, attributes,
+the name scope among them, the shapes it writes, the persistable variables it reads and
 writes by name) and parameter for parameter (name, shape, dtype, trainable), main and
 startup: what a checkpoint and the benchmark's `trace_scopes` reader find
-things by. `DIGESTS` and `CENSUS` were taken on the commit before
+things by. `decoder_case.DIGESTS` and `CENSUS` were taken on the commit before
 `models/_decoder.py`, `observe/census.py` and `ops/_kernels.py` existed
 (PR 58's parent; `olmo_hybrid`'s on PR 63, which added the model and gave the
 census of a program with `gated_delta_rule` ops the key
@@ -15,106 +15,24 @@ Nemotron-H's digest unmoved, and gave the census of a program with `ssd_scan`
 ops its groups, heads a group and chunk; `lfm2_moe`'s on PR 69, which added
 the model, `layers.causal_conv1d(activation=)`, `noaux_router(norm_eps=)` and
 the census kind `short_conv`, with every other digest and census unmoved) at
-the models' own tests' tiny sizes, forward,
+the models' own tests' tiny sizes (`decoder_case.tiny_args`), forward,
 backward and Adam; after a deliberate change to a model take them again with
 `program_digest(*build_program(model)[:2])` and
 `census.program_detail(build_program(model)[0])`.
 """
 
 import ast
-import hashlib
 import os
 
 import pytest
 
-import paddle_tpu as fluid
-from paddle_tpu import models
 from paddle_tpu.core import registry
 from paddle_tpu.observe import census
 
-from test_granite_hybrid import TINY as GRANITE_HYBRID_TINY
-from test_kanana2 import TINY as KANANA2_TINY
-from test_lfm2_moe import TINY as LFM2_MOE_TINY
-from test_keye_vl2 import TINY as KEYE_VL2_TINY
-from test_mellum2 import TINY as MELLUM2_TINY
-from test_nemotron_h import TINY as NEMOTRON_H_TINY
-from test_olmo_hybrid import TINY as OLMO_HYBRID_TINY
-from test_olmoe import TINY as OLMOE_TINY
-from test_ouro import TINY as OURO_TINY
-from test_qwen3_next import TINY as QWEN3_NEXT_TINY
-from test_trinity import TINY as TRINITY_TINY
+from decoder_case import DIGESTS, build_program, program_digest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 PACKAGE = os.path.join(HERE, "..", "paddle_tpu")
-
-SIZES = {"granite_hybrid": GRANITE_HYBRID_TINY, "lfm2_moe": LFM2_MOE_TINY,
-         "olmoe": OLMOE_TINY, "olmo_hybrid": OLMO_HYBRID_TINY,
-         "ouro": OURO_TINY,
-         "qwen3_next": QWEN3_NEXT_TINY, "kanana2": KANANA2_TINY,
-         "mellum2": MELLUM2_TINY, "trinity": TRINITY_TINY,
-         "keye_vl2": KEYE_VL2_TINY, "nemotron_h": NEMOTRON_H_TINY}
-
-
-def build_program(model):
-    """(main, startup, feeds, fetches) of one training step of
-    `models.<model>` at its own tests' tiny sizes: forward, backward and
-    Adam."""
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        feeds, fetches = getattr(models, model).build(**SIZES[model])
-        fluid.optimizer.Adam(learning_rate=1e-3).minimize(fetches["loss"])
-    return main, startup, feeds, fetches
-
-
-def program_digest(*programs):
-    """The programs' global blocks parameter for parameter and op for op:
-    every parameter's name, shape, dtype and whether it trains; every op's
-    type, attributes (its name scope is one; but the generated names), the
-    shapes of what it writes and the persistable variables it touches, by
-    slot."""
-    lines = []
-    for program in programs:
-        block = program.global_block()
-        lines += [f"parameter {p.name} {tuple(p.shape)} {p.dtype} "
-                  f"{p.trainable}" for p in block.all_parameters()]
-        kept = {n for n, v in block.vars.items() if v.persistable}
-        for op in block.ops:
-            attrs = sorted((k, repr(v)) for k, v in op.attrs.items()
-                           if not k.startswith("__") or k == "__role__")
-            outs = [tuple(block.var(n).shape) for n in op.output_arg_names
-                    if block.has_var(n)]
-            held = sorted((way, slot, n) for way, slots in
-                          (("in", op.inputs), ("out", op.outputs))
-                          for slot, names in slots.items()
-                          for n in names if n in kept)
-            lines.append(f"{op.type} {attrs} {outs} {held}")
-    return len(lines), hashlib.sha256("\n".join(lines).encode()).hexdigest()
-
-
-DIGESTS = {
-    "granite_hybrid": (1670, "b8d7a55da93effa62a1255980f4075f3"   # PR 65's own
-                             "07c6385913c2ee7311fc1c317fe9f87d"),
-    "kanana2": (618, "7e1a4d0a35d9e8c487e85c5fd2d5ca8f"
-                     "8a05a524c1f8174aa3de0527033685df"),
-    "keye_vl2": (655, "0649d664f592fadd5d847958da86b217"
-                      "95f2689bda87d28ed6e4b2dfe0ed6e23"),
-    "lfm2_moe": (700, "232d480348c1f734b566184cf830617a"          # PR 69's own
-                      "648afc881d999dcea62b73969ab340ee"),
-    "mellum2": (747, "63f94049afbd0dd88ed8281da85f666f"
-                     "5379fe7da48e1c9c99f130000d3af739"),
-    "nemotron_h": (957, "bb4a9beb840fe95d57a8b1bee2a34470"
-                        "a3444ab5db5c1fccba256058024defa9"),
-    "olmoe": (397, "d8847a00387005278bfc31daf8556e30"
-                   "1eb7d918126f76aafef7dc06b6d29cb9"),
-    "olmo_hybrid": (725, "3d6b51bdb3f5b30704e77b84ef566b09"       # PR 63's own
-                         "25752028f333b5369d0c325de1a10c8e"),
-    "ouro": (790, "6ea230c9082de14ccaa4df13364540aa"
-                  "790498e29ea98a844998f066efecfee8"),
-    "qwen3_next": (1021, "e431cca320eca95789aee1fbdb3a8e2f"
-                         "b2607d1abf037deb504eee331f0fb8e3"),
-    "trinity": (1231, "391890374a0cbe3ad429a97497bb07a2"
-                      "ab5c431c4f6824d731aeff15d0707e75"),
-}
 
 CENSUS = {
     "granite_hybrid": {
@@ -194,12 +112,12 @@ CENSUS = {
 }
 
 
-@pytest.mark.parametrize("model", sorted(SIZES))
+@pytest.mark.parametrize("model", sorted(DIGESTS))
 def test_a_decoder_program_is_unchanged_op_for_op(model):
     assert program_digest(*build_program(model)[:2]) == DIGESTS[model]
 
 
-@pytest.mark.parametrize("model", sorted(SIZES))
+@pytest.mark.parametrize("model", sorted(CENSUS))
 def test_a_decoder_programs_compile_detail_is_unchanged(model):
     assert census.program_detail(build_program(model)[0]) == CENSUS[model]
 

@@ -21,8 +21,9 @@ from paddle_tpu.ops.pallas_attention import (flash_attention,
                                              _attention_reference,
                                              ring_attention)
 
-from attention_program import (attention_grads, float32_grad_layer,
-                               kernel_calls, qkv_feed, step_text)
+from attention_program import (attention_grads, flash_calls,
+                               float32_grad_layer, kernel_calls, qkv_feed,
+                               step_text)
 
 
 # the one-pass forward kernel (a row is one K block, `_fwd_plan`) and the
@@ -802,49 +803,6 @@ def test_windowed_grids_cover_the_bands_tiles(monkeypatch, T, tiles, window,
 
 
 # -- a causal grid's dead steps fetch nothing -------------------------------------
-
-def flash_calls(monkeypatch, T, tiles, causal=True, token_major=False,
-                kept=False, split=False, every_step=False):
-    """name -> (grid, input specs) of the forward and the backward calls of
-    one row length, as `_forward` and `_bwd_specs` build them: `pallas_call`
-    is stood in for, nothing runs. `every_step`: the maps without the hold,
-    every operand's own block on every grid step."""
-    from jax.experimental import pallas as pl
-
-    calls = {}
-
-    def pallas_call(kernel, *, grid, in_specs, out_shape, name, **kw):
-        calls[name] = (grid, in_specs)
-        return lambda *operands: jax.tree.map(
-            lambda x: jnp.zeros(x.shape, x.dtype), out_shape)
-
-    B, H, D = 2, 2, 128
-    x = jax.ShapeDtypeStruct((B, T, H, D) if token_major else (B, H, T, D),
-                             jnp.float32)
-    the_set = jnp.ones((B, T, T), jnp.int8) if kept else None
-
-    def both(q, k, v, g):
-        out, lse = pallas_attention._flash_forward(
-            q, k, v, causal, 1.0, token_major=token_major, kept=the_set)
-        return pallas_attention._flash_backward(
-            q, k, v, out, lse, g, causal, 1.0, 0.0, 0,
-            token_major=token_major, kept=the_set)
-
-    with monkeypatch.context() as patch:
-        patch.setattr(pl, "pallas_call", pallas_call)
-        patch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
-        # a jitted call keeps its trace: the stand-in has to be called
-        patch.setattr(pallas_attention, "_token_major_forward",
-                      pallas_attention._forward)
-        patch.setattr(pallas_attention, "_token_major_backward",
-                      pallas_attention._backward)
-        patch.setattr(pallas_attention, "_bwd_plan",
-                      lambda *a: "split" if split else "fused")
-        if every_step:
-            patch.setattr(pallas_attention, "_dead_steps", lambda *a: 0)
-        jax.eval_shape(both, x, x, x, x)
-    return calls
-
 
 def walk_the_grid(name, call, every, bq, bk):
     """The input blocks of the causal call `name` over its grid in the

@@ -18,8 +18,9 @@ from paddle_tpu.ops import _kernels
 from paddle_tpu.ops import decoder_block as db
 
 from attention_program import kernel_calls, step_text
-from test_olmoe import run_piece
-from test_qwen3_next import TINY
+from decoder_case import run_piece, tiny_args
+
+TINY = tiny_args("qwen3_next")
 
 EPS = 1e-6
 # (leading dims, tokens, heads, head): the published head of 128 at 2 and 32

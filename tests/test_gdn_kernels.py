@@ -25,8 +25,8 @@ from paddle_tpu.ops import _kernels
 from paddle_tpu.ops import linear_attention as la
 
 import qwen3_next_reference as ref
-from test_olmoe import piece_noted, run_piece
-from test_qwen3_next import REGIMES, RTOL, frob
+from decoder_case import (REGIMES, RTOL, _instruction, frob, piece_noted,
+                          run_piece)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 B, HK, HV, D, CHUNK = 1, 2, 4, 128, 64
@@ -384,20 +384,6 @@ def test_the_program_declares_the_saved_states_at_the_given_head_dims():
 def _metric(name):
     with open(os.path.join(ROOT, "benchmark", "metrics", name + ".json")) as f:
         return json.load(f)
-
-
-def _instruction(eqn):
-    """The line a TPU trace names a `pallas_call` by: its name, then the
-    tuple of its results in row-major layouts."""
-    def result(aval):
-        dtype = {"float32": "f32", "bfloat16": "bf16"}[str(aval.dtype)]
-        dims = ",".join(str(d) for d in aval.shape)
-        order = ",".join(str(i) for i in reversed(range(len(aval.shape))))
-        return f"{dtype}[{dims}]{{{order}}}"
-
-    name = eqn.params["name"]
-    results = ", ".join(result(v.aval) for v in eqn.outvars)
-    return f"%{name}.1 = ({results}) custom-call(%reshape.8, %reshape.9)"
 
 
 def _cell_instructions():

@@ -16,10 +16,8 @@ reason). The chunked scan against the recurrence sums the same products in
 another order, exponentials of differences in place of products of
 exponentials: 2e-5 of the largest value (RTOL)."""
 
-import filecmp
 import json
 import os
-import subprocess
 import sys
 
 import jax
@@ -28,29 +26,25 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu import layers, models, observe
+from paddle_tpu import layers, models
 from paddle_tpu.core import registry
 from paddle_tpu.observe import census
 from paddle_tpu.ops import decoder_block as db
 from paddle_tpu.ops import state_space as ss
 
 import granite_hybrid_reference as ref
-from test_nemotron_h import (SCAN_NAMES, _forward_ops_by_scope,
-                             _published_scan, _recurrence, _scan_inputs,
-                             _scan_layer)
-from test_olmoe import rel_err, run_piece
-from test_qwen3_next import frob
+from decoder_case import (ROOT, SCAN_NAMES, DecoderCase,
+                          _forward_ops_by_scope, _published_scan, _recurrence,
+                          _scan_inputs, _scan_layer, build_program,
+                          carries_the_census, config, frob, rel_err,
+                          run_piece, runs_through_the_benchmark, tiny_args)
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(HERE)
-with open(os.path.join(ROOT, "benchmark", "configs",
-                       "granite_4_0_h_micro.json")) as f:
-    CONFIG = json.load(f)
+CONFIG = config("granite_hybrid")
 TYPES = ["mamba"] * 5 + ["attention"] + ["mamba"] * 4
 # the model's own first ten layers, hidden 64, feed-forwards of 96, ONE group
 # of 4 state-space heads of 16 over a state of 16, 128 tokens in chunks of 64,
 # 4/2 attention heads of 16 at the published scale 1/64
-TINY = {**CONFIG["build_args"], **CONFIG["tiny"]["build_args"]}
+TINY = tiny_args("granite_hybrid")
 REF_KW = {k: TINY[k] for k in (
     "layer_types", "mamba_heads", "mamba_head_dim", "n_groups", "ssm_state",
     "n_head", "n_kv_head", "head_dim", "embedding_multiplier",
@@ -264,28 +258,6 @@ def test_a_wide_head_takes_fewer_rows_a_block(T, H, D, itemsize, forward,
 
 # -- the model -----------------------------------------------------------------------------------
 
-def _program(optimizer=None, **sizes):
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        feeds, fetches = models.granite_hybrid.build(**{**TINY, **sizes})
-        if optimizer is None:
-            pairs = fluid.append_backward(fetches["loss"])
-        else:
-            optimizer.minimize(fetches["loss"])
-            pairs = []
-    main.random_seed = startup.random_seed = 7
-    return main, startup, fetches, pairs
-
-
-def _batch(seed=0, batch=2):
-    rng = np.random.RandomState(seed)
-    shape = (batch, TINY["seq_len"])
-    return {"tokens": rng.randint(0, TINY["vocab_size"], shape)
-            .astype(np.int32),
-            "labels": rng.randint(0, TINY["vocab_size"], shape)
-            .astype(np.int32)}
-
-
 def _seeded_values(shapes, seed=3):
     """Weights far from their initial values, so that no term of the
     comparison is small by construction: norm weights and D in [0.5, 1.5],
@@ -323,35 +295,18 @@ def _seeded_values(shapes, seed=3):
 FETCHES = ["loss", "ce", "logits"]
 
 
-def _run_tiny(amp, seeded=True, weights=None, **sizes):
-    main, startup, fetches, pairs = _program(**sizes)
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace(), amp=amp)
-    exe.run(startup, scope=scope)
-    names = [p.name for p in main.global_block().all_parameters()]
-    if seeded:
-        weights = weights or _seeded_values(
-            {n: np.shape(scope.find_var(n)) for n in names})
-        for name in names:
-            scope.set_var(name, jnp.asarray(weights[name]))
-    params = {n: np.asarray(scope.find_var(n)) for n in names}
-    feed = _batch()
-    out = exe.run(main, feed=feed,
-                  fetch_list=[fetches[n] for n in FETCHES]
-                  + [g for _, g in pairs], scope=scope)
-    got = dict(zip(FETCHES, out))
-    grads = dict(zip((p.name for p, _ in pairs), out[len(FETCHES):]))
-    return main, params, feed, got, grads
+# what each planted fault has to move, at least: the logits or a gradient by
+# 1% where the true reference is met within 2e-4
+FAULT_WRT = ["embed.w", "l0.mamba.in.w", "l0.mamba.A_log", "l0.mamba.dt_bias",
+             "l0.mamba.conv.b", "l0.mamba.norm.w", "l2.mamba.D",
+             "l5.attn.q.w", "l5.attn.k.w", "l0.mlp.up.w", "final_norm.w"]
+CASE = DecoderCase(models.granite_hybrid.build, TINY, ref, REF_KW, FETCHES,
+                   seeded_values=_seeded_values, fault_wrt=FAULT_WRT)
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    main, params, feed, got, grads = _run_tiny(amp=False)
-    tokens, labels = jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"])
-    want, want_grads = ref.loss_and_grads(
-        params, tokens, labels, last=TINY["seq_len"], **REF_KW)
-    return dict(main=main, params=params, tokens=tokens, labels=labels,
-                got=got, grads=grads, want=want, want_grads=want_grads)
+    return CASE.tiny_model()
 
 
 MAMBA = ["mamba.in.w", "mamba.conv.w", "mamba.conv.b", "mamba.A_log",
@@ -365,27 +320,21 @@ TRAINED = (["embed.w", "final_norm.w"]
 
 
 def test_tiny_model_has_the_reference_parameters(tiny):
-    assert sorted(tiny["params"]) == sorted(TRAINED)
-    assert "head.w" not in tiny["params"]           # tied
-    shapes = {n: v.shape for n, v in tiny["params"].items()}
     inner, bc = 4 * 16, 1 * 16
-    assert shapes["embed.w"] == (128, 64)
-    assert shapes["l0.mamba.in.w"] == (64, 2 * inner + 2 * bc + 4)
-    assert shapes["l0.mamba.conv.w"] == (inner + 2 * bc, 4)
-    assert shapes["l0.mamba.conv.b"] == (inner + 2 * bc,)
-    assert shapes["l2.mamba.A_log"] == shapes["l2.mamba.dt_bias"] \
-        == shapes["l2.mamba.D"] == (4,)
-    assert shapes["l4.mamba.norm.w"] == (inner,)
-    assert shapes["l5.attn.q.w"] == (64, 4 * 16)
-    assert shapes["l5.attn.k.w"] == shapes["l5.attn.v.w"] == (64, 2 * 16)
-    assert shapes["l5.mlp.gate.w"] == shapes["l0.mlp.up.w"] == (64, 96)
-    assert shapes["l9.mlp.down.w"] == (96, 64)
-    # a gradient for every parameter
-    assert sorted(tiny["grads"]) == sorted(TRAINED)
+    CASE.has_the_reference_parameters(tiny, TRAINED, {
+        "embed.w": (128, 64), "l0.mamba.in.w": (64, 2 * inner + 2 * bc + 4),
+        "l0.mamba.conv.w": (inner + 2 * bc, 4),
+        "l0.mamba.conv.b": (inner + 2 * bc,), "l2.mamba.A_log": (4,),
+        "l2.mamba.dt_bias": (4,), "l2.mamba.D": (4,),
+        "l4.mamba.norm.w": (inner,), "l5.attn.q.w": (64, 4 * 16),
+        "l5.attn.k.w": (64, 2 * 16), "l5.attn.v.w": (64, 2 * 16),
+        "l5.mlp.gate.w": (64, 96), "l0.mlp.up.w": (64, 96),
+        "l9.mlp.down.w": (96, 64)})
+    assert "head.w" not in tiny["params"]           # tied
 
 
 def test_the_initial_values_are_the_public_ones():
-    main, startup, _, _ = _program()
+    main, startup, _, _ = CASE.program()
     scope = fluid.Scope()
     fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
     value = lambda n: np.asarray(scope.find_var(n))
@@ -411,13 +360,12 @@ def test_the_initial_values_are_the_public_ones():
 
 @pytest.mark.parametrize("name", FETCHES)
 def test_tiny_model_output_matches_reference(tiny, name):
-    want = np.asarray(tiny["want"][name])
-    assert rel_err(np.reshape(tiny["got"][name], want.shape), want) < 1e-4
+    CASE.output_matches_reference(tiny, name)
 
 
 @pytest.mark.parametrize("name", TRAINED)
 def test_tiny_model_gradient_matches_reference(tiny, name):
-    assert frob(tiny["grads"][name], tiny["want_grads"][name]) < 2e-4
+    CASE.gradient_matches_reference(tiny, name)
 
 
 # -- the tied table ------------------------------------------------------------------------------
@@ -428,8 +376,8 @@ def test_the_tied_tables_gradient_is_the_sum_of_the_untied_models_two(tiny):
     gradient is the untied embedding's (the look-up's row scatter) plus the
     untied head's, transposed (the dense product)."""
     weights = {**tiny["params"], "head.w": tiny["params"]["embed.w"].T.copy()}
-    _, params, _, got, grads = _run_tiny(amp=False, weights=weights,
-                                         tie_embeddings=False)
+    _, params, _, got, grads, _ = CASE.run_tiny(
+        amp=False, weights=weights, tie_embeddings=False)
     assert sorted(params) == sorted(TRAINED + ["head.w"])
     assert rel_err(got["logits"], tiny["got"]["logits"]) < 1e-6
     both = grads["embed.w"] + grads["head.w"].T
@@ -449,7 +397,7 @@ def test_the_tied_tables_gradient_is_the_sum_of_the_untied_models_two(tiny):
 def test_the_table_is_read_twice_and_summed_once(tiny):
     """One parameter, two forward reads (`lookup_table`'s W and `matmul`'s Y),
     a fan-in of two in the backward pass, one Adam op."""
-    main, _, _, _ = _program(fluid.optimizer.Adam(learning_rate=1e-3))
+    main, _, _, _ = CASE.program(fluid.optimizer.Adam(learning_rate=1e-3))
     block = main.global_block()
     reads = [op.type for op in block.ops
              if op.attrs.get("__role__") is None
@@ -481,8 +429,8 @@ def test_a_tied_head_reads_the_table_embed_made():
 def test_each_multiplier_is_read_from_its_argument(tiny, name):
     """Set to 1 the program is another function, and the reference's with
     the same argument."""
-    _, params, _, got, grads = _run_tiny(amp=False, weights=tiny["params"],
-                                         **{name: 1.0})
+    _, params, _, got, grads, _ = CASE.run_tiny(
+        amp=False, weights=tiny["params"], **{name: 1.0})
     moved = rel_err(got["logits"], tiny["got"]["logits"])
     assert moved > 0.01, (name, moved)
     want, want_grads = ref.loss_and_grads(
@@ -507,31 +455,11 @@ def test_the_softmax_scale_is_the_published_number(tiny):
 
 # -- the planted faults --------------------------------------------------------------------------
 
-# what each planted fault has to move, at least: the logits or a gradient by
-# 1% where the true reference is met within 2e-4
-FAULT_WRT = ["embed.w", "l0.mamba.in.w", "l0.mamba.A_log", "l0.mamba.dt_bias",
-             "l0.mamba.conv.b", "l0.mamba.norm.w", "l2.mamba.D",
-             "l5.attn.q.w", "l5.attn.k.w", "l0.mlp.up.w", "final_norm.w"]
-
-
 @pytest.mark.parametrize("fault", sorted(ref.FAULTS))
 def test_each_planted_fault_is_refused(tiny, fault):
-    """The comparison that passes the reference refuses each fault: the
-    logits or a gradient moves by far more than the system's distance from
-    the true reference. (`untied_head` moves no forward number: the table's
-    gradient alone.)"""
-    bad, bad_grads = ref.loss_and_grads(
-        tiny["params"], tiny["tokens"], tiny["labels"], wrt=FAULT_WRT,
-        last=TINY["seq_len"], fault=fault, **REF_KW)
-    moved = [rel_err(tiny["got"]["logits"], bad["logits"])] \
-        + [frob(tiny["grads"][n], bad_grads[n]) for n in FAULT_WRT]
-    held = [rel_err(tiny["got"]["logits"], tiny["want"]["logits"])] \
-        + [frob(tiny["grads"][n], tiny["want_grads"][n]) for n in FAULT_WRT]
-    assert max(held) < 2e-4
-    # a fault that overflows (a step size below 0 makes the decay a growth)
-    # reads nan: not within any limit, as `run.py::misses` has it
-    assert not max(np.nan_to_num(moved, nan=np.inf)) <= 50 * 2e-4, \
-        (fault, moved)
+    """(`untied_head` moves no forward number: the table's gradient
+    alone.)"""
+    CASE.planted_fault_is_refused(tiny, fault)
 
 
 def test_the_config_names_every_fault_and_no_other():
@@ -540,35 +468,21 @@ def test_the_config_names_every_fault_and_no_other():
 
 
 def test_an_unknown_fault_is_refused(tiny):
-    with pytest.raises(ValueError, match="fault is one of"):
-        ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
-                       fault="no_such", **REF_KW)
+    CASE.unknown_fault_is_refused(tiny)
 
 
 def test_reference_in_blocks_is_the_reference(tiny):
-    """`q_block`, `token_block` and `remat` are the reference's memory, not
-    its mathematics."""
-    parts, grads = ref.loss_and_grads(
-        tiny["params"], tiny["tokens"], tiny["labels"],
-        wrt=["l0.mamba.in.w", "l2.mamba.A_log", "l5.attn.k.w",
-             "l3.mlp.down.w", "embed.w"],
-        q_block=32, token_block=16, remat=True, **REF_KW)
-    assert abs(float(parts["loss"]) - float(tiny["want"]["loss"])) < 1e-5
-    for name, g in grads.items():
-        assert frob(g, tiny["want_grads"][name]) < 1e-5, name
+    CASE.reference_in_blocks_is_the_reference(
+        tiny, ["l0.mamba.in.w", "l2.mamba.A_log", "l5.attn.k.w",
+               "l3.mlp.down.w", "embed.w"], q_block=32, token_block=16)
 
 
 def test_reference_last_positions_equal_the_full_pass(tiny):
-    parts = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
-                           last=16, **REF_KW)
-    assert rel_err(parts["logits"], tiny["want"]["logits"][:, -16:]) < 1e-6
+    CASE.reference_last_positions_equal_the_full_pass(tiny)
 
 
 def test_reference_in_bfloat16_is_another_number(tiny):
-    low = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
-                         dtype=jnp.bfloat16, **REF_KW)
-    assert low["loss"].dtype == jnp.bfloat16
-    assert abs(float(low["loss"]) - float(tiny["want"]["loss"])) > 1e-4
+    CASE.reference_in_bfloat16_is_another_number(tiny)
 
 
 # -- AMP -----------------------------------------------------------------------------------------
@@ -582,21 +496,11 @@ def test_tiny_model_amp_within_bf16_of_reference():
     mean, the loss within 0.002, a gradient within 5% in the Frobenius norm,
     the decay's and step size's (a few numbers downstream of every rounding)
     within 15%."""
-    main, params, feed, got, grads = _run_tiny(amp=True, seeded=False)
-    want, want_grads = ref.loss_and_grads(
-        params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
-        last=TINY["seq_len"], **REF_KW)
-    assert abs(float(got["loss"][0]) - float(want["loss"])) < 0.002
-    assert got["logits"].dtype == jnp.bfloat16
-    err = np.abs(np.asarray(got["logits"], np.float32)
-                 - np.asarray(want["logits"]))
-    assert err.mean() < 0.002 and err.max() < 0.02
-    for name in ("l0.mamba.in.w", "l0.mamba.out.w", "l5.attn.k.w",
-                 "l0.mlp.up.w", "embed.w", "final_norm.w"):
-        assert grads[name].dtype == jnp.float32
-        assert frob(grads[name], want_grads[name]) < 0.05, name
-    for name in ("l0.mamba.A_log", "l0.mamba.dt_bias", "l0.mamba.conv.b"):
-        assert frob(grads[name], want_grads[name]) < 0.15, name
+    CASE.amp_within_bf16_of_reference(
+        {0.05: ("l0.mamba.in.w", "l0.mamba.out.w", "l5.attn.k.w",
+                "l0.mlp.up.w", "embed.w", "final_norm.w"),
+         0.15: ("l0.mamba.A_log", "l0.mamba.dt_bias", "l0.mamba.conv.b")},
+        mean=0.002, most=0.02, of_std=False)
 
 
 def test_amp_lists_say_what_reads_the_table_in_which_precision():
@@ -612,15 +516,7 @@ def test_amp_lists_say_what_reads_the_table_in_which_precision():
 
 
 def test_five_adam_steps_lower_the_loss():
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.Adam(learning_rate=3e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    feed = _batch()
-    losses = [float(exe.run(main, feed=feed, fetch_list=[fetches["loss"]],
-                            scope=scope)[0][0]) for _ in range(6)]
-    assert np.all(np.isfinite(losses)) and losses[-1] < losses[0] - 0.05
+    CASE.adam_steps_lower_the_loss()
 
 
 def test_layer_types_is_a_list_over_mamba_and_attention():
@@ -673,27 +569,20 @@ def test_layer_census_reads_the_issues_counts():
     """9 state-space layers of one group of 64 heads at chunk 256, 1
     full-attention layer with no rotary at a key-value group of 4, one tied
     head, 20 sublayers under a residual multiplier."""
-    main, _, _, _ = _program(fluid.optimizer.SGD(learning_rate=1e-3),
+    main, _, _, _ = CASE.program(fluid.optimizer.SGD(learning_rate=1e-3),
                              **CENSUS_SIZES)
     got = census.layer_census(main)
     assert got == CENSUS
     assert "attention_rotary_layers" not in got
     assert "dense_ffn_layers" not in got        # that key goes with routers
     # untied, and with every multiplier written as 1, the program says so
-    main, _, _, _ = _program(tie_embeddings=False)
+    main, _, _, _ = CASE.program(tie_embeddings=False)
     assert "tied_heads" not in census.layer_census(main)
 
 
 @pytest.fixture(scope="module")
 def compile_detail():
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.SGD(learning_rate=1e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    exe.run(main, feed=_batch(), fetch_list=[fetches["loss"]], scope=scope)
-    latest = observe.observatory().latest
-    return latest(main._uid).detail, latest(startup._uid).detail
+    return CASE.compile_detail()
 
 
 @pytest.mark.parametrize("key,value", [
@@ -703,11 +592,8 @@ def compile_detail():
     ("ssd_plan", "xla"), ("tied_heads", 1),
     ("residual_scaled_sublayers", 20), ("grad_fanin_max", 2)])
 def test_compile_event_carries_the_census(compile_detail, key, value):
-    detail, startup_detail = compile_detail
-    assert detail[key] == value
-    # the startup program holds no layer (its sharing counts read 0)
-    assert not startup_detail.get(key)
-    assert "ssd_grid_steps" not in detail       # tallied where kernels run
+    carries_the_census(compile_detail, {key: value},
+                       absent=["ssd_grid_steps"])   # tallied where kernels run
 
 
 @pytest.mark.parametrize("model,keys", [
@@ -718,8 +604,7 @@ def test_the_new_keys_go_with_what_they_count(model, keys):
     """Nemotron-H's scans gain their three keys; no program without a scan,
     a tied table or a scaled branch gains any (Trinity scales its embedding
     outside every scope, Ouro shares every weight but ties no head)."""
-    import test_decoder_models
-    got = census.layer_census(test_decoder_models.build_program(model)[0])
+    got = census.layer_census(build_program(model)[0])
     new = ("state_space_groups", "state_space_heads_per_group",
            "state_space_chunk", "tied_heads", "residual_scaled_sublayers")
     assert {k: got[k] for k in new if k in got} == keys
@@ -728,10 +613,7 @@ def test_the_new_keys_go_with_what_they_count(model, keys):
 # -- the copies and the harness ------------------------------------------------------------------
 
 def test_the_two_copies_of_the_reference_are_identical():
-    assert filecmp.cmp(
-        os.path.join(HERE, "granite_hybrid_reference.py"),
-        os.path.join(ROOT, "benchmark", "references",
-                     "granite_hybrid_reference.py"), shallow=False)
+    CASE.two_copies_of_the_reference_are_identical()
 
 
 def test_the_config_holds_the_published_widths_and_the_cut():
@@ -800,16 +682,4 @@ def test_the_parameter_count_is_the_programs():
 
 
 def test_the_tiny_block_runs_through_the_benchmark():
-    """`run.py --tiny` on the cell: the configuration's tiny block through
-    the harness's own rehearsal, the in-run reference comparison
-    included."""
-    out = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
-         "--workload", "granite_4_0_h_micro.s2048", "--seed",
-         "3000000019", "--seconds", "1", "--trace", "0", "--tiny"],
-        capture_output=True, text=True, timeout=600, cwd=ROOT,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
-    last = json.loads(out.stdout.strip().splitlines()[-1])
-    assert last["correct"] is True and last["rehearsal"] is True
-    assert "reference check after" in out.stdout
+    runs_through_the_benchmark("granite_4_0_h_micro.s2048")

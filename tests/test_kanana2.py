@@ -6,33 +6,24 @@ through `layers` -> Program IR -> `Executor`, against the plain reference
 loop over the held experts, `next_bias`). Seeded random weights, float32, AMP
 off unless a test says otherwise."""
 
-import filecmp
-import json
-import os
-import subprocess
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu import io, layers, models, observe
+from paddle_tpu import io, layers, models
 from paddle_tpu.core import ir, registry
 
 import kanana2_reference as ref
-from test_olmoe import rel_err, run_piece
-from test_qwen3_next import frob
+from decoder_case import (DIGESTS, DecoderCase, _planted, build_program,
+                          carries_the_census, frob,
+                          layers_are_built_under_their_scopes, program_digest,
+                          rel_err, run_piece, runs_through_the_benchmark,
+                          tiny_args)
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(HERE)
-GAMMA = 0.001
-TINY = dict(vocab_size=64, seq_len=128, n_layer=3, n_dense_layer=1,
-            d_model=32, d_dense=48, n_head=4, kv_rank=16, qk_nope_dim=16,
-            qk_rope_dim=8, v_head_dim=16, rope_theta=1e4, n_expert=16,
-            top_k=3, d_expert=16, n_shared=2, routed_scaling_factor=2.448,
-            bias_update_rate=GAMMA, first_expert=4, experts_held=4)
+TINY = tiny_args("kanana2")
+GAMMA = TINY["bias_update_rate"]
 REF_KW = {k: TINY[k] for k in (
     "n_layer", "n_head", "qk_nope_dim", "qk_rope_dim", "v_head_dim",
     "rope_theta", "top_k", "first_expert", "routed_scaling_factor")}
@@ -69,13 +60,6 @@ def test_interleaved_rotary_is_the_public_codes_deinterleave_form():
 
 
 # -- the router: sigmoid scores, a bias that moves the choice alone -----------------
-
-def _planted(name, value):
-    """A bias that starts at `value`: `run_piece` takes the gradient of every
-    parameter it is handed, and the bias has none."""
-    return fluid.ParamAttr(
-        name=name, initializer=fluid.initializer.NumpyArrayInitializer(value))
-
 
 def _route(x, w, b, k=3, **kw):
     attrs = dict(norm_topk_prob=True, score_func="sigmoid", norm_eps=1e-20,
@@ -227,40 +211,15 @@ def test_the_eight_shares_add_up_to_the_whole_layer(path, monkeypatch):
 
 # -- the model ----------------------------------------------------------------------------
 
-def _program(optimizer=None, **sizes):
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        feeds, fetches = models.kanana2.build(**{**TINY, **sizes})
-        if optimizer is None:
-            pairs = fluid.append_backward(fetches["loss"])
-        else:
-            optimizer.minimize(fetches["loss"])
-            pairs = []
-    main.random_seed = startup.random_seed = 7
-    return main, startup, fetches, pairs
-
-
-def _batch(seed=0, batch=2):
-    rng = np.random.RandomState(seed)
-    shape = (batch, TINY["seq_len"])
-    return {"tokens": rng.randint(0, TINY["vocab_size"], shape)
-            .astype(np.int32),
-            "labels": rng.randint(0, TINY["vocab_size"], shape)
-            .astype(np.int32)}
-
-
-def _parameter_names(main):
-    return [p.name for p in main.global_block().all_parameters()]
-
-
-def _seeded_weights(scope, names, seed=3):
+def _seeded_values(shapes, seed=3):
     """Weights far from their initial values, so that no term of the
     comparison is small by construction: norm weights in [0.5, 1.5], a
     router five times as sharp, a planted bias of std 0.2 (the sigmoids'
     spread is about 0.25), matrices of std 0.1 (five times the initial)."""
     rng = np.random.RandomState(seed)
-    for name in sorted(names):
-        shape = np.shape(scope.find_var(name))
+    values = {}
+    for name in sorted(shapes):
+        shape = shapes[name]
         if name.endswith("router.bias"):
             value = rng.randn(*shape) * 0.2
         elif "norm" in name:
@@ -269,41 +228,19 @@ def _seeded_weights(scope, names, seed=3):
             value = rng.randn(*shape) * 0.5
         else:
             value = rng.randn(*shape) * 0.1
-        scope.set_var(name, jnp.asarray(value.astype(np.float32)))
+        values[name] = value.astype(np.float32)
+    return values
 
 
 FETCHES = ["loss", "ce", "logits", "tokens_per_expert"]
-
-
-def _run_tiny(amp, seeded=True):
-    main, startup, fetches, pairs = _program()
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace(), amp=amp)
-    exe.run(startup, scope=scope)
-    names = _parameter_names(main)
-    if seeded:
-        _seeded_weights(scope, names)
-    params = {n: np.asarray(scope.find_var(n)) for n in names}
-    feed = _batch()
-    out = exe.run(main, feed=feed,
-                  fetch_list=[fetches[n] for n in FETCHES]
-                  + [g for _, g in pairs], scope=scope)
-    got = dict(zip(FETCHES, out))
-    grads = dict(zip((p.name for p, _ in pairs), out[len(FETCHES):]))
-    after = {n: np.asarray(scope.find_var(n)) for n in names
-             if n.endswith("router.bias")}
-    return main, params, feed, got, grads, after
+BIASES = ["l1.router.bias", "l2.router.bias"]
+CASE = DecoderCase(models.kanana2.build, TINY, ref, REF_KW, FETCHES,
+                   state=BIASES, seeded_values=_seeded_values)
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    main, params, feed, got, grads, after = _run_tiny(amp=False)
-    tokens, labels = jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"])
-    want, want_grads = ref.loss_and_grads(
-        params, tokens, labels, last=TINY["seq_len"], **REF_KW)
-    return dict(main=main, params=params, tokens=tokens, labels=labels,
-                got=got, grads=grads, after=after, want=want,
-                want_grads=want_grads)
+    return CASE.tiny_model()
 
 
 MLA = ["in_norm.w", "post_norm.w", "mla.q.w", "mla.kv_a.w", "mla.kv_norm.w",
@@ -314,78 +251,48 @@ MOE = ["router.w", "experts.gate.w", "experts.up.w", "experts.down.w",
 TRAINED = (["embed.w", "final_norm.w", "head.w"]
            + [f"l{i}.{n}" for i in range(3)
               for n in MLA + (DENSE if i == 0 else MOE)])
-BIASES = ["l1.router.bias", "l2.router.bias"]
 
 
 def test_tiny_model_has_the_reference_parameters(tiny):
-    assert sorted(tiny["params"]) == sorted(TRAINED + BIASES)
-    assert tiny["params"]["l1.experts.gate.w"].shape == (4, 32, 16)
-    assert tiny["params"]["l1.router.w"].shape == (32, 16)
-    assert tiny["params"]["l1.router.bias"].shape == (16,)
-    assert tiny["params"]["l0.mla.q.w"].shape == (32, 4 * (16 + 8))
-    assert tiny["params"]["l0.mla.kv_a.w"].shape == (32, 16 + 8)
-    assert tiny["params"]["l0.mla.kv_b.w"].shape == (16, 4 * (16 + 16))
-    assert tiny["params"]["l1.shared.gate.w"].shape == (32, 2 * 16)
-    assert tiny["params"]["l0.mlp.gate.w"].shape == (32, 48)
-    # a gradient for every trained parameter and for no bias
-    assert sorted(tiny["grads"]) == sorted(TRAINED)
+    CASE.has_the_reference_parameters(tiny, TRAINED, {
+        "l1.experts.gate.w": (4, 32, 16), "l1.router.w": (32, 16),
+        "l1.router.bias": (16,), "l0.mla.q.w": (32, 4 * (16 + 8)),
+        "l0.mla.kv_a.w": (32, 16 + 8), "l0.mla.kv_b.w": (16, 4 * (16 + 16)),
+        "l1.shared.gate.w": (32, 2 * 16), "l0.mlp.gate.w": (32, 48)})
 
 
 @pytest.mark.parametrize("name", FETCHES)
 def test_tiny_model_output_matches_reference(tiny, name):
-    if name == "tokens_per_expert":
-        assert np.array_equal(tiny["got"][name], tiny["want"][name])
-    else:
-        want = np.asarray(tiny["want"][name])
-        assert rel_err(np.reshape(tiny["got"][name], want.shape), want) < 1e-4
+    CASE.output_matches_reference(tiny, name)
 
 
 def test_tiny_routing_sends_most_assignments_elsewhere(tiny):
-    counts = tiny["got"]["tokens_per_expert"]
-    assert counts.shape == (2, 16) and np.all(counts.sum(1) == 2 * 128 * 3)
-    held = counts[:, 4:8].sum(1)
-    assert np.all(held > 0) and np.all(held < counts.sum(1) / 2)
+    CASE.routing_sends_most_assignments_elsewhere(tiny, routed_layers=2)
 
 
 @pytest.mark.parametrize("name", TRAINED)
 def test_tiny_model_gradient_matches_reference(tiny, name):
-    assert frob(tiny["grads"][name], tiny["want_grads"][name]) < 2e-4
+    CASE.gradient_matches_reference(tiny, name)
 
 
 @pytest.mark.parametrize("layer", [1, 2])
 def test_one_step_moves_the_bias_as_next_bias_does(tiny, layer):
-    name = f"l{layer}.router.bias"
-    want = ref.next_bias(tiny["params"][name],
-                         tiny["got"]["tokens_per_expert"][layer - 1], GAMMA)
-    assert np.array_equal(tiny["after"][name], np.asarray(want))
-    moved = tiny["after"][name] - tiny["params"][name]
-    assert np.all(np.isclose(np.abs(moved), GAMMA, rtol=1e-3)
-                  | (moved == 0)) and np.any(moved != 0)
+    CASE.one_step_moves_the_bias_as_next_bias_does(
+        tiny, f"l{layer}.router.bias", GAMMA)
 
 
 def test_reference_in_blocks_is_the_reference(tiny):
-    """`q_block` and `remat` are the reference's memory, not its
-    mathematics."""
-    parts, grads = ref.loss_and_grads(
-        tiny["params"], tiny["tokens"], tiny["labels"],
-        wrt=["l0.mla.q.w", "l1.mla.kv_b.w", "l2.router.w", "embed.w"],
-        q_block=32, remat=True, **REF_KW)
-    assert abs(float(parts["loss"]) - float(tiny["want"]["loss"])) < 1e-5
-    for name, g in grads.items():
-        assert frob(g, tiny["want_grads"][name]) < 1e-5, name
+    CASE.reference_in_blocks_is_the_reference(
+        tiny, ["l0.mla.q.w", "l1.mla.kv_b.w", "l2.router.w", "embed.w"],
+        q_block=32)
 
 
 def test_reference_last_positions_equal_the_full_pass(tiny):
-    parts = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
-                           last=16, **REF_KW)
-    assert rel_err(parts["logits"], tiny["want"]["logits"][:, -16:]) < 1e-6
+    CASE.reference_last_positions_equal_the_full_pass(tiny)
 
 
 def test_reference_in_bfloat16_is_another_number(tiny):
-    low = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
-                         dtype=jnp.bfloat16, **REF_KW)
-    assert low["loss"].dtype == jnp.bfloat16
-    assert abs(float(low["loss"]) - float(tiny["want"]["loss"])) > 1e-4
+    CASE.reference_in_bfloat16_is_another_number(tiny)
 
 
 # -- the bias as state -------------------------------------------------------------------
@@ -395,7 +302,7 @@ def test_three_adam_steps_move_the_bias_exactly(amp, tmp_path):
     """`b` after three steps is `next_bias` applied three times to the
     system's own counts, bit for bit; it has no gradient and no moments,
     stays float32 under AMP, and a checkpoint carries it."""
-    main, startup, fetches, _ = _program(
+    main, startup, fetches, _ = CASE.program(
         fluid.optimizer.Adam(learning_rate=1e-3))
     scope = fluid.Scope()
     exe = fluid.Executor(fluid.CPUPlace(), amp=amp)
@@ -403,7 +310,7 @@ def test_three_adam_steps_move_the_bias_exactly(amp, tmp_path):
     assert np.all(np.asarray(scope.find_var("l1.router.bias")) == 0)
     want = {n: np.zeros(16, np.float32) for n in BIASES}
     for step in range(3):
-        (counts,) = exe.run(main, feed=_batch(step),
+        (counts,) = exe.run(main, feed=CASE.batch(step),
                             fetch_list=[fetches["tokens_per_expert"]],
                             scope=scope)
         for i, n in enumerate(BIASES):
@@ -437,21 +344,13 @@ def test_the_backward_pass_differentiates_the_choice_the_forward_made():
     reads the scope's values: the router reads a copy taken before. With a
     huge update rate, after which the overwritten bias would choose other
     experts, the router's gradient is still the reference's at the old b."""
-    main, startup, fetches, pairs = _program(bias_update_rate=5.0, n_layer=2)
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    names = _parameter_names(main)
-    _seeded_weights(scope, names)
-    params = {n: np.asarray(scope.find_var(n)) for n in names}
-    feed = _batch()
-    grad = next(g for p, g in pairs if p.name == "l1.router.w")
-    (got,) = exe.run(main, feed=feed, fetch_list=[grad], scope=scope)
-    assert np.abs(np.asarray(scope.find_var("l1.router.bias"))).max() > 4
+    _, params, feed, _, grads, after = CASE.run_tiny(
+        amp=False, bias_update_rate=5.0, n_layer=2)
+    assert np.abs(after["l1.router.bias"]).max() > 4
     _, want = ref.loss_and_grads(
         params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
         wrt=["l1.router.w"], **{**REF_KW, "n_layer": 2})
-    assert frob(got, want["l1.router.w"]) < 2e-4
+    assert frob(grads["l1.router.w"], want["l1.router.w"]) < 2e-4
 
 
 def test_tiny_model_amp_within_bf16_of_reference():
@@ -459,26 +358,12 @@ def test_tiny_model_amp_within_bf16_of_reference():
     experts are bf16; the router's scores, `b`, every norm's statistics and
     rotary's trigonometry stay float32. At the initial weights (a sharper
     router flips a few assignments under bf16 inputs)."""
-    _, params, feed, got, grads, after = _run_tiny(amp=True, seeded=False)
-    want, want_grads = ref.loss_and_grads(
-        params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
-        last=TINY["seq_len"], **REF_KW)
-    assert abs(float(got["loss"][0]) - float(want["loss"])) < 0.002
-    assert got["logits"].dtype == jnp.bfloat16
-    err = np.abs(np.asarray(got["logits"], np.float32)
-                 - np.asarray(want["logits"]))
-    std = float(np.std(want["logits"]))
-    assert err.mean() < 0.02 * std and err.max() < 0.1 * std
-    for name in ("l0.mla.q.w", "l0.mla.kv_a.w", "l0.mla.kv_b.w",
-                 "l2.mla.o.w", "l0.mlp.gate.w", "l1.experts.gate.w",
-                 "l1.shared.up.w", "embed.w"):
-        assert grads[name].dtype == np.float32
-        # a routed expert's gradient feels every assignment that a bf16
-        # input flips to another expert (a whole row of it)
-        limit = 0.08 if ".experts." in name else 0.04
-        assert frob(grads[name], want_grads[name]) < limit, name
-    for name in BIASES:
-        assert after[name].dtype == np.float32
+    # a routed expert's gradient feels every assignment that a bf16 input
+    # flips to another expert (a whole row of it)
+    CASE.amp_within_bf16_of_reference(
+        {0.04: ("l0.mla.q.w", "l0.mla.kv_a.w", "l0.mla.kv_b.w", "l2.mla.o.w",
+                "l0.mlp.gate.w", "l1.shared.up.w", "embed.w"),
+         0.08: ("l1.experts.gate.w",)})
 
 
 def test_amp_lists_hold_the_router_and_attention():
@@ -491,19 +376,11 @@ def test_amp_lists_hold_the_router_and_attention():
 
 
 def test_five_adam_steps_lower_the_loss():
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.Adam(learning_rate=3e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    feed = _batch()
-    losses = [float(exe.run(main, feed=feed, fetch_list=[fetches["loss"]],
-                            scope=scope)[0][0]) for _ in range(6)]
-    assert np.all(np.isfinite(losses)) and losses[-1] < losses[0] - 0.05
+    CASE.adam_steps_lower_the_loss()
 
 
 def test_attention_out_has_the_value_width():
-    main, _, _, _ = _program(n_layer=1)
+    main, _, _, _ = CASE.program(n_layer=1)
     block = main.global_block()
     (op,) = [o for o in block.ops if o.type == "fused_attention"]
     assert block.var(op.input("Q")[0]).shape[1:] == (4, 128, 24)
@@ -516,41 +393,24 @@ def test_attention_out_has_the_value_width():
 # -- spans and counters ---------------------------------------------------------------------
 
 def test_compile_event_carries_the_census():
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.SGD(learning_rate=1e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    exe.run(main, feed=_batch(), fetch_list=[fetches["loss"]], scope=scope)
-    detail = observe.observatory().latest(main._uid).detail
-    assert detail["layer_kinds"] == {"latent_attention": 3}
-    assert detail["attention_qk_width"] == 24
-    assert detail["attention_value_width"] == 16
-    assert detail["dense_ffn_layers"] == 1
-    assert detail["moe_router_score"] == "sigmoid"
-    assert detail["moe_router_bias_updates"] == 2
-    assert detail["moe_experts_routed"] == 16
-    assert detail["moe_experts_held"] == 4
-    assert detail["moe_row_buffer_rows"] == 2 * 128 * 3 + 4 * 128
-    assert detail["moe_share_bounded_moves"] == 2 * 4
-    assert "layer_kinds" not in observe.observatory().latest(
-        startup._uid).detail
+    carries_the_census(CASE.compile_detail(), {
+        "layer_kinds": {"latent_attention": 3}, "attention_qk_width": 24,
+        "attention_value_width": 16, "dense_ffn_layers": 1,
+        "moe_router_score": "sigmoid", "moe_router_bias_updates": 2,
+        "moe_experts_routed": 16, "moe_experts_held": 4,
+        "moe_row_buffer_rows": 2 * 128 * 3 + 4 * 128,
+        "moe_share_bounded_moves": 2 * 4}, startup_lacks=["layer_kinds"])
 
 
 def test_every_layer_is_built_under_its_name_scopes(tiny):
-    scopes = {}
-    for op in tiny["main"].global_block().ops:
-        if op.attrs.get("__role__") is None:
-            scopes.setdefault(op.attrs.get(ir.NAME_SCOPE_ATTR), set()) \
-                .add(op.type)
-    assert {"l0.mla", "l1.mla", "l2.mla", "l0.mlp", "l1.moe",
-            "l2.moe"} <= set(scopes)
-    assert "l0.moe" not in scopes and "l1.mlp" not in scopes
-    assert {"fused_attention", "rotary_embedding", "concat", "expand",
-            "rms_norm"} <= scopes["l1.mla"]
-    assert "swiglu" in scopes["l0.mlp"]
-    assert {"moe_router", "moe_dispatch", "grouped_matmul", "moe_combine",
-            "sign", "assign"} <= scopes["l2.moe"]
+    layers_are_built_under_their_scopes(
+        tiny["main"],
+        ["l0.mla", "l1.mla", "l2.mla", "l0.mlp", "l1.moe", "l2.moe"],
+        absent=["l0.moe", "l1.mlp"],
+        holds={"l1.mla": ["fused_attention", "rotary_embedding", "concat",
+                          "expand", "rms_norm"], "l0.mlp": ["swiglu"],
+               "l2.moe": ["moe_router", "moe_dispatch", "grouped_matmul",
+                          "moe_combine", "sign", "assign"]})
 
 
 # -- the others are what they were -------------------------------------------------------------
@@ -560,8 +420,7 @@ def test_softmax_routed_programs_are_unchanged_op_for_op(model):
     """The router took a score function, a bias and a scaling factor,
     rotary an interleaved pairing and `fused_attention` a value width in
     this file's PR; a program that passes none of them is the program it
-    was (`test_decoder_models.DIGESTS`)."""
-    from test_decoder_models import DIGESTS, build_program, program_digest
+    was (`decoder_case.DIGESTS`)."""
     main, startup, _, _ = build_program(model)
     assert program_digest(main, startup) == DIGESTS[model]
     routers = [o for o in main.global_block().ops if o.type == "moe_router"]
@@ -572,23 +431,8 @@ def test_softmax_routed_programs_are_unchanged_op_for_op(model):
 
 
 def test_the_two_copies_of_the_reference_are_identical():
-    assert filecmp.cmp(
-        os.path.join(HERE, "kanana2_reference.py"),
-        os.path.join(ROOT, "benchmark", "references",
-                     "kanana2_reference.py"), shallow=False)
+    CASE.two_copies_of_the_reference_are_identical()
 
 
 def test_the_tiny_block_runs_through_the_benchmark():
-    """`run.py --tiny` on the cell: the configuration's tiny block through
-    the harness's own rehearsal, the in-run reference comparison
-    included."""
-    out = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
-         "--workload", "kanana_2_30b_a3b.bs1", "--seed", "3000000019",
-         "--seconds", "1", "--trace", "0", "--tiny"],
-        capture_output=True, text=True, timeout=600, cwd=ROOT,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
-    assert "REHEARSAL" in out.stdout and "reference check after" in out.stdout
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["correct"] is True and line["rehearsal"] is True
+    runs_through_the_benchmark("kanana_2_30b_a3b.bs1")

@@ -25,8 +25,7 @@ from paddle_tpu.models._decoder import dt_bias_init
 from paddle_tpu.ops import linear_attention as la
 
 import ling3_reference as ref
-from test_olmoe import piece_noted, run_piece
-from test_qwen3_next import frob
+from decoder_case import _instruction, frob, piece_noted, run_piece
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 B, H, D, CHUNK = 1, 2, 128, 64
@@ -277,8 +276,7 @@ def _metric(name):
 
 def _cell_instructions():
     """`kda_fwd` and `kda_bwd` as `ling_3_0_flash_vl.s2048` calls them, as a
-    TPU trace names them (`test_gdn_kernels._instruction`)."""
-    from test_gdn_kernels import _instruction
+    TPU trace names them (`decoder_case._instruction`)."""
     x = jax.ShapeDtypeStruct((1, 2048, 32, 128), jnp.bfloat16)
     g = jax.ShapeDtypeStruct((1, 2048, 32, 128), jnp.float32)
     beta = jax.ShapeDtypeStruct((1, 2048, 32), jnp.float32)

@@ -9,12 +9,6 @@ the kept sets, and with the reference handed the system's kept sets the
 logits, the loss and the gradients. Seeded random weights, float32, AMP off
 unless a test says otherwise."""
 
-import filecmp
-import json
-import os
-import subprocess
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,28 +16,20 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import layers, models, observe
-from paddle_tpu.core import ir, registry
+from paddle_tpu.core import registry
 from paddle_tpu.ops import pallas_attention as pa
 from paddle_tpu.ops import sparse_attention as sa
 
 import keye_vl2_reference as ref
-from test_flash_attention import flash_calls
-from test_olmoe import rel_err, run_piece
-from test_qwen3_next import frob
+from attention_program import flash_calls
+from decoder_case import (DecoderCase, carries_the_census, config, frob,
+                          layers_are_built_under_their_scopes, rel_err,
+                          run_piece, runs_through_the_benchmark, tiny_args)
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(HERE)
-with open(os.path.join(ROOT, "benchmark", "configs",
-                       "keye_vl_2_30b_a3b.json")) as f:
-    CONFIG = json.load(f)
+CONFIG = config("keye_vl2")
 # the configuration's tiny block at a narrower model: 256 tokens, topk 64,
 # index heads 4 x 16, 16 experts of which 4 held from expert 4
-BLOCK = CONFIG["tiny"]["build_args"]
-TINY = dict({k: BLOCK[k] for k in (
-    "seq_len", "topk", "n_index_head", "index_dim", "index_tile", "n_expert",
-    "top_k", "first_expert", "experts_held")},
-    vocab_size=64, n_layer=3, d_model=32, n_head=4, n_kv_head=2, head_dim=16,
-    rope_theta=1e4, d_expert=16)
+TINY = tiny_args("keye_vl2")
 REF_KW = {k: TINY[k] for k in (
     "n_layer", "n_head", "n_kv_head", "head_dim", "rope_theta",
     "n_index_head", "index_dim", "topk", "top_k", "first_expert")}
@@ -333,68 +319,35 @@ def test_the_sixteen_shares_add_up_to_the_whole_layer():
 
 # -- the model ----------------------------------------------------------------------------
 
-def _program(optimizer=None, **sizes):
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        feeds, fetches = models.keye_vl2.build(**{**TINY, **sizes})
-        if optimizer is None:
-            pairs = fluid.append_backward(fetches["loss"])
-        else:
-            optimizer.minimize(fetches["loss"])
-            pairs = []
-    main.random_seed = startup.random_seed = 7
-    return main, startup, fetches, pairs
-
-
-def _batch(seed=0, batch=2):
-    rng = np.random.RandomState(seed)
-    shape = (batch, T)
-    return {"tokens": rng.randint(0, TINY["vocab_size"], shape)
-            .astype(np.int32),
-            "labels": rng.randint(0, TINY["vocab_size"], shape)
-            .astype(np.int32)}
-
-
-def _parameter_names(main):
-    return [p.name for p in main.global_block().all_parameters()]
-
-
-def _seeded_weights(scope, names, seed=3):
+def _seeded_values(shapes, seed=3):
     """Weights far from their initial values: norm weights in [0.5, 1.5],
     the LayerNorm's bias and the matrices of std 0.1, a sharper router."""
     rng = np.random.RandomState(seed)
-    for name in sorted(names):
-        shape = np.shape(scope.find_var(name))
+    values = {}
+    for name in sorted(shapes):
+        shape = shapes[name]
         if name.endswith("norm.w"):
             value = rng.uniform(0.5, 1.5, shape)
         elif name.endswith("router.w"):
             value = rng.randn(*shape) * 0.5
         else:
             value = rng.randn(*shape) * 0.1
-        scope.set_var(name, jnp.asarray(value.astype(np.float32)))
+        values[name] = value.astype(np.float32)
+    return values
 
 
 FETCHES = ["loss", "ce", "load_balance", "logits", "tokens_per_expert"]
 KEPT = [f"l{i}.kept" for i in range(TINY["n_layer"])]
-
-
-def _run_tiny(amp, seeded=True):
-    main, startup, fetches, pairs = _program()
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace(), amp=amp)
-    exe.run(startup, scope=scope)
-    names = _parameter_names(main)
-    if seeded:
-        _seeded_weights(scope, names)
-    params = {n: np.asarray(scope.find_var(n)) for n in names}
-    feed = _batch()
-    out = exe.run(main, feed=feed,
-                  fetch_list=[fetches[n] for n in FETCHES + KEPT]
-                  + [g for _, g in pairs], scope=scope)
-    got = dict(zip(FETCHES + KEPT, out))
-    grads = dict(zip((p.name for p, _ in pairs),
-                     out[len(FETCHES) + len(KEPT):]))
-    return main, params, feed, got, grads
+LAYER = ["in_norm.w", "post_norm.w", "attn.q.w", "attn.k.w", "attn.v.w",
+         "attn.q_norm.w", "attn.k_norm.w", "attn.o.w", "router.w",
+         "experts.gate.w", "experts.up.w", "experts.down.w"]
+INDEXER = ["index.q.w", "index.k.w", "index.k_norm.w", "index.k_norm.b",
+           "index.w.w"]
+TRAINED = (["embed.w", "final_norm.w", "head.w"]
+           + [f"l{i}.{n}" for i in range(TINY["n_layer"]) for n in LAYER])
+FROZEN = [f"l{i}.{n}" for i in range(TINY["n_layer"]) for n in INDEXER]
+CASE = DecoderCase(models.keye_vl2.build, TINY, ref, REF_KW, FETCHES + KEPT,
+                   state=FROZEN, seeded_values=_seeded_values)
 
 
 def _agreement(got, want):
@@ -405,36 +358,21 @@ def _agreement(got, want):
 
 @pytest.fixture(scope="module")
 def tiny():
-    main, params, feed, got, grads = _run_tiny(amp=False)
-    tokens, labels = jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"])
-    own = ref.loss_parts(params, tokens, labels, return_kept=True, **REF_KW)
-    want, want_grads = ref.loss_and_grads(
-        params, tokens, labels, last=T, kept=[got[n] for n in KEPT], **REF_KW)
-    return dict(main=main, params=params, tokens=tokens, labels=labels,
-                got=got, grads=grads, own=own, want=want,
-                want_grads=want_grads)
-
-
-LAYER = ["in_norm.w", "post_norm.w", "attn.q.w", "attn.k.w", "attn.v.w",
-         "attn.q_norm.w", "attn.k_norm.w", "attn.o.w", "router.w",
-         "experts.gate.w", "experts.up.w", "experts.down.w"]
-INDEXER = ["index.q.w", "index.k.w", "index.k_norm.w", "index.k_norm.b",
-           "index.w.w"]
-TRAINED = (["embed.w", "final_norm.w", "head.w"]
-           + [f"l{i}.{n}" for i in range(TINY["n_layer"]) for n in LAYER])
-FROZEN = [f"l{i}.{n}" for i in range(TINY["n_layer"]) for n in INDEXER]
+    """The reference twice, as on the chip: its own selection (`own`), and
+    handed the system's kept sets (`want`)."""
+    run = CASE.run_tiny(amp=False)
+    made = CASE.tiny_model(run, kept=[run.got[n] for n in KEPT])
+    made["own"] = ref.loss_parts(made["params"], made["tokens"],
+                                 made["labels"], return_kept=True, **REF_KW)
+    return made
 
 
 def test_tiny_model_has_the_reference_parameters(tiny):
-    assert sorted(tiny["params"]) == sorted(TRAINED + FROZEN)
-    assert tiny["params"]["l0.attn.q.w"].shape == (32, 4 * 16)
-    assert tiny["params"]["l0.attn.k.w"].shape == (32, 2 * 16)
-    assert tiny["params"]["l1.index.q.w"].shape == (32, 4 * 16)
-    assert tiny["params"]["l1.index.k.w"].shape == (32, 16)
-    assert tiny["params"]["l1.index.w.w"].shape == (32, 4)
-    assert tiny["params"]["l2.index.k_norm.b"].shape == (16,)
-    assert tiny["params"]["l1.experts.gate.w"].shape == (4, 32, 16)
-    assert sorted(tiny["grads"]) == sorted(TRAINED)
+    CASE.has_the_reference_parameters(tiny, TRAINED, {
+        "l0.attn.q.w": (32, 4 * 16), "l0.attn.k.w": (32, 2 * 16),
+        "l1.index.q.w": (32, 4 * 16), "l1.index.k.w": (32, 16),
+        "l1.index.w.w": (32, 4), "l2.index.k_norm.b": (16,),
+        "l1.experts.gate.w": (4, 32, 16)})
 
 
 @pytest.mark.parametrize("name", KEPT)
@@ -453,16 +391,12 @@ def test_kept_sets_are_the_references(tiny, name):
 @pytest.mark.parametrize("name", FETCHES)
 def test_tiny_model_output_matches_reference(tiny, name):
     """Comparison (b): the reference under the system's kept sets."""
-    if name == "tokens_per_expert":
-        assert np.array_equal(tiny["got"][name], tiny["want"][name])
-    else:
-        want = np.asarray(tiny["want"][name])
-        assert rel_err(np.reshape(tiny["got"][name], want.shape), want) < 1e-4
+    CASE.output_matches_reference(tiny, name)
 
 
 @pytest.mark.parametrize("name", TRAINED)
 def test_tiny_model_gradient_matches_reference(tiny, name):
-    assert frob(tiny["grads"][name], tiny["want_grads"][name]) < 2e-4
+    CASE.gradient_matches_reference(tiny, name)
 
 
 @pytest.mark.parametrize("name", FROZEN)
@@ -482,6 +416,8 @@ WRT = ["l0.attn.q.w", "l1.attn.k.w", "l2.attn.v.w", "l1.in_norm.w",
        "embed.w", "l1.index.q.w", "l2.index.w.w"]
 
 
+# its own body: a fault may show in the kept sets alone (comparison a), or as
+# a gradient that reaches the indexer
 @pytest.mark.parametrize("fault", sorted(ref.FAULTS))
 def test_each_planted_fault_is_refused(tiny, fault):
     """The two comparisons that pass the reference refuse each fault: its
@@ -506,9 +442,7 @@ def test_each_planted_fault_is_refused(tiny, fault):
 
 
 def test_an_unknown_fault_is_refused(tiny):
-    with pytest.raises(ValueError, match="fault is one of"):
-        ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
-                       fault="no_such", **REF_KW)
+    CASE.unknown_fault_is_refused(tiny)
 
 
 def test_interpreted_kernels_give_the_reference_too(tiny, monkeypatch):
@@ -516,7 +450,7 @@ def test_interpreted_kernels_give_the_reference_too(tiny, monkeypatch):
     under the Pallas interpreter (at 256 tokens the one-pass forward and the
     fused backward) instead of the CPU path's jnp forms."""
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-    _, params, feed, got, grads = _run_tiny(amp=False)
+    _, params, feed, got, grads, _ = CASE.run_tiny(amp=False)
     for name in KEPT:
         assert _agreement(got[name], tiny["got"][name]) >= 0.9995
         assert np.array_equal(got[name].sum(-1)[0], ROW_KEEPS)
@@ -532,7 +466,7 @@ def test_interpreted_kernels_give_the_reference_too(tiny, monkeypatch):
 def test_topk_over_the_sequence_gives_the_causal_model(tiny):
     """`topk >= T`: every layer keeps the whole triangle and the model is
     the reference with no selection."""
-    main, startup, fetches, _ = _program(topk=T)
+    main, startup, fetches, _ = CASE.program(topk=T)
     scope = fluid.Scope()
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(startup, scope=scope)
@@ -550,6 +484,8 @@ def test_topk_over_the_sequence_gives_the_causal_model(tiny):
     assert abs(float(want["loss"]) - float(tiny["want"]["loss"])) > 1e-4
 
 
+# its own body: in blocks the reference selects again, so the kept sets are
+# compared too, and the gradients only under the system's sets
 def test_reference_in_blocks_is_the_reference(tiny):
     """`q_block` and `remat` are the reference's memory, not its
     mathematics."""
@@ -566,6 +502,7 @@ def test_reference_in_blocks_is_the_reference(tiny):
     assert abs(float(handed["loss"]) - float(tiny["want"]["loss"])) < 1e-5
 
 
+# its own body: in bfloat16 the reference keeps other pairs, too
 def test_reference_in_bfloat16_is_another_number(tiny):
     low = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
                          dtype=jnp.bfloat16, return_kept=True, **REF_KW)
@@ -575,12 +512,14 @@ def test_reference_in_bfloat16_is_another_number(tiny):
         low["kept"], tiny["own"]["kept"])) < 0.9995
 
 
+# its own body: the bf16 kept sets are compared first, and the reference is
+# handed them
 def test_tiny_model_amp_within_bf16_of_reference():
     """Under AMP the index products run in bf16: the kept sets differ from
     the float32 reference's at the threshold and nowhere else (every row
     still keeps exactly min(t + 1, topk)); under the system's own sets the
     loss, the logits and the gradients are within bf16 of the reference."""
-    _, params, feed, got, grads = _run_tiny(amp=True, seeded=False)
+    _, params, feed, got, grads, _ = CASE.run_tiny(amp=True, seeded=False)
     tokens, labels = jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"])
     own = ref.loss_parts(params, tokens, labels, return_kept=True, **REF_KW)
     for name, mine in zip(KEPT, own["kept"]):
@@ -605,14 +544,14 @@ def test_tiny_model_amp_within_bf16_of_reference():
 # -- what the loss cannot reach ------------------------------------------------------------
 
 def test_a_step_leaves_the_indexer_bitwise_unchanged_and_without_moments():
-    main, startup, fetches, _ = _program(
+    main, startup, fetches, _ = CASE.program(
         fluid.optimizer.Adam(learning_rate=3e-3))
     scope = fluid.Scope()
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(startup, scope=scope)
-    names = _parameter_names(main)
+    names = TRAINED + FROZEN
     before = {n: np.asarray(scope.find_var(n)) for n in names}
-    feed = _batch()
+    feed = CASE.batch()
     losses = [float(exe.run(main, feed=feed, fetch_list=[fetches["loss"]],
                             scope=scope)[0][0]) for _ in range(6)]
     assert np.all(np.isfinite(losses)) and losses[-1] < losses[0] - 0.05
@@ -633,31 +572,21 @@ def test_a_step_leaves_the_indexer_bitwise_unchanged_and_without_moments():
 # -- spans and counters ---------------------------------------------------------------------
 
 def test_compile_event_carries_the_census():
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.SGD(learning_rate=1e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    exe.run(main, feed=_batch(), fetch_list=[fetches["loss"]], scope=scope)
-    detail = observe.observatory().latest(main._uid).detail
-    assert detail["layer_kinds"] == {"sparse_attention": 3}
-    assert detail["dsa_layers"] == 3
-    assert detail["attention_rotary_layers"] == 3
-    assert detail["moe_experts_routed"] == 16
-    assert detail["moe_experts_held"] == 4
-    # batch 2 x 3 layers x (64 x 65 / 2 + 192 x 64) kept pairs
-    assert detail["dsa_keys_kept"] == 2 * 3 * int(ROW_KEEPS.sum())
     assert int(ROW_KEEPS.sum()) == pa.kept_pairs(T, K) == 14368
-    # batch 2 x 4 heads x 3 layers, one 256 x 256 tile a head
-    assert detail["dsa_tiles_computed"] == 2 * 4 * 3 * pa.causal_tiles(T)
-    # that one tile holds the diagonal: none runs without the causal mask
-    assert detail["flash_tiles_unmasked"] == 0 == pa.interior_tiles(T)
-    # and its grid has no step above the diagonal to hold
-    assert detail["flash_dead_steps_held"] == 0
-    assert detail["frozen_parameters"] == len(FROZEN)
-    assert "window_tiles_computed" not in detail
-    assert "dsa_layers" not in observe.observatory().latest(
-        startup._uid).detail
+    assert 0 == pa.interior_tiles(T)
+    carries_the_census(CASE.compile_detail(), {
+        "layer_kinds": {"sparse_attention": 3}, "dsa_layers": 3,
+        "attention_rotary_layers": 3, "moe_experts_routed": 16,
+        "moe_experts_held": 4,
+        # batch 2 x 3 layers x (64 x 65 / 2 + 192 x 64) kept pairs
+        "dsa_keys_kept": 2 * 3 * int(ROW_KEEPS.sum()),
+        # batch 2 x 4 heads x 3 layers, one 256 x 256 tile a head
+        "dsa_tiles_computed": 2 * 4 * 3 * pa.causal_tiles(T),
+        # that one tile holds the diagonal: none runs without the causal mask
+        "flash_tiles_unmasked": 0,
+        # and its grid has no step above the diagonal to hold
+        "flash_dead_steps_held": 0, "frozen_parameters": len(FROZEN)},
+        absent=["window_tiles_computed"], startup_lacks=["dsa_layers"])
 
 
 @pytest.mark.parametrize("seq,topk,pairs", [
@@ -685,39 +614,27 @@ def test_the_unmasked_tally_follows_the_tiles(monkeypatch):
     kept set alone: the counter is the forward ops', summed over the layers,
     and the grad ops' traces add nothing to it."""
     monkeypatch.setattr(pa, "_BLOCK_OVERRIDE", (128, 128))
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.SGD(learning_rate=1e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    exe.run(main, feed=_batch(), fetch_list=[fetches["loss"]], scope=scope)
-    detail = observe.observatory().latest(main._uid).detail
     assert pa.causal_tiles(T) == 3 and pa.interior_tiles(T) == 1
-    assert detail["dsa_tiles_computed"] == 2 * 4 * 3 * 3
-    assert detail["flash_tiles_unmasked"] == 2 * 4 * 3 * 1
-    # one of the grid's four steps lies above the diagonal
-    assert detail["flash_dead_steps_held"] == 2 * 4 * 3 * 1
-    assert "flash_tiles_unmasked" not in observe.observatory().latest(
-        startup._uid).detail
+    carries_the_census(CASE.compile_detail(), {
+        "dsa_tiles_computed": 2 * 4 * 3 * 3,
+        "flash_tiles_unmasked": 2 * 4 * 3 * 1,
+        # one of the grid's four steps lies above the diagonal
+        "flash_dead_steps_held": 2 * 4 * 3 * 1},
+        startup_lacks=["flash_tiles_unmasked"])
 
 
 def test_every_layer_is_built_under_its_name_scopes(tiny):
-    scopes = {}
-    for op in tiny["main"].global_block().ops:
-        if op.attrs.get("__role__") is None:
-            scopes.setdefault(op.attrs.get(ir.NAME_SCOPE_ATTR), set()) \
-                .add(op.type)
-    assert {"l0.dsa", "l1.dsa", "l2.dsa", "l0.moe", "l2.moe"} <= set(scopes)
-    for name in ("l0.dsa", "l2.dsa"):
-        assert {"fused_attention", "dsa_index_scores", "dsa_select",
-                "layer_norm", "rotary_embedding", "expand", "rms_norm",
-                "mul"} <= scopes[name]
-    assert {"moe_router", "moe_dispatch", "grouped_matmul",
-            "moe_combine"} <= scopes["l1.moe"]
+    mixer = ["fused_attention", "dsa_index_scores", "dsa_select",
+             "layer_norm", "rotary_embedding", "expand", "rms_norm", "mul"]
+    layers_are_built_under_their_scopes(
+        tiny["main"], ["l0.dsa", "l1.dsa", "l2.dsa", "l0.moe", "l2.moe"],
+        holds={"l0.dsa": mixer, "l2.dsa": mixer,
+               "l1.moe": ["moe_router", "moe_dispatch", "grouped_matmul",
+                          "moe_combine"]})
 
 
 def test_attention_ops_take_the_kept_set():
-    main, _, _, _ = _program(n_layer=1)
+    main, _, _, _ = CASE.program(n_layer=1)
     block = main.global_block()
     (op,) = [o for o in block.ops if o.type == "fused_attention"]
     (select,) = [o for o in block.ops if o.type == "dsa_select"]
@@ -750,8 +667,8 @@ def test_amp_lists_leave_the_indexer_to_its_rules():
 
 
 def test_the_program_round_trips_with_its_new_ops_and_slot():
-    main, _, fetches, _ = _program(fluid.optimizer.Adam(learning_rate=1e-3),
-                                   n_layer=1)
+    main, _, fetches, _ = CASE.program(
+        fluid.optimizer.Adam(learning_rate=1e-3), n_layer=1)
     parsed = fluid.Program.parse_from_string(main.serialize_to_string())
     (op,) = [o for o in parsed.global_block().ops
              if o.type == "fused_attention"]
@@ -763,10 +680,7 @@ def test_the_program_round_trips_with_its_new_ops_and_slot():
 # -- the files ------------------------------------------------------------------------------
 
 def test_the_two_copies_of_the_reference_are_identical():
-    assert filecmp.cmp(
-        os.path.join(HERE, "keye_vl2_reference.py"),
-        os.path.join(ROOT, "benchmark", "references",
-                     "keye_vl2_reference.py"), shallow=False)
+    CASE.two_copies_of_the_reference_are_identical()
 
 
 @pytest.mark.parametrize("key,value", [
@@ -791,16 +705,4 @@ def test_the_configuration_keeps_the_published_widths(key, value):
 
 
 def test_the_tiny_block_runs_through_the_benchmark():
-    """`run.py --tiny` on the cell: the configuration's tiny block through
-    the harness's own rehearsal, the in-run reference comparison
-    included."""
-    out = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
-         "--workload", "keye_vl_2_30b_a3b.s8192", "--seed", "3000000019",
-         "--seconds", "1", "--trace", "0", "--tiny"],
-        capture_output=True, text=True, timeout=600, cwd=ROOT,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
-    assert "REHEARSAL" in out.stdout and "reference check after" in out.stdout
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["correct"] is True and line["rehearsal"] is True
+    runs_through_the_benchmark("keye_vl_2_30b_a3b.s8192")

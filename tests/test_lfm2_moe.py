@@ -14,11 +14,8 @@ a softmax and a top-k the logits stay within 1e-4 of their largest value and a
 gradient within 2e-4 in the Frobenius norm (`test_trinity.py`'s limits, for
 its reason). A piece alone: 2e-5 of the largest value (RTOL)."""
 
-import filecmp
 import json
 import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -26,49 +23,29 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu import layers, models, observe
+from paddle_tpu import layers, models
 from paddle_tpu.core import registry
 from paddle_tpu.observe import census
 from paddle_tpu.ops import linear_attention as la
 
 import lfm2_moe_reference as ref
-from test_kanana2 import _planted
-from test_nemotron_h import _forward_ops_by_scope
-from test_olmoe import piece_noted, rel_err, run_piece
-from test_qwen3_next import frob
+from decoder_case import (DecoderCase, _forward_ops_by_scope, _planted,
+                          build_program, carries_the_census, config, frob,
+                          piece_noted, rel_err, run_piece,
+                          runs_through_the_benchmark, tiny_args)
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(HERE)
-with open(os.path.join(ROOT, "benchmark", "configs",
-                       "lfm2_8b_a1b.json")) as f:
-    CONFIG = json.load(f)
+CONFIG = config("lfm2_moe")
 GAMMA = 0.001
 TYPES = ["conv", "full_attention", "conv", "conv", "conv"]
 PUBLISHED = range(1, 6)         # the cut's layers by their published index
 # the model's own layers 1-5, hidden 64, a dense MLP of 96, 4/2 heads of 16,
 # 128 tokens, 16 experts of 32 at top-4 of which 4 are held from expert 0
-TINY = {**CONFIG["build_args"], **CONFIG["tiny"]["build_args"]}
+TINY = tiny_args("lfm2_moe")
 REF_KW = {k: TINY[k] for k in (
     "layer_types", "first_layer", "n_head", "n_kv_head", "head_dim",
     "rope_theta", "top_k", "first_expert", "route_scale", "route_norm_eps",
     "tie_embeddings", "rms_eps")}
 RTOL = 2e-5
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _release_the_compiled_programs():
-    """After this file's tests, drop what jax keeps of them. Every compiled
-    XLA:CPU program holds memory maps for as long as an executable is alive,
-    jax's caches keep every one alive, and a worker that has run a few model
-    files stands near `vm.max_map_count` (65530): PR 69 counted 32 k maps
-    after `test_nemotron_h.py` and 54 k after this file behind it, and the
-    next file's compile then segfaulted inside jax (three whole runs of
-    three, and a replay of that worker's files in one process). This file
-    hands back the ~21 k it took."""
-    yield
-    import gc
-    jax.clear_caches()
-    gc.collect()
 
 
 def test_the_tiny_block_is_the_issues():
@@ -278,28 +255,6 @@ def test_the_shares_add_up_to_the_whole_layer():
 
 # -- the model -----------------------------------------------------------------------------------
 
-def _program(optimizer=None, **sizes):
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        feeds, fetches = models.lfm2_moe.build(**{**TINY, **sizes})
-        if optimizer is None:
-            pairs = fluid.append_backward(fetches["loss"])
-        else:
-            optimizer.minimize(fetches["loss"])
-            pairs = []
-    main.random_seed = startup.random_seed = 7
-    return main, startup, fetches, pairs
-
-
-def _batch(seed=0, batch=2):
-    rng = np.random.RandomState(seed)
-    shape = (batch, TINY["seq_len"])
-    return {"tokens": rng.randint(0, TINY["vocab_size"], shape)
-            .astype(np.int32),
-            "labels": rng.randint(0, TINY["vocab_size"], shape)
-            .astype(np.int32)}
-
-
 def _seeded_values(shapes, seed=3):
     """Weights far from their initial values, so that no term of the
     comparison is small by construction: norm weights in [0.5, 1.5] (the
@@ -341,37 +296,19 @@ FETCHES = ["loss", "ce", "logits", "tokens_per_expert"]
 BIASES = [f"l{p}.router.bias" for p in range(2, 6)]
 
 
-def _run_tiny(amp, seeded=True, weights=None, **sizes):
-    main, startup, fetches, pairs = _program(**sizes)
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace(), amp=amp)
-    exe.run(startup, scope=scope)
-    names = [p.name for p in main.global_block().all_parameters()]
-    if seeded:
-        weights = weights or _seeded_values(
-            {n: np.shape(scope.find_var(n)) for n in names})
-        for name in names:
-            scope.set_var(name, jnp.asarray(weights[name]))
-    params = {n: np.asarray(scope.find_var(n)) for n in names}
-    feed = _batch()
-    out = exe.run(main, feed=feed,
-                  fetch_list=[fetches[n] for n in FETCHES]
-                  + [g for _, g in pairs], scope=scope)
-    got = dict(zip(FETCHES, out))
-    grads = dict(zip((p.name for p, _ in pairs), out[len(FETCHES):]))
-    after = {n: np.asarray(scope.find_var(n)) for n in BIASES}
-    return main, params, feed, got, grads, after
+# what each planted fault has to move, at least: the logits or a gradient by
+# 1% where the true reference is met within 2e-4
+FAULT_WRT = ["embed.w", "l1.conv.in.w", "l1.conv.conv.w", "l1.mlp.up.w",
+             "l2.attn.q.w", "l2.attn.k.w", "l2.attn.q_norm.w", "l2.router.w",
+             "l2.experts.gate.w", "l5.conv.in.w", "final_norm.w"]
+CASE = DecoderCase(models.lfm2_moe.build, TINY, ref, REF_KW, FETCHES,
+                   state=BIASES, seeded_values=_seeded_values,
+                   fault_wrt=FAULT_WRT)
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    main, params, feed, got, grads, after = _run_tiny(amp=False)
-    tokens, labels = jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"])
-    want, want_grads = ref.loss_and_grads(
-        params, tokens, labels, last=TINY["seq_len"], **REF_KW)
-    return dict(main=main, params=params, tokens=tokens, labels=labels,
-                got=got, grads=grads, after=after, want=want,
-                want_grads=want_grads)
+    return CASE.tiny_model()
 
 
 CONV = ["conv.in.w", "conv.conv.w", "conv.out.w"]
@@ -387,31 +324,22 @@ TRAINED = (["embed.w", "final_norm.w"]
 
 
 def test_tiny_model_has_the_reference_parameters(tiny):
-    assert sorted(tiny["params"]) == sorted(TRAINED + BIASES)
-    assert "head.w" not in tiny["params"]           # tied
-    shapes = {n: v.shape for n, v in tiny["params"].items()}
-    assert shapes["embed.w"] == (128, 64)
-    assert shapes["l1.conv.in.w"] == (64, 3 * 64)       # [B | C | x']
-    assert shapes["l3.conv.conv.w"] == (64, 3)
-    assert shapes["l5.conv.out.w"] == (64, 64)
-    assert shapes["l2.attn.q.w"] == shapes["l2.attn.o.w"] == (64, 4 * 16)
-    assert shapes["l2.attn.k.w"] == shapes["l2.attn.v.w"] == (64, 2 * 16)
-    assert shapes["l2.attn.q_norm.w"] == shapes["l2.attn.k_norm.w"] == (16,)
-    assert shapes["l1.mlp.gate.w"] == (64, 96)
-    assert shapes["l2.router.w"] == (64, 16)
-    assert shapes["l2.router.bias"] == (16,)
-    assert shapes["l4.experts.gate.w"] == shapes["l4.experts.up.w"] \
-        == (4, 64, 32)
-    assert shapes["l5.experts.down.w"] == (4, 32, 64)
-    # no layer has a shared expert, no conv a bias, layer 1 no router
-    assert not any(".shared." in n or n.endswith("conv.b")
-                   or n.startswith("l1.router") for n in shapes)
-    # a gradient for every trained parameter, none for a bias
-    assert sorted(tiny["grads"]) == sorted(TRAINED)
+    CASE.has_the_reference_parameters(tiny, TRAINED, {
+        "embed.w": (128, 64), "l1.conv.in.w": (64, 3 * 64),     # [B | C | x']
+        "l3.conv.conv.w": (64, 3), "l5.conv.out.w": (64, 64),
+        "l2.attn.q.w": (64, 4 * 16), "l2.attn.o.w": (64, 4 * 16),
+        "l2.attn.k.w": (64, 2 * 16), "l2.attn.v.w": (64, 2 * 16),
+        "l2.attn.q_norm.w": (16,), "l2.attn.k_norm.w": (16,),
+        "l1.mlp.gate.w": (64, 96), "l2.router.w": (64, 16),
+        "l2.router.bias": (16,), "l4.experts.gate.w": (4, 64, 32),
+        "l4.experts.up.w": (4, 64, 32), "l5.experts.down.w": (4, 32, 64)})
+    # tied; no layer has a shared expert, no conv a bias, layer 1 no router
+    assert not any(n == "head.w" or ".shared." in n or n.endswith("conv.b")
+                   or n.startswith("l1.router") for n in tiny["params"])
 
 
 def test_the_initial_values_are_the_assumed_ones():
-    main, startup, _, _ = _program()
+    main, startup, _, _ = CASE.program()
     scope = fluid.Scope()
     fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
     value = lambda n: np.asarray(scope.find_var(n))
@@ -428,34 +356,22 @@ def test_the_initial_values_are_the_assumed_ones():
 
 @pytest.mark.parametrize("name", FETCHES)
 def test_tiny_model_output_matches_reference(tiny, name):
-    want = np.asarray(tiny["want"][name])
-    if name == "tokens_per_expert":
-        assert np.array_equal(tiny["got"][name], want)
-    else:
-        assert rel_err(np.reshape(tiny["got"][name], want.shape), want) < 1e-4
+    CASE.output_matches_reference(tiny, name)
 
 
 def test_tiny_routing_sends_most_assignments_elsewhere(tiny):
-    counts = tiny["got"]["tokens_per_expert"]
-    assert counts.shape == (4, 16) and np.all(counts.sum(1) == 2 * 128 * 4)
-    held = counts[:, :4].sum(1)
-    assert np.all(held > 0) and np.all(held < counts.sum(1) / 2)
+    CASE.routing_sends_most_assignments_elsewhere(tiny, routed_layers=4)
 
 
 @pytest.mark.parametrize("name", TRAINED)
 def test_tiny_model_gradient_matches_reference(tiny, name):
-    assert frob(tiny["grads"][name], tiny["want_grads"][name]) < 2e-4
+    CASE.gradient_matches_reference(tiny, name)
 
 
 @pytest.mark.parametrize("layer", [2, 3, 4, 5])
 def test_one_step_moves_the_bias_as_next_bias_does(tiny, layer):
-    name = f"l{layer}.router.bias"
-    want = ref.next_bias(tiny["params"][name],
-                         tiny["got"]["tokens_per_expert"][layer - 2], GAMMA)
-    assert np.array_equal(tiny["after"][name], np.asarray(want))
-    moved = tiny["after"][name] - tiny["params"][name]
-    assert np.all(np.isclose(np.abs(moved), GAMMA, rtol=1e-3)
-                  | (moved == 0)) and np.any(moved != 0)
+    CASE.one_step_moves_the_bias_as_next_bias_does(
+        tiny, f"l{layer}.router.bias", GAMMA)
 
 
 @pytest.mark.parametrize("amp", [False, True])
@@ -463,14 +379,14 @@ def test_three_adam_steps_move_the_bias_exactly(amp):
     """`b` after three steps is `next_bias` applied three times to the
     system's own counts, bit for bit; it has no gradient and no moments and
     stays float32 under AMP."""
-    main, startup, fetches, _ = _program(
+    main, startup, fetches, _ = CASE.program(
         fluid.optimizer.Adam(learning_rate=1e-3))
     scope = fluid.Scope()
     exe = fluid.Executor(fluid.CPUPlace(), amp=amp)
     exe.run(startup, scope=scope)
     want = {n: np.zeros(16, np.float32) for n in BIASES}
     for step in range(3):
-        (counts,) = exe.run(main, feed=_batch(step),
+        (counts,) = exe.run(main, feed=CASE.batch(step),
                             fetch_list=[fetches["tokens_per_expert"]],
                             scope=scope)
         for i, n in enumerate(BIASES):
@@ -497,8 +413,8 @@ def test_the_tied_tables_gradient_is_the_sum_of_its_two_uses(tiny):
     gradient is the untied embedding's (the look-up's row scatter) plus the
     untied head's, transposed (the dense product)."""
     weights = {**tiny["params"], "head.w": tiny["params"]["embed.w"].T.copy()}
-    _, params, _, got, grads, _ = _run_tiny(amp=False, weights=weights,
-                                            tie_embeddings=False)
+    _, params, _, got, grads, _ = CASE.run_tiny(
+        amp=False, weights=weights, tie_embeddings=False)
     assert sorted(params) == sorted(TRAINED + BIASES + ["head.w"])
     assert rel_err(got["logits"], tiny["got"]["logits"]) < 1e-6
     both = grads["embed.w"] + grads["head.w"].T
@@ -516,7 +432,7 @@ def test_the_tied_tables_gradient_is_the_sum_of_its_two_uses(tiny):
 
 
 def test_the_table_is_read_twice_and_summed_once():
-    main, _, _, _ = _program(fluid.optimizer.Adam(learning_rate=1e-3))
+    main, _, _, _ = CASE.program(fluid.optimizer.Adam(learning_rate=1e-3))
     block = main.global_block()
     reads = [op.type for op in block.ops
              if op.attrs.get("__role__") is None
@@ -531,29 +447,11 @@ def test_the_table_is_read_twice_and_summed_once():
 
 # -- the planted faults --------------------------------------------------------------------------
 
-# what each planted fault has to move, at least: the logits or a gradient by
-# 1% where the true reference is met within 2e-4
-FAULT_WRT = ["embed.w", "l1.conv.in.w", "l1.conv.conv.w", "l1.mlp.up.w",
-             "l2.attn.q.w", "l2.attn.k.w", "l2.attn.q_norm.w", "l2.router.w",
-             "l2.experts.gate.w", "l5.conv.in.w", "final_norm.w"]
-
-
 @pytest.mark.parametrize("fault", sorted(ref.FAULTS))
 def test_each_planted_fault_is_refused(tiny, fault):
-    """The comparison that passes the reference refuses each fault: the
-    logits or a gradient moves by far more than the system's distance from
-    the true reference. (`untied_head` moves no forward number: the table's
-    gradient alone.)"""
-    bad, bad_grads = ref.loss_and_grads(
-        tiny["params"], tiny["tokens"], tiny["labels"], wrt=FAULT_WRT,
-        last=TINY["seq_len"], fault=fault, **REF_KW)
-    moved = [rel_err(tiny["got"]["logits"], bad["logits"])] \
-        + [frob(tiny["grads"][n], bad_grads[n]) for n in FAULT_WRT]
-    held = [rel_err(tiny["got"]["logits"], tiny["want"]["logits"])] \
-        + [frob(tiny["grads"][n], tiny["want_grads"][n]) for n in FAULT_WRT]
-    assert max(held) < 2e-4
-    assert not max(np.nan_to_num(moved, nan=np.inf)) <= 50 * 2e-4, \
-        (fault, moved)
+    """(`untied_head` moves no forward number: the table's gradient
+    alone.)"""
+    CASE.planted_fault_is_refused(tiny, fault)
 
 
 def test_the_config_names_every_fault_and_no_other():
@@ -562,42 +460,28 @@ def test_the_config_names_every_fault_and_no_other():
 
 
 def test_an_unknown_fault_is_refused(tiny):
-    with pytest.raises(ValueError, match="fault is one of"):
-        ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
-                       fault="no_such", **REF_KW)
+    CASE.unknown_fault_is_refused(tiny)
 
 
 def test_reference_in_blocks_is_the_reference(tiny):
-    """`q_block` and `remat` are the reference's memory, not its
-    mathematics."""
-    parts, grads = ref.loss_and_grads(
-        tiny["params"], tiny["tokens"], tiny["labels"],
-        wrt=["l1.conv.in.w", "l3.conv.conv.w", "l2.attn.k.w", "l2.router.w",
-             "l4.experts.down.w", "embed.w"],
-        q_block=32, remat=True, **REF_KW)
-    assert abs(float(parts["loss"]) - float(tiny["want"]["loss"])) < 1e-5
-    for name, g in grads.items():
-        assert frob(g, tiny["want_grads"][name]) < 1e-5, name
+    CASE.reference_in_blocks_is_the_reference(
+        tiny, ["l1.conv.in.w", "l3.conv.conv.w", "l2.attn.k.w", "l2.router.w",
+               "l4.experts.down.w", "embed.w"], q_block=32)
 
 
 def test_reference_last_positions_equal_the_full_pass(tiny):
-    parts = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
-                           last=16, **REF_KW)
-    assert rel_err(parts["logits"], tiny["want"]["logits"][:, -16:]) < 1e-6
+    CASE.reference_last_positions_equal_the_full_pass(tiny)
 
 
 def test_reference_in_bfloat16_is_another_number(tiny):
-    low = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
-                         dtype=jnp.bfloat16, **REF_KW)
-    assert low["loss"].dtype == jnp.bfloat16
-    assert abs(float(low["loss"]) - float(tiny["want"]["loss"])) > 1e-4
+    CASE.reference_in_bfloat16_is_another_number(tiny)
 
 
 def test_the_cut_follows_the_published_indices(tiny):
     """`first_layer` 1: the names carry the published index, layer 1 is the
     last dense layer, and the same kinds built from `first_layer` 0 put the
     dense MLP into TWO layers."""
-    main, _, _, _ = _program(first_layer=0)
+    main, _, _, _ = CASE.program(first_layer=0)
     names = [p.name for p in main.global_block().all_parameters()]
     assert "l0.conv.in.w" in names and "l1.attn.q.w" in names
     assert {"l0.mlp.gate.w", "l1.mlp.gate.w", "l2.router.w"} <= set(names)
@@ -617,27 +501,12 @@ def test_tiny_model_amp_within_bf16_of_reference():
     router's scores, `b`, the convolution's sums, every norm's statistics
     and rotary's trigonometry stay float32. At the initial weights (a
     sharper router flips a few assignments under bf16 inputs)."""
-    main, params, feed, got, grads, after = _run_tiny(amp=True, seeded=False)
-    want, want_grads = ref.loss_and_grads(
-        params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
-        last=TINY["seq_len"], **REF_KW)
-    assert abs(float(got["loss"][0]) - float(want["loss"])) < 0.002
-    assert got["logits"].dtype == jnp.bfloat16
-    err = np.abs(np.asarray(got["logits"], np.float32)
-                 - np.asarray(want["logits"]))
-    std = float(np.std(want["logits"]))
-    assert err.mean() < 0.02 * std and err.max() < std
-    for name in ("l1.conv.in.w", "l1.conv.out.w", "l2.attn.q.w",
-                 "l2.attn.k.w", "l1.mlp.gate.w", "l2.experts.gate.w",
-                 "l5.conv.in.w", "embed.w"):
-        assert grads[name].dtype == np.float32
-        # a routed expert's gradient feels every assignment that a bf16
-        # input flips to another expert (a whole row of it)
-        limit = 0.12 if ".experts." in name else 0.08
-        assert frob(grads[name], want_grads[name]) < limit, name
-    assert frob(grads["l1.conv.conv.w"], want_grads["l1.conv.conv.w"]) < 0.08
-    for name in BIASES:
-        assert after[name].dtype == np.float32
+    # a routed expert's gradient feels every assignment that a bf16 input
+    # flips to another expert (a whole row of it)
+    CASE.amp_within_bf16_of_reference(
+        {0.08: ("l1.conv.in.w", "l1.conv.out.w", "l2.attn.q.w",
+                "l2.attn.k.w", "l1.mlp.gate.w", "l5.conv.in.w", "embed.w",
+                "l1.conv.conv.w"), 0.12: ("l2.experts.gate.w",)}, most=1.0)
 
 
 def test_amp_lists_leave_the_convolution_and_the_gates_alone():
@@ -650,19 +519,12 @@ def test_amp_lists_leave_the_convolution_and_the_gates_alone():
 
 
 def test_five_adam_steps_lower_the_loss():
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.Adam(learning_rate=3e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    feed = _batch()
-    losses = [float(exe.run(main, feed=feed, fetch_list=[fetches["loss"]],
-                            scope=scope)[0][0]) for _ in range(6)]
-    assert np.all(np.isfinite(losses)) and losses[-1] < losses[0] - 0.05
+    CASE.adam_steps_lower_the_loss()
 
 
 # -- what the Program holds; spans and counters --------------------------------------------------
 
+# its own body: a case a layer, and the ops of a scope counted and in order
 @pytest.mark.parametrize("layer", PUBLISHED)
 def test_every_layer_is_built_under_its_name_scopes(tiny, layer):
     """A layer's operator with its norm and add under `l<p>.conv` or
@@ -716,39 +578,29 @@ CENSUS = {"layer_kinds": {"full_attention": 1, "short_conv": 4},
 
 
 def test_layer_census_reads_the_issues_counts():
-    main, _, _, _ = _program(fluid.optimizer.SGD(learning_rate=1e-3))
+    main, _, _, _ = CASE.program(fluid.optimizer.SGD(learning_rate=1e-3))
     got = census.layer_census(main)
     assert got == CENSUS
     for absent in ("attention_unrotated_layers", "residual_out_norms",
                    "moe_router_groups", "state_space_layers"):
         assert absent not in got
     # at the published heads the group is 4
-    main, _, _, _ = _program(n_head=32, n_kv_head=8, head_dim=4)
+    main, _, _, _ = CASE.program(n_head=32, n_kv_head=8, head_dim=4)
     assert census.layer_census(main)["attention_kv_group"] == 4
-    main, _, _, _ = _program(tie_embeddings=False)
+    main, _, _, _ = CASE.program(tie_embeddings=False)
     assert "tied_heads" not in census.layer_census(main)
 
 
 @pytest.fixture(scope="module")
 def compile_detail():
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.SGD(learning_rate=1e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    exe.run(main, feed=_batch(), fetch_list=[fetches["loss"]], scope=scope)
-    latest = observe.observatory().latest
-    return latest(main._uid).detail, latest(startup._uid).detail
+    return CASE.compile_detail()
 
 
 @pytest.mark.parametrize("key,value", sorted(
     {**CENSUS, "causal_conv_plan": "xla", "grad_fanin_max": 2,
      "moe_row_buffer_rows": 2 * 128 * 4 + 4 * 128}.items()))
 def test_compile_event_carries_the_census(compile_detail, key, value):
-    detail, startup_detail = compile_detail
-    assert detail[key] == value
-    # the startup program holds no layer (its sharing counts read 0)
-    assert not startup_detail.get(key)
+    carries_the_census(compile_detail, {key: value})
 
 
 @pytest.mark.parametrize("model,has_conv", [
@@ -758,8 +610,7 @@ def test_the_new_keys_go_with_what_they_count(model, has_conv):
     """A convolution with silu in front of a scan or a delta rule is no
     short-conv layer: no accepted program gains a `short_conv` key, and only
     a program with a convolution notes its plan."""
-    import test_decoder_models
-    main, startup, feeds, fetches = test_decoder_models.build_program(model)
+    main, startup, feeds, fetches = build_program(model)
     got = census.layer_census(main)
     assert not [k for k in got if k.startswith("short_conv")]
     assert "short_conv" not in got.get("layer_kinds", {})
@@ -790,10 +641,7 @@ def test_a_bare_convolution_in_front_of_a_scan_is_no_short_conv():
 # -- the copies and the harness ------------------------------------------------------------------
 
 def test_the_two_copies_of_the_reference_are_identical():
-    assert filecmp.cmp(
-        os.path.join(HERE, "lfm2_moe_reference.py"),
-        os.path.join(ROOT, "benchmark", "references",
-                     "lfm2_moe_reference.py"), shallow=False)
+    CASE.two_copies_of_the_reference_are_identical()
 
 
 def test_the_config_holds_the_published_widths_and_the_cut():
@@ -860,16 +708,4 @@ def test_the_config_states_what_build_gives():
 
 
 def test_the_tiny_block_runs_through_the_benchmark():
-    """`run.py --tiny` on the cell: the configuration's tiny block through
-    the harness's own rehearsal, the in-run reference comparison
-    included."""
-    out = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
-         "--workload", "lfm2_8b_a1b.s4096", "--seed", "3000000019",
-         "--seconds", "1", "--trace", "0", "--tiny"],
-        capture_output=True, text=True, timeout=600, cwd=ROOT,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
-    assert "REHEARSAL" in out.stdout and "reference check after" in out.stdout
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["correct"] is True and line["rehearsal"] is True
+    runs_through_the_benchmark("lfm2_8b_a1b.s4096")

@@ -7,12 +7,7 @@ masked softmax whose mask is two inequalities, `jnp.repeat`, YaRN from its
 formulas, a loop over the held experts). Seeded random weights, float32, AMP
 off unless a test says otherwise."""
 
-import filecmp
-import json
 import math
-import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -20,25 +15,18 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu import io, layers, models, observe
+from paddle_tpu import io, layers, models
 from paddle_tpu.core import ir, registry
 from paddle_tpu.ops import decoder_block
 
 import mellum2_reference as ref
-from test_olmoe import rel_err, run_piece
-from test_qwen3_next import frob
+from decoder_case import (DIGESTS, TINY_YARN, DecoderCase, build_program,
+                          carries_the_census, frob,
+                          layers_are_built_under_their_scopes, program_digest,
+                          rel_err, run_piece, runs_through_the_benchmark,
+                          tiny_args)
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(HERE)
-# a YaRN block that bends the frequencies of a 16-wide head: low 0, high 3
-TINY_YARN = {"factor": 4.0, "original_max_position_embeddings": 64,
-             "beta_fast": 32.0, "beta_slow": 1.0}
-# the published pattern; a window shorter than the sequence and no multiple
-# of 128; a group of 2; a share that starts above expert 0
-TINY = dict(vocab_size=64, seq_len=256, n_layer=4, d_model=32, n_head=4,
-            n_kv_head=2, head_dim=16, sliding_window=96, rope_theta=1e4,
-            rope_scaling=TINY_YARN, n_expert=16, top_k=3, d_expert=16,
-            first_expert=4, experts_held=4)
+TINY = tiny_args("mellum2")
 REF_KW = {k: TINY[k] for k in (
     "n_layer", "n_head", "n_kv_head", "head_dim", "sliding_window",
     "rope_theta", "rope_scaling", "top_k", "first_expert")}
@@ -189,78 +177,37 @@ def test_the_eight_shares_add_up_to_the_whole_layer(path, monkeypatch):
 
 # -- the model ----------------------------------------------------------------------------
 
-def _program(optimizer=None, **sizes):
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        feeds, fetches = models.mellum2.build(**{**TINY, **sizes})
-        if optimizer is None:
-            pairs = fluid.append_backward(fetches["loss"])
-        else:
-            optimizer.minimize(fetches["loss"])
-            pairs = []
-    main.random_seed = startup.random_seed = 7
-    return main, startup, fetches, pairs
-
-
-def _batch(seed=0, batch=2, seq_len=TINY["seq_len"]):
-    rng = np.random.RandomState(seed)
-    shape = (batch, seq_len)
-    return {"tokens": rng.randint(0, TINY["vocab_size"], shape)
-            .astype(np.int32),
-            "labels": rng.randint(0, TINY["vocab_size"], shape)
-            .astype(np.int32)}
-
-
-def _parameter_names(main):
-    return [p.name for p in main.global_block().all_parameters()]
-
-
-def _seeded_weights(scope, names, seed=3):
+def _seeded_values(shapes, seed=3):
     """Weights far from their initial values, so that no term of the
     comparison is small by construction: norm weights in [0.5, 1.5], a
     router five times as sharp, matrices of std 0.1 (five times the
     initial)."""
     rng = np.random.RandomState(seed)
-    for name in sorted(names):
-        shape = np.shape(scope.find_var(name))
+    values = {}
+    for name in sorted(shapes):
+        shape = shapes[name]
         if "norm" in name:
             value = rng.uniform(0.5, 1.5, shape)
         elif name.endswith("router.w"):
             value = rng.randn(*shape) * 0.5
         else:
             value = rng.randn(*shape) * 0.1
-        scope.set_var(name, jnp.asarray(value.astype(np.float32)))
+        values[name] = value.astype(np.float32)
+    return values
 
 
 FETCHES = ["loss", "ce", "load_balance", "logits", "tokens_per_expert"]
-
-
-def _run_tiny(amp, seeded=True):
-    main, startup, fetches, pairs = _program()
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace(), amp=amp)
-    exe.run(startup, scope=scope)
-    names = _parameter_names(main)
-    if seeded:
-        _seeded_weights(scope, names)
-    params = {n: np.asarray(scope.find_var(n)) for n in names}
-    feed = _batch()
-    out = exe.run(main, feed=feed,
-                  fetch_list=[fetches[n] for n in FETCHES]
-                  + [g for _, g in pairs], scope=scope)
-    got = dict(zip(FETCHES, out))
-    grads = dict(zip((p.name for p, _ in pairs), out[len(FETCHES):]))
-    return main, params, feed, got, grads
+# what each planted fault has to move, at least: the loss by 1e-4 or a
+# gradient by 1% where the true reference is met within 2e-4
+FAULT_WRT = ["l0.attn.q.w", "l0.attn.k.w", "l0.attn.v.w", "l3.attn.q.w",
+             "l3.attn.k.w"]
+CASE = DecoderCase(models.mellum2.build, TINY, ref, REF_KW, FETCHES,
+                   seeded_values=_seeded_values, fault_wrt=FAULT_WRT)
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    main, params, feed, got, grads = _run_tiny(amp=False)
-    tokens, labels = jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"])
-    want, want_grads = ref.loss_and_grads(
-        params, tokens, labels, last=TINY["seq_len"], **REF_KW)
-    return dict(main=main, params=params, tokens=tokens, labels=labels,
-                got=got, grads=grads, want=want, want_grads=want_grads)
+    return CASE.tiny_model()
 
 
 LAYER = ["in_norm.w", "post_norm.w", "attn.q.w", "attn.k.w", "attn.v.w",
@@ -271,57 +218,31 @@ TRAINED = (["embed.w", "final_norm.w", "head.w"]
 
 
 def test_tiny_model_has_the_reference_parameters(tiny):
-    assert sorted(tiny["params"]) == sorted(TRAINED)
-    assert tiny["params"]["l0.attn.q.w"].shape == (32, 4 * 16)
-    assert tiny["params"]["l0.attn.k.w"].shape == (32, 2 * 16)
-    assert tiny["params"]["l3.attn.v.w"].shape == (32, 2 * 16)
-    assert tiny["params"]["l0.attn.q_norm.w"].shape == (16,)
-    assert tiny["params"]["l1.experts.gate.w"].shape == (4, 32, 16)
-    assert tiny["params"]["l1.router.w"].shape == (32, 16)
-    assert sorted(tiny["grads"]) == sorted(TRAINED)
+    CASE.has_the_reference_parameters(tiny, TRAINED, {
+        "l0.attn.q.w": (32, 4 * 16), "l0.attn.k.w": (32, 2 * 16),
+        "l3.attn.v.w": (32, 2 * 16), "l0.attn.q_norm.w": (16,),
+        "l1.experts.gate.w": (4, 32, 16), "l1.router.w": (32, 16)})
 
 
 @pytest.mark.parametrize("name", FETCHES)
 def test_tiny_model_output_matches_reference(tiny, name):
-    if name == "tokens_per_expert":
-        assert np.array_equal(tiny["got"][name], tiny["want"][name])
-    else:
-        want = np.asarray(tiny["want"][name])
-        assert rel_err(np.reshape(tiny["got"][name], want.shape), want) < 1e-4
+    CASE.output_matches_reference(tiny, name)
 
 
 @pytest.mark.parametrize("name", TRAINED)
 def test_tiny_model_gradient_matches_reference(tiny, name):
-    assert frob(tiny["grads"][name], tiny["want_grads"][name]) < 2e-4
+    CASE.gradient_matches_reference(tiny, name)
 
 
-# what each planted fault has to move, at least: the loss by 1e-4 or a
-# gradient by 1% where the true reference is met within 2e-4
 @pytest.mark.parametrize("fault", sorted(ref.FAULTS))
 def test_each_planted_fault_is_refused(tiny, fault):
-    """The comparison that passes the reference refuses each fault: the
-    logits, the loss or a mixer's gradient moves by far more than the
-    system's distance from the true reference."""
-    wrt = ["l0.attn.q.w", "l0.attn.k.w", "l0.attn.v.w", "l3.attn.q.w",
-           "l3.attn.k.w"]
-    bad, bad_grads = ref.loss_and_grads(
-        tiny["params"], tiny["tokens"], tiny["labels"], wrt=wrt,
-        last=TINY["seq_len"], fault=fault, **REF_KW)
-    moved = [rel_err(tiny["got"]["logits"], bad["logits"])] \
-        + [frob(tiny["grads"][n], bad_grads[n]) for n in wrt]
-    held = [rel_err(tiny["got"]["logits"], tiny["want"]["logits"])] \
-        + [frob(tiny["grads"][n], tiny["want_grads"][n]) for n in wrt]
-    assert max(held) < 2e-4
-    assert max(moved) > 50 * 2e-4, (fault, moved)
-    # a fault on one kind of layer leaves the other kind's rotary and mask
-    # alone, but everything downstream still feels it
-    assert abs(float(bad["loss"]) - float(tiny["want"]["loss"])) > 1e-4
+    """A fault on one kind of layer leaves the other kind's rotary and mask
+    alone, but everything downstream still feels it: the loss moves too."""
+    CASE.planted_fault_is_refused(tiny, fault, loss=1e-4)
 
 
 def test_an_unknown_fault_is_refused(tiny):
-    with pytest.raises(ValueError, match="fault is one of"):
-        ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
-                       fault="no_such", **REF_KW)
+    CASE.unknown_fault_is_refused(tiny)
 
 
 def test_interpreted_kernels_give_the_reference_too(monkeypatch):
@@ -329,7 +250,7 @@ def test_interpreted_kernels_give_the_reference_too(monkeypatch):
     (the windowed one-pass forward and the fused backward at 256 tokens)
     instead of the CPU path's jnp reference."""
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-    _, params, feed, got, grads = _run_tiny(amp=False)
+    _, params, feed, got, grads, _ = CASE.run_tiny(amp=False)
     want, want_grads = ref.loss_and_grads(
         params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
         wrt=["l0.attn.q.w", "l1.attn.k.w", "l2.attn.v.w", "l3.attn.q.w"],
@@ -340,28 +261,17 @@ def test_interpreted_kernels_give_the_reference_too(monkeypatch):
 
 
 def test_reference_in_blocks_is_the_reference(tiny):
-    """`q_block` and `remat` are the reference's memory, not its
-    mathematics."""
-    parts, grads = ref.loss_and_grads(
-        tiny["params"], tiny["tokens"], tiny["labels"],
-        wrt=["l0.attn.q.w", "l3.attn.k.w", "l2.router.w", "embed.w"],
-        q_block=32, remat=True, **REF_KW)
-    assert abs(float(parts["loss"]) - float(tiny["want"]["loss"])) < 1e-5
-    for name, g in grads.items():
-        assert frob(g, tiny["want_grads"][name]) < 1e-5, name
+    CASE.reference_in_blocks_is_the_reference(
+        tiny, ["l0.attn.q.w", "l3.attn.k.w", "l2.router.w", "embed.w"],
+        q_block=32)
 
 
 def test_reference_last_positions_equal_the_full_pass(tiny):
-    parts = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
-                           last=16, **REF_KW)
-    assert rel_err(parts["logits"], tiny["want"]["logits"][:, -16:]) < 1e-6
+    CASE.reference_last_positions_equal_the_full_pass(tiny)
 
 
 def test_reference_in_bfloat16_is_another_number(tiny):
-    low = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
-                         dtype=jnp.bfloat16, **REF_KW)
-    assert low["loss"].dtype == jnp.bfloat16
-    assert abs(float(low["loss"]) - float(tiny["want"]["loss"])) > 1e-4
+    CASE.reference_in_bfloat16_is_another_number(tiny)
 
 
 def test_layer_types_repeat_as_a_period():
@@ -372,7 +282,7 @@ def test_layer_types_repeat_as_a_period():
     assert models.mellum2.PERIOD == ref.PERIOD
     with pytest.raises(ValueError, match="layer_types holds"):
         kinds(2, ["sliding_attention", "linear_attention"])
-    main, _, _, _ = _program(n_layer=2, layer_types=["full_attention",
+    main, _, _, _ = CASE.program(n_layer=2, layer_types=["full_attention",
                                                      "sliding_attention"])
     windows = [op.attrs.get("window") for op in main.global_block().ops
                if op.type == "fused_attention"]
@@ -384,46 +294,26 @@ def test_tiny_model_amp_within_bf16_of_reference():
     experts are bf16; the router, every norm's statistics and rotary's
     trigonometry stay float32. At the initial weights (a sharper router
     flips a few assignments under bf16 inputs)."""
-    _, params, feed, got, grads = _run_tiny(amp=True, seeded=False)
-    want, want_grads = ref.loss_and_grads(
-        params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
-        last=TINY["seq_len"], **REF_KW)
-    assert abs(float(got["loss"][0]) - float(want["loss"])) < 0.002
-    assert got["logits"].dtype == jnp.bfloat16
-    err = np.abs(np.asarray(got["logits"], np.float32)
-                 - np.asarray(want["logits"]))
-    std = float(np.std(want["logits"]))
-    assert err.mean() < 0.02 * std and err.max() < 0.15 * std
-    for name in ("l0.attn.q.w", "l0.attn.k.w", "l0.attn.v.w",
-                 "l0.attn.q_norm.w", "l3.attn.q.w", "l3.attn.k.w",
-                 "l1.experts.gate.w", "embed.w"):
-        assert grads[name].dtype == np.float32
-        limit = 0.08 if ".experts." in name else 0.04
-        assert frob(grads[name], want_grads[name]) < limit, name
+    CASE.amp_within_bf16_of_reference(
+        {0.04: ("l0.attn.q.w", "l0.attn.k.w", "l0.attn.v.w",
+                "l0.attn.q_norm.w", "l3.attn.q.w", "l3.attn.k.w", "embed.w"),
+         0.08: ("l1.experts.gate.w",)}, most=0.15)
 
 
 def test_five_adam_steps_lower_the_loss():
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.Adam(learning_rate=3e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    feed = _batch()
-    losses = [float(exe.run(main, feed=feed, fetch_list=[fetches["loss"]],
-                            scope=scope)[0][0]) for _ in range(6)]
-    assert np.all(np.isfinite(losses)) and losses[-1] < losses[0] - 0.05
+    CASE.adam_steps_lower_the_loss()
 
 
 def test_save_and_load_carry_the_weights_and_the_new_attributes(tmp_path):
     """A checkpoint into a fresh scope gives the same loss; the program
     written out and parsed back keeps `window` and `scaling`, and runs to
     the same loss."""
-    main, startup, fetches, _ = _program(
+    main, startup, fetches, _ = CASE.program(
         fluid.optimizer.Adam(learning_rate=1e-3))
     scope = fluid.Scope()
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(startup, scope=scope)
-    feed = _batch()
+    feed = CASE.batch()
     exe.run(main, feed=feed, fetch_list=[fetches["loss"]], scope=scope)
     io.save_persistables(exe, str(tmp_path), main_program=main, scope=scope)
     (loss,) = exe.run(main, feed=feed, fetch_list=[fetches["loss"]],
@@ -450,7 +340,7 @@ def test_save_and_load_carry_the_weights_and_the_new_attributes(tmp_path):
 
 
 def test_attention_ops_have_the_groups_shapes():
-    main, _, _, _ = _program(n_layer=1)
+    main, _, _, _ = CASE.program(n_layer=1)
     block = main.global_block()
     (op,) = [o for o in block.ops if o.type == "fused_attention"]
     for slot in ("Q", "K", "V"):
@@ -463,26 +353,14 @@ def test_attention_ops_have_the_groups_shapes():
 # -- spans and counters ---------------------------------------------------------------------
 
 def test_compile_event_carries_the_census():
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.SGD(learning_rate=1e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    exe.run(main, feed=_batch(), fetch_list=[fetches["loss"]], scope=scope)
-    detail = observe.observatory().latest(main._uid).detail
-    assert detail["layer_kinds"] == {"window_attention": 3,
-                                     "full_attention": 1}
-    assert detail["attention_window_layers"] == 3
-    assert detail["attention_window"] == 96
-    assert detail["attention_kv_group"] == 2
-    assert detail["moe_experts_routed"] == 16
-    assert detail["moe_experts_held"] == 4
-    assert detail["moe_share_bounded_moves"] == 4 * 4
-    # batch 2 x 4 heads x 3 layers, 128 x 128 tiles under a window of 96:
-    # all three of the triangle's meet the band
-    assert detail["window_tiles_computed"] == 72
-    assert "layer_kinds" not in observe.observatory().latest(
-        startup._uid).detail
+    carries_the_census(CASE.compile_detail(), {
+        "layer_kinds": {"window_attention": 3, "full_attention": 1},
+        "attention_window_layers": 3, "attention_window": 96,
+        "attention_kv_group": 2, "moe_experts_routed": 16,
+        "moe_experts_held": 4, "moe_share_bounded_moves": 4 * 4,
+        # batch 2 x 4 heads x 3 layers, 128 x 128 tiles under a window of
+        # 96: all three of the triangle's meet the band
+        "window_tiles_computed": 72}, startup_lacks=["layer_kinds"])
 
 
 def test_the_tally_follows_the_tiles(monkeypatch):
@@ -492,14 +370,7 @@ def test_the_tally_follows_the_tiles(monkeypatch):
     adds nothing to it."""
     from paddle_tpu.ops import pallas_attention
     monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", (128, 128))
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.SGD(learning_rate=1e-3), seq_len=512, n_layer=4)
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    exe.run(main, feed=_batch(seq_len=512), fetch_list=[fetches["loss"]],
-            scope=scope)
-    detail = observe.observatory().latest(main._uid).detail
+    detail, _ = CASE.compile_detail(seq_len=512, n_layer=4)
     assert detail["window_tiles_computed"] == 3 * 2 * 4 * 7
 
 
@@ -516,50 +387,32 @@ def test_the_unmasked_tally_follows_the_tiles(monkeypatch, window, windowed,
     monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", (128, 128))
     assert pallas_attention.interior_tiles(512) == 6
     assert pallas_attention.interior_tiles(512, window) == windowed
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.SGD(learning_rate=1e-3), seq_len=512, n_layer=4,
-        sliding_window=window)
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    exe.run(main, feed=_batch(seq_len=512), fetch_list=[fetches["loss"]],
-            scope=scope)
-    detail = observe.observatory().latest(main._uid).detail
-    assert detail["window_tiles_computed"] == 3 * 2 * 4 * band
-    assert detail["flash_tiles_unmasked"] == 2 * 4 * 3 * windowed
-    # the full layer's grid has six steps above the diagonal, a head
-    assert detail["flash_dead_steps_held"] == 2 * 4 * 1 * 6
-    assert "flash_tiles_unmasked" not in observe.observatory().latest(
-        startup._uid).detail
+    carries_the_census(
+        CASE.compile_detail(seq_len=512, n_layer=4, sliding_window=window),
+        {"window_tiles_computed": 3 * 2 * 4 * band,
+         "flash_tiles_unmasked": 2 * 4 * 3 * windowed,
+         # the full layer's grid has six steps above the diagonal, a head
+         "flash_dead_steps_held": 2 * 4 * 1 * 6},
+        startup_lacks=["flash_tiles_unmasked"])
 
 
 def test_a_window_over_the_whole_sequence_is_counted_as_full():
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.SGD(learning_rate=1e-3), sliding_window=256,
-        n_layer=2, layer_types=["sliding_attention"])
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    exe.run(main, feed=_batch(), fetch_list=[fetches["loss"]], scope=scope)
-    detail = observe.observatory().latest(main._uid).detail
+    detail, _ = CASE.compile_detail(sliding_window=256, n_layer=2,
+                                    layer_types=["sliding_attention"])
     assert detail["layer_kinds"] == {"full_attention": 2}
     assert "attention_window_layers" not in detail
 
 
 def test_every_layer_is_built_under_its_name_scopes(tiny):
-    scopes = {}
-    for op in tiny["main"].global_block().ops:
-        if op.attrs.get("__role__") is None:
-            scopes.setdefault(op.attrs.get(ir.NAME_SCOPE_ATTR), set()) \
-                .add(op.type)
-    assert {"l0.swa", "l1.swa", "l2.swa", "l3.attn", "l0.moe",
-            "l3.moe"} <= set(scopes)
-    assert "l3.swa" not in scopes and "l0.attn" not in scopes
-    for name in ("l0.swa", "l3.attn"):
-        assert {"fused_attention", "rotary_embedding", "expand", "rms_norm",
-                "mul"} <= scopes[name]
-    assert {"moe_router", "moe_dispatch", "grouped_matmul",
-            "moe_combine"} <= scopes["l2.moe"]
+    mixer = ["fused_attention", "rotary_embedding", "expand", "rms_norm",
+             "mul"]
+    layers_are_built_under_their_scopes(
+        tiny["main"],
+        ["l0.swa", "l1.swa", "l2.swa", "l3.attn", "l0.moe", "l3.moe"],
+        absent=["l3.swa", "l0.attn"],
+        holds={"l0.swa": mixer, "l3.attn": mixer,
+               "l2.moe": ["moe_router", "moe_dispatch", "grouped_matmul",
+                          "moe_combine"]})
 
 
 def test_amp_lists_hold_the_router_and_attention():
@@ -576,8 +429,7 @@ def test_programs_without_a_window_are_unchanged_op_for_op(model):
     """`fused_attention` took a window and `rotary_embedding` a scaling
     block in this file's PR; a program that passes neither is the program
     it was, op for op and attribute for attribute
-    (`test_decoder_models.DIGESTS`)."""
-    from test_decoder_models import DIGESTS, build_program, program_digest
+    (`decoder_case.DIGESTS`)."""
     main, startup, _, _ = build_program(model)
     assert program_digest(main, startup) == DIGESTS[model]
     for op in main.global_block().ops:
@@ -585,23 +437,8 @@ def test_programs_without_a_window_are_unchanged_op_for_op(model):
 
 
 def test_the_two_copies_of_the_reference_are_identical():
-    assert filecmp.cmp(
-        os.path.join(HERE, "mellum2_reference.py"),
-        os.path.join(ROOT, "benchmark", "references",
-                     "mellum2_reference.py"), shallow=False)
+    CASE.two_copies_of_the_reference_are_identical()
 
 
 def test_the_tiny_block_runs_through_the_benchmark():
-    """`run.py --tiny` on the cell: the configuration's tiny block through
-    the harness's own rehearsal, the in-run reference comparison
-    included."""
-    out = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
-         "--workload", "mellum2_12b_a2_5b.s8192", "--seed", "3000000019",
-         "--seconds", "1", "--trace", "0", "--tiny"],
-        capture_output=True, text=True, timeout=600, cwd=ROOT,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
-    assert "REHEARSAL" in out.stdout and "reference check after" in out.stdout
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["correct"] is True and line["rehearsal"] is True
+    runs_through_the_benchmark("mellum2_12b_a2_5b.s8192")

@@ -16,12 +16,6 @@ its reason). The chunked scan against the recurrence sums the same products
 in another order, exponentials of differences in place of products of
 exponentials: 2e-5 of the largest value (RTOL)."""
 
-import filecmp
-import json
-import os
-import subprocess
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,28 +23,26 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import layers, models, observe
-from paddle_tpu.core import ir, registry
+from paddle_tpu.core import registry
 from paddle_tpu.observe import census
 from paddle_tpu.ops import decoder_block as db
 from paddle_tpu.ops import linear_attention as la
 from paddle_tpu.ops import state_space as ss
 
 import nemotron_h_reference as ref
-from test_kanana2 import _planted
-from test_olmoe import rel_err, run_piece
-from test_qwen3_next import frob
+from decoder_case import (SCAN_NAMES, DecoderCase, _forward_ops_by_scope,
+                          _planted, _published_scan, _recurrence,
+                          _scan_inputs, _scan_layer, carries_the_census,
+                          config, frob, rel_err, run_piece,
+                          runs_through_the_benchmark, tiny_args)
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(HERE)
-with open(os.path.join(ROOT, "benchmark", "configs",
-                       "nemotron_3_nano_30b_a3b.json")) as f:
-    CONFIG = json.load(f)
+CONFIG = config("nemotron_h")
 GAMMA = 0.001
 PATTERN = "MEMEM*EME"
 # the pattern's nine layers, hidden 64, 4 state-space heads of 16 in 2 groups
 # over a state of 16, 4/2 attention heads of 16, 256 tokens in chunks of 128,
 # 16 experts top-3 of width 24, 4 held from expert 4, a shared expert of 48
-TINY = {**CONFIG["build_args"], **CONFIG["tiny"]["build_args"]}
+TINY = tiny_args("nemotron_h")
 REF_KW = {k: TINY[k] for k in (
     "layer_pattern", "mamba_heads", "mamba_head_dim", "n_groups", "ssm_state",
     "n_head", "n_kv_head", "head_dim", "top_k", "first_expert",
@@ -74,39 +66,6 @@ def test_the_tiny_block_is_the_issues():
 
 
 # -- the scan: chunks against the recurrence -------------------------------------------------
-
-def _scan_inputs(B, T, H, P, G, N, seed=0):
-    rng = np.random.RandomState(seed)
-    f = np.float32
-    return ({"x": rng.randn(B, T, H, P).astype(f),
-             "b": rng.randn(B, T, G, N).astype(f) * 0.5,
-             "c": rng.randn(B, T, G, N).astype(f) * 0.5,
-             "dt_raw": rng.randn(B, T, H).astype(f)},
-            {"A_log": np.log(rng.uniform(1, 8, H)).astype(f),
-             "dt_bias": (rng.randn(H) * 0.5 - 1.0).astype(f),
-             "D": rng.uniform(0.5, 1.5, H).astype(f)})
-
-
-def _recurrence(x, b, c, dt_raw, A_log, dt_bias, D):
-    dt = jax.nn.softplus(dt_raw + dt_bias)
-    r = x.shape[2] // b.shape[2]
-    return ref.selective_scan(x, dt, -jnp.exp(A_log) * dt,
-                              jnp.repeat(b, r, axis=2),
-                              jnp.repeat(c, r, axis=2), D)
-
-
-def _scan_layer(chunk):
-    def build(d):
-        return [layers.ssd_scan(
-            d["x"], d["b"], d["c"], d["dt_raw"], chunk=chunk,
-            a_log_attr=fluid.ParamAttr(name="A_log"),
-            dt_bias_attr=fluid.ParamAttr(name="dt_bias"),
-            d_attr=fluid.ParamAttr(name="D"))]
-    return build
-
-
-SCAN_NAMES = ["x", "b", "c", "dt_raw", "A_log", "dt_bias", "D"]
-
 
 @pytest.mark.parametrize("B,T,chunk", [(1, 64, 64), (1, 256, 64),
                                        (2, 128, 64), (1, 256, 128)],
@@ -141,20 +100,6 @@ def test_scan_refuses_a_length_off_the_chunk():
     feed, params = _scan_inputs(1, 96, 4, 8, 2, 16)
     with pytest.raises(Exception, match="multiple of the chunk"):
         run_piece(_scan_layer(64), feed, params)
-
-
-def _published_scan(seed=2, T=256, heads=8):
-    """One group at the published head shapes: 8 heads of 64 over a state of
-    128, chunk 128."""
-    rng = np.random.RandomState(seed)
-    f = jnp.float32
-    x = jnp.asarray(rng.randn(1, T, heads, 64), f)
-    b = jnp.asarray(rng.randn(1, T, 1, 128) * 0.3, f)
-    c = jnp.asarray(rng.randn(1, T, 1, 128) * 0.3, f)
-    dt = jax.nn.softplus(jnp.asarray(rng.randn(1, T, heads) - 1.0, f))
-    a = -jnp.asarray(rng.uniform(1, 8, heads), f) * dt
-    D = jnp.asarray(rng.uniform(0.5, 1.5, heads), f)
-    return x, dt, a, b, c, D
 
 
 def test_interpreted_kernels_are_the_chunked_form(monkeypatch):
@@ -464,37 +409,16 @@ def test_an_expert_layer_is_gated_silu_or_ungated_relu2():
 
 # -- the model ----------------------------------------------------------------------------------
 
-def _program(optimizer=None, **sizes):
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        feeds, fetches = models.nemotron_h.build(**{**TINY, **sizes})
-        if optimizer is None:
-            pairs = fluid.append_backward(fetches["loss"])
-        else:
-            optimizer.minimize(fetches["loss"])
-            pairs = []
-    main.random_seed = startup.random_seed = 7
-    return main, startup, fetches, pairs
-
-
-def _batch(seed=0, batch=2):
-    rng = np.random.RandomState(seed)
-    shape = (batch, TINY["seq_len"])
-    return {"tokens": rng.randint(0, TINY["vocab_size"], shape)
-            .astype(np.int32),
-            "labels": rng.randint(0, TINY["vocab_size"], shape)
-            .astype(np.int32)}
-
-
-def _seeded_weights(scope, names, seed=3):
+def _seeded_values(shapes, seed=3):
     """Weights far from their initial values, so that no term of the
     comparison is small by construction: norm weights and D in [0.5, 1.5], a
     router five times as sharp, a planted bias of std 0.2, decays `A_log` in
     log [1, 8], `dt_bias` around -1, a convolution bias of std 0.3, the other
     matrices of std 0.1 (five times the initial)."""
     rng = np.random.RandomState(seed)
-    for name in sorted(names):
-        shape = np.shape(scope.find_var(name))
+    values = {}
+    for name in sorted(shapes):
+        shape = shapes[name]
         if name.endswith("router.bias"):
             value = rng.randn(*shape) * 0.2
         elif "norm" in name or name.endswith(".D"):
@@ -511,41 +435,26 @@ def _seeded_weights(scope, names, seed=3):
             value = rng.uniform(-0.5, 0.5, shape)
         else:
             value = rng.randn(*shape) * 0.1
-        scope.set_var(name, jnp.asarray(value.astype(np.float32)))
+        values[name] = value.astype(np.float32)
+    return values
 
 
 FETCHES = ["loss", "ce", "logits", "tokens_per_expert"]
-
-
-def _run_tiny(amp, seeded=True):
-    main, startup, fetches, pairs = _program()
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace(), amp=amp)
-    exe.run(startup, scope=scope)
-    names = [p.name for p in main.global_block().all_parameters()]
-    if seeded:
-        _seeded_weights(scope, names)
-    params = {n: np.asarray(scope.find_var(n)) for n in names}
-    feed = _batch()
-    out = exe.run(main, feed=feed,
-                  fetch_list=[fetches[n] for n in FETCHES]
-                  + [g for _, g in pairs], scope=scope)
-    got = dict(zip(FETCHES, out))
-    grads = dict(zip((p.name for p, _ in pairs), out[len(FETCHES):]))
-    after = {n: np.asarray(scope.find_var(n)) for n in names
-             if n.endswith("router.bias")}
-    return main, params, feed, got, grads, after
+E_LAYERS = [i for i, kind in enumerate(PATTERN) if kind == "E"]
+BIASES = [f"l{i}.router.bias" for i in E_LAYERS]
+# what each planted fault has to move, at least: the logits or a gradient by
+# 1% where the true reference is met within 2e-4
+FAULT_WRT = ["l0.mamba.in.w", "l0.mamba.A_log", "l0.mamba.dt_bias",
+             "l0.mamba.conv.b", "l0.mamba.norm.w", "l2.mamba.D",
+             "l5.attn.k.w", "l1.experts.up.w", "l1.router.w", "embed.w"]
+CASE = DecoderCase(models.nemotron_h.build, TINY, ref, REF_KW, FETCHES,
+                   state=BIASES, seeded_values=_seeded_values,
+                   fault_wrt=FAULT_WRT)
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    main, params, feed, got, grads, after = _run_tiny(amp=False)
-    tokens, labels = jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"])
-    want, want_grads = ref.loss_and_grads(
-        params, tokens, labels, last=TINY["seq_len"], **REF_KW)
-    return dict(main=main, params=params, tokens=tokens, labels=labels,
-                got=got, grads=grads, after=after, want=want,
-                want_grads=want_grads)
+    return CASE.tiny_model()
 
 
 MAMBA = ["mamba.in.w", "mamba.conv.w", "mamba.conv.b", "mamba.A_log",
@@ -557,33 +466,24 @@ OF_KIND = {"M": MAMBA, "*": ATTN, "E": MOE}
 TRAINED = (["embed.w", "final_norm.w", "head.w"]
            + [f"l{i}.{n}" for i, kind in enumerate(PATTERN)
               for n in ["norm.w"] + OF_KIND[kind]])
-E_LAYERS = [i for i, kind in enumerate(PATTERN) if kind == "E"]
-BIASES = [f"l{i}.router.bias" for i in E_LAYERS]
 
 
 def test_tiny_model_has_the_reference_parameters(tiny):
-    assert sorted(tiny["params"]) == sorted(TRAINED + BIASES)
-    shapes = {n: v.shape for n, v in tiny["params"].items()}
     inner, bc = 4 * 16, 2 * 16
-    assert shapes["l0.mamba.in.w"] == (64, 2 * inner + 2 * bc + 4)
-    assert shapes["l0.mamba.conv.w"] == (inner + 2 * bc, 4)
-    assert shapes["l0.mamba.conv.b"] == (inner + 2 * bc,)
-    assert shapes["l2.mamba.A_log"] == shapes["l2.mamba.dt_bias"] \
-        == shapes["l2.mamba.D"] == (4,)
-    assert shapes["l4.mamba.norm.w"] == (inner,)
-    assert shapes["l5.attn.q.w"] == (64, 4 * 16)
-    assert shapes["l5.attn.k.w"] == shapes["l5.attn.v.w"] == (64, 2 * 16)
-    assert shapes["l1.experts.up.w"] == (4, 64, 24)
-    assert shapes["l1.experts.down.w"] == (4, 24, 64)
-    assert shapes["l1.router.w"] == (64, 16)
-    assert shapes["l1.shared.up.w"] == (64, 48)
-    assert not any(".gate." in n for n in shapes)
-    # a gradient for every trained parameter and for no bias
-    assert sorted(tiny["grads"]) == sorted(TRAINED)
+    CASE.has_the_reference_parameters(tiny, TRAINED, {
+        "l0.mamba.in.w": (64, 2 * inner + 2 * bc + 4),
+        "l0.mamba.conv.w": (inner + 2 * bc, 4),
+        "l0.mamba.conv.b": (inner + 2 * bc,), "l2.mamba.A_log": (4,),
+        "l2.mamba.dt_bias": (4,), "l2.mamba.D": (4,),
+        "l4.mamba.norm.w": (inner,), "l5.attn.q.w": (64, 4 * 16),
+        "l5.attn.k.w": (64, 2 * 16), "l5.attn.v.w": (64, 2 * 16),
+        "l1.experts.up.w": (4, 64, 24), "l1.experts.down.w": (4, 24, 64),
+        "l1.router.w": (64, 16), "l1.shared.up.w": (64, 48)})
+    assert not any(".gate." in n for n in tiny["params"])
 
 
 def test_the_initial_values_are_the_public_ones():
-    main, startup, _, _ = _program()
+    main, startup, _, _ = CASE.program()
     scope = fluid.Scope()
     fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
     value = lambda n: np.asarray(scope.find_var(n))
@@ -606,62 +506,27 @@ def test_the_initial_values_are_the_public_ones():
 
 @pytest.mark.parametrize("name", FETCHES)
 def test_tiny_model_output_matches_reference(tiny, name):
-    if name == "tokens_per_expert":
-        assert np.array_equal(tiny["got"][name], tiny["want"][name])
-    else:
-        want = np.asarray(tiny["want"][name])
-        assert rel_err(np.reshape(tiny["got"][name], want.shape), want) < 1e-4
+    CASE.output_matches_reference(tiny, name)
 
 
 def test_tiny_routing_sends_most_assignments_elsewhere(tiny):
-    counts = tiny["got"]["tokens_per_expert"]
-    assert counts.shape == (4, 16) and np.all(counts.sum(1) == 2 * 256 * 3)
-    held = counts[:, 4:8].sum(1)
-    assert np.all(held > 0) and np.all(held < counts.sum(1) / 2)
+    CASE.routing_sends_most_assignments_elsewhere(tiny, routed_layers=4)
 
 
 @pytest.mark.parametrize("name", TRAINED)
 def test_tiny_model_gradient_matches_reference(tiny, name):
-    assert frob(tiny["grads"][name], tiny["want_grads"][name]) < 2e-4
+    CASE.gradient_matches_reference(tiny, name)
 
 
 @pytest.mark.parametrize("layer", E_LAYERS)
 def test_one_step_moves_the_bias_as_next_bias_does(tiny, layer):
-    name = f"l{layer}.router.bias"
-    want = ref.next_bias(tiny["params"][name],
-                         tiny["got"]["tokens_per_expert"][
-                             E_LAYERS.index(layer)], GAMMA)
-    assert np.array_equal(tiny["after"][name], np.asarray(want))
-    moved = tiny["after"][name] - tiny["params"][name]
-    assert np.all(np.isclose(np.abs(moved), GAMMA, rtol=1e-3)
-                  | (moved == 0)) and np.any(moved != 0)
-
-
-# what each planted fault has to move, at least: the logits or a gradient by
-# 1% where the true reference is met within 2e-4
-FAULT_WRT = ["l0.mamba.in.w", "l0.mamba.A_log", "l0.mamba.dt_bias",
-             "l0.mamba.conv.b", "l0.mamba.norm.w", "l2.mamba.D",
-             "l5.attn.k.w", "l1.experts.up.w", "l1.router.w", "embed.w"]
+    CASE.one_step_moves_the_bias_as_next_bias_does(
+        tiny, f"l{layer}.router.bias", GAMMA)
 
 
 @pytest.mark.parametrize("fault", sorted(ref.FAULTS))
 def test_each_planted_fault_is_refused(tiny, fault):
-    """The comparison that passes the reference refuses each fault: the
-    logits, the loss or a gradient moves by far more than the system's
-    distance from the true reference."""
-    bad, bad_grads = ref.loss_and_grads(
-        tiny["params"], tiny["tokens"], tiny["labels"], wrt=FAULT_WRT,
-        last=TINY["seq_len"], fault=fault, **REF_KW)
-    moved = [rel_err(tiny["got"]["logits"], bad["logits"])] \
-        + [frob(tiny["grads"][n], bad_grads[n]) for n in FAULT_WRT]
-    held = [rel_err(tiny["got"]["logits"], tiny["want"]["logits"])] \
-        + [frob(tiny["grads"][n], tiny["want_grads"][n]) for n in FAULT_WRT]
-    assert max(held) < 2e-4
-    # a fault that overflows (a step size below 0 makes the decay a growth)
-    # reads nan: not within any limit, as `run.py::misses` has it
-    assert not max(np.nan_to_num(moved, nan=np.inf)) <= 50 * 2e-4, \
-        (fault, moved)
-    assert not abs(float(bad["loss"]) - float(tiny["want"]["loss"])) <= 1e-5
+    CASE.planted_fault_is_refused(tiny, fault, loss=1e-5)
 
 
 def test_the_config_names_every_fault_and_no_other():
@@ -670,35 +535,21 @@ def test_the_config_names_every_fault_and_no_other():
 
 
 def test_an_unknown_fault_is_refused(tiny):
-    with pytest.raises(ValueError, match="fault is one of"):
-        ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
-                       fault="no_such", **REF_KW)
+    CASE.unknown_fault_is_refused(tiny)
 
 
 def test_reference_in_blocks_is_the_reference(tiny):
-    """`q_block`, `token_block` and `remat` are the reference's memory, not
-    its mathematics."""
-    parts, grads = ref.loss_and_grads(
-        tiny["params"], tiny["tokens"], tiny["labels"],
-        wrt=["l0.mamba.in.w", "l2.mamba.A_log", "l5.attn.k.w", "l3.router.w",
-             "embed.w"],
-        q_block=32, token_block=16, remat=True, **REF_KW)
-    assert abs(float(parts["loss"]) - float(tiny["want"]["loss"])) < 1e-5
-    for name, g in grads.items():
-        assert frob(g, tiny["want_grads"][name]) < 1e-5, name
+    CASE.reference_in_blocks_is_the_reference(
+        tiny, ["l0.mamba.in.w", "l2.mamba.A_log", "l5.attn.k.w",
+               "l3.router.w", "embed.w"], q_block=32, token_block=16)
 
 
 def test_reference_last_positions_equal_the_full_pass(tiny):
-    parts = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
-                           last=16, **REF_KW)
-    assert rel_err(parts["logits"], tiny["want"]["logits"][:, -16:]) < 1e-6
+    CASE.reference_last_positions_equal_the_full_pass(tiny)
 
 
 def test_reference_in_bfloat16_is_another_number(tiny):
-    low = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
-                         dtype=jnp.bfloat16, **REF_KW)
-    assert low["loss"].dtype == jnp.bfloat16
-    assert abs(float(low["loss"]) - float(tiny["want"]["loss"])) > 1e-4
+    CASE.reference_in_bfloat16_is_another_number(tiny)
 
 
 def test_tiny_model_amp_within_bf16_of_reference():
@@ -711,23 +562,11 @@ def test_tiny_model_amp_within_bf16_of_reference():
     expert's whole contribution), the loss within 0.005, a gradient within 5% in the Frobenius norm, the
     decay's and step size's (a few numbers downstream of every rounding)
     within 15%."""
-    main, params, feed, got, grads, after = _run_tiny(amp=True, seeded=False)
-    want, want_grads = ref.loss_and_grads(
-        params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
-        last=TINY["seq_len"], **REF_KW)
-    assert abs(float(got["loss"][0]) - float(want["loss"])) < 0.005
-    assert got["logits"].dtype == jnp.bfloat16
-    err = np.abs(np.asarray(got["logits"], np.float32)
-                 - np.asarray(want["logits"]))
-    assert err.max() < 0.12 and err.mean() < 0.01
-    for name in ("l0.mamba.in.w", "l0.mamba.out.w", "l5.attn.k.w",
-                 "l1.shared.up.w", "embed.w", "head.w"):
-        assert grads[name].dtype == jnp.float32
-        assert frob(grads[name], want_grads[name]) < 0.05, name
-    for name in ("l0.mamba.A_log", "l0.mamba.dt_bias", "l0.mamba.conv.b"):
-        assert frob(grads[name], want_grads[name]) < 0.15, name
-    for n in BIASES:
-        assert after[n].dtype == np.float32
+    CASE.amp_within_bf16_of_reference(
+        {0.05: ("l0.mamba.in.w", "l0.mamba.out.w", "l5.attn.k.w",
+                "l1.shared.up.w", "embed.w", "head.w"),
+         0.15: ("l0.mamba.A_log", "l0.mamba.dt_bias", "l0.mamba.conv.b")},
+        loss=0.005, mean=0.01, most=0.12, of_std=False)
 
 
 def test_amp_lists_hold_the_gates_and_leave_the_scan_alone():
@@ -739,15 +578,7 @@ def test_amp_lists_hold_the_gates_and_leave_the_scan_alone():
 
 
 def test_five_adam_steps_lower_the_loss():
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.Adam(learning_rate=3e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    feed = _batch()
-    losses = [float(exe.run(main, feed=feed, fetch_list=[fetches["loss"]],
-                            scope=scope)[0][0]) for _ in range(6)]
-    assert np.all(np.isfinite(losses)) and losses[-1] < losses[0] - 0.05
+    CASE.adam_steps_lower_the_loss()
 
 
 def test_a_pattern_is_a_string_over_m_e_and_star():
@@ -757,15 +588,6 @@ def test_a_pattern_is_a_string_over_m_e_and_star():
 
 
 # -- what the Program holds; spans and counters -------------------------------------------------
-
-def _forward_ops_by_scope(main):
-    scopes = {}
-    for op in main.global_block().ops:
-        if op.attrs.get("__role__") is None:
-            scopes.setdefault(op.attrs.get(ir.NAME_SCOPE_ATTR), []) \
-                .append(op.type)
-    return scopes
-
 
 @pytest.mark.parametrize("layer", range(9))
 def test_every_layer_is_one_sublayer_under_its_own_scope(tiny, layer):
@@ -803,7 +625,7 @@ def test_layer_census_reads_the_issues_counts():
     """4 state-space layers, 1 full-attention layer with no rotary at a
     key-value group of 16, 4 expert layers with 128 routed, 8 held, sigmoid
     scores and 4 bias updates."""
-    main, _, _, _ = _program(fluid.optimizer.SGD(learning_rate=1e-3),
+    main, _, _, _ = CASE.program(fluid.optimizer.SGD(learning_rate=1e-3),
                              **CENSUS_SIZES)
     got = census.layer_census(main)
     assert got == CENSUS
@@ -813,14 +635,7 @@ def test_layer_census_reads_the_issues_counts():
 
 @pytest.fixture(scope="module")
 def compile_detail():
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.SGD(learning_rate=1e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    exe.run(main, feed=_batch(), fetch_list=[fetches["loss"]], scope=scope)
-    latest = observe.observatory().latest
-    return latest(main._uid).detail, latest(startup._uid).detail
+    return CASE.compile_detail()
 
 
 @pytest.mark.parametrize("key,value", [
@@ -829,9 +644,7 @@ def compile_detail():
     ("moe_router_bias_updates", 4), ("ssd_plan", "xla"),
     ("moe_share_bounded_ops", 2 * 4), ("moe_share_bounded_moves", 4 * 4)])
 def test_compile_event_carries_the_census(compile_detail, key, value):
-    detail, startup_detail = compile_detail
-    assert detail[key] == value
-    assert key not in startup_detail
+    carries_the_census(compile_detail, {key: value})
 
 
 def test_the_scan_tallies_its_grid_steps(monkeypatch):
@@ -869,16 +682,9 @@ def test_the_census_of_the_other_models_is_what_it_was(model, want):
     """A program without state-space layers gains no key: no
     `state_space_layers`, no `moe_expert_activation`, and
     `attention_unrotated_layers` only beside layers that turn."""
-    import test_kanana2
-    import test_mellum2
-    import test_qwen3_next
-    import test_trinity
-    sizes = {"mellum2": test_mellum2.TINY, "kanana2": test_kanana2.TINY,
-             "qwen3_next": test_qwen3_next.TINY,
-             "trinity": test_trinity.TINY}[model]
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        getattr(models, model).build(**sizes)
+        getattr(models, model).build(**tiny_args(model))
     got = census.layer_census(main)
     keys = ("attention_rotary_layers", "attention_unrotated_layers",
             "state_space_layers", "moe_expert_activation")
@@ -889,10 +695,7 @@ def test_the_census_of_the_other_models_is_what_it_was(model, want):
 # -- the copies and the harness -----------------------------------------------------------------
 
 def test_the_two_copies_of_the_reference_are_identical():
-    assert filecmp.cmp(
-        os.path.join(HERE, "nemotron_h_reference.py"),
-        os.path.join(ROOT, "benchmark", "references",
-                     "nemotron_h_reference.py"), shallow=False)
+    CASE.two_copies_of_the_reference_are_identical()
 
 
 def test_the_config_holds_the_published_widths_and_the_cut():
@@ -923,16 +726,4 @@ def test_the_config_holds_the_published_widths_and_the_cut():
 
 
 def test_the_tiny_block_runs_through_the_benchmark():
-    """`run.py --tiny` on the cell: the configuration's tiny block through
-    the harness's own rehearsal, the in-run reference comparison
-    included."""
-    out = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
-         "--workload", "nemotron_3_nano_30b_a3b.s2048", "--seed",
-         "3000000019", "--seconds", "1", "--trace", "0", "--tiny"],
-        capture_output=True, text=True, timeout=600, cwd=ROOT,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
-    last = json.loads(out.stdout.strip().splitlines()[-1])
-    assert last["correct"] is True and last["rehearsal"] is True
-    assert "reference check after" in out.stdout
+    runs_through_the_benchmark("nemotron_3_nano_30b_a3b.s2048")

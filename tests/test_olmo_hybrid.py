@@ -5,9 +5,6 @@ out, one chip's share of each layer's heads) through `layers` -> Program IR
 the delta rule as its token-by-token recurrence). Seeded random weights,
 float32, AMP off unless a test says otherwise."""
 
-import filecmp
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,18 +12,17 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import layers, models, observe
-from paddle_tpu.core import ir, registry
+from paddle_tpu.core import registry
 from paddle_tpu.models import olmo_hybrid as model
 from paddle_tpu.ops import linear_attention as la
 
 import olmo_hybrid_reference as ref
-from test_olmoe import piece_noted, rel_err, run_piece
-from test_qwen3_next import RTOL, frob
+from decoder_case import (DIGESTS, RTOL, DecoderCase, build_program,
+                          carries_the_census, frob,
+                          layers_are_built_under_their_scopes, piece_noted,
+                          program_digest, rel_err, run_piece, tiny_args)
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-TINY = dict(vocab_size=128, seq_len=128, n_layer=4, d_model=64, d_ff=96,
-            n_head=4, heads_held=2, head_dim=16, key_dim=12, value_dim=24,
-            conv_kernel=4)
+TINY = tiny_args("olmo_hybrid")
 REF_KW = {k: TINY[k] for k in ("n_layer", "head_dim", "key_dim",
                                "value_dim")}
 
@@ -216,35 +212,16 @@ def test_rule_layer_carries_the_factor_on_beta_as_an_attribute(scale):
 
 # -- the whole tiny model ------------------------------------------------------------
 
-def _program(optimizer=None, **sizes):
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        feeds, fetches = models.olmo_hybrid.build(**{**TINY, **sizes})
-        if optimizer is None:
-            pairs = fluid.append_backward(fetches["loss"])
-        else:
-            optimizer.minimize(fetches["loss"])
-            pairs = []
-    main.random_seed = startup.random_seed = 7
-    return main, startup, fetches, pairs
-
-
-def _batch(seed=0, batch=2):
-    rng = np.random.RandomState(seed)
-    shape = (batch, TINY["seq_len"])
-    return {n: rng.randint(0, TINY["vocab_size"], shape).astype(np.int32)
-            for n in ("tokens", "labels")}
-
-
-def _seeded_weights(scope, names, seed=3):
+def _seeded_values(shapes, seed=3):
     """Weights far from their initial values, so that no term of the
     comparison is small by construction: norm weights in [0.5, 1.5], a decay
     that forgets slowly (A in [0.05, 1]), an embedding of unit size and `W_b`
     at std 0.25, so that beta = 2 sigmoid(x W_b) lies on both sides of 1 in
     every layer, the first among them; the other matrices at std 0.1."""
     rng = np.random.RandomState(seed)
-    for name in sorted(names):
-        shape = np.shape(scope.find_var(name))
+    values = {}
+    for name in sorted(shapes):
+        shape = shapes[name]
         if "norm" in name:
             value = rng.uniform(0.5, 1.5, shape)
         elif name.endswith("A_log"):
@@ -259,37 +236,18 @@ def _seeded_weights(scope, names, seed=3):
             value = rng.randn(*shape) * 0.25
         else:
             value = rng.randn(*shape) * 0.1
-        scope.set_var(name, jnp.asarray(value.astype(np.float32)))
+        values[name] = value.astype(np.float32)
+    return values
 
 
 FETCHES = ["loss", "ce", "logits"]
-
-
-def _run_tiny(amp, seeded=True, **sizes):
-    main, startup, fetches, pairs = _program(**sizes)
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace(), amp=amp)
-    exe.run(startup, scope=scope)
-    if seeded:
-        _seeded_weights(scope, [p.name for p, _ in pairs])
-    params = {p.name: np.asarray(scope.find_var(p.name)) for p, _ in pairs}
-    feed = _batch()
-    out = exe.run(main, feed=feed,
-                  fetch_list=[fetches[n] for n in FETCHES]
-                  + [g for _, g in pairs], scope=scope)
-    got = dict(zip(FETCHES, out))
-    grads = dict(zip((p.name for p, _ in pairs), out[len(FETCHES):]))
-    return main, params, feed, got, grads
+CASE = DecoderCase(models.olmo_hybrid.build, TINY, ref, REF_KW, FETCHES,
+                   seeded_values=_seeded_values)
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    main, params, feed, got, grads = _run_tiny(amp=False)
-    tokens, labels = jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"])
-    want, want_grads = ref.loss_and_grads(
-        params, tokens, labels, last=TINY["seq_len"], **REF_KW)
-    return dict(main=main, params=params, tokens=tokens, labels=labels,
-                got=got, grads=grads, want=want, want_grads=want_grads)
+    return CASE.tiny_model()
 
 
 BLOCK = ["mixer_norm.w", "mlp_norm.w", "mlp.gate.w", "mlp.up.w",
@@ -304,18 +262,15 @@ PARAM_NAMES = (["embed.w", "final_norm.w", "head.w"]
 
 
 def test_tiny_model_has_the_reference_parameters_at_the_shares_widths(tiny):
-    shapes = {n: v.shape for n, v in tiny["params"].items()}
-    assert sorted(shapes) == sorted(PARAM_NAMES)
-    assert shapes["l0.gdn.q.w"] == shapes["l0.gdn.k.w"] == (64, 2 * 12)
-    assert shapes["l0.gdn.v.w"] == shapes["l0.gdn.g.w"] == (64, 2 * 24)
-    assert shapes["l0.gdn.a.w"] == shapes["l0.gdn.b.w"] == (64, 2)
-    assert shapes["l0.gdn.conv.w"] == (2 * (12 + 12 + 24), 4)
-    assert shapes["l0.gdn.norm.w"] == (24,)
-    assert shapes["l0.gdn.o.w"] == (2 * 24, 64)
-    assert shapes["l3.attn.q.w"] == shapes["l3.attn.v.w"] == (64, 2 * 16)
-    assert shapes["l3.attn.q_norm.w"] == shapes["l3.attn.k_norm.w"] == (32,)
-    assert shapes["l3.attn.o.w"] == (32, 64)
-    assert shapes["l0.mlp.gate.w"] == (64, 96)      # the feed-forward whole
+    CASE.has_the_reference_parameters(tiny, PARAM_NAMES, {
+        "l0.gdn.q.w": (64, 2 * 12), "l0.gdn.k.w": (64, 2 * 12),
+        "l0.gdn.v.w": (64, 2 * 24), "l0.gdn.g.w": (64, 2 * 24),
+        "l0.gdn.a.w": (64, 2), "l0.gdn.b.w": (64, 2),
+        "l0.gdn.conv.w": (2 * (12 + 12 + 24), 4), "l0.gdn.norm.w": (24,),
+        "l0.gdn.o.w": (2 * 24, 64), "l3.attn.q.w": (64, 2 * 16),
+        "l3.attn.v.w": (64, 2 * 16), "l3.attn.q_norm.w": (32,),
+        "l3.attn.k_norm.w": (32,), "l3.attn.o.w": (32, 64),
+        "l0.mlp.gate.w": (64, 96)})                 # the feed-forward whole
 
 
 def test_seeded_beta_lies_on_both_sides_of_one_in_the_first_layer(tiny):
@@ -328,37 +283,28 @@ def test_seeded_beta_lies_on_both_sides_of_one_in_the_first_layer(tiny):
 
 @pytest.mark.parametrize("name", FETCHES)
 def test_tiny_model_output_matches_reference(tiny, name):
-    want = np.asarray(tiny["want"][name])
-    assert rel_err(np.reshape(tiny["got"][name], want.shape), want) < 1e-4
+    CASE.output_matches_reference(tiny, name)
 
 
 @pytest.mark.parametrize("name", PARAM_NAMES)
 def test_tiny_model_gradient_matches_reference(tiny, name):
-    assert frob(tiny["grads"][name], tiny["want_grads"][name]) < 2e-4
+    CASE.gradient_matches_reference(tiny, name)
 
 
 @pytest.mark.parametrize("kind", model.KINDS)
 def test_one_layer_of_each_kind_matches_reference(kind):
     sizes = dict(n_layer=1, layer_types=[kind])
-    main, startup, fetches, pairs = _program(**sizes)
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    _seeded_weights(scope, [p.name for p, _ in pairs], seed=11)
-    params = {p.name: np.asarray(scope.find_var(p.name)) for p, _ in pairs}
+    main, params, feed, got, grads, _ = CASE.run_tiny(
+        amp=False, seed=11, batch_seed=4, **sizes)
     assert any(n.startswith("l0.gdn.") for n in params) \
         == (kind == "linear_attention")
-    feed = _batch(seed=4)
-    out = exe.run(main, feed=feed, fetch_list=[fetches["loss"],
-                                               fetches["logits"]]
-                  + [g for _, g in pairs], scope=scope)
     want, want_grads = ref.loss_and_grads(
         params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
         last=TINY["seq_len"], **{**REF_KW, **sizes})
-    assert abs(float(out[0][0]) - float(want["loss"])) < 1e-5
-    assert rel_err(out[1], want["logits"]) < 1e-4
-    for (p, _), g in zip(pairs, out[2:]):
-        assert frob(g, want_grads[p.name]) < 2e-4, p.name
+    assert abs(float(got["loss"][0]) - float(want["loss"])) < 1e-5
+    assert rel_err(got["logits"], want["logits"]) < 1e-4
+    for name, g in grads.items():
+        assert frob(g, want_grads[name]) < 2e-4, name
     assert observe.observatory().latest(main._uid).detail["layer_kinds"] \
         == {kind: 1}
 
@@ -371,7 +317,7 @@ def test_one_layer_of_each_kind_matches_reference(kind):
 def test_the_other_readings_match_the_reference_too(sizes):
     """What `assumed` leaves as a build argument: sigmoid alone, the full
     layers turned at OLMo 3's theta, the whole layer and another share."""
-    _, params, feed, got, grads = _run_tiny(amp=False, **sizes)
+    _, params, feed, got, grads, _ = CASE.run_tiny(amp=False, **sizes)
     kw = {k: v for k, v in sizes.items() if k != "heads_held"}
     want, want_grads = ref.loss_and_grads(
         params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
@@ -387,21 +333,13 @@ def test_the_other_readings_match_the_reference_too(sizes):
 
 
 def test_reference_in_blocks_is_the_reference(tiny):
-    """`q_block`, `token_block` and `remat` are the reference's memory, not
-    its mathematics."""
-    parts, grads = ref.loss_and_grads(
-        tiny["params"], tiny["tokens"], tiny["labels"],
-        wrt=["l0.gdn.q.w", "l0.gdn.b.w", "l3.attn.q.w", "embed.w"],
-        q_block=32, token_block=16, remat=True, **REF_KW)
-    assert abs(float(parts["loss"]) - float(tiny["want"]["loss"])) < 1e-5
-    for name, g in grads.items():
-        assert frob(g, tiny["want_grads"][name]) < 5e-5, name
+    CASE.reference_in_blocks_is_the_reference(
+        tiny, ["l0.gdn.q.w", "l0.gdn.b.w", "l3.attn.q.w", "embed.w"],
+        tol=5e-5, q_block=32, token_block=16)
 
 
 def test_reference_last_positions_equal_the_full_pass(tiny):
-    parts = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
-                           last=16, **REF_KW)
-    assert rel_err(parts["logits"], tiny["want"]["logits"][:, -16:]) < 1e-5
+    CASE.reference_last_positions_equal_the_full_pass(tiny, tol=1e-5)
 
 
 @pytest.mark.parametrize("fault", sorted(ref.FAULTS))
@@ -548,36 +486,24 @@ def test_the_two_attention_shares_add_up_given_the_whole_layers_statistic():
 def test_heads_held_is_checked_when_the_program_is_built():
     for bad in (0, 5):
         with pytest.raises(ValueError, match="heads_held"):
-            _program(heads_held=bad)
+            CASE.program(heads_held=bad)
     with pytest.raises(ValueError, match="layer_types"):
-        _program(layer_types=["sliding_attention"])
+        CASE.program(layer_types=["sliding_attention"])
 
 
 # -- what the Program says of itself --------------------------------------------------
 
 def test_compile_event_carries_the_census():
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.SGD(learning_rate=1e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    exe.run(main, feed=_batch(), fetch_list=[fetches["loss"]], scope=scope)
-    detail = observe.observatory().latest(main._uid).detail
-    assert detail["layer_kinds"] == {"linear_attention": 3,
-                                     "full_attention": 1}
-    assert detail["linear_attention_head_dims"] == [12, 24]
-    assert detail["delta_rule_beta_scale"] == 2
-    assert detail["attention_heads_held"] == 2
-    assert detail["attention_heads"] == 4
-    assert detail["residual_out_norms"] == 8
-    assert detail["gdn_plan"] == "xla"
-    assert detail["grad_fanin_max"] == 1
-    assert "attention_rotary_layers" not in detail
-    assert "moe_experts_routed" not in detail
+    carries_the_census(CASE.compile_detail(), {
+        "layer_kinds": {"linear_attention": 3, "full_attention": 1},
+        "linear_attention_head_dims": [12, 24], "delta_rule_beta_scale": 2,
+        "attention_heads_held": 2, "attention_heads": 4,
+        "residual_out_norms": 8, "gdn_plan": "xla", "grad_fanin_max": 1},
+        absent=["attention_rotary_layers", "moe_experts_routed"])
 
 
 def test_the_whole_layer_says_nothing_of_a_share():
-    main, _, _, _ = _program(heads_held=None, allow_neg_eigval=False)
+    main, _, _, _ = CASE.program(heads_held=None, allow_neg_eigval=False)
     from paddle_tpu.observe import census
     detail = census.program_detail(main)
     assert "attention_heads" not in detail
@@ -586,20 +512,15 @@ def test_the_whole_layer_says_nothing_of_a_share():
 
 
 def test_every_layer_is_built_under_its_name_scopes(tiny):
-    scopes = {}
-    for op in tiny["main"].global_block().ops:
-        if op.attrs.get("__role__") is None:
-            scopes.setdefault(op.attrs.get(ir.NAME_SCOPE_ATTR), set()) \
-                .add(op.type)
-    assert {"l0.gdn", "l1.gdn", "l2.gdn", "l3.attn", "l0.mlp", "l1.mlp",
-            "l2.mlp", "l3.mlp"} <= set(scopes)
-    assert "l3.gdn" not in scopes and "l0.attn" not in scopes
-    assert {"gated_delta_rule", "delta_rule_gates", "causal_conv1d",
-            "gated_rms_norm"} <= scopes["l0.gdn"]
-    assert "fused_attention" in scopes["l3.attn"]
-    assert "rotary_embedding" not in scopes["l3.attn"]
-    assert "transpose" not in scopes["l3.attn"]     # token-major as it lies
-    assert "swiglu" in scopes["l2.mlp"]
+    layers_are_built_under_their_scopes(
+        tiny["main"],
+        ["l0.gdn", "l1.gdn", "l2.gdn", "l3.attn", "l0.mlp", "l1.mlp",
+         "l2.mlp", "l3.mlp"], absent=["l3.gdn", "l0.attn"],
+        holds={"l0.gdn": ["gated_delta_rule", "delta_rule_gates",
+                          "causal_conv1d", "gated_rms_norm"],
+               "l3.attn": ["fused_attention"], "l2.mlp": ["swiglu"]},
+        # token-major as it lies
+        lacks={"l3.attn": ["rotary_embedding", "transpose"]})
 
 
 def test_tiny_model_amp_within_bf16_of_reference():
@@ -614,26 +535,15 @@ def test_tiny_model_amp_within_bf16_of_reference():
     gradients by several times their size (the reference computed in
     bfloat16 throughout misses them by 0.3 too); the published 96 has no
     such tokens."""
-    sizes = dict(key_dim=48, value_dim=96)
-    _, params, feed, got, grads = _run_tiny(amp=True, **sizes)
-    want, want_grads = ref.loss_and_grads(
-        params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
-        last=TINY["seq_len"], **{**REF_KW, **sizes})
-    assert abs(float(got["loss"][0]) - float(want["loss"])) < 0.01
-    assert got["logits"].dtype == jnp.bfloat16
-    err = np.abs(np.asarray(got["logits"], np.float32)
-                 - np.asarray(want["logits"]))
-    std = float(np.std(want["logits"]))
-    assert err.mean() < 0.04 * std and err.max() < 0.5 * std
-    for name in ("l0.gdn.q.w", "l0.gdn.b.w", "l0.gdn.conv.w", "l0.gdn.o.w",
-                 "l3.attn.q.w", "l3.attn.q_norm.w", "l0.mlp.up.w",
-                 "l0.mixer_norm.w", "embed.w", "head.w"):
-        assert grads[name].dtype == np.float32
-        assert frob(grads[name], want_grads[name]) < 0.3, name
+    CASE.amp_within_bf16_of_reference(
+        {0.3: ("l0.gdn.q.w", "l0.gdn.b.w", "l0.gdn.conv.w", "l0.gdn.o.w",
+               "l3.attn.q.w", "l3.attn.q_norm.w", "l0.mlp.up.w",
+               "l0.mixer_norm.w", "embed.w", "head.w")},
+        loss=0.01, mean=0.04, most=0.5, seeded=True, key_dim=48, value_dim=96)
 
 
 def test_amp_keeps_the_gates_in_float32():
-    main, _, _, _ = _program(n_layer=1)
+    main, _, _, _ = CASE.program(n_layer=1)
     block = main.global_block()
     gates = next(o for o in block.ops if o.type == "delta_rule_gates")
     assert "delta_rule_gates" in registry.AMP_F32_OPS
@@ -642,22 +552,14 @@ def test_amp_keeps_the_gates_in_float32():
 
 
 def test_five_adam_steps_lower_the_loss():
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.Adam(learning_rate=3e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    feed = _batch()
-    losses = [float(exe.run(main, feed=feed, fetch_list=[fetches["loss"]],
-                            scope=scope)[0][0]) for _ in range(6)]
-    assert np.all(np.isfinite(losses)) and losses[-1] < losses[0] - 0.05
+    CASE.adam_steps_lower_the_loss()
 
 
 def test_the_initial_values_are_the_public_codes():
     """`A_log` = log of uniform(0, 16), `dt_bias` the inverse softplus of a
     log-uniform draw in [0.001, 0.1], the convolution uniform(+-0.5), the
     norms' weights 1, matrices of std 0.02."""
-    main, startup, _, _ = _program(n_layer=1, layer_types=model.KINDS[:1])
+    main, startup, _, _ = CASE.program(n_layer=1, layer_types=model.KINDS[:1])
     scope = fluid.Scope()
     fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
     value = lambda n: np.asarray(scope.find_var(n))
@@ -680,7 +582,6 @@ def test_the_models_that_share_the_moved_code_are_unchanged_op_for_op(other):
     convolution to its gated norm are written once in `models/_decoder.py`
     since this file's PR; their Programs are the Programs they were, and
     Qwen3-Next's gates op carries no factor."""
-    from test_decoder_models import DIGESTS, build_program, program_digest
     main, startup, _, _ = build_program(other)
     assert program_digest(main, startup) == DIGESTS[other]
     assert not any("beta_scale" in op.attrs or "heads_total" in op.attrs
@@ -688,7 +589,4 @@ def test_the_models_that_share_the_moved_code_are_unchanged_op_for_op(other):
 
 
 def test_the_two_copies_of_the_reference_are_identical():
-    assert filecmp.cmp(
-        os.path.join(HERE, "olmo_hybrid_reference.py"),
-        os.path.join(HERE, "..", "benchmark", "references",
-                     "olmo_hybrid_reference.py"), shallow=False)
+    CASE.two_copies_of_the_reference_are_identical()

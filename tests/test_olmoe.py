@@ -3,78 +3,23 @@ reference (`tests/olmoe_reference.py`): each new op against its piece of the
 reference, forward and gradient, then the whole tiny model. Seeded random
 weights, float32, AMP off unless a test says otherwise."""
 
-import filecmp
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu import layers, models, observe
+from paddle_tpu import layers, models
 
 import olmoe_reference as ref
+from decoder_case import DecoderCase, rel_err, run_piece, tiny_args
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-TINY = dict(vocab_size=128, seq_len=128, n_layer=2, d_model=64, n_head=2,
-            n_expert=8, top_k=2, d_expert=32)
+TINY = tiny_args("olmoe")
 REF_KW = dict(n_layer=2, n_head=2, top_k=2)
 # float32 against float32 highest: the two sides differ by the order of
 # their sums (the grouped matmul sums a group, the reference a dense mask;
 # the flash reference path and the einsum), a few ulp of 6e-8 each
 RTOL = 1e-5
-
-
-def rel_err(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    assert got.shape == want.shape, (got.shape, want.shape)
-    return np.max(np.abs(got - want)) / (np.max(np.abs(want)) + 1e-30)
-
-
-def run_piece(build, feed, params=None):
-    """Build a few layers on data vars, take the mean of the first output
-    times a fixed random tensor as a loss, and return the outputs and the
-    gradients of every float feed and every parameter."""
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        data = {}
-        for name, value in feed.items():
-            is_float = value.dtype.kind == "f"
-            data[name] = layers.data(name=name, shape=list(value.shape),
-                                     dtype=str(value.dtype),
-                                     append_batch_size=False,
-                                     stop_gradient=not is_float)
-        outs = build(data)
-        first = outs[0]
-        probe = layers.data(name="probe", shape=list(first.shape),
-                            dtype="float32", append_batch_size=False)
-        loss = layers.reduce_sum(layers.elementwise_mul(first, probe))
-        fluid.append_backward(loss)
-    run_piece.program_uid = main._uid       # whose event `piece_noted` reads
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    for name, value in (params or {}).items():
-        scope.set_var(name, jnp.asarray(value))
-    rng = np.random.RandomState(99)
-    probe_value = rng.randn(*first.shape).astype(np.float32)
-    wrt = [n for n, v in feed.items() if v.dtype.kind == "f"] \
-        + sorted(params or {})
-    fetched = exe.run(main, feed={**feed, "probe": probe_value},
-                      fetch_list=list(outs) + [n + "@GRAD" for n in wrt],
-                      scope=scope)
-    return (fetched[:len(outs)], dict(zip(wrt, fetched[len(outs):])),
-            probe_value)
-
-
-def piece_noted(key):
-    """What the rules noted under `key` on the compile event of the program
-    `run_piece` ran last, None where none did: that program's own event, not
-    the last of the observatory's list, which is the process's (every test
-    file of an xdist worker writes it, and it holds 256 events)."""
-    return observe.observatory().latest(
-        run_piece.program_uid).detail.get(key)
 
 
 # -- ops against their piece of the reference ---------------------------------
@@ -265,58 +210,18 @@ def test_expert_layer_matches_reference(kind, path, monkeypatch):
 
 FETCHES = ["loss", "ce", "load_balance", "z_loss", "logits",
            "tokens_per_expert"]
-
-
-def _tiny_program(optimizer=None):
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        feeds, fetches = models.olmoe.build(**TINY)
-        if optimizer is None:
-            pairs = fluid.append_backward(fetches["loss"])
-        else:
-            optimizer.minimize(fetches["loss"])
-            pairs = []
-    main.random_seed = startup.random_seed = 7
-    return main, startup, fetches, pairs
-
-
-def _batch(seed=0, batch=2):
-    rng = np.random.RandomState(seed)
-    shape = (batch, TINY["seq_len"])
-    return {"tokens": rng.randint(0, TINY["vocab_size"], shape)
-            .astype(np.int32),
-            "labels": rng.randint(0, TINY["vocab_size"], shape)
-            .astype(np.int32)}
-
-
-def _run_tiny(amp):
-    main, startup, fetches, pairs = _tiny_program()
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace(), amp=amp)
-    exe.run(startup, scope=scope)
-    params = {p.name: np.asarray(scope.find_var(p.name)) for p, _ in pairs}
-    feed = _batch()
-    out = exe.run(main, feed=feed,
-                  fetch_list=[fetches[n] for n in FETCHES]
-                  + [g for _, g in pairs], scope=scope)
-    got = dict(zip(FETCHES, out))
-    grads = dict(zip((p.name for p, _ in pairs), out[len(FETCHES):]))
-    return params, feed, got, grads
+# the largest entry's error for a gradient too: no router is sharp here
+CASE = DecoderCase(models.olmoe.build, TINY, ref, REF_KW, FETCHES,
+                   interpreted=True, out_tol=RTOL, grad_tol=RTOL,
+                   grad_err=rel_err)
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    """The tiny model through the chip's kernels (megablox gmm / tgmm with
-    the op's own gradient, the flash kernels), interpreted on the CPU."""
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-        from paddle_tpu.ops import moe
-        assert moe._kernel() is not None
-        params, feed, got, grads = _run_tiny(amp=False)
-    parts, ref_grads = ref.loss_and_grads(params, feed["tokens"],
-                                          feed["labels"], **REF_KW)
-    return {"params": params, "feed": feed, "got": got, "grads": grads,
-            "want": parts, "want_grads": ref_grads}
+    """The tiny model at its initial weights through the chip's kernels
+    (megablox gmm / tgmm with the op's own gradient, the flash kernels),
+    interpreted on the CPU."""
+    return CASE.tiny_model(last=None)
 
 
 PARAM_NAMES = (["embed.w", "final_norm.w", "head.w"]
@@ -327,23 +232,20 @@ PARAM_NAMES = (["embed.w", "final_norm.w", "head.w"]
 
 
 def test_tiny_model_has_the_reference_parameters(tiny):
-    assert sorted(tiny["params"]) == sorted(PARAM_NAMES)
+    CASE.has_the_reference_parameters(tiny, PARAM_NAMES)
 
 
 @pytest.mark.parametrize("name", FETCHES)
 def test_tiny_model_output_matches_reference(tiny, name):
+    CASE.output_matches_reference(tiny, name)
     if name == "tokens_per_expert":
-        np.testing.assert_array_equal(tiny["got"][name], tiny["want"][name])
         assert tiny["got"][name].shape == (2, 8)
         assert np.all(tiny["got"][name].sum(-1) == 2 * 128 * 2)
-    else:
-        assert rel_err(np.asarray(tiny["got"][name]).reshape(
-            np.shape(tiny["want"][name])), tiny["want"][name]) < RTOL
 
 
 @pytest.mark.parametrize("name", PARAM_NAMES)
 def test_tiny_model_gradient_matches_reference(tiny, name):
-    assert rel_err(tiny["grads"][name], tiny["want_grads"][name]) < RTOL
+    CASE.gradient_matches_reference(tiny, name)
 
 
 def test_reference_with_given_routing_equals_its_own(tiny):
@@ -355,6 +257,7 @@ def test_reference_with_given_routing_equals_its_own(tiny):
         assert float(again[name]) == float(want[name]), name
 
 
+# its own body: this reference's `last` is `forward`'s, not `loss_parts`'
 def test_reference_last_positions_equal_the_full_pass(tiny):
     """The chip check compares the last positions against the whole
     context; that path must be the full forward pass's tail."""
@@ -363,6 +266,7 @@ def test_reference_last_positions_equal_the_full_pass(tiny):
     assert rel_err(logits, np.asarray(tiny["want"]["logits"])[:, -16:]) < RTOL
 
 
+# its own body: against `tiny`'s reference, every loss part and the counts
 def test_tiny_model_amp_within_bf16_of_reference(tiny):
     """AMP on: projections and expert matmuls in bf16 (8 bits of mantissa,
     relative rounding 2^-9 = 0.002 an operand), router, norms' statistics
@@ -373,7 +277,7 @@ def test_tiny_model_amp_within_bf16_of_reference(tiny):
     gradients are therefore compared in the Frobenius norm, not entry by
     entry (read: 0.5-1% outside the experts, 6-9% in them with 6 of 1024
     assignments flipped; losses within 1e-5 to 2e-4)."""
-    _, _, got, grads = _run_tiny(amp=True)
+    _, _, _, got, grads, _ = CASE.run_tiny(amp=True)
     want = tiny["want"]
     assert np.max(np.abs(np.asarray(got["logits"], np.float32)
                          - np.asarray(want["logits"]))) < 0.03
@@ -392,22 +296,8 @@ def test_tiny_model_amp_within_bf16_of_reference(tiny):
 
 
 def test_five_adam_steps_lower_the_loss():
-    main, startup, fetches, _ = _tiny_program(
-        fluid.optimizer.Adam(learning_rate=4e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    feed = _batch(seed=5)
-    losses = [float(np.asarray(exe.run(main, feed=feed,
-                                       fetch_list=[fetches["loss"]],
-                                       scope=scope)[0]).reshape(-1)[0])
-              for _ in range(5)]
-    assert np.all(np.isfinite(losses))
-    assert losses[-1] < losses[0] - 0.05, losses
+    CASE.adam_steps_lower_the_loss(lr=4e-3, seed=5, steps=5)
 
 
 def test_the_two_copies_of_the_reference_are_identical():
-    other = os.path.join(os.path.dirname(HERE), "benchmark", "references",
-                         "olmoe_reference.py")
-    assert filecmp.cmp(os.path.join(HERE, "olmoe_reference.py"), other,
-                       shallow=False)
+    CASE.two_copies_of_the_reference_are_identical()

@@ -4,9 +4,6 @@ shared weights, an exit gate, the expected-loss objective) through `layers`
 (`tests/ouro_reference.py`). Seeded random weights, float32, AMP off unless
 a test says otherwise."""
 
-import filecmp
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,10 +15,9 @@ from paddle_tpu.core import ir
 from paddle_tpu.observe.census import parameter_sharing
 
 import ouro_reference as ref
+from decoder_case import DecoderCase, tiny_args
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-TINY = dict(vocab_size=128, seq_len=128, n_layer=2, d_model=64, n_head=2,
-            d_ff=96, n_loop=4)
+TINY = tiny_args("ouro")
 REF_KW = dict(n_layer=2, n_head=2, n_loop=4)
 # float32 against float32 highest: the two sides differ by the order of
 # their sums (the flash path and the einsum; log-space exit probabilities
@@ -42,42 +38,22 @@ def rel_err(got, want):
     return np.max(np.abs(got - want)) / (np.max(np.abs(want)) + 1e-30)
 
 
-def _program(optimizer=None, **sizes):
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        feeds, fetches = models.ouro.build(**{**TINY, **sizes})
-        if optimizer is None:
-            pairs = fluid.append_backward(fetches["loss"])
-        else:
-            optimizer.minimize(fetches["loss"])
-            pairs = []
-    main.random_seed = startup.random_seed = 7
-    return main, startup, fetches, pairs
-
-
-def _batch(seed=0, batch=2):
-    rng = np.random.RandomState(seed)
-    shape = (batch, TINY["seq_len"])
-    return {"tokens": rng.randint(0, TINY["vocab_size"], shape)
-            .astype(np.int32),
-            "labels": rng.randint(0, TINY["vocab_size"], shape)
-            .astype(np.int32)}
-
-
-def _seeded_weights(scope, names, seed=3):
+def _seeded_values(shapes, seed=3):
     """Weights far from their initial values, so that no term of the
     comparison is small by construction: norm weights in [0.5, 1.5], a gate
     that is not at 1/2, matrices of std 0.1 (five times the initial), gate 0.5."""
     rng = np.random.RandomState(seed)
-    for name in sorted(names):
-        shape = np.shape(scope.find_var(name))
+    values = {}
+    for name in sorted(shapes):
+        shape = shapes[name]
         if "norm" in name:
             value = rng.uniform(0.5, 1.5, shape)
         elif name.startswith("exit_gate"):
             value = rng.randn(*shape) * 0.5
         else:
             value = rng.randn(*shape) * 0.1
-        scope.set_var(name, jnp.asarray(value.astype(np.float32)))
+        values[name] = value.astype(np.float32)
+    return values
 
 
 def _head_outputs(main):
@@ -86,48 +62,30 @@ def _head_outputs(main):
             if op.type == "mul" and "head.w" in op.input_arg_names]
 
 
-def _run_tiny(amp):
-    main, startup, fetches, pairs = _program()
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace(), amp=amp)
-    exe.run(startup, scope=scope)
-    _seeded_weights(scope, [p.name for p, _ in pairs])
-    params = {p.name: np.asarray(scope.find_var(p.name)) for p, _ in pairs}
-    feed = _batch()
-    heads = _head_outputs(main)
-    names = SCALARS + ["exit_probs"]
-    out = exe.run(main, feed=feed,
-                  fetch_list=[fetches[n] for n in names] + heads
-                  + [g for _, g in pairs], scope=scope)
-    got = dict(zip(names, out))
-    got["logits"] = out[len(names):len(names) + len(heads)]
-    grads = dict(zip((p.name for p, _ in pairs),
-                     out[len(names) + len(heads):]))
-    return main, params, feed, got, grads
+# a gradient is held entry by entry, by this file's `rel_err` (it reshapes: a
+# fetched scalar is `[1]`, the reference's `[]`), not in the Frobenius norm
+CASE = DecoderCase(
+    models.ouro.build, TINY, ref, REF_KW, SCALARS + ["exit_probs"],
+    seeded_values=_seeded_values, interpreted=True, out_tol=RTOL,
+    grad_tol=RTOL, grad_err=rel_err,
+    also_fetch=lambda main: {"logits": _head_outputs(main)})
 
 
 @pytest.fixture(scope="module")
 def tiny():
     """The tiny model through the chip's flash kernels, interpreted on the
     CPU."""
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-        main, params, feed, got, grads = _run_tiny(amp=False)
-    parts, ref_grads = ref.loss_and_grads(
-        params, feed["tokens"], feed["labels"], last=TINY["seq_len"],
-        **REF_KW)
-    return {"main": main, "params": params, "feed": feed, "got": got,
-            "grads": grads, "want": parts, "want_grads": ref_grads}
+    return CASE.tiny_model()
 
 
 # -- the program: weights shared, not copied -------------------------------------
 
 def test_the_scope_holds_one_set_of_layer_weights_for_all_passes(tiny):
-    assert sorted(tiny["params"]) == sorted(PARAM_NAMES)
+    CASE.has_the_reference_parameters(tiny, PARAM_NAMES)
 
 
 def test_one_adam_op_a_parameter_and_a_fanin_of_the_loop_count():
-    main, _, _, _ = _program(fluid.optimizer.Adam(learning_rate=1e-3))
+    main, _, _, _ = CASE.program(fluid.optimizer.Adam(learning_rate=1e-3))
     block = main.global_block()
     adam = [op.input("Param")[0] for op in block.ops if op.type == "adam"]
     assert sorted(adam) == sorted(PARAM_NAMES)
@@ -146,13 +104,13 @@ def test_one_adam_op_a_parameter_and_a_fanin_of_the_loop_count():
 
 
 def test_compile_event_carries_the_sharing_counters():
-    main, startup, fetches, _ = _program(
+    main, startup, fetches, _ = CASE.program(
         fluid.optimizer.SGD(learning_rate=1e-3), n_layer=1, n_loop=2,
         seq_len=16)
     scope = fluid.Scope()
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(startup, scope=scope)
-    feed = {k: v[:, :16] for k, v in _batch().items()}
+    feed = {k: v[:, :16] for k, v in CASE.batch().items()}
     exe.run(main, feed=feed, fetch_list=[fetches["loss"]], scope=scope)
     detail = observe.observatory().latest(main._uid).detail
     assert detail["grad_fanin_max"] == 2
@@ -210,7 +168,7 @@ def test_name_scope_reaches_the_lowered_text_and_nests():
 
 @pytest.mark.parametrize("name", SCALARS + ["exit_probs"])
 def test_tiny_model_output_matches_reference(tiny, name):
-    assert rel_err(tiny["got"][name], tiny["want"][name]) < RTOL
+    CASE.output_matches_reference(tiny, name)
 
 
 @pytest.mark.parametrize("step", [1, 2, 3, 4])
@@ -238,7 +196,7 @@ def test_exit_probabilities_sum_to_one_and_are_not_uniform(tiny):
 
 @pytest.mark.parametrize("name", PARAM_NAMES)
 def test_tiny_model_gradient_matches_reference(tiny, name):
-    assert rel_err(tiny["grads"][name], tiny["want_grads"][name]) < RTOL
+    CASE.gradient_matches_reference(tiny, name)
 
 
 @pytest.mark.parametrize("name", ["l0.q.w", "l1.down.w",
@@ -287,20 +245,12 @@ def test_shared_gradient_is_the_sum_over_an_untied_twin(tiny, name):
 def test_one_pass_without_a_gate_is_a_plain_decoder():
     """`n_loop=1`: p_1 = 1, no gate is built, the entropy is 0 and the loss
     is the mean cross-entropy of a plain pre- and post-norm decoder."""
-    main, startup, fetches, pairs = _program(n_loop=1)
-    names = sorted(p.name for p, _ in pairs)
-    assert not [n for n in names if n.startswith("exit_gate")]
+    main, params, feed, got, grads, _ = CASE.run_tiny(
+        amp=False, batch_seed=1, n_loop=1)
+    assert not [n for n in params if n.startswith("exit_gate")]
     assert parameter_sharing(main)["grad_fanin_max"] == 1
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    _seeded_weights(scope, names)
-    params = {n: np.asarray(scope.find_var(n)) for n in names}
-    feed = _batch(seed=1)
-    loss, ce, entropy, probs, grad = exe.run(
-        main, feed=feed,
-        fetch_list=[fetches[n] for n in SCALARS + ["exit_probs"]]
-        + ["l1.up.w@GRAD"], scope=scope)
+    loss, ce, entropy, probs = (got[n] for n in SCALARS + ["exit_probs"])
+    grad = grads["l1.up.w"]
     with jax.default_matmul_precision("highest"):
         (g,), p = ref.passes(params, feed["tokens"], n_layer=2, n_head=2,
                              n_loop=1)
@@ -313,6 +263,8 @@ def test_one_pass_without_a_gate_is_a_plain_decoder():
     assert rel_err(grad, want_grads["l1.up.w"]) < RTOL
 
 
+# its own body: every scalar, the exit distribution and every pass's logits
+# are compared, at the last 16 positions
 def test_reference_in_blocks_is_the_reference(tiny):
     """`q_block` and `remat` are the reference's memory at published
     widths, not its mathematics: they change the order of a few float32
@@ -339,6 +291,8 @@ def test_reference_exit_distribution_by_hand():
                   (1 - s(2.0)) * (1 - s(-1.0))], rtol=1e-6)
 
 
+# its own body: the seeded weights against `tiny`'s reference, every pass's
+# logits and the exit distribution
 def test_tiny_model_amp_within_bf16_of_reference(tiny):
     """AMP on: projections, attention and the head in bf16 (relative
     rounding 2^-9 an operand); the gate's logit, the exit distribution, the
@@ -349,7 +303,7 @@ def test_tiny_model_amp_within_bf16_of_reference(tiny):
     Frobenius norm)."""
     with pytest.MonkeyPatch.context() as patch:
         patch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-        _, _, _, got, grads = _run_tiny(amp=True)
+        _, _, _, got, grads, _ = CASE.run_tiny(amp=True)
     want = tiny["want"]
     for step in range(4):
         assert got["logits"][step].dtype == jnp.bfloat16
@@ -371,22 +325,8 @@ def test_tiny_model_amp_within_bf16_of_reference(tiny):
 
 
 def test_five_adam_steps_lower_the_loss():
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.Adam(learning_rate=3e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    feed = _batch(seed=5)
-    losses = [float(np.asarray(exe.run(main, feed=feed,
-                                       fetch_list=[fetches["loss"]],
-                                       scope=scope)[0]).reshape(-1)[0])
-              for _ in range(5)]
-    assert np.all(np.isfinite(losses))
-    assert losses[-1] < losses[0] - 0.05, losses
+    CASE.adam_steps_lower_the_loss(seed=5, steps=5)
 
 
 def test_the_two_copies_of_the_reference_are_identical():
-    other = os.path.join(os.path.dirname(HERE), "benchmark", "references",
-                         "ouro_reference.py")
-    assert filecmp.cmp(os.path.join(HERE, "ouro_reference.py"), other,
-                       shallow=False)
+    CASE.two_copies_of_the_reference_are_identical()

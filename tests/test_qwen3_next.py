@@ -5,9 +5,7 @@ through `layers` -> Program IR -> `Executor`, against the plain reference
 recurrence, a loop over the held experts). Seeded random weights,
 float32, AMP off unless a test says otherwise."""
 
-import filecmp
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -16,45 +14,23 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import layers, models, observe
-from paddle_tpu.core import ir
 from paddle_tpu.ops import linear_attention as la
 from paddle_tpu.ops import moe
 
 import qwen3_next_reference as ref
-from test_olmoe import rel_err, run_piece
+from decoder_case import (DIGESTS, REGIMES, RTOL, DecoderCase, build_program,
+                          carries_the_census, frob,
+                          layers_are_built_under_their_scopes, program_digest,
+                          rel_err, run_piece, tiny_args)
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-TINY = dict(vocab_size=64, seq_len=128, n_layer=4, d_model=32,
-            full_attention_interval=4, n_head=4, n_kv_head=2, head_dim=16,
-            rotary_dim=4, rope_theta=1e4, n_key_head=2, n_value_head=4,
-            key_dim=8, value_dim=8, conv_kernel=4, n_expert=16, top_k=4,
-            d_expert=16, d_shared=16, first_expert=4, experts_held=4)
+TINY = tiny_args("qwen3_next")
 REF_KW = {k: TINY[k] for k in (
     "n_layer", "n_head", "n_kv_head", "head_dim", "rotary_dim", "rope_theta",
     "full_attention_interval", "n_key_head", "n_value_head", "key_dim",
     "value_dim", "top_k", "first_expert")}
-# float32 against float32 highest: the two sides differ by the order of
-# their sums (chunks and a triangular solve against a recurrence)
-RTOL = 2e-5
-
-
-def frob(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return np.linalg.norm(got.reshape(want.shape) - want) \
-        / (np.linalg.norm(want) + 1e-30)
 
 
 # -- the delta rule: chunks against the recurrence ----------------------------
-
-REGIMES = {
-    # (scale and offset of g's pre-activation, of beta's logit)
-    "mixed": ((1.0, 0.0), (1.0, 0.0)),
-    "g_near_0": ((0.1, -9.0), (1.0, 0.0)),          # g ~ -1e-4
-    "g_strongly_negative": ((1.0, 3.0), (1.0, 0.0)),  # g ~ -30 a token
-    "beta_near_0": ((1.0, 0.0), (0.3, -7.0)),
-    "beta_near_1": ((1.0, 0.0), (0.3, 7.0)),
-}
-
 
 def _rule_inputs(t, regime, heads=3, dk=8, dv=6, seed=0):
     rng = np.random.RandomState(seed)
@@ -907,37 +883,16 @@ def test_a_share_counts_its_three_bounded_ops_a_layer(held, bounded):
 
 # -- the whole tiny model ----------------------------------------------------------
 
-def _program(optimizer=None, **sizes):
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        feeds, fetches = models.qwen3_next.build(**{**TINY, **sizes})
-        if optimizer is None:
-            pairs = fluid.append_backward(fetches["loss"])
-        else:
-            optimizer.minimize(fetches["loss"])
-            pairs = []
-    main.random_seed = startup.random_seed = 7
-    return main, startup, fetches, pairs
-
-
-def _batch(seed=0, batch=2):
-    rng = np.random.RandomState(seed)
-    shape = (batch, TINY["seq_len"])
-    return {"tokens": rng.randint(0, TINY["vocab_size"], shape)
-            .astype(np.int32),
-            "labels": rng.randint(0, TINY["vocab_size"], shape)
-            .astype(np.int32)}
-
-
-def _seeded_weights(scope, names, seed=3):
+def _seeded_values(shapes, seed=3):
     """Weights far from their initial values, so that no term of the
     comparison is small by construction: zero-centred norm weights in
     [-0.5, 0.5], the gated norm's in [0.5, 1.5], a decay that forgets
     slowly (A in [0.05, 1]) so that the state carries over many chunks,
     matrices of std 0.1 (five times the initial)."""
     rng = np.random.RandomState(seed)
-    for name in sorted(names):
-        shape = np.shape(scope.find_var(name))
+    values = {}
+    for name in sorted(shapes):
+        shape = shapes[name]
         if name.endswith("gdn.norm.w"):
             value = rng.uniform(0.5, 1.5, shape)
         elif "norm" in name:
@@ -952,37 +907,18 @@ def _seeded_weights(scope, names, seed=3):
             value = rng.randn(*shape) * 0.5
         else:
             value = rng.randn(*shape) * 0.1
-        scope.set_var(name, jnp.asarray(value.astype(np.float32)))
+        values[name] = value.astype(np.float32)
+    return values
 
 
 FETCHES = ["loss", "ce", "load_balance", "logits", "tokens_per_expert"]
-
-
-def _run_tiny(amp, seeded=True):
-    main, startup, fetches, pairs = _program()
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace(), amp=amp)
-    exe.run(startup, scope=scope)
-    if seeded:
-        _seeded_weights(scope, [p.name for p, _ in pairs])
-    params = {p.name: np.asarray(scope.find_var(p.name)) for p, _ in pairs}
-    feed = _batch()
-    out = exe.run(main, feed=feed,
-                  fetch_list=[fetches[n] for n in FETCHES]
-                  + [g for _, g in pairs], scope=scope)
-    got = dict(zip(FETCHES, out))
-    grads = dict(zip((p.name for p, _ in pairs), out[len(FETCHES):]))
-    return main, params, feed, got, grads
+CASE = DecoderCase(models.qwen3_next.build, TINY, ref, REF_KW, FETCHES,
+                   seeded_values=_seeded_values)
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    main, params, feed, got, grads = _run_tiny(amp=False)
-    tokens, labels = jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"])
-    want, want_grads = ref.loss_and_grads(
-        params, tokens, labels, last=TINY["seq_len"], **REF_KW)
-    return dict(main=main, params=params, tokens=tokens, labels=labels,
-                got=got, grads=grads, want=want, want_grads=want_grads)
+    return CASE.tiny_model()
 
 
 GDN = ["in_norm.w", "post_norm.w", "gdn.qkvz.w", "gdn.ba.w", "gdn.conv.w",
@@ -997,113 +933,80 @@ PARAM_NAMES = (["embed.w", "final_norm.w", "head.w"]
 
 
 def test_tiny_model_has_the_reference_parameters(tiny):
-    assert sorted(tiny["params"]) == sorted(PARAM_NAMES)
-    assert tiny["params"]["l0.experts.gate.w"].shape == (4, 32, 16)
-    assert tiny["params"]["l0.router.w"].shape == (32, 16)
-    assert tiny["params"]["l3.attn.q.w"].shape == (32, 4 * 2 * 16)
-    assert tiny["params"]["l0.gdn.qkvz.w"].shape == (32, 2 * (16 + 32))
+    CASE.has_the_reference_parameters(tiny, PARAM_NAMES, {
+        "l0.experts.gate.w": (4, 32, 16), "l0.router.w": (32, 16),
+        "l3.attn.q.w": (32, 4 * 2 * 16),
+        "l0.gdn.qkvz.w": (32, 2 * (16 + 32))})
 
 
 @pytest.mark.parametrize("name", FETCHES)
 def test_tiny_model_output_matches_reference(tiny, name):
-    if name == "tokens_per_expert":
-        assert np.array_equal(tiny["got"][name], tiny["want"][name])
-    else:
-        want = np.asarray(tiny["want"][name])
-        assert rel_err(np.reshape(tiny["got"][name], want.shape), want) < 1e-4
+    CASE.output_matches_reference(tiny, name)
 
 
 def test_tiny_routing_sends_most_assignments_elsewhere(tiny):
-    counts = tiny["got"]["tokens_per_expert"]
-    assert counts.shape == (4, 16) and np.all(counts.sum(1) == 2 * 128 * 4)
-    held = counts[:, 4:8].sum(1)
-    assert np.all(held > 0) and np.all(held < counts.sum(1) / 2)
+    CASE.routing_sends_most_assignments_elsewhere(tiny, routed_layers=4)
 
 
 @pytest.mark.parametrize("name", PARAM_NAMES)
 def test_tiny_model_gradient_matches_reference(tiny, name):
-    assert frob(tiny["grads"][name], tiny["want_grads"][name]) < 2e-4
+    CASE.gradient_matches_reference(tiny, name)
 
 
 @pytest.mark.parametrize("kind,sizes", [
     ("linear_attention", dict(n_layer=1)),
     ("full_attention", dict(n_layer=1, full_attention_interval=1))])
 def test_one_layer_of_each_kind_matches_reference(kind, sizes):
-    main, startup, fetches, pairs = _program(**sizes)
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    _seeded_weights(scope, [p.name for p, _ in pairs], seed=11)
-    params = {p.name: np.asarray(scope.find_var(p.name)) for p, _ in pairs}
+    main, params, feed, got, grads, _ = CASE.run_tiny(
+        amp=False, seed=11, batch_seed=4, **sizes)
     assert any(("gdn" in n) == (kind == "linear_attention") for n in params
                if n.startswith("l0.") and ("gdn" in n or "attn" in n))
-    feed = _batch(seed=4)
-    out = exe.run(main, feed=feed, fetch_list=[fetches["loss"],
-                                               fetches["logits"]]
-                  + [g for _, g in pairs], scope=scope)
     want, want_grads = ref.loss_and_grads(
         params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
         last=TINY["seq_len"], **{**REF_KW, **sizes})
-    assert abs(float(out[0][0]) - float(want["loss"])) < 1e-5
-    assert rel_err(out[1], want["logits"]) < 1e-4
-    for (p, _), g in zip(pairs, out[2:]):
-        assert frob(g, want_grads[p.name]) < 2e-4, p.name
+    assert abs(float(got["loss"][0]) - float(want["loss"])) < 1e-5
+    assert rel_err(got["logits"], want["logits"]) < 1e-4
+    for name, g in grads.items():
+        assert frob(g, want_grads[name]) < 2e-4, name
     detail = observe.observatory().latest(main._uid).detail
     assert detail["layer_kinds"] == {kind: 1}
 
 
 def test_reference_in_blocks_is_the_reference(tiny):
-    """`q_block`, `token_block` and `remat` are the reference's memory, not
-    its mathematics."""
-    parts, grads = ref.loss_and_grads(
-        tiny["params"], tiny["tokens"], tiny["labels"],
-        wrt=["l0.gdn.qkvz.w", "l3.attn.q.w", "embed.w"], q_block=32,
-        token_block=16, remat=True, **REF_KW)
-    assert abs(float(parts["loss"]) - float(tiny["want"]["loss"])) < 1e-5
-    for name, g in grads.items():
-        assert frob(g, tiny["want_grads"][name]) < 1e-5, name
+    CASE.reference_in_blocks_is_the_reference(
+        tiny, ["l0.gdn.qkvz.w", "l3.attn.q.w", "embed.w"], q_block=32,
+        token_block=16)
 
 
 def test_reference_last_positions_equal_the_full_pass(tiny):
-    parts = ref.loss_parts(tiny["params"], tiny["tokens"], tiny["labels"],
-                           last=16, **REF_KW)
-    assert rel_err(parts["logits"], tiny["want"]["logits"][:, -16:]) < 1e-6
+    CASE.reference_last_positions_equal_the_full_pass(tiny)
 
 
 def test_compile_event_carries_the_census():
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.SGD(learning_rate=1e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    exe.run(main, feed=_batch(), fetch_list=[fetches["loss"]], scope=scope)
-    detail = observe.observatory().latest(main._uid).detail
-    assert detail["layer_kinds"] == {"linear_attention": 3,
-                                     "full_attention": 1}
-    assert detail["moe_experts_routed"] == 16
-    assert detail["moe_experts_held"] == 4
-    # noted by `moe_dispatch`'s rule under the trace: 2 x 128 tokens x 4
-    # choices + 4 held experts x 128
-    assert detail["moe_row_buffer_rows"] == 2 * 128 * 4 + 4 * 128
-    # dispatch, combine and their grads in each of the four layers, lowered
-    # over the rows the held groups use (`ops/moe.py::_over_used_rows`)
-    assert detail["moe_share_bounded_moves"] == 4 * 4
-    assert detail["grad_fanin_max"] == 1
-    # the startup program has neither mixers nor experts
-    assert "layer_kinds" not in observe.observatory().latest(
-        startup._uid).detail
+    carries_the_census(CASE.compile_detail(), {
+        "layer_kinds": {"linear_attention": 3, "full_attention": 1},
+        "moe_experts_routed": 16, "moe_experts_held": 4,
+        # noted by `moe_dispatch`'s rule under the trace: 2 x 128 tokens x 4
+        # choices + 4 held experts x 128
+        "moe_row_buffer_rows": 2 * 128 * 4 + 4 * 128,
+        # dispatch, combine and their grads in each of the four layers,
+        # lowered over the rows the held groups use
+        # (`ops/moe.py::_over_used_rows`)
+        "moe_share_bounded_moves": 4 * 4, "grad_fanin_max": 1},
+        # the startup program has neither mixers nor experts
+        startup_lacks=["layer_kinds"])
 
 
 def test_the_rows_on_the_compile_event_follow_the_batch():
     """`moe_row_buffer_rows` is what the rule laid out, not a setting: one
     sequence instead of two is another compile of the same program with
     fewer rows; a rule called outside a lowering notes nothing."""
-    main, startup, fetches, _ = _program(
+    main, startup, fetches, _ = CASE.program(
         fluid.optimizer.SGD(learning_rate=1e-3), n_layer=1)
     scope = fluid.Scope()
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(startup, scope=scope)
-    one = {k: v[:1] for k, v in _batch().items()}
+    one = {k: v[:1] for k, v in CASE.batch().items()}
     exe.run(main, feed=one, fetch_list=[fetches["loss"]], scope=scope)
     assert observe.observatory().latest(main._uid).detail[
         "moe_row_buffer_rows"] == 128 * 4 + 4 * 128
@@ -1112,19 +1015,15 @@ def test_the_rows_on_the_compile_event_follow_the_batch():
 
 
 def test_every_layer_is_built_under_its_name_scopes(tiny):
-    scopes = {}
-    for op in tiny["main"].global_block().ops:
-        if op.attrs.get("__role__") is None:
-            scopes.setdefault(op.attrs.get(ir.NAME_SCOPE_ATTR), set()) \
-                .add(op.type)
-    assert {"l0.gdn", "l1.gdn", "l2.gdn", "l3.attn", "l0.moe", "l1.moe",
-            "l2.moe", "l3.moe"} <= set(scopes)
-    assert "l3.gdn" not in scopes and "l0.attn" not in scopes
-    assert {"gated_delta_rule", "delta_rule_gates", "causal_conv1d",
-            "gated_rms_norm"} <= scopes["l0.gdn"]
-    assert "fused_attention" in scopes["l3.attn"]
-    assert {"moe_router", "moe_dispatch", "grouped_matmul",
-            "moe_combine"} <= scopes["l2.moe"]
+    layers_are_built_under_their_scopes(
+        tiny["main"],
+        ["l0.gdn", "l1.gdn", "l2.gdn", "l3.attn", "l0.moe", "l1.moe",
+         "l2.moe", "l3.moe"], absent=["l3.gdn", "l0.attn"],
+        holds={"l0.gdn": ["gated_delta_rule", "delta_rule_gates",
+                          "causal_conv1d", "gated_rms_norm"],
+               "l3.attn": ["fused_attention"],
+               "l2.moe": ["moe_router", "moe_dispatch", "grouped_matmul",
+                          "moe_combine"]})
 
 
 def test_tiny_model_amp_within_bf16_of_reference():
@@ -1135,24 +1034,13 @@ def test_tiny_model_amp_within_bf16_of_reference():
     weights: with the seeded ones (a router five times as sharp) a few of
     the 1024 assignments flip under bf16 inputs and move the gradients by
     more than the rounding does."""
-    _, params, feed, got, grads = _run_tiny(amp=True, seeded=False)
-    want, want_grads = ref.loss_and_grads(
-        params, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
-        last=TINY["seq_len"], **REF_KW)
-    assert abs(float(got["loss"][0]) - float(want["loss"])) < 0.002
-    assert got["logits"].dtype == jnp.bfloat16
-    err = np.abs(np.asarray(got["logits"], np.float32)
-                 - np.asarray(want["logits"]))
-    std = float(np.std(want["logits"]))
-    assert err.mean() < 0.02 * std and err.max() < 0.1 * std
-    for name in ("l0.gdn.qkvz.w", "l0.gdn.conv.w", "l3.attn.q.w",
-                 "l0.experts.gate.w", "l0.shared.gate.w", "embed.w"):
-        assert grads[name].dtype == np.float32
-        assert frob(grads[name], want_grads[name]) < 0.04, name
+    CASE.amp_within_bf16_of_reference(
+        {0.04: ("l0.gdn.qkvz.w", "l0.gdn.conv.w", "l3.attn.q.w",
+                "l0.experts.gate.w", "l0.shared.gate.w", "embed.w")})
 
 
 def test_amp_keeps_the_gates_and_the_router_in_float32():
-    main, startup, fetches, _ = _program(n_layer=1)
+    main, startup, fetches, _ = CASE.program(n_layer=1)
     block = main.global_block()
     gates = next(o for o in block.ops if o.type == "delta_rule_gates")
     from paddle_tpu.core import registry
@@ -1164,19 +1052,11 @@ def test_amp_keeps_the_gates_and_the_router_in_float32():
 
 
 def test_five_adam_steps_lower_the_loss():
-    main, startup, fetches, _ = _program(
-        fluid.optimizer.Adam(learning_rate=3e-3))
-    scope = fluid.Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    feed = _batch()
-    losses = [float(exe.run(main, feed=feed, fetch_list=[fetches["loss"]],
-                            scope=scope)[0][0]) for _ in range(6)]
-    assert np.all(np.isfinite(losses)) and losses[-1] < losses[0] - 0.05
+    CASE.adam_steps_lower_the_loss()
 
 
 def test_a_log_starts_as_the_log_of_a_uniform_draw_and_dt_bias_at_one():
-    main, startup, _, _ = _program(n_layer=1)
+    main, startup, _, _ = CASE.program(n_layer=1)
     scope = fluid.Scope()
     fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
     a = np.exp(np.asarray(scope.find_var("l0.gdn.A_log")))
@@ -1191,8 +1071,7 @@ def test_a_log_starts_as_the_log_of_a_uniform_draw_and_dt_bias_at_one():
 def test_olmoe_program_is_unchanged_op_for_op():
     """The expert layer, the router, rms_norm and rotary_embedding took new
     attributes in this file's PR; a program that passes none of them is the
-    program it was (`test_decoder_models.DIGESTS`)."""
-    from test_decoder_models import DIGESTS, build_program, program_digest
+    program it was (`decoder_case.DIGESTS`)."""
     main, startup, feeds, fetches = build_program("olmoe")
     assert program_digest(main, startup) == DIGESTS["olmoe"]
     # and its movements are the static ones: every row is an assignment
@@ -1209,7 +1088,4 @@ def test_olmoe_program_is_unchanged_op_for_op():
 
 
 def test_the_two_copies_of_the_reference_are_identical():
-    assert filecmp.cmp(
-        os.path.join(HERE, "qwen3_next_reference.py"),
-        os.path.join(HERE, "..", "benchmark", "references",
-                     "qwen3_next_reference.py"), shallow=False)
+    CASE.two_copies_of_the_reference_are_identical()

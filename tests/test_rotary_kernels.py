@@ -17,8 +17,9 @@ from paddle_tpu.ops import _kernels
 from paddle_tpu.ops import decoder_block as db
 
 from attention_program import kernel_calls, step_text
-from test_mellum2 import TINY, TINY_YARN
-from test_olmoe import run_piece
+from decoder_case import TINY_YARN, run_piece, tiny_args
+
+TINY = tiny_args("mellum2")
 
 YARN = {"factor": 8.0, "original_max_position_embeddings": 64}
 # the regimes: (leading dims, tokens, head, interleaved, scaling)
