@@ -113,7 +113,8 @@ that stream tiles run it in a body without the mask's compare-and-select,
 and the tiles an edge crosses (the diagonal, the band's lower one) in the
 body that has it (`_on_live_tile`): 28 of a head's 36 tiles at 8192 tokens
 are interior, 15 of 45 under a window of 1024 in tiles of 512, 18 of 30
-under 2048 over 4096. Leaving out a select whose predicate is false in every
+under 2048 over 4096 (at the tiles of 1024 such windows take since PR 72,
+none of 15 and 3 of 9). Leaving out a select whose predicate is false in every
 element changes no bit. A kept set is data and masks every tile of its call;
 the causal mask stays on that call's edge tiles, so its meaning does not
 rest on the set lying under the diagonal. Its int8 tile is fetched for the
@@ -122,6 +123,30 @@ held maps (`_kept_spec`). A plain causal call keeps the mask
 on every live tile, the instructions it was: at its 1024 x 1024 tiles the
 pass hides behind the products and a second body is a cost
 (`_interior_apart`); so does every call whose row is one K block.
+
+An edge tile is half masked, and where its edge runs from corner to corner
+it is not computed whole. *Aligned* (`_strip_side`): square tiles of 1024,
+so the diagonal tile's edge is its own diagonal, and under a window one of
+whole tiles, which then takes such tiles (`_blk`), so the band's lower-edge
+tile (the one that starts `window` keys under its queries) is the mirror
+image. The four kernels that stream tiles
+run such a tile in a `pl.when` body of its own, strip by strip
+(`_edge_strips`): the forward and dQ by rows (a strip of rows against the
+keys it sees: its own statistics, one product a contraction), dK/dV and the
+fused backward by keys (a strip of keys against the rows that see it; dQ
+from the key strips' `ds`, a row strip's parts side by side in ascending
+key order, `_row_shares`), by static slices of the blocks: 10 of the 16
+sub-blocks of a tile in fourths. Only the sub-block the edge crosses keeps
+a select. Every term a strip leaves out of a row's sum of weights or of a
+product's contraction is an exact zero at one end of the sum, and the MXU
+adds a contraction's passes in order: `Out`, `Lse`, dQ, dK and dV are the
+bits they were (tests/test_flash_grad_tpu.py holds it on the chip at the
+cells' shapes). Which strips, and how wide, follows from the shapes alone:
+strips of a fourth of 1024 pay 5-12% of a call's forward + backward, strips
+of 128 rows cost more than they skip (`_strip_side` has the readings). A
+call that is not aligned (tiles that are not square or under 1024, a window
+off the tiles), a token-major step of several heads and a row of one K
+block keep the bodies they had, the instructions they were.
 
 On a CPU backend the same kernels run under the Pallas interpreter when
 PADDLE_TPU_PALLAS_INTERPRET=1 (used by the CPU test suite); otherwise a
@@ -197,14 +222,25 @@ def _blk(T, causal=False, window=None):
     3.67 at 512^2 (30 of 36), 6.29 at 256^2, 3.95-5.00 at the four mixed
     shapes (without a window there 4.31 at 1024^2, 4.35 at 512^2: the smaller
     tile costs a hundredth, the band's edges a twentieth). Both shapes'
-    result is 512; every other length and window takes the rule as a
-    default that no run has tried, and does not consult the sweep table
-    above, whose entries were measured without a window."""
+    result was 512 while every tile ran whole. Since PR 72 an aligned edge
+    tile of 1024 runs in strips (`_strip_side`), tiles of 1024 in strips
+    cover no more than tiles of 512 whole in a third of the grid steps, and
+    a window that is whole tiles of 1024 takes them: forward + fused
+    backward a call, the kernels alone chained on the host's clock (chip
+    runs, PR 72, `tools/interior_mask_probe.py`), tiles of 512 whole ->
+    tiles of 1024 in strips: W = 1024 over 8192 tokens 6.185 -> 5.541 ms (a
+    head's 45 tiles -> 15, every one an edge tile), W = 2048 over 4096 3.658
+    -> 3.328 (30 -> 9, six of them edge tiles). Every other length and
+    window takes the rule of half the window as a default that no run has
+    tried, and does not consult the sweep table above, whose entries were
+    measured without a window."""
     if _BLOCK_OVERRIDE is not None:
         bq, bk = _BLOCK_OVERRIDE
         if T % bq == 0 and T % bk == 0:
             return bq, bk
     if window is not None and window < T:
+        if window % _STRIP_TILE == 0 and T % _STRIP_TILE == 0:
+            return _STRIP_TILE, _STRIP_TILE
         for b in (512, 256, 128):
             if T % b == 0 and b <= max(window // 2, 128):
                 return b, b
@@ -373,26 +409,190 @@ def _interior_apart(window, kept):
     return window is not None or kept is not None
 
 
-def _on_live_tile(update, causal, qi, kj, blk_q, blk_k, window, apart):
-    """`update(masked)` if the (qi, kj) tile is live. Where interior tiles
+_ALL = slice(None)
+
+
+class _Piece(NamedTuple):
+    """What a kernel computes of a (qi, kj) tile at once: `rows` of its
+    queries and `keys` of its keys, static slices of the blocks, and `mask`,
+    what the causal mask does to that many scores (None: nothing). A whole
+    tile is one piece of every row and key (`_whole`), the instructions it
+    was; an aligned edge tile is several (`_edge_strips`)."""
+    rows: slice
+    keys: slice
+    mask: object
+
+    @property
+    def whole(self):
+        return self.rows == _ALL
+
+
+def _sub(idx, part):
+    """The index `idx` of a head's rows in a block or in scratch (`_Head`'s
+    fields), narrowed to `part` of them; to every one, `idx` as it was."""
+    if part == _ALL:
+        return idx
+    if idx is ...:
+        return part
+    return (idx, part) if isinstance(idx, int) else idx + (part,)
+
+
+def _whole(masked, qi, kj, blk_q, blk_k, window=None):
+    """The (qi, kj) tile as one piece, under `_apply_causal_mask` where it
+    is `masked`."""
+    def mask(s):
+        return _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
+    return [_Piece(_ALL, _ALL, mask if masked else None)]
+
+
+# The least tile whose edge tiles run in strips (`_strip_side`).
+_STRIP_TILE = 1024
+
+
+def _strip_side(blk_q, blk_k, window=None):
+    """Rows (and keys) of a strip of an *aligned* edge tile, or None where
+    an edge tile of these shapes runs whole. Aligned: square tiles, so that
+    the diagonal crosses a tile from corner to corner, and under a `window`
+    one that is whole tiles long, so that the band's lower edge does too.
+    A strip is a fourth of a tile of 1024 or more: 256 rows, whole vregs of
+    lanes, which every dtype's sublane packing divides. From the shapes
+    alone.
+    Measured (TPU v5 lite, bf16, the kernels alone, forward + fused
+    backward a call chained on the host's clock, `Out`, `Lse`, dQ, dK, dV
+    bitwise the whole tile's in every row; `tools/interior_mask_probe.py`,
+    chip runs, PR 72), ms a call, every edge tile whole -> in fourths / in
+    halves. Tiles of 1024: [32, 8192, 128] 14.838 -> 14.035 / 14.047, under
+    a kept set 15.272 -> 14.393 / 14.405; [32, 4096, 192] over 128 6.362 ->
+    5.627 / 5.781; [16, 4096, 128] 2.108 -> 1.893 / 1.898; [16, 4096, 256]
+    3.859 -> 3.378 / 3.521; [32, 4096, 64] 4.332 -> 3.931 / 3.947;
+    [32, 8192, 128] under W = 1024 7.050 -> 5.541 / 5.463; [32, 4096, 128]
+    under W = 2048 3.926 -> 3.328 / 3.287. Tiles of 512 (the windowed calls
+    before PR 72): W = 1024 6.185 -> 6.479 / 6.134, W = 2048 3.845 -> 3.903
+    / 3.654 (3.658 -> 3.664 in halves in a second call): strips of 128 rows
+    cost more than the sub-blocks they skip, and a tile of 512 in halves
+    gains nothing. So a tile under 1024 runs whole, and an aligned window
+    takes tiles of 1024 (`_blk`)."""
+    if blk_q != blk_k or blk_q < _STRIP_TILE \
+            or (window is not None and window % blk_k):
+        return None
+    return blk_q // 4
+
+
+def _on_edge(qi, kj, blk_q, blk_k, window=None):
+    """(Whether the (qi, kj) tile of an aligned call is its row's diagonal
+    tile, whether it is the band's lower-edge tile: the one that starts
+    `window` keys under its queries.) Every other live tile of such a call
+    is interior. On Python ints (`edge_strips`) and on traced int32."""
+    diagonal = qi * blk_q == kj * blk_k
+    if window is None:
+        return diagonal, False
+    return diagonal, kj * blk_k == qi * blk_q - window
+
+
+def _edge_strips(lower, by, blk, side):
+    """The live part of an aligned edge tile as `blk // side` pieces. Local
+    to the tile, key c is visible to row r iff c <= r on the diagonal tile
+    and iff c > r on the band's `lower` one, so of the (blk / side)^2
+    sub-blocks of `side` x `side` those on one side of the local diagonal
+    are live whole, those on the other dead whole (10 and 6 of 16 in
+    fourths, 3 and 1 of 4 in halves), and the ones on it are crossed.
+    `by` "rows": row strip i and the keys its rows see, `[0, (i + 1) side)`
+    or `[i side, blk)`: rows are independent, so a forward or dQ kernel
+    runs each with its own statistics and one product a contraction. `by`
+    "keys": key strip j and the rows that see it, `[j side, blk)` or `[0,
+    (j + 1) side)`: what a kernel that accumulates dK and dV wants. Each
+    piece's mask is one select on its one crossed sub-block, under a
+    predicate of local indices. Every term a piece leaves out of a sum of
+    the whole tile (a row's sum of weights, a product's contraction) is an
+    exact zero at one end of that sum."""
+    iota = functools.partial(lax.broadcasted_iota, jnp.int32, (side, side))
+
+    def piece(i):
+        own = slice(i * side, (i + 1) * side)
+        seen = slice(own.start, blk) if lower == (by == "rows") \
+            else slice(0, own.stop)
+        axis = 1 if by == "rows" else 0
+        at = own.start - seen.start     # the crossed sub-block, in `seen`
+
+        def mask(s):
+            dead = iota(1) <= iota(0) if lower else iota(1) > iota(0)
+            cut = jnp.where(dead, NEG_INF,
+                            lax.slice_in_dim(s, at, at + side, axis=axis))
+            parts = [lax.slice_in_dim(s, 0, at, axis=axis), cut,
+                     lax.slice_in_dim(s, at + side, s.shape[axis], axis=axis)]
+            parts = [x for x in parts if x.shape[axis]]
+            return cut if len(parts) == 1 else jnp.concatenate(parts, axis)
+
+        return _Piece(own, seen, mask) if by == "rows" \
+            else _Piece(seen, own, mask)
+
+    return [piece(i) for i in range(blk // side)]
+
+
+def _row_shares(pieces, shares):
+    """(rows, their `ds`, its keys) for the dQ of a tile whose `pieces` by
+    keys left the `shares` of `ds`: a whole tile's one share as it is; of
+    an edge tile in strips, for every row strip the parts of the key strips
+    its rows see, side by side in ascending key order."""
+    if pieces[0].whole:
+        return [(_ALL, shares[0], _ALL)]
+    gathered = []
+    for own in (pc.keys for pc in pieces):      # a square tile's row strips
+        seen = [(pc, ds) for pc, ds in zip(pieces, shares)
+                if pc.rows.start <= own.start and own.stop <= pc.rows.stop]
+        parts = [lax.slice_in_dim(ds, own.start - pc.rows.start,
+                                  own.stop - pc.rows.start, axis=0)
+                 for pc, ds in seen]
+        gathered.append((own, parts[0] if len(parts) == 1
+                         else jnp.concatenate(parts, axis=1),
+                         slice(seen[0][0].keys.start, seen[-1][0].keys.stop)))
+    return gathered
+
+
+def _on_live_tile(update, causal, qi, kj, blk_q, blk_k, window, apart,
+                  strips=None):
+    """`update(pieces)` if the (qi, kj) tile is live. Where interior tiles
     run `apart` (`_interior_apart`, and a row of several K blocks): in a
     body without the causal mask where the tile is interior, in the masked
     body where an edge (the diagonal, the band's lower one) crosses it. Two
     `pl.when` bodies, each straight-line, and not a conditional on the
     score tile, which cuts a body's products from its vector work (a fourth
-    slower than the mask on every tile). Elsewhere, and where the window is
-    narrower than a tile, one masked body; a call that is not causal has no
-    mask and no condition."""
+    slower than the mask on every tile). Where the kernel takes `strips`
+    ("rows" or "keys", `_edge_strips`) and the call is aligned
+    (`_strip_side`, which holds what strips read on the chip), an edge tile
+    runs in a body of its own, strip by strip over its live extent, and
+    every other live tile is interior: the masked body is left to the plain
+    causal call, which runs its interior tiles in it as it did (a third
+    body costs it nothing it does not get back: [16, 4096, 128] 2.108 ->
+    1.893 ms forward + backward, chip run, PR 72). A window of one tile has
+    no third kind of tile and no third body. Elsewhere, and where the
+    window is narrower than a tile, one masked body; a call that is not
+    causal has no mask and no condition."""
     from jax.experimental import pallas as pl
 
+    whole = functools.partial(_whole, qi=qi, kj=kj, blk_q=blk_q, blk_k=blk_k,
+                              window=window)
     if not causal:
-        return update(False)
+        return update(whole(False))
     live = _causal_live(qi, kj, blk_q, blk_k, window)
+    side = strips and _strip_side(blk_q, blk_k, window)
+    if side:
+        diagonal, lower = _on_edge(qi, kj, blk_q, blk_k, window)
+        pl.when(diagonal)(lambda: update(
+            _edge_strips(False, strips, blk_q, side)))
+        if window is not None:
+            pl.when(lower)(lambda: update(
+                _edge_strips(True, strips, blk_q, side)))
+            diagonal = diagonal | lower
+        if window is None or window > blk_k:    # else no third kind of tile
+            pl.when(live & jnp.logical_not(diagonal))(
+                lambda: update(whole(not apart)))
+        return
     interior = apart and _causal_interior(qi, kj, blk_q, blk_k, window)
     if interior is False:
-        return pl.when(live)(lambda: update(True))
-    pl.when(interior)(lambda: update(False))
-    pl.when(live & jnp.logical_not(interior))(lambda: update(True))
+        return pl.when(live)(lambda: update(whole(True)))
+    pl.when(interior)(lambda: update(whole(False)))
+    pl.when(live & jnp.logical_not(interior))(lambda: update(whole(True)))
 
 
 def _apply_causal_mask(s, qi, kj, blk_q, blk_k, window=None):
@@ -406,14 +606,24 @@ def _apply_causal_mask(s, qi, kj, blk_q, blk_k, window=None):
     return s
 
 
-def _apply_kept(s, kept_ref):
-    """Mask one score tile by the kept set's tile of the same rows and keys
+def _apply_kept(s, kept_ref, piece):
+    """Mask the scores of one `piece` of a tile by the kept set's tile of
+    the same rows and keys
     (int8 `[1, blk_q, blk_k]`, one for all heads of its batch row): a key
     whose entry is 0 is not seen. Widened to int32 first: a v5e's vector
     unit compares no bytes. A tile of ones leaves `s` the bits it had."""
     if kept_ref is None:
         return s
-    return jnp.where(kept_ref[0].astype(jnp.int32) != 0, s, NEG_INF)
+    kept = kept_ref[0] if piece.whole else kept_ref[0, piece.rows, piece.keys]
+    return jnp.where(kept.astype(jnp.int32) != 0, s, NEG_INF)
+
+
+def _masked(s, piece, kept_ref):
+    """The scores `s` of one `piece` of a tile under its causal mask, where
+    it has one, and under the kept set's."""
+    if piece.mask is not None:
+        s = piece.mask(s)
+    return _apply_kept(s, kept_ref, piece)
 
 
 # -- the band of a window over tiles ----------------------------------------
@@ -520,6 +730,29 @@ def interior_tiles(T, window=None):
     bq, bk = _blk(T, True, window)
     return sum(_causal_interior(qi, kj, bq, bk, window)
                for qi in range(T // bq) for kj in range(T // bk))
+
+
+def edge_strips(T, window=None):
+    """(Edge tiles of a causal head-major forward call that run in strips,
+    sub-blocks of them that are not computed), a head: what
+    `fused_attention` tallies as `flash_edge_tiles_stripped` and
+    `flash_subblocks_skipped`, times its batch and heads. The kernels' own
+    predicates on Python ints (`_strip_side`, `_on_edge`): a row's diagonal
+    tile and, under a window of whole tiles, the band's lower one, each
+    short of `n (n - 1) / 2` of its `n x n` sub-blocks (6 of 16). (0, 0)
+    where a row is one K block, where the call is not aligned, and at a
+    length outside the kernels' envelope, which the reference path runs."""
+    if T % _LANES:
+        return 0, 0
+    window = _window_of(window, T)
+    bq, bk = _blk(T, True, window)
+    side = _fwd_plan(T, bk) == "stream" and _strip_side(bq, bk, window)
+    if not side:
+        return 0, 0
+    tiles = sum(sum(map(bool, _on_edge(qi, kj, bq, bk, window)))
+                for qi in range(T // bq) for kj in range(T // bk))
+    n = bq // side
+    return tiles, tiles * (n * (n - 1) // 2)
 
 
 def kept_pairs(T, topk):
@@ -644,8 +877,9 @@ def _rmw(hd, ref, idx, f):
     ref[idx] = new if hd.mask is None else jnp.where(hd.mask, new, old)
 
 
-def _delta(hd, delta_ref, do):
-    """rowsum(dOut * Out) of the head's rows, float32. A head-major call is
+def _delta(hd, delta_ref, do, rows=_ALL):
+    """rowsum(dOut * Out) of the head's rows (of `rows` of them, as `do`
+    is), float32. A head-major call is
     handed it, a row of the `Lse`-shaped delta block (XLA sums over `[B*H,
     T, Dv]` beside the call). A token-major call is handed `Out`'s block in
     its place and sums here, a column, over the group's lanes of `do`, which
@@ -654,7 +888,7 @@ def _delta(hd, delta_ref, do):
     as the copies were (and its fusion took XLA longer to compile than the
     kernel takes Mosaic)."""
     if hd.whole:
-        return delta_ref[hd.row]
+        return delta_ref[_sub(hd.row, rows)]
     return jnp.sum(do.astype(jnp.float32)
                    * delta_ref[hd.blk].astype(jnp.float32),
                    axis=1, keepdims=True)
@@ -665,32 +899,42 @@ def _col(x):
     return x[:, None] if x.ndim == 1 else x
 
 
-def _score_tile(q_ref, k_ref, hd, qi, kj, sm_scale, masked, window=None,
-                kept_ref=None):
-    """One float32 [blk_q, blk_k] tile of q k^T * sm_scale, the causal mask
-    applied in-register where the tile is `masked` (a causal call's edge
-    tiles, `_on_live_tile`; every tile of a one-pass call), the kept set's
-    on every tile. The dots run in the INPUT dtype (bf16 under AMP ->
-    full MXU rate; the round-3 kernels upcast to f32 first, quartering
-    matmul throughput) with f32 accumulation via preferred_element_type;
-    sm_scale is applied to the f32 product so no operand precision is
-    spent on it."""
-    s = lax.dot_general(_own(hd, q_ref[hd.blk]), k_ref[hd.blk],
+def _score_tile(q_ref, k_ref, hd, sm_scale, piece, kept_ref=None):
+    """The float32 scores q k^T * sm_scale of one `piece` of a tile (the
+    whole `[blk_q, blk_k]` of it, or a strip's rows by the keys they see),
+    the causal mask applied in-register where the piece has one (a causal
+    call's edge tiles, `_on_live_tile`; every tile of a one-pass call), the
+    kept set's on every piece. The dots run in the INPUT dtype (bf16 under
+    AMP -> full MXU rate; the round-3 kernels upcast to f32 first,
+    quartering matmul throughput) with f32 accumulation via
+    preferred_element_type; sm_scale is applied to the f32 product so no
+    operand precision is spent on it."""
+    s = lax.dot_general(_own(hd, q_ref[_sub(hd.blk, piece.rows)]),
+                        k_ref[_sub(hd.blk, piece.keys)],
                         (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32) * sm_scale
-    if masked:
-        s = _apply_causal_mask(s, qi, kj, q_ref.shape[1], k_ref.shape[1],
-                               window)
-    return _apply_kept(s, kept_ref)
+    return _masked(s, piece, kept_ref)
 
 
-def _weights_times_v(p, v_ref, hd, seed_ref, qi, kj, dropout_rate):
-    """dropout(p) v for one tile, float32 [blk_q, Dv] (the group's lanes:
-    the caller keeps the head's)."""
+def _tile_dropout(seed_ref, hd, qi, kj, tile, dropout_rate):
+    """`keep(piece)`: the dropout keep-mask of one piece of the (qi, kj)
+    tile, its part of the one mask the `tile` (blk_q, blk_k) draws
+    (`_dropout_mask`) whichever pieces it runs in: drawn where the first
+    piece asks for it, once."""
+    draw = functools.cache(lambda: _dropout_mask(
+        seed_ref, hd.bh, qi, kj, tile, dropout_rate))
+
+    def keep(piece):
+        return draw() if piece.whole else draw()[piece.rows, piece.keys]
+    return keep
+
+
+def _weights_times_v(p, v_ref, hd, dropout_rate, piece, keep):
+    """dropout(p) v for one piece of a tile, float32 [its rows, Dv] (the
+    group's lanes: the caller keeps the head's); `keep`: `_tile_dropout`."""
     if dropout_rate:
-        keep = _dropout_mask(seed_ref, hd.bh, qi, kj, p.shape, dropout_rate)
-        p = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
-    v = v_ref[hd.blk]
+        p = jnp.where(keep(piece), p / (1.0 - dropout_rate), 0.0)
+    v = v_ref[_sub(hd.blk, piece.keys)]
     return lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                            preferred_element_type=jnp.float32)
 
@@ -708,14 +952,17 @@ def _flash_fwd_onepass_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     from jax.experimental import pallas as pl
 
     first, qi = _grid_ids(heads, tile_axes=1)
+    s_shape = (q_ref.shape[1], k_ref.shape[1])
 
     def head(hd):
-        s = _score_tile(q_ref, k_ref, hd, qi, 0, sm_scale, causal, window,
-                        kept_ref)
+        (tile,) = _whole(causal, qi, 0, *s_shape, window)
+        s = _score_tile(q_ref, k_ref, hd, sm_scale, tile, kept_ref)
         m = jnp.max(s, axis=1, keepdims=True)              # [blk_q, 1]
         p = jnp.exp(s - m)
         l = jnp.maximum(jnp.sum(p, axis=1, keepdims=True), 1e-20)
-        acc = _weights_times_v(p, v_ref, hd, seed_ref, qi, 0, dropout_rate)
+        acc = _weights_times_v(p, v_ref, hd, dropout_rate, tile,
+                               _tile_dropout(seed_ref, hd, qi, 0, s_shape,
+                                             dropout_rate))
         _put(hd, o_ref, (acc / l).astype(o_ref.dtype))
         lse_ref[hd.row] = (m + jnp.log(l))[:, 0]
 
@@ -784,26 +1031,30 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    def _update(masked):
+    def _update(pieces):
         def head(hd):
-            s = _score_tile(q_ref, k_ref, hd, qi, kj, sm_scale, masked,
-                            window, kept_ref)
-            m = m_sc[hd.stat]
-            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - _lanes(m_new, blk_k))
-            alpha = jnp.exp(m - m_new)
-            l_sc[hd.stat] = l_sc[hd.stat] * alpha \
-                + jnp.sum(p, axis=1, keepdims=True)
-            _rmw(hd, acc_sc, hd.acc,
-                 lambda acc: acc * _lanes(alpha, Dv) + _weights_times_v(
-                     p, v_ref, hd, seed_ref, qi, kj, dropout_rate))
-            m_sc[hd.stat] = m_new
+            keep = _tile_dropout(seed_ref, hd, qi, kj, (blk_q, blk_k),
+                                 dropout_rate)
+            for pc in pieces:       # rows of the tile, independent
+                s = _score_tile(q_ref, k_ref, hd, sm_scale, pc, kept_ref)
+                stat = _sub(hd.stat, pc.rows)
+                m = m_sc[stat]
+                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - _lanes(m_new, s.shape[1]))
+                alpha = jnp.exp(m - m_new)
+                l_sc[stat] = l_sc[stat] * alpha \
+                    + jnp.sum(p, axis=1, keepdims=True)
+                _rmw(hd, acc_sc, _sub(hd.acc, pc.rows),
+                     lambda acc: acc * _lanes(alpha, Dv) + _weights_times_v(
+                         p, v_ref, hd, dropout_rate, pc, keep))
+                m_sc[stat] = m_new
 
         _each_head(heads, first, head)
 
     # causal: blocks entirely above the diagonal contribute nothing
     _on_live_tile(_update, causal, qi, kj, blk_q, blk_k, window,
-                  _interior_apart(window, kept_ref))
+                  _interior_apart(window, kept_ref),
+                  strips=None if heads else "rows")
 
     @pl.when(step == nk - 1)
     def _finalize():
@@ -818,9 +1069,12 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                      delta_ref, dq_ref, dq_sc, *, sm_scale, causal,
-                     dropout_rate, window=None, heads=None, kept_ref=None):
+                     dropout_rate, window=None, heads=None, kept_ref=None,
+                     several=True):
     """dQ with K/V streamed through the innermost grid dim (see
-    _flash_fwd_kernel); the dQ accumulator lives in VMEM scratch."""
+    _flash_fwd_kernel); the dQ accumulator lives in VMEM scratch. `several`:
+    a row has several K blocks (one: every tile whole, as in the fused
+    kernel)."""
     from jax.experimental import pallas as pl
 
     first, qi, step, inner, _ = _grid_ids(heads)
@@ -833,37 +1087,39 @@ def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     def _init():
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
-    def _update(masked):
+    def _update(pieces):
         def head(hd):
-            q = _own(hd, q_ref[hd.blk])
-            do = _own(hd, do_ref[hd.blk])                  # [blk_q, D]
-            lse = lse_ref[hd.row]                          # [blk_q]
-            delta = _delta(hd, delta_ref, do)
-            k = k_ref[hd.blk]
-            v = v_ref[hd.blk]
-            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-            if masked:
-                s = _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
-            s = _apply_kept(s, kept_ref)
-            w = jnp.exp(s - lse[:, None])                  # normalized weights
-            dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-            if dropout_rate:
-                keep = _dropout_mask(seed_ref, hd.bh, qi, kj, (blk_q, blk_k),
-                                     dropout_rate)
-                dw = jnp.where(keep, dpv / (1.0 - dropout_rate), 0.0)
-            else:
-                dw = dpv
-            ds = w * (dw - _col(delta)) * sm_scale
-            _rmw(hd, dq_sc, hd.acc, lambda dq: dq + lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
+            keep = _tile_dropout(seed_ref, hd, qi, kj, (blk_q, blk_k),
+                                 dropout_rate)
+            for pc in pieces:       # rows of the tile, independent
+                q = _own(hd, q_ref[_sub(hd.blk, pc.rows)])
+                do = _own(hd, do_ref[_sub(hd.blk, pc.rows)])   # [blk_q, D]
+                lse = lse_ref[_sub(hd.row, pc.rows)]           # [blk_q]
+                delta = _delta(hd, delta_ref, do, pc.rows)
+                k = k_ref[_sub(hd.blk, pc.keys)]
+                v = v_ref[_sub(hd.blk, pc.keys)]
+                s = lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * sm_scale
+                s = _masked(s, pc, kept_ref)
+                w = jnp.exp(s - lse[:, None])              # normalized weights
+                dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+                if dropout_rate:
+                    dw = jnp.where(keep(pc), dpv / (1.0 - dropout_rate), 0.0)
+                else:
+                    dw = dpv
+                ds = w * (dw - _col(delta)) * sm_scale
+                _rmw(hd, dq_sc, _sub(hd.acc, pc.rows),
+                     lambda dq: dq + lax.dot_general(
+                         ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                         preferred_element_type=jnp.float32))
 
         _each_head(heads, first, head)
 
     _on_live_tile(_update, causal, qi, kj, blk_q, blk_k, window,
-                  _interior_apart(window, kept_ref))
+                  _interior_apart(window, kept_ref),
+                  strips="rows" if several and not heads else None)
 
     @pl.when(step == nk - 1)
     def _finalize():
@@ -873,11 +1129,12 @@ def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _flash_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                       delta_ref, dk_ref, dv_ref, dk_sc, dv_sc, *,
                       sm_scale, causal, dropout_rate, window=None,
-                      q_tiles=None, heads=None, kept_ref=None):
+                      q_tiles=None, heads=None, kept_ref=None, several=True):
     """dK/dV with Q/dOut/lse/delta streamed through the innermost grid
     dim (grid = (BH, kj, qi)); accumulators in VMEM scratch. Under a
     `window` the inner axis counts the Q tiles of the k-block's band
-    (`_band_qi`; `q_tiles` is the row's whole count)."""
+    (`_band_qi`; `q_tiles` is the row's whole count). `several`: as in
+    `_flash_dq_kernel`."""
     from jax.experimental import pallas as pl
 
     first, kj, step, inner, _ = _grid_ids(heads)
@@ -892,42 +1149,46 @@ def _flash_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
 
-    def _update(masked):
+    def _update(pieces):
         def head(hd):
-            k = k_ref[hd.blk]                              # [blk_k, D]
-            v = v_ref[hd.blk]
-            q = _own(hd, q_ref[hd.blk])
-            do = _own(hd, do_ref[hd.blk])
-            lse = lse_ref[hd.row]
-            delta = _delta(hd, delta_ref, do)
-            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-            if masked:
-                s = _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
-            s = _apply_kept(s, kept_ref)
-            w = jnp.exp(s - lse[:, None])                  # [blk_q, blk_k]
-            dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-            if dropout_rate:
-                keep = _dropout_mask(seed_ref, hd.bh, qi, kj, (blk_q, blk_k),
-                                     dropout_rate)
-                w_drop = jnp.where(keep, w / (1.0 - dropout_rate), 0.0)
-                dw = jnp.where(keep, dpv / (1.0 - dropout_rate), 0.0)
-            else:
-                w_drop, dw = w, dpv
-            _rmw(hd, dv_sc, hd.acc, lambda dv: dv + lax.dot_general(
-                w_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
-            ds = w * (dw - _col(delta)) * sm_scale
-            _rmw(hd, dk_sc, hd.acc, lambda dk: dk + lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
+            keep = _tile_dropout(seed_ref, hd, qi, kj, (blk_q, blk_k),
+                                 dropout_rate)
+            for pc in pieces:       # keys of the tile, the rows on them
+                k = k_ref[_sub(hd.blk, pc.keys)]           # [blk_k, D]
+                v = v_ref[_sub(hd.blk, pc.keys)]
+                q = _own(hd, q_ref[_sub(hd.blk, pc.rows)])
+                do = _own(hd, do_ref[_sub(hd.blk, pc.rows)])
+                lse = lse_ref[_sub(hd.row, pc.rows)]
+                delta = _delta(hd, delta_ref, do, pc.rows)
+                s = lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * sm_scale
+                s = _masked(s, pc, kept_ref)
+                w = jnp.exp(s - lse[:, None])              # [blk_q, blk_k]
+                dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+                if dropout_rate:
+                    on = keep(pc)
+                    w_drop = jnp.where(on, w / (1.0 - dropout_rate), 0.0)
+                    dw = jnp.where(on, dpv / (1.0 - dropout_rate), 0.0)
+                else:
+                    w_drop, dw = w, dpv
+                _rmw(hd, dv_sc, _sub(hd.acc, pc.keys),
+                     lambda dv: dv + lax.dot_general(
+                         w_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                         preferred_element_type=jnp.float32))
+                ds = w * (dw - _col(delta)) * sm_scale
+                _rmw(hd, dk_sc, _sub(hd.acc, pc.keys),
+                     lambda dk: dk + lax.dot_general(
+                         ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                         preferred_element_type=jnp.float32))
 
         _each_head(heads, first, head)
 
     # causal: q blocks strictly above this k block see none of it
     _on_live_tile(_update, causal, qi, kj, blk_q, blk_k, window,
-                  _interior_apart(window, kept_ref))
+                  _interior_apart(window, kept_ref),
+                  strips="keys" if several and not heads else None)
 
     @pl.when(step == nq - 1)
     def _finalize():
@@ -969,49 +1230,65 @@ def _flash_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         def _init_row():
             dq_sc[...] = jnp.zeros_like(dq_sc)
 
-    def _update(masked):
+    def _update(pieces):
         def head(hd):
-            k = k_ref[hd.blk]                              # [blk_k, D]
-            v = v_ref[hd.blk]
-            q = _own(hd, q_ref[hd.blk])
-            do = _own(hd, do_ref[hd.blk])
-            lse = lse_ref[hd.row]
-            delta = _delta(hd, delta_ref, do)
-            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-            if masked:
-                s = _apply_causal_mask(s, qi, kj, blk_q, blk_k, window)
-            s = _apply_kept(s, kept_ref)
-            w = jnp.exp(s - lse[:, None])                  # [blk_q, blk_k]
-            dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-            if dropout_rate:
-                keep = _dropout_mask(seed_ref, hd.bh, qi, kj, (blk_q, blk_k),
-                                     dropout_rate)
-                w_drop = jnp.where(keep, w / (1.0 - dropout_rate), 0.0)
-                dw = jnp.where(keep, dpv / (1.0 - dropout_rate), 0.0)
-            else:
-                w_drop, dw = w, dpv
-            _rmw(hd, dv_sc, hd.acc, lambda dv: dv + lax.dot_general(
-                w_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
-            ds = (w * (dw - _col(delta)) * sm_scale).astype(q.dtype)
-            _rmw(hd, dk_sc, hd.acc, lambda dk: dk + lax.dot_general(
-                ds, q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
-            dq = lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-            if dq_sc is None:
-                _put(hd, dq_ref, dq.astype(dq_ref.dtype))
-            else:
-                rows = pl.ds(pl.multiple_of(qi * blk_q, blk_q), blk_q)
-                _rmw(hd, dq_sc, (rows, hd.lanes), lambda acc: acc + dq)
+            keep = _tile_dropout(seed_ref, hd, qi, kj, (blk_q, blk_k),
+                                 dropout_rate)
+            shares = []
+            for pc in pieces:       # keys of the tile, the rows on them
+                k = k_ref[_sub(hd.blk, pc.keys)]           # [blk_k, D]
+                v = v_ref[_sub(hd.blk, pc.keys)]
+                q = _own(hd, q_ref[_sub(hd.blk, pc.rows)])
+                do = _own(hd, do_ref[_sub(hd.blk, pc.rows)])
+                lse = lse_ref[_sub(hd.row, pc.rows)]
+                delta = _delta(hd, delta_ref, do, pc.rows)
+                s = lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * sm_scale
+                s = _masked(s, pc, kept_ref)
+                w = jnp.exp(s - lse[:, None])              # [blk_q, blk_k]
+                dpv = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+                if dropout_rate:
+                    on = keep(pc)
+                    w_drop = jnp.where(on, w / (1.0 - dropout_rate), 0.0)
+                    dw = jnp.where(on, dpv / (1.0 - dropout_rate), 0.0)
+                else:
+                    w_drop, dw = w, dpv
+                _rmw(hd, dv_sc, _sub(hd.acc, pc.keys),
+                     lambda dv: dv + lax.dot_general(
+                         w_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                         preferred_element_type=jnp.float32))
+                ds = (w * (dw - _col(delta)) * sm_scale).astype(q.dtype)
+                _rmw(hd, dk_sc, _sub(hd.acc, pc.keys),
+                     lambda dk: dk + lax.dot_general(
+                         ds, q, (((0,), (0,)), ((), ())),
+                         preferred_element_type=jnp.float32))
+                shares.append(ds)
+            # dQ: a row's shares of the tile's key strips, gathered in
+            # ascending key order into one product's contraction, which is
+            # the whole tile's product without its zero terms
+            for rows, ds, keys in _row_shares(pieces, shares):
+                if keys != _ALL:
+                    k = k_ref[_sub(hd.blk, keys)]
+                dq = lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+                if dq_sc is None:
+                    _put(hd, dq_ref, dq.astype(dq_ref.dtype))
+                    continue
+                first_row, n = (0, blk_q) if rows == _ALL else \
+                    (rows.start, rows.stop - rows.start)
+                at = qi * blk_q + first_row if first_row else qi * blk_q
+                _rmw(hd, dq_sc, (pl.ds(pl.multiple_of(at, n), n), hd.lanes),
+                     lambda acc: acc + dq)
 
         _each_head(heads, first, head)
 
     # with one K block a row (kj == 0) every tile is live, and none interior
+    several = dq_sc is not None
     _on_live_tile(_update, causal, qi, kj, blk_q, blk_k, window,
-                  dq_sc is not None and _interior_apart(window, kept_ref))
+                  several and _interior_apart(window, kept_ref),
+                  strips="keys" if several and not heads else None)
 
     @pl.when(step == nq - 1)
     def _finalize():
@@ -1202,14 +1479,25 @@ class _Plan(NamedTuple):
     kernels are built: the tiles, the kernel ("onepass" | "stream" of the
     forward, "fused" | "split" of the backward), how a token-major call's
     blocks hold heads, the scoped VMEM it asks for, whether the kernels are
-    interpreted. Hashable: a token-major call's kernels are traced and
-    lowered once for each plan and each set of operand shapes and attributes
-    (`_token_major_forward`, `_token_major_backward`), not once a call."""
+    interpreted, and `built_with`, the module's predicates and rules a
+    kernel's body is traced through, as they stand (`_plan`). Hashable: a
+    call's kernels are traced and lowered once for each plan and each set of
+    operand shapes and attributes (`_jitted_forward`, `_jitted_backward`),
+    not once a call; a test or a probe that stands one of those functions in
+    for another gets kernels built anew."""
     tiles: tuple
     kernel: str
     heads: object = None
     vmem: object = None
     interpret: bool = False
+    built_with: tuple = ()
+
+
+def _plan(tiles, kernel, heads=None, vmem=None):
+    return _Plan(tiles, kernel, heads, vmem, _kernels.interpret(), (
+        _STRIP_TILE, _strip_side, _on_edge, _causal_live, _causal_interior,
+        _interior_apart, _apply_causal_mask, _apply_kept, _dropout_mask,
+        _dead_steps, _kept_spec, _grid_ids))
 
 
 def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0,
@@ -1222,23 +1510,23 @@ def _flash_forward(q, k, v, causal, sm_scale, dropout_rate=0.0, seed=0,
         _check_kept(kept, q, causal, window, token_major)
         vmem = _fwd_vmem(D, 1, BQ, BK, q.dtype.itemsize, kernel == "stream") \
             + _kept_vmem(BQ, BK)
-        return _forward(q, k, v, seed, causal, sm_scale, dropout_rate, window,
-                        _Plan((BQ, BK), kernel, interpret=_kernels.interpret(),
-                              vmem=max(vmem, _SCOPED_VMEM_FLOOR_BYTES)),
-                        kept)
+        return _jitted_forward(
+            q, k, v, jnp.asarray(seed, jnp.int32), causal, sm_scale,
+            dropout_rate, window, _plan(
+                (BQ, BK), kernel, vmem=max(vmem, _SCOPED_VMEM_FLOOR_BYTES)),
+            kept)
     if not token_major:
-        return _forward(q, k, v, seed, causal, sm_scale, dropout_rate, window,
-                        _Plan((BQ, BK), kernel,
-                              interpret=_kernels.interpret()))
+        return _jitted_forward(
+            q, k, v, jnp.asarray(seed, jnp.int32), causal, sm_scale,
+            dropout_rate, window, _plan((BQ, BK), kernel))
 
     def need(lanes):
         return _fwd_vmem(lanes, lanes // D, BQ, BK, q.dtype.itemsize,
                          kernel == "stream")
     heads, vmem = _token_major_heads(H, D, need)
-    return _token_major_forward(
+    return _jitted_forward(
         q, k, v, jnp.asarray(seed, jnp.int32), causal, sm_scale,
-        dropout_rate, window, _Plan((BQ, BK), kernel, heads, vmem,
-                                    _kernels.interpret()))
+        dropout_rate, window, _plan((BQ, BK), kernel, heads, vmem))
 
 
 def _forward(q, k, v, seed, causal, sm_scale, dropout_rate, window, plan,
@@ -1319,12 +1607,16 @@ def _forward(q, k, v, seed, causal, sm_scale, dropout_rate, window, plan,
     return out.reshape(v.shape), lse
 
 
-# A token-major call under a `jax.jit` of its own, the plan and the
-# attributes static: the 18 attention blocks of a transformer step hold two
-# forward and two backward kernels between them (causal or not), and jax
-# traces and lowers a jitted function once for each signature. The head-major
-# calls stay inline, the lowered text they always were.
-_token_major_forward = jax.jit(_forward, static_argnums=(4, 5, 6, 7, 8))
+# A call under a `jax.jit` of its own, the plan and the attributes static:
+# the 18 attention blocks of a transformer step hold two forward and two
+# backward kernels between them (causal or not), Ouro's sixteen one of each,
+# and jax traces and lowers a jitted function once for each signature.
+# Token-major calls since PR 46. Head-major ones since PR 72, whose strip
+# bodies made a kernel's trace and lowering 2.6 times what it was: inline,
+# the sixteen call sites of `ouro_2_6b.bs1` read `first_step_trace_s.train`
+# 5.58 -> 15.36 s and `first_step_lower_s.train` 5.27 -> 7.56 (chip run, PR
+# 72, call 3). XLA inlines the call: the compiled step is the one it was.
+_jitted_forward = jax.jit(_forward, static_argnums=(4, 5, 6, 7, 8))
 
 
 def _flash_backward(q, k, v, o, lse, g, causal, sm_scale, dropout_rate, seed,
@@ -1338,15 +1630,16 @@ def _flash_backward(q, k, v, o, lse, g, causal, sm_scale, dropout_rate, seed,
         _check_kept(kept, q, causal, window, token_major)
         vmem = _fused_bwd_vmem(T if T != BK else BQ, D, Dv, BQ, BK, itemsize) \
             + _kept_vmem(BQ, BK)
-        return _backward(q, k, v, o, lse, g, seed, causal, sm_scale,
-                         dropout_rate, window, _Plan(
-                             (BQ, BK), _bwd_plan(T, D, Dv, BQ, BK, itemsize),
-                             vmem=vmem, interpret=_kernels.interpret()), kept)
+        return _jitted_backward(
+            q, k, v, o, lse, g, jnp.asarray(seed, jnp.int32), causal,
+            sm_scale, dropout_rate, window, _plan(
+                (BQ, BK), _bwd_plan(T, D, Dv, BQ, BK, itemsize), vmem=vmem),
+            kept)
     if not token_major:
-        return _backward(q, k, v, o, lse, g, seed, causal, sm_scale,
-                         dropout_rate, window, _Plan(
-                             (BQ, BK), _bwd_plan(T, D, Dv, BQ, BK, itemsize),
-                             interpret=_kernels.interpret()))
+        return _jitted_backward(
+            q, k, v, o, lse, g, jnp.asarray(seed, jnp.int32), causal,
+            sm_scale, dropout_rate, window, _plan(
+                (BQ, BK), _bwd_plan(T, D, Dv, BQ, BK, itemsize)))
 
     # a row of one K block keeps a q-block's dQ, not the row's; `Out`'s
     # block comes in beside dOut's (`_delta`)
@@ -1355,11 +1648,11 @@ def _flash_backward(q, k, v, o, lse, g, causal, sm_scale, dropout_rate, seed,
                                itemsize) + 2 * itemsize * BQ * lanes
     heads, vmem = _token_major_heads(H, D, need)
     lanes = heads.step * D
-    return _token_major_backward(
+    return _jitted_backward(
         q, k, v, o, lse, g, jnp.asarray(seed, jnp.int32), causal, sm_scale,
-        dropout_rate, window, _Plan(
+        dropout_rate, window, _plan(
             (BQ, BK), _bwd_plan(T, lanes, lanes, BQ, BK, itemsize), heads,
-            vmem, _kernels.interpret()))
+            vmem))
 
 
 def _backward(q, k, v, o, lse, g, seed, causal, sm_scale, dropout_rate,
@@ -1385,7 +1678,7 @@ def _backward(q, k, v, o, lse, g, seed, causal, sm_scale, dropout_rate,
     return tuple(d.reshape(x.shape) for d, x in zip(grads, (q, k, v)))
 
 
-_token_major_backward = jax.jit(_backward, static_argnums=(7, 8, 9, 10, 11))
+_jitted_backward = jax.jit(_backward, static_argnums=(7, 8, 9, 10, 11))
 
 
 def _bwd_specs(rows, T, BQ, BK, lanes, lanes_v, q_axis, causal, window):
@@ -1524,6 +1817,8 @@ def _flash_bwd_split(args, rows, plan, attrs):
     kept = _kept_of(args)
 
     def kernel_of(body, **more):
+        if T == BK:
+            more["several"] = False
         body = functools.partial(body, **attrs, **more)
         return body if kept is None else _takes_kept(body, 7)
 
@@ -1760,7 +2055,11 @@ def _fused_attention(ctx, Q, K, V, Kept=None):
     kept set does, those of them that run without the causal mask
     (`flash_tiles_unmasked`); a causal op without a window tallies the steps
     of its forward grid above the diagonal, for which nothing is fetched
-    (`flash_dead_steps_held`). `Kept` (optional): int8
+    (`flash_dead_steps_held`); a causal op tallies the edge tiles its forward
+    grid runs in strips and the sub-blocks of them it skips
+    (`flash_edge_tiles_stripped`, `flash_subblocks_skipped`: 0 where a row
+    is one K block, of token-major operands, off the alignment).
+    `Kept` (optional): int8
     [B, T, T], the keys each query keeps of those below the diagonal, one
     set for all heads; no gradient, no dropout, not with a window; the
     `dsa_` kernels read its tiles beside the score tiles, and the op tallies
@@ -1818,6 +2117,10 @@ def _fused_attention(ctx, Q, K, V, Kept=None):
             and T % _LANES == 0:
         ctx.tally("flash_dead_steps_held",
                   B * H * _dead_steps(T, *_blk(T, True)))
+    if forward_op and causal:
+        tiles, skipped = (0, 0) if token_major else edge_strips(T, window)
+        ctx.tally("flash_edge_tiles_stripped", B * H * tiles)
+        ctx.tally("flash_subblocks_skipped", B * H * skipped)
     if Kept is not None:
         if rate:
             raise NotImplementedError(

@@ -155,9 +155,9 @@ def flash_calls(monkeypatch, T, tiles, causal=True, token_major=False,
         patch.setattr(pl, "pallas_call", pallas_call)
         patch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
         # a jitted call keeps its trace: the stand-in has to be called
-        patch.setattr(pallas_attention, "_token_major_forward",
+        patch.setattr(pallas_attention, "_jitted_forward",
                       pallas_attention._forward)
-        patch.setattr(pallas_attention, "_token_major_backward",
+        patch.setattr(pallas_attention, "_jitted_backward",
                       pallas_attention._backward)
         patch.setattr(pallas_attention, "_bwd_plan",
                       lambda *a: "split" if split else "fused")

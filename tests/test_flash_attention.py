@@ -765,10 +765,10 @@ def test_window_with_dropout_takes_the_causal_paths_fallback():
 
 
 @pytest.mark.parametrize("T,tiles,window,band,causal", [
-    (8192, None, 1024, 45, 136),        # the cell: 512 x 512 tiles
-    (8192, (1024, 1024), 1024, 15, 36),
-    (4096, None, 2048, 30, 36),         # Trinity-Mini's: 512 x 512 tiles
-    (4096, (1024, 1024), 2048, 9, 10),
+    (8192, (512, 512), 1024, 45, 136),  # the cell before PR 72
+    (8192, None, 1024, 15, 36),         # the cell: 1024 x 1024 tiles
+    (4096, (512, 512), 2048, 30, 36),   # Trinity-Mini's before PR 72
+    (4096, None, 2048, 9, 10),          # Trinity-Mini's: 1024 x 1024 tiles
     (512, (128, 128), 128, 7, 10),
     (512, (128, 128), 129, 7, 10),
     (512, (128, 128), 130, 9, 10),
@@ -933,9 +933,9 @@ def test_held_dead_steps_give_the_bits_of_blocks_fetched_there(
     monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
     monkeypatch.setattr(pallas_attention, "_bwd_plan", lambda *a: plan)
     # a jitted call keeps the trace of the first form
-    monkeypatch.setattr(pallas_attention, "_token_major_forward",
+    monkeypatch.setattr(pallas_attention, "_jitted_forward",
                         pallas_attention._forward)
-    monkeypatch.setattr(pallas_attention, "_token_major_backward",
+    monkeypatch.setattr(pallas_attention, "_jitted_backward",
                         pallas_attention._backward)
     assert pallas_attention._dead_steps(T, *tiles) > 0
     q, k, v = _qkv(rng, B, H, T, D, Dv)
@@ -1024,22 +1024,27 @@ W2048 = dict(B=1, H=1, T=4096, D=32, window=2048)
 
 
 def test_the_tile_rule_at_a_window_of_2048():
-    """Square tiles of half the window and no more than 512 (PR 49 measured
-    1024 x 1024, what half the window gave, a twentieth slower): 512 x 512
-    at 4096 tokens, where a row of keys is eight K blocks (the streaming
-    forward; the fused backward keeps the row's dQ resident)."""
+    """A window of whole tiles of 1024 takes them, its edge tiles in strips
+    (PR 72: with every tile whole 1024 x 1024 read a twentieth slower than
+    512 x 512, PR 49; in strips a tenth faster): 1024 x 1024 at 4096
+    tokens, where a row of keys is four K blocks (the streaming forward; the
+    fused backward keeps the row's dQ resident). Any other window: square
+    tiles of half the window and no more than 512."""
     T, window = W2048["T"], W2048["window"]
-    assert pallas_attention._blk(T, True, window) == (512, 512)
-    assert pallas_attention._blk(T, True, 1024) == (512, 512)
-    assert pallas_attention._blk(16384, True, 4096) == (512, 512)
+    assert pallas_attention._blk(T, True, window) == (1024, 1024)
+    assert pallas_attention._blk(T, True, 1024) == (1024, 1024)
+    assert pallas_attention._blk(16384, True, 4096) == (1024, 1024)
+    assert pallas_attention._blk(T, True, 1536) == (512, 512)
+    assert pallas_attention._blk(T, True, 2000) == (512, 512)
     assert pallas_attention._blk(T, True, 600) == (256, 256)
     assert pallas_attention._blk(T, True) == (1024, 1024)
-    assert pallas_attention._fwd_plan(T, 512) == "stream"
+    assert pallas_attention._fwd_plan(T, 1024) == "stream"
+    assert pallas_attention._band_steps(T, 1024, 1024, window) == (3, 3)
     assert pallas_attention._band_steps(T, 512, 512, window) == (5, 5)
 
 
-@pytest.mark.parametrize("tiles", [None, (1024, 1024)],
-                         ids=["rule", "1024x1024"])
+@pytest.mark.parametrize("tiles", [None, (512, 512)],
+                         ids=["rule", "512x512"])
 def test_windowed_forward_at_2048_is_the_masked_softmax(interpret_kernels,
                                                         monkeypatch, tiles):
     rng = np.random.RandomState(17)
@@ -1362,12 +1367,14 @@ def test_interior_is_a_tile_whose_mask_hides_nothing(monkeypatch, tiles,
 @pytest.mark.parametrize("T,window,tiles,interior", [
     (8192, None, (1024, 1024), 28),     # Keye's and Mellum2's full layer: of 36
     (4096, None, (1024, 1024), 6),      # Ouro, Kanana-2, OLMoE, ...: of 10
-    (8192, 1024, (512, 512), 15),       # Mellum2's windowed layers: of 45
-    (4096, 2048, (512, 512), 18),       # Trinity-Mini's: of 30
+    (8192, 1024, (1024, 1024), 0),      # Mellum2's windowed layers: of 15
+    (4096, 2048, (1024, 1024), 3),      # Trinity-Mini's: of 9
+    (8192, 1536, (512, 512), 29),       # a window off the tiles of 1024: of 58
     (2048, None, (256, 2048), 0),       # seq 2048: one K block a row
     (256, None, (256, 256), 0),
     (200, None, None, 0)],              # the reference path's
-    ids=["8192", "4096", "8192_w1024", "4096_w2048", "2048", "256", "200"])
+    ids=["8192", "4096", "8192_w1024", "4096_w2048", "8192_w1536", "2048",
+         "256", "200"])
 def test_interior_tiles_at_the_cells_lengths(T, window, tiles, interior):
     if tiles:
         assert pallas_attention._blk(T, True, window) == tiles
@@ -1474,3 +1481,293 @@ def test_unmasked_interior_is_bitwise_the_mask_on_every_tile(
                           ("Out", "dQ", "dK", "dV")):
         np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
                                    atol=tol, rtol=tol, err_msg=name)
+
+
+# -- an aligned edge tile runs in strips ----------------------------------
+# The diagonal tile of a call of square tiles of 1024, and under a window of
+# whole tiles the band's lower-edge tile, are crossed by their edge from
+# corner to corner (`_strip_side`): the four kernels that stream tiles run
+# such a tile strip by strip over the extent the edge leaves live
+# (`_edge_strips`), and only the sub-block the edge crosses keeps a select.
+# The oracle is the same kernels with `_strip_side` answering "not aligned":
+# every edge tile whole under the mask.
+
+def _local_visible(lower, blk):
+    """Key c against row r of an aligned edge tile, local to it."""
+    r, c = np.arange(blk)[:, None], np.arange(blk)[None, :]
+    return c > r if lower else c <= r
+
+
+@pytest.mark.parametrize("blk,side", [(512, 128), (1024, 256), (256, 128),
+                                      (512, 256)])
+@pytest.mark.parametrize("by", ["rows", "keys"])
+@pytest.mark.parametrize("lower", [False, True], ids=["diagonal", "lower"])
+def test_strips_cover_the_pairs_an_edge_leaves_live(lower, by, blk, side):
+    """Against the tile's own mask: the pieces' rows x keys are disjoint,
+    hold every visible pair and only sub-blocks that hold one; a piece's
+    mask, on scores of zeros, is `NEG_INF` exactly on its pairs that are not
+    visible; `blk / side` strips, each its own rows (or keys)."""
+    visible = _local_visible(lower, blk)
+    pieces = pallas_attention._edge_strips(lower, by, blk, side)
+    assert len(pieces) == blk // side
+    covered = np.zeros((blk, blk), int)
+    for i, pc in enumerate(pieces):
+        own = pc.rows if by == "rows" else pc.keys
+        assert (own.start, own.stop) == (i * side, (i + 1) * side)
+        covered[pc.rows, pc.keys] += 1
+        part = visible[pc.rows, pc.keys]
+        for r0 in range(0, part.shape[0], side):
+            for c0 in range(0, part.shape[1], side):
+                assert part[r0:r0 + side, c0:c0 + side].any()
+        masked = np.asarray(pc.mask(jnp.zeros(part.shape, jnp.float32)))
+        np.testing.assert_array_equal(masked == pallas_attention.NEG_INF,
+                                      ~part)
+        np.testing.assert_array_equal(masked[part], 0.0)
+    assert covered.max() == 1 and (covered[visible] == 1).all()
+    n = blk // side
+    assert covered.sum() == (n * (n + 1) // 2) * side * side
+
+
+@pytest.mark.parametrize("window", [None, 512, 1000, 1024, 2048, 3072, 4096])
+@pytest.mark.parametrize("tiles", [(512, 512), (1024, 1024), (2048, 2048),
+                                   (1024, 2048), (1024, 512)],
+                         ids=lambda t: f"{t[0]}x{t[1]}")
+def test_edge_strips_counts_what_a_brute_force_count_finds(monkeypatch,
+                                                            tiles, window):
+    """`edge_strips` against the `[T, T]` mask itself on a small grid: where
+    the call is aligned (`_strip_side`), the tiles an edge crosses (live and
+    not interior) and, of their side x side sub-blocks, those without a
+    visible pair; `_on_edge` names exactly those tiles, on Python ints as on
+    traced int32, and every other live tile is interior. (0, 0) where the
+    tiles are not square, the window no whole number of tiles, a tile
+    under 1024."""
+    T, (bq, bk) = 8192, tiles
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
+    w = pallas_attention._window_of(window, T)
+    side = pallas_attention._strip_side(bq, bk, w)
+    aligned = bq == bk and bq >= 1024 and (w is None or w % bk == 0)
+    assert bool(side) == aligned
+    if not aligned:
+        assert pallas_attention.edge_strips(T, window) == (0, 0)
+        return
+    assert side == bq // 4
+    visible = _brute_visible(T, w)
+    edge_tiles = skipped = 0
+    for qi in range(T // bq):
+        for kj in range(T // bk):
+            tile = visible[qi * bq:(qi + 1) * bq, kj * bk:(kj + 1) * bk]
+            on_edge = tile.any() and not tile.all()
+            diagonal, lower = pallas_attention._on_edge(qi, kj, bq, bk, w)
+            assert bool(diagonal) + bool(lower) == on_edge, (qi, kj)
+            traced = pallas_attention._on_edge(jnp.int32(qi), jnp.int32(kj),
+                                               bq, bk, w)
+            assert [bool(x) for x in traced] == [bool(diagonal), bool(lower)]
+            if not on_edge:
+                continue
+            np.testing.assert_array_equal(
+                tile, _local_visible(bool(lower), bq), err_msg=f"{qi} {kj}")
+            edge_tiles += 1
+            skipped += sum(
+                not tile[r:r + side, c:c + side].any()
+                for r in range(0, bq, side) for c in range(0, bk, side))
+    assert pallas_attention.edge_strips(T, window) == (edge_tiles, skipped)
+
+
+@pytest.mark.parametrize("T,window,strips", [
+    (8192, 1024, (15, 90)),     # Mellum2's windowed layers: 8 + 7, all 15
+    (8192, None, (8, 48)),      # its full layer, Keye's call: of 36
+    (4096, 2048, (6, 36)),      # Trinity-Mini's windowed layers: 4 + 2 of 9
+    (4096, None, (4, 24)),      # Ouro, Kanana-2, OLMoE, ...: of 10
+    (8192, 1000, (0, 0)),       # a window off the tiles
+    (2048, None, (0, 0)),       # seq 2048: one K block a row
+    (256, None, (0, 0)),
+    (200, None, (0, 0))],       # the reference path's
+    ids=["8192_w1024", "8192", "4096_w2048", "4096", "8192_w1000", "2048",
+         "256", "200"])
+def test_edge_strips_at_the_cells_lengths(T, window, strips):
+    assert pallas_attention.edge_strips(T, window) == strips
+
+
+# name -> T, tiles (None: `_blk`'s), (D, Dv), window, kept set, dtype, whether
+# strips run
+STRIP_CASES = {
+    # four tiles of 1024 a row in strips of 256: plain causal (Ouro's
+    # pattern), latent attention's widths in bf16 (Kanana-2's)
+    "causal": (4096, None, (64, 64), None, None, jnp.float32, True),
+    "causal_D192_Dv128": (4096, None, (192, 128), None, None, jnp.bfloat16,
+                          True),
+    # a window of one tile (Mellum2's: a lower-edge tile beside the diagonal
+    # one, none interior) and of two (Trinity-Mini's: one interior between)
+    "window_1_tile": (2048, None, (64, 64), 1024, None, jnp.float32, True),
+    "window_2_tiles": (4096, None, (64, 64), 2048, None, jnp.float32, True),
+    # Keye's: the kept tile is cut with the scores
+    "kept_selected": (2048, (1024, 1024), (64, 64), None, "selected",
+                      jnp.float32, True),
+    # calls that are not aligned keep the body they had: the same bits
+    "window_off_the_tiles": (2048, None, (64, 64), 1000, None, jnp.float32,
+                             False),
+    "tiles_512x1024": (2048, (512, 1024), (64, 64), None, None, jnp.float32,
+                       False),
+    "tiles_1024x512_window": (2048, (1024, 512), (64, 64), 1024, None,
+                              jnp.float32, False),
+    "tiles_512": (2048, (512, 512), (64, 64), 1024, None, jnp.float32, False),
+}
+
+
+@pytest.mark.parametrize("plan", ["fused", "split"])
+@pytest.mark.parametrize("case", sorted(STRIP_CASES))
+def test_edge_tiles_in_strips_are_the_whole_masked_tiles(
+        interpret_kernels, monkeypatch, case, plan):
+    """`Out`, `Lse`, dQ, dK and dV of the streaming forward, the fused
+    backward and the split pair with aligned edge tiles in strips, against
+    the same kernels with every edge tile whole under the mask. `Lse` `==`:
+    a strip's row maximum and sum of weights run over the live keys in the
+    order the whole tile's do, and what they leave out is zeros at one end.
+    `Out`, dQ, dK and dV, each a product whose contraction a strip
+    shortens, to float32 rounding here and `==` on the chip
+    (tests/test_flash_grad_tpu.py): XLA:CPU's dot, which runs the
+    interpreted bodies, blocks a contraction of 256 otherwise than one of
+    1024 (the MXU adds a contraction's passes in order). A call that is not
+    aligned runs the body it had and every result is `==`. The scale is a
+    power of two (see
+    `test_unmasked_interior_is_bitwise_the_mask_on_every_tile`)."""
+    T, tiles, (D, Dv), window, kept, dtype, strips = STRIP_CASES[case]
+    rng = np.random.RandomState(72)
+    B, H, scale = 1, 1, 2.0 ** round(np.log2(D ** -0.5))
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
+    if plan == "split":
+        monkeypatch.setattr(pallas_attention, "_bwd_plan",
+                            lambda *a: "split")
+    bq, bk = pallas_attention._blk(T, True, window)
+    assert bool(pallas_attention._strip_side(bq, bk, window)) == strips
+    assert bool(pallas_attention.edge_strips(T, window)[0]) == strips
+    q, k, v = (x.astype(dtype) for x in _qkv(rng, B, H, T, D, Dv))
+    g = jnp.asarray(rng.randn(B, H, T, Dv), dtype)
+    if kept == "selected":
+        kept = _selected(rng, B, T, 600)
+
+    def run():
+        """A trace of its own for each form: jax keeps one a function."""
+        def both(q, k, v):
+            out, lse = pallas_attention._flash_forward(
+                q, k, v, True, scale, window=window, kept=kept)
+            return (out, lse) + tuple(pallas_attention._flash_backward(
+                q, k, v, out, lse, g, True, scale, 0.0, 0, window, kept=kept))
+        return str(jax.make_jaxpr(both)(q, k, v)).count("cond["), \
+            both(q, k, v)
+
+    conds, got = run()
+    monkeypatch.setattr(pallas_attention, "_strip_side", lambda *a: None)
+    conds_whole, want = run()
+    # in each kernel (the forward, and one or two backward) a body for each
+    # edge and, unless the window is one tile and the band two edge tiles,
+    # one for every other live tile, where the whole form has the
+    # masked body and, under a window or a kept set that leaves a tile
+    # interior, the one without the mask
+    apart = (window is not None or kept is not None) and any(
+        pallas_attention._causal_interior(qi, kj, bq, bk, window)
+        for qi in range(T // bq) for kj in range(T // bk))
+    edges = 1 + (window is not None)
+    rest = window is None or window > bk     # a tile that is no edge tile
+    assert conds - conds_whole == strips * (edges + rest - (1 + apart)) * (
+        2 if plan == "fused" else 3)
+    for a, b, name in zip(got, want, ("Out", "Lse", "dQ", "dK", "dV")):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a).max() > 0, name
+        if strips and name != "Lse":
+            tol = 2e-6 if dtype == jnp.float32 else 2 ** -7
+            np.testing.assert_allclose(a, b, rtol=tol,
+                                       atol=tol * np.abs(b).max(),
+                                       err_msg=name)
+        else:
+            np.testing.assert_array_equal(a, b, err_msg=name)
+    mask = None if kept is None else kept * jnp.asarray(
+        _brute_visible(T, None), jnp.int8)[None]
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    ref, vjp = jax.vjp(lambda *a: _attention_reference(
+        *a, True, scale, window=window, kept=mask), *f32)
+    tol = 1e-4 if dtype == jnp.float32 else 6e-2
+    for a, b, name in zip((got[0],) + got[2:],
+                          (ref,) + vjp(g.astype(jnp.float32)),
+                          ("Out", "dQ", "dK", "dV")):
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
+                                   atol=tol, rtol=tol, err_msg=name)
+
+
+@pytest.mark.parametrize("plan", ["fused", "split"])
+@pytest.mark.parametrize("T,window", [(2048, 1024), (4096, 2048)],
+                         ids=["w1024", "w2048"])
+def test_strips_hold_the_window_to_the_key(interpret_kernels, monkeypatch,
+                                           T, window, plan):
+    """No score that was computed and unmasked is skipped, none that was
+    masked is let in: on scores of std 6, where one key more or less moves a
+    row's softmax, `Out` and the gradients of a call whose edge tiles run in
+    strips are the masked softmax's at the window it was given, and are
+    refused, by the same limits, by the softmax of a window one key longer
+    and of one a key shorter."""
+    rng = np.random.RandomState(73)
+    B, H, D = 1, 1, 64
+    if plan == "split":
+        monkeypatch.setattr(pallas_attention, "_bwd_plan",
+                            lambda *a: "split")
+    assert pallas_attention._blk(T, True, window) == (1024, 1024)
+    assert pallas_attention.edge_strips(T, window)[0] == T // 1024 + (
+        T - window) // 1024
+    q, k, v = _qkv(rng, B, H, T, D, D)
+    q = q * 6.0
+    g = jnp.asarray(rng.randn(B, H, T, D), jnp.float32)
+    scale = D ** -0.5
+    out, vjp = jax.vjp(lambda *a: flash_attention(
+        *a, jnp.int32(0), True, scale, 0.0, window), q, k, v)
+    got = (out,) + vjp(g)
+
+    def softmax_of(w):
+        ref, ref_vjp = jax.vjp(lambda *a: _masked_softmax(*a, w, scale),
+                               q, k, v)
+        return (ref,) + ref_vjp(g)
+
+    def close(a, b):
+        return np.allclose(np.asarray(a), np.asarray(b), atol=2e-4, rtol=2e-4)
+
+    for a, b, name in zip(got, softmax_of(window), ("Out", "dQ", "dK", "dV")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4,
+                                   rtol=2e-4, err_msg=name)
+    for off in (window + 1, window - 1):
+        assert not any(close(a, b) for a, b in zip(got, softmax_of(off))), off
+
+
+@pytest.mark.parametrize("why,kw,tiles,want", [
+    ("causal", dict(causal=True), (1024, 1024), (2 * 2 * 2, 2 * 2 * 12)),
+    ("a window of one tile", dict(causal=True, window=1024), None,
+     (2 * 2 * 3, 2 * 2 * 18)),
+    ("a window off the tiles", dict(causal=True, window=1000), None, (0, 0)),
+    ("tiles under 1024", dict(causal=True, window=1024), (512, 512), (0, 0)),
+    ("tiles that are not square", dict(causal=True), (512, 1024), (0, 0)),
+    ("token-major", dict(causal=True, layout="BTHD"), (1024, 1024), (0, 0)),
+    ("one K block a row", dict(causal=True), None, (0, 0)),
+    ("not causal", dict(causal=False), (1024, 1024), (None, None))])
+def test_the_op_tallies_the_edge_tiles_it_runs_in_strips(monkeypatch, why, kw,
+                                                         tiles, want):
+    """`flash_edge_tiles_stripped` and `flash_subblocks_skipped` on the
+    compile event of a built Program: batch x heads x the forward grid's
+    aligned edge tiles and the sub-blocks of them that are not computed; 0
+    of a causal op that falls back (off the alignment, tiles under 1024,
+    token-major operands, a row of one K block: both transformer cells); an
+    op that is not causal
+    does not count, and the grad op's trace adds nothing."""
+    from paddle_tpu import observe
+    monkeypatch.setattr(pallas_attention, "_BLOCK_OVERRIDE", tiles)
+    B, H, T, D = 2, 2, 2048, 16
+    shape = [B, T, H, D] if kw.get("layout") == "BTHD" else [B, H, T, D]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        q = layers.data(name="q", shape=shape, dtype="float32",
+                        append_batch_size=False, stop_gradient=False)
+        loss = layers.reduce_sum(layers.fused_attention(q, q, q, **kw))
+        fluid.append_backward(loss)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(main, feed={"q": np.ones(shape, np.float32)}, fetch_list=[loss],
+            scope=fluid.Scope())
+    detail = observe.observatory().latest(main._uid).detail
+    assert (detail.get("flash_edge_tiles_stripped"),
+            detail.get("flash_subblocks_skipped")) == want

@@ -15,7 +15,9 @@ And that the kernels which run a causal call's interior tiles without the
 mask give the bits of the mask on every tile, at the cells' own scales. And
 that a causal call whose index maps hold every operand on the live
 neighbour of a step above the diagonal gives the bits of the call that
-fetches each one's own block there."""
+fetches each one's own block there. And that a call whose aligned edge tiles
+(the diagonal one, a window's lower one) run in strips over their live
+extent gives the bits of the call that runs them whole under the mask."""
 
 import numpy as np
 import pytest
@@ -201,6 +203,10 @@ def test_unmasked_interior_is_bitwise_the_mask_on_every_tile(monkeypatch,
     `_kept_spec`)."""
     shape, Dv, window, kept = INTERIOR[case]
     B, H, T, D = shape
+    # no tile large enough for strips: a window's tiles are 512 (what they
+    # were until PR 72, and are at a window off the tiles of 1024) and every
+    # edge tile is whole, so what is held is the interior tiles alone
+    monkeypatch.setattr(pallas_attention, "_STRIP_TILE", 1 << 30)
     assert pallas_attention.interior_tiles(T, window) > 0
     if plan == "split":
         monkeypatch.setattr(pallas_attention, "_bwd_plan",
@@ -236,6 +242,124 @@ def test_unmasked_interior_is_bitwise_the_mask_on_every_tile(monkeypatch,
         np.testing.assert_array_equal(a, b, err_msg=name)
 
 
+# name -> (q shape, value width, window, kept set, dropout): the calls whose
+# edge tiles run in strips (`_strip_side`: tiles of 1024, strips of 256), at
+# the cells' shapes: Mellum2's windowed layers and its full layer,
+# Trinity-Mini's windowed layers, Kanana-2's latent heads, Ouro's and
+# OLMoE's, Qwen3-Next's, LFM2's, Keye's call under its kept set (the first
+# case the `dsa_` kernels have in this file against another form of
+# themselves than a held index map), and one with the hardware PRNG's masks
+STRIPS = {"8192x128_w1024": ((1, 32, 8192, 128), 128, 1024, False, 0.0),
+          "8192x128": ((1, 32, 8192, 128), 128, None, False, 0.0),
+          "4096x128_w2048": ((1, 32, 4096, 128), 128, 2048, False, 0.0),
+          "4096x192_128": ((1, 32, 4096, 192), 128, None, False, 0.0),
+          "4096x128": ((1, 16, 4096, 128), 128, None, False, 0.0),
+          "4096x256": ((1, 16, 4096, 256), 256, None, False, 0.0),
+          "4096x64": ((1, 32, 4096, 64), 64, None, False, 0.0),
+          "8192x128_kept": ((1, 32, 8192, 128), 128, None, True, 0.0),
+          "8192x128_dropout": ((1, 32, 8192, 128), 128, None, False, 0.1)}
+
+
+@pytest.mark.parametrize("plan", ["fused", "split"])
+@pytest.mark.parametrize("case", sorted(STRIPS))
+def test_edge_tiles_in_strips_give_the_bits_of_whole_masked_tiles(
+        monkeypatch, case, plan):
+    """By Mosaic's own arithmetic, at the cells' shapes, tiles and scales:
+    `Out`, `Lse`, dQ, dK and dV of the kernels that run an aligned edge tile
+    strip by strip over its live extent (the streaming forward and dQ by
+    rows, dK/dV and the fused backward by keys, its dQ from the key strips'
+    shares side by side) are the bits of the same kernels with every edge
+    tile whole under the mask (`_strip_side` answering "not aligned": the
+    lowered text before PR 72). Every term a strip leaves out of a row's sum
+    of weights or of a product's contraction is an exact zero at one end of
+    the sum, and the MXU adds a contraction's passes in order."""
+    shape, Dv, window, kept, rate = STRIPS[case]
+    B, H, T, D = shape
+    assert pallas_attention.edge_strips(T, window)[0] > 0
+    if plan == "split":
+        monkeypatch.setattr(pallas_attention, "_bwd_plan",
+                            lambda *a: "split")
+    rng = np.random.RandomState(T + D + 72)
+    q, k = (jnp.asarray(rng.randn(*shape), jnp.bfloat16) for _ in range(2))
+    v, g = (jnp.asarray(rng.randn(B, H, T, Dv), jnp.bfloat16)
+            for _ in range(2))
+    if kept:        # a third of the keys below the diagonal, and a row's own
+        kept = jnp.asarray(np.tril(rng.rand(B, T, T) < 0.3)
+                           | np.eye(T, dtype=bool), jnp.int8)
+    else:
+        kept = None
+
+    def run(q, k, v, g):
+        out, lse = pallas_attention._flash_forward(
+            q, k, v, True, D ** -0.5, rate, 77, window, kept=kept)
+        return (out, lse) + pallas_attention._flash_backward(
+            q, k, v, out, lse, g, True, D ** -0.5, rate, 77, window,
+            kept=kept)
+
+    def compiled_run():
+        """`run` traced afresh: the strips in force are read."""
+        return jax.jit(lambda *a: run(*a)).lower(q, k, v, g).compile(
+            compiler_options=resolve_compiler_options("tpu"))(q, k, v, g)
+
+    got = compiled_run()
+    monkeypatch.setattr(pallas_attention, "_strip_side", lambda *a: None)
+    want = compiled_run()
+    for a, b, name in zip(got, want, ("Out", "Lse", "dQ", "dK", "dV")):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a).all() and np.abs(a).max() > 0, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+@pytest.mark.parametrize("case", ["8192x128_w1024", "4096x128_w2048"])
+def test_a_window_at_tiles_of_1024_is_the_call_at_512_to_a_rounding(
+        monkeypatch, case):
+    """A window of whole tiles of 1024 takes them since PR 72 (`_blk`),
+    where it took 512 x 512: another tile is another running maximum under
+    the weights at the moment they are rounded to bf16 for `p v`, and
+    another order of every sum over a row's tiles, so `Out`, `Lse`, dQ, dK
+    and dV of Mellum2's and Trinity-Mini's windowed calls are not the bits
+    they were (as they were not when PR 49 took Trinity-Mini's tiles from
+    1024 to 512): the same arithmetic at the same precision in another
+    order. Held here: float32 `Lse` to 1e-5 of its range, the bf16 results
+    to two roundings of their last place on every element (a sixth of
+    `Out`'s elements differ by one, chip run, PR 72; the shares are
+    printed)."""
+    shape, Dv, window, _, _ = STRIPS[case]
+    B, H, T, D = shape
+    rng = np.random.RandomState(T + D + 1024)
+    q, k, v, g = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+                  for _ in range(4))
+
+    def run(q, k, v, g):
+        out, lse = pallas_attention._flash_forward(
+            q, k, v, True, D ** -0.5, window=window)
+        return (out, lse) + pallas_attention._flash_backward(
+            q, k, v, out, lse, g, True, D ** -0.5, 0.0, 0, window)
+
+    def compiled_run(tiles):
+        """`run` traced afresh: the tile rule in force is read."""
+        assert pallas_attention._blk(T, True, window) == tiles
+        return jax.jit(lambda *a: run(*a)).lower(q, k, v, g).compile(
+            compiler_options=resolve_compiler_options("tpu"))(q, k, v, g)
+
+    got = compiled_run((1024, 1024))
+    monkeypatch.setattr(pallas_attention, "_STRIP_TILE", 1 << 30)
+    want = compiled_run((512, 512))
+    for a, b, name in zip(got, want, ("Out", "Lse", "dQ", "dK", "dV")):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a).all() and np.abs(a).max() > 0, name
+        if name == "Lse":
+            np.testing.assert_allclose(a, b, rtol=0, atol=1e-5 * np.ptp(b),
+                                       err_msg=name)
+            continue
+        np.testing.assert_allclose(a, b, rtol=2 ** -6,
+                                   atol=2 ** -8 * np.abs(b).max(),
+                                   err_msg=name)
+        print(name, "elements off the bit:", float((a != b).mean()),
+              "largest difference:", float(np.abs(a - b).max()),
+              "of", float(np.abs(b).max()))
+
+
 def _held_against_every_step(monkeypatch, run, operands):
     """`run(*operands)` compiled with the index maps in force, then with
     every operand's own block on every grid step (`_dead_steps` answering
@@ -249,9 +373,9 @@ def _held_against_every_step(monkeypatch, run, operands):
     got = compiled_run()
     monkeypatch.setattr(pallas_attention, "_dead_steps", lambda *a: 0)
     # a token-major call is jitted and keeps the trace of the first form
-    monkeypatch.setattr(pallas_attention, "_token_major_forward",
+    monkeypatch.setattr(pallas_attention, "_jitted_forward",
                         pallas_attention._forward)
-    monkeypatch.setattr(pallas_attention, "_token_major_backward",
+    monkeypatch.setattr(pallas_attention, "_jitted_backward",
                         pallas_attention._backward)
     want = compiled_run()
     for a, b, name in zip(got, want, ("Out", "Lse", "dQ", "dK", "dV")):

@@ -395,7 +395,8 @@ def test_flash_kernels_compile_at_latent_attentions_widths(one_chip,
     assert not _custom_calls(bwd, "flash_dkv")
 
 
-@pytest.mark.parametrize("window,tile,steps", [(1024, 512, 3),
+@pytest.mark.parametrize("window,tile,steps", [(1024, 1024, 2),
+                                               (1536, 512, 4),
                                                (1000, 256, 5)])
 def test_windowed_flash_kernels_compile_at_the_cells_shape(one_chip,
                                                            monkeypatch,
@@ -403,7 +404,9 @@ def test_windowed_flash_kernels_compile_at_the_cells_shape(one_chip,
                                                            steps):
     """The streaming forward and the fused backward under a window as
     `mellum2_12b_a2_5b.s8192` calls them: bf16 `[1, 32, 8192, 128]`, tiles of
-    512 x 512 (half the window), the inner grid axis three tiles long; at a
+    1024 x 1024 (the window), their edge tiles in strips, the inner grid axis
+    two tiles long; at a window of 1536 tiles of 512 (half the window and 512
+    at most), every tile whole, four steps; at a
     window of 1000, no multiple of 128, tiles of 256 and five steps; index
     maps with a clamp and a division in them. One Mosaic custom call each, under the names the
     benchmark's patterns tell from the full layer's."""
@@ -432,20 +435,21 @@ def test_windowed_flash_kernels_compile_at_the_cells_shape(one_chip,
     assert not _custom_calls(bwd, "flash_dq")
 
 
-@pytest.mark.parametrize("tiles,steps", [(None, 5), ((1024, 1024), 3)],
-                         ids=["rule_512", "1024"])
+@pytest.mark.parametrize("tiles,steps", [(None, 3), ((512, 512), 5)],
+                         ids=["rule_1024", "512"])
 def test_windowed_flash_kernels_compile_at_a_window_of_2048(one_chip,
                                                             monkeypatch,
                                                             tiles, steps):
     """The second window width, as `trinity_mini_26b_a3b.s4096` calls the
     kernels: bf16 `[1, 32, 4096, 128]` under a window of 2048, where the tile
-    rule (half the window, 512 at most) gives 512 x 512 and an inner grid
-    axis five tiles long; and at 1024 x 1024, three long, what the rule gave
-    before PR 49 measured both. The streaming forward and the fused
+    rule (a window of whole tiles of 1024 takes them, PR 72) gives 1024 x
+    1024, edge tiles in strips, and an inner grid axis three tiles long; and
+    at 512 x 512, five long, what the rule gave from PR 49 to PR 71. The
+    streaming forward and the fused
     backward, one Mosaic custom call each, under the windowed names."""
     from paddle_tpu.ops import pallas_attention as pa
     monkeypatch.setattr(_kernels, "interpret", lambda: False)
-    assert pa._blk(4096, True, 2048) == (512, 512)
+    assert pa._blk(4096, True, 2048) == (1024, 1024)
     if tiles:
         monkeypatch.setattr(pa, "_BLOCK_OVERRIDE", tiles)
     tile = pa._blk(4096, True, 2048)[0]
@@ -512,7 +516,13 @@ def _lowered_digest(lowered):
 # hold K and V, and Q, dOut, `Lse` and delta, on the live neighbour of a step
 # above the diagonal, a `min` / `max` in each map and not an instruction of a
 # kernel's body; the two cases without such a step (not causal; one K block a
-# row) stand as they were taken, and so does every digest of `OTHER_FLASH`
+# row) stand as they were taken, and so does every digest of `OTHER_FLASH`.
+# Since PR 72 the three causal cases here and the two windowed ones of
+# `OTHER_FLASH` run their aligned edge tiles in strips, the windowed ones at
+# tiles of 1024: `IN_STRIPS` holds what they lower to as called (taken on that
+# PR's tree, whose parent is 6752eec), and the digests here are what they
+# lower to with no tile large enough for strips (`_STRIP_TILE` out of reach:
+# every edge tile whole, a window's tiles half the window and 512 at most)
 PLAIN_FLASH = {
     "olmoe_4096x128_causal": (
         (1, 16, 4096, 128), (1, 16, 4096, 128), True,
@@ -539,6 +549,45 @@ PLAIN_FLASH = {
 }
 
 
+# case -> (forward, backward by plan) as called, of the calls whose aligned
+# edge tiles run in strips
+IN_STRIPS = {
+    "olmoe_4096x128_causal": (
+        "3d572cfefa42af83bcb80829f0cfffec",
+        {"fused": "08dd7efbda62c70ab0c3fff061676c1a"}),
+    "kanana_4096x192_128_causal": (
+        "af4dc034bd9679f77fc7e996e7d590d8",
+        {"fused": "0308282d387929d90d6b2dd26e12b985",
+         "split": "618db36dd2750860d1b19a6766183bc7"}),
+    "long_8192x128_causal": (
+        "357ed548abb558959571d14c51bbcc8c",
+        {"fused": "c77088b5bebb9938da8322e805678856",
+         "split": "9c10e52ec69cc3132cf023632abce7c1"}),
+    "mellum2_8192_w1024": (
+        "27dad093ff917fbed899bb2284242202",
+        {"fused": "4cd4ca01baaf49276eabd1343b84627f"}),
+    "trinity_4096_w2048": (
+        "15a04f4e38985c0eee9f7cb9ca1df1ff",
+        {"fused": "b4e88f89462c527095a6aabd082fa29c"}),
+}
+
+
+def _forms(monkeypatch, pa, case, recorded, head_major=True):
+    """(forward digest, backward digests) of the call as it is, where its
+    aligned edge tiles run in strips (`IN_STRIPS`), and then of the call as
+    it was `recorded`: no tile large enough for strips, and a head-major
+    call inline in its caller (since PR 72 it is a call of a jitted function,
+    as a token-major one has been since PR 46: the same kernels, traced and
+    lowered once a signature)."""
+    if case in IN_STRIPS:
+        yield IN_STRIPS[case]
+        monkeypatch.setattr(pa, "_STRIP_TILE", 1 << 30)
+    if head_major:
+        monkeypatch.setattr(pa, "_jitted_forward", pa._forward)
+        monkeypatch.setattr(pa, "_jitted_backward", pa._backward)
+    yield recorded
+
+
 @pytest.mark.parametrize("case", sorted(PLAIN_FLASH))
 def test_flash_kernels_without_a_window_lower_to_the_text_they_did(
         one_chip, monkeypatch, case):
@@ -556,18 +605,23 @@ def test_flash_kernels_without_a_window_lower_to_the_text_they_did(
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     q, v, scale = arg(qs), arg(vs), qs[-1] ** -0.5
-    fwd = jax.jit(lambda q, k, v: pa._flash_forward(
-        q, k, v, causal, scale)).lower(q, q, v)
-    assert _lowered_digest(fwd)[:32] == fwd_digest
     assert pa._bwd_plan(qs[2], qs[3], vs[3], *pa._blk(qs[2], causal),
                         2) == "fused"
-    for plan, digest in bwd_digests.items():
-        if plan == "split":
-            monkeypatch.setattr(pa, "_bwd_plan", lambda *a: "split")
-        bwd = jax.jit(lambda q, k, v, o, lse, g: pa._flash_backward(
-            q, k, v, o, lse, g, causal, scale, 0.0, 0)).lower(
-                q, q, v, v, arg((qs[0] * qs[1], 1, qs[2]), jnp.float32), v)
-        assert _lowered_digest(bwd)[:32] == digest, plan
+    assert bool(causal and pa.edge_strips(qs[2])[0]) == (case in IN_STRIPS)
+    plan_of = pa._bwd_plan
+    for fwd_digest, bwd_digests in _forms(monkeypatch, pa, case,
+                                          (fwd_digest, bwd_digests)):
+        fwd = jax.jit(lambda q, k, v: pa._flash_forward(
+            q, k, v, causal, scale)).lower(q, q, v)
+        assert _lowered_digest(fwd)[:32] == fwd_digest
+        for plan, digest in bwd_digests.items():
+            monkeypatch.setattr(pa, "_bwd_plan", plan_of if plan == "fused"
+                                else lambda *a: "split")
+            bwd = jax.jit(lambda q, k, v, o, lse, g: pa._flash_backward(
+                q, k, v, o, lse, g, causal, scale, 0.0, 0)).lower(
+                    q, q, v, v, arg((qs[0] * qs[1], 1, qs[2]), jnp.float32),
+                    v)
+            assert _lowered_digest(bwd)[:32] == digest, plan
 
 
 # the calls no case of `PLAIN_FLASH` lowers, digests of the forward and of the
@@ -604,8 +658,10 @@ def test_token_major_and_windowed_flash_kernels_lower_to_the_text_they_did(
     """A call without a kept set is the instructions it was where its
     blocks hold several heads in their lanes (`_each_head`'s groups, the
     one-pass forward and the fused backward with dropout, as both
-    transformer cells call them) and where its grid follows a band (the
-    streaming forward and the fused backward with clamped index maps): no
+    transformer cells call them) and, at tiles of 512 with every edge tile
+    whole, where its grid follows a band (the streaming forward and the
+    fused backward with clamped index maps; as called, at tiles of 1024 with
+    their edge tiles in strips, they lower to `IN_STRIPS`): no
     case of `PLAIN_FLASH` is token-major or windowed, and the calls under a
     kept set share `_forward`, `_bwd_specs` and the kernels' bodies with
     these."""
@@ -619,16 +675,21 @@ def test_token_major_and_windowed_flash_kernels_lower_to_the_text_they_did(
 
     B, H, T, D = pa._shape_of(arg(shape), token_major)
     q, seed = arg(shape), arg((), jnp.int32)
-    # without dropout the op hands the kernels the constant 0 for a seed
-    fwd = jax.jit(lambda q, k, v, seed: pa._flash_forward(
-        q, k, v, causal, D ** -0.5, rate, seed if rate else 0, window,
-        token_major)).lower(q, q, q, seed)
-    assert _lowered_digest(fwd)[:32] == fwd_digest
-    bwd = jax.jit(lambda q, k, v, o, lse, g, seed: pa._flash_backward(
-        q, k, v, o, lse, g, causal, D ** -0.5, rate, seed if rate else 0,
-        window, token_major)).lower(
-            q, q, q, q, arg((B * H, 1, T), jnp.float32), q, seed)
-    assert _lowered_digest(bwd)[:32] == bwd_digest
+    assert bool(not token_major and pa.edge_strips(T, window)[0]) == (
+        case in IN_STRIPS)
+    for fwd_digest, bwd_digests in _forms(
+            monkeypatch, pa, case, (fwd_digest, {"fused": bwd_digest}),
+            head_major=not token_major):
+        # without dropout the op hands the kernels the constant 0 for a seed
+        fwd = jax.jit(lambda q, k, v, seed: pa._flash_forward(
+            q, k, v, causal, D ** -0.5, rate, seed if rate else 0, window,
+            token_major)).lower(q, q, q, seed)
+        assert _lowered_digest(fwd)[:32] == fwd_digest
+        bwd = jax.jit(lambda q, k, v, o, lse, g, seed: pa._flash_backward(
+            q, k, v, o, lse, g, causal, D ** -0.5, rate, seed if rate else 0,
+            window, token_major)).lower(
+                q, q, q, q, arg((B * H, 1, T), jnp.float32), q, seed)
+        assert _lowered_digest(bwd)[:32] == bwd_digests["fused"]
 
 
 @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])

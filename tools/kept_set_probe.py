@@ -139,7 +139,7 @@ def main():
     sums = {}
 
     def time_form(form):
-        pa._apply_kept = (lambda s, kept_ref: s) if form == "fetched" \
+        pa._apply_kept = (lambda s, *kept: s) if form == "fetched" \
             else apply
         pa._kept_spec = {"set": spec, "applied": held}.get(form, every_step)
         pa._dead_steps = (lambda *a: 0) if form in (
