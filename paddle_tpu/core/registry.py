@@ -243,7 +243,12 @@ AMP_F32_OPS = frozenset({"log_softmax", "cross_entropy",
                          # strength from two bf16 projections: exp and both
                          # sigmoids in float32; `kda_delta_rule` keeps its
                          # sums, norms and state in float32 inside its rule
-                         "kda_gates"})
+                         "kda_gates",
+                         # a Mamba-1 scan whole: x, dt's raw form, B and C
+                         # arrive bf16 and are widened before the rule, whose
+                         # softplus, decays, state and sums are float32; y
+                         # leaves float32
+                         "selective_scan"})
 # Mixed-dtype elementwise ops downcast the f32 side to bf16 instead of
 # letting numpy promotion upcast the bf16 side: one f32 mask/bias/table
 # leaking into the residual or attention-score stream would otherwise

@@ -9,6 +9,8 @@ IR ops; the executor compiles the whole block into one XLA computation.
 
 from __future__ import annotations
 
+import numpy as np
+
 from ..core import ir
 from ..core import registry as _registry
 from ..core.ir import seqlen_var_name
@@ -1599,6 +1601,51 @@ def ssd_scan(x, b, c, dt_raw, a_log_attr=None, dt_bias_attr=None, d_attr=None,
     states = new("float32", stop_gradient=True)
     helper.append_op("ssd_scan",
                      inputs={"X": [x.name], "Dt": [dt.name], "A": [a.name],
+                             "B": [b.name], "C": [c.name], "D": [skip.name]},
+                     outputs={"Out": [out.name], "States": [states.name]},
+                     attrs={"chunk": int(chunk)})
+    return out
+
+
+def selective_scan(x, dt_raw, b, c, state, a_log_attr=None, dt_bias_attr=None,
+                   d_attr=None, chunk=128, name=None):
+    """The selective scan of a Mamba-1 layer (`ops/selective_scan.py`) on x
+    and `dt_raw` `[batch, seq, channels]` and b, c `[batch, seq, state]`:
+    `dt = softplus(dt_raw + dt_bias)`, `A = -exp(A_log)` with the learned
+    `A_log` `[channels, state]`, `dt_bias` and the skip `D` `[channels]`
+    (`a_log_attr`, `dt_bias_attr`, `d_attr`; float32; by default `A_log` =
+    log(1..state) a channel, `dt_bias` 0, `D` 1). Per channel c and state n a
+    float32 state from 0: `S_t = exp(dt_t[c] A[c, n]) S_{t-1} + dt_t[c]
+    b_t[n] x_t[c]`, `y_t[c] = sum_n S_t c_t[n] + D[c] x_t[c]`. The op is on
+    AMP_F32_OPS: bf16 operands are widened before its rule and the result is
+    float32 `[batch, seq, channels]`. `chunk`: the tokens whose states the
+    plain form holds at once (the kernels' chunk is 128).
+
+    The op has a second output, `States`: float32 `[seq / 128, batch, state,
+    channels]`, the state each chunk of 128 tokens started from, as the
+    forward kernel `sscan_fwd` saves it. `selective_scan_grad` reads it back
+    and runs `sscan_bwd` alone. Where the forward op wrote none (channels off
+    the lane tile, a CPU backend: the plain form) the grad op traces the scan
+    again under `jax.vjp`."""
+    helper = LayerHelper("selective_scan", name=name)
+    channels = x.shape[-1]
+    a_log = helper.create_parameter(
+        a_log_attr, [channels, state], "float32",
+        default_initializer=init.NumpyArrayInitializer(np.tile(
+            np.log(np.arange(1, state + 1, dtype="float32")),
+            (channels, 1))))
+    dt_bias = helper.create_parameter(
+        dt_bias_attr, [channels], "float32",
+        default_initializer=init.ConstantInitializer(0.0))
+    skip = helper.create_parameter(
+        d_attr, [channels], "float32",
+        default_initializer=init.ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference("float32")
+    states = helper.create_variable_for_type_inference("float32",
+                                                       stop_gradient=True)
+    helper.append_op("selective_scan",
+                     inputs={"X": [x.name], "DtRaw": [dt_raw.name],
+                             "DtBias": [dt_bias.name], "ALog": [a_log.name],
                              "B": [b.name], "C": [c.name], "D": [skip.name]},
                      outputs={"Out": [out.name], "States": [states.name]},
                      attrs={"chunk": int(chunk)})

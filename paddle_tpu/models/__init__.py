@@ -45,7 +45,13 @@ between two gates that are projections of the same input) in three layers of
 four beside QK-normed rotary attention, routed experts with no shared expert
 under a sigmoid router whose renormalisation publishes its epsilon, a tied
 table (the first model most of whose mixers hold neither attention nor a
-recurrence)."""
+recurrence), and Phi-4-mini-flash: Mamba-1 mixers (a selective scan whose decay
+is per channel and per state) beside differential attention (two softmax maps
+a pair of heads, their difference under a learned scalar) under a window and
+full, gated memory units on an earlier layer's scan output and cross
+attention on an earlier layer's keys and values, LayerNorm (the first model
+whose layers read what other layers computed, and the first built as a run of
+its own layers that a later stage would be handed memory from)."""
 
 from . import mnist  # noqa: F401
 from . import resnet  # noqa: F401
@@ -68,3 +74,4 @@ from . import ling3  # noqa: F401
 from . import olmo_hybrid  # noqa: F401
 from . import granite_hybrid  # noqa: F401
 from . import lfm2_moe  # noqa: F401
+from . import phi4_flash  # noqa: F401

@@ -24,8 +24,11 @@ def parameter_sharing(program: ir.Program) -> Dict[str, int]:
     every parameter has one contribution, 0 in a program without a backward
     pass. An unrolled loop over shared layers reads `uses = loops x
     parameters` and a fan-in of `loops`; a copy of the weights per pass
-    would read a fan-in of 1. Goes on the program's compile events
-    (`observe.observatory()`, `detail`)."""
+    would read a fan-in of 1. PARAMETERS' fan-in only: the contributions
+    summed into an activation that several ops read (a layer's keys and
+    values or a scan's output read by later layers) are counted by
+    `layer_census`'s `activation_grad_fanin_max`, not here. Goes on the
+    program's compile events (`observe.observatory()`, `detail`)."""
     block = program.global_block()
     params = {p.name for p in block.all_parameters()}
     grads = {grad_var_name(n) for n in params}
@@ -112,6 +115,28 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     layers' `attention_kv_group` as one with scans does (`causal_conv_plan`,
     the form the convolution ran in, is noted by the op's rule on the same
     event).
+    `selective_scan`: a `selective_scan` op (a Mamba-1 mixer: a decay per
+    channel and state), with `selective_scan_layers`, their count again as a
+    flat number, and `selective_scan_state`, the states a channel
+    (`selective_scan_plan`, the form the scan ran in, and
+    `selective_scan_grid_steps` are noted by the op's rule on the same
+    event). `differential_attention`: a `name_scope` that holds TWO
+    `fused_attention` ops and an `elementwise_sub` (two softmax maps a pair of
+    heads and their difference), whose keys were made under the same layer,
+    with `diff_attention_layers`, every such scope whoever made its keys, as
+    a flat number; `cross_decoder_attention`: such a scope whose calls read
+    keys made under ANOTHER layer's scope, with `shared_kv_readers`, their
+    count again. A differential layer under a window shorter than its
+    sequence counts in `attention_window_layers` with `attention_window`
+    beside it, as a windowed `fused_attention` does, and is no
+    `window_attention`, `latent_attention` or `full_attention` layer.
+    `gated_memory`: a `swiglu` whose `Up` is the output of a
+    `selective_scan` op of another layer (a gated memory unit), with
+    `memory_readers`, their count again. Where the program has either kind of
+    reader, `activation_grad_fanin_max`: the most gradient contributions the
+    backward pass sums into one of the tensors those readers read (the
+    served keys, the served values, the scan's output): the longest `X` of
+    the `sum` ops that write their gradients.
     (`moe_row_buffer_rows`, the rows of the expert layer's layout, follows
     the batch: `moe_dispatch`'s rule notes it on the same event under the
     trace, `LoweringContext.note`.)"""
@@ -119,7 +144,9 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     kinds = {"linear_attention": 0, "full_attention": 0,
              "latent_attention": 0, "window_attention": 0,
              "sparse_attention": 0, "state_space": 0, "kda": 0,
-             "short_conv": 0}
+             "short_conv": 0, "selective_scan": 0,
+             "differential_attention": 0, "cross_decoder_attention": 0,
+             "gated_memory": 0}
     out: Dict[str, object] = {}
     copies: Dict[str, str] = {}     # an `assign` op's result -> what it copied
     biases = []                     # the routers' selection biases
@@ -137,13 +164,45 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     #                                     of scans and delta rules
     sliced, products = set(), []        # `slice` results; (layer, operands)
     #                                     of the `elementwise_mul`s
-    for op in block.ops:
-        if op.attrs.get("__role__") is not None:
-            continue
+    forward = [op for op in block.ops if op.attrs.get("__role__") is None]
+    differential = _differential_scopes(forward)
+    made_under = {n: op.attrs.get(ir.NAME_SCOPE_ATTR) for op in forward
+                  for n in op.output_arg_names}
+    scans = {}                          # a `selective_scan`'s Out -> its scope
+    shared = set()                      # tensors another layer's ops read
+    windowed_pairs = 0                  # differential scopes under a window
+    counted = set()                     # differential scopes met already
+    for op in forward:
         scope = op.attrs.get(ir.NAME_SCOPE_ATTR)
         layer = scope.split("/")[0] if scope else None
-        if op.type in ("gated_delta_rule", "ssd_scan", "kda_delta_rule"):
+        if op.type in ("gated_delta_rule", "ssd_scan", "kda_delta_rule",
+                       "selective_scan"):
             recurrent.add(layer)
+        if op.type == "selective_scan":
+            kinds["selective_scan"] += 1
+            scans[op.output("Out")[0]] = scope
+            out["selective_scan_state"] = \
+                block.var(op.input("ALog")[0]).shape[-1]
+            continue
+        if op.type == "swiglu" and scans.get(op.input("Up")[0],
+                                             scope) != scope:
+            kinds["gated_memory"] += 1
+            shared.add(op.input("Up")[0])
+        if op.type == "fused_attention" and scope in differential:
+            keys = op.input("K")[0]
+            borrowed = made_under.get(keys) != scope
+            if borrowed:
+                shared.update(op.input("K") + op.input("V"))
+            if scope not in counted:            # the scope's first call
+                counted.add(scope)
+                kinds["cross_decoder_attention" if borrowed
+                      else "differential_attention"] += 1
+                window = op.attrs.get("window")
+                if window is not None \
+                        and window < block.var(keys).shape[-2]:
+                    windowed_pairs += 1
+                    out["attention_window"] = window
+            continue
         if op.type == "causal_conv1d" and not op.attrs.get("activation",
                                                            "silu"):
             bare_convs.append((layer,
@@ -226,8 +285,9 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
         elif op.type == "moe_dispatch":
             out["moe_experts_held"] = op.attrs.get(
                 "experts_held", out.get("moe_experts_routed"))
-    if kinds["window_attention"]:
-        out["attention_window_layers"] = kinds["window_attention"]
+    if kinds["window_attention"] or windowed_pairs:
+        out["attention_window_layers"] = kinds["window_attention"] \
+            + windowed_pairs
     if kinds["sparse_attention"]:
         out["dsa_layers"] = kinds["sparse_attention"]
     if kinds["kda"]:
@@ -244,6 +304,21 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
             if layer in short and sliced.intersection(operands))
     if kinds["state_space"]:
         out["state_space_layers"] = kinds["state_space"]
+    if kinds["selective_scan"]:
+        out["selective_scan_layers"] = kinds["selective_scan"]
+    pairs = kinds["differential_attention"] + kinds["cross_decoder_attention"]
+    if pairs:
+        out["diff_attention_layers"] = pairs
+    if kinds["cross_decoder_attention"]:
+        out["shared_kv_readers"] = kinds["cross_decoder_attention"]
+    if kinds["gated_memory"]:
+        out["memory_readers"] = kinds["gated_memory"]
+    if shared:
+        grads = {grad_var_name(n) for n in shared}
+        out["activation_grad_fanin_max"] = max(
+            [len(op.input("X")) for op in block.ops
+             if op.attrs.get("__role__") == "backward" and op.type == "sum"
+             and op.output("Out")[0] in grads], default=1)
     if kinds["state_space"] or kinds["short_conv"]:
         group = max([_expanded_by(block, k) for k in full_keys], default=1)
         if group > 1:
@@ -280,6 +355,21 @@ def layer_census(program: ir.Program) -> Dict[str, object]:
     if scaled & added:
         out["residual_scaled_sublayers"] = len(scaled & added)
     return out
+
+
+def _differential_scopes(forward):
+    """Every `name_scope` that holds two `fused_attention` ops and an
+    `elementwise_sub`: the two softmax maps of a differential attention layer
+    and their difference."""
+    calls, subtracts = {}, set()
+    for op in forward:
+        scope = op.attrs.get(ir.NAME_SCOPE_ATTR)
+        if op.type == "fused_attention":
+            calls[scope] = calls.get(scope, 0) + 1
+        elif op.type == "elementwise_sub":
+            subtracts.add(scope)
+    return {scope for scope, n in calls.items()
+            if scope is not None and n == 2 and scope in subtracts}
 
 
 def _expanded_by(block, name) -> int:

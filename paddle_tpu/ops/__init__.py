@@ -20,4 +20,5 @@ from . import decoder_block  # noqa: F401
 from . import moe  # noqa: F401
 from . import linear_attention  # noqa: F401
 from . import state_space  # noqa: F401
+from . import selective_scan  # noqa: F401
 from . import sparse_attention  # noqa: F401

@@ -210,8 +210,8 @@ def _blk(T, causal=False, window=None):
     innermost dimension, VMEM per program is O(blk_q * blk_k + blk * D)
     regardless of T — no sequence-length cap (validated to seq 32768).
 
-    Under a `window` shorter than T the tiles are square, at most half the
-    window and at most 512 (128 at least): a band of W keys a row meets about
+    Under a `window` shorter than T the tiles are square, 512 under a window
+    of 512 or more and half a shorter window (128 at least): a band of W keys a row meets about
     W + b keys of b-wide tiles, so 1024 x 1024 tiles at W = 1024 compute
     twice the visible pairs and 512 x 512 one and a half times. Measured at
     two shapes, bf16, forward + backward a layer: [32, 8192, 128] with W =
@@ -230,10 +230,17 @@ def _blk(T, causal=False, window=None):
     runs, PR 72, `tools/interior_mask_probe.py`), tiles of 512 whole ->
     tiles of 1024 in strips: W = 1024 over 8192 tokens 6.185 -> 5.541 ms (a
     head's 45 tiles -> 15, every one an edge tile), W = 2048 over 4096 3.658
-    -> 3.328 (30 -> 9, six of them edge tiles). Every other length and
-    window takes the rule of half the window as a default that no run has
-    tried, and does not consult the sweep table above, whose entries were
-    measured without a window."""
+    -> 3.328 (30 -> 9, six of them edge tiles). A window of 512 over 4096
+    tokens (a differential layer's map: 20 heads, q and k at 64, v at 128;
+    chip run, PR 73, `tools/window_tile_probe.py --value-dim 128`), forward +
+    fused backward a call: 1.679 ms at the rule's 256^2 (half the window),
+    **1.248 at 512^2**, 1.901 at 1024^2, 1.444-1.639 at the four mixed shapes
+    (without a window there 2.326 at 1024^2): tiles of 256 cost more in grid
+    steps than they save in masked pairs, as they did at both longer windows,
+    so since PR 73 a window of 512 or more takes 512 and only a shorter one
+    half the window (128 at least), which no run has tried. The rule does not
+    consult the sweep table above, whose entries were measured without a
+    window."""
     if _BLOCK_OVERRIDE is not None:
         bq, bk = _BLOCK_OVERRIDE
         if T % bq == 0 and T % bk == 0:
@@ -241,8 +248,9 @@ def _blk(T, causal=False, window=None):
     if window is not None and window < T:
         if window % _STRIP_TILE == 0 and T % _STRIP_TILE == 0:
             return _STRIP_TILE, _STRIP_TILE
+        limit = 512 if window >= 512 else max(window // 2, 128)
         for b in (512, 256, 128):
-            if T % b == 0 and b <= max(window // 2, 128):
+            if T % b == 0 and b <= limit:
                 return b, b
     tbl = _table_blk(T, causal)
     if tbl is not None and T % tbl[0] == 0 and T % tbl[1] == 0:

@@ -1,4 +1,4 @@
-"""One harness for a decoder model's checks: what the twelve
+"""One harness for a decoder model's checks: what the thirteen
 `tests/test_<model>.py` files share, written once. pytest does not collect
 this module (as it does not `attention_program.py`); no test module imports
 another.
@@ -196,6 +196,7 @@ CONFIGS = {"granite_hybrid": "granite_4_0_h_micro",
            "keye_vl2": "keye_vl_2_30b_a3b", "lfm2_moe": "lfm2_8b_a1b",
            "ling3": "ling_3_0_flash_vl",
            "nemotron_h": "nemotron_3_nano_30b_a3b",
+           "phi4_flash": "phi_4_mini_flash_reasoning",
            "trinity": "trinity_mini_26b_a3b"}
 # a YaRN block that bends the frequencies of a 16-wide head: low 0, high 3
 TINY_YARN = {"factor": 4.0, "original_max_position_embeddings": 64,
@@ -315,6 +316,8 @@ DIGESTS = {
                          "25752028f333b5369d0c325de1a10c8e"),
     "ouro": (790, "6ea230c9082de14ccaa4df13364540aa"
                   "790498e29ea98a844998f066efecfee8"),
+    "phi4_flash": (1249, "3478f951db8a660db86da3280297bdda"       # PR 73's own
+                         "e3611e1ec7f2fb1ba68140a85673fa88"),
     "qwen3_next": (1021, "e431cca320eca95789aee1fbdb3a8e2f"
                          "b2607d1abf037deb504eee331f0fb8e3"),
     "trinity": (1231, "391890374a0cbe3ad429a97497bb07a2"

@@ -14,7 +14,10 @@ model, moved the Mamba-2 mixer from `nemotron_h.py` to `_decoder.py` with
 Nemotron-H's digest unmoved, and gave the census of a program with `ssd_scan`
 ops its groups, heads a group and chunk; `lfm2_moe`'s on PR 69, which added
 the model, `layers.causal_conv1d(activation=)`, `noaux_router(norm_eps=)` and
-the census kind `short_conv`, with every other digest and census unmoved) at
+the census kind `short_conv`, with every other digest and census unmoved;
+`phi4_flash`'s on PR 73, which added the model, the op `selective_scan` and the
+census kinds `selective_scan`, `differential_attention`,
+`cross_decoder_attention` and `gated_memory`, likewise) at
 the models' own tests' tiny sizes (`decoder_case.tiny_args`), forward,
 backward and Adam; after a deliberate change to a model take them again with
 `program_digest(*build_program(model)[:2])` and
@@ -93,6 +96,15 @@ CENSUS = {
         "parameters": 27, "parameter_uses": 103, "grad_fanin_max": 4,
         "layer_kinds": {"full_attention": 8}, "attention_rotary_layers": 8,
         "residual_out_norms": 19},
+    "phi4_flash": {
+        "parameters": 100, "parameter_uses": 101, "grad_fanin_max": 2,
+        "selective_scan_state": 8, "attention_window": 48,
+        "attention_window_layers": 1,
+        "layer_kinds": {"selective_scan": 2, "differential_attention": 2,
+                        "cross_decoder_attention": 1, "gated_memory": 1},
+        "selective_scan_layers": 2, "diff_attention_layers": 3,
+        "shared_kv_readers": 1, "memory_readers": 1,
+        "activation_grad_fanin_max": 4, "tied_heads": 1},
     "qwen3_next": {
         "parameters": 70, "parameter_uses": 70, "grad_fanin_max": 1,
         "moe_experts_routed": 16, "moe_experts_held": 4,
@@ -128,7 +140,7 @@ SHARED = {"models": "_decoder", "ops": "_kernels"}
 # the model families' op modules, whose op types the autodiff module may
 # not name
 FAMILY_OPS = ("pallas_attention", "moe", "linear_attention", "state_space",
-              "decoder_block", "sparse_attention")
+              "selective_scan", "decoder_block", "sparse_attention")
 
 
 def _sources(package):
@@ -162,7 +174,8 @@ def test_the_autodiff_module_names_no_op_of_a_model_family():
     family = {t for t in registry.registered_ops()
               if registry.get_op_def(t).lower.__module__ in modules}
     assert {"fused_attention", "moe_router", "gated_delta_rule", "ssd_scan",
-            "rms_norm", "dsa_select"} <= family, sorted(family)
+            "selective_scan", "rms_norm", "dsa_select"} <= family, \
+        sorted(family)
     with open(os.path.join(PACKAGE, "core", "backward.py")) as f:
         tree = ast.parse(f.read())
     named = {node.value for node in ast.walk(tree)

@@ -397,7 +397,7 @@ def test_flash_kernels_compile_at_latent_attentions_widths(one_chip,
 
 @pytest.mark.parametrize("window,tile,steps", [(1024, 1024, 2),
                                                (1536, 512, 4),
-                                               (1000, 256, 5)])
+                                               (1000, 512, 3)])
 def test_windowed_flash_kernels_compile_at_the_cells_shape(one_chip,
                                                            monkeypatch,
                                                            window, tile,
@@ -407,7 +407,8 @@ def test_windowed_flash_kernels_compile_at_the_cells_shape(one_chip,
     1024 x 1024 (the window), their edge tiles in strips, the inner grid axis
     two tiles long; at a window of 1536 tiles of 512 (half the window and 512
     at most), every tile whole, four steps; at a
-    window of 1000, no multiple of 128, tiles of 256 and five steps; index
+    window of 1000, no multiple of 128, tiles of 512 and three steps (256 and
+    five until PR 73: half the window lost to 512 on the chip); index
     maps with a clamp and a division in them. One Mosaic custom call each, under the names the
     benchmark's patterns tell from the full layer's."""
     from paddle_tpu.ops import pallas_attention as pa
@@ -965,6 +966,36 @@ def test_nemotron_h_scan_lowers_as_it_did_before_head_blocks(one_chip,
     lowered = dict(zip(("forward", "backward"),
                        _scan_lowerings(one_chip, 8, 128)))[way]
     assert _lowered_digest(lowered)[:32] == NEMOTRON_SCAN[way]
+
+
+def test_phi4_flash_scan_kernels_compile_at_the_cells_shapes(one_chip,
+                                                             monkeypatch):
+    """`sscan_fwd` / `sscan_bwd` as `phi_4_mini_flash_reasoning.s4096` calls
+    them: float32 x and dt `[1, 4096, 5120]`, A `[5120, 16]`, B and C `[1,
+    4096, 16]`, a grid of 32 chunks x 10 channel blocks of 512. One Mosaic
+    custom call each way; the forward's first result is the 32 saved states
+    (10.5 MB, not the 1.34 GB of `[4096, 5120, 16]`), the backward writes
+    dB's and dC's per-lane parts; neither program holds an array of
+    `[4096, 5120, 16]` or `[4096, 16, 5120]`."""
+    from paddle_tpu.ops import selective_scan as sscan
+    monkeypatch.setattr(_kernels, "on_chip", lambda: True)
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    assert sscan._kernels_run(4096, 5120, 16)
+    x, A, bc, D = arg((1, 4096, 5120)), arg((5120, 16)), \
+        arg((1, 4096, 16)), arg((5120,))
+    fwd = jax.jit(sscan._sscan_forward).lower(x, x, A, bc, bc, D).compile()
+    (call,) = _custom_calls(fwd, "sscan_fwd")
+    assert "(f32[32,1,16,5120]{" in call and "tpu_custom_call" in call
+    bwd = jax.jit(sscan._sscan_backward).lower(
+        x, x, A, bc, bc, D, arg((32, 1, 16, 5120)), x).compile()
+    (call,) = _custom_calls(bwd, "sscan_bwd")
+    assert call.count("f32[1,4096,16,128]{") >= 2 and "tpu_custom_call" in call
+    for text in (fwd.as_text(), bwd.as_text()):
+        assert "[4096,5120,16]" not in text and "[4096,16,5120]" not in text
 
 
 # the two kernel pairs as `ling_3_0_flash_vl.s2048` calls them and no other

@@ -588,6 +588,12 @@ SPECS.update({
                              "A": T(1, 8, 2, lo=-1.0, hi=-0.05),
                              "B": T(1, 8, 1, 4), "C": T(1, 8, 1, 4),
                              "D": T(2)}, attrs={"chunk": 4}),
+    # two chunks of four tokens, three channels of two states each
+    "selective_scan": Spec(inputs={"X": T(1, 8, 3), "DtRaw": T(1, 8, 3),
+                                   "DtBias": T(3, lo=-2.0, hi=0.0),
+                                   "ALog": T(3, 2), "B": T(1, 8, 2),
+                                   "C": T(1, 8, 2), "D": T(3)},
+                           attrs={"chunk": 4}),
     "moe_router": Spec(inputs={"X": T(6, 5), "W": T(5, 4) * 2},
                        attrs={"k": 2},
                        outs=("TopKWeight", "TopKIndex", "TokensPerExpert",

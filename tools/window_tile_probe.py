@@ -3,9 +3,10 @@
 most"). TPU-only. Two modes:
 
     python tools/window_tile_probe.py [--seq 4096] [--window 2048]
-        [--heads 32] [--head-dim 128]
+        [--heads 32] [--head-dim 128] [--value-dim DV]
       the kernels alone: forward + fused backward a call at
-      bf16[1, heads, seq, head_dim], causal, sixteen calls chained in one
+      bf16[1, heads, seq, head_dim] (v at `--value-dim` where given: a
+      differential layer's 64 / 128), causal, sixteen calls chained in one
       jitted loop, median of five slopes on the host's clock, for the rule's
       own choice and for each tile forced through `_BLOCK_OVERRIDE`; then the
       same without a window at the rule's choice, 1024^2 and 512^2.
@@ -16,7 +17,8 @@ most"). TPU-only. Two modes:
       flash call's tiles forced: run it beside the same cell and seed without
       this wrapper for an A/B of the rule in the step.
 
-Read on the chip (PR 49, calls 1 and 5): `PERF.md` section 6.
+Read on the chip (PR 49, calls 1 and 5; PR 73 at a window of 512 over 4096
+tokens, 20 heads of 64 / 128): `PERF.md` section 6.
 """
 
 from __future__ import annotations
@@ -35,15 +37,15 @@ TILES = (None, (1024, 1024), (512, 512), (256, 256), (512, 1024),
          (1024, 512), (256, 512), (512, 256))
 
 
-def kernels_alone(T, W, H, D):
+def kernels_alone(T, W, H, D, Dv=None):
     import jax
     import jax.numpy as jnp
     import numpy as np
     from paddle_tpu.ops import pallas_attention as pa
 
     rng = np.random.RandomState(0)
-    q, k, v = (jnp.asarray(rng.randn(1, H, T, D), jnp.bfloat16)
-               for _ in range(3))
+    q, k, v = (jnp.asarray(rng.randn(1, H, T, width), jnp.bfloat16)
+               for width in (D, D, Dv or D))
     seed = jnp.int32(0)
 
     def make_step(tiles, window):
@@ -114,6 +116,7 @@ def main():
     ap.add_argument("--window", type=int, default=2048)
     ap.add_argument("--heads", type=int, default=32)
     ap.add_argument("--head-dim", type=int, default=128)
+    ap.add_argument("--value-dim", type=int)
     ap.add_argument("--cell")
     ap.add_argument("--tiles", type=int, nargs=2)
     ap.add_argument("--seed", type=int, default=0)
@@ -122,7 +125,8 @@ def main():
     if args.cell:
         cell_with_tiles(args.cell, args.tiles, args.seed, args.seconds, rest)
     else:
-        kernels_alone(args.seq, args.window, args.heads, args.head_dim)
+        kernels_alone(args.seq, args.window, args.heads, args.head_dim,
+                      args.value_dim)
 
 
 if __name__ == "__main__":
